@@ -1,0 +1,724 @@
+"""chip_smoke.py — the quickest proof that the trainer still starts on the chip.
+
+    python chip_smoke.py            # on a TPU host, through the chip tool
+    JAX_PLATFORMS=cpu python chip_smoke.py --tiny    # the CPU rehearsal
+
+One process drives the training path — fluid Program -> core/lowering.py ->
+Executor(TPUPlace()).run — through the entry points a user calls, at the
+full width and depth of the two headline models, on random weights made
+from a seed, and checks what comes out by the repo's own means:
+
+  A  ResNet-50 (the BASELINE.json headline): bf16, batch 256, Momentum;
+     single steps on one device-resident batch, then one run(steps=K)
+     block so the lax.scan lowering compiles too.
+  B  the base transformer (bench.py's config) at T=256 (dense attention,
+     Pallas layer_norm + softmax_xent) and T=2048 (flash fwd + both bwd).
+  C  every Pallas kernel family, as a one-op Program at the shape and
+     dtype its shipped model gives it, forward and backward, against the
+     same op's XLA lowering under a written tolerance — and the trace is
+     inspected: the kernel was dispatched, and not interpreted on a TPU.
+  D  (>= 4 devices) ParallelExecutor over a dp=4 mesh on phase A's
+     program, replicated and with the ZeRO-sharded weight update.
+  E  the timing-barrier premise: K steps timed to jax.block_until_ready
+     and to a device->host fetch agree (core/utils.device_fetch_barrier).
+
+Every phase that fails makes the exit code non-zero. Timings are printed
+for the next reader, labelled with the device; they are not metrics. The
+last line of stdout is one JSON object, {"ok": ..., "device": {...}}.
+Exit codes: 0 all phases passed, 1 a phase failed, 2 no TPU (nothing ran).
+"""
+import argparse
+import json
+import os
+import statistics
+import sys
+import time
+import traceback
+
+import numpy as np
+
+FULL = {
+    "resnet": dict(model="resnet50", class_dim=1000, hw=224, batch=256,
+                   steps=8, scan_steps=4),
+    "transformer": dict(n_layer=6, d_model=512, n_head=8, d_inner=2048,
+                        vocab=30000, steps=4,
+                        # (T, batch): below / above the flash crossover
+                        shapes=((256, 32), (2048, 4))),
+    "kernels": dict(
+        attn=dict(b=4, t=2048, h=8, d=64),          # transformer T=2048
+        xent=dict(n=8192, v=30000),                 # its [B*T, vocab] loss
+        ln=dict(b=4, t=2048, d=512),                # its d_model rows
+        # dynamic_lstm hidden sizes of stacked_lstm (bench: 512/4),
+        # language_model (64) and machine_translation (32)
+        lstm=(dict(b=128, t=64, d=128, reverse=True),
+              dict(b=32, t=32, d=64, reverse=False),
+              dict(b=32, t=16, d=32, reverse=False)),
+        lstmp=dict(b=128, t=64, d=128, p=64),
+        seq_softmax=dict(b=64, t=50),               # MT attention scores
+        seq_pool=dict(b=128, t=64, f=32)),          # sentiment conv pool
+    "barrier": dict(steps=5, rounds=3, tol=0.15),
+    "dp_loss_rtol": 2e-2,
+}
+TINY = {
+    "resnet": dict(model="resnet20", class_dim=10, hw=32, batch=8,
+                   steps=4, scan_steps=2),
+    "transformer": dict(n_layer=1, d_model=32, n_head=2, d_inner=64,
+                        vocab=64, steps=3, shapes=((16, 4), (32, 2))),
+    "kernels": dict(
+        attn=dict(b=2, t=32, h=2, d=16),
+        xent=dict(n=32, v=64),
+        ln=dict(b=2, t=16, d=32),
+        lstm=(dict(b=5, t=6, d=8, reverse=True),),
+        lstmp=dict(b=5, t=6, d=8, p=4),
+        seq_softmax=dict(b=6, t=10),
+        seq_pool=dict(b=6, t=9, f=4)),
+    "barrier": dict(steps=5, rounds=3, tol=0.75),
+    "dp_loss_rtol": 2e-2,
+}
+# tiny runs pin the flash crossover to their long T and turn the kernels
+# on, so the CPU rehearsal walks the same dispatch (interpreted)
+TINY_ENV = {"PADDLE_TPU_PALLAS": "1", "FLAGS_flash_min_seq": "32"}
+
+# written tolerances for phase C, as max|a-b| / (max|b| + 1e-6). f32
+# elementwise kernels differ from XLA only by exp/log/rsqrt rounding; the
+# LSTM kernels and flash multiply on the MXU, where Mosaic's and XLA's
+# f32/bf16 matmul passes differ and T recurrent steps compound it.
+TOL = {"attn": 3e-2, "xent": 1e-4, "ln": 1e-4, "lstm": 3e-2, "seq": 1e-4}
+
+
+class Smoke(object):
+    """What the phases share: the device, the sizes, the compile
+    counters, and phase A's program for D and E."""
+
+    def __init__(self, device, n_devices, cfg, counts):
+        self.device = device
+        self.n_devices = n_devices
+        self.cfg = cfg
+        self.counts = counts        # XLA compile requests / cache hits
+        self.tag = "[platform=%s kind=%s]" % (device.platform,
+                                              device.device_kind)
+        self.resnet = None          # set by phase A
+
+    def say(self, msg):
+        print("%s %s" % (self.tag, msg), flush=True)
+
+
+def _check_training(name, losses):
+    if not all(np.isfinite(losses)):
+        raise AssertionError("%s: non-finite loss in %r" % (name, losses))
+    if not losses[-1] < losses[0]:
+        raise AssertionError("%s: loss did not fall: %r" % (name, losses))
+
+
+def _train_steps(smoke, name, exe, program, feed, loss, steps):
+    """`steps` single steps on one fixed batch; returns the losses. Checks
+    that nothing compiles after step 1."""
+    losses, secs = [], []
+    for i in range(steps):
+        t0 = time.perf_counter()
+        out, = exe.run(program, feed=feed, fetch_list=[loss])
+        secs.append(time.perf_counter() - t0)
+        losses.append(float(np.ravel(out)[0]))
+        if i == 0:
+            cached = len(exe._cache)
+            requests = smoke.counts["requests"]
+    if len(exe._cache) != cached:
+        raise AssertionError("%s: executor jit cache grew after step 1 "
+                             "(%d -> %d)" % (name, cached, len(exe._cache)))
+    if smoke.counts["requests"] != requests:
+        raise AssertionError("%s: %d XLA compile request(s) after step 1"
+                             % (name, smoke.counts["requests"] - requests))
+    smoke.say("%s: step 1 %.2fs (compile + run), steps 2-%d median %.4fs; "
+              "loss %.4f -> %.4f" % (name, secs[0], steps,
+                                     statistics.median(secs[1:]),
+                                     losses[0], losses[-1]))
+    _check_training(name, losses)
+    return losses
+
+
+def _check_state_on_device(smoke, name, scope):
+    import jax
+    n = 0
+    for var in scope.names():
+        v = scope.get(var)
+        if isinstance(v, jax.Array):
+            n += 1
+            if any(d.platform != smoke.device.platform
+                   for d in v.devices()):
+                raise AssertionError(
+                    "%s: persistable %r lives on %s, not on a %s device"
+                    % (name, var, v.devices(), smoke.device.platform))
+    if n == 0:
+        raise AssertionError("%s: no device-resident persistable" % name)
+    smoke.say("%s: %d persistables, all on %s" % (name, n,
+                                                 smoke.device.platform))
+
+
+# --------------------------------------------------------------- phase A --
+def phase_a(smoke):
+    import jax
+    import paddle_tpu as fluid
+    from paddle_tpu.models.image_classification import build_train
+
+    c = smoke.cfg["resnet"]
+    main, startup = fluid.Program(), fluid.Program()
+    main.random_seed = startup.random_seed = 1
+    with fluid.unique_name.guard(), fluid.program_guard(main, startup):
+        _, _, avg_cost, _ = build_train(
+            model=c["model"], class_dim=c["class_dim"],
+            image_shape=(3, c["hw"], c["hw"]), use_bf16=True)
+    rng = np.random.RandomState(0)
+    host_feed = {
+        "image": rng.rand(c["batch"], 3, c["hw"], c["hw"]).astype("f"),
+        "label": rng.randint(0, c["class_dim"],
+                             (c["batch"], 1)).astype("int32")}
+    feed = {k: jax.device_put(v, smoke.device) for k, v in host_feed.items()}
+    exe = fluid.Executor(fluid.TPUPlace())
+    scope = fluid.Scope()
+    name = "%s b%d bf16" % (c["model"], c["batch"])
+    with fluid.scope_guard(scope):
+        t0 = time.perf_counter()
+        exe.run(startup)
+        smoke.say("%s: startup %.2fs" % (name, time.perf_counter() - t0))
+        losses = _train_steps(smoke, name, exe, main, feed, avg_cost,
+                              c["steps"])
+        _check_state_on_device(smoke, name, scope)
+        t0 = time.perf_counter()
+        block, = exe.run(main, feed=feed, fetch_list=[avg_cost],
+                         steps=c["scan_steps"])
+        smoke.say("%s: run(steps=%d) %.2fs (compile + run), losses %s"
+                  % (name, c["scan_steps"], time.perf_counter() - t0,
+                     np.round(np.ravel(block), 4).tolist()))
+        if np.ravel(block).shape != (c["scan_steps"],) \
+                or not np.isfinite(block).all():
+            raise AssertionError("%s: run(steps=%d) fetched %r"
+                                 % (name, c["scan_steps"], block))
+    smoke.resnet = dict(main=main, startup=startup, loss=avg_cost, exe=exe,
+                        scope=scope, feed=feed, host_feed=host_feed,
+                        first_loss=losses[0], name=name)
+
+
+# --------------------------------------------------------------- phase B --
+def phase_b(smoke):
+    import jax
+    import paddle_tpu as fluid
+    from paddle_tpu.models import transformer
+    from paddle_tpu.ops.kernel_config import flash_at
+
+    c = smoke.cfg["transformer"]
+    for seq, batch in c["shapes"]:
+        main, startup = fluid.Program(), fluid.Program()
+        main.random_seed = startup.random_seed = 1
+        main.enable_mixed_precision()
+        with fluid.unique_name.guard(), fluid.program_guard(main, startup):
+            _, avg_cost, _ = transformer.build_train(
+                src_vocab_size=c["vocab"], trg_vocab_size=c["vocab"],
+                max_length=seq, n_layer=c["n_layer"], n_head=c["n_head"],
+                d_key=c["d_model"] // c["n_head"],
+                d_value=c["d_model"] // c["n_head"], d_model=c["d_model"],
+                d_inner_hid=c["d_inner"], label_smooth_eps=0.1,
+                use_fused_attention=True)
+        rng = np.random.RandomState(0)
+        srcs = [rng.randint(3, c["vocab"], seq).tolist()
+                for _ in range(batch)]
+        feed = {k: jax.device_put(v, smoke.device) for k, v in
+                transformer.prepare_batch(srcs, srcs, seq, c["n_head"],
+                                          fused=True).items()}
+        name = "transformer L%d d%d T%d b%d bf16 (%s attention)" % (
+            c["n_layer"], c["d_model"], seq, batch,
+            "flash" if flash_at(seq) else "dense")
+        exe = fluid.Executor(fluid.TPUPlace())
+        scope = fluid.Scope()
+        with fluid.scope_guard(scope):
+            t0 = time.perf_counter()
+            exe.run(startup)
+            smoke.say("%s: startup %.2fs" % (name, time.perf_counter() - t0))
+            _train_steps(smoke, name, exe, main, feed, avg_cost, c["steps"])
+            _check_state_on_device(smoke, name, scope)
+    if [flash_at(seq) for seq, _ in c["shapes"]] != [False, True]:
+        raise AssertionError("the two shapes must sit on either side of "
+                             "the flash crossover")
+
+
+# --------------------------------------------------------------- phase C --
+def _pallas_calls(program, feed, fetch_names, scope, device):
+    """The `interpret` flag of every pallas_call in the function the
+    Executor would compile for this dispatch."""
+    import jax
+    from paddle_tpu.core import lowering
+    from paddle_tpu.core.executor import convert_feeds
+
+    feed_arrays = convert_feeds(program, feed)
+    feed_names = sorted(feed_arrays)
+    rw, ro, out = lowering.analyze_state(program, feed_names, fetch_names)
+    fn = lowering.build_program_fn(program, feed_names, fetch_names, rw, ro,
+                                   out, collect_errors=True)
+    with jax.default_device(device):
+        closed = jax.make_jaxpr(fn)(
+            [feed_arrays[n] for n in feed_names],
+            [scope.get(n) for n in rw], [scope.get(n) for n in ro],
+            np.uint32(0))
+    found = []
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                found.append(bool(eqn.params["interpret"]))
+            for val in eqn.params.values():
+                for sub in (val if isinstance(val, (list, tuple))
+                            else [val]):
+                    sub = getattr(sub, "jaxpr", sub)
+                    if hasattr(sub, "eqns"):
+                        walk(sub)
+    walk(closed.jaxpr)
+    return found
+
+
+def _one_op_program(build):
+    """(main, startup, fetch names) of the one-op Program `build` makes."""
+    import paddle_tpu as fluid
+
+    main, startup = fluid.Program(), fluid.Program()
+    main.random_seed = startup.random_seed = 3
+    with fluid.unique_name.guard(), fluid.program_guard(main, startup):
+        fetches = build(main)
+    return main, startup, [f if isinstance(f, str) else f.name
+                           for f in fetches]
+
+
+def _normalized_errors(names, got, want):
+    """{fetch: max|a-b| / (max|b| + 1e-6)}; shapes equal, values finite."""
+    errs = {}
+    for n, a, b in zip(names, got, want):
+        a = np.asarray(a, np.float64)
+        b = np.asarray(b, np.float64)
+        if a.shape != b.shape or not np.isfinite(a).all():
+            raise AssertionError("%s: shape %s vs %s, finite=%s"
+                                 % (n, a.shape, b.shape,
+                                    bool(np.isfinite(a).all())))
+        errs[n] = float(np.abs(a - b).max() / (np.abs(b).max() + 1e-6))
+    return errs
+
+
+def _kernel_case(smoke, label, op, build, feed, tol):
+    """One family at one shape: build the one-op Program, run it with the
+    kernel on and off, compare every fetch, inspect the trace."""
+    import paddle_tpu as fluid
+
+    main, startup, names = _one_op_program(build)
+    exe = fluid.Executor(fluid.TPUPlace())
+    scope = fluid.Scope()
+    saved = os.environ.get("PADDLE_TPU_PALLAS")
+    try:
+        with fluid.scope_guard(scope):
+            exe.run(startup)
+            os.environ["PADDLE_TPU_PALLAS"] = op
+            calls = _pallas_calls(main, feed, names, scope, smoke.device)
+            t0 = time.perf_counter()
+            got = exe.run(main, feed=feed, fetch_list=names)
+            secs = time.perf_counter() - t0
+            os.environ["PADDLE_TPU_PALLAS"] = "0"
+            if _pallas_calls(main, feed, names, scope, smoke.device):
+                raise AssertionError("PADDLE_TPU_PALLAS=0 still traces a "
+                                     "pallas_call")
+            want = exe.run(main, feed=feed, fetch_list=names)
+    finally:
+        if saved is None:
+            os.environ.pop("PADDLE_TPU_PALLAS", None)
+        else:
+            os.environ["PADDLE_TPU_PALLAS"] = saved
+    if not calls:
+        raise AssertionError("the kernel was not dispatched (no "
+                             "pallas_call in the trace)")
+    interpreted = smoke.device.platform != "tpu"
+    if calls != [interpreted] * len(calls):
+        raise AssertionError("pallas_call interpret flags %r on %s"
+                             % (calls, smoke.device.platform))
+    errs = _normalized_errors(names, got, want)
+    worst = max(errs, key=errs.get)
+    smoke.say("kernel %s: %d pallas_call(s), %s; first run %.2fs; max "
+              "normalized error %.2e (%s) <= %.0e"
+              % (label, len(calls),
+                 "interpreted" if interpreted else "Mosaic", secs,
+                 errs[worst], worst, tol))
+    if errs[worst] > tol:
+        raise AssertionError("%s disagrees with the XLA path: %r (tol %g)"
+                             % (label, errs, tol))
+
+
+def _weighted_loss(fluid, out, g):
+    """sum(out * g): a random cotangent, so the backward is not trivial."""
+    loss = fluid.layers.reduce_sum(out * g)
+    fluid.append_backward(loss)
+    return loss
+
+
+def _param_grads(main):
+    return [p.name + "@GRAD" for p in main.global_block().all_parameters()]
+
+
+def _ragged(rng, b, t, feat, scale):
+    """b sequences of ragged length <= t (one full, one of length 1)."""
+    lens = rng.randint(1, t + 1, size=b)
+    lens[0], lens[-1] = t, 1
+    return [(rng.randn(n, feat) * scale).astype("float32") for n in lens]
+
+
+def _kernel_cases(cfg):
+    """(label, PADDLE_TPU_PALLAS op name, build(main) -> fetches, feed,
+    tolerance) for every family."""
+    import paddle_tpu as fluid
+    from paddle_tpu.core.lod import LoDTensor
+
+    layers = fluid.layers
+    rng = np.random.RandomState(7)
+    cases = []
+
+    # flash attention: bf16 q/k/v as the AMP transformer feeds it (the
+    # fused_attention op is an AMP bf16 op), decoder-style (causal +
+    # key lengths) and encoder-style (key lengths only)
+    c = cfg["attn"]
+    shape = (c["b"], c["t"], c["h"], c["d"])
+    for causal in (True, False):
+        def build(main, causal=causal, shape=shape):
+            main.enable_mixed_precision()
+            q, k, v, g = (layers.data(name=n, shape=list(shape[1:]),
+                                      dtype="float32") for n in "qkvg")
+            for x in (q, k, v):
+                x.stop_gradient = False
+            kv_len = layers.data(name="kv_len", shape=[1], dtype="int32")
+            out = layers.fused_attention(q, k, v, causal=causal,
+                                         kv_len=kv_len)
+            _weighted_loss(fluid, out, g)
+            return [out, "q@GRAD", "k@GRAD", "v@GRAD"]
+        feed = {n: (rng.randn(*shape) * 0.5).astype("f") for n in "qkvg"}
+        feed["kv_len"] = rng.randint(shape[1] // 2, shape[1] + 1,
+                                     (shape[0], 1)).astype("int32")
+        cases.append(("flash_attention %s bf16 %s"
+                      % (list(shape), "causal" if causal else "padded"),
+                      "attn", build, feed, TOL["attn"]))
+
+    # softmax_xent: f32 logits (AMP forces the loss ops to f32)
+    c = cfg["xent"]
+
+    def build(main, c=c):
+        logits = layers.data(name="logits", shape=[c["v"]],
+                             dtype="float32")
+        logits.stop_gradient = False
+        label = layers.data(name="label", shape=[1], dtype="int64")
+        g = layers.data(name="g", shape=[1], dtype="float32")
+        loss = layers.softmax_with_cross_entropy(logits=logits, label=label)
+        _weighted_loss(fluid, loss, g)
+        return [loss, "logits@GRAD"]
+    cases.append(("softmax_xent [%d, %d] f32" % (c["n"], c["v"]), "xent",
+                  build,
+                  {"logits": (rng.randn(c["n"], c["v"]) * 2).astype("f"),
+                   "label": rng.randint(0, c["v"],
+                                        (c["n"], 1)).astype("int32"),
+                   "g": rng.rand(c["n"], 1).astype("f")}, TOL["xent"]))
+
+    # layer_norm: f32 rows (the residual sum it follows is f32 under AMP)
+    c = cfg["ln"]
+
+    def build(main, c=c):
+        x = layers.data(name="x", shape=[c["t"], c["d"]], dtype="float32")
+        x.stop_gradient = False
+        g = layers.data(name="g", shape=[c["t"], c["d"]], dtype="float32")
+        y = layers.layer_norm(x, begin_norm_axis=2)
+        _weighted_loss(fluid, y, g)
+        return [y, "x@GRAD"] + _param_grads(main)
+    shape = (c["b"], c["t"], c["d"])
+    cases.append(("layer_norm %s f32" % list(shape), "ln", build,
+                  {"x": (rng.randn(*shape) * 2 + 0.5).astype("f"),
+                   "g": rng.randn(*shape).astype("f")}, TOL["ln"]))
+
+    # fused LSTM / LSTMP: f32, no peepholes (the kernel's only config)
+    def lstm_case(c, proj):
+        d = c["d"]
+
+        def build(main):
+            x = layers.data(name="x", shape=[4 * d], dtype="float32",
+                            lod_level=1)
+            x.stop_gradient = False
+            if proj:
+                hidden, _ = layers.dynamic_lstmp(
+                    input=x, size=4 * d, proj_size=c["p"],
+                    use_peepholes=False)
+            else:
+                hidden, _ = layers.dynamic_lstm(
+                    input=x, size=4 * d, use_peepholes=False,
+                    is_reverse=c["reverse"])
+            fluid.append_backward(layers.mean(layers.square(hidden)))
+            return [hidden, "x@GRAD"] + _param_grads(main)
+        label = "fused_%s B%d T%d D%d%s f32" % (
+            "lstmp" if proj else "lstm", c["b"], c["t"], d,
+            " P%d" % c["p"] if proj
+            else (" reverse" if c["reverse"] else ""))
+        feed = {"x": LoDTensor.from_sequences(
+            _ragged(rng, c["b"], c["t"], 4 * d, 0.4))}
+        return (label, "lstm", build, feed, TOL["lstm"])
+    cases.extend(lstm_case(c, False) for c in cfg["lstm"])
+    cases.append(lstm_case(cfg["lstmp"], True))
+
+    # masked softmax / pool over ragged sequences, f32
+    def seq_case(label, feat, build_out, c):
+        def build(main):
+            x = layers.data(name="x", shape=[feat], dtype="float32",
+                            lod_level=1)
+            x.stop_gradient = False
+            out = build_out(x)
+            fluid.append_backward(layers.mean(layers.square(out)))
+            return [out, "x@GRAD"]
+        feed = {"x": LoDTensor.from_sequences(
+            _ragged(rng, c["b"], c["t"], feat, 1.5))}
+        return (label, "seq", build, feed, TOL["seq"])
+    c = cfg["seq_softmax"]
+    cases.append(seq_case(
+        "masked_softmax [%d, %d] f32" % (c["b"], c["t"]), 1,
+        lambda x: layers.sequence_softmax(input=x), c))
+    c = cfg["seq_pool"]
+    for ptype in ("sqrt", "average"):
+        cases.append(seq_case(
+            "masked_pool %s [%d, %d, %d] f32" % (ptype, c["b"], c["t"],
+                                                 c["f"]), c["f"],
+            lambda x, ptype=ptype: layers.sequence_pool(input=x,
+                                                        pool_type=ptype),
+            c))
+    return cases
+
+
+def _cpu_place_case(smoke, build, feed, tol):
+    """Executor(CPUPlace()) on this host dispatches to the CPU: whatever
+    the default backend is, its trace must hold no Mosaic call, and its
+    result must agree with the TPUPlace executor's."""
+    import jax
+    import paddle_tpu as fluid
+
+    main, startup, names = _one_op_program(build)
+    outs = []
+    for place in (fluid.CPUPlace(), fluid.TPUPlace()):
+        scope = fluid.Scope()
+        with fluid.scope_guard(scope):
+            exe = fluid.Executor(place)
+            exe.run(startup)
+            if not outs:
+                calls = _pallas_calls(main, feed, names, scope,
+                                      jax.devices("cpu")[0])
+            outs.append(exe.run(main, feed=feed, fetch_list=names))
+    if not all(calls):
+        raise AssertionError("Executor(CPUPlace()) traced a Mosaic "
+                             "pallas_call: interpret flags %r" % calls)
+    err = max(_normalized_errors(names, *outs).values())
+    smoke.say("Executor(CPUPlace()) next to Executor(TPUPlace()): %d "
+              "pallas_call(s) in the CPU trace, none Mosaic; max "
+              "normalized error %.2e <= %.0e" % (len(calls), err, tol))
+    if err > tol:
+        raise AssertionError("CPUPlace and TPUPlace disagree: %g" % err)
+
+
+def phase_c(smoke):
+    cases = _kernel_cases(smoke.cfg["kernels"])
+    runs = [(c[0], lambda c=c: _kernel_case(smoke, *c)) for c in cases]
+    _, _, ln_build, ln_feed, ln_tol = next(c for c in cases if c[1] == "ln")
+    runs.append(("layer_norm on Executor(CPUPlace())",
+                 lambda: _cpu_place_case(smoke, ln_build, ln_feed, ln_tol)))
+    failed = []
+    for label, run in runs:
+        # one family's refusal must not hide the next one's: collect them
+        # all, then fail the phase
+        try:
+            run()
+        except Exception as e:  # noqa: BLE001 — reported, phase fails below
+            failed.append(label)
+            smoke.say("kernel %s: FAILED %s: %s"
+                      % (label, type(e).__name__, e))
+            traceback.print_exc()
+    if failed:
+        raise AssertionError("%d kernel case(s) failed: %s"
+                             % (len(failed), "; ".join(failed)))
+
+
+# --------------------------------------------------------------- phase D --
+def phase_d(smoke):
+    import jax
+    import paddle_tpu as fluid
+    from paddle_tpu.parallel.mesh import batch_sharded, make_mesh
+
+    if smoke.n_devices < 4:
+        return "needs 4 devices, this host has %d" % smoke.n_devices
+    if smoke.resnet is None:
+        raise AssertionError("phase A left no program to run")
+    r = smoke.resnet
+    devices = jax.devices()[:4]
+    mesh = make_mesh({"dp": 4}, devices)
+    feed = {k: jax.device_put(v, batch_sharded(mesh, v.ndim))
+            for k, v in r["host_feed"].items()}
+    for k, v in feed.items():
+        spread = {s.device for s in v.addressable_shards}
+        if spread != set(devices) or any(
+                s.data.shape[0] * 4 != v.shape[0]
+                for s in v.addressable_shards):
+            raise AssertionError("feed %r is not split over the four "
+                                 "devices: %s" % (k, v.sharding))
+    for sharded in (False, True):
+        name = "%s dp=4%s" % (r["name"], " sharded_weight_update"
+                              if sharded else "")
+        scope = fluid.Scope()
+        with fluid.scope_guard(scope):
+            fluid.Executor(fluid.TPUPlace()).run(r["startup"])
+            pexe = fluid.ParallelExecutor(
+                main_program=r["main"], loss_name=r["loss"].name, mesh=mesh,
+                sharded_weight_update=sharded)
+            losses, secs = [], []
+            for _ in range(4):
+                t0 = time.perf_counter()
+                out, = pexe.run([r["loss"].name], feed=feed)
+                secs.append(time.perf_counter() - t0)
+                losses.append(float(np.ravel(out)[0]))
+            smoke.say("%s: step 1 %.2fs (compile + run), steps 2-4 median "
+                      "%.4fs; loss %.4f -> %.4f"
+                      % (name, secs[0], statistics.median(secs[1:]),
+                         losses[0], losses[-1]))
+            _check_training(name, losses)
+            rtol = smoke.cfg["dp_loss_rtol"]
+            if abs(losses[0] - r["first_loss"]) > rtol * abs(r["first_loss"]):
+                raise AssertionError(
+                    "%s: first-step loss %.5f vs one chip %.5f (rtol %g)"
+                    % (name, losses[0], r["first_loss"], rtol))
+            n_split = 0
+            for var in scope.names():
+                v = scope.get(var)
+                if not isinstance(v, jax.Array) or v.ndim == 0:
+                    continue
+                shards = v.addressable_shards
+                if {s.device for s in shards} != set(devices):
+                    raise AssertionError(
+                        "%s: %r is on %s, not on all four devices"
+                        % (name, var, {s.device for s in shards}))
+                n_split += shards[0].data.shape != v.shape
+            smoke.say("%s: state on 4 distinct devices, %d variable(s) "
+                      "split 1/4 per device" % (name, n_split))
+            if bool(n_split) != sharded:
+                raise AssertionError(
+                    "%s: %d split variables" % (name, n_split))
+    return None
+
+
+# --------------------------------------------------------------- phase E --
+def phase_e(smoke):
+    import jax
+    import paddle_tpu as fluid
+
+    if smoke.resnet is None:
+        raise AssertionError("phase A left no program to run")
+    r, c = smoke.resnet, smoke.cfg["barrier"]
+
+    def timed(wait):
+        t0 = time.perf_counter()
+        for _ in range(c["steps"]):
+            out = r["exe"].run(r["main"], feed=r["feed"],
+                               fetch_list=[r["loss"]], return_numpy=False)
+        wait(out[0].array)
+        return time.perf_counter() - t0
+
+    block, fetch = [], []
+    with fluid.scope_guard(r["scope"]):
+        for _ in range(c["rounds"]):
+            block.append(timed(jax.block_until_ready))
+            fetch.append(timed(np.asarray))
+    tb, tf = statistics.median(block), statistics.median(fetch)
+    smoke.say("barrier: %d steps to block_until_ready %.4fs, to a host "
+              "fetch %.4fs (medians of %d; ratio %.3f, tol %.2f)"
+              % (c["steps"], tb, tf, c["rounds"], tb / tf, c["tol"]))
+    if abs(tb - tf) > c["tol"] * tf:
+        raise AssertionError("block_until_ready and a device->host fetch "
+                             "disagree: %.4fs vs %.4fs" % (tb, tf))
+
+
+PHASES = (("A", "ResNet-50 training", phase_a),
+          ("B", "transformer training", phase_b),
+          ("C", "Pallas kernel families", phase_c),
+          ("D", "four-chip data parallel", phase_d),
+          ("E", "timing barrier", phase_e))
+
+
+def main(argv=None):
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--tiny", action="store_true",
+                    help="CPU rehearsal at toy sizes (needs "
+                         "JAX_PLATFORMS=cpu)")
+    ap.add_argument("--phases", default="ABCDE",
+                    help="letters of the phases to run (default all)")
+    args = ap.parse_args(argv)
+
+    import jax
+    from paddle_tpu.places import cpu_only_env
+    if args.tiny:
+        if not cpu_only_env():
+            print("chip_smoke: --tiny is the CPU rehearsal; run it under "
+                  "JAX_PLATFORMS=cpu", file=sys.stderr)
+            return 2
+        os.environ.update(TINY_ENV)
+    from paddle_tpu.core.compile_cache import enable_persistent_cache
+    cache_dir = enable_persistent_cache()
+    counts = {"requests": 0, "hits": 0}
+
+    def on_event(event, **_):
+        if event == "/jax/compilation_cache/compile_requests_use_cache":
+            counts["requests"] += 1
+        elif event == "/jax/compilation_cache/cache_hits":
+            counts["hits"] += 1
+    jax.monitoring.register_event_listener(on_event)
+
+    devices = jax.devices()
+    dev = devices[0]
+    device = {"platform": dev.platform, "kind": dev.device_kind,
+              "count": len(devices)}
+    print("chip_smoke: jax=%s platform=%s device_kind=%r devices=%d "
+          "cache_dir=%s" % (jax.__version__, dev.platform, dev.device_kind,
+                            len(devices), cache_dir), flush=True)
+    if dev.platform != "tpu" and not args.tiny:
+        print("chip_smoke: no TPU (jax found platform %r): nothing was run"
+              % dev.platform, file=sys.stderr)
+        return 2
+
+    smoke = Smoke(dev, len(devices), TINY if args.tiny else FULL, counts)
+    from paddle_tpu.native import native_status
+    smoke.say("native libraries: %s" % ", ".join(
+        "%s=%s" % (k, "native" if v else "python fallback")
+        for k, v in sorted(native_status().items())))
+
+    results = {}
+    t_all = time.perf_counter()
+    for letter, title, fn in PHASES:
+        if letter not in args.phases.upper():
+            results[letter] = "not selected"
+            continue
+        t0 = time.perf_counter()
+        before = dict(counts)
+        try:
+            skipped = fn(smoke)
+        except Exception:  # noqa: BLE001 — recorded; the exit code is 1
+            traceback.print_exc()
+            results[letter] = "FAILED"
+            skipped = None
+        else:
+            results[letter] = "skipped: %s" % skipped if skipped \
+                else "passed"
+        smoke.say("phase %s (%s) %s in %.1fs; XLA compile requests %d, "
+                  "persistent-cache hits %d"
+                  % (letter, title, results[letter],
+                     time.perf_counter() - t0,
+                     counts["requests"] - before["requests"],
+                     counts["hits"] - before["hits"]))
+    ok = "FAILED" not in results.values()
+    smoke.say("total %.1fs; compile requests %d, persistent-cache hits %d "
+              "(%s); phases %s"
+              % (time.perf_counter() - t_all, counts["requests"],
+                 counts["hits"], cache_dir, json.dumps(results)))
+    print(json.dumps({"ok": ok, "device": device}), flush=True)
+    return 0 if ok else 1
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -15,9 +15,6 @@ Execution is whole-program XLA compilation (core/lowering.py), autodiff is
 jax.vjp over op lowering rules (core/backward.py), and multi-device runs ride
 jax.sharding Meshes (parallel/).
 """
-from . import tpu_guard  # MUST be first: installs the exclusive TPU-client
-                         # lock on jax backend init (see tpu_guard.py)
-
 # Sharding-invariant PRNG, process-wide: with the legacy (non-
 # partitionable) threefry, the SAME program traced under a tensor-
 # parallel mesh draws DIFFERENT random bits than single-device (XLA's

@@ -11,9 +11,8 @@ can't read (the schema-guard satellite of PR 19):
              unit   (non-empty str)   e.g. "images/sec/chip"
   optional   error  (non-empty str)   present IFF the line is a
                                       failure placeholder, never a
-                                      measurement — the machine-
-                                      readable rule BENCH_LOG.md
-                                      documents: baselines skip any
+                                      measurement — the baseline
+                                      rule: baselines skip any
                                       record carrying an "error" key.
              vs_baseline (number|None)
              everything else          leg-specific config/result detail
@@ -99,7 +98,7 @@ def check_record(rec):
 
 
 def is_error(rec):
-    """The BENCH_LOG.md rule, machine-readable: a record carrying an
+    """The baseline rule, machine-readable: a record carrying an
     "error" key is a failure placeholder, never a baseline."""
     return isinstance(rec, dict) and "error" in rec
 
@@ -107,8 +106,8 @@ def is_error(rec):
 def device_kind(rec):
     """Hardware family key: "TPU v5 lite0" -> "TPU v5 lite" (trailing
     chip index stripped — chips of one kind share baselines), anything
-    CPU-ish -> "cpu", absent -> "unknown" (the committed error
-    placeholders never initialized a device)."""
+    CPU-ish -> "cpu", absent -> "unknown" (an error placeholder may
+    never have initialized a device)."""
     dev = rec.get("device") if isinstance(rec, dict) else rec
     if not dev or not isinstance(dev, str):
         return "unknown"

@@ -832,7 +832,7 @@ class Executor(object):
             # an in-process miss, a warm disk entry replaces the whole
             # trace+lower+compile with one deserialize — the restart /
             # serving-warmup cold-start killer. Off (akey=None) unless
-            # FLAGS_aot_cache_dir / maybe_enable_aot_cache enabled it.
+            # FLAGS_aot_cache_dir / enable_aot_cache enabled it.
             # use_program_cache=False opts out of caching wholesale:
             # consulting the disk cache there would re-deserialize (and
             # count a hit + 'time saved') on EVERY call of the loop.
@@ -846,7 +846,8 @@ class Executor(object):
                     self.place.device())
             executable = None
             if akey is not None:
-                loaded = compile_cache.aot_load(aot_dir, *akey)
+                loaded = compile_cache.aot_load(
+                    aot_dir, akey[0], akey[1], [self.place.device()])
                 if loaded is not None:
                     executable, aot_saved = loaded
                     aot_hit = True
@@ -866,38 +867,22 @@ class Executor(object):
                 if akey is not None:
                     # eager AOT: lower+compile NOW (against the real
                     # argument avals — .lower only traces, it consumes
-                    # nothing) so the executable can be serialized.
-                    # Serialized artifacts are compiled WITHOUT buffer
-                    # donation: a deserialized executable with
-                    # input-output aliasing corrupts the heap on its
-                    # second call in this jax (bisected: numpy or jax
-                    # array state alike; the donation-free variant is
-                    # stable and bit-identical). The cold process keeps
-                    # THIS executable too — one compile, not two — so a
-                    # cache-enabled key trades in-place state donation
-                    # for restartability; inference programs (serving
-                    # warmup, the headline path) have no donated state
-                    # at all. Store failures fall back to the plain
-                    # donating jit below.
+                    # nothing) so the executable can be serialized; the
+                    # cold process keeps THIS executable too — one
+                    # compile, not two. A failed store still leaves a
+                    # usable executable.
                     try:
                         t0c = time.perf_counter()
                         with jax.default_device(self.place.device()):
-                            comp = jax.jit(fn).lower(
+                            comp = jax.jit(fn, donate_argnums=(1,)).lower(
                                 [feed_arrays[n] for n in feed_names],
                                 read_state(state_rw),
                                 read_state(state_ro),
                                 np.uint32(0)).compile()
                         aot_compile_s = time.perf_counter() - t0c
-                        if compile_cache.aot_store(
-                                aot_dir, akey[0], akey[1], comp,
-                                aot_compile_s):
-                            executable = comp
-                        # store failed (full disk, lost race to an
-                        # unreadable dir): comp bought no
-                        # restartability, so don't pay its donation
-                        # loss for the whole process — fall through to
-                        # the donating jit (costs one extra compile on
-                        # this rare path)
+                        compile_cache.aot_store(
+                            aot_dir, akey[0], akey[1], comp, aot_compile_s)
+                        executable = comp
                     except Exception:  # noqa: BLE001 — best-effort
                         # cache; the jitted fn path raises real trace
                         # errors with their op annotations at dispatch
@@ -922,10 +907,24 @@ class Executor(object):
         t0 = time.perf_counter() if profiling else 0.0
 
         def _call(fn_obj):
-            with jax.default_device(self.place.device()):
-                return fn_obj([feed_arrays[n] for n in feed_names],
-                              read_state(state_rw), read_state(state_ro),
-                              seed)
+            dev = self.place.device()
+            with jax.default_device(dev):
+                feeds = [feed_arrays[n] for n in feed_names]
+                rw, ro = read_state(state_rw), read_state(state_ro)
+                if any(getattr(f, "committed", False) for f in feeds):
+                    # committed feeds (a reader staging to the place, a
+                    # caller's device_put) commit this call's outputs.
+                    # Commit the state too, or the next step — whose
+                    # state IS those outputs — lowers under another
+                    # argument signature and XLA compiles the whole
+                    # program a second time.
+                    rw = _commit(rw, dev)
+                    placed = _commit(ro, dev)
+                    for n, old, new in zip(state_ro, ro, placed):
+                        if new is not old:
+                            scope.set(n, new)  # never donated: keep it
+                    ro = placed
+                return fn_obj(feeds, rw, ro, seed)
 
         def _find_aot_entry():
             aot_dir = compile_cache.active_aot_cache_dir()
@@ -1025,6 +1024,13 @@ class Executor(object):
         return [FetchHandle(f) for f in fetches]
 
 
+
+
+def _commit(vals, device):
+    """`vals` committed to `device` (no copy for an array already
+    there)."""
+    return [v if getattr(v, "committed", False)
+            else jax.device_put(v, device) for v in vals]
 
 
 def _to_array(value, var=None, host=False):

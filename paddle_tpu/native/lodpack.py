@@ -17,7 +17,7 @@ __all__ = ["available", "pack_into", "unpack"]
 
 
 def _lib():
-    lib = load_library("lodpack", make_target="liblodpack.so")
+    lib = load_library("lodpack")
     if lib is None:
         return None
     if not getattr(lib, "_lodpack_ready", False):

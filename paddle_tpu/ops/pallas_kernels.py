@@ -13,8 +13,9 @@ softmax_xent — fused log-softmax + label pick over the vocab dim: one VMEM
 pass computes the loss and the logsumexp residual; the probability matrix is
 only formed in the backward (where it is the gradient anyway).
 
-Both run as real pallas kernels on TPU and fall back to interpret mode on
-CPU (the unit tests exercise the same kernel code path everywhere).
+Every kernel compiles through Mosaic when the program dispatches to a TPU
+and runs the same body in interpret mode elsewhere (the unit tests exercise
+the same kernel code path on the CPU).
 
 Parity note: the reference has no fused attention (its transformer builds
 q@k^T + softmax + @v from separate ops, paddle/fluid/operators/matmul_op.cc
@@ -29,32 +30,28 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
-try:
-    from jax.experimental.pallas import tpu as pltpu
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover - pallas tpu backend unavailable
-    pltpu = None
-    _VMEM = None
+from jax.experimental.pallas import tpu as pltpu
+
+from .kernel_config import dispatch_platform
 
 __all__ = ["flash_attention", "softmax_xent", "layer_norm",
-           "fused_lstm", "fused_lstmp", "masked_softmax", "masked_pool",
-           "attention_available"]
+           "fused_lstm", "fused_lstmp", "masked_softmax", "masked_pool"]
 
 _NEG = -1e30
 
 
 def _interpret_default():
-    return jax.default_backend() != "tpu"
-
-
-def attention_available():
-    return pltpu is not None
+    """Mosaic exactly when the traced computation dispatches to a TPU;
+    everywhere else the same kernel body runs in the interpreter."""
+    return dispatch_platform() != "tpu"
 
 
 def _vmem_spec(*args, **kwargs):
-    if _VMEM is not None:
-        kwargs.setdefault("memory_space", _VMEM)
+    kwargs.setdefault("memory_space", pltpu.VMEM)
     return pl.BlockSpec(*args, **kwargs)
+
+
+_SMEM_WHOLE = pl.BlockSpec(memory_space=pltpu.SMEM)
 
 
 # ---------------------------------------------------------------------------
@@ -131,7 +128,6 @@ def _flash_fwd(q, k, v, kv_len, scale, causal, block_q, block_k, interpret):
     # lens: whole array in SMEM (no blocking); lse: [BH, T, 1] so the
     # block's trailing dims are (block_q, 1) — Mosaic requires last-two
     # block dims divisible by (8, 128) or equal to the array's
-    smem = {} if pltpu is None else {"memory_space": pltpu.SMEM}
     out, lse = pl.pallas_call(
         kernel,
         grid=(bh, t_pad // block_q),
@@ -139,7 +135,7 @@ def _flash_fwd(q, k, v, kv_len, scale, causal, block_q, block_k, interpret):
             _vmem_spec((1, block_q, d), lambda b, i: (b, i, 0)),
             _vmem_spec((1, t_pad, d), lambda b, i: (b, 0, 0)),
             _vmem_spec((1, t_pad, d), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec(**smem),
+            _SMEM_WHOLE,
         ],
         out_specs=[
             _vmem_spec((1, block_q, d), lambda b, i: (b, i, 0)),
@@ -266,7 +262,6 @@ def _flash_bwd(scale, causal, block_q, block_k, interpret, res, g):
     lse3 = lse[..., None].astype(jnp.float32)
     delta3 = delta[..., None].astype(jnp.float32)
     lens = kv_len.reshape(bh, 1).astype(jnp.int32)
-    smem = {} if pltpu is None else {"memory_space": pltpu.SMEM}
 
     dk, dv = pl.pallas_call(
         functools.partial(_flash_bwd_dkdv_kernel, scale=scale,
@@ -280,7 +275,7 @@ def _flash_bwd(scale, causal, block_q, block_k, interpret, res, g):
             _vmem_spec((1, block_k, d), lambda b, j: (b, j, 0)),   # v
             _vmem_spec((1, t_pad, 1), lambda b, j: (b, 0, 0)),     # lse
             _vmem_spec((1, t_pad, 1), lambda b, j: (b, 0, 0)),     # delta
-            pl.BlockSpec(**smem),
+            _SMEM_WHOLE,
         ],
         out_specs=[
             _vmem_spec((1, block_k, d), lambda b, j: (b, j, 0)),
@@ -304,7 +299,7 @@ def _flash_bwd(scale, causal, block_q, block_k, interpret, res, g):
             _vmem_spec((1, t_pad, d), lambda b, i: (b, 0, 0)),     # v
             _vmem_spec((1, block_q, 1), lambda b, i: (b, i, 0)),   # lse
             _vmem_spec((1, block_q, 1), lambda b, i: (b, i, 0)),   # delta
-            pl.BlockSpec(**smem),
+            _SMEM_WHOLE,
         ],
         out_specs=_vmem_spec((1, block_q, d), lambda b, i: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, t_pad, d), q.dtype),
@@ -575,9 +570,6 @@ def _lstm_seq_kernel(x_ref, m_ref, w_ref, b_ref, h0_ref, c0_ref,
 def _lstm_fwd_call(xs, ms, w, b, h0, c0, block_b, interpret):
     """xs [T, B, 4D] f32, ms [T, B, 1], w [D, 4D], b [4D], h0/c0 [B, D]
     -> (hs, cs) [T, B, D]."""
-    if pltpu is None:  # pragma: no cover - VMEM scratch needs the backend
-        raise RuntimeError("fused_lstm needs the pallas TPU backend "
-                           "(guard dispatch on attention_available())")
     t, bsz, four_d = xs.shape
     d = four_d // 4
     blk, b_pad = _resolve_block_b(bsz, block_b)
@@ -743,9 +735,6 @@ def _lstmp_seq_kernel(x_ref, m_ref, w_ref, wp_ref, b_ref, r0_ref, c0_ref,
 
 
 def _lstmp_fwd_call(xs, ms, w, w_proj, b, r0, c0, block_b, interpret):
-    if pltpu is None:  # pragma: no cover - VMEM scratch needs the backend
-        raise RuntimeError("fused_lstmp needs the pallas TPU backend "
-                           "(guard dispatch on attention_available())")
     t, bsz, four_d = xs.shape
     d = four_d // 4
     p = w_proj.shape[1]

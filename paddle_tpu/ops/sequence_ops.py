@@ -18,21 +18,13 @@ import jax.numpy as jnp
 from jax import lax
 
 from ..core.registry import register, single
+from .kernel_config import pallas_on
 
 
 def _mask(xlen, max_len, dtype=jnp.float32):
     """[B, T] 1/0 validity mask from lengths."""
     t = jnp.arange(max_len, dtype=jnp.int32)
     return (t[None, :] < xlen.astype(jnp.int32)[:, None]).astype(dtype)
-
-
-def _seq_pallas_on(op):
-    """Pallas fast-path gate for the sequence ops (kernel_config owns
-    the flag parse; the kernels need the pallas TPU package importable
-    even for interpret mode)."""
-    from . import pallas_kernels as pk
-    from .kernel_config import pallas_on
-    return pk.attention_available() and pallas_on(op)
 
 
 def _feat_mask(x, xlen):
@@ -50,7 +42,7 @@ def _sequence_pool(ctx, ins, attrs):
     # in f32, so an int accumulation (exact in the dense path) or a
     # bf16 input must not silently change numerics under the flag
     if ptype in ("SUM", "AVERAGE", "SQRT") and x.ndim >= 2 \
-            and x.dtype == jnp.float32 and _seq_pallas_on("seq"):
+            and x.dtype == jnp.float32 and pallas_on("seq"):
         # fused masked pool: one VMEM pass builds the @SEQLEN mask and
         # reduces (linear pools only — MAX/LAST/FIRST keep the dense
         # path). Feature dims flatten to one trailing axis.
@@ -105,7 +97,7 @@ def _sequence_softmax(ctx, ins, attrs):
     squeeze = x.ndim == 3 and x.shape[-1] == 1
     logits = x.reshape(x.shape[0], x.shape[1]) if squeeze else x
     if logits.ndim == 2 and logits.dtype == jnp.float32 \
-            and _seq_pallas_on("seq"):
+            and pallas_on("seq"):
         # fused masked softmax: mask + online max + normalize in one
         # VMEM pass per row block (bit-exact vs the where-mask path:
         # masked lanes underflow exp to exactly 0 either way)
@@ -354,7 +346,7 @@ def _lstm(ctx, ins, attrs):
             and attrs.get("gate_activation", "sigmoid") == "sigmoid"
             and attrs.get("cell_activation", "tanh") == "tanh"
             and attrs.get("candidate_activation", "tanh") == "tanh"
-            and _seq_pallas_on("lstm")):
+            and pallas_on("lstm")):
         # fused pallas recurrence (default activations, no peepholes —
         # the long tail keeps the scan): four gates + state update in
         # one VMEM pass per step, carried state resident in VMEM
@@ -451,7 +443,7 @@ def _lstmp(ctx, ins, attrs):
             and attrs.get("cell_activation", "tanh") == "tanh"
             and attrs.get("candidate_activation", "tanh") == "tanh"
             and attrs.get("proj_activation", "tanh") == "tanh"
-            and _seq_pallas_on("lstm")):
+            and pallas_on("lstm")):
         from . import pallas_kernels as pk
         from .kernel_config import tiles_for
         if h0 is not None:

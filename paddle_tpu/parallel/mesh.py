@@ -23,15 +23,8 @@ __all__ = ["make_mesh", "data_parallel_mesh", "replicated", "batch_sharded",
 
 def vary(x, axes):
     """Mark a constant as device-varying over `axes` so shard_map loop
-    carries type-check (jax version compat: pcast on newest jax, pvary
-    on 0.5/0.6). JAX <= 0.4.x predates the varying-manual-axes type
-    system entirely — there the annotation is meaningless and identity
-    is the correct no-op. Shared by ring_attention and pipeline."""
-    if hasattr(lax, "pcast"):
-        return lax.pcast(x, tuple(axes), to="varying")
-    if hasattr(lax, "pvary"):
-        return lax.pvary(x, tuple(axes))
-    return x
+    carries type-check. Shared by ring_attention and pipeline."""
+    return lax.pcast(x, tuple(axes), to="varying")
 
 
 def device_count():

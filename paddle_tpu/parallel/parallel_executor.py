@@ -130,7 +130,8 @@ class ParallelExecutor(object):
         # device; concurrent programs starve the pool and abort). Real TPU
         # collectives don't have this failure mode — only serialize
         # dispatch on the CPU (test/virtual-mesh) backend.
-        self._sync_dispatch = jax.default_backend() == "cpu"
+        self._device0 = self.mesh.devices.flat[0]
+        self._sync_dispatch = self._device0.platform == "cpu"
         self._check_nan_inf = _nan_inf_enabled(check_nan_inf)
         self._array_safety = _array_safety_enabled()
         self._scope = global_scope()
@@ -300,7 +301,7 @@ class ParallelExecutor(object):
         from ..core import compile_cache
         from ..core.lowering import trace_env_key
         unroll = lowering.resolve_multistep_unroll(
-            self.mesh.devices.flat[0].platform) if steps > 1 else False
+            self._device0.platform) if steps > 1 else False
         multi_sig = (steps, fetch_reduce if steps > 1 else None, unroll,
                      tuple(sorted(stacked_names)))
         key = (program._uid, program._version,
@@ -308,7 +309,7 @@ class ParallelExecutor(object):
                trace_env_key(), multi_sig)
         if info is not None:
             info["cache_key"] = key
-        def build_jitted(state_rw, state_ro, state_out, donate):
+        def build_jitted(state_rw, state_ro, state_out):
             rep = replicated(self.mesh)
             in_shardings = (
                 [_feed_sharding(n, feed_arrays[n].ndim)
@@ -346,7 +347,7 @@ class ParallelExecutor(object):
                     collect_errors=True, shard_constraints=constraints)
             return jax.jit(fn, in_shardings=in_shardings,
                            out_shardings=out_shardings,
-                           donate_argnums=(1,) if donate else ())
+                           donate_argnums=(1,))
 
         def aot_key():
             # the sharded executable is keyed on everything that shapes
@@ -361,7 +362,7 @@ class ParallelExecutor(object):
             return aot_dir, compile_cache.aot_entry_key(
                 program, _feed_signature(feed_arrays),
                 tuple(fetch_names), trace_env_key(), multi_sig,
-                self.mesh.devices.flat[0],
+                self._device0,
                 extra={
                     "executor": "parallel",
                     "num_devices": int(self.mesh.devices.size),
@@ -390,7 +391,9 @@ class ParallelExecutor(object):
             aot_dir, akey = aot_key()
             executable = None
             if akey is not None:
-                loaded = compile_cache.aot_load(aot_dir, *akey)
+                loaded = compile_cache.aot_load(
+                    aot_dir, akey[0], akey[1],
+                    list(self.mesh.devices.flat))
                 if loaded is not None:
                     executable, aot_saved = loaded
                     aot_hit = True
@@ -401,42 +404,39 @@ class ParallelExecutor(object):
                     try:
                         t0c = _time.perf_counter()
 
-                        # serialized artifacts compile WITHOUT donation
-                        # (deserialized input-output aliasing corrupts
-                        # the heap — see Executor._run_impl). Lower from
-                        # AVALS, not live values: scope arrays may still
-                        # be committed to a DIFFERENT plan's layout
-                        # (fresh executor over a scope another plan
-                        # trained — the elastic-reshard handoff), and
-                        # lowering committed arrays against conflicting
-                        # explicit in_shardings raises, silently
-                        # forfeiting the artifact; the in_shardings
-                        # alone decide placement.
+                        # Lower from AVALS, not live values: scope
+                        # arrays may still be committed to a DIFFERENT
+                        # plan's layout (fresh executor over a scope
+                        # another plan trained — the elastic-reshard
+                        # handoff), and lowering committed arrays
+                        # against conflicting explicit in_shardings
+                        # raises, silently forfeiting the artifact; the
+                        # in_shardings alone decide placement.
                         def _aval(v):
                             return jax.ShapeDtypeStruct(
                                 np.shape(v),
                                 getattr(v, "dtype", None)
                                 or np.asarray(v).dtype)
 
-                        comp = build_jitted(
-                            state_rw, state_ro, state_out,
-                            donate=False).lower(
-                            [_aval(feed_arrays[n]) for n in feed_names],
-                            [_aval(scope.get(n)) for n in state_rw],
-                            [_aval(scope.get(n)) for n in state_ro],
-                            jax.ShapeDtypeStruct((), np.uint32)).compile()
+                        with jax.default_device(self._device0):
+                            comp = build_jitted(
+                                state_rw, state_ro, state_out).lower(
+                                [_aval(feed_arrays[n])
+                                 for n in feed_names],
+                                [_aval(scope.get(n)) for n in state_rw],
+                                [_aval(scope.get(n)) for n in state_ro],
+                                jax.ShapeDtypeStruct(
+                                    (), np.uint32)).compile()
                         aot_compile_s = _time.perf_counter() - t0c
-                        if compile_cache.aot_store(
-                                aot_dir, akey[0], akey[1], comp,
-                                aot_compile_s):
-                            executable = comp
-                        # store failed: no artifact on disk, so keep
-                        # donation (see Executor._run_impl)
+                        compile_cache.aot_store(
+                            aot_dir, akey[0], akey[1], comp,
+                            aot_compile_s)
+                        executable = comp
                     except Exception:  # noqa: BLE001 — cache is
                         pass           # best-effort; jit path raises
                 if executable is None:
                     executable = build_jitted(state_rw, state_ro,
-                                              state_out, donate=True)
+                                              state_out)
             entry = (executable, state_rw, state_ro, state_out)
             _cache_put_lru(self._cache, key, entry, _jit_cache_capacity())
         jitted, state_rw, state_ro, state_out = entry
@@ -480,31 +480,15 @@ class ParallelExecutor(object):
         from .. import profiler as _prof
         profiling = _prof.is_active()
 
-        def _donating_call_guard(fn_obj):
-            # a donating jit must never compile through the jax
-            # persistent HLO cache: warm-cache deserialization breaks
-            # donation in this jax (silently wrong numerics — see
-            # compile_cache.donating_multidevice_compile_guard). Every
-            # call of a plain-jit entry is guarded, not just the first:
-            # a plain jit also RETRACES silently when state avals drift
-            # under an unchanged key, and a first call that failed
-            # leaves the entry cached with its compile still pending —
-            # both would otherwise compile unguarded. The guard is a
-            # refcounted pair of free config flips (measured ~1µs) on
-            # the cache-enabled path and a no-op otherwise; AOT
-            # artifacts (jax.stages.Compiled) are donation-free and
-            # never guarded.
-            import contextlib
-            if not isinstance(fn_obj, jax.stages.Compiled):
-                return compile_cache.donating_multidevice_compile_guard()
-            return contextlib.nullcontext()
-
         # device-enqueue span (async; see Executor) — open = wedged here
         dsp = tspan.child("exec/dispatch")
         t0 = _time.perf_counter() if profiling else 0.0
 
         def _call(fn_obj):
-            with _donating_call_guard(fn_obj):
+            # the pin names the mesh's platform to trace-time dispatch
+            # decisions (kernel_config.dispatch_platform); placement is
+            # the in_shardings' alone
+            with jax.default_device(self._device0):
                 return fn_obj(feed_vals, read_state(state_rw),
                               read_state(state_ro, commit=True), seed)
 
@@ -514,8 +498,7 @@ class ParallelExecutor(object):
 
         def _rebuild():
             # fresh donating jit — see call_with_aval_fallback
-            fresh = build_jitted(state_rw, state_ro, state_out,
-                                 donate=True)
+            fresh = build_jitted(state_rw, state_ro, state_out)
             _cache_put_lru(self._cache, key,
                            (fresh, state_rw, state_ro, state_out),
                            _jit_cache_capacity())

@@ -29,10 +29,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax import lax
-try:
-    from jax import shard_map
-except ImportError:  # older jax
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from .mesh import P, vary as _vary
 
@@ -188,21 +185,6 @@ def pipeline_apply(stage_fn, stacked_params, x, mesh, num_microbatches=None,
 
     vary_axes = (axis,) if batch_axis is None else (axis, batch_axis)
     x_spec = P(None, batch_axis) if batch_axis else P()
-    # jax 0.4.x GSPMD workaround (the pre-pvary era this repo's vary()
-    # fallback targets): a stack/concatenate of replicated per-stage
-    # params built INSIDE the jit, consumed by a shard_map slicing it
-    # over `axis` on a MULTI-axis mesh (dp x pp), partitions wrong and
-    # scales the pipeline output by a device-count factor. Pinning the
-    # stacked tree replicated before the shard_map boundary restores
-    # correct slicing; newer jax (pvary/pcast present) doesn't need it
-    # and keeps the memory-scaling sliced placement.
-    if len(mesh.shape) > 1 and not (hasattr(lax, "pcast")
-                                    or hasattr(lax, "pvary")):
-        from jax.sharding import NamedSharding
-        rep = NamedSharding(mesh, P())
-        stacked_params = jax.tree_util.tree_map(
-            lambda a: lax.with_sharding_constraint(a, rep)
-            if isinstance(a, jax.core.Tracer) else a, stacked_params)
     fn = shard_map(
         functools.partial(_pipeline_shard, stage_fn=stage_fn,
                           axis_name=axis, vary_axes=vary_axes),

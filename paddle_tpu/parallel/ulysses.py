@@ -28,8 +28,7 @@ def ulysses_attention(q, k, v, axis_name, causal=False, scale=None,
     kv_len: optional [B] true key lengths — after the all-to-all each
     shard holds the FULL sequence for its head slice, so key-padding is
     the plain dense mask."""
-    sp = lax.axis_size(axis_name) if hasattr(lax, "axis_size") \
-        else lax.psum(1, axis_name)
+    sp = lax.axis_size(axis_name)
     h = q.shape[2]
     if h % sp != 0:
         raise ValueError(

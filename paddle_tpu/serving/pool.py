@@ -602,9 +602,8 @@ class ReplicaPool(object):
 
     def _place_for(self, idx):
         """Round-robin placement over the visible devices. An explicit
-        place (or list of places) wins; default is TPUPlace(idx), whose
-        device() already wraps modulo the accelerator count and falls
-        back to CPU when none exist. Tensor-parallel replicas default
+        place (or list of places) wins; default is TPUPlace(idx modulo
+        the accelerator count). Tensor-parallel replicas default
         to CPUPlace instead: the place is only the LOADER's device (the
         mesh owns dispatch), and materializing a bigger-than-one-chip
         model's full weights on TPUPlace(idx) — a chip inside some
@@ -619,7 +618,7 @@ class ReplicaPool(object):
             return place
         if self.tp is not None:
             return CPUPlace()
-        return TPUPlace(idx)
+        return TPUPlace(idx % len(TPUPlace.devices()))
 
     def _tp_span(self, idx):
         """Replica idx's contiguous tp-device span. The span START wraps

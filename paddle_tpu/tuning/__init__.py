@@ -26,12 +26,12 @@ change reads as "untuned" — defaults, the safe fallback.
 from .autotuner import (Autotuner, TuningResult, tune_kernels,
                         tune_serving_batching, tune_training_multistep)
 from .store import (KNOWN_KNOBS, STORE_VERSION, TuningStore,
-                    default_store_dir, device_key, program_signature,
+                    device_key, program_signature,
                     resolve_store_dir)
 
 __all__ = [
     "Autotuner", "TuningResult", "TuningStore", "KNOWN_KNOBS",
-    "STORE_VERSION", "default_store_dir", "device_key",
+    "STORE_VERSION", "device_key",
     "program_signature", "resolve_store_dir", "tune_kernels",
     "tune_serving_batching", "tune_training_multistep", "lookup_program",
     "apply_to_run",

@@ -16,8 +16,9 @@ interpret it beyond equality. The *device key* is "platform/device_kind"
 so a config tuned on one chip generation never silently applies to
 another.
 
-Store root: FLAGS_tuning_store_dir, or the ``root`` argument, or the
-per-uid default next to the AOT cache. Format bumps of STORE_VERSION
+Store root: the ``root`` argument, else FLAGS_tuning_store_dir; with
+neither there is no store, so only files the operator named shape what
+is compiled. Format bumps of STORE_VERSION
 invalidate every older entry (read returns None), exactly like the AOT
 cache's format_version — stale tuned configs are never applied.
 """
@@ -60,19 +61,8 @@ KNOWN_KNOBS = frozenset({
 })
 
 
-def default_store_dir():
-    import tempfile
-    return os.path.join(tempfile.gettempdir(),
-                        "ptpu_tuning_store_%d" % os.getuid())
-
-
 def resolve_store_dir(root=None):
-    if root:
-        return root
-    env = os.environ.get("FLAGS_tuning_store_dir")
-    if env is not None:
-        return env or None  # '' = explicit off
-    return default_store_dir()
+    return root or os.environ.get("FLAGS_tuning_store_dir") or None
 
 
 def device_key(device):
@@ -105,8 +95,8 @@ class TuningStore(object):
         Returns the entry path. Unknown knob names raise (see
         KNOWN_KNOBS)."""
         if self.root is None:
-            raise ValueError("tuning store is disabled "
-                             "(FLAGS_tuning_store_dir='')")
+            raise ValueError("no tuning store: set "
+                             "FLAGS_tuning_store_dir or pass root=")
         bad = sorted(set(knobs) - KNOWN_KNOBS)
         if bad:
             raise ValueError("unknown tuning knob(s) %r; known: %s"

@@ -202,36 +202,3 @@ def test_default_scope_funcs_stack_and_lookup():
         seen["inside"] = dsf.find_var("scoped_v") is not None
     dsf.scoped_function(body)
     assert seen["inside"]
-
-
-def test_persistent_compile_cache_opt_in(tmp_path, monkeypatch):
-    """FLAGS_compile_cache_dir points jax's persistent executable cache
-    at the given dir (bench/sweep repeat configs load from disk); unset
-    + no default leaves it off. Round-5 runtime feature."""
-    import jax
-    from paddle_tpu.core import compile_cache
-
-    monkeypatch.setattr(compile_cache, "_enabled_dir", None)
-    monkeypatch.delenv("FLAGS_compile_cache_dir", raising=False)
-    assert compile_cache.maybe_enable_persistent_cache() is None
-
-    # explicitly-empty flag = off, even when the caller passes a default
-    monkeypatch.setenv("FLAGS_compile_cache_dir", "")
-    assert compile_cache.maybe_enable_persistent_cache("/tmp/dflt") is None
-
-    cache_dir = str(tmp_path / "xc")
-    monkeypatch.setenv("FLAGS_compile_cache_dir", cache_dir)
-    saved = jax.config.jax_compilation_cache_dir
-    saved_min = jax.config.jax_persistent_cache_min_compile_time_secs
-    try:
-        got = compile_cache.maybe_enable_persistent_cache()
-        assert got == cache_dir
-        assert jax.config.jax_compilation_cache_dir == cache_dir
-        assert os.path.isdir(cache_dir)
-        # idempotent: second call keeps the first dir even if env changes
-        monkeypatch.setenv("FLAGS_compile_cache_dir", "/tmp/other")
-        assert compile_cache.maybe_enable_persistent_cache() == cache_dir
-    finally:
-        jax.config.update("jax_compilation_cache_dir", saved)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                          saved_min)

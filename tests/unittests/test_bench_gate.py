@@ -1,19 +1,32 @@
 """tools/ptpu_bench.py — the CLI surface of paddle_tpu.benchd, run as
-subprocesses on CPU the way CI and the driver run it (PR 19).
+subprocesses on CPU the way CI runs it.
 
-The smoke test is the CI hook itself: `ptpu_bench gate` over the
-COMMITTED repo artifacts (BENCH_r01-r05.json + BENCH_LOG.md) must exit
-0 — r02-r05 are probe failures, not regressions — while a synthetic
-20% throughput drop against the r01 baseline must exit 1.
+The store is seeded with one measured line and one error placeholder
+(a run that died before measuring): `ptpu_bench gate` over it must exit
+0 — the placeholder is a failed run, not a regression — while a
+synthetic 20% throughput drop against the measured baseline must exit 1.
 """
 import json
 import os
 import subprocess
 import sys
 
+from paddle_tpu.benchd.store import BenchStore
+
 REPO = os.path.dirname(os.path.dirname(
     os.path.dirname(os.path.abspath(__file__))))
 CLI = os.path.join(REPO, "tools", "ptpu_bench.py")
+
+BASE = {"metric": "resnet50_imagenet_train_throughput", "value": 1000.0,
+        "unit": "images/sec/chip", "batch": 64, "device": "TPU v5 lite0"}
+
+
+def _seed(tmp_path):
+    store = BenchStore(tmp_path / "bench_store")
+    store.append(dict(BASE), source="run1", ts=1.0)
+    store.append(dict(BASE, value=0.0,
+                      error="device init did not return"),
+                 source="run2", ts=2.0)
 
 
 def _run(tmp_path, *argv):
@@ -27,41 +40,41 @@ def _run(tmp_path, *argv):
         env=env, capture_output=True, text=True, timeout=300)
 
 
+def _fresh(tmp_path, value):
+    fresh = tmp_path / "fresh.jsonl"
+    fresh.write_text(json.dumps(dict(BASE, value=value)) + "\n")
+    return str(fresh)
+
+
 def test_bench_gate_smoke(tmp_path):
-    """The CI gate over the committed artifacts: backfill ingests the
-    driver series and BENCH_LOG.md, the error placeholders skip, and
-    nothing regresses — exit 0."""
+    """Self-gate: the newest entry is an error placeholder, which skips
+    and is shown; nothing regresses — exit 0."""
+    _seed(tmp_path)
     out = _run(tmp_path, "gate")
     assert out.returncode == 0, out.stdout + out.stderr
     assert "0 regression(s)" in out.stdout
-    assert "error placeholders" in out.stdout  # r02-r05 skipped, shown
+    assert "error placeholders" in out.stdout
 
 
 def test_bench_gate_synthetic_regression(tmp_path):
-    """A 20% throughput drop in the r01 config must FAIL the gate (exit
-    1) against the 1076.48 images/sec/chip baseline — the same store
-    that just exited 0 on the error placeholders."""
-    fresh = tmp_path / "fresh.jsonl"
-    fresh.write_text(json.dumps({
-        "metric": "resnet50_imagenet_train_throughput",
-        "value": 861.2, "unit": "images/sec/chip",
-        "batch": 64, "device": "TPU v5 lite0"}) + "\n")
-    out = _run(tmp_path, "gate", "--fresh", str(fresh), "--json")
+    """A 20% throughput drop in the same config must FAIL the gate (exit
+    1) against the measured baseline — the same store that just exited
+    0 on the error placeholder."""
+    _seed(tmp_path)
+    out = _run(tmp_path, "gate", "--fresh", _fresh(tmp_path, 800.0),
+               "--json")
     assert out.returncode == 1, out.stdout + out.stderr
     report = json.loads(out.stdout)
     (verdict,) = report["verdicts"]
     assert verdict["verdict"] == "regression"
-    assert verdict["baseline_source"] == "backfill:BENCH_r01.json"
-    assert verdict["baseline"] == 1076.48
+    assert verdict["baseline_source"] == "run1"
+    assert verdict["baseline"] == 1000.0
 
 
 def test_bench_gate_fresh_improvement_passes(tmp_path):
-    fresh = tmp_path / "fresh.jsonl"
-    fresh.write_text(json.dumps({
-        "metric": "resnet50_imagenet_train_throughput",
-        "value": 1290.0, "unit": "images/sec/chip",
-        "batch": 64, "device": "TPU v5 lite0"}) + "\n")
-    out = _run(tmp_path, "gate", "--fresh", str(fresh), "--json")
+    _seed(tmp_path)
+    out = _run(tmp_path, "gate", "--fresh", _fresh(tmp_path, 1200.0),
+               "--json")
     assert out.returncode == 0, out.stdout + out.stderr
     assert json.loads(out.stdout)["counts"]["improvement"] == 1
 
@@ -73,23 +86,13 @@ def test_bench_gate_bad_fresh_file_is_usage_error(tmp_path):
     assert out.returncode == 2, out.stdout + out.stderr
 
 
-def test_bench_status_classifies_driver_series(tmp_path):
-    """`ptpu_bench status` must report r01 as the ONLY last-good
-    hardware baseline of the BENCH_rNN driver series, with r02-r05 as
-    probe failures."""
+def test_bench_status_reports_last_good(tmp_path):
+    """`ptpu_bench status`: the measured line is the key's last-good
+    baseline; the placeholder counts as an error, never as a value."""
+    _seed(tmp_path)
     out = _run(tmp_path, "status", "--json")
     assert out.returncode == 0, out.stdout + out.stderr
     status = json.loads(out.stdout)
-    drv = status["driver_series"]
-    assert drv["last_good"] == ["BENCH_r01.json"]
-    classes = {r["source"]: r["class"] for r in drv["rows"]}
-    assert classes == {
-        "BENCH_r01.json": "hardware-baseline",
-        "BENCH_r02.json": "probe-failure",
-        "BENCH_r03.json": "probe-failure",
-        "BENCH_r04.json": "probe-failure",
-        "BENCH_r05.json": "probe-failure",
-    }
-    # the full sweep queue rides along, nothing measured yet
-    assert len(status["queue"]["pending"]) >= 15
-    assert status["queue"]["done"] == []
+    assert status["store"] == {"records": 2, "errors": 1}
+    (slot,) = status["last_good"].values()
+    assert slot == {"value": 1000.0, "source": "run1"}

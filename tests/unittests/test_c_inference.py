@@ -39,7 +39,7 @@ def _save_model(dirname):
 
 def _load_lib():
     from paddle_tpu.native import load_library
-    lib = load_library("ptpu_infer", make_target="libptpu_infer.so")
+    lib = load_library("ptpu_infer")
     if lib is None:
         pytest.skip("libptpu_infer.so unavailable (no toolchain)")
     lib.ptpu_create.restype = ctypes.c_int64

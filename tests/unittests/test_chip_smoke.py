@@ -1,0 +1,114 @@
+"""chip_smoke.py, rehearsed on the CPU (the chip run itself is sent
+through the chip tool; CHANGES.md records it).
+
+What tier-1 can hold on to: the --tiny rehearsal walks every phase and
+exits 0 with the contract's last line; a failing phase makes the exit
+code non-zero; without --tiny a host with no TPU exits non-zero before
+running a step; and importing the entry points initialises no backend,
+because bench.py's compile-cache leg and tools/ptpu_elastic.py start
+children that need the chip a parent would otherwise be holding.
+"""
+import json
+import os
+import subprocess
+import sys
+
+REPO = os.path.dirname(os.path.dirname(
+    os.path.dirname(os.path.abspath(__file__))))
+SMOKE = os.path.join(REPO, "chip_smoke.py")
+
+
+def _env(**extra):
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    env.pop("XLA_FLAGS", None)     # one CPU device: phase D must skip
+    env.update(extra)
+    return env
+
+
+def test_tiny_rehearsal_passes_every_phase():
+    out = subprocess.run([sys.executable, SMOKE, "--tiny"], env=_env(),
+                         cwd=REPO, capture_output=True, text=True,
+                         timeout=900)
+    assert out.returncode == 0, out.stdout + out.stderr
+    lines = out.stdout.strip().splitlines()
+    assert json.loads(lines[-1]) == {
+        "ok": True,
+        "device": {"platform": "cpu", "kind": "cpu", "count": 1}}
+    assert lines[0].startswith("chip_smoke: jax=")
+    # a CPU line can never be mistaken for a chip line
+    assert all("platform=cpu" in line for line in lines[:-1])
+    for letter in "ABCE":
+        assert any("phase %s " % letter in line and " passed in " in line
+                   for line in lines), letter
+    assert any("phase D " in line and "skipped: needs 4 devices" in line
+               for line in lines)
+    # phase C walked the kernels (interpreted here, Mosaic on the chip)
+    assert sum("interpreted;" in line for line in lines) >= 7
+
+
+_FAILING_RUN = """
+import sys
+sys.path.insert(0, %r)
+import chip_smoke
+
+def boom(smoke):
+    raise RuntimeError("injected failure")
+
+chip_smoke.PHASES = (
+    ("A", "fails", boom),
+    ("B", "passes", lambda smoke: None),
+    ("C", "skips", lambda smoke: "not applicable here"))
+sys.exit(chip_smoke.main(sys.argv[1:]))
+""" % REPO
+
+
+def test_a_failing_phase_makes_the_exit_code_nonzero():
+    def run(*argv):
+        return subprocess.run(
+            [sys.executable, "-c", _FAILING_RUN, "--tiny"] + list(argv),
+            env=_env(), cwd=REPO, capture_output=True, text=True,
+            timeout=300)
+    out = run()
+    assert out.returncode == 1, out.stdout + out.stderr
+    lines = out.stdout.strip().splitlines()
+    assert json.loads(lines[-1])["ok"] is False
+    assert any("phase A (fails) FAILED" in line for line in lines)
+    assert "injected failure" in out.stderr
+    # the later phases still ran: one chip call reports every failure
+    assert any("phase B (passes) passed" in line for line in lines)
+    assert any("phase C (skips) skipped: not applicable here" in line
+               for line in lines)
+    # and with only passing phases selected the same run exits 0
+    assert run("--phases", "BC").returncode == 0
+
+
+def test_without_a_tpu_nothing_runs():
+    out = subprocess.run([sys.executable, SMOKE], env=_env(), cwd=REPO,
+                         capture_output=True, text=True, timeout=300)
+    assert out.returncode == 2, out.stdout + out.stderr
+    assert "platform=cpu" in out.stdout
+    assert "phase" not in out.stdout          # not one step was taken
+    assert not out.stdout.strip().splitlines()[-1].startswith("{")
+    assert "no TPU" in out.stderr
+
+
+def test_tiny_needs_the_explicit_cpu_pin():
+    env = _env()
+    env["JAX_PLATFORMS"] = "tpu,cpu"
+    out = subprocess.run([sys.executable, SMOKE, "--tiny"], env=env,
+                         cwd=REPO, capture_output=True, text=True,
+                         timeout=300)
+    assert out.returncode == 2, out.stdout + out.stderr
+    assert out.stdout == ""
+
+
+def test_importing_the_entry_points_initialises_no_backend():
+    code = ("import sys; sys.path.insert(0, %r)\n"
+            "import paddle_tpu, bench, chip_smoke\n"
+            "from jax._src import xla_bridge\n"
+            "assert not xla_bridge._backends, xla_bridge._backends\n"
+            % REPO)
+    out = subprocess.run([sys.executable, "-c", code], env=_env(),
+                         cwd=REPO, capture_output=True, text=True,
+                         timeout=300)
+    assert out.returncode == 0, out.stdout + out.stderr

@@ -333,30 +333,6 @@ def test_profile_report_shows_cache_columns(aot_dir):
     assert "2 AOT hits" in stats_line and "0 compiles" in stats_line
 
 
-def test_persistent_cache_flag_change_warns(monkeypatch, tmp_path):
-    """Satellite: maybe_enable_persistent_cache no longer silently
-    ignores a mid-process flag change, and enable failures warn with
-    the reason instead of returning None silently."""
-    monkeypatch.setattr(cc, "_enabled_dir", str(tmp_path / "first"))
-    monkeypatch.setenv("FLAGS_compile_cache_dir", str(tmp_path / "second"))
-    cc._warned.discard("xla-cache-repoint")
-    with pytest.warns(RuntimeWarning, match="already enabled"):
-        got = cc.maybe_enable_persistent_cache()
-    assert got == str(tmp_path / "first")
-    monkeypatch.setenv("FLAGS_compile_cache_dir", "")
-    cc._warned.discard("xla-cache-disable")
-    with pytest.warns(RuntimeWarning, match="cannot be disabled"):
-        assert cc.maybe_enable_persistent_cache() == str(
-            tmp_path / "first")
-    # enable failure: unwritable path warns with the reason
-    monkeypatch.setattr(cc, "_enabled_dir", None)
-    monkeypatch.setenv("FLAGS_compile_cache_dir",
-                       "/proc/definitely/not/writable")
-    cc._warned.discard("xla-cache-enable")
-    with pytest.warns(RuntimeWarning, match="could not enable"):
-        assert cc.maybe_enable_persistent_cache() is None
-
-
 def test_gc_retention(aot_dir):
     main, startup, loss = _build_model()
     _train(main, startup, loss)
@@ -510,3 +486,68 @@ def test_serving_warmup_through_aot_cache(aot_dir):
     got = e2.run_direct(req)[0]
     e2.close()
     assert np.array_equal(want[fetch], got[fetch])
+
+
+# ------------------------------------------------- devices and signatures --
+def test_aot_artifact_binds_to_the_executors_own_device(aot_dir):
+    """jax 0.9.0's deserialize_and_load binds an artifact to EVERY local
+    device unless told otherwise (the five seed failures: "expected 8
+    shards"); the executor passes its place's device, so a warm hit on a
+    device other than the first works and lands there."""
+    import jax
+
+    class ThirdCpu(fluid.CPUPlace):
+        device_id = 3
+
+    def run():
+        main, startup, loss = _build_model()
+        exe = fluid.Executor(ThirdCpu())
+        scope = fluid.Scope()
+        with fluid.scope_guard(scope):
+            exe.run(startup)
+            out = [exe.run(main, feed=_feed(), fetch_list=[loss])[0]
+                   for _ in range(2)]
+            where = {d for n in scope.names()
+                     if isinstance(scope.get(n), jax.Array)
+                     for d in scope.get(n).devices()}
+        return out, where
+
+    cold, _ = run()
+    cc.reset_aot_stats()
+    warm, where = run()
+    assert cc.aot_stats()["hits"] == 2 and cc.aot_stats()["stores"] == 0
+    assert where == {jax.devices("cpu")[3]}
+    for a, b in zip(cold, warm):
+        assert np.array_equal(a, b)
+
+
+def test_committed_feeds_compile_the_step_once():
+    """Feeds staged with jax.device_put(x, device) are committed and
+    commit the step's outputs; the executor commits the state with them,
+    or step 2 (whose state is those outputs) would lower under another
+    argument signature and XLA would compile the program twice."""
+    import jax
+
+    compiles = []
+
+    def on_compile(event, duration, **_):
+        if event == "/jax/core/compile/backend_compile_duration":
+            compiles.append(duration)
+
+    main, startup, loss = _build_model()
+    exe = fluid.Executor(fluid.CPUPlace())
+    dev = exe.place.device()
+    feed = {k: jax.device_put(v, dev) for k, v in _feed().items()}
+    assert all(v.committed for v in feed.values())
+    jax.monitoring.register_event_duration_secs_listener(on_compile)
+    try:
+        with fluid.scope_guard(fluid.Scope()):
+            exe.run(startup)
+            exe.run(main, feed=feed, fetch_list=[loss])
+            assert compiles                    # step 1 compiled
+            del compiles[:]
+            for _ in range(3):
+                exe.run(main, feed=feed, fetch_list=[loss])
+    finally:
+        jax.monitoring.unregister_event_duration_listener(on_compile)
+    assert compiles == []

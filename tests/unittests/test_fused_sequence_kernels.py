@@ -245,7 +245,14 @@ def test_dynamic_lstmp_fused_path_matches_scan_path(monkeypatch):
                               proj_size=p)
     dense = _run_lstm_program("0", seqs, w, b, monkeypatch, d,
                               proj_size=p)
-    assert np.array_equal(np.asarray(fused[0]), np.asarray(dense[0]))
+    # f32 forward within 2 ulp of the output's scale: the kernel body and
+    # the scan step are the same primitives, but XLA fuses the
+    # projection's tanh(h @ w_proj) differently in the two programs
+    # (1 ulp at the largest magnitude seen on jax 0.9.0)
+    want = np.asarray(dense[0])
+    np.testing.assert_allclose(
+        np.asarray(fused[0]), want, rtol=0,
+        atol=2 * np.spacing(np.abs(want).max()))
     np.testing.assert_allclose(np.asarray(fused[2]), np.asarray(dense[2]),
                                rtol=1e-4, atol=1e-6)
 

@@ -54,6 +54,55 @@ def test_pallas_flag_typo_raises(monkeypatch):
         kc.pallas_explicit("attn")
 
 
+def test_dispatch_platform_is_the_pinned_device_not_the_backend(monkeypatch):
+    """Both executors trace inside jax.default_device(<their device>):
+    that pin, not the process default backend, decides Mosaic vs the
+    interpreter — an Executor(CPUPlace()) on a TPU host must never hand
+    Mosaic a CPU compile."""
+    class Chip(object):
+        platform = "tpu"
+
+    cpu = jax.devices("cpu")[0]
+    monkeypatch.setattr(jax, "devices", lambda *a: [Chip()])
+    assert kc.dispatch_platform() == "tpu"          # no pin: the backend
+    assert pk._interpret_default() is False
+    with jax.default_device(cpu):
+        assert kc.dispatch_platform() == "cpu"      # the pin wins
+        assert pk._interpret_default() is True
+        monkeypatch.delenv("PADDLE_TPU_PALLAS", raising=False)
+        assert kc.pallas_on("ln") is False
+
+
+def test_default_on_table_decides_on_a_tpu(monkeypatch):
+    """With the flag unset, an op is on exactly when it dispatches to a
+    TPU AND its DEFAULT_ON entry holds — the table a kernel leaves when
+    Mosaic refuses it; the explicit flag still wins."""
+    monkeypatch.delenv("PADDLE_TPU_PALLAS", raising=False)
+    monkeypatch.setattr(kc, "dispatch_platform", lambda: "tpu")
+    assert set(kc.DEFAULT_ON) == set(kc.KERNEL_OPS)
+    assert all(kc.pallas_on(op) for op in kc.KERNEL_OPS)
+    monkeypatch.setitem(kc.DEFAULT_ON, "lstm", False)
+    monkeypatch.setitem(kc.DEFAULT_ON, "attn", False)
+    assert kc.pallas_on("lstm") is False and kc.pallas_on("ln") is True
+    assert kc.flash_at(4096) is False               # dense by table
+    monkeypatch.setenv("PADDLE_TPU_PALLAS", "lstm,attn")
+    assert kc.pallas_on("lstm") is True and kc.flash_at(4096) is True
+
+
+def test_no_flag_means_no_tuning_store(monkeypatch):
+    """Tiles, the flash crossover and every cache key are read at trace
+    time: with FLAGS_tuning_store_dir unset nothing outside the checkout
+    can shape what is compiled."""
+    monkeypatch.delenv("FLAGS_tuning_store_dir", raising=False)
+    assert TuningStore().root is None
+    assert kc.kernel_env_key() == ""
+    assert kc.tiles_for("attn", 2048) == kc.DEFAULT_TILES["attn"]
+    monkeypatch.delenv("FLAGS_flash_min_seq", raising=False)
+    assert kc.flash_min_seq() == kc.DEFAULT_FLASH_MIN_SEQ
+    with pytest.raises(ValueError, match="no tuning store"):
+        TuningStore().put("kernel:attn/b2048", "cpu/cpu", {"block_q": 64})
+
+
 def test_shape_bucket():
     assert kc.shape_bucket(1) == 8
     assert kc.shape_bucket(8) == 8
