@@ -630,27 +630,21 @@ def call_with_aval_fallback(call, jitted, aot_entry, find_aot_entry,
         return call(rebuild()), True
 
 
-def profile_dispatch(owner, tag, sync_tag, t0, arrays, compiled, aot_hit,
+def profile_dispatch(tag, sync_tag, t0, arrays, compiled, aot_hit,
                      aot_saved, aot_compile_s):
-    """Profiling-mode dispatch accounting (one copy): sync, per-tag
-    seconds (a compiled call's seconds include its eager-AOT compile —
+    """Profiling-mode dispatch accounting (one copy): sync, then per-tag
+    host seconds (a compiled call's seconds include its eager-AOT compile —
     it ran before t0, so add it back or Compile(s) reports a 30s compile
-    as free), and the device-idle gap — this dispatch STARTED after the
-    previous one had already completed, so the device sat with nothing
-    queued for (t0 - last_ready). `owner` carries `_last_ready_t`."""
+    as free). Device idle is not estimated here: a host clock behind a
+    sync cannot see it; the device trace does (profiler.device_op_table,
+    the benchmark's device_idle_share)."""
     import jax as _jax
     from .. import profiler as _prof
     _prof.note_sync(sync_tag)
     _jax.block_until_ready(arrays)
-    t_ready = time.perf_counter()
-    idle = None
-    if owner._last_ready_t is not None and t0 > owner._last_ready_t:
-        idle = t0 - owner._last_ready_t
-    owner._last_ready_t = t_ready
-    _prof.record_run(tag, t_ready - t0 + (aot_compile_s if compiled
-                                          else 0.0),
-                     compiled=compiled, aot_hit=aot_hit,
-                     saved_s=aot_saved, idle_s=idle)
+    _prof.record_run(tag, time.perf_counter() - t0
+                     + (aot_compile_s if compiled else 0.0),
+                     compiled=compiled, aot_hit=aot_hit, saved_s=aot_saved)
 
 
 def run_with_deadline(fn, timeout, what="dispatch"):

@@ -626,8 +626,6 @@ class Executor(object):
         self._prefetcher = None  # core/dispatch.HostIoPrefetcher, armed
         # lazily by the first run(prefetch=True) on a reader-fed program
         self._has_read = {}  # (uid, version) -> program has `read` ops
-        self._last_ready_t = None  # profiling: previous dispatch's
-        # completion time, for the device-idle-gap column
         self.last_stats = {}  # guard stat channel (grad_norm, ...):
         # device-resident values peeled off the newest dispatch's error
         # dict — the sentinel's zero-extra-sync tap
@@ -1007,7 +1005,7 @@ class Executor(object):
                 " x%d" % steps if steps > 1 else "",
                 ",".join(fetch_names) or "-")
             _dispatch.profile_dispatch(
-                self, tag, "executor/profiling", t0,
+                tag, "executor/profiling", t0,
                 (fetches, new_state), compiled, aot_hit, aot_saved,
                 aot_compile_s)
 

@@ -12,6 +12,8 @@ forward op's registered rule; recomputed forward subexpressions are
 deduplicated by XLA CSE, so the backward pass costs the same as hand-written
 grad kernels (reference: paddle/fluid/operators/*_grad kernels).
 """
+import re
+
 import numpy as np
 
 import jax
@@ -473,9 +475,49 @@ def _annotate_op_error(e, op):
     e._op_notes = noted + 1
 
 
+# --- fluid-op scopes: device time gets the program's own names -------------
+# Every op lowers inside one jax.named_scope, "op:<type>/<instance>", which
+# reaches the compiled HLO as each instruction's metadata op_name (a fusion
+# carries its root instruction's) and from there the profiler's device trace.
+# The marker is what no jax primitive or transform can produce: `transpose`,
+# `scale` and `sum` are fluid ops AND jax names, and a backward pass wraps
+# everything in `transpose(jvp(...))`. A `grad_of` op is named by what it
+# differentiates, `<fwd_type>_grad`; the instance is the op's first output
+# variable. XLA cuts an op_name at its first "@" (its own `name@function`
+# convention), so "x@GRAD" travels as "x~GRAD"; "/", "(" and ")" structure
+# the path and turn into "_".
+SCOPE_MARK = "op:"
+_SCOPE_ESCAPES = str.maketrans({"@": "~", "/": "_", "(": "_", ")": "_"})
+_SCOPE_RE = re.compile(r"(?:^|[/(])" + SCOPE_MARK + r"([^/()]+)/([^/()]+)")
+
+
+def op_scope(op):
+    """The named scope of one fluid op: "op:<type>/<instance>"."""
+    op_type = op.type
+    if op_type == "grad_of":
+        op_type = op.attrs["fwd_type"] + "_grad"
+    instance = next((n for names in op.outputs.values() for n in names if n),
+                    "-")
+    return "%s%s/%s" % (SCOPE_MARK, op_type,
+                        instance.translate(_SCOPE_ESCAPES))
+
+
+def parse_op_scope(op_name):
+    """(type, instance) of the innermost fluid scope on an HLO op_name path
+    such as "jit(fn)/transpose(jvp(op:mul/fc_0.tmp_1))/dot_general", or None
+    where the path carries none. The inverse of `op_scope` for variable
+    names free of "~", "/", "(" and ")"."""
+    found = _SCOPE_RE.findall(op_name)
+    if not found:
+        return None
+    op_type, instance = found[-1]
+    return op_type, instance.replace("~", "@")
+
+
 def lower_op(ctx, op, env):
     try:
-        _lower_op_inner(ctx, op, env)
+        with jax.named_scope(op_scope(op)):
+            _lower_op_inner(ctx, op, env)
     except EnvReadError as e:
         # str(KeyError) reprs its arg, which would render the multi-line
         # creation-site note as literal \n escapes — re-raise the
@@ -606,7 +648,13 @@ def _lower_grad_of(ctx, op, env):
             # of the f32->bf16 cast upcasts)
             ins = _apply_amp(fwd_type, ins)
         ctx.begin_op(fwd_uid)  # replay the forward op's exact PRNG stream
-        outs = od.lower(ctx, ins, fwd_attrs)
+        # the grad op's scope once more, inside the differentiated function:
+        # jax renders a transform around the first scope inside it, and
+        # without this one that is a Pallas kernel's own name scope, whose
+        # HLO instruction would be `jvp_ptpu_layer_norm_fwd_` and not
+        # `ptpu_layer_norm_fwd`
+        with jax.named_scope(op_scope(op)):
+            outs = od.lower(ctx, ins, fwd_attrs)
         flat = []
         for slot, i, n in out_order:
             flat.append(outs[slot][i])

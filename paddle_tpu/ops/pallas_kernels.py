@@ -39,6 +39,16 @@ __all__ = ["flash_attention", "softmax_xent", "layer_norm",
 
 _NEG = -1e30
 
+# The name each pallas_call gives its Mosaic custom call: the HLO instruction
+# is `<name>.<n>`, and that is what a device trace, the profiler's per-op
+# table and the benchmark's per-kernel metrics find it by (without one it is
+# named by the sanitised name stack: `fn.65`, `jvp__.33`). Every call below
+# takes one of these; no name is a prefix of another but for a further word.
+KERNEL_NAMES = (
+    "ptpu_flash_fwd", "ptpu_flash_bwd_dkdv", "ptpu_flash_bwd_dq",
+    "ptpu_softmax_xent_fwd", "ptpu_layer_norm_fwd", "ptpu_lstm_seq",
+    "ptpu_lstmp_seq", "ptpu_masked_softmax", "ptpu_masked_pool")
+
 
 def _interpret_default():
     """Mosaic exactly when the traced computation dispatches to a TPU;
@@ -146,6 +156,7 @@ def _flash_fwd(q, k, v, kv_len, scale, causal, block_q, block_k, interpret):
             jax.ShapeDtypeStruct((bh, t_pad, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="ptpu_flash_fwd",
     )(q, k, v, lens)
     return out[:, :t], lse[:, :t, 0]
 
@@ -286,6 +297,7 @@ def _flash_bwd(scale, causal, block_q, block_k, interpret, res, g):
             jax.ShapeDtypeStruct((bh, t_pad, d), v.dtype),
         ],
         interpret=interpret,
+        name="ptpu_flash_bwd_dkdv",
     )(q, g, k, v, lse3, delta3, lens)
 
     dq = pl.pallas_call(
@@ -304,6 +316,7 @@ def _flash_bwd(scale, causal, block_q, block_k, interpret, res, g):
         out_specs=_vmem_spec((1, block_q, d), lambda b, i: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, t_pad, d), q.dtype),
         interpret=interpret,
+        name="ptpu_flash_bwd_dq",
     )(q, g, k, v, lse3, delta3, lens)
     return dq[:, :t], dk[:, :t], dv[:, :t]
 
@@ -403,6 +416,7 @@ def _xent_fwd_call(logits, labels, block_n, interpret):
             jax.ShapeDtypeStruct((n_pad, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="ptpu_softmax_xent_fwd",
     )(lp, lb)
     return loss[:n], lse[:n]
 
@@ -479,6 +493,7 @@ def _ln_fwd_call(x, scale, bias, eps, block_n, interpret):
             jax.ShapeDtypeStruct((n_pad, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="ptpu_layer_norm_fwd",
     )(xp, scale.reshape(1, d), bias.reshape(1, d))
     return y[:n], mean[:n], rstd[:n]
 
@@ -604,6 +619,7 @@ def _lstm_fwd_call(xs, ms, w, b, h0, c0, block_b, interpret):
             pltpu.VMEM((blk, d), jnp.float32),
         ],
         interpret=interpret,
+        name="ptpu_lstm_seq",
     )(xs, ms, w, b.reshape(1, -1), h0, c0)
     return hs[:, :bsz], cs[:, :bsz]
 
@@ -769,6 +785,7 @@ def _lstmp_fwd_call(xs, ms, w, w_proj, b, r0, c0, block_b, interpret):
             pltpu.VMEM((blk, d), jnp.float32),
         ],
         interpret=interpret,
+        name="ptpu_lstmp_seq",
     )(xs, ms, w, w_proj, b.reshape(1, -1), r0, c0)
     return rs[:, :bsz], cs[:, :bsz]
 
@@ -896,6 +913,7 @@ def _masked_softmax_call(x, lens, block_n, interpret):
         out_specs=_vmem_spec((block_n, t), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n_pad, t), x.dtype),
         interpret=interpret,
+        name="ptpu_masked_softmax",
     )(xp, lp)
     return y[:n]
 
@@ -962,6 +980,7 @@ def _masked_pool_call(x, lens, ptype, block_n, interpret):
         out_specs=_vmem_spec((block_n, f), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n_pad, f), x.dtype),
         interpret=interpret,
+        name="ptpu_masked_pool",
     )(xp, lp)
     return out[:n]
 

@@ -140,8 +140,6 @@ class ParallelExecutor(object):
         self._prefetcher = None  # core/dispatch.HostIoPrefetcher, armed
         # lazily by the first run(prefetch=True) on a reader-fed program
         self._has_read = {}  # (uid, version) -> program has `read` ops
-        self._last_ready_t = None  # profiling: previous completion, for
-        # the device-idle-gap column
 
     def _state_sharding(self, name):
         return self.plan.sharding_for(name)
@@ -557,7 +555,7 @@ class ParallelExecutor(object):
                     program._uid, program._version, self.device_count,
                     ",".join(fetch_names) or "-")
                 _dispatch.profile_dispatch(
-                    self, tag, "pexe/profiling", t0,
+                    tag, "pexe/profiling", t0,
                     (fetches, new_state), compiled, aot_hit, aot_saved,
                     aot_compile_s)
 

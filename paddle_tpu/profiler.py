@@ -2,21 +2,38 @@
 
 Parity: python/paddle/fluid/profiler.py (cuda_profiler/profiler context
 managers over platform::Profiler, whose report printed an Event table sorted
-by `sorted_key` in {calls,total,max,min,ave}). TPU-native: one jitted XLA
-computation replaces the reference's per-op kernel stream, so the profiled
-unit is the jit entry — per (program, feed-signature) call counts, compile
-time, and blocked run times — plus a jax.profiler trace (TensorBoard/XProf)
-for intra-computation detail.
+by `sorted_key` in {calls,total,max,min,ave}). Two tables take its place:
+
+  entries     one row a jit entry (program, feed signature): calls, compiles,
+              AOT hits, and in profiling mode host seconds behind a
+              block_until_ready. One jitted XLA computation replaces the
+              reference's per-op kernel stream, so the host sees entries.
+  device ops  the reference's per-op Event table, on the device's clock: one
+              row a fluid op type, read from the jax.profiler trace the
+              profiler wrote (or any kept trace: `python -m paddle_tpu.profiler
+              <trace dir>`). Every fluid op lowers inside a named scope
+              (core/lowering.op_scope) and every Pallas kernel has a name
+              (ops/pallas_kernels.KERNEL_NAMES); `device_op_table` sums each
+              device operation's self time under the innermost fluid scope of
+              its HLO op_name. A fusion belongs to its root instruction's
+              scope. Off a TPU the trace has no device plane and the table
+              is empty.
 """
 import contextlib
+import glob
+import os
 import threading
 import time
 
 import jax
 
+from .core.lowering import parse_op_scope
+
 __all__ = ["profiler", "start_profiler", "stop_profiler", "reset_profiler",
            "profile_report", "record_event", "cache_stats", "note_sync",
-           "sync_stats", "dispatch_path", "record_idle", "snapshot"]
+           "sync_stats", "dispatch_path", "record_idle", "snapshot",
+           "device_op_table", "device_op_table_from", "read_op_names",
+           "render_device_ops"]
 
 _active = False
 _trace_dir = None
@@ -87,9 +104,11 @@ def sync_stats():
 def record_idle(tag, idle_s):
     """Account `idle_s` seconds the device spent with no dispatch queued
     under `tag` (between one dispatch's completion and the next
-    dispatch's enqueue). The serving InflightWindow's completion thread
-    and the executors' profiling path report through here; the report's
-    Idle(s)/Util% columns render it."""
+    dispatch's enqueue). The serving InflightWindow's completion thread,
+    which observes real completions, reports through here; the report's
+    Idle(s)/Util% columns render it. The executors report none (their
+    host clock behind a sync cannot see it): their rows read "-", and a
+    training step's idle share comes from the device trace."""
     e = _entries.setdefault(tag, _fresh_entry())
     e["idle_s"] += idle_s
     e["gaps"] += 1
@@ -101,8 +120,7 @@ def _fresh_entry():
             "aot_hits": 0, "saved_s": 0.0, "idle_s": 0.0, "gaps": 0}
 
 
-def record_run(tag, seconds, compiled=False, aot_hit=False, saved_s=0.0,
-               idle_s=None):
+def record_run(tag, seconds, compiled=False, aot_hit=False, saved_s=0.0):
     """Executor hook: one jitted dispatch of `tag` took `seconds` (blocked).
     Calls that traced+compiled are counted separately (Compiles/Compile(s))
     so Total/Max/Min/Ave stay honest cache-hit execution times.
@@ -112,16 +130,9 @@ def record_run(tag, seconds, compiled=False, aot_hit=False, saved_s=0.0,
     compile — still an execution call (the deserialize happens before
     the timed dispatch), but counted in its own column with `saved_s`,
     the compile seconds the recording process paid minus the load time,
-    so warm-vs-cold process starts are visible per tag in one report.
-
-    idle_s: seconds the device sat with nothing queued before this
-    dispatch was enqueued (None = previous completion unknown or the
-    device still had work) — feeds the Idle(s)/Util% columns."""
+    so warm-vs-cold process starts are visible per tag in one report."""
     e = _entries.setdefault(tag, _fresh_entry())
     e["calls"] += 1
-    if idle_s is not None:
-        e["idle_s"] += idle_s
-        e["gaps"] += 1
     if aot_hit:
         e["aot_hits"] += 1
         e["saved_s"] += saved_s
@@ -164,7 +175,8 @@ def snapshot():
     """Machine-readable export of everything the profiler tracks, in one
     dict: {"entries": {tag: {calls, runs, total, max, min, ave,
     compiles, compile_s, aot_hits, saved_s, idle_s, gaps}},
-    "sync_stats": sync_stats(), "cache_stats": cache_stats()}. This is
+    "sync_stats": sync_stats(), "cache_stats": cache_stats(),
+    "device_ops": the newest `device_op_table` or None}. This is
     the PUBLIC surface for bench.py / the observability registry / CI
     gates — nothing should read the private `_entries` dict (its
     "min" sentinel and optional keys are internal). Values are plain
@@ -182,7 +194,7 @@ def snapshot():
              "idle_s": e.get("idle_s", 0.0), "gaps": e.get("gaps", 0)}
         entries[tag] = d
     return {"entries": entries, "sync_stats": sync_stats(),
-            "cache_stats": cache_stats()}
+            "cache_stats": cache_stats(), "device_ops": _device_ops}
 
 
 _SORT_KEYS = ("calls", "total", "max", "min", "ave")
@@ -236,9 +248,9 @@ def profile_report(sorted_key=None, json=False):
         idle = e.get("idle_s", 0.0)
         # device utilization under this tag between first and last
         # dispatch: busy time over busy+observed idle gaps. Only
-        # meaningful where completion times were observed (profiling
-        # executors, the serving in-flight window) — tags with no idle
-        # observations render "-".
+        # meaningful where completion times were observed (the serving
+        # in-flight window) — tags with no idle observations, every
+        # executor's among them, render "-".
         util = (100.0 * total / (total + idle)
                 if (total + idle) > 0 and e.get("gaps", 0) else None)
         rows.append((tag, e["calls"], total, e["max"],
@@ -257,9 +269,10 @@ def profile_report(sorted_key=None, json=False):
     for (tag, calls, total, mx, mn, ave, ncomp, comp, ahit,
          saved, idle, util) in rows:
         lines.append("%-40s %8d %10.4f %10.4f %10.4f %10.4f %9d %10.4f "
-                     "%7d %9.4f %8.4f %6s"
+                     "%7d %9.4f %8s %6s"
                      % (tag[:40], calls, total, mx, mn, ave, ncomp, comp,
-                        ahit, saved, idle,
+                        ahit, saved,
+                        "-" if util is None else "%.4f" % idle,
                         "-" if util is None else "%.1f" % util))
     if rows:
         cs = cache_stats()
@@ -278,12 +291,255 @@ def profile_report(sorted_key=None, json=False):
     return "\n".join(lines)
 
 
+# --- the device's per-op table ---------------------------------------------
+_OPS_LINE = "XLA Ops"
+_DEVICE_PLANE = "/device:TPU:"
+_MOSAIC = 'custom_call_target="tpu_custom_call"'
+_COLLECTIVES = ("all-reduce", "all-gather", "reduce-scatter", "all-to-all",
+                "collective-permute", "collective-broadcast")
+_device_ops = None  # the newest device_op_table(), for profile_report
+
+
+def find_xplane(trace_dir):
+    """The newest .xplane.pb under a jax.profiler trace directory (or
+    `trace_dir` itself, if it is the file), else None."""
+    if os.path.isfile(trace_dir):
+        return trace_dir
+    found = sorted(glob.glob(os.path.join(
+        trace_dir, "plugins", "profile", "*", "*.xplane.pb")))
+    return found[-1] if found else None
+
+
+def _self_times(events):
+    """[[event, self_ns]] of one line's properly nested events (children
+    nest inside `while` and `conditional` parents): an event's self time is
+    its duration less its direct children's. The rule of
+    benchmark/trace_reduce.py, which the program may not import."""
+    timed = sorted(((float(e.start_ns), float(e.start_ns + e.duration_ns), e)
+                    for e in events if e.duration_ns > 0),
+                   key=lambda t: (t[0], -t[1]))
+    out, stack = [], []         # stack of (index into out, end)
+    for start, end, e in timed:
+        while stack and stack[-1][1] <= start:
+            stack.pop()
+        if stack:
+            out[stack[-1][0]][1] -= min(end, stack[-1][1]) - start
+        out.append([e, end - start])
+        stack.append((len(out) - 1, end))
+    return out
+
+
+def _varint(buf, i):
+    shift = value = 0
+    while True:
+        byte = buf[i]
+        i += 1
+        value |= (byte & 0x7F) << shift
+        if not byte & 0x80:
+            return value, i
+        shift += 7
+
+
+def _fields(buf):
+    """(field number, value) of one protobuf message on the wire: a varint
+    as an int, a length-delimited field as a slice of `buf`."""
+    i, n = 0, len(buf)
+    while i < n:
+        key, i = _varint(buf, i)
+        wire = key & 7
+        if wire == 0:
+            value, i = _varint(buf, i)
+        elif wire == 2:
+            size, i = _varint(buf, i)
+            value, i = buf[i:i + size], i + size
+        elif wire in (1, 5):
+            size = 8 if wire == 1 else 4
+            value, i = buf[i:i + size], i + size
+        else:
+            raise ValueError("wire type %d: not an .xplane.pb" % wire)
+        yield key >> 3, value
+
+
+def read_op_names(space):
+    """{HLO instruction text: HLO metadata op_name} of the device operations
+    of one .xplane.pb, given as its bytes. The TPU runtime writes an operation's op_name (the
+    path of named scopes it was lowered under) as the stat `tf_op` of the
+    event's METADATA, which jax.profiler.ProfileData does not hand out (it
+    gives an event's own stats: offsets and durations). So the few fields
+    that lead there are read off the wire: XSpace.planes=1; XPlane.name=2,
+    .event_metadata=4 and .stat_metadata=5 (maps: key=1, value=2);
+    XEventMetadata.name=2, .stats=5; XStatMetadata.name=2;
+    XStat.metadata_id=1, .str_value=5. Operations XLA adds on its own
+    (prefetch copies, `slice-done`) carry no op_name and are left out."""
+    op_names = {}
+    for field, plane in _fields(memoryview(space)):
+        if field != 1:
+            continue
+        name, event_meta, stat_names = "", [], {}
+        for k, v in _fields(plane):
+            if k == 2:
+                name = bytes(v).decode()
+            elif k == 4:
+                event_meta.append(dict(_fields(v)).get(2, b""))
+            elif k == 5:
+                meta = dict(_fields(dict(_fields(v)).get(2, b"")))
+                stat_names[meta.get(1)] = bytes(meta.get(2, b""))
+        if not name.startswith(_DEVICE_PLANE):
+            continue
+        for meta in event_meta:
+            text = op_name = None
+            for k, v in _fields(meta):
+                if k == 2:
+                    text = bytes(v).decode()
+                elif k == 5:
+                    stat = dict(_fields(v))
+                    if stat_names.get(stat.get(1)) == b"tf_op" and 5 in stat:
+                        op_name = bytes(stat[5]).decode()
+            if text and op_name:
+                op_names[text] = op_name
+    return op_names
+
+
+def device_op_table(planes, op_names, by="type"):
+    """The per-op Event table of a device trace.
+
+    `planes`: the planes of a jax.profiler.ProfileData (anything with
+    `name` and `lines`; a line has `name` and `events`; an event `name`,
+    `start_ns` and `duration_ns`). Only the `XLA Ops` line of the
+    `/device:TPU:<n>` planes is read. `op_names`: {an event's name, the
+    operation's HLO text: its HLO metadata op_name}, as `read_op_names`
+    reads it from the same bytes.
+
+    Returns {"planes": n, "busy_self_ms", "scoped_ms", "rows": [...]}: one
+    row a fluid op type (`by="type"`) or a fluid op instance
+    (`by="instance"`: type/first output variable), the named Pallas kernels
+    and the collectives of a fluid op in rows of their own (`kernel`: the
+    kernel's name, or `all-reduce` and the like), and every operation that
+    carries no fluid scope under its own instruction name (`scoped` False),
+    never dropped: the rows' `total_ms` add up to `busy_self_ms`, the self
+    time of all device operations, averaged over the device planes. A row:
+    {"name", "kernel", "pass" (bwd: a `<type>_grad` op; fwd: any other
+    fluid op, the optimizer's among them; -: no scope), "scoped", "events",
+    "total_ms", "max_ms", "min_ms", "ave_ms", "share" (% of busy_self_ms)}.
+    """
+    if by not in ("type", "instance"):
+        raise ValueError("by must be 'type' or 'instance', got %r" % (by,))
+    device_planes = [p for p in planes if p.name.startswith(_DEVICE_PLANE)]
+    rows, keys = {}, {}
+    n = 0
+    for plane in device_planes:
+        events = [e for ln in plane.lines if ln.name == _OPS_LINE
+                  for e in ln.events]
+        if not events:
+            continue
+        n += 1
+        for e, self_ns in _self_times(events):
+            key = keys.get(e.name)
+            if key is None:     # one look at an operation's text
+                key = keys[e.name] = _row_key(
+                    e.name, op_names.get(e.name, ""), by)
+            row = rows.setdefault(key, [0, 0.0, 0.0, float("inf")])
+            row[0] += 1
+            row[1] += self_ns
+            row[2] = max(row[2], self_ns)
+            row[3] = min(row[3], self_ns)
+    busy = sum(r[1] for r in rows.values())
+    out = []
+    for (name, kernel, scoped), (count, total, mx, mn) in rows.items():
+        out.append({
+            "name": name, "kernel": kernel, "scoped": scoped,
+            "pass": "-" if not scoped else
+                    "bwd" if name.split("/")[0].endswith("_grad") else "fwd",
+            "events": count, "total_ms": total / 1e6 / n,
+            "max_ms": mx / 1e6, "min_ms": mn / 1e6,
+            "ave_ms": total / 1e6 / count,
+            "share": 100.0 * total / busy if busy else 0.0})
+    out.sort(key=lambda r: -r["total_ms"])
+    return {"planes": n, "busy_self_ms": busy / 1e6 / max(n, 1),
+            "scoped_ms": sum(
+                r["total_ms"] for r in out if r["scoped"] or
+                r["kernel"] and not r["kernel"].startswith(_COLLECTIVES)),
+            "rows": out}
+
+
+def _row_key(text, op_name, by):
+    """(row name, kernel or collective name or '', carries a fluid scope)
+    of one device operation from its whole HLO instruction text and its
+    op_name. GSPMD gives a collective the op_name of the fluid op whose
+    values it reduces, so it is set apart as a kernel is."""
+    instruction = text.split(" = ", 1)[0].lstrip("%")
+    base, _, n = instruction.rpartition(".")
+    if not n.isdigit():
+        base = instruction
+    kernel = base if _MOSAIC in text or base.startswith(_COLLECTIVES) else ""
+    scope = parse_op_scope(op_name)
+    if scope is None:
+        return (instruction if by == "instance" else base), kernel, False
+    return ("/".join(scope) if by == "instance" else scope[0]), kernel, True
+
+
+def device_op_table_from(trace_dir, by="type"):
+    """`device_op_table` of the newest trace under `trace_dir` (what
+    `profiler(profile_path=...)` or `benchmark/run.py --keep-trace` left
+    there); an empty table where there is none."""
+    path = find_xplane(trace_dir)
+    if path is None:
+        return device_op_table([], {}, by)
+    with open(path, "rb") as f:
+        space = f.read()
+    planes = jax.profiler.ProfileData.from_serialized_xspace(space).planes
+    return device_op_table(list(planes), read_op_names(space), by)
+
+
+_DEVICE_SORT = {"calls": "events", "total": "total_ms", "max": "max_ms",
+                "min": "min_ms", "ave": "ave_ms"}
+
+
+def render_device_ops(table, sorted_key=None, limit=None):
+    """The table `device_op_table` made, as text: most device time first,
+    or by `sorted_key` as the reference's Event table was."""
+    _check_sorted_key(sorted_key)
+    if not table["rows"]:
+        return ("device ops: the trace holds no TPU device plane (off the "
+                "chip there is no device clock): nothing to list")
+    rows = sorted(table["rows"],
+                  key=lambda r: -r[_DEVICE_SORT[sorted_key or "total"]])
+    busy = table["busy_self_ms"]
+    lines = ["%-44s %-22s %4s %8s %11s %9s %9s %9s %7s" % (
+        "Device op (fluid type, else instruction)", "Kernel/collective",
+        "Pass",
+        "Events", "Total(ms)", "Max(ms)", "Min(ms)", "Ave(ms)", "Busy%")]
+    for r in rows[:limit]:
+        lines.append("%-44s %-22s %4s %8d %11.3f %9.4f %9.4f %9.4f %7.2f" % (
+            r["name"][:44], r["kernel"][:22] or "-", r["pass"], r["events"],
+            r["total_ms"], r["max_ms"], r["min_ms"], r["ave_ms"],
+            r["share"]))
+    if limit is not None and len(rows) > limit:
+        rest = rows[limit:]
+        lines.append("%-44s %-22s %4s %8d %11.3f %9s %9s %9s %7.2f" % (
+            "(%d more rows)" % len(rest), "", "",
+            sum(r["events"] for r in rest),
+            sum(r["total_ms"] for r in rest), "", "", "",
+            sum(r["share"] for r in rest)))
+    lines.append(
+        "device ops: %.3f ms of self time a device over %d device plane(s); "
+        "%.2f%% under a fluid op or a named kernel (fwd %.3f ms, bwd %.3f "
+        "ms); a fusion counts under its root instruction's scope"
+        % (busy, table["planes"],
+           100.0 * table["scoped_ms"] / busy if busy else 0.0,
+           sum(r["total_ms"] for r in rows if r["pass"] == "fwd"),
+           sum(r["total_ms"] for r in rows if r["pass"] == "bwd")))
+    return "\n".join(lines)
+
+
 def stop_profiler(sorted_key=None, profile_path="/tmp/profile"):
-    global _trace_dir, _active
+    global _trace_dir, _active, _device_ops
     _active = False
+    traced = False
     if _trace_dir is not None:
         try:
             jax.profiler.stop_trace()
+            traced = True
         except Exception:
             pass
         _trace_dir = None
@@ -293,11 +549,15 @@ def stop_profiler(sorted_key=None, profile_path="/tmp/profile"):
               % (_span[1] - _span[0], profile_path))
     if _entries:
         print(profile_report(sorted_key))
+    if traced:
+        _device_ops = device_op_table_from(profile_path)
+        print(render_device_ops(_device_ops, sorted_key))
 
 
 def reset_profiler():
-    global _syncs_on_dispatch
+    global _syncs_on_dispatch, _device_ops
     _entries.clear()
+    _device_ops = None
     with _sync_lock:
         _syncs.clear()
         _syncs_on_dispatch = 0
@@ -309,3 +569,31 @@ def cuda_profiler(*args, **kwargs):
     """Reference API kept for script compatibility; profiles the TPU."""
     with profiler():
         yield
+
+
+def main(argv=None):
+    """python -m paddle_tpu.profiler <trace dir>: the device's per-op table
+    of a kept trace."""
+    import argparse
+    import json
+    ap = argparse.ArgumentParser(
+        prog="python -m paddle_tpu.profiler", description=main.__doc__)
+    ap.add_argument("trace", help="a jax.profiler trace directory (what "
+                    "profiler(profile_path=...) or benchmark/run.py "
+                    "--keep-trace left), or an .xplane.pb file")
+    ap.add_argument("--sorted-key", choices=_SORT_KEYS, default=None)
+    ap.add_argument("--by", choices=("type", "instance"), default="type")
+    ap.add_argument("--limit", type=int, default=None,
+                    help="print the first LIMIT rows and sum the rest")
+    ap.add_argument("--json", action="store_true",
+                    help="print the table as one JSON object")
+    args = ap.parse_args(argv)
+    if find_xplane(args.trace) is None:
+        ap.error("no .xplane.pb under %s" % args.trace)
+    table = device_op_table_from(args.trace, args.by)
+    print(json.dumps(table) if args.json
+          else render_device_ops(table, args.sorted_key, args.limit))
+
+
+if __name__ == "__main__":
+    main()

@@ -1,0 +1,520 @@
+"""Device time carries the program's own names: a named scope a fluid op
+(core/lowering.op_scope / parse_op_scope), a name a Pallas kernel
+(ops/pallas_kernels.KERNEL_NAMES), and the profiler's per-op table of a
+device trace (profiler.device_op_table)."""
+import ast
+import os
+import re
+import types
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+import paddle_tpu as fluid
+from paddle_tpu import profiler
+from paddle_tpu.core import lowering
+from paddle_tpu.ops import pallas_kernels
+
+
+# --- a scope a fluid op ----------------------------------------------------
+
+def _program(amp=False, remat=False):
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.unique_name.guard(), fluid.program_guard(main, startup):
+        x = fluid.layers.data(name="x", shape=[13], dtype="float32")
+        y = fluid.layers.data(name="y", shape=[1], dtype="float32")
+        h = x
+        for _ in range(4 if remat else 1):   # remat wants >= 8 forward ops
+            h = fluid.layers.fc(input=h, size=8, act="relu")
+        pred = fluid.layers.fc(input=h, size=1)
+        loss = fluid.layers.mean(
+            x=fluid.layers.square_error_cost(input=pred, label=y))
+        fluid.optimizer.Momentum(learning_rate=0.1,
+                                 momentum=0.9).minimize(loss)
+    if amp:
+        main.enable_mixed_precision()
+    if remat:
+        fluid.memory_optimization_transpiler.enable_rematerialization(main)
+    return main, startup, loss
+
+
+def _lowered_op_names(main, startup, loss, steps, unroll):
+    """The op_name paths of the lowered step (its MLIR locations)."""
+    feeds = ["x", "y"]
+    rw, ro, out = lowering.analyze_state(main, feeds, [loss.name])
+    scope = fluid.Scope()
+    with fluid.scope_guard(scope):
+        fluid.Executor(fluid.CPUPlace()).run(startup)
+        vals = {n: np.asarray(scope.find_var(n).get_tensor())
+                for n in set(rw) | set(ro)}
+    if steps > 1:
+        fn = lowering.lower_multi_step(main, feeds, [loss.name], rw, ro, out,
+                                       steps, unroll=unroll)
+    else:
+        fn = lowering.build_program_fn(main, feeds, [loss.name], rw, ro, out)
+    lowered = jax.jit(lambda f, a, b: fn(f, a, b, 0)).lower(
+        [np.zeros((4, 13), "float32"), np.zeros((4, 1), "float32")],
+        [vals[n] for n in rw], [vals[n] for n in ro])
+    names = set(re.findall(r'loc\("([^"]*)"',
+                           lowered.as_text(debug_info=True)))
+    if steps > 1 and not unroll:    # the scan's body is a call of its own
+        names |= set(re.findall(    # there; the compiled HLO has whole paths
+            r'op_name="([^"]*)"', lowered.compile().as_text()))
+    return names
+
+
+@pytest.mark.parametrize("amp,remat,steps,unroll", [
+    (False, False, 1, False), (True, False, 1, False),
+    (False, True, 1, False), (False, False, 2, False),
+    (False, False, 2, True), (True, True, 2, False)],
+    ids=["plain", "amp", "remat", "steps2_scan", "steps2_unrolled",
+         "amp_remat_steps2"])
+def test_every_fluid_op_of_the_block_lowers_under_its_scope(
+        amp, remat, steps, unroll):
+    main, startup, loss = _program(amp, remat)
+    names = _lowered_op_names(main, startup, loss, steps, unroll)
+    got = {lowering.parse_op_scope(n) for n in names} - {None}
+    ops = main.global_block().ops
+    want = {}
+    for op in ops:
+        op_type = (op.attrs["fwd_type"] + "_grad" if op.type == "grad_of"
+                   else op.type)
+        instance = next(n for v in op.outputs.values() for n in v if n)
+        want[(op_type, instance)] = op
+    assert {t for t, _ in want} >= {"mul", "mul_grad", "momentum", "mean",
+                                    "relu_grad", "fill_constant"}
+    assert set(want) == got
+    if steps > 1 and not unroll:
+        assert any("while/body" in n and lowering.parse_op_scope(n)
+                   for n in names)
+    if remat:       # a segment replayed by the backward nests in a grad op
+        assert any(n.count(lowering.SCOPE_MARK) >= 2 for n in names)
+
+
+@pytest.mark.parametrize("path,want", [
+    ("jit(fn)/op:mul/fc_0.tmp_0/dot_general", ("mul", "fc_0.tmp_0")),
+    ("jit(fn)/jit(main)/transpose(jvp(op:scale/tmp_3))/mul",
+     ("scale", "tmp_3")),
+    ("jit(fn)/op:mul_grad/fc_0.w_0~GRAD/transpose(jvp())/dot_general",
+     ("mul_grad", "fc_0.w_0@GRAD")),
+    ("jit(fn)/op:relu_grad/a~GRAD/transpose(op:relu_grad/a~GRAD)/jvp()/"
+     "select_n", ("relu_grad", "a@GRAD")),
+    ("jit(fn)/op:conv2d_grad/w~GRAD/transpose(jvp(op:conv2d_grad/w~GRAD))/"
+     "jvp()/checkpoint/rematted_computation/conv_general_dilated",
+     ("conv2d_grad", "w@GRAD")),
+    ("jit(fn)/while/body/op:sum/x~GRAD/add_any", ("sum", "x@GRAD")),
+    ("jit(fn)/op:while/out_1/while/body/op:transpose/tmp_3/transpose",
+     ("transpose", "tmp_3")),
+    ("jit(fn)/op:mul_grad/a~GRAD/op:reshape/tmp_1/reshape",
+     ("reshape", "tmp_1")),
+    ("jit(fn)/transpose(jvp(mul.4))/dot_general", None),
+    ("jit(fn)/transpose/scale/sum/top:k", None),
+    ("", None)])
+def test_parse_op_scope_finds_the_innermost_fluid_scope(path, want):
+    assert lowering.parse_op_scope(path) == want
+
+
+@pytest.mark.parametrize("op_type,attrs,outputs,want", [
+    ("conv2d", {}, {"Output": ["conv2d_0.tmp_0"]},
+     ("conv2d", "conv2d_0.tmp_0")),
+    ("grad_of", {"fwd_type": "conv2d"},
+     {"InGrad::Input": [""], "InGrad::Filter": ["res2a_branch2a.w_0@GRAD"]},
+     ("conv2d_grad", "res2a_branch2a.w_0@GRAD")),
+    ("while", {}, {"Out": ["x@GRAD@RENAME@0"]},
+     ("while", "x@GRAD@RENAME@0")),
+    ("print", {}, {}, ("print", "-"))])
+def test_op_scope_and_parse_op_scope_are_inverse(op_type, attrs, outputs,
+                                                 want):
+    op = types.SimpleNamespace(type=op_type, attrs=attrs, outputs=outputs)
+    name = lowering.op_scope(op)
+    assert name.startswith(lowering.SCOPE_MARK) and "@" not in name
+    for path in (name, "jit(fn)/%s/add" % name,
+                 "jit(fn)/transpose(jvp(%s))/mul" % name,
+                 "jit(fn)/while/body/%s/checkpoint/rematted_computation/dot"
+                 % name):
+        assert lowering.parse_op_scope(path) == want
+
+
+def test_an_at_sign_would_cut_the_op_name_short():
+    """Why op_scope writes '~' for '@': XLA keeps an op_name up to its
+    first '@' only, and the rest of the path would go with it."""
+    def f(x):
+        with jax.named_scope("op:scale/x@GRAD"):
+            return x * 2.0
+    text = jax.jit(f).lower(jnp.ones((4,))).compile().as_text()
+    assert "op:scale/x~GRAD" not in text and "x@GRAD" not in text
+    assert 'op_name="jit(f)/op:scale/x"' in text
+
+
+# --- a name a kernel -------------------------------------------------------
+
+def test_every_pallas_call_takes_its_name_from_kernel_names():
+    with open(pallas_kernels.__file__) as f:
+        tree = ast.parse(f.read())
+    names = []
+    for node in ast.walk(tree):
+        if isinstance(node, ast.Call) and isinstance(
+                node.func, ast.Attribute) and node.func.attr == "pallas_call":
+            kw = {k.arg: k.value for k in node.keywords}
+            assert isinstance(kw.get("name"), ast.Constant), \
+                "pallas_call at line %d has no literal name=" % node.lineno
+            names.append(kw["name"].value)
+    assert sorted(names) == sorted(pallas_kernels.KERNEL_NAMES)
+    assert len(set(names)) == len(names) == 9
+    for a in names:         # a reader matching `<name>` or `<name>.<n>`
+        for b in names:     # never counts one kernel under another
+            assert a == b or not (b + ".").startswith(a + ".")
+            assert a == b or not b.startswith(a) or b[len(a)] == "_"
+
+
+def test_no_transform_wraps_a_kernels_name_scope(monkeypatch):
+    """pallas_call opens a scope named after the kernel, and XLA names the
+    Mosaic call's instruction by the path's last scope as jax renders it:
+    `jvp(ptpu_layer_norm_fwd)` would become `jvp_ptpu_layer_norm_fwd_`. The
+    grad op's scope inside the differentiated function keeps every kernel's
+    scope bare, forward (run again by the grad op) and backward."""
+    monkeypatch.setenv("PADDLE_TPU_PALLAS", "1")    # interpreted, off a TPU
+    monkeypatch.setenv("FLAGS_flash_min_seq", "0")
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.unique_name.guard(), fluid.program_guard(main, startup):
+        x = fluid.layers.data(name="x", shape=[16, 2, 8], dtype="float32")
+        lab = fluid.layers.data(name="lab", shape=[1], dtype="int64")
+        q = fluid.layers.reshape(
+            fluid.layers.fc(input=x, size=16, num_flatten_dims=2),
+            shape=[-1, 16, 2, 8])
+        a = fluid.layers.fused_attention(q, q, q, causal=True)
+        h = fluid.layers.layer_norm(fluid.layers.reshape(a, shape=[-1, 256]))
+        loss = fluid.layers.mean(fluid.layers.softmax_with_cross_entropy(
+            fluid.layers.fc(input=h, size=32), lab))
+        fluid.optimizer.SGD(learning_rate=0.1).minimize(loss)
+    feeds = ["x", "lab"]
+    rw, ro, out = lowering.analyze_state(main, feeds, [loss.name])
+    scope = fluid.Scope()
+    with fluid.scope_guard(scope):
+        fluid.Executor(fluid.CPUPlace()).run(startup)
+        vals = {n: np.asarray(scope.find_var(n).get_tensor())
+                for n in set(rw) | set(ro)}
+    fn = lowering.build_program_fn(main, feeds, [loss.name], rw, ro, out)
+    text = jax.jit(lambda f, a, b: fn(f, a, b, 0)).lower(
+        [np.zeros((4, 16, 2, 8), "float32"), np.zeros((4, 1), "int32")],
+        [vals[n] for n in rw], [vals[n] for n in ro]).as_text(debug_info=True)
+    under = {}      # kernel -> the fluid op types it lowered under
+    for path in set(re.findall(r'loc\("([^"]*)"', text)):
+        for part in path.split("/"):
+            if "ptpu_" in part:
+                assert part in pallas_kernels.KERNEL_NAMES, path
+                under.setdefault(part, set()).add(
+                    lowering.parse_op_scope(path)[0])
+    assert under == {
+        "ptpu_flash_fwd": {"fused_attention", "fused_attention_grad"},
+        "ptpu_flash_bwd_dkdv": {"fused_attention_grad"},
+        "ptpu_flash_bwd_dq": {"fused_attention_grad"},
+        "ptpu_layer_norm_fwd": {"layer_norm", "layer_norm_grad"},
+        "ptpu_softmax_xent_fwd": {"softmax_with_cross_entropy",
+                                  "softmax_with_cross_entropy_grad"}}
+
+
+# --- the profiler's table of a device trace --------------------------------
+
+class _Ev(object):
+    def __init__(self, name, start, dur):
+        self.name, self.start_ns, self.duration_ns = name, start, dur
+
+
+class _Line(object):
+    def __init__(self, name, events):
+        self.name, self.events = name, events
+
+
+class _Plane(object):
+    def __init__(self, name, lines):
+        self.name, self.lines = name, lines
+
+
+_MOSAIC = ('%%%s = f32[8,128]{1,0} custom-call(f32[8,128]{1,0} %%p.1), '
+           'custom_call_target="tpu_custom_call", operand_layout_constrain'
+           'ts={f32[8,128]{1,0}}')
+
+
+def _planes():
+    """(planes, op_names). One device: a `while` of 100 us whose body holds
+    a forward fusion (30) and a backward fusion (20); a named Mosaic call
+    under a forward op (40) and the same kernel under its grad op (10,
+    twice); a copy XLA added, with no op_name (5); an all-reduce GSPMD put
+    under a fluid op (8) and one with no op_name (2); an async line and a
+    host plane the table must not read."""
+    body = "jit(fn)/op:while/out/while/body/"
+    texts = {
+        "while": "%while.3 = (s32[], f32[4]) while(%tuple.1), body=%b, "
+                 "condition=%c",
+        "fwd": "%fusion.7 = f32[4]{0} fusion(%p.2), kind=kOutput, calls=%fc",
+        "bwd": "%fusion.9 = f32[4]{0} fusion(%p.3), kind=kLoop, calls=%fd",
+        "ln": _MOSAIC % "ptpu_layer_norm_fwd.2",
+        "ln_again": _MOSAIC % "ptpu_layer_norm_fwd.5",
+        "copy": "%copy.11 = f32[4]{0} copy(f32[4]{0} %p.9)",
+        "sum": "%all-reduce.4 = f32[4]{0} all-reduce(f32[4]{0} %p.5), "
+               "replica_groups={{0,1}}, to_apply=%add",
+        "sum_xla": "%all-reduce-done.1 = f32[4]{0} all-reduce-done(%s.1)"}
+    op_names = {
+        texts["while"]: "jit(fn)/op:while/out/while:",
+        texts["fwd"]: body + "op:mul/fc_0.tmp_0/dot_general:",
+        texts["bwd"]: body + "op:mul_grad/fc_0.w_0~GRAD/transpose(jvp(op:mul_"
+                             "grad/fc_0.w_0~GRAD))/dot_general:",
+        texts["sum"]: "jit(fn)/op:batch_norm_grad/x~GRAD/transpose(jvp(op:"
+                      "batch_norm_grad/x~GRAD))/reduce_sum:",
+        texts["ln"]: "jit(fn)/op:layer_norm/ln_0.tmp_2/ptpu_layer_norm_fwd/"
+                     "pallas_call:",
+        texts["ln_again"]: "jit(fn)/op:layer_norm_grad/x~GRAD/jvp(op:layer_"
+                           "norm_grad/x~GRAD)/ptpu_layer_norm_fwd/pallas_call:"}
+    ops = [_Ev(texts["while"], 0, 100), _Ev(texts["fwd"], 10, 30),
+           _Ev(texts["bwd"], 50, 20), _Ev(texts["ln"], 100, 40),
+           _Ev(texts["ln_again"], 150, 10), _Ev(texts["ln_again"], 170, 10),
+           _Ev(texts["copy"], 200, 5), _Ev("%zero = f32[] constant(0)", 210, 0),
+           _Ev(texts["sum"], 220, 8), _Ev(texts["sum_xla"], 230, 2)]
+    planes = [
+        _Plane("/device:TPU:0", [
+            _Line("XLA Ops", ops),
+            _Line("Async XLA Ops", [_Ev("%all-reduce-start.1 = f32[4]{0} "
+                                        "all-reduce-start(%p)", 0, 500)])]),
+        _Plane("/host:CPU", [_Line("python", [_Ev("bench/run_call", 0,
+                                                  900)])])]
+    return planes, op_names
+
+
+def test_device_op_table_sums_to_the_busy_self_time():
+    table = profiler.device_op_table(*_planes())
+    rows = {(r["name"], r["kernel"]): r for r in table["rows"]}
+    assert table["planes"] == 1
+    assert table["busy_self_ms"] == pytest.approx(175e-6)
+    assert sum(r["total_ms"] for r in table["rows"]) == pytest.approx(
+        table["busy_self_ms"])
+    assert sum(r["share"] for r in table["rows"]) == pytest.approx(100.0)
+    # the while keeps what its body did not use; the innermost scope owns
+    assert rows[("while", "")]["total_ms"] == pytest.approx(50e-6)
+    assert rows[("mul", "")]["pass"] == "fwd"
+    assert rows[("mul_grad", "")]["pass"] == "bwd"
+    assert rows[("mul_grad", "")]["total_ms"] == pytest.approx(20e-6)
+    # one kernel under two fluid ops: a row each
+    assert rows[("layer_norm", "ptpu_layer_norm_fwd")]["events"] == 1
+    twice = rows[("layer_norm_grad", "ptpu_layer_norm_fwd")]
+    assert (twice["events"], twice["pass"]) == (2, "bwd")
+    assert twice["total_ms"] == pytest.approx(20e-6)
+    assert twice["ave_ms"] == twice["max_ms"] == twice["min_ms"]
+    # no scope: listed under its own name, never dropped
+    copy = rows[("copy", "")]
+    assert (copy["scoped"], copy["pass"]) == (False, "-")
+    # a collective is set apart from its fluid op's own arithmetic
+    reduce = rows[("batch_norm_grad", "all-reduce")]
+    assert (reduce["scoped"], reduce["pass"], reduce["events"]) == (
+        True, "bwd", 1)
+    assert rows[("all-reduce-done", "all-reduce-done")]["scoped"] is False
+    assert table["scoped_ms"] == pytest.approx(168e-6)
+
+
+def test_device_op_table_by_instance_and_over_planes():
+    planes, op_names = _planes()
+    planes.insert(1, _Plane("/device:TPU:1", planes[0].lines))
+    table = profiler.device_op_table(planes, op_names, by="instance")
+    rows = {(r["name"], r["kernel"]): r for r in table["rows"]}
+    assert table["planes"] == 2
+    assert table["busy_self_ms"] == pytest.approx(175e-6)   # a device
+    assert rows[("mul_grad/fc_0.w_0@GRAD", "")]["events"] == 2
+    assert rows[("mul_grad/fc_0.w_0@GRAD", "")]["total_ms"] == \
+        pytest.approx(20e-6)
+    assert ("copy.11", "") in rows
+    with pytest.raises(ValueError):
+        profiler.device_op_table(planes, op_names, by="layer")
+
+
+@pytest.mark.parametrize("sorted_key", [None, "calls", "total", "max",
+                                        "min", "ave"])
+def test_render_device_ops_sorts_by_every_sorted_key(sorted_key):
+    table = profiler.device_op_table(*_planes())
+    text = profiler.render_device_ops(table, sorted_key)
+    order = [ln.split()[0] for ln in text.splitlines()[1:-1]]
+    field = {None: "total_ms", "calls": "events", "total": "total_ms",
+             "max": "max_ms", "min": "min_ms", "ave": "ave_ms"}[sorted_key]
+    values = [next(r[field] for r in table["rows"]
+                   if r["name"] == name and
+                   (r["kernel"] or "-") == ln.split()[1])
+              for name, ln in zip(order, text.splitlines()[1:-1])]
+    assert len(values) == 8 and values == sorted(values, reverse=True)
+    assert "root instruction's scope" in text.splitlines()[-1]
+    cut = profiler.render_device_ops(table, sorted_key, limit=2)
+    assert "(6 more rows)" in cut and len(cut.splitlines()) == 5
+    with pytest.raises(ValueError):
+        profiler.render_device_ops(table, "bogus")
+
+
+def _varint(n):
+    out = b""
+    while True:
+        out += bytes([(n & 0x7F) | (0x80 if n > 0x7F else 0)])
+        n >>= 7
+        if not n:
+            return out
+
+
+def _msg(*fields):
+    """A protobuf message: (number, int) a varint, (number, bytes or str)
+    length-delimited."""
+    out = b""
+    for number, value in fields:
+        if isinstance(value, int):
+            out += _varint(number << 3) + _varint(value)
+        else:
+            value = value.encode() if isinstance(value, str) else value
+            out += _varint(number << 3 | 2) + _varint(len(value)) + value
+    return out
+
+
+def test_read_op_names_takes_tf_op_from_the_events_metadata(tmp_path):
+    """An .xplane.pb written by hand, field numbers as in xplane.proto: the
+    op_name is the stat `tf_op` of an XEventMetadata on a device plane."""
+    def plane(name, tf_op_id):
+        long_text = "%fusion.1 = f32[4]{0} fusion(%p), kind=kLoop " + "x" * 300
+        return _msg(
+            (1, 7), (2, name),
+            (5, _msg((1, 3), (2, _msg((1, 3), (2, "flops"))))),
+            (5, _msg((1, tf_op_id), (2, _msg((1, tf_op_id), (2, "tf_op"))))),
+            (4, _msg((1, 1), (2, _msg(
+                (1, 1), (2, long_text), (4, "fusion"),
+                (5, _msg((1, 3), (3, 1 << 40))),
+                (5, _msg((1, tf_op_id),
+                         (5, "jit(fn)/op:mul/fc_0.tmp_0/dot_general:"))))))),
+            (4, _msg((1, 2), (2, _msg(      # XLA's own: no op_name
+                (1, 2), (2, "%copy.2 = f32[4]{0} copy(%p)"),
+                (5, _msg((1, 3), (3, 0))))))),
+            (3, _msg((1, 1), (2, "XLA Ops"),
+                     (4, _msg((1, 1), (2, 1000), (3, 5000))))))
+    path = tmp_path / "hand.xplane.pb"
+    path.write_bytes(_msg((1, plane("/device:TPU:0", 26)),
+                          (1, plane("/host:CPU", 26)),
+                          (2, "an error string")))
+    got = profiler.read_op_names(path.read_bytes())
+    assert list(got.values()) == ["jit(fn)/op:mul/fc_0.tmp_0/dot_general:"]
+    assert next(iter(got)).startswith("%fusion.1 = ")
+    # and the whole way, through ProfileData: one event, under `mul`
+    table = profiler.device_op_table_from(str(path))
+    assert [(r["name"], r["events"], r["pass"]) for r in table["rows"]] == \
+        [("mul", 1, "fwd")]
+    assert table["busy_self_ms"] == pytest.approx(5e-6)
+
+
+def test_the_table_is_empty_and_harmless_off_the_chip(tmp_path, capsys):
+    main, startup, loss = _program()
+    scope = fluid.Scope()
+    profiler.reset_profiler()
+    with fluid.scope_guard(scope):
+        exe = fluid.Executor(fluid.CPUPlace())
+        exe.run(startup)
+        feed = {"x": np.ones((4, 13), "float32"),
+                "y": np.ones((4, 1), "float32")}
+        with profiler.profiler(profile_path=str(tmp_path)):
+            for _ in range(3):
+                exe.run(main, feed=feed, fetch_list=[loss])
+    out = capsys.readouterr().out
+    assert "no TPU device plane" in out
+    assert profiler.find_xplane(str(tmp_path)) is not None
+    snap = profiler.profile_report(json=True)
+    assert snap["device_ops"]["rows"] == []
+    assert snap["device_ops"]["busy_self_ms"] == 0.0
+    # the executors report no idle: their rows read "-" in both columns
+    row = next(ln for ln in profiler.profile_report().splitlines()
+               if ln.startswith("program_"))
+    assert row.split()[-2:] == ["-", "-"]
+    assert all(e["gaps"] == 0 for e in snap["entries"].values())
+    # an empty directory is no error either
+    empty = profiler.device_op_table_from(str(tmp_path / "nothing"))
+    assert empty["rows"] == []
+    profiler.main([str(tmp_path), "--sorted-key", "total", "--by",
+                   "instance"])
+    assert "no TPU device plane" in capsys.readouterr().out
+    profiler.reset_profiler()
+    assert profiler.profile_report(json=True)["device_ops"] is None
+
+
+# --- the names on a v5e, compiled here without the chip --------------------
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no TPU compiler on this machine
+        pytest.skip("no v5e:2x2 topology can be described here: %s" % e)
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def test_mosaic_calls_are_named_on_a_described_v5e(one_chip):
+    """layer_norm, softmax_xent and flash attention, forward and backward as
+    lower_op and _lower_grad_of scope them, compiled for a TPU: every
+    tpu_custom_call is named from KERNEL_NAMES, none by the name stack
+    (`fn`, `jvp__`, `transpose_jvp___`, `jvp_ptpu_layer_norm_fwd_`)."""
+    def grad_op(scope, rule, *ins):
+        with jax.named_scope(scope):            # lower_op
+            def f(*a):
+                with jax.named_scope(scope):    # _lower_grad_of, inside vjp
+                    return rule(*a)
+            out, vjp = jax.vjp(f, *ins)
+            return vjp(jnp.ones_like(out))
+
+    def loss(q, k, v, x, scale, bias, logits, labels):
+        def attn(q, k, v):
+            return pallas_kernels.flash_attention(q, k, v, causal=True,
+                                                  interpret=False)
+
+        def norm(x, scale, bias):
+            return pallas_kernels.layer_norm(x, scale, bias,
+                                             interpret=False)[0]
+
+        def xent(logits):
+            return pallas_kernels.softmax_xent(logits, labels,
+                                               interpret=False)
+        with jax.named_scope("op:fused_attention/attn_0.tmp_0"):
+            a = attn(q, k, v)
+        with jax.named_scope("op:layer_norm/ln_0.tmp_2"):
+            y = norm(x, scale, bias)
+        with jax.named_scope("op:softmax_with_cross_entropy/xent_0.tmp_0"):
+            nll = xent(logits)
+        return (a, y, nll,
+                grad_op("op:fused_attention_grad/q~GRAD", attn, q, k, v),
+                grad_op("op:layer_norm_grad/x~GRAD", norm, x, scale, bias),
+                grad_op("op:softmax_with_cross_entropy_grad/fc~GRAD", xent,
+                        logits))
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    args = (arg((2, 256, 2, 64), jnp.bfloat16),) * 3 + (
+        arg((64, 512), jnp.float32), arg((512,), jnp.float32),
+        arg((512,), jnp.float32), arg((64, 1024), jnp.float32),
+        arg((64,), jnp.int32))
+    from jax.experimental.compilation_cache import compilation_cache
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        text = jax.jit(loss).lower(*args).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        compilation_cache.reset_cache()
+    calls = re.findall(
+        r'%?([\w.\-]+) = [^\n]*custom_call_target="tpu_custom_call"', text)
+    kernels = {c.rpartition(".")[0] if c.rpartition(".")[2].isdigit() else c
+               for c in calls}
+    assert kernels == {"ptpu_flash_fwd", "ptpu_flash_bwd_dkdv",
+                       "ptpu_flash_bwd_dq", "ptpu_layer_norm_fwd",
+                       "ptpu_softmax_xent_fwd"}
+    assert kernels <= set(pallas_kernels.KERNEL_NAMES)
+    assert len(calls) >= 8      # each forward kernel under both its ops
+    assert not re.search(r"%(fn|jvp_|transpose_jvp_)[\w.]* = ", text)
+    scoped = {lowering.parse_op_scope(m)
+              for m in re.findall(r'op_name="([^"]*)"', text)}
+    assert {("layer_norm", "ln_0.tmp_2"), ("layer_norm_grad", "x@GRAD"),
+            ("fused_attention_grad", "q@GRAD")} <= scoped

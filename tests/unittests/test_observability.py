@@ -237,7 +237,9 @@ def test_profiler_snapshot_and_json_report():
     profiler.record_run("obs_entry", 0.25, compiled=True)
     profiler.note_sync("obs/sync")
     snap = profiler.snapshot()
-    assert set(snap) == {"entries", "sync_stats", "cache_stats"}
+    assert set(snap) == {"entries", "sync_stats", "cache_stats",
+                         "device_ops"}
+    assert snap["device_ops"] is None   # no trace was read since the reset
     e = snap["entries"]["obs_entry"]
     assert e["calls"] == 2 and e["runs"] == 1 and e["compiles"] == 1
     assert e["total"] == 0.5 and e["min"] == 0.5 and e["ave"] == 0.5
