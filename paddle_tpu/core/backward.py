@@ -4,7 +4,10 @@ Parity: python/paddle/fluid/backward.py + the reference's per-op GradOpMaker
 machinery (paddle/fluid/framework/grad_op_desc_maker.h). The reference needs a
 hand-written grad kernel per op; here every forward op gets a single generic
 "grad_of" op whose lowering computes input grads with jax.vjp of the forward
-lowering rule (core/lowering.py:_lower_grad_of). Gradient accumulation for
+lowering rule (core/lowering.py:_lower_grad_of): a replay of the rule, or,
+for the ops whose rule can reach a Pallas kernel, the vjp_fn the forward op
+of the same block kept under the `fwd_uid` the grad op carries
+(core/lowering.py:_linearizations). Gradient accumulation for
 fan-out (the reference's inserted sum_op after @RENAME@ bookkeeping) is
 handled by emitting grad ops in reverse topological order and accumulating
 into <var>@GRAD at lowering time.

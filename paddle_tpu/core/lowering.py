@@ -8,9 +8,14 @@ fuses elementwise chains into the matmuls/convs, plans memory, and overlaps
 collectives — none of which an op-at-a-time interpreter can do.
 
 Gradient ops ("grad_of" appended by core/backward.py) lower via jax.vjp of the
-forward op's registered rule; recomputed forward subexpressions are
+forward op's registered rule. For an op XLA generates, the grad op replays
+the rule under jax.vjp: the recomputed forward subexpressions are
 deduplicated by XLA CSE, so the backward pass costs the same as hand-written
-grad kernels (reference: paddle/fluid/operators/*_grad kernels).
+grad kernels (reference: paddle/fluid/operators/*_grad kernels). A Mosaic
+custom call is not deduplicated, so a forward op whose rule can reach a
+Pallas kernel (OpDef.calls_pallas) and whose gradient is taken in the same
+block lowers under jax.vjp once and keeps its vjp_fn for the grad op
+(_linearizations): every Pallas forward kernel runs once a step.
 """
 import re
 
@@ -148,6 +153,11 @@ class LowerCtx(object):
         # after the step (same channel as TensorArray overflow). Sticky OR
         # per message.
         self.op_errors = {}
+        # forward op uid -> (primal outputs, vjp_fn), for the forward ops of
+        # the block being lowered whose grad ops call the vjp_fn the forward
+        # op kept (see _linearizations); None until the forward op has
+        # lowered. lower_block gives every block a dict of its own.
+        self.linearized = {}
 
     def add_error(self, message, flag):
         """Record an in-graph assertion (checkify-style). Only valid at the
@@ -174,9 +184,10 @@ class LowerCtx(object):
 
     def rng(self, salt=0, seed=0):
         """Deterministic key derived from (run seed, op uid, call index within
-        the op). Re-lowering the same forward op inside jax.vjp (backward)
-        replays the identical key stream, so dropout masks / random inits are
-        grad-consistent and XLA CSE dedupes the recomputation.
+        the op). Re-lowering the same forward op inside jax.vjp (a grad op's
+        replay, a remat segment) replays the identical key stream, so dropout
+        masks / random inits are grad-consistent and XLA CSE dedupes what it
+        generated itself (not a Pallas kernel: _linearizations).
 
         A nonzero user `seed` (the op's seed attr — fluid's reproducibility
         contract) pins the key independent of the run counter, so the op
@@ -269,8 +280,31 @@ def lower_block(ctx, block, env):
     if getattr(ctx.program, "_rematerialize", False) and block.idx == 0 \
             and not ctx.is_startup and _lower_block_remat(ctx, ops, env):
         return
-    for op in ops:
-        lower_op(ctx, op, env)
+    outer = ctx.linearized
+    ctx.linearized = _linearizations(ctx, ops)
+    try:
+        for op in ops:
+            lower_op(ctx, op, env)
+    finally:
+        ctx.linearized = outer
+
+
+def _linearizations(ctx, ops):
+    """{uid: None} for the forward ops among `ops` that lower under jax.vjp
+    and keep their linearization, (primal outputs, vjp_fn), for the grad ops
+    of the same block: those a `grad_of` op names whose rule can reach a
+    Pallas kernel (OpDef.calls_pallas). A grad op differentiates its forward
+    rule, and differentiating it from scratch runs the rule's forward again;
+    where XLA generated the forward it merges the two, a Mosaic custom call
+    it runs twice. Every other op keeps the replay: nothing would be gained
+    on the device, and the HLO of every program would change. Under
+    rematerialization the replay is the point, so nothing is kept."""
+    if getattr(ctx.program, "_rematerialize", False):
+        return {}
+    return {op.attrs["fwd_uid"]: None for op in ops
+            if op.type == "grad_of" and "fwd_uid" in op.attrs
+            and registry.is_registered(op.attrs["fwd_type"])
+            and registry.get(op.attrs["fwd_type"]).calls_pallas}
 
 
 def _is_traced_array(v):
@@ -503,14 +537,23 @@ def op_scope(op):
 
 
 def parse_op_scope(op_name):
-    """(type, instance) of the innermost fluid scope on an HLO op_name path
-    such as "jit(fn)/transpose(jvp(op:mul/fc_0.tmp_1))/dot_general", or None
-    where the path carries none. The inverse of `op_scope` for variable
-    names free of "~", "/", "(" and ")"."""
-    found = _SCOPE_RE.findall(op_name)
+    """(type, instance) of the fluid op an HLO op_name path such as
+    "jit(fn)/transpose(jvp(op:mul/fc_0.tmp_1))/dot_general" belongs to, or
+    None where the path carries no fluid scope. That is the innermost scope
+    of the path, with one exception: a grad op that calls the linearization
+    its forward op kept (_linearizations) transposes equations traced under
+    the FORWARD op's scope, "op:layer_norm_grad/x~GRAD/transpose(jvp(op:
+    layer_norm/y))/mul", and those are the grad op's work. The inverse of
+    `op_scope` for variable names free of "~", "/", "(" and ")"."""
+    found = list(_SCOPE_RE.finditer(op_name))
     if not found:
         return None
-    op_type, instance = found[-1]
+    op_type, instance = found[-1].groups()
+    for m in reversed(found[:-1]):
+        if m.group(1) == op_type + "_grad" and "transpose(" in \
+                op_name[m.end():found[-1].start() + 1]:
+            op_type, instance = m.groups()
+            break
     return op_type, instance.replace("~", "@")
 
 
@@ -544,11 +587,24 @@ def _lower_op_inner(ctx, op, env):
     od = registry.get(op.type)
     ins = {slot: [env.read(n) for n in names]
            for slot, names in op.inputs.items()}
-    if ctx.amp:
-        ins = _apply_amp(op.type, ins)
-    ctx.begin_op(op.uid)
-    outs = od.lower(ctx, ins, op.attrs)
-    err = outs.pop("__errors__", None) if isinstance(outs, dict) else None
+    if op.uid in ctx.linearized:
+        # a grad op of this block differentiates this op: run the rule once,
+        # under jax.vjp, and keep what the backward needs
+        out_order = _out_order(op.outputs)
+        f, primal = _differentiable(ctx, od, op_scope(op), op.type, op.attrs,
+                                    op.uid, ins, out_order)
+        primals, vjp_fn, err = jax.vjp(f, primal, has_aux=True)
+        ctx.linearized[op.uid] = (primals, vjp_fn)
+        outs = {slot: [None] * len(names)
+                for slot, names in op.outputs.items()}
+        for (slot, i, _), p in zip(out_order, primals):
+            outs[slot][i] = p
+    else:
+        if ctx.amp:
+            ins = _apply_amp(op.type, ins)
+        ctx.begin_op(op.uid)
+        outs = od.lower(ctx, ins, op.attrs)
+        err = outs.pop("__errors__", None) if isinstance(outs, dict) else None
     if err is not None:
         accumulate_error(env, err)
     _write_outputs(op, outs, env)
@@ -603,76 +659,102 @@ SPECIAL_GRADS = {
 }
 
 
-def _lower_grad_of(ctx, op, env):
-    """Lower a generic gradient op via jax.vjp of the forward rule.
+def _out_order(outputs):
+    """A forward op's named outputs in a deterministic order: [(slot, index,
+    name)]. The differentiated function returns them so, and only the
+    floating-point ones carry cotangents."""
+    return [(slot, i, n)
+            for slot, names in sorted(outputs.items())
+            for i, n in enumerate(names) if n]
 
-    The grad op (built by core/backward.py) carries the forward op's type,
-    attrs, and input/output name maps. Cotangents for forward outputs come
-    from env (<out>@GRAD); outputs missing a grad var get zeros. Produced
-    input grads are ACCUMULATED into <in>@GRAD names, which is correct
-    because backward.py emits grad ops in reverse topological order.
-    """
-    fwd_type = op.attrs["fwd_type"]
-    if fwd_type in SPECIAL_GRADS:
-        SPECIAL_GRADS[fwd_type]["fn"](ctx, op, env)
-        return
-    fwd_attrs = op.attrs["fwd_attrs"]
-    fwd_inputs = op.attrs["fwd_inputs"]    # slot -> [names]
-    fwd_outputs = op.attrs["fwd_outputs"]  # slot -> [names]
-    od = registry.get(fwd_type)
 
-    fwd_in_vals = {slot: [env.read(n) for n in names]
-                   for slot, names in fwd_inputs.items()}
-    fwd_uid = op.attrs.get("fwd_uid", 0)
+def _differentiable(ctx, od, scope, op_type, attrs, uid, in_vals, out_order):
+    """(f, primal): a forward op's rule as a function of its floating-point
+    inputs `primal` ({(slot, index): value}), for jax.vjp with has_aux. f
+    returns the outputs in `out_order` and, as auxiliary data, the rule's
+    `__errors__` flag or None. One builder for both users: the forward op
+    that keeps its linearization (_lower_op_inner) and the grad op that
+    replays the forward (_lower_grad_of), so the two differentiate the same
+    function.
 
-    # Differentiate only w.r.t. floating-point inputs.
-    diff_keys = []
-    for slot, vals in fwd_in_vals.items():
-        for i, v in enumerate(vals):
-            if _is_float(v):
-                diff_keys.append((slot, i))
-    diff_primal = {k: fwd_in_vals[k[0]][k[1]] for k in diff_keys}
-
-    # Forward outputs in deterministic order; only float outputs carry cotangents.
-    out_order = [(slot, i, n)
-                 for slot, names in sorted(fwd_outputs.items())
-                 for i, n in enumerate(names) if n]
+    `scope` is the lowering op's own scope, opened once more inside f: jax
+    renders a transform around the first scope inside it, and without this
+    one that is a Pallas kernel's own name scope, whose HLO instruction
+    would be `jvp_ptpu_layer_norm_fwd_` and not `ptpu_layer_norm_fwd`."""
+    primal = {(slot, i): v for slot, vals in in_vals.items()
+              for i, v in enumerate(vals) if _is_float(v)}
 
     def f(diff):
-        ins = {slot: list(vals) for slot, vals in fwd_in_vals.items()}
+        ins = {slot: list(vals) for slot, vals in in_vals.items()}
         for (slot, i), v in diff.items():
             ins[slot][i] = v
         if ctx.amp:
             # the casts live inside the vjp, so bf16 ops get bf16 activation
             # cotangents while f32 master params receive f32 grads (the vjp
             # of the f32->bf16 cast upcasts)
-            ins = _apply_amp(fwd_type, ins)
-        ctx.begin_op(fwd_uid)  # replay the forward op's exact PRNG stream
-        # the grad op's scope once more, inside the differentiated function:
-        # jax renders a transform around the first scope inside it, and
-        # without this one that is a Pallas kernel's own name scope, whose
-        # HLO instruction would be `jvp_ptpu_layer_norm_fwd_` and not
-        # `ptpu_layer_norm_fwd`
-        with jax.named_scope(op_scope(op)):
-            outs = od.lower(ctx, ins, fwd_attrs)
-        flat = []
-        for slot, i, n in out_order:
-            flat.append(outs[slot][i])
-        return flat
+            ins = _apply_amp(op_type, ins)
+        ctx.begin_op(uid)  # the forward op's exact PRNG stream
+        with jax.named_scope(scope):
+            outs = od.lower(ctx, ins, attrs)
+        return ([outs[slot][i] for slot, i, _ in out_order],
+                outs.get("__errors__"))
 
-    # Rematerialization: when the segment-level pass handles this grad op
-    # (top-level backward of a >=8-op forward), it hands the replay
-    # recomputed barrier-guarded primals — per-op jax.checkpoint must NOT
-    # stack on top: for boundary/checkpoint inputs the replay SHOULD CSE
-    # with the forward (the residual is live anyway; blocking that was
-    # measured at +15G HBM on ResNet-50@512). Everywhere the segment pass
-    # cannot reach (grad ops inside control-flow sub-blocks, programs
-    # below the segment gate) the per-op checkpoint is still the only
-    # remat lever, so it stays as the fallback.
-    if getattr(ctx.program, "_rematerialize", False) \
-            and not getattr(ctx, "_segment_handled", False):
-        f = jax.checkpoint(f)
-    primals, vjp_fn = jax.vjp(f, diff_primal)
+    return f, primal
+
+
+def _count_grad_op(path, fwd_type):
+    from ..observability.registry import REGISTRY
+    REGISTRY.counter(
+        "ptpu_lowering_grad_ops_total",
+        "grad ops lowered, by forward op type and by whether the op used the "
+        "linearization its forward op kept or replayed the forward rule"
+    ).inc(path=path, op=fwd_type)
+
+
+def _lower_grad_of(ctx, op, env):
+    """Lower a generic gradient op: the vjp of the forward op's rule.
+
+    The grad op (built by core/backward.py) carries the forward op's type,
+    attrs, uid and input/output name maps. Where the forward op kept its
+    linearization (ctx.linearized, see _linearizations) the grad op calls that
+    vjp_fn; everywhere else it replays the rule under jax.vjp here.
+    Cotangents for forward outputs come from env (<out>@GRAD); outputs
+    missing a grad var get zeros. Produced input grads are ACCUMULATED into
+    <in>@GRAD names, which is correct because backward.py emits grad ops in
+    reverse topological order.
+    """
+    fwd_type = op.attrs["fwd_type"]
+    if fwd_type in SPECIAL_GRADS:
+        SPECIAL_GRADS[fwd_type]["fn"](ctx, op, env)
+        return
+    fwd_inputs = op.attrs["fwd_inputs"]    # slot -> [names]
+    fwd_outputs = op.attrs["fwd_outputs"]  # slot -> [names]
+    out_order = _out_order(fwd_outputs)
+    # read, not popped: calc_gradient may differentiate one op twice
+    kept = ctx.linearized.get(op.attrs.get("fwd_uid"))
+    if kept is not None:
+        primals, vjp_fn = kept
+    else:
+        fwd_in_vals = {slot: [env.read(n) for n in names]
+                       for slot, names in fwd_inputs.items()}
+        f, primal = _differentiable(
+            ctx, registry.get(fwd_type), op_scope(op), fwd_type,
+            op.attrs["fwd_attrs"], op.attrs.get("fwd_uid", 0), fwd_in_vals,
+            out_order)
+        # Rematerialization: when the segment-level pass handles this grad
+        # op (top-level backward of a >=8-op forward), it hands the replay
+        # recomputed barrier-guarded primals — per-op jax.checkpoint must
+        # NOT stack on top: for boundary/checkpoint inputs the replay SHOULD
+        # CSE with the forward (the residual is live anyway; blocking that
+        # was measured at +15G HBM on ResNet-50@512). Everywhere the segment
+        # pass cannot reach (grad ops inside control-flow sub-blocks,
+        # programs below the segment gate) the per-op checkpoint is still
+        # the only remat lever, so it stays as the fallback.
+        if getattr(ctx.program, "_rematerialize", False) \
+                and not getattr(ctx, "_segment_handled", False):
+            f = jax.checkpoint(f)
+        primals, vjp_fn, _ = jax.vjp(f, primal, has_aux=True)
+    _count_grad_op("replayed" if kept is None else "kept", fwd_type)
 
     cotangents = []
     for (slot, i, n), p in zip(out_order, primals):

@@ -41,20 +41,29 @@ def squeeze_label(label):
 
 
 class OpDef(object):
-    def __init__(self, type, lower, infer=None, uses_rng=False):
+    def __init__(self, type, lower, infer=None, uses_rng=False,
+                 calls_pallas=False):
         self.type = type
         self.lower = lower
         self.infer = infer
         self.uses_rng = uses_rng
+        # the rule can reach a Pallas kernel (it imports ops/pallas_kernels).
+        # XLA does not merge two runs of one Mosaic custom call as it does
+        # two copies of an op it generated itself, so the lowering keeps
+        # such an op's linearization for its grad op and does not run the
+        # rule again there (core/lowering.py: _linearizations)
+        self.calls_pallas = calls_pallas
 
 
 _OPS = {}
 
 
-def register(type, lower=None, infer=None, uses_rng=False):
+def register(type, lower=None, infer=None, uses_rng=False,
+             calls_pallas=False):
     """Register an op. Usable as decorator: @register('relu')."""
     def deco(fn):
-        _OPS[type] = OpDef(type, fn, infer=infer, uses_rng=uses_rng)
+        _OPS[type] = OpDef(type, fn, infer=infer, uses_rng=uses_rng,
+                           calls_pallas=calls_pallas)
         return fn
     if lower is not None:
         return deco(lower)

@@ -223,7 +223,7 @@ def _batch_norm(ctx, ins, attrs):
             "SavedMean": [saved_mean], "SavedVariance": [saved_var]}
 
 
-@register("layer_norm")
+@register("layer_norm", calls_pallas=True)
 def _layer_norm(ctx, ins, attrs):
     x = single(ins, "X")
     scale = single(ins, "Scale")
@@ -346,7 +346,7 @@ def _flash_min_seq():
     return flash_min_seq()
 
 
-@register("softmax_with_cross_entropy")
+@register("softmax_with_cross_entropy", calls_pallas=True)
 def _softmax_xent(ctx, ins, attrs):
     logits = single(ins, "Logits")
     label = single(ins, "Label")
@@ -372,7 +372,7 @@ def _softmax_xent(ctx, ins, attrs):
             "Loss": [loss.astype(logits.dtype)]}
 
 
-@register("fused_attention")
+@register("fused_attention", calls_pallas=True)
 def _fused_attention(ctx, ins, attrs):
     """flash attention over [B, T, H, D] q/k/v (TPU-native addition; see
     ops/pallas_kernels.py). Differentiable via the kernel's custom_vjp.

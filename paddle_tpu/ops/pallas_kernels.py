@@ -259,10 +259,8 @@ def _flash_bwd(scale, causal, block_q, block_k, interpret, res, g):
     grid axis (double-buffered) is the follow-up if longer single-core
     sequences are ever benched; ring/Ulysses SP is the intended path for
     those lengths (parallel/ring_attention.py)."""
-    q, k, v, kv_len, out, lse = res
+    q, k, v, kv_len, delta, lse = res
     bh, t, d = q.shape
-    delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32),
-                    axis=-1)                               # [BH, T]
     blk = int(np.lcm(block_q, block_k))
     t_pad = int(-(-t // blk) * blk)
     if t_pad != t:
@@ -321,25 +319,65 @@ def _flash_bwd(scale, causal, block_q, block_k, interpret, res, g):
     return dq[:, :t], dk[:, :t], dv[:, :t]
 
 
+def _wait_for(g, *residuals):
+    """(g, *residuals) behind one optimization barrier, for a backward rule
+    whose linearization the forward op kept (core/lowering.py). What a
+    backward rule first does to its residuals depends on nothing the
+    backward pass computes: a layout change to the kernels' [B*H, T, D],
+    which pads D=64 to the 128 lanes of a tile (32 MiB for 16), or a
+    [N] -> [N, 1] reshape, which pads one lane to 128 (64 MiB for the
+    logsumexp of one flash call, 8 MiB for layer norm's mean). Left alone,
+    XLA merges each with its inverse in the forward rule or runs it as soon
+    as the residual exists, and holds the padded array from the forward to
+    the backward pass: +1.4 GiB (flash) and +0.5 GiB (layer norm) on the
+    T=2048 transformer step (AOT compile for a v5e, PR 25). Behind the
+    barrier the residuals are held as the forward rule saved them until the
+    cotangent `g` exists."""
+    return lax.optimization_barrier((g,) + residuals)
+
+
+def _to_bh(x):
+    """[B, T, H, D] as the op has it -> the kernels' [B*H, T, D]."""
+    b, t, h, d = x.shape
+    return jnp.transpose(x, (0, 2, 1, 3)).reshape(b * h, t, d)
+
+
+def _from_bh(x, b):
+    bh, t, d = x.shape
+    return jnp.transpose(x.reshape(b, bh // b, t, d), (0, 2, 1, 3))
+
+
+# q, k, v and the output are [B, T, H, D] on both sides of the custom_vjp
+# boundary, and the two layout changes are inside it: the residuals are the
+# op's own inputs and output (live anyway for the ops around it) plus the
+# logsumexp, and _wait_for keeps them in that form up to the backward pass.
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
 def _flash_core(q, k, v, kv_len, scale, causal, block_q, block_k,
                 interpret):
-    out, _ = _flash_fwd(q, k, v, kv_len, scale, causal, block_q, block_k,
-                        interpret)
-    return out
+    return _flash_core_fwd(q, k, v, kv_len, scale, causal, block_q, block_k,
+                           interpret)[0]
 
 
 def _flash_core_fwd(q, k, v, kv_len, scale, causal, block_q, block_k,
                     interpret):
-    out, lse = _flash_fwd(q, k, v, kv_len, scale, causal, block_q, block_k,
-                          interpret)
+    out, lse = _flash_fwd(_to_bh(q), _to_bh(k), _to_bh(v), kv_len, scale,
+                          causal, block_q, block_k, interpret)
+    out = _from_bh(out, q.shape[0])
     return out, (q, k, v, kv_len, out, lse)
 
 
 def _flash_core_bwd(scale, causal, block_q, block_k, interpret, res, g):
-    dq, dk, dv = _flash_bwd(scale, causal, block_q, block_k, interpret,
-                            res, g)
-    return dq, dk, dv, None
+    q, k, v, kv_len, out, lse = res
+    g, q, k, v, out, lse = _wait_for(g, q, k, v, out, lse)
+    b, t, h, _ = q.shape
+    # rowsum(dO * O) where the output lives, so that the backward asks for
+    # no second copy of it in the kernels' layout
+    delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32),
+                    axis=-1).transpose(0, 2, 1).reshape(b * h, t)
+    dq, dk, dv = _flash_bwd(
+        scale, causal, block_q, block_k, interpret,
+        (_to_bh(q), _to_bh(k), _to_bh(v), kv_len, delta, lse), _to_bh(g))
+    return _from_bh(dq, b), _from_bh(dk, b), _from_bh(dv, b), None
 
 
 _flash_core.defvjp(_flash_core_fwd, _flash_core_bwd)
@@ -368,14 +406,8 @@ def flash_attention(q, k, v, causal=False, scale=None, kv_len=None,
         lens = jnp.full((b * h,), t, jnp.int32)
     else:
         lens = jnp.repeat(jnp.asarray(kv_len, jnp.int32).reshape(b), h)
-
-    def to_bh(x):
-        return x.transpose(0, 2, 1, 3).reshape(b * h, t, d)
-
-    out = _flash_core(to_bh(q), to_bh(k), to_bh(v), lens, float(scale),
-                      bool(causal), int(block_q), int(block_k),
-                      bool(interpret))
-    return out.reshape(b, h, t, d).transpose(0, 2, 1, 3)
+    return _flash_core(q, k, v, lens, float(scale), bool(causal),
+                       int(block_q), int(block_k), bool(interpret))
 
 
 # ---------------------------------------------------------------------------
@@ -507,11 +539,14 @@ def _ln_core(x, scale, bias, eps, block_n, interpret):
 def _ln_core_fwd(x, scale, bias, eps, block_n, interpret):
     y, mean, rstd = _ln_fwd_call(x, scale, bias, eps, block_n, interpret)
     # residuals must be jax values: a 0-size sentinel carries bias's dtype
-    return y, (x, scale, jnp.zeros((0,), bias.dtype), mean, rstd)
+    return y, (x, scale, jnp.zeros((0,), bias.dtype), mean.reshape(-1),
+               rstd.reshape(-1))
 
 
 def _ln_core_bwd(eps, block_n, interpret, res, g):
     x, scale, bias_like, mean, rstd = res
+    g, mean, rstd = _wait_for(g, mean, rstd)
+    mean, rstd = mean.reshape(-1, 1), rstd.reshape(-1, 1)
     xf = x.astype(jnp.float32)
     gf = g.astype(jnp.float32)
     xhat = (xf - mean) * rstd                                # [N, D]

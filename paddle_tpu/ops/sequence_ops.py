@@ -33,7 +33,7 @@ def _feat_mask(x, xlen):
     return m.reshape(m.shape + (1,) * (x.ndim - 2))
 
 
-@register("sequence_pool")
+@register("sequence_pool", calls_pallas=True)
 def _sequence_pool(ctx, ins, attrs):
     x = single(ins, "X")          # [B, T, ...]
     xlen = single(ins, "XLen")    # [B]
@@ -90,7 +90,7 @@ def _sequence_first_step(ctx, ins, attrs):
     return _sequence_pool(ctx, ins, dict(attrs, pooltype="FIRST"))
 
 
-@register("sequence_softmax")
+@register("sequence_softmax", calls_pallas=True)
 def _sequence_softmax(ctx, ins, attrs):
     x = single(ins, "X")        # [B, T] or [B, T, 1]
     xlen = single(ins, "XLen")
@@ -316,7 +316,7 @@ def _amp_recurrence(ctx, x_dtype):
     return state_dt, rmat
 
 
-@register("lstm")
+@register("lstm", calls_pallas=True)
 def _lstm(ctx, ins, attrs):
     """dynamic_lstm: input [B, T, 4D] (pre-projected by an fc), weight
     [D, 4D] recurrent, bias [1, 4D] (+[1, 3D] peepholes if use_peepholes).
@@ -409,7 +409,7 @@ def _lstm(ctx, ins, attrs):
             "BatchGate": [x], "BatchCellPreAct": [cell]}
 
 
-@register("lstmp")
+@register("lstmp", calls_pallas=True)
 def _lstmp(ctx, ins, attrs):
     """lstmp_op.cc — LSTM with recurrent projection: the [B, P] PROJECTED
     state (not the [B, D] hidden) feeds the next step's gate matmul

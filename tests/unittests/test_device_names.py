@@ -110,10 +110,27 @@ def test_every_fluid_op_of_the_block_lowers_under_its_scope(
      ("transpose", "tmp_3")),
     ("jit(fn)/op:mul_grad/a~GRAD/op:reshape/tmp_1/reshape",
      ("reshape", "tmp_1")),
+    # a grad op on the linearization its forward op kept: what it transposes
+    # was traced under the forward op's scope, and is the grad op's work
+    ("jit(fn)/op:layer_norm_grad/x~GRAD/transpose(jvp(op:layer_norm/"
+     "ln_0.tmp_2))/mul", ("layer_norm_grad", "x@GRAD")),
+    ("jit(fn)/op:fused_attention_grad/q~GRAD/transpose(op:fused_attention/"
+     "attn_0.tmp_0)/jvp(op:fused_attention/attn_0.tmp_0)/ptpu_flash_bwd_dkdv/"
+     "pallas_call", ("fused_attention_grad", "q@GRAD")),
+    ("jit(fn)/while/body/op:softmax_with_cross_entropy_grad/fc~GRAD/"
+     "transpose(jvp(op:softmax_with_cross_entropy/loss))/sub",
+     ("softmax_with_cross_entropy_grad", "fc@GRAD")),
+    # ... and what is not: the forward op itself, a forward op a remat
+    # segment lowers again inside its own grad op, another op's scope
+    ("jit(fn)/op:layer_norm/ln_0.tmp_2/jvp(op:layer_norm/ln_0.tmp_2)/"
+     "ptpu_layer_norm_fwd/pallas_call", ("layer_norm", "ln_0.tmp_2")),
+    ("jit(fn)/op:relu_grad/a~GRAD/op:relu/a/max", ("relu", "a")),
+    ("jit(fn)/op:rnn_scan_grad/h~GRAD/transpose(jvp(op:rnn_scan_grad/"
+     "h~GRAD))/while/body/op:mul/tmp_1/dot_general", ("mul", "tmp_1")),
     ("jit(fn)/transpose(jvp(mul.4))/dot_general", None),
     ("jit(fn)/transpose/scale/sum/top:k", None),
     ("", None)])
-def test_parse_op_scope_finds_the_innermost_fluid_scope(path, want):
+def test_parse_op_scope_finds_the_fluid_op_of_a_path(path, want):
     assert lowering.parse_op_scope(path) == want
 
 
@@ -170,14 +187,8 @@ def test_every_pallas_call_takes_its_name_from_kernel_names():
             assert a == b or not b.startswith(a) or b[len(a)] == "_"
 
 
-def test_no_transform_wraps_a_kernels_name_scope(monkeypatch):
-    """pallas_call opens a scope named after the kernel, and XLA names the
-    Mosaic call's instruction by the path's last scope as jax renders it:
-    `jvp(ptpu_layer_norm_fwd)` would become `jvp_ptpu_layer_norm_fwd_`. The
-    grad op's scope inside the differentiated function keeps every kernel's
-    scope bare, forward (run again by the grad op) and backward."""
-    monkeypatch.setenv("PADDLE_TPU_PALLAS", "1")    # interpreted, off a TPU
-    monkeypatch.setenv("FLAGS_flash_min_seq", "0")
+def _kernel_program():
+    """flash attention, layer_norm and softmax_xent in one training step."""
     main, startup = fluid.Program(), fluid.Program()
     with fluid.unique_name.guard(), fluid.program_guard(main, startup):
         x = fluid.layers.data(name="x", shape=[16, 2, 8], dtype="float32")
@@ -190,6 +201,12 @@ def test_no_transform_wraps_a_kernels_name_scope(monkeypatch):
         loss = fluid.layers.mean(fluid.layers.softmax_with_cross_entropy(
             fluid.layers.fc(input=h, size=32), lab))
         fluid.optimizer.SGD(learning_rate=0.1).minimize(loss)
+    return main, startup, loss
+
+
+def _kernel_program_args(main, startup, loss, shard=None):
+    """(fn, args) of the step; `shard` turns the arguments into shapes on a
+    described device."""
     feeds = ["x", "lab"]
     rw, ro, out = lowering.analyze_state(main, feeds, [loss.name])
     scope = fluid.Scope()
@@ -198,9 +215,27 @@ def test_no_transform_wraps_a_kernels_name_scope(monkeypatch):
         vals = {n: np.asarray(scope.find_var(n).get_tensor())
                 for n in set(rw) | set(ro)}
     fn = lowering.build_program_fn(main, feeds, [loss.name], rw, ro, out)
-    text = jax.jit(lambda f, a, b: fn(f, a, b, 0)).lower(
-        [np.zeros((4, 16, 2, 8), "float32"), np.zeros((4, 1), "int32")],
-        [vals[n] for n in rw], [vals[n] for n in ro]).as_text(debug_info=True)
+    args = ([np.zeros((4, 16, 2, 8), "float32"), np.zeros((4, 1), "int32")],
+            [vals[n] for n in rw], [vals[n] for n in ro])
+    if shard is not None:
+        args = jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=shard),
+            args)
+    return (lambda f, a, b: fn(f, a, b, 0)), args
+
+
+def test_no_transform_wraps_a_kernels_name_scope(monkeypatch):
+    """pallas_call opens a scope named after the kernel, and XLA names the
+    Mosaic call's instruction by the path's last scope as jax renders it:
+    `jvp(ptpu_layer_norm_fwd)` would become `jvp_ptpu_layer_norm_fwd_`. The
+    lowering op's scope inside the differentiated function keeps every
+    kernel's scope bare. A forward kernel runs once, under its forward op
+    (which keeps the linearization); the backward kernels under the grad
+    op that calls it."""
+    monkeypatch.setenv("PADDLE_TPU_PALLAS", "1")    # interpreted, off a TPU
+    monkeypatch.setenv("FLAGS_flash_min_seq", "0")
+    fn, args = _kernel_program_args(*_kernel_program())
+    text = jax.jit(fn).lower(*args).as_text(debug_info=True)
     under = {}      # kernel -> the fluid op types it lowered under
     for path in set(re.findall(r'loc\("([^"]*)"', text)):
         for part in path.split("/"):
@@ -209,12 +244,11 @@ def test_no_transform_wraps_a_kernels_name_scope(monkeypatch):
                 under.setdefault(part, set()).add(
                     lowering.parse_op_scope(path)[0])
     assert under == {
-        "ptpu_flash_fwd": {"fused_attention", "fused_attention_grad"},
+        "ptpu_flash_fwd": {"fused_attention"},
         "ptpu_flash_bwd_dkdv": {"fused_attention_grad"},
         "ptpu_flash_bwd_dq": {"fused_attention_grad"},
-        "ptpu_layer_norm_fwd": {"layer_norm", "layer_norm_grad"},
-        "ptpu_softmax_xent_fwd": {"softmax_with_cross_entropy",
-                                  "softmax_with_cross_entropy_grad"}}
+        "ptpu_layer_norm_fwd": {"layer_norm"},
+        "ptpu_softmax_xent_fwd": {"softmax_with_cross_entropy"}}
 
 
 # --- the profiler's table of a device trace --------------------------------
@@ -452,69 +486,45 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
-def test_mosaic_calls_are_named_on_a_described_v5e(one_chip):
-    """layer_norm, softmax_xent and flash attention, forward and backward as
-    lower_op and _lower_grad_of scope them, compiled for a TPU: every
-    tpu_custom_call is named from KERNEL_NAMES, none by the name stack
-    (`fn`, `jvp__`, `transpose_jvp___`, `jvp_ptpu_layer_norm_fwd_`)."""
-    def grad_op(scope, rule, *ins):
-        with jax.named_scope(scope):            # lower_op
-            def f(*a):
-                with jax.named_scope(scope):    # _lower_grad_of, inside vjp
-                    return rule(*a)
-            out, vjp = jax.vjp(f, *ins)
-            return vjp(jnp.ones_like(out))
-
-    def loss(q, k, v, x, scale, bias, logits, labels):
-        def attn(q, k, v):
-            return pallas_kernels.flash_attention(q, k, v, causal=True,
-                                                  interpret=False)
-
-        def norm(x, scale, bias):
-            return pallas_kernels.layer_norm(x, scale, bias,
-                                             interpret=False)[0]
-
-        def xent(logits):
-            return pallas_kernels.softmax_xent(logits, labels,
-                                               interpret=False)
-        with jax.named_scope("op:fused_attention/attn_0.tmp_0"):
-            a = attn(q, k, v)
-        with jax.named_scope("op:layer_norm/ln_0.tmp_2"):
-            y = norm(x, scale, bias)
-        with jax.named_scope("op:softmax_with_cross_entropy/xent_0.tmp_0"):
-            nll = xent(logits)
-        return (a, y, nll,
-                grad_op("op:fused_attention_grad/q~GRAD", attn, q, k, v),
-                grad_op("op:layer_norm_grad/x~GRAD", norm, x, scale, bias),
-                grad_op("op:softmax_with_cross_entropy_grad/fc~GRAD", xent,
-                        logits))
-
-    def arg(shape, dtype):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
-    args = (arg((2, 256, 2, 64), jnp.bfloat16),) * 3 + (
-        arg((64, 512), jnp.float32), arg((512,), jnp.float32),
-        arg((512,), jnp.float32), arg((64, 1024), jnp.float32),
-        arg((64,), jnp.int32))
+def test_mosaic_calls_are_named_on_a_described_v5e(one_chip, monkeypatch):
+    """A training step with layer_norm, softmax_xent and flash attention,
+    lowered by build_program_fn and compiled for a TPU: five Mosaic calls
+    (each forward kernel once, dK/dV, dQ), every one named from
+    KERNEL_NAMES and none by the name stack (`fn`, `jvp__`,
+    `transpose_jvp___`, `jvp_ptpu_layer_norm_fwd_`); the forward kernels
+    under their forward op, the backward kernels under the grad op."""
+    from paddle_tpu.ops import kernel_config
+    monkeypatch.setenv("FLAGS_flash_min_seq", "0")
+    # the rules ask where the step will run; here it is only described
+    monkeypatch.setattr(kernel_config, "dispatch_platform", lambda: "tpu")
+    monkeypatch.setattr(pallas_kernels, "dispatch_platform", lambda: "tpu")
+    fn, args = _kernel_program_args(*_kernel_program(), shard=one_chip)
     from jax.experimental.compilation_cache import compilation_cache
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
     try:
-        text = jax.jit(loss).lower(*args).compile().as_text()
+        text = jax.jit(fn).lower(*args).compile().as_text()
     finally:
         jax.config.update("jax_enable_compilation_cache", was)
         compilation_cache.reset_cache()
     calls = re.findall(
-        r'%?([\w.\-]+) = [^\n]*custom_call_target="tpu_custom_call"', text)
-    kernels = {c.rpartition(".")[0] if c.rpartition(".")[2].isdigit() else c
-               for c in calls}
-    assert kernels == {"ptpu_flash_fwd", "ptpu_flash_bwd_dkdv",
-                       "ptpu_flash_bwd_dq", "ptpu_layer_norm_fwd",
-                       "ptpu_softmax_xent_fwd"}
-    assert kernels <= set(pallas_kernels.KERNEL_NAMES)
-    assert len(calls) >= 8      # each forward kernel under both its ops
+        r'%?([\w.\-]+) = [^\n]*custom_call_target="tpu_custom_call"'
+        r'[^\n]*op_name="([^"]*)"', text)
+    assert len(calls) == len(re.findall(
+        r'custom_call_target="tpu_custom_call"', text)) == 5
+    under = {(name.rpartition(".")[0] if name.rpartition(".")[2].isdigit()
+              else name): lowering.parse_op_scope(op_name)[0]
+             for name, op_name in calls}
+    assert under == {
+        "ptpu_flash_fwd": "fused_attention",
+        "ptpu_flash_bwd_dkdv": "fused_attention_grad",
+        "ptpu_flash_bwd_dq": "fused_attention_grad",
+        "ptpu_layer_norm_fwd": "layer_norm",
+        "ptpu_softmax_xent_fwd": "softmax_with_cross_entropy"}
     assert not re.search(r"%(fn|jvp_|transpose_jvp_)[\w.]* = ", text)
-    scoped = {lowering.parse_op_scope(m)
-              for m in re.findall(r'op_name="([^"]*)"', text)}
-    assert {("layer_norm", "ln_0.tmp_2"), ("layer_norm_grad", "x@GRAD"),
-            ("fused_attention_grad", "q@GRAD")} <= scoped
+    scoped = {lowering.parse_op_scope(m)[0]
+              for m in re.findall(r'op_name="([^"]*)"', text)
+              if lowering.parse_op_scope(m)}
+    assert {"layer_norm", "layer_norm_grad", "fused_attention_grad",
+            "softmax_with_cross_entropy_grad", "mul_grad"} <= scoped
