@@ -105,6 +105,8 @@ def register_special(type):
 _AMP_BF16_OPS = frozenset({
     "conv2d", "depthwise_conv2d", "conv2d_transpose", "mul", "matmul",
     "fused_attention"})
+# `moe_ffn` is in neither table: one input feeds its float32 router and its
+# bf16 experts, so its rule casts for itself (ops/parallel_ops.py).
 # Numerically sensitive ops: force their float inputs back up to f32 so the
 # loss/probability path never rounds through bf16.
 _AMP_F32_OPS = frozenset({
@@ -587,6 +589,8 @@ def _lower_op_inner(ctx, op, env):
     od = registry.get(op.type)
     ins = {slot: [env.read(n) for n in names]
            for slot, names in op.inputs.items()}
+    if op.type == "moe_ffn":
+        _count_moe_layer(op.attrs, ins["Router"][0].shape[1])
     if op.uid in ctx.linearized:
         # a grad op of this block differentiates this op: run the rule once,
         # under jax.vjp, and keep what the backward needs
@@ -709,6 +713,17 @@ def _count_grad_op(path, fwd_type):
         "grad ops lowered, by forward op type and by whether the op used the "
         "linearization its forward op kept or replayed the forward rule"
     ).inc(path=path, op=fwd_type)
+
+
+def _count_moe_layer(attrs, experts):
+    from ..observability.registry import REGISTRY
+    from ..parallel.moe import GROUPED_MATMUL
+    REGISTRY.counter(
+        "ptpu_moe_layers_total",
+        "moe_ffn ops lowered (forward ops, not a grad op's replay), by "
+        "experts a token, stored experts and the grouped-matmul route"
+    ).inc(top_k=str(attrs["top_k"]), experts=str(experts),
+          path=GROUPED_MATMUL)
 
 
 def _lower_grad_of(ctx, op, env):

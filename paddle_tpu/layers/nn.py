@@ -23,7 +23,7 @@ __all__ = [
     "multiplex", "label_smooth", "nce", "lrn", "maxout", "relu", "log",
     "expand", "sequence_mask", "linear_chain_crf", "crf_decoding",
     "chunk_eval", "warpctc", "ctc_greedy_decoder", "sequence_erase",
-    "edit_distance", "fused_attention",
+    "edit_distance", "fused_attention", "rms_norm", "rotary_embedding",
 ]
 
 
@@ -264,6 +264,38 @@ def layer_norm(input, scale=True, shift=True, begin_norm_axis=1,
         outputs={"Y": [out], "Mean": [mean_out], "Variance": [var_out]},
         attrs={"epsilon": epsilon, "begin_norm_axis": begin_norm_axis})
     return helper.append_activation(out)
+
+
+def rms_norm(input, begin_norm_axis=-1, epsilon=1e-5, param_attr=None,
+             name=None):
+    """Root-mean-square norm over the axes from begin_norm_axis on, with a
+    learned scale (initialised to 1) and no shift: scale * x /
+    sqrt(mean(x^2) + epsilon), accumulated in float32 (ops/nn_ops.py)."""
+    helper = LayerHelper("rms_norm", **locals())
+    dtype = helper.input_dtype()
+    begin = begin_norm_axis % len(input.shape)
+    scale = helper.create_parameter(
+        attr=helper.param_attr,
+        shape=[int(np.prod(input.shape[begin:]))], dtype=dtype,
+        default_initializer=ConstantInitializer(1.0))
+    out = helper.create_variable_for_type_inference(dtype)
+    helper.append_op(
+        type="rms_norm", inputs={"X": [input], "Scale": [scale]},
+        outputs={"Y": [out]},
+        attrs={"epsilon": float(epsilon), "begin_norm_axis": begin})
+    return out
+
+
+def rotary_embedding(x, pos, base=10000.0, name=None):
+    """Rotary position embedding of x [B, T, H, D] at the integer positions
+    pos [B, T], a Variable (fed or computed), so that a decode step can pass
+    its own. Half-split pairs (i, i + D/2), angle pos * base^(-2i/D)."""
+    helper = LayerHelper("rotary_embedding", **locals())
+    out = helper.create_variable_for_type_inference(x.dtype)
+    helper.append_op(
+        type="rotary_embedding", inputs={"X": [x], "Pos": [pos]},
+        outputs={"Out": [out]}, attrs={"base": float(base)})
+    return out
 
 
 def dropout(x, dropout_prob, is_test=False, seed=None, name=None):

@@ -1,5 +1,6 @@
-"""Fluid layers for the parallel subsystems: pipelined_stack (PP) and
-switch_moe (EP).
+"""Fluid layers for the parallel subsystems: pipelined_stack (PP),
+switch_moe (EP, top-1 with capacity) and moe_ffn (dropless top-k routed
+experts, one chip).
 
 These are the Program-path entries to parallel/pipeline.py and
 parallel/moe.py: build the model with them like any other layer, train it
@@ -15,7 +16,7 @@ from ..core.layer_helper import LayerHelper
 from ..core.param_attr import ParamAttr
 from ..core import unique_name
 
-__all__ = ["pipelined_stack", "switch_moe"]
+__all__ = ["pipelined_stack", "switch_moe", "moe_ffn"]
 
 
 def _freeze(v):
@@ -216,6 +217,16 @@ def pipelined_stack(input, num_stages, build_stage, num_microbatches=None,
     return out
 
 
+def _suffixed(base, suffix):
+    """`base`'s settings for one of a layer's several parameters, named
+    <base name>.<suffix> where the base has a name."""
+    return ParamAttr(
+        name=(base.name + "." + suffix) if base.name else None,
+        initializer=base.initializer, learning_rate=base.learning_rate,
+        regularizer=base.regularizer, trainable=base.trainable,
+        gradient_clip=base.gradient_clip)
+
+
 def switch_moe(input, num_experts, d_hidden, capacity_factor=1.25,
                param_attr=None, name=None):
     """Top-1 switch mixture-of-experts FFN (lowering: ops/parallel_ops.py
@@ -239,13 +250,8 @@ def switch_moe(input, num_experts, d_hidden, capacity_factor=1.25,
         raise ValueError("switch_moe requires parameters")
 
     def attr(suffix, shape, is_bias=False):
-        a = ParamAttr(
-            name=(base.name + "." + suffix) if base.name else None,
-            initializer=base.initializer,
-            learning_rate=base.learning_rate,
-            regularizer=base.regularizer, trainable=base.trainable,
-            gradient_clip=base.gradient_clip)
-        return helper.create_parameter(attr=a, shape=shape, dtype=dtype,
+        return helper.create_parameter(attr=_suffixed(base, suffix),
+                                       shape=shape, dtype=dtype,
                                        is_bias=is_bias)
 
     gate = attr("gate", [d, e])
@@ -262,3 +268,49 @@ def switch_moe(input, num_experts, d_hidden, capacity_factor=1.25,
         outputs={"Out": [out], "AuxLoss": [aux]},
         attrs={"capacity_factor": float(capacity_factor)})
     return out, aux
+
+
+def moe_ffn(input, num_experts, d_expert, top_k, norm_topk_prob=False,
+            param_attr=None, name=None):
+    """Dropless top-k routed experts, each a gated-SiLU FFN without bias
+    (lowering: ops/parallel_ops.py -> parallel/moe.py routed_ffn). input
+    [..., D] -> (out [..., D], balance_loss [1], z_loss [1], expert_load
+    [num_experts] int32).
+
+    A token's softmax router probabilities pick its top_k experts and weigh
+    their outputs (renormalised to sum 1 only with norm_topk_prob). Every
+    one of the top_k * N assignments is computed, whatever the imbalance:
+    there is no capacity. balance_loss is num_experts * sum_e (c_e / N) *
+    mean_n p[n, e] and z_loss mean_n logsumexp(router logits)^2: add small
+    multiples of both to the training loss. expert_load is c_e, the
+    assignments an expert received; it sums to top_k * N.
+    """
+    helper = LayerHelper("moe_ffn", name=name)
+    dtype = input.dtype
+    d, e, f = int(input.shape[-1]), int(num_experts), int(d_expert)
+    if not 1 <= int(top_k) <= e:
+        raise ValueError("moe_ffn top_k must be in [1, num_experts=%d], got "
+                         "%r" % (e, top_k))
+    base = ParamAttr.to_attr(param_attr)
+    if base is False:
+        raise ValueError("moe_ffn requires parameters")
+
+    def param(suffix, shape):
+        return helper.create_parameter(attr=_suffixed(base, suffix),
+                                       shape=shape, dtype=dtype)
+
+    inputs = {"X": [input], "Router": [param("router", [d, e])],
+              "WGate": [param("w_gate", [e, d, f])],
+              "WUp": [param("w_up", [e, d, f])],
+              "WDown": [param("w_down", [e, f, d])]}
+    out = helper.create_variable_for_type_inference(dtype)
+    balance = helper.create_variable_for_type_inference("float32")
+    z = helper.create_variable_for_type_inference("float32")
+    load = helper.create_variable_for_type_inference("int32",
+                                                     stop_gradient=True)
+    helper.append_op(
+        type="moe_ffn", inputs=inputs,
+        outputs={"Out": [out], "BalanceLoss": [balance], "ZLoss": [z],
+                 "ExpertLoad": [load]},
+        attrs={"top_k": int(top_k), "norm_topk_prob": bool(norm_topk_prob)})
+    return out, balance, z, load

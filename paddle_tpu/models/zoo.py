@@ -61,8 +61,15 @@ def _builders():
         from . import language_model as m
         m.build(vocab_size=120, emb_size=8, hidden_size=8, num_layers=2)
 
-    return {"mnist": mnist, "sentiment": sentiment, "seq2seq": seq2seq,
-            "transformer": transformer, "srl": srl, "ctr": ctr,
+    def causal_lm():
+        from . import causal_lm as m
+        m.build_train(dict(vocab_size=40, hidden_size=16,
+                           num_hidden_layers=1, num_attention_heads=2,
+                           intermediate_size=8, num_experts=4,
+                           num_experts_per_tok=2, qk_norm=True), seq_len=8)
+
+    return {"causal_lm": causal_lm, "mnist": mnist, "sentiment": sentiment,
+            "seq2seq": seq2seq, "transformer": transformer, "srl": srl, "ctr": ctr,
             "word2vec": word2vec, "recommender": recommender,
             "language_model": language_model}
 

@@ -253,6 +253,46 @@ def _layer_norm(ctx, ins, attrs):
             "Mean": [mean.reshape(lead)], "Variance": [var.reshape(lead)]}
 
 
+@register("rms_norm")
+def _rms_norm(ctx, ins, attrs):
+    """y = scale * x / sqrt(mean(x^2) + eps) over the axes from
+    begin_norm_axis on; the statistics and the product accumulate in
+    float32 whatever x's dtype is, and y comes back in it. Plain jax.numpy:
+    no Pallas kernel until a benchmark cell shows one winning."""
+    x = single(ins, "X")
+    scale = single(ins, "Scale")
+    begin = attrs.get("begin_norm_axis", 1) % x.ndim
+    x32 = x.astype(jnp.float32)
+    ms = jnp.mean(jnp.square(x32), axis=tuple(range(begin, x.ndim)),
+                  keepdims=True)
+    y = x32 * lax.rsqrt(ms + attrs.get("epsilon", 1e-5)) \
+        * scale.astype(jnp.float32).reshape(x.shape[begin:])
+    return {"Y": [y.astype(x.dtype)]}
+
+
+@register("rotary_embedding")
+def _rotary_embedding(ctx, ins, attrs):
+    """Rotary position embedding of x [B, T, H, D] at the positions Pos
+    [B, T] (an input, not a constant: a decode step feeds its own). The
+    half-split convention: the pair (i, i + D/2) of every head turns by
+    pos * base^(-2i/D). Angles, cos and sin and the rotation are float32;
+    the result comes back in x's dtype."""
+    x = single(ins, "X")
+    pos = single(ins, "Pos")
+    d = x.shape[-1]
+    if d % 2:
+        raise ValueError("rotary_embedding needs an even head width, got %d"
+                         % d)
+    inv_freq = attrs.get("base", 10000.0) ** (
+        -jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    angle = pos.reshape(x.shape[:2]).astype(jnp.float32)[:, :, None, None] \
+        * inv_freq
+    cos, sin = jnp.cos(angle), jnp.sin(angle)
+    x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
+    out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
+    return _out(out.astype(x.dtype))
+
+
 @register("lrn")
 def _lrn(ctx, ins, attrs):
     x = single(ins, "X")  # NCHW

@@ -1,6 +1,7 @@
 """Program-level lowerings of the parallel subsystems: the `pipeline` op
-(GPipe looped pipeline, parallel/pipeline.py) and the `moe` op (top-1
-switch expert parallelism, parallel/moe.py).
+(GPipe looped pipeline, parallel/pipeline.py), the `moe` op (top-1
+switch expert parallelism, parallel/moe.py) and the `moe_ffn` op (dropless
+top-k routed experts, parallel/moe.py routed_ffn).
 
 These make PP and EP reachable from the fluid Program path
 (layers.pipelined_stack / layers.switch_moe build the ops; Executor runs
@@ -135,3 +136,23 @@ def _moe_infer(block, op, out_vars):
 
 
 registry.register("moe", _moe_lower, infer=_moe_infer)
+
+
+def _moe_ffn_lower(ctx, ins, attrs):
+    """Dropless top-k routed gated-SiLU experts (parallel/moe.py
+    routed_ffn). Under AMP the router stays float32 and the experts compute
+    in bfloat16 from the float32 master weights; the op decides that here
+    because one input, X, feeds both."""
+    from ..parallel.moe import routed_ffn
+    x = single(ins, "X")
+    out, balance, z, load = routed_ffn(
+        x.reshape(-1, x.shape[-1]), single(ins, "Router"),
+        single(ins, "WGate"), single(ins, "WUp"), single(ins, "WDown"),
+        top_k=int(attrs["top_k"]),
+        norm_topk_prob=bool(attrs.get("norm_topk_prob", False)),
+        expert_dtype=jnp.bfloat16 if getattr(ctx, "amp", False) else None)
+    return {"Out": [out.reshape(x.shape)], "BalanceLoss": [balance],
+            "ZLoss": [z], "ExpertLoad": [load]}
+
+
+registry.register("moe_ffn", _moe_ffn_lower)

@@ -11,7 +11,14 @@ exactly as a hand-written collective would, but fused and overlapped.
 
 Everything is a pure jax function over an explicit params pytree —
 differentiable, jit/pjit-friendly, composable with dp on the same mesh.
+
+`routed_ffn` (the `moe_ffn` op) is the other design, for one chip today:
+top-k routing without capacity, the assignments sorted by expert and the
+expert matmuls grouped over them, so no token is dropped and no operation
+is spent on an expert a token did not choose.
 """
+import functools
+
 import numpy as np
 
 import jax
@@ -20,7 +27,7 @@ import jax.numpy as jnp
 from .mesh import P, NamedSharding
 
 __all__ = ["init_moe_params", "moe_layer", "moe_param_specs",
-           "dense_reference"]
+           "dense_reference", "routed_ffn"]
 
 
 def init_moe_params(rng, d_model, d_hidden, num_experts, dtype="float32"):
@@ -111,3 +118,99 @@ def moe_layer(params, x, capacity_factor=1.25, mesh=None, axis="ep"):
     frac_probs = jnp.mean(probs, axis=0)                     # [E]
     aux = jnp.sum(frac_tokens * frac_probs) * e
     return y.astype(x.dtype), aux
+
+
+# --- dropless top-k routed experts (the `moe_ffn` op) -----------------------
+# The grouped-matmul route every expert matmul of `routed_ffn` takes, as the
+# counter ptpu_moe_layers_total{path} names it.
+GROUPED_MATMUL = "ragged_dot"
+
+
+def _grouped_matmul(lhs, rhs, group_sizes):
+    """lhs [M, K] rows sorted by group, rhs [G, K, N], group_sizes [G] with
+    sum M: row m times its own group's matrix, [M, N] in lhs's dtype. Costs
+    M x K x N multiply-adds whatever G is."""
+    return jax.lax.ragged_dot(lhs, rhs, group_sizes,
+                              preferred_element_type=lhs.dtype)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _take_rows(x, index, inverse, repeat):
+    """repeat(x, repeat, axis=0)[index] for a permutation `index` of those
+    rows whose inverse is `inverse`, as one gather. The gradient of a gather
+    is a scatter-add; of a permutation it is the gather by the inverse, then
+    the sum over a row's `repeat` copies, which is what the backward rule
+    does."""
+    return x[index // repeat]
+
+
+def _take_rows_fwd(x, index, inverse, repeat):
+    return x[index // repeat], inverse
+
+
+def _take_rows_bwd(repeat, inverse, g):
+    g = g[inverse]
+    if repeat > 1:
+        g = g.reshape(-1, repeat, g.shape[-1]).sum(1, dtype=jnp.float32) \
+            .astype(g.dtype)
+    return g, None, None
+
+
+_take_rows.defvjp(_take_rows_fwd, _take_rows_bwd)
+
+
+def _gated_silu(gate, up):
+    """silu(gate) * up in float32, back in the inputs' dtype."""
+    return (jax.nn.silu(gate.astype(jnp.float32))
+            * up.astype(jnp.float32)).astype(gate.dtype)
+
+
+def routed_ffn(x, router, w_gate, w_up, w_down, top_k, norm_topk_prob=False,
+               expert_dtype=None):
+    """Dropless top-k routed gated-SiLU experts over tokens x [N, D].
+
+    router [D, E]; w_gate, w_up [E, D, F]; w_down [E, F, D]; no bias. Every
+    one of the top_k * N assignments is computed, whatever the imbalance:
+    there is no capacity. The assignments are sorted by expert and the three
+    expert matmuls run grouped over them (`_grouped_matmul`), so they cost
+    the top_k active experts' operations and not the E stored ones.
+
+    The router's matmul, softmax and top-k are float32 at full precision
+    whatever x's dtype; the experts compute in `expert_dtype` (x's own if
+    None; bfloat16 under AMP) and the top_k weighted outputs of a token are
+    summed in float32.
+
+    Returns (out [N, D] in the experts' dtype,
+             load-balance term [1]: E * sum_e (c_e / N) * mean_n p[n, e],
+             router z term [1]: mean_n logsumexp(logits[n])^2,
+             c [E] int32: assignments an expert, sum = top_k * N).
+    """
+    n, d = x.shape
+    e = router.shape[1]
+    dtype = jnp.dtype(expert_dtype or x.dtype)
+    logits = jnp.dot(x.astype(jnp.float32), router.astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    lse = jax.nn.logsumexp(logits, axis=-1)
+    probs = jnp.exp(logits - lse[:, None])
+    gate, expert = jax.lax.top_k(probs, top_k)             # [N, top_k]
+    if norm_topk_prob:
+        gate = gate / gate.sum(-1, keepdims=True)
+
+    # assignment a = n * top_k + j; `order` lists the assignments by expert
+    # (stable, so by token inside an expert), `rank` is where each went
+    expert = expert.reshape(-1)
+    order = jnp.argsort(expert, stable=True)
+    rank = jnp.zeros_like(order).at[order].set(
+        jnp.arange(order.shape[0], dtype=order.dtype))
+    load = jnp.sum(expert[:, None] == jnp.arange(e), axis=0, dtype=jnp.int32)
+
+    rows = _take_rows(x.astype(dtype), order, rank, top_k)
+    hidden = _gated_silu(_grouped_matmul(rows, w_gate.astype(dtype), load),
+                         _grouped_matmul(rows, w_up.astype(dtype), load))
+    y = _grouped_matmul(hidden, w_down.astype(dtype), load)
+    y = _take_rows(y, rank, order, 1).reshape(n, top_k, d).astype(jnp.float32)
+    out = jnp.sum(y * gate[:, :, None], axis=1).astype(dtype)
+
+    balance = e * jnp.sum(load.astype(jnp.float32) / n * probs.mean(0))
+    z = jnp.mean(jnp.square(lse))
+    return out, balance.reshape(1), z.reshape(1), load
