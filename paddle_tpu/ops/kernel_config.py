@@ -60,9 +60,16 @@ __all__ = [
 # knob names the TuningStore accepts (store.KNOWN_KNOBS); values are
 # what every dispatch uses when no tuned entry exists for its
 # (op, shape-bucket, device_kind).  block_b=0 means "the whole batch in
-# one block" (the fused LSTM kernel's pre-knob behavior).
+# one block" (the fused LSTM kernel's pre-knob behavior).  "attn" is what a
+# sweep of block_q in {128, 256, 512, 1024} x block_k in {128, 256, 512,
+# 1024} on the v5e chose for bf16 inputs at the shapes the benchmark's
+# cells run ([64, 2048, 64] unmasked and causal, [64, 4096, 128] causal;
+# PERF.md section 6, PR 27): at 128 x 128 the forward kernel takes 2.9-4.1
+# times as long, dK/dV 2.2-2.6 and dQ 2.5-3.2 times, and the best pair is
+# the same at D=64 and D=128, so the default stays one table entry and no
+# function of the shape.
 DEFAULT_TILES = {
-    "attn": {"block_q": 128, "block_k": 128},
+    "attn": {"block_q": 512, "block_k": 512},
     "xent": {"block_n": 8},
     "ln": {"block_n": 8},
     "lstm": {"block_b": 0},

@@ -7,7 +7,13 @@ while k/v blocks stream past, so peak memory is O(T·D) instead of O(T²) and
 the two matmuls per block ride the MXU back to back. Backward is the
 standard flash recompute from the saved logsumexp, also as pallas kernels
 (a dK/dV kernel over k-blocks + a dQ kernel over q-blocks, both with
-causal block skipping), differentiable via custom_vjp.
+causal block skipping), differentiable via custom_vjp. Every dot in the
+three kernels takes its operands in the dtype of q, k, v and accumulates in
+float32: bf16 inputs (every AMP program) reach the MXU as bf16, float32
+inputs run float32 dots (float32 tolerance in the interpreter; on the v5e
+Mosaic runs a float32 dot at default precision as one bf16 pass as well:
+same time, same error, my chip run, PR 27). The softmax between the dots
+is float32 either way.
 
 softmax_xent — fused log-softmax + label pick over the vocab dim: one VMEM
 pass computes the loss and the logsumexp residual; the probability matrix is
@@ -32,7 +38,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .kernel_config import dispatch_platform
+from .kernel_config import DEFAULT_TILES, dispatch_platform
 
 __all__ = ["flash_attention", "softmax_xent", "layer_norm",
            "fused_lstm", "fused_lstmp", "masked_softmax", "masked_pool"]
@@ -68,32 +74,47 @@ _SMEM_WHOLE = pl.BlockSpec(memory_space=pltpu.SMEM)
 # flash attention
 # ---------------------------------------------------------------------------
 
+# The three kernels below share one rule for precision: a dot takes its
+# operands in the dtype the kernel was given and accumulates in float32.
+# q, k, v and dO tiles go to the MXU as loaded; s, the running max and sum,
+# exp, lse, delta and the acc / dq / dk / dv accumulators are float32 always;
+# p and ds, float32 results of VPU work, are cast to the inputs' dtype where
+# they enter a dot. The softmax scale multiplies s in float32 after the dot,
+# and the dq / dk accumulators once at the end (scaling q or ds in bf16
+# would round them once more).
+
+_NT = (((1,), (1,)), ((), ()))       # a @ b.T, as the MXU takes it
+
+
+def _dot(a, b, dims=(((1,), (0,)), ((), ()))):
+    return lax.dot_general(a, b, dims, preferred_element_type=jnp.float32)
+
+
+def _k_blocks(qb, kv_len, causal, block_q, block_k, t_pad):
+    """How many k blocks a q block streams: only those up to its causal
+    frontier do any work (skipping the rest halves the attention FLOPs),
+    and none entirely past this row's key length."""
+    nk = jnp.minimum(t_pad // block_k, (kv_len + block_k - 1) // block_k)
+    if causal:
+        nk = jnp.minimum(nk, ((qb + 1) * block_q + block_k - 1) // block_k)
+    return nk
+
+
 def _flash_fwd_kernel(q_ref, k_ref, v_ref, len_ref, o_ref, lse_ref, *,
                       scale, causal, block_q, block_k, t_pad):
     qb = pl.program_id(1)
-    q = q_ref[0].astype(jnp.float32) * scale                 # [bq, d]
+    q = q_ref[0]                                             # [bq, d]
     bq, d = q.shape
     qpos = qb * block_q + lax.broadcasted_iota(jnp.int32, (bq, 1), 0)
     # whole [BH, 1] array lives in SMEM (a (1,1)-blocked spec violates
     # Mosaic's (8,128) block rule — caught on first real-TPU run, round 4)
     kv_len = len_ref[pl.program_id(0), 0]                    # this row's T
 
-    nk = t_pad // block_k
-    if causal:
-        # only k blocks up to this q block's causal frontier do any work —
-        # skipping the rest halves the attention FLOPs for causal decode
-        nk_dyn = jnp.minimum(nk, ((qb + 1) * block_q + block_k - 1)
-                             // block_k)
-    else:
-        nk_dyn = nk
-    # key-padding early exit: blocks entirely past this row's length
-    nk_dyn = jnp.minimum(nk_dyn, (kv_len + block_k - 1) // block_k)
-
     def body(kb, carry):
         m, l, acc = carry
-        k = k_ref[0, pl.ds(kb * block_k, block_k), :].astype(jnp.float32)
-        v = v_ref[0, pl.ds(kb * block_k, block_k), :].astype(jnp.float32)
-        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32)  # [bq, bk]
+        k = k_ref[0, pl.ds(kb * block_k, block_k), :]
+        v = v_ref[0, pl.ds(kb * block_k, block_k), :]
+        s = _dot(q, k, _NT) * scale                          # [bq, bk] f32
         kpos = kb * block_k + lax.broadcasted_iota(jnp.int32, (1, block_k),
                                                    1)
         valid = kpos < kv_len
@@ -105,12 +126,11 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, len_ref, o_ref, lse_ref, *,
         p = jnp.where(valid, p, 0.0)                         # masked -> 0
         corr = jnp.exp(m - m_new)
         l = l * corr + jnp.sum(p, axis=-1, keepdims=True)
-        acc = acc * corr + jnp.dot(p, v,
-                                   preferred_element_type=jnp.float32)
+        acc = acc * corr + _dot(p.astype(v.dtype), v)
         return m_new, l, acc
 
     m, l, acc = lax.fori_loop(
-        0, nk_dyn, body,
+        0, _k_blocks(qb, kv_len, causal, block_q, block_k, t_pad), body,
         (jnp.full((bq, 1), _NEG, jnp.float32),
          jnp.zeros((bq, 1), jnp.float32),
          jnp.zeros((bq, d), jnp.float32)))
@@ -166,12 +186,19 @@ def _flash_bwd_dkdv_kernel(q_ref, g_ref, k_ref, v_ref, lse_ref, delta_ref,
                            block_q, block_k, t_pad):
     """One k-block's dK/dV: stream q-blocks past it, starting at the
     causal frontier (q blocks strictly before this k block contribute
-    nothing — the same 2x FLOP skip the forward kernel does)."""
+    nothing — the same 2x FLOP skip the forward kernel does).
+
+    The block step works on the transposed scores, s.T = k @ q.T [bk, bq]:
+    p.T and ds.T then enter their dots as they are, with no transpose of a
+    [bq, bk] tile a block (a fifth to a quarter of this kernel's time, my
+    chip run, PR 27), and lse / delta are rows [1, bq] that broadcast down
+    the sublanes, so they arrive lane-dense as [nq, block_q] and not as
+    [t_pad, 1] columns of one lane in 128."""
     kb = pl.program_id(1)
-    k = k_ref[0].astype(jnp.float32)                     # [bk, d]
-    v = v_ref[0].astype(jnp.float32)
+    k = k_ref[0]                                             # [bk, d]
+    v = v_ref[0]
     bk, d = k.shape
-    kpos = kb * block_k + lax.broadcasted_iota(jnp.int32, (1, bk), 1)
+    kpos = kb * block_k + lax.broadcasted_iota(jnp.int32, (bk, 1), 0)
     kv_len = len_ref[pl.program_id(0), 0]
     nq = t_pad // block_q
     qb0 = (kb * block_k) // block_q if causal else 0
@@ -181,27 +208,25 @@ def _flash_bwd_dkdv_kernel(q_ref, g_ref, k_ref, v_ref, lse_ref, delta_ref,
 
     def body(qb, carry):
         dk, dv = carry
-        q = q_ref[0, pl.ds(qb * block_q, block_q), :].astype(jnp.float32)
-        g = g_ref[0, pl.ds(qb * block_q, block_q), :].astype(jnp.float32)
-        lse = lse_ref[0, pl.ds(qb * block_q, block_q), :]     # [bq, 1] f32
-        delta = delta_ref[0, pl.ds(qb * block_q, block_q), :]
-        qpos = qb * block_q + lax.broadcasted_iota(
-            jnp.int32, (block_q, 1), 0)
-        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
+        q = q_ref[0, pl.ds(qb * block_q, block_q), :]        # [bq, d]
+        g = g_ref[0, pl.ds(qb * block_q, block_q), :]
+        lse = lse_ref[0, pl.ds(qb, 1), :]                    # [1, bq] f32
+        delta = delta_ref[0, pl.ds(qb, 1), :]
         valid = kpos < kv_len
         if causal:
+            qpos = qb * block_q + lax.broadcasted_iota(
+                jnp.int32, (1, block_q), 1)
             valid = valid & (qpos >= kpos)
-        p = jnp.where(valid, jnp.exp(s - lse), 0.0)           # [bq, bk]
-        dv = dv + jnp.dot(p.T, g, preferred_element_type=jnp.float32)
-        dp = jnp.dot(g, v.T, preferred_element_type=jnp.float32)
-        ds = p * (dp - delta) * scale
-        dk = dk + jnp.dot(ds.T, q, preferred_element_type=jnp.float32)
+        p = jnp.where(valid, jnp.exp(_dot(k, q, _NT) * scale - lse), 0.0)
+        dv = dv + _dot(p.astype(g.dtype), g)                 # p [bk, bq]
+        ds = p * (_dot(v, g, _NT) - delta)
+        dk = dk + _dot(ds.astype(q.dtype), q)
         return dk, dv
 
     dk, dv = lax.fori_loop(qb0, nq, body,
                            (jnp.zeros((bk, d), jnp.float32),
                             jnp.zeros((bk, d), jnp.float32)))
-    dk_ref[0] = dk.astype(dk_ref.dtype)
+    dk_ref[0] = (dk * scale).astype(dk_ref.dtype)
     dv_ref[0] = dv.astype(dv_ref.dtype)
 
 
@@ -211,54 +236,60 @@ def _flash_bwd_dq_kernel(q_ref, g_ref, k_ref, v_ref, lse_ref, delta_ref,
     """One q-block's dQ: stream k-blocks up to the causal / key-length
     frontier (mirror of the forward loop)."""
     qb = pl.program_id(1)
-    q = q_ref[0].astype(jnp.float32)                     # [bq, d]
-    g = g_ref[0].astype(jnp.float32)
-    lse = lse_ref[0]                                     # [bq, 1] f32
+    q = q_ref[0]                                             # [bq, d]
+    g = g_ref[0]
+    lse = lse_ref[0]                                         # [bq, 1] f32
     delta = delta_ref[0]
     bq, d = q.shape
     qpos = qb * block_q + lax.broadcasted_iota(jnp.int32, (bq, 1), 0)
     kv_len = len_ref[pl.program_id(0), 0]
-    nk = t_pad // block_k
-    if causal:
-        nk_dyn = jnp.minimum(nk, ((qb + 1) * block_q + block_k - 1)
-                             // block_k)
-    else:
-        nk_dyn = nk
-    nk_dyn = jnp.minimum(nk_dyn, (kv_len + block_k - 1) // block_k)
 
     def body(kb, dq):
-        k = k_ref[0, pl.ds(kb * block_k, block_k), :].astype(jnp.float32)
-        v = v_ref[0, pl.ds(kb * block_k, block_k), :].astype(jnp.float32)
+        k = k_ref[0, pl.ds(kb * block_k, block_k), :]
+        v = v_ref[0, pl.ds(kb * block_k, block_k), :]
         kpos = kb * block_k + lax.broadcasted_iota(
             jnp.int32, (1, block_k), 1)
-        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
         valid = kpos < kv_len
         if causal:
             valid = valid & (qpos >= kpos)
-        p = jnp.where(valid, jnp.exp(s - lse), 0.0)
-        dp = jnp.dot(g, v.T, preferred_element_type=jnp.float32)
-        ds = p * (dp - delta) * scale
-        return dq + jnp.dot(ds, k, preferred_element_type=jnp.float32)
+        p = jnp.where(valid, jnp.exp(_dot(q, k, _NT) * scale - lse), 0.0)
+        ds = p * (_dot(g, v, _NT) - delta)
+        return dq + _dot(ds.astype(k.dtype), k)
 
-    dq = lax.fori_loop(0, nk_dyn, body, jnp.zeros((bq, d), jnp.float32))
-    dq_ref[0] = dq.astype(dq_ref.dtype)
+    dq = lax.fori_loop(
+        0, _k_blocks(qb, kv_len, causal, block_q, block_k, t_pad), body,
+        jnp.zeros((bq, d), jnp.float32))
+    dq_ref[0] = (dq * scale).astype(dq_ref.dtype)
 
 
 def _flash_bwd(scale, causal, block_q, block_k, interpret, res, g):
     """Flash backward as two pallas kernels (standard flash-attention
     recompute from the saved logsumexp — the [T, T] matrix never exists):
     a dK/dV kernel gridded over k-blocks and a dQ kernel gridded over
-    q-blocks, both with causal block skipping. Replaces the r4 plain-lax
-    scan, which the microbench measured at 0.75x XLA's dense backward
-    (no causal skip, no VMEM residency control).
+    q-blocks, both with causal block skipping. Their dots run in the dtype
+    of q, k, v and dO (bf16 under AMP, float32 for float32 inputs) with
+    float32 accumulation; p is recomputed in float32 from the float32 lse,
+    and p and ds are cast to that dtype where they enter a dot.
 
-    VMEM budget (ADVICE r4 #3): each kernel pins one full [t_pad, d]
-    operand pair in VMEM per grid step (q+g for dK/dV, k+v for dQ) —
-    2*t_pad*d*2B bf16 ≈ 0.5 MB at t=2048, d=64, comfortably inside the
-    ~16 MB/core budget up to t≈32k. Streaming that pair through a second
-    grid axis (double-buffered) is the follow-up if longer single-core
-    sequences are ever benched; ring/Ulysses SP is the intended path for
-    those lengths (parallel/ring_attention.py)."""
+    VMEM budget, at the benchmark's shapes and the default 512 x 512 blocks
+    (16 MiB scoped a core on the v5e). Each kernel pins one full [t_pad, d]
+    operand pair a grid step (q+dO for dK/dV, k+v for dQ); in VMEM d=64
+    pads to the 128 lanes of a tile, so a bf16 operand is t_pad * 256 B:
+    0.5 MiB at T=2048 (D=64), 1 MiB at T=4096 (D=128), 2 and 4 MiB for the
+    pair double-buffered. The [bq, bk] float32 tiles of a block step (s, p,
+    dp, ds, and the bf16 copies of p and ds) are 1 MiB each at 512 x 512,
+    about 5 MiB live. lse and delta: the dQ kernel takes them as (block_q,
+    1) float32 blocks, one lane in 128, 256 KiB each (1 MiB for the two,
+    double-buffered); the dK/dV kernel needs the whole row and takes it
+    lane-dense as [nq, block_q] (16 KiB each at T=4096), where [t_pad, 1]
+    blocks were 2 MiB a buffer and 8 MiB for the two double-buffered at
+    T=4096. Mosaic takes every pair of {128, 256, 512} x {128, 256, 512,
+    1024} at both shapes and 1024 x 512; it refuses 1024 x 1024 at D=128
+    (my chip run, PR 27). The pinned pair sets the longest sequence: T=8192
+    compiles at D=64 and D=128, T=16384 at no block size (AOT compile, PR
+    27). Streaming the pair through a second grid axis is the follow-up;
+    ring/Ulysses SP is the intended path for those lengths
+    (parallel/ring_attention.py)."""
     q, k, v, kv_len, delta, lse = res
     bh, t, d = q.shape
     blk = int(np.lcm(block_q, block_k))
@@ -268,8 +299,8 @@ def _flash_bwd(scale, causal, block_q, block_k, interpret, res, g):
         q, k, v, g = (jnp.pad(a, pad3) for a in (q, k, v, g))
         lse = jnp.pad(lse, [(0, 0), (0, t_pad - t)])
         delta = jnp.pad(delta, [(0, 0), (0, t_pad - t)])
-    lse3 = lse[..., None].astype(jnp.float32)
-    delta3 = delta[..., None].astype(jnp.float32)
+    lse, delta = lse.astype(jnp.float32), delta.astype(jnp.float32)
+    nq = t_pad // block_q
     lens = kv_len.reshape(bh, 1).astype(jnp.int32)
 
     dk, dv = pl.pallas_call(
@@ -282,8 +313,8 @@ def _flash_bwd(scale, causal, block_q, block_k, interpret, res, g):
             _vmem_spec((1, t_pad, d), lambda b, j: (b, 0, 0)),     # g
             _vmem_spec((1, block_k, d), lambda b, j: (b, j, 0)),   # k
             _vmem_spec((1, block_k, d), lambda b, j: (b, j, 0)),   # v
-            _vmem_spec((1, t_pad, 1), lambda b, j: (b, 0, 0)),     # lse
-            _vmem_spec((1, t_pad, 1), lambda b, j: (b, 0, 0)),     # delta
+            _vmem_spec((1, nq, block_q), lambda b, j: (b, 0, 0)),  # lse
+            _vmem_spec((1, nq, block_q), lambda b, j: (b, 0, 0)),  # delta
             _SMEM_WHOLE,
         ],
         out_specs=[
@@ -296,7 +327,8 @@ def _flash_bwd(scale, causal, block_q, block_k, interpret, res, g):
         ],
         interpret=interpret,
         name="ptpu_flash_bwd_dkdv",
-    )(q, g, k, v, lse3, delta3, lens)
+    )(q, g, k, v, lse.reshape(bh, nq, block_q),
+      delta.reshape(bh, nq, block_q), lens)
 
     dq = pl.pallas_call(
         functools.partial(_flash_bwd_dq_kernel, scale=scale, causal=causal,
@@ -315,7 +347,7 @@ def _flash_bwd(scale, causal, block_q, block_k, interpret, res, g):
         out_shape=jax.ShapeDtypeStruct((bh, t_pad, d), q.dtype),
         interpret=interpret,
         name="ptpu_flash_bwd_dq",
-    )(q, g, k, v, lse3, delta3, lens)
+    )(q, g, k, v, lse[..., None], delta[..., None], lens)
     return dq[:, :t], dk[:, :t], dv[:, :t]
 
 
@@ -384,24 +416,38 @@ _flash_core.defvjp(_flash_core_fwd, _flash_core_bwd)
 
 
 def flash_attention(q, k, v, causal=False, scale=None, kv_len=None,
-                    block_q=128, block_k=128, interpret=None):
+                    block_q=None, block_k=None, interpret=None):
     """Exact attention, flash-style. q,k,v: [B, T, H, D] (BTHD, the layout
-    ring_attention uses); returns [B, T, H, D].
+    ring_attention uses); returns [B, T, H, D]. block_q / block_k default to
+    kernel_config.DEFAULT_TILES["attn"] and are clamped to T.
 
     kv_len: optional [B] int true key lengths — keys at position >= kv_len
     are masked out AND their blocks skipped entirely (the padded-batch
-    regime every fluid sequence model runs in). Differentiable; matches
-    attention_reference to fp32 tolerance. On TPU the forward runs as a
-    pallas kernel (online softmax in VMEM); off-TPU it runs the same
-    kernel in interpret mode.
+    regime every fluid sequence model runs in). Differentiable. On TPU the
+    forward and both backward kernels compile through Mosaic (online
+    softmax in VMEM); off-TPU the same bodies run in interpret mode.
+
+    Precision follows the inputs' dtype, the one thing the kernels look at:
+    float32 q, k, v run every dot on float32 operands and match
+    attention_reference to float32 tolerance in the interpreter (2e-4
+    forward, 2e-3 gradients; on the TPU a float32 dot at default precision
+    is one bf16 pass of the MXU, as XLA's own are); bf16 q, k, v (and dO)
+    go to the MXU as bf16 with float32 accumulation, the softmax, its
+    statistics and every accumulator stay float32, and p and ds are rounded
+    to bf16 where they enter a dot: within 0.5 % of the largest value of
+    the float32 result on the same rounded inputs, forward and gradients,
+    which is closer than the dense path gets under bf16 AMP (its logits and
+    softmax are bf16 too).
     """
     if interpret is None:
         interpret = _interpret_default()
     b, t, h, d = q.shape
     if scale is None:
         scale = 1.0 / float(np.sqrt(d))
-    block_q = max(8, min(block_q, int(-(-t // 8) * 8)))
-    block_k = max(8, min(block_k, int(-(-t // 8) * 8)))
+    block_q = max(8, min(block_q or DEFAULT_TILES["attn"]["block_q"],
+                         int(-(-t // 8) * 8)))
+    block_k = max(8, min(block_k or DEFAULT_TILES["attn"]["block_k"],
+                         int(-(-t // 8) * 8)))
     if kv_len is None:
         lens = jnp.full((b * h,), t, jnp.int32)
     else:

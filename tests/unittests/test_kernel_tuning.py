@@ -103,6 +103,15 @@ def test_no_flag_means_no_tuning_store(monkeypatch):
         TuningStore().put("kernel:attn/b2048", "cpu/cpu", {"block_q": 64})
 
 
+@pytest.mark.parametrize("t", [2048, 4096], ids=["t2048_d64", "t4096_d128"])
+def test_default_attn_tiles_are_the_sweeps_choice(monkeypatch, t):
+    """PERF.md section 6, PR 27: 512 x 512 won the v5e sweep at both shapes
+    the benchmark's cells run, D=64 and D=128 alike, so the default is one
+    table entry with no dependence on the head width."""
+    monkeypatch.delenv("FLAGS_tuning_store_dir", raising=False)
+    assert kc.tiles_for("attn", t) == {"block_q": 512, "block_k": 512}
+
+
 def test_shape_bucket():
     assert kc.shape_bucket(1) == 8
     assert kc.shape_bucket(8) == 8

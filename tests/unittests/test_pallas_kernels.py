@@ -1,5 +1,7 @@
 """Pallas fused kernels vs dense references (interpret mode on CPU — the
 same kernel code path that runs compiled on TPU)."""
+import functools
+
 import numpy as np
 import pytest
 
@@ -355,3 +357,114 @@ def test_flash_attention_kv_len_block_boundaries():
     ref = attention_reference(q, k, v, kv_len=jnp.asarray(lens))
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-4, atol=2e-5)
+
+
+@pytest.mark.parametrize("causal", [False, True], ids=["full", "causal"])
+@pytest.mark.parametrize("bq,bk", [(16, 32), (32, 16), (16, 16)])
+def test_flash_attention_kv_len_grads_block_grid(bq, bk, causal):
+    """Gradients where both frontiers cross blocks: key lengths on, before
+    and after a block boundary (and one row of a single key), with and
+    without the causal diagonal, on uneven tilings."""
+    rng = np.random.RandomState(bq * 3 + bk)
+    q, k, v = _qkv(rng, b=5, t=64, h=2, d=8)
+    lens = jnp.asarray([32, 31, 33, 64, 1], "int32")
+
+    def loss(fn):
+        return lambda q, k, v: jnp.sum(fn(q, k, v) ** 2)
+
+    gf = jax.grad(loss(lambda q, k, v: pk.flash_attention(
+        q, k, v, causal=causal, kv_len=lens, block_q=bq, block_k=bk)),
+        argnums=(0, 1, 2))(q, k, v)
+    gd = jax.grad(loss(lambda q, k, v: attention_reference(
+        q, k, v, causal=causal, kv_len=lens)), argnums=(0, 1, 2))(q, k, v)
+    for a, b in zip(gf, gd):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=5e-3, atol=5e-4)
+
+
+# ---------------------------------------------------------------------------
+# bf16 inputs: the kernels' dots take bf16 operands and accumulate in float32
+# ---------------------------------------------------------------------------
+
+# block_q, block_k; None is the default table's pair
+_BF16_BLOCKS = {"128x128": (128, 128), "256x512": (256, 512),
+                "default": (None, None)}
+
+
+@functools.lru_cache(maxsize=2)     # the four cases of one key run in a row
+def _bf16_flash_and_reference(mask, d, blocks):
+    """(flash, reference) as (out, dq, dk, dv) in float32: the kernels on
+    bf16 q, k, v and a bf16 cotangent; dense attention in float32 on the
+    same rounded values."""
+    rng = np.random.RandomState(d + len(mask))
+    b, t, h = 2, 1024, 1
+    q, k, v, g = (jnp.asarray(rng.randn(b, t, h, d), jnp.bfloat16)
+                  for _ in range(4))
+    kw = {"causal": mask == "causal"}
+    if mask == "kv_len":
+        kw["kv_len"] = jnp.asarray([t - 200, 130], jnp.int32)
+    bq, bk = _BF16_BLOCKS[blocks]
+    out, vjp = jax.vjp(lambda q, k, v: pk.flash_attention(
+        q, k, v, block_q=bq, block_k=bk, **kw), q, k, v)
+    assert out.dtype == jnp.bfloat16
+    f32 = [x.astype(jnp.float32) for x in (q, k, v)]
+    ref, ref_vjp = jax.vjp(lambda q, k, v: attention_reference(
+        q, k, v, **kw), *f32)
+    return (tuple(np.asarray(x.astype(jnp.float32))
+                  for x in (out,) + vjp(g)),
+            tuple(np.asarray(x)
+                  for x in (ref,) + ref_vjp(g.astype(jnp.float32))))
+
+
+@pytest.mark.parametrize("which", ["out", "dq", "dk", "dv"])
+@pytest.mark.parametrize("blocks", sorted(_BF16_BLOCKS))
+@pytest.mark.parametrize("d", [64, 128])
+@pytest.mark.parametrize("mask", ["full", "causal", "kv_len"])
+def test_flash_attention_bf16_matches_float32_reference(mask, d, blocks,
+                                                        which):
+    """What bf16 operands cost in accuracy, as the largest error over the
+    largest reference value. q @ k.T and dO @ v.T are exact products summed
+    in float32, so the only roundings the kernels add to the float32
+    reference are of p and ds to bf16 where they enter a dot and of each
+    result to bf16: up to 2**-8 = 0.39 % of a value each, and the sums
+    over keys average the first down. Measured 0.18-0.49 %; 1 % holds a
+    second rounding of an operand, a missing mask or a wrong block out."""
+    got, want = _bf16_flash_and_reference(mask, d, blocks)
+    i = ["out", "dq", "dk", "dv"].index(which)
+    err = np.abs(got[i] - want[i]).max() / np.abs(want[i]).max()
+    assert err <= 1e-2, "%s off by %.2f %% of its largest value" % (
+        which, 100 * err)
+
+
+def _kernel_dot_operands(dtype):
+    """{kernel name: [(lhs dtype, rhs dtype) of every dot_general in its
+    body]} for the forward and both backward kernels traced on `dtype`."""
+    x = jnp.zeros((1, 64, 2, 16), dtype)
+    jaxpr = jax.make_jaxpr(lambda q, k, v: jax.vjp(
+        lambda *a: pk.flash_attention(*a, causal=True, block_q=16,
+                                      block_k=16), q, k, v)[1](q))(x, x, x)
+    found = {}
+
+    def walk(j, kernel):
+        for e in j.eqns:
+            if e.primitive.name == "dot_general" and kernel:
+                found.setdefault(kernel, []).append(
+                    tuple(str(a.aval.dtype) for a in e.invars))
+            inside = e.params["name"] if e.primitive.name == "pallas_call" \
+                else kernel
+            for sub in jax.core.jaxprs_in_params(e.params):
+                walk(sub, inside)
+    walk(jaxpr.jaxpr, None)
+    return found
+
+
+@pytest.mark.parametrize("kernel,n_dots", [
+    ("ptpu_flash_fwd", 2), ("ptpu_flash_bwd_dkdv", 4),
+    ("ptpu_flash_bwd_dq", 3)])
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+def test_flash_kernel_dots_take_the_input_dtype(dtype, kernel, n_dots):
+    """No cast in front of the MXU: with bf16 inputs no dot in a kernel
+    body has a float32 operand, with float32 inputs every one has."""
+    dots = _kernel_dot_operands(dtype)[kernel]
+    assert len(dots) == n_dots
+    assert all(pair == (dtype, dtype) for pair in dots), dots

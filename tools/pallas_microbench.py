@@ -37,7 +37,7 @@ def _time(fn, *args, iters=20, warmup=3):
 
 def _attention_setup(b, t, h, d, causal, dtype):
     """Shared q/k/v construction + dense baseline so bench_attention and
-    tune_attention_blocks stay comparable by construction."""
+    sweep_flash_blocks stay comparable by construction."""
     import jax.numpy as jnp
     from paddle_tpu.parallel.ring_attention import attention_reference
 
@@ -104,71 +104,91 @@ def bench_softmax_xent(n=8192, v=32000):
         "shape": [n, v], "device": str(jax.devices()[0])}), flush=True)
 
 
-def tune_attention_blocks(b=8, t=2048, h=8, d=64, causal=True,
-                          dtype="bfloat16"):
-    """Sweep flash block_q/block_k against the dense baseline, timing the
-    forward alone and fwd+bwd separately (the r4 microbench measured
-    fwd+bwd at 0.75x dense with the 128/128 default — this isolates
-    whether the forward tiling or the backward kernel is the regression)."""
+_SWEEP_SHAPES = "64x2048x64x0,64x2048x64x1,64x4096x128x1"
+_SWEEP_BLOCKS = tuple((bq, bk) for bq in (128, 256, 512, 1024)
+                      for bk in (128, 256, 512, 1024))
+
+
+def sweep_flash_blocks(shapes=_SWEEP_SHAPES, blocks=_SWEEP_BLOCKS,
+                       dtype="bfloat16"):
+    """ms a call of each of the three flash kernels alone, in the kernels'
+    own [BH, T, D] layout, over block_q x block_k at the shapes the
+    benchmark's cells run (BHxTxDxcausal), and each result's largest error
+    against dense float32 attention on the same rounded inputs (first two
+    head-sequences). A pair Mosaic refuses is a line with its error. Under
+    jit a kernel whose result is dropped is dead code, so `_flash_bwd`'s
+    dq alone times the dQ kernel and (dk, dv) alone the dK/dV kernel."""
     jax = _await()
     import jax.numpy as jnp
     from paddle_tpu.ops import pallas_kernels as pk
+    from paddle_tpu.parallel.ring_attention import attention_reference
 
-    q, k, v, dense_fwd, dense_loss = _attention_setup(b, t, h, d, causal,
-                                                      dtype)
-    dense_f = jax.jit(dense_fwd)
-    dense_g = jax.jit(jax.grad(dense_loss, argnums=(0, 1, 2)))
-    dfms = _time(dense_f, q, k, v)
-    dgms = _time(dense_g, q, k, v)
-    print(json.dumps({"kernel": "attention_dense_baseline",
-                      "fwd_ms": round(dfms, 3), "fwdbwd_ms": round(dgms, 3),
-                      "shape": [b, t, h, d], "causal": causal,
-                      "device": str(jax.devices()[0])}), flush=True)
+    for spec in shapes.split(","):
+        bh, t, d, causal = (int(x) for x in spec.split("x"))
+        causal, scale = bool(causal), 1.0 / float(np.sqrt(d))
+        rng = np.random.RandomState(0)
+        q, k, v, g = (jnp.asarray(rng.randn(bh, t, d).astype("f") * 0.5,
+                                  dtype=dtype) for _ in range(4))
+        lens = jnp.full((bh,), t, jnp.int32)
 
-    for bq in (128, 256, 512):
-        for bk in (128, 256, 512):
+        def dense(q, k, v):      # [2, T, D] -> heads of a [1, T, 2, D]
+            return attention_reference(
+                *(x.astype(jnp.float32).transpose(1, 0, 2)[None]
+                  for x in (q, k, v)), causal=causal)[0].transpose(1, 0, 2)
+        ref_o, vjp = jax.vjp(dense, q[:2], k[:2], v[:2])
+        refs = (ref_o,) + vjp(g[:2].astype(jnp.float32))
+
+        for bq, bk in blocks:
             if bq > t or bk > t:
                 continue
+            fwd = jax.jit(lambda q, k, v, bq=bq, bk=bk: pk._flash_fwd(
+                q, k, v, lens, scale, causal, bq, bk, False))
 
-            def flash_loss(q, k, v, bq=bq, bk=bk):
-                return jnp.sum(pk.flash_attention(
-                    q, k, v, causal=causal, block_q=bq, block_k=bk)
-                    .astype(jnp.float32))
-
-            # fwd and fwd+bwd fail independently (e.g. a block config
-            # whose backward kernel exceeds VMEM) — time them separately
-            # so a bwd failure cannot discard a banked fwd number
-            err = None
+            def bwd(q, k, v, o, lse, g, bq=bq, bk=bk):
+                delta = jnp.sum(g.astype(jnp.float32)
+                                * o.astype(jnp.float32), axis=-1)
+                return pk._flash_bwd(scale, causal, bq, bk, False,
+                                     (q, k, v, lens, delta, lse), g)
+            line = {"kernel": "flash_sweep", "shape": [bh, t, d],
+                    "causal": causal, "dtype": dtype, "block_q": bq,
+                    "block_k": bk, "device": str(jax.devices()[0])}
             try:
-                ff = jax.jit(lambda q, k, v, bq=bq, bk=bk:
-                             pk.flash_attention(q, k, v, causal=causal,
-                                                block_q=bq, block_k=bk))
-                ffms = _time(ff, q, k, v)
+                o, lse = fwd(q, k, v)
+                line["fwd_ms"] = round(_time(fwd, q, k, v), 3)
+                got = [o]
             except Exception as e:  # noqa: BLE001 — record, keep sweeping
-                ffms = None
-                err = "fwd: " + str(e)[:140]
-            try:
-                fg = jax.jit(jax.grad(flash_loss, argnums=(0, 1, 2)))
-                fgms = _time(fg, q, k, v)
-            except Exception as e:  # noqa: BLE001
-                fgms = None
-                err = (err + "; " if err else "") + "bwd: " + str(e)[:140]
-            print(json.dumps({
-                "kernel": "flash_tune", "block_q": bq, "block_k": bk,
-                "fwd_ms": ffms and round(ffms, 3),
-                "fwdbwd_ms": fgms and round(fgms, 3),
-                "fwd_speedup": ffms and round(dfms / ffms, 3),
-                "fwdbwd_speedup": fgms and round(dgms / fgms, 3),
-                "error": err}), flush=True)
+                line["fwd_error"] = str(e).replace("\n", " ")[:200]
+                o, lse = jax.jit(lambda q, k, v: pk._flash_fwd(
+                    q, k, v, lens, scale, causal, 128, 128, False))(q, k, v)
+                got = [None]
+            for name, pick in (("dq", lambda r: r[:1]),
+                               ("dkdv", lambda r: r[1:])):
+                fn = jax.jit(lambda *a, pick=pick: pick(bwd(*a)))
+                try:
+                    got += list(fn(q, k, v, o, lse, g))
+                    line[name + "_ms"] = round(
+                        _time(fn, q, k, v, o, lse, g), 3)
+                except Exception as e:  # noqa: BLE001
+                    got += [None] * (1 if name == "dq" else 2)
+                    line[name + "_error"] = str(e).replace("\n", " ")[:200]
+            line["max_err"] = {
+                n: None if a is None else round(float(jnp.max(jnp.abs(
+                    a[:2].astype(jnp.float32) - r))), 5)
+                for n, a, r in zip(("o", "dq", "dk", "dv"), got, refs)}
+            print(json.dumps(line), flush=True)
 
 
 if __name__ == "__main__":
     # MB_* knobs shrink the config for smoke runs (CPU interpret mode is
     # orders of magnitude slower than the real kernel)
     if os.environ.get("MB_TUNE") == "1":
-        tune_attention_blocks(b=int(os.environ.get("MB_B", "8")),
-                              t=int(os.environ.get("MB_SEQ", "2048")),
-                              h=int(os.environ.get("MB_H", "8")))
+        # MB_SHAPES=BHxTxDxcausal[,...], MB_BLOCKS=BQxBK[,...]
+        sweep_flash_blocks(
+            os.environ.get("MB_SHAPES", _SWEEP_SHAPES),
+            tuple(tuple(int(x) for x in b.split("x"))
+                  for b in os.environ["MB_BLOCKS"].split(","))
+            if os.environ.get("MB_BLOCKS") else _SWEEP_BLOCKS,
+            os.environ.get("MB_DTYPE", "bfloat16"))
     elif os.environ.get("MB_SHAPES"):
         # MB_SHAPES=BxTxHxD[,BxTxHxD...]: attention fwd+bwd comparison
         # at each shape (one line per shape, cheapest-first ordering is
