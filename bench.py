@@ -70,10 +70,6 @@ def _error_line(msg):
         return {"metric": "observability_overhead", "value": 0.0,
                 "unit": "steps/sec/chip", "vs_baseline": None,
                 "error": msg}
-    if os.environ.get("BENCH_KERNELS") == "1":
-        return {"metric": "kernel_floor_speedup", "value": 0.0,
-                "unit": "x fused/unfused", "vs_baseline": None,
-                "error": msg}
     if os.environ.get("BENCH_DECODE") == "1" \
             and os.environ.get("BENCH_MODEL", "") != "transformer":
         # the standalone continuous-batching leg (BENCH_MODEL=transformer
@@ -2330,238 +2326,6 @@ def bench_compile_cache():
         shutil.rmtree(workdir, ignore_errors=True)
 
 
-def bench_kernels():
-    """BENCH_KERNELS=1: the kernel-floor leg (ARCHITECTURE.md §25) —
-    per-op fused-vs-unfused and tuned-vs-default-tile timings plus max
-    numeric divergence, one JSON line.
-
-    Gate split (the CPU-vs-TPU measurement discipline): correctness
-    (divergence bounds per op + the bf16/int8 serving divergence gate)
-    is enforced EVERYWHERE — on CPU the kernels run interpret mode, the
-    same code path, so a numerics break fails the leg before it ever
-    reaches hardware. Speed is asserted only on real TPU (interpret
-    mode is orders slower by construction): at least one op must beat
-    its unfused path by BENCH_KERNELS_MIN_SPEEDUP (default 1.2; 0
-    disables). The >=1.5x-on->=2-ops ROADMAP claim is the sweep tier-3
-    target, recorded in the JSON, not asserted here.
-
-    Dims via BENCH_KERNELS_{SEQ,VOCAB,DIM,BATCH}; defaults are small on
-    CPU (a correctness leg must stay inside the tier-1 budget) and
-    hot-set-sized on TPU."""
-    import tempfile
-
-    import numpy as np
-
-    import jax
-    import jax.numpy as jnp
-
-    import paddle_tpu as fluid
-    from paddle_tpu.ops import kernel_config as kc
-    from paddle_tpu.ops import pallas_kernels as pk
-    from paddle_tpu.parallel.ring_attention import attention_reference
-    from paddle_tpu.serving.engine import InferenceEngine
-    from paddle_tpu.serving.quantize import divergence_bound
-    from paddle_tpu.tuning.autotuner import _time_best
-
-    on_tpu = any(d.platform != "cpu" for d in jax.devices())
-    repeats = int(os.environ.get("BENCH_KERNELS_REPEATS", "3"))
-    t = int(os.environ.get("BENCH_KERNELS_SEQ",
-                           "2048" if on_tpu else "32"))
-    vocab = int(os.environ.get("BENCH_KERNELS_VOCAB",
-                               "32000" if on_tpu else "128"))
-    d = int(os.environ.get("BENCH_KERNELS_DIM",
-                           "512" if on_tpu else "16"))
-    batch = int(os.environ.get("BENCH_KERNELS_BATCH",
-                               "8" if on_tpu else "3"))
-    rng = np.random.RandomState(0)
-
-    def div(a, b):
-        a = np.asarray(a, np.float64)
-        b = np.asarray(b, np.float64)
-        return float(np.abs(a - b).max() / (np.abs(b).max() + 1e-6))
-
-    per_op = {}
-
-    def leg(name, fused_fn, unfused_fn, args, bound):
-        f = jax.jit(fused_fn)
-        u = jax.jit(unfused_fn)
-        got, want = f(*args), u(*args)
-        d_ = div(got, want)
-        ft = _time_best(f, args, repeats)
-        ut = _time_best(u, args, repeats)
-        per_op[name] = {"fused_s": round(ft, 6), "unfused_s": round(ut, 6),
-                        "speedup": round(ut / ft, 3),
-                        "divergence": d_, "bound": bound}
-        if d_ > bound:
-            raise RuntimeError("kernel %s divergence %.3e exceeds bound "
-                               "%.3e" % (name, d_, bound))
-
-    # attention: fused flash (tuned tiles) vs the dense einsum reference
-    h, hd = 4, 64
-    q, k, v = (jnp.asarray(rng.randn(batch, t, h, hd), jnp.float32) * 0.3
-               for _ in range(3))
-    tiles = kc.tiles_for("attn", t)
-    leg("attn",
-        lambda q, k, v: pk.flash_attention(q, k, v, causal=True,
-                                           block_q=tiles["block_q"],
-                                           block_k=tiles["block_k"]),
-        lambda q, k, v: attention_reference(q, k, v, causal=True),
-        (q, k, v), 1e-3)
-
-    # tuned-vs-default tiles (same kernel both sides): only reported
-    # when a tuned entry actually changed the tiles
-    default_tiles = kc.DEFAULT_TILES["attn"]
-    tuned = None
-    if tiles != default_tiles:
-        tf = _time_best(jax.jit(
-            lambda q, k, v: pk.flash_attention(
-                q, k, v, causal=True, block_q=tiles["block_q"],
-                block_k=tiles["block_k"])), (q, k, v), repeats)
-        df = _time_best(jax.jit(
-            lambda q, k, v: pk.flash_attention(
-                q, k, v, causal=True, block_q=default_tiles["block_q"],
-                block_k=default_tiles["block_k"])), (q, k, v), repeats)
-        tuned = {"tiles": tiles, "default": default_tiles,
-                 "tuned_s": round(tf, 6), "default_s": round(df, 6),
-                 "speedup": round(df / tf, 3)}
-
-    # softmax-xent
-    n = batch * 32
-    logits = jnp.asarray(rng.randn(n, vocab), jnp.float32)
-    labels = jnp.asarray(rng.randint(0, vocab, (n,)), jnp.int32)
-
-    def xent_dense(lg, lb):
-        lp = jax.nn.log_softmax(lg, axis=-1)
-        return -lp[jnp.arange(lg.shape[0]), lb].reshape(-1, 1)
-
-    leg("xent",
-        lambda lg, lb: pk.softmax_xent(
-            lg, lb, block_n=kc.tiles_for("xent", vocab)["block_n"]),
-        xent_dense, (logits, labels), 1e-5)
-
-    # layer norm
-    x_ln = jnp.asarray(rng.randn(n, d), jnp.float32)
-    scale = jnp.asarray(rng.rand(d) + 0.5, jnp.float32)
-    bias = jnp.asarray(rng.randn(d), jnp.float32)
-
-    def ln_dense(x, s, b):
-        mu = jnp.mean(x, -1, keepdims=True)
-        var = jnp.var(x, -1, keepdims=True)
-        return (x - mu) * jax.lax.rsqrt(var + 1e-5) * s + b
-
-    leg("ln",
-        lambda x, s, b: pk.layer_norm(
-            x, s, b, block_n=kc.tiles_for("ln", d)["block_n"])[0],
-        ln_dense, (x_ln, scale, bias), 1e-4)
-
-    # fused LSTM vs the lax.scan path
-    lt = max(8, t // 8)
-    x_l = jnp.asarray(rng.randn(batch, lt, 4 * d), jnp.float32) * 0.3
-    w_l = jnp.asarray(rng.randn(d, 4 * d), jnp.float32) * 0.2
-    b_l = jnp.asarray(rng.randn(4 * d), jnp.float32) * 0.1
-    lens = jnp.asarray(rng.randint(1, lt + 1, (batch,)), jnp.int32)
-
-    def lstm_scan(x, w, b, lens):
-        tt = x.shape[1]
-        m = (jnp.arange(tt)[None, :] < lens[:, None]).astype(jnp.float32)
-        xs = jnp.swapaxes(x, 0, 1)
-        ms = m.T[:, :, None]
-        dd = w.shape[0]
-        h0 = jnp.zeros((x.shape[0], dd), jnp.float32)
-        c0 = jnp.zeros((x.shape[0], dd), jnp.float32)
-
-        def step(carry, inp):
-            h_prev, c_prev = carry
-            xt, mt = inp
-            gates = xt + h_prev @ w + b
-            gc, gi, gf, go = jnp.split(gates, 4, axis=-1)
-            i = jax.nn.sigmoid(gi)
-            f = jax.nn.sigmoid(gf)
-            c_new = f * c_prev + i * jnp.tanh(gc)
-            o = jax.nn.sigmoid(go)
-            h_new = o * jnp.tanh(c_new)
-            hh = mt * h_new + (1 - mt) * h_prev
-            cc = mt * c_new + (1 - mt) * c_prev
-            return (hh, cc), hh
-
-        _, hs = jax.lax.scan(step, (h0, c0), (xs, ms))
-        return jnp.swapaxes(hs, 0, 1)
-
-    leg("lstm",
-        lambda x, w, b, lens: pk.fused_lstm(
-            x, w, b, None, None, lens,
-            block_b=kc.tiles_for("lstm", d)["block_b"])[0],
-        lstm_scan, (x_l, w_l, b_l, lens), 1e-5)
-
-    # masked sequence softmax
-    x_s = jnp.asarray(rng.randn(batch * 16, t), jnp.float32)
-    lens_s = jnp.asarray(rng.randint(1, t + 1, (batch * 16,)), jnp.int32)
-
-    def seq_dense(x, lens):
-        m = (jnp.arange(x.shape[1])[None, :]
-             < lens[:, None]).astype(x.dtype)
-        return jax.nn.softmax(jnp.where(m > 0, x, -1e30), axis=1) * m
-
-    leg("seq_softmax",
-        lambda x, lens: pk.masked_softmax(
-            x, lens, block_n=kc.tiles_for("seq", t)["block_n"]),
-        seq_dense, (x_s, lens_s), 1e-6)
-
-    # quantized serving divergence gate: tiny MLP, fp32 vs bf16/int8
-    # engines over the same weights (run_direct: no batcher noise)
-    feat, classes = 16, 4
-    main_p, startup = fluid.Program(), fluid.Program()
-    main_p.random_seed = startup.random_seed = 11
-    with fluid.unique_name.guard(), fluid.program_guard(main_p, startup):
-        xv = fluid.layers.data(name="x", shape=[feat], dtype="float32")
-        hv = fluid.layers.fc(input=xv, size=32, act="relu")
-        pred = fluid.layers.fc(input=hv, size=classes, act="softmax")
-    exe = fluid.Executor(fluid.CPUPlace())
-    mdl = tempfile.mkdtemp(prefix="bench_kernels_model_")
-    with fluid.scope_guard(fluid.Scope()):
-        exe.run(startup)
-        fluid.io.save_inference_model(mdl, ["x"], [pred], exe, main_p)
-    feed = {"x": rng.randn(4, feat).astype("float32")}
-    quant = {}
-    ref_eng = InferenceEngine(mdl, warmup=False)
-    ref_out, _ = ref_eng.run_direct(feed)
-    for wd in ("bf16", "int8"):
-        eng = InferenceEngine(mdl, weights_dtype=wd, warmup=False)
-        out, _ = eng.run_direct(feed)
-        dv = max(div(out[nm], ref_out[nm]) for nm in ref_out)
-        bound = divergence_bound(wd)
-        quant[wd] = {"divergence": dv, "bound": bound,
-                     "bytes_before": eng.quantize_report["bytes_before"],
-                     "bytes_after": eng.quantize_report["bytes_after"]}
-        eng.close()
-        if dv > bound:
-            ref_eng.close()
-            raise RuntimeError("%s serving divergence %.3e exceeds gate "
-                               "%.3e" % (wd, dv, bound))
-    ref_eng.close()
-
-    speedups = [rec["speedup"] for rec in per_op.values()]
-    geomean = float(np.exp(np.mean(np.log(np.maximum(speedups, 1e-9)))))
-    min_speedup = float(os.environ.get("BENCH_KERNELS_MIN_SPEEDUP",
-                                       "1.2"))
-    if on_tpu and min_speedup > 0 and max(speedups) < min_speedup:
-        raise RuntimeError(
-            "TPU speed gate: no fused op beat its unfused path by %.2fx "
-            "(best %.2fx)" % (min_speedup, max(speedups)))
-    _emit({
-        "metric": "kernel_floor_speedup",
-        "value": round(geomean, 3), "unit": "x fused/unfused",
-        "vs_baseline": None,
-        "device": str(jax.devices()[0]),
-        "on_tpu": on_tpu,
-        "speed_asserted": bool(on_tpu and min_speedup > 0),
-        "ops_ge_1p5x": sum(1 for s in speedups if s >= 1.5),
-        "per_op": per_op,
-        "tuned_vs_default": tuned,
-        "quantized": quant,
-        "dims": {"seq": t, "vocab": vocab, "dim": d, "batch": batch}})
-
-
 def main():
     # The compile-cache leg runs each scenario in child processes that
     # need the chip, and a chip belongs to one process: this parent must
@@ -2624,13 +2388,6 @@ def main():
         return
     if os.environ.get("BENCH_OBS") == "1":
         bench_obs()
-        return
-    if os.environ.get("BENCH_KERNELS") == "1":
-        try:
-            bench_kernels()
-        except Exception as e:  # noqa: BLE001 — one JSON error line
-            _emit(_error_line("kernels leg failed: %r" % (e,)))
-            sys.exit(2)
         return
     if os.environ.get("BENCH_DECODE") == "1" \
             and os.environ.get("BENCH_MODEL", "") != "transformer":

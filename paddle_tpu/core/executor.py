@@ -621,8 +621,6 @@ class Executor(object):
         self._check_nan_inf = _nan_inf_enabled(check_nan_inf)
         self._array_safety = _array_safety_enabled()
         self._validated = set()  # (uid, version, feeds, fetches, multi)
-        self._tuned = {}  # (uid, version) -> tuning entry | None, so
-        # apply_tuned costs one store read per program, not per dispatch
         self._prefetcher = None  # core/dispatch.HostIoPrefetcher, armed
         # lazily by the first run(prefetch=True) on a reader-fed program
         self._has_read = {}  # (uid, version) -> program has `read` ops
@@ -633,7 +631,7 @@ class Executor(object):
     def run(self, program=None, feed=None, fetch_list=None, scope=None,
             return_numpy=True, use_program_cache=True, steps=1,
             fetch_reduce="stack", validate=None, timeout=None,
-            apply_tuned=False, prefetch=False):
+            prefetch=False):
         """Run `program` once — or, with steps=K > 1, K times inside ONE
         device-resident lax.scan dispatch: params/optimizer state stay
         donated on device across the K steps and the host syncs once per
@@ -655,18 +653,6 @@ class Executor(object):
         FLAGS_validate_program env flag; validation is cached per
         (program version, feed/fetch signature) so steady-state runs pay
         nothing.
-
-        apply_tuned=True consults the tuning store (paddle_tpu.tuning)
-        for a recorded config under this program's content signature on
-        this device and starts at the tuned point: tuned `steps` applies
-        when the caller left steps=1 AND the program is reader-fed (an
-        explicit-feed program would replay the same batch K times — a
-        semantic change, so it is never auto-applied), the recorded
-        fetch_reduce rides along when the caller left the default
-        'stack' (so fetches keep single-step shape instead of a
-        surprise leading-K axis), and a tuned multistep_unroll
-        overrides the platform default for the lowered loop. No
-        recorded config = unchanged behavior.
 
         timeout=SECONDS arms the hang watchdog (None = off, the default,
         zero overhead): the whole dispatch — io pre-pass, compile if any,
@@ -694,20 +680,18 @@ class Executor(object):
             return self._run_impl(program, feed, fetch_list, scope,
                                   return_numpy, use_program_cache, steps,
                                   fetch_reduce, validate,
-                                  apply_tuned=apply_tuned,
                                   prefetch=prefetch)
         return dispatch_with_deadline(
             lambda cancelled, info: self._run_impl(
                 program, feed, fetch_list, scope, return_numpy,
                 use_program_cache, steps, fetch_reduce, validate,
                 cancelled=cancelled, info=info, sync=True,
-                apply_tuned=apply_tuned, prefetch=prefetch),
+                prefetch=prefetch),
             timeout, "Executor.run dispatch")
 
     def _run_impl(self, program, feed, fetch_list, scope, return_numpy,
                   use_program_cache, steps, fetch_reduce, validate,
-                  cancelled=None, info=None, sync=False,
-                  apply_tuned=False, prefetch=False):
+                  cancelled=None, info=None, sync=False, prefetch=False):
         # one trace per training step (ARCHITECTURE.md §24), via the
         # executors' ONE shared wrapper (core/dispatch.run_step_traced):
         # the root span lives on THIS thread — in watchdog mode that is
@@ -720,11 +704,11 @@ class Executor(object):
             lambda tspan: self._run_traced(
                 program, feed, fetch_list, scope, return_numpy,
                 use_program_cache, steps, fetch_reduce, validate,
-                cancelled, info, sync, apply_tuned, prefetch, tspan))
+                cancelled, info, sync, prefetch, tspan))
 
     def _run_traced(self, program, feed, fetch_list, scope, return_numpy,
                     use_program_cache, steps, fetch_reduce, validate,
-                    cancelled, info, sync, apply_tuned, prefetch, tspan):
+                    cancelled, info, sync, prefetch, tspan):
         if program is None:
             program = default_main_program()
         feed = feed or {}
@@ -735,17 +719,6 @@ class Executor(object):
             raise ValueError("steps must be >= 1, got %r" % (steps,))
         tspan.set(program=str(program._uid),
                   version=int(program._version), steps=steps)
-        tuned_unroll = None
-        if apply_tuned:
-            from .. import tuning
-            tkey = (program._uid, program._version)
-            if tkey not in self._tuned:
-                self._tuned[tkey] = tuning.lookup_program(
-                    program, self.place.device())
-            cfg = self._tuned[tkey]
-            if cfg is not None:
-                steps, fetch_reduce, tuned_unroll = tuning.apply_to_run(
-                    cfg, program, steps, fetch_reduce)
         if fetch_reduce not in lowering.FETCH_REDUCE_POLICIES:
             raise ValueError("fetch_reduce must be one of %r, got %r"
                              % (lowering.FETCH_REDUCE_POLICIES, fetch_reduce))
@@ -794,8 +767,6 @@ class Executor(object):
         from .lowering import trace_env_key
         unroll = lowering.resolve_multistep_unroll(
             self.place.device().platform) if steps > 1 else False
-        if tuned_unroll is not None and steps > 1:
-            unroll = tuned_unroll
         multi_sig = (steps, fetch_reduce if steps > 1 else None, unroll,
                      tuple(sorted(stacked_names)))
         key = (program._uid, program._version,

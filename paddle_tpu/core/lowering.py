@@ -62,24 +62,21 @@ def trace_env_key():
     silently serve the other configuration's compiled fn.
 
     Current flags: FLAGS_conv_layout (conv/pool compute layout),
-    the resolved flash crossover (kernel_config.flash_min_seq: env pin
-    -> tuned store entry -> default), FLAGS_remat_segment_len
-    (segment-remat tuning knob), the raw PADDLE_TPU_PALLAS env string —
-    the RAW string, not pallas_on(): that helper also reads the
+    the flash crossover (kernel_config.flash_min_seq:
+    FLAGS_flash_min_seq, else the constant), FLAGS_remat_segment_len
+    (segment-remat tuning knob) and the raw PADDLE_TPU_PALLAS env string
+    — the RAW string, not pallas_on(): that helper also reads the
     dispatch platform, which is fixed per executor (and in the AOT key
     through the device), so the env string alone captures everything
-    that can change between runs of one executor — and
-    kernel_config.kernel_env_key(), the digest of every tuned
-    kernel-tile store entry in effect: the per-shape block knobs are
-    read at trace time inside the op lowerings, so recording a tuned
-    tile must re-key the jit caches and the AOT compile cache exactly
-    like flipping any other trace-time flag. When adding a trace-time
-    flag, add its resolved value HERE."""
+    that can change between runs of one executor. A function of the
+    environment and the jax config only: both executors call it on every
+    run, and it touches no file. When adding a trace-time flag, add its
+    resolved value HERE."""
     import os
-    from ..ops.kernel_config import flash_min_seq, kernel_env_key
+    from ..ops.kernel_config import flash_min_seq
     from ..ops.nn_ops import _conv_layout
     return (_conv_layout(), flash_min_seq(), remat_segment_len_flag(),
-            os.environ.get("PADDLE_TPU_PALLAS", ""), kernel_env_key(),
+            os.environ.get("PADDLE_TPU_PALLAS", ""),
             # the PRNG formulation is traced into every random op; the
             # package __init__ pins it partitionable, so this entry's
             # real job is re-keying AOT artifacts serialized under the

@@ -68,17 +68,22 @@ def write_bytes_fsync(path, data):
 
 
 def atomic_write_json(path, obj, fsync=False, **dump_kw):
-    """Publish a JSON document atomically: serialize, write to a
-    pid-suffixed tmp sibling, one os.replace. Readers never see a torn
-    document. fsync=True adds the write_bytes_fsync durability step for
-    documents that must survive power loss (the cluster plan); liveness
-    signals (heartbeats, fired every fraction of a second) skip it.
-    ONE implementation for every tmp+replace JSON writer so the
-    atomicity discipline can't drift per copy."""
+    """Publish a JSON document atomically: serialize, write to a tmp
+    sibling named by pid and thread, one os.replace. Readers never see a
+    torn document, also when two threads of one process publish the same
+    path (a heartbeat's beat thread and an update() from the training
+    loop: with one tmp file a process, the first replace would publish
+    the second writer's half-written bytes). fsync=True adds the
+    write_bytes_fsync durability step for documents that must survive
+    power loss (the cluster plan); liveness signals (heartbeats, fired
+    every fraction of a second) skip it. ONE implementation for every
+    tmp+replace JSON writer so the atomicity discipline can't drift per
+    copy."""
     import json
     import os
+    import threading
     data = json.dumps(obj, **dump_kw).encode("utf-8")
-    tmp = "%s.tmp.%d" % (path, os.getpid())
+    tmp = "%s.tmp.%d.%d" % (path, os.getpid(), threading.get_ident())
     if fsync:
         write_bytes_fsync(tmp, data)
     else:

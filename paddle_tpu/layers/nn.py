@@ -612,7 +612,7 @@ def autoincreased_step_counter(counter_name=None, begin=1, step=1):
 
 
 def fused_attention(q, k, v, causal=False, scale=None, kv_len=None,
-                    block_q=None, block_k=None, sp_impl="ring", name=None):
+                    sp_impl="ring", name=None):
     """Flash attention over [B, T, H, D] q/k/v (TPU-native addition — the
     reference era built attention from matmul+softmax ops; this is the
     fused pallas path, see ops/pallas_kernels.py). kv_len: optional [B]
@@ -638,11 +638,6 @@ def fused_attention(q, k, v, causal=False, scale=None, kv_len=None,
         outputs={"Out": [out]},
         attrs={"causal": bool(causal),
                "scale": None if scale is None else float(scale),
-               # None = unpinned: the trace-time dispatch resolves tiles
-               # from kernel_config (per-shape tuned table; defaults =
-               # the old 128/128 literals). An explicit int here pins.
-               "block_q": None if block_q is None else int(block_q),
-               "block_k": None if block_k is None else int(block_k),
                "sp_impl": str(sp_impl)})
     if q.shape is not None:
         out.shape = tuple(q.shape)

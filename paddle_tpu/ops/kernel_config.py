@@ -1,73 +1,55 @@
-"""One owner of the kernel-layer dispatch configuration.
+"""Which kernel runs for an op, and at which tile: the one module that
+decides, from what the code can observe. Read top to bottom:
 
-Every pallas fast path used to read its own env flag and run at
-hard-coded block sizes (`block_q=128` literals in nn_ops, `block_n=8`
-in pallas_kernels) with a single measured-once crossover
-(FLAGS_flash_min_seq).  This module centralizes all three surfaces:
+  DEFAULT_TILES            the one table a block size is written in
+  DEFAULT_FLASH_MIN_SEQ    the flash-or-dense query length
+  dispatch_device/platform what the traced computation runs on
+  pallas_explicit(op)      PADDLE_TPU_PALLAS, parsed in one place
+  pallas_on(op)            the explicit setting, else "on a TPU"
+  flash_min_seq()          FLAGS_flash_min_seq if set, else the constant
+  flash_at(q_len)          the one flash-or-dense rule
 
-* **Gating** — `pallas_explicit()` / `pallas_on(op)` parse
-  PADDLE_TPU_PALLAS once, in one place.  Accepted forms:
-    - unset/""          : per-op default (DEFAULT_ON when the program
-                          dispatches to a TPU, off on CPU)
+PADDLE_TPU_PALLAS and FLAGS_flash_min_seq are how the tests and
+chip_smoke.py force a path (an interpret-mode kernel on the CPU, flash
+below the crossover); no model, tool or benchmark cell sets either.
+Accepted forms of PADDLE_TPU_PALLAS:
+    - unset/""          : every kernel on when the program dispatches
+                          to a TPU, off on the CPU
     - "0"/"false"       : every pallas path off
     - "1"/"true"        : every pallas path on (interpret mode on CPU)
     - "attn,xent"       : allowlist — exactly the named ops on, the
-                          rest off.  Unknown names raise LOUDLY (the
-                          FLAGS_conv_layout discipline: a typo must not
-                          silently run the other configuration).
-  Op names: attn, xent, ln, lstm, seq (KERNEL_OPS).  Exception: for
-  'attn' the flag is an opt-OUT only — fused_attention's positive
-  dispatch is always the flash_min_seq() crossover (enabling 'attn'
-  does not force flash below the crossover; pin FLAGS_flash_min_seq=0
-  for that, as the kernel-coverage tests do).
+                          rest off.  Unknown names raise LOUDLY (a typo
+                          must not silently run the other path).
+Op names: attn, xent, ln, lstm, seq (KERNEL_OPS).  For 'attn' the flag
+is an opt-OUT only: fused_attention's positive dispatch is always the
+flash_at() rule, so enabling 'attn' does not force flash below the
+crossover (pin FLAGS_flash_min_seq=0 for that).
 
-* **Default tiles** — DEFAULT_TILES is the one shared table the
-  per-shape candidate grids are built from; the old literals live here
-  and ONLY here.
-
-* **Tuned tiles** — `tiles_for(op, dim)` consults the TuningStore for
-  a per-(op, shape-bucket, device_kind) entry recorded by
-  `tuning.tune_kernels(...)` and overlays it on the defaults.  Lookups
-  happen at TRACE time (inside the op lowering), so a store entry
-  changes the traced computation: `kernel_env_key()` — a digest of
-  every kernel:* store entry in effect — joins
-  `core.lowering.trace_env_key()`, which both executors' jit caches and
-  the AOT compile cache key on.  Writing a tuned entry therefore
-  re-keys the compiled artifacts instead of silently serving the old
-  tiles (regression-tested in test_kernel_tuning.py).
-
-* **Crossover** — `flash_min_seq()` resolves the flash-vs-dense
-  attention dispatch point: FLAGS_flash_min_seq when set (0 forces
-  flash always), else a tuned `flash_min_seq` knob recorded under the
-  CROSSOVER_SIGNATURE store entry for this device, else the measured
-  v5e default (1024).
+Both variables are read at trace time, so both are part of
+core.lowering.trace_env_key(), which the executors' jit caches and the
+AOT compile cache key on.
 """
-import hashlib
 import os
 
 import jax
 
 __all__ = [
     "KERNEL_OPS", "DEFAULT_TILES", "DEFAULT_FLASH_MIN_SEQ",
-    "DEFAULT_ON", "CROSSOVER_SIGNATURE", "dispatch_device",
-    "dispatch_platform",
-    "pallas_explicit", "pallas_on",
-    "flash_min_seq", "flash_at", "shape_bucket", "kernel_signature",
-    "tiles_for", "kernel_env_key", "local_device_key",
+    "dispatch_device", "dispatch_platform", "pallas_explicit",
+    "pallas_on", "flash_min_seq", "flash_at",
 ]
 
-# the one shared default table — the pre-tuning literals.  Keys are the
-# knob names the TuningStore accepts (store.KNOWN_KNOBS); values are
-# what every dispatch uses when no tuned entry exists for its
-# (op, shape-bucket, device_kind).  block_b=0 means "the whole batch in
-# one block" (the fused LSTM kernel's pre-knob behavior).  "attn" is what a
-# sweep of block_q in {128, 256, 512, 1024} x block_k in {128, 256, 512,
-# 1024} on the v5e chose for bf16 inputs at the shapes the benchmark's
-# cells run ([64, 2048, 64] unmasked and causal, [64, 4096, 128] causal;
-# PERF.md section 6, PR 27): at 128 x 128 the forward kernel takes 2.9-4.1
-# times as long, dK/dV 2.2-2.6 and dQ 2.5-3.2 times, and the best pair is
-# the same at D=64 and D=128, so the default stays one table entry and no
-# function of the shape.
+# Every Pallas wrapper in pallas_kernels.py resolves a block argument
+# left None from this table; the lowering rules pass none.  block_b=0
+# means "the whole batch in one block".  "attn" is what a sweep of
+# block_q in {128, 256, 512, 1024} x block_k in {128, 256, 512, 1024} on
+# the v5e chose for bf16 inputs at the shapes the benchmark's cells run
+# ([64, 2048, 64] unmasked and causal, [64, 4096, 128] causal; PERF.md
+# section 6, PR 27): at 128 x 128 the forward kernel takes 2.9-4.1 times
+# as long, dK/dV 2.2-2.6 and dQ 2.5-3.2 times, and the best pair is the
+# same at D=64 and D=128, so the tile is one entry and no function of
+# the shape.  The 8-row tiles of "xent", "ln" and "seq" have not been
+# swept on the chip (ROADMAP A3).
 DEFAULT_TILES = {
     "attn": {"block_q": 512, "block_k": 512},
     "xent": {"block_n": 8},
@@ -76,16 +58,11 @@ DEFAULT_TILES = {
     "seq": {"block_n": 8},
 }
 KERNEL_OPS = frozenset(DEFAULT_TILES)
-# default-on dispatch on a TPU, per op: the table a kernel leaves when
-# Mosaic refuses it on the chip (chip_smoke.py phase C compiles every
-# family and compares it with its XLA path), so the op takes the XLA
-# path there by table rather than failing at first use
-DEFAULT_ON = {"attn": True, "xent": True, "ln": True, "lstm": True,
-              "seq": True}
+# Dense attention below this query length, flash at and above it.  The
+# cells sit on both sides (T=256 dense; T=2048 and T=4096 flash).  The
+# value predates PR 27's kernels, which are 3-4 times faster, and has
+# not been measured against them (ROADMAP A1 (c)).
 DEFAULT_FLASH_MIN_SEQ = 1024
-# store signature for the per-device flash-vs-dense crossover knob
-# (shape-independent: it IS the shape rule)
-CROSSOVER_SIGNATURE = "kernel:flash_crossover"
 
 
 def pallas_explicit(op):
@@ -124,176 +101,55 @@ def dispatch_device():
 
 def dispatch_platform():
     """Decides between Mosaic and the interpreter, and whether an op's
-    default-on kernel applies."""
+    kernel applies when nothing is set."""
     return dispatch_device().platform
 
 
 def pallas_on(op):
-    """Is the pallas fast path enabled for `op`?  Explicit flag wins;
-    default is DEFAULT_ON[op] exactly when dispatching to a real TPU
-    (interpret-mode kernels on CPU are a test/debug path, not a
-    default).  `fused_attention` is the one exception: its default
-    dispatch is the flash_min_seq() shape rule, so it consults
-    pallas_explicit('attn') directly and treats None as 'apply the
-    crossover'."""
+    """Is the pallas fast path enabled for `op`?  The explicit flag
+    wins; with nothing set a kernel runs exactly when dispatching to a
+    real TPU (an interpret-mode kernel on the CPU is a test path, not a
+    default).  `fused_attention` does not ask here: its rule is
+    flash_at()."""
     explicit = pallas_explicit(op)
     if explicit is not None:
         return explicit
-    return DEFAULT_ON[op] and dispatch_platform() == "tpu"
-
-
-def shape_bucket(dim):
-    """Power-of-two bucket (>= 8) of an op's VMEM-pressure dimension —
-    T for attention and sequence ops, the row width (vocab / feature
-    dim) for xent/ln, the hidden size for the LSTM kernel.  Tuned
-    entries are recorded and looked up per bucket so one sweep covers a
-    band of real shapes without an entry per literal dim."""
-    dim = max(8, int(dim))
-    b = 8
-    while b < dim:
-        b *= 2
-    return b
-
-
-def kernel_signature(op, bucket):
-    """TuningStore signature for a kernel-knob entry."""
-    return "kernel:%s/b%d" % (op, int(bucket))
-
-
-def local_device_key():
-    """The store device key for the device the traced computation
-    dispatches to (tuned tiles are per device generation)."""
-    from ..tuning.store import device_key
-    return device_key(dispatch_device())
-
-
-def _store():
-    from ..tuning.store import TuningStore
-    return TuningStore()
-
-
-def tiles_for(op, dim):
-    """Resolved block knobs for `op` at VMEM-pressure dimension `dim`:
-    DEFAULT_TILES overlaid with the tuned entry for
-    (kernel:<op>/b<bucket>, device_kind), if recorded.  Called at trace
-    time only — one store read per compiled shape, not per dispatch."""
-    if op not in DEFAULT_TILES:
-        raise KeyError("unknown kernel op %r (known: %s)"
-                       % (op, sorted(DEFAULT_TILES)))
-    knobs = dict(DEFAULT_TILES[op])
-    st = _store()
-    if st.root is not None:
-        entry = st.get(kernel_signature(op, shape_bucket(dim)),
-                       local_device_key())
-        if entry is not None:
-            for k in knobs:
-                if k in entry["knobs"]:
-                    knobs[k] = int(entry["knobs"][k])
-    return knobs
-
-
-_crossover_cache = {}  # root -> (dir_mtime_ns, resolved value)
+    return dispatch_platform() == "tpu"
 
 
 def flash_min_seq():
-    """Flash-vs-dense attention dispatch crossover.  Resolution order:
-    FLAGS_flash_min_seq (explicit env pin; 0 forces flash always) ->
-    tuned `flash_min_seq` knob for this device (CROSSOVER_SIGNATURE)
-    -> 1024 (the round-4 v5e measurement: dense wins at 256, flash at
-    2048).  Single owner of the read: the fused_attention dispatch and
-    trace_env_key() both resolve through here.  The store lookup sits
-    on trace_env_key()'s per-run path, so it caches on the store dir's
-    mtime_ns like kernel_env_key (one os.stat per run, not a JSON
-    parse)."""
+    """The flash-or-dense query length: FLAGS_flash_min_seq when set (0
+    forces flash at every length above 1), else
+    DEFAULT_FLASH_MIN_SEQ."""
     env = os.environ.get("FLAGS_flash_min_seq", "")
     if env:
         try:
             return int(env)
         except ValueError:
             return DEFAULT_FLASH_MIN_SEQ
-    st = _store()
-    if st.root is None or not os.path.isdir(st.root):
-        return DEFAULT_FLASH_MIN_SEQ
-    try:
-        stamp = os.stat(st.root).st_mtime_ns
-    except OSError:
-        return DEFAULT_FLASH_MIN_SEQ
-    cached = _crossover_cache.get(st.root)
-    if cached is not None and cached[0] == stamp:
-        return cached[1]
-    value = DEFAULT_FLASH_MIN_SEQ
-    entry = st.get(CROSSOVER_SIGNATURE, local_device_key())
-    if entry is not None and "flash_min_seq" in entry["knobs"]:
-        value = int(entry["knobs"]["flash_min_seq"])
-    _crossover_cache[st.root] = (stamp, value)
-    return value
+    return DEFAULT_FLASH_MIN_SEQ
 
 
 def flash_at(q_len):
-    """The one flash-vs-dense decision for fused_attention at query
+    """The one flash-or-dense decision for fused_attention at query
     length `q_len` (the traced q.shape[1]; None when symbolic).
 
-    Decode-shaped dispatch is STRUCTURAL, not a crossover knob:
-    at q_len <= 1 (one query row per step — the decode-serving shape)
-    the flash kernel's block_q tiling is wrong by construction (a
-    128-row q block for a 1-row query; the kernel grid degenerates and
-    the crossover knob was never measured there), so the dense path is
-    taken unconditionally — EVEN when FLAGS_flash_min_seq=0 pins
-    "flash always" for the coverage tests.  Above that:
+      * q_len <= 1 (one query row a step, the decode-serving shape) ->
+        dense, even under FLAGS_flash_min_seq=0: a block_q-row tile for
+        a 1-row query is wrong by construction, not a matter of where
+        the crossover sits;
+      * PADDLE_TPU_PALLAS opts 'attn' out (=0, or an allowlist without
+        it) -> dense at every length;
+      * q_len is None -> flash (the crossover cannot be evaluated on a
+        symbolic length);
+      * otherwise flash exactly when q_len >= flash_min_seq().
 
-      * explicit PADDLE_TPU_PALLAS opt-out (=0 or allowlist without
-        'attn'), or DEFAULT_ON['attn'] off with the flag unset ->
-        dense, regardless of length;
-      * q_len >= flash_min_seq() -> flash;
-      * otherwise dense.
-
-    q_len=None (symbolic trace dim) keeps the historical behavior:
-    not decode-shaped, crossover can't be evaluated, flash unless
-    explicitly opted out."""
+    The platform is not asked: off a TPU the flash kernel runs in
+    interpret mode, and the dispatch stays one function of the shape."""
     if q_len is not None and q_len <= 1:
         return False
-    explicit = pallas_explicit("attn")
-    if explicit is False or (explicit is None and not DEFAULT_ON["attn"]):
+    if pallas_explicit("attn") is False:
         return False
     if q_len is None:
         return True
     return q_len >= flash_min_seq()
-
-
-# ---------------------------------------------------------------------------
-# trace-env keying: tuned tiles are trace-time state
-# ---------------------------------------------------------------------------
-
-_digest_cache = {}  # (root) -> (dir_mtime_ns, digest)
-
-
-def kernel_env_key():
-    """Digest of every kernel:* TuningStore entry in effect — joined
-    into core.lowering.trace_env_key() so the jit caches AND the AOT
-    compile cache re-key when a tuned tile changes.  Cached on the
-    store directory's mtime_ns: steady state costs one os.stat per
-    executor run; a put() (atomic os.replace into the dir) bumps the
-    mtime and invalidates."""
-    from ..tuning.store import resolve_store_dir
-    root = resolve_store_dir()
-    if not root or not os.path.isdir(root):
-        return ""
-    try:
-        stamp = os.stat(root).st_mtime_ns
-    except OSError:
-        return ""
-    cached = _digest_cache.get(root)
-    if cached is not None and cached[0] == stamp:
-        return cached[1]
-    h = hashlib.sha256()
-    st = _store()
-    for record in st.entries():
-        sig = record.get("signature", "")
-        if not isinstance(sig, str) or not sig.startswith("kernel:"):
-            continue
-        h.update(repr((sig, record.get("device_key"),
-                       sorted((record.get("knobs") or {}).items())))
-                 .encode("utf-8"))
-    digest = h.hexdigest()[:16]
-    _digest_cache[root] = (stamp, digest)
-    return digest

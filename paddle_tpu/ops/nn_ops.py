@@ -20,6 +20,7 @@ from jax import lax
 
 from ..core.registry import register, single
 from ..core.utils import pair as _pair
+from .kernel_config import flash_at, pallas_on
 
 
 def _out(x):
@@ -231,14 +232,10 @@ def _layer_norm(ctx, ins, attrs):
     eps = attrs.get("epsilon", 1e-5)
     begin = attrs.get("begin_norm_axis", 1)
     lead = int(np.prod(x.shape[:begin]))
-    if scale is not None and bias is not None and _pallas_enabled("ln"):
+    if scale is not None and bias is not None and pallas_on("ln"):
         from . import pallas_kernels as pk
-        from .kernel_config import tiles_for
-        d_norm = int(np.prod(x.shape[begin:]))
         y, mean, var = pk.layer_norm(x.reshape(lead, -1), scale.reshape(-1),
-                                     bias.reshape(-1), eps=eps,
-                                     block_n=tiles_for("ln",
-                                                       d_norm)["block_n"])
+                                     bias.reshape(-1), eps=eps)
         return {"Y": [y.reshape(x.shape).astype(x.dtype)],
                 "Mean": [mean], "Variance": [var]}
     x2 = x.reshape(lead, -1).astype(jnp.float32)
@@ -369,37 +366,17 @@ def _cross_entropy(ctx, ins, attrs):
     return {"Y": [loss]}
 
 
-def _pallas_enabled(op="xent"):
-    """Per-op pallas gating — delegates to ops.kernel_config.pallas_on,
-    the ONE owner of the PADDLE_TPU_PALLAS parse (0/1 and the
-    per-op allowlist form, e.g. PADDLE_TPU_PALLAS=attn,xent,ln)."""
-    from .kernel_config import pallas_on
-    return pallas_on(op)
-
-
-def _flash_min_seq():
-    """Flash-vs-dense attention dispatch crossover — delegates to
-    ops.kernel_config.flash_min_seq (env pin -> tuned store entry ->
-    1024 default). Kept as a name because trace_env_key() historically
-    imported it from here."""
-    from .kernel_config import flash_min_seq
-    return flash_min_seq()
-
-
 @register("softmax_with_cross_entropy", calls_pallas=True)
 def _softmax_xent(ctx, ins, attrs):
     logits = single(ins, "Logits")
     label = single(ins, "Label")
     if not attrs.get("soft_label", False) and logits.ndim == 2 \
-            and _pallas_enabled("xent"):
+            and pallas_on("xent"):
         # fused pallas path: loss + logsumexp in one VMEM pass, softmax
         # never materialized in the forward (the dense Softmax slot below
         # is DCE'd by XLA unless the program actually consumes it)
         from . import pallas_kernels as pk
-        from .kernel_config import tiles_for
-        loss = pk.softmax_xent(
-            logits, label.reshape(-1),
-            block_n=tiles_for("xent", logits.shape[-1])["block_n"])
+        loss = pk.softmax_xent(logits, label.reshape(-1))
         logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
         return {"Softmax": [jnp.exp(logp).astype(logits.dtype)],
                 "Loss": [loss.astype(logits.dtype)]}
@@ -441,34 +418,18 @@ def _fused_attention(ctx, ins, attrs):
         from ..parallel.ring_attention import ring_attention_sharded
         return _out(ring_attention_sharded(
             q, k, v, mesh, causal=causal, scale=scale, kv_len=kv_len))
-    # Per-shape dispatch (round-4 measurements, real v5e: dense XLA
-    # attention beat the flash kernel at T=256 — 130.0k vs 102.0k tok/s —
-    # while flash was 12.1x dense at T=2048): short sequences take the
-    # dense einsum path, long ones the pallas kernel. Crossover from
-    # kernel_config.flash_min_seq (FLAGS_flash_min_seq pin -> tuned
-    # store entry -> 1024 default; 0 forces flash always — used by
-    # kernel-coverage tests and the block-tune sweep). An explicit
-    # PADDLE_TPU_PALLAS opt-out (=0, or an allowlist without 'attn')
-    # forces the dense path regardless of length.
-    # kernel_config.flash_at owns the decision, including the structural
-    # decode rule: q_len <= 1 (decode serving steps one token at a time)
-    # is dense by construction — no flash tiling exists for a 1-row q
-    # block, so not even FLAGS_flash_min_seq=0 forces the kernel there.
-    from .kernel_config import flash_at, tiles_for
-    t = q.shape[1]
-    if not flash_at(t):
+    # kernel_config.flash_at owns the flash-or-dense decision: dense
+    # below the crossover, at one query row (decode), and when
+    # PADDLE_TPU_PALLAS opts 'attn' out; the Pallas kernel otherwise, at
+    # the tile kernel_config.DEFAULT_TILES holds.
+    if not flash_at(q.shape[1]):
         from ..parallel.ring_attention import attention_reference
         return _out(attention_reference(
             q, k, v, causal=causal, scale=scale,
             kv_len=kv_len).astype(q.dtype))
     from . import pallas_kernels as pk
-    # explicit layer attrs pin the tiles; otherwise the per-shape tuned
-    # table (defaults = the old 128/128 literals) decides
-    tiles = tiles_for("attn", t if t else 128)
     out = pk.flash_attention(
-        q, k, v, causal=causal, scale=scale, kv_len=kv_len,
-        block_q=attrs.get("block_q") or tiles["block_q"],
-        block_k=attrs.get("block_k") or tiles["block_k"])
+        q, k, v, causal=causal, scale=scale, kv_len=kv_len)
     return _out(out)
 
 

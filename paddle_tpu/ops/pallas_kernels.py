@@ -62,6 +62,13 @@ def _interpret_default():
     return dispatch_platform() != "tpu"
 
 
+def _tile(op, knob, given):
+    """A wrapper's block argument: what the caller gave (a sweep, a kernel
+    test), else the entry of kernel_config.DEFAULT_TILES, which is what
+    every lowering rule runs at."""
+    return DEFAULT_TILES[op][knob] if given is None else int(given)
+
+
 def _vmem_spec(*args, **kwargs):
     kwargs.setdefault("memory_space", pltpu.VMEM)
     return pl.BlockSpec(*args, **kwargs)
@@ -444,9 +451,9 @@ def flash_attention(q, k, v, causal=False, scale=None, kv_len=None,
     b, t, h, d = q.shape
     if scale is None:
         scale = 1.0 / float(np.sqrt(d))
-    block_q = max(8, min(block_q or DEFAULT_TILES["attn"]["block_q"],
+    block_q = max(8, min(_tile("attn", "block_q", block_q),
                          int(-(-t // 8) * 8)))
-    block_k = max(8, min(block_k or DEFAULT_TILES["attn"]["block_k"],
+    block_k = max(8, min(_tile("attn", "block_k", block_k),
                          int(-(-t // 8) * 8)))
     if kv_len is None:
         lens = jnp.full((b * h,), t, jnp.int32)
@@ -522,13 +529,13 @@ def _xent_core_bwd(block_n, interpret, res, g):
 _xent_core.defvjp(_xent_core_fwd, _xent_core_bwd)
 
 
-def softmax_xent(logits, labels, block_n=8, interpret=None):
+def softmax_xent(logits, labels, block_n=None, interpret=None):
     """Fused log-softmax + NLL. logits [N, V], labels [N] (or [N,1]) int.
     Returns loss [N, 1] float32. Differentiable (custom_vjp)."""
     if interpret is None:
         interpret = _interpret_default()
-    return _xent_core(logits, labels.reshape(-1), int(block_n),
-                      bool(interpret))
+    return _xent_core(logits, labels.reshape(-1),
+                      _tile("xent", "block_n", block_n), bool(interpret))
 
 
 # ---------------------------------------------------------------------------
@@ -615,11 +622,11 @@ def _pad_rows(a, rows):
 
 
 def _resolve_block_b(b, block_b):
-    """(block, padded_b) for a batch-blocked kernel. block_b=0 (the
-    default-table value) = the whole batch in one block; both forms pad
-    b up to a multiple of 8 (the f32 sublane tile)."""
-    if block_b and int(block_b) > 0:
-        blk = max(8, int(block_b))
+    """(block, padded_b) for a batch-blocked kernel. block_b=0 = the
+    whole batch in one block; both forms pad b up to a multiple of 8 (the
+    f32 sublane tile)."""
+    if block_b > 0:
+        blk = max(8, block_b)
     else:
         blk = int(-(-b // 8) * 8)
     return blk, int(-(-b // blk) * blk)
@@ -763,7 +770,7 @@ def _lstm_seq_core_bwd(block_b, interpret, res, g):
 _lstm_seq_core.defvjp(_lstm_seq_core_fwd, _lstm_seq_core_bwd)
 
 
-def fused_lstm(x, w, gate_bias, h0, c0, xlen, reverse=False, block_b=0,
+def fused_lstm(x, w, gate_bias, h0, c0, xlen, reverse=False, block_b=None,
                interpret=None):
     """Fused-gate dynamic LSTM over the padded-dense layout: x [B, T, 4D]
     (pre-projected gate inputs), w [D, 4D] recurrent weight, gate_bias
@@ -790,7 +797,8 @@ def fused_lstm(x, w, gate_bias, h0, c0, xlen, reverse=False, block_b=0,
         else c0.astype(jnp.float32)
     hs, cs = _lstm_seq_core(xs, mask, w.astype(jnp.float32),
                             gate_bias.reshape(-1).astype(jnp.float32),
-                            h0, c0, int(block_b), bool(interpret))
+                            h0, c0, _tile("lstm", "block_b", block_b),
+                            bool(interpret))
     if reverse:
         hs, cs = hs[::-1], cs[::-1]
     return (jnp.swapaxes(hs, 0, 1).astype(x.dtype),
@@ -931,7 +939,7 @@ _lstmp_seq_core.defvjp(_lstmp_seq_core_fwd, _lstmp_seq_core_bwd)
 
 
 def fused_lstmp(x, w, w_proj, gate_bias, r0, c0, xlen, reverse=False,
-                block_b=0, interpret=None):
+                block_b=None, interpret=None):
     """Fused LSTMP (recurrent projection): x [B, T, 4D], w [P, 4D],
     w_proj [D, P], r0 [B, P] the PROJECTED initial state (the caller
     projects h0 — its grads flow through that projection's own vjp),
@@ -953,7 +961,8 @@ def fused_lstmp(x, w, w_proj, gate_bias, r0, c0, xlen, reverse=False,
     rs, cs = _lstmp_seq_core(xs, mask, w.astype(jnp.float32),
                              w_proj.astype(jnp.float32),
                              gate_bias.reshape(-1).astype(jnp.float32),
-                             r0.astype(jnp.float32), c0, int(block_b),
+                             r0.astype(jnp.float32), c0,
+                             _tile("lstm", "block_b", block_b),
                              bool(interpret))
     if reverse:
         rs, cs = rs[::-1], cs[::-1]
@@ -1020,7 +1029,7 @@ _masked_softmax_core.defvjp(_masked_softmax_core_fwd,
                             _masked_softmax_core_bwd)
 
 
-def masked_softmax(x, xlen, block_n=8, interpret=None):
+def masked_softmax(x, xlen, block_n=None, interpret=None):
     """Sequence softmax over the time dim of x [B, T] with true lengths
     xlen [B]: positions >= xlen contribute nothing and get 0. One VMEM
     pass per row block; differentiable (custom_vjp from the saved
@@ -1029,7 +1038,8 @@ def masked_softmax(x, xlen, block_n=8, interpret=None):
     if interpret is None:
         interpret = _interpret_default()
     return _masked_softmax_core(x, jnp.asarray(xlen, jnp.int32),
-                                int(block_n), bool(interpret))
+                                _tile("seq", "block_n", block_n),
+                                bool(interpret))
 
 
 def _masked_pool_kernel(x_ref, len_ref, o_ref, *, ptype):
@@ -1098,7 +1108,7 @@ def _masked_pool_core_bwd(ptype, block_n, interpret, res, g):
 _masked_pool_core.defvjp(_masked_pool_core_fwd, _masked_pool_core_bwd)
 
 
-def masked_pool(x, xlen, ptype="AVERAGE", block_n=8, interpret=None):
+def masked_pool(x, xlen, ptype="AVERAGE", block_n=None, interpret=None):
     """Masked sequence pool over the time dim of x [B, T, F]:
     SUM / AVERAGE / SQRT (the linear pools — MAX/LAST/FIRST keep the
     dense path, their grads are selection-shaped). Returns [B, F];
@@ -1109,10 +1119,11 @@ def masked_pool(x, xlen, ptype="AVERAGE", block_n=8, interpret=None):
     if interpret is None:
         interpret = _interpret_default()
     return _masked_pool_core(x, jnp.asarray(xlen, jnp.int32), str(ptype),
-                             int(block_n), bool(interpret))
+                             _tile("seq", "block_n", block_n),
+                             bool(interpret))
 
 
-def layer_norm(x, scale, bias, eps=1e-5, block_n=8, interpret=None):
+def layer_norm(x, scale, bias, eps=1e-5, block_n=None, interpret=None):
     """Fused layer norm over the trailing dim of 2D x [N, D]; one VMEM pass
     computes y + the (mean, rstd) backward residuals. Differentiable
     (custom_vjp; dense backward — the fwd is the HBM-bound pass worth
@@ -1121,6 +1132,7 @@ def layer_norm(x, scale, bias, eps=1e-5, block_n=8, interpret=None):
     XLA DCEs when (as usual) nothing consumes them."""
     if interpret is None:
         interpret = _interpret_default()
-    y = _ln_core(x, scale, bias, float(eps), int(block_n), bool(interpret))
+    y = _ln_core(x, scale, bias, float(eps), _tile("ln", "block_n", block_n),
+                 bool(interpret))
     xf = x.astype(jnp.float32)
     return y, jnp.mean(xf, axis=-1), jnp.var(xf, axis=-1)

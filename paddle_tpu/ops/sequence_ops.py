@@ -47,13 +47,11 @@ def _sequence_pool(ctx, ins, attrs):
         # reduces (linear pools only — MAX/LAST/FIRST keep the dense
         # path). Feature dims flatten to one trailing axis.
         from . import pallas_kernels as pk
-        from .kernel_config import tiles_for
         b, t = x.shape[:2]
         feat = x.shape[2:]
         f = int(np.prod(feat)) if feat else 1
         out = pk.masked_pool(
-            x.reshape(b, t, f), xlen, ptype=ptype,
-            block_n=tiles_for("seq", t)["block_n"]).reshape((b,) + feat)
+            x.reshape(b, t, f), xlen, ptype=ptype).reshape((b,) + feat)
         return {"Out": [out.astype(x.dtype)]}
     m = _feat_mask(x, xlen)
     denom = jnp.maximum(xlen.astype(x.dtype), 1).reshape(
@@ -102,10 +100,7 @@ def _sequence_softmax(ctx, ins, attrs):
         # VMEM pass per row block (bit-exact vs the where-mask path:
         # masked lanes underflow exp to exactly 0 either way)
         from . import pallas_kernels as pk
-        from .kernel_config import tiles_for
-        out = pk.masked_softmax(
-            logits, xlen,
-            block_n=tiles_for("seq", logits.shape[1])["block_n"])
+        out = pk.masked_softmax(logits, xlen)
         if squeeze:
             out = out.reshape(x.shape)
         return {"Out": [out.astype(x.dtype)]}
@@ -351,10 +346,8 @@ def _lstm(ctx, ins, attrs):
         # the long tail keeps the scan): four gates + state update in
         # one VMEM pass per step, carried state resident in VMEM
         from . import pallas_kernels as pk
-        from .kernel_config import tiles_for
         hidden, cell = pk.fused_lstm(
-            x, w, bias.reshape(-1)[:4 * d], h0, c0, xlen,
-            reverse=is_rev, block_b=tiles_for("lstm", d)["block_b"])
+            x, w, bias.reshape(-1)[:4 * d], h0, c0, xlen, reverse=is_rev)
         return {"Hidden": [hidden], "Cell": [cell],
                 "BatchGate": [x], "BatchCellPreAct": [cell]}
 
@@ -445,7 +438,6 @@ def _lstmp(ctx, ins, attrs):
             and attrs.get("proj_activation", "tanh") == "tanh"
             and pallas_on("lstm")):
         from . import pallas_kernels as pk
-        from .kernel_config import tiles_for
         if h0 is not None:
             r0 = jnp.tanh(h0.astype(jnp.float32) @
                           w_proj.astype(jnp.float32))
@@ -453,7 +445,7 @@ def _lstmp(ctx, ins, attrs):
             r0 = jnp.zeros((b, p), jnp.float32)
         proj, cell = pk.fused_lstmp(
             x, w, w_proj, bias.reshape(-1)[:4 * d], r0, c0, xlen,
-            reverse=is_rev, block_b=tiles_for("lstm", d)["block_b"])
+            reverse=is_rev)
         return {"Projection": [proj], "Cell": [cell],
                 "BatchGate": [x], "BatchCellPreAct": [cell],
                 "BatchHidden": [cell], "OrderedP0": [r0.astype(x.dtype)]}

@@ -193,27 +193,30 @@ class HeartbeatMonitor(object):
         beats = read_heartbeats(self.cluster_dir)
         for wid, hb in beats.items():
             hb["age"] = max(0.0, now - float(hb.get("wall_time", 0.0)))
+            hb["pid_gone"] = self._pid_gone(hb)
             hb["alive"] = self._alive(hb)
         return beats
 
-    def _alive(self, hb):
-        if hb.get("status") in TERMINAL_STATUSES:
-            return True  # finished, not dead — staleness is expected
-        # same-host fast path: a SIGKILL'd worker is detected the
-        # instant its pid vanishes, not a heartbeat-timeout later. A
-        # zombie (dead but not yet reaped by its parent) still answers
-        # kill(pid, 0) — on Linux, /proc exposes the truth.
+    def _pid_gone(self, hb):
+        """Same-host fast path: a SIGKILL'd worker is detected the
+        instant its pid vanishes, not a heartbeat-timeout later. A
+        zombie (dead but not yet reaped by its parent) still answers
+        kill(pid, 0) — on Linux, /proc exposes the truth."""
         pid = hb.get("pid")
         if pid and hb.get("host") == self._host:
             try:
                 os.kill(int(pid), 0)
-                if _is_zombie(int(pid)):
-                    return False
+                return _is_zombie(int(pid))
             except ProcessLookupError:
-                return False
+                return True
             except OSError:
                 pass  # EPERM etc: alive under another uid
-        return hb["age"] <= self.timeout
+        return False
+
+    def _alive(self, hb):
+        if hb.get("status") in TERMINAL_STATUSES:
+            return True  # finished, not dead — staleness is expected
+        return not hb["pid_gone"] and hb["age"] <= self.timeout
 
     def fleet_view(self):
         """The fleet gauge rows derived from the heartbeats — ONE
@@ -222,7 +225,8 @@ class HeartbeatMonitor(object):
         once; never again): per worker the lifecycle status, liveness
         (the monitor's staleness/pid verdict), step cursor, steps
         behind the cohort's front-runner (None when the worker never
-        reported a step), plan generations, beat age, the metrics port
+        reported a step, or when nobody is left to be in front), plan
+        generations, beat age, the metrics port
         it published (if any), and the training-health fields
         (ARCHITECTURE.md §29): the worker's last sentinel status dict
         (z-scores, spike count), canary status dict, the fault repr a
@@ -230,15 +234,26 @@ class HeartbeatMonitor(object):
         conviction named — the WHY behind a fence, not just the
         that."""
         beats = self.poll()
-        # the front-runner is the furthest LIVE, still-participating
-        # worker: a dead worker's stale file (nothing ever deletes it)
-        # or a finished worker's terminal beat would otherwise pin
-        # `front` past a rollback forever and every healthy worker
-        # would read permanently behind
-        live_steps = [int(b.get("step", -1)) for b in beats.values()
-                      if int(b.get("step", -1)) >= 0 and b.get("alive")
-                      and b.get("status") not in TERMINAL_STATUSES]
-        front = max(live_steps) if live_steps else 0
+        # the front-runner is the furthest worker still taking part: it
+        # has reported a step, has not finished, its process is not gone,
+        # and its beat is no more than `timeout` older than the newest
+        # such beat. A dead worker's stale file (nothing ever deletes
+        # it) or a finished worker's terminal beat would otherwise pin
+        # `front` past a rollback forever and every healthy worker would
+        # read permanently behind. Staleness is measured against the
+        # fleet's newest beat and not against the reader's clock, so the
+        # lags are a function of the heartbeat files: a reader that
+        # arrives late (a status CLI that took seconds to start, a
+        # paused job) reads them as the fleet last stated them, the same
+        # as a collector that read at once. `alive` stays the reader's
+        # own verdict.
+        taking_part = [b for b in beats.values()
+                       if int(b.get("step", -1)) >= 0
+                       and b.get("status") not in TERMINAL_STATUSES
+                       and not b["pid_gone"]]
+        newest = min([b["age"] for b in taking_part], default=0.0)
+        front = max([int(b["step"]) for b in taking_part
+                     if b["age"] - newest <= self.timeout], default=None)
         rows = []
         for wid, b in sorted(beats.items()):
             step = int(b.get("step", -1))
@@ -248,7 +263,8 @@ class HeartbeatMonitor(object):
                 "alive": bool(b.get("alive")),
                 "step": step,
                 "steps_behind": (max(0, front - step)
-                                 if step >= 0 else None),
+                                 if step >= 0 and front is not None
+                                 else None),
                 "gen": int(b.get("gen", 0) or 0),
                 "gen_acked": int(b.get("gen_acked", 0) or 0),
                 "beat_age_s": float(b.get("age", 0.0)),

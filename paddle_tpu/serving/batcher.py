@@ -128,9 +128,12 @@ class RequestFuture(object):
         return self._value
 
 
-# dispatch this far ahead of a pending deadline: a batch released exactly
-# AT the deadline would lose the strict expiry check to scheduler jitter
-_DEADLINE_MARGIN_S = 1e-3
+# the share of a request's deadline budget (enqueue to deadline) it may be
+# held for coalescing. The rest is for being picked up: the worker's
+# wake-up, batch formation, the hand-off to the dispatch worker. Held to
+# within a millisecond of its deadline, a request on a loaded host woke
+# 5 ms late and was expired by the batcher's own wait.
+_DEADLINE_HOLD_SHARE = 0.5
 
 
 class _Request(object):
@@ -306,11 +309,12 @@ class Batcher(object):
             # coalescing window: anchored at the OLDEST pending request so
             # queue time is bounded by max_queue_delay even under trickle
             # arrivals; a full batch releases immediately. A pending
-            # DEADLINE inside the window caps it — a request whose
-            # deadline is shorter than max_queue_delay must be dispatched
-            # before it expires, not held for coalescing it can't afford
-            # (waiting the full window would 504 every such request under
-            # light load).
+            # DEADLINE inside the window caps it at _DEADLINE_HOLD_SHARE of
+            # that request's budget — a request whose deadline is shorter
+            # than max_queue_delay must be dispatched well before it
+            # expires, not held for coalescing it can't afford (waiting
+            # the full window would 504 every such request under light
+            # load).
             leave_at = self._queue[0].enqueued_at + self.max_queue_delay_s
             if not self.coalesce:
                 leave_at = self._queue[0].enqueued_at  # nothing to wait for
@@ -321,9 +325,10 @@ class Batcher(object):
                 wake_at = leave_at
                 if self._deadlined:  # only then is a scan needed at all
                     wake_at = min(
-                        [leave_at] + [r.deadline - _DEADLINE_MARGIN_S
-                                      for r in self._queue
-                                      if r.deadline is not None])
+                        [leave_at] + [
+                            r.enqueued_at + _DEADLINE_HOLD_SHARE
+                            * (r.deadline - r.enqueued_at)
+                            for r in self._queue if r.deadline is not None])
                 remaining = wake_at - time.monotonic()
                 if remaining <= 0:
                     break

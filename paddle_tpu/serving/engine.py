@@ -151,9 +151,8 @@ class InferenceEngine(object):
                  batch_buckets=None, seq_buckets=None, max_batch_size=None,
                  max_queue_delay_ms=None, queue_capacity=256,
                  default_deadline_ms=None, validate=True, warmup=True,
-                 latency_window=2048, apply_tuned=False,
-                 pipeline_depth=None, tp=None, mesh_devices=None,
-                 weights_dtype=None):
+                 latency_window=2048, pipeline_depth=None, tp=None,
+                 mesh_devices=None, weights_dtype=None):
         from ..places import CPUPlace
         self.name = name or (os.path.basename(os.path.normpath(model_dir))
                              if model_dir else "model")
@@ -237,34 +236,8 @@ class InferenceEngine(object):
                 "engine has no loaded weights to quantize"
                 % (self.weights_dtype,))
 
-        # apply_tuned: start at the recorded batching config for this
-        # model's content signature on this device (paddle_tpu.tuning).
-        # Explicit constructor arguments always win — a tuned config
-        # fills only the knobs the caller left unset, so deploy-time
-        # overrides stay overrides. No recorded entry = defaults.
-        tuned_knobs = {}
-        self.tuned_config = None  # the store entry in effect, if any
-        if apply_tuned:
-            from .. import tuning
-            entry = tuning.lookup_program(self.program,
-                                          self._exe.place.device())
-            if entry is not None:
-                tuned_knobs = entry.get("knobs", {})
-                self.tuned_config = entry
-        # the lattice knobs form one coherent set (buckets bound
-        # max_batch): they apply all-or-nothing, only when the caller
-        # pinned NONE of them — a tuned max_batch under explicit
-        # buckets could exceed the caller's largest bucket
-        if (batch_buckets is None and max_batch_size is None
-                and seq_buckets is None):
-            if tuned_knobs.get("batch_buckets"):
-                batch_buckets = list(tuned_knobs["batch_buckets"])
-            if tuned_knobs.get("max_batch_size"):
-                max_batch_size = int(tuned_knobs["max_batch_size"])
-            if tuned_knobs.get("seq_buckets"):
-                seq_buckets = list(tuned_knobs["seq_buckets"])
         if max_queue_delay_ms is None:
-            max_queue_delay_ms = tuned_knobs.get("max_queue_delay_ms", 5.0)
+            max_queue_delay_ms = 5.0
 
         # feed contract: per-feed declared feature dims + sequence-ness
         self._feed_vars = {}
@@ -942,7 +915,7 @@ class DecodeEngine(object):
     one `Executor.run` of that step at the ONE compiled shape: the
     executor's state machinery keeps the slot state device-resident and
     DONATES the read-and-written arrays (the KV cache never round-trips
-    the host), the AOT compile cache / tuned-kernel trace keys compose
+    the host), the AOT compile cache and the trace-env key compose
     unchanged because a step IS an ordinary run, and the DecodeBatcher
     admits/retires streams between iterations (Orca-style continuous
     batching — see serving/batcher.DecodeBatcher).

@@ -489,42 +489,6 @@ def test_bench_sharded_smoke():
     assert np.isfinite(rec["final_loss"])
 
 
-def test_bench_kernels_smoke():
-    """The BENCH_KERNELS=1 kernel-floor leg (PR 13): one subprocess run
-    on CPU at tiny dims must emit the JSON contract line with per-op
-    fused-vs-unfused timings + divergences and the bf16/int8 serving
-    divergence gate — correctness gated here, speed only on TPU."""
-    env = dict(os.environ)
-    env.pop("XLA_FLAGS", None)
-    env.update({
-        "JAX_PLATFORMS": "cpu",
-        "PYTHONPATH": REPO + os.pathsep + env.get("PYTHONPATH", ""),
-        "BENCH_KERNELS": "1", "BENCH_KERNELS_SEQ": "16",
-        "BENCH_KERNELS_VOCAB": "64", "BENCH_KERNELS_DIM": "8",
-        "BENCH_KERNELS_BATCH": "2", "BENCH_KERNELS_REPEATS": "1",
-    })
-    out = subprocess.run(
-        [sys.executable, os.path.join(REPO, "bench.py")],
-        env=env, capture_output=True, text=True, timeout=900)
-    assert out.returncode == 0, out.stdout + out.stderr
-    rec = json.loads(out.stdout.strip().splitlines()[-1])
-    assert rec["metric"] == "kernel_floor_speedup"
-    assert rec["unit"] == "x fused/unfused"
-    assert rec["vs_baseline"] is None
-    assert not rec.get("error")
-    # CPU run: correctness gated, speed NOT asserted
-    assert rec["on_tpu"] is False and rec["speed_asserted"] is False
-    assert set(rec["per_op"]) == {"attn", "xent", "ln", "lstm",
-                                  "seq_softmax"}
-    for name, leg in rec["per_op"].items():
-        assert leg["divergence"] <= leg["bound"], name
-        assert leg["fused_s"] > 0 and leg["unfused_s"] > 0
-    for wd in ("bf16", "int8"):
-        q = rec["quantized"][wd]
-        assert q["divergence"] <= q["bound"]
-        assert q["bytes_after"] < q["bytes_before"]
-
-
 @pytest.mark.slow
 def test_bench_transformer_decode_smoke():
     """The decode bench mode the sweep runs unattended: one subprocess

@@ -169,7 +169,7 @@ _ERROR_MODES = [
     {"BENCH_FLEET": "1"}, {"BENCH_CKPT": "1"}, {"BENCH_RESIL": "1"},
     {"BENCH_COMPILE_CACHE": "1"}, {"BENCH_SHARDED": "1"},
     {"BENCH_TP": "1"}, {"BENCH_PIPELINE": "1"}, {"BENCH_OBS": "1"},
-    {"BENCH_KERNELS": "1"}, {"BENCH_DECODE": "1"},
+    {"BENCH_DECODE": "1"},
     {"BENCH_MODEL": "transformer"},
     {"BENCH_MODEL": "transformer", "BENCH_DECODE": "1"},
     {"BENCH_MODEL": "stacked_lstm"},

@@ -204,8 +204,15 @@ class FakeWorker(object):
         self.status_on_run = "done"
         self.w.update(status="done")
 
-    def fault(self, gen):
-        self.w.update(status="fault", gen=gen, fault="DispatchTimeout")
+    def fault(self, gen, fault="DispatchTimeout", **why):
+        """Report a fault in generation `gen`, once this worker's own ack
+        of that generation's run plan has landed: the ack writes
+        status_on_run and, coming second, would take the fault back."""
+        deadline = time.monotonic() + 10
+        while self.w.snapshot()["gen"] != gen \
+                and time.monotonic() < deadline:
+            time.sleep(0.005)
+        self.w.update(status="fault", gen=gen, fault=fault, **why)
 
     def die(self):
         self._stop.set()

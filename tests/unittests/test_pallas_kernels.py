@@ -106,8 +106,7 @@ def test_fused_attention_layer_through_executor():
         k = fluid.layers.data(name="k", shape=[t, h, d], dtype="float32")
         v = fluid.layers.data(name="v", shape=[t, h, d], dtype="float32")
         q.stop_gradient = False  # data vars default to stop_gradient=True
-        out = fluid.layers.fused_attention(q, k, v, causal=True,
-                                           block_q=8, block_k=8)
+        out = fluid.layers.fused_attention(q, k, v, causal=True)
         loss = fluid.layers.mean(fluid.layers.square(out))
         fluid.append_backward(loss)
     exe = fluid.Executor(fluid.CPUPlace())
@@ -136,7 +135,11 @@ def test_fused_attention_kv_len_through_executor(monkeypatch):
     dispatch would otherwise route this tiny T to the dense path and
     the test would stop covering the kernel's KVLen/custom_vjp)."""
     import paddle_tpu as fluid
+    from paddle_tpu.ops import kernel_config
     monkeypatch.setenv("FLAGS_flash_min_seq", "0")
+    # two blocks over the longest row, so that block skipping is on the path
+    monkeypatch.setitem(kernel_config.DEFAULT_TILES, "attn",
+                        {"block_q": 8, "block_k": 8})
     rng = np.random.RandomState(12)
     H, D = 2, 8
     seqs = [rng.randn(n, H * D).astype("float32") * 0.5 for n in (9, 5, 2)]
@@ -149,8 +152,7 @@ def test_fused_attention_kv_len_through_executor(monkeypatch):
         x = fluid.layers.reshape(seq, shape=[0, -1, H, D])
         # reshape drops the lengths companion, so pass kv_len explicitly
         kv = seq.block.var_recursive(seq.seq_len_var)
-        att = fluid.layers.fused_attention(x, x, x, kv_len=kv,
-                                           block_q=8, block_k=8)
+        att = fluid.layers.fused_attention(x, x, x, kv_len=kv)
         loss = fluid.layers.mean(fluid.layers.square(att))
         fluid.append_backward(loss)
     exe = fluid.Executor(fluid.CPUPlace())

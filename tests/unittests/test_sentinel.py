@@ -595,9 +595,8 @@ def test_coordinator_quarantines_sdc_device(tmp_path):
         _wait_event(coord, "formed")
         gen = cl.read_plan(d)["gen"]
         # wb's canary convicted its local device 1
-        b.w.update(status="fault", gen=gen,
-                   fault="SilentCorruptionError('canary mismatch')",
-                   sdc_device=1)
+        b.fault(gen, fault="SilentCorruptionError('canary mismatch')",
+                sdc_device=1)
         q = _wait_event(coord, "quarantine")
         assert q["worker"] == "wb" and q["device"] == 1
         ev = _wait_event(coord, "rescale")
