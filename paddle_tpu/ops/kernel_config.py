@@ -48,12 +48,23 @@ __all__ = [
 # section 6, PR 27): at 128 x 128 the forward kernel takes 2.9-4.1 times
 # as long, dK/dV 2.2-2.6 and dQ 2.5-3.2 times, and the best pair is the
 # same at D=64 and D=128, so the tile is one entry and no function of
-# the shape.  The 8-row tiles of "xent", "ln" and "seq" have not been
-# swept on the chip (ROADMAP A3).
+# the shape.  "ln" is no count of rows but a budget: the bytes of the
+# float32 working copy of one input tile, which
+# pallas_kernels._ln_block_rows turns into rows from the N, D and dtype
+# it sees (512 rows of [16384, 512] float32, 32 at D=8192).  Swept on
+# the v5e over 128 KiB to 4 MiB (PERF.md section 6, PR 30): at
+# [16384, 512] float32 a call takes 0.152 ms at 128 KiB, 0.110 at 256,
+# 0.103 at 512 KiB, 1 MiB and 2 MiB (0.62 at the 8 rows it had), the
+# same at D=2048 and D=8192; in bf16 0.068 at 512 KiB, 0.055 at 1 MiB,
+# 0.054 at 2; Mosaic refuses 4 MiB of float32 tile at every width at
+# its default VMEM limit.  1 MiB is the smallest budget past the knee
+# at every width and dtype measured.  The 8-row tiles of
+# "xent" and "seq" have not been swept on the chip (ROADMAP A3);
+# softmax_xent at 8 rows already runs near the HBM rate.
 DEFAULT_TILES = {
     "attn": {"block_q": 512, "block_k": 512},
     "xent": {"block_n": 8},
-    "ln": {"block_n": 8},
+    "ln": {"tile_bytes": 1 << 20},
     "lstm": {"block_b": 0},
     "seq": {"block_n": 8},
 }

@@ -555,8 +555,28 @@ def _ln_kernel(x_ref, scale_ref, bias_ref, y_ref, mean_ref, rstd_ref, *,
     rstd_ref[:] = rstd
 
 
+def _ln_block_rows(n, d, dtype, tile_bytes):
+    """Rows of x [n, d] one grid step of the layer_norm kernel takes, from
+    a budget in bytes for the float32 working copy of one input tile (the
+    kernel casts to float32 whatever `dtype` is). A multiple of the
+    dtype's sublane granule (8 rows, 16 for a 2-byte dtype), at least one
+    granule however wide a row is, at most n rounded up to one. Where a
+    multiple that divides n lies within a factor of two below, that one:
+    the call then adds no pad before the kernel and no slice after it."""
+    granule = 8 * max(1, 4 // jnp.dtype(dtype).itemsize)
+    rows = max(granule, tile_bytes // (d * 4) // granule * granule)
+    rows = min(rows, -(-n // granule) * granule)
+    for fit in range(rows, rows // 2, -granule):
+        if n % fit == 0:
+            return fit
+    return rows
+
+
 def _ln_fwd_call(x, scale, bias, eps, block_n, interpret):
     n, d = x.shape
+    if block_n is None:
+        block_n = _ln_block_rows(n, d, x.dtype,
+                                 DEFAULT_TILES["ln"]["tile_bytes"])
     n_pad = int(-(-n // block_n) * block_n)
     xp = jnp.pad(x, [(0, n_pad - n), (0, 0)]) if n_pad != n else x
     y, mean, rstd = pl.pallas_call(
@@ -1127,12 +1147,15 @@ def layer_norm(x, scale, bias, eps=1e-5, block_n=None, interpret=None):
     """Fused layer norm over the trailing dim of 2D x [N, D]; one VMEM pass
     computes y + the (mean, rstd) backward residuals. Differentiable
     (custom_vjp; dense backward — the fwd is the HBM-bound pass worth
-    fusing). Returns (y, mean [N], variance [N]) matching the layer_norm
-    op's output contract; the fetchable mean/variance are plain reductions
-    XLA DCEs when (as usual) nothing consumes them."""
+    fusing). `block_n` rows a grid step where given (a sweep, a kernel
+    test); else as many as DEFAULT_TILES["ln"]'s byte budget holds at this
+    N, D and dtype (_ln_block_rows). Returns (y, mean [N], variance [N])
+    matching the layer_norm op's output contract; the fetchable
+    mean/variance are plain reductions XLA DCEs when (as usual) nothing
+    consumes them."""
     if interpret is None:
         interpret = _interpret_default()
-    y = _ln_core(x, scale, bias, float(eps), _tile("ln", "block_n", block_n),
-                 bool(interpret))
+    y = _ln_core(x, scale, bias, float(eps),
+                 None if block_n is None else int(block_n), bool(interpret))
     xf = x.astype(jnp.float32)
     return y, jnp.mean(xf, axis=-1), jnp.var(xf, axis=-1)

@@ -486,6 +486,20 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
+def _compile_uncached(fn, *args):
+    """Compiled for the described chip with the persistent cache off: an
+    executable for a device that is not attached cannot be read back."""
+    from jax.experimental.compilation_cache import compilation_cache
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        return jax.jit(fn).lower(*args).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        compilation_cache.reset_cache()
+
+
 def test_mosaic_calls_are_named_on_a_described_v5e(one_chip, monkeypatch):
     """A training step with layer_norm, softmax_xent and flash attention,
     lowered by build_program_fn and compiled for a TPU: five Mosaic calls
@@ -499,15 +513,7 @@ def test_mosaic_calls_are_named_on_a_described_v5e(one_chip, monkeypatch):
     monkeypatch.setattr(kernel_config, "dispatch_platform", lambda: "tpu")
     monkeypatch.setattr(pallas_kernels, "dispatch_platform", lambda: "tpu")
     fn, args = _kernel_program_args(*_kernel_program(), shard=one_chip)
-    from jax.experimental.compilation_cache import compilation_cache
-    was = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    compilation_cache.reset_cache()
-    try:
-        text = jax.jit(fn).lower(*args).compile().as_text()
-    finally:
-        jax.config.update("jax_enable_compilation_cache", was)
-        compilation_cache.reset_cache()
+    text = _compile_uncached(fn, *args).as_text()
     calls = re.findall(
         r'%?([\w.\-]+) = [^\n]*custom_call_target="tpu_custom_call"'
         r'[^\n]*op_name="([^"]*)"', text)
@@ -528,3 +534,26 @@ def test_mosaic_calls_are_named_on_a_described_v5e(one_chip, monkeypatch):
               if lowering.parse_op_scope(m)}
     assert {"layer_norm", "layer_norm_grad", "fused_attention_grad",
             "softmax_with_cross_entropy_grad", "mul_grad"} <= scoped
+
+
+@pytest.mark.parametrize("n,d,dtype", [
+    (16384, 512, jnp.float32), (16384, 1024, jnp.float32),
+    (4096, 2048, jnp.float32), (1024, 8192, jnp.float32),
+    (16384, 512, jnp.bfloat16), (1024, 8192, jnp.bfloat16),
+    (3000, 520, jnp.float32),
+], ids=lambda v: getattr(v, "__name__", None) or str(v))
+def test_layer_norm_tile_fits_the_default_vmem_limit(one_chip, n, d, dtype):
+    """The one budget of DEFAULT_TILES["ln"] gives a tile Mosaic takes at
+    its default scoped-VMEM limit at every width, the cells' D=512 and the
+    D=2048 and D=8192 no cell runs (PERF.md section 6, PR 30: 4 MiB of
+    float32 tile is refused at all three, by 4 to 64 KiB)."""
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    text = _compile_uncached(
+        lambda x, s, b: pallas_kernels._ln_fwd_call(x, s, b, 1e-5, None,
+                                                    False),
+        sds((n, d), dtype), sds((d,), jnp.float32),
+        sds((d,), jnp.float32)).as_text()
+    assert len(re.findall(r'%?ptpu_layer_norm_fwd[\w.]* = [^\n]*'
+                          r'custom_call_target="tpu_custom_call"',
+                          text)) == 1

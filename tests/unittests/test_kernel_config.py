@@ -186,9 +186,12 @@ _RULES = {
         {"Q": [_f32(2, 16, 2, 8)], "K": [_f32(2, 16, 2, 8)],
          "V": [_f32(2, 16, 2, 8)]}, {}, "attn", "block_q", 1, 8,
         lambda block_q: min(block_q, 16)),
+    # the table holds bytes of one float32 tile, not rows: 64 a row here,
+    # and never more rows than the 32 there are
     "layer_norm": (
         {"X": [_f32(4, 8, 16)], "Scale": [_f32(16)], "Bias": [_f32(16)]},
-        {"begin_norm_axis": 2}, "ln", "block_n", 0, 16, int),
+        {"begin_norm_axis": 2}, "ln", "tile_bytes", 0, 1024,
+        lambda tile_bytes: min(tile_bytes // 64, 32)),
     "softmax_with_cross_entropy": (
         {"Logits": [_f32(32, 10)], "Label": [_i32(32, 1)]}, {},
         "xent", "block_n", 0, 16, int),
@@ -230,11 +233,103 @@ def test_lowering_uses_the_tables_tile(monkeypatch, pallas_calls, op_type):
         assert pallas_calls, "%s reached no pallas_call" % op_type
         return pallas_calls[0]["in_specs"][0].block_shape[axis]
 
-    assert block_seen() == block_at(kc.DEFAULT_TILES[op][knob])
+    first = block_seen()
+    assert first == block_at(kc.DEFAULT_TILES[op][knob])
     assert other != kc.DEFAULT_TILES[op][knob]
     monkeypatch.setitem(kc.DEFAULT_TILES, op,
                         dict(kc.DEFAULT_TILES[op], **{knob: other}))
-    assert block_seen() == block_at(other) == other
+    assert block_seen() == block_at(other) != first
+
+
+_BF16 = jnp.bfloat16
+
+
+@pytest.mark.parametrize("n,d,dtype,block_n,grid,rows", [
+    # both transformer cells: 32 grid steps a call where 8 rows took 2048
+    (16384, 512, jnp.float32, None, 32, 512),
+    (16384, 512, _BF16, None, 32, 512),
+    # other widths keep the bytes and change the rows
+    (16384, 1024, jnp.float32, None, 64, 256),
+    (16384, 8192, jnp.float32, None, 512, 32),
+    # a row wider than the whole budget: one sublane granule all the same
+    (64, 65536, jnp.float32, None, 8, 8),
+    (64, 65536, _BF16, None, 4, 16),
+    # fewer rows than one tile (a decode step's [B, D]): one padded tile
+    (4, 512, jnp.float32, None, 1, 8),
+    (4, 512, _BF16, None, 1, 16),
+    (40, 512, jnp.float32, None, 1, 40),
+    # no divisor of N near the budget: whole tiles and a padded tail
+    (1000, 512, jnp.float32, None, 2, 512),
+    (3000, 512, jnp.float32, None, 6, 512),
+    # a divisor within a factor of two below: no pad, no slice
+    (1200, 512, jnp.float32, None, 3, 400),
+    (12288, 512, jnp.float32, None, 24, 512),
+    # D not a multiple of 128; bf16 rows come in sixteens
+    (100, 520, _BF16, None, 1, 112),
+    (1000, 520, jnp.float32, None, 2, 504),
+    (40, 8192, _BF16, None, 2, 32),
+    # what a sweep or a kernel test names wins over the table
+    (16384, 512, jnp.float32, 8, 2048, 8),
+    (1000, 512, jnp.float32, 24, 42, 24),
+], ids=lambda v: getattr(v, "__name__", None) or str(v))
+def test_layer_norm_tile_follows_the_budget(pallas_calls, n, d, dtype,
+                                            block_n, grid, rows):
+    """DEFAULT_TILES["ln"] is a budget in bytes for the float32 copy of
+    one input tile; the rows of a grid step follow from the N, D and dtype
+    the call sees, in whole sublane granules, with no pad where a divisor
+    of N is near."""
+    assert kc.DEFAULT_TILES["ln"] == {"tile_bytes": 1 << 20}
+    out = jax.eval_shape(
+        lambda x, s, b: pk.layer_norm(x, s, b, block_n=block_n),
+        jax.ShapeDtypeStruct((n, d), dtype), _f32(d), _f32(d))
+    call, = pallas_calls
+    assert call["name"] == "ptpu_layer_norm_fwd"
+    assert call["grid"] == (grid,)
+    assert call["in_specs"][0].block_shape == (rows, d)
+    assert [o.block_shape for o in call["out_specs"]] == [
+        (rows, d), (rows, 1), (rows, 1)]
+    # the kernel sees whole tiles; the caller sees its own N and dtype
+    assert call["out_shape"][0].shape == (grid * rows, d)
+    assert 0 <= grid * rows - n < rows
+    if block_n is None:
+        assert rows % (16 if dtype == _BF16 else 8) == 0
+        assert rows * d * 4 <= max(1 << 20, 8 * d * 4 * (
+            2 if dtype == _BF16 else 1))
+    assert (out[0].shape, out[0].dtype) == ((n, d), dtype)
+    assert out[1].shape == out[2].shape == (n,)
+
+
+@pytest.mark.parametrize("shape", [(64, 256, 512), (8, 2048, 512)],
+                         ids=["t256", "t2048"])
+def test_layer_norm_reaches_its_named_kernel_on_a_tpu(monkeypatch,
+                                                      pallas_calls, shape):
+    """What refused PR 29 (PERF.md section 7): BENCHMARK.json lists
+    `layer_norm_ms_per_step` for both transformer cells, and its reader
+    leaves the metric out where no Mosaic call named `ptpu_layer_norm_fwd`
+    ran. With nothing set and a TPU to dispatch to, the rule at the cells'
+    shape reaches exactly that call, once, and the reader takes the name
+    the trace gives it."""
+    from benchmark import kernel_ms
+    monkeypatch.delenv("PADDLE_TPU_PALLAS", raising=False)
+    monkeypatch.setattr(kc, "dispatch_platform", lambda: "tpu")
+    monkeypatch.setattr(pk, "dispatch_platform", lambda: "tpu")
+    rule = registry.get("layer_norm")
+    ins = {"X": [_f32(*shape)], "Scale": [_f32(512)], "Bias": [_f32(512)]}
+    out = jax.eval_shape(
+        lambda ins: rule.lower(types.SimpleNamespace(mesh=None, amp=False),
+                               ins, {"begin_norm_axis": 2}), ins)
+    assert out["Y"][0].shape == shape
+    call, = pallas_calls
+    assert call["name"] == "ptpu_layer_norm_fwd" in pk.KERNEL_NAMES
+    assert not call["interpret"]
+    assert call["grid"] == (32,)
+    for op, want in (
+            ("ptpu_layer_norm_fwd custom-call tpu_custom_call", True),
+            ("ptpu_layer_norm_fwd.17 custom-call tpu_custom_call", True),
+            ("jvp_ptpu_layer_norm_fwd_.17 custom-call tpu_custom_call",
+             False),
+            ("ptpu_layer_norm_fwd.17 fusion kLoop", False)):
+        assert kernel_ms._is_kernel(op, call["name"]) is want
 
 
 # ---------------------------------------------------------------------------

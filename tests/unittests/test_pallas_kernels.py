@@ -252,6 +252,51 @@ def test_fused_layer_norm_grads_match_dense():
                                    rtol=2e-4, atol=2e-5)
 
 
+@pytest.mark.parametrize("n,d,dtype,tile_bytes,tiles,tol", [
+    # the table's own budget: 512 rows a tile at D=512
+    (1100, 512, "float32", None, 3, 2e-5),
+    # smaller budgets, so that a small N is several tiles too
+    (50, 24, "float32", 1536, 4, 2e-5),
+    (1000, 128, "float32", 250 * 512, 5, 2e-5),        # 200 rows divide N
+    (70, 32, "bfloat16", 4096, 3, 2e-2),
+], ids=["n1100_d512", "n50_d24", "n1000_d128_whole", "n70_d32_bf16"])
+def test_fused_layer_norm_tiles_match_dense(monkeypatch, n, d, dtype,
+                                            tile_bytes, tiles, tol):
+    """Several tiles of the byte budget and (but for one case) a padded
+    tail: y and all three gradients agree with the dense float32 math on
+    the same inputs, under a random cotangent, and no row of the pad
+    leaks into dscale or dbias."""
+    from paddle_tpu.ops import kernel_config as kc
+    if tile_bytes is not None:
+        monkeypatch.setitem(kc.DEFAULT_TILES, "ln",
+                            {"tile_bytes": tile_bytes})
+    rows = pk._ln_block_rows(n, d, jnp.dtype(dtype),
+                             kc.DEFAULT_TILES["ln"]["tile_bytes"])
+    assert -(-n // rows) == tiles
+    rng = np.random.RandomState(12)
+    x = jnp.asarray(rng.randn(n, d).astype("float32") * 2 + 1, dtype)
+    scale = jnp.asarray(rng.rand(d).astype("float32") + 0.5)
+    bias = jnp.asarray(rng.randn(d).astype("float32"))
+    g = jnp.asarray(rng.randn(n, d).astype("float32"), dtype)
+
+    def fused(x, s, b):
+        return pk.layer_norm(x, s, b)[0]
+
+    def dense(x, s, b):
+        xf = x.astype(jnp.float32)
+        mu = jnp.mean(xf, -1, keepdims=True)
+        v = jnp.var(xf, -1, keepdims=True)
+        return ((xf - mu) * jax.lax.rsqrt(v + 1e-5) * s + b).astype(x.dtype)
+
+    y1, vjp1 = jax.vjp(fused, x, scale, bias)
+    y2, vjp2 = jax.vjp(dense, x, scale, bias)
+    assert y1.dtype == x.dtype and y1.shape == (n, d)
+    for a, b in zip((y1,) + vjp1(g), (y2,) + vjp2(g)):
+        assert a.dtype == b.dtype
+        a, b = (np.asarray(v.astype(jnp.float32)) for v in (a, b))
+        assert np.abs(a - b).max() <= tol * (np.abs(b).max() + 1e-6)
+
+
 def test_layer_norm_op_pallas_path_matches_dense(monkeypatch):
     import paddle_tpu as fluid
     rng = np.random.RandomState(11)

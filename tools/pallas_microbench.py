@@ -2,6 +2,8 @@
 vs their dense XLA counterparts, fwd+bwd, on the chip (one process; send
 it through the chip tool). One JSON line per comparison: {"kernel": ...,
 "dense_ms": ..., "fused_ms": ..., "speedup": ..., "shape": ...}.
+MB_TUNE=1 sweeps the flash blocks; MB_LN=1 compares the layer_norm kernel
+at a list of tile budgets with its XLA path (device ms from a trace).
 """
 import json
 import os
@@ -178,10 +180,126 @@ def sweep_flash_blocks(shapes=_SWEEP_SHAPES, blocks=_SWEEP_BLOCKS,
             print(json.dumps(line), flush=True)
 
 
+def _device_ms(fn, *args, iters=10):
+    """Device milliseconds a call of `fn`, from a jax.profiler trace of
+    `iters` calls read as `python -m paddle_tpu.profiler` reads one: (self
+    time of every device operation, {Pallas kernel name: its own ms})."""
+    import shutil
+    import tempfile
+    import jax
+    from paddle_tpu import profiler
+    from paddle_tpu.core.utils import device_fetch_barrier
+    device_fetch_barrier(fn(*args))
+    trace_dir = tempfile.mkdtemp(prefix="mb_trace_")
+    try:
+        with jax.profiler.trace(trace_dir):
+            for _ in range(iters):
+                out = fn(*args)
+            device_fetch_barrier(out)
+        table = profiler.device_op_table_from(trace_dir)
+    finally:
+        shutil.rmtree(trace_dir, ignore_errors=True)
+    kernels = {}
+    for row in table["rows"]:
+        if row["kernel"]:
+            kernels[row["kernel"]] = (kernels.get(row["kernel"], 0.0)
+                                      + row["total_ms"] / iters)
+    return (round(table["busy_self_ms"] / iters, 4),
+            {name: round(ms, 4) for name, ms in kernels.items()})
+
+
+_LN_SHAPES = "16384x512"            # both transformer cells' 32 calls a step
+_LN_KIB = (128, 256, 512, 1024, 2048, 4096)
+
+
+def bench_layer_norm(shapes=_LN_SHAPES, budgets_kib=_LN_KIB,
+                     dtype="float32"):
+    """The layer_norm kernel at each byte budget of one input tile (what
+    DEFAULT_TILES["ln"] holds; rows by pallas_kernels._ln_block_rows)
+    against the op's XLA path at x [N, D]: device ms a call from a traced
+    run, forward alone and forward plus backward (dx, dscale, dbias under
+    a random cotangent), GB/s over the bytes the pass has to move (x in
+    and y out; with the backward x and dy in and dx out besides), and the
+    largest difference from the XLA path over the largest value there. A
+    budget Mosaic refuses is a line with its error. One line with path
+    "xla" a shape comes first."""
+    jax = _await()
+    import jax.numpy as jnp
+    from paddle_tpu.ops import pallas_kernels as pk
+
+    def xla(x, s, b):                # the layer_norm rule's dense path
+        x2 = x.astype(jnp.float32)
+        mean = jnp.mean(x2, axis=1, keepdims=True)
+        var = jnp.var(x2, axis=1, keepdims=True)
+        y = (x2 - mean) * jax.lax.rsqrt(var + 1e-5)
+        return (y * s.reshape(1, -1) + b.reshape(1, -1)).astype(x.dtype)
+
+    for spec in shapes.split(","):
+        n, d = (int(v) for v in spec.split("x"))
+        rng = np.random.RandomState(0)
+        x, g = (jnp.asarray(rng.randn(n, d).astype("f") * 2 + 0.5,
+                            dtype=dtype) for _ in range(2))
+        s = jnp.asarray(rng.rand(d).astype("f") + 0.5)
+        b = jnp.asarray(rng.randn(d).astype("f"))
+        nbytes = n * d * x.dtype.itemsize
+        want = None
+        for kib in (None,) + tuple(budgets_kib):
+            line = {"kernel": "layer_norm", "shape": [n, d], "dtype": dtype,
+                    "path": "xla" if kib is None else "pallas",
+                    "device": str(jax.devices()[0])}
+            if kib is None:
+                fwd = xla
+            else:
+                rows = pk._ln_block_rows(n, d, x.dtype, kib * 1024)
+                line.update(tile_kib=kib, block_n=rows, grid=-(-n // rows))
+
+                def fwd(x, s, b, rows=rows):
+                    return pk.layer_norm(x, s, b, block_n=rows)[0]
+
+            def both(x, s, b, g, fwd=fwd):
+                y, vjp = jax.vjp(fwd, x, s, b)
+                return (y,) + vjp(g)
+            try:
+                f, fb = jax.jit(fwd), jax.jit(both)
+                got = fb(x, s, b, g)
+                line["fwd_ms"], k = _device_ms(f, x, s, b)
+                line["fwd_bwd_ms"], kb = _device_ms(fb, x, s, b, g)
+                if line["fwd_ms"]:      # 0 off a TPU: no device plane
+                    line["fwd_gbps"] = round(
+                        2 * nbytes / line["fwd_ms"] / 1e6, 1)
+                    line["fwd_bwd_gbps"] = round(
+                        5 * nbytes / line["fwd_bwd_ms"] / 1e6, 1)
+                if kib is None:
+                    want = got
+                else:
+                    # with no op scope around it jax names the call
+                    # under vjp jvp_ptpu_layer_norm_fwd_
+                    line["kernel_ms"], line["kernel_ms_under_vjp"] = (
+                        round(sum(ms for name, ms in t.items()
+                                  if "ptpu_layer_norm_fwd" in name), 4)
+                        for t in (k, kb))
+                    pairs = [(np.asarray(a, np.float64),
+                              np.asarray(w, np.float64))
+                             for a, w in zip(got, want)]
+                    line["max_err"] = max(      # as chip_smoke.py's
+                        float(np.abs(a - w).max() / (np.abs(w).max() + 1e-6))
+                        for a, w in pairs)
+            except Exception as e:  # noqa: BLE001 — record, keep sweeping
+                line["error"] = str(e).replace("\n", " ")[-300:]
+            print(json.dumps(line), flush=True)
+
+
 if __name__ == "__main__":
     # MB_* knobs shrink the config for smoke runs (CPU interpret mode is
     # orders of magnitude slower than the real kernel)
-    if os.environ.get("MB_TUNE") == "1":
+    if os.environ.get("MB_LN") == "1":
+        # MB_SHAPES=NxD[,...], MB_BLOCKS=<KiB of one float32 tile>[,...]
+        bench_layer_norm(
+            os.environ.get("MB_SHAPES", _LN_SHAPES),
+            tuple(int(k) for k in os.environ["MB_BLOCKS"].split(","))
+            if os.environ.get("MB_BLOCKS") else _LN_KIB,
+            os.environ.get("MB_DTYPE", "float32"))
+    elif os.environ.get("MB_TUNE") == "1":
         # MB_SHAPES=BHxTxDxcausal[,...], MB_BLOCKS=BQxBK[,...]
         sweep_flash_blocks(
             os.environ.get("MB_SHAPES", _SWEEP_SHAPES),
