@@ -46,6 +46,10 @@ FULL = {
                         shapes=((256, 32), (2048, 4))),
     "kernels": dict(
         attn=dict(b=4, t=2048, h=8, d=64),          # transformer T=2048
+        # SmallThinker's: 7 query heads on 1 key/value head of 128, a
+        # window of half the sequence
+        attn_window=dict(b=1, t=4096, h=7, hkv=1, d=128, window=2048),
+        moe=dict(n=4096, d=512, f=256),
         xent=dict(n=8192, v=30000),                 # its [B*T, vocab] loss
         # its d_model rows: the benchmark cells' [16384, 512], whole tiles
         # at the table's budget, and an N that leaves a padded tail
@@ -68,6 +72,8 @@ TINY = {
                         vocab=64, steps=3, shapes=((16, 4), (32, 2))),
     "kernels": dict(
         attn=dict(b=2, t=32, h=2, d=16),
+        attn_window=dict(b=2, t=40, h=4, hkv=2, d=16, window=12),
+        moe=dict(n=64, d=16, f=8),
         xent=dict(n=32, v=64),
         ln=(dict(b=2, t=16, d=32), dict(b=3, t=7, d=32)),
         lstm=(dict(b=5, t=6, d=8, reverse=True),),
@@ -400,6 +406,31 @@ def _kernel_cases(cfg):
                       % (list(shape), "causal" if causal else "padded"),
                       "attn", build, feed, TOL["attn"]))
 
+    # the same kernels with a sliding window and grouped queries (K and V
+    # with fewer heads): forward and the three gradients, dK and dV summed
+    # over each group. No key lengths: a query whose whole window lies past
+    # them has no row to compare (the kernel gives zeros, XLA an average)
+    c = cfg["attn_window"]
+
+    def build(main, c=c):
+        main.enable_mixed_precision()
+        q, g = (layers.data(name=n, shape=[c["t"], c["h"], c["d"]],
+                            dtype="float32") for n in "qg")
+        k, v = (layers.data(name=n, shape=[c["t"], c["hkv"], c["d"]],
+                            dtype="float32") for n in "kv")
+        for x in (q, k, v):
+            x.stop_gradient = False
+        out = layers.fused_attention(q, k, v, causal=True,
+                                     window=c["window"])
+        _weighted_loss(fluid, out, g)
+        return [out, "q@GRAD", "k@GRAD", "v@GRAD"]
+    feed = {n: (rng.randn(c["b"], c["t"], c["h" if n in "qg" else "hkv"],
+                          c["d"]) * 0.5).astype("f") for n in "qkvg"}
+    cases.append(("flash_attention [%d, %d, %d on %d, %d] bf16 causal "
+                  "window %d" % (c["b"], c["t"], c["h"], c["hkv"], c["d"],
+                                 c["window"]),
+                  "attn", build, feed, TOL["attn"]))
+
     # softmax_xent: f32 logits (AMP forces the loss ops to f32)
     c = cfg["xent"]
 
@@ -519,9 +550,75 @@ def _cpu_place_case(smoke, build, feed, tol):
         raise AssertionError("CPUPlace and TPUPlace disagree: %g" % err)
 
 
+def _held_experts_case(smoke, c, tol):
+    """parallel/moe.py routed_ffn told that it holds 4 of 16 experts, on
+    this device, against the plain reference: the partial sum and its
+    gradients. On a TPU `ragged_dot` leaves the rows past the groups' sum
+    unwritten (PERF.md, PR 31); whatever is there must reach nothing.
+
+    Twice: SiLU experts at the device's default matmul precision (one bf16
+    pass on a TPU, hence the flash tolerance), and ReLU experts with both
+    sides at "highest": a ReLU gate's derivative is a step, so at one bf16
+    pass the pre-activations within 0.2 % of zero change side and the
+    gradients that pass through the gate (dx, dw_gate) leave the float32
+    ones by a fifth (my chip run, PR 31), which says nothing about the
+    rows."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.models import causal_lm_reference as reference
+    from paddle_tpu.parallel import moe
+
+    rng = np.random.RandomState(11)
+    n, d, f, e, held, first = c["n"], c["d"], c["f"], 16, 4, 8
+    x, a, g = (jnp.asarray(rng.randn(n, d), jnp.float32) for _ in range(3))
+    router = jnp.asarray(rng.randn(d, e), jnp.float32)
+    wg, wu = (jnp.asarray(rng.randn(held, d, f) * d ** -0.5, jnp.float32)
+              for _ in range(2))
+    wd = jnp.asarray(rng.randn(held, f, d) * f ** -0.5, jnp.float32)
+
+    for activation, precision, limit in (("silu", None, tol),
+                                         ("relu", "highest", 1e-4)):
+        conf = {"num_experts": e, "num_experts_per_tok": 6,
+                "norm_topk_prob": True, "hidden_act": activation}
+
+        def program(x, wg, wu, wd):
+            return moe.routed_ffn(x, router, wg, wu, wd, top_k=6,
+                                  norm_topk_prob=True, router_x=a,
+                                  activation=activation,
+                                  first_expert=first)[0]
+
+        def plain(x, wg, wu, wd):
+            return reference.routed_experts(x, router, wg, wu, wd, conf,
+                                            router_x=a,
+                                            first_expert=first)[0]
+
+        with jax.default_device(smoke.device):
+            with jax.default_matmul_precision(precision or "default"):
+                got, vjp = jax.vjp(jax.jit(program), x, wg, wu, wd)
+                got = (got,) + vjp(g)
+            with jax.default_matmul_precision("highest"):
+                want, vjp = jax.vjp(jax.jit(plain), x, wg, wu, wd)
+                want = (want,) + vjp(g)
+        errs = _normalized_errors(
+            ("out", "dx", "dw_gate", "dw_up", "dw_down"), got, want)
+        worst = max(errs, key=errs.get)
+        smoke.say("routed_ffn holding experts %d-%d of %d, [%d, %d] x [%d], "
+                  "%s at precision %s: max normalized error %.2e (%s) <= "
+                  "%.0e" % (first, first + held - 1, e, n, d, f, activation,
+                            precision or "default", errs[worst], worst,
+                            limit))
+        if errs[worst] > limit:
+            raise AssertionError(
+                "the held experts' share disagrees with the reference: %r "
+                "(tol %g)" % (errs, limit))
+
+
 def phase_c(smoke):
     cases = _kernel_cases(smoke.cfg["kernels"])
     runs = [(c[0], lambda c=c: _kernel_case(smoke, *c)) for c in cases]
+    runs.append(("routed_ffn with a share of the experts",
+                 lambda: _held_experts_case(smoke, smoke.cfg["kernels"]["moe"],
+                                            TOL["attn"])))
     _, _, ln_build, ln_feed, ln_tol = next(c for c in cases if c[1] == "ln")
     runs.append(("layer_norm on Executor(CPUPlace())",
                  lambda: _cpu_place_case(smoke, ln_build, ln_feed, ln_tol)))

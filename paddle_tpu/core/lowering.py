@@ -587,7 +587,9 @@ def _lower_op_inner(ctx, op, env):
     ins = {slot: [env.read(n) for n in names]
            for slot, names in op.inputs.items()}
     if op.type == "moe_ffn":
-        _count_moe_layer(op.attrs, ins["Router"][0].shape[1])
+        _count_moe_layer(op.attrs, ins)
+    elif op.type == "fused_attention":
+        _count_attention_layer(ctx, op.attrs, ins)
     if op.uid in ctx.linearized:
         # a grad op of this block differentiates this op: run the rule once,
         # under jax.vjp, and keep what the backward needs
@@ -712,15 +714,39 @@ def _count_grad_op(path, fwd_type):
     ).inc(path=path, op=fwd_type)
 
 
-def _count_moe_layer(attrs, experts):
+def _count_moe_layer(attrs, ins):
     from ..observability.registry import REGISTRY
     from ..parallel.moe import GROUPED_MATMUL
     REGISTRY.counter(
         "ptpu_moe_layers_total",
         "moe_ffn ops lowered (forward ops, not a grad op's replay), by "
-        "experts a token, stored experts and the grouped-matmul route"
-    ).inc(top_k=str(attrs["top_k"]), experts=str(experts),
+        "experts a token, experts routed over, experts held, the gate's "
+        "activation, what the router reads (the experts' own input or "
+        "another tensor, pre_attention) and the grouped-matmul route"
+    ).inc(top_k=str(attrs["top_k"]), experts=str(ins["Router"][0].shape[1]),
+          held=str(ins["WGate"][0].shape[0]),
+          activation=str(attrs.get("activation", "silu")),
+          router_input="pre_attention" if ins.get("RouterX") else "own",
           path=GROUPED_MATMUL)
+
+
+def _count_attention_layer(ctx, attrs, ins):
+    from ..observability.registry import REGISTRY
+    from ..ops.kernel_config import flash_at
+    q, k = ins["Q"][0], ins["K"][0]
+    window = attrs.get("window")
+    if ctx.mesh is not None and ctx.mesh.shape.get("sp", 1) > 1:
+        path = str(attrs.get("sp_impl", "ring"))
+    else:
+        path = "flash" if flash_at(q.shape[1]) else "dense"
+    REGISTRY.counter(
+        "ptpu_attention_layers_total",
+        "fused_attention ops lowered (forward ops, not a grad op's replay), "
+        "by kind (full, or window with its size), query and key/value heads "
+        "and the path taken (flash, dense, or the sequence-parallel one)"
+    ).inc(kind="full" if window is None else "window",
+          window=str(window or 0), q_heads=str(q.shape[2]),
+          kv_heads=str(k.shape[2]), path=path)
 
 
 def _lower_grad_of(ctx, op, env):

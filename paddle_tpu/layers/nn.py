@@ -612,13 +612,18 @@ def autoincreased_step_counter(counter_name=None, begin=1, step=1):
 
 
 def fused_attention(q, k, v, causal=False, scale=None, kv_len=None,
-                    sp_impl="ring", name=None):
-    """Flash attention over [B, T, H, D] q/k/v (TPU-native addition — the
-    reference era built attention from matmul+softmax ops; this is the
-    fused pallas path, see ops/pallas_kernels.py). kv_len: optional [B]
-    int32 Variable of true key lengths (padded-batch masking + block
+                    sp_impl="ring", name=None, window=None):
+    """Flash attention over q [B, T, Hq, D] and k, v [B, T, Hkv, D]
+    (TPU-native addition — the reference era built attention from
+    matmul+softmax ops; this is the fused pallas path, see
+    ops/pallas_kernels.py). Grouped queries come from the shapes: Hkv
+    divides Hq and query head h reads key/value head h // (Hq // Hkv).
+    window: None or an int, query i sees key j only where i - j < window
+    (with causal, the `window` newest keys up to itself). kv_len: optional
+    [B] int32 Variable of true key lengths (padded-batch masking + block
     skipping); defaults to k's sequence-lengths companion when k is a
-    lod_level>0 sequence. Under a ParallelExecutor mesh with an 'sp'
+    lod_level>0 sequence. The sequence-parallel paths take neither a
+    window nor grouped queries and raise NotImplementedError. Under a ParallelExecutor mesh with an 'sp'
     axis the op runs sequence-parallel; sp_impl chooses the algorithm:
     "ring" (K/V rotation over ICI, any head count) or "ulysses"
     (all-to-all head sharding, needs heads % sp == 0)."""
@@ -626,6 +631,9 @@ def fused_attention(q, k, v, causal=False, scale=None, kv_len=None,
         raise ValueError(
             "fused_attention sp_impl must be 'ring' or 'ulysses', got %r"
             % (sp_impl,))
+    if window is not None and int(window) < 1:
+        raise ValueError("fused_attention window must be None or >= 1, got "
+                         "%r" % (window,))
     helper = LayerHelper("fused_attention", **locals())
     out = helper.create_variable_for_type_inference(q.dtype)
     inputs = {"Q": [q], "K": [k], "V": [v]}
@@ -633,12 +641,14 @@ def fused_attention(q, k, v, causal=False, scale=None, kv_len=None,
         kv_len = k.block.var_recursive(k.seq_len_var)
     if kv_len is not None:
         inputs["KVLen"] = [kv_len]
+    attrs = {"causal": bool(causal),
+             "scale": None if scale is None else float(scale),
+             "sp_impl": str(sp_impl)}
+    if window is not None:
+        attrs["window"] = int(window)
     helper.append_op(
         type="fused_attention", inputs=inputs,
-        outputs={"Out": [out]},
-        attrs={"causal": bool(causal),
-               "scale": None if scale is None else float(scale),
-               "sp_impl": str(sp_impl)})
+        outputs={"Out": [out]}, attrs=attrs)
     if q.shape is not None:
         out.shape = tuple(q.shape)
     return out

@@ -271,8 +271,9 @@ def switch_moe(input, num_experts, d_hidden, capacity_factor=1.25,
 
 
 def moe_ffn(input, num_experts, d_expert, top_k, norm_topk_prob=False,
-            param_attr=None, name=None):
-    """Dropless top-k routed experts, each a gated-SiLU FFN without bias
+            param_attr=None, name=None, router_input=None, activation="silu",
+            experts_held=None, first_expert=0):
+    """Dropless top-k routed experts, each a gated FFN without bias
     (lowering: ops/parallel_ops.py -> parallel/moe.py routed_ffn). input
     [..., D] -> (out [..., D], balance_loss [1], z_loss [1], expert_load
     [num_experts] int32).
@@ -284,13 +285,28 @@ def moe_ffn(input, num_experts, d_expert, top_k, norm_topk_prob=False,
     mean_n p[n, e] and z_loss mean_n logsumexp(router logits)^2: add small
     multiples of both to the training loss. expert_load is c_e, the
     assignments an expert received; it sums to top_k * N.
+
+    router_input: the tensor the router reads, [..., D] like input; None:
+    input itself. activation: "silu" or "relu", the gate branch's. A layer
+    that is one chip's share of an expert-parallel one gives experts_held
+    (None: all) and first_expert: the router keeps num_experts columns and
+    the top_k is over all of them, the expert weights are [experts_held, ..],
+    only assignments to those experts are computed and `out` is their
+    partial sum; expert_load still counts all assignments.
     """
     helper = LayerHelper("moe_ffn", name=name)
     dtype = input.dtype
     d, e, f = int(input.shape[-1]), int(num_experts), int(d_expert)
+    held = e if experts_held is None else int(experts_held)
     if not 1 <= int(top_k) <= e:
         raise ValueError("moe_ffn top_k must be in [1, num_experts=%d], got "
                          "%r" % (e, top_k))
+    if not (1 <= held and 0 <= int(first_expert) <= e - held):
+        raise ValueError("moe_ffn cannot hold experts %d..%d of %d"
+                         % (first_expert, int(first_expert) + held - 1, e))
+    if activation not in ("silu", "relu"):
+        raise ValueError("moe_ffn activation must be 'silu' or 'relu', got "
+                         "%r" % (activation,))
     base = ParamAttr.to_attr(param_attr)
     if base is False:
         raise ValueError("moe_ffn requires parameters")
@@ -300,9 +316,19 @@ def moe_ffn(input, num_experts, d_expert, top_k, norm_topk_prob=False,
                                        shape=shape, dtype=dtype)
 
     inputs = {"X": [input], "Router": [param("router", [d, e])],
-              "WGate": [param("w_gate", [e, d, f])],
-              "WUp": [param("w_up", [e, d, f])],
-              "WDown": [param("w_down", [e, f, d])]}
+              "WGate": [param("w_gate", [held, d, f])],
+              "WUp": [param("w_up", [held, d, f])],
+              "WDown": [param("w_down", [held, f, d])]}
+    # what the defaults leave as it was is not written: a layer that holds
+    # every expert, routes from its input and gates with SiLU is the op it
+    # always was
+    attrs = {"top_k": int(top_k), "norm_topk_prob": bool(norm_topk_prob)}
+    if router_input is not None:
+        inputs["RouterX"] = [router_input]
+    if activation != "silu":
+        attrs["activation"] = str(activation)
+    if first_expert:
+        attrs["first_expert"] = int(first_expert)
     out = helper.create_variable_for_type_inference(dtype)
     balance = helper.create_variable_for_type_inference("float32")
     z = helper.create_variable_for_type_inference("float32")
@@ -312,5 +338,5 @@ def moe_ffn(input, num_experts, d_expert, top_k, norm_topk_prob=False,
         type="moe_ffn", inputs=inputs,
         outputs={"Out": [out], "BalanceLoss": [balance], "ZLoss": [z],
                  "ExpertLoad": [load]},
-        attrs={"top_k": int(top_k), "norm_topk_prob": bool(norm_topk_prob)})
+        attrs=attrs)
     return out, balance, z, load

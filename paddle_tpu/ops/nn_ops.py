@@ -405,8 +405,18 @@ def _fused_attention(ctx, ins, attrs):
     kv_len = single(ins, "KVLen") if ins.get("KVLen") else None
     causal = attrs.get("causal", False)
     scale = attrs.get("scale", None)
+    # grouped queries come from the shapes (K and V with fewer heads than Q,
+    # a divisor of them); `window` is an attr: query i sees key j only where
+    # i - j < window
+    window = attrs.get("window", None)
     mesh = ctx.mesh
     if mesh is not None and mesh.shape.get("sp", 1) > 1:
+        if window is not None or k.shape[2] != q.shape[2]:
+            raise NotImplementedError(
+                "fused_attention under an 'sp' mesh axis has neither a "
+                "window nor grouped queries: the ring and Ulysses paths "
+                "would ignore window=%r and %d key/value heads for %d "
+                "query heads" % (window, k.shape[2], q.shape[2]))
         # sp_impl picks the sequence-parallel algorithm: "ring" (default;
         # K/V blocks rotate over ICI, O(T/sp) memory, any head count) or
         # "ulysses" (all-to-all head sharding — one collective round
@@ -425,11 +435,11 @@ def _fused_attention(ctx, ins, attrs):
     if not flash_at(q.shape[1]):
         from ..parallel.ring_attention import attention_reference
         return _out(attention_reference(
-            q, k, v, causal=causal, scale=scale,
-            kv_len=kv_len).astype(q.dtype))
+            q, k, v, causal=causal, scale=scale, kv_len=kv_len,
+            window=window).astype(q.dtype))
     from . import pallas_kernels as pk
     out = pk.flash_attention(
-        q, k, v, causal=causal, scale=scale, kv_len=kv_len)
+        q, k, v, causal=causal, scale=scale, kv_len=kv_len, window=window)
     return _out(out)
 
 

@@ -97,18 +97,34 @@ def _dot(a, b, dims=(((1,), (0,)), ((), ()))):
     return lax.dot_general(a, b, dims, preferred_element_type=jnp.float32)
 
 
-def _k_blocks(qb, kv_len, causal, block_q, block_k, t_pad):
-    """How many k blocks a q block streams: only those up to its causal
-    frontier do any work (skipping the rest halves the attention FLOPs),
-    and none entirely past this row's key length."""
+def _k_blocks(qb, kv_len, causal, window, block_q, block_k, t_pad):
+    """[first, end) of the k blocks a q block streams: none past its causal
+    frontier (skipping them halves the attention FLOPs), none entirely past
+    this row's key length and, under a window, none entirely older than the
+    block's first query can see."""
     nk = jnp.minimum(t_pad // block_k, (kv_len + block_k - 1) // block_k)
     if causal:
         nk = jnp.minimum(nk, ((qb + 1) * block_q + block_k - 1) // block_k)
-    return nk
+    if window is None:
+        return 0, nk
+    return jnp.maximum(qb * block_q - (window - 1), 0) // block_k, nk
+
+
+def _visible(valid, qpos, kpos, causal, window):
+    """The mask of one block step, from `valid` = key j < kv_len: key j is
+    visible to query i iff it is valid, and j <= i where causal, and i - j <
+    window where there is a window (a query sees the `window` newest keys
+    up to itself; without `causal` the keys after it too). qpos and kpos
+    broadcast against each other."""
+    if causal:
+        valid = valid & (qpos >= kpos)
+    if window is not None:
+        valid = valid & (qpos - kpos < window)
+    return valid
 
 
 def _flash_fwd_kernel(q_ref, k_ref, v_ref, len_ref, o_ref, lse_ref, *,
-                      scale, causal, block_q, block_k, t_pad):
+                      scale, causal, window, block_q, block_k, t_pad):
     qb = pl.program_id(1)
     q = q_ref[0]                                             # [bq, d]
     bq, d = q.shape
@@ -124,9 +140,7 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, len_ref, o_ref, lse_ref, *,
         s = _dot(q, k, _NT) * scale                          # [bq, bk] f32
         kpos = kb * block_k + lax.broadcasted_iota(jnp.int32, (1, block_k),
                                                    1)
-        valid = kpos < kv_len
-        if causal:
-            valid = valid & (qpos >= kpos)
+        valid = _visible(kpos < kv_len, qpos, kpos, causal, window)
         s = jnp.where(valid, s, _NEG)
         m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
         p = jnp.exp(s - m_new)
@@ -137,7 +151,7 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, len_ref, o_ref, lse_ref, *,
         return m_new, l, acc
 
     m, l, acc = lax.fori_loop(
-        0, _k_blocks(qb, kv_len, causal, block_q, block_k, t_pad), body,
+        *_k_blocks(qb, kv_len, causal, window, block_q, block_k, t_pad), body,
         (jnp.full((bq, 1), _NEG, jnp.float32),
          jnp.zeros((bq, 1), jnp.float32),
          jnp.zeros((bq, d), jnp.float32)))
@@ -147,10 +161,23 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, len_ref, o_ref, lse_ref, *,
     lse_ref[0] = m + jnp.log(l_safe)                         # [bq, 1]
 
 
-def _flash_fwd(q, k, v, kv_len, scale, causal, block_q, block_k, interpret):
-    """q,k,v: [BH, T, D]; kv_len: [BH] int32 (true key length per row)
-    -> (out [BH, T, D], lse [BH, T])."""
+def _kv_row(bh_q, bh_kv):
+    """The index map's first coordinate of the K/V row a query row reads.
+    Rows are (batch, head) pairs, heads fastest, and query head h reads
+    key/value head h // group: row b of q reads row b // group of k and v,
+    whatever the batch. The grouped K/V are found here, by the index map,
+    and never repeated in HBM; with as many K/V heads as query heads the
+    map is the identity it always was."""
+    group = bh_q // bh_kv
+    return (lambda b: b) if group == 1 else (lambda b: b // group)
+
+
+def _flash_fwd(q, k, v, kv_len, scale, causal, window, block_q, block_k,
+               interpret):
+    """q: [BHq, T, D]; k, v: [BHkv, T, D]; kv_len: [BHq] int32 (true key
+    length per query row) -> (out [BHq, T, D], lse [BHq, T])."""
     bh, t, d = q.shape
+    kv = _kv_row(bh, k.shape[0])
     # pad T so BOTH the q grid and the k loop divide exactly (mismatched
     # block sizes otherwise drop tail k blocks / leave q rows unwritten)
     blk = int(np.lcm(block_q, block_k))
@@ -160,8 +187,8 @@ def _flash_fwd(q, k, v, kv_len, scale, causal, block_q, block_k, interpret):
         q, k, v = (jnp.pad(a, pad) for a in (q, k, v))
     lens = kv_len.reshape(bh, 1).astype(jnp.int32)
     kernel = functools.partial(
-        _flash_fwd_kernel, scale=scale, causal=causal, block_q=block_q,
-        block_k=block_k, t_pad=t_pad)
+        _flash_fwd_kernel, scale=scale, causal=causal, window=window,
+        block_q=block_q, block_k=block_k, t_pad=t_pad)
     # lens: whole array in SMEM (no blocking); lse: [BH, T, 1] so the
     # block's trailing dims are (block_q, 1) — Mosaic requires last-two
     # block dims divisible by (8, 128) or equal to the array's
@@ -170,8 +197,8 @@ def _flash_fwd(q, k, v, kv_len, scale, causal, block_q, block_k, interpret):
         grid=(bh, t_pad // block_q),
         in_specs=[
             _vmem_spec((1, block_q, d), lambda b, i: (b, i, 0)),
-            _vmem_spec((1, t_pad, d), lambda b, i: (b, 0, 0)),
-            _vmem_spec((1, t_pad, d), lambda b, i: (b, 0, 0)),
+            _vmem_spec((1, t_pad, d), lambda b, i: (kv(b), 0, 0)),
+            _vmem_spec((1, t_pad, d), lambda b, i: (kv(b), 0, 0)),
             _SMEM_WHOLE,
         ],
         out_specs=[
@@ -190,10 +217,11 @@ def _flash_fwd(q, k, v, kv_len, scale, causal, block_q, block_k, interpret):
 
 def _flash_bwd_dkdv_kernel(q_ref, g_ref, k_ref, v_ref, lse_ref, delta_ref,
                            len_ref, dk_ref, dv_ref, *, scale, causal,
-                           block_q, block_k, t_pad):
+                           window, block_q, block_k, t_pad):
     """One k-block's dK/dV: stream q-blocks past it, starting at the
     causal frontier (q blocks strictly before this k block contribute
-    nothing — the same 2x FLOP skip the forward kernel does).
+    nothing — the same 2x FLOP skip the forward kernel does) and, under a
+    window, ending at the last q block that can still see the k block.
 
     The block step works on the transposed scores, s.T = k @ q.T [bk, bq]:
     p.T and ds.T then enter their dots as they are, with no transpose of a
@@ -212,6 +240,10 @@ def _flash_bwd_dkdv_kernel(q_ref, g_ref, k_ref, v_ref, lse_ref, delta_ref,
     # key-padding early exit (mirror of the forward's): a k block entirely
     # past this row's length contributes nothing — skip its q loop
     qb0 = jnp.where(kb * block_k >= kv_len, nq, qb0)
+    if window is not None:
+        # the block's newest key, (kb + 1) * block_k - 1, is seen last by
+        # the query window - 1 after it
+        nq = jnp.minimum(nq, ((kb + 1) * block_k + window - 2) // block_q + 1)
 
     def body(qb, carry):
         dk, dv = carry
@@ -220,10 +252,10 @@ def _flash_bwd_dkdv_kernel(q_ref, g_ref, k_ref, v_ref, lse_ref, delta_ref,
         lse = lse_ref[0, pl.ds(qb, 1), :]                    # [1, bq] f32
         delta = delta_ref[0, pl.ds(qb, 1), :]
         valid = kpos < kv_len
-        if causal:
+        if causal or window is not None:
             qpos = qb * block_q + lax.broadcasted_iota(
                 jnp.int32, (1, block_q), 1)
-            valid = valid & (qpos >= kpos)
+            valid = _visible(valid, qpos, kpos, causal, window)
         p = jnp.where(valid, jnp.exp(_dot(k, q, _NT) * scale - lse), 0.0)
         dv = dv + _dot(p.astype(g.dtype), g)                 # p [bk, bq]
         ds = p * (_dot(v, g, _NT) - delta)
@@ -238,10 +270,10 @@ def _flash_bwd_dkdv_kernel(q_ref, g_ref, k_ref, v_ref, lse_ref, delta_ref,
 
 
 def _flash_bwd_dq_kernel(q_ref, g_ref, k_ref, v_ref, lse_ref, delta_ref,
-                         len_ref, dq_ref, *, scale, causal, block_q,
-                         block_k, t_pad):
-    """One q-block's dQ: stream k-blocks up to the causal / key-length
-    frontier (mirror of the forward loop)."""
+                         len_ref, dq_ref, *, scale, causal, window,
+                         block_q, block_k, t_pad):
+    """One q-block's dQ: stream the k-blocks between the window's edge and
+    the causal / key-length frontier (mirror of the forward loop)."""
     qb = pl.program_id(1)
     q = q_ref[0]                                             # [bq, d]
     g = g_ref[0]
@@ -256,20 +288,18 @@ def _flash_bwd_dq_kernel(q_ref, g_ref, k_ref, v_ref, lse_ref, delta_ref,
         v = v_ref[0, pl.ds(kb * block_k, block_k), :]
         kpos = kb * block_k + lax.broadcasted_iota(
             jnp.int32, (1, block_k), 1)
-        valid = kpos < kv_len
-        if causal:
-            valid = valid & (qpos >= kpos)
+        valid = _visible(kpos < kv_len, qpos, kpos, causal, window)
         p = jnp.where(valid, jnp.exp(_dot(q, k, _NT) * scale - lse), 0.0)
         ds = p * (_dot(g, v, _NT) - delta)
         return dq + _dot(ds.astype(k.dtype), k)
 
     dq = lax.fori_loop(
-        0, _k_blocks(qb, kv_len, causal, block_q, block_k, t_pad), body,
+        *_k_blocks(qb, kv_len, causal, window, block_q, block_k, t_pad), body,
         jnp.zeros((bq, d), jnp.float32))
     dq_ref[0] = (dq * scale).astype(dq_ref.dtype)
 
 
-def _flash_bwd(scale, causal, block_q, block_k, interpret, res, g):
+def _flash_bwd(scale, causal, window, block_q, block_k, interpret, res, g):
     """Flash backward as two pallas kernels (standard flash-attention
     recompute from the saved logsumexp — the [T, T] matrix never exists):
     a dK/dV kernel gridded over k-blocks and a dQ kernel gridded over
@@ -292,13 +322,40 @@ def _flash_bwd(scale, causal, block_q, block_k, interpret, res, g):
     blocks were 2 MiB a buffer and 8 MiB for the two double-buffered at
     T=4096. Mosaic takes every pair of {128, 256, 512} x {128, 256, 512,
     1024} at both shapes and 1024 x 512; it refuses 1024 x 1024 at D=128
-    (my chip run, PR 27). The pinned pair sets the longest sequence: T=8192
-    compiles at D=64 and D=128, T=16384 at no block size (AOT compile, PR
-    27). Streaming the pair through a second grid axis is the follow-up;
-    ring/Ulysses SP is the intended path for those lengths
-    (parallel/ring_attention.py)."""
+    (my chip run, PR 27). The pinned pair sets the longest sequence. At
+    T=8192, D=128 a bf16 operand is 2 MiB and the pair double-buffered 8
+    MiB, 13 MiB with the tiles: all three kernels compile at 512 x 512 and
+    run there (7 query heads on 1 key/value head: forward 1.40 ms, forward
+    and backward 4.72 ms a layer full, 1.19 and 3.89 ms under a window of
+    4096; my chip run, PR 31). T=16384 compiles at no block size (AOT
+    compile, PR 27). Streaming the pair through a second grid axis is the
+    follow-up; ring/Ulysses SP is the intended path for those lengths
+    (parallel/ring_attention.py).
+
+    Grouped queries (q with group x as many rows as k and v): the index
+    maps send query row b to K/V row b // group (`_kv_row`), so the pinned
+    pair of the forward and dQ kernels is fetched once a group and not once
+    a head, and nothing is repeated in HBM. dK/dV of a key/value head is
+    the sum over its group, taken AFTER the kernel: it runs a query head at
+    a time, as ungrouped, writes that head's share in float32 and XLA sums
+    the group. Summing inside the kernel would put the group on a third,
+    innermost grid axis and re-fetch the pinned q and dO pair (4 MiB) at
+    every step of it, 16 k blocks x 7 heads = 448 MiB a layer at T=8192
+    against the 59 MiB of float32 shares the sum reads; it would also give
+    the ungrouped kernel a scratch accumulator it does not have today. The
+    sum costs 0.4 ms a layer (4.72 ms against 4.31 for 7 heads on 7; my
+    chip run, PR 31); the inside variant was not built, so not measured.
+
+    Under a window the forward and dQ loops start at the first k block the
+    q block's first query can see and the dK/dV loop ends at the last q
+    block that can see the k block's newest key (`_k_blocks`); every block
+    that is computed is masked as before (`_visible`), the edges' and the
+    interior's alike, so `window=None` compiles to the kernels it always
+    did."""
     q, k, v, kv_len, delta, lse = res
     bh, t, d = q.shape
+    bh_kv = k.shape[0]
+    kv = _kv_row(bh, bh_kv)
     blk = int(np.lcm(block_q, block_k))
     t_pad = int(-(-t // blk) * blk)
     if t_pad != t:
@@ -309,17 +366,22 @@ def _flash_bwd(scale, causal, block_q, block_k, interpret, res, g):
     lse, delta = lse.astype(jnp.float32), delta.astype(jnp.float32)
     nq = t_pad // block_q
     lens = kv_len.reshape(bh, 1).astype(jnp.int32)
+    # grouped queries: the dK/dV kernel runs a query head at a time, as it
+    # does ungrouped, and gives that head's float32 share of its K/V head's
+    # gradient; the group's shares are summed after it (see _flash_bwd's
+    # docstring for why after and not inside)
+    dkv_dtype = k.dtype if bh == bh_kv else jnp.float32
 
     dk, dv = pl.pallas_call(
         functools.partial(_flash_bwd_dkdv_kernel, scale=scale,
-                          causal=causal, block_q=block_q, block_k=block_k,
-                          t_pad=t_pad),
+                          causal=causal, window=window, block_q=block_q,
+                          block_k=block_k, t_pad=t_pad),
         grid=(bh, t_pad // block_k),
         in_specs=[
             _vmem_spec((1, t_pad, d), lambda b, j: (b, 0, 0)),     # q
             _vmem_spec((1, t_pad, d), lambda b, j: (b, 0, 0)),     # g
-            _vmem_spec((1, block_k, d), lambda b, j: (b, j, 0)),   # k
-            _vmem_spec((1, block_k, d), lambda b, j: (b, j, 0)),   # v
+            _vmem_spec((1, block_k, d), lambda b, j: (kv(b), j, 0)),   # k
+            _vmem_spec((1, block_k, d), lambda b, j: (kv(b), j, 0)),   # v
             _vmem_spec((1, nq, block_q), lambda b, j: (b, 0, 0)),  # lse
             _vmem_spec((1, nq, block_q), lambda b, j: (b, 0, 0)),  # delta
             _SMEM_WHOLE,
@@ -329,23 +391,27 @@ def _flash_bwd(scale, causal, block_q, block_k, interpret, res, g):
             _vmem_spec((1, block_k, d), lambda b, j: (b, j, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bh, t_pad, d), k.dtype),
-            jax.ShapeDtypeStruct((bh, t_pad, d), v.dtype),
+            jax.ShapeDtypeStruct((bh, t_pad, d), dkv_dtype),
+            jax.ShapeDtypeStruct((bh, t_pad, d), dkv_dtype),
         ],
         interpret=interpret,
         name="ptpu_flash_bwd_dkdv",
     )(q, g, k, v, lse.reshape(bh, nq, block_q),
       delta.reshape(bh, nq, block_q), lens)
+    if bh != bh_kv:
+        dk, dv = (a.reshape(bh_kv, bh // bh_kv, t_pad, d).sum(1)
+                  .astype(k.dtype) for a in (dk, dv))
 
     dq = pl.pallas_call(
         functools.partial(_flash_bwd_dq_kernel, scale=scale, causal=causal,
-                          block_q=block_q, block_k=block_k, t_pad=t_pad),
+                          window=window, block_q=block_q, block_k=block_k,
+                          t_pad=t_pad),
         grid=(bh, t_pad // block_q),
         in_specs=[
             _vmem_spec((1, block_q, d), lambda b, i: (b, i, 0)),   # q
             _vmem_spec((1, block_q, d), lambda b, i: (b, i, 0)),   # g
-            _vmem_spec((1, t_pad, d), lambda b, i: (b, 0, 0)),     # k
-            _vmem_spec((1, t_pad, d), lambda b, i: (b, 0, 0)),     # v
+            _vmem_spec((1, t_pad, d), lambda b, i: (kv(b), 0, 0)),  # k
+            _vmem_spec((1, t_pad, d), lambda b, i: (kv(b), 0, 0)),  # v
             _vmem_spec((1, block_q, 1), lambda b, i: (b, i, 0)),   # lse
             _vmem_spec((1, block_q, 1), lambda b, i: (b, i, 0)),   # delta
             _SMEM_WHOLE,
@@ -390,22 +456,23 @@ def _from_bh(x, b):
 # boundary, and the two layout changes are inside it: the residuals are the
 # op's own inputs and output (live anyway for the ops around it) plus the
 # logsumexp, and _wait_for keeps them in that form up to the backward pass.
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
-def _flash_core(q, k, v, kv_len, scale, causal, block_q, block_k,
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9))
+def _flash_core(q, k, v, kv_len, scale, causal, window, block_q, block_k,
                 interpret):
-    return _flash_core_fwd(q, k, v, kv_len, scale, causal, block_q, block_k,
-                           interpret)[0]
+    return _flash_core_fwd(q, k, v, kv_len, scale, causal, window, block_q,
+                           block_k, interpret)[0]
 
 
-def _flash_core_fwd(q, k, v, kv_len, scale, causal, block_q, block_k,
+def _flash_core_fwd(q, k, v, kv_len, scale, causal, window, block_q, block_k,
                     interpret):
     out, lse = _flash_fwd(_to_bh(q), _to_bh(k), _to_bh(v), kv_len, scale,
-                          causal, block_q, block_k, interpret)
+                          causal, window, block_q, block_k, interpret)
     out = _from_bh(out, q.shape[0])
     return out, (q, k, v, kv_len, out, lse)
 
 
-def _flash_core_bwd(scale, causal, block_q, block_k, interpret, res, g):
+def _flash_core_bwd(scale, causal, window, block_q, block_k, interpret, res,
+                    g):
     q, k, v, kv_len, out, lse = res
     g, q, k, v, out, lse = _wait_for(g, q, k, v, out, lse)
     b, t, h, _ = q.shape
@@ -414,7 +481,7 @@ def _flash_core_bwd(scale, causal, block_q, block_k, interpret, res, g):
     delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32),
                     axis=-1).transpose(0, 2, 1).reshape(b * h, t)
     dq, dk, dv = _flash_bwd(
-        scale, causal, block_q, block_k, interpret,
+        scale, causal, window, block_q, block_k, interpret,
         (_to_bh(q), _to_bh(k), _to_bh(v), kv_len, delta, lse), _to_bh(g))
     return _from_bh(dq, b), _from_bh(dk, b), _from_bh(dv, b), None
 
@@ -423,10 +490,23 @@ _flash_core.defvjp(_flash_core_fwd, _flash_core_bwd)
 
 
 def flash_attention(q, k, v, causal=False, scale=None, kv_len=None,
-                    block_q=None, block_k=None, interpret=None):
-    """Exact attention, flash-style. q,k,v: [B, T, H, D] (BTHD, the layout
-    ring_attention uses); returns [B, T, H, D]. block_q / block_k default to
-    kernel_config.DEFAULT_TILES["attn"] and are clamped to T.
+                    block_q=None, block_k=None, interpret=None, window=None):
+    """Exact attention, flash-style. q: [B, T, Hq, D], k, v: [B, T, Hkv, D]
+    (BTHD, the layout ring_attention uses); returns [B, T, Hq, D]. block_q /
+    block_k default to kernel_config.DEFAULT_TILES["attn"] and are clamped
+    to T.
+
+    Grouped queries come from the shapes: Hq a multiple of Hkv, and query
+    head h reads key/value head h // (Hq // Hkv); the kernels find that
+    head's blocks by their index maps, and no copy of K or V is repeated in
+    HBM. dk and dv come back [B, T, Hkv, D], summed over each group.
+
+    window: None, or a static int: query i sees key j only where i - j <
+    window, so with `causal` the `window` newest keys up to itself (the
+    Hugging Face sliding-window mask's convention). K blocks wholly older
+    than the window are skipped like those past the causal frontier, in
+    all three kernels; a window of T or more changes nothing but the loop
+    bounds' arithmetic.
 
     kv_len: optional [B] int true key lengths — keys at position >= kv_len
     are masked out AND their blocks skipped entirely (the padded-batch
@@ -449,6 +529,14 @@ def flash_attention(q, k, v, causal=False, scale=None, kv_len=None,
     if interpret is None:
         interpret = _interpret_default()
     b, t, h, d = q.shape
+    if k.shape != v.shape or h % k.shape[2]:
+        raise ValueError(
+            "flash_attention: q %s needs k and v alike, [B, T, Hkv, D] with "
+            "Hkv dividing the query heads; got k %s, v %s"
+            % (q.shape, k.shape, v.shape))
+    if window is not None and int(window) < 1:
+        raise ValueError("flash_attention: window must be None or >= 1, got "
+                         "%r" % (window,))
     if scale is None:
         scale = 1.0 / float(np.sqrt(d))
     block_q = max(8, min(_tile("attn", "block_q", block_q),
@@ -460,6 +548,7 @@ def flash_attention(q, k, v, causal=False, scale=None, kv_len=None,
     else:
         lens = jnp.repeat(jnp.asarray(kv_len, jnp.int32).reshape(b), h)
     return _flash_core(q, k, v, lens, float(scale), bool(causal),
+                       None if window is None else int(window),
                        int(block_q), int(block_k), bool(interpret))
 
 

@@ -139,18 +139,25 @@ registry.register("moe", _moe_lower, infer=_moe_infer)
 
 
 def _moe_ffn_lower(ctx, ins, attrs):
-    """Dropless top-k routed gated-SiLU experts (parallel/moe.py
-    routed_ffn). Under AMP the router stays float32 and the experts compute
-    in bfloat16 from the float32 master weights; the op decides that here
-    because one input, X, feeds both."""
+    """Dropless top-k routed gated experts (parallel/moe.py routed_ffn).
+    Under AMP the router stays float32 and the experts compute in bfloat16
+    from the float32 master weights; the op decides that here because one
+    input, X, may feed both. RouterX, where the model gives it, is what the
+    router reads instead of X; the weights' leading dimension is the experts
+    held, `first_expert` the index of the first."""
     from ..parallel.moe import routed_ffn
     x = single(ins, "X")
+    router_x = single(ins, "RouterX") if ins.get("RouterX") else None
     out, balance, z, load = routed_ffn(
         x.reshape(-1, x.shape[-1]), single(ins, "Router"),
         single(ins, "WGate"), single(ins, "WUp"), single(ins, "WDown"),
         top_k=int(attrs["top_k"]),
         norm_topk_prob=bool(attrs.get("norm_topk_prob", False)),
-        expert_dtype=jnp.bfloat16 if getattr(ctx, "amp", False) else None)
+        expert_dtype=jnp.bfloat16 if getattr(ctx, "amp", False) else None,
+        router_x=None if router_x is None
+        else router_x.reshape(-1, router_x.shape[-1]),
+        activation=str(attrs.get("activation", "silu")),
+        first_expert=int(attrs.get("first_expert", 0)))
     return {"Out": [out.reshape(x.shape)], "BalanceLoss": [balance],
             "ZLoss": [z], "ExpertLoad": [load]}
 

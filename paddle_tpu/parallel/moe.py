@@ -15,7 +15,10 @@ differentiable, jit/pjit-friendly, composable with dp on the same mesh.
 `routed_ffn` (the `moe_ffn` op) is the other design, for one chip today:
 top-k routing without capacity, the assignments sorted by expert and the
 expert matmuls grouped over them, so no token is dropped and no operation
-is spent on an expert a token did not choose.
+is spent on an expert a token did not choose. It can be told which experts
+it holds: it then routes over all of them and computes its own share of the
+layer's output, as one chip of an expert-parallel layer would, without the
+exchange.
 """
 import functools
 
@@ -165,15 +168,59 @@ def _gated_silu(gate, up):
             * up.astype(jnp.float32)).astype(gate.dtype)
 
 
-def routed_ffn(x, router, w_gate, w_up, w_down, top_k, norm_topk_prob=False,
-               expert_dtype=None):
-    """Dropless top-k routed gated-SiLU experts over tokens x [N, D].
+def _gated_relu(gate, up):
+    """relu(gate) * up (ReGLU) in float32, back in the inputs' dtype."""
+    return (jax.nn.relu(gate.astype(jnp.float32))
+            * up.astype(jnp.float32)).astype(gate.dtype)
 
-    router [D, E]; w_gate, w_up [E, D, F]; w_down [E, F, D]; no bias. Every
-    one of the top_k * N assignments is computed, whatever the imbalance:
-    there is no capacity. The assignments are sorted by expert and the three
-    expert matmuls run grouped over them (`_grouped_matmul`), so they cost
-    the top_k active experts' operations and not the E stored ones.
+
+def _gated(gate, up, activation):
+    if activation not in ("silu", "relu"):
+        raise ValueError("routed_ffn activation must be 'silu' or 'relu', "
+                         "got %r" % (activation,))
+    return (_gated_silu if activation == "silu" else _gated_relu)(gate, up)
+
+
+def routed_ffn(x, router, w_gate, w_up, w_down, top_k, norm_topk_prob=False,
+               expert_dtype=None, router_x=None, activation="silu",
+               first_expert=0):
+    """Dropless top-k routed gated experts over tokens x [N, D].
+
+    router [D, E]; w_gate, w_up [H, D, F]; w_down [H, F, D]; no bias. The
+    gated unit is act(x @ w_gate) * (x @ w_up) with `activation` "silu"
+    (SwiGLU) or "relu" (ReGLU). The router reads `router_x` [N, D] where it
+    is given (a model that routes from another tensor than the one the
+    experts transform, so that a deployment can fetch experts early) and x
+    itself where it is None.
+
+    H is the experts held: the weights' leading dimension. With H = E every
+    expert lives here. With H < E this is one chip's share of an
+    expert-parallel layer: it holds experts first_expert .. first_expert +
+    H - 1, routes every token over all E, computes the assignments that fall
+    on its own experts, and returns that partial sum (a token none of whose
+    choices is held gets zeros). The shares of all chips add up to the
+    whole layer's output; nothing here stands in for the exchange.
+
+    Every assignment to a held expert is computed, whatever the imbalance:
+    there is no capacity. The assignments are sorted by expert, those to
+    experts that are not held last, and the three expert matmuls run grouped
+    over the sorted rows (`_grouped_matmul`) with the held experts' counts
+    as group sizes, so they cost the held assignments' operations and not
+    the stored experts'. The row buffer is top_k * N rows whatever H is,
+    because every one of a token's choices may be held (1.5 N on average at
+    6 of 64 with 16 held); the rows past the groups' sum belong to no group.
+    What `ragged_dot` does with them depends on the backend: on the CPU it
+    writes zeros there and its transpose gives them a zero gradient; on the
+    v5e it neither reads nor writes them, so its time follows the groups'
+    sum and not the buffer (0.75 ms for 12288 of 49152 rows of [2560] x
+    [16, 2560, 768], 2.03 ms for all 49152), and they hold whatever the
+    buffer held before, in the output and in the gradient of its left
+    operand alike (my chip run, PR 31). So nothing may rest on them: the
+    gathered rows past the sum are set to zero before the first matmul,
+    which makes their gradient zero whatever the matmul's transpose left
+    there, before `_take_rows`' backward adds a row's gradient into its
+    token's; and the combine selects (not multiplies) the held assignments'
+    outputs, so no NaN in an unwritten row reaches a token.
 
     The router's matmul, softmax and top-k are float32 at full precision
     whatever x's dtype; the experts compute in `expert_dtype` (x's own if
@@ -183,12 +230,17 @@ def routed_ffn(x, router, w_gate, w_up, w_down, top_k, norm_topk_prob=False,
     Returns (out [N, D] in the experts' dtype,
              load-balance term [1]: E * sum_e (c_e / N) * mean_n p[n, e],
              router z term [1]: mean_n logsumexp(logits[n])^2,
-             c [E] int32: assignments an expert, sum = top_k * N).
+             c [E] int32: assignments an expert over ALL E, sum = top_k * N;
+             c[first_expert : first_expert + H] are the rows computed).
     """
     n, d = x.shape
-    e = router.shape[1]
+    e, held = router.shape[1], w_gate.shape[0]
+    if not 0 <= first_expert <= e - held:
+        raise ValueError("routed_ffn holds experts %d..%d of %d"
+                         % (first_expert, first_expert + held - 1, e))
     dtype = jnp.dtype(expert_dtype or x.dtype)
-    logits = jnp.dot(x.astype(jnp.float32), router.astype(jnp.float32),
+    logits = jnp.dot((x if router_x is None else router_x)
+                     .astype(jnp.float32), router.astype(jnp.float32),
                      precision=jax.lax.Precision.HIGHEST)
     lse = jax.nn.logsumexp(logits, axis=-1)
     probs = jnp.exp(logits - lse[:, None])
@@ -199,16 +251,29 @@ def routed_ffn(x, router, w_gate, w_up, w_down, top_k, norm_topk_prob=False,
     # assignment a = n * top_k + j; `order` lists the assignments by expert
     # (stable, so by token inside an expert), `rank` is where each went
     expert = expert.reshape(-1)
-    order = jnp.argsort(expert, stable=True)
+    if held == e:
+        here, sort_key = None, expert
+    else:
+        local = expert - first_expert
+        here = (local >= 0) & (local < held)
+        sort_key = jnp.where(here, local, held)    # not held: past the groups
+    order = jnp.argsort(sort_key, stable=True)
     rank = jnp.zeros_like(order).at[order].set(
         jnp.arange(order.shape[0], dtype=order.dtype))
     load = jnp.sum(expert[:, None] == jnp.arange(e), axis=0, dtype=jnp.int32)
+    sizes = load if here is None else load[first_expert:first_expert + held]
 
     rows = _take_rows(x.astype(dtype), order, rank, top_k)
-    hidden = _gated_silu(_grouped_matmul(rows, w_gate.astype(dtype), load),
-                         _grouped_matmul(rows, w_up.astype(dtype), load))
-    y = _grouped_matmul(hidden, w_down.astype(dtype), load)
+    if here is not None:
+        in_group = jnp.arange(rows.shape[0]) < sizes.sum()
+        rows = jnp.where(in_group[:, None], rows, 0)
+    hidden = _gated(_grouped_matmul(rows, w_gate.astype(dtype), sizes),
+                    _grouped_matmul(rows, w_up.astype(dtype), sizes),
+                    activation)
+    y = _grouped_matmul(hidden, w_down.astype(dtype), sizes)
     y = _take_rows(y, rank, order, 1).reshape(n, top_k, d).astype(jnp.float32)
+    if here is not None:
+        y = jnp.where(here.reshape(n, top_k, 1), y, 0.0)
     out = jnp.sum(y * gate[:, :, None], axis=1).astype(dtype)
 
     balance = e * jnp.sum(load.astype(jnp.float32) / n * probs.mean(0))

@@ -30,17 +30,32 @@ __all__ = ["ring_attention", "attention_reference", "ring_attention_sharded",
 _NEG_INF = -1e30
 
 
-def attention_reference(q, k, v, causal=False, scale=None, kv_len=None):
-    """Dense single-device attention, [B,T,H,D]. The numerical reference the
-    ring path must match; also the fallback when no `sp` axis exists.
-    kv_len: optional [B] true key lengths (key-padding mask)."""
+def attention_reference(q, k, v, causal=False, scale=None, kv_len=None,
+                        window=None):
+    """Dense single-device attention, q [B,T,Hq,D], k and v [B,T,Hkv,D]. The
+    numerical reference the ring path and the flash kernels must match; also
+    the fallback when no `sp` axis exists and the path under the flash
+    crossover. kv_len: optional [B] true key lengths (key-padding mask).
+    Grouped queries from the shapes: query head h reads key/value head
+    h // (Hq // Hkv). window: None or an int, query i sees key j only where
+    i - j < window (with `causal`, the `window` newest keys up to itself)."""
     if scale is None:
         scale = 1.0 / np.sqrt(q.shape[-1])
+    group = q.shape[2] // k.shape[2]
+    if group * k.shape[2] != q.shape[2]:
+        raise ValueError("attention_reference: %d query heads are no "
+                         "multiple of %d key/value heads"
+                         % (q.shape[2], k.shape[2]))
+    if group > 1:
+        k, v = (jnp.repeat(x, group, axis=2) for x in (k, v))
     logits = jnp.einsum("bqhd,bkhd->bhqk", q, k) * scale
+    tq, tk = logits.shape[-2], logits.shape[-1]
     if causal:
-        tq, tk = logits.shape[-2], logits.shape[-1]
         mask = jnp.tril(jnp.ones((tq, tk), dtype=bool))
         logits = jnp.where(mask, logits, _NEG_INF)
+    if window is not None:
+        age = jnp.arange(tq)[:, None] - jnp.arange(tk)[None, :]
+        logits = jnp.where(age < window, logits, _NEG_INF)
     if kv_len is not None:
         # accept [B] or the fluid-convention [B, 1] (the flash kernel
         # normalizes the same way; a [B, 1] here would silently

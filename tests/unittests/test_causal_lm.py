@@ -56,7 +56,8 @@ def _feed(seed=0):
 def _moe_layers_lowered():
     from paddle_tpu.parallel.moe import GROUPED_MATMUL
     return REGISTRY.counter("ptpu_moe_layers_total", "").value(
-        top_k="2", experts="8", path=GROUPED_MATMUL)
+        top_k="2", experts="8", held="8", activation="silu",
+        router_input="own", path=GROUPED_MATMUL)
 
 
 def _run_program(amp):
@@ -162,8 +163,12 @@ def test_the_tolerance_catches(mutant, program, want, monkeypatch):
 
 
 def test_builder_refuses_what_it_cannot_build():
-    with pytest.raises(NotImplementedError, match="num_key_value_heads"):
-        causal_lm.resolve(dict(CFG, num_key_value_heads=2))
+    # grouped queries are built since PR 31; a count of key/value heads that
+    # does not divide the query heads is still no model
+    with pytest.raises(ValueError, match="key/value"):
+        causal_lm.resolve(dict(CFG, num_key_value_heads=3))
+    with pytest.raises(NotImplementedError, match="rope_scaling"):
+        causal_lm.resolve(dict(CFG, rope_scaling={"type": "yarn"}))
     with pytest.raises(NotImplementedError, match="tie_word_embeddings"):
         causal_lm.resolve(dict(CFG, tie_word_embeddings=True))
 

@@ -1,0 +1,432 @@
+"""SmallThinker-21BA3B's layer through models/causal_lm.py at a tiny size on
+the CPU: grouped queries, a window and rotary positions by layer (period 4),
+the router read before attention, ReGLU experts of which one chip holds a
+share. The Program against models/causal_lm_reference.py (loss, logits,
+every gradient) at T > window; `routed_ffn` told which experts it holds; and
+the test that ties the share to the model: the shares of all chips sum to
+the whole layer.
+"""
+import types
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+import paddle_tpu as fluid
+from paddle_tpu.models import causal_lm, causal_lm_reference as reference
+from paddle_tpu.observability.registry import REGISTRY
+from paddle_tpu.parallel import moe
+
+# chip 1 of the 2 that share a layer: 4 of 8 query heads on 2 of 4 key/value
+# heads of 8, experts 8..15 of 16, half a vocabulary of 128; four layers, one
+# period: full attention without rotary, then three windowed (16) with
+CFG = dict(
+    hidden_size=32, head_dim=8, num_attention_heads=4, num_key_value_heads=2,
+    num_hidden_layers=4, vocab_size=64, moe_ffn_hidden_size=16,
+    moe_num_primary_experts=8, moe_num_active_primary_experts=3,
+    moe_primary_router_apply_softmax=True, norm_topk_prob=True,
+    rms_norm_eps=1e-6, rope_theta=1.5e6, rope_scaling=None,
+    rope_layout=[0, 1, 1, 1] * 2, sliding_window_layout=[0, 1, 1, 1] * 2,
+    sliding_window_size=16, tie_word_embeddings=False, hidden_act="relu",
+    router_input="pre_attention", router_aux_loss_coef=0.0,
+    router_z_loss_coef=0.0,
+    share=dict(chips=2, chip=1, published=dict(
+        num_attention_heads=8, num_key_value_heads=4,
+        moe_num_primary_experts=16, vocab_size=128)))
+B, T = 2, 40                    # T > the window
+TOLERANCE = 2e-5                # float32 against float32, other summation order
+
+
+def _error(got, want):
+    want = np.asarray(want, np.float32)
+    got = np.asarray(got, np.float32).reshape(want.shape)
+    return float(np.abs(got - want).max() / np.abs(want).max())
+
+
+def _feed(seed=0):
+    tok = np.random.RandomState(seed).randint(0, CFG["vocab_size"],
+                                              (B, T + 1))
+    return {"ids": tok[:, :-1],
+            "pos": np.broadcast_to(np.arange(T), (B, T)).copy(),
+            "labels": tok[:, 1:, None]}
+
+
+def _counter(name, **labels):
+    return REGISTRY.counter(name, "").value(**labels)
+
+
+def _counts():
+    from paddle_tpu.parallel.moe import GROUPED_MATMUL
+    return {
+        "moe": _counter("ptpu_moe_layers_total", top_k="3", experts="16",
+                        held="8", activation="relu",
+                        router_input="pre_attention", path=GROUPED_MATMUL),
+        "full": _counter("ptpu_attention_layers_total", kind="full",
+                         window="0", q_heads="4", kv_heads="2", path="dense"),
+        "window": _counter("ptpu_attention_layers_total", kind="window",
+                           window="16", q_heads="4", kv_heads="2",
+                           path="dense")}
+
+
+def _run_program(amp):
+    main, startup = fluid.Program(), fluid.Program()
+    main.random_seed = startup.random_seed = 11
+    with fluid.unique_name.guard(), fluid.program_guard(main, startup):
+        if amp:
+            main.enable_mixed_precision()
+        loss, logits, load = causal_lm.build_train(CFG, T)
+    params = main.global_block().all_parameters()
+    scope = fluid.Scope()
+    before = _counts()
+    with fluid.scope_guard(scope):
+        exe = fluid.Executor(fluid.CPUPlace())
+        exe.run(startup)
+        weights = [np.asarray(scope.get(p.name)) for p in params]
+        out = exe.run(main, feed=_feed(), fetch_list=[loss, logits, load]
+                      + [p.name + "@GRAD" for p in params])
+    after = _counts()
+    got = {"loss": out[0], "logits": out[1], "expert_load": out[2],
+           "grads": dict(zip((p.name for p in params), out[3:])),
+           "counted": {k: after[k] - before[k] for k in after},
+           "ops": [op.type for op in main.global_block().ops]}
+    return params, weights, got
+
+
+@pytest.fixture(scope="module")
+def program():
+    return _run_program(amp=False)
+
+
+@pytest.fixture(scope="module")
+def want(program):
+    params, weights, _ = program
+    feed = {k: jnp.asarray(v) for k, v in _feed().items()}
+    (loss, (logits, load)), grads = jax.jit(
+        lambda p: reference.loss_and_grads(CFG, p, feed["ids"], feed["pos"],
+                                           feed["labels"]))(weights)
+    return {"loss": loss, "logits": logits, "expert_load": load,
+            "grads": dict(zip((p.name for p in params), grads))}
+
+
+def test_resolve_maps_smallthinkers_keys():
+    c = causal_lm.resolve(CFG)
+    assert (c["num_experts"], c["experts_held"], c["first_expert"]) \
+        == (16, 8, 8)
+    assert (c["intermediate_size"], c["num_experts_per_tok"]) == (16, 3)
+    assert c["rope_layers"] == [False, True, True, True]
+    assert c["window_layers"] == [None, 16, 16, 16]
+    assert causal_lm._layer(c, 0)["rope_theta"] is None
+    assert causal_lm._layer(c, 1)["window"] == 16
+    # a model whose layers are all alike sees its own config in every layer
+    plain = causal_lm.resolve(dict(
+        vocab_size=8, hidden_size=8, num_hidden_layers=2,
+        num_attention_heads=2, intermediate_size=4))
+    assert causal_lm._layer(plain, 1) is plain
+    assert plain["head_dim"] == 4 and plain["experts_held"] == 0
+
+
+@pytest.mark.parametrize("edit,error,match", [
+    (dict(rope_layout=[0, 1]), ValueError, "rope_layout"),
+    (dict(sliding_window_layout=[1]), ValueError, "sliding_window_layout"),
+    (dict(moe_primary_router_apply_softmax=False), NotImplementedError,
+     "moe_primary_router_apply_softmax"),
+    (dict(hidden_act="gelu"), NotImplementedError, "hidden_act"),
+    (dict(router_input="post_mlp"), NotImplementedError, "router_input"),
+    (dict(share=dict(chips=2, chip=2, published=dict(
+        moe_num_primary_experts=16))), ValueError, "cannot hold")])
+def test_resolve_refuses_what_the_builder_cannot_build(edit, error, match):
+    with pytest.raises(error, match=match):
+        causal_lm.resolve(dict(CFG, **edit))
+
+
+def test_program_has_the_shares_shapes(program):
+    params, weights, got = program
+    shapes = [w.shape for w in weights]
+    assert len(params) == 1 + 4 * 10 + 2
+    assert shapes[0] == (64, 32)                        # half the vocabulary
+    assert shapes[1:11] == [
+        (32,), (32, 32), (32, 16), (32, 16), (32, 32), (32,),
+        (32, 16), (8, 32, 16), (8, 32, 16), (8, 16, 32)]
+    assert shapes[-1] == (32, 64)
+    assert got["ops"].count("rotary_embedding") == 2 * 3    # not on layer 0
+    assert got["ops"].count("fused_attention") == 4
+    assert got["ops"].count("moe_ffn") == 4
+
+
+def test_program_agrees_with_the_reference(program, want):
+    _, _, got = program
+    assert _error(got["loss"], want["loss"]) < TOLERANCE
+    assert _error(got["logits"], want["logits"]) < TOLERANCE
+    np.testing.assert_array_equal(got["expert_load"], want["expert_load"])
+    # every assignment is counted, over all 16 experts, held or not
+    assert got["expert_load"].shape == (16,)
+    assert got["expert_load"].sum() == 4 * 3 * B * T
+    assert 0 < got["expert_load"][8:].sum() < got["expert_load"].sum()
+
+
+def test_every_gradient_agrees_with_the_reference(program, want):
+    params, _, got = program
+    errors = {p.name: _error(got["grads"][p.name], want["grads"][p.name])
+              for p in params}
+    assert max(errors.values()) < TOLERANCE, errors
+    assert all(np.abs(want["grads"][p.name]).max() > 0 for p in params)
+
+
+def test_amp_program_agrees_with_the_reference(want):
+    _, _, got = _run_program(amp=True)
+    assert got["logits"].dtype == jnp.bfloat16
+    assert _error(got["logits"], want["logits"]) < 5e-2
+    assert _error(got["loss"], want["loss"]) < 1e-3
+    assert got["expert_load"].sum() == 4 * 3 * B * T
+
+
+def test_the_new_counters_and_labels(program):
+    """ptpu_moe_layers_total says what is held, the activation and what the
+    router reads; ptpu_attention_layers_total counts forward fused_attention
+    ops by kind, window, heads and path, not a grad op's replay."""
+    assert program[2]["counted"] == {"moe": 4, "full": 1, "window": 3}
+
+
+@pytest.mark.parametrize("mutant", ["window_off", "rope_on_global",
+                                    "silu_for_relu", "router_after_attention",
+                                    "wrong_kv_head", "top5"])
+def test_reference_tells_a_broken_model(program, want, mutant):
+    """The reference with one mechanism changed is further from the Program
+    than the tolerance: the comparison sees each of them."""
+    _, weights, got = program
+    cfg = dict(CFG)
+    if mutant == "window_off":
+        cfg["sliding_window_layout"] = [0] * 8
+    elif mutant == "rope_on_global":
+        cfg["rope_layout"] = [1] * 8
+    elif mutant == "silu_for_relu":
+        cfg["hidden_act"] = "silu"
+    elif mutant == "router_after_attention":
+        cfg["router_input"] = "own"
+    elif mutant == "top5":
+        cfg["moe_num_active_primary_experts"] = 2
+    elif mutant == "wrong_kv_head":     # query head h reads head 1 - h // 2
+        weights = [np.concatenate([w[:, 8:], w[:, :8]], 1)
+                   if w.shape == (32, 16) and i % 10 in (3, 4) else w
+                   for i, w in enumerate(weights)]
+    feed = {k: jnp.asarray(v) for k, v in _feed().items()}
+    logits = reference.forward(cfg, weights, feed["ids"], feed["pos"])[0]
+    assert _error(got["logits"], logits) > 10 * TOLERANCE
+
+
+# --- routed_ffn told which experts it holds ----------------------------------
+
+def _layer_inputs(seed, n=48, d=16, e=16, f=8):
+    rng = np.random.RandomState(seed)
+    x, a = (jnp.asarray(rng.randn(n, d), jnp.float32) for _ in range(2))
+    router = jnp.asarray(rng.randn(d, e), jnp.float32)
+    wg, wu = (jnp.asarray(rng.randn(e, d, f) * 0.3, jnp.float32)
+              for _ in range(2))
+    wd = jnp.asarray(rng.randn(e, f, d) * 0.3, jnp.float32)
+    return x, a, router, wg, wu, wd
+
+
+REF_C = {"num_experts": 16, "num_experts_per_tok": 6, "norm_topk_prob": True,
+         "hidden_act": "relu"}
+
+
+@pytest.mark.parametrize("activation", ["relu", "silu"])
+def test_routed_ffn_reads_the_router_elsewhere(activation):
+    x, a, router, wg, wu, wd = _layer_inputs(1)
+    c = dict(REF_C, hidden_act=activation)
+    with jax.default_matmul_precision("highest"):
+        got = moe.routed_ffn(x, router, wg, wu, wd, top_k=6,
+                             norm_topk_prob=True, router_x=a,
+                             activation=activation)
+        want = reference.routed_experts(x, router, wg, wu, wd, c, router_x=a)
+        own = reference.routed_experts(x, router, wg, wu, wd, c)
+    assert _error(got[0], want[0]) < TOLERANCE
+    np.testing.assert_array_equal(got[3], want[3])
+    assert _error(got[0], own[0]) > 0.1     # the router's input matters
+
+
+def test_four_shares_of_four_experts_sum_to_the_layer():
+    """Forward and every gradient: each share routes over all 16, computes
+    its 4, and the four partial sums (and the four gradients of x and of the
+    router's input) add up to what the uncut layer gives."""
+    x, a, router, wg, wu, wd = _layer_inputs(2)
+
+    def share(i, x, a, router, wg, wu, wd):
+        sl = slice(4 * i, 4 * i + 4)
+        return moe.routed_ffn(x, router, wg[sl], wu[sl], wd[sl], top_k=6,
+                              norm_topk_prob=True, router_x=a,
+                              activation="relu", first_expert=4 * i)
+
+    def whole(x, a, router, wg, wu, wd):
+        return reference.routed_experts(x, router, wg, wu, wd, REF_C,
+                                        router_x=a)
+
+    args = (x, a, router, wg, wu, wd)
+    g = jnp.asarray(np.random.RandomState(3).randn(*x.shape), jnp.float32)
+    with jax.default_matmul_precision("highest"):
+        want, vjp = jax.vjp(lambda *p: whole(*p)[0], *args)
+        want_grads = vjp(g)
+        outs, grads, rows = [], [], 0
+        for i in range(4):
+            out, _, _, load = share(i, *args)
+            np.testing.assert_array_equal(load, whole(*args)[3])
+            rows += int(load[4 * i:4 * i + 4].sum())
+            outs.append(out)
+            grads.append(jax.vjp(lambda *p: share(i, *p)[0], *args)[1](g))
+    assert rows == 6 * x.shape[0]       # every assignment, once
+    assert _error(sum(outs), want) < TOLERANCE
+    assert all(float(jnp.abs(o).max()) > 0 for o in outs)
+    for j, name in enumerate(("x", "router_x", "router", "w_gate", "w_up",
+                              "w_down")):
+        total = sum(gr[j] for gr in grads)
+        assert _error(total, want_grads[j]) < 5 * TOLERANCE, name
+
+
+def test_rows_past_the_groups_sum_reach_nothing(monkeypatch):
+    """On the CPU ragged_dot writes zeros past the groups' sum; on the v5e
+    it leaves those rows unwritten (PR 31's chip run). Here they are filled
+    with NaN, forward and in the transposes, and neither the output nor a
+    gradient sees it."""
+    x, a, router, wg, wu, wd = _layer_inputs(4)
+    grouped = moe._grouped_matmul
+
+    @jax.custom_vjp
+    def poison(y, total):
+        return jnp.where(jnp.arange(y.shape[0])[:, None] < total, y, jnp.nan)
+
+    poison.defvjp(lambda y, total: (poison(y, total), total),
+                  lambda total, g: (poison(g, total), None))
+
+    def unwritten(lhs, rhs, sizes):
+        total = sizes.sum()
+        return poison(grouped(poison(lhs, total), rhs, sizes), total)
+
+    def run(x, a, wg, wu, wd):
+        return moe.routed_ffn(x, router, wg[:4], wu[:4], wd[:4], top_k=6,
+                              norm_topk_prob=True, router_x=a,
+                              activation="relu")[0]
+
+    g = jnp.ones_like(x)
+    want, vjp = jax.vjp(run, x, a, wg, wu, wd)
+    want_grads = vjp(g)
+    monkeypatch.setattr(moe, "_grouped_matmul", unwritten)
+    got, vjp = jax.vjp(run, x, a, wg, wu, wd)
+    got_grads = vjp(g)
+    assert bool(jnp.isfinite(got).all())
+    np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-6)
+    for a_, b_ in zip(got_grads, want_grads):
+        assert bool(jnp.isfinite(a_).all())
+        np.testing.assert_allclose(a_, b_, rtol=1e-5, atol=1e-6)
+
+
+def test_the_op_takes_the_share_through_the_layer():
+    with pytest.raises(ValueError, match="cannot hold"):
+        with fluid.program_guard(fluid.Program(), fluid.Program()):
+            x = fluid.layers.data("x", [4, 8], dtype="float32")
+            fluid.layers.moe_ffn(x, num_experts=8, d_expert=4, top_k=2,
+                                 experts_held=4, first_expert=6)
+    with pytest.raises(ValueError, match="activation"):
+        with fluid.program_guard(fluid.Program(), fluid.Program()):
+            x = fluid.layers.data("x", [4, 8], dtype="float32")
+            fluid.layers.moe_ffn(x, num_experts=8, d_expert=4, top_k=2,
+                                 activation="gelu")
+
+
+# --- the share tied to the model ---------------------------------------------
+
+def test_the_shares_of_a_layer_sum_to_the_whole_layer():
+    """One layer on one input, 4 chips: chip i holds query heads 2i, 2i + 1
+    on key/value head i, and experts 4i .. 4i + 3. The four attention
+    outputs sum to the whole layer's (8 heads on 4), and the four expert
+    outputs, computed by the Program's routed_ffn from that same h1, sum to
+    the uncut reference routed_experts."""
+    rng = np.random.RandomState(6)
+    d, hd, t = 32, 8, 24
+    c = causal_lm.resolve(dict(
+        CFG, num_attention_heads=8, num_key_value_heads=4,
+        moe_num_primary_experts=16, share=None, num_hidden_layers=1,
+        sliding_window_layout=[1], rope_layout=[1], sliding_window_size=10))
+    cl = causal_lm._layer(c, 0)
+    x = jnp.asarray(rng.randn(1, t, d), jnp.float32)
+    pos = jnp.arange(t)[None]
+    w_in, w_post = (jnp.asarray(rng.rand(d) + 0.5, jnp.float32)
+                    for _ in range(2))
+    wq = jnp.asarray(rng.randn(d, 8 * hd) * 0.2, jnp.float32)
+    wk, wv = (jnp.asarray(rng.randn(d, 4 * hd) * 0.2, jnp.float32)
+              for _ in range(2))
+    wo = jnp.asarray(rng.randn(8 * hd, d) * 0.2, jnp.float32)
+    router = jnp.asarray(rng.randn(d, 16), jnp.float32)
+    wg, wu = (jnp.asarray(rng.randn(16, d, 16) * 0.2, jnp.float32)
+              for _ in range(2))
+    wd = jnp.asarray(rng.randn(16, 16, d) * 0.2, jnp.float32)
+    with jax.default_matmul_precision("highest"):
+        a = reference.rms_norm(x, w_in, 1e-6)
+        whole = reference.attention(a, pos, wq, wk, wv, None, None, wo, cl)
+        parts = [reference.attention(
+            a, pos, wq[:, 16 * i:16 * i + 16], wk[:, 8 * i:8 * i + 8],
+            wv[:, 8 * i:8 * i + 8], None, None, wo[16 * i:16 * i + 16], cl)
+            for i in range(4)]
+        assert _error(sum(parts), whole) < TOLERANCE
+        assert _error(parts[0], whole) > 0.1
+        h1 = x + whole
+        m = reference.rms_norm(h1, w_post, 1e-6).reshape(t, d)
+        uncut = reference.routed_experts(m, router, wg, wu, wd, c,
+                                         router_x=a.reshape(t, d))[0]
+        shares = [moe.routed_ffn(
+            m, router, wg[4 * i:4 * i + 4], wu[4 * i:4 * i + 4],
+            wd[4 * i:4 * i + 4], top_k=3, norm_topk_prob=True,
+            router_x=a.reshape(t, d), activation="relu",
+            first_expert=4 * i)[0] for i in range(4)]
+    assert _error(sum(shares), uncut) < TOLERANCE
+
+
+# --- fused_attention: the attr, the shapes, the paths that refuse -------------
+
+def _attention_op(q, k, v, mesh=None, **attrs):
+    from paddle_tpu.core import registry
+    ctx = types.SimpleNamespace(mesh=mesh, amp=False)
+    return registry.get("fused_attention").lower(
+        ctx, {"Q": [q], "K": [k], "V": [v]}, dict(causal=True, **attrs))
+
+
+def test_fused_attention_takes_a_window_and_grouped_queries():
+    from paddle_tpu.parallel.ring_attention import attention_reference
+    rng = np.random.RandomState(8)
+    q = jnp.asarray(rng.randn(2, 24, 4, 8), jnp.float32)
+    k, v = (jnp.asarray(rng.randn(2, 24, 2, 8), jnp.float32)
+            for _ in range(2))
+    out = _attention_op(q, k, v, window=5)["Out"][0]
+    np.testing.assert_allclose(
+        out, attention_reference(q, k, v, causal=True, window=5), rtol=1e-6)
+    full = _attention_op(q, k, v)["Out"][0]
+    assert _error(out, full) > 1e-2
+
+
+@pytest.mark.parametrize("sp_impl", ["ring", "ulysses"])
+@pytest.mark.parametrize("what", ["window", "grouped"])
+def test_sequence_parallel_paths_refuse_rather_than_ignore(what, sp_impl):
+    from paddle_tpu.parallel.mesh import make_mesh
+    mesh = make_mesh({"sp": 2}, jax.devices()[:2])
+    q = jnp.zeros((2, 16, 4, 8), jnp.float32)
+    kv = q if what == "window" else q[:, :, :2]
+    attrs = dict(sp_impl=sp_impl, **({"window": 4} if what == "window"
+                                     else {}))
+    with pytest.raises(NotImplementedError, match="window nor grouped"):
+        _attention_op(q, kv, kv, mesh=mesh, **attrs)
+
+
+def test_layer_writes_the_window_only_where_there_is_one():
+    """A program without a window is, attr for attr, the program it was."""
+    with fluid.program_guard(fluid.Program(), fluid.Program()):
+        q = fluid.layers.data("q", [16, 4, 8], dtype="float32")
+        kv = fluid.layers.data("kv", [16, 2, 8], dtype="float32")
+        fluid.layers.fused_attention(q, q, q, causal=True)
+        fluid.layers.fused_attention(q, kv, kv, causal=True, window=4)
+        ops = fluid.default_main_program().global_block().ops
+    assert "window" not in ops[0].attrs and ops[1].attrs["window"] == 4
+    with pytest.raises(ValueError, match="window"):
+        with fluid.program_guard(fluid.Program(), fluid.Program()):
+            q = fluid.layers.data("q", [16, 4, 8], dtype="float32")
+            fluid.layers.fused_attention(q, q, q, window=0)
