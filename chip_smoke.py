@@ -551,62 +551,85 @@ def _cpu_place_case(smoke, build, feed, tol):
 
 
 def _held_experts_case(smoke, c, tol):
-    """parallel/moe.py routed_ffn told that it holds 4 of 16 experts, on
-    this device, against the plain reference: the partial sum and its
+    """parallel/moe.py routed_ffn told that it holds a share of 16 experts,
+    on this device, against the plain reference: the partial sum and its
     gradients. On a TPU `ragged_dot` leaves the rows past the groups' sum
-    unwritten (PERF.md, PR 31); whatever is there must reach nothing.
+    unwritten (PERF.md, PR 31), and the sorted rows are gathered only up to
+    that sum (PR 32); whatever lies past it must reach nothing.
 
-    Twice: SiLU experts at the device's default matmul precision (one bf16
-    pass on a TPU, hence the flash tolerance), and ReLU experts with both
-    sides at "highest": a ReLU gate's derivative is a step, so at one bf16
-    pass the pre-activations within 0.2 % of zero change side and the
-    gradients that pass through the gate (dx, dw_gate) leave the float32
-    ones by a fifth (my chip run, PR 31), which says nothing about the
-    rows."""
+    Holding experts 8-11, twice: SiLU experts at the device's default matmul
+    precision (one bf16 pass on a TPU, hence the flash tolerance), and ReLU
+    experts with both sides at "highest": a ReLU gate's derivative is a
+    step, so at one bf16 pass the pre-activations within 0.2 % of zero
+    change side and the gradients that pass through the gate (dx, dw_gate)
+    leave the float32 ones by a fifth (my chip run, PR 31), which says
+    nothing about the rows. Then the two extremes of imbalance, holding
+    experts 4-11 with a router steered by a constant feature of its input:
+    every assignment held (the loops run every tile) and none (no trip; the
+    share and its gradients are zero)."""
     import jax
     import jax.numpy as jnp
     from paddle_tpu.models import causal_lm_reference as reference
     from paddle_tpu.parallel import moe
 
     rng = np.random.RandomState(11)
-    n, d, f, e, held, first = c["n"], c["d"], c["f"], 16, 4, 8
+    n, d, f, e = c["n"], c["d"], c["f"], 16
     x, a, g = (jnp.asarray(rng.randn(n, d), jnp.float32) for _ in range(3))
     router = jnp.asarray(rng.randn(d, e), jnp.float32)
-    wg, wu = (jnp.asarray(rng.randn(held, d, f) * d ** -0.5, jnp.float32)
+    wg, wu = (jnp.asarray(rng.randn(e, d, f) * d ** -0.5, jnp.float32)
               for _ in range(2))
-    wd = jnp.asarray(rng.randn(held, f, d) * f ** -0.5, jnp.float32)
+    wd = jnp.asarray(rng.randn(e, f, d) * f ** -0.5, jnp.float32)
+    # the other features small, so that no held expert's probability
+    # underflows to the absent ones' zero and ties with them
+    steered_a = (a * d ** -0.5).at[:, 0].set(10.0)
 
-    for activation, precision, limit in (("silu", None, tol),
-                                         ("relu", "highest", 1e-4)):
+    for activation, precision, limit, first, held, steer in (
+            ("silu", None, tol, 8, 4, None),
+            ("relu", "highest", 1e-4, 8, 4, None),
+            ("relu", "highest", 1e-4, 4, 8, 5.0),
+            ("relu", "highest", 1e-4, 4, 8, -5.0)):
         conf = {"num_experts": e, "num_experts_per_tok": 6,
                 "norm_topk_prob": True, "hidden_act": activation}
+        sl = slice(first, first + held)
+        route, route_x = router, a
+        if steer is not None:
+            route = router.at[0].set(0.0).at[0, sl].set(steer)
+            route_x = steered_a
 
         def program(x, wg, wu, wd):
-            return moe.routed_ffn(x, router, wg, wu, wd, top_k=6,
-                                  norm_topk_prob=True, router_x=a,
+            return moe.routed_ffn(x, route, wg, wu, wd, top_k=6,
+                                  norm_topk_prob=True, router_x=route_x,
                                   activation=activation,
-                                  first_expert=first)[0]
+                                  first_expert=first)[::3]
 
         def plain(x, wg, wu, wd):
-            return reference.routed_experts(x, router, wg, wu, wd, conf,
-                                            router_x=a,
+            return reference.routed_experts(x, route, wg, wu, wd, conf,
+                                            router_x=route_x,
                                             first_expert=first)[0]
 
+        args = (x, wg[sl], wu[sl], wd[sl])
         with jax.default_device(smoke.device):
             with jax.default_matmul_precision(precision or "default"):
-                got, vjp = jax.vjp(jax.jit(program), x, wg, wu, wd)
+                got, vjp, load = jax.vjp(jax.jit(program), *args,
+                                         has_aux=True)
                 got = (got,) + vjp(g)
             with jax.default_matmul_precision("highest"):
-                want, vjp = jax.vjp(jax.jit(plain), x, wg, wu, wd)
+                want, vjp = jax.vjp(jax.jit(plain), *args)
                 want = (want,) + vjp(g)
+        rows = int(np.asarray(load)[sl].sum())
+        if steer is not None and rows != (6 * n if steer > 0 else 0):
+            raise AssertionError("the steered router left %d of %d "
+                                 "assignments on the held experts"
+                                 % (rows, 6 * n))
         errs = _normalized_errors(
             ("out", "dx", "dw_gate", "dw_up", "dw_down"), got, want)
         worst = max(errs, key=errs.get)
-        smoke.say("routed_ffn holding experts %d-%d of %d, [%d, %d] x [%d], "
-                  "%s at precision %s: max normalized error %.2e (%s) <= "
-                  "%.0e" % (first, first + held - 1, e, n, d, f, activation,
-                            precision or "default", errs[worst], worst,
-                            limit))
+        smoke.say("routed_ffn holding experts %d-%d of %d (%d of %d "
+                  "assignments), [%d, %d] x [%d], %s at precision %s: max "
+                  "normalized error %.2e (%s) <= %.0e"
+                  % (first, first + held - 1, e, rows, 6 * n, n, d, f,
+                     activation, precision or "default", errs[worst], worst,
+                     limit))
         if errs[worst] > limit:
             raise AssertionError(
                 "the held experts' share disagrees with the reference: %r "

@@ -716,18 +716,20 @@ def _count_grad_op(path, fwd_type):
 
 def _count_moe_layer(attrs, ins):
     from ..observability.registry import REGISTRY
-    from ..parallel.moe import GROUPED_MATMUL
+    from ..parallel.moe import GROUPED_MATMUL, rows_moved
+    experts, held = ins["Router"][0].shape[1], ins["WGate"][0].shape[0]
     REGISTRY.counter(
         "ptpu_moe_layers_total",
         "moe_ffn ops lowered (forward ops, not a grad op's replay), by "
         "experts a token, experts routed over, experts held, the gate's "
         "activation, what the router reads (the experts' own input or "
-        "another tensor, pre_attention) and the grouped-matmul route"
-    ).inc(top_k=str(attrs["top_k"]), experts=str(ins["Router"][0].shape[1]),
-          held=str(ins["WGate"][0].shape[0]),
+        "another tensor, pre_attention), the grouped-matmul route and the "
+        "rows of the slot-major buffer the expert-side gathers move (all, "
+        "or the tiles of the held assignments)"
+    ).inc(top_k=str(attrs["top_k"]), experts=str(experts), held=str(held),
           activation=str(attrs.get("activation", "silu")),
           router_input="pre_attention" if ins.get("RouterX") else "own",
-          path=GROUPED_MATMUL)
+          path=GROUPED_MATMUL, rows=rows_moved(experts, held))
 
 
 def _count_attention_layer(ctx, attrs, ins):

@@ -18,14 +18,15 @@ expert matmuls grouped over them, so no token is dropped and no operation
 is spent on an expert a token did not choose. It can be told which experts
 it holds: it then routes over all of them and computes its own share of the
 layer's output, as one chip of an expert-parallel layer would, without the
-exchange.
+exchange. Its row buffer (top_k * N rows) is numbered slot-major, so that a
+token's slots are summed without a relayout, and of the sorted rows only the
+tiles that hold a held assignment are gathered (`_dispatch`, `_combine`).
 """
-import functools
-
 import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.layout import Layout, with_layout_constraint
 
 from .mesh import P, NamedSharding
 
@@ -137,29 +138,149 @@ def _grouped_matmul(lhs, rhs, group_sizes):
                               preferred_element_type=lhs.dtype)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _take_rows(x, index, inverse, repeat):
-    """repeat(x, repeat, axis=0)[index] for a permutation `index` of those
-    rows whose inverse is `inverse`, as one gather. The gradient of a gather
-    is a scatter-add; of a permutation it is the gather by the inverse, then
-    the sum over a row's `repeat` copies, which is what the backward rule
-    does."""
-    return x[index // repeat]
+def rows_moved(experts, held):
+    """Which rows of the top_k * N row buffer `routed_ffn`'s two expert-side
+    gathers move, decided from the shapes: "all" where every expert is held
+    (every row is in a group), "held" where a share is (the tiles below the
+    held assignments' count, a run-time value)."""
+    return "all" if held == experts else "held"
 
 
-def _take_rows_fwd(x, index, inverse, repeat):
-    return x[index // repeat], inverse
+# Rows a trip of the held-rows loops moves; flat from 512 to 2048 (my chip run,
+# PR 32). One SmallThinker layer forward and backward, 12426 of 49152 rows held,
+# ms: 128 20.95, 256 20.60, 512 20.38, 1024 20.56, 2048 20.60, 4096 +0.7, 8192
+# +1.2. The cell, tokens/s/chip over two seeds: 512 44,211, 1024 44,261, 2048
+# 44,269, 4096 44,213. Half a tile is gathered for nothing; a trip costs little.
+ROW_TILE = 1024
 
 
-def _take_rows_bwd(repeat, inverse, g):
-    g = g[inverse]
-    if repeat > 1:
-        g = g.reshape(-1, repeat, g.shape[-1]).sum(1, dtype=jnp.float32) \
-            .astype(g.dtype)
-    return g, None, None
+def _held_tiles(rows, total, one_tile, carry):
+    """carry = one_tile(start, tile, live [tile, 1], carry) for each tile of
+    the `rows` sorted rows that holds a row below `total`: ceil(total / tile)
+    trips, a number known at run time only (a `while`, which has no automatic
+    gradient: the callers are `custom_vjp` rules). `rows` need not be a
+    multiple of the tile: the last trip then starts at rows - tile and
+    writes some rows a second time, with the same values."""
+    tile = min(ROW_TILE, rows)
+
+    def trip(i, carry):
+        start = jnp.minimum(i * tile, rows - tile)
+        live = (start + jnp.arange(tile) < total)[:, None]
+        return one_tile(start, tile, live, carry)
+
+    return jax.lax.fori_loop(0, (total + tile - 1) // tile, trip, carry)
 
 
-_take_rows.defvjp(_take_rows_fwd, _take_rows_bwd)
+def _slot_sum(rows, rank, total, slots, gate=None):
+    """rows [A, D] by sorted row -> [N, D] in their dtype: the float32 sum
+    over a token's `slots` assignments of the row (times gate [slots, N],
+    where given). The gather by `rank` lays the rows slot-major, which is
+    [slots, N, D] as it stands, and the sum is accumulated slot by slot: no
+    relayout, and no float32 copy of the buffer. An assignment that is not
+    held is selected away, not multiplied by zero: its row may hold
+    anything."""
+    by_slot = rows[rank].reshape(slots, -1, rows.shape[1])
+    held = None if total is None else (rank < total).reshape(slots, -1, 1)
+    acc = 0.0
+    for j in range(slots):
+        # the select a slot, so that it fuses into the sum: over the whole
+        # buffer it is a pass of its own (1.5 ms a layer, my chip run, PR 32)
+        term = by_slot[j] if held is None else jnp.where(held[j], by_slot[j],
+                                                         0)
+        term = term.astype(jnp.float32)
+        acc = acc + (term if gate is None else term * gate[j][:, None])
+    # row-major, as the gather made the rows: where the consumer wants the
+    # tokens minor (a [1, T, D] residual stream on the v5e), XLA otherwise
+    # carries that layout back through the sum and transposes the [A, D]
+    # buffer instead of the [N, D] result (AOT compile, PR 32)
+    return with_layout_constraint(acc.astype(rows.dtype),
+                                  Layout(major_to_minor=(0, 1)))
+
+
+@jax.custom_vjp
+def _dispatch(x, order, rank, total):
+    """x [N, D]; order [A] the assignment (slot-major: a = slot * N + token,
+    A = top_k * N) at each sorted row, rank [A] its inverse; `total` the
+    sorted rows that are held (None, at trace time: all). -> [A, D], sorted
+    row r is x[order[r] % N] below `total` and zero from there on."""
+    token = order % x.shape[0]
+    if total is None:
+        return x[token]
+
+    def one_tile(start, tile, live, rows):
+        t = jax.lax.dynamic_slice(token, (start,), (tile,))
+        return jax.lax.dynamic_update_slice(
+            rows, jnp.where(live, x[t], 0), (start, 0))
+
+    return _held_tiles(order.shape[0], total, one_tile,
+                       jnp.zeros((order.shape[0], x.shape[1]), x.dtype))
+
+
+def _dispatch_fwd(x, order, rank, total):
+    return _dispatch(x, order, rank, total), (
+        rank, total, order.shape[0] // x.shape[0])
+
+
+def _dispatch_bwd(res, g):
+    """A token's gradient is the sum of its held assignments' rows; a row
+    past `total` holds whatever the matmuls' transposes left there."""
+    rank, total, slots = res
+    return _slot_sum(g, rank, total, slots), None, None, None
+
+
+_dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
+
+
+@jax.custom_vjp
+def _combine(y, gate, order, rank, total):
+    """y [A, D] the experts' outputs by sorted row, gate [top_k, N] float32
+    the assignments' weights; order, rank and `total` as in `_dispatch`.
+    -> [N, D] in y's dtype: the float32 sum over a token's slots of weight
+    times output (`_slot_sum`)."""
+    return _slot_sum(y, rank, total, gate.shape[0], gate)
+
+
+def _combine_fwd(y, gate, order, rank, total):
+    return _combine(y, gate, order, rank, total), (y, gate, order, rank,
+                                                   total)
+
+
+def _combine_bwd(res, g):
+    """Both gradients on the experts' side, where the held rows are
+    contiguous: sorted row r of dy is g[its token] times its weight, and its
+    weight's gradient is the dot of g[its token] with y[r]; the weights'
+    gradients then go back to slot-major by `rank`, [A] numbers. Only the
+    tiles below `total` are gathered; dy is zero from `total` on."""
+    y, gate, order, rank, total = res
+    token, weight = order % gate.shape[1], gate.reshape(-1)[order]
+
+    def both(g_rows, y_rows, w_rows):
+        g_rows = g_rows.astype(jnp.float32)
+        return ((g_rows * w_rows[:, None]).astype(y.dtype),
+                jnp.sum(g_rows * y_rows.astype(jnp.float32), axis=-1))
+
+    if total is None:
+        dy, dweight = both(g[token], y, weight)
+    else:
+        def one_tile(start, tile, live, carry):
+            t = jax.lax.dynamic_slice(token, (start,), (tile,))
+            dy_rows, dw_rows = both(
+                jnp.where(live, g[t], 0),
+                jnp.where(live, jax.lax.dynamic_slice(
+                    y, (start, 0), (tile, y.shape[1])), 0),
+                jax.lax.dynamic_slice(weight, (start,), (tile,)))
+            return (jax.lax.dynamic_update_slice(carry[0], dy_rows,
+                                                 (start, 0)),
+                    jax.lax.dynamic_update_slice(carry[1], dw_rows,
+                                                 (start,)))
+
+        dy, dweight = _held_tiles(
+            order.shape[0], total, one_tile,
+            (jnp.zeros_like(y), jnp.zeros(order.shape, jnp.float32)))
+    return dy, dweight[rank].reshape(gate.shape), None, None, None
+
+
+_combine.defvjp(_combine_fwd, _combine_bwd)
 
 
 def _gated_silu(gate, up):
@@ -202,25 +323,39 @@ def routed_ffn(x, router, w_gate, w_up, w_down, top_k, norm_topk_prob=False,
     whole layer's output; nothing here stands in for the exchange.
 
     Every assignment to a held expert is computed, whatever the imbalance:
-    there is no capacity. The assignments are sorted by expert, those to
-    experts that are not held last, and the three expert matmuls run grouped
-    over the sorted rows (`_grouped_matmul`) with the held experts' counts
-    as group sizes, so they cost the held assignments' operations and not
-    the stored experts'. The row buffer is top_k * N rows whatever H is,
-    because every one of a token's choices may be held (1.5 N on average at
-    6 of 64 with 16 held); the rows past the groups' sum belong to no group.
-    What `ragged_dot` does with them depends on the backend: on the CPU it
-    writes zeros there and its transpose gives them a zero gradient; on the
-    v5e it neither reads nor writes them, so its time follows the groups'
-    sum and not the buffer (0.75 ms for 12288 of 49152 rows of [2560] x
-    [16, 2560, 768], 2.03 ms for all 49152), and they hold whatever the
-    buffer held before, in the output and in the gradient of its left
-    operand alike (my chip run, PR 31). So nothing may rest on them: the
-    gathered rows past the sum are set to zero before the first matmul,
-    which makes their gradient zero whatever the matmul's transpose left
-    there, before `_take_rows`' backward adds a row's gradient into its
-    token's; and the combine selects (not multiplies) the held assignments'
-    outputs, so no NaN in an unwritten row reaches a token.
+    there is no capacity. The assignments are numbered slot-major (a = slot
+    * N + token) and sorted by expert, those to experts that are not held
+    last, and the three expert matmuls run grouped over the sorted rows
+    (`_grouped_matmul`) with the held experts' counts as group sizes, so
+    they cost the held assignments' operations and not the stored experts'.
+    The row buffer is top_k * N rows whatever H is, because every one of a
+    token's choices may be held (1.5 N on average at 6 of 64 with 16 held).
+
+    Four permutations move rows, a layer's forward and backward. The two
+    that produce sorted rows (`_dispatch` forward, `_combine` backward)
+    gather, where a share is held (`rows_moved`), only the tiles of
+    `ROW_TILE` rows below total = sizes.sum(), in a loop whose trip count
+    is known at run time: the share of the buffer they touch is
+    ExpertLoad[first_expert : first_expert + H].sum() / (top_k * N), a
+    value every caller can fetch. Sorted rows from `total` on are zero,
+    in the rows and in the gradient that `_combine` hands the matmuls. The
+    two that produce a token's rows (`_combine` forward, `_dispatch`
+    backward) gather all top_k * N, a token's held slots being scattered,
+    and sum over the leading axis of [top_k, N, D], which is the buffer as
+    it lies: numbered token-major, top_k = 6 made each of them a relayout
+    of the buffer (6 rows do not fill a tile of 8; PERF.md section 6, PR
+    32).
+
+    The rows past `total` belong to no group. What `ragged_dot` does with
+    them depends on the backend: on the CPU it writes zeros there and its
+    transpose gives them a zero gradient; on the v5e it neither reads nor
+    writes them, so its time follows the groups' sum and not the buffer
+    (0.75 ms for 12288 of 49152 rows of [2560] x [16, 2560, 768], 2.03 ms
+    for all 49152), and they hold whatever the buffer held before, in the
+    output and in the gradient of its left operand alike (my chip run, PR
+    31). So nothing rests on them: `_slot_sum` selects (not multiplies)
+    the held assignments' rows, forward and backward, so no NaN in an
+    unwritten row reaches a token or its gradient.
 
     The router's matmul, softmax and top-k are float32 at full precision
     whatever x's dtype; the experts compute in `expert_dtype` (x's own if
@@ -233,7 +368,7 @@ def routed_ffn(x, router, w_gate, w_up, w_down, top_k, norm_topk_prob=False,
              c [E] int32: assignments an expert over ALL E, sum = top_k * N;
              c[first_expert : first_expert + H] are the rows computed).
     """
-    n, d = x.shape
+    n = x.shape[0]
     e, held = router.shape[1], w_gate.shape[0]
     if not 0 <= first_expert <= e - held:
         raise ValueError("routed_ffn holds experts %d..%d of %d"
@@ -248,33 +383,29 @@ def routed_ffn(x, router, w_gate, w_up, w_down, top_k, norm_topk_prob=False,
     if norm_topk_prob:
         gate = gate / gate.sum(-1, keepdims=True)
 
-    # assignment a = n * top_k + j; `order` lists the assignments by expert
-    # (stable, so by token inside an expert), `rank` is where each went
-    expert = expert.reshape(-1)
-    if held == e:
-        here, sort_key = None, expert
+    # assignment a = j * N + n (slot-major); `order` lists the assignments by
+    # expert (stable, so by slot then token inside an expert), `rank` is
+    # where each went
+    expert, gate = expert.T.reshape(-1), gate.T
+    load = jnp.sum(expert[:, None] == jnp.arange(e), axis=0, dtype=jnp.int32)
+    if rows_moved(e, held) == "all":
+        sort_key, sizes, total = expert, load, None
     else:
         local = expert - first_expert
         here = (local >= 0) & (local < held)
         sort_key = jnp.where(here, local, held)    # not held: past the groups
+        sizes = load[first_expert:first_expert + held]
+        total = sizes.sum()
     order = jnp.argsort(sort_key, stable=True)
     rank = jnp.zeros_like(order).at[order].set(
         jnp.arange(order.shape[0], dtype=order.dtype))
-    load = jnp.sum(expert[:, None] == jnp.arange(e), axis=0, dtype=jnp.int32)
-    sizes = load if here is None else load[first_expert:first_expert + held]
 
-    rows = _take_rows(x.astype(dtype), order, rank, top_k)
-    if here is not None:
-        in_group = jnp.arange(rows.shape[0]) < sizes.sum()
-        rows = jnp.where(in_group[:, None], rows, 0)
+    rows = _dispatch(x.astype(dtype), order, rank, total)
     hidden = _gated(_grouped_matmul(rows, w_gate.astype(dtype), sizes),
                     _grouped_matmul(rows, w_up.astype(dtype), sizes),
                     activation)
     y = _grouped_matmul(hidden, w_down.astype(dtype), sizes)
-    y = _take_rows(y, rank, order, 1).reshape(n, top_k, d).astype(jnp.float32)
-    if here is not None:
-        y = jnp.where(here.reshape(n, top_k, 1), y, 0.0)
-    out = jnp.sum(y * gate[:, :, None], axis=1).astype(dtype)
+    out = _combine(y, gate, order, rank, total)
 
     balance = e * jnp.sum(load.astype(jnp.float32) / n * probs.mean(0))
     z = jnp.mean(jnp.square(lse))

@@ -57,7 +57,7 @@ def _moe_layers_lowered():
     from paddle_tpu.parallel.moe import GROUPED_MATMUL
     return REGISTRY.counter("ptpu_moe_layers_total", "").value(
         top_k="2", experts="8", held="8", activation="silu",
-        router_input="own", path=GROUPED_MATMUL)
+        router_input="own", path=GROUPED_MATMUL, rows="all")
 
 
 def _run_program(amp):

@@ -62,7 +62,8 @@ def _counts():
     return {
         "moe": _counter("ptpu_moe_layers_total", top_k="3", experts="16",
                         held="8", activation="relu",
-                        router_input="pre_attention", path=GROUPED_MATMUL),
+                        router_input="pre_attention", path=GROUPED_MATMUL,
+                        rows="held"),
         "full": _counter("ptpu_attention_layers_total", kind="full",
                          window="0", q_heads="4", kv_heads="2", path="dense"),
         "window": _counter("ptpu_attention_layers_total", kind="window",
@@ -183,8 +184,9 @@ def test_amp_program_agrees_with_the_reference(want):
 
 
 def test_the_new_counters_and_labels(program):
-    """ptpu_moe_layers_total says what is held, the activation and what the
-    router reads; ptpu_attention_layers_total counts forward fused_attention
+    """ptpu_moe_layers_total says what is held, the activation, what the
+    router reads and that the held rows alone are moved (rows="held", PR
+    32); ptpu_attention_layers_total counts forward fused_attention
     ops by kind, window, heads and path, not a grad op's replay."""
     assert program[2]["counted"] == {"moe": 4, "full": 1, "window": 3}
 
@@ -284,11 +286,13 @@ def test_four_shares_of_four_experts_sum_to_the_layer():
         assert _error(total, want_grads[j]) < 5 * TOLERANCE, name
 
 
-def test_rows_past_the_groups_sum_reach_nothing(monkeypatch):
+@pytest.mark.parametrize("tile", [32, 2048])
+def test_rows_past_the_groups_sum_reach_nothing(monkeypatch, tile):
     """On the CPU ragged_dot writes zeros past the groups' sum; on the v5e
     it leaves those rows unwritten (PR 31's chip run). Here they are filled
     with NaN, forward and in the transposes, and neither the output nor a
-    gradient sees it."""
+    gradient sees it, with the held rows in one tile or in several."""
+    monkeypatch.setattr(moe, "ROW_TILE", tile)
     x, a, router, wg, wu, wd = _layer_inputs(4)
     grouped = moe._grouped_matmul
 
@@ -319,6 +323,183 @@ def test_rows_past_the_groups_sum_reach_nothing(monkeypatch):
     for a_, b_ in zip(got_grads, want_grads):
         assert bool(jnp.isfinite(a_).all())
         np.testing.assert_allclose(a_, b_, rtol=1e-5, atol=1e-6)
+
+
+# --- the rows routed_ffn moves: slot-major, held tiles only (PR 32) ------------
+
+def _token_major_routed_ffn(x, router, w_gate, w_up, w_down, top_k, router_x,
+                            first_expert):
+    """routed_ffn as PR 31 had it, ReLU and renormalised gates: assignment
+    a = n * top_k + j, every gather and mask over all top_k * N rows."""
+    n, d = x.shape
+    e, held = router.shape[1], w_gate.shape[0]
+    probs = jax.nn.softmax(jnp.dot(router_x, router), -1)
+    gate, expert = jax.lax.top_k(probs, top_k)
+    gate = gate / gate.sum(-1, keepdims=True)
+    expert = expert.reshape(-1)
+    local = expert - first_expert
+    here = (local >= 0) & (local < held)
+    order = jnp.argsort(jnp.where(here, local, held), stable=True)
+    rank = jnp.argsort(order)
+    load = jnp.sum(expert[:, None] == jnp.arange(e), axis=0, dtype=jnp.int32)
+    sizes = load[first_expert:first_expert + held]
+    rows = jnp.where((jnp.arange(top_k * n) < sizes.sum())[:, None],
+                     x[order // top_k], 0)
+    hidden = jax.nn.relu(moe._grouped_matmul(rows, w_gate, sizes)) \
+        * moe._grouped_matmul(rows, w_up, sizes)
+    y = moe._grouped_matmul(hidden, w_down, sizes)[rank].reshape(n, top_k, d)
+    y = jnp.where(here.reshape(n, top_k, 1), y, 0.0)
+    return jnp.sum(y * gate[:, :, None], axis=1)
+
+
+HELD = {"all": (16, 0), "share": (4, 8), "last": (4, 12)}
+
+
+@pytest.mark.parametrize("tile", [32, 2048])
+@pytest.mark.parametrize("held", sorted(HELD))
+def test_routed_ffn_keeps_the_values_of_the_token_major_order(
+        monkeypatch, held, tile):
+    """Value, dx, d router_x, dw_gate, dw_up, dw_down: against the plain
+    reference and against PR 31's algorithm, with every expert held (no
+    loop), a share in the middle and the last share; the loop in one trip
+    (tile 2048 > 288 rows) and in several."""
+    monkeypatch.setattr(moe, "ROW_TILE", tile)
+    x, a, router, wg, wu, wd = _layer_inputs(7)
+    count, first = HELD[held]
+    sl = slice(first, first + count)
+
+    def new(x, a, wg, wu, wd):
+        return moe.routed_ffn(x, router, wg, wu, wd, top_k=6,
+                              norm_topk_prob=True, router_x=a,
+                              activation="relu", first_expert=first)[0]
+
+    def old(x, a, wg, wu, wd):
+        return _token_major_routed_ffn(x, router, wg, wu, wd, 6, a, first)
+
+    def plain(x, a, wg, wu, wd):
+        return reference.routed_experts(x, router, wg, wu, wd, REF_C,
+                                        router_x=a, first_expert=first)[0]
+
+    args = (x, a, wg[sl], wu[sl], wd[sl])
+    g = jnp.asarray(np.random.RandomState(8).randn(*x.shape), jnp.float32)
+    with jax.default_matmul_precision("highest"):
+        got, want, ref = (
+            (out,) + vjp(g) for out, vjp in
+            (jax.vjp(f, *args) for f in (new, old, plain)))
+    for name, u, v, w in zip(("out", "dx", "drouter_x", "dw_gate", "dw_up",
+                              "dw_down"), got, want, ref):
+        assert _error(u, v) < 2e-6, name
+        assert _error(u, w) < 5 * TOLERANCE, name
+
+
+def _steered(held_logit):
+    """Inputs whose router sends every token's six choices to experts 4..11
+    (held_logit > 0) or away from them (< 0): the router's input carries a
+    constant feature that only those eight columns read."""
+    x, a, router, wg, wu, wd = _layer_inputs(9)
+    a = a.at[:, 0].set(10.0)
+    router = router.at[0].set(0.0).at[0, 4:12].set(held_logit)
+    return x, a, router, wg[4:12], wu[4:12], wd[4:12]
+
+
+@pytest.mark.parametrize("extreme", ["none_held", "every_one_held"])
+def test_routed_ffn_at_the_extremes_of_imbalance(monkeypatch, extreme):
+    """No assignment on the held experts: the loops make no trip, zeros and
+    zero gradients. Every assignment on them: sizes.sum() == top_k * N, the
+    loops run every tile, and the share is the whole layer."""
+    monkeypatch.setattr(moe, "ROW_TILE", 32)
+    x, a, router, wg, wu, wd = _steered(-5.0 if extreme == "none_held"
+                                        else 5.0)
+
+    def run(x, a, wg, wu, wd):
+        return moe.routed_ffn(x, router, wg, wu, wd, top_k=6,
+                              norm_topk_prob=True, router_x=a,
+                              activation="relu", first_expert=4)
+
+    g = jnp.ones_like(x)
+    with jax.default_matmul_precision("highest"):
+        load = run(x, a, wg, wu, wd)[3]
+        got, vjp = jax.vjp(lambda *p: run(*p)[0], x, a, wg, wu, wd)
+        grads = vjp(g)
+    held_rows = int(load[4:12].sum())
+    if extreme == "none_held":
+        assert held_rows == 0
+        assert not np.asarray(got).any()
+        assert all(not np.asarray(gr).any() for gr in grads)
+        return
+    assert held_rows == 6 * x.shape[0]
+
+    def plain(x, a, wg, wu, wd):
+        return reference.routed_experts(x, router, wg, wu, wd, REF_C,
+                                        router_x=a, first_expert=4)[0]
+
+    with jax.default_matmul_precision("highest"):
+        want, vjp = jax.vjp(plain, x, a, wg, wu, wd)
+        want_grads = vjp(g)
+    assert _error(got, want) < TOLERANCE
+    for u, v in zip(grads, want_grads):
+        assert _error(u, v) < 5 * TOLERANCE
+
+
+def _sorted_assignments(seed, n, top_k, experts, held):
+    """(order, rank, total) as routed_ffn builds them for random choices of
+    which the first `held` experts are held."""
+    expert = jnp.asarray(np.random.RandomState(seed).randint(
+        0, experts, top_k * n))
+    order = jnp.argsort(jnp.where(expert < held, expert, held), stable=True)
+    return order, jnp.argsort(order), jnp.sum(expert < held)
+
+
+@pytest.mark.parametrize("tile", [32, 100, 2048])
+def test_the_last_tiles_tail_is_zero_in_the_rows_and_in_their_gradient(
+        monkeypatch, tile):
+    """sizes.sum() is no multiple of the tile (nor the buffer, at 100):
+    `_dispatch` gives x[token] below the sum and zeros from there on, and
+    `_combine`'s backward the weighted g[token] below it and zeros from
+    there on, whatever y holds past the sum."""
+    monkeypatch.setattr(moe, "ROW_TILE", tile)
+    n, k, d = 48, 6, 16
+    order, rank, total = _sorted_assignments(10, n, k, 16, 4)
+    assert 0 < int(total) < k * n and int(total) % min(tile, k * n)
+    rng = np.random.RandomState(11)
+    x, g = (jnp.asarray(rng.randn(n, d), jnp.float32) for _ in range(2))
+    gate = jnp.asarray(rng.rand(k, n), jnp.float32)
+    live = (np.arange(k * n) < int(total))[:, None]
+
+    rows = jax.jit(moe._dispatch)(x, order, rank, total)
+    np.testing.assert_array_equal(
+        rows, np.where(live, np.asarray(x)[np.asarray(order) % n], 0))
+
+    y = jnp.where(live, jnp.asarray(rng.randn(k * n, d), jnp.float32),
+                  jnp.nan)
+    out, vjp = jax.vjp(lambda y, gate: moe._combine(y, gate, order, rank,
+                                                    total), y, gate)
+    assert bool(jnp.isfinite(out).all())
+    dy, dgate = vjp(g)
+    weight = np.asarray(gate).reshape(-1)[np.asarray(order)][:, None]
+    g_rows = np.asarray(g)[np.asarray(order) % n]
+    np.testing.assert_allclose(dy, np.where(live, g_rows * weight, 0),
+                               rtol=1e-6)
+    want = np.where(live, g_rows * np.nan_to_num(np.asarray(y)), 0).sum(-1)
+    np.testing.assert_allclose(np.asarray(dgate).reshape(-1),
+                               want[np.asarray(rank)], rtol=1e-5, atol=1e-6)
+    dx = jax.vjp(lambda x: moe._dispatch(x, order, rank, total), x)[1](
+        jnp.where(live, rows, jnp.nan))[0]
+    assert bool(jnp.isfinite(dx).all())
+
+
+def test_the_share_of_the_buffer_gathered_reads_from_expert_load():
+    """What the held-rows loops touch is ExpertLoad[first : first + held]
+    .sum() / (top_k * N), a value every step fetches: a quarter of the
+    buffer when 4 of 16 experts are held and the router is even."""
+    x, a, router, wg, wu, wd = _layer_inputs(12, n=256)
+    load = moe.routed_ffn(x, router, wg[8:12], wu[8:12], wd[8:12], top_k=6,
+                          norm_topk_prob=True, router_x=a, activation="relu",
+                          first_expert=8)[3]
+    assert int(load.sum()) == 6 * 256
+    share = float(load[8:12].sum()) / (6 * 256)
+    assert 0.15 < share < 0.35
+    assert moe.rows_moved(16, 4) == "held" and moe.rows_moved(16, 16) == "all"
 
 
 def test_the_op_takes_the_share_through_the_layer():

@@ -557,3 +557,37 @@ def test_layer_norm_tile_fits_the_default_vmem_limit(one_chip, n, d, dtype):
     assert len(re.findall(r'%?ptpu_layer_norm_fwd[\w.]* = [^\n]*'
                           r'custom_call_target="tpu_custom_call"',
                           text)) == 1
+
+
+@pytest.mark.parametrize("held", [4, 16], ids=["a_share", "every_expert"])
+def test_routed_ffn_moves_its_rows_without_a_relayout(one_chip, held):
+    """routed_ffn forward and backward at top-6 of 16, compiled for a TPU:
+    no reshape, copy, transpose or convert over the row buffer ([6144, 256],
+    [6, 1024, 256]; PR 31's order made three relayouts of it a layer and
+    PERF.md section 6, PR 32, has what they cost), and where a share of the
+    experts is held the two expert-side gathers are loops over its tiles."""
+    from paddle_tpu.parallel import moe
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def step(x, a, router, wg, wu, wd, g):
+        out, vjp = jax.vjp(
+            lambda *p: moe.routed_ffn(
+                p[0], router, *p[2:], top_k=6, norm_topk_prob=True,
+                expert_dtype=jnp.bfloat16, router_x=p[1],
+                activation="relu")[0], x, a, wg, wu, wd)
+        return out, vjp(g)
+
+    n, d, f = 1024, 256, 128
+    text = _compile_uncached(
+        step, sds((n, d), jnp.bfloat16), sds((n, d), jnp.float32),
+        sds((d, 16), jnp.float32), sds((held, d, f), jnp.float32),
+        sds((held, d, f), jnp.float32), sds((held, f, d), jnp.float32),
+        sds((n, d), jnp.bfloat16)).as_text()
+    moved = re.findall(     # in the entry computation: a gather's fusion
+        r"= \w+\[(?:6144,256|6,1024,256|1024,6,256)\]\S* "     # has its own
+        r"(reshape|copy|transpose|convert)\(", text[text.index("ENTRY"):])
+    assert moved == []
+    assert len(re.findall(r"%ragged-dot-none[\w.]* = ", text)) == 9
+    assert len(re.findall(r" while\(", text)) == (2 if held < 16 else 0)
