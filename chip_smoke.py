@@ -17,6 +17,8 @@ from a seed, and checks what comes out by the repo's own means:
      dtype its shipped model gives it, forward and backward, against the
      same op's XLA lowering under a written tolerance — and the trace is
      inspected: the kernel was dispatched, and not interpreted on a TPU.
+     The gated delta rule's two kernels and its lax.scan path against the
+     token-by-token recurrence at the Qwen3-Next cell's shapes.
   D  (>= 4 devices) ParallelExecutor over a dp=4 mesh on phase A's
      program, replicated and with the ZeRO-sharded weight update.
   E  the timing-barrier premise: K steps timed to jax.block_until_ready
@@ -50,6 +52,9 @@ FULL = {
         # window of half the sequence
         attn_window=dict(b=1, t=4096, h=7, hkv=1, d=128, window=2048),
         moe=dict(n=4096, d=512, f=256),
+        # Qwen3-Next's gated delta rule at its cell's shapes: one sequence
+        # of 4096, 16 key heads on 32 value heads of 128
+        gated_delta=dict(b=1, t=4096, hk=16, hv=32, d=128),
         xent=dict(n=8192, v=30000),                 # its [B*T, vocab] loss
         # its d_model rows: the benchmark cells' [16384, 512], whole tiles
         # at the table's budget, and an N that leaves a padded tail
@@ -74,6 +79,7 @@ TINY = {
         attn=dict(b=2, t=32, h=2, d=16),
         attn_window=dict(b=2, t=40, h=4, hkv=2, d=16, window=12),
         moe=dict(n=64, d=16, f=8),
+        gated_delta=dict(b=2, t=40, hk=2, hv=4, d=16),
         xent=dict(n=32, v=64),
         ln=(dict(b=2, t=16, d=32), dict(b=3, t=7, d=32)),
         lstm=(dict(b=5, t=6, d=8, reverse=True),),
@@ -636,12 +642,94 @@ def _held_experts_case(smoke, c, tol):
                 "(tol %g)" % (errs, limit))
 
 
+def _gated_delta_case(smoke, c, tol):
+    """ops/gated_delta_kernels.py on this device at the Qwen3-Next cell's
+    shapes, bf16 operands as under AMP: the chunked forward and backward on
+    the Pallas kernels, and on lax.scan, against the token-by-token
+    recurrence of models/causal_lm_reference.py in float32 at "highest" and
+    jax.grad of it, under two decays: a layer's at initialisation (most
+    heads forget within a token or two, so what a chunk hands the next one
+    hardly shows) and a slow one (a state lives some hundred tokens, across
+    the chunks of 64: the carry of S and dS decides the answer). The times
+    of one forward and backward of either path are printed for the next
+    reader (no metric)."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.models import causal_lm_reference as reference
+    from paddle_tpu.ops import gated_delta_kernels
+
+    rng = np.random.RandomState(13)
+    b, t, hk, hv, d = c["b"], c["t"], c["hk"], c["hv"], c["d"]
+    q, k = (jnp.asarray(rng.randn(b, t, hk, d), jnp.bfloat16)
+            for _ in range(2))
+    v, ct = (jnp.asarray(rng.randn(b, t, hv, d), jnp.bfloat16)
+             for _ in range(2))
+    step = np.log1p(np.exp(rng.randn(b, t, hv) + 1.0))
+    beta = jax.nn.sigmoid(jnp.asarray(rng.randn(b, t, hv), jnp.float32))
+    decays = (      # A in (0, 16) with dt_bias 1, and A in (0, 0.02)
+        ("initialisation", rng.uniform(0, 16, (hv,))),
+        ("slow", rng.uniform(0, 0.02, (hv,))))
+
+    @jax.checkpoint
+    def one_head(xs):       # [B, T, 1, ..]: a value head's T states alone
+        return reference.delta_rule(*xs)
+
+    def recurrence(q, k, v, g, beta):
+        # a value head at a time, recomputed in the backward pass: jax.grad
+        # of the whole recurrence keeps every token's state of every head
+        # (8.6 GB at 4096 tokens, 32 heads of [128, 128])
+        q, k = (jnp.repeat(reference.l2norm(x.astype(jnp.float32)),
+                           hv // hk, axis=2) for x in (q, k))
+        heads = tuple(jnp.moveaxis(x, 2, 0)[:, :, :, None] for x in (
+            q * d ** -0.5, k, v.astype(jnp.float32), g, beta))
+        return jnp.moveaxis(jax.lax.map(one_head, heads)[:, :, :, 0], 0, 2)
+
+    def both(fn):
+        def run(*a):
+            out, vjp = jax.vjp(fn, *a)
+            return (out,) + vjp(ct.astype(out.dtype))
+        return jax.jit(run)
+
+    names = ("out", "dq", "dk", "dv", "dg", "dbeta")
+    for decay, a in decays:
+        args = (q, k, v, -jnp.asarray(a * step, jnp.float32), beta)
+        with jax.default_device(smoke.device):
+            with jax.default_matmul_precision("highest"):
+                want = both(recurrence)(*args)
+            for path in ("kernel", "scan"):
+                run = both(lambda *a: gated_delta_kernels.gated_delta_rule(
+                    *a, path=path, operand_dtype=jnp.bfloat16))
+                got = jax.block_until_ready(run(*args))
+                times = []
+                for _ in range(5):
+                    t0 = time.perf_counter()
+                    jax.block_until_ready(run(*args))
+                    times.append(time.perf_counter() - t0)
+                errs = _normalized_errors(names, got, want)
+                worst = max(errs, key=errs.get)
+                smoke.say("gated_delta_rule %s path, %s decay, q/k [%d, %d, "
+                          "%d, %d] v [.., %d, %d] bf16: max normalized error "
+                          "%.2e (%s) <= %.0e; forward + backward %.2f ms "
+                          "(median of 5)"
+                          % (path, decay, b, t, hk, d, hv, d, errs[worst],
+                             worst, tol, 1e3 * statistics.median(times)))
+                if errs[worst] > tol:
+                    raise AssertionError(
+                        "the %s path disagrees with the recurrence under "
+                        "the %s decay: %r (tol %g)" % (path, decay, errs,
+                                                       tol))
+
+
 def phase_c(smoke):
     cases = _kernel_cases(smoke.cfg["kernels"])
     runs = [(c[0], lambda c=c: _kernel_case(smoke, *c)) for c in cases]
     runs.append(("routed_ffn with a share of the experts",
                  lambda: _held_experts_case(smoke, smoke.cfg["kernels"]["moe"],
                                             TOL["attn"])))
+    runs.append(("gated_delta_rule against the recurrence",
+                 lambda: _gated_delta_case(
+                     smoke, smoke.cfg["kernels"]["gated_delta"],
+                     TOL["attn"])))
     _, _, ln_build, ln_feed, ln_tol = next(c for c in cases if c[1] == "ln")
     runs.append(("layer_norm on Executor(CPUPlace())",
                  lambda: _cpu_place_case(smoke, ln_build, ln_feed, ln_tol)))

@@ -40,6 +40,20 @@ class UniformInitializer(Initializer):
             infer_shape=False)
 
 
+class LogUniformInitializer(Initializer):
+    """log(u), u uniform in (low, high): uniform_random, then log in place
+    (the A_log of a state-space or delta-rule mixer's decay)."""
+
+    def __init__(self, low=0.0, high=1.0, seed=0):
+        self.low, self.high, self.seed = low, high, seed
+
+    def __call__(self, var, block):
+        UniformInitializer(self.low, self.high, self.seed)(var, block)
+        return block.append_op(
+            type="log", inputs={"X": [var]}, outputs={"Out": [var]},
+            infer_shape=False)
+
+
 class NormalInitializer(Initializer):
     def __init__(self, loc=0.0, scale=1.0, seed=0):
         self.mean, self.std, self.seed = loc, scale, seed
@@ -149,6 +163,7 @@ class NumpyArrayInitializer(Initializer):
 Constant = ConstantInitializer
 Uniform = UniformInitializer
 Normal = NormalInitializer
+LogUniform = LogUniformInitializer
 Xavier = XavierInitializer
 MSRA = MSRAInitializer
 Bilinear = BilinearInitializer

@@ -590,6 +590,8 @@ def _lower_op_inner(ctx, op, env):
         _count_moe_layer(op.attrs, ins)
     elif op.type == "fused_attention":
         _count_attention_layer(ctx, op.attrs, ins)
+    elif op.type == "gated_delta_rule":
+        _count_linear_attention_layer(ins)
     if op.uid in ctx.linearized:
         # a grad op of this block differentiates this op: run the rule once,
         # under jax.vjp, and keep what the backward needs
@@ -744,11 +746,27 @@ def _count_attention_layer(ctx, attrs, ins):
     REGISTRY.counter(
         "ptpu_attention_layers_total",
         "fused_attention ops lowered (forward ops, not a grad op's replay), "
-        "by kind (full, or window with its size), query and key/value heads "
-        "and the path taken (flash, dense, or the sequence-parallel one)"
+        "by kind (full, or window with its size), query and key/value "
+        "heads, the path taken (flash, dense, or the sequence-parallel one) "
+        "and the head's width"
     ).inc(kind="full" if window is None else "window",
           window=str(window or 0), q_heads=str(q.shape[2]),
-          kv_heads=str(k.shape[2]), path=path)
+          kv_heads=str(k.shape[2]), path=path, head_dim=str(q.shape[3]))
+
+
+def _count_linear_attention_layer(ins):
+    from ..observability.registry import REGISTRY
+    from ..ops.kernel_config import DEFAULT_TILES
+    from ..ops.linear_attention_ops import gated_delta_path
+    k, v = ins["K"][0], ins["V"][0]
+    REGISTRY.counter(
+        "ptpu_linear_attention_layers_total",
+        "linear-attention ops lowered (forward ops, not a grad op's replay), "
+        "by kind, key and value heads and their widths, the chunk and the "
+        "path of the pass over chunks (the Pallas kernels, or lax.scan)"
+    ).inc(kind="gated_delta", k_heads=str(k.shape[2]),
+          v_heads=str(v.shape[2]), d_k=str(k.shape[3]), d_v=str(v.shape[3]),
+          chunk=str(DEFAULT_TILES["gdr"]["chunk"]), path=gated_delta_path())
 
 
 def _lower_grad_of(ctx, op, env):

@@ -24,6 +24,7 @@ __all__ = [
     "expand", "sequence_mask", "linear_chain_crf", "crf_decoding",
     "chunk_eval", "warpctc", "ctc_greedy_decoder", "sequence_erase",
     "edit_distance", "fused_attention", "rms_norm", "rotary_embedding",
+    "causal_conv1d", "gated_delta_rule",
 ]
 
 
@@ -267,34 +268,92 @@ def layer_norm(input, scale=True, shift=True, begin_norm_axis=1,
 
 
 def rms_norm(input, begin_norm_axis=-1, epsilon=1e-5, param_attr=None,
-             name=None):
+             name=None, zero_centered=False, gate=None):
     """Root-mean-square norm over the axes from begin_norm_axis on, with a
     learned scale (initialised to 1) and no shift: scale * x /
-    sqrt(mean(x^2) + epsilon), accumulated in float32 (ops/nn_ops.py)."""
+    sqrt(mean(x^2) + epsilon), accumulated in float32 (ops/nn_ops.py).
+    zero_centered: the weight is stored around 0 (initialised to 0) and the
+    result is (1 + scale) * x_hat. gate: a Variable of input's shape, the
+    result is scale * x_hat * silu(gate) (a gated norm)."""
     helper = LayerHelper("rms_norm", **locals())
     dtype = helper.input_dtype()
     begin = begin_norm_axis % len(input.shape)
     scale = helper.create_parameter(
         attr=helper.param_attr,
         shape=[int(np.prod(input.shape[begin:]))], dtype=dtype,
-        default_initializer=ConstantInitializer(1.0))
+        default_initializer=ConstantInitializer(
+            0.0 if zero_centered else 1.0))
     out = helper.create_variable_for_type_inference(dtype)
-    helper.append_op(
-        type="rms_norm", inputs={"X": [input], "Scale": [scale]},
-        outputs={"Y": [out]},
-        attrs={"epsilon": float(epsilon), "begin_norm_axis": begin})
+    inputs = {"X": [input], "Scale": [scale]}
+    attrs = {"epsilon": float(epsilon), "begin_norm_axis": begin}
+    # what the defaults leave as it was is not written
+    if zero_centered:
+        attrs["zero_centered"] = True
+    if gate is not None:
+        inputs["Gate"] = [gate]
+    helper.append_op(type="rms_norm", inputs=inputs, outputs={"Y": [out]},
+                     attrs=attrs)
     return out
 
 
-def rotary_embedding(x, pos, base=10000.0, name=None):
+def rotary_embedding(x, pos, base=10000.0, name=None, rotary_dim=None):
     """Rotary position embedding of x [B, T, H, D] at the integer positions
     pos [B, T], a Variable (fed or computed), so that a decode step can pass
-    its own. Half-split pairs (i, i + D/2), angle pos * base^(-2i/D)."""
+    its own. Half-split pairs (i, i + R/2) over the first R = rotary_dim
+    channels of every head (None: all D), angle pos * base^(-2i/R); the
+    channels from R on pass unchanged."""
     helper = LayerHelper("rotary_embedding", **locals())
     out = helper.create_variable_for_type_inference(x.dtype)
+    attrs = {"base": float(base)}
+    if rotary_dim is not None and int(rotary_dim) != int(x.shape[-1]):
+        attrs["rotary_dim"] = int(rotary_dim)
     helper.append_op(
         type="rotary_embedding", inputs={"X": [x], "Pos": [pos]},
-        outputs={"Out": [out]}, attrs={"base": float(base)})
+        outputs={"Out": [out]}, attrs=attrs)
+    return out
+
+
+def causal_conv1d(input, kernel_size, act=None, param_attr=None, name=None):
+    """A causal depthwise convolution over time without bias: input [B, T,
+    C], one filter of `kernel_size` taps a channel (the parameter is [C,
+    kernel_size]), y_t[c] = sum_m w[c, m] x_(t - kernel_size + 1 + m)[c]
+    with zeros before the sequence; act None or "silu", applied in the same
+    op (ops/linear_attention_ops.py)."""
+    if act not in (None, "silu"):
+        raise ValueError("causal_conv1d act must be None or 'silu', got %r"
+                         % (act,))
+    helper = LayerHelper("causal_conv1d", input=input, param_attr=param_attr,
+                         name=name)
+    w = helper.create_parameter(
+        attr=helper.param_attr,
+        shape=[int(input.shape[-1]), int(kernel_size)], dtype=input.dtype)
+    out = helper.create_variable_for_type_inference(input.dtype)
+    helper.append_op(
+        type="causal_conv1d", inputs={"X": [input], "Filter": [w]},
+        outputs={"Out": [out]},
+        attrs={"activation": act} if act else {})
+    if input.shape is not None:
+        out.shape = tuple(input.shape)
+    return out
+
+
+def gated_delta_rule(q, k, v, g, beta, name=None):
+    """The gated delta rule, a linear-attention mixer with a [dk, dv] state
+    a value head (ops/gated_delta_kernels.py): q, k [B, T, Hk, dk], v [B,
+    T, Hv, dv] with Hk dividing Hv (key head j serves value heads j * Hv /
+    Hk on), g [B, T, Hv] the log decay (<= 0) and beta [B, T, Hv] the write
+    strength -> [B, T, Hv, dv]. Per head, from S_0 = 0:
+    S' = exp(g_t) S_(t-1); S_t = S' + beta_t k_t (v_t - S'^T k_t)^T;
+    o_t = S_t^T q_t, computed in chunks. q and k are l2-normalised over dk
+    first (1e-6 inside the root) and q multiplied by dk^-0.5."""
+    helper = LayerHelper("gated_delta_rule", name=name)
+    out = helper.create_variable_for_type_inference(v.dtype)
+    helper.append_op(
+        type="gated_delta_rule",
+        inputs={"Q": [q], "K": [k], "V": [v], "G": [g], "Beta": [beta]},
+        outputs={"Out": [out]}, attrs={})
+    if v.shape is not None:
+        out.shape = tuple(v.shape)
     return out
 
 

@@ -2,17 +2,24 @@
 dict whose keys are those of a Hugging Face `config.json`.
 
 One builder for the family: token embedding (no scale, no position table),
-N x (RMS norm, self-attention with grouped queries, optional QK-norm, rotary
-positions on the layers whose pattern says so and a sliding window on the
-layers whose pattern says so, RMS norm, a dense SwiGLU FFN or dropless top-k
-routed experts), final RMS norm, an untied output head, next-token
-cross-entropy plus the routers' auxiliary losses. A new decoder-only
+N x (RMS norm, a mixer, RMS norm, a dense SwiGLU FFN or dropless top-k
+routed experts with an optional gated shared expert beside them), final RMS
+norm, an untied output head, next-token cross-entropy plus the routers'
+auxiliary losses. The mixer is, by layer, self-attention (grouped queries,
+optional QK-norm over all channels or a head, rotary positions on the whole
+head or its first channels on the layers whose pattern says so, a sliding
+window on the layers whose pattern says so, an optional sigmoid gate on the
+output) or a gated delta net (a causal depthwise convolution over q, k and
+v, the gated delta rule, a gated RMS norm a head). A new decoder-only
 architecture is a config plus the ops it lacks, not a model file. Users:
-OLMoE-1B-7B (`model_type: olmoe`; Muennighoff et al. 2024, arXiv:2409.02060)
-and SmallThinker-21BA3B (PowerInfer; window and full attention mixed with
+OLMoE-1B-7B (`model_type: olmoe`; Muennighoff et al. 2024, arXiv:2409.02060),
+SmallThinker-21BA3B (PowerInfer; window and full attention mixed with
 period 4, no rotary on the full layers, the router read from the attention's
-normed input, ReGLU experts), whose equations the module follows;
-`causal_lm_reference.py` is the same forward in plain jax.numpy.
+normed input, ReGLU experts) and Qwen3-Next-80B-A3B (`model_type:
+qwen3_next`; three gated delta nets to one gated full-attention layer,
+zero-centred norm weights, 512 experts beside a gated shared one), whose
+equations the module follows; `causal_lm_reference.py` is the same forward
+in plain jax.numpy.
 
 Config keys read (HF names): vocab_size, hidden_size, num_hidden_layers,
 num_attention_heads, num_key_value_heads (a divisor of the heads: query
@@ -23,9 +30,22 @@ norm_topk_prob, rms_norm_eps, rope_theta (None: no rotary), hidden_act
 (silu; relu for experts), attention_bias (false), clip_qkv (null),
 rope_scaling (null), tie_word_embeddings (false), initializer_range, embedding_initializer_range (absent: the
 same), router_aux_loss_coef, router_z_loss_coef; `qk_norm`, which `config.json`
-does not carry because `modeling_olmoe.py` always applies it; and
+does not carry because `modeling_olmoe.py` always applies it (true: over all
+channels before the head split; "head": over each head's own); and
 `router_input` ("own", or "pre_attention": the router reads the
-attention's normed input). SmallThinker's own names are mapped onto these:
+attention's normed input). Qwen3-Next's: full_attention_interval (layer i is
+full attention where (i + 1) % interval == 0, else a gated delta net),
+linear_num_key_heads, linear_num_value_heads, linear_key_head_dim,
+linear_value_head_dim, linear_conv_kernel_dim, partial_rotary_factor (the
+share of a head's channels that rotary turns), moe_intermediate_size (an
+expert's width), shared_expert_intermediate_size, mlp_only_layers (empty),
+decoder_sparse_step (1); and, as keys of their own like `qk_norm`, what
+`modeling_qwen3_next.py` always applies and `config.json` therefore does
+not carry: `norm_zero_centered` (a norm's weight is stored around 0,
+(1 + w) * x_hat) and `attention_gate` (the query projection is twice as
+wide, a head [q, gate], and ctx * sigmoid(gate) enters Wo). `model_type` is
+not read: a config says what it builds by these keys. SmallThinker's own
+names are mapped onto these:
 moe_ffn_hidden_size (intermediate_size), moe_num_primary_experts
 (num_experts), moe_num_active_primary_experts (num_experts_per_tok),
 moe_primary_router_apply_softmax (true), rope_layout and
@@ -41,9 +61,11 @@ computes experts chip * held .. chip * held + held - 1, and attention's and
 the experts' outputs are the partial sums of what is held.
 
 Parameters are created in the order the reference reads them: embedding;
-a layer's input norm, Wq, Wk, Wv, q norm, k norm, Wo, post-attention norm,
-then router, gate, up, down (experts) or gate, up, down (dense); final
-norm; head.
+a layer's input norm, then Wq, Wk, Wv, q norm, k norm, Wo (attention) or
+W_qkvz, W_ba, the convolution's filter, dt_bias, A_log, the gated norm's
+weight, W_out (gated delta net), post-attention norm, then router, gate, up,
+down (experts; then the shared expert's gate, up, down and its sigmoid
+gate's weight) or gate, up, down (dense); final norm; head.
 """
 import paddle_tpu as fluid
 
@@ -54,26 +76,37 @@ DEFAULTS = {
     "initializer_range": 0.02, "router_aux_loss_coef": 0.01,
     "router_z_loss_coef": 0.001, "qk_norm": False, "rope_scaling": None,
     "router_input": "own", "window": None,
-    "moe_primary_router_apply_softmax": True}
+    "moe_primary_router_apply_softmax": True, "norm_zero_centered": False,
+    "attention_gate": False, "partial_rotary_factor": 1.0,
+    "full_attention_interval": 1, "shared_expert_intermediate_size": 0,
+    "mlp_only_layers": [], "decoder_sparse_step": 1}
 # SmallThinker's key -> the key the builder reads
 ALIASES = {"moe_ffn_hidden_size": "intermediate_size",
            "moe_num_primary_experts": "num_experts",
            "moe_num_active_primary_experts": "num_experts_per_tok"}
+# the keys a gated delta net needs
+LINEAR_KEYS = ("linear_num_key_heads", "linear_num_value_heads",
+               "linear_key_head_dim", "linear_value_head_dim",
+               "linear_conv_kernel_dim")
 
 
 def resolve(cfg):
     """`cfg` over DEFAULTS, refusing what the builder cannot build rather
     than building something else under the model's name. Adds what the
-    builder derives: head_dim, experts_held and first_expert (the share),
-    and the two per-layer patterns `rope_layers` and `window_layers`."""
+    builder derives: head_dim, rotary_dim, experts_held and first_expert
+    (the share), and the per-layer patterns `rope_layers`, `window_layers`
+    and `mixer_layers` ("attention" or "gated_delta")."""
     c = dict(DEFAULTS, **cfg)
     for theirs, ours in ALIASES.items():
         if theirs in c:
             c[ours] = c[theirs]
+    if c["num_experts"] and "moe_intermediate_size" in c:
+        c["intermediate_size"] = c["moe_intermediate_size"]
     c.setdefault("num_key_value_heads", c["num_attention_heads"])
     for key, want in (("attention_bias", False), ("clip_qkv", None),
                       ("tie_word_embeddings", False), ("rope_scaling", None),
-                      ("moe_primary_router_apply_softmax", True)):
+                      ("moe_primary_router_apply_softmax", True),
+                      ("mlp_only_layers", []), ("decoder_sparse_step", 1)):
         if c[key] != want:
             raise NotImplementedError(
                 "causal_lm builds %s=%r only, the config has %r"
@@ -87,6 +120,10 @@ def resolve(cfg):
         raise NotImplementedError("causal_lm builds router_input own or "
                                   "pre_attention, the config has %r"
                                   % (c["router_input"],))
+    if c["qk_norm"] not in (False, True, "head"):
+        raise NotImplementedError("causal_lm builds qk_norm false, true (all "
+                                  "channels) or 'head', the config has %r"
+                                  % (c["qk_norm"],))
     if "head_dim" not in c:
         if c["hidden_size"] % c["num_attention_heads"]:
             raise ValueError("hidden_size %d is not a multiple of %d heads"
@@ -100,8 +137,10 @@ def resolve(cfg):
     # is the published number of experts
     share = c.get("share") or {}
     c["experts_held"] = c["num_experts"]
-    c["num_experts"] = share.get("published", {}).get(
-        "moe_num_primary_experts", c["num_experts"])
+    published = share.get("published", {})
+    c["num_experts"] = published.get(
+        "moe_num_primary_experts", published.get("num_experts",
+                                                 c["num_experts"]))
     c["first_expert"] = share.get("chip", 0) * c["experts_held"] \
         if c["experts_held"] != c["num_experts"] else 0
     if c["first_expert"] + c["experts_held"] > c["num_experts"]:
@@ -120,6 +159,24 @@ def resolve(cfg):
         c["sliding_window_size"]
         if c.get("sliding_window_layout", [0] * layers)[i] else None
         for i in range(layers)]
+    c["rotary_dim"] = int(c["head_dim"] * c["partial_rotary_factor"])
+    if c["rotary_dim"] % 2 or not 0 < c["rotary_dim"] <= c["head_dim"]:
+        raise ValueError("partial_rotary_factor %r of a head of %d turns %d "
+                         "channels: not an even number in (0, %d]"
+                         % (c["partial_rotary_factor"], c["head_dim"],
+                            c["rotary_dim"], c["head_dim"]))
+    interval = int(c["full_attention_interval"])
+    c["mixer_layers"] = ["attention" if (i + 1) % interval == 0
+                         else "gated_delta" for i in range(layers)]
+    if "gated_delta" in c["mixer_layers"]:
+        missing = [key for key in LINEAR_KEYS if key not in c]
+        if missing:
+            raise ValueError("full_attention_interval %d makes gated delta "
+                             "nets, which need %s" % (interval, missing))
+        if c["linear_num_value_heads"] % c["linear_num_key_heads"]:
+            raise ValueError("%d linear value heads are no multiple of %d "
+                             "key heads" % (c["linear_num_value_heads"],
+                                            c["linear_num_key_heads"]))
     return c
 
 
@@ -142,34 +199,105 @@ def _linear(x, size, c):
 
 
 def _norm(x, c):
-    return fluid.layers.rms_norm(x, epsilon=c["rms_norm_eps"])
+    return fluid.layers.rms_norm(x, epsilon=c["rms_norm_eps"],
+                                 zero_centered=c["norm_zero_centered"])
 
 
 def attention(x, pos, c):
     """Causal self-attention over x [B, T, D], with the window c["window"]
     where the layer has one. QK-norm, where the config has it, is over all
-    channels before the head split; rotary positions, where the layer has
-    them, turn every head of q and k; key/value heads may be fewer than
-    query heads; the core is layers.fused_attention."""
+    channels before the head split or ("head") over each head after it;
+    rotary positions, where the layer has them, turn the first rotary_dim
+    channels of every head of q and k; key/value heads may be fewer than
+    query heads; the core is layers.fused_attention. With attention_gate the
+    query projection is twice as wide, a head [q, gate], and the context is
+    multiplied by sigmoid(gate) before the output projection."""
     d, hd = c["hidden_size"], c["head_dim"]
     h, hkv = c["num_attention_heads"], c["num_key_value_heads"]
-    q, k, v = (_linear(x, n * hd, c) for n in (h, hkv, hkv))
-    if c["qk_norm"]:
+    gated = c["attention_gate"]
+    q, k, v = (_linear(x, n * hd, c) for n in (2 * h if gated else h, hkv,
+                                               hkv))
+    if c["qk_norm"] is True:
         q, k = _norm(q, c), _norm(k, c)
-    q, k, v = (fluid.layers.reshape(t, shape=[0, -1, n, hd])
-               for t, n in ((q, h), (k, hkv), (v, hkv)))
+    q = fluid.layers.reshape(q, shape=[0, -1, h, 2 * hd if gated else hd])
+    k, v = (fluid.layers.reshape(t, shape=[0, -1, hkv, hd]) for t in (k, v))
+    if gated:
+        q, gate = fluid.layers.split(q, 2, dim=-1)
+    if c["qk_norm"] == "head":
+        q, k = _norm(q, c), _norm(k, c)
     if c["rope_theta"] is not None:
-        q, k = (fluid.layers.rotary_embedding(t, pos, base=c["rope_theta"])
-                for t in (q, k))
+        q, k = (fluid.layers.rotary_embedding(
+            t, pos, base=c["rope_theta"], rotary_dim=c["rotary_dim"])
+            for t in (q, k))
     ctx = fluid.layers.fused_attention(q, k, v, causal=True,
                                        window=c["window"])
+    if gated:
+        ctx = ctx * fluid.layers.sigmoid(gate)
     return _linear(fluid.layers.reshape(ctx, shape=[0, -1, h * hd]), d, c)
+
+
+def gated_delta_net(x, c):
+    """The linear-attention mixer over x [B, T, D]: one projection to q, k
+    (Hk heads of dk), v and the norm's gate z (Hv heads of dv; stored a key
+    head as [q, k, v of its value heads, z of them]) and one to b and a (a
+    value head each); a causal depthwise convolution then SiLU over q, k, v
+    side by side; beta = sigmoid(b), g = -exp(A_log) * softplus(a +
+    dt_bias) in float32; the gated delta rule (q, k l2-normalised, q over
+    sqrt(dk)); w * o_hat * silu(z) over each head's dv; the output
+    projection. A_log is log(U(0, 16)), dt_bias 1, the norm's weight 1."""
+    init = fluid.initializer
+    hk, hv = c["linear_num_key_heads"], c["linear_num_value_heads"]
+    dk, dv = c["linear_key_head_dim"], c["linear_value_head_dim"]
+    rep = hv // hk
+    qkvz = fluid.layers.reshape(
+        _linear(x, hk * (2 * dk + 2 * rep * dv), c),
+        shape=[0, -1, hk, 2 * dk + 2 * rep * dv])
+    q, k, v, z = fluid.layers.split(qkvz, [dk, dk, rep * dv, rep * dv],
+                                    dim=-1)
+    b, a = fluid.layers.split(
+        fluid.layers.reshape(_linear(x, 2 * hv, c), shape=[0, -1, hk,
+                                                           2 * rep]),
+        2, dim=-1)
+    b, a = (fluid.layers.cast(fluid.layers.reshape(t, shape=[0, -1, hv]),
+                              "float32") for t in (b, a))
+    mixed = fluid.layers.causal_conv1d(
+        fluid.layers.concat([fluid.layers.reshape(t, shape=[0, -1, n])
+                             for t, n in ((q, hk * dk), (k, hk * dk),
+                                          (v, hv * dv))], axis=2),
+        c["linear_conv_kernel_dim"], act="silu",
+        param_attr=fluid.ParamAttr(initializer=init.Normal(
+            0.0, c["initializer_range"])))
+    q, k, v = fluid.layers.split(mixed, [hk * dk, hk * dk, hv * dv], dim=-1)
+    q, k = (fluid.layers.reshape(t, shape=[0, -1, hk, dk]) for t in (q, k))
+    v = fluid.layers.reshape(v, shape=[0, -1, hv, dv])
+    dt_bias = fluid.layers.create_parameter(
+        [hv], "float32", default_initializer=init.Constant(1.0))
+    a_log = fluid.layers.create_parameter(
+        [hv], "float32", default_initializer=init.LogUniform(0.0, 16.0))
+    g = fluid.layers.scale(
+        fluid.layers.exp(a_log) * fluid.layers.softplus(a + dt_bias),
+        scale=-1.0)
+    o = fluid.layers.gated_delta_rule(q, k, v, g, fluid.layers.sigmoid(b))
+    o = fluid.layers.rms_norm(
+        o, epsilon=c["rms_norm_eps"],
+        gate=fluid.layers.reshape(z, shape=[0, -1, hv, dv]))
+    return _linear(fluid.layers.reshape(o, shape=[0, -1, hv * dv]),
+                   c["hidden_size"], c)
+
+
+def _swiglu(x, width, c):
+    gate = fluid.layers.swish(_linear(x, width, c))
+    up = _linear(x, width, c)
+    return _linear(gate * up, c["hidden_size"], c)
 
 
 def feed_forward(x, c, router_input=None):
     """(out, aux) of one layer's FFN on x [B, T, D]: routed experts give
     aux = (balance_loss, z_loss, expert_load), the dense SwiGLU None.
-    `router_input` is what the router reads where it is not x."""
+    `router_input` is what the router reads where it is not x. A shared
+    expert (shared_expert_intermediate_size), a SwiGLU every token passes
+    scaled by sigmoid(x w_s), is added to the routed experts' output; in a
+    share of a layer it is every chip's own, computed once."""
     if c["num_experts"]:
         out, balance, z, load = fluid.layers.moe_ffn(
             x, num_experts=c["num_experts"], d_expert=c["intermediate_size"],
@@ -179,10 +307,33 @@ def feed_forward(x, c, router_input=None):
                 0.0, c["initializer_range"])),
             router_input=router_input, activation=c["hidden_act"],
             experts_held=c["experts_held"], first_expert=c["first_expert"])
+        if c["shared_expert_intermediate_size"]:
+            shared = _swiglu(x, c["shared_expert_intermediate_size"], c)
+            out = out + shared * fluid.layers.sigmoid(_linear(x, 1, c))
         return out, (balance, z, load)
-    gate = fluid.layers.swish(_linear(x, c["intermediate_size"], c))
-    up = _linear(x, c["intermediate_size"], c)
-    return _linear(gate * up, c["hidden_size"], c), None
+    return _swiglu(x, c["intermediate_size"], c), None
+
+
+def _count_layer(c, mixer):
+    """One count a layer built, by what the model puts around its ops and
+    no op can observe (the ops' own counters have the rest: heads, widths,
+    paths)."""
+    from ..observability.registry import REGISTRY
+    attention = mixer == "attention"
+    REGISTRY.counter(
+        "ptpu_causal_lm_layers_total",
+        "decoder layers causal_lm built, by mixer, the channels of a head "
+        "its rotary turns (0: none), whether a sigmoid gate multiplies the "
+        "attention's output, the taps of the convolution before a gated "
+        "delta rule (0: none) and the width of the shared expert beside the "
+        "routed ones (0: none)"
+    ).inc(mixer=mixer,
+          rotary_dim=str(c["rotary_dim"] if attention
+                         and c["rope_theta"] is not None else 0),
+          gate=str(bool(attention and c["attention_gate"])).lower(),
+          conv=str(0 if attention else c["linear_conv_kernel_dim"]),
+          shared=str(c["shared_expert_intermediate_size"]
+                     if c["num_experts"] else 0))
 
 
 def causal_lm(cfg, seq_len):
@@ -206,8 +357,10 @@ def causal_lm(cfg, seq_len):
     aux = []
     for i in range(c["num_hidden_layers"]):
         cl = _layer(c, i)
+        _count_layer(cl, c["mixer_layers"][i])
         a = _norm(h, cl)
-        h = h + attention(a, pos, cl)
+        h = h + (attention(a, pos, cl) if c["mixer_layers"][i] == "attention"
+                 else gated_delta_net(a, cl))
         out, layer_aux = feed_forward(
             _norm(h, cl), cl,
             router_input=a if c["router_input"] == "pre_attention" else None)

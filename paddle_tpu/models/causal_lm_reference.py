@@ -8,8 +8,12 @@ it (tests/unittests/test_causal_lm.py).
 It follows Hugging Face's `modeling_olmoe.py` and, for the keys
 SmallThinker-21BA3B adds (grouped queries, a window and rotary positions by
 layer, the router read before attention, ReGLU experts), the layer as
-PowerInfer's `config.json` and model card give it; each departure is marked
-"Departure:" below. `params` is the list of the Program's parameters in the
+PowerInfer's `config.json` and model card give it; for Qwen3-Next's (gated
+delta nets on three layers of four, a gated full-attention layer with
+QK-norm a head and partial rotary, zero-centred norm weights, a gated shared
+expert) Hugging Face's `modeling_qwen3_next.py`, the delta rule as its
+token-by-token recurrence (`torch_recurrent_gated_delta_rule`). Each
+departure is marked "Departure:" below. `params` is the list of the Program's parameters in the
 order models/causal_lm.py creates them.
 
 One chip's share of a layer comes as arguments: `attention` computes the
@@ -35,22 +39,29 @@ def layer_config(c, i):
                 else None, window=c["window_layers"][i])
 
 
-def rms_norm(x, w, eps):
+def rms_norm(x, w, eps, zero_centered=False):
+    """w * x_hat, or (1 + w) * x_hat where the weight is stored around 0
+    (Qwen3NextRMSNorm)."""
     # Departure: HF rounds the normalised value to the input dtype before
     # the weight multiplies it; in float32 the two are the same
+    if zero_centered:
+        w = 1.0 + w
     return w * x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps)
 
 
-def rope(x, pos, theta):
-    """x [B, T, H, D], pos [B, T]: HF's rotate_half convention, the pair
-    (i, i + D/2) turns by pos * theta^(-2i/D)."""
-    d = x.shape[-1]
+def rope(x, pos, theta, rotary_dim=None):
+    """x [B, T, H, D], pos [B, T]: HF's rotate_half convention over the
+    first R = rotary_dim channels (all by default), the pair (i, i + R/2)
+    turns by pos * theta^(-2i/R); the channels from R on pass."""
+    d = x.shape[-1] if rotary_dim is None else rotary_dim
     inv_freq = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
     angle = pos.astype(jnp.float32)[:, :, None, None] * inv_freq
     angle = jnp.concatenate([angle, angle], -1)
+    x, rest = x[..., :d], x[..., d:]
     x1, x2 = jnp.split(x, 2, axis=-1)
     rotated = jnp.concatenate([-x2, x1], -1)
-    return x * jnp.cos(angle) + rotated * jnp.sin(angle)
+    return jnp.concatenate(
+        [x * jnp.cos(angle) + rotated * jnp.sin(angle), rest], -1)
 
 
 def attention(a, pos, wq, wk, wv, q_norm, k_norm, wo, c):
@@ -58,18 +69,28 @@ def attention(a, pos, wq, wk, wv, q_norm, k_norm, wo, c):
     hd], wk and wv [D, Hkv x hd], wo [Hq x hd, D]; query head h reads
     key/value head h // (Hq / Hkv). c["rope_theta"] None: no position
     enters (NoPE). c["window"] w: key j is visible to query i iff j <= i and
-    i - j < w (the Hugging Face sliding-window mask's convention)."""
+    i - j < w (the Hugging Face sliding-window mask's convention). With
+    attention_gate wq is [D, Hq x 2 hd], a head [q, gate], and the context
+    is multiplied by sigmoid(gate) before wo; qk_norm "head" is over each
+    head's hd after the split, with one weight [hd]."""
     b, t, _ = a.shape
-    hd = c["head_dim"]
-    h, hkv = wq.shape[1] // hd, wk.shape[1] // hd
+    hd, eps = c["head_dim"], c["rms_norm_eps"]
+    gated, centred = c["attention_gate"], c["norm_zero_centered"]
+    h, hkv = wq.shape[1] // (2 * hd if gated else hd), wk.shape[1] // hd
     q, k, v = a @ wq, a @ wk, a @ wv
-    if c["qk_norm"]:     # over all channels, before the head split
-        q = rms_norm(q, q_norm, c["rms_norm_eps"])
-        k = rms_norm(k, k_norm, c["rms_norm_eps"])
-    q = q.reshape(b, t, h, hd)
+    if c["qk_norm"] is True:     # over all channels, before the head split
+        q = rms_norm(q, q_norm, eps, centred)
+        k = rms_norm(k, k_norm, eps, centred)
+    q = q.reshape(b, t, h, -1)
+    if gated:
+        q, gate = q[..., :hd], q[..., hd:]
     k, v = (x.reshape(b, t, hkv, hd) for x in (k, v))
+    if c["qk_norm"] == "head":
+        q = rms_norm(q, q_norm, eps, centred)
+        k = rms_norm(k, k_norm, eps, centred)
     if c["rope_theta"] is not None:
-        q, k = rope(q, pos, c["rope_theta"]), rope(k, pos, c["rope_theta"])
+        q, k = (rope(x, pos, c["rope_theta"], c["rotary_dim"])
+                for x in (q, k))
     k, v = (jnp.repeat(x, h // hkv, axis=2) for x in (k, v))
     s = jnp.einsum("bqhd,bkhd->bhqk", q, k) * hd ** -0.5
     age = jnp.arange(t)[:, None] - jnp.arange(t)[None, :]       # i - j
@@ -78,7 +99,76 @@ def attention(a, pos, wq, wk, wv, q_norm, k_norm, wo, c):
         visible = visible & (age < c["window"])
     s = jnp.where(visible, s, -jnp.inf)
     ctx = jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s, -1), v)
+    if gated:
+        ctx = ctx * jax.nn.sigmoid(gate)
     return ctx.reshape(b, t, h * hd) @ wo
+
+
+def causal_conv(x, w):
+    """x [B, T, C], w [C, K]: y_t[c] = sum_m w[c, m] x_(t-K+1+m)[c], zeros
+    before the sequence: K shifted adds."""
+    width, t = w.shape[1], x.shape[1]
+    xp = jnp.pad(x, [(0, 0), (width - 1, 0), (0, 0)])
+    return sum(xp[:, m:m + t] * w[:, m] for m in range(width))
+
+
+def l2norm(x, eps=1e-6):
+    return x * jax.lax.rsqrt(jnp.sum(x * x, -1, keepdims=True) + eps)
+
+
+def delta_rule(q, k, v, g, beta):
+    """The gated delta rule, token by token: q, k [B, T, H, dk] (already
+    normalised and scaled), v [B, T, H, dv], g and beta [B, T, H] -> o [B,
+    T, H, dv]. A head's state S [dk, dv] starts at zero; S' = exp(g_t) S;
+    S = S' + beta_t k_t (v_t - S'^T k_t)^T; o_t = S^T q_t."""
+    def step(state, xs):
+        qt, kt, vt, gt, bt = xs
+        state = state * jnp.exp(gt)[..., None, None]
+        written = vt - jnp.einsum("bhkv,bhk->bhv", state, kt)
+        state = state + kt[..., :, None] * (bt[..., None] * written)[
+            ..., None, :]
+        return state, jnp.einsum("bhkv,bhk->bhv", state, qt)
+
+    b, _, h, dk = q.shape
+    _, o = jax.lax.scan(step, jnp.zeros((b, h, dk, v.shape[-1]), q.dtype),
+                        tuple(jnp.moveaxis(x, 1, 0)
+                              for x in (q, k, v, g, beta)))
+    return jnp.moveaxis(o, 0, 1)
+
+
+def gated_delta_net(a, w_qkvz, w_ba, w_conv, dt_bias, a_log, w_norm, w_out,
+                    c):
+    """Qwen3NextGatedDeltaNet on a [B, T, D]: w_qkvz's columns are a key
+    head's [q dk, k dk, v rep x dv, z rep x dv] and w_ba's its [b rep, a
+    rep] (fix_query_key_value_ordering), rep value heads a key head; key
+    head j serves value heads j rep .. j rep + rep - 1
+    (repeat_interleave)."""
+    b, t, _ = a.shape
+    hk, hv = c["linear_num_key_heads"], c["linear_num_value_heads"]
+    dk, dv = c["linear_key_head_dim"], c["linear_value_head_dim"]
+    rep = hv // hk
+    qkvz = (a @ w_qkvz).reshape(b, t, hk, -1)
+    q, k, v, z = jnp.split(qkvz, [dk, 2 * dk, 2 * dk + rep * dv], axis=-1)
+    ba = (a @ w_ba).reshape(b, t, hk, 2 * rep)
+    beta = jax.nn.sigmoid(ba[..., :rep].reshape(b, t, hv))
+    g = -jnp.exp(a_log) * jax.nn.softplus(
+        ba[..., rep:].reshape(b, t, hv) + dt_bias)
+    mixed = jax.nn.silu(causal_conv(jnp.concatenate(
+        [x.reshape(b, t, -1) for x in (q, k, v)], -1), w_conv))
+    q, k, v = jnp.split(mixed, [hk * dk, 2 * hk * dk], axis=-1)
+    q, k = (jnp.repeat(l2norm(x.reshape(b, t, hk, dk)), rep, axis=2)
+            for x in (q, k))
+    o = delta_rule(q * dk ** -0.5, k, v.reshape(b, t, hv, dv), g, beta)
+    # Qwen3NextRMSNormGated: the weight, initialised to 1, is not
+    # zero-centred
+    o = rms_norm(o, w_norm, c["rms_norm_eps"]) * jax.nn.silu(
+        z.reshape(b, t, hv, dv))
+    return o.reshape(b, t, hv * dv) @ w_out
+
+
+def shared_expert(m, wg, wu, wd, ws):
+    """sigmoid(m w_s) * SwiGLU(m): every token passes it."""
+    return jax.nn.sigmoid(m @ ws) * ((jax.nn.silu(m @ wg) * (m @ wu)) @ wd)
 
 
 def gated_unit(m, wg, wu, c):
@@ -143,27 +233,32 @@ def forward(cfg, params, ids, pos):
     with jax.default_matmul_precision("highest"):
         h = take(1)[0][ids]
         b, t, d = h.shape
+        centred = c["norm_zero_centered"]
         for i in range(layers):
-            w_in, wq, wk, wv = take(4)
-            q_norm, k_norm = take(2) if c["qk_norm"] else (None, None)
-            wo, w_post = take(2)
-            a = rms_norm(h, w_in, eps)
-            h = h + attention(a, pos, wq, wk, wv, q_norm, k_norm, wo,
-                              layer_config(c, i))
-            m = rms_norm(h, w_post, eps)
+            a = rms_norm(h, take(1)[0], eps, centred)
+            if c["mixer_layers"][i] == "gated_delta":
+                h = h + gated_delta_net(a, *take(7), c)
+            else:
+                wq, wk, wv = take(3)
+                q_norm, k_norm = take(2) if c["qk_norm"] else (None, None)
+                h = h + attention(a, pos, wq, wk, wv, q_norm, k_norm,
+                                  take(1)[0], layer_config(c, i))
+            m = rms_norm(h, take(1)[0], eps, centred)
             if e:
                 out, lb, lz, ld = routed_experts(
                     m.reshape(b * t, d), *take(4), c,
                     router_x=a.reshape(b * t, d)
                     if c["router_input"] == "pre_attention" else None)
                 h = h + out.reshape(b, t, d)
+                if c["shared_expert_intermediate_size"]:
+                    h = h + shared_expert(m, *take(4))
                 balance, z, load = balance + lb / layers, z + lz / layers, \
                     load + ld
             else:
                 wg, wu, wd = take(3)
                 h = h + (jax.nn.silu(m @ wg) * (m @ wu)) @ wd
         w_f, w_lm = take(2)
-        logits = rms_norm(h, w_f, eps) @ w_lm
+        logits = rms_norm(h, w_f, eps, centred) @ w_lm
     if next(params, None) is not None:
         raise ValueError("the reference read fewer parameters than the "
                          "program has: the two are not the same architecture")

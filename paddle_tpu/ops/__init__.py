@@ -12,3 +12,4 @@ from . import tail_ops       # noqa: F401
 from . import volumetric_ops  # noqa: F401
 from . import guard_ops      # noqa: F401
 from . import quant_ops      # noqa: F401
+from . import linear_attention_ops  # noqa: F401

@@ -20,7 +20,7 @@ Accepted forms of PADDLE_TPU_PALLAS:
     - "attn,xent"       : allowlist — exactly the named ops on, the
                           rest off.  Unknown names raise LOUDLY (a typo
                           must not silently run the other path).
-Op names: attn, xent, ln, lstm, seq (KERNEL_OPS).  For 'attn' the flag
+Op names: attn, xent, ln, lstm, seq, gdr (KERNEL_OPS).  For 'attn' the flag
 is an opt-OUT only: fused_attention's positive dispatch is always the
 flash_at() rule, so enabling 'attn' does not force flash below the
 crossover (pin FLAGS_flash_min_seq=0 for that).
@@ -67,6 +67,7 @@ DEFAULT_TILES = {
     "ln": {"tile_bytes": 1 << 20},
     "lstm": {"block_b": 0},
     "seq": {"block_n": 8},
+    "gdr": {"chunk": 64, "block_h": 8},
 }
 KERNEL_OPS = frozenset(DEFAULT_TILES)
 # Dense attention below this query length, flash at and above it.  The

@@ -1,0 +1,52 @@
+"""Lowerings of the linear-attention mixer's ops: the gated delta rule
+(ops/gated_delta_kernels.py) and the short causal depthwise convolution
+over time that feeds it. No reference-era op computes either: sequence_conv
+is LoD-based and dense over channels."""
+import jax
+import jax.numpy as jnp
+
+from ..core.registry import register, single
+from .kernel_config import pallas_on
+
+
+def gated_delta_path():
+    """"kernel" where the gated delta rule's Pallas kernels are on
+    (kernel_config.pallas_on("gdr"): a TPU, or PADDLE_TPU_PALLAS), else
+    "scan". The one place that decides; the layer counter reads it too."""
+    return "kernel" if pallas_on("gdr") else "scan"
+
+
+@register("gated_delta_rule", calls_pallas=True)
+def _gated_delta_rule(ctx, ins, attrs):
+    """Out [B, T, Hv, dv] of the gated delta rule for Q, K [B, T, Hk, dk],
+    V [B, T, Hv, dv], G (log decay) and Beta [B, T, Hv]. Under AMP the
+    matmuls take bf16 operands; G, Beta, the running sums, exponentials and
+    the state are float32 either way, so the op is in neither AMP table."""
+    from .gated_delta_kernels import gated_delta_rule
+    out = gated_delta_rule(
+        *(single(ins, name) for name in ("Q", "K", "V", "G", "Beta")),
+        operand_dtype=jnp.bfloat16 if getattr(ctx, "amp", False) else None,
+        path=gated_delta_path())
+    return {"Out": [out]}
+
+
+@register("causal_conv1d")
+def _causal_conv1d(ctx, ins, attrs):
+    """y_t[c] = sum_m w[c, m] x_(t-K+1+m)[c] over X [B, T, C] with Filter
+    [C, K], zeros before the sequence, then `activation` ("silu" or none):
+    K shifted multiply-adds in float32, back in x's dtype. Under
+    jax.checkpoint: the backward pass keeps x as it came (bf16 under AMP)
+    and converts and pads it again, where XLA would hold the float32 padded
+    copy from the forward pass (256 MiB a layer at [2, 4096, 8192]; AOT
+    compile, PR 33)."""
+    silu = attrs.get("activation") == "silu"
+
+    @jax.checkpoint
+    def conv(x, w):
+        width, t = w.shape[1], x.shape[1]
+        xp = jnp.pad(x.astype(jnp.float32), [(0, 0), (width - 1, 0), (0, 0)])
+        w = w.astype(jnp.float32)
+        y = sum(xp[:, m:m + t] * w[:, m] for m in range(width))
+        return (jax.nn.silu(y) if silu else y).astype(x.dtype)
+
+    return {"Out": [conv(single(ins, "X"), single(ins, "Filter"))]}

@@ -250,44 +250,67 @@ def _layer_norm(ctx, ins, attrs):
             "Mean": [mean.reshape(lead)], "Variance": [var.reshape(lead)]}
 
 
+def _rms_norm_math(x, scale, gate, begin, eps, zero_centered):
+    x32 = x.astype(jnp.float32)
+    ms = jnp.mean(jnp.square(x32), axis=tuple(range(begin, x.ndim)),
+                  keepdims=True)
+    scale = scale.astype(jnp.float32)
+    if zero_centered:
+        scale = 1.0 + scale
+    y = x32 * lax.rsqrt(ms + eps) * scale.reshape(x.shape[begin:])
+    if gate is not None:
+        y = y * jax.nn.silu(gate.astype(jnp.float32))
+    return y.astype(x.dtype)
+
+
 @register("rms_norm")
 def _rms_norm(ctx, ins, attrs):
     """y = scale * x / sqrt(mean(x^2) + eps) over the axes from
     begin_norm_axis on; the statistics and the product accumulate in
-    float32 whatever x's dtype is, and y comes back in it. Plain jax.numpy:
-    no Pallas kernel until a benchmark cell shows one winning."""
+    float32 whatever x's dtype is, and y comes back in it. zero_centered:
+    the weight is stored around 0, y = (1 + scale) * x_hat. Gate, where
+    given (x's shape): y = scale * x_hat * silu(gate), under jax.checkpoint
+    so that the backward pass keeps x and the gate as they came and not
+    their float32 copies (192 MiB a layer at [2, 4096, 32, 128]; AOT
+    compile, PR 33). Plain jax.numpy: no Pallas kernel until a benchmark
+    cell shows one winning."""
     x = single(ins, "X")
-    scale = single(ins, "Scale")
-    begin = attrs.get("begin_norm_axis", 1) % x.ndim
-    x32 = x.astype(jnp.float32)
-    ms = jnp.mean(jnp.square(x32), axis=tuple(range(begin, x.ndim)),
-                  keepdims=True)
-    y = x32 * lax.rsqrt(ms + attrs.get("epsilon", 1e-5)) \
-        * scale.astype(jnp.float32).reshape(x.shape[begin:])
-    return {"Y": [y.astype(x.dtype)]}
+    args = (attrs.get("begin_norm_axis", 1) % x.ndim,
+            attrs.get("epsilon", 1e-5), attrs.get("zero_centered", False))
+    if ins.get("Gate"):
+        y = jax.checkpoint(lambda x, s, g: _rms_norm_math(x, s, g, *args))(
+            x, single(ins, "Scale"), single(ins, "Gate"))
+    else:
+        y = _rms_norm_math(x, single(ins, "Scale"), None, *args)
+    return {"Y": [y]}
 
 
 @register("rotary_embedding")
 def _rotary_embedding(ctx, ins, attrs):
     """Rotary position embedding of x [B, T, H, D] at the positions Pos
     [B, T] (an input, not a constant: a decode step feeds its own). The
-    half-split convention: the pair (i, i + D/2) of every head turns by
-    pos * base^(-2i/D). Angles, cos and sin and the rotation are float32;
-    the result comes back in x's dtype."""
+    half-split convention over the first R = rotary_dim channels (all D by
+    default): the pair (i, i + R/2) of every head turns by pos *
+    base^(-2i/R), the channels from R on pass. Angles, cos and sin and the
+    rotation are float32; the result comes back in x's dtype."""
     x = single(ins, "X")
     pos = single(ins, "Pos")
-    d = x.shape[-1]
-    if d % 2:
-        raise ValueError("rotary_embedding needs an even head width, got %d"
-                         % d)
+    d = attrs.get("rotary_dim") or x.shape[-1]
+    if d % 2 or d > x.shape[-1]:
+        raise ValueError("rotary_embedding needs an even width up to the "
+                         "head's %d, got %d" % (x.shape[-1], d))
     inv_freq = attrs.get("base", 10000.0) ** (
         -jnp.arange(0, d, 2, dtype=jnp.float32) / d)
     angle = pos.reshape(x.shape[:2]).astype(jnp.float32)[:, :, None, None] \
         * inv_freq
     cos, sin = jnp.cos(angle), jnp.sin(angle)
-    x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
-    out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
-    return _out(out.astype(x.dtype))
+    x32 = x.astype(jnp.float32)
+    whole = d == x.shape[-1]
+    x1, x2 = jnp.split(x32 if whole else x32[..., :d], 2, axis=-1)
+    parts = [x1 * cos - x2 * sin, x2 * cos + x1 * sin]
+    if not whole:
+        parts.append(x32[..., d:])
+    return _out(jnp.concatenate(parts, -1).astype(x.dtype))
 
 
 @register("lrn")

@@ -46,14 +46,14 @@ __all__ = ["flash_attention", "softmax_xent", "layer_norm",
 _NEG = -1e30
 
 # The name each pallas_call gives its Mosaic custom call: the HLO instruction
-# is `<name>.<n>`, and that is what a device trace, the profiler's per-op
-# table and the benchmark's per-kernel metrics find it by (without one it is
-# named by the sanitised name stack: `fn.65`, `jvp__.33`). Every call below
-# takes one of these; no name is a prefix of another but for a further word.
+# is `<name>.<n>`, which a device trace, the profiler's per-op table and the
+# benchmark's per-kernel metrics find it by. Every call below takes one, the
+# last two are gated_delta_kernels.py's; none prefixes another but by a word.
 KERNEL_NAMES = (
     "ptpu_flash_fwd", "ptpu_flash_bwd_dkdv", "ptpu_flash_bwd_dq",
     "ptpu_softmax_xent_fwd", "ptpu_layer_norm_fwd", "ptpu_lstm_seq",
-    "ptpu_lstmp_seq", "ptpu_masked_softmax", "ptpu_masked_pool")
+    "ptpu_lstmp_seq", "ptpu_masked_softmax", "ptpu_masked_pool",
+    "ptpu_gated_delta_fwd", "ptpu_gated_delta_bwd")
 
 
 def _interpret_default():

@@ -65,10 +65,11 @@ def _counts():
                         router_input="pre_attention", path=GROUPED_MATMUL,
                         rows="held"),
         "full": _counter("ptpu_attention_layers_total", kind="full",
-                         window="0", q_heads="4", kv_heads="2", path="dense"),
+                         window="0", q_heads="4", kv_heads="2", path="dense",
+                         head_dim="8"),
         "window": _counter("ptpu_attention_layers_total", kind="window",
                            window="16", q_heads="4", kv_heads="2",
-                           path="dense")}
+                           path="dense", head_dim="8")}
 
 
 def _run_program(amp):

@@ -169,18 +169,22 @@ def test_an_at_sign_would_cut_the_op_name_short():
 # --- a name a kernel -------------------------------------------------------
 
 def test_every_pallas_call_takes_its_name_from_kernel_names():
-    with open(pallas_kernels.__file__) as f:
-        tree = ast.parse(f.read())
+    from paddle_tpu.ops import gated_delta_kernels
     names = []
-    for node in ast.walk(tree):
-        if isinstance(node, ast.Call) and isinstance(
-                node.func, ast.Attribute) and node.func.attr == "pallas_call":
-            kw = {k.arg: k.value for k in node.keywords}
-            assert isinstance(kw.get("name"), ast.Constant), \
-                "pallas_call at line %d has no literal name=" % node.lineno
-            names.append(kw["name"].value)
+    for module in (pallas_kernels, gated_delta_kernels):
+        with open(module.__file__) as f:
+            tree = ast.parse(f.read())
+        for node in ast.walk(tree):
+            if isinstance(node, ast.Call) and isinstance(
+                    node.func, ast.Attribute) \
+                    and node.func.attr == "pallas_call":
+                kw = {k.arg: k.value for k in node.keywords}
+                assert isinstance(kw.get("name"), ast.Constant), \
+                    "pallas_call at line %d has no literal name=" \
+                    % node.lineno
+                names.append(kw["name"].value)
     assert sorted(names) == sorted(pallas_kernels.KERNEL_NAMES)
-    assert len(set(names)) == len(names) == 9
+    assert len(set(names)) == len(names) == 11
     for a in names:         # a reader matching `<name>` or `<name>.<n>`
         for b in names:     # never counts one kernel under another
             assert a == b or not (b + ".").startswith(a + ".")
@@ -591,3 +595,51 @@ def test_routed_ffn_moves_its_rows_without_a_relayout(one_chip, held):
     assert moved == []
     assert len(re.findall(r"%ragged-dot-none[\w.]* = ", text)) == 9
     assert len(re.findall(r" while\(", text)) == (2 if held < 16 else 0)
+
+
+def test_gated_delta_kernels_at_the_cells_shapes_on_a_described_v5e(
+        one_chip, monkeypatch):
+    """fluid.layers.gated_delta_rule and its grad op at the Qwen3-Next
+    cell's shapes (2 x 4096 tokens, 16 key heads on 32 value heads of 128,
+    bf16 operands), lowered by build_program_fn and compiled for a TPU:
+    Mosaic takes both kernels at the default chunk and heads a step (their
+    transposed dots, a [64, 64] tile, 512 KiB of state scratch), the
+    forward kernel runs under the forward op and once more, for the states,
+    under the grad op beside the reverse kernel, and each instruction is
+    named from KERNEL_NAMES."""
+    from paddle_tpu.ops import kernel_config
+    monkeypatch.delenv("PADDLE_TPU_PALLAS", raising=False)
+    monkeypatch.setattr(kernel_config, "dispatch_platform", lambda: "tpu")
+    b, t, hk, hv, d = 2, 4096, 16, 32, 128
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.unique_name.guard(), fluid.program_guard(main, startup):
+        main.enable_mixed_precision()
+        feeds = [fluid.layers.data(name=n, shape=list(s), dtype="float32")
+                 for n, s in (("q", (t, hk, d)), ("k", (t, hk, d)),
+                              ("v", (t, hv, d)), ("g", (t, hv)),
+                              ("beta", (t, hv)))]
+        for var in feeds:
+            var.stop_gradient = False
+        loss = fluid.layers.mean(fluid.layers.gated_delta_rule(*feeds))
+        fluid.backward.append_backward(loss)
+    names = ["q", "k", "v", "g", "beta"]
+    fetch = [loss.name] + [n + "@GRAD" for n in names]
+    rw, ro, out = lowering.analyze_state(main, names, fetch)
+    fn = lowering.build_program_fn(main, names, fetch, rw, ro, out)
+    args = [jax.ShapeDtypeStruct((b,) + tuple(v.shape[1:]),
+                                 jnp.bfloat16 if v.name in "qkv"
+                                 else jnp.float32, sharding=one_chip)
+            for v in feeds]
+    text = _compile_uncached(lambda *a: fn(list(a), [], [], 0),
+                             *args).as_text()
+    calls = re.findall(
+        r'%?([\w.\-]+) = [^\n]*custom_call_target="tpu_custom_call"'
+        r'[^\n]*op_name="([^"]*)"', text)
+    under = sorted(
+        (name.rpartition(".")[0] if name.rpartition(".")[2].isdigit()
+         else name, lowering.parse_op_scope(op_name)[0])
+        for name, op_name in calls)
+    assert under == [
+        ("ptpu_gated_delta_bwd", "gated_delta_rule_grad"),
+        ("ptpu_gated_delta_fwd", "gated_delta_rule"),
+        ("ptpu_gated_delta_fwd", "gated_delta_rule_grad")]

@@ -18,7 +18,8 @@ from paddle_tpu.models import image_classification
 from paddle_tpu.observability.registry import REGISTRY
 
 CALLS_PALLAS = {"fused_attention", "layer_norm", "softmax_with_cross_entropy",
-                "sequence_pool", "sequence_softmax", "lstm", "lstmp"}
+                "sequence_pool", "sequence_softmax", "lstm", "lstmp",
+                "gated_delta_rule"}
 
 
 @pytest.fixture(autouse=True)
@@ -304,7 +305,8 @@ def test_the_rules_that_import_pallas_kernels_carry_the_field():
     reach = set()
     for op_type, od in registry._OPS.items():
         src = inspect.getsource(od.lower)
-        if re.search(r"import pallas_kernels|pallas_kernels\.", src):
+        if re.search(r"import pallas_kernels|pallas_kernels\.|"
+                     r"gated_delta_kernels", src):
             reach.add(op_type)
     assert reach == CALLS_PALLAS
     assert {t for t, od in registry._OPS.items()
