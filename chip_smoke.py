@@ -23,6 +23,10 @@ from a seed, and checks what comes out by the repo's own means:
      program, replicated and with the ZeRO-sharded weight update.
   E  the timing-barrier premise: K steps timed to jax.block_until_ready
      and to a device->host fetch agree (core/utils.device_fetch_barrier).
+  F  causal_conv1d at the Qwen3-Next cell's shape, [1, 4096, 8192] bf16:
+     the two Pallas kernels against the rule's jax.numpy passes, forward
+     and forward + backward on the host's clock, and the largest error of
+     y, dx and dw of either against a float32 recomputation.
 
 Every phase that fails makes the exit code non-zero. Timings are printed
 for the next reader, labelled with the device; they are not metrics. The
@@ -55,6 +59,8 @@ FULL = {
         # Qwen3-Next's gated delta rule at its cell's shapes: one sequence
         # of 4096, 16 key heads on 32 value heads of 128
         gated_delta=dict(b=1, t=4096, hk=16, hv=32, d=128),
+        # its convolution: q, k and v of a delta layer side by side
+        causal_conv=dict(b=1, t=4096, c=8192, width=4),
         xent=dict(n=8192, v=30000),                 # its [B*T, vocab] loss
         # its d_model rows: the benchmark cells' [16384, 512], whole tiles
         # at the table's budget, and an N that leaves a padded tail
@@ -80,6 +86,7 @@ TINY = {
         attn_window=dict(b=2, t=40, h=4, hkv=2, d=16, window=12),
         moe=dict(n=64, d=16, f=8),
         gated_delta=dict(b=2, t=40, hk=2, hv=4, d=16),
+        causal_conv=dict(b=2, t=64, c=256, width=4),
         xent=dict(n=32, v=64),
         ln=(dict(b=2, t=16, d=32), dict(b=3, t=7, d=32)),
         lstm=(dict(b=5, t=6, d=8, reverse=True),),
@@ -272,19 +279,20 @@ def _pallas_calls(program, feed, fetch_names, scope, device):
             [feed_arrays[n] for n in feed_names],
             [scope.get(n) for n in rw], [scope.get(n) for n in ro],
             np.uint32(0))
-    found = []
+    return _interpret_flags(closed.jaxpr)
 
-    def walk(jaxpr):
-        for eqn in jaxpr.eqns:
-            if eqn.primitive.name == "pallas_call":
-                found.append(bool(eqn.params["interpret"]))
-            for val in eqn.params.values():
-                for sub in (val if isinstance(val, (list, tuple))
-                            else [val]):
-                    sub = getattr(sub, "jaxpr", sub)
-                    if hasattr(sub, "eqns"):
-                        walk(sub)
-    walk(closed.jaxpr)
+
+def _interpret_flags(jaxpr):
+    """The `interpret` flag of every pallas_call under `jaxpr`."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            found.append(bool(eqn.params["interpret"]))
+        for val in eqn.params.values():
+            for sub in (val if isinstance(val, (list, tuple)) else [val]):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    found.extend(_interpret_flags(sub))
     return found
 
 
@@ -846,11 +854,99 @@ def phase_e(smoke):
                              "disagree: %.4fs vs %.4fs" % (tb, tf))
 
 
+# --------------------------------------------------------------- phase F --
+def phase_f(smoke):
+    """The causal_conv1d rule on this device, with its kernels on and off
+    (PADDLE_TPU_PALLAS, as phase C switches a family): both against the
+    same arithmetic on float32 copies of x and dy, where the kernels may
+    be no further off than the jax.numpy passes are (dw's float32 sums run
+    in another order: 1e-6 of slack). The times are printed for the next
+    reader (no metric)."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.core import registry
+
+    c = smoke.cfg["kernels"]["causal_conv"]
+    rng = np.random.RandomState(17)
+    shape = (c["b"], c["t"], c["c"])
+    x, dy = (jnp.asarray(rng.randn(*shape), jnp.bfloat16) for _ in range(2))
+    w = jnp.asarray(rng.randn(c["c"], c["width"]) * 0.5, jnp.float32)
+    rule = registry.get("causal_conv1d").lower
+
+    def traced_now():
+        # new functions a path: jax keeps a function's trace, and the rule
+        # reads PADDLE_TPU_PALLAS while it is traced
+        def forward(x, w):
+            return rule(None, {"X": [x], "Filter": [w]},
+                        {"activation": "silu"})["Out"][0]
+
+        def both(x, w, dy):
+            y, vjp = jax.vjp(forward, x, w)
+            return (y,) + vjp(dy)
+        return forward, both
+
+    def timed(fn, *args):
+        jax.block_until_ready(fn(*args))
+        times = []
+        for _ in range(5):
+            t0 = time.perf_counter()
+            for _ in range(10):
+                out = fn(*args)
+            jax.block_until_ready(out)
+            times.append((time.perf_counter() - t0) / 10)
+        return 1e3 * statistics.median(times)
+
+    names = ("y", "dx", "dw")
+    saved = os.environ.get("PADDLE_TPU_PALLAS")
+    found = {}
+    try:
+        with jax.default_device(smoke.device):
+            os.environ["PADDLE_TPU_PALLAS"] = "0"
+            want = jax.jit(traced_now()[1])(x.astype(jnp.float32), w,
+                                            dy.astype(jnp.float32))
+            for path, flag in (("kernel", "conv"), ("xla", "0")):
+                os.environ["PADDLE_TPU_PALLAS"] = flag
+                forward, both = traced_now()
+                calls = _interpret_flags(
+                    jax.make_jaxpr(both)(x, w, dy).jaxpr)
+                interpreted = smoke.device.platform != "tpu"
+                if calls != ([interpreted] * 2 if path == "kernel" else []):
+                    raise AssertionError(
+                        "causal_conv1d, PADDLE_TPU_PALLAS=%s on %s: "
+                        "pallas_call interpret flags %r"
+                        % (flag, smoke.device.platform, calls))
+                run = jax.jit(both)
+                errs = _normalized_errors(names, run(x, w, dy), want)
+                found[path] = errs
+                smoke.say(
+                    "causal_conv1d %s path, x %s bf16, %d taps: forward "
+                    "%.3f ms, forward + backward %.3f ms (median of 5 x "
+                    "10 calls); off the float32 recomputation by %s"
+                    % (path, list(shape), c["width"],
+                       timed(jax.jit(forward), x, w), timed(run, x, w, dy),
+                       ", ".join("%s %.2e" % (n, errs[n]) for n in names)))
+    finally:
+        if saved is None:
+            os.environ.pop("PADDLE_TPU_PALLAS", None)
+        else:
+            os.environ["PADDLE_TPU_PALLAS"] = saved
+    # off a TPU the interpreter's approximate reciprocal is bf16's, and the
+    # kernels' Newton step leaves the sigmoid 1.5e-5 off, not 1e-7
+    slack = 1e-6 if smoke.device.platform == "tpu" else 1e-4
+    worse = {n: (found["kernel"][n], found["xla"][n]) for n in names
+             if found["kernel"][n] > max(1.05 * found["xla"][n], slack)}
+    if worse:
+        raise AssertionError(
+            "the kernels are further from the float32 recomputation than "
+            "the jax.numpy passes (kernel, xla): %r" % (worse,))
+
+
 PHASES = (("A", "ResNet-50 training", phase_a),
           ("B", "transformer training", phase_b),
           ("C", "Pallas kernel families", phase_c),
           ("D", "four-chip data parallel", phase_d),
-          ("E", "timing barrier", phase_e))
+          ("E", "timing barrier", phase_e),
+          ("F", "causal_conv1d kernels", phase_f))
 
 
 def main(argv=None):
@@ -858,7 +954,7 @@ def main(argv=None):
     ap.add_argument("--tiny", action="store_true",
                     help="CPU rehearsal at toy sizes (needs "
                          "JAX_PLATFORMS=cpu)")
-    ap.add_argument("--phases", default="ABCDE",
+    ap.add_argument("--phases", default="ABCDEF",
                     help="letters of the phases to run (default all)")
     args = ap.parse_args(argv)
 

@@ -592,6 +592,8 @@ def _lower_op_inner(ctx, op, env):
         _count_attention_layer(ctx, op.attrs, ins)
     elif op.type == "gated_delta_rule":
         _count_linear_attention_layer(ins)
+    elif op.type == "causal_conv1d":
+        _count_causal_conv_layer(op.attrs, ins)
     if op.uid in ctx.linearized:
         # a grad op of this block differentiates this op: run the rule once,
         # under jax.vjp, and keep what the backward needs
@@ -767,6 +769,20 @@ def _count_linear_attention_layer(ins):
     ).inc(kind="gated_delta", k_heads=str(k.shape[2]),
           v_heads=str(v.shape[2]), d_k=str(k.shape[3]), d_v=str(v.shape[3]),
           chunk=str(DEFAULT_TILES["gdr"]["chunk"]), path=gated_delta_path())
+
+
+def _count_causal_conv_layer(attrs, ins):
+    from ..observability.registry import REGISTRY
+    from ..ops.linear_attention_ops import causal_conv_path
+    x, w = ins["X"][0], ins["Filter"][0]
+    REGISTRY.counter(
+        "ptpu_causal_conv_layers_total",
+        "causal_conv1d ops lowered (forward ops, not a grad op's replay), by "
+        "the path taken (the two Pallas kernels, or XLA's shifted passes), "
+        "the filter's taps, the channels and the activation"
+    ).inc(path=causal_conv_path(x, w), width=str(w.shape[1]),
+          channels=str(w.shape[0]),
+          activation=str(attrs.get("activation", "none")))
 
 
 def _lower_grad_of(ctx, op, env):

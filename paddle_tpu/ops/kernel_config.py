@@ -20,7 +20,7 @@ Accepted forms of PADDLE_TPU_PALLAS:
     - "attn,xent"       : allowlist — exactly the named ops on, the
                           rest off.  Unknown names raise LOUDLY (a typo
                           must not silently run the other path).
-Op names: attn, xent, ln, lstm, seq, gdr (KERNEL_OPS).  For 'attn' the flag
+Op names: attn, xent, ln, lstm, seq, gdr, conv (KERNEL_OPS).  For 'attn' the flag
 is an opt-OUT only: fused_attention's positive dispatch is always the
 flash_at() rule, so enabling 'attn' does not force flash below the
 crossover (pin FLAGS_flash_min_seq=0 for that).
@@ -58,7 +58,21 @@ __all__ = [
 # same at D=2048 and D=8192; in bf16 0.068 at 512 KiB, 0.055 at 1 MiB,
 # 0.054 at 2; Mosaic refuses 4 MiB of float32 tile at every width at
 # its default VMEM limit.  1 MiB is the smallest budget past the knee
-# at every width and dtype measured.  The 8-row tiles of
+# at every width and dtype measured.  "conv" is the same kind of
+# budget, for causal_conv_kernels.blocks: the float32 working copy of
+# one [block_t, block_c] tile of x, block_c at most 512 channels.
+# Swept on the v5e at [1, 4096, 8192] bf16, the Qwen3-Next cell's
+# shape, forward / backward kernel alone on the host's clock (PERF.md
+# section 6, PR 34; the HBM floor is 0.164 / 0.246 ms): at 512
+# channels 0.509 / 0.717 ms at 128 KiB, 0.382 / 0.565 at 256, 0.314 /
+# 0.485 at 512 KiB, 0.270 / 0.446 at 1 MiB, 0.243 / 0.440 at 2 MiB; at
+# 1 MiB 0.293 / 0.431 with 256 channels, 0.276 / 0.564 with 1024, 0.335
+# / 0.765 with 2048 (the backward pass's registers spill).  In float32
+# 0.422 / 0.675 at 1 MiB, and at 2 MiB Mosaic refuses the backward
+# kernel's two scratches and six buffers at its default VMEM limit, so
+# 1 MiB it is.  At [2, 4096, 2048] every tile from 256 KiB up reads 0.20
+# / 0.39 and XLA's own forward 0.21: the host's dispatch more than the
+# kernel.  The 8-row tiles of
 # "xent" and "seq" have not been swept on the chip (ROADMAP A3);
 # softmax_xent at 8 rows already runs near the HBM rate.
 DEFAULT_TILES = {
@@ -68,6 +82,7 @@ DEFAULT_TILES = {
     "lstm": {"block_b": 0},
     "seq": {"block_n": 8},
     "gdr": {"chunk": 64, "block_h": 8},
+    "conv": {"tile_bytes": 1 << 20},
 }
 KERNEL_OPS = frozenset(DEFAULT_TILES)
 # Dense attention below this query length, flash at and above it.  The

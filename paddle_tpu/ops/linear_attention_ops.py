@@ -1,6 +1,6 @@
 """Lowerings of the linear-attention mixer's ops: the gated delta rule
 (ops/gated_delta_kernels.py) and the short causal depthwise convolution
-over time that feeds it. No reference-era op computes either: sequence_conv
+over time that feeds it (ops/causal_conv_kernels.py). No reference-era op computes either: sequence_conv
 is LoD-based and dense over channels."""
 import jax
 import jax.numpy as jnp
@@ -30,16 +30,32 @@ def _gated_delta_rule(ctx, ins, attrs):
     return {"Out": [out]}
 
 
-@register("causal_conv1d")
+def causal_conv_path(x, w):
+    """"kernel" where causal_conv_kernels' two streaming passes run for X
+    [B, T, C] under Filter [C, K]: kernel_config.pallas_on("conv") (a TPU,
+    or PADDLE_TPU_PALLAS) and blocks that divide the shape (T a multiple of
+    16, C of 128); else "xla", the jax.numpy passes below. The one place
+    that decides; the layer counter reads it too."""
+    from .causal_conv_kernels import applies
+    fits = x.ndim == 3 and applies(x.shape[1], x.shape[2], w.shape[1])
+    return "kernel" if fits and pallas_on("conv") else "xla"
+
+
+@register("causal_conv1d", calls_pallas=True)
 def _causal_conv1d(ctx, ins, attrs):
     """y_t[c] = sum_m w[c, m] x_(t-K+1+m)[c] over X [B, T, C] with Filter
     [C, K], zeros before the sequence, then `activation` ("silu" or none):
-    K shifted multiply-adds in float32, back in x's dtype. Under
-    jax.checkpoint: the backward pass keeps x as it came (bf16 under AMP)
-    and converts and pads it again, where XLA would hold the float32 padded
-    copy from the forward pass (256 MiB a layer at [2, 4096, 8192]; AOT
-    compile, PR 33)."""
+    K shifted multiply-adds in float32, back in x's dtype. As one Pallas
+    pass forward and one backward where `causal_conv_path` says so. Else
+    K passes over a padded float32 copy, under jax.checkpoint: the backward
+    pass keeps x as it came (bf16 under AMP) and converts and pads it
+    again, where XLA would hold the float32 padded copy from the forward
+    pass (256 MiB a layer at [2, 4096, 8192]; AOT compile, PR 33)."""
     silu = attrs.get("activation") == "silu"
+    x, w = single(ins, "X"), single(ins, "Filter")
+    if causal_conv_path(x, w) == "kernel":
+        from .causal_conv_kernels import causal_conv1d
+        return {"Out": [causal_conv1d(x, w, silu=silu)]}
 
     @jax.checkpoint
     def conv(x, w):
@@ -49,4 +65,4 @@ def _causal_conv1d(ctx, ins, attrs):
         y = sum(xp[:, m:m + t] * w[:, m] for m in range(width))
         return (jax.nn.silu(y) if silu else y).astype(x.dtype)
 
-    return {"Out": [conv(single(ins, "X"), single(ins, "Filter"))]}
+    return {"Out": [conv(x, w)]}

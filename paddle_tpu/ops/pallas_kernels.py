@@ -46,14 +46,14 @@ __all__ = ["flash_attention", "softmax_xent", "layer_norm",
 _NEG = -1e30
 
 # The name each pallas_call gives its Mosaic custom call: the HLO instruction
-# is `<name>.<n>`, which a device trace, the profiler's per-op table and the
-# benchmark's per-kernel metrics find it by. Every call below takes one, the
-# last two are gated_delta_kernels.py's; none prefixes another but by a word.
+# is `<name>.<n>`, which a device trace, the profiler's table and the
+# benchmark's per-kernel metrics find it by. The last four are other modules'.
 KERNEL_NAMES = (
     "ptpu_flash_fwd", "ptpu_flash_bwd_dkdv", "ptpu_flash_bwd_dq",
     "ptpu_softmax_xent_fwd", "ptpu_layer_norm_fwd", "ptpu_lstm_seq",
     "ptpu_lstmp_seq", "ptpu_masked_softmax", "ptpu_masked_pool",
-    "ptpu_gated_delta_fwd", "ptpu_gated_delta_bwd")
+    "ptpu_gated_delta_fwd", "ptpu_gated_delta_bwd",
+    "ptpu_causal_conv1d_fwd", "ptpu_causal_conv1d_bwd")
 
 
 def _interpret_default():

@@ -46,11 +46,11 @@ def _error(got, want):
     return float(np.abs(got - want).max() / np.abs(want).max())
 
 
-def _feed(seed=0):
+def _feed(seed=0, t=T):
     tok = np.random.RandomState(seed).randint(0, CFG["vocab_size"],
-                                              (B, T + 1))
+                                              (B, t + 1))
     return {"ids": tok[:, :-1],
-            "pos": np.broadcast_to(np.arange(T), (B, T)).copy(),
+            "pos": np.broadcast_to(np.arange(t), (B, t)).copy(),
             "labels": tok[:, 1:, None]}
 
 
@@ -81,7 +81,7 @@ def _counts():
                                 gate="false", conv="4", shared="8")}
 
 
-def _run_program(amp, pallas=None, monkeypatch=None):
+def _run_program(amp, pallas=None, monkeypatch=None, cfg=CFG, t=T):
     if monkeypatch is not None:
         monkeypatch.setenv("PADDLE_TPU_PALLAS", pallas)
     main, startup = fluid.Program(), fluid.Program()
@@ -90,14 +90,14 @@ def _run_program(amp, pallas=None, monkeypatch=None):
     with fluid.unique_name.guard(), fluid.program_guard(main, startup):
         if amp:
             main.enable_mixed_precision()
-        loss, logits, load = causal_lm.build_train(CFG, T)
+        loss, logits, load = causal_lm.build_train(cfg, t)
     params = main.global_block().all_parameters()
     scope = fluid.Scope()
     with fluid.scope_guard(scope):
         exe = fluid.Executor(fluid.CPUPlace())
         exe.run(startup)
         weights = [np.asarray(scope.get(p.name)) for p in params]
-        out = exe.run(main, feed=_feed(), fetch_list=[loss, logits, load]
+        out = exe.run(main, feed=_feed(t=t), fetch_list=[loss, logits, load]
                       + [p.name + "@GRAD" for p in params])
     after = _counts()
     got = {"loss": out[0], "logits": out[1], "expert_load": out[2],
@@ -222,6 +222,41 @@ def test_the_kernel_path_agrees_with_the_reference(monkeypatch, want):
     errors = {p.name: _error(got["grads"][p.name], want["grads"][p.name])
               for p in params}
     assert max(errors.values()) < TOLERANCE, errors
+
+
+def test_the_convolutions_kernels_agree_with_its_xla_path(monkeypatch):
+    """The same Program at heads of 16 and 48 tokens, where the three
+    convolutions run over [2, 48, 128] and the kernels' blocks divide that:
+    loss, logits and every gradient with the two Pallas passes
+    (interpreted) equal those with the jax.numpy passes, and the counter
+    says which ran. The interpreter's approximate reciprocal is bf16's (4e-3
+    off, 1.5e-5 after the kernels' Newton step; the chip's leaves 1e-7), and
+    four layers carry that into gradients 5e-3 apart: the logistic stands in
+    for it here, and test_causal_conv_kernels.py holds the step itself."""
+    from paddle_tpu.ops import causal_conv_kernels
+    monkeypatch.setattr(causal_conv_kernels, "_sigmoid", jax.nn.sigmoid)
+    cfg = dict(CFG, linear_key_head_dim=16, linear_value_head_dim=16)
+    labels = dict(width="4", channels="128", activation="silu")
+
+    def run(pallas):
+        before = {p: _counter("ptpu_causal_conv_layers_total", path=p,
+                              **labels) for p in ("kernel", "xla")}
+        params, _, got = _run_program(False, pallas=pallas,
+                                      monkeypatch=monkeypatch, cfg=cfg, t=48)
+        return params, got, {
+            p: _counter("ptpu_causal_conv_layers_total", path=p, **labels)
+            - before[p] for p in before}
+
+    params, got, counted = run("conv")
+    _, want, counted_xla = run("0")
+    assert counted == {"kernel": 3, "xla": 0}
+    assert counted_xla == {"kernel": 0, "xla": 3}
+    assert _error(got["loss"], want["loss"]) < 2e-6
+    assert _error(got["logits"], want["logits"]) < TOLERANCE
+    errors = {p.name: _error(got["grads"][p.name], want["grads"][p.name])
+              for p in params}
+    assert max(errors.values()) < TOLERANCE, errors
+    np.testing.assert_array_equal(got["expert_load"], want["expert_load"])
 
 
 def test_amp_program_agrees_with_the_reference(want):

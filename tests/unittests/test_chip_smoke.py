@@ -37,13 +37,18 @@ def test_tiny_rehearsal_passes_every_phase():
     assert lines[0].startswith("chip_smoke: jax=")
     # a CPU line can never be mistaken for a chip line
     assert all("platform=cpu" in line for line in lines[:-1])
-    for letter in "ABCE":
+    for letter in "ABCEF":
         assert any("phase %s " % letter in line and " passed in " in line
                    for line in lines), letter
     assert any("phase D " in line and "skipped: needs 4 devices" in line
                for line in lines)
     # phase C walked the kernels (interpreted here, Mosaic on the chip)
     assert sum("interpreted;" in line for line in lines) >= 7
+    # phase F ran the convolution on both of its paths
+    for path in ("kernel", "xla"):
+        assert any("causal_conv1d %s path" % path in line
+                   and "off the float32 recomputation by y " in line
+                   for line in lines), path
 
 
 _FAILING_RUN = """

@@ -169,9 +169,9 @@ def test_an_at_sign_would_cut_the_op_name_short():
 # --- a name a kernel -------------------------------------------------------
 
 def test_every_pallas_call_takes_its_name_from_kernel_names():
-    from paddle_tpu.ops import gated_delta_kernels
+    from paddle_tpu.ops import causal_conv_kernels, gated_delta_kernels
     names = []
-    for module in (pallas_kernels, gated_delta_kernels):
+    for module in (pallas_kernels, gated_delta_kernels, causal_conv_kernels):
         with open(module.__file__) as f:
             tree = ast.parse(f.read())
         for node in ast.walk(tree):
@@ -184,7 +184,7 @@ def test_every_pallas_call_takes_its_name_from_kernel_names():
                     % node.lineno
                 names.append(kw["name"].value)
     assert sorted(names) == sorted(pallas_kernels.KERNEL_NAMES)
-    assert len(set(names)) == len(names) == 11
+    assert len(set(names)) == len(names) == 13
     for a in names:         # a reader matching `<name>` or `<name>.<n>`
         for b in names:     # never counts one kernel under another
             assert a == b or not (b + ".").startswith(a + ".")
@@ -643,3 +643,53 @@ def test_gated_delta_kernels_at_the_cells_shapes_on_a_described_v5e(
         ("ptpu_gated_delta_bwd", "gated_delta_rule_grad"),
         ("ptpu_gated_delta_fwd", "gated_delta_rule"),
         ("ptpu_gated_delta_fwd", "gated_delta_rule_grad")]
+
+
+@pytest.mark.parametrize("batch,t,c,dtype", [
+    (1, 4096, 8192, jnp.bfloat16),      # the Qwen3-Next cell's three layers
+    (2, 4096, 2048, jnp.bfloat16), (1, 4096, 8192, jnp.float32),
+    (2, 48, 640, jnp.float32),          # one tile of 48 rows, blocks of 128
+], ids=lambda v: getattr(v, "__name__", None) or str(v))
+def test_causal_conv_kernels_on_a_described_v5e(one_chip, monkeypatch, batch,
+                                                t, c, dtype):
+    """fluid.layers.causal_conv1d and its grad op, lowered by
+    build_program_fn and compiled for a TPU: Mosaic takes both kernels at
+    the tile DEFAULT_TILES["conv"] gives (the rolls of a 48-row window, the
+    backward pass's two float32 scratches and three double-buffered tiles
+    inside the default scoped-VMEM limit), the forward kernel under the
+    forward op and the backward one under the grad op, each instruction
+    named from KERNEL_NAMES; and nothing converts, pads or transposes the
+    [B, T, C] operands on the way in or out."""
+    from paddle_tpu.ops import kernel_config
+    monkeypatch.delenv("PADDLE_TPU_PALLAS", raising=False)
+    monkeypatch.setattr(kernel_config, "dispatch_platform", lambda: "tpu")
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.unique_name.guard(), fluid.program_guard(main, startup):
+        x = fluid.layers.data(name="x", shape=[t, c], dtype="float32")
+        x.stop_gradient = False
+        out = fluid.layers.causal_conv1d(x, 4, act="silu")
+        ct = fluid.layers.data(name="ct", shape=[t, c], dtype="float32")
+        fluid.backward.append_backward(fluid.layers.reduce_sum(out * ct))
+        w, = main.global_block().all_parameters()
+    fetch = [out.name, "x@GRAD", w.name + "@GRAD"]
+    rw, ro, outs = lowering.analyze_state(main, ["x", "ct"], fetch)
+    fn = lowering.build_program_fn(main, ["x", "ct"], fetch, rw, ro, outs)
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    text = _compile_uncached(
+        lambda x, ct, w: fn([x, ct], [], [w], 0), sds((batch, t, c), dtype),
+        sds((batch, t, c), dtype), sds((c, 4), jnp.float32)).as_text()
+    calls = re.findall(
+        r'%?([\w.\-]+) = [^\n]*custom_call_target="tpu_custom_call"'
+        r'[^\n]*op_name="([^"]*)"', text)
+    under = sorted(
+        (name.rpartition(".")[0] if name.rpartition(".")[2].isdigit()
+         else name, lowering.parse_op_scope(op_name)[0])
+        for name, op_name in calls)
+    assert under == [("ptpu_causal_conv1d_bwd", "causal_conv1d_grad"),
+                     ("ptpu_causal_conv1d_fwd", "causal_conv1d")]
+    whole = r"\[%d,%d,%d\]" % (batch, t, c)
+    moved = re.findall(r"= \w+%s\S* (copy|transpose|pad|convert)\(" % whole,
+                       text[text.index("ENTRY"):])
+    assert moved == []
