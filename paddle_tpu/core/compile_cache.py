@@ -117,6 +117,96 @@ def enable_persistent_cache():
     return path
 
 
+# ------------------------------------------- what a first run waited for
+# jax.monitoring reports a duration when something compiles and never on
+# a warm call: the trace of a jitted function to a jaxpr (the program's
+# lowering rules run under it), the jaxpr's lowering to StableHLO, and
+# `backend_compile_duration`, which is the XLA compile OR the persistent
+# cache's read and load, whole; on a hit the cache's retrieval time is
+# reported too and lies inside it.
+_COMPILE_PHASES = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "compile_or_load",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "cache_read",
+}
+_watching = False
+
+
+def _on_duration(event, seconds, **_):
+    phase = _COMPILE_PHASES.get(event)
+    if phase is None:
+        return
+    from .dispatch import open_step
+    step = open_step()
+    if step is None:    # the caller's own jit, not an executor's dispatch
+        return
+    now = time.perf_counter()
+    step.compile_events.append((phase, now - seconds, now,
+                                step.innermost()))
+
+
+def watch_compile_phases():
+    """Register, once a process, the listener that collects the compile
+    phases of an executor's dispatch (`book_compile_phases`)."""
+    global _watching
+    if _watching:
+        return
+    _watching = True
+    import jax
+    jax.monitoring.register_event_duration_secs_listener(_on_duration)
+
+
+def outermost_phases(events):
+    """Of one step's `(phase, start, end, span)` events, those that count.
+    A jit inside the step's trace (a Pallas wrapper's, an op evaluated
+    eagerly on constants) reports phases of its own INSIDE the interval
+    of the phase that called it, before that one reports: an event that
+    lies within another's interval, whatever the two phases, is part of
+    that one's seconds already and is left out. `cache_read` is the
+    exception by design: it is kept wherever the `compile_or_load` around
+    it is, and read beside it, never added to it."""
+    main = sorted((e for e in events if e[0] != "cache_read"),
+                  key=lambda e: (e[1], -e[2]))
+    kept = []
+    for e in main:
+        if not kept or e[2] > kept[-1][2]:
+            kept.append(e)
+    reads = [e for e in events if e[0] == "cache_read" and not any(
+        k[0] != "compile_or_load" and k[1] <= e[1] and e[2] <= k[2]
+        for k in kept)]
+    return kept, reads
+
+
+def book_compile_phases(events):
+    """Book what one exec/step waited for: each phase as a child of the
+    exec/* span that was innermost when it was reported (`jax/trace`,
+    `jax/lower`, `jax/compile_or_load` with `cache_hit`), and into
+    `ptpu_compile_phase_seconds_total{phase}` with
+    `ptpu_compile_phase_events_total{phase}` beside it."""
+    from ..observability.registry import REGISTRY
+    seconds = REGISTRY.counter(
+        "ptpu_compile_phase_seconds_total",
+        "seconds an executor's dispatch waited for jax, by phase: trace "
+        "(the lowering rules under jax's trace), lower (jaxpr to "
+        "StableHLO), compile_or_load (XLA compile, or the persistent "
+        "cache's read and load), cache_read (inside compile_or_load)")
+    count = REGISTRY.counter(
+        "ptpu_compile_phase_events_total",
+        "phases counted into ptpu_compile_phase_seconds_total")
+    kept, reads = outermost_phases(events)
+    for phase, t0, t1, span in kept + reads:
+        seconds.inc(t1 - t0, phase=phase)
+        count.inc(phase=phase)
+        if phase == "cache_read":
+            continue
+        args = {}
+        if phase == "compile_or_load":
+            args["cache_hit"] = any(t0 <= r[1] and r[2] <= t1
+                                    for r in reads)
+        span.child_at("jax/" + phase, t0, t1, **args)
+
+
 # ------------------------------------------------------ AOT artifact cache
 def enable_aot_cache():
     """Default the AOT artifact cache on, in an ``aot`` directory inside

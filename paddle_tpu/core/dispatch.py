@@ -462,6 +462,70 @@ def kick_next_prepass(executor, program, scope, steps, host, cancelled,
     return pf
 
 
+class StepPhases(object):
+    """One exec/step and the children that tile it, ONE copy for both
+    executors. A call passes through them in this order, each opened at
+    the instant the one before ends (`Span.then`), so that their sum is
+    the step:
+
+      exec/prepare        entry to the host-io consume: feed conversion,
+                          validation, the barrier and fault hooks
+      exec/host_io        the staged block's claim, else the inline
+                          prepass (`consume_host_io`)
+      exec/lookup         cache key and in-process cache; on a miss
+                          analyze_state, build_program_fn /
+                          lower_multi_step, the AOT cache's load or its
+                          eager compile
+      exec/dispatch       state read, feed placement and, inside it,
+        exec/jit_call     the call of the jitted function alone
+                          (`with phases:`)
+      exec/watchdog_sync  watchdog mode only
+      exec/writeback      scope write-back, prefetch kick, post-dispatch
+                          checks, fetch handles
+      exec/d2h            return_numpy only
+
+    `compile_events` collects what jax.monitoring reported while the
+    step was open on this thread (core/compile_cache.py books it when
+    the step ends); `innermost()` is the span such an event belongs
+    under."""
+
+    __slots__ = ("step", "cur", "inner", "compile_events")
+
+    def __init__(self, step):
+        self.step = step
+        self.cur = step.child("exec/prepare")
+        self.inner = None
+        self.compile_events = []
+
+    def enter(self, name):
+        self.cur = self.cur.then(name)
+        return self.cur
+
+    def __enter__(self):
+        """`with phases:` around the call of the jitted function alone is
+        exec/jit_call. A `with` and no method that makes the call: a first
+        call traces under it, and jax records the Python stack with every
+        equation, so a frame here would be paid for by every rule."""
+        self.inner = self.cur.child("exec/jit_call")
+
+    def __exit__(self, *exc):
+        self.inner.end()
+        self.inner = None
+        return False
+
+    def innermost(self):
+        return self.inner if self.inner is not None else self.cur
+
+
+_open_steps = threading.local()
+
+
+def open_step():
+    """The StepPhases of the exec/step open on the calling thread, or
+    None outside an executor's run."""
+    return getattr(_open_steps, "phases", None)
+
+
 def run_step_traced(label, cancelled, body_fn, **span_args):
     """The executors' shared step-trace wrapper (ONE copy for
     Executor._run_impl and ParallelExecutor._run_impl — its error
@@ -470,27 +534,37 @@ def run_step_traced(label, cancelled, body_fn, **span_args):
     inheriting the thread's ambient trace when a layer above (the
     serving batcher's per-batch scope_trace) already owns one, so a
     serving dispatch's exec/step span correlates with its batch — call
-    `body_fn(tspan)`, and close the trace honestly: a raise ends every
+    `body_fn(phases)` with the step's StepPhases, and close the trace
+    honestly: a raise ends every
     open span of the trace with the error name; a watchdog-cancelled
     body that unwedged after the caller's DispatchTimeoutError must not
     render as a clean step. Runs on the dispatching thread (the
     monitored worker in watchdog mode), so a wedge leaves the step's
     spans OPEN for the diagnostic bundle."""
+    from . import compile_cache
+    compile_cache.watch_compile_phases()
     tr = _trace.ambient()
     tspan = _trace.span("exec/step", cat="train",
                         trace=tr if tr is not None else _trace.new_trace(),
                         executor=label, **span_args)
+    phases = StepPhases(tspan)
+    outer, _open_steps.phases = open_step(), phases
     try:
-        out = body_fn(tspan)
+        out = body_fn(phases)
     except BaseException as e:
         err = type(e).__name__
         _trace.end_open(tspan.trace, error=err)
         tspan.end(error=err)
         raise
+    finally:
+        _open_steps.phases = outer
+        if phases.compile_events:
+            compile_cache.book_compile_phases(phases.compile_events)
     if cancelled is not None and cancelled.is_set():
         _trace.end_open(tspan.trace, error="DispatchCancelled")
         tspan.end(error="DispatchCancelled")
         return out
+    phases.cur.end()
     tspan.end()
     return out
 
@@ -528,12 +602,13 @@ def run_dispatch_hooks(program, steps, feed_arrays, prefetcher=None,
 
 
 def consume_host_io(executor, program, scope, steps, host, cancelled,
-                    feed_arrays, stacked_names, tspan, **inline_kw):
+                    feed_arrays, stacked_names, phases, **inline_kw):
     """The host-io consume choreography, shared by both executors: claim
     the prefetcher's staged block when its identity matches (refunding a
     mismatched one BEFORE the inline prepass pops the stream, or the
     staged records would replay out of order), else run the inline
-    prepass; the exec/host_io span closes honestly on every path.
+    prepass; the exec/host_io span closes honestly on every path, and
+    exec/lookup opens where it ends.
     Returns the staged block, None (inline prepass ran), or the
     CANCELLED sentinel (the caller's watchdog fired — unwind without
     touching more state). `inline_kw` carries the per-executor prepass
@@ -542,7 +617,7 @@ def consume_host_io(executor, program, scope, steps, host, cancelled,
     from .executor import run_host_io_prepass, _DispatchCancelled
     pf = executor._prefetcher
     staged = None
-    iosp = tspan.child("exec/host_io")
+    iosp = phases.enter("exec/host_io")
     try:
         if pf is not None and pf.has_work():
             # consult the prefetcher even on a prefetch=False call: a
@@ -568,7 +643,8 @@ def consume_host_io(executor, program, scope, steps, host, cancelled,
     except BaseException as e:  # EOF / reader faults: close the span,
         iosp.end(error=type(e).__name__)  # the fault rides up
         raise
-    iosp.end(staged=staged is not None)
+    iosp.set(staged=staged is not None)
+    phases.enter("exec/lookup")
     return staged
 
 

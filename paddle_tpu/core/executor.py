@@ -701,14 +701,17 @@ class Executor(object):
         from .dispatch import run_step_traced
         return run_step_traced(
             "exe", cancelled,
-            lambda tspan: self._run_traced(
+            lambda phases: self._run_traced(
                 program, feed, fetch_list, scope, return_numpy,
                 use_program_cache, steps, fetch_reduce, validate,
-                cancelled, info, sync, prefetch, tspan))
+                cancelled, info, sync, prefetch, phases))
 
     def _run_traced(self, program, feed, fetch_list, scope, return_numpy,
                     use_program_cache, steps, fetch_reduce, validate,
-                    cancelled, info, sync, prefetch, tspan):
+                    cancelled, info, sync, prefetch, phases):
+        # `phases` (core/dispatch.StepPhases) opened exec/prepare; the
+        # shared helpers and the enter() calls below move the step
+        # through the rest of its children
         if program is None:
             program = default_main_program()
         feed = feed or {}
@@ -717,8 +720,8 @@ class Executor(object):
         steps = int(steps)
         if steps < 1:
             raise ValueError("steps must be >= 1, got %r" % (steps,))
-        tspan.set(program=str(program._uid),
-                  version=int(program._version), steps=steps)
+        phases.step.set(program=str(program._uid),
+                        version=int(program._version), steps=steps)
         if fetch_reduce not in lowering.FETCH_REDUCE_POLICIES:
             raise ValueError("fetch_reduce must be one of %r, got %r"
                              % (lowering.FETCH_REDUCE_POLICIES, fetch_reduce))
@@ -750,7 +753,7 @@ class Executor(object):
         stacked_names = set()
         staged = _dispatch.consume_host_io(
             self, program, scope, steps, False, cancelled, feed_arrays,
-            stacked_names, tspan, place=self.place)
+            stacked_names, phases, place=self.place)
         if staged is _dispatch.CANCELLED:
             return None  # deadline raised on the caller's thread
         if cancelled is not None and cancelled.is_set():
@@ -872,7 +875,7 @@ class Executor(object):
         # host-side enqueue (+ trace/compile when compiling) — a hang
         # inside leaves it OPEN, which is exactly what the bundle's
         # recorder dump needs to show
-        dsp = tspan.child("exec/dispatch")
+        dsp = phases.enter("exec/dispatch")
         t0 = time.perf_counter() if profiling else 0.0
 
         def _call(fn_obj):
@@ -893,7 +896,8 @@ class Executor(object):
                         if new is not old:
                             scope.set(n, new)  # never donated: keep it
                     ro = placed
-                return fn_obj(feeds, rw, ro, seed)
+                with phases:    # exec/jit_call
+                    return fn_obj(feeds, rw, ro, seed)
 
         def _find_aot_entry():
             aot_dir = compile_cache.active_aot_cache_dir()
@@ -932,7 +936,7 @@ class Executor(object):
         # sentinel stat tap: peel float statistics (grad norm) off the
         # error dict before any error sync; values stay device-resident
         self.last_stats = pop_guard_stats(errors)
-        dsp.end(compiled=compiled, aot_hit=aot_hit)
+        dsp.set(compiled=compiled, aot_hit=aot_hit)
         if cancelled is not None and cancelled.is_set():
             # the caller already raised DispatchTimeoutError and may be
             # mid-rollback: a late scope write here would race the
@@ -947,11 +951,11 @@ class Executor(object):
             # old donated-and-deleted buffers raise instead, which
             # write_bundle records per-var as state_unavailable)
             _prof.note_sync("executor/watchdog_sync")
-            wsp = tspan.child("exec/watchdog_sync")
+            phases.enter("exec/watchdog_sync")
             jax.block_until_ready((fetches, new_state))
-            wsp.end()
             if cancelled is not None and cancelled.is_set():
                 return None
+        phases.enter("exec/writeback")
         # write state back BEFORE anything that can raise (including the
         # profiler's block_until_ready): state_rw inputs were donated to the
         # jit, so on an exception path the scope must already hold the
@@ -988,8 +992,8 @@ class Executor(object):
             prefetcher=pf, cancelled=cancelled, sync_fn=_sync_extra)
         if return_numpy:
             _prof.note_sync("executor/return_numpy")
-            with tspan.child("exec/d2h"):
-                return [np.asarray(f) for f in fetches]
+            phases.enter("exec/d2h")
+            return [np.asarray(f) for f in fetches]
         return [FetchHandle(f) for f in fetches]
 
 

@@ -18,6 +18,8 @@ block lowers under jax.vjp once and keeps its vjp_fn for the grad op
 (_linearizations): every Pallas forward kernel runs once a step.
 """
 import re
+import threading
+import time
 
 import numpy as np
 
@@ -524,14 +526,19 @@ _SCOPE_ESCAPES = str.maketrans({"@": "~", "/": "_", "(": "_", ")": "_"})
 _SCOPE_RE = re.compile(r"(?:^|[/(])" + SCOPE_MARK + r"([^/()]+)/([^/()]+)")
 
 
+def scope_type(op):
+    """The type `op_scope` names an op by: a `grad_of` op is
+    `<fwd_type>_grad`."""
+    if op.type == "grad_of":
+        return op.attrs["fwd_type"] + "_grad"
+    return op.type
+
+
 def op_scope(op):
     """The named scope of one fluid op: "op:<type>/<instance>"."""
-    op_type = op.type
-    if op_type == "grad_of":
-        op_type = op.attrs["fwd_type"] + "_grad"
     instance = next((n for names in op.outputs.values() for n in names if n),
                     "-")
-    return "%s%s/%s" % (SCOPE_MARK, op_type,
+    return "%s%s/%s" % (SCOPE_MARK, scope_type(op),
                         instance.translate(_SCOPE_ESCAPES))
 
 
@@ -556,7 +563,23 @@ def parse_op_scope(op_name):
     return op_type, instance.replace("~", "@")
 
 
+# seconds of lower_op calls nested in the one running on this thread (a
+# `while` or `conditional` op lowers its sub-block's ops through lower_op)
+_nested_lowering = threading.local()
+
+
 def lower_op(ctx, op, env):
+    """Lower one op: the ONE site every rule passes, so also where the
+    trace phase is split by fluid op type. Runs at trace time only; an
+    op's seconds are its own Python time, less its sub-block's ops. The
+    clock sits in this function and in no wrapper around it: jax records
+    the Python stack with every equation it traces, so each frame between
+    the jitted function and the rules is paid for by every rule (two more
+    read 10 % more `jaxpr_trace_s` in the ResNet cell and 30 % more in the
+    Qwen3-Next cell, whose kernels trace their bodies; my chip run, PR 35)."""
+    t0 = time.perf_counter()
+    outer = getattr(_nested_lowering, "seconds", 0.0)
+    _nested_lowering.seconds = 0.0
     try:
         with jax.named_scope(op_scope(op)):
             _lower_op_inner(ctx, op, env)
@@ -574,6 +597,16 @@ def lower_op(ctx, op, env):
     except Exception as e:
         _annotate_op_error(e, op)
         raise
+    finally:
+        total = time.perf_counter() - t0
+        own = max(0.0, total - _nested_lowering.seconds)
+        _nested_lowering.seconds = outer + total
+        from ..observability.registry import REGISTRY
+        REGISTRY.counter(
+            "ptpu_lowering_seconds_total",
+            "host seconds in lowering rules under jax's trace, by fluid op "
+            "type as op_scope names it (a grad op is <fwd>_grad), less the "
+            "ops of an op's sub-block").inc(own, op=scope_type(op))
 
 
 def _lower_op_inner(ctx, op, env):

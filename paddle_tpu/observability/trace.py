@@ -25,10 +25,21 @@ Design constraints (all load-bearing, all tested):
     at span start/end. Nothing here ever touches a device value, so the
     `sync_stats()["on_dispatch_path"] == 0` discipline holds with the
     recorder on (regression-tested).
-  * BOUNDED. The ring holds `capacity` completed events (default 4096,
-    `PTPU_TRACE_RING` overrides); older events fall off, `dropped`
-    counts them. The open-span table is capped too — a leaked span can
-    never grow memory without bound.
+  * BOUNDED. The ring holds `capacity` completed events (4096;
+    `configure(capacity=)` swaps in another ring); older events fall
+    off, `dropped` counts them. A training step records eight events
+    (exec/step and its children, below), so 4096 hold a 10 s window of
+    92 ms steps four times over. The open-span table is capped too — a
+    leaked span can never grow memory without bound.
+  * ONE CLOCK WITH THE DEVICE TRACE. A span that begins and ends on one
+    thread is also a `jax.profiler.TraceAnnotation` named
+    `ptpu/<span name>`: with no profiler session that is one atomic
+    load, and under one the span lands on the `/host:` plane of the same
+    `.xplane.pb` as the device's operations, nested as the code nests
+    (`python -m paddle_tpu.profiler <trace>` books each idle gap of the
+    device to the innermost of them). `dump()` gives `epoch_perf`, the
+    `time.perf_counter()` reading of `ts` 0, for a reader that has
+    perf_counter readings of its own.
 
 Span identity: every span carries a process-local `trace` id (one per
 request / per training step — the correlation key across threads: the
@@ -46,22 +57,19 @@ import collections
 import contextlib
 import itertools
 import json
-import os
 import threading
 import time
 
+from jax.profiler import TraceAnnotation as _Annotation
+
 __all__ = ["FlightRecorder", "Span", "recorder", "configure",
-           "set_enabled", "enabled", "new_trace", "span", "instant",
+           "set_enabled", "new_trace", "span", "instant",
            "ambient", "scope_trace", "end_open",
            "dump", "clear", "export_chrome_trace", "render_timeline"]
 
 
-def _default_capacity():
-    try:
-        return max(64, int(os.environ.get("PTPU_TRACE_RING", "4096")))
-    except ValueError:
-        return 4096
-
+_CAPACITY = 4096
+ANNOTATION_PREFIX = "ptpu/"
 
 # id sources: itertools.count.__next__ is atomic under the GIL, so trace
 # and span ids need no lock even from concurrent submit threads
@@ -75,17 +83,15 @@ _span_ids = itertools.count(1)
 # OLDEST open span is often the wedged one a postmortem needs — so the
 # cap sits comfortably ABOVE the open-span count of a fully backed-up
 # default serving config (queue_capacity=256 requests x 2 spans each,
-# plus formed/window/dispatch batch spans): 4096, PTPU_TRACE_OPEN_CAP
-# overrides for unusually large queue configurations.
-def _open_cap():
-    try:
-        return max(64, int(os.environ.get("PTPU_TRACE_OPEN_CAP",
-                                          "4096")))
-    except ValueError:
-        return 4096
+# plus formed/window/dispatch batch spans).
+_OPEN_CAP = 4096
 
-
-_OPEN_CAP = _open_cap()
+# annotations of spans that were ended on another thread than began them
+# (the serving window's: opened at submit, closed by the completion
+# thread). A TraceMe records itself on whatever thread stops or drops
+# it, so these are neither stopped nor dropped while a profiler session
+# is on: they wait here and go, unrecorded, once it is over.
+_orphans = []
 
 
 class _NoopSpan(object):
@@ -103,7 +109,10 @@ class _NoopSpan(object):
     def child(self, name, cat=None, **args):
         return self
 
-    def event(self, name, **args):
+    def child_at(self, name, t0, t1, **args):
+        return self
+
+    def then(self, name, **args):
         return self
 
     def end(self, **args):
@@ -125,9 +134,9 @@ class Span(object):
     path may both try to close the same span, only the first records."""
 
     __slots__ = ("name", "cat", "trace", "sid", "parent", "tid", "args",
-                 "_t0", "_rec", "_ended")
+                 "_t0", "_rec", "_ended", "_ident", "_ann")
 
-    def __init__(self, rec, name, cat, trace, parent, args):
+    def __init__(self, rec, name, cat, trace, parent, args, t0=None):
         self.name = name
         self.cat = cat
         self.trace = trace
@@ -135,10 +144,19 @@ class Span(object):
         self.parent = parent
         self.tid = threading.current_thread().name
         self.args = args or None
-        self._t0 = time.perf_counter()
+        self._t0 = time.perf_counter() if t0 is None else t0
         self._rec = rec
         self._ended = False
         rec._open_add(self)
+        # the profiler's clock (module doc): the ONE site where a span
+        # becomes a TraceMe
+        if _Annotation.is_enabled():
+            self._ann = _Annotation(ANNOTATION_PREFIX + name)
+            self._ident = threading.get_ident()
+        else:
+            self._ann = None
+            if _orphans:
+                _orphans.clear()
 
     def set(self, **args):
         """Merge args into the span (recorded at end)."""
@@ -151,19 +169,40 @@ class Span(object):
         return Span(self._rec, name, cat or self.cat, self.trace,
                     self.sid, args)
 
-    def event(self, name, **args):
-        """An instant event inside this span's trace."""
-        self._rec.instant(name, cat=self.cat, trace=self.trace,
-                          parent=self.sid, **args)
+    def child_at(self, name, t0, t1, **args):
+        """A child that ran from `t0` to `t1` (time.perf_counter
+        readings), for work that reports its duration once it is over:
+        jax.monitoring's compile phases (core/compile_cache.py)."""
+        self._rec._record({"ph": "X", "name": name, "cat": self.cat,
+                           "ts": (t0 - self._rec._epoch) * 1e6,
+                           "dur": (t1 - t0) * 1e6,
+                           "tid": threading.current_thread().name,
+                           "trace": self.trace, "span": next(_span_ids),
+                           "parent": self.sid, "args": args or None})
         return self
 
-    def end(self, **args):
+    def then(self, name, **args):
+        """End this span and open its next sibling at the same instant:
+        phases that tile their parent (exec/step's) leave no gap between
+        them for a reader to account for."""
+        t = time.perf_counter()
+        self.end(_t1=t)
+        return Span(self._rec, name, self.cat, self.trace, self.parent,
+                    args, t0=t)
+
+    def end(self, _t1=None, **args):
         if self._ended:
             return self
         self._ended = True
+        ann, self._ann = self._ann, None
+        if ann is not None:
+            if threading.get_ident() == self._ident:
+                ann.__exit__(None, None, None)
+            else:
+                _orphans.append(ann)
         if args:
             self.args = dict(self.args or (), **args)
-        t1 = time.perf_counter()
+        t1 = time.perf_counter() if _t1 is None else _t1
         rec = self._rec
         rec._open_remove(self)
         rec._record({"ph": "X", "name": self.name, "cat": self.cat,
@@ -191,7 +230,7 @@ class FlightRecorder(object):
     """The always-on bounded event ring (see module doc)."""
 
     def __init__(self, capacity=None):
-        self.capacity = int(capacity or _default_capacity())
+        self.capacity = int(capacity or _CAPACITY)
         self._ring = collections.deque(maxlen=self.capacity)
         self._seq = itertools.count(1)  # per-event seq; the newest seq
         # IS the total-recorded count (dropped = seq_max - ring length)
@@ -221,13 +260,13 @@ class FlightRecorder(object):
             return _NOOP
         return Span(self, name, cat, trace, parent, args)
 
-    def instant(self, name, cat="event", trace=None, parent=None, **args):
+    def instant(self, name, cat="event", trace=None, **args):
         if not self.enabled:
             return
         self._record({"ph": "i", "name": name, "cat": cat,
                       "ts": (time.perf_counter() - self._epoch) * 1e6,
                       "tid": threading.current_thread().name,
-                      "trace": trace, "span": None, "parent": parent,
+                      "trace": trace, "span": None, "parent": None,
                       "args": args or None})
 
     # ------------------------------------------------------------ read --
@@ -254,6 +293,7 @@ class FlightRecorder(object):
         now = time.perf_counter()
         recorded = max((ev.get("seq", 0) for ev in events), default=0)
         out = {"epoch_wall": self._epoch_wall,
+               "epoch_perf": self._epoch,
                "capacity": self.capacity,
                "recorded": recorded,
                "dropped": max(0, recorded - len(events)),
@@ -302,10 +342,6 @@ def set_enabled(flag):
     """Overhead A/B switch (BENCH_OBS). The recorder defaults ON and is
     meant to stay on — spans are host timestamps into a bounded ring."""
     _recorder.enabled = bool(flag)
-
-
-def enabled():
-    return _recorder.enabled
 
 
 def new_trace():

@@ -190,14 +190,14 @@ class ParallelExecutor(object):
         from ..core.dispatch import run_step_traced
         return run_step_traced(
             "pexe", cancelled,
-            lambda tspan: self._run_traced(
+            lambda phases: self._run_traced(
                 fetch_list, feed, feed_dict, return_numpy, steps,
-                fetch_reduce, cancelled, info, sync, prefetch, tspan),
+                fetch_reduce, cancelled, info, sync, prefetch, phases),
             devices=int(self.mesh.devices.size))
 
     def _run_traced(self, fetch_list, feed, feed_dict, return_numpy,
                     steps, fetch_reduce, cancelled, info, sync, prefetch,
-                    tspan):
+                    phases):
         feed = feed if feed is not None else (feed_dict or {})
         program = self._program
         scope = self._scope
@@ -205,8 +205,8 @@ class ParallelExecutor(object):
         steps = int(steps)
         if steps < 1:
             raise ValueError("steps must be >= 1, got %r" % (steps,))
-        tspan.set(program=str(program._uid),
-                  version=int(program._version), steps=steps)
+        phases.step.set(program=str(program._uid),
+                        version=int(program._version), steps=steps)
         if fetch_reduce not in lowering.FETCH_REDUCE_POLICIES:
             raise ValueError("fetch_reduce must be one of %r, got %r"
                              % (lowering.FETCH_REDUCE_POLICIES, fetch_reduce))
@@ -273,7 +273,7 @@ class ParallelExecutor(object):
         stacked_names = set()
         staged = _dispatch.consume_host_io(
             self, program, scope, steps, True, cancelled, feed_arrays,
-            stacked_names, tspan, validate=_validate_record)
+            stacked_names, phases, validate=_validate_record)
         if staged is _dispatch.CANCELLED:
             return None  # watchdog deadline raised on the caller
         feed_names = sorted(feed_arrays)
@@ -468,6 +468,9 @@ class ParallelExecutor(object):
                 vals.append(v)
             return vals
 
+        # device-enqueue span (async; see Executor) — open = wedged
+        # here. The feeds' sharded placement is part of it.
+        dsp = phases.enter("exec/dispatch")
         feed_vals = [jax.device_put(
             feed_arrays[n], _feed_sharding(n, feed_arrays[n].ndim))
             for n in feed_names]
@@ -478,8 +481,6 @@ class ParallelExecutor(object):
         from .. import profiler as _prof
         profiling = _prof.is_active()
 
-        # device-enqueue span (async; see Executor) — open = wedged here
-        dsp = tspan.child("exec/dispatch")
         t0 = _time.perf_counter() if profiling else 0.0
 
         def _call(fn_obj):
@@ -487,8 +488,10 @@ class ParallelExecutor(object):
             # decisions (kernel_config.dispatch_platform); placement is
             # the in_shardings' alone
             with jax.default_device(self._device0):
-                return fn_obj(feed_vals, read_state(state_rw),
-                              read_state(state_ro, commit=True), seed)
+                rw = read_state(state_rw)
+                ro = read_state(state_ro, commit=True)
+                with phases:    # exec/jit_call
+                    return fn_obj(feed_vals, rw, ro, seed)
 
         def _find_aot_entry():
             aot_dir_, akey_ = aot_key()
@@ -512,7 +515,7 @@ class ParallelExecutor(object):
         # error dict before any error sync (see Executor._run_impl)
         from ..core.executor import pop_guard_stats
         self.last_stats = pop_guard_stats(errors)
-        dsp.end(compiled=compiled, aot_hit=aot_hit)
+        dsp.set(compiled=compiled, aot_hit=aot_hit)
         if cancelled is not None and cancelled.is_set():
             # caller already raised DispatchTimeoutError; a late scope
             # write would race its rollback (see Executor._run_impl)
@@ -521,11 +524,11 @@ class ParallelExecutor(object):
             # watchdog mode: device-sync BEFORE the scope write-back so
             # an execution-phase hang can't park unresolved arrays in
             # the scope (see Executor._run_impl)
-            wsp = tspan.child("exec/watchdog_sync")
+            phases.enter("exec/watchdog_sync")
             jax.block_until_ready((fetches, new_state))
-            wsp.end()
             if cancelled is not None and cancelled.is_set():
                 return None
+        phases.enter("exec/writeback")
         # state write-back precedes any raise point (incl. the sync below):
         # rw inputs were donated (see Executor.run)
         for n, v in zip(state_out, new_state):
@@ -569,7 +572,7 @@ class ParallelExecutor(object):
             sync_fn=_sync_extra)
         if return_numpy:
             _prof.note_sync("pexe/return_numpy")
-            with tspan.child("exec/d2h"):
-                return [np.asarray(f) for f in fetches]
+            phases.enter("exec/d2h")
+            return [np.asarray(f) for f in fetches]
         from ..core.executor import FetchHandle
         return [FetchHandle(f) for f in fetches]

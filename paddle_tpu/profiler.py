@@ -18,7 +18,15 @@ by `sorted_key` in {calls,total,max,min,ave}). Two tables take its place:
               its HLO op_name. A fusion belongs to its root instruction's
               scope. Off a TPU the trace has no device plane and the table
               is empty.
+
+Two smaller blocks: `profile_report` ends with the host seconds the lowering
+rules took under jax's trace, by fluid op type (core/lowering.lower_op), and
+`python -m paddle_tpu.profiler <trace dir>` with the device's idle gaps by
+program span: the flight recorder's spans are `ptpu/...` annotations on the
+trace's host plane (observability/trace.py), and each gap between device
+operations is booked to the innermost of them open for most of it.
 """
+import bisect
 import contextlib
 import glob
 import os
@@ -28,12 +36,13 @@ import time
 import jax
 
 from .core.lowering import parse_op_scope
+from .observability.trace import ANNOTATION_PREFIX
 
 __all__ = ["profiler", "start_profiler", "stop_profiler", "reset_profiler",
            "profile_report", "record_event", "cache_stats", "note_sync",
            "sync_stats", "dispatch_path", "record_idle", "snapshot",
            "device_op_table", "device_op_table_from", "read_op_names",
-           "render_device_ops"]
+           "render_device_ops", "idle_gaps_by_span", "render_idle_gaps"]
 
 _active = False
 _trace_dir = None
@@ -288,7 +297,28 @@ def profile_report(sorted_key=None, json=False):
                 % (ss["total"], ss["on_dispatch_path"],
                    ", ".join("%s=%d" % kv
                              for kv in sorted(ss["by_tag"].items()))))
+        lines.extend(_lowering_lines())
     return "\n".join(lines)
+
+
+def _lowering_lines(limit=10):
+    """The "Lowering(s) by op type" block: what the trace phase of a first
+    run is made of (`ptpu_lowering_seconds_total`), most seconds first."""
+    from .observability.registry import REGISTRY
+    rows = sorted(((dict(key)["op"], v) for key, v in REGISTRY.counter(
+        "ptpu_lowering_seconds_total").samples()), key=lambda r: -r[1])
+    if not rows:
+        return []
+    total = sum(v for _, v in rows)
+    lines = ["%-40s %12s %7s" % ("Lowering(s) by op type", "Seconds", "%")]
+    lines += ["%-40s %12.4f %7.2f" % (op[:40], v, 100.0 * v / total)
+              for op, v in rows[:limit]]
+    if len(rows) > limit:
+        rest = sum(v for _, v in rows[limit:])
+        lines.append("%-40s %12.4f %7.2f" % (
+            "(%d more op types)" % (len(rows) - limit), rest,
+            100.0 * rest / total))
+    return lines
 
 
 # --- the device's per-op table ---------------------------------------------
@@ -478,17 +508,135 @@ def _row_key(text, op_name, by):
     return ("/".join(scope) if by == "instance" else scope[0]), kernel, True
 
 
+def _read_trace(trace_dir):
+    """(planes, {HLO text: op_name}) of the newest trace under `trace_dir`;
+    ([], {}) where there is none."""
+    path = find_xplane(trace_dir)
+    if path is None:
+        return [], {}
+    with open(path, "rb") as f:
+        space = f.read()
+    planes = jax.profiler.ProfileData.from_serialized_xspace(space).planes
+    return list(planes), read_op_names(space)
+
+
 def device_op_table_from(trace_dir, by="type"):
     """`device_op_table` of the newest trace under `trace_dir` (what
     `profiler(profile_path=...)` or `benchmark/run.py --keep-trace` left
     there); an empty table where there is none."""
-    path = find_xplane(trace_dir)
-    if path is None:
-        return device_op_table([], {}, by)
-    with open(path, "rb") as f:
-        space = f.read()
-    planes = jax.profiler.ProfileData.from_serialized_xspace(space).planes
-    return device_op_table(list(planes), read_op_names(space), by)
+    planes, op_names = _read_trace(trace_dir)
+    return device_op_table(planes, op_names, by)
+
+
+# --- the device's idle gaps, by what the program was doing -----------------
+_HOST_PLANE = "/host:"
+
+
+def _innermost_segments(spans):
+    """Disjoint sorted (start, end, name) covering the same points as the
+    host annotations `spans`, each stretch under the name of the span open
+    there that started last: the innermost, where they nest."""
+    bounds = sorted({t for a, b, _ in spans for t in (a, b)})
+    spans = sorted(spans)
+    out, live, i = [], [], 0
+    for lo, hi in zip(bounds, bounds[1:]):
+        while i < len(spans) and spans[i][0] <= lo:
+            live.append(spans[i])
+            i += 1
+        live = [sp for sp in live if sp[1] > lo]
+        if live:
+            name = max(live, key=lambda sp: (sp[0], -sp[1]))[2]
+            if out and out[-1][2] == name and out[-1][1] == lo:
+                out[-1] = (out[-1][0], hi, name)
+            else:
+                out.append((lo, hi, name))
+    return out
+
+
+def idle_gaps_by_span(planes, prefix=ANNOTATION_PREFIX, longest=5):
+    """The device's idle time by host annotation. A gap is a stretch between
+    the first and the last operation of a `/device:TPU:<n>` plane's `XLA
+    Ops` line in which none runs; each is split over the annotations named
+    `<prefix>...` on the `/host:` planes by the innermost one open at each
+    instant ("none" where there is none), and named by the one with the
+    largest part: the rule of benchmark/trace_reduce.py for the benchmark's
+    own `bench/` annotations, with nesting.
+
+    Returns {"planes", "idle_ms" (a device), "gaps" (a device), "named_ms"
+    (idle under any annotation), "by_span": [[name, ms, gaps it leads]]
+    most first, "longest": [[name, ms]]}, times averaged over the device
+    planes."""
+    spans = [(float(e.start_ns), float(e.start_ns + e.duration_ns), e.name)
+             for p in planes if p.name.startswith(_HOST_PLANE)
+             for ln in p.lines for e in ln.events
+             if e.name.startswith(prefix) and e.duration_ns > 0]
+    segments = _innermost_segments(spans)
+    starts = [sg[0] for sg in segments]
+    by_span, gaps, n = {}, [], 0
+    for plane in planes:
+        if not plane.name.startswith(_DEVICE_PLANE):
+            continue
+        busy = []
+        for a, b in sorted(
+                (float(e.start_ns), float(e.start_ns + e.duration_ns))
+                for ln in plane.lines if ln.name == _OPS_LINE
+                for e in ln.events if e.duration_ns > 0):
+            if busy and a <= busy[-1][1]:
+                busy[-1][1] = max(busy[-1][1], b)
+            else:
+                busy.append([a, b])
+        if not busy:
+            continue
+        n += 1
+        for (_, a), (b, _) in zip(busy, busy[1:]):
+            shares, covered = {}, 0.0
+            i = max(0, bisect.bisect_right(starts, a) - 1)
+            while i < len(segments) and segments[i][0] < b:
+                lo, hi, name = segments[i]
+                part = min(b, hi) - max(a, lo)
+                if part > 0:
+                    shares[name] = shares.get(name, 0.0) + part
+                    covered += part
+                i += 1
+            if b - a > covered:
+                shares["none"] = shares.get("none", 0.0) + b - a - covered
+            lead = max(shares, key=shares.get)
+            gaps.append((lead, b - a))
+            for name, ns in shares.items():
+                row = by_span.setdefault(name, [0.0, 0])
+                row[0] += ns
+            by_span[lead][1] += 1
+    gaps.sort(key=lambda g: -g[1])
+    per = 1e6 * max(n, 1)       # ns over all planes -> ms a device
+    return {"planes": n,
+            "idle_ms": sum(ns for _, ns in gaps) / per,
+            "gaps": len(gaps) / max(n, 1),
+            "named_ms": sum(v[0] for k, v in by_span.items()
+                            if k != "none") / per,
+            "by_span": [[k, v[0] / per, v[1]] for k, v in sorted(
+                by_span.items(), key=lambda kv: -kv[1][0])],
+            "longest": [[k, ns / 1e6] for k, ns in gaps[:longest]]}
+
+
+def render_idle_gaps(table):
+    """The block `idle_gaps_by_span` made, as text."""
+    if not table["by_span"]:
+        return ("idle gaps by program span: the trace holds no gap between "
+                "device operations (off the chip there is no device plane)")
+    idle = table["idle_ms"]
+    lines = ["%-44s %11s %7s %10s" % (
+        "Idle gaps by program span (innermost)", "Idle(ms)", "Idle%",
+        "Gaps led")]
+    for name, ms, led in table["by_span"]:
+        lines.append("%-44s %11.3f %7.2f %10d" % (
+            name[:44], ms, 100.0 * ms / idle if idle else 0.0, led))
+    lines.append(
+        "idle gaps: %.3f ms a device in %d gap(s) over %d device plane(s); "
+        "%.2f%% under a program span; the longest: %s"
+        % (idle, table["gaps"], table["planes"],
+           100.0 * table["named_ms"] / idle if idle else 0.0,
+           ", ".join("%s %.3f ms" % (k, ms) for k, ms in table["longest"])))
+    return "\n".join(lines)
 
 
 _DEVICE_SORT = {"calls": "events", "total": "total_ms", "max": "max_ms",
@@ -573,7 +721,7 @@ def cuda_profiler(*args, **kwargs):
 
 def main(argv=None):
     """python -m paddle_tpu.profiler <trace dir>: the device's per-op table
-    of a kept trace."""
+    of a kept trace, then its idle gaps by program span."""
     import argparse
     import json
     ap = argparse.ArgumentParser(
@@ -590,9 +738,14 @@ def main(argv=None):
     args = ap.parse_args(argv)
     if find_xplane(args.trace) is None:
         ap.error("no .xplane.pb under %s" % args.trace)
-    table = device_op_table_from(args.trace, args.by)
-    print(json.dumps(table) if args.json
-          else render_device_ops(table, args.sorted_key, args.limit))
+    planes, op_names = _read_trace(args.trace)
+    table = device_op_table(planes, op_names, args.by)
+    gaps = idle_gaps_by_span(planes)
+    if args.json:
+        print(json.dumps(dict(table, idle_gaps=gaps)))
+    else:
+        print(render_device_ops(table, args.sorted_key, args.limit))
+        print(render_idle_gaps(gaps))
 
 
 if __name__ == "__main__":

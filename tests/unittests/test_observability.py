@@ -57,7 +57,7 @@ def test_span_nesting_dump_and_chrome_export():
     with trace.span("outer", cat="t", trace=tr, k=1) as sp:
         with sp.child("inner"):
             pass
-        sp.event("mark", why="x")
+        trace.instant("mark", trace=tr, why="x")
     leak = trace.span("leaky", cat="t", trace=trace.new_trace())
     d = trace.dump()
     names = [e["name"] for e in d["events"]]
