@@ -728,6 +728,64 @@ def _gated_delta_case(smoke, c, tol):
                                                        tol))
 
 
+def _in_flight_ms(run, args, calls=10, rounds=5):
+    """Median over `rounds` of the time of `calls` calls in flight, a call,
+    in ms: a part of a millisecond or two is not timed through one
+    dispatch."""
+    import jax
+    jax.block_until_ready(run(*args))
+    times = []
+    for _ in range(rounds):
+        t0 = time.perf_counter()
+        outs = [run(*args) for _ in range(calls)]
+        jax.block_until_ready(outs)
+        times.append((time.perf_counter() - t0) / calls)
+    return 1e3 * statistics.median(times)
+
+
+def _gated_delta_parts(smoke, c):
+    """What XLA runs around the delta rule's kernels, each part alone at the
+    Qwen3-Next cell's shapes and the table's chunk, bf16 operands as under
+    AMP: (I + L)^-1 of every chunk, `_prepare` forward, and `_prepare`
+    forward with the transpose jax derives for it (what a layer pays a
+    step). Printed for the next reader (no metric): PERF.md section 7
+    quotes them."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.ops import gated_delta_kernels, kernel_config
+
+    rng = np.random.RandomState(17)
+    b, t, hk, hv, d = c["b"], c["t"], c["hk"], c["hv"], c["d"]
+    chunk = kernel_config.DEFAULT_TILES["gdr"]["chunk"]
+    n = -(-t // chunk)
+    q, k = (jnp.asarray(rng.randn(b, t, hk, d), jnp.bfloat16)
+            for _ in range(2))
+    v = jnp.asarray(rng.randn(b, t, hv, d), jnp.bfloat16)
+    g = -jnp.asarray(rng.rand(b, t, hv), jnp.float32)
+    beta = jax.nn.sigmoid(jnp.asarray(rng.randn(b, t, hv), jnp.float32))
+    low = jnp.asarray(np.tril(rng.randn(b, hv, n, chunk, chunk) * 0.3, -1),
+                      jnp.float32)
+
+    def prepare(*a):
+        return gated_delta_kernels._prepare(*a, chunk=chunk,
+                                            dt=jnp.bfloat16)
+
+    def both(*a):
+        out, vjp = jax.vjp(prepare, *a)
+        return vjp(jax.tree_util.tree_map(jnp.ones_like, out))
+
+    with jax.default_device(smoke.device):
+        parts = (_in_flight_ms(jax.jit(gated_delta_kernels.unit_lower_inverse),
+                               (low,)),
+                 _in_flight_ms(jax.jit(prepare), (q, k, v, g, beta)),
+                 _in_flight_ms(jax.jit(both), (q, k, v, g, beta)))
+    smoke.say("gated_delta_rule's XLA parts, q/k [%d, %d, %d, %d] v [.., %d, "
+              "%d] bf16, chunk %d: (I + L)^-1 of [%d, %d, %d, %d, %d] %.3f "
+              "ms; _prepare forward %.3f ms; _prepare forward + transpose "
+              "%.3f ms (median of 5 x 10 calls in flight)"
+              % ((b, t, hk, d, hv, d, chunk, b, hv, n, chunk, chunk) + parts))
+
+
 def phase_c(smoke):
     cases = _kernel_cases(smoke.cfg["kernels"])
     runs = [(c[0], lambda c=c: _kernel_case(smoke, *c)) for c in cases]
@@ -738,6 +796,9 @@ def phase_c(smoke):
                  lambda: _gated_delta_case(
                      smoke, smoke.cfg["kernels"]["gated_delta"],
                      TOL["attn"])))
+    runs.append(("gated_delta_rule's XLA parts",
+                 lambda: _gated_delta_parts(
+                     smoke, smoke.cfg["kernels"]["gated_delta"])))
     _, _, ln_build, ln_feed, ln_tol = next(c for c in cases if c[1] == "ln")
     runs.append(("layer_norm on Executor(CPUPlace())",
                  lambda: _cpu_place_case(smoke, ln_build, ln_feed, ln_tol)))
@@ -885,17 +946,6 @@ def phase_f(smoke):
             return (y,) + vjp(dy)
         return forward, both
 
-    def timed(fn, *args):
-        jax.block_until_ready(fn(*args))
-        times = []
-        for _ in range(5):
-            t0 = time.perf_counter()
-            for _ in range(10):
-                out = fn(*args)
-            jax.block_until_ready(out)
-            times.append((time.perf_counter() - t0) / 10)
-        return 1e3 * statistics.median(times)
-
     names = ("y", "dx", "dw")
     saved = os.environ.get("PADDLE_TPU_PALLAS")
     found = {}
@@ -923,7 +973,8 @@ def phase_f(smoke):
                     "%.3f ms, forward + backward %.3f ms (median of 5 x "
                     "10 calls); off the float32 recomputation by %s"
                     % (path, list(shape), c["width"],
-                       timed(jax.jit(forward), x, w), timed(run, x, w, dy),
+                       _in_flight_ms(jax.jit(forward), (x, w)),
+                       _in_flight_ms(run, (x, w, dy)),
                        ", ".join("%s %.2e" % (n, errs[n]) for n in names)))
     finally:
         if saved is None:

@@ -21,26 +21,26 @@ state that enters it, i and j positions inside it:
 What no chunk needs another for (`_prepare`: the l2 norms, the running sums
 and their exponentials, L, T, U, W, M) is jax.numpy, batched over all
 chunks at once and differentiated by jax; only (I + L)^-1 has a rule of its
-own. The pass over chunks is the Pallas kernel `ptpu_gated_delta_fwd`, grid
-(batch x value heads / block_h, T / C) with S in VMEM scratch, and its
-reverse, which carries dS, is `ptpu_gated_delta_bwd`. `path="scan"` is the
-same chunked form with `lax.scan` over chunks, forward and backward XLA's
-own: what runs where the kernels are off (the CPU by default), and what the
-kernels are measured against.
+own, and its products run on the VPU with a chunk in every lane (`_inverse`
+says why). q, k and v move to [B, H, N, C, d] in the dtype they arrive in and
+are converted after. The pass over chunks is the Pallas kernel
+`ptpu_gated_delta_fwd`, grid (batch x value heads / block_h, T / C) with S
+in VMEM scratch, and its reverse, which carries dS, `ptpu_gated_delta_bwd`.
+`path="scan"` is the same chunked form with `lax.scan` over chunks, forward
+and backward XLA's own: what runs where the kernels are off (the CPU by
+default), and what the kernels are measured against.
 
 Memory: the kernel path keeps, from the forward to the backward pass, the
-chunk pass's operands and what `_prepare`'s transpose needs (0.6 GiB a layer
-at one sequence of 4096, 32 value heads; AOT compile, PR 33), as lax.scan's
+chunk pass's operands and what `_prepare`'s transpose needs, as lax.scan's
 own backward does. The backward pass runs the forward kernel once more to
 write the state that enters every chunk (T / C x [d_k, d_v] a head, alive
-inside the grad op only), then the reverse kernel, then the transpose.
-Preparing the chunks again in the backward pass instead keeps q, k, v, g and
-beta only and costs 3.2 ms a layer and step on the v5e (my chip run, PR 33).
+inside the grad op only), then the reverse kernel, then the transpose
+(recomputing `_prepare` instead costs 3.2 ms a layer; my chip run, PR 33).
 
-Precision: g, beta, the running sums, every exponential, (I + L)^-1, the
-state and every accumulator are float32. A matmul takes its operands in
-`operand_dtype` (bf16 under AMP, else the inputs' float32) and accumulates
-in float32; the inverse's own matmuls are float32 at precision "highest".
+Precision: g, beta, the running sums, every exponential, (I + L)^-1 (its
+backward rule's two matmuls at precision "highest"), the state and every
+accumulator are float32. A matmul takes its operands in `operand_dtype`
+(bf16 under AMP, else the inputs' float32) and accumulates in float32.
 
 In a module of its own: jax keeps source locations inside a Mosaic call's
 serialized kernel, so an edit above a kernel in pallas_kernels.py re-keys
@@ -73,35 +73,43 @@ _NT = ((1,), (1,))      # a @ b.T
 _TN = ((0,), (0,))      # a.T @ b
 
 
-# ---------------------------------------------------------------------------
-# (I + L)^-1 of a strictly lower-triangular L
-# ---------------------------------------------------------------------------
+# ---- (I + L)^-1 of a strictly lower-triangular L ----------------------------
 
-def _mm(a, b):
-    return jnp.matmul(a, b, precision=lax.Precision.HIGHEST)
+def _lane_mm(a, b):
+    """[.., p, q, G] x [.., q, r, G] -> [.., p, r, G] on the VPU, G the lanes."""
+    return jnp.sum(a[..., :, :, None, :] * b[..., None, :, :, :], axis=-3)
 
 
 def _inverse(low):
     """(I + low)^-1 for low [..., n, n] strictly lower-triangular, n = 16 x
-    2^m. A 16 x 16 block by its finite power series sum_k (-L)^k = (I + X)
-    (I + X^2)(I + X^4)(I + X^8) with X = -L (L^16 = 0); larger ones from
-    their halves: [[A, 0], [B, D]]^-1 = [[A^-1, 0], [-D^-1 B A^-1, D^-1]].
-    The series over a whole chunk would cancel catastrophically where
-    consecutive keys are alike (its terms grow like binomials of n); over
-    16 they stay within a few digits."""
-    n = low.shape[-1]
-    if n <= _INVERSE_BASE:
-        x = -low
-        out = jnp.eye(n, dtype=low.dtype) + x
-        for _ in range(3):
-            x = _mm(x, x)
-            out = out + _mm(out, x)
-        return out
-    h = n // 2
-    a, d = _inverse(low[..., :h, :h]), _inverse(low[..., h:, h:])
-    c = -_mm(_mm(d, low[..., h:, :h]), a)
-    top = jnp.concatenate([a, jnp.zeros_like(c)], -1)
-    return jnp.concatenate([top, jnp.concatenate([c, d], -1)], -2)
+    2^m. The 16 x 16 diagonal blocks by their finite power series sum_k
+    (-L)^k = (I + X)(I + X^2)(I + X^4)(I + X^8) with X = -L (L^16 = 0), then
+    neighbours merged: [[A, 0], [B, D]]^-1 = [[A^-1, 0], [-D^-1 B A^-1,
+    D^-1]]. The series over a whole chunk would cancel catastrophically where
+    consecutive keys are alike (its terms grow like binomials of n); over 16
+    they stay within a few digits. The products run on the VPU, the chunks
+    in the lanes: a [.., 64, 64] float32 array is padded to 128 lanes on the
+    v5e, and batched matmuls cost the passes over such arrays (PERF.md, PR 36)."""
+    n, m = low.shape[-1], _INVERSE_BASE
+    lanes = jnp.moveaxis(low.reshape((-1, n, n)), 0, 2)         # [n, n, G]
+
+    def blocks(s):                  # [n / s, s rows, n / s, s columns, G]
+        return lanes.reshape((n // s, s, n // s, s, -1))
+
+    x = -jnp.stack([blocks(m)[i, :, i] for i in range(n // m)])
+    out = jnp.eye(m, dtype=low.dtype)[:, :, None] + x          # [n / m, m, m, G]
+    for _ in range(3):
+        x = _lane_mm(x, x)
+        out = out + _lane_mm(out, x)
+    while m < n:
+        a, d = jnp.moveaxis(out.reshape((-1, 2) + out.shape[1:]), 1, 0)
+        b = jnp.stack([blocks(m)[2 * p + 1, :, 2 * p]
+                       for p in range(n // m // 2)])
+        c = -_lane_mm(_lane_mm(d, b), a)
+        out = jnp.concatenate([jnp.concatenate([a, jnp.zeros_like(a)], 2),
+                               jnp.concatenate([c, d], 2)], 1)
+        m *= 2
+    return jnp.moveaxis(out[0], 2, 0).reshape(low.shape)
 
 
 @jax.custom_vjp
@@ -118,15 +126,14 @@ def _unit_lower_inverse_fwd(low):
 
 def _unit_lower_inverse_bwd(t, g):
     tt = jnp.swapaxes(t, -1, -2)
-    return (jnp.tril(-_mm(_mm(tt, g), tt), -1),)
+    mm = functools.partial(jnp.matmul, precision=lax.Precision.HIGHEST)
+    return (jnp.tril(-mm(mm(tt, g), tt), -1),)
 
 
 unit_lower_inverse.defvjp(_unit_lower_inverse_fwd, _unit_lower_inverse_bwd)
 
 
-# ---------------------------------------------------------------------------
-# what no chunk needs another for
-# ---------------------------------------------------------------------------
+# ---- what no chunk needs another for ----------------------------------------
 
 def _l2norm(x):
     return x * lax.rsqrt(jnp.sum(x * x, -1, keepdims=True) + _EPS)
@@ -144,13 +151,15 @@ def _prepare(q, k, v, g, beta, *, chunk, dt):
     padded with beta = g = 0: they write nothing and decay nothing."""
     b, t, hk, dk = q.shape
     hv, dv = v.shape[2], v.shape[3]
-    rep = hv // hk
-    n = -(-t // chunk)
+    rep, n = hv // hk, -(-t // chunk)
 
     def chunks(x):                      # [B, T, H, ...] -> [B, H, N, C, ...]
-        x = jnp.pad(x.astype(_F32),
-                    [(0, 0), (0, n * chunk - t)] + [(0, 0)] * (x.ndim - 2))
-        return jnp.moveaxis(x.reshape((b, n, chunk) + x.shape[2:]), 3, 1)
+        # in x's dtype: the transposes move bf16, the convert fuses after
+        x = jnp.pad(x, [(0, 0), (0, n * chunk - t)] + [(0, 0)] * (x.ndim - 2))
+        x = jnp.moveaxis(x.reshape((b, n, chunk) + x.shape[2:]), 3, 1)
+        if x.dtype != _F32:             # or XLA hoists the convert back
+            x = lax.optimization_barrier(x)
+        return x.astype(_F32)
 
     def heads(x):                       # [B, Hk, ...] -> [B, Hv, ...]
         x = jnp.broadcast_to(x[:, :, None],
@@ -167,19 +176,14 @@ def _prepare(q, k, v, g, beta, *, chunk, dt):
     decay = jnp.exp(jnp.where(row >= col, c[..., :, None] - c[..., None, :],
                               -jnp.inf))
     qd, kd = qc.astype(dt), kc.astype(dt)
-    kk = heads(jnp.einsum("bhnid,bhnjd->bhnij", kd, kd,
-                          preferred_element_type=_F32))
-    qk = heads(jnp.einsum("bhnid,bhnjd->bhnij", qd, kd,
-                          preferred_element_type=_F32))
+    kk, qk = (heads(jnp.einsum("bhnid,bhnjd->bhnij", x, kd,
+                               preferred_element_type=_F32)) for x in (kd, qd))
     low = jnp.where(row > col, bc[..., None] * kk * decay, 0.0)
     tm = unit_lower_inverse(low).astype(dt)
     kv_heads = heads(kc)
-    u = jnp.einsum("bhnij,bhnjd->bhnid", tm,
-                   (bc[..., None] * vc).astype(dt),
-                   preferred_element_type=_F32)
-    w = jnp.einsum("bhnij,bhnjd->bhnid", tm,
-                   ((bc * e)[..., None] * kv_heads).astype(dt),
-                   preferred_element_type=_F32)
+    u, w = (jnp.einsum("bhnij,bhnjd->bhnid", tm, x.astype(dt),
+                       preferred_element_type=_F32)
+            for x in (bc[..., None] * vc, (bc * e)[..., None] * kv_heads))
     erow = jnp.broadcast_to(e[..., -1:, None], (b, hv, n, 1, dv))
     out = ((heads(qc) * e[..., None]).astype(dt),
            (kv_heads * tail[..., None]).astype(dt),
@@ -187,9 +191,7 @@ def _prepare(q, k, v, g, beta, *, chunk, dt):
     return tuple(x.reshape((b * hv,) + x.shape[2:]) for x in out)
 
 
-# ---------------------------------------------------------------------------
-# the pass over chunks: lax.scan
-# ---------------------------------------------------------------------------
+# ---- the pass over chunks: lax.scan -----------------------------------------
 
 def _chunk_pass_scan(qe, kd, m, u, w, erow):
     """o [BH, N, C, dv] of the chunk pass, the state carried by
@@ -216,9 +218,7 @@ def _chunk_pass_scan(qe, kd, m, u, w, erow):
     return jnp.moveaxis(o, 0, 1)
 
 
-# ---------------------------------------------------------------------------
-# the pass over chunks: Pallas
-# ---------------------------------------------------------------------------
+# ---- the pass over chunks: Pallas -------------------------------------------
 
 def _fwd_kernel(qe_ref, kd_ref, m_ref, u_ref, w_ref, e_ref, *rest, hb, emit):
     """One grid step: `hb` heads' chunk n. With `emit` the state that enters

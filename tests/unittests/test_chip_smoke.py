@@ -44,6 +44,10 @@ def test_tiny_rehearsal_passes_every_phase():
                for line in lines)
     # phase C walked the kernels (interpreted here, Mosaic on the chip)
     assert sum("interpreted;" in line for line in lines) >= 7
+    # and timed what XLA runs around the delta rule's kernels, part by part
+    assert any("gated_delta_rule's XLA parts" in line
+               and "(I + L)^-1 of [2, 4, 1, 64, 64]" in line
+               and "_prepare forward + transpose" in line for line in lines)
     # phase F ran the convolution on both of its paths
     for path in ("kernel", "xla"):
         assert any("causal_conv1d %s path" % path in line

@@ -1,7 +1,8 @@
 """The gated delta rule op (ops/gated_delta_kernels.py): the chunked forward
 and backward, on the Pallas kernels (interpreted here) and on lax.scan,
 against the token-by-token recurrence of models/causal_lm_reference.py and
-jax.grad of it; (I + L)^-1; the op through a Program; its counter."""
+jax.grad of it; (I + L)^-1 and the shape of its products; `_prepare` on
+bf16 against float32 inputs; the op through a Program; its counter."""
 import numpy as np
 import pytest
 
@@ -139,6 +140,95 @@ def test_unit_lower_inverse(n, kind):
                         @ np.swapaxes(want, 1, 2), -1)
     assert np.abs(np.asarray(grad) - want_grad).max() \
         < 1e-4 * np.abs(want_grad).max()
+
+
+@pytest.mark.parametrize("keys", ["random", "alike"])
+def test_unit_lower_inverse_of_what_prepare_builds(keys):
+    """At the batch shape of the Qwen3-Next cell ([sequences, value heads,
+    chunks, 64, 64], fewer heads and chunks), L as `_prepare` builds it:
+    beta_i (k_i . k_j) exp(c_i - c_j) under the diagonal, from l2-normalised
+    keys (`alike`: one key a chunk, so every k_i . k_j is 1) and a slow
+    decay, against numpy's inverse in float64 (alike keys condition the
+    inverse worst: the recursion over slices read 1e-5 there too)."""
+    rng = np.random.RandomState(5)
+    shape, dk = (1, 4, 8, 64), 128
+    k = rng.randn(*(shape[:3] + (1 if keys == "alike" else 64, dk)))
+    k = np.broadcast_to(k / np.linalg.norm(k, axis=-1, keepdims=True),
+                        shape + (dk,))
+    beta = 1.0 / (1.0 + np.exp(-rng.randn(*shape)))
+    c = np.cumsum(-rng.rand(*shape) * 0.02, -1)
+    low = np.tril(beta[..., None] * (k @ np.swapaxes(k, -1, -2))
+                  * np.exp(c[..., :, None] - c[..., None, :]), -1)
+    want = np.linalg.inv(np.eye(64) + low)
+    got = np.asarray(gdk.unit_lower_inverse(jnp.asarray(low, jnp.float32)))
+    assert got.shape == shape + (64,)
+    assert np.abs(got - want).max() < 1e-4 * np.abs(want).max()
+
+
+def _equations(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs its equations hold (a
+    custom_vjp call, a jitted jax.numpy function)."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for held in jax.tree_util.tree_leaves(
+                list(eqn.params.values()),
+                is_leaf=lambda x: hasattr(x, "eqns") or hasattr(x, "jaxpr")):
+            held = getattr(held, "jaxpr", held)
+            if hasattr(held, "eqns"):
+                for inner in _equations(held):
+                    yield inner
+
+
+@pytest.mark.parametrize("n,products", [(16, 6), (32, 8), (64, 10),
+                                        (128, 12)])
+def test_unit_lower_inverse_multiplies_with_the_chunks_in_the_lanes(
+        n, products):
+    """6 + 2 log2(n / 16) products forward, every one float32 multiplies
+    and a sum on arrays whose minor axis is the batch of chunks, and not
+    one batched matmul: a [.., 16, 16] or [.., 64, 64] float32 operand is
+    padded to 128 lanes on the chip, which is what both the recursion over
+    slices and ten matmuls of whole blocks paid (PERF.md, PR 36). The
+    backward rule keeps its two matmuls of whole blocks at "highest"."""
+    shape = (1, 4, 8, n, n)
+    batch = 1 * 4 * 8
+    low = jax.ShapeDtypeStruct(shape, jnp.float32)
+    eqns = list(_equations(jax.make_jaxpr(gdk._inverse)(low).jaxpr))
+    names = [e.primitive.name for e in eqns]
+    assert "dot_general" not in names
+    assert names.count("reduce_sum") == names.count("mul") == products
+    for e in eqns:
+        out = e.outvars[0].aval
+        if e.primitive.name in ("mul", "reduce_sum") or (
+                e.primitive.name in ("add", "neg") and out.ndim > 2):
+            assert out.shape[-1] == batch and out.dtype == jnp.float32
+    grad = jax.make_jaxpr(jax.grad(
+        lambda x: jnp.sum(gdk.unit_lower_inverse(x))))(low)
+    dots = [e for e in _equations(grad.jaxpr)
+            if e.primitive.name == "dot_general"]
+    assert len(dots) == 2
+    for e in dots:
+        assert [v.aval.shape[-2:] for v in e.invars] == [(n, n)] * 2
+        assert e.outvars[0].aval.dtype == jnp.float32
+        assert all(p == jax.lax.Precision.HIGHEST
+                   for p in e.params["precision"])
+
+
+@pytest.mark.parametrize("t,chunk", [(64, 64), (75, 32)],
+                         ids=["whole_chunks", "ragged"])
+def test_prepare_on_bf16_inputs_equals_prepare_on_their_float32_copies(
+        t, chunk):
+    """`chunks()` pads, reshapes and moves the head axis in the dtype the
+    inputs arrive in and converts after: a convert commutes with a
+    permutation, so not one bit of what the chunk pass is given moves."""
+    q, k, v, g, beta = _inputs(t, dk=32, dv=32)
+    q, k, v = (x.astype(jnp.bfloat16) for x in (q, k, v))
+    got = gdk._prepare(q, k, v, g, beta, chunk=chunk, dt=jnp.bfloat16)
+    want = gdk._prepare(*(x.astype(jnp.float32) for x in (q, k, v)), g, beta,
+                        chunk=chunk, dt=jnp.bfloat16)
+    for a, b in zip(got, want):
+        assert a.dtype == b.dtype and a.shape == b.shape
+        assert np.array_equal(np.asarray(a.astype(jnp.float32)),
+                              np.asarray(b.astype(jnp.float32)))
 
 
 @pytest.mark.parametrize("bad", ["chunk", "path", "heads", "g"])
