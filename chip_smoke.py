@@ -27,6 +27,11 @@ from a seed, and checks what comes out by the repo's own means:
      the two Pallas kernels against the rule's jax.numpy passes, forward
      and forward + backward on the host's clock, and the largest error of
      y, dx and dw of either against a float32 recomputation.
+  G  a looped decoder (models/causal_lm.py, total_ut_steps 4) at the Ouro
+     cell's widths and depth 2: the gradients of weights the four passes
+     share, summed through the loop op's transpose, against jax.grad of
+     the plain float32 reference, with the loop's recomputation and
+     without it. The benchmark's `correct` sees only the forward pass.
 
 Every phase that fails makes the exit code non-zero. Timings are printed
 for the next reader, labelled with the device; they are not metrics. The
@@ -73,6 +78,11 @@ FULL = {
         lstmp=dict(b=128, t=64, d=128, p=64),
         seq_softmax=dict(b=64, t=50),               # MT attention scores
         seq_pool=dict(b=128, t=64, f=32)),          # sentiment conv pool
+    # the Ouro cell's widths; depth 2, T and the vocabulary cut so that the
+    # float32 reference's backward pass fits beside the program
+    "looped": dict(hidden_size=2048, num_attention_heads=16,
+                   intermediate_size=5632, vocab_size=8192,
+                   num_hidden_layers=2, t=2048, tol=5e-2),
     "barrier": dict(steps=5, rounds=3, tol=0.15),
     "dp_loss_rtol": 2e-2,
 }
@@ -93,6 +103,9 @@ TINY = {
         lstmp=dict(b=5, t=6, d=8, p=4),
         seq_softmax=dict(b=6, t=10),
         seq_pool=dict(b=6, t=9, f=4)),
+    "looped": dict(hidden_size=32, num_attention_heads=4,
+                   intermediate_size=48, vocab_size=64, num_hidden_layers=2,
+                   t=32, tol=5e-2),
     "barrier": dict(steps=5, rounds=3, tol=0.75),
     "dp_loss_rtol": 2e-2,
 }
@@ -992,12 +1005,81 @@ def phase_f(smoke):
             "the jax.numpy passes (kernel, xla): %r" % (worse,))
 
 
+def phase_g(smoke):
+    """The summed gradient of weights that four passes share, under bf16
+    AMP as the cell runs, against the float32 reference's: a matrix of each
+    layer's two branches, a norm between passes, the gate and the head.
+    With recomputation and without the program's two answers may differ by
+    rounding alone."""
+    import jax
+    import jax.numpy as jnp
+    import paddle_tpu as fluid
+    from paddle_tpu.models import causal_lm, causal_lm_reference as plain
+
+    c = dict(smoke.cfg["looped"])
+    t, tol = c.pop("t"), c.pop("tol")
+    cfg = dict(c, rms_norm_eps=1e-6, rope_theta=1e6, total_ut_steps=4,
+               sandwich_norm=True, exit_gate=True, exit_entropy_coef=0.05)
+    names = ("layer_0.wq", "layer_0.w_down", "layer_1.wo", "layer_1.w_up",
+             "layer_1.ffn_out_norm", "final_norm", "exit_gate.w", "head")
+    rng = np.random.RandomState(23)
+    tok = rng.randint(0, cfg["vocab_size"], (1, t + 1))
+    feed = {"ids": tok[:, :-1], "pos": np.arange(t)[None],
+            "labels": tok[:, 1:, None]}
+    got = {}
+    for recompute in (True, False):
+        main, startup = fluid.Program(), fluid.Program()
+        main.random_seed = startup.random_seed = 5
+        with fluid.unique_name.guard(), fluid.program_guard(main, startup):
+            main.enable_mixed_precision()
+            loss, _, _ = causal_lm.causal_lm(cfg, t, recompute=recompute)
+            fluid.backward.append_backward(loss)
+        params = main.global_block().all_parameters()
+        scope = fluid.Scope()
+        with fluid.scope_guard(scope):
+            exe = fluid.Executor(fluid.TPUPlace())
+            exe.run(startup)
+            weights = [np.asarray(scope.get(p.name)) for p in params]
+            t0 = time.perf_counter()
+            out = exe.run(main, feed=feed, fetch_list=[loss] + [
+                n + "@GRAD" for n in names])
+            got[recompute] = dict(zip(("loss",) + names, out))
+            smoke.say("looped decoder, 4 passes of %d layers at hidden %d, "
+                      "T=%d, recompute %s: loss %.5f, first run %.1fs"
+                      % (cfg["num_hidden_layers"], cfg["hidden_size"], t,
+                         recompute, float(out[0][0]),
+                         time.perf_counter() - t0))
+    (loss, _), grads = jax.jit(
+        lambda w, ids, pos, labels: plain.loss_and_grads(
+            cfg, w, ids, pos, labels))(
+                weights, *(jnp.asarray(feed[k]) for k in ("ids", "pos",
+                                                          "labels")))
+    want = dict(zip((p.name for p in params), grads), loss=loss)
+    against = _normalized_errors(
+        names, [got[True][n] for n in names], [want[n] for n in names])
+    between = _normalized_errors(
+        names, [got[True][n] for n in names], [got[False][n] for n in names])
+    smoke.say("gradients through four passes, recomputed, off the float32 "
+              "reference by %s (loss %.5f against %.5f); recomputed against "
+              "kept %s" % (
+                  ", ".join("%s %.2e" % (n, against[n]) for n in names),
+                  float(got[True]["loss"][0]), float(loss),
+                  ", ".join("%s %.2e" % (n, between[n]) for n in names)))
+    wrong = {n: e for n, e in against.items() if not e <= tol}
+    wrong.update({n + " (recomputed against kept)": e
+                  for n, e in between.items() if not e <= tol / 5})
+    if wrong:
+        raise AssertionError("gradients of shared weights off by more than "
+                             "%g: %r" % (tol, wrong))
+
+
 PHASES = (("A", "ResNet-50 training", phase_a),
           ("B", "transformer training", phase_b),
           ("C", "Pallas kernel families", phase_c),
           ("D", "four-chip data parallel", phase_d),
           ("E", "timing barrier", phase_e),
-          ("F", "causal_conv1d kernels", phase_f))
+          ("F", "causal_conv1d kernels", phase_f),
+          ("G", "looped decoder's summed gradients", phase_g))
 
 
 def main(argv=None):
@@ -1005,7 +1087,7 @@ def main(argv=None):
     ap.add_argument("--tiny", action="store_true",
                     help="CPU rehearsal at toy sizes (needs "
                          "JAX_PLATFORMS=cpu)")
-    ap.add_argument("--phases", default="ABCDEF",
+    ap.add_argument("--phases", default="ABCDEFG",
                     help="letters of the phases to run (default all)")
     args = ap.parse_args(argv)
 

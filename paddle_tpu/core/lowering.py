@@ -154,6 +154,8 @@ class LowerCtx(object):
         # after the step (same channel as TensorArray overflow). Sticky OR
         # per message.
         self.op_errors = {}
+        # trips of the loops lowered that recompute, by sub-block
+        self.loop_trips = {}
         # forward op uid -> (primal outputs, vjp_fn), for the forward ops of
         # the block being lowered whose grad ops call the vjp_fn the forward
         # op kept (see _linearizations); None until the forward op has
@@ -283,6 +285,12 @@ def lower_block(ctx, block, env):
         return
     outer = ctx.linearized
     ctx.linearized = _linearizations(ctx, ops)
+    if block.idx == 0 and any(op.attrs.get("recompute") for op in ops):
+        # a loop of this block replays its body (ops/control_ops.py counts
+        # the body's ops): the forward ops around it are counted here
+        _count_remat_ops("forward", [
+            op for op in ops[:_first_backward_op(ops)]
+            if not op.attrs.get("recompute")])
     try:
         for op in ops:
             lower_op(ctx, op, env)
@@ -297,15 +305,28 @@ def _linearizations(ctx, ops):
     Pallas kernel (OpDef.calls_pallas). A grad op differentiates its forward
     rule, and differentiating it from scratch runs the rule's forward again;
     where XLA generated the forward it merges the two, a Mosaic custom call
-    it runs twice. Every other op keeps the replay: nothing would be gained
-    on the device, and the HLO of every program would change. Under
-    rematerialization the replay is the point, so nothing is kept."""
+    it runs twice, and a loop that recomputes its body (`recompute` on an
+    rnn_scan op) it would run twice as well. Every other op keeps the
+    replay: nothing would be gained on the device, and the HLO of every
+    program would change. Under rematerialization the replay is the point,
+    so nothing is kept."""
     if getattr(ctx.program, "_rematerialize", False):
         return {}
     return {op.attrs["fwd_uid"]: None for op in ops
             if op.type == "grad_of" and "fwd_uid" in op.attrs
             and registry.is_registered(op.attrs["fwd_type"])
-            and registry.get(op.attrs["fwd_type"]).calls_pallas}
+            and (registry.get(op.attrs["fwd_type"]).calls_pallas
+                 or op.attrs["fwd_attrs"].get("recompute"))}
+
+
+def _first_backward_op(ops):
+    """Index of the first op of the backward region (a grad op, or one that
+    writes a gradient), or None where there is none."""
+    for i, op in enumerate(ops):
+        if op.type == "grad_of" or any(
+                n.endswith(GRAD_SUFFIX) for n in op.all_output_vars() if n):
+            return i
+    return None
 
 
 def _is_traced_array(v):
@@ -332,12 +353,7 @@ def _lower_block_remat(ctx, ops, env):
     forward's. Returns False when the program has no backward region to
     rematerialize (caller falls back to plain lowering).
     """
-    first_bwd = None
-    for i, op in enumerate(ops):
-        if op.type == "grad_of" or any(
-                n.endswith(GRAD_SUFFIX) for n in op.all_output_vars() if n):
-            first_bwd = i
-            break
+    first_bwd = _first_backward_op(ops)
     if first_bwd is None or first_bwd < 8:
         return False
     fwd_ops, bwd_ops = ops[:first_bwd], ops[first_bwd:]
@@ -391,6 +407,7 @@ def _lower_block_remat(ctx, ops, env):
     for k, seg in enumerate(segments):
         has_special = any(op.type in _SPECIAL for op in seg)
         before = dict(env.values)
+        _count_remat_ops("forward", seg)
         for op in seg:
             if op.type in _SPECIAL:
                 resolve_lazies()
@@ -430,6 +447,7 @@ def _lower_block_remat(ctx, ops, env):
                         arrs[i] = b
                 sub = Env()
                 sub.values.update(zip(names, arrs))
+                _count_remat_ops("replayed", seg)
                 for op in seg:
                     lower_op(ctx, op, sub)
                 for nm in interior:
@@ -455,6 +473,19 @@ def _lower_block_remat(ctx, ops, env):
         finally:
             ctx._segment_handled = False
     return True
+
+
+def _count_remat_ops(kind, ops, times=1):
+    from ..observability.registry import REGISTRY
+    counter = REGISTRY.counter(
+        "ptpu_remat_ops_total",
+        "forward ops of a program that recomputes, by fluid op type, as "
+        "often as they run a step: `forward` in the forward pass, `replayed` "
+        "a second time in the backward pass (a segment of enable_"
+        "rematerialization behind its barrier, or the body of a loop op "
+        "that recomputes, once a trip)")
+    for op in ops:
+        counter.inc(times, kind=kind, op=op.type)
 
 
 # Reserved env name carrying the OR of sub-block-confined TensorArray
@@ -526,6 +557,15 @@ _SCOPE_ESCAPES = str.maketrans({"@": "~", "/": "_", "(": "_", ")": "_"})
 _SCOPE_RE = re.compile(r"(?:^|[/(])" + SCOPE_MARK + r"([^/()]+)/([^/()]+)")
 
 
+# the attribute a model's builder writes on the ops that run a stack of
+# layers over the same weights more than once (models/causal_lm.py): the
+# passes the op holds, "1-4" on the loop op of four. A grad op has it with
+# its forward op's attributes.
+PASS_ATTR = "__pass__"
+PASS_MARK = "pass:"
+_PASS_RE = re.compile(r"(?:^|[/(])" + PASS_MARK + r"(\d+(?:-\d+)?)/")
+
+
 def scope_type(op):
     """The type `op_scope` names an op by: a `grad_of` op is
     `<fwd_type>_grad`."""
@@ -535,11 +575,25 @@ def scope_type(op):
 
 
 def op_scope(op):
-    """The named scope of one fluid op: "op:<type>/<instance>"."""
+    """The named scope of one fluid op: "op:<type>/<instance>", under
+    "pass:<passes>/" where the op runs passes of a looped stack."""
     instance = next((n for names in op.outputs.values() for n in names if n),
                     "-")
-    return "%s%s/%s" % (SCOPE_MARK, scope_type(op),
-                        instance.translate(_SCOPE_ESCAPES))
+    scope = "%s%s/%s" % (SCOPE_MARK, scope_type(op),
+                         instance.translate(_SCOPE_ESCAPES))
+    attrs = op.attrs.get("fwd_attrs", ()) if op.type == "grad_of" \
+        else op.attrs
+    if PASS_ATTR in attrs:
+        return "%s%s/%s" % (PASS_MARK, attrs[PASS_ATTR], scope)
+    return scope
+
+
+def parse_pass_scope(op_name):
+    """The passes of a looped stack an HLO op_name path belongs to, as the
+    program wrote them ("1-4": the innermost "pass:<passes>/" of the path),
+    or None."""
+    found = _PASS_RE.findall(op_name)
+    return found[-1] if found else None
 
 
 def parse_op_scope(op_name):
@@ -862,6 +916,9 @@ def _lower_grad_of(ctx, op, env):
             f = jax.checkpoint(f)
         primals, vjp_fn, _ = jax.vjp(f, primal, has_aux=True)
     _count_grad_op("replayed" if kept is None else "kept", fwd_type)
+    if kept is not None and op.attrs["fwd_attrs"].get("recompute"):
+        from ..ops.control_ops import count_loop_ops
+        count_loop_ops(ctx, "replayed", op.attrs["fwd_attrs"])
 
     cotangents = []
     for (slot, i, n), p in zip(out_order, primals):

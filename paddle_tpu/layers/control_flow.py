@@ -542,6 +542,8 @@ class _RNNBase(object):
         self._step_block = None
         self._seq_var = None      # first sequence step input (for SeqLen)
         self._masked = True
+        self._steps = None        # trip count of a loop with no step input
+        self._recompute = False
 
     # -- block guard --------------------------------------------------------
     def _assert_in_rnn_block(self, method):
@@ -638,7 +640,7 @@ class _RNNBase(object):
         program = self.helper.main_program
         step_block = self._step_block
         parent_block = program.blocks[step_block.parent_idx]
-        if not self._step_inputs:
+        if not self._step_inputs and not self._steps:
             raise ValueError("RNN needs at least one step_input")
         for m in self._memories:
             if m["update"] is None:
@@ -680,7 +682,9 @@ class _RNNBase(object):
                    "pre_names": pre_names,
                    "update_names": [m["update"].name for m in self._memories],
                    "out_names": [o.name for o, _ in self._outputs],
-                   "max_len": None})
+                   "max_len": self._steps})
+        if self._recompute:     # what the default leaves is not written
+            parent_block.ops[-1].attrs["recompute"] = True
 
 
 class _RNNGuard(BlockGuard):
@@ -706,11 +710,17 @@ class StaticRNN(_RNNBase):
     """Fixed-length RNN over [batch, time, ...] inputs (no length masking).
 
     Parity: control_flow.py StaticRNN / recurrent_op.cc. Lowered to one
-    lax.scan; BPTT comes from jax.vjp of the scan."""
+    lax.scan; BPTT comes from jax.vjp of the scan. `steps`: the trip count
+    of a loop with no step input, memories and stacked outputs alone (a
+    stack of layers run `steps` times over weights the block closes over,
+    models/causal_lm.py). `recompute`: the body runs under jax.checkpoint,
+    so a trip keeps its memories and the backward pass replays it; the
+    forward op then keeps its linearization for its grad op."""
 
-    def __init__(self, name=None):
+    def __init__(self, name=None, steps=None, recompute=False):
         super(StaticRNN, self).__init__("static_rnn", name)
         self._masked = False
+        self._steps, self._recompute = steps, recompute
 
 
 class DynamicRNN(_RNNBase):

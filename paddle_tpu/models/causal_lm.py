@@ -17,9 +17,12 @@ SmallThinker-21BA3B (PowerInfer; window and full attention mixed with
 period 4, no rotary on the full layers, the router read from the attention's
 normed input, ReGLU experts) and Qwen3-Next-80B-A3B (`model_type:
 qwen3_next`; three gated delta nets to one gated full-attention layer,
-zero-centred norm weights, 512 experts beside a gated shared one), whose
-equations the module follows; `causal_lm_reference.py` is the same forward
-in plain jax.numpy.
+zero-centred norm weights, 512 experts beside a gated shared one) and
+Ouro-2.6B (`model_type: ouro`; Zhu et al. 2025, arXiv:2510.25741: one stack
+of dense layers run total_ut_steps times over the same weights, every
+branch normed going in and coming out, an exit gate that weighs the passes'
+losses), whose equations the module follows; `causal_lm_reference.py` is
+the same forward in plain jax.numpy.
 
 Config keys read (HF names): vocab_size, hidden_size, num_hidden_layers,
 num_attention_heads, num_key_value_heads (a divisor of the heads: query
@@ -43,8 +46,16 @@ decoder_sparse_step (1); and, as keys of their own like `qk_norm`, what
 `modeling_qwen3_next.py` always applies and `config.json` therefore does
 not carry: `norm_zero_centered` (a norm's weight is stored around 0,
 (1 + w) * x_hat) and `attention_gate` (the query projection is twice as
-wide, a head [q, gate], and ctx * sigmoid(gate) enters Wo). `model_type` is
-not read: a config says what it builds by these keys. SmallThinker's own
+wide, a head [q, gate], and ctx * sigmoid(gate) enters Wo). Ouro's:
+total_ut_steps (the passes over the stack; 1: no loop) and
+early_exit_threshold (1: every pass runs, the only value built); and, as
+keys of their own, what `modeling_ouro.py` always does: `sandwich_norm` (a
+layer is a = x + N2(mixer(N1(x))), a + N4(FFN(N3(a)))) and `exit_gate`
+(lambda_t = sigmoid(h_t w_g + b_g) on every pass's normed state; the loss
+is the exit distribution's expected cross-entropy less `exit_entropy_coef`
+x its entropy). The final norm closes every pass, and the next pass starts
+from the normed state. A loop over routed experts is refused. `model_type`
+is not read: a config says what it builds by these keys. SmallThinker's own
 names are mapped onto these:
 moe_ffn_hidden_size (intermediate_size), moe_num_primary_experts
 (num_experts), moe_num_active_primary_experts (num_experts_per_tok),
@@ -63,11 +74,20 @@ the experts' outputs are the partial sums of what is held.
 Parameters are created in the order the reference reads them: embedding;
 a layer's input norm, then Wq, Wk, Wv, q norm, k norm, Wo (attention) or
 W_qkvz, W_ba, the convolution's filter, dt_bias, A_log, the gated norm's
-weight, W_out (gated delta net), post-attention norm, then router, gate, up,
-down (experts; then the shared expert's gate, up, down and its sigmoid
-gate's weight) or gate, up, down (dense); final norm; head.
+weight, W_out (gated delta net), [the mixer's outgoing norm], post-attention
+norm, then router, gate, up, down (experts; then the shared expert's gate,
+up, down and its sigmoid gate's weight) or gate, up, down (dense), [the
+FFN's outgoing norm]; final norm; [the exit gate's weight and bias]; head.
+The bracketed ones exist with sandwich_norm and exit_gate. A parameter is
+named by layer and role, `layer_<i>.<role>` (`layer_0.wq`,
+`layer_3.experts.w_gate`) and `embedding`, `final_norm`, `exit_gate.w`,
+`exit_gate.b`, `head`: the passes of a looped model find their weights by
+name.
 """
+import contextlib
+
 import paddle_tpu as fluid
+from ..core.lowering import PASS_ATTR
 
 DEFAULTS = {
     "num_experts": 0, "num_experts_per_tok": 0, "norm_topk_prob": False,
@@ -79,7 +99,9 @@ DEFAULTS = {
     "moe_primary_router_apply_softmax": True, "norm_zero_centered": False,
     "attention_gate": False, "partial_rotary_factor": 1.0,
     "full_attention_interval": 1, "shared_expert_intermediate_size": 0,
-    "mlp_only_layers": [], "decoder_sparse_step": 1}
+    "mlp_only_layers": [], "decoder_sparse_step": 1, "total_ut_steps": 1,
+    "early_exit_threshold": 1, "sandwich_norm": False, "exit_gate": False,
+    "exit_entropy_coef": 0.0}
 # SmallThinker's key -> the key the builder reads
 ALIASES = {"moe_ffn_hidden_size": "intermediate_size",
            "moe_num_primary_experts": "num_experts",
@@ -106,7 +128,8 @@ def resolve(cfg):
     for key, want in (("attention_bias", False), ("clip_qkv", None),
                       ("tie_word_embeddings", False), ("rope_scaling", None),
                       ("moe_primary_router_apply_softmax", True),
-                      ("mlp_only_layers", []), ("decoder_sparse_step", 1)):
+                      ("mlp_only_layers", []), ("decoder_sparse_step", 1),
+                      ("early_exit_threshold", 1)):
         if c[key] != want:
             raise NotImplementedError(
                 "causal_lm builds %s=%r only, the config has %r"
@@ -124,6 +147,19 @@ def resolve(cfg):
         raise NotImplementedError("causal_lm builds qk_norm false, true (all "
                                   "channels) or 'head', the config has %r"
                                   % (c["qk_norm"],))
+    c["total_ut_steps"] = int(c["total_ut_steps"])
+    if c["total_ut_steps"] < 1:
+        raise ValueError("total_ut_steps %d: a model runs its layers at "
+                         "least once" % c["total_ut_steps"])
+    if c["total_ut_steps"] > 1 and c["num_experts"]:
+        raise NotImplementedError(
+            "causal_lm runs a stack of dense layers total_ut_steps=%d times, "
+            "not one with routed experts (their auxiliary losses and "
+            "assignment counts have no meaning summed over passes yet)"
+            % c["total_ut_steps"])
+    if c["exit_gate"] and c["total_ut_steps"] == 1:
+        raise ValueError("exit_gate weighs the passes of a looped model; "
+                         "total_ut_steps is 1")
     if "head_dim" not in c:
         if c["hidden_size"] % c["num_attention_heads"]:
             raise ValueError("hidden_size %d is not a multiple of %d heads"
@@ -181,26 +217,39 @@ def resolve(cfg):
 
 
 def _layer(c, i):
-    """The config as layer i sees it: `rope_theta` None where the pattern
-    gives the layer no rotary, `window` its sliding window or None. Where
-    every layer is alike it is `c` itself."""
-    theta = c["rope_theta"] if c["rope_layers"][i] else None
-    window = c["window_layers"][i]
-    if theta == c["rope_theta"] and window == c["window"]:
-        return c
-    return dict(c, rope_theta=theta, window=window)
+    """The config as layer i sees it: `layer` its index, which names its
+    parameters; `rope_theta` None where the pattern gives the layer no
+    rotary, `window` its sliding window or None."""
+    return dict(c, layer=i, window=c["window_layers"][i],
+                rope_theta=c["rope_theta"] if c["rope_layers"][i] else None)
 
 
-def _linear(x, size, c):
-    return fluid.layers.fc(
-        input=x, size=size, bias_attr=False, num_flatten_dims=2,
-        param_attr=fluid.ParamAttr(initializer=fluid.initializer.Normal(
-            0.0, c["initializer_range"])))
+def _attr(c, role, initializer=None):
+    """The ParamAttr of one parameter, named by layer and role:
+    `layer_<i>.<role>`, or `<role>` outside the layers (no role: a name of
+    its own). A pass of a looped model that asks for a name again is given
+    the parameter the first pass made (core/layer_helper.py)."""
+    if role is not None and "layer" in c:
+        role = "layer_%d.%s" % (c["layer"], role)
+    return fluid.ParamAttr(name=role, initializer=initializer)
 
 
-def _norm(x, c):
+def _matrix(c, role):
+    return _attr(c, role, fluid.initializer.Normal(0.0,
+                                                   c["initializer_range"]))
+
+
+def _linear(x, size, c, role):
+    return fluid.layers.fc(input=x, size=size, bias_attr=False,
+                           num_flatten_dims=2, param_attr=_matrix(c, role))
+
+
+def _norm(x, c, role=None):
+    """An RMS norm with its own weight, named by `role` or, called with two
+    arguments, by c["role"] where the caller put one there."""
     return fluid.layers.rms_norm(x, epsilon=c["rms_norm_eps"],
-                                 zero_centered=c["norm_zero_centered"])
+                                 zero_centered=c["norm_zero_centered"],
+                                 param_attr=_attr(c, role or c.get("role")))
 
 
 def attention(x, pos, c):
@@ -215,16 +264,17 @@ def attention(x, pos, c):
     d, hd = c["hidden_size"], c["head_dim"]
     h, hkv = c["num_attention_heads"], c["num_key_value_heads"]
     gated = c["attention_gate"]
-    q, k, v = (_linear(x, n * hd, c) for n in (2 * h if gated else h, hkv,
-                                               hkv))
+    q, k, v = (_linear(x, n * hd, c, role) for n, role in (
+        (2 * h if gated else h, "wq"), (hkv, "wk"), (hkv, "wv")))
+    cq, ck = dict(c, role="q_norm"), dict(c, role="k_norm")
     if c["qk_norm"] is True:
-        q, k = _norm(q, c), _norm(k, c)
+        q, k = _norm(q, cq), _norm(k, ck)
     q = fluid.layers.reshape(q, shape=[0, -1, h, 2 * hd if gated else hd])
     k, v = (fluid.layers.reshape(t, shape=[0, -1, hkv, hd]) for t in (k, v))
     if gated:
         q, gate = fluid.layers.split(q, 2, dim=-1)
     if c["qk_norm"] == "head":
-        q, k = _norm(q, c), _norm(k, c)
+        q, k = _norm(q, cq), _norm(k, ck)
     if c["rope_theta"] is not None:
         q, k = (fluid.layers.rotary_embedding(
             t, pos, base=c["rope_theta"], rotary_dim=c["rotary_dim"])
@@ -233,7 +283,8 @@ def attention(x, pos, c):
                                        window=c["window"])
     if gated:
         ctx = ctx * fluid.layers.sigmoid(gate)
-    return _linear(fluid.layers.reshape(ctx, shape=[0, -1, h * hd]), d, c)
+    return _linear(fluid.layers.reshape(ctx, shape=[0, -1, h * hd]), d, c,
+                   "wo")
 
 
 def gated_delta_net(x, c):
@@ -250,13 +301,13 @@ def gated_delta_net(x, c):
     dk, dv = c["linear_key_head_dim"], c["linear_value_head_dim"]
     rep = hv // hk
     qkvz = fluid.layers.reshape(
-        _linear(x, hk * (2 * dk + 2 * rep * dv), c),
+        _linear(x, hk * (2 * dk + 2 * rep * dv), c, "w_qkvz"),
         shape=[0, -1, hk, 2 * dk + 2 * rep * dv])
     q, k, v, z = fluid.layers.split(qkvz, [dk, dk, rep * dv, rep * dv],
                                     dim=-1)
     b, a = fluid.layers.split(
-        fluid.layers.reshape(_linear(x, 2 * hv, c), shape=[0, -1, hk,
-                                                           2 * rep]),
+        fluid.layers.reshape(_linear(x, 2 * hv, c, "w_ba"),
+                             shape=[0, -1, hk, 2 * rep]),
         2, dim=-1)
     b, a = (fluid.layers.cast(fluid.layers.reshape(t, shape=[0, -1, hv]),
                               "float32") for t in (b, a))
@@ -265,30 +316,29 @@ def gated_delta_net(x, c):
                              for t, n in ((q, hk * dk), (k, hk * dk),
                                           (v, hv * dv))], axis=2),
         c["linear_conv_kernel_dim"], act="silu",
-        param_attr=fluid.ParamAttr(initializer=init.Normal(
-            0.0, c["initializer_range"])))
+        param_attr=_matrix(c, "conv"))
     q, k, v = fluid.layers.split(mixed, [hk * dk, hk * dk, hv * dv], dim=-1)
     q, k = (fluid.layers.reshape(t, shape=[0, -1, hk, dk]) for t in (q, k))
     v = fluid.layers.reshape(v, shape=[0, -1, hv, dv])
     dt_bias = fluid.layers.create_parameter(
-        [hv], "float32", default_initializer=init.Constant(1.0))
+        [hv], "float32", attr=_attr(c, "dt_bias", init.Constant(1.0)))
     a_log = fluid.layers.create_parameter(
-        [hv], "float32", default_initializer=init.LogUniform(0.0, 16.0))
+        [hv], "float32", attr=_attr(c, "a_log", init.LogUniform(0.0, 16.0)))
     g = fluid.layers.scale(
         fluid.layers.exp(a_log) * fluid.layers.softplus(a + dt_bias),
         scale=-1.0)
     o = fluid.layers.gated_delta_rule(q, k, v, g, fluid.layers.sigmoid(b))
     o = fluid.layers.rms_norm(
-        o, epsilon=c["rms_norm_eps"],
+        o, epsilon=c["rms_norm_eps"], param_attr=_attr(c, "gated_norm"),
         gate=fluid.layers.reshape(z, shape=[0, -1, hv, dv]))
     return _linear(fluid.layers.reshape(o, shape=[0, -1, hv * dv]),
-                   c["hidden_size"], c)
+                   c["hidden_size"], c, "w_out")
 
 
-def _swiglu(x, width, c):
-    gate = fluid.layers.swish(_linear(x, width, c))
-    up = _linear(x, width, c)
-    return _linear(gate * up, c["hidden_size"], c)
+def _swiglu(x, width, c, role=""):
+    gate = fluid.layers.swish(_linear(x, width, c, role + "w_gate"))
+    up = _linear(x, width, c, role + "w_up")
+    return _linear(gate * up, c["hidden_size"], c, role + "w_down")
 
 
 def feed_forward(x, c, router_input=None):
@@ -303,13 +353,14 @@ def feed_forward(x, c, router_input=None):
             x, num_experts=c["num_experts"], d_expert=c["intermediate_size"],
             top_k=c["num_experts_per_tok"],
             norm_topk_prob=c["norm_topk_prob"],
-            param_attr=fluid.ParamAttr(initializer=fluid.initializer.Normal(
-                0.0, c["initializer_range"])),
-            router_input=router_input, activation=c["hidden_act"],
+            param_attr=_matrix(c, "experts"), router_input=router_input,
+            activation=c["hidden_act"],
             experts_held=c["experts_held"], first_expert=c["first_expert"])
         if c["shared_expert_intermediate_size"]:
-            shared = _swiglu(x, c["shared_expert_intermediate_size"], c)
-            out = out + shared * fluid.layers.sigmoid(_linear(x, 1, c))
+            shared = _swiglu(x, c["shared_expert_intermediate_size"], c,
+                             "shared_expert.")
+            out = out + shared * fluid.layers.sigmoid(
+                _linear(x, 1, c, "shared_expert.gate"))
         return out, (balance, z, load)
     return _swiglu(x, c["intermediate_size"], c), None
 
@@ -317,7 +368,7 @@ def feed_forward(x, c, router_input=None):
 def _count_layer(c, mixer):
     """One count a layer built, by what the model puts around its ops and
     no op can observe (the ops' own counters have the rest: heads, widths,
-    paths)."""
+    paths). A looped model builds a layer once and counts it once."""
     from ..observability.registry import REGISTRY
     attention = mixer == "attention"
     REGISTRY.counter(
@@ -325,18 +376,46 @@ def _count_layer(c, mixer):
         "decoder layers causal_lm built, by mixer, the channels of a head "
         "its rotary turns (0: none), whether a sigmoid gate multiplies the "
         "attention's output, the taps of the convolution before a gated "
-        "delta rule (0: none) and the width of the shared expert beside the "
-        "routed ones (0: none)"
+        "delta rule (0: none), the width of the shared expert beside the "
+        "routed ones (0: none) and whether each branch is normed going out "
+        "as well as going in (a sandwich)"
     ).inc(mixer=mixer,
           rotary_dim=str(c["rotary_dim"] if attention
                          and c["rope_theta"] is not None else 0),
           gate=str(bool(attention and c["attention_gate"])).lower(),
           conv=str(0 if attention else c["linear_conv_kernel_dim"]),
           shared=str(c["shared_expert_intermediate_size"]
-                     if c["num_experts"] else 0))
+                     if c["num_experts"] else 0),
+          sandwich=str(bool(c["sandwich_norm"])).lower())
 
 
-def causal_lm(cfg, seq_len):
+def exit_distribution(states, c):
+    """(p, log p) [B, passes, T] of the exit gate on the passes' normed
+    states, each [B, T, D]: lambda_t = sigmoid(h_t w_g + b_g) a token, p_t
+    = lambda_t prod_(j<t) (1 - lambda_j) and the last pass takes what is
+    left, so the passes' shares sum to 1. In float32 and in logarithms: log
+    lambda = logsigmoid(z), log(1 - lambda) = logsigmoid(-z), so a
+    saturated gate gives p = 0 and p log p = 0, not 0 x inf."""
+    layers = fluid.layers
+    w = layers.create_parameter(
+        [c["hidden_size"]], "float32", attr=_matrix(c, "exit_gate.w"))
+    b = layers.create_parameter(
+        [1], "float32",
+        attr=_attr(c, "exit_gate.b", fluid.initializer.Constant(0.0)))
+    left, log_p = None, []              # log prod_(j<t) (1 - lambda_j)
+    for h in states[:-1]:
+        z = layers.reduce_sum(layers.elementwise_mul(
+            layers.cast(h, "float32"), w, axis=2), dim=-1)
+        z = layers.reshape(layers.elementwise_add(z, b), shape=[0, 1, -1])
+        exits = layers.logsigmoid(z)
+        stays = layers.logsigmoid(layers.scale(z, scale=-1.0))
+        log_p.append(exits if left is None else exits + left)
+        left = stays if left is None else left + stays
+    log_p = layers.concat(log_p + [left], axis=1)
+    return layers.exp(log_p), log_p
+
+
+def causal_lm(cfg, seq_len, extras=None, recompute=True):
     """Build the training graph in the current program guard. Feeds: `ids`
     [B, T] token ids, `pos` [B, T] their positions, `labels` [B, T, 1] the
     next token at every position. Returns (loss, logits [B, T, V],
@@ -344,50 +423,130 @@ def causal_lm(cfg, seq_len):
     router_aux_loss_coef x the layers' mean balance loss plus
     router_z_loss_coef x their mean z loss (neither term is built where
     both coefficients are 0); expert_load [E] int32 sums the layers'
-    assignment counts (None without experts)."""
+    assignment counts (None without experts).
+
+    With total_ut_steps = P > 1 the layers and the final norm are built
+    once, as the sub-block of one loop op (a StaticRNN with no step input:
+    one lax.scan of P trips whose carry is the normed state), and run P
+    times over the same weights, which the loop closes over; a weight's
+    gradient is the sum over its P uses through the scan's transpose. With
+    `recompute` the loop's body runs under jax.checkpoint: a pass keeps the
+    state it started from and the backward pass replays its forward, so
+    what crosses from the forward to the backward pass is P states and not
+    P x layers' worth of activations. `logits` are the last pass's. With
+    exit_gate every pass's state goes through the head and the loss is
+    the mean a position of sum_t p_t CE_t - exit_entropy_coef x
+    H(p), p the exit distribution; without it only the last pass has a
+    loss. A dict given as `extras` is filled with `pass_logits`, the list of
+    the passes' logits [B, T, V] that have a loss, and `exit_p` [B, P, T]."""
     c = resolve(cfg)
-    ids = fluid.layers.data("ids", [seq_len], dtype="int64")
-    pos = fluid.layers.data("pos", [seq_len], dtype="int64")
-    labels = fluid.layers.data("labels", [seq_len, 1], dtype="int64")
-    h = fluid.layers.embedding(
+    layers, passes = fluid.layers, c["total_ut_steps"]
+    ids = layers.data("ids", [seq_len], dtype="int64")
+    pos = layers.data("pos", [seq_len], dtype="int64")
+    labels = layers.data("labels", [seq_len, 1], dtype="int64")
+    h = layers.embedding(
         ids, size=[c["vocab_size"], c["hidden_size"]],
-        param_attr=fluid.ParamAttr(initializer=fluid.initializer.Normal(
+        param_attr=_attr(c, "embedding", fluid.initializer.Normal(
             0.0, c.get("embedding_initializer_range",
                        c["initializer_range"]))))
+    # One pass: the layers, then the final norm; a looped model builds it
+    # as the sub-block of a loop op. A layer is h + mixer(N1(h)), then that
+    # + FFN(N3(.)); with sandwich_norm each branch passes a norm of its own
+    # on the way out too: a = h + N2(mixer(N1(h))), a + N4(FFN(N3(a))).
+    # Written out here and not in a function of its own: jax records the
+    # Python stack with every equation a rule's shape inference traces, and
+    # each frame more is paid for by every op of every layer (PERF.md 6).
     aux = []
-    for i in range(c["num_hidden_layers"]):
-        cl = _layer(c, i)
-        _count_layer(cl, c["mixer_layers"][i])
-        a = _norm(h, cl)
-        h = h + (attention(a, pos, cl) if c["mixer_layers"][i] == "attention"
-                 else gated_delta_net(a, cl))
-        out, layer_aux = feed_forward(
-            _norm(h, cl), cl,
-            router_input=a if c["router_input"] == "pre_attention" else None)
-        h = h + out
-        if layer_aux is not None:
-            aux.append(layer_aux)
-    logits = _linear(_norm(h, c), c["vocab_size"], c)
-    cost = fluid.layers.softmax_with_cross_entropy(
-        logits=fluid.layers.reshape(logits, shape=[-1, c["vocab_size"]]),
-        label=fluid.layers.reshape(labels, shape=[-1, 1]))
-    loss = fluid.layers.mean(cost)
+    loop = layers.StaticRNN(steps=passes, recompute=recompute) \
+        if passes > 1 else None
+    with loop.step() if loop else contextlib.nullcontext():
+        if loop:
+            h = state = loop.memory(init=h)
+        for i in range(c["num_hidden_layers"]):
+            cl, mixer = _layer(c, i), c["mixer_layers"][i]
+            _count_layer(cl, mixer)
+            a = _norm(h, cl, "input_norm")
+            mixed = attention(a, pos, cl) if mixer == "attention" \
+                else gated_delta_net(a, cl)
+            if c["sandwich_norm"]:
+                mixed = _norm(mixed, cl, "mixer_out_norm")
+            h = h + mixed
+            out, layer_aux = feed_forward(
+                _norm(h, cl, "post_attention_norm"), cl,
+                router_input=a if c["router_input"] == "pre_attention"
+                else None)
+            if c["sandwich_norm"]:
+                out = _norm(out, cl, "ffn_out_norm")
+            h = h + out
+            if layer_aux is not None:
+                aux.append(layer_aux)
+        h = _norm(h, c, "final_norm")
+        if loop:
+            loop.update_memory(state, h)
+            loop.output(h)
+    if loop:
+        fluid.default_main_program().global_block().ops[-1].attrs[
+            PASS_ATTR] = "1-%d" % passes
+        states = [layers.reshape(one, shape=[0, seq_len, c["hidden_size"]])
+                  for one in layers.split(loop(), passes, dim=1)]
+    else:
+        states = [h]
+    _count_passes(c)
+
+    def head(state):
+        logits = _linear(state, c["vocab_size"], c, "head")
+        return logits, layers.softmax_with_cross_entropy(
+            logits=layers.reshape(logits, shape=[-1, c["vocab_size"]]),
+            label=layers.reshape(labels, shape=[-1, 1]))
+
+    if c["exit_gate"]:
+        # a pass's head and its loss at a time: one [B x T, V] array of
+        # logits is alive, not P of them
+        p, log_p = exit_distribution(states, c)
+        pass_logits, costs = zip(*(head(state) for state in states))
+        cost = layers.concat([layers.reshape(cost, shape=[-1, 1, seq_len])
+                              for cost in costs], axis=1)
+        # sum_t p_t CE_t - beta H(p), H(p) = -sum_t p_t log p_t
+        loss = layers.mean(layers.reduce_sum(
+            p * (cost + layers.scale(log_p, scale=c["exit_entropy_coef"])),
+            dim=1))
+        found = {"exit_p": p}
+    else:
+        found, (pass_logits, costs) = {}, zip(head(states[-1]))
+        loss = layers.mean(costs[0])
+    logits = pass_logits[-1]
+    if extras is not None:
+        extras.update(found, pass_logits=list(pass_logits))
     load = None
     if aux and (c["router_aux_loss_coef"] or c["router_z_loss_coef"]):
-        balance, z, load = (fluid.layers.sums(list(terms))
-                            for terms in zip(*aux))
+        balance, z, load = (layers.sums(list(terms)) for terms in zip(*aux))
         loss = loss + balance * (c["router_aux_loss_coef"] / len(aux)) \
             + z * (c["router_z_loss_coef"] / len(aux))
     elif aux:
-        load = fluid.layers.sums([terms[2] for terms in aux])
+        load = layers.sums([terms[2] for terms in aux])
     return loss, logits, load
 
 
+def _count_passes(c):
+    from ..observability.registry import REGISTRY
+    REGISTRY.counter(
+        "ptpu_layer_passes_total",
+        "models causal_lm built, by the times the stack of layers runs over "
+        "the same weights, the layers in it and the form the passes have in "
+        "the program (scan: one loop op; none: one pass, no loop)"
+    ).inc(passes=str(c["total_ut_steps"]),
+          layers=str(c["num_hidden_layers"]),
+          form="scan" if c["total_ut_steps"] > 1 else "none")
+
+
 def build_train(cfg, seq_len, learning_rate=4e-4, beta1=0.9, beta2=0.95,
-                epsilon=1e-8, clip_norm=1.0):
+                epsilon=1e-8, clip_norm=1.0, recompute=True, extras=None):
     """causal_lm + Adam under global-norm gradient clipping (clip_norm None:
-    no clipping). Returns (loss, logits, expert_load)."""
-    loss, logits, load = causal_lm(cfg, seq_len)
+    no clipping). Returns (loss, logits, expert_load). `recompute` and
+    `extras` are causal_lm's: a looped model replays each pass in the
+    backward pass unless told to keep every activation."""
+    loss, logits, load = causal_lm(cfg, seq_len, extras=extras,
+                                   recompute=recompute)
     if clip_norm is not None:
         fluid.clip.set_gradient_clip(
             fluid.clip.GradientClipByGlobalNorm(clip_norm=clip_norm))

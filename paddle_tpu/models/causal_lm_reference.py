@@ -13,8 +13,12 @@ delta nets on three layers of four, a gated full-attention layer with
 QK-norm a head and partial rotary, zero-centred norm weights, a gated shared
 expert) Hugging Face's `modeling_qwen3_next.py`, the delta rule as its
 token-by-token recurrence (`torch_recurrent_gated_delta_rule`). Each
-departure is marked "Departure:" below. `params` is the list of the Program's parameters in the
-order models/causal_lm.py creates them.
+departure is marked "Departure:" below. For a looped model (Ouro,
+`model_type: ouro`; arXiv:2510.25741: total_ut_steps passes over the same
+layers, sandwich norms, an exit gate over the passes' losses) the layer is
+Hugging Face's `modeling_ouro.py` and the loss the paper's. `params` is the
+list of the Program's parameters in the order models/causal_lm.py creates
+them.
 
 One chip's share of a layer comes as arguments: `attention` computes the
 heads whose weights it is given (Wq's, Wk's and Wv's columns and Wo's rows
@@ -216,10 +220,33 @@ def routed_experts(m, router, w_gate, w_up, w_down, c, router_x=None,
     return out, balance, z, load
 
 
-def forward(cfg, params, ids, pos):
-    """(logits [B, T, V], balance term, z term, expert_load) of the model
-    on ids, pos [B, T]; the two terms are means over the layers and the
-    load is their sum (zeros without experts)."""
+def exit_distribution(lam):
+    """lam [P, B, T], the gates' sigmoids -> p [P, B, T]: p_1 = lam_1, p_t =
+    lam_t prod_(j<t) (1 - lam_j), and the last pass takes the remainder
+    prod_(j<P) (1 - lam_j), whatever its own gate says: the shares sum to 1
+    (arXiv:2510.25741, section 3)."""
+    left, p = jnp.ones_like(lam[0]), []
+    for t in range(lam.shape[0] - 1):
+        p.append(lam[t] * left)
+        left = left * (1.0 - lam[t])
+    return jnp.stack(p + [left])
+
+
+def passes(cfg, params, ids, pos):
+    """([logits [B, T, V] of pass 1 .. P], p [P, B, T] or None, balance
+    term, z term, expert_load) of the model on ids, pos [B, T]. A model
+    with total_ut_steps = P > 1 runs the same layers and the same final
+    norm P times, each pass on the normed state of the pass before: a
+    Python loop over one set of weights, each read from `params` once. With
+    sandwich_norm a layer is a = x + N2(mixer(N1(x))), y = a + N4(FFN(N3(
+    a))). With exit_gate, p is the exit distribution of lambda_t =
+    sigmoid(h_t w_g + b_g); without it only the last pass has logits that
+    count and p is None.
+
+    Departure: the paper composes the passes as head(M^L(...M^L(emb))) with
+    the final norm inside the head; the released `modeling_ouro.py` norms the
+    state at the end of every pass and starts the next pass from the normed
+    state, which is what this does."""
     c = resolve(cfg)
     params = iter(params)
 
@@ -227,58 +254,111 @@ def forward(cfg, params, ids, pos):
         return [jnp.asarray(next(params), jnp.float32) for _ in range(n)]
 
     e, eps = c["num_experts"], c["rms_norm_eps"]
-    layers = c["num_hidden_layers"]
-    balance = z = 0.0
-    load = jnp.zeros((max(e, 1),), jnp.int32)
-    with jax.default_matmul_precision("highest"):
-        h = take(1)[0][ids]
-        b, t, d = h.shape
-        centred = c["norm_zero_centered"]
-        for i in range(layers):
-            a = rms_norm(h, take(1)[0], eps, centred)
-            if c["mixer_layers"][i] == "gated_delta":
-                h = h + gated_delta_net(a, *take(7), c)
-            else:
-                wq, wk, wv = take(3)
-                q_norm, k_norm = take(2) if c["qk_norm"] else (None, None)
-                h = h + attention(a, pos, wq, wk, wv, q_norm, k_norm,
-                                  take(1)[0], layer_config(c, i))
-            m = rms_norm(h, take(1)[0], eps, centred)
-            if e:
-                out, lb, lz, ld = routed_experts(
-                    m.reshape(b * t, d), *take(4), c,
-                    router_x=a.reshape(b * t, d)
-                    if c["router_input"] == "pre_attention" else None)
-                h = h + out.reshape(b, t, d)
-                if c["shared_expert_intermediate_size"]:
-                    h = h + shared_expert(m, *take(4))
-                balance, z, load = balance + lb / layers, z + lz / layers, \
-                    load + ld
-            else:
-                wg, wu, wd = take(3)
-                h = h + (jax.nn.silu(m @ wg) * (m @ wu)) @ wd
-        w_f, w_lm = take(2)
-        logits = rms_norm(h, w_f, eps, centred) @ w_lm
+    layers, centred = c["num_hidden_layers"], c["norm_zero_centered"]
+    sandwich = c["sandwich_norm"]
+    embedding = take(1)[0]
+    weights = []                # a layer: (N1, mixer, N2, N3, ffn, N4)
+    for i in range(layers):
+        n1 = take(1)[0]
+        if c["mixer_layers"][i] == "gated_delta":
+            mixer = take(7)
+        else:
+            mixer = take(3) + (take(2) if c["qk_norm"] else [None, None]) \
+                + take(1)
+        n2 = take(1)[0] if sandwich else None
+        n3 = take(1)[0]
+        ffn = take(4 + (4 if c["shared_expert_intermediate_size"] else 0)) \
+            if e else take(3)
+        weights.append((n1, mixer, n2, n3, ffn,
+                        take(1)[0] if sandwich else None))
+    w_f = take(1)[0]
+    w_g, b_g = take(2) if c["exit_gate"] else (None, None)
+    w_lm = take(1)[0]
     if next(params, None) is not None:
         raise ValueError("the reference read fewer parameters than the "
                          "program has: the two are not the same architecture")
-    return logits, balance, z, load
+
+    balance = z = 0.0
+    load = jnp.zeros((max(e, 1),), jnp.int32)
+    logits, lam = [], []
+    with jax.default_matmul_precision("highest"):
+        h = embedding[ids]
+        b, t, d = h.shape
+        for _ in range(c["total_ut_steps"]):
+            for i, (n1, mixer, n2, n3, ffn, n4) in enumerate(weights):
+                a = rms_norm(h, n1, eps, centred)
+                if c["mixer_layers"][i] == "gated_delta":
+                    mixed = gated_delta_net(a, *mixer, c)
+                else:
+                    mixed = attention(a, pos, *mixer, layer_config(c, i))
+                if sandwich:
+                    mixed = rms_norm(mixed, n2, eps, centred)
+                h = h + mixed
+                m = rms_norm(h, n3, eps, centred)
+                if e:
+                    out, lb, lz, ld = routed_experts(
+                        m.reshape(b * t, d), *ffn[:4], c,
+                        router_x=a.reshape(b * t, d)
+                        if c["router_input"] == "pre_attention" else None)
+                    out = out.reshape(b, t, d)
+                    if c["shared_expert_intermediate_size"]:
+                        out = out + shared_expert(m, *ffn[4:])
+                    balance, z, load = balance + lb / layers, \
+                        z + lz / layers, load + ld
+                else:
+                    wg, wu, wd = ffn
+                    out = (jax.nn.silu(m @ wg) * (m @ wu)) @ wd
+                if sandwich:
+                    out = rms_norm(out, n4, eps, centred)
+                h = h + out
+            h = rms_norm(h, w_f, eps, centred)
+            logits.append(h @ w_lm)
+            if c["exit_gate"]:
+                lam.append(jax.nn.sigmoid(h @ w_g + b_g))
+    p = exit_distribution(jnp.stack(lam)) if c["exit_gate"] else None
+    return logits, p, balance, z, load
 
 
-def loss_fn(cfg, params, ids, pos, labels):
-    """(loss, (logits, expert_load)).
+def forward(cfg, params, ids, pos):
+    """(logits [B, T, V], balance term, z term, expert_load) of the model
+    on ids, pos [B, T]: the last pass's logits, which is what a user reads
+    out; the two terms are means over the layers and the load is their sum
+    (zeros without experts)."""
+    logits, _, balance, z, load = passes(cfg, params, ids, pos)
+    return logits[-1], balance, z, load
+
+
+def loss_fn(cfg, params, ids, pos, labels, with_passes=False):
+    """(loss, (logits, expert_load)), or with_passes (loss, (logits of every
+    pass, p, expert_load)). With exit_gate the loss is the mean a position
+    of sum_t p_t CE(z_t, y) - exit_entropy_coef x H(p), H(p) = -sum_t p_t
+    log p_t (0 log 0 = 0): the entropy-regularised objective of
+    arXiv:2510.25741 with a uniform prior over the exit step.
 
     Departure: HF shifts `labels` by one inside the model and drops the last
     position; here `labels[b, t]` is already the token after position t, so
-    every position carries a loss."""
+    every position carries a loss. Departure: the paper's beta is a
+    hyper-parameter of its stage I; `exit_entropy_coef` is an assumed
+    value."""
     c = resolve(cfg)
-    logits, balance, z, load = forward(cfg, params, ids, pos)
-    logp = jax.nn.log_softmax(logits, -1)
-    nll = -jnp.take_along_axis(
-        logp, labels.reshape(logits.shape[:2] + (1,)), axis=-1)
-    loss = nll.mean() + c["router_aux_loss_coef"] * balance \
+    logits, p, balance, z, load = passes(cfg, params, ids, pos)
+
+    def nll(one):
+        return -jnp.take_along_axis(
+            jax.nn.log_softmax(one, -1),
+            labels.reshape(one.shape[:2] + (1,)), axis=-1)[..., 0]
+
+    if p is None:
+        loss = nll(logits[-1]).mean()
+    else:
+        ce = jnp.stack([nll(one) for one in logits])            # [P, B, T]
+        entropy = -jax.scipy.special.xlogy(p, p).sum(0)
+        loss = ((p * ce).sum(0) - c["exit_entropy_coef"] * entropy).mean()
+    loss = loss + c["router_aux_loss_coef"] * balance \
         + c["router_z_loss_coef"] * z
-    return loss, (logits, load)
+    if with_passes:
+        return loss, (logits, p, load)
+    return loss, (logits[-1], load)
 
 
 def loss_and_grads(cfg, params, ids, pos, labels):

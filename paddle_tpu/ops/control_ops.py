@@ -413,6 +413,11 @@ def _rnn_scan_lower(ctx, ins, attrs):
         return (t + 1, tuple(new_mems), _sweep_overflow(benv, err)), \
             tuple(outs)
 
+    if attrs.get("recompute"):
+        # a trip keeps its carry and the backward pass replays its body
+        step = jax.checkpoint(step)
+        ctx.loop_trips[attrs["sub_block"]] = T
+        count_loop_ops(ctx, "forward", attrs)
     (_, final_mems, final_err), stacked = lax.scan(
         step, (jnp.zeros((), jnp.int32), tuple(boots),
                jnp.zeros((), bool)), tuple(xs_t),
@@ -421,6 +426,15 @@ def _rnn_scan_lower(ctx, ins, attrs):
     # "__errors__" is accumulated into the enclosing env by lower_op
     return {"Out": outs, "LastMem": list(final_mems),
             "__errors__": final_err}
+
+
+def count_loop_ops(ctx, kind, attrs):
+    """The ops of a recomputing loop's body in ptpu_remat_ops_total, once a
+    trip: `forward` where the loop is lowered, `replayed` where its grad op
+    is."""
+    sub = ctx.program.blocks[attrs["sub_block"]]
+    lowering._count_remat_ops(kind, sub.ops,
+                              times=ctx.loop_trips[attrs["sub_block"]])
 
 
 def _rnn_scan_infer(block, op, out_vars):

@@ -30,12 +30,13 @@ import bisect
 import contextlib
 import glob
 import os
+import re
 import threading
 import time
 
 import jax
 
-from .core.lowering import parse_op_scope
+from .core.lowering import parse_op_scope, parse_pass_scope
 from .observability.trace import ANNOTATION_PREFIX
 
 __all__ = ["profiler", "start_profiler", "stop_profiler", "reset_profiler",
@@ -341,10 +342,12 @@ def find_xplane(trace_dir):
 
 
 def _self_times(events):
-    """[[event, self_ns]] of one line's properly nested events (children
-    nest inside `while` and `conditional` parents): an event's self time is
-    its duration less its direct children's. The rule of
-    benchmark/trace_reduce.py, which the program may not import."""
+    """[[event, self_ns, outermost]] of one line's properly nested events
+    (children nest inside `while` and `conditional` parents), in the order
+    they start: an event's self time is its duration less its direct
+    children's (the rule of benchmark/trace_reduce.py, which the program
+    may not import); `outermost` is the index in the list of the outermost
+    event it lies in, its own where it lies in none."""
     timed = sorted(((float(e.start_ns), float(e.start_ns + e.duration_ns), e)
                     for e in events if e.duration_ns > 0),
                    key=lambda t: (t[0], -t[1]))
@@ -354,7 +357,7 @@ def _self_times(events):
             stack.pop()
         if stack:
             out[stack[-1][0]][1] -= min(end, stack[-1][1]) - start
-        out.append([e, end - start])
+        out.append([e, end - start, stack[0][0] if stack else len(out)])
         stack.append((len(out) - 1, end))
     return out
 
@@ -463,7 +466,7 @@ def device_op_table(planes, op_names, by="type"):
         if not events:
             continue
         n += 1
-        for e, self_ns in _self_times(events):
+        for e, self_ns, _ in _self_times(events):
             key = keys.get(e.name)
             if key is None:     # one look at an operation's text
                 key = keys[e.name] = _row_key(
@@ -490,6 +493,100 @@ def device_op_table(planes, op_names, by="type"):
                 r["total_ms"] for r in out if r["scoped"] or
                 r["kernel"] and not r["kernel"].startswith(_COLLECTIVES)),
             "rows": out}
+
+
+_FIRST_PASS_OP = re.compile(r"pass:\d+(?:-\d+)?/op:([^/()]+)/")
+
+
+def device_pass_table(planes, op_names):
+    """Device self time by pass of a stack of layers that one loop op runs
+    several times over the same weights (models/causal_lm.py writes the
+    passes on the loop op, "pass:1-4/op:rnn_scan/..."; its grad op has them
+    too).
+
+    The loop is one `while` on the device and the operations of its body
+    nest inside it, once a trip, so the trace has no name for a trip: under
+    one execution of the loop the k-th run of an instruction is trip k (an
+    instruction of a loop inside the body runs n times a trip: its runs are
+    dealt out n to a trip). A trip of the forward loop is that pass; the
+    backward loop, the grad op's, walks the passes from the last to the
+    first, and holds the replay of a pass's forward where the loop
+    recomputes.
+
+    Returns {"planes", "busy_self_ms", "rows"}: a row a pass {"pass",
+    "events", "fwd_ms", "bwd_ms", "total_ms", "share"}, a row under the
+    loop's own label ("1-4") for what is the loop's and no trip's (the
+    `while`s' own time, what XLA hoisted out of the body) and a last row
+    "outside" for everything under no pass: embedding, heads, loss,
+    optimizer. Milliseconds of self time a device plane, over the whole
+    trace; no rows where the trace holds no operation under a pass."""
+    device_planes = [p for p in planes if p.name.startswith(_DEVICE_PLANE)]
+    rows, n, busy, passes = {}, 0, 0.0, 0
+    for plane in device_planes:
+        events = [e for ln in plane.lines if ln.name == _OPS_LINE
+                  for e in ln.events]
+        if not events:
+            continue
+        n += 1
+        timed = _self_times(events)
+        runs = {}       # (outermost, instruction) -> indices of its runs
+        for i, (e, self_ns, top) in enumerate(timed):
+            busy += self_ns
+            label = parse_pass_scope(op_names.get(e.name, ""))
+            if label is not None and top != i:
+                runs.setdefault((top, e.name), []).append(i)
+            elif label is not None:     # the `while` itself, or hoisted
+                row = rows.setdefault(label, [0, 0.0, 0.0])
+                row[0] += 1
+                row[1] += self_ns
+        for (_, name), indices in runs.items():
+            op_name = op_names[name]
+            first, _, last = parse_pass_scope(op_name).partition("-")
+            count = int(last or first) - int(first) + 1
+            passes = max(passes, count)
+            backward = _FIRST_PASS_OP.search(op_name).group(1).endswith(
+                "_grad")
+            a_trip = max(1, len(indices) // count)
+            for k, i in enumerate(indices):
+                trip = min(count - 1, k // a_trip)
+                row = rows.setdefault(
+                    int(first) + (count - 1 - trip if backward else trip),
+                    [0, 0.0, 0.0])
+                row[0] += 1
+                row[2 if backward else 1] += timed[i][1]
+    if not rows:
+        return {"planes": n, "busy_self_ms": busy / 1e6 / max(n, 1),
+                "rows": []}
+    out = [{"pass": str(t), "events": rows[t][0],
+            "fwd_ms": rows[t][1] / 1e6 / n, "bwd_ms": rows[t][2] / 1e6 / n}
+           for t in sorted(rows, key=str)]
+    inside = sum(r[1] + r[2] for r in rows.values())
+    out.append({"pass": "outside", "events": 0, "fwd_ms": 0.0, "bwd_ms": 0.0,
+                "total_ms": (busy - inside) / 1e6 / n})
+    for r in out:
+        r.setdefault("total_ms", r["fwd_ms"] + r["bwd_ms"])
+        r["share"] = 100.0 * r["total_ms"] * 1e6 * n / busy
+    return {"planes": n, "busy_self_ms": busy / 1e6 / n, "rows": out}
+
+
+def render_pass_table(table):
+    """The table `device_pass_table` made, as text; nothing where the
+    program ran no pass under a name."""
+    if not table["rows"]:
+        return ""
+    lines = ["%-8s %8s %12s %13s %11s %7s" % (
+        "Pass", "Events", "Forward(ms)", "Backward(ms)", "Total(ms)",
+        "Busy%")]
+    for r in table["rows"]:
+        lines.append("%-8s %8d %12.3f %13.3f %11.3f %7.2f" % (
+            r["pass"], r["events"], r["fwd_ms"], r["bwd_ms"], r["total_ms"],
+            r["share"]))
+    lines.append(
+        "device time by pass: a trip of the loop op's `while` is a pass "
+        "(the k-th run of an instruction under one run of the loop), the "
+        "backward loop's trips run from the last pass to the first and hold "
+        "the replayed forward; `outside`: no pass, the heads among it")
+    return "\n".join(lines)
 
 
 def _row_key(text, op_name, by):
@@ -698,8 +795,12 @@ def stop_profiler(sorted_key=None, profile_path="/tmp/profile"):
     if _entries:
         print(profile_report(sorted_key))
     if traced:
-        _device_ops = device_op_table_from(profile_path)
+        planes, op_names = _read_trace(profile_path)
+        _device_ops = device_op_table(planes, op_names)
         print(render_device_ops(_device_ops, sorted_key))
+        by_pass = render_pass_table(device_pass_table(planes, op_names))
+        if by_pass:
+            print(by_pass)
 
 
 def reset_profiler():
@@ -721,7 +822,8 @@ def cuda_profiler(*args, **kwargs):
 
 def main(argv=None):
     """python -m paddle_tpu.profiler <trace dir>: the device's per-op table
-    of a kept trace, then its idle gaps by program span."""
+    of a kept trace, its time by pass where a loop op ran a stack of layers
+    several times, then its idle gaps by program span."""
     import argparse
     import json
     ap = argparse.ArgumentParser(
@@ -740,11 +842,15 @@ def main(argv=None):
         ap.error("no .xplane.pb under %s" % args.trace)
     planes, op_names = _read_trace(args.trace)
     table = device_op_table(planes, op_names, args.by)
+    by_pass = device_pass_table(planes, op_names)
     gaps = idle_gaps_by_span(planes)
     if args.json:
-        print(json.dumps(dict(table, idle_gaps=gaps)))
+        print(json.dumps(dict(table, by_pass=by_pass["rows"],
+                              idle_gaps=gaps)))
     else:
         print(render_device_ops(table, args.sorted_key, args.limit))
+        if by_pass["rows"]:
+            print(render_pass_table(by_pass))
         print(render_idle_gaps(gaps))
 
 

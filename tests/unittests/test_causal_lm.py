@@ -121,7 +121,7 @@ def test_amp_program_agrees_with_the_reference(want):
     router, norms, rotary angles, loss and master weights."""
     _, _, got = _run_program(amp=True)
     assert got["logits"].dtype == jnp.bfloat16
-    assert got["grads"]["moe_ffn_0.w_1"].dtype == np.float32
+    assert got["grads"]["layer_0.experts.w_gate"].dtype == np.float32
     assert _error(got["logits"], want["logits"]) < AMP_TOLERANCE
     assert _error(got["loss"], want["loss"]) < 1e-3
     assert got["expert_load"].sum() == 2 * 2 * B * T

@@ -75,10 +75,11 @@ def _counts():
                           path="scan"),
         "built_full": _counter("ptpu_causal_lm_layers_total",
                                mixer="attention", rotary_dim="4", gate="true",
-                               conv="0", shared="8"),
+                               conv="0", shared="8", sandwich="false"),
         "built_delta": _counter("ptpu_causal_lm_layers_total",
                                 mixer="gated_delta", rotary_dim="0",
-                                gate="false", conv="4", shared="8")}
+                                gate="false", conv="4", shared="8",
+                                sandwich="false")}
 
 
 def _run_program(amp, pallas=None, monkeypatch=None, cfg=CFG, t=T):

@@ -121,11 +121,12 @@ def test_resolve_maps_smallthinkers_keys():
     assert c["window_layers"] == [None, 16, 16, 16]
     assert causal_lm._layer(c, 0)["rope_theta"] is None
     assert causal_lm._layer(c, 1)["window"] == 16
-    # a model whose layers are all alike sees its own config in every layer
+    # a model whose layers are all alike sees its own config in every layer,
+    # and which layer it is (its parameters are named by it)
     plain = causal_lm.resolve(dict(
         vocab_size=8, hidden_size=8, num_hidden_layers=2,
         num_attention_heads=2, intermediate_size=4))
-    assert causal_lm._layer(plain, 1) is plain
+    assert causal_lm._layer(plain, 1) == dict(plain, layer=1)
     assert plain["head_dim"] == 4 and plain["experts_held"] == 0
 
 

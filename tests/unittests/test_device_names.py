@@ -322,6 +322,85 @@ def _planes():
     return planes, op_names
 
 
+def _looped_planes():
+    """(planes, op_names). A loop op of 2 passes run twice (two steps). The
+    forward `while` of a step holds a matmul once a trip (10, then 12) and,
+    in a loop of its own, an inner fusion twice a trip (1 each); the grad
+    op's `while` holds the replayed matmul and its gradient once a trip (8
+    + 20, then 6 + 20); a head's matmul runs under no pass (30)."""
+    fwd = "jit(fn)/pass:1-2/op:rnn_scan/rnn.out_0/while/body/"
+    bwd = "jit(fn)/pass:1-2/op:rnn_scan_grad/x~GRAD/transpose(jvp(pass:1-2/" \
+        "op:rnn_scan/rnn.out_0))/while/body/"
+    texts = {
+        "loop": "%while.1 = (s32[], f32[4]) while(%t.1), body=%b, "
+                "condition=%c",
+        "loop_grad": "%while.2 = (s32[], f32[4]) while(%t.2), body=%b2, "
+                     "condition=%c2",
+        "dot": "%fusion.1 = f32[4]{0} fusion(%p.1), kind=kOutput, calls=%f1",
+        "inner": "%fusion.2 = f32[4]{0} fusion(%p.2), kind=kLoop, calls=%f2",
+        "replay": "%fusion.3 = f32[4]{0} fusion(%p.3), kind=kOutput, "
+                  "calls=%f3",
+        "dot_grad": "%fusion.4 = f32[4]{0} fusion(%p.4), kind=kOutput, "
+                    "calls=%f4",
+        "head": "%fusion.5 = f32[4]{0} fusion(%p.5), kind=kOutput, calls=%f5"}
+    op_names = {
+        texts["loop"]: "jit(fn)/pass:1-2/op:rnn_scan/rnn.out_0/while:",
+        texts["loop_grad"]: "jit(fn)/pass:1-2/op:rnn_scan_grad/x~GRAD/"
+                            "transpose(jvp(pass:1-2/op:rnn_scan/rnn.out_0))/"
+                            "while:",
+        texts["dot"]: fwd + "op:mul/fc_0.tmp_0/dot_general:",
+        texts["inner"]: fwd + "op:rms_norm/n.tmp_0/while/body/mul:",
+        texts["replay"]: bwd + "checkpoint/op:mul/fc_0.tmp_0/dot_general:",
+        texts["dot_grad"]: bwd + "op:mul/fc_0.tmp_0/transpose/dot_general:",
+        texts["head"]: "jit(fn)/op:mul/fc_9.tmp_0/dot_general:"}
+    ops = []
+    for step in (0, 1000):
+        ops += [_Ev(texts["loop"], step, 100),
+                _Ev(texts["dot"], step + 1, 10),
+                _Ev(texts["inner"], step + 20, 1),
+                _Ev(texts["inner"], step + 22, 1),
+                _Ev(texts["dot"], step + 50, 12),
+                _Ev(texts["inner"], step + 70, 1),
+                _Ev(texts["inner"], step + 72, 1),
+                _Ev(texts["head"], step + 100, 30),
+                _Ev(texts["loop_grad"], step + 200, 100),
+                _Ev(texts["replay"], step + 201, 8),
+                _Ev(texts["dot_grad"], step + 210, 20),
+                _Ev(texts["replay"], step + 250, 6),
+                _Ev(texts["dot_grad"], step + 260, 20)]
+    return [_Plane("/device:TPU:0", [_Line("XLA Ops", ops)])], op_names
+
+
+def test_device_pass_table_deals_a_loops_trips_out_to_its_passes():
+    """The k-th run of an instruction under one run of the loop is trip k;
+    the backward loop walks the passes from the last to the first."""
+    table = profiler.device_pass_table(*_looped_planes())
+    rows = {r["pass"]: r for r in table["rows"]}
+    assert list(rows) == ["1", "1-2", "2", "outside"]
+    assert table["busy_self_ms"] == pytest.approx(2 * 230e-6)
+    # pass 1: forward 10 + 2 x 1; backward the second trip's 6 + 20
+    assert rows["1"]["fwd_ms"] == pytest.approx(2 * 12e-6)
+    assert rows["1"]["bwd_ms"] == pytest.approx(2 * 26e-6)
+    assert rows["2"]["fwd_ms"] == pytest.approx(2 * 14e-6)
+    assert rows["2"]["bwd_ms"] == pytest.approx(2 * 28e-6)
+    assert rows["1"]["events"] == rows["2"]["events"] == 2 * 5
+    # the two `while`s' own time is the loop's and no trip's; the head is
+    # outside every pass
+    assert rows["1-2"]["total_ms"] == pytest.approx(2 * 120e-6)
+    assert rows["outside"]["total_ms"] == pytest.approx(2 * 30e-6)
+    assert sum(r["share"] for r in table["rows"]) == pytest.approx(100.0)
+    text = profiler.render_pass_table(table)
+    assert text.splitlines()[0].split() == [
+        "Pass", "Events", "Forward(ms)", "Backward(ms)", "Total(ms)", "Busy%"]
+    assert len(text.splitlines()) == 6
+
+
+def test_device_pass_table_is_empty_without_a_loop_op():
+    table = profiler.device_pass_table(*_planes())
+    assert table["rows"] == [] and profiler.render_pass_table(table) == ""
+    assert table["busy_self_ms"] == pytest.approx(175e-6)
+
+
 def test_device_op_table_sums_to_the_busy_self_time():
     table = profiler.device_op_table(*_planes())
     rows = {(r["name"], r["kernel"]): r for r in table["rows"]}

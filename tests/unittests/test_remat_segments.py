@@ -125,6 +125,24 @@ def test_remat_duplicates_forward_compute():
     assert count(base, "optimization_barrier") == 0
 
 
+def test_remat_counts_forward_and_replayed_ops():
+    """ptpu_remat_ops_total: every forward op of a recomputing program once
+    (`forward`) and, once more, each op of a segment the backward pass
+    replayed; a program that keeps its activations counts nothing."""
+    from paddle_tpu.observability.registry import REGISTRY
+
+    def counted(kind, op="conv2d"):
+        return REGISTRY.counter("ptpu_remat_ops_total", "").value(
+            kind=kind, op=op)
+
+    before = counted("forward"), counted("replayed")
+    _train(False, steps=1)
+    assert (counted("forward"), counted("replayed")) == before
+    _train(True, steps=1)
+    assert counted("forward") - before[0] == 3
+    assert 1 <= counted("replayed") - before[1] <= 3
+
+
 def test_remat_with_top_level_while_matches_base():
     """While/conditional_block read enclosing vars via env copies that are
     not op inputs — remat must treat them as barriers, not replay them."""
