@@ -39,6 +39,7 @@ last line of stdout is one JSON object, {"ok": ..., "device": {...}}.
 Exit codes: 0 all phases passed, 1 a phase failed, 2 no TPU (nothing ran).
 """
 import argparse
+import itertools
 import json
 import os
 import statistics
@@ -56,7 +57,9 @@ FULL = {
                         # (T, batch): below / above the flash crossover
                         shapes=((256, 32), (2048, 4))),
     "kernels": dict(
-        attn=dict(b=4, t=2048, h=8, d=64),          # transformer T=2048
+        # the transformer's T=2048 cell (two heads of 64 a 128-lane block)
+        # and OLMoE's and Ouro's heads of 128, one sequence
+        attn=(dict(b=8, t=2048, h=8, d=64), dict(b=1, t=4096, h=16, d=128)),
         # SmallThinker's: 7 query heads on 1 key/value head of 128, a
         # window of half the sequence
         attn_window=dict(b=1, t=4096, h=7, hkv=1, d=128, window=2048),
@@ -92,7 +95,7 @@ TINY = {
     "transformer": dict(n_layer=1, d_model=32, n_head=2, d_inner=64,
                         vocab=64, steps=3, shapes=((16, 4), (32, 2))),
     "kernels": dict(
-        attn=dict(b=2, t=32, h=2, d=16),
+        attn=(dict(b=2, t=32, h=2, d=64), dict(b=2, t=32, h=2, d=16)),
         attn_window=dict(b=2, t=40, h=4, hkv=2, d=16, window=12),
         moe=dict(n=64, d=16, f=8),
         gated_delta=dict(b=2, t=40, hk=2, hv=4, d=16),
@@ -412,9 +415,9 @@ def _kernel_cases(cfg):
     # flash attention: bf16 q/k/v as the AMP transformer feeds it (the
     # fused_attention op is an AMP bf16 op), decoder-style (causal +
     # key lengths) and encoder-style (key lengths only)
-    c = cfg["attn"]
-    shape = (c["b"], c["t"], c["h"], c["d"])
-    for causal in (True, False):
+    for c, causal in itertools.product(cfg["attn"], (True, False)):
+        shape = (c["b"], c["t"], c["h"], c["d"])
+
         def build(main, causal=causal, shape=shape):
             main.enable_mixed_precision()
             q, k, v, g = (layers.data(name=n, shape=list(shape[1:]),

@@ -826,21 +826,28 @@ def _count_moe_layer(attrs, ins):
 def _count_attention_layer(ctx, attrs, ins):
     from ..observability.registry import REGISTRY
     from ..ops.kernel_config import flash_at
+    from ..ops.pallas_kernels import heads_a_block
     q, k = ins["Q"][0], ins["K"][0]
     window = attrs.get("window")
     if ctx.mesh is not None and ctx.mesh.shape.get("sp", 1) > 1:
         path = str(attrs.get("sp_impl", "ring"))
     else:
         path = "flash" if flash_at(q.shape[1]) else "dense"
+    # how the flash kernels index a head of [B, T, H*D]: in place, so many
+    # heads a lane block, or after a transpose to a row a head
+    heads = heads_a_block(q.shape[2], k.shape[2], q.shape[3]) \
+        or "transposed" if path == "flash" else "none"
     REGISTRY.counter(
         "ptpu_attention_layers_total",
         "fused_attention ops lowered (forward ops, not a grad op's replay), "
         "by kind (full, or window with its size), query and key/value "
-        "heads, the path taken (flash, dense, or the sequence-parallel one) "
-        "and the head's width"
+        "heads, the path taken (flash, dense, or the sequence-parallel one), "
+        "the head's width and, on the flash path, the heads the kernels "
+        "index in one lane block (or transposed)"
     ).inc(kind="full" if window is None else "window",
           window=str(window or 0), q_heads=str(q.shape[2]),
-          kv_heads=str(k.shape[2]), path=path, head_dim=str(q.shape[3]))
+          kv_heads=str(k.shape[2]), path=path, head_dim=str(q.shape[3]),
+          heads_a_block=str(heads))
 
 
 def _count_linear_attention_layer(ins):

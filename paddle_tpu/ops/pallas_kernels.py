@@ -123,20 +123,105 @@ def _visible(valid, qpos, kpos, causal, window):
     return valid
 
 
+def heads_a_block(hq, hkv, d):
+    """How many query heads one lane block of q as [B, T, Hq*D] holds, from
+    the shapes alone; None where a head cannot be indexed in place and the
+    arrays go through `_to_bh` first.
+
+    A head is a run of D lanes of a row. Where D is a multiple of 128 it is
+    whole lane blocks by itself (1), and a grouped key/value head is found
+    by the block's index. Where D divides 128 and the heads fill whole
+    blocks, 128 // D heads share one (2 at D=64); where all the heads
+    together are under 128 lanes the block is the whole row. The last two
+    need as many key/value heads as query heads: a block of K has to hold
+    the same heads at the same lanes as the block of Q it meets."""
+    if d % 128 == 0:
+        return 1
+    if hq == hkv and 128 % d == 0 and (hq * d) % 128 == 0:
+        return 128 // d
+    if hq == hkv and hq * d < 128:
+        return hq
+    return None
+
+
+# What these helpers and the index maps add to a kernel is written with lax
+# primitives, not jnp functions or operators on tracers: each of those is a
+# call of a jitted function, half a millisecond of a step's trace on the
+# chip's host, and a kernel's body and index maps are traced anew at every
+# call site (2,000 such calls more were 2 s of `jaxpr_trace_s` at T=2048,
+# 9 % of `setup_s`; my chip run, PR 38).
+
+def _head_lanes(w, d, hb):
+    """[1, W] mask of the D lanes of the head this grid step works on, of
+    the hb heads its W-lane blocks hold; None for one head a block."""
+    if hb == 1:
+        return None
+    lane = lax.broadcasted_iota(jnp.int32, (1, w), 1)
+    return lax.eq(lax.div(lane, lax.full_like(lane, d)),
+                  lax.broadcast(pl.program_id(3), (1, w)))
+
+
+def _select(lanes, x, other):
+    return lax.select(lax.broadcast_in_dim(lanes, x.shape, (0, 1)), x, other)
+
+
+def _only(lanes, x):
+    """x with every lane outside the head's set to zero. A dot that
+    contracts the W lanes of such a block with a whole block then adds
+    exact zeros for the other heads: the head's own D-deep product, bit for
+    bit, at the depth D < 128 pads to on the MXU anyway."""
+    return x if lanes is None else _select(lanes, x, lax.full_like(x, 0))
+
+
+def _put(lanes, ref, x):
+    """Write a [rows, W] result to the block `ref` holds: all of it, or the
+    head's lanes of it. The block keeps its index across the heads-in-block
+    grid axis, so it stays in VMEM until every head has put its lanes and
+    is written back once."""
+    ref[...] = x if lanes is None else _select(lanes, x, ref[...])
+
+
+def _as_row(col):
+    """[n, 1] -> [1, n] inside a kernel: the statistics of a q block are
+    columns where the arithmetic uses them ([bq, bk] tiles, keys in the
+    lanes) and rows in HBM (lane-dense). One XLU transpose of a 128-lane
+    broadcast, once a q block."""
+    n = col.shape[0]
+    wide = lax.broadcast_in_dim(col, (n, 128), (0, 1))
+    return lax.slice(lax.transpose(wide, (1, 0)), (0, 0), (1, n))
+
+
+def _as_col(row):
+    """[1, n] -> [n, 1], the inverse of `_as_row`."""
+    n = row.shape[1]
+    tall = lax.broadcast_in_dim(row, (128, n), (0, 1))
+    return lax.slice(lax.transpose(tall, (1, 0)), (0, 0), (n, 1))
+
+
+def _lin(*terms):
+    """sum(index * n) over (index, n) terms of an index map."""
+    total = None
+    for x, n in terms:
+        x = x if n == 1 else lax.mul(x, np.int32(n))
+        total = x if total is None else lax.add(total, x)
+    return total
+
+
 def _flash_fwd_kernel(q_ref, k_ref, v_ref, len_ref, o_ref, lse_ref, *,
-                      scale, causal, window, block_q, block_k, t_pad):
-    qb = pl.program_id(1)
-    q = q_ref[0]                                             # [bq, d]
-    bq, d = q.shape
+                      scale, causal, window, block_q, block_k, t_pad, d, hb):
+    qb = pl.program_id(2)
+    bq, w = q_ref.shape
+    lanes = _head_lanes(w, d, hb)
+    q = _only(lanes, q_ref[...])                             # [bq, W]
     qpos = qb * block_q + lax.broadcasted_iota(jnp.int32, (bq, 1), 0)
-    # whole [BH, 1] array lives in SMEM (a (1,1)-blocked spec violates
+    # whole [B, 1] array lives in SMEM (a (1,1)-blocked spec violates
     # Mosaic's (8,128) block rule — caught on first real-TPU run, round 4)
     kv_len = len_ref[pl.program_id(0), 0]                    # this row's T
 
     def body(kb, carry):
         m, l, acc = carry
-        k = k_ref[0, pl.ds(kb * block_k, block_k), :]
-        v = v_ref[0, pl.ds(kb * block_k, block_k), :]
+        k = k_ref[pl.ds(kb * block_k, block_k), :]
+        v = v_ref[pl.ds(kb * block_k, block_k), :]
         s = _dot(q, k, _NT) * scale                          # [bq, bk] f32
         kpos = kb * block_k + lax.broadcasted_iota(jnp.int32, (1, block_k),
                                                    1)
@@ -154,70 +239,97 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, len_ref, o_ref, lse_ref, *,
         *_k_blocks(qb, kv_len, causal, window, block_q, block_k, t_pad), body,
         (jnp.full((bq, 1), _NEG, jnp.float32),
          jnp.zeros((bq, 1), jnp.float32),
-         jnp.zeros((bq, d), jnp.float32)))
+         jnp.zeros((bq, w), jnp.float32)))
 
     l_safe = jnp.maximum(l, 1e-30)
-    o_ref[0] = (acc / l_safe).astype(o_ref.dtype)
-    lse_ref[0] = m + jnp.log(l_safe)                         # [bq, 1]
+    _put(lanes, o_ref, (acc / l_safe).astype(o_ref.dtype))
+    lse_ref[0, 0] = _as_row(m + jnp.log(l_safe))             # [1, bq]
 
 
-def _kv_row(bh_q, bh_kv):
-    """The index map's first coordinate of the K/V row a query row reads.
-    Rows are (batch, head) pairs, heads fastest, and query head h reads
-    key/value head h // group: row b of q reads row b // group of k and v,
-    whatever the batch. The grouped K/V are found here, by the index map,
-    and never repeated in HBM; with as many K/V heads as query heads the
-    map is the identity it always was."""
-    group = bh_q // bh_kv
-    return (lambda b: b) if group == 1 else (lambda b: b // group)
+def _kv_row(n_q, n_kv):
+    """The index map's coordinate of the K/V row (or head) a query row (or
+    head) reads: n_q query rows on n_kv of K and V, the group's members
+    next to each other, so query b reads b // group. The grouped K/V are
+    found here, by the index map, and never repeated in HBM; with as many
+    of one as of the other the map is the identity."""
+    group = n_q // n_kv
+    return (lambda b: b) if group == 1 \
+        else (lambda b: lax.div(b, np.int32(group)))
 
 
-def _flash_fwd(q, k, v, kv_len, scale, causal, window, block_q, block_k,
-               interpret):
-    """q: [BHq, T, D]; k, v: [BHkv, T, D]; kv_len: [BHq] int32 (true key
-    length per query row) -> (out [BHq, T, D], lse [BHq, T])."""
-    bh, t, d = q.shape
-    kv = _kv_row(bh, k.shape[0])
-    # pad T so BOTH the q grid and the k loop divide exactly (mismatched
-    # block sizes otherwise drop tail k blocks / leave q rows unwritten)
+def _pad_t(t, block_q, block_k):
+    """T padded so that BOTH the q grid and the k loop divide exactly
+    (mismatched block sizes otherwise drop tail k blocks / leave q rows
+    unwritten)."""
     blk = int(np.lcm(block_q, block_k))
-    t_pad = int(-(-t // blk) * blk)
+    return int(-(-t // blk) * blk)
+
+
+def _flash_fwd(q, k, v, kv_len, d, hb, scale, causal, window, block_q,
+               block_k, interpret):
+    """q: [Bq, T, Hq*D]; k, v: [Bk, T, Hkv*D], heads of D lanes side by
+    side in a row; kv_len: [Bq] int32 (true key length per query row); hb
+    query heads a lane block (`heads_a_block`) -> (out [Bq, T, Hq*D], lse
+    [Bq*Hq, nq, 1, block_q] over the padded T). The grid is (row, block of
+    heads, q block, head in its block); a head's blocks are W = hb * D
+    lanes wide at lane block `head // hb`, and K/V rows and heads may be
+    fewer than the queries' (`_kv_row`, both). The pallas_call takes the
+    arrays as [Bq * T, Hq*D], tokens by features, the shape the projections
+    around the op give and take: with [B, T, H*D] operands XLA laid the
+    neighbouring matmuls' operands out tokens-minor, and in the OLMoE cell,
+    at its memory limit, scheduled and rematerialised its way to a step 15
+    ms slower (my chip run and AOT compile, PR 38)."""
+    rows, t, hd = q.shape
+    heads, w = hd // d, hb * d
+    kv_b, kv_h = _kv_row(rows, k.shape[0]), _kv_row(heads, k.shape[2] // d)
+    t_pad = _pad_t(t, block_q, block_k)
     if t_pad != t:
         pad = [(0, 0), (0, t_pad - t), (0, 0)]
         q, k, v = (jnp.pad(a, pad) for a in (q, k, v))
-    lens = kv_len.reshape(bh, 1).astype(jnp.int32)
+    nq = t_pad // block_q
+    q, k, v = (a.reshape(-1, a.shape[2]) for a in (q, k, v))
+
+    def q_block(b, p, i, hh):
+        return _lin((b, nq), (i, 1)), p
+
+    def kv_pair(b, p, i, hh):        # the whole K or V of the head's row
+        return kv_b(b), kv_h(p)
+
     kernel = functools.partial(
         _flash_fwd_kernel, scale=scale, causal=causal, window=window,
-        block_q=block_q, block_k=block_k, t_pad=t_pad)
-    # lens: whole array in SMEM (no blocking); lse: [BH, T, 1] so the
-    # block's trailing dims are (block_q, 1) — Mosaic requires last-two
-    # block dims divisible by (8, 128) or equal to the array's
+        block_q=block_q, block_k=block_k, t_pad=t_pad, d=d, hb=hb)
+    # lens: whole array in SMEM (no blocking); lse: a row of block_q lanes
+    # a (head, q block): Mosaic requires the last two block dims divisible
+    # by (8, 128) or equal to the array's, and (1, block_q) is the array's
     out, lse = pl.pallas_call(
         kernel,
-        grid=(bh, t_pad // block_q),
+        grid=(rows, heads // hb, nq, hb),
         in_specs=[
-            _vmem_spec((1, block_q, d), lambda b, i: (b, i, 0)),
-            _vmem_spec((1, t_pad, d), lambda b, i: (kv(b), 0, 0)),
-            _vmem_spec((1, t_pad, d), lambda b, i: (kv(b), 0, 0)),
+            _vmem_spec((block_q, w), q_block),
+            _vmem_spec((t_pad, w), kv_pair),
+            _vmem_spec((t_pad, w), kv_pair),
             _SMEM_WHOLE,
         ],
         out_specs=[
-            _vmem_spec((1, block_q, d), lambda b, i: (b, i, 0)),
-            _vmem_spec((1, block_q, 1), lambda b, i: (b, i, 0)),
+            _vmem_spec((block_q, w), q_block),
+            _vmem_spec((1, 1, 1, block_q), lambda b, p, i, hh: (
+                _lin((b, heads), (p, hb), (hh, 1)), i, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bh, t_pad, d), q.dtype),
-            jax.ShapeDtypeStruct((bh, t_pad, 1), jnp.float32),
+            jax.ShapeDtypeStruct((rows * t_pad, hd), q.dtype),
+            jax.ShapeDtypeStruct((rows * heads, nq, 1, block_q),
+                                 jnp.float32),
         ],
         interpret=interpret,
         name="ptpu_flash_fwd",
-    )(q, k, v, lens)
-    return out[:, :t], lse[:, :t, 0]
+    )(q, k, v, kv_len.reshape(rows, 1).astype(jnp.int32))
+    out = out.reshape(rows, t_pad, hd)
+    return (out if t_pad == t else out[:, :t]), lse
 
 
 def _flash_bwd_dkdv_kernel(q_ref, g_ref, k_ref, v_ref, lse_ref, delta_ref,
                            len_ref, dk_ref, dv_ref, *, scale, causal,
-                           window, block_q, block_k, t_pad):
+                           window, block_q, block_k, t_pad, d, hb):
     """One k-block's dK/dV: stream q-blocks past it, starting at the
     causal frontier (q blocks strictly before this k block contribute
     nothing — the same 2x FLOP skip the forward kernel does) and, under a
@@ -227,12 +339,12 @@ def _flash_bwd_dkdv_kernel(q_ref, g_ref, k_ref, v_ref, lse_ref, delta_ref,
     p.T and ds.T then enter their dots as they are, with no transpose of a
     [bq, bk] tile a block (a fifth to a quarter of this kernel's time, my
     chip run, PR 27), and lse / delta are rows [1, bq] that broadcast down
-    the sublanes, so they arrive lane-dense as [nq, block_q] and not as
-    [t_pad, 1] columns of one lane in 128."""
-    kb = pl.program_id(1)
-    k = k_ref[0]                                             # [bk, d]
-    v = v_ref[0]
-    bk, d = k.shape
+    the sublanes, as they lie in HBM: [nq, 1, block_q] a head."""
+    kb = pl.program_id(2)
+    bk, w = k_ref.shape
+    lanes = _head_lanes(w, d, hb)
+    k = _only(lanes, k_ref[...])                             # [bk, W]
+    v = _only(lanes, v_ref[...])
     kpos = kb * block_k + lax.broadcasted_iota(jnp.int32, (bk, 1), 0)
     kv_len = len_ref[pl.program_id(0), 0]
     nq = t_pad // block_q
@@ -245,47 +357,69 @@ def _flash_bwd_dkdv_kernel(q_ref, g_ref, k_ref, v_ref, lse_ref, delta_ref,
         # the query window - 1 after it
         nq = jnp.minimum(nq, ((kb + 1) * block_k + window - 2) // block_q + 1)
 
-    def body(qb, carry):
-        dk, dv = carry
-        q = q_ref[0, pl.ds(qb * block_q, block_q), :]        # [bq, d]
-        g = g_ref[0, pl.ds(qb * block_q, block_q), :]
-        lse = lse_ref[0, pl.ds(qb, 1), :]                    # [1, bq] f32
-        delta = delta_ref[0, pl.ds(qb, 1), :]
+    def step(qb):
+        """This q block's terms of (dk, dv)."""
+        q = q_ref[pl.ds(qb * block_q, block_q), :]           # [bq, W]
+        g = g_ref[pl.ds(qb * block_q, block_q), :]
+        lse = lse_ref[0, qb]                                 # [1, bq] f32
+        delta = delta_ref[0, qb]
         valid = kpos < kv_len
         if causal or window is not None:
             qpos = qb * block_q + lax.broadcasted_iota(
                 jnp.int32, (1, block_q), 1)
             valid = _visible(valid, qpos, kpos, causal, window)
         p = jnp.where(valid, jnp.exp(_dot(k, q, _NT) * scale - lse), 0.0)
-        dv = dv + _dot(p.astype(g.dtype), g)                 # p [bk, bq]
         ds = p * (_dot(v, g, _NT) - delta)
-        dk = dk + _dot(ds.astype(q.dtype), q)
-        return dk, dv
+        return (_dot(ds.astype(q.dtype), q),
+                _dot(p.astype(g.dtype), g))                  # p [bk, bq]
 
-    dk, dv = lax.fori_loop(qb0, nq, body,
-                           (jnp.zeros((bk, d), jnp.float32),
-                            jnp.zeros((bk, d), jnp.float32)))
-    dk_ref[0] = (dk * scale).astype(dk_ref.dtype)
-    dv_ref[0] = dv.astype(dv_ref.dtype)
+    zeros = jnp.zeros((bk, w), jnp.float32)
+    if dk_ref.dtype == jnp.float32 and lanes is None:
+        # a group's float32 shares: the output blocks are the accumulators,
+        # and no [bk, W] float32 pair is carried beside them (2.3 MiB of
+        # VMEM at W=256: what T=4096 at D=256 does not have to spare)
+        dk_ref[...] = dv_ref[...] = zeros
+
+        def body(qb, carry):
+            dk, dv = step(qb)
+            dk_ref[...] += dk
+            dv_ref[...] += dv
+            return carry
+        lax.fori_loop(qb0, nq, body, 0)
+        dk_ref[...] *= scale
+    else:
+        def body(qb, carry):
+            dk, dv = step(qb)
+            return carry[0] + dk, carry[1] + dv
+        dk, dv = lax.fori_loop(qb0, nq, body, (zeros, zeros))
+        _put(lanes, dk_ref, (dk * scale).astype(dk_ref.dtype))
+        _put(lanes, dv_ref, dv.astype(dv_ref.dtype))
 
 
-def _flash_bwd_dq_kernel(q_ref, g_ref, k_ref, v_ref, lse_ref, delta_ref,
-                         len_ref, dq_ref, *, scale, causal, window,
-                         block_q, block_k, t_pad):
+def _flash_bwd_dq_kernel(q_ref, g_ref, o_ref, k_ref, v_ref, lse_ref,
+                         len_ref, dq_ref, delta_ref, *, scale, causal,
+                         window, block_q, block_k, t_pad, d, hb):
     """One q-block's dQ: stream the k-blocks between the window's edge and
-    the causal / key-length frontier (mirror of the forward loop)."""
-    qb = pl.program_id(1)
-    q = q_ref[0]                                             # [bq, d]
-    g = g_ref[0]
-    lse = lse_ref[0]                                         # [bq, 1] f32
-    delta = delta_ref[0]
-    bq, d = q.shape
+    the causal / key-length frontier (mirror of the forward loop). Before
+    the loop, delta = rowsum(dO * O) of the block's own rows, from the dO
+    block it holds anyway and the O block beside it: the column its tiles
+    want, and written out as a row for the dK/dV kernel, which runs after
+    this one."""
+    qb = pl.program_id(2)
+    bq, w = q_ref.shape
+    lanes = _head_lanes(w, d, hb)
+    q = _only(lanes, q_ref[...])                             # [bq, W]
+    g = _only(lanes, g_ref[...])
+    lse = _as_col(lse_ref[0, 0])                             # [bq, 1] f32
+    delta = jnp.sum(g.astype(jnp.float32) * o_ref[...].astype(jnp.float32),
+                    axis=-1, keepdims=True)
+    delta_ref[0, 0] = _as_row(delta)
     qpos = qb * block_q + lax.broadcasted_iota(jnp.int32, (bq, 1), 0)
     kv_len = len_ref[pl.program_id(0), 0]
 
     def body(kb, dq):
-        k = k_ref[0, pl.ds(kb * block_k, block_k), :]
-        v = v_ref[0, pl.ds(kb * block_k, block_k), :]
+        k = k_ref[pl.ds(kb * block_k, block_k), :]
+        v = v_ref[pl.ds(kb * block_k, block_k), :]
         kpos = kb * block_k + lax.broadcasted_iota(
             jnp.int32, (1, block_k), 1)
         valid = _visible(kpos < kv_len, qpos, kpos, causal, window)
@@ -295,56 +429,79 @@ def _flash_bwd_dq_kernel(q_ref, g_ref, k_ref, v_ref, lse_ref, delta_ref,
 
     dq = lax.fori_loop(
         *_k_blocks(qb, kv_len, causal, window, block_q, block_k, t_pad), body,
-        jnp.zeros((bq, d), jnp.float32))
-    dq_ref[0] = (dq * scale).astype(dq_ref.dtype)
+        jnp.zeros((bq, w), jnp.float32))
+    _put(lanes, dq_ref, (dq * scale).astype(dq_ref.dtype))
 
 
-def _flash_bwd(scale, causal, window, block_q, block_k, interpret, res, g):
-    """Flash backward as two pallas kernels (standard flash-attention
-    recompute from the saved logsumexp — the [T, T] matrix never exists):
-    a dK/dV kernel gridded over k-blocks and a dQ kernel gridded over
-    q-blocks, both with causal block skipping. Their dots run in the dtype
-    of q, k, v and dO (bf16 under AMP, float32 for float32 inputs) with
-    float32 accumulation; p is recomputed in float32 from the float32 lse,
-    and p and ds are cast to that dtype where they enter a dot.
+def _flash_bwd(d, hb, scale, causal, window, block_q, block_k, interpret,
+               res, g):
+    """Flash backward as two pallas kernels (standard flash-attention recompute
+    from the saved logsumexp — the [T, T] matrix never exists): a dK/dV
+    kernel gridded over k-blocks and a dQ kernel gridded over q-blocks,
+    both with causal block skipping. Their dots run in the dtype of q, k, v
+    and dO (bf16 under AMP, float32 for float32 inputs) with float32
+    accumulation; p is recomputed in float32 from the float32 lse, and p
+    and ds are cast to that dtype where they enter a dot.
+
+    Operands are as `_flash_fwd`'s: q, dO and O [Bq, T, Hq*D], k, v [Bk, T,
+    Hkv*D] (tokens by features in the calls), lse [Bq*Hq, nq, 1, block_q];
+    a head's blocks are W = hb * D lanes wide. The dQ kernel runs first: it
+    makes delta = rowsum(dO * O) of its q block from the dO block it holds
+    and the O block beside it, and writes it as a row, shaped as lse, for
+    the dK/dV kernel. With two heads a block (D=64) the head in its block
+    is the innermost grid axis: the q (or k) block, the pinned pair and the
+    output block keep their index across its steps, so each is fetched once
+    and the output written back once; a step masks the other head's lanes
+    out of the two operands it loads once a block (`_only`) and selects its
+    own lanes of the result (`_put`).
 
     VMEM budget, at the benchmark's shapes and the default 512 x 512 blocks
-    (16 MiB scoped a core on the v5e). Each kernel pins one full [t_pad, d]
-    operand pair a grid step (q+dO for dK/dV, k+v for dQ); in VMEM d=64
-    pads to the 128 lanes of a tile, so a bf16 operand is t_pad * 256 B:
-    0.5 MiB at T=2048 (D=64), 1 MiB at T=4096 (D=128), 2 and 4 MiB for the
+    (16 MiB scoped a core on the v5e). Each kernel pins one full [t_pad, W]
+    operand pair a grid step (q+dO for dK/dV, k+v for dQ), W = 128 at D=64
+    and D above: a bf16 operand is t_pad * 256 B at W=128: 0.5 MiB at
+    T=2048 (D=64, two heads), 1 MiB at T=4096 (D=128), 2 and 4 MiB for the
     pair double-buffered. The [bq, bk] float32 tiles of a block step (s, p,
     dp, ds, and the bf16 copies of p and ds) are 1 MiB each at 512 x 512,
-    about 5 MiB live. lse and delta: the dQ kernel takes them as (block_q,
-    1) float32 blocks, one lane in 128, 256 KiB each (1 MiB for the two,
-    double-buffered); the dK/dV kernel needs the whole row and takes it
-    lane-dense as [nq, block_q] (16 KiB each at T=4096), where [t_pad, 1]
-    blocks were 2 MiB a buffer and 8 MiB for the two double-buffered at
-    T=4096. Mosaic takes every pair of {128, 256, 512} x {128, 256, 512,
-    1024} at both shapes and 1024 x 512; it refuses 1024 x 1024 at D=128
-    (my chip run, PR 27). The pinned pair sets the longest sequence. At
-    T=8192, D=128 a bf16 operand is 2 MiB and the pair double-buffered 8
-    MiB, 13 MiB with the tiles: all three kernels compile at 512 x 512 and
-    run there (7 query heads on 1 key/value head: forward 1.40 ms, forward
-    and backward 4.72 ms a layer full, 1.19 and 3.89 ms under a window of
-    4096; my chip run, PR 31). T=16384 compiles at no block size (AOT
-    compile, PR 27). Streaming the pair through a second grid axis is the
-    follow-up; ring/Ulysses SP is the intended path for those lengths
+    about 5 MiB live. lse and delta are rows of block_q lanes everywhere:
+    the forward writes lse, and dQ reads lse and writes delta, one (1,
+    block_q) block a step, and the column their [bq, bk] tiles want is
+    turned to or from that row inside the kernel (`_as_row`, `_as_col`);
+    the dK/dV kernel needs a head's whole rows and takes [nq, 1, block_q]
+    (16 KiB each at T=4096). As (block_q, 1) columns of [B*H, T, 1] they
+    were one lane in 128: 256 KiB a block in VMEM and 64 MiB an array in
+    HBM at T=2048. Mosaic takes every pair of {128, 256, 512} x {128, 256,
+    512, 1024} at both shapes and 1024 x 512; it refuses 1024 x 1024 at
+    D=128 (my chip run, PR 27). The pinned pair sets the longest sequence.
+    At T=4096, D=256 with float32 dK and dV blocks (grouped queries) the
+    dK/dV kernel needed 16.8 MiB with a [bk, W] float32 pair carried beside
+    the blocks, and 14.5 with the blocks as their own accumulators (AOT
+    compile, PR 38; the [BH, T, D] form needed the same 16.8 and compiled
+    where XLA happened to hold an operand in VMEM already). At T=8192,
+    D=128 a bf16 operand is 2 MiB and the pair double-buffered 8 MiB, 13
+    MiB with the tiles: all three kernels compile at 512 x 512 and run
+    there (7 query heads on 1 key/value head: forward 1.40 ms, forward and
+    backward 4.72 ms a layer full, 1.19 and 3.89 ms under a window of 4096;
+    my chip run, PR 31). T=16384 compiles at no block size (AOT compile, PR
+    27). Streaming the pair through a second grid axis is the follow-up;
+    ring/Ulysses SP is the intended path for those lengths
     (parallel/ring_attention.py).
 
-    Grouped queries (q with group x as many rows as k and v): the index
-    maps send query row b to K/V row b // group (`_kv_row`), so the pinned
-    pair of the forward and dQ kernels is fetched once a group and not once
-    a head, and nothing is repeated in HBM. dK/dV of a key/value head is
-    the sum over its group, taken AFTER the kernel: it runs a query head at
-    a time, as ungrouped, writes that head's share in float32 and XLA sums
-    the group. Summing inside the kernel would put the group on a third,
-    innermost grid axis and re-fetch the pinned q and dO pair (4 MiB) at
-    every step of it, 16 k blocks x 7 heads = 448 MiB a layer at T=8192
-    against the 59 MiB of float32 shares the sum reads; it would also give
-    the ungrouped kernel a scratch accumulator it does not have today. The
-    sum costs 0.4 ms a layer (4.72 ms against 4.31 for 7 heads on 7; my
-    chip run, PR 31); the inside variant was not built, so not measured.
+    Grouped queries (q with group x as many heads as k and v): the index
+    maps send query head h to K/V head h // group (`_kv_row`), so the
+    pinned pair of the forward and dQ kernels is fetched once a group and
+    not once a head, and nothing is repeated in HBM. dK/dV of a key/value
+    head is the sum over its group, taken AFTER the kernel: it runs a query
+    head at a time, as ungrouped, writes that head's share in float32, in
+    the slab of the head's place in its group ([group * T, Hkv*D]: summed
+    over the leading axis the shares need no relayout; as lanes of one [T,
+    Hq*D] array XLA transposed all of them first), and XLA sums the group.
+    Summing inside the kernel would put the group on a further, innermost
+    grid axis and re-fetch the pinned q and dO pair (4 MiB) at every step
+    of it, 16 k blocks x 7 heads = 448 MiB a layer at T=8192 against the 59
+    MiB of float32 shares the sum reads; it would also give the ungrouped
+    kernel a scratch accumulator it does not have today. The sum costs 0.4
+    ms a layer (4.72 ms against 4.31 for 7 heads on 7; my chip run, PR 31);
+    the inside variant was not built, so not measured.
 
     Under a window the forward and dQ loops start at the first k block the
     q block's first query can see and the dK/dV loop ends at the last q
@@ -352,97 +509,123 @@ def _flash_bwd(scale, causal, window, block_q, block_k, interpret, res, g):
     that is computed is masked as before (`_visible`), the edges' and the
     interior's alike, so `window=None` compiles to the kernels it always
     did."""
-    q, k, v, kv_len, delta, lse = res
-    bh, t, d = q.shape
-    bh_kv = k.shape[0]
-    kv = _kv_row(bh, bh_kv)
-    blk = int(np.lcm(block_q, block_k))
-    t_pad = int(-(-t // blk) * blk)
+    q, k, v, kv_len, out, lse = res
+    rows, t, hd = q.shape
+    rows_kv, heads, heads_kv = k.shape[0], hd // d, k.shape[2] // d
+    w = hb * d
+    kv_b, kv_h = _kv_row(rows, rows_kv), _kv_row(heads, heads_kv)
+    t_pad = _pad_t(t, block_q, block_k)
     if t_pad != t:
-        pad3 = [(0, 0), (0, t_pad - t), (0, 0)]
-        q, k, v, g = (jnp.pad(a, pad3) for a in (q, k, v, g))
-        lse = jnp.pad(lse, [(0, 0), (0, t_pad - t)])
-        delta = jnp.pad(delta, [(0, 0), (0, t_pad - t)])
-    lse, delta = lse.astype(jnp.float32), delta.astype(jnp.float32)
-    nq = t_pad // block_q
-    lens = kv_len.reshape(bh, 1).astype(jnp.int32)
+        pad = [(0, 0), (0, t_pad - t), (0, 0)]
+        q, k, v, g, out = (jnp.pad(a, pad) for a in (q, k, v, g, out))
+    nq, nk = t_pad // block_q, t_pad // block_k
+    q, k, v, g, out = (a.reshape(-1, a.shape[2]) for a in (q, k, v, g, out))
+    lens = kv_len.reshape(rows, 1).astype(jnp.int32)
+    grouped = rows * heads != rows_kv * heads_kv
     # grouped queries: the dK/dV kernel runs a query head at a time, as it
     # does ungrouped, and gives that head's float32 share of its K/V head's
-    # gradient; the group's shares are summed after it (see _flash_bwd's
-    # docstring for why after and not inside)
-    dkv_dtype = k.dtype if bh == bh_kv else jnp.float32
+    # gradient; the group's shares are summed after it (see the docstring
+    # for why after and not inside)
+    dkv_dtype = jnp.float32 if grouped else k.dtype
+    static = dict(scale=scale, causal=causal, window=window,
+                  block_q=block_q, block_k=block_k, t_pad=t_pad, d=d, hb=hb)
 
-    dk, dv = pl.pallas_call(
-        functools.partial(_flash_bwd_dkdv_kernel, scale=scale,
-                          causal=causal, window=window, block_q=block_q,
-                          block_k=block_k, t_pad=t_pad),
-        grid=(bh, t_pad // block_k),
+    def stat_block(b, p, i, hh):     # a head's (1, block_q) of q block i
+        return _lin((b, heads), (p, hb), (hh, 1)), i, 0, 0
+
+    def q_block(b, p, i, hh):
+        return _lin((b, nq), (i, 1)), p
+
+    def kv_pair(b, p, i, hh):        # the whole K or V of the head's row
+        return kv_b(b), kv_h(p)
+
+    dq, delta = pl.pallas_call(
+        functools.partial(_flash_bwd_dq_kernel, **static),
+        grid=(rows, heads // hb, nq, hb),
         in_specs=[
-            _vmem_spec((1, t_pad, d), lambda b, j: (b, 0, 0)),     # q
-            _vmem_spec((1, t_pad, d), lambda b, j: (b, 0, 0)),     # g
-            _vmem_spec((1, block_k, d), lambda b, j: (kv(b), j, 0)),   # k
-            _vmem_spec((1, block_k, d), lambda b, j: (kv(b), j, 0)),   # v
-            _vmem_spec((1, nq, block_q), lambda b, j: (b, 0, 0)),  # lse
-            _vmem_spec((1, nq, block_q), lambda b, j: (b, 0, 0)),  # delta
+            _vmem_spec((block_q, w), q_block),                         # q
+            _vmem_spec((block_q, w), q_block),                         # g
+            _vmem_spec((block_q, w), q_block),                         # o
+            _vmem_spec((t_pad, w), kv_pair),                           # k
+            _vmem_spec((t_pad, w), kv_pair),                           # v
+            _vmem_spec((1, 1, 1, block_q), stat_block),                # lse
             _SMEM_WHOLE,
         ],
         out_specs=[
-            _vmem_spec((1, block_k, d), lambda b, j: (b, j, 0)),
-            _vmem_spec((1, block_k, d), lambda b, j: (b, j, 0)),
+            _vmem_spec((block_q, w), q_block),
+            _vmem_spec((1, 1, 1, block_q), stat_block),              # delta
         ],
-        out_shape=[
-            jax.ShapeDtypeStruct((bh, t_pad, d), dkv_dtype),
-            jax.ShapeDtypeStruct((bh, t_pad, d), dkv_dtype),
-        ],
-        interpret=interpret,
-        name="ptpu_flash_bwd_dkdv",
-    )(q, g, k, v, lse.reshape(bh, nq, block_q),
-      delta.reshape(bh, nq, block_q), lens)
-    if bh != bh_kv:
-        dk, dv = (a.reshape(bh_kv, bh // bh_kv, t_pad, d).sum(1)
-                  .astype(k.dtype) for a in (dk, dv))
-
-    dq = pl.pallas_call(
-        functools.partial(_flash_bwd_dq_kernel, scale=scale, causal=causal,
-                          window=window, block_q=block_q, block_k=block_k,
-                          t_pad=t_pad),
-        grid=(bh, t_pad // block_q),
-        in_specs=[
-            _vmem_spec((1, block_q, d), lambda b, i: (b, i, 0)),   # q
-            _vmem_spec((1, block_q, d), lambda b, i: (b, i, 0)),   # g
-            _vmem_spec((1, t_pad, d), lambda b, i: (kv(b), 0, 0)),  # k
-            _vmem_spec((1, t_pad, d), lambda b, i: (kv(b), 0, 0)),  # v
-            _vmem_spec((1, block_q, 1), lambda b, i: (b, i, 0)),   # lse
-            _vmem_spec((1, block_q, 1), lambda b, i: (b, i, 0)),   # delta
-            _SMEM_WHOLE,
-        ],
-        out_specs=_vmem_spec((1, block_q, d), lambda b, i: (b, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((bh, t_pad, d), q.dtype),
+        out_shape=[jax.ShapeDtypeStruct((rows * t_pad, hd), q.dtype),
+                   jax.ShapeDtypeStruct(lse.shape, jnp.float32)],
         interpret=interpret,
         name="ptpu_flash_bwd_dq",
-    )(q, g, k, v, lse[..., None], delta[..., None], lens)
-    return dq[:, :t], dk[:, :t], dv[:, :t]
+    )(q, g, out, k, v, lse, lens)
+
+    def stat_row(b, p, j, hh):       # a head's whole [nq, 1, block_q]
+        return _lin((b, heads), (p, hb), (hh, 1)), 0, 0, 0
+
+    # a head's dK and dV blocks lie where its K and V blocks do, in the slab
+    # of its place in its group: [rows * group * T, Hkv*D], which is [rows *
+    # T, Hq*D] where there is no group, and a sum over slabs where there is
+    group = heads // heads_kv
+
+    def k_block(b, p, j, hh):
+        return _lin((kv_b(b), nk), (j, 1)), kv_h(p)
+
+    def share(b, p, j, hh):
+        if group == 1:
+            return _lin((b, nk), (j, 1)), p
+        member = lax.rem(p, np.int32(group))
+        return _lin((b, group * nk), (member, nk), (j, 1)), kv_h(p)
+
+    dk, dv = pl.pallas_call(
+        functools.partial(_flash_bwd_dkdv_kernel, **static),
+        grid=(rows, heads // hb, t_pad // block_k, hb),
+        in_specs=[
+            _vmem_spec((t_pad, w), lambda b, p, j, hh: (b, p)),         # q
+            _vmem_spec((t_pad, w), lambda b, p, j, hh: (b, p)),         # g
+            _vmem_spec((block_k, w), k_block),                         # k
+            _vmem_spec((block_k, w), k_block),                         # v
+            _vmem_spec((1, nq, 1, block_q), stat_row),                # lse
+            _vmem_spec((1, nq, 1, block_q), stat_row),              # delta
+            _SMEM_WHOLE,
+        ],
+        out_specs=[_vmem_spec((block_k, w), share)] * 2,
+        out_shape=[jax.ShapeDtypeStruct(
+            (rows * group * t_pad, heads_kv * d), dkv_dtype)] * 2,
+        interpret=interpret,
+        name="ptpu_flash_bwd_dkdv",
+    )(q, g, k, v, lse, delta, lens)
+    dq = dq.reshape(rows, t_pad, hd)
+    if grouped:
+        dk, dv = (a.reshape(rows_kv, -1, t_pad, heads_kv * d).sum(1)
+                  .astype(k.dtype) for a in (dk, dv))
+    else:
+        dk, dv = (a.reshape(rows_kv, t_pad, -1) for a in (dk, dv))
+    if t_pad != t:
+        dq, dk, dv = dq[:, :t], dk[:, :t], dv[:, :t]
+    return dq, dk, dv
 
 
 def _wait_for(g, *residuals):
     """(g, *residuals) behind one optimization barrier, for a backward rule
     whose linearization the forward op kept (core/lowering.py). What a
     backward rule first does to its residuals depends on nothing the
-    backward pass computes: a layout change to the kernels' [B*H, T, D],
-    which pads D=64 to the 128 lanes of a tile (32 MiB for 16), or a
-    [N] -> [N, 1] reshape, which pads one lane to 128 (64 MiB for the
-    logsumexp of one flash call, 8 MiB for layer norm's mean). Left alone,
-    XLA merges each with its inverse in the forward rule or runs it as soon
-    as the residual exists, and holds the padded array from the forward to
-    the backward pass: +1.4 GiB (flash) and +0.5 GiB (layer norm) on the
-    T=2048 transformer step (AOT compile for a v5e, PR 25). Behind the
-    barrier the residuals are held as the forward rule saved them until the
-    cotangent `g` exists."""
+    backward pass computes: a [N] -> [N, 1] reshape, which pads one lane to
+    128 (8 MiB for layer norm's mean). Left alone, XLA merges each with its
+    inverse in the forward rule or runs it as soon as the residual exists,
+    and holds the padded array from the forward to the backward pass: +0.5
+    GiB (layer norm) on the T=2048 transformer step (AOT compile for a v5e,
+    PR 25). Behind the barrier the residuals are held as the forward rule
+    saved them until the cotangent `g` exists."""
     return lax.optimization_barrier((g,) + residuals)
 
 
 def _to_bh(x):
-    """[B, T, H, D] as the op has it -> the kernels' [B*H, T, D]."""
+    """[B, T, H, D] -> [B*H, T, D]: every head a row of its own, for the
+    shapes whose heads cannot be indexed in place (`heads_a_block` None:
+    grouped queries at a D that is no multiple of 128, and heads of a D
+    that neither divides 128 nor fills whole blocks)."""
     b, t, h, d = x.shape
     return jnp.transpose(x, (0, 2, 1, 3)).reshape(b * h, t, d)
 
@@ -452,10 +635,32 @@ def _from_bh(x, b):
     return jnp.transpose(x.reshape(b, bh // b, t, d), (0, 2, 1, 3))
 
 
+def _rows(x, hb):
+    """An operand of the op, [B, T, H, D], as the kernels take it: [B, T,
+    H*D], a reshape; [B*H, T, D] where its heads cannot be indexed in
+    place."""
+    return _to_bh(x) if hb is None else x.reshape(x.shape[:2] + (-1,))
+
+
+def _unrows(x, like, hb):
+    """`_rows`'s inverse, to the shape of the op's operand `like`."""
+    return _from_bh(x, like.shape[0]) if hb is None else x.reshape(like.shape)
+
+
 # q, k, v and the output are [B, T, H, D] on both sides of the custom_vjp
-# boundary, and the two layout changes are inside it: the residuals are the
-# op's own inputs and output (live anyway for the ops around it) plus the
-# logsumexp, and _wait_for keeps them in that form up to the backward pass.
+# boundary. The kernels take them as [B*T, H*D], a reshape of what the op
+# has (both model builders make that very array by a matmul and reshape it
+# to heads): a head's blocks are indexed in place, W = D lanes wide where D
+# is a multiple of 128 and 128 wide with two heads a block at D=64
+# (`heads_a_block`), and no XLA pass stands between the op's operands and
+# the pallas_calls, forward or backward. The residuals are the op's own
+# inputs and output (live anyway for the ops around it) plus the logsumexp
+# as [B*H, nq, 1, block_q] rows, lane-dense as the kernels write and read
+# it; delta = rowsum(dO * O) is the dQ kernel's, into rows of the same shape
+# (as an XLA reduction its [B, T, H] result has the heads in the lanes, and
+# XLA ran it tokens-minor behind three float32 relayouts of dO and O, 220
+# MiB a call at [8, 2048, 8, 64]; AOT compile, PR 38). No barrier holds the
+# residuals back: the backward rule's first touch of them is a reshape.
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9))
 def _flash_core(q, k, v, kv_len, scale, causal, window, block_q, block_k,
                 interpret):
@@ -463,27 +668,32 @@ def _flash_core(q, k, v, kv_len, scale, causal, window, block_q, block_k,
                            block_k, interpret)[0]
 
 
+def _flash_layout(q, k, kv_len):
+    """(heads a block, or None for the kernels' rows a head; the key
+    lengths a kernel row)."""
+    hb = heads_a_block(q.shape[2], k.shape[2], q.shape[3])
+    return hb, kv_len if hb else jnp.repeat(kv_len, q.shape[2])
+
+
 def _flash_core_fwd(q, k, v, kv_len, scale, causal, window, block_q, block_k,
                     interpret):
-    out, lse = _flash_fwd(_to_bh(q), _to_bh(k), _to_bh(v), kv_len, scale,
-                          causal, window, block_q, block_k, interpret)
-    out = _from_bh(out, q.shape[0])
+    hb, lens = _flash_layout(q, k, kv_len)
+    out, lse = _flash_fwd(_rows(q, hb), _rows(k, hb), _rows(v, hb), lens,
+                          q.shape[3], hb or 1, scale, causal, window,
+                          block_q, block_k, interpret)
+    out = _unrows(out, q, hb)
     return out, (q, k, v, kv_len, out, lse)
 
 
 def _flash_core_bwd(scale, causal, window, block_q, block_k, interpret, res,
                     g):
     q, k, v, kv_len, out, lse = res
-    g, q, k, v, out, lse = _wait_for(g, q, k, v, out, lse)
-    b, t, h, _ = q.shape
-    # rowsum(dO * O) where the output lives, so that the backward asks for
-    # no second copy of it in the kernels' layout
-    delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32),
-                    axis=-1).transpose(0, 2, 1).reshape(b * h, t)
+    hb, lens = _flash_layout(q, k, kv_len)
     dq, dk, dv = _flash_bwd(
-        scale, causal, window, block_q, block_k, interpret,
-        (_to_bh(q), _to_bh(k), _to_bh(v), kv_len, delta, lse), _to_bh(g))
-    return _from_bh(dq, b), _from_bh(dk, b), _from_bh(dv, b), None
+        q.shape[3], hb or 1, scale, causal, window, block_q, block_k,
+        interpret, (_rows(q, hb), _rows(k, hb), _rows(v, hb), lens,
+                    _rows(out, hb), lse), _rows(g, hb))
+    return _unrows(dq, q, hb), _unrows(dk, k, hb), _unrows(dv, v, hb), None
 
 
 _flash_core.defvjp(_flash_core_fwd, _flash_core_bwd)
@@ -544,9 +754,9 @@ def flash_attention(q, k, v, causal=False, scale=None, kv_len=None,
     block_k = max(8, min(_tile("attn", "block_k", block_k),
                          int(-(-t // 8) * 8)))
     if kv_len is None:
-        lens = jnp.full((b * h,), t, jnp.int32)
+        lens = jnp.full((b,), t, jnp.int32)
     else:
-        lens = jnp.repeat(jnp.asarray(kv_len, jnp.int32).reshape(b), h)
+        lens = jnp.asarray(kv_len, jnp.int32).reshape(b)
     return _flash_core(q, k, v, lens, float(scale), bool(causal),
                        None if window is None else int(window),
                        int(block_q), int(block_k), bool(interpret))

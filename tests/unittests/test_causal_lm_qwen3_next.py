@@ -67,7 +67,7 @@ def _counts():
                         path=GROUPED_MATMUL, rows="held"),
         "full": _counter("ptpu_attention_layers_total", kind="full",
                          window="0", q_heads="4", kv_heads="2", path="dense",
-                         head_dim="16"),
+                         head_dim="16", heads_a_block="none"),
         "delta": _counter("ptpu_linear_attention_layers_total",
                           kind="gated_delta", k_heads="2", v_heads="4",
                           d_k="8", d_v="8",

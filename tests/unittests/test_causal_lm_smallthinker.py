@@ -66,10 +66,10 @@ def _counts():
                         rows="held"),
         "full": _counter("ptpu_attention_layers_total", kind="full",
                          window="0", q_heads="4", kv_heads="2", path="dense",
-                         head_dim="8"),
+                         head_dim="8", heads_a_block="none"),
         "window": _counter("ptpu_attention_layers_total", kind="window",
                            window="16", q_heads="4", kv_heads="2",
-                           path="dense", head_dim="8")}
+                           path="dense", head_dim="8", heads_a_block="none")}
 
 
 def _run_program(amp):

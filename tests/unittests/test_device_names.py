@@ -772,3 +772,133 @@ def test_causal_conv_kernels_on_a_described_v5e(one_chip, monkeypatch, batch,
     moved = re.findall(r"= \w+%s\S* (copy|transpose|pad|convert)\(" % whole,
                        text[text.index("ENTRY"):])
     assert moved == []
+
+
+# --- the flash kernels, heads indexed in place (PR 38) -----------------------
+
+# a cell's flash shape: (B, T, Hq, Hkv, D), the window, heads a lane block
+_FLASH_CELLS = {
+    "transformer_t2048": ((8, 2048, 8, 8, 64), None, "2"),
+    "olmoe_t4096": ((4, 4096, 16, 16, 128), None, "1"),
+    "ouro_t4096": ((1, 4096, 16, 16, 128), None, "1"),
+    "smallthinker_t8192": ((1, 8192, 7, 1, 128), 4096, "1"),
+    "qwen3_next_t4096": ((1, 4096, 16, 2, 256), None, "1"),
+}
+
+
+def _attention_counts():
+    """{heads_a_block: flash layers counted so far}."""
+    from paddle_tpu.observability.registry import REGISTRY
+    counts = {}
+    for key, n in REGISTRY.counter("ptpu_attention_layers_total",
+                                   "").samples():
+        labels = dict(key)
+        if labels["path"] == "flash":
+            counts[labels["heads_a_block"]] = counts.get(
+                labels["heads_a_block"], 0) + n
+    return counts
+
+
+def _counted_since(before):
+    after = _attention_counts()
+    return {key: after[key] - before.get(key, 0) for key in after
+            if after[key] != before.get(key, 0)}
+
+
+@pytest.mark.parametrize("cell", sorted(_FLASH_CELLS))
+def test_flash_kernels_index_heads_in_place_on_a_described_v5e(
+        one_chip, monkeypatch, cell):
+    """fluid.layers.fused_attention and its grad op at a cell's shape,
+    lowered by build_program_fn and compiled for a TPU: Mosaic takes the
+    three kernels with a head's blocks indexed in [B, T, H*D] (the XLU
+    transposes that turn the statistics' rows into columns, the lane masks
+    of two heads a block, one buffer for the pinned pair where two do not
+    fit: Qwen3-Next's dK/dV), the counter says how many heads a block, and
+    no transpose or pad of an operand is left in the compiled step."""
+    from paddle_tpu.ops import kernel_config
+    monkeypatch.delenv("PADDLE_TPU_PALLAS", raising=False)
+    monkeypatch.delenv("FLAGS_flash_min_seq", raising=False)
+    monkeypatch.setattr(kernel_config, "dispatch_platform", lambda: "tpu")
+    monkeypatch.setattr(pallas_kernels, "dispatch_platform", lambda: "tpu")
+    (b, t, h, hkv, d), window, heads = _FLASH_CELLS[cell]
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.unique_name.guard(), fluid.program_guard(main, startup):
+        main.enable_mixed_precision()
+        q, g = (fluid.layers.data(name=n, shape=[t, h * d], dtype="float32")
+                for n in "qg")
+        k, v = (fluid.layers.data(name=n, shape=[t, hkv * d],
+                                  dtype="float32") for n in "kv")
+        for var in (q, k, v):
+            var.stop_gradient = False
+        # heads by a reshape of a [B, T, H*D] stream, as both builders do
+        out = fluid.layers.fused_attention(
+            *(fluid.layers.reshape(x, shape=[0, -1, n, d])
+              for x, n in ((q, h), (k, hkv), (v, hkv))),
+            causal=True, window=window)
+        out = fluid.layers.reshape(out, shape=[0, -1, h * d])
+        fluid.backward.append_backward(fluid.layers.reduce_sum(out * g))
+    names = ["q", "k", "v", "g"]
+    fetch = [out.name] + [n + "@GRAD" for n in "qkv"]
+    rw, ro, outs = lowering.analyze_state(main, names, fetch)
+    fn = lowering.build_program_fn(main, names, fetch, rw, ro, outs)
+    args = [jax.ShapeDtypeStruct((b, t, (h if n in "qg" else hkv) * d),
+                                 jnp.bfloat16, sharding=one_chip)
+            for n in names]
+    before = _attention_counts()
+    text = _compile_uncached(lambda *a: fn(list(a), [], [], 0),
+                             *args).as_text()
+    assert _counted_since(before) == {heads: 1}
+    kernels = sorted(re.sub(r"\.\d+$", "", name) for name in re.findall(
+        r'%?([\w.\-]+) = [^\n]*custom_call_target="tpu_custom_call"', text))
+    assert kernels == ["ptpu_flash_bwd_dkdv", "ptpu_flash_bwd_dq",
+                       "ptpu_flash_fwd"]
+    big = b * t * hkv * d           # the smallest of the op's operands
+    for shape in re.findall(r"= \w+\[([\d,]+)\]\S* (?:transpose|pad)\(",
+                            text):
+        assert np.prod([int(n) for n in shape.split(",")]) < big, shape
+
+
+@pytest.mark.parametrize("model,heads", [("transformer", "2"),
+                                         ("causal_lm", "1")])
+def test_the_attention_counter_says_how_many_heads_a_block(monkeypatch,
+                                                           model, heads):
+    """Lowering the two model builders at the cells' head shapes (8 heads
+    of 64: the transformer; 16 of 128: OLMoE and Ouro) counts every flash
+    layer under heads_a_block "2" and "1", by the function the kernels'
+    wrapper asks (pallas_kernels.heads_a_block)."""
+    from paddle_tpu.models import causal_lm, transformer
+    monkeypatch.setenv("PADDLE_TPU_PALLAS", "1")    # interpreted, off a TPU
+    monkeypatch.setenv("FLAGS_flash_min_seq", "0")
+    t = 16
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.unique_name.guard(), fluid.program_guard(main, startup):
+        if model == "transformer":
+            transformer.transformer(
+                64, 64, t, n_layer=1, n_head=8, d_key=64, d_value=64,
+                d_model=512, d_inner_hid=64, dropout_rate=0.0,
+                use_fused_attention=True)
+            layers = 3              # encoder, decoder self and cross
+        else:
+            causal_lm.causal_lm(dict(
+                hidden_size=2048, head_dim=128, num_attention_heads=16,
+                num_key_value_heads=16, num_hidden_layers=2, vocab_size=64,
+                intermediate_size=64, rms_norm_eps=1e-6, rope_theta=1e4,
+                tie_word_embeddings=False, hidden_act="silu"), t)
+            layers = 2
+    feeds = [v.name for v in main.global_block().vars.values()
+             if getattr(v, "is_data", False)]
+    s_rw, s_ro, s_out = lowering.analyze_state(startup, [])
+    state = dict(zip(s_out, jax.eval_shape(
+        lambda: lowering.build_program_fn(startup, [], [], s_rw, s_ro, s_out)(
+            [], [], [], np.uint32(0)))[1]))
+    rw, ro, out = lowering.analyze_state(main, feeds, [])
+    fn = lowering.build_program_fn(main, feeds, [], rw, ro, out)
+    block = main.global_block()
+    shapes = [jax.ShapeDtypeStruct(
+        (2,) + tuple(block.var(n).shape[1:]),
+        np.dtype(block.var(n).dtype) if np.dtype(block.var(n).dtype)
+        != np.int64 else np.int32) for n in feeds]
+    before = _attention_counts()
+    jax.eval_shape(fn, shapes, [state[n] for n in rw],
+                   [state[n] for n in ro], np.uint32(0))
+    assert _counted_since(before) == {heads: layers}

@@ -123,3 +123,109 @@ def test_shapes_that_are_no_grouping_are_refused():
         attention_reference(q, k, v)
     with pytest.raises(ValueError, match="window"):
         flash_attention(q, q, q, window=0, interpret=True)
+
+
+# --- heads indexed in place: [B, T, H*D], so many heads a lane block ---------
+
+# id: (T, Hq, Hkv, D, kwargs, heads a block); blocks of 16 x 16, so 40 is no
+# multiple of the block
+_IN_PLACE = {
+    "d64_two_a_block_causal": (32, 8, 8, 64, dict(causal=True), 2),
+    "d64_two_a_block_full": (32, 8, 8, 64, dict(), 2),
+    "d64_two_a_block_ragged_keys": (40, 8, 8, 64, dict(kv_len=[40, 23]), 2),
+    "d128_ungrouped": (32, 2, 2, 128, dict(causal=True), 1),
+    "d128_7_on_1_window": (48, 7, 1, 128, dict(causal=True, window=20), 1),
+    "d256_16_on_2": (32, 16, 2, 256, dict(causal=True), 1),
+    "d128_t_no_multiple": (40, 3, 3, 128, dict(causal=True), 1),
+    "d32_four_a_block": (40, 8, 8, 32, dict(causal=True), 4),
+    "tiny_heads_one_block": (40, 3, 3, 16, dict(causal=True), 3),
+    "d64_grouped_transposed": (32, 4, 2, 64, dict(causal=True), None),
+    "d64_three_heads_transposed": (32, 3, 3, 64, dict(causal=True), None),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_IN_PLACE))
+def test_heads_indexed_in_place_against_the_dense_path(case):
+    """Forward, dQ, dK and dV of every way the kernels find a head: a lane
+    block a head (D a multiple of 128, grouped or not), two and four heads
+    a block (D=64, D=32), all the heads in one narrow block, and the two
+    kinds of shape that still go through a transpose."""
+    from paddle_tpu.ops.pallas_kernels import heads_a_block
+    t, hq, hkv, d, kw, want = _IN_PLACE[case]
+    assert heads_a_block(hq, hkv, d) == want
+    rng = np.random.RandomState(len(case))
+    q, g = (jnp.asarray(rng.randn(2, t, hq, d), jnp.float32)
+            for _ in range(2))
+    k, v = (jnp.asarray(rng.randn(2, t, hkv, d), jnp.float32)
+            for _ in range(2))
+    if "kv_len" in kw:
+        kw = dict(kw, kv_len=jnp.asarray(kw["kv_len"]))
+    flash, dense = _both(q, k, v, g, **kw)
+    assert [x.shape for x in flash] == [q.shape, q.shape, k.shape, v.shape]
+    for name, got, want in zip(("out", "dq", "dk", "dv"), flash, dense):
+        assert _error(got, want) < 2e-5, (name, _error(got, want))
+
+
+@pytest.mark.parametrize("causal", [True, False])
+def test_two_heads_a_block_equal_a_head_alone_bit_for_bit(causal):
+    """At D=64 a head's scores are (q2 * m_h) @ k2.T over the 128 lanes of
+    its pair: the other head's lanes add exact zeros, so the output and the
+    gradients are those of the head run alone (one head of 64 lanes is a
+    block of its own: the old layout's row a head), bit for bit in
+    float32."""
+    q, k, v, g = (jnp.asarray(np.random.RandomState(i).randn(2, 40, 4, 64),
+                              jnp.float32) for i in range(4))
+    pair, _ = _both(q, k, v, g, causal=causal)
+    for h in range(4):
+        alone = _both(*(x[:, :, h:h + 1] for x in (q, k, v, g)),
+                      causal=causal)[0]
+        for got, want in zip(pair, alone):
+            np.testing.assert_array_equal(got[:, :, h:h + 1], want)
+
+
+# the six transformer-family cells' flash shapes: (q, k and v, kwargs)
+_CELL_SHAPES = {
+    "transformer_t2048_causal": ((8, 2048, 8, 64), 8, dict(causal=True)),
+    "transformer_t2048_keys": ((8, 2048, 8, 64), 8, dict(kv_len=True)),
+    "olmoe_t4096": ((4, 4096, 16, 128), 16, dict(causal=True)),
+    "ouro_t4096": ((1, 4096, 16, 128), 16, dict(causal=True)),
+    "smallthinker_t8192_window": ((1, 8192, 7, 128), 1,
+                                  dict(causal=True, window=4096)),
+    "qwen3_next_t4096": ((1, 4096, 16, 256), 2, dict(causal=True)),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(_CELL_SHAPES))
+def test_no_layout_pass_around_the_kernels_at_the_cells_shapes(cell):
+    """flash_attention and its vjp, traced at a cell's shape: outside the
+    three pallas_calls there is no transpose, and no operand or result of a
+    pallas_call is a [.., T, 1] column (one lane in 128 in HBM)."""
+    shape, hkv, kw = _CELL_SHAPES[cell]
+    b, t, h, d = shape
+    if kw.pop("kv_len", False):
+        kw["kv_len"] = jnp.full((b,), t - 7, jnp.int32)
+    q = jnp.zeros(shape, jnp.bfloat16)
+    k = jnp.zeros((b, t, hkv, d), jnp.bfloat16)
+
+    def both(q, k, v, g):
+        out, vjp = jax.vjp(lambda q, k, v: flash_attention(
+            q, k, v, interpret=False, **kw), q, k, v)
+        return (out,) + vjp(g)
+    calls, outside = [], []
+
+    def walk(jaxpr):
+        for e in jaxpr.eqns:
+            if e.primitive.name == "pallas_call":
+                calls.append(e)
+                continue            # a kernel's body is Mosaic's, not XLA's
+            outside.append(e.primitive.name)
+            for sub in jax.core.jaxprs_in_params(e.params):
+                walk(sub)
+    walk(jax.make_jaxpr(both)(q, k, k, q).jaxpr)
+    assert sorted(e.params["name"] for e in calls) == [
+        "ptpu_flash_bwd_dkdv", "ptpu_flash_bwd_dq", "ptpu_flash_fwd"]
+    assert "transpose" not in outside
+    for e in calls:
+        for var in list(e.invars) + list(e.outvars):
+            assert not (var.aval.shape[-1] == 1 and var.aval.shape[-2] >= t), (
+                e.params["name"], var.aval.shape)

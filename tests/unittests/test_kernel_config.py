@@ -157,12 +157,15 @@ def test_default_attn_tiles_are_the_sweeps_choice(pallas_calls, t, d):
     """PERF.md section 6, PR 27: 512 x 512 won the v5e sweep at both shapes
     the benchmark's cells run, D=64 and D=128 alike, so a call that names
     no block runs at that pair whatever the head width."""
-    q = jax.ShapeDtypeStruct((1, t, 1, d), jnp.bfloat16)
+    q = jax.ShapeDtypeStruct((1, t, 2, d), jnp.bfloat16)
     jax.eval_shape(lambda q: pk.flash_attention(q, q, q, causal=True), q)
     fwd = pallas_calls[0]
     assert fwd["name"] == "ptpu_flash_fwd"
-    assert fwd["grid"] == (1, t // 512)
-    assert fwd["in_specs"][0].block_shape == (1, 512, d)
+    # (sequence, block of heads, q block, head in its block): two heads of
+    # 64 share a 128-lane block, a head of 128 has one to itself
+    per_block = 128 // d
+    assert fwd["grid"] == (1, 2 // per_block, t // 512, per_block)
+    assert fwd["in_specs"][0].block_shape == (512, 128)
     assert fwd["kernel"].keywords["block_k"] == 512
 
 
@@ -184,7 +187,7 @@ def _whole_batch(b):
 _RULES = {
     "fused_attention": (
         {"Q": [_f32(2, 16, 2, 8)], "K": [_f32(2, 16, 2, 8)],
-         "V": [_f32(2, 16, 2, 8)]}, {}, "attn", "block_q", 1, 8,
+         "V": [_f32(2, 16, 2, 8)]}, {}, "attn", "block_q", 0, 8,
         lambda block_q: min(block_q, 16)),
     # the table holds bytes of one float32 tile, not rows: 64 a row here,
     # and never more rows than the 32 there are

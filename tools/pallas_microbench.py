@@ -2,8 +2,10 @@
 vs their dense XLA counterparts, fwd+bwd, on the chip (one process; send
 it through the chip tool). One JSON line per comparison: {"kernel": ...,
 "dense_ms": ..., "fused_ms": ..., "speedup": ..., "shape": ...}.
-MB_TUNE=1 sweeps the flash blocks; MB_LN=1 compares the layer_norm kernel
-at a list of tile budgets with its XLA path (device ms from a trace).
+MB_TUNE=1 times the three flash kernels and the whole op over blocks
+(MB_BLOCKS=512x512 for the default pair alone); MB_LN=1 compares the
+layer_norm kernel at a list of tile budgets with its XLA path (device ms
+from a trace).
 """
 import json
 import os
@@ -106,77 +108,91 @@ def bench_softmax_xent(n=8192, v=32000):
         "shape": [n, v], "device": str(jax.devices()[0])}), flush=True)
 
 
-_SWEEP_SHAPES = "64x2048x64x0,64x2048x64x1,64x4096x128x1"
+_SWEEP_SHAPES = "8x2048x8x8x64x0,8x2048x8x8x64x1,4x4096x16x16x128x1"
 _SWEEP_BLOCKS = tuple((bq, bk) for bq in (128, 256, 512, 1024)
                       for bk in (128, 256, 512, 1024))
 
 
 def sweep_flash_blocks(shapes=_SWEEP_SHAPES, blocks=_SWEEP_BLOCKS,
                        dtype="bfloat16"):
-    """ms a call of each of the three flash kernels alone, in the kernels'
-    own [BH, T, D] layout, over block_q x block_k at the shapes the
-    benchmark's cells run (BHxTxDxcausal), and each result's largest error
-    against dense float32 attention on the same rounded inputs (first two
-    head-sequences). A pair Mosaic refuses is a line with its error. Under
-    jit a kernel whose result is dropped is dead code, so `_flash_bwd`'s
-    dq alone times the dQ kernel and (dk, dv) alone the dK/dV kernel."""
+    """ms a call of each of the three flash kernels alone and of
+    `flash_attention` whole, forward and forward + backward, so that what
+    lies around the kernels is read off one line; over block_q x block_k at
+    the shapes the benchmark's cells run (BxTxHqxHkvxDxcausal); and each
+    result's largest error against dense float32 attention on the same
+    rounded inputs (first sequence). A pair Mosaic refuses is a line with
+    its error. The kernels take the op's arrays as [B, T, H*D] (or a row a
+    head: `heads_a_block`, on the line). Under jit a kernel whose result is
+    dropped is dead code, so `_flash_bwd`'s dq alone times the dQ kernel;
+    dK/dV reads the delta rows the dQ kernel writes, so (dk, dv) alone
+    times both, and `dkdv_ms` is that less `dq_ms`."""
     jax = _await()
     import jax.numpy as jnp
     from paddle_tpu.ops import pallas_kernels as pk
     from paddle_tpu.parallel.ring_attention import attention_reference
 
     for spec in shapes.split(","):
-        bh, t, d, causal = (int(x) for x in spec.split("x"))
+        b, t, h, hkv, d, causal = (int(x) for x in spec.split("x"))
         causal, scale = bool(causal), 1.0 / float(np.sqrt(d))
         rng = np.random.RandomState(0)
-        q, k, v, g = (jnp.asarray(rng.randn(bh, t, d).astype("f") * 0.5,
-                                  dtype=dtype) for _ in range(4))
-        lens = jnp.full((bh,), t, jnp.int32)
+        q, g = (jnp.asarray(rng.randn(b, t, h, d).astype("f") * 0.5,
+                            dtype=dtype) for _ in range(2))
+        k, v = (jnp.asarray(rng.randn(b, t, hkv, d).astype("f") * 0.5,
+                            dtype=dtype) for _ in range(2))
+        hb = pk.heads_a_block(h, hkv, d)
+        lens = jnp.full((b if hb else b * h,), t, jnp.int32)
+        rows = [pk._rows(x, hb) for x in (q, k, v, g)]
 
-        def dense(q, k, v):      # [2, T, D] -> heads of a [1, T, 2, D]
+        def dense(q, k, v):
             return attention_reference(
-                *(x.astype(jnp.float32).transpose(1, 0, 2)[None]
-                  for x in (q, k, v)), causal=causal)[0].transpose(1, 0, 2)
-        ref_o, vjp = jax.vjp(dense, q[:2], k[:2], v[:2])
-        refs = (ref_o,) + vjp(g[:2].astype(jnp.float32))
+                *(x.astype(jnp.float32) for x in (q, k, v)), causal=causal)
+        ref_o, vjp = jax.vjp(dense, q[:1], k[:1], v[:1])
+        refs = (ref_o,) + vjp(g[:1].astype(jnp.float32))
 
         for bq, bk in blocks:
             if bq > t or bk > t:
                 continue
             fwd = jax.jit(lambda q, k, v, bq=bq, bk=bk: pk._flash_fwd(
-                q, k, v, lens, scale, causal, bq, bk, False))
+                q, k, v, lens, d, hb or 1, scale, causal, None, bq, bk,
+                False))
 
             def bwd(q, k, v, o, lse, g, bq=bq, bk=bk):
-                delta = jnp.sum(g.astype(jnp.float32)
-                                * o.astype(jnp.float32), axis=-1)
-                return pk._flash_bwd(scale, causal, bq, bk, False,
-                                     (q, k, v, lens, delta, lse), g)
-            line = {"kernel": "flash_sweep", "shape": [bh, t, d],
-                    "causal": causal, "dtype": dtype, "block_q": bq,
-                    "block_k": bk, "device": str(jax.devices()[0])}
+                return pk._flash_bwd(d, hb or 1, scale, causal, None, bq,
+                                     bk, False, (q, k, v, lens, o, lse), g)
+
+            def whole(q, k, v, bq=bq, bk=bk):
+                return pk.flash_attention(q, k, v, causal=causal,
+                                          block_q=bq, block_k=bk)
+
+            def whole_bwd(q, k, v, g):
+                out, vjp = jax.vjp(whole, q, k, v)
+                return (out,) + vjp(g)
+            line = {"kernel": "flash_sweep", "shape": [b, t, h, hkv, d],
+                    "heads_a_block": hb or "transposed", "causal": causal,
+                    "dtype": dtype, "block_q": bq, "block_k": bk,
+                    "device": str(jax.devices()[0])}
             try:
-                o, lse = fwd(q, k, v)
-                line["fwd_ms"] = round(_time(fwd, q, k, v), 3)
-                got = [o]
+                o, lse = fwd(*rows[:3])
+                line["fwd_ms"] = round(_time(fwd, *rows[:3]), 3)
+                for name, fn, args in (
+                        ("dq_ms", lambda *a: bwd(*a)[:1], None),
+                        ("dq_dkdv_ms", lambda *a: bwd(*a)[1:], None),
+                        ("op_fwd_ms", whole, (q, k, v)),
+                        ("op_fwd_bwd_ms", whole_bwd, (q, k, v, g))):
+                    args = args or (*rows[:3], o, lse, rows[3])
+                    line[name] = round(_time(jax.jit(fn), *args), 3)
+                line["dkdv_ms"] = round(line["dq_dkdv_ms"]
+                                        - line["dq_ms"], 3)
+                line["around_kernels_ms"] = round(
+                    line["op_fwd_bwd_ms"] - line["fwd_ms"]
+                    - line["dq_dkdv_ms"], 3)
+                got = jax.jit(whole_bwd)(q, k, v, g)
+                line["max_err"] = {
+                    n: round(float(jnp.max(jnp.abs(
+                        a[:1].astype(jnp.float32) - r))), 5)
+                    for n, a, r in zip(("o", "dq", "dk", "dv"), got, refs)}
             except Exception as e:  # noqa: BLE001 — record, keep sweeping
-                line["fwd_error"] = str(e).replace("\n", " ")[:200]
-                o, lse = jax.jit(lambda q, k, v: pk._flash_fwd(
-                    q, k, v, lens, scale, causal, 128, 128, False))(q, k, v)
-                got = [None]
-            for name, pick in (("dq", lambda r: r[:1]),
-                               ("dkdv", lambda r: r[1:])):
-                fn = jax.jit(lambda *a, pick=pick: pick(bwd(*a)))
-                try:
-                    got += list(fn(q, k, v, o, lse, g))
-                    line[name + "_ms"] = round(
-                        _time(fn, q, k, v, o, lse, g), 3)
-                except Exception as e:  # noqa: BLE001
-                    got += [None] * (1 if name == "dq" else 2)
-                    line[name + "_error"] = str(e).replace("\n", " ")[:200]
-            line["max_err"] = {
-                n: None if a is None else round(float(jnp.max(jnp.abs(
-                    a[:2].astype(jnp.float32) - r))), 5)
-                for n, a, r in zip(("o", "dq", "dk", "dv"), got, refs)}
+                line["error"] = str(e).replace("\n", " ")[-300:]
             print(json.dumps(line), flush=True)
 
 
@@ -300,7 +316,7 @@ if __name__ == "__main__":
             if os.environ.get("MB_BLOCKS") else _LN_KIB,
             os.environ.get("MB_DTYPE", "float32"))
     elif os.environ.get("MB_TUNE") == "1":
-        # MB_SHAPES=BHxTxDxcausal[,...], MB_BLOCKS=BQxBK[,...]
+        # MB_SHAPES=BxTxHqxHkvxDxcausal[,...], MB_BLOCKS=BQxBK[,...]
         sweep_flash_blocks(
             os.environ.get("MB_SHAPES", _SWEEP_SHAPES),
             tuple(tuple(int(x) for x in b.split("x"))
