@@ -86,6 +86,10 @@ FULL = {
     "looped": dict(hidden_size=2048, num_attention_heads=16,
                    intermediate_size=5632, vocab_size=8192,
                    num_hidden_layers=2, t=2048, tol=5e-2),
+    # the LFM2 cell's: one sequence of 8192 at hidden 2048, 3 taps; 8 of 32
+    # experts of 1792 held, 4 a token
+    "lfm2": dict(t=8192, d=2048, width=3, experts=32, held=8, f=1792,
+                 top_k=4),
     "barrier": dict(steps=5, rounds=3, tol=0.15),
     "dp_loss_rtol": 2e-2,
 }
@@ -109,6 +113,7 @@ TINY = {
     "looped": dict(hidden_size=32, num_attention_heads=4,
                    intermediate_size=48, vocab_size=64, num_hidden_layers=2,
                    t=32, tol=5e-2),
+    "lfm2": dict(t=64, d=256, width=3, experts=8, held=4, f=128, top_k=2),
     "barrier": dict(steps=5, rounds=3, tol=0.75),
     "dp_loss_rtol": 2e-2,
 }
@@ -1076,13 +1081,154 @@ def phase_g(smoke):
                              "%g: %r" % (tol, wrong))
 
 
+def phase_h(smoke):
+    """One LFM2 short_conv mixer without its two matmuls, and one expert
+    layer with its sigmoid router, each alone at the cell's sizes, bf16
+    operands as under AMP. The mixer's part: [B, C, u] as the input
+    projection leaves them, v = B * u, the convolution's rule, C * that;
+    forward and forward + backward, with the kernels and with the jax.numpy
+    passes, and the convolution's rule alone, beside the least time one
+    pass over the operands takes at the chip's HBM rate. The router's part
+    of `routed_ffn`: the float32 matmul, sigmoid, the biased top-k and the
+    sort, against the whole layer. Both are checked against float32
+    recomputations; the times are printed for the next reader (no metric):
+    PERF.md section 6 quotes them."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.core import registry
+    from paddle_tpu.models import causal_lm_reference as plain
+    from paddle_tpu.parallel import moe
+
+    c = smoke.cfg["lfm2"]
+    t, d, e, held = c["t"], c["d"], c["experts"], c["held"]
+    rng = np.random.RandomState(23)
+    bcu, dy = (jnp.asarray(rng.randn(1, t, n), jnp.bfloat16)
+               for n in (3 * d, d))
+    w = jnp.asarray(rng.randn(d, c["width"]) * 0.5, jnp.float32)
+    rule = registry.get("causal_conv1d").lower
+
+    def traced_now():
+        def conv(v, w):
+            return rule(None, {"X": [v], "Filter": [w]}, {})["Out"][0]
+
+        def mixer(bcu, w):
+            b, gate, u = jnp.split(bcu, 3, axis=-1)
+            return gate * conv(b * u, w)
+
+        def both(f):
+            def run(x, w, dy):
+                y, vjp = jax.vjp(f, x, w)
+                return (y,) + vjp(dy)
+            return run
+        return conv, mixer, both
+
+    def float32_mixer(bcu, w):
+        b, gate, u = jnp.split(bcu, 3, axis=-1)
+        return gate * plain.causal_conv(b * u, w)
+
+    array_ms = 1e3 * t * d * 2 / 819e9     # one [T, D] bf16 array at 819 GB/s
+    saved = os.environ.get("PADDLE_TPU_PALLAS")
+    try:
+        with jax.default_device(smoke.device):
+            y, vjp = jax.vjp(float32_mixer, bcu.astype(jnp.float32), w)
+            want = (y,) + vjp(dy.astype(jnp.float32))
+            for path, flag in (("kernel", "conv"), ("xla", "0")):
+                os.environ["PADDLE_TPU_PALLAS"] = flag
+                conv, mixer, both = traced_now()
+                run = jax.jit(both(mixer))
+                errs = _normalized_errors(("y", "dbcu", "dw"),
+                                          run(bcu, w, dy), want)
+                if max(errs.values()) > 3e-2:
+                    raise AssertionError(
+                        "the gated convolution (%s path) is off its float32 "
+                        "recomputation: %r" % (path, errs))
+                v = bcu[..., :d]
+                smoke.say(
+                    "short_conv mixer without its matmuls, %s path, [1, %d, "
+                    "3 x %d] bf16, %d taps: forward %.3f ms (least %.3f: 4 "
+                    "arrays), forward + backward %.3f ms (least %.3f: 11 "
+                    "arrays); the convolution's rule alone forward %.3f "
+                    "ms, forward + backward %.3f ms; off float32 by %s"
+                    % (path, t, d, c["width"],
+                       _in_flight_ms(jax.jit(mixer), (bcu, w)), 4 * array_ms,
+                       _in_flight_ms(run, (bcu, w, dy)), 11 * array_ms,
+                       _in_flight_ms(jax.jit(conv), (v, w)),
+                       _in_flight_ms(jax.jit(both(conv)), (v, w, dy)),
+                       ", ".join("%s %.2e" % kv for kv in errs.items())))
+    finally:
+        if saved is None:
+            os.environ.pop("PADDLE_TPU_PALLAS", None)
+        else:
+            os.environ["PADDLE_TPU_PALLAS"] = saved
+
+    # the expert layer: 8 of 32 held, the router sigmoid with a bias
+    x = jnp.asarray(rng.randn(t, d), jnp.bfloat16)
+    router = jnp.asarray(rng.randn(d, e) * 0.02, jnp.float32)
+    bias = jnp.asarray(rng.randn(e) * 0.05, jnp.float32)
+    wg, wu = (jnp.asarray(rng.randn(held, d, c["f"]) * 0.02, jnp.float32)
+              for _ in range(2))
+    wd = jnp.asarray(rng.randn(held, c["f"], d) * 0.02, jnp.float32)
+    cfg = dict(num_experts=e, num_experts_per_tok=c["top_k"],
+               norm_topk_prob=True, router_scoring="sigmoid", first_expert=0)
+
+    def layer(x, router, wg, wu, wd):
+        out, _, _, load = moe.routed_ffn(
+            x, router, wg, wu, wd, c["top_k"], True,
+            expert_dtype=jnp.bfloat16, scoring="sigmoid", expert_bias=bias)
+        return out, load
+
+    def trained(x, router, wg, wu, wd):
+        def loss(*a):
+            return layer(*a)[0].astype(jnp.float32).sum()
+        return jax.grad(loss, argnums=(0, 1, 2, 3, 4))(x, router, wg, wu, wd)
+
+    def routing(x, router):
+        logits = jnp.dot(x.astype(jnp.float32), router,
+                         precision=jax.lax.Precision.HIGHEST)
+        _, _, gate, expert = moe._route(logits, c["top_k"], True, "sigmoid",
+                                        bias, 1.0)
+        return gate, jnp.argsort(expert.T.reshape(-1), stable=True)
+
+    with jax.default_device(smoke.device):
+        out, load = jax.jit(layer)(x, router, wg, wu, wd)
+        with jax.default_matmul_precision("highest"):
+            want, _, _, want_load = jax.jit(
+                lambda *a: plain.routed_experts(*a, cfg, expert_bias=bias))(
+                    x.astype(jnp.float32), router, wg, wu, wd)
+        # a token whose choice moved under bf16 is off by a whole expert:
+        # the error is the decided tokens', and the moved ones are counted
+        off = jnp.abs(out.astype(jnp.float32) - want).max(-1) \
+            / jnp.abs(want).max()
+        moved = int(jnp.abs(load - want_load).sum()) // 2
+        err = float(jnp.sort(off)[-1 - 4 * max(moved, t // 1000)])
+        if int(load.sum()) != c["top_k"] * t or err > 3e-2 \
+                or moved > t // 100:
+            raise AssertionError(
+                "the sigmoid-routed layer: %d assignments counted of %d, %d "
+                "moved, output off by %.2e" % (load.sum(), c["top_k"] * t,
+                                               moved, err))
+        args = (x, router, wg, wu, wd)
+        smoke.say(
+            "expert layer alone, %d tokens of %d, top-%d of %d sigmoid "
+            "scores + bias, %d held of width %d (%d rows): forward %.3f ms, "
+            "forward + backward %.3f ms; the router's part (float32 matmul, "
+            "sigmoid, top-k of s + b, gather from s, the sort) %.3f ms; off "
+            "float32 by %.2e outside the tokens a moved assignment can have "
+            "touched, %d assignments moved"
+            % (t, d, c["top_k"], e, held, c["f"], load[:held].sum(),
+               _in_flight_ms(jax.jit(layer), args),
+               _in_flight_ms(jax.jit(trained), args),
+               _in_flight_ms(jax.jit(routing), (x, router)), err, moved))
+
+
 PHASES = (("A", "ResNet-50 training", phase_a),
           ("B", "transformer training", phase_b),
           ("C", "Pallas kernel families", phase_c),
           ("D", "four-chip data parallel", phase_d),
           ("E", "timing barrier", phase_e),
           ("F", "causal_conv1d kernels", phase_f),
-          ("G", "looped decoder's summed gradients", phase_g))
+          ("G", "looped decoder's summed gradients", phase_g),
+          ("H", "LFM2's gated convolution and sigmoid router", phase_h))
 
 
 def main(argv=None):
@@ -1090,7 +1236,7 @@ def main(argv=None):
     ap.add_argument("--tiny", action="store_true",
                     help="CPU rehearsal at toy sizes (needs "
                          "JAX_PLATFORMS=cpu)")
-    ap.add_argument("--phases", default="ABCDEFG",
+    ap.add_argument("--phases", default="ABCDEFGH",
                     help="letters of the phases to run (default all)")
     args = ap.parse_args(argv)
 

@@ -563,15 +563,23 @@ _SCOPE_RE = re.compile(r"(?:^|[/(])" + SCOPE_MARK + r"([^/()]+)/([^/()]+)")
 # its forward op's attributes.
 PASS_ATTR = "__pass__"
 PASS_MARK = "pass:"
+# the attribute a model's builder writes on ops whose device time belongs
+# under a name of the model's own in the table by op type (the gate
+# multiplies of models/causal_lm.py:short_conv): the type `op_scope` names
+# the op by. A grad op has it with its forward op's attributes.
+SCOPE_ATTR = "__scope__"
 _PASS_RE = re.compile(r"(?:^|[/(])" + PASS_MARK + r"(\d+(?:-\d+)?)/")
 
 
 def scope_type(op):
-    """The type `op_scope` names an op by: a `grad_of` op is
-    `<fwd_type>_grad`."""
+    """The type `op_scope` names an op by: its own, or the name a model
+    gave it under SCOPE_ATTR (the two gate multiplies of a short_conv mixer
+    read `short_conv`, not `elementwise_mul`); a `grad_of` op is
+    `<that of its forward op>_grad`."""
     if op.type == "grad_of":
-        return op.attrs["fwd_type"] + "_grad"
-    return op.type
+        return op.attrs.get("fwd_attrs", {}).get(
+            SCOPE_ATTR, op.attrs["fwd_type"]) + "_grad"
+    return op.attrs.get(SCOPE_ATTR, op.type)
 
 
 def op_scope(op):
@@ -814,13 +822,18 @@ def _count_moe_layer(attrs, ins):
         "moe_ffn ops lowered (forward ops, not a grad op's replay), by "
         "experts a token, experts routed over, experts held, the gate's "
         "activation, what the router reads (the experts' own input or "
-        "another tensor, pre_attention), the grouped-matmul route and the "
+        "another tensor, pre_attention), the grouped-matmul route, the "
         "rows of the slot-major buffer the expert-side gathers move (all, "
-        "or the tiles of the held assignments)"
+        "or the tiles of the held assignments), how the router scores "
+        "(softmax or sigmoid), whether an expert bias enters the choice of "
+        "the top_k and the factor that scales the weights"
     ).inc(top_k=str(attrs["top_k"]), experts=str(experts), held=str(held),
           activation=str(attrs.get("activation", "silu")),
           router_input="pre_attention" if ins.get("RouterX") else "own",
-          path=GROUPED_MATMUL, rows=rows_moved(experts, held))
+          path=GROUPED_MATMUL, rows=rows_moved(experts, held),
+          scoring=str(attrs.get("scoring", "softmax")),
+          bias=str(bool(ins.get("ExpertBias"))).lower(),
+          scale="%g" % attrs.get("scale", 1.0))
 
 
 def _count_attention_layer(ctx, attrs, ins):

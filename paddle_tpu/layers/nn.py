@@ -318,7 +318,11 @@ def causal_conv1d(input, kernel_size, act=None, param_attr=None, name=None):
     C], one filter of `kernel_size` taps a channel (the parameter is [C,
     kernel_size]), y_t[c] = sum_m w[c, m] x_(t - kernel_size + 1 + m)[c]
     with zeros before the sequence; act None or "silu", applied in the same
-    op (ops/linear_attention_ops.py)."""
+    op (ops/linear_attention_ops.py); any other act is refused. The op takes
+    no gate: a gated short convolution (models/causal_lm.py:short_conv, C *
+    conv(B * u)) is two elementwise multiplies around it, which on the v5e
+    cost 1.15 times one fused pass over the operands at [1, 8192, 2048]
+    (PERF.md section 6, PR 39), so the kernels were left as they are."""
     if act not in (None, "silu"):
         raise ValueError("causal_conv1d act must be None or 'silu', got %r"
                          % (act,))

@@ -13,6 +13,7 @@ all-to-all — no model rewrite. The reference era (mozga-intel/Paddle,
 """
 from ..core.framework import Variable
 from ..core.layer_helper import LayerHelper
+from ..core.initializer import ConstantInitializer
 from ..core.param_attr import ParamAttr
 from ..core import unique_name
 
@@ -272,7 +273,8 @@ def switch_moe(input, num_experts, d_hidden, capacity_factor=1.25,
 
 def moe_ffn(input, num_experts, d_expert, top_k, norm_topk_prob=False,
             param_attr=None, name=None, router_input=None, activation="silu",
-            experts_held=None, first_expert=0):
+            experts_held=None, first_expert=0, scoring="softmax",
+            expert_bias_attr=None, routed_scaling_factor=1.0):
     """Dropless top-k routed experts, each a gated FFN without bias
     (lowering: ops/parallel_ops.py -> parallel/moe.py routed_ffn). input
     [..., D] -> (out [..., D], balance_loss [1], z_loss [1], expert_load
@@ -293,6 +295,20 @@ def moe_ffn(input, num_experts, d_expert, top_k, norm_topk_prob=False,
     the top_k is over all of them, the expert weights are [experts_held, ..],
     only assignments to those experts are computed and `out` is their
     partial sum; expert_load still counts all assignments.
+
+    scoring: "softmax" (the above) or "sigmoid": a token's scores are s =
+    sigmoid(router logits), an expert each on its own; with norm_topk_prob
+    the chosen scores are divided by their sum + 1e-6; balance_loss and
+    z_loss are zeros, both being defined on a softmax. expert_bias_attr: a
+    ParamAttr (or True) makes a float32 parameter [num_experts], named
+    <param_attr's name>.expert_bias and created after the router, that is
+    persistable and not trainable: it has no gradient variable, the
+    optimizer holds no state for it and a gradient clip does not see it. It
+    is added to the scores for the choice of the top_k and to nothing else:
+    the weights come from the scores without it. Its initializer is the
+    attr's (zeros by default); None or False: no bias. It is refused with
+    softmax scoring, where no model defines it. routed_scaling_factor
+    multiplies the weights after the renormalisation.
     """
     helper = LayerHelper("moe_ffn", name=name)
     dtype = input.dtype
@@ -307,6 +323,13 @@ def moe_ffn(input, num_experts, d_expert, top_k, norm_topk_prob=False,
     if activation not in ("silu", "relu"):
         raise ValueError("moe_ffn activation must be 'silu' or 'relu', got "
                          "%r" % (activation,))
+    if scoring not in ("softmax", "sigmoid"):
+        raise ValueError("moe_ffn scoring must be 'softmax' or 'sigmoid', "
+                         "got %r" % (scoring,))
+    biased = expert_bias_attr not in (None, False)
+    if biased and scoring != "sigmoid":
+        raise ValueError("moe_ffn adds an expert bias to sigmoid scores "
+                         "only, scoring is %r" % (scoring,))
     base = ParamAttr.to_attr(param_attr)
     if base is False:
         raise ValueError("moe_ffn requires parameters")
@@ -315,10 +338,22 @@ def moe_ffn(input, num_experts, d_expert, top_k, norm_topk_prob=False,
         return helper.create_parameter(attr=_suffixed(base, suffix),
                                        shape=shape, dtype=dtype)
 
-    inputs = {"X": [input], "Router": [param("router", [d, e])],
+    inputs = {"X": [input], "Router": [param("router", [d, e])]}
+    if biased:
+        given = ParamAttr() if expert_bias_attr is True \
+            else ParamAttr.to_attr(expert_bias_attr)
+        bias = helper.create_parameter(
+            attr=ParamAttr(
+                name=(base.name + ".expert_bias") if base.name else None,
+                initializer=given.initializer or ConstantInitializer(0.0),
+                trainable=False),
+            shape=[e], dtype="float32")
+        bias.stop_gradient = True
+        inputs["ExpertBias"] = [bias]
+    inputs.update({
               "WGate": [param("w_gate", [held, d, f])],
               "WUp": [param("w_up", [held, d, f])],
-              "WDown": [param("w_down", [held, f, d])]}
+              "WDown": [param("w_down", [held, f, d])]})
     # what the defaults leave as it was is not written: a layer that holds
     # every expert, routes from its input and gates with SiLU is the op it
     # always was
@@ -329,6 +364,10 @@ def moe_ffn(input, num_experts, d_expert, top_k, norm_topk_prob=False,
         attrs["activation"] = str(activation)
     if first_expert:
         attrs["first_expert"] = int(first_expert)
+    if scoring != "softmax":
+        attrs["scoring"] = str(scoring)
+    if float(routed_scaling_factor) != 1.0:
+        attrs["scale"] = float(routed_scaling_factor)
     out = helper.create_variable_for_type_inference(dtype)
     balance = helper.create_variable_for_type_inference("float32")
     z = helper.create_variable_for_type_inference("float32")

@@ -2,15 +2,17 @@
 dict whose keys are those of a Hugging Face `config.json`.
 
 One builder for the family: token embedding (no scale, no position table),
-N x (RMS norm, a mixer, RMS norm, a dense SwiGLU FFN or dropless top-k
-routed experts with an optional gated shared expert beside them), final RMS
-norm, an untied output head, next-token cross-entropy plus the routers'
-auxiliary losses. The mixer is, by layer, self-attention (grouped queries,
+N x (RMS norm, a mixer, RMS norm, by layer a dense SwiGLU FFN or dropless
+top-k routed experts with an optional gated shared expert beside them),
+final RMS norm, an output head of its own or tied to the embedding,
+next-token cross-entropy plus the routers' auxiliary losses. The mixer is,
+by layer, self-attention (grouped queries,
 optional QK-norm over all channels or a head, rotary positions on the whole
 head or its first channels on the layers whose pattern says so, a sliding
 window on the layers whose pattern says so, an optional sigmoid gate on the
 output) or a gated delta net (a causal depthwise convolution over q, k and
-v, the gated delta rule, a gated RMS norm a head). A new decoder-only
+v, the gated delta rule, a gated RMS norm a head) or a gated short
+convolution (C * conv(B * u) between two projections). A new decoder-only
 architecture is a config plus the ops it lacks, not a model file. Users:
 OLMoE-1B-7B (`model_type: olmoe`; Muennighoff et al. 2024, arXiv:2409.02060),
 SmallThinker-21BA3B (PowerInfer; window and full attention mixed with
@@ -21,8 +23,12 @@ zero-centred norm weights, 512 experts beside a gated shared one) and
 Ouro-2.6B (`model_type: ouro`; Zhu et al. 2025, arXiv:2510.25741: one stack
 of dense layers run total_ut_steps times over the same weights, every
 branch normed going in and coming out, an exit gate that weighs the passes'
-losses), whose equations the module follows; `causal_lm_reference.py` is
-the same forward in plain jax.numpy.
+losses) and LFM2-8B-A1B (`model_type: lfm2_moe`, LiquidAI: three gated
+short convolutions to one attention layer with QK-norm a head, leading
+dense layers before the expert layers, a sigmoid router whose top-k is
+chosen with an expert bias and weighed without it, a tied head), whose
+equations the module follows; `causal_lm_reference.py` is the same forward
+in plain jax.numpy.
 
 Config keys read (HF names): vocab_size, hidden_size, num_hidden_layers,
 num_attention_heads, num_key_value_heads (a divisor of the heads: query
@@ -31,8 +37,9 @@ heads), intermediate_size (the dense FFN's width, or one expert's),
 num_experts (0 or absent: dense SwiGLU), num_experts_per_tok,
 norm_topk_prob, rms_norm_eps, rope_theta (None: no rotary), hidden_act
 (silu; relu for experts), attention_bias (false), clip_qkv (null),
-rope_scaling (null), tie_word_embeddings (false), initializer_range, embedding_initializer_range (absent: the
-same), router_aux_loss_coef, router_z_loss_coef; `qk_norm`, which `config.json`
+rope_scaling (null), tie_word_embeddings (false; true: LFM2's, below),
+initializer_range, embedding_initializer_range (absent: the same),
+router_aux_loss_coef, router_z_loss_coef; `qk_norm`, which `config.json`
 does not carry because `modeling_olmoe.py` always applies it (true: over all
 channels before the head split; "head": over each head's own); and
 `router_input` ("own", or "pre_attention": the router reads the
@@ -53,8 +60,27 @@ keys of their own, what `modeling_ouro.py` always does: `sandwich_norm` (a
 layer is a = x + N2(mixer(N1(x))), a + N4(FFN(N3(a)))) and `exit_gate`
 (lambda_t = sigmoid(h_t w_g + b_g) on every pass's normed state; the loss
 is the exit distribution's expected cross-entropy less `exit_entropy_coef`
-x its entropy). The final norm closes every pass, and the next pass starts
-from the normed state. A loop over routed experts is refused. `model_type`
+x its entropy). LFM2's: layer_types (one "conv" or "full_attention" a
+layer: a short_conv mixer or attention; a list longer than the stack is
+cut to it only where it is one kind throughout; absent:
+full_attention_interval decides), conv_L_cache (the short convolution's
+taps), conv_bias (false), num_dense_layers (the first so many layers have
+a dense FFN at intermediate_size, the others experts at
+moe_intermediate_size; 0),
+use_expert_bias (false), routed_scaling_factor (1), norm_eps (an alias of
+rms_norm_eps), tie_word_embeddings true (the head reads the embedding's
+parameter, transposed); and, as keys of their own, what
+`modeling_lfm2_moe.py` always does: `router_scoring` ("softmax", or
+"sigmoid": s = sigmoid(logits), the top k chosen over s + the expert bias,
+weighed by s, renormalised over the chosen with 1e-6 added to their sum;
+router_aux_loss_coef and router_z_loss_coef must then be 0, and
+use_expert_bias needs it) and `expert_bias_initializer_range` (the bias is
+held, not trained: a parameter without gradient or optimizer state, drawn
+normal(0, that) at startup from a stream of its own, EXPERT_BIAS_SEED, the
+same draw in every run as a checkpoint's buffer is the same under whatever
+else is initialised around it; 0: zeros). A loop over short_conv mixers is
+refused. The final norm closes every pass, and the next pass starts from
+the normed state. A loop over routed experts is refused. `model_type`
 is not read: a config says what it builds by these keys. SmallThinker's own
 names are mapped onto these:
 moe_ffn_hidden_size (intermediate_size), moe_num_primary_experts
@@ -74,20 +100,23 @@ the experts' outputs are the partial sums of what is held.
 Parameters are created in the order the reference reads them: embedding;
 a layer's input norm, then Wq, Wk, Wv, q norm, k norm, Wo (attention) or
 W_qkvz, W_ba, the convolution's filter, dt_bias, A_log, the gated norm's
-weight, W_out (gated delta net), [the mixer's outgoing norm], post-attention
-norm, then router, gate, up, down (experts; then the shared expert's gate,
+weight, W_out (gated delta net) or w_in, the convolution's filter, w_out
+(short_conv), [the mixer's outgoing norm], post-attention norm, then router,
+[the expert bias], gate, up, down (experts; then the shared expert's gate,
 up, down and its sigmoid gate's weight) or gate, up, down (dense), [the
-FFN's outgoing norm]; final norm; [the exit gate's weight and bias]; head.
-The bracketed ones exist with sandwich_norm and exit_gate. A parameter is
+FFN's outgoing norm]; final norm; [the exit gate's weight and bias]; head
+(none of its own where it is tied). The bracketed ones exist with
+sandwich_norm, use_expert_bias and exit_gate. A parameter is
 named by layer and role, `layer_<i>.<role>` (`layer_0.wq`,
-`layer_3.experts.w_gate`) and `embedding`, `final_norm`, `exit_gate.w`,
+`layer_3.experts.w_gate`, `layer_3.experts.expert_bias`) and `embedding`,
+`final_norm`, `exit_gate.w`,
 `exit_gate.b`, `head`: the passes of a looped model find their weights by
 name.
 """
 import contextlib
 
 import paddle_tpu as fluid
-from ..core.lowering import PASS_ATTR
+from ..core.lowering import PASS_ATTR, SCOPE_ATTR
 
 DEFAULTS = {
     "num_experts": 0, "num_experts_per_tok": 0, "norm_topk_prob": False,
@@ -101,11 +130,19 @@ DEFAULTS = {
     "full_attention_interval": 1, "shared_expert_intermediate_size": 0,
     "mlp_only_layers": [], "decoder_sparse_step": 1, "total_ut_steps": 1,
     "early_exit_threshold": 1, "sandwich_norm": False, "exit_gate": False,
-    "exit_entropy_coef": 0.0}
-# SmallThinker's key -> the key the builder reads
+    "exit_entropy_coef": 0.0, "conv_bias": False, "num_dense_layers": 0,
+    "use_expert_bias": False, "routed_scaling_factor": 1,
+    "router_scoring": "softmax", "expert_bias_initializer_range": 0.0}
+# the stream every expert bias is drawn from, whatever the program's seed:
+# the draw the LFM2 cell's limits were read under (PERF.md section 4)
+EXPERT_BIAS_SEED = 39
+# SmallThinker's and LFM2's key -> the key the builder reads
 ALIASES = {"moe_ffn_hidden_size": "intermediate_size",
            "moe_num_primary_experts": "num_experts",
-           "moe_num_active_primary_experts": "num_experts_per_tok"}
+           "moe_num_active_primary_experts": "num_experts_per_tok",
+           "norm_eps": "rms_norm_eps"}
+# a layer's kind in `layer_types` -> its mixer here
+LAYER_TYPES = {"conv": "short_conv", "full_attention": "attention"}
 # the keys a gated delta net needs
 LINEAR_KEYS = ("linear_num_key_heads", "linear_num_value_heads",
                "linear_key_head_dim", "linear_value_head_dim",
@@ -116,17 +153,21 @@ def resolve(cfg):
     """`cfg` over DEFAULTS, refusing what the builder cannot build rather
     than building something else under the model's name. Adds what the
     builder derives: head_dim, rotary_dim, experts_held and first_expert
-    (the share), and the per-layer patterns `rope_layers`, `window_layers`
-    and `mixer_layers` ("attention" or "gated_delta")."""
+    (the share), dense_intermediate_size (the dense FFN's width, where
+    `intermediate_size` became an expert's) and the per-layer patterns
+    `rope_layers`, `window_layers`, `mixer_layers` ("attention",
+    "gated_delta" or "short_conv") and `ffn_layers` ("dense" or
+    "experts")."""
     c = dict(DEFAULTS, **cfg)
     for theirs, ours in ALIASES.items():
         if theirs in c:
             c[ours] = c[theirs]
+    c["dense_intermediate_size"] = c.get("intermediate_size")
     if c["num_experts"] and "moe_intermediate_size" in c:
         c["intermediate_size"] = c["moe_intermediate_size"]
     c.setdefault("num_key_value_heads", c["num_attention_heads"])
     for key, want in (("attention_bias", False), ("clip_qkv", None),
-                      ("tie_word_embeddings", False), ("rope_scaling", None),
+                      ("rope_scaling", None), ("conv_bias", False),
                       ("moe_primary_router_apply_softmax", True),
                       ("mlp_only_layers", []), ("decoder_sparse_step", 1),
                       ("early_exit_threshold", 1)):
@@ -143,6 +184,26 @@ def resolve(cfg):
         raise NotImplementedError("causal_lm builds router_input own or "
                                   "pre_attention, the config has %r"
                                   % (c["router_input"],))
+    if c["tie_word_embeddings"] not in (False, True):
+        raise NotImplementedError("causal_lm builds tie_word_embeddings "
+                                  "false or true, the config has %r"
+                                  % (c["tie_word_embeddings"],))
+    if c["router_scoring"] not in ("softmax", "sigmoid"):
+        raise NotImplementedError("causal_lm builds router_scoring softmax "
+                                  "or sigmoid, the config has %r"
+                                  % (c["router_scoring"],))
+    if c["router_scoring"] == "sigmoid" and c["num_experts"]:
+        for key in ("router_aux_loss_coef", "router_z_loss_coef"):
+            if c[key]:
+                raise NotImplementedError(
+                    "causal_lm builds %s=0 under router_scoring sigmoid (the "
+                    "term is defined on a softmax router's probabilities), "
+                    "the config has %r" % (key, c[key]))
+    if c["use_expert_bias"] and c["router_scoring"] != "sigmoid":
+        raise NotImplementedError(
+            "causal_lm builds use_expert_bias under router_scoring sigmoid "
+            "only, the config has router_scoring %r"
+            % (c["router_scoring"],))
     if c["qk_norm"] not in (False, True, "head"):
         raise NotImplementedError("causal_lm builds qk_norm false, true (all "
                                   "channels) or 'head', the config has %r"
@@ -202,8 +263,34 @@ def resolve(cfg):
                          % (c["partial_rotary_factor"], c["head_dim"],
                             c["rotary_dim"], c["head_dim"]))
     interval = int(c["full_attention_interval"])
-    c["mixer_layers"] = ["attention" if (i + 1) % interval == 0
-                         else "gated_delta" for i in range(layers)]
+    if "layer_types" in c:
+        kinds = list(c["layer_types"])
+        unknown = sorted(set(kinds) - set(LAYER_TYPES))
+        # a published list over a stack cut short says which layers were
+        # kept only where it is one kind throughout
+        if unknown or len(kinds) < layers or (
+                len(kinds) > layers and len(set(kinds)) > 1):
+            raise NotImplementedError(
+                "causal_lm builds layer_types of %s, one a layer (more only "
+                "of one kind); the config has %d for %d layers%s"
+                % (sorted(LAYER_TYPES), len(kinds), layers,
+                   ", among them %s" % unknown if unknown else ""))
+        c["mixer_layers"] = [LAYER_TYPES[kind] for kind in kinds[:layers]]
+    else:
+        c["mixer_layers"] = ["attention" if (i + 1) % interval == 0
+                             else "gated_delta" for i in range(layers)]
+    if "short_conv" in c["mixer_layers"]:
+        if "conv_L_cache" not in c:
+            raise ValueError("layer_types has conv layers, which need "
+                             "conv_L_cache, the filter's taps")
+        if c["total_ut_steps"] > 1:
+            raise NotImplementedError(
+                "causal_lm runs a stack of attention layers total_ut_steps="
+                "%d times, not one with short_conv mixers"
+                % c["total_ut_steps"])
+    dense = min(int(c["num_dense_layers"]), layers) if c["num_experts"] \
+        else layers
+    c["ffn_layers"] = ["dense"] * dense + ["experts"] * (layers - dense)
     if "gated_delta" in c["mixer_layers"]:
         missing = [key for key in LINEAR_KEYS if key not in c]
         if missing:
@@ -219,8 +306,9 @@ def resolve(cfg):
 def _layer(c, i):
     """The config as layer i sees it: `layer` its index, which names its
     parameters; `rope_theta` None where the pattern gives the layer no
-    rotary, `window` its sliding window or None."""
+    rotary, `window` its sliding window or None, `ffn` its FFN's kind."""
     return dict(c, layer=i, window=c["window_layers"][i],
+                ffn=c["ffn_layers"][i],
                 rope_theta=c["rope_theta"] if c["rope_layers"][i] else None)
 
 
@@ -335,6 +423,33 @@ def gated_delta_net(x, c):
                    c["hidden_size"], c, "w_out")
 
 
+def short_conv(x, c):
+    """The gated short convolution (Lfm2MoeShortConv) over x [B, T, D]: one
+    projection to [B, C, u], D channels each in that order; v = B * u; a
+    causal depthwise convolution of conv_L_cache taps over v, no bias and
+    no activation; (C * that) through the output projection. Three ops, not
+    one with the gates inside the convolution's kernels: on the v5e at [1,
+    8192, 2048] a layer's mixer outside its two matmuls takes 1.15 times
+    what one pass over its operands would (PERF.md section 6, PR 39). The
+    two gate multiplies are named `short_conv` in the table by op type."""
+    d = c["hidden_size"]
+    b, gate, u = fluid.layers.split(_linear(x, 3 * d, c, "w_in"), 3, dim=-1)
+    conv = fluid.layers.causal_conv1d(
+        _gated(b, u), c["conv_L_cache"], param_attr=_matrix(c, "conv"))
+    return _linear(_gated(gate, conv), d, c, "w_out")
+
+
+def _gated(gate, x):
+    """gate * x, every op the product appends to the block named
+    `short_conv` under SCOPE_ATTR."""
+    ops = fluid.default_main_program().current_block().ops
+    first = len(ops)
+    out = gate * x
+    for op in ops[first:]:
+        op.attrs[SCOPE_ATTR] = "short_conv"
+    return out
+
+
 def _swiglu(x, width, c, role=""):
     gate = fluid.layers.swish(_linear(x, width, c, role + "w_gate"))
     up = _linear(x, width, c, role + "w_up")
@@ -347,22 +462,36 @@ def feed_forward(x, c, router_input=None):
     `router_input` is what the router reads where it is not x. A shared
     expert (shared_expert_intermediate_size), a SwiGLU every token passes
     scaled by sigmoid(x w_s), is added to the routed experts' output; in a
-    share of a layer it is every chip's own, computed once."""
-    if c["num_experts"]:
+    share of a layer it is every chip's own, computed once. c["ffn"] is the
+    layer's kind (absent, a config seen outside a stack: experts where it
+    has any): a model with num_dense_layers has dense layers at
+    dense_intermediate_size before its expert layers. An expert bias is
+    drawn at expert_bias_initializer_range from EXPERT_BIAS_SEED's stream
+    and training does not move it."""
+    if c.get("ffn", "experts" if c["num_experts"] else "dense") == "experts":
+        bias = None
+        if c["use_expert_bias"]:
+            bias = fluid.ParamAttr(initializer=fluid.initializer.Normal(
+                0.0, c["expert_bias_initializer_range"],
+                seed=EXPERT_BIAS_SEED)
+                if c["expert_bias_initializer_range"] else None)
         out, balance, z, load = fluid.layers.moe_ffn(
             x, num_experts=c["num_experts"], d_expert=c["intermediate_size"],
             top_k=c["num_experts_per_tok"],
             norm_topk_prob=c["norm_topk_prob"],
             param_attr=_matrix(c, "experts"), router_input=router_input,
             activation=c["hidden_act"],
-            experts_held=c["experts_held"], first_expert=c["first_expert"])
+            experts_held=c["experts_held"], first_expert=c["first_expert"],
+            scoring=c["router_scoring"],
+            expert_bias_attr=bias,
+            routed_scaling_factor=c["routed_scaling_factor"])
         if c["shared_expert_intermediate_size"]:
             shared = _swiglu(x, c["shared_expert_intermediate_size"], c,
                              "shared_expert.")
             out = out + shared * fluid.layers.sigmoid(
                 _linear(x, 1, c, "shared_expert.gate"))
         return out, (balance, z, load)
-    return _swiglu(x, c["intermediate_size"], c), None
+    return _swiglu(x, c["dense_intermediate_size"], c), None
 
 
 def _count_layer(c, mixer):
@@ -376,16 +505,20 @@ def _count_layer(c, mixer):
         "decoder layers causal_lm built, by mixer, the channels of a head "
         "its rotary turns (0: none), whether a sigmoid gate multiplies the "
         "attention's output, the taps of the convolution before a gated "
-        "delta rule (0: none), the width of the shared expert beside the "
-        "routed ones (0: none) and whether each branch is normed going out "
-        "as well as going in (a sandwich)"
+        "delta rule or of a short_conv mixer's own (0: none), the FFN's "
+        "kind (dense, or routed experts), the width of the shared expert "
+        "beside the routed ones (0: none) and whether each branch is normed "
+        "going out as well as going in (a sandwich)"
     ).inc(mixer=mixer,
           rotary_dim=str(c["rotary_dim"] if attention
                          and c["rope_theta"] is not None else 0),
           gate=str(bool(attention and c["attention_gate"])).lower(),
-          conv=str(0 if attention else c["linear_conv_kernel_dim"]),
+          conv=str(0 if attention else c["conv_L_cache"]
+                   if mixer == "short_conv"
+                   else c["linear_conv_kernel_dim"]),
+          ffn=c["ffn"],
           shared=str(c["shared_expert_intermediate_size"]
-                     if c["num_experts"] else 0),
+                     if c["ffn"] == "experts" else 0),
           sandwich=str(bool(c["sandwich_norm"])).lower())
 
 
@@ -467,6 +600,7 @@ def causal_lm(cfg, seq_len, extras=None, recompute=True):
             _count_layer(cl, mixer)
             a = _norm(h, cl, "input_norm")
             mixed = attention(a, pos, cl) if mixer == "attention" \
+                else short_conv(a, cl) if mixer == "short_conv" \
                 else gated_delta_net(a, cl)
             if c["sandwich_norm"]:
                 mixed = _norm(mixed, cl, "mixer_out_norm")
@@ -493,8 +627,17 @@ def causal_lm(cfg, seq_len, extras=None, recompute=True):
         states = [h]
     _count_passes(c)
 
+    _count_head(c)
+    tied = fluid.default_main_program().global_block().var("embedding") \
+        if c["tie_word_embeddings"] else None
+
     def head(state):
-        logits = _linear(state, c["vocab_size"], c, "head")
+        # tied: h E^T on the embedding's own [V, D] parameter; its gradient
+        # is the lookup's scatter-add plus this matmul's, summed where
+        # core/backward.py accumulates a variable's several uses
+        logits = layers.matmul(state, tied, transpose_y=True) \
+            if tied is not None else _linear(state, c["vocab_size"], c,
+                                             "head")
         return logits, layers.softmax_with_cross_entropy(
             logits=layers.reshape(logits, shape=[-1, c["vocab_size"]]),
             label=layers.reshape(labels, shape=[-1, 1]))
@@ -525,6 +668,15 @@ def causal_lm(cfg, seq_len, extras=None, recompute=True):
     elif aux:
         load = layers.sums([terms[2] for terms in aux])
     return loss, logits, load
+
+
+def _count_head(c):
+    from ..observability.registry import REGISTRY
+    REGISTRY.counter(
+        "ptpu_causal_lm_heads_total",
+        "models causal_lm built, by whether the output head reads the "
+        "embedding's parameter (tied) or has a matrix of its own"
+    ).inc(tied=str(bool(c["tie_word_embeddings"])).lower())
 
 
 def _count_passes(c):

@@ -16,7 +16,11 @@ token-by-token recurrence (`torch_recurrent_gated_delta_rule`). Each
 departure is marked "Departure:" below. For a looped model (Ouro,
 `model_type: ouro`; arXiv:2510.25741: total_ut_steps passes over the same
 layers, sandwich norms, an exit gate over the passes' losses) the layer is
-Hugging Face's `modeling_ouro.py` and the loss the paper's. `params` is the
+Hugging Face's `modeling_ouro.py` and the loss the paper's. For LFM2
+(`model_type: lfm2_moe`: gated short convolutions and attention layers by
+`layer_types`, leading dense layers, a sigmoid router whose top-k is chosen
+with an expert bias and weighed without it, a tied head) Hugging Face's
+`modeling_lfm2_moe.py`; it has no multi-token prediction. `params` is the
 list of the Program's parameters in the order models/causal_lm.py creates
 them.
 
@@ -116,6 +120,14 @@ def causal_conv(x, w):
     return sum(xp[:, m:m + t] * w[:, m] for m in range(width))
 
 
+def short_conv(a, w_in, w_conv, w_out):
+    """Lfm2MoeShortConv on a [B, T, D]: [B, C, u] = a w_in, chunked in that
+    order; v = B * u; c = the causal convolution of v under w_conv [D, K],
+    no bias, no activation, as K shifted multiply-adds; (C * c) w_out."""
+    b, gate, u = jnp.split(a @ w_in, 3, axis=-1)
+    return (gate * causal_conv(b * u, w_conv)) @ w_out
+
+
 def l2norm(x, eps=1e-6):
     return x * jax.lax.rsqrt(jnp.sum(x * x, -1, keepdims=True) + eps)
 
@@ -183,22 +195,38 @@ def gated_unit(m, wg, wu, c):
 
 
 def routed_experts(m, router, w_gate, w_up, w_down, c, router_x=None,
-                   first_expert=None):
+                   first_expert=None, expert_bias=None):
     """m [N, D] -> (out [N, D], balance term, z term, load [E] int32). The
     router reads router_x where it is given, m otherwise, and routes over
     all E columns; the experts computed are those whose weights are given,
     first_expert .. first_expert + len(w_gate) - 1 (c's own by default), and
-    `out` is their part of the sum."""
+    `out` is their part of the sum. With router_scoring sigmoid
+    (Lfm2MoeSparseMoeBlock) the scores are s = sigmoid(logits), the top k is
+    chosen over s + expert_bias [E] where there is one and weighed by s
+    itself, renormalised over the chosen with 1e-6 added to their sum, and
+    scaled by routed_scaling_factor; the two auxiliary terms are 0.
+
+    Departure: the expert bias is a buffer in `modeling_lfm2_moe.py`, moved
+    during pre-training by a rule the config does not give; here it is an
+    input that training does not move, and it has no gradient (lax.top_k's
+    indices carry none)."""
     n, e, k = m.shape[0], c["num_experts"], c["num_experts_per_tok"]
     first = c.get("first_expert", 0) if first_expert is None \
         else first_expert
     logits = (m if router_x is None else router_x) @ router
-    probs = jax.nn.softmax(logits, -1)
-    gate, idx = jax.lax.top_k(probs, k)
+    sigmoid = c.get("router_scoring", "softmax") == "sigmoid"
+    probs = jax.nn.sigmoid(logits) if sigmoid else jax.nn.softmax(logits, -1)
+    if expert_bias is None:
+        gate, idx = jax.lax.top_k(probs, k)
+    else:
+        _, idx = jax.lax.top_k(probs + expert_bias, k)
+        gate = jnp.take_along_axis(probs, idx, axis=-1)
     if c["norm_topk_prob"]:
         # a softmax over all E renormalised over the chosen k is the softmax
         # over the k chosen logits
-        gate = gate / gate.sum(-1, keepdims=True)
+        gate = gate / (gate.sum(-1, keepdims=True)
+                       + (1e-6 if sigmoid else 0.0))
+    gate = gate * c.get("routed_scaling_factor", 1)
 
     # Departure: HF gathers an expert's tokens and index_adds its outputs;
     # here every expert sees every token and a token's weight for an expert
@@ -212,6 +240,8 @@ def routed_experts(m, router, w_gate, w_up, w_down, c, router_x=None,
                             w_up, w_down)).sum(0)
     load = jnp.sum(idx[:, :, None] == jnp.arange(e), axis=(0, 1),
                    dtype=jnp.int32)
+    if sigmoid:
+        return out, 0.0, 0.0, load
     # Departure: HF pools the router probabilities of all layers before the
     # product and has no z term; these are the per-layer terms of
     # arXiv:2409.02060 (the same at depth 1)
@@ -256,24 +286,30 @@ def passes(cfg, params, ids, pos):
     e, eps = c["num_experts"], c["rms_norm_eps"]
     layers, centred = c["num_hidden_layers"], c["norm_zero_centered"]
     sandwich = c["sandwich_norm"]
+    routed = c["ffn_layers"].count("experts")   # the terms' mean is theirs
     embedding = take(1)[0]
     weights = []                # a layer: (N1, mixer, N2, N3, ffn, N4)
     for i in range(layers):
         n1 = take(1)[0]
         if c["mixer_layers"][i] == "gated_delta":
             mixer = take(7)
+        elif c["mixer_layers"][i] == "short_conv":
+            mixer = take(3)
         else:
             mixer = take(3) + (take(2) if c["qk_norm"] else [None, None]) \
                 + take(1)
         n2 = take(1)[0] if sandwich else None
         n3 = take(1)[0]
-        ffn = take(4 + (4 if c["shared_expert_intermediate_size"] else 0)) \
-            if e else take(3)
+        # experts: router, [expert bias], gate, up, down, [shared expert's 4]
+        ffn = take(3) if c["ffn_layers"][i] == "dense" else (
+            take(1) + (take(1) if c["use_expert_bias"] else [None])
+            + take(3 + (4 if c["shared_expert_intermediate_size"] else 0)))
         weights.append((n1, mixer, n2, n3, ffn,
                         take(1)[0] if sandwich else None))
     w_f = take(1)[0]
     w_g, b_g = take(2) if c["exit_gate"] else (None, None)
-    w_lm = take(1)[0]
+    # a tied head is the embedding read again, transposed
+    w_lm = embedding.T if c["tie_word_embeddings"] else take(1)[0]
     if next(params, None) is not None:
         raise ValueError("the reference read fewer parameters than the "
                          "program has: the two are not the same architecture")
@@ -289,22 +325,25 @@ def passes(cfg, params, ids, pos):
                 a = rms_norm(h, n1, eps, centred)
                 if c["mixer_layers"][i] == "gated_delta":
                     mixed = gated_delta_net(a, *mixer, c)
+                elif c["mixer_layers"][i] == "short_conv":
+                    mixed = short_conv(a, *mixer)
                 else:
                     mixed = attention(a, pos, *mixer, layer_config(c, i))
                 if sandwich:
                     mixed = rms_norm(mixed, n2, eps, centred)
                 h = h + mixed
                 m = rms_norm(h, n3, eps, centred)
-                if e:
+                if c["ffn_layers"][i] == "experts":
                     out, lb, lz, ld = routed_experts(
-                        m.reshape(b * t, d), *ffn[:4], c,
+                        m.reshape(b * t, d), ffn[0], *ffn[2:5], c,
                         router_x=a.reshape(b * t, d)
-                        if c["router_input"] == "pre_attention" else None)
+                        if c["router_input"] == "pre_attention" else None,
+                        expert_bias=ffn[1])
                     out = out.reshape(b, t, d)
                     if c["shared_expert_intermediate_size"]:
-                        out = out + shared_expert(m, *ffn[4:])
-                    balance, z, load = balance + lb / layers, \
-                        z + lz / layers, load + ld
+                        out = out + shared_expert(m, *ffn[5:])
+                    balance, z, load = balance + lb / routed, \
+                        z + lz / routed, load + ld
                 else:
                     wg, wu, wd = ffn
                     out = (jax.nn.silu(m @ wg) * (m @ wu)) @ wd

@@ -144,7 +144,10 @@ def _moe_ffn_lower(ctx, ins, attrs):
     from the float32 master weights; the op decides that here because one
     input, X, may feed both. RouterX, where the model gives it, is what the
     router reads instead of X; the weights' leading dimension is the experts
-    held, `first_expert` the index of the first."""
+    held, `first_expert` the index of the first. `scoring` (softmax where
+    absent, or sigmoid), ExpertBias [E] (added to the scores for the choice
+    of the top_k alone; an input without a gradient variable) and `scale`
+    are routed_ffn's."""
     from ..parallel.moe import routed_ffn
     x = single(ins, "X")
     router_x = single(ins, "RouterX") if ins.get("RouterX") else None
@@ -157,7 +160,11 @@ def _moe_ffn_lower(ctx, ins, attrs):
         router_x=None if router_x is None
         else router_x.reshape(-1, router_x.shape[-1]),
         activation=str(attrs.get("activation", "silu")),
-        first_expert=int(attrs.get("first_expert", 0)))
+        first_expert=int(attrs.get("first_expert", 0)),
+        scoring=str(attrs.get("scoring", "softmax")),
+        expert_bias=single(ins, "ExpertBias") if ins.get("ExpertBias")
+        else None,
+        scale=float(attrs.get("scale", 1.0)))
     return {"Out": [out.reshape(x.shape)], "BalanceLoss": [balance],
             "ZLoss": [z], "ExpertLoad": [load]}
 

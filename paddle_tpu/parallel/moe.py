@@ -302,9 +302,43 @@ def _gated(gate, up, activation):
     return (_gated_silu if activation == "silu" else _gated_relu)(gate, up)
 
 
+# what a sigmoid router's renormalisation adds to the chosen scores' sum
+# (modeling_lfm2_moe.py; a softmax router's sum cannot vanish and adds nothing)
+SIGMOID_NORM_EPS = 1e-6
+
+
+def _route(logits, top_k, norm_topk_prob, scoring, expert_bias, scale):
+    """(scores [N, E], the logits' logsumexp [N] or None under sigmoid, the
+    chosen experts' weights [N, top_k], their indices) from float32 router
+    logits [N, E]: `routed_ffn`'s choice, by its docstring."""
+    if scoring not in ("softmax", "sigmoid"):
+        raise ValueError("routed_ffn scoring must be 'softmax' or 'sigmoid', "
+                         "got %r" % (scoring,))
+    if scoring == "softmax":
+        lse = jax.nn.logsumexp(logits, axis=-1)
+        probs = jnp.exp(logits - lse[:, None])
+    else:
+        lse, probs = None, jax.nn.sigmoid(logits)
+    if expert_bias is None:
+        gate, expert = jax.lax.top_k(probs, top_k)         # [N, top_k]
+    else:
+        _, expert = jax.lax.top_k(
+            probs + expert_bias.astype(jnp.float32), top_k)
+        gate = jnp.take_along_axis(probs, expert, axis=-1)
+    if norm_topk_prob:
+        total = gate.sum(-1, keepdims=True)
+        if scoring == "sigmoid":
+            total = total + SIGMOID_NORM_EPS
+        gate = gate / total
+    if scale != 1.0:
+        gate = gate * scale
+    return probs, lse, gate, expert
+
+
 def routed_ffn(x, router, w_gate, w_up, w_down, top_k, norm_topk_prob=False,
                expert_dtype=None, router_x=None, activation="silu",
-               first_expert=0):
+               first_expert=0, scoring="softmax", expert_bias=None,
+               scale=1.0):
     """Dropless top-k routed gated experts over tokens x [N, D].
 
     router [D, E]; w_gate, w_up [H, D, F]; w_down [H, F, D]; no bias. The
@@ -362,6 +396,17 @@ def routed_ffn(x, router, w_gate, w_up, w_down, top_k, norm_topk_prob=False,
     None; bfloat16 under AMP) and the top_k weighted outputs of a token are
     summed in float32.
 
+    `scoring` "softmax" scores a token's experts by the softmax of its
+    router logits, "sigmoid" by s = sigmoid(logits), each expert on its own.
+    `expert_bias` [E], where given, enters the choice and nothing else: the
+    top_k is over s + b and the weights are gathered from s, so the bias
+    moves which experts run and never how much one counts, and it has no
+    gradient (its only use is an argument of top_k; the router's gradient
+    comes through s). With norm_topk_prob a sigmoid router divides by the
+    chosen scores' sum + SIGMOID_NORM_EPS. `scale` multiplies the weights
+    last. Under sigmoid scoring the balance and z terms are zeros: both are
+    defined on a softmax's probabilities and its logsumexp.
+
     Returns (out [N, D] in the experts' dtype,
              load-balance term [1]: E * sum_e (c_e / N) * mean_n p[n, e],
              router z term [1]: mean_n logsumexp(logits[n])^2,
@@ -377,11 +422,8 @@ def routed_ffn(x, router, w_gate, w_up, w_down, top_k, norm_topk_prob=False,
     logits = jnp.dot((x if router_x is None else router_x)
                      .astype(jnp.float32), router.astype(jnp.float32),
                      precision=jax.lax.Precision.HIGHEST)
-    lse = jax.nn.logsumexp(logits, axis=-1)
-    probs = jnp.exp(logits - lse[:, None])
-    gate, expert = jax.lax.top_k(probs, top_k)             # [N, top_k]
-    if norm_topk_prob:
-        gate = gate / gate.sum(-1, keepdims=True)
+    probs, lse, gate, expert = _route(logits, top_k, norm_topk_prob, scoring,
+                                      expert_bias, scale)
 
     # assignment a = j * N + n (slot-major); `order` lists the assignments by
     # expert (stable, so by slot then token inside an expert), `rank` is
@@ -407,6 +449,9 @@ def routed_ffn(x, router, w_gate, w_up, w_down, top_k, norm_topk_prob=False,
     y = _grouped_matmul(hidden, w_down.astype(dtype), sizes)
     out = _combine(y, gate, order, rank, total)
 
+    if scoring == "sigmoid":
+        return out, jnp.zeros((1,), jnp.float32), \
+            jnp.zeros((1,), jnp.float32), load
     balance = e * jnp.sum(load.astype(jnp.float32) / n * probs.mean(0))
     z = jnp.mean(jnp.square(lse))
     return out, balance.reshape(1), z.reshape(1), load

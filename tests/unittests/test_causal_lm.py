@@ -57,7 +57,8 @@ def _moe_layers_lowered():
     from paddle_tpu.parallel.moe import GROUPED_MATMUL
     return REGISTRY.counter("ptpu_moe_layers_total", "").value(
         top_k="2", experts="8", held="8", activation="silu",
-        router_input="own", path=GROUPED_MATMUL, rows="all")
+        router_input="own", path=GROUPED_MATMUL, rows="all",
+        scoring="softmax", bias="false", scale="1")
 
 
 def _run_program(amp):
@@ -169,8 +170,9 @@ def test_builder_refuses_what_it_cannot_build():
         causal_lm.resolve(dict(CFG, num_key_value_heads=3))
     with pytest.raises(NotImplementedError, match="rope_scaling"):
         causal_lm.resolve(dict(CFG, rope_scaling={"type": "yarn"}))
+    # a tied head is built since PR 39; the key is a boolean
     with pytest.raises(NotImplementedError, match="tie_word_embeddings"):
-        causal_lm.resolve(dict(CFG, tie_word_embeddings=True))
+        causal_lm.resolve(dict(CFG, tie_word_embeddings="input_only"))
 
 
 def test_dense_swiglu_variant_trains():

@@ -64,7 +64,8 @@ def _counts():
     return {
         "moe": _counter("ptpu_moe_layers_total", top_k="3", experts="8",
                         held="4", activation="silu", router_input="own",
-                        path=GROUPED_MATMUL, rows="held"),
+                        path=GROUPED_MATMUL, rows="held",
+                        scoring="softmax", bias="false", scale="1"),
         "full": _counter("ptpu_attention_layers_total", kind="full",
                          window="0", q_heads="4", kv_heads="2", path="dense",
                          head_dim="16", heads_a_block="none"),
@@ -75,11 +76,12 @@ def _counts():
                           path="scan"),
         "built_full": _counter("ptpu_causal_lm_layers_total",
                                mixer="attention", rotary_dim="4", gate="true",
-                               conv="0", shared="8", sandwich="false"),
+                               conv="0", ffn="experts", shared="8",
+                               sandwich="false"),
         "built_delta": _counter("ptpu_causal_lm_layers_total",
                                 mixer="gated_delta", rotary_dim="0",
-                                gate="false", conv="4", shared="8",
-                                sandwich="false")}
+                                gate="false", conv="4", ffn="experts",
+                                shared="8", sandwich="false")}
 
 
 def _run_program(amp, pallas=None, monkeypatch=None, cfg=CFG, t=T):

@@ -63,7 +63,8 @@ def _counts():
         "moe": _counter("ptpu_moe_layers_total", top_k="3", experts="16",
                         held="8", activation="relu",
                         router_input="pre_attention", path=GROUPED_MATMUL,
-                        rows="held"),
+                        rows="held", scoring="softmax", bias="false",
+                        scale="1"),
         "full": _counter("ptpu_attention_layers_total", kind="full",
                          window="0", q_heads="4", kv_heads="2", path="dense",
                          head_dim="8", heads_a_block="none"),
@@ -126,7 +127,7 @@ def test_resolve_maps_smallthinkers_keys():
     plain = causal_lm.resolve(dict(
         vocab_size=8, hidden_size=8, num_hidden_layers=2,
         num_attention_heads=2, intermediate_size=4))
-    assert causal_lm._layer(plain, 1) == dict(plain, layer=1)
+    assert causal_lm._layer(plain, 1) == dict(plain, layer=1, ffn="dense")
     assert plain["head_dim"] == 4 and plain["experts_held"] == 0
 
 
@@ -251,21 +252,31 @@ def test_routed_ffn_reads_the_router_elsewhere(activation):
     assert _error(got[0], own[0]) > 0.1     # the router's input matters
 
 
-def test_four_shares_of_four_experts_sum_to_the_layer():
+@pytest.mark.parametrize("routing", ["softmax", "sigmoid_with_bias"])
+def test_four_shares_of_four_experts_sum_to_the_layer(routing):
     """Forward and every gradient: each share routes over all 16, computes
     its 4, and the four partial sums (and the four gradients of x and of the
-    router's input) add up to what the uncut layer gives."""
+    router's input) add up to what the uncut layer gives. Under a softmax
+    router, and under a sigmoid one whose top 6 is chosen with an expert
+    bias (PR 39): the bias, like the router, keeps all 16 columns in every
+    share."""
     x, a, router, wg, wu, wd = _layer_inputs(2)
+    more, c = {}, REF_C
+    if routing == "sigmoid_with_bias":
+        bias = jnp.asarray(np.random.RandomState(4).randn(16) * 0.3,
+                           jnp.float32)
+        more = dict(scoring="sigmoid", expert_bias=bias, scale=1.5)
+        c = dict(REF_C, router_scoring="sigmoid", routed_scaling_factor=1.5)
 
     def share(i, x, a, router, wg, wu, wd):
         sl = slice(4 * i, 4 * i + 4)
         return moe.routed_ffn(x, router, wg[sl], wu[sl], wd[sl], top_k=6,
                               norm_topk_prob=True, router_x=a,
-                              activation="relu", first_expert=4 * i)
+                              activation="relu", first_expert=4 * i, **more)
 
     def whole(x, a, router, wg, wu, wd):
-        return reference.routed_experts(x, router, wg, wu, wd, REF_C,
-                                        router_x=a)
+        return reference.routed_experts(x, router, wg, wu, wd, c, router_x=a,
+                                        expert_bias=more.get("expert_bias"))
 
     args = (x, a, router, wg, wu, wd)
     g = jnp.asarray(np.random.RandomState(3).randn(*x.shape), jnp.float32)
