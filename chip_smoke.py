@@ -64,6 +64,13 @@ FULL = {
         # window of half the sequence
         attn_window=dict(b=1, t=4096, h=7, hkv=1, d=128, window=2048),
         moe=dict(n=4096, d=512, f=256),
+        # one expert layer of the SmallThinker and Qwen3-Next cells: tokens,
+        # hidden, experts routed over and held, their width, experts a token
+        moe_layers=(
+            dict(name="SmallThinker", n=8192, d=2560, e=64, held=16, f=768,
+                 top_k=6, activation="relu", scoring="softmax"),
+            dict(name="Qwen3-Next", n=4096, d=2048, e=512, held=32, f=512,
+                 top_k=10, activation="silu", scoring="softmax")),
         # Qwen3-Next's gated delta rule at its cell's shapes: one sequence
         # of 4096, 16 key heads on 32 value heads of 128
         gated_delta=dict(b=1, t=4096, hk=16, hv=32, d=128),
@@ -102,6 +109,8 @@ TINY = {
         attn=(dict(b=2, t=32, h=2, d=64), dict(b=2, t=32, h=2, d=16)),
         attn_window=dict(b=2, t=40, h=4, hkv=2, d=16, window=12),
         moe=dict(n=64, d=16, f=8),
+        moe_layers=(dict(name="tiny", n=64, d=128, e=8, held=2, f=64,
+                         top_k=3, activation="relu", scoring="softmax"),),
         gated_delta=dict(b=2, t=40, hk=2, hv=4, d=16),
         causal_conv=dict(b=2, t=64, c=256, width=4),
         xent=dict(n=32, v=64),
@@ -671,6 +680,103 @@ def _held_experts_case(smoke, c, tol):
                 "(tol %g)" % (errs, limit))
 
 
+def _slot_sum_of_pr_32(rows, rank, total, slots, gate=None):
+    """A token's sum as PR 32 left it where a share is held: the gather of
+    all top_k * N rows by `rank`, the unheld ones selected away, a float32
+    sum slot by slot. Kept here so that the form PR 40 replaced can be timed
+    beside the one in parallel/moe.py."""
+    import jax.numpy as jnp
+    by_slot = rows[rank].reshape(slots, -1, rows.shape[1])
+    held = (rank < total).reshape(slots, -1, 1)
+    acc = 0.0
+    for j in range(slots):
+        term = jnp.where(held[j], by_slot[j], 0).astype(jnp.float32)
+        acc = acc + (term if gate is None else term * gate[j][:, None])
+    return acc.astype(rows.dtype)
+
+
+def _held_layer_times(smoke, c, expert_bias=None):
+    """One expert layer of which a share is held, alone at a cell's sizes,
+    bf16 experts as under AMP: the token-side sum alone, weighted
+    (`_combine` forward) and plain (the backward of the rows' dispatch), in
+    PR 32's form (a gather of all top_k * N rows) and in parallel/moe.py's
+    (`_token_sum`: the held rows only), the two compared; then the layer
+    forward and forward + backward. Times for the next reader (no metric);
+    PERF.md section 6, PR 40, quotes them. Sub-millisecond times taken this
+    way hold the host's dispatch."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.parallel import moe
+
+    n, d, e, held, f, k = (c[key] for key in ("n", "d", "e", "held", "f",
+                                              "top_k"))
+    rng = np.random.RandomState(29)
+    x, g = (jnp.asarray(rng.randn(n, d), jnp.bfloat16) for _ in range(2))
+    router = jnp.asarray(rng.randn(d, e) * 0.02, jnp.float32)
+    wg, wu = (jnp.asarray(rng.randn(held, d, f) * 0.02, jnp.float32)
+              for _ in range(2))
+    wd = jnp.asarray(rng.randn(held, f, d) * 0.02, jnp.float32)
+    rows = jnp.asarray(rng.randn(k * n, d), jnp.bfloat16)
+
+    def integers(x, router):
+        """gate [top_k, N], rank [A] and the held rows, as routed_ffn makes
+        them for experts 0 .. held - 1."""
+        logits = jnp.dot(x.astype(jnp.float32), router,
+                         precision=jax.lax.Precision.HIGHEST)
+        _, _, gate, expert = moe._route(logits, k, True, c["scoring"],
+                                        expert_bias, 1.0)
+        expert = expert.T.reshape(-1)
+        order = jnp.argsort(jnp.where(expert < held, expert, held),
+                            stable=True)
+        return gate.T, jnp.argsort(order), jnp.sum(expert < held)
+
+    def layer(x, router, wg, wu, wd):
+        return moe.routed_ffn(
+            x, router, wg, wu, wd, k, True, expert_dtype=jnp.bfloat16,
+            activation=c["activation"], scoring=c["scoring"],
+            expert_bias=expert_bias)[0]
+
+    def trained(x, router, wg, wu, wd, g):
+        return jax.vjp(layer, x, router, wg, wu, wd)[1](g)
+
+    def new(rows, rank, total, gate=None):
+        return moe._token_sum((rows,), rank,
+                              moe._token_places(rank, total, k), gate,
+                              tile=moe.SUM_TILE)
+
+    def old(rows, rank, total, gate=None):
+        return _slot_sum_of_pr_32(rows, rank, total, k, gate)
+
+    with jax.default_device(smoke.device):
+        gate, rank, total = jax.jit(integers)(x, router)
+        times, worst = {}, 0.0
+        for form, fn in (("old", old), ("new", new)):
+            for name, args in (("weighted", (rows, rank, total, gate)),
+                               ("plain", (rows, rank, total))):
+                times[form, name] = _in_flight_ms(jax.jit(fn), args)
+        for args in ((rows, rank, total, gate), (rows, rank, total)):
+            want = jax.jit(old)(*args).astype(jnp.float32)
+            got = jax.jit(new)(*args).astype(jnp.float32)
+            worst = max(worst, float(jnp.abs(got - want).max()
+                                     / jnp.abs(want).max()))
+        if worst > 2.0 ** -7:       # both round a float32 sum to bf16 once
+            raise AssertionError(
+                "the token-side sum over the held rows is %.2e off the "
+                "gather of all rows" % worst)
+        args = (x, router, wg, wu, wd)
+        smoke.say(
+            "expert layer at %s's sizes, %d tokens of %d, top-%d of %d, %d "
+            "held of width %d, %d of %d assignments held: a token's sum "
+            "alone, weighted / plain, as a gather of all rows (PR 32) %.3f "
+            "/ %.3f ms, over the held rows (%.2e apart at most) %.3f / %.3f "
+            "ms; the layer forward %.3f ms, forward + backward %.3f ms"
+            % (c["name"], n, d, k, e, held, f, total, k * n,
+               times["old", "weighted"], times["old", "plain"], worst,
+               times["new", "weighted"], times["new", "plain"],
+               _in_flight_ms(jax.jit(layer), args),
+               _in_flight_ms(jax.jit(trained), args + (g,))))
+
+
 def _gated_delta_case(smoke, c, tol):
     """ops/gated_delta_kernels.py on this device at the Qwen3-Next cell's
     shapes, bf16 operands as under AMP: the chunked forward and backward on
@@ -813,6 +919,9 @@ def phase_c(smoke):
     runs.append(("routed_ffn with a share of the experts",
                  lambda: _held_experts_case(smoke, smoke.cfg["kernels"]["moe"],
                                             TOL["attn"])))
+    for c in smoke.cfg["kernels"]["moe_layers"]:
+        runs.append(("routed_ffn's passes over the held rows, %s" % c["name"],
+                     lambda c=c: _held_layer_times(smoke, c)))
     runs.append(("gated_delta_rule against the recurrence",
                  lambda: _gated_delta_case(
                      smoke, smoke.cfg["kernels"]["gated_delta"],
@@ -1219,6 +1328,10 @@ def phase_h(smoke):
                _in_flight_ms(jax.jit(layer), args),
                _in_flight_ms(jax.jit(trained), args),
                _in_flight_ms(jax.jit(routing), (x, router)), err, moved))
+    _held_layer_times(
+        smoke, dict(name="LFM2", n=t, d=d, e=e, held=held, f=c["f"],
+                    top_k=c["top_k"], activation="silu", scoring="sigmoid"),
+        expert_bias=bias)
 
 
 PHASES = (("A", "ResNet-50 training", phase_a),

@@ -823,8 +823,10 @@ def _count_moe_layer(attrs, ins):
         "experts a token, experts routed over, experts held, the gate's "
         "activation, what the router reads (the experts' own input or "
         "another tensor, pre_attention), the grouped-matmul route, the "
-        "rows of the slot-major buffer the expert-side gathers move (all, "
-        "or the tiles of the held assignments), how the router scores "
+        "rows of the slot-major buffer that every pass between the router "
+        "and the layer's output touches (all, or the tiles of the held "
+        "assignments: the four permutations, the two d rows' sum and the "
+        "gate's transpose), how the router scores "
         "(softmax or sigmoid), whether an expert bias enters the choice of "
         "the top_k and the factor that scales the weights"
     ).inc(top_k=str(attrs["top_k"]), experts=str(experts), held=str(held),

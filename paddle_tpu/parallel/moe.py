@@ -19,9 +19,18 @@ is spent on an expert a token did not choose. It can be told which experts
 it holds: it then routes over all of them and computes its own share of the
 layer's output, as one chip of an expert-parallel layer would, without the
 exchange. Its row buffer (top_k * N rows) is numbered slot-major, so that a
-token's slots are summed without a relayout, and of the sorted rows only the
-tiles that hold a held assignment are gathered (`_dispatch`, `_combine`).
+token's slots are summed without a relayout. Where a share is held, the
+passes between the router and the layer's output touch the held rows only
+(all but `_gated` forward, one elementwise pass), in loops whose trip count
+is the held assignments' (`_held_experts`, `_combine`): the sorted rows are
+gathered and the output's gradient weighted a tile of held rows at a time
+(`_held_rows`, `_held_weighted`), a token's held rows are gathered and
+summed by a one-hot matmul a tile of token-major places at a time
+(`_token_sum`), and `_gated`'s transpose runs in the held tiles
+(`_held_gated_transpose`).
 """
+import functools
+
 import numpy as np
 
 import jax
@@ -139,10 +148,12 @@ def _grouped_matmul(lhs, rhs, group_sizes):
 
 
 def rows_moved(experts, held):
-    """Which rows of the top_k * N row buffer `routed_ffn`'s two expert-side
-    gathers move, decided from the shapes: "all" where every expert is held
-    (every row is in a group), "held" where a share is (the tiles below the
-    held assignments' count, a run-time value)."""
+    """Which rows of the top_k * N row buffer `routed_ffn`'s passes touch,
+    decided from the shapes: "all" where every expert is held (every row is
+    in a group: four gathers of the buffer and the elementwise passes XLA
+    makes of the rest), "held" where a share is: the four permutations, the
+    sum of the two d rows and `_gated`'s transpose all run in the tiles
+    below the held assignments' count, a run-time value."""
     return "all" if held == experts else "held"
 
 
@@ -153,15 +164,34 @@ def rows_moved(experts, held):
 # 44,269, 4096 44,213. Half a tile is gathered for nothing; a trip costs little.
 ROW_TILE = 1024
 
+# Places a trip of the token-side sum handles (`_token_sum`): its gather's rows
+# and the side of its one-hot matmul, whose operations grow with the square.
+# One sum alone, weighted / plain, ms (my chip runs, PR 40), with the slots
+# taken one by one: at SmallThinker's sizes (13,557 places of 49,152) 256 1.47 /
+# 1.24, 512 1.66 / 1.19; Qwen3-Next's (4,664 of 40,960) 256 0.53 / 0.43, 512
+# 0.61 / 0.42; LFM2's (10,654 of 32,768) 256 0.95 / 0.80, 512 1.02 / 0.73; 128
+# and 384 were slower in an earlier form. A layer forward and backward moved by
+# under 0.1 ms from 128 to 512. With the slots taken together, at 256: 1.44 /
+# 1.21, 0.48 / 0.41, 0.93 / 0.79.
+SUM_TILE = 256
 
-def _held_tiles(rows, total, one_tile, carry):
-    """carry = one_tile(start, tile, live [tile, 1], carry) for each tile of
-    the `rows` sorted rows that holds a row below `total`: ceil(total / tile)
-    trips, a number known at run time only (a `while`, which has no automatic
-    gradient: the callers are `custom_vjp` rules). `rows` need not be a
-    multiple of the tile: the last trip then starts at rows - tile and
-    writes some rows a second time, with the same values."""
-    tile = min(ROW_TILE, rows)
+
+# The four loops over the held rows (`_held_rows`, `_token_sum`,
+# `_held_weighted`, `_held_gated_transpose`) are jitted with their tile static:
+# a model's expert layers are alike, so a step is traced and lowered with one
+# copy of each loop and a call a layer, which XLA inlines. Traced anew in every
+# layer the SmallThinker cell's warm set-up read 18.4 s against the parent's
+# 16.2, so 17.1 (my chip runs, PR 40). What a loop reads of the module it is
+# handed as an argument: a trace is kept under its arguments.
+def _held_tiles(rows, total, one_tile, carry, tile):
+    """carry = one_tile(start, tile, live [tile, 1], carry) for each tile
+    (`ROW_TILE` rows) of the `rows` sorted rows that holds a row below
+    `total`: ceil(total / tile) trips, a number known at run time only (a
+    `while`, which has no automatic gradient: the callers are `custom_vjp`
+    rules). `rows` need not be a multiple of the tile: the last trip then
+    starts at rows - tile and meets some rows a second time
+    (`_first_time`)."""
+    tile = min(tile, rows)
 
     def trip(i, carry):
         start = jnp.minimum(i * tile, rows - tile)
@@ -171,41 +201,186 @@ def _held_tiles(rows, total, one_tile, carry):
     return jax.lax.fori_loop(0, (total + tile - 1) // tile, trip, carry)
 
 
-def _slot_sum(rows, rank, total, slots, gate=None):
-    """rows [A, D] by sorted row -> [N, D] in their dtype: the float32 sum
-    over a token's `slots` assignments of the row (times gate [slots, N],
-    where given). The gather by `rank` lays the rows slot-major, which is
-    [slots, N, D] as it stands, and the sum is accumulated slot by slot: no
-    relayout, and no float32 copy of the buffer. An assignment that is not
-    held is selected away, not multiplied by zero: its row may hold
-    anything."""
+def _met(buffer, start, tile):
+    """The tile at `start` of a buffer that the trip goes on to write over,
+    read once: behind a barrier, so that XLA does not fuse the slice into
+    several consumers that each read the buffer, which it then copies whole
+    in every trip before the update (AOT compile of the Qwen3-Next cell's
+    step, PR 40)."""
+    return jax.lax.optimization_barrier(jax.lax.dynamic_slice(
+        buffer, (start, 0), (tile, buffer.shape[1])))
+
+
+def _first_time(start, tile):
+    """[tile, 1]: the rows of the tile at `start` that no earlier trip of
+    `_held_tiles` met. All of them, but in a last trip moved back to end
+    with the buffer. A trip that writes what it computed from the carry
+    itself keeps the others as they are."""
+    return (start + jnp.arange(tile) >= -(-start // tile) * tile)[:, None]
+
+
+def _slot_sum(rows, rank, slots, gate=None):
+    """Every expert held. rows [A, D] by sorted row -> [N, D] in their dtype:
+    the float32 sum over a token's `slots` assignments of the row (times gate
+    [slots, N], where given). The gather by `rank` lays the rows slot-major,
+    which is [slots, N, D] as it stands, and the sum is accumulated slot by
+    slot: no relayout, and no float32 copy of the buffer."""
     by_slot = rows[rank].reshape(slots, -1, rows.shape[1])
-    held = None if total is None else (rank < total).reshape(slots, -1, 1)
     acc = 0.0
     for j in range(slots):
-        # the select a slot, so that it fuses into the sum: over the whole
-        # buffer it is a pass of its own (1.5 ms a layer, my chip run, PR 32)
-        term = by_slot[j] if held is None else jnp.where(held[j], by_slot[j],
-                                                         0)
-        term = term.astype(jnp.float32)
+        term = by_slot[j].astype(jnp.float32)
         acc = acc + (term if gate is None else term * gate[j][:, None])
-    # row-major, as the gather made the rows: where the consumer wants the
-    # tokens minor (a [1, T, D] residual stream on the v5e), XLA otherwise
-    # carries that layout back through the sum and transposes the [A, D]
-    # buffer instead of the [N, D] result (AOT compile, PR 32)
-    return with_layout_constraint(acc.astype(rows.dtype),
-                                  Layout(major_to_minor=(0, 1)))
+    return _row_major(acc.astype(rows.dtype))
+
+
+def _row_major(out):
+    """[N, D] row-major, as the passes over the rows made it: where the
+    consumer wants the tokens minor (a [1, T, D] residual stream on the
+    v5e), XLA otherwise carries that layout back through the sum and
+    transposes the [A, D] buffer instead of the [N, D] result (AOT compile,
+    PR 32)."""
+    return with_layout_constraint(out, Layout(major_to_minor=(0, 1)))
+
+
+def _token_places(rank, total, slots):
+    """Where a share is held: the held assignments numbered token-major (a
+    token's held slots adjacent in slot order, tokens ascending), on [N]
+    integers only: no sort, no row moves. A token with no held assignment
+    takes one place all the same (it reads nothing), so that p places never
+    span more than p tokens and miss none.
+
+    rank [A] by slot-major assignment, `total` the held ones (rank < total)
+    -> (held [slots, N] bool, begin [N] the first place of each token, count
+    the places: total + the tokens without)."""
+    held = (rank < total).reshape(slots, -1)
+    taken = jnp.maximum(jnp.sum(held, axis=0, dtype=jnp.int32), 1)
+    end = jnp.cumsum(taken)
+    return held, end - taken, end[-1]
+
+
+def _bf16_terms(w):
+    """float32 w as three bfloat16 terms whose sum is w (24 bits of
+    mantissa in three times 8): a bfloat16 product with each is exact in
+    float32."""
+    terms = []
+    for _ in range(3):
+        terms.append(w.astype(jnp.bfloat16))
+        w = w - terms[-1].astype(jnp.float32)
+    return terms
+
+
+@functools.partial(jax.jit, static_argnames=("tile",))
+def _token_sum(parts, rank, places, gate=None, *, tile):
+    """A share held. parts: arrays [A, D] by sorted row whose sum, formed in
+    their dtype as `add_any` would, is the row (the two that the transposes
+    of `w_gate`'s and `w_up`'s matmuls return, added where they are read and
+    not in a pass of their own) -> [N, D] in that dtype: the float32 sum
+    over a token's HELD assignments of the row (times gate [slots, N]
+    float32, where given), touching the held rows only: ceil(count /
+    SUM_TILE) trips over the places of `_token_places`.
+
+    A trip takes SUM_TILE places, which are slots of at most SUM_TILE
+    consecutive tokens starting at the token its first place belongs to. It
+    works out on that window of [slots, SUM_TILE] integers which slot each
+    place is, gathers the places' rows, and sums them by token with one
+    matmul: the left operand [tokens, places] has a place's weight in its
+    token's row and zeros elsewhere, the accumulation is float32, and the
+    [SUM_TILE, D] result is written at the window's first row (the rows
+    past the trip's last token are zeros that later trips write over). The
+    product of a float32 weight and a row is formed in float32 and never
+    rounded before the sum: bfloat16 rows meet the weight as three bfloat16
+    terms (three blocks of the left operand, exact products, the three
+    results added), float32 rows a float32 operand at full precision. A
+    token whose places straddle two trips is carried in float32 from one
+    to the next, which writes its row again, whole. No row from `total` on
+    is read."""
+    held, begin, count = places
+    slots, n = held.shape
+    d, dtype = parts[0].shape[1], parts[0].dtype
+    exact = dtype == jnp.bfloat16
+    tile = min(tile, slots * n)
+    # the token each trip starts in (the last one whose first place is not
+    # past the trip's), and one more for the trip after the last
+    starts = jnp.arange(-(-slots * n // tile) + 1, dtype=jnp.int32) * tile
+    first_of = jnp.sum(begin <= starts[:, None], axis=1, dtype=jnp.int32) - 1
+    # windows of `tile` tokens are sliced anywhere up to the last token
+    held = jnp.pad(held, ((0, 0), (0, tile)))
+    sorted_row = jnp.pad(rank.reshape(slots, n).astype(jnp.int32),
+                         ((0, 0), (0, tile)))
+    begin = jnp.pad(begin, (0, tile))
+    weight = None if gate is None else jnp.pad(gate, ((0, 0), (0, tile)))
+    place = jnp.arange(tile)[:, None]
+
+    def trip(i, carry):
+        out, partial = carry
+        start, first = i * tile, first_of[i]
+
+        def window(v):
+            return jax.lax.dynamic_slice(v, (0, first), (slots, tile))
+
+        here, at_row = window(held), window(sorted_row)
+        b = jax.lax.dynamic_slice(begin, (first,), (tile,))
+        # a token's held slots take its places in slot order; one without
+        # takes one place, which no slot is
+        taken = here.at[0].set(here[0] | ~here.any(0)).astype(jnp.int32)
+        at = b - start + jnp.cumsum(taken, axis=0) - taken   # [slots, tokens]
+        # [slots, places, tokens], the tokens in the lanes as the windows
+        # have them: a place is at most one slot of one token
+        is_place = (at[:, None] == place) & here[:, None]
+        read = jnp.sum(jnp.where(is_place, at_row[:, None] + 1, 0),
+                       axis=(0, 2)) - 1
+        lhs = jnp.sum(
+            jnp.where(is_place, 1 if gate is None else window(weight)[:, None],
+                      0), axis=0, dtype=dtype if gate is None else jnp.float32)
+        part = jnp.where((read >= 0)[:, None],
+                         sum(p[jnp.maximum(read, 0)] for p in parts), 0)
+        blocks = _bf16_terms(lhs) if exact and gate is not None else [lhs]
+        sums = jax.lax.dot_general(
+            jnp.concatenate(blocks, axis=1), part, (((0,), (0,)), ((), ())),
+            precision=None if exact else jax.lax.Precision.HIGHEST,
+            preferred_element_type=jnp.float32)
+        sums = sum(sums[k * tile:(k + 1) * tile] for k in range(len(blocks)))
+        sums = sums.at[0].add(jnp.where(b[0] < start, partial, 0))
+        carried = jnp.clip(first_of[i + 1] - first, 0, tile - 1)
+        return (jax.lax.dynamic_update_slice(out, sums.astype(dtype),
+                                             (first, 0)),
+                jax.lax.dynamic_index_in_dim(sums, carried, 0,
+                                             keepdims=False))
+
+    out, _ = jax.lax.fori_loop(
+        0, (count + tile - 1) // tile, trip,
+        (jnp.zeros((n + tile, d), dtype), jnp.zeros((d,), jnp.float32)))
+    return _row_major(out[:n])
 
 
 @jax.custom_vjp
-def _dispatch(x, order, rank, total):
-    """x [N, D]; order [A] the assignment (slot-major: a = slot * N + token,
-    A = top_k * N) at each sorted row, rank [A] its inverse; `total` the
-    sorted rows that are held (None, at trace time: all). -> [A, D], sorted
-    row r is x[order[r] % N] below `total` and zero from there on."""
+def _dispatch(x, order, rank):
+    """Every expert held. x [N, D]; order [A] the assignment (slot-major: a
+    = slot * N + token, A = top_k * N) at each sorted row, rank [A] its
+    inverse. -> [A, D], sorted row r is x[order[r] % N]."""
+    return x[order % x.shape[0]]
+
+
+def _dispatch_fwd(x, order, rank):
+    return _dispatch(x, order, rank), (rank, order.shape[0] // x.shape[0])
+
+
+def _dispatch_bwd(res, g):
+    """A token's gradient is the sum of its assignments' rows."""
+    rank, slots = res
+    return _slot_sum(g, rank, slots), None, None
+
+
+_dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
+
+
+@functools.partial(jax.jit, static_argnames=("tile",))
+def _held_rows(x, order, total, *, tile):
+    """A share held. x [N, D], order as in `_dispatch`, `total` the sorted
+    rows that are held. -> [A, D], sorted row r is x[order[r] % N] below
+    `total` and zero from there on: only the tiles below `total` are
+    gathered."""
     token = order % x.shape[0]
-    if total is None:
-        return x[token]
 
     def one_tile(start, tile, live, rows):
         t = jax.lax.dynamic_slice(token, (start,), (tile,))
@@ -213,71 +388,74 @@ def _dispatch(x, order, rank, total):
             rows, jnp.where(live, x[t], 0), (start, 0))
 
     return _held_tiles(order.shape[0], total, one_tile,
-                       jnp.zeros((order.shape[0], x.shape[1]), x.dtype))
-
-
-def _dispatch_fwd(x, order, rank, total):
-    return _dispatch(x, order, rank, total), (
-        rank, total, order.shape[0] // x.shape[0])
-
-
-def _dispatch_bwd(res, g):
-    """A token's gradient is the sum of its held assignments' rows; a row
-    past `total` holds whatever the matmuls' transposes left there."""
-    rank, total, slots = res
-    return _slot_sum(g, rank, total, slots), None, None, None
-
-
-_dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
+                       jnp.zeros((order.shape[0], x.shape[1]), x.dtype), tile)
 
 
 @jax.custom_vjp
-def _combine(y, gate, order, rank, total):
+def _combine(y, gate, order, rank, total, places):
     """y [A, D] the experts' outputs by sorted row, gate [top_k, N] float32
-    the assignments' weights; order, rank and `total` as in `_dispatch`.
-    -> [N, D] in y's dtype: the float32 sum over a token's slots of weight
-    times output (`_slot_sum`)."""
-    return _slot_sum(y, rank, total, gate.shape[0], gate)
+    the assignments' weights; order and rank as in `_dispatch`; `total` the
+    sorted rows that are held and `places` their token-major numbering
+    (`_token_places`), both None where every expert is held. -> [N, D] in
+    y's dtype: the float32 sum over a token's slots of weight times output
+    (`_slot_sum`; over its held slots, `_token_sum`)."""
+    if total is None:
+        return _slot_sum(y, rank, gate.shape[0], gate)
+    return _token_sum((y,), rank, places, gate, tile=SUM_TILE)
 
 
-def _combine_fwd(y, gate, order, rank, total):
-    return _combine(y, gate, order, rank, total), (y, gate, order, rank,
-                                                   total)
+def _combine_fwd(y, gate, order, rank, total, places):
+    return _combine(y, gate, order, rank, total, places), (
+        y, gate, order, rank, total)
+
+
+def _weighted(g_rows, y_rows, w_rows):
+    """(d y, d weight) of rows of weight * y summed into a token whose
+    gradient the rows of g hold: in float32, d y back in y's dtype."""
+    g_rows = g_rows.astype(jnp.float32)
+    return ((g_rows * w_rows[:, None]).astype(y_rows.dtype),
+            jnp.sum(g_rows * y_rows.astype(jnp.float32), axis=-1))
+
+
+@functools.partial(jax.jit, static_argnames=("tile",))
+def _held_weighted(y, g, token, weight, total, *, tile):
+    """`_weighted` of g[token] in the tiles below `total`, d y written over
+    y, tile by tile: from `total` on it holds what y held, which no group
+    reads, and d weight [A] zeros."""
+    def one_tile(start, tile, live, carry):
+        y_then_dy, dweight = carry
+        t = jax.lax.dynamic_slice(token, (start,), (tile,))
+        met = _met(y_then_dy, start, tile)
+        dy_rows, dw_rows = _weighted(
+            jnp.where(live, g[t], 0), jnp.where(live, met, 0),
+            jax.lax.dynamic_slice(weight, (start,), (tile,)))
+        first = _first_time(start, tile)
+        return (jax.lax.dynamic_update_slice(
+                    y_then_dy, jnp.where(first, dy_rows, met), (start, 0)),
+                jax.lax.dynamic_update_slice(
+                    dweight, jnp.where(
+                        first[:, 0], dw_rows, jax.lax.dynamic_slice(
+                            dweight, (start,), (tile,))), (start,)))
+
+    return _held_tiles(token.shape[0], total, one_tile,
+                       (y, jnp.zeros(token.shape, jnp.float32)), tile)
 
 
 def _combine_bwd(res, g):
     """Both gradients on the experts' side, where the held rows are
     contiguous: sorted row r of dy is g[its token] times its weight, and its
     weight's gradient is the dot of g[its token] with y[r]; the weights'
-    gradients then go back to slot-major by `rank`, [A] numbers. Only the
-    tiles below `total` are gathered; dy is zero from `total` on."""
+    gradients then go back to slot-major by `rank`, [A] numbers. Where a
+    share is held only the tiles below `total` are gathered
+    (`_held_weighted`)."""
     y, gate, order, rank, total = res
     token, weight = order % gate.shape[1], gate.reshape(-1)[order]
-
-    def both(g_rows, y_rows, w_rows):
-        g_rows = g_rows.astype(jnp.float32)
-        return ((g_rows * w_rows[:, None]).astype(y.dtype),
-                jnp.sum(g_rows * y_rows.astype(jnp.float32), axis=-1))
-
     if total is None:
-        dy, dweight = both(g[token], y, weight)
+        dy, dweight = _weighted(g[token], y, weight)
     else:
-        def one_tile(start, tile, live, carry):
-            t = jax.lax.dynamic_slice(token, (start,), (tile,))
-            dy_rows, dw_rows = both(
-                jnp.where(live, g[t], 0),
-                jnp.where(live, jax.lax.dynamic_slice(
-                    y, (start, 0), (tile, y.shape[1])), 0),
-                jax.lax.dynamic_slice(weight, (start,), (tile,)))
-            return (jax.lax.dynamic_update_slice(carry[0], dy_rows,
-                                                 (start, 0)),
-                    jax.lax.dynamic_update_slice(carry[1], dw_rows,
-                                                 (start,)))
-
-        dy, dweight = _held_tiles(
-            order.shape[0], total, one_tile,
-            (jnp.zeros_like(y), jnp.zeros(order.shape, jnp.float32)))
-    return dy, dweight[rank].reshape(gate.shape), None, None, None
+        dy, dweight = _held_weighted(y, g, token, weight, total,
+                                     tile=ROW_TILE)
+    return dy, dweight[rank].reshape(gate.shape), None, None, None, None
 
 
 _combine.defvjp(_combine_fwd, _combine_bwd)
@@ -295,11 +473,87 @@ def _gated_relu(gate, up):
             * up.astype(jnp.float32)).astype(gate.dtype)
 
 
-def _gated(gate, up, activation):
+def _gated_unit(activation):
+    """The gated unit of `activation`, looked up in the module when called."""
     if activation not in ("silu", "relu"):
         raise ValueError("routed_ffn activation must be 'silu' or 'relu', "
                          "got %r" % (activation,))
-    return (_gated_silu if activation == "silu" else _gated_relu)(gate, up)
+    return _gated_silu if activation == "silu" else _gated_relu
+
+
+def _gated(gate, up, activation):
+    return _gated_unit(activation)(gate, up)
+
+
+def _matmul_transposes(lhs, rhs, sizes, d_out):
+    """(d lhs, d rhs) of `_grouped_matmul(lhs, rhs, sizes)`."""
+    return jax.vjp(lambda a, b: _grouped_matmul(a, b, sizes), lhs, rhs)[1](
+        d_out)
+
+
+@functools.partial(jax.jit, static_argnames=("unit", "tile"))
+def _held_gated_transpose(gate, up, d_hidden, total, *, unit, tile):
+    """(d gate, d up) of unit(gate, up) in the tiles below `total`: d gate
+    written over d hidden, tile by tile (from `total` on it holds what d
+    hidden held), d up zeros there."""
+    def one_tile(start, tile, live, carry):
+        d_hidden_then_gate, d_up = carry
+
+        def cut(v):
+            return jax.lax.dynamic_slice(v, (start, 0), (tile, v.shape[1]))
+
+        met = _met(d_hidden_then_gate, start, tile)
+        d_gate_rows, d_up_rows = jax.vjp(unit, cut(gate), cut(up))[1](met)
+        first = _first_time(start, tile)
+        return (jax.lax.dynamic_update_slice(
+                    d_hidden_then_gate, jnp.where(first, d_gate_rows, met),
+                    (start, 0)),
+                jax.lax.dynamic_update_slice(
+                    d_up, jnp.where(first, d_up_rows, cut(d_up)), (start, 0)))
+
+    return _held_tiles(gate.shape[0], total, one_tile,
+                       (d_hidden, jnp.zeros_like(up)), tile)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(9,))
+def _held_experts(x, w_gate, w_up, w_down, order, rank, sizes, total, places,
+                  activation):
+    """A share held: x [N, D] -> the held experts' outputs by sorted row
+    [A, D], through `_held_rows`, the three grouped matmuls and `_gated`; the
+    integers as in `_combine`. One rule, so that its backward pass can run
+    the passes between the matmuls' transposes in the held tiles."""
+    return _held_experts_fwd(x, w_gate, w_up, w_down, order, rank, sizes,
+                             total, places, activation)[0]
+
+
+def _held_experts_fwd(x, w_gate, w_up, w_down, order, rank, sizes, total,
+                      places, activation):
+    rows = _held_rows(x, order, total, tile=ROW_TILE)
+    gate = _grouped_matmul(rows, w_gate, sizes)
+    up = _grouped_matmul(rows, w_up, sizes)
+    hidden = _gated(gate, up, activation)
+    return _grouped_matmul(hidden, w_down, sizes), (
+        rows, gate, up, hidden, w_gate, w_up, w_down, rank, sizes, total,
+        places)
+
+
+def _held_experts_bwd(activation, res, dy):
+    """The matmuls' transposes as `_grouped_matmul`'s own; `_gated`'s in the
+    tiles below `total` (`_held_gated_transpose`); and a token's gradient
+    summed from the two matmuls' d rows where they lie (`_token_sum`): a row
+    past `total` holds whatever the transposes left there."""
+    rows, gate, up, hidden, w_gate, w_up, w_down, rank, sizes, total, \
+        places = res
+    d_hidden, d_down = _matmul_transposes(hidden, w_down, sizes, dy)
+    d_gate, d_up = _held_gated_transpose(
+        gate, up, d_hidden, total, unit=_gated_unit(activation), tile=ROW_TILE)
+    d_rows_gate, d_w_gate = _matmul_transposes(rows, w_gate, sizes, d_gate)
+    d_rows_up, d_w_up = _matmul_transposes(rows, w_up, sizes, d_up)
+    return (_token_sum((d_rows_gate, d_rows_up), rank, places, tile=SUM_TILE),
+            d_w_gate, d_w_up, d_down) + (None,) * 5
+
+
+_held_experts.defvjp(_held_experts_fwd, _held_experts_bwd)
 
 
 # what a sigmoid router's renormalisation adds to the chosen scores' sum
@@ -365,20 +619,29 @@ def routed_ffn(x, router, w_gate, w_up, w_down, top_k, norm_topk_prob=False,
     The row buffer is top_k * N rows whatever H is, because every one of a
     token's choices may be held (1.5 N on average at 6 of 64 with 16 held).
 
-    Four permutations move rows, a layer's forward and backward. The two
-    that produce sorted rows (`_dispatch` forward, `_combine` backward)
-    gather, where a share is held (`rows_moved`), only the tiles of
-    `ROW_TILE` rows below total = sizes.sum(), in a loop whose trip count
-    is known at run time: the share of the buffer they touch is
+    Four permutations move rows, a layer's forward and backward, and where
+    a share is held (`rows_moved`) all four, and the elementwise passes
+    between them, touch only the held rows, in loops whose trip count is
+    known at run time: the share of the buffer they touch is
     ExpertLoad[first_expert : first_expert + H].sum() / (top_k * N), a
-    value every caller can fetch. Sorted rows from `total` on are zero,
-    in the rows and in the gradient that `_combine` hands the matmuls. The
-    two that produce a token's rows (`_combine` forward, `_dispatch`
-    backward) gather all top_k * N, a token's held slots being scattered,
-    and sum over the leading axis of [top_k, N, D], which is the buffer as
-    it lies: numbered token-major, top_k = 6 made each of them a relayout
-    of the buffer (6 rows do not fill a tile of 8; PERF.md section 6, PR
-    32).
+    value every caller can fetch. The two that produce sorted rows
+    (`_held_rows` forward, `_held_weighted` in `_combine`'s backward) gather
+    the tiles of `ROW_TILE` rows below total = sizes.sum(); `_held_rows`
+    leaves zeros from `total` on, `_held_weighted` writes over y and leaves
+    what y held. The two that produce a token's rows (`_combine` forward,
+    `_held_experts` backward) number the held assignments token-major on
+    [N] integers (`_token_places`) and, `SUM_TILE` places a trip, gather
+    their rows and sum them by token with a one-hot matmul (`_token_sum`):
+    about 50 ns a gathered row on the v5e whatever the form, so the held
+    rows' gather is what is left of them. The two d rows that `w_gate`'s and
+    `w_up`'s transposes return are added where that sum reads them, and
+    `_gated`'s transpose runs in the held tiles, d gate written over d
+    hidden; `_gated` forward is a pass over all A rows still (a new carry's
+    zero fill costs what the tiles save). Where every expert is held the
+    four are gathers of the whole buffer: the two token-side ones sum over
+    the leading axis of [top_k, N, D], which is the buffer as it lies
+    (numbered token-major, top_k = 6 made each of them a relayout of the
+    buffer: 6 rows do not fill a tile of 8; PERF.md section 6, PR 32).
 
     The rows past `total` belong to no group. What `ragged_dot` does with
     them depends on the backend: on the CPU it writes zeros there and its
@@ -387,9 +650,9 @@ def routed_ffn(x, router, w_gate, w_up, w_down, top_k, norm_topk_prob=False,
     (0.75 ms for 12288 of 49152 rows of [2560] x [16, 2560, 768], 2.03 ms
     for all 49152), and they hold whatever the buffer held before, in the
     output and in the gradient of its left operand alike (my chip run, PR
-    31). So nothing rests on them: `_slot_sum` selects (not multiplies)
-    the held assignments' rows, forward and backward, so no NaN in an
-    unwritten row reaches a token or its gradient.
+    31). So nothing rests on them: no pass reads a row from `total` on into
+    a token's sum or a weight's gradient, forward or backward, so no NaN in
+    an unwritten row reaches either.
 
     The router's matmul, softmax and top-k are float32 at full precision
     whatever x's dtype; the experts compute in `expert_dtype` (x's own if
@@ -442,12 +705,18 @@ def routed_ffn(x, router, w_gate, w_up, w_down, top_k, norm_topk_prob=False,
     rank = jnp.zeros_like(order).at[order].set(
         jnp.arange(order.shape[0], dtype=order.dtype))
 
-    rows = _dispatch(x.astype(dtype), order, rank, total)
-    hidden = _gated(_grouped_matmul(rows, w_gate.astype(dtype), sizes),
-                    _grouped_matmul(rows, w_up.astype(dtype), sizes),
-                    activation)
-    y = _grouped_matmul(hidden, w_down.astype(dtype), sizes)
-    out = _combine(y, gate, order, rank, total)
+    if total is None:
+        rows, places = _dispatch(x.astype(dtype), order, rank), None
+        hidden = _gated(_grouped_matmul(rows, w_gate.astype(dtype), sizes),
+                        _grouped_matmul(rows, w_up.astype(dtype), sizes),
+                        activation)
+        y = _grouped_matmul(hidden, w_down.astype(dtype), sizes)
+    else:
+        places = _token_places(rank, total, top_k)
+        y = _held_experts(x.astype(dtype), w_gate.astype(dtype),
+                          w_up.astype(dtype), w_down.astype(dtype), order,
+                          rank, sizes, total, places, activation)
+    out = _combine(y, gate, order, rank, total, places)
 
     if scoring == "sigmoid":
         return out, jnp.zeros((1,), jnp.float32), \

@@ -415,12 +415,15 @@ def _steered(held_logit):
     return x, a, router, wg[4:12], wu[4:12], wd[4:12]
 
 
+@pytest.mark.parametrize("tile", [32, 100])
 @pytest.mark.parametrize("extreme", ["none_held", "every_one_held"])
-def test_routed_ffn_at_the_extremes_of_imbalance(monkeypatch, extreme):
-    """No assignment on the held experts: the loops make no trip, zeros and
-    zero gradients. Every assignment on them: sizes.sum() == top_k * N, the
-    loops run every tile, and the share is the whole layer."""
-    monkeypatch.setattr(moe, "ROW_TILE", 32)
+def test_routed_ffn_at_the_extremes_of_imbalance(monkeypatch, extreme, tile):
+    """No assignment on the held experts: the row loops make no trip, zeros
+    and zero gradients. Every assignment on them: sizes.sum() == top_k * N,
+    the loops run every tile, and the share is the whole layer; at a tile of
+    100 the last of three trips over the 288 rows meets 12 rows a second
+    time, also where it writes over what it reads (PR 40)."""
+    monkeypatch.setattr(moe, "ROW_TILE", tile)
     x, a, router, wg, wu, wd = _steered(-5.0 if extreme == "none_held"
                                         else 5.0)
 
@@ -454,51 +457,233 @@ def test_routed_ffn_at_the_extremes_of_imbalance(monkeypatch, extreme):
         assert _error(u, v) < 5 * TOLERANCE
 
 
-def _sorted_assignments(seed, n, top_k, experts, held):
+def _assignments(seed, n, top_k, experts, first, held):
     """(order, rank, total) as routed_ffn builds them for random choices of
-    which the first `held` experts are held."""
+    which experts first .. first + held - 1 are held."""
     expert = jnp.asarray(np.random.RandomState(seed).randint(
         0, experts, top_k * n))
-    order = jnp.argsort(jnp.where(expert < held, expert, held), stable=True)
-    return order, jnp.argsort(order), jnp.sum(expert < held)
+    local = expert - first
+    here = (local >= 0) & (local < held)
+    order = jnp.argsort(jnp.where(here, local, held), stable=True)
+    return order, jnp.argsort(order), jnp.sum(here)
 
 
 @pytest.mark.parametrize("tile", [32, 100, 2048])
 def test_the_last_tiles_tail_is_zero_in_the_rows_and_in_their_gradient(
         monkeypatch, tile):
-    """sizes.sum() is no multiple of the tile (nor the buffer, at 100):
-    `_dispatch` gives x[token] below the sum and zeros from there on, and
-    `_combine`'s backward the weighted g[token] below it and zeros from
-    there on, whatever y holds past the sum."""
+    """sizes.sum() is no multiple of the tile (nor the buffer, at 100, where
+    the last trip meets rows a second time): `_held_rows` gives x[token]
+    below the sum and zeros from there on; `_combine`'s backward the
+    weighted g[token] below it and, since PR 40 writes dy over y, what y
+    held from there on, which no group reads; and no NaN past the sum
+    reaches a weight's or a token's gradient."""
     monkeypatch.setattr(moe, "ROW_TILE", tile)
     n, k, d = 48, 6, 16
-    order, rank, total = _sorted_assignments(10, n, k, 16, 4)
+    order, rank, total = _assignments(10, n, k, 16, 0, 4)
     assert 0 < int(total) < k * n and int(total) % min(tile, k * n)
     rng = np.random.RandomState(11)
     x, g = (jnp.asarray(rng.randn(n, d), jnp.float32) for _ in range(2))
     gate = jnp.asarray(rng.rand(k, n), jnp.float32)
     live = (np.arange(k * n) < int(total))[:, None]
+    places = moe._token_places(rank, total, k)
 
-    rows = jax.jit(moe._dispatch)(x, order, rank, total)
+    rows = moe._held_rows(x, order, total, tile=tile)
     np.testing.assert_array_equal(
         rows, np.where(live, np.asarray(x)[np.asarray(order) % n], 0))
 
     y = jnp.where(live, jnp.asarray(rng.randn(k * n, d), jnp.float32),
                   jnp.nan)
     out, vjp = jax.vjp(lambda y, gate: moe._combine(y, gate, order, rank,
-                                                    total), y, gate)
+                                                    total, places), y, gate)
     assert bool(jnp.isfinite(out).all())
     dy, dgate = vjp(g)
     weight = np.asarray(gate).reshape(-1)[np.asarray(order)][:, None]
     g_rows = np.asarray(g)[np.asarray(order) % n]
-    np.testing.assert_allclose(dy, np.where(live, g_rows * weight, 0),
-                               rtol=1e-6)
+    np.testing.assert_allclose(np.asarray(dy)[:int(total)],
+                               (g_rows * weight)[:int(total)], rtol=1e-6)
+    tail = np.asarray(dy)[int(total):]      # zero in the last tile met
+    assert ((tail == 0) | np.isnan(tail)).all()
     want = np.where(live, g_rows * np.nan_to_num(np.asarray(y)), 0).sum(-1)
+    assert bool(jnp.isfinite(dgate).all())
     np.testing.assert_allclose(np.asarray(dgate).reshape(-1),
                                want[np.asarray(rank)], rtol=1e-5, atol=1e-6)
-    dx = jax.vjp(lambda x: moe._dispatch(x, order, rank, total), x)[1](
-        jnp.where(live, rows, jnp.nan))[0]
+    dx = moe._token_sum((jnp.where(live, rows, jnp.nan),) * 2, rank, places,
+                        tile=moe.SUM_TILE)
     assert bool(jnp.isfinite(dx).all())
+    np.testing.assert_allclose(
+        dx, 2 * _add_at(rows, None, order, total, n), rtol=1e-6, atol=1e-6)
+
+
+# --- the token side: the held rows only (PR 40) --------------------------------
+
+def _slot_sum_of_pr_32(rows, rank, total, slots, gate=None):
+    """`_slot_sum` as PR 32 left it: the gather of all top_k * N rows by
+    `rank`, the unheld ones selected away, a float32 sum slot by slot."""
+    by_slot = rows[rank].reshape(slots, -1, rows.shape[1])
+    held = (rank < total).reshape(slots, -1, 1)
+    acc = 0.0
+    for j in range(slots):
+        term = jnp.where(held[j], by_slot[j], 0).astype(jnp.float32)
+        acc = acc + (term if gate is None else term * gate[j][:, None])
+    return acc.astype(rows.dtype)
+
+
+def _token_sum_inputs(seed, order, total, n, k, d, dtype, poison):
+    rng = np.random.RandomState(seed)
+    rows = jnp.asarray(rng.randn(k * n, d), dtype)
+    if poison:
+        rows = jnp.where((jnp.arange(k * n) < total)[:, None], rows, jnp.nan)
+    return rows, jnp.asarray(rng.rand(k, n) + 0.1, jnp.float32)
+
+
+def _add_at(rows, gate, order, total, n):
+    """The sum in float64, `np.add.at` over the held sorted rows."""
+    total, order = int(total), np.asarray(order)
+    r64 = np.asarray(rows.astype(jnp.float32), np.float64)[:total]
+    want = np.zeros((n, rows.shape[1]))
+    if gate is not None:
+        r64 = r64 * np.asarray(gate, np.float64).reshape(-1)[
+            order[:total], None]
+    np.add.at(want, order[:total] % n, r64)
+    return want
+
+
+def _tokens_without(rank, total, n, k):
+    return int((np.asarray(rank < total).reshape(k, n).sum(0) == 0).sum())
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("sum_tile", [8, 32])
+@pytest.mark.parametrize("tile", [32, 1024])
+@pytest.mark.parametrize("held", sorted(HELD))
+def test_the_token_side_sum_touches_the_held_rows_only(
+        monkeypatch, held, tile, sum_tile, dtype):
+    """`_token_sum`, weighted (`_combine` forward) and plain (`_dispatch`
+    backward), against np.add.at in float64 and against PR 32's gather of
+    all top_k * N rows: every assignment held (total == A), a share in the
+    middle, the last share; trips of 8 and of 32 places, which tokens
+    straddle (the 300 places are a multiple of neither: the arrays are
+    padded). NaN in every row past `total` reaches no token. bfloat16
+    rows meet the float32 weight as three bfloat16 terms: the sum is the
+    float32 one, not a sum of rounded products."""
+    monkeypatch.setattr(moe, "ROW_TILE", tile)
+    monkeypatch.setattr(moe, "SUM_TILE", sum_tile)
+    n, k, d = 50, 6, 16
+    count, first = HELD[held]
+    order, rank, total = _assignments(13, n, k, 16, first, count)
+    assert int(total) == k * n if held == "all" else 0 < int(total) < k * n
+    rows, gate = _token_sum_inputs(14, order, total, n, k, d, dtype, True)
+    places = moe._token_places(rank, total, k)
+    held_slots, begin, count = (np.asarray(v) for v in places)
+    assert int(count) == int(total) + _tokens_without(rank, total, n, k)
+    assert held_slots.sum() == int(total) and begin[0] == 0
+    assert (np.diff(begin) == np.maximum(held_slots.sum(0), 1)[:-1]).all()
+    for weighted in (True, False):
+        got = moe._token_sum((rows,), rank, places,
+                             gate if weighted else None,
+                             tile=moe.SUM_TILE)
+        assert got.dtype == rows.dtype and got.shape == (n, d)
+        assert bool(jnp.isfinite(got).all())
+        want = _add_at(rows, gate if weighted else None, order, total, n)
+        old = _slot_sum_of_pr_32(rows, rank, total, k,
+                                 gate if weighted else None)
+        if dtype == "float32":
+            assert _error(got, want) < 1e-6
+            assert _error(got, old) < 1e-6
+        else:
+            # the float64 sum rounded once to bfloat16, within an ulp
+            assert _error(got, want) < 2.0 ** -8
+            assert _error(got, old) < 2.0 ** -8
+
+
+def test_a_bfloat16_weighted_sum_is_not_a_sum_of_rounded_products():
+    """Weight times row in float32, summed in float32, rounded once: as PR
+    32's sum. With the weight rounded to bfloat16 first (one term of the
+    three) the result is a bfloat16 ulp off in many places."""
+    n, k, d = 64, 2, 32
+    order, rank, total = _assignments(15, n, k, 4, 0, 2)
+    rows, gate = _token_sum_inputs(16, order, total, n, k, d, "bfloat16",
+                                   False)
+    places = moe._token_places(rank, total, k)
+    got = moe._token_sum((rows,), rank, places, gate, tile=moe.SUM_TILE)
+    want = _slot_sum_of_pr_32(rows, rank, total, k, gate)
+    # three exact partial products summed in float32 against one rounded
+    # product: a float32 ulp apart at most, which moves a bfloat16 tie
+    assert (np.asarray(got, np.float32)
+            != np.asarray(want, np.float32)).mean() < 0.005
+    rounded = _slot_sum_of_pr_32(
+        rows, rank, total, k,
+        gate.astype(jnp.bfloat16).astype(jnp.float32))
+    assert (np.asarray(rounded, np.float32)
+            != np.asarray(want, np.float32)).mean() > 0.05
+
+
+@pytest.mark.parametrize("extreme", ["none_held", "a_run_of_tokens_without"])
+def test_the_token_side_sum_where_no_row_is_held(monkeypatch, extreme):
+    """No assignment held: every token takes its one empty place, exactly
+    zero. Twenty consecutive tokens none of whose choices is held, more than
+    two trips of 8 places: exactly zero there, the float64 sum elsewhere."""
+    monkeypatch.setattr(moe, "ROW_TILE", 32)
+    monkeypatch.setattr(moe, "SUM_TILE", 8)
+    n, k, d = 48, 6, 16
+    expert = np.random.RandomState(17).randint(0, 16, (k, n))
+    if extreme == "none_held":
+        expert = 4 + expert % 12
+    else:
+        expert[:, 14:34] = 4 + expert[:, 14:34] % 12
+    here = jnp.asarray(expert.reshape(-1) < 4)
+    order = jnp.argsort(jnp.where(here, jnp.asarray(expert.reshape(-1)), 4),
+                        stable=True)
+    rank, total = jnp.argsort(order), jnp.sum(here)
+    rows, gate = _token_sum_inputs(18, order, total, n, k, d, "float32", True)
+    places = moe._token_places(rank, total, k)
+    got = np.asarray(moe._token_sum((rows,), rank, places, gate,
+                                    tile=moe.SUM_TILE))
+    if extreme == "none_held":
+        assert int(total) == 0 and int(places[2]) == n and not got.any()
+        return
+    assert _tokens_without(rank, total, n, k) >= 20
+    assert not got[14:34].any()
+    assert _error(got, _add_at(rows, gate, order, total, n)) < 1e-6
+
+
+@pytest.mark.parametrize("tile", [100, 1024])
+@pytest.mark.parametrize("held", ["share", "last"])
+def test_a_share_has_the_gradients_of_the_layer_with_the_rest_zeroed(
+        monkeypatch, held, tile):
+    """routed_ffn holding a share (the held rows only, every pass) against
+    routed_ffn holding every expert (rows="all": no loop, no one-hot sum)
+    with the other experts' weights zero, on the same inputs: the value and
+    the gradients of x, the router's input, the router and the three
+    weights, at 1e-6 in float32. At a tile of 100 the 288 rows are no
+    multiple of it: the last trip of each loop meets rows a second time,
+    also where it writes d gate over d hidden and dy over y."""
+    monkeypatch.setattr(moe, "ROW_TILE", tile)
+    monkeypatch.setattr(moe, "SUM_TILE", 64)
+    x, a, router, wg, wu, wd = _layer_inputs(19)
+    count, first = HELD[held]
+    sl = slice(first, first + count)
+    mask = jnp.zeros((16, 1, 1)).at[sl].set(1.0)
+
+    def share(x, a, router, wg, wu, wd):
+        return moe.routed_ffn(x, router, wg[sl], wu[sl], wd[sl], top_k=6,
+                              norm_topk_prob=True, router_x=a,
+                              activation="relu", first_expert=first)[0]
+
+    def whole(x, a, router, wg, wu, wd):
+        return moe.routed_ffn(x, router, wg * mask, wu * mask, wd * mask,
+                              top_k=6, norm_topk_prob=True, router_x=a,
+                              activation="relu")[0]
+
+    g = jnp.asarray(np.random.RandomState(20).randn(*x.shape), jnp.float32)
+    with jax.default_matmul_precision("highest"):
+        got, want = ((out,) + vjp(g) for out, vjp in
+                     (jax.vjp(f, x, a, router, wg, wu, wd)
+                      for f in (share, whole)))
+    for name, u, v in zip(("out", "dx", "drouter_x", "drouter", "dw_gate",
+                           "dw_up", "dw_down"), got, want):
+        assert _error(u, v) < 1e-6, name
+        assert np.asarray(v).any(), name
 
 
 def test_the_share_of_the_buffer_gathered_reads_from_expert_load():

@@ -3,6 +3,7 @@
 (ops/pallas_kernels.KERNEL_NAMES), and the profiler's per-op table of a
 device trace (profiler.device_op_table)."""
 import ast
+import collections
 import os
 import re
 import types
@@ -642,17 +643,10 @@ def test_layer_norm_tile_fits_the_default_vmem_limit(one_chip, n, d, dtype):
                           text)) == 1
 
 
-@pytest.mark.parametrize("held", [4, 16], ids=["a_share", "every_expert"])
-def test_routed_ffn_moves_its_rows_without_a_relayout(one_chip, held):
-    """routed_ffn forward and backward at top-6 of 16, compiled for a TPU:
-    no reshape, copy, transpose or convert over the row buffer ([6144, 256],
-    [6, 1024, 256]; PR 31's order made three relayouts of it a layer and
-    PERF.md section 6, PR 32, has what they cost), and where a share of the
-    experts is held the two expert-side gathers are loops over its tiles."""
+def _routed_ffn_step(held, sds):
+    """routed_ffn forward and backward at top-6 of 16, `held` of them here,
+    and the shapes it is lowered for."""
     from paddle_tpu.parallel import moe
-
-    def sds(shape, dtype):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
     def step(x, a, router, wg, wu, wd, g):
         out, vjp = jax.vjp(
@@ -663,17 +657,52 @@ def test_routed_ffn_moves_its_rows_without_a_relayout(one_chip, held):
         return out, vjp(g)
 
     n, d, f = 1024, 256, 128
-    text = _compile_uncached(
-        step, sds((n, d), jnp.bfloat16), sds((n, d), jnp.float32),
+    return step, (
+        sds((n, d), jnp.bfloat16), sds((n, d), jnp.float32),
         sds((d, 16), jnp.float32), sds((held, d, f), jnp.float32),
         sds((held, d, f), jnp.float32), sds((held, f, d), jnp.float32),
-        sds((n, d), jnp.bfloat16)).as_text()
+        sds((n, d), jnp.bfloat16))
+
+
+@pytest.mark.parametrize("held", [4, 16], ids=["a_share", "every_expert"])
+def test_routed_ffn_moves_its_rows_without_a_relayout(one_chip, held):
+    """routed_ffn forward and backward at top-6 of 16, compiled for a TPU:
+    no reshape, copy, transpose or convert over the row buffer ([6144, 256],
+    [6, 1024, 256]; PR 31's order made three relayouts of it a layer and
+    PERF.md section 6, PR 32, has what they cost). Where a share of the
+    experts is held every pass over the buffer is a loop over the held tiles
+    (PR 32: the two expert-side gathers; PR 40: the two token-side sums,
+    each a gather and a one-hot matmul a trip, and `_gated`'s transpose), no
+    gather gives the whole row buffer, no `add` runs over it (the two
+    matmuls' d rows are added where the token-side sum reads them) and only
+    `_held_rows` fills one with zeros; where every expert is held there is
+    no loop. The nine grouped matmuls are the same calls either way."""
+    step, shapes = _routed_ffn_step(
+        held, lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                        sharding=one_chip))
+    text = _compile_uncached(step, *shapes).as_text()
+    buffer = r"= \w+\[(?:6144,256|6,1024,256|1024,6,256)\]\S* "
     moved = re.findall(     # in the entry computation: a gather's fusion
-        r"= \w+\[(?:6144,256|6,1024,256|1024,6,256)\]\S* "     # has its own
-        r"(reshape|copy|transpose|convert)\(", text[text.index("ENTRY"):])
+        buffer + r"(reshape|copy|transpose|convert)\(",     # has its own
+        text[text.index("ENTRY"):])
     assert moved == []
+    whole = collections.Counter(re.findall(buffer + r"(gather|add|copy|"
+                                           r"broadcast)\(", text))
+    assert whole == ({"broadcast": 1} if held < 16 else
+                     {"gather": 4, "add": 1, "broadcast": 1})
     assert len(re.findall(r"%ragged-dot-none[\w.]* = ", text)) == 9
-    assert len(re.findall(r" while\(", text)) == (2 if held < 16 else 0)
+    assert len(re.findall(r" while\(", text)) == (5 if held < 16 else 0)
+
+
+def test_routed_ffn_holding_every_expert_lowers_as_the_parent_did():
+    """Where every expert is held (`rows_moved` says "all": the OLMoE cell)
+    PR 40 changed nothing: the StableHLO of the step above is, byte for
+    byte, what the parent commit (PR 39) lowered."""
+    import hashlib
+    step, shapes = _routed_ffn_step(16, jax.ShapeDtypeStruct)
+    text = jax.jit(step).lower(*shapes).as_text()
+    assert hashlib.sha256(text.encode()).hexdigest() == (
+        "e8ba112ccb352c8daa7adcf2b1ff3351682dc84de61f9a9f95ea1c4cf1dcb384")
 
 
 def test_gated_delta_kernels_at_the_cells_shapes_on_a_described_v5e(
