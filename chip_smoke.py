@@ -32,6 +32,11 @@ from a seed, and checks what comes out by the repo's own means:
      share, summed through the loop op's transpose, against jax.grad of
      the plain float32 reference, with the loop's recomputation and
      without it. The benchmark's `correct` sees only the forward pass.
+  I  the embedding's backward: lookup_table's rule, forward + backward
+     alone, at the seven token cells' (rows, vocabulary, width), under
+     uniform ids, Zipf ids and one id throughout, beside jnp.take's own
+     transpose and the least time the bytes take; each checked against
+     float64 sums of the rows on the host.
 
 Every phase that fails makes the exit code non-zero. Timings are printed
 for the next reader, labelled with the device; they are not metrics. The
@@ -97,6 +102,13 @@ FULL = {
     # experts of 1792 held, 4 a token
     "lfm2": dict(t=8192, d=2048, width=3, experts=32, held=8, f=1792,
                  top_k=4),
+    # lookup_table at the token cells: (rows, vocabulary, width)
+    "embedding": (("transformer_base _t256 and _t2048", 16384, 32000, 512),
+                  ("olmoe_1b_7b", 16384, 50304, 2048),
+                  ("smallthinker_21b_a3b", 8192, 37984, 2560),
+                  ("qwen3_next_80b_a3b", 4096, 18992, 2048),
+                  ("ouro_2_6b", 4096, 49152, 2048),
+                  ("lfm2_8b_a1b", 8192, 16384, 2048)),
     "barrier": dict(steps=5, rounds=3, tol=0.15),
     "dp_loss_rtol": 2e-2,
 }
@@ -123,6 +135,8 @@ TINY = {
                    intermediate_size=48, vocab_size=64, num_hidden_layers=2,
                    t=32, tol=5e-2),
     "lfm2": dict(t=64, d=256, width=3, experts=8, held=4, f=128, top_k=2),
+    "embedding": (("a row of 8 KiB", 96, 200, 2048),
+                  ("a row of 1 KiB", 96, 200, 256)),
     "barrier": dict(steps=5, rounds=3, tol=0.75),
     "dp_loss_rtol": 2e-2,
 }
@@ -1334,6 +1348,103 @@ def phase_h(smoke):
         expert_bias=bias)
 
 
+def _token_ids(kind, rows, vocab, seed):
+    """`rows` ids under one of the three traffics phase I reads: uniform
+    over the vocabulary (the benchmark's), Zipf with exponent 1 over a
+    shuffled vocabulary (packed text: the commonest word about a tenth of
+    the rows), one id throughout (the longest run there is)."""
+    rng = np.random.RandomState(seed)
+    if kind == "uniform":
+        return rng.randint(0, vocab, rows)
+    if kind == "zipf":
+        p = 1.0 / np.arange(1, vocab + 1)
+        return rng.permutation(vocab)[rng.choice(vocab, rows, p=p / p.sum())]
+    return np.full(rows, vocab // 3)
+
+
+def phase_i(smoke):
+    """lookup_table's rule alone: the gather and its backward (the dense
+    float32 [V, D] gradient of the table) at each token cell's (rows,
+    vocabulary, width), in flight, for uniform, Zipf and one-id traffic:
+    as the rule runs it (XLA's scatter, or `ptpu_embedding_grad` where the
+    rule hands it the table), with jnp.take's own transpose and with the
+    kernel whatever the rule says, beside the time the bytes take at the
+    chip's HBM rate (the gradient written once, the rows read and written
+    forward and read backward). The gradient of sixteen vocabulary rows
+    (the commonest ids and ids that do not occur) is held to float64 sums
+    on the host, and the whole of it to jnp.take's own. The times are
+    printed for the next reader (no metric, and no check: with one id
+    throughout the kernel's one busy block adds every row while nothing is
+    written, 0.1-0.25 ms that uniform ids hide under the writes): PERF.md
+    sections 5 and 6 quote them."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.core import registry
+    from paddle_tpu.ops.embedding_grad import dense_grad, grad_form
+
+    rule = registry.get("lookup_table").lower
+
+    def with_rule(w, ids, g):
+        y, vjp = jax.vjp(lambda w: rule(
+            registry.AbstractCtx(), {"W": [w], "Ids": [ids]},
+            {"padding_idx": -1})["Out"][0], w)
+        return y, vjp(g)[0]
+
+    def with_take(w, ids, g):
+        y, vjp = jax.vjp(lambda w: jnp.take(w, ids[:, 0], axis=0), w)
+        return y, vjp(g)[0]
+
+    def forward(w, ids, g):
+        return jnp.take(w, ids[:, 0], axis=0)
+
+    def with_kernel(w, ids, g):
+        return (jnp.take(w, ids[:, 0], axis=0),
+                dense_grad(ids[:, 0], g, w.shape[0]))
+
+    runs = {"forward": jax.jit(forward), "rule": jax.jit(with_rule),
+            "take": jax.jit(with_take), "kernel": jax.jit(with_kernel)}
+    for name, rows, vocab, width in smoke.cfg["embedding"]:
+        rng = np.random.RandomState(41)
+        w = jnp.asarray(rng.randn(vocab, width), jnp.float32)
+        g_host = rng.randn(rows, width).astype(np.float32)
+        g = jnp.asarray(g_host)
+        floor = 1e3 * 4.0 * (vocab + 3 * rows) * width / 819e9
+        times = {}
+        for kind in ("uniform", "zipf", "one"):
+            ids_host = _token_ids(kind, rows, vocab, seed=43)
+            ids = jnp.asarray(ids_host[:, None], jnp.int32)
+            times[kind] = {k: _in_flight_ms(f, (w, ids, g))
+                           for k, f in runs.items()}
+            counts = np.bincount(ids_host, minlength=vocab)
+            probe = np.concatenate([np.argsort(-counts)[:8],
+                                    np.flatnonzero(counts == 0)[:8]])
+            want = np.stack([g_host[ids_host == v].sum(0, dtype=np.float64)
+                             for v in probe])
+            own = runs["take"](w, ids, g)[1]
+            for k in ("rule", "kernel"):
+                dw = runs[k](w, ids, g)[1]
+                got = np.asarray(dw[jnp.asarray(probe)], np.float64)
+                err = float(np.abs(got - want).max() / np.abs(want).max())
+                apart = float(jnp.max(jnp.abs(dw - own))
+                              / jnp.max(jnp.abs(own)))
+                del dw
+                if err > 1e-5 or apart > 1e-5:
+                    raise AssertionError(
+                        "%s, %s ids, %s: the gradient is %.2e off float64 "
+                        "sums and %.2e off jnp.take's own"
+                        % (name, kind, k, err, apart))
+            del own
+        smoke.say("I lookup_table %s: %d rows of [%d, %d], gradient by %s; "
+                  "forward + backward ms as the rule runs it (jnp.take's "
+                  "own, the kernel; of which the forward; the bytes' floor "
+                  "%.2f): %s"
+                  % (name, rows, vocab, width, grad_form(rows, width), floor,
+                     ", ".join("%s %.3f (%.3f, %.3f; %.3f)"
+                               % (k, t["rule"], t["take"], t["kernel"],
+                                  t["forward"])
+                               for k, t in times.items())))
+
+
 PHASES = (("A", "ResNet-50 training", phase_a),
           ("B", "transformer training", phase_b),
           ("C", "Pallas kernel families", phase_c),
@@ -1341,7 +1452,8 @@ PHASES = (("A", "ResNet-50 training", phase_a),
           ("E", "timing barrier", phase_e),
           ("F", "causal_conv1d kernels", phase_f),
           ("G", "looped decoder's summed gradients", phase_g),
-          ("H", "LFM2's gated convolution and sigmoid router", phase_h))
+          ("H", "LFM2's gated convolution and sigmoid router", phase_h),
+          ("I", "the embedding's backward", phase_i))
 
 
 def main(argv=None):
@@ -1349,7 +1461,7 @@ def main(argv=None):
     ap.add_argument("--tiny", action="store_true",
                     help="CPU rehearsal at toy sizes (needs "
                          "JAX_PLATFORMS=cpu)")
-    ap.add_argument("--phases", default="ABCDEFGH",
+    ap.add_argument("--phases", default="ABCDEFGHI",
                     help="letters of the phases to run (default all)")
     args = ap.parse_args(argv)
 

@@ -689,6 +689,8 @@ def _lower_op_inner(ctx, op, env):
         _count_linear_attention_layer(ins)
     elif op.type == "causal_conv1d":
         _count_causal_conv_layer(op.attrs, ins)
+    elif op.type == "lookup_table":
+        _count_embedding_layer(ctx, ins)
     if op.uid in ctx.linearized:
         # a grad op of this block differentiates this op: run the rule once,
         # under jax.vjp, and keep what the backward needs
@@ -892,6 +894,20 @@ def _count_causal_conv_layer(attrs, ins):
     ).inc(path=causal_conv_path(x, w), width=str(w.shape[1]),
           channels=str(w.shape[0]),
           activation=str(attrs.get("activation", "none")))
+
+
+def _count_embedding_layer(ctx, ins):
+    from ..observability.registry import REGISTRY
+    from ..ops.embedding_grad import grad_form
+    w, ids = ins["W"][0], ins["Ids"][0]
+    REGISTRY.counter(
+        "ptpu_embedding_layers_total",
+        "lookup_table ops lowered (forward ops, not a grad op's replay), by "
+        "the rows looked up, the table's rows and width, and who builds the "
+        "table's dense gradient in the backward pass (XLA's scatter, or the "
+        "kernel that writes the table block by block)"
+    ).inc(rows=str(ids.size), vocab=str(w.shape[0]), width=str(w.shape[1]),
+          grad=grad_form(ids.size, w.shape[1], ctx.mesh))
 
 
 def _lower_grad_of(ctx, op, env):

@@ -71,8 +71,12 @@ def fc(input, size, num_flatten_dims=1, param_attr=None, bias_attr=None,
 def embedding(input, size, is_sparse=False, is_distributed=False,
               padding_idx=None, param_attr=None, dtype="float32"):
     """Parity: fluid.layers.embedding → lookup_table op. `is_sparse` selects
-    the reference's SelectedRows grad path; on TPU gathers/scatter-adds are
-    already sparse-efficient XLA HLO, so it's accepted and ignored."""
+    the reference's SelectedRows grad path; here it is accepted and ignored:
+    the table's gradient is always a dense [V, D] in the table's dtype, the
+    rows of a repeated id summed in float32. On one TPU a Pallas kernel
+    writes a table of rows of 8 KiB and more (ops/embedding_grad.py),
+    elsewhere XLA's scatter-add does, which on a TPU is no sparse-efficient
+    operation (PERF.md section 6, PR 41)."""
     helper = LayerHelper("embedding", **locals())
     w = helper.create_parameter(
         attr=helper.param_attr, shape=size, dtype=dtype, is_bias=False)

@@ -20,7 +20,7 @@ Accepted forms of PADDLE_TPU_PALLAS:
     - "attn,xent"       : allowlist — exactly the named ops on, the
                           rest off.  Unknown names raise LOUDLY (a typo
                           must not silently run the other path).
-Op names: attn, xent, ln, lstm, seq, gdr, conv (KERNEL_OPS).  For 'attn' the flag
+Op names: attn, xent, ln, lstm, seq, gdr, conv, emb (KERNEL_OPS).  For 'attn' the flag
 is an opt-OUT only: fused_attention's positive dispatch is always the
 flash_at() rule, so enabling 'attn' does not force flash below the
 crossover (pin FLAGS_flash_min_seq=0 for that).
@@ -74,7 +74,15 @@ __all__ = [
 # / 0.39 and XLA's own forward 0.21: the host's dispatch more than the
 # kernel.  The 8-row tiles of
 # "xent" and "seq" have not been swept on the chip (ROADMAP A3);
-# softmax_xent at 8 rows already runs near the HBM rate.
+# softmax_xent at 8 rows already runs near the HBM rate.  "emb" is the
+# bytes of one block of vocabulary rows of embedding_grad's kernel, the
+# float32 [rows, D] a grid step zero-fills, adds to and writes.  Swept
+# on the v5e at 1, 2 and 4 MiB (PERF.md section 6, PR 41; the whole
+# backward alone, uniform ids): 1.72 / 1.52 / 1.44 ms for 8192 rows into
+# [37984, 2560], 2.61 / 2.41 / 2.32 for 16384 into [50304, 2048], 1.00 /
+# 0.95 / 0.91 for 8192 into [16384, 2048], no difference at [49152,
+# 2048], [18992, 2048] and [32000, 512]; two blocks in flight are half of
+# Mosaic's default scoped VMEM at 4 MiB.
 DEFAULT_TILES = {
     "attn": {"block_q": 512, "block_k": 512},
     "xent": {"block_n": 8},
@@ -83,6 +91,7 @@ DEFAULT_TILES = {
     "seq": {"block_n": 8},
     "gdr": {"chunk": 64, "block_h": 8},
     "conv": {"tile_bytes": 1 << 20},
+    "emb": {"tile_bytes": 4 << 20},
 }
 KERNEL_OPS = frozenset(DEFAULT_TILES)
 # Dense attention below this query length, flash at and above it.  The

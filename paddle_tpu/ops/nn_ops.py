@@ -564,7 +564,8 @@ def _lookup_table(ctx, ins, attrs):
     ids = single(ins, "Ids")    # [N, 1] int64
     flat = ids.reshape(-1).astype(jnp.int32)
     padding_idx = attrs.get("padding_idx", -1)
-    out = jnp.take(w, flat, axis=0)
+    from .embedding_grad import grad_form, take_rows
+    out = take_rows(w, flat, grad_form(flat.size, w.shape[1], ctx.mesh))
     if padding_idx is not None and padding_idx >= 0:
         out = jnp.where((flat == padding_idx)[:, None], 0.0, out)
     out_shape = tuple(ids.shape[:-1]) + (w.shape[-1],) \

@@ -53,7 +53,7 @@ KERNEL_NAMES = (
     "ptpu_softmax_xent_fwd", "ptpu_layer_norm_fwd", "ptpu_lstm_seq",
     "ptpu_lstmp_seq", "ptpu_masked_softmax", "ptpu_masked_pool",
     "ptpu_gated_delta_fwd", "ptpu_gated_delta_bwd",
-    "ptpu_causal_conv1d_fwd", "ptpu_causal_conv1d_bwd")
+    "ptpu_causal_conv1d_fwd", "ptpu_causal_conv1d_bwd", "ptpu_embedding_grad")
 
 
 def _interpret_default():

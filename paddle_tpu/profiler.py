@@ -298,8 +298,23 @@ def profile_report(sorted_key=None, json=False):
                 % (ss["total"], ss["on_dispatch_path"],
                    ", ".join("%s=%d" % kv
                              for kv in sorted(ss["by_tag"].items()))))
+        lines.extend(_embedding_lines())
         lines.extend(_lowering_lines())
     return "\n".join(lines)
+
+
+def _embedding_lines():
+    """One line a kind of lookup_table lowered: its rows, its table and who
+    builds the table's dense gradient, XLA's scatter or the kernel
+    (`ptpu_embedding_layers_total`)."""
+    from .observability.registry import REGISTRY
+    lines = []
+    for key, n in REGISTRY.counter(
+            "ptpu_embedding_layers_total").samples():
+        k = dict(key)
+        lines.append("embedding: %d x %s rows of [%s, %s], gradient by %s"
+                     % (n, k["rows"], k["vocab"], k["width"], k["grad"]))
+    return lines
 
 
 def _lowering_lines(limit=10):

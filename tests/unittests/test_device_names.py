@@ -170,9 +170,11 @@ def test_an_at_sign_would_cut_the_op_name_short():
 # --- a name a kernel -------------------------------------------------------
 
 def test_every_pallas_call_takes_its_name_from_kernel_names():
-    from paddle_tpu.ops import causal_conv_kernels, gated_delta_kernels
+    from paddle_tpu.ops import causal_conv_kernels, embedding_grad, \
+        gated_delta_kernels
     names = []
-    for module in (pallas_kernels, gated_delta_kernels, causal_conv_kernels):
+    for module in (pallas_kernels, gated_delta_kernels, causal_conv_kernels,
+                   embedding_grad):
         with open(module.__file__) as f:
             tree = ast.parse(f.read())
         for node in ast.walk(tree):
@@ -185,7 +187,7 @@ def test_every_pallas_call_takes_its_name_from_kernel_names():
                     % node.lineno
                 names.append(kw["name"].value)
     assert sorted(names) == sorted(pallas_kernels.KERNEL_NAMES)
-    assert len(set(names)) == len(names) == 13
+    assert len(set(names)) == len(names) == 14
     for a in names:         # a reader matching `<name>` or `<name>.<n>`
         for b in names:     # never counts one kernel under another
             assert a == b or not (b + ".").startswith(a + ".")
@@ -931,3 +933,55 @@ def test_the_attention_counter_says_how_many_heads_a_block(monkeypatch,
     jax.eval_shape(fn, shapes, [state[n] for n in rw],
                    [state[n] for n in ro], np.uint32(0))
     assert _counted_since(before) == {heads: layers}
+
+
+# --- the embedding's backward (PR 41) ----------------------------------------
+
+# (rows, vocabulary, width) of a token cell's lookup
+_LOOKUP_CELLS = {
+    "smallthinker_t8192": (8192, 37984, 2560),
+    "olmoe_t4096": (16384, 50304, 2048),
+    "lfm2_t8192": (8192, 16384, 2048),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(_LOOKUP_CELLS))
+def test_the_embeddings_backward_on_a_described_v5e(one_chip, monkeypatch,
+                                                    cell):
+    """fluid.layers.embedding and its grad op at a cell's shape, lowered by
+    build_program_fn and compiled for a TPU: Mosaic takes the kernel at the
+    block DEFAULT_TILES["emb"] gives (the row adds at a dynamic sublane, the
+    ids in SMEM, two 4 MiB blocks in flight inside the default scoped-VMEM
+    limit), named from KERNEL_NAMES under the grad op, and no scatter is
+    left in the compiled step."""
+    from paddle_tpu.ops import kernel_config
+    rows, vocab, width = _LOOKUP_CELLS[cell]
+    monkeypatch.delenv("PADDLE_TPU_PALLAS", raising=False)
+    monkeypatch.setattr(kernel_config, "dispatch_platform", lambda: "tpu")
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.unique_name.guard(), fluid.program_guard(main, startup):
+        ids = fluid.layers.data(name="ids", shape=[rows, 1], dtype="int64",
+                                append_batch_size=False)
+        ct = fluid.layers.data(name="ct", shape=[rows, width],
+                               dtype="float32", append_batch_size=False)
+        emb = fluid.layers.embedding(
+            ids, size=[vocab, width], param_attr=fluid.ParamAttr("table"))
+        fluid.backward.append_backward(fluid.layers.reduce_sum(emb * ct))
+    fetch = ["table@GRAD"]
+    rw, ro, outs = lowering.analyze_state(main, ["ids", "ct"], fetch)
+    fn = lowering.build_program_fn(main, ["ids", "ct"], fetch, rw, ro, outs)
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    text = _compile_uncached(
+        lambda ids, ct, w: fn([ids, ct], [], [w], 0),
+        sds((rows, 1), jnp.int32), sds((rows, width), jnp.float32),
+        sds((vocab, width), jnp.float32)).as_text()
+    calls = re.findall(
+        r'%?([\w.\-]+) = [^\n]*custom_call_target="tpu_custom_call"'
+        r'[^\n]*op_name="([^"]*)"', text)
+    assert [(name.rpartition(".")[0] if name.rpartition(".")[2].isdigit()
+             else name, lowering.parse_op_scope(op_name)[0])
+            for name, op_name in calls] \
+        == [("ptpu_embedding_grad", "lookup_table_grad")]
+    assert " scatter(" not in text
