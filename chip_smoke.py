@@ -11,7 +11,7 @@ from a seed, and checks what comes out by the repo's own means:
   A  ResNet-50 (the BASELINE.json headline): bf16, batch 256, Momentum;
      single steps on one device-resident batch, then one run(steps=K)
      block so the lax.scan lowering compiles too.
-  B  the base transformer (bench.py's config) at T=256 (dense attention,
+  B  the base transformer (the paper's base widths) at T=256 (dense attention,
      Pallas layer_norm + softmax_xent) and T=2048 (flash fwd + both bwd).
   C  every Pallas kernel family, as a one-op Program at the shape and
      dtype its shipped model gives it, forward and backward, against the

@@ -422,8 +422,8 @@ class CheckpointManager(object):
             job.handle._finish(path=path)
             wsp.end()
             # save-latency surface (ARCHITECTURE.md §24): the registry's
-            # histogram is what the bench-regression gate and /metrics
-            # read — one observation per published snapshot
+            # histogram is what /metrics reads — one observation per
+            # published snapshot
             reg.histogram(
                 "ptpu_checkpoint_save_seconds",
                 "background snapshot write+hash+fsync latency"

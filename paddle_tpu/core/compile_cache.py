@@ -57,7 +57,7 @@ _aot_default_dir = None
 _warned = set()
 
 # always-on counters (the profiler's per-tag view needs an active
-# profiler; subprocess tests and bench legs read these instead)
+# profiler; the subprocess tests read these instead)
 _aot_stats = {"hits": 0, "misses": 0, "stores": 0, "store_errors": 0,
               "load_errors": 0, "saved_s": 0.0}
 
@@ -102,8 +102,8 @@ def enable_persistent_cache():
     The one rule, and the tree's only ``jax_compilation_cache_dir``
     update: when ``JAX_COMPILATION_CACHE_DIR`` is set jax has already
     read it at import and nothing is set here; when it is not, the cache
-    goes to ``repo_cache_dir()``. Idempotent; entry points (bench.py,
-    chip_smoke.py, the tools) call it once before their first compile."""
+    goes to ``repo_cache_dir()``. Idempotent; entry points
+    (chip_smoke.py, the tools) call it once before their first compile."""
     import jax
     env_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if env_dir:

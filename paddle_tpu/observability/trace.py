@@ -16,7 +16,7 @@ Design constraints (all load-bearing, all tested):
     enable after the incident — it is a bounded ring that is always
     recording, so the watchdog/cluster abort bundle can embed "what the
     pipeline was doing" at the moment it wedged. `set_enabled(False)`
-    exists for A/B overhead benches (BENCH_OBS) and is not the
+    exists for an on/off comparison of its cost and is not the
     production configuration.
   * LOCK-CHEAP, NO HOST SYNCS. Events are host-side timestamps only
     (time.perf_counter); recording is one dict build + one append to a
@@ -339,7 +339,7 @@ def configure(capacity=None, enabled=None):
 
 
 def set_enabled(flag):
-    """Overhead A/B switch (BENCH_OBS). The recorder defaults ON and is
+    """Overhead A/B switch. The recorder defaults ON and is
     meant to stay on — spans are host timestamps into a bounded ring."""
     _recorder.enabled = bool(flag)
 

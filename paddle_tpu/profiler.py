@@ -187,8 +187,8 @@ def snapshot():
     compiles, compile_s, aot_hits, saved_s, idle_s, gaps}},
     "sync_stats": sync_stats(), "cache_stats": cache_stats(),
     "device_ops": the newest `device_op_table` or None}. This is
-    the PUBLIC surface for bench.py / the observability registry / CI
-    gates — nothing should read the private `_entries` dict (its
+    the PUBLIC surface for the observability registry and the tests
+    — nothing should read the private `_entries` dict (its
     "min" sentinel and optional keys are internal). Values are plain
     numbers (JSON-safe); `min` reads 0.0 for entries with no exec
     calls, matching the report."""
@@ -246,8 +246,8 @@ def profile_report(sorted_key=None, json=False):
     | 'ave' (reference profiler.py sorted_key contract).
 
     json=True returns the `snapshot()` dict instead of the rendered
-    table — the machine-readable contract bench.py and the
-    observability registry consume (sorted_key is still validated but
+    table — the machine-readable contract the
+    observability registry consumes (sorted_key is still validated but
     irrelevant: consumers sort their own views)."""
     _check_sorted_key(sorted_key)
     if json:

@@ -21,8 +21,8 @@ syncs), not a per-tensor D2H. On a trip the executor raises the typed
 was gated on device, the scope still holds the last-good state — "skip
 batch" recovery is exact, not hopeful. The backups are trace-time
 aliases (no copy op): XLA fuses each select into the update expression,
-so donation/in-place param updates survive and the measured overhead on
-a dispatch-bound model stays well under 10% (bench.py BENCH_RESIL=1).
+so donation/in-place param updates survive (what the guards cost on the
+chip: not measured, no cell installs them).
 
 Host side (`DivergenceDetector`): a running EMA of the loss with a
 configurable window; a loss that spikes past `threshold` x EMA (or goes

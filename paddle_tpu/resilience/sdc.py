@@ -61,7 +61,7 @@ class CanaryChecker(object):
     eagerly at startup, before any chip has had hours to degrade.
 
     The cadence cost is one small dispatch per `Supervisor(sdc_every=)`
-    steps; BENCH_SENTINEL=1 measures it (<3%% gated)."""
+    steps (on the chip: not measured)."""
 
     def __init__(self, shape=(128, 128), seed=0, iters=4, devices=None,
                  history=32):

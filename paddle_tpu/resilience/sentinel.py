@@ -98,7 +98,7 @@ class RobustWindow(object):
     The np.median formulation this replaces cost ~90us per observe
     (five median kernels over tiny arrays is all dispatch overhead),
     which at CPU smoke-model step rates was alone a measurable slice
-    of the <=3% overhead budget BENCH_SENTINEL=1 gates."""
+    of a step."""
 
     def __init__(self, window=64, warmup=16, eps=1e-9):
         self.window = max(2, int(window))

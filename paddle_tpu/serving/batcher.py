@@ -255,8 +255,8 @@ class Batcher(object):
         req.qspan = req.span.child("serving/queue")
         if req.span is not _trace._NOOP:
             # recorder disabled = genuinely zero per-request cost: the
-            # BENCH_OBS off leg is the baseline the <5% gate compares
-            # against, so it must not keep the callback overhead
+            # off side of an on/off comparison must not keep the
+            # callback overhead
             req.future.add_done_callback(_span_closer(req.span))
         with self._cond:
             if self._closed:

@@ -3,7 +3,7 @@ depth, rejection/deadline counters.
 
 One `ServingMetrics` per `InferenceEngine`. Writers are the request
 threads (submit/reject) and the batcher worker (dispatch); readers are
-`/metrics` (Prometheus text), `/v1/models` (JSON), and bench.py — all
+`/metrics` (Prometheus text) and `/v1/models` (JSON) — all
 under one lock, all O(window) worst case.
 
 The batcher worker also threads every dispatch into
@@ -139,8 +139,8 @@ class DecodeMetrics(object):
     consecutive tokens — the latency a generative client feels), and
     tokens/s is measured over a recent bounded window so the gauge
     tracks current load, not lifetime average.  Readers: the
-    observability-registry decoder collector (`/metrics`),
-    `pool_state()`, and bench.py."""
+    observability-registry decoder collector (`/metrics`) and
+    `pool_state()`."""
 
     def __init__(self, latency_window=4096):
         self._lock = threading.Lock()

@@ -4,7 +4,7 @@ honoured by jax and is also what lets TPUPlace code run here
 (places.cpu_only_env)."""
 import os
 
-os.environ["JAX_PLATFORMS"] = "cpu"  # the chip is for chip_smoke.py/bench.py
+os.environ["JAX_PLATFORMS"] = "cpu"  # the chip is for chip_smoke.py and benchmark/run.py
 flags = os.environ.get("XLA_FLAGS", "")
 if "xla_force_host_platform_device_count" not in flags:
     os.environ["XLA_FLAGS"] = (

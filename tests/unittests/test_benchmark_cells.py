@@ -1,0 +1,133 @@
+"""The cells of BENCHMARK.json, as far as a CPU can see them with no array.
+
+The driver judges every PR by `python benchmark/run.py` in each cell, on the
+chip. Two things a program change can break there are cheap to see here:
+
+  * a cell's builder at its published widths (a renamed layer argument, an
+    op the analyzer refuses): every cell's whole training Program (forward,
+    backward, optimizer) is built and analyzed, nothing is run;
+  * a name the benchmark's readers spell: a Pallas kernel's, a counter
+    family's, a span's. A rename in the program reads as `null` under
+    `per_layer` on the chip; here it fails by name.
+
+The rehearsals of the driver's command are test_benchmark_rehearsal.py.
+"""
+import functools
+import glob
+import json
+import os
+import re
+import sys
+
+import pytest
+
+REPO = os.path.dirname(os.path.dirname(
+    os.path.dirname(os.path.abspath(__file__))))
+MANIFEST = os.path.join(REPO, "BENCHMARK.json")
+sys.path.insert(0, REPO)
+
+
+def _read(path):
+    with open(path) as f:
+        return f.read()
+
+
+def _sources(*tops):
+    files = []
+    for top in tops:
+        path = os.path.join(REPO, top)
+        if os.path.isfile(path):
+            files.append(path)
+        for root, _, names in os.walk(path):
+            files += [os.path.join(root, n) for n in names
+                      if n.endswith(".py")]
+    return sorted(files)
+
+
+# ------------------------------------------------------- (a) the builders --
+CELLS = [w["name"] for w in json.loads(_read(MANIFEST))["workloads"]]
+
+
+@pytest.mark.parametrize("name", CELLS)
+def test_cell_builds_its_training_program_at_published_widths(name):
+    import paddle_tpu as fluid
+    from paddle_tpu.analysis import analyze
+    from benchmark import manifest
+
+    cell = manifest.load_cell(MANIFEST, name)
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.unique_name.guard(), fluid.program_guard(main, startup):
+        fetches = cell.config_module.build(fluid, cell.config, cell.traffic)
+    ops = {op.type for op in main.global_block().ops}
+    assert "grad_of" in ops, "no backward pass"
+    assert ops & {"adam", "momentum"}, "no optimizer"
+    assert main.global_block().all_parameters()
+    assert fetches
+    result = analyze(main)
+    assert not result.errors, [str(d) for d in result.errors]
+    assert cell.config_module.ops_per_sample(cell.config, cell.traffic) > 0
+    assert cell.config_module.samples_per_step(cell.config, cell.traffic) > 0
+
+
+# ------------------------------------------ (b) the names the readers spell --
+READERS = sorted(
+    glob.glob(os.path.join(REPO, "benchmark", "layer_metrics", "*.py"))
+    + glob.glob(os.path.join(REPO, "benchmark", "configs", "*.py"))
+    + [os.path.join(REPO, "benchmark", "program_reads.py"),
+       os.path.join(REPO, "benchmark", "kernel_ms.py")])
+
+
+def _spelled():
+    names = set()
+    for path in READERS:
+        names.update(re.findall(r"\bptpu_[a-z0-9_]+\b", _read(path)))
+    names.update(re.findall(
+        r'"(exec/[a-z_]+)"',
+        _read(os.path.join(REPO, "benchmark", "program_reads.py"))))
+    return sorted(names)
+
+
+@functools.lru_cache(maxsize=None)
+def _declared():
+    """Counter families and spans a module under paddle_tpu/ declares: the
+    first argument of a registry family's constructor, or of a span's."""
+    families, spans = set(), set()
+    for path in _sources("paddle_tpu"):
+        src = _read(path)
+        families.update(re.findall(
+            r'\b(?:counter|gauge|histogram)\(\s*"(ptpu_[a-z0-9_]+)"', src))
+        spans.update(re.findall(
+            r'\b(?:span|child|enter)\(\s*"([a-z_]+/[a-z_]+)"', src))
+    return families, spans
+
+
+@pytest.mark.parametrize("name", _spelled())
+def test_a_name_the_benchmark_reads_is_one_the_program_defines(name):
+    from paddle_tpu.ops.pallas_kernels import KERNEL_NAMES
+    families, spans = _declared()
+    if name.startswith("exec/"):
+        assert name in spans, "no span %r under paddle_tpu/" % name
+    elif name.endswith("_total"):
+        assert name in families, "no counter family %r" % name
+    else:
+        assert name in KERNEL_NAMES, "no Pallas kernel named %r" % name
+
+
+def test_the_readers_spell_some_names():
+    """The scan above finds what it is for: kernels, counters and spans."""
+    names = _spelled()
+    assert any(n.startswith("exec/") for n in names)
+    assert any(n.endswith("_total") for n in names)
+    assert len(names) >= 10
+
+
+# -------------------------------------------------- (c) one way to measure --
+def test_no_program_file_reads_a_bench_environment_name():
+    """Source guard: what selects a measurement is the benchmark's manifest
+    and its command's arguments, never an environment name of the deleted
+    bench script's family."""
+    prefix = "BENCH" + "_"
+    hits = [os.path.relpath(path, REPO)
+            for path in _sources("paddle_tpu", "tools", "chip_smoke.py")
+            if re.search(r"\b%s[A-Z0-9_]*" % prefix, _read(path))]
+    assert hits == []

@@ -636,6 +636,58 @@ def test_async_save_backpressure_and_capture_isolation(tmp_path):
         assert not np.array_equal(np.asarray(scope.get(param)), at_save)
 
 
+@pytest.mark.parametrize("mode", ["async", "sync"])
+def test_save_returns_before_the_write_only_when_async(tmp_path,
+                                                       monkeypatch, mode):
+    """What async saving is for, as an order of events and not a stall in
+    milliseconds: an async save() hands the snapshot to the writer thread
+    and returns while that thread still holds the write; a sync save (the
+    manager's, or wait=True on an async manager's) returns only after the
+    snapshot is published."""
+    import threading
+    from paddle_tpu.checkpoint import manager as mgr_mod
+    main, startup, loss = _build("sgd")
+    exe = fluid.Executor(fluid.CPUPlace())
+    events, held = [], threading.Event()
+    in_write = threading.Event()
+    real_write = mgr_mod._snap.write_snapshot
+
+    def write(*args, **kwargs):
+        events.append("write begins")
+        in_write.set()
+        if mode == "async":          # the writer waits for save()'s return
+            assert held.wait(60)
+        path = real_write(*args, **kwargs)
+        events.append("write ends")
+        return path
+
+    monkeypatch.setattr(mgr_mod._snap, "write_snapshot", write)
+    scope = fluid.Scope()
+    with fluid.scope_guard(scope):
+        exe.run(startup)
+        with CheckpointManager(str(tmp_path),
+                               async_save=(mode == "async")) as mgr:
+            h = mgr.save(1, program=main, scope=scope)
+            if mode == "async":
+                assert in_write.wait(60)     # the writer has the snapshot
+                assert not h.done()          # and save() is already back
+            events.append("save returns")
+            held.set()
+            path = h.result(60)
+            if mode == "async":
+                # wait=True on the same manager is the sync contract
+                mgr.save(2, program=main, scope=scope, wait=True)
+                events.append("waited save returns")
+    if mode == "async":
+        assert events == ["write begins", "save returns", "write ends",
+                          "write begins", "write ends",
+                          "waited save returns"]
+    else:
+        assert events == ["write begins", "write ends", "save returns"]
+        assert h.done()
+    assert verify_snapshot(path) == []
+
+
 # ----------------------------------------------------- serving + tools --
 def test_engine_from_checkpoint(tmp_path):
     """The serving engine loads the newest valid training snapshot as a

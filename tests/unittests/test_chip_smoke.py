@@ -5,13 +5,15 @@ What tier-1 can hold on to: the --tiny rehearsal walks every phase and
 exits 0 with the contract's last line; a failing phase makes the exit
 code non-zero; without --tiny a host with no TPU exits non-zero before
 running a step; and importing the entry points initialises no backend,
-because bench.py's compile-cache leg and tools/ptpu_elastic.py start
+because tools/ptpu_elastic.py starts
 children that need the chip a parent would otherwise be holding.
 """
 import json
 import os
 import subprocess
 import sys
+
+import pytest
 
 REPO = os.path.dirname(os.path.dirname(
     os.path.dirname(os.path.abspath(__file__))))
@@ -25,9 +27,29 @@ def _env(**extra):
     return env
 
 
-def test_tiny_rehearsal_passes_every_phase():
-    out = subprocess.run([sys.executable, SMOKE, "--tiny"], env=_env(),
-                         cwd=REPO, capture_output=True, text=True,
+# what a phase's own lines have to say, beside its verdict
+PHASE_SAYS = {
+    # phase C walked the kernels (interpreted here, Mosaic on the chip) and
+    # timed what XLA runs around the delta rule's kernels, part by part
+    "C": lambda lines: sum("interpreted;" in line for line in lines) >= 7
+    and any("gated_delta_rule's XLA parts" in line
+            and "(I + L)^-1 of [2, 4, 1, 64, 64]" in line
+            and "_prepare forward + transpose" in line for line in lines),
+    # phase F ran the convolution on both of its paths
+    "F": lambda lines: all(
+        any("causal_conv1d %s path" % path in line
+            and "off the float32 recomputation by y " in line
+            for line in lines) for path in ("kernel", "xla")),
+}
+
+
+@pytest.mark.parametrize("letter", "ABCDEFGHI")
+def test_tiny_rehearsal_passes_every_phase(letter):
+    """One case a phase (`--phases <letter>`), so that a red run names it."""
+    # phase E times the program phase A left
+    phases = {"E": "AE"}.get(letter, letter)
+    out = subprocess.run([sys.executable, SMOKE, "--tiny", "--phases", phases],
+                         env=_env(), cwd=REPO, capture_output=True, text=True,
                          timeout=900)
     assert out.returncode == 0, out.stdout + out.stderr
     lines = out.stdout.strip().splitlines()
@@ -37,22 +59,13 @@ def test_tiny_rehearsal_passes_every_phase():
     assert lines[0].startswith("chip_smoke: jax=")
     # a CPU line can never be mistaken for a chip line
     assert all("platform=cpu" in line for line in lines[:-1])
-    for letter in "ABCEF":
-        assert any("phase %s " % letter in line and " passed in " in line
-                   for line in lines), letter
-    assert any("phase D " in line and "skipped: needs 4 devices" in line
-               for line in lines)
-    # phase C walked the kernels (interpreted here, Mosaic on the chip)
-    assert sum("interpreted;" in line for line in lines) >= 7
-    # and timed what XLA runs around the delta rule's kernels, part by part
-    assert any("gated_delta_rule's XLA parts" in line
-               and "(I + L)^-1 of [2, 4, 1, 64, 64]" in line
-               and "_prepare forward + transpose" in line for line in lines)
-    # phase F ran the convolution on both of its paths
-    for path in ("kernel", "xla"):
-        assert any("causal_conv1d %s path" % path in line
-                   and "off the float32 recomputation by y " in line
-                   for line in lines), path
+    # one CPU device: phase D must skip
+    verdict = " skipped: needs 4 devices" if letter == "D" else " passed in "
+    ran = [line for line in lines if "] phase " in line]
+    assert len(ran) == len(phases) and "phase %s " % letter in ran[-1] \
+        and verdict in ran[-1], ran
+    if letter in PHASE_SAYS:
+        assert PHASE_SAYS[letter](lines)
 
 
 _FAILING_RUN = """
@@ -113,7 +126,7 @@ def test_tiny_needs_the_explicit_cpu_pin():
 
 def test_importing_the_entry_points_initialises_no_backend():
     code = ("import sys; sys.path.insert(0, %r)\n"
-            "import paddle_tpu, bench, chip_smoke\n"
+            "import paddle_tpu, chip_smoke\n"
             "from jax._src import xla_bridge\n"
             "assert not xla_bridge._backends, xla_bridge._backends\n"
             % REPO)

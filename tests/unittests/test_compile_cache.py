@@ -246,6 +246,44 @@ def test_seeded_program_hit_replays_rng_stream(aot_dir):
         assert np.array_equal(a, b)
 
 
+def test_restart_from_a_checkpoint_reenters_from_the_cache(aot_dir,
+                                                          tmp_path):
+    """The trainer's restart (and the supervisor's rollback re-entry): a
+    rebuilt program in a fresh executor restores the saved step and takes
+    its next one from the cache (no fresh compile), bit-identical to the
+    run that was never interrupted."""
+    from paddle_tpu.checkpoint import CheckpointManager
+    feed = _feed()
+
+    def trainer():
+        main, startup, loss = _build_model()
+        exe, scope = fluid.Executor(fluid.CPUPlace()), fluid.Scope()
+        with fluid.scope_guard(scope):
+            exe.run(startup)
+        return main, loss, exe, scope
+
+    def steps(main, loss, exe, scope, n):
+        with fluid.scope_guard(scope):
+            return [exe.run(main, feed=feed, fetch_list=[loss])[0]
+                    for _ in range(n)]
+
+    main, loss, exe, scope = trainer()
+    steps(main, loss, exe, scope, 4)
+    with CheckpointManager(str(tmp_path / "ckpt"), async_save=False) as mgr:
+        mgr.save(4, program=main, scope=scope)
+    want = steps(main, loss, exe, scope, 2)       # steps 5 and 6, unbroken
+
+    cc.reset_aot_stats()
+    main2, loss2, exe2, scope2 = trainer()
+    with CheckpointManager(str(tmp_path / "ckpt"), async_save=False) as mgr:
+        assert mgr.restore(program=main2, scope=scope2) == 4
+    got = steps(main2, loss2, exe2, scope2, 2)
+    st = cc.aot_stats()
+    assert st["stores"] == 0 and st["hits"] == 2 and st["load_errors"] == 0
+    for a, b in zip(want, got):
+        assert np.array_equal(a, b)
+
+
 # ------------------------------------------------------------ cross-process
 _CHILD = r"""
 import json, os, sys

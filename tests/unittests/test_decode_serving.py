@@ -129,19 +129,33 @@ def test_mixed_streams_bit_exact_vs_solo(eng, solo):
     assert after["mean_slot_occupancy"] > 1.0
 
 
-def test_incremental_delivery_and_admit_mid_decode(eng, solo):
+def test_incremental_delivery_and_admit_mid_decode(eng, solo, monkeypatch):
     """Tokens arrive per ITERATION (not at stream end), and a stream
     submitted while another decodes is admitted at an iteration boundary
     mid-flight — proven by the decode_step span that carries both
-    stream ids after earlier steps carried only the first."""
+    stream ids after earlier steps carried only the first. The step loop
+    is let through one iteration at a time until B is in the queue, so
+    that "mid-decode" is an order of events and not a race between this
+    thread and ten CPU iterations (it lost that race beside five other
+    test workers)."""
     trace.clear()
     rng = np.random.RandomState(1)
     fa, fb = stream_feed(3, rng), stream_feed(9, rng)
+    real_step, let = eng._batcher._step, threading.Semaphore(0)
+
+    def step():
+        assert let.acquire(timeout=60)
+        return real_step()
+
+    monkeypatch.setattr(eng._batcher, "_step", step)
     a = eng.submit(fa, max_new_tokens=10)
+    let.release()                           # A's first iteration, no more
     first = a.next_token(timeout=30)        # delivered before A is done
     assert first is not None and not a.done()
     a_count_at_b = a.token_count()
     b = eng.submit(fb, max_new_tokens=4)
+    monkeypatch.setattr(eng._batcher, "_step", real_step)
+    let.release()                           # the step the loop waits in
     got_a = toks(a.result(60))
     got_b = toks(b.result(60))
     assert a_count_at_b < len(got_a)        # B arrived mid-decode of A

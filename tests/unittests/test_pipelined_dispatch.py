@@ -161,6 +161,68 @@ def test_pipelined_drain_and_close_complete_everything(tmp_path):
         f.result(1).numpy()
 
 
+def test_depth_two_dispatches_the_next_batch_before_a_completion_and_no_third(
+        tmp_path, monkeypatch):
+    """What continuous batching is, as an order of events and not a
+    latency at a load point: with pipeline_depth=2 batch n+1 is dispatched
+    while batch n still holds its window slot, a third batch waits for a
+    slot, and it goes when the first completes. The window's completion
+    thread is held at its one device wait so that the order is not left
+    to how fast a CPU steps."""
+    import jax
+    from paddle_tpu import serving
+    from paddle_tpu.observability import trace
+    model_dir, feat = _save_mlp(tmp_path)
+    engine = serving.InferenceEngine(
+        model_dir, name="order", max_batch_size=1, batch_buckets=[1],
+        max_queue_delay_ms=0, pipeline_depth=2)
+    release = threading.Event()
+    real = jax.block_until_ready
+
+    def held(arrays):
+        if threading.current_thread().name.startswith("ptpu-window-"):
+            assert release.wait(60)
+        return real(arrays)
+
+    def names(spans):
+        return sorted(s["name"] for s in spans
+                      if s["name"] in ("serving/dispatch", "serving/execute",
+                                       "serving/window_wait"))
+
+    try:
+        monkeypatch.setattr(jax, "block_until_ready", held)
+        trace.configure(capacity=4096)
+        rng = np.random.RandomState(0)
+        futures = [engine.submit({"x": rng.rand(1, feat).astype("float32")})
+                   for _ in range(3)]
+        # two batches dispatched and in the window, the third waiting
+        want = ["serving/execute", "serving/execute", "serving/window_wait"]
+        deadline = time.monotonic() + 60
+        while time.monotonic() < deadline and \
+                names(trace.dump()["open"]) != want:
+            time.sleep(0.01)
+        held_now = trace.dump()
+        assert names(held_now["open"]) == want
+        assert names(held_now["events"]).count("serving/dispatch") == 2
+        release.set()
+        for f in futures:
+            f.result(60).numpy()
+        engine.drain(30)
+        deadline = time.monotonic() + 10    # execute spans close off-thread
+        while time.monotonic() < deadline and trace.dump()["open"]:
+            time.sleep(0.01)
+        events = trace.dump()["events"]
+        dispatch = sorted(e["ts"] for e in events
+                          if e["name"] == "serving/dispatch")
+        done = sorted(e["ts"] + e["dur"] for e in events
+                      if e["name"] == "serving/execute")
+        assert len(dispatch) == len(done) == 3
+        assert dispatch[1] < done[0] <= dispatch[2]
+    finally:
+        release.set()
+        engine.close()
+
+
 def test_serial_mode_still_available(tmp_path):
     """pipeline_depth=0 keeps the PR-3 serial loop (the bench baseline
     and a conservative fallback) — same results, no window."""

@@ -111,10 +111,9 @@ def test_default_cache_dir_is_the_checkout_in_every_process():
 
 def test_one_cache_dir_update_in_the_tree_and_no_temp_paths():
     """Source guard: one `config.update("jax_compilation_cache_dir", ...)`
-    in paddle_tpu, bench.py, chip_smoke.py and tools — in the deciding
+    in paddle_tpu, chip_smoke.py and tools — in the deciding
     function — and core/compile_cache.py builds no path from tempfile."""
-    files = [os.path.join(REPO, "bench.py"),
-             os.path.join(REPO, "chip_smoke.py")]
+    files = [os.path.join(REPO, "chip_smoke.py")]
     for top in ("paddle_tpu", "tools"):
         for root, _, names in os.walk(os.path.join(REPO, top)):
             files += [os.path.join(root, n) for n in names

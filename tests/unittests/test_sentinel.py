@@ -64,10 +64,11 @@ def _feed_fn(i):
 _CACHE = {}
 
 
-def _feed_setup(grad_norm=False):
+def _feed_setup(grad_norm=False, guarded=True):
     """A guarded feed-fed Adam trainer; grad_norm=True adds the stat
-    channel (one cached program per mode)."""
-    key = "feed_gn" if grad_norm else "feed"
+    channel, guarded=False installs nothing (one cached program per
+    mode)."""
+    key = ("feed_gn" if grad_norm else "feed") if guarded else "feed_plain"
     if key not in _CACHE:
         main, startup = fluid.Program(), fluid.Program()
         main.random_seed = 5
@@ -80,7 +81,8 @@ def _feed_setup(grad_norm=False):
             loss = fluid.layers.mean(
                 x=fluid.layers.square_error_cost(input=p, label=y))
             fluid.optimizer.Adam(learning_rate=0.01).minimize(loss)
-        rz.install_numeric_guards(main, loss=loss, grad_norm=grad_norm)
+        if guarded:
+            rz.install_numeric_guards(main, loss=loss, grad_norm=grad_norm)
         _CACHE[key] = (main, startup, loss)
     return _CACHE[key]
 
@@ -270,6 +272,48 @@ def test_grad_norm_stat_channel(tmp_path):
     assert isinstance(
         s.observe(1.0, grad_norm=float(g1) * 1e8, step=6),
         LossSpikeError)
+
+
+@pytest.mark.parametrize("steps", [1, 4])
+@pytest.mark.parametrize("watched", ["guards", "sentinel"])
+def test_watching_a_trainer_adds_no_host_read_to_a_step(watched, steps):
+    """What "the sentinel is on everywhere" may cost, as a count and not a
+    share of a CPU's milliseconds: a watched step records the spans an
+    unwatched one records, one exec/d2h among them (the fetch the caller
+    asked for), and books the one host sync it books. The guards' flags
+    and the grad-norm statistic ride the dispatch's own outputs; observe()
+    is host arithmetic on values already fetched. Plain and steps=K."""
+    from paddle_tpu import profiler
+    from paddle_tpu.observability import trace
+
+    run_kw = {"steps": steps, "fetch_reduce": "last"} if steps > 1 else {}
+    sentinel = TrainingSentinel(window=8, warmup=4)
+
+    def steady_step(main, startup, loss, observe):
+        """(span names, host syncs by tag) of one run after a warm one."""
+        with fluid.scope_guard(fluid.Scope()):
+            EXE.run(startup)
+            EXE.run(main, feed=_feed_fn(0), fetch_list=[loss], **run_kw)
+            trace.configure(capacity=4096)
+            profiler.reset_profiler()
+            out = EXE.run(main, feed=_feed_fn(1), fetch_list=[loss],
+                          **run_kw)
+            if observe:
+                gn = EXE.last_stats["grad_norm"]
+                assert sentinel.observe(
+                    float(np.asarray(out[0]).reshape(-1)[0]),
+                    grad_norm=float(np.asarray(gn)), step=0) is None
+            names = [ev["name"] for ev in trace.dump()["events"]]
+            return names, profiler.sync_stats()["by_tag"]
+
+    plain_names, plain_syncs = steady_step(*_feed_setup(guarded=False),
+                                           observe=False)
+    names, syncs = steady_step(*_feed_setup(grad_norm=watched == "sentinel"),
+                               observe=watched == "sentinel")
+    assert plain_names.count("exec/d2h") == 1
+    assert plain_syncs == {"executor/return_numpy": 1}
+    assert names == plain_names
+    assert syncs == plain_syncs
 
 
 # -------------------------------------------------------- fault kinds --

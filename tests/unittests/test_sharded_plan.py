@@ -499,7 +499,7 @@ def test_dedicated_shard_axis_trains_and_matches():
 # donating multi-device executables through jax's persistent HLO cache.
 # An older jax corrupted donated buffers after deserializing them and the
 # ParallelExecutor opted those compiles out of the cache; on jax 0.9.0 the
-# fault is gone (PR 21: 12 warm BENCH_SHARDED runs bit-identical), so they
+# fault is gone (PR 21: 12 warm runs bit-identical), so they
 # cache like everything else — and a warm load must train bit-identically.
 # --------------------------------------------------------------------------
 def test_donating_pe_compile_round_trips_through_jax_hlo_cache(tmp_path):
