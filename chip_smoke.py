@@ -38,6 +38,14 @@ from a seed, and checks what comes out by the repo's own means:
      transpose and the least time the bytes take; each checked against
      float64 sums of the rows on the host.
 
+  J  latent attention and the hyper-connections at the Xing4.0 cell's
+     shapes: the three flash kernels at a head of 192 on values of 128
+     with the one rotary key all heads share ([1, 4096, 32, 128 | 64]
+     bf16), forward, dq, dk, dv, dq_rope and dk_rope against the dense
+     float32 attention on the same rounded inputs; the `ptpu_mhc_*` kernels
+     on a stream [4096, 4 x 3584] bf16, forward and backward, against the
+     plain jax.numpy passes in float32; each part's milliseconds.
+
 Every phase that fails makes the exit code non-zero. Timings are printed
 for the next reader, labelled with the device; they are not metrics. The
 last line of stdout is one JSON object, {"ok": ..., "device": {...}}.
@@ -109,6 +117,10 @@ FULL = {
                   ("qwen3_next_80b_a3b", 4096, 18992, 2048),
                   ("ouro_2_6b", 4096, 49152, 2048),
                   ("lfm2_8b_a1b", 8192, 16384, 2048)),
+    # the Xing4.0 cell's: one sequence of 4096, 32 heads of 128 + 64 on
+    # values of 128; four streams of 3584
+    "latent": dict(t=4096, h=32, d=128, dr=64, streams=4, c=3584,
+                   iters=20, tol=3e-2),
     "barrier": dict(steps=5, rounds=3, tol=0.15),
     "dp_loss_rtol": 2e-2,
 }
@@ -137,6 +149,8 @@ TINY = {
     "lfm2": dict(t=64, d=256, width=3, experts=8, held=4, f=128, top_k=2),
     "embedding": (("a row of 8 KiB", 96, 200, 2048),
                   ("a row of 1 KiB", 96, 200, 256)),
+    "latent": dict(t=64, h=2, d=128, dr=64, streams=4, c=128, iters=20,
+                   tol=3e-2),
     "barrier": dict(steps=5, rounds=3, tol=0.75),
     "dp_loss_rtol": 2e-2,
 }
@@ -1445,6 +1459,141 @@ def phase_i(smoke):
                                for k, t in times.items())))
 
 
+def phase_j(smoke):
+    """The latent form of the flash kernels and the hyper-connections'
+    kernels, each alone at the Xing4.0 cell's shapes, against float32
+    references on the same (bf16-rounded) inputs; times in flight, printed
+    for the next reader."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.ops import mhc_kernels, pallas_kernels
+    from paddle_tpu.parallel.ring_attention import attention_reference
+
+    c = smoke.cfg["latent"]
+    t, h, d, dr, tol = c["t"], c["h"], c["d"], c["dr"], c["tol"]
+    keys = jax.random.split(jax.random.key(43), 12)
+    bf = jnp.bfloat16
+
+    def draw(key, shape, scale=1.0):
+        return (scale * jax.random.normal(key, shape, jnp.float32)).astype(bf)
+
+    q, k, v, g = (draw(keys[i], (1, t, h, d)) for i in range(4))
+    qr, kr = draw(keys[4], (1, t, h, dr)), draw(keys[5], (1, t, 1, dr))
+    scale = 0.14468
+
+    def flash(q, k, v, qr, kr):
+        return pallas_kernels.flash_attention(
+            q, k, v, causal=True, scale=scale, q_rope=qr, k_rope=kr)
+
+    def dense(q, k, v, qr, kr):     # float32, the naive way, a head at once
+        qq = jnp.concatenate([q, qr], -1).astype(jnp.float32)
+        kk = jnp.concatenate([k, jnp.broadcast_to(kr, qr.shape)],
+                             -1).astype(jnp.float32)
+        with jax.default_matmul_precision("highest"):
+            return jax.lax.map(
+                lambda xs: attention_reference(
+                    xs[0][None, :, None], xs[1][None, :, None],
+                    xs[2][None, :, None], causal=True, scale=scale)[0, :, 0],
+                (qq[0].transpose(1, 0, 2), kk[0].transpose(1, 0, 2),
+                 v[0].astype(jnp.float32).transpose(1, 0, 2))
+            ).transpose(1, 0, 2)[None]
+
+    def both(fn):
+        def run(*args):
+            out, vjp = jax.vjp(fn, *args)
+            return (out,) + vjp(g.astype(out.dtype))
+        return jax.jit(run)
+
+    names = ("out", "dq", "dk", "dv", "dq_rope", "dk_rope")
+    got = both(flash)(q, k, v, qr, kr)
+    want = both(dense)(q, k, v, qr, kr)
+    errors = _normalized_errors(names, got, want)
+    worst = max(errors.values())
+    ms = {"forward": _in_flight_ms(jax.jit(flash), (q, k, v, qr, kr)),
+          "forward + backward": _in_flight_ms(both(flash),
+                                              (q, k, v, qr, kr))}
+    plain = jax.jit(lambda q, k, v: pallas_kernels.flash_attention(
+        q, k, v, causal=True))
+    ms["forward at 128 | 128, no rotary part"] = _in_flight_ms(plain,
+                                                                (q, k, v))
+    smoke.say("J flash at %d | %d with one rotary key, [1, %d, %d]: off the "
+              "float32 dense attention by %s (tolerance %g); ms %s"
+              % (d + dr, d, t, h, ", ".join(
+                  "%s %.2e" % kv for kv in errors.items()), tol,
+                 ", ".join("%s %.3f" % kv for kv in ms.items())))
+    if not worst <= tol:
+        raise AssertionError("the latent flash kernels are %.2e off" % worst)
+
+    n, width, iters = c["streams"], c["c"], c["iters"]
+    kk = mhc_kernels.columns(n)
+    x = draw(keys[6], (t, n * width))
+    phi = 0.02 * jax.random.normal(keys[7], (n * width, kk), jnp.float32)
+    alpha = jnp.full((3,), 0.5, jnp.float32)
+    bias = 0.5 * jax.random.normal(keys[8], (kk,), jnp.float32)
+    y, gh = draw(keys[9], (t, width)), draw(keys[10], (t, width))
+    gx = draw(keys[11], (t, n * width))
+    args = (n, iters, 1e-6, (-30.0, 30.0))
+
+    def layer(kernels, dtype):
+        def run(x, phi, alpha, bias, y):
+            x, y = x.astype(dtype), y.astype(dtype)
+            read, coef, stream = mhc_kernels.pre(x, phi, alpha, bias, *args,
+                                                 kernels)
+            out = mhc_kernels.post(stream, y, coef, n, kernels)
+            return read, out, coef
+
+        def with_grads(x, phi, alpha, bias, y):
+            (read, out, coef), vjp = jax.vjp(run, x, phi, alpha, bias, y)
+            return (read, out, coef) + vjp((
+                gh.astype(dtype), gx.astype(dtype), jnp.zeros_like(coef)))
+        return jax.jit(run), jax.jit(with_grads)
+
+    fwd, full = layer(True, bf)
+    _, reference = layer(False, jnp.float32)
+    names = ("h", "x_out", "coef", "dx", "dphi", "dalpha", "dbias", "dy")
+    errors = _normalized_errors(names, full(x, phi, alpha, bias, y),
+                                reference(x, phi, alpha, bias, y))
+    worst = max(errors.values())
+    parts = {
+        "pre": jax.jit(lambda x: mhc_kernels.pre(x, phi, alpha, bias, *args,
+                                                 True)[:2]),
+        "expand": jax.jit(lambda y: mhc_kernels.expand(y, n, True)),
+        "reduce": jax.jit(lambda x: mhc_kernels.reduce(x, n, True))}
+    ms = {"forward": _in_flight_ms(fwd, (x, phi, alpha, bias, y)),
+          "forward + backward": _in_flight_ms(full, (x, phi, alpha, bias, y)),
+          "pre alone": _in_flight_ms(parts["pre"], (x,)),
+          "expand": _in_flight_ms(parts["expand"], (y,)),
+          "reduce": _in_flight_ms(parts["reduce"], (x,))}
+    # the kernels' one tile, rows a block: forward + backward at each
+    from paddle_tpu.ops import kernel_config
+    tile = kernel_config.DEFAULT_TILES["mhc"]
+    chosen, by_rows = tile["block_rows"], {}
+    try:
+        for rows in (64, 128, 256):
+            if t % rows:
+                continue
+            tile["block_rows"] = rows
+            try:
+                by_rows[rows] = "%.3f" % _in_flight_ms(
+                    layer(True, bf)[1], (x, phi, alpha, bias, y))
+            except Exception as e:  # noqa: BLE001 — Mosaic refused the tile
+                by_rows[rows] = "refused (%s)" % type(e).__name__
+    finally:
+        tile["block_rows"] = chosen
+    ms["forward + backward by rows a block"] = json.dumps(by_rows)
+    stream_ms = 1e3 * x.size * 2 / 819e9
+    smoke.say("J hyper-connection of %d streams, [%d, %d] bf16 (a stream "
+              "array's bytes take %.3f ms): off the float32 jax.numpy passes "
+              "by %s (tolerance %g); ms %s"
+              % (n, t, n * width, stream_ms, ", ".join(
+                  "%s %.2e" % kv for kv in errors.items()), tol,
+                 ", ".join("%s %s" % (k, v if isinstance(v, str)
+                                      else "%.3f" % v)
+                           for k, v in ms.items())))
+    if not worst <= tol:
+        raise AssertionError("the mhc kernels are %.2e off" % worst)
+
+
 PHASES = (("A", "ResNet-50 training", phase_a),
           ("B", "transformer training", phase_b),
           ("C", "Pallas kernel families", phase_c),
@@ -1453,7 +1602,8 @@ PHASES = (("A", "ResNet-50 training", phase_a),
           ("F", "causal_conv1d kernels", phase_f),
           ("G", "looped decoder's summed gradients", phase_g),
           ("H", "LFM2's gated convolution and sigmoid router", phase_h),
-          ("I", "the embedding's backward", phase_i))
+          ("I", "the embedding's backward", phase_i),
+          ("J", "latent attention and hyper-connections", phase_j))
 
 
 def main(argv=None):
@@ -1461,7 +1611,7 @@ def main(argv=None):
     ap.add_argument("--tiny", action="store_true",
                     help="CPU rehearsal at toy sizes (needs "
                          "JAX_PLATFORMS=cpu)")
-    ap.add_argument("--phases", default="ABCDEFGHI",
+    ap.add_argument("--phases", default="ABCDEFGHIJ",
                     help="letters of the phases to run (default all)")
     args = ap.parse_args(argv)
 

@@ -691,6 +691,8 @@ def _lower_op_inner(ctx, op, env):
         _count_causal_conv_layer(op.attrs, ins)
     elif op.type == "lookup_table":
         _count_embedding_layer(ctx, ins)
+    elif op.type == "mhc_pre":
+        _count_hyper_connection_layer(ctx, op.attrs, ins)
     if op.uid in ctx.linearized:
         # a grad op of this block differentiates this op: run the rule once,
         # under jax.vjp, and keep what the backward needs
@@ -854,17 +856,44 @@ def _count_attention_layer(ctx, attrs, ins):
     # heads a lane block, or after a transpose to a row a head
     heads = heads_a_block(q.shape[2], k.shape[2], q.shape[3]) \
         or "transposed" if path == "flash" else "none"
+    # the latent form's labels are its own: an op without QRope counts
+    # under the labels it always had
+    latent = {}
+    if ins.get("QRope"):
+        q_rope, k_rope = ins["QRope"][0], ins["KRope"][0]
+        latent = dict(form="latent", v_dim=str(ins["V"][0].shape[3]),
+                      rope_dim=str(q_rope.shape[3]),
+                      rope_key_group=str(q_rope.shape[2] // k_rope.shape[2]))
     REGISTRY.counter(
         "ptpu_attention_layers_total",
         "fused_attention ops lowered (forward ops, not a grad op's replay), "
         "by kind (full, or window with its size), query and key/value "
         "heads, the path taken (flash, dense, or the sequence-parallel one), "
         "the head's width and, on the flash path, the heads the kernels "
-        "index in one lane block (or transposed)"
+        "index in one lane block (or transposed); the latent form besides "
+        "by form=latent, the value's width, the rotary part's width "
+        "(head_dim is then the part without position) and the query heads "
+        "that read one rotary key"
     ).inc(kind="full" if window is None else "window",
           window=str(window or 0), q_heads=str(q.shape[2]),
           kv_heads=str(k.shape[2]), path=path, head_dim=str(q.shape[3]),
-          heads_a_block=str(heads))
+          heads_a_block=str(heads), **latent)
+
+
+def _count_hyper_connection_layer(ctx, attrs, ins):
+    from ..observability.registry import REGISTRY
+    from ..ops.hyper_connection_ops import mhc_path
+    x = ins["X"][0]
+    REGISTRY.counter(
+        "ptpu_hyper_connection_layers_total",
+        "mhc_pre ops lowered (forward ops, not a grad op's replay): the "
+        "sub-layers that read from and write to several residual streams, "
+        "by the streams, a stream's width, the Sinkhorn steps and the path "
+        "of the passes over the streams (the Pallas kernels, or XLA)"
+    ).inc(streams=str(attrs["streams"]),
+          width=str(x.shape[-1] // attrs["streams"]),
+          sinkhorn_iters=str(attrs["sinkhorn_iters"]),
+          path=mhc_path(ctx.mesh, x.shape, attrs["streams"]))
 
 
 def _count_linear_attention_layer(ins):

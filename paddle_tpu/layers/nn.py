@@ -24,7 +24,8 @@ __all__ = [
     "expand", "sequence_mask", "linear_chain_crf", "crf_decoding",
     "chunk_eval", "warpctc", "ctc_greedy_decoder", "sequence_erase",
     "edit_distance", "fused_attention", "rms_norm", "rotary_embedding",
-    "causal_conv1d", "gated_delta_rule",
+    "causal_conv1d", "gated_delta_rule", "mhc_pre", "mhc_post", "mhc_expand",
+    "mhc_reduce",
 ]
 
 
@@ -300,17 +301,32 @@ def rms_norm(input, begin_norm_axis=-1, epsilon=1e-5, param_attr=None,
     return out
 
 
-def rotary_embedding(x, pos, base=10000.0, name=None, rotary_dim=None):
+def rotary_embedding(x, pos, base=10000.0, name=None, rotary_dim=None,
+                     inv_freq=None, table_scale=1.0, layout="half"):
     """Rotary position embedding of x [B, T, H, D] at the integer positions
     pos [B, T], a Variable (fed or computed), so that a decode step can pass
     its own. Half-split pairs (i, i + R/2) over the first R = rotary_dim
     channels of every head (None: all D), angle pos * base^(-2i/R); the
-    channels from R on pass unchanged."""
+    channels from R on pass unchanged. inv_freq: R/2 floats, a frequency
+    table made outside (a scaled one: YaRN's), which replaces base^(-2i/R);
+    table_scale multiplies cos and sin. layout "interleaved": the pairs are
+    (2i, 2i + 1); "half", the default, is what every caller before the
+    latent form uses."""
+    if layout not in ("half", "interleaved"):
+        raise ValueError("rotary_embedding layout must be 'half' or "
+                         "'interleaved', got %r" % (layout,))
     helper = LayerHelper("rotary_embedding", **locals())
     out = helper.create_variable_for_type_inference(x.dtype)
     attrs = {"base": float(base)}
     if rotary_dim is not None and int(rotary_dim) != int(x.shape[-1]):
         attrs["rotary_dim"] = int(rotary_dim)
+    # what the defaults leave as it was is not written
+    if inv_freq is not None:
+        attrs["inv_freq"] = [float(f) for f in inv_freq]
+    if float(table_scale) != 1.0:
+        attrs["table_scale"] = float(table_scale)
+    if layout != "half":
+        attrs["layout"] = layout
     helper.append_op(
         type="rotary_embedding", inputs={"X": [x], "Pos": [pos]},
         outputs={"Out": [out]}, attrs=attrs)
@@ -679,7 +695,8 @@ def autoincreased_step_counter(counter_name=None, begin=1, step=1):
 
 
 def fused_attention(q, k, v, causal=False, scale=None, kv_len=None,
-                    sp_impl="ring", name=None, window=None):
+                    sp_impl="ring", name=None, window=None, q_rope=None,
+                    k_rope=None):
     """Flash attention over q [B, T, Hq, D] and k, v [B, T, Hkv, D]
     (TPU-native addition — the reference era built attention from
     matmul+softmax ops; this is the fused pallas path, see
@@ -693,7 +710,15 @@ def fused_attention(q, k, v, causal=False, scale=None, kv_len=None,
     window nor grouped queries and raise NotImplementedError. Under a ParallelExecutor mesh with an 'sp'
     axis the op runs sequence-parallel; sp_impl chooses the algorithm:
     "ring" (K/V rotation over ICI, any head count) or "ulysses"
-    (all-to-all head sharding, needs heads % sp == 0)."""
+    (all-to-all head sharding, needs heads % sp == 0).
+
+    The latent form: q_rope [B, T, Hq, dr] and k_rope [B, T, 1, dr], given
+    together: a head's score is q . k + q_rope . k_rope, so its keys are D
+    + dr wide, dr of them one rotary key that all heads read, and its
+    values D (latent attention's head of 192 on values of 128). `scale`
+    defaults to 1 / sqrt(D + dr)."""
+    if (q_rope is None) != (k_rope is None):
+        raise ValueError("fused_attention takes q_rope and k_rope together")
     if sp_impl not in ("ring", "ulysses"):
         raise ValueError(
             "fused_attention sp_impl must be 'ring' or 'ulysses', got %r"
@@ -704,6 +729,8 @@ def fused_attention(q, k, v, causal=False, scale=None, kv_len=None,
     helper = LayerHelper("fused_attention", **locals())
     out = helper.create_variable_for_type_inference(q.dtype)
     inputs = {"Q": [q], "K": [k], "V": [v]}
+    if q_rope is not None:
+        inputs.update(QRope=[q_rope], KRope=[k_rope])
     if kv_len is None and getattr(k, "seq_len_var", None):
         kv_len = k.block.var_recursive(k.seq_len_var)
     if kv_len is not None:
@@ -718,6 +745,94 @@ def fused_attention(q, k, v, causal=False, scale=None, kv_len=None,
         outputs={"Out": [out]}, attrs=attrs)
     if q.shape is not None:
         out.shape = tuple(q.shape)
+    return out
+
+
+def mhc_pre(x, streams, sinkhorn_iters=20, epsilon=1e-6, clamp=(-30.0, 30.0),
+            phi_attr=None, bias_attr=None, alpha_attr=None, name=None):
+    """A sub-layer's read from `streams` residual streams x [B, T, streams *
+    C], a token's vectors side by side (manifold-constrained
+    hyper-connections, ops/mhc_kernels.py): returns (h [B, T, C] =
+    sum_i H_pre[i] x[i], coef [B, T, 128] float32, the stream for `mhc_post`
+    to read). The coefficients are made from the token's own streams: x' =
+    x / sqrt(mean(x^2) + epsilon) over all streams * C, Ht = alpha * (x'
+    Phi) + b; H_pre = sigmoid, H_post = 2 sigmoid, H_res = `sinkhorn_iters`
+    Sinkhorn steps (columns, then rows, epsilon in both divisors) on
+    exp(clip(Ht, *clamp)). Three parameters, float32: Phi [streams * C, K],
+    b [K] and alpha [3] (pre, post, res), K = streams^2 + 2 streams, columns
+    [pre | post | res by rows]. Defaults: Phi normal(0, 0.02), alpha 0.01,
+    b_pre = -log(streams - 1) (H_pre = 1 / streams), b_post = 0 (H_post =
+    1), b_res = 0."""
+    helper = LayerHelper("mhc_pre", **locals())
+    n = int(streams)
+    k = n * n + 2 * n
+    width = int(x.shape[-1])
+    if width % n:
+        raise ValueError("mhc_pre: a stream %d wide is not %d vectors"
+                         % (width, n))
+    bias0 = np.zeros([k], "float32")
+    bias0[:n] = -np.log(max(n - 1, 1))
+    from ..core.initializer import NumpyArrayInitializer
+    phi = helper.create_parameter(
+        attr=ParamAttr.to_attr(phi_attr), shape=[width, k], dtype="float32",
+        default_initializer=NormalInitializer(0.0, 0.02))
+    bias = helper.create_parameter(
+        attr=ParamAttr.to_attr(bias_attr), shape=[k], dtype="float32",
+        default_initializer=NumpyArrayInitializer(bias0))
+    alpha = helper.create_parameter(
+        attr=ParamAttr.to_attr(alpha_attr), shape=[3], dtype="float32",
+        default_initializer=ConstantInitializer(0.01))
+    out = helper.create_variable_for_type_inference(x.dtype)
+    coef = helper.create_variable_for_type_inference("float32")
+    stream = helper.create_variable_for_type_inference(x.dtype)
+    helper.append_op(
+        type="mhc_pre",
+        inputs={"X": [x], "Phi": [phi], "Bias": [bias], "Alpha": [alpha]},
+        outputs={"Out": [out], "Coef": [coef], "Stream": [stream]},
+        attrs={"streams": n, "sinkhorn_iters": int(sinkhorn_iters),
+               "epsilon": float(epsilon), "clamp_min": float(clamp[0]),
+               "clamp_max": float(clamp[1])})
+    if x.shape is not None:
+        out.shape = tuple(x.shape[:-1]) + (width // n,)
+        coef.shape = tuple(x.shape[:-1]) + (128,)
+        stream.shape = tuple(x.shape)
+    return out, coef, stream
+
+
+def mhc_post(x, y, coef, streams, name=None):
+    """out[i] = sum_j H_res[i, j] x[j] + H_post[i] y: the streams x [B, T,
+    streams * C] (mhc_pre's third result) mixed and the sub-layer's output y
+    [B, T, C] written into them, by mhc_pre's `coef`."""
+    helper = LayerHelper("mhc_post", **locals())
+    out = helper.create_variable_for_type_inference(x.dtype)
+    helper.append_op(type="mhc_post",
+                     inputs={"X": [x], "Y": [y], "Coef": [coef]},
+                     outputs={"Out": [out]}, attrs={"streams": int(streams)})
+    if x.shape is not None:
+        out.shape = tuple(x.shape)
+    return out
+
+
+def mhc_expand(x, streams, name=None):
+    """x [B, T, C] -> [B, T, streams * C]: `streams` copies side by side,
+    the residual streams' start (bfloat16 under AMP)."""
+    helper = LayerHelper("mhc_expand", **locals())
+    out = helper.create_variable_for_type_inference(x.dtype)
+    helper.append_op(type="mhc_expand", inputs={"X": [x]},
+                     outputs={"Out": [out]}, attrs={"streams": int(streams)})
+    if x.shape is not None:
+        out.shape = tuple(x.shape[:-1]) + (int(x.shape[-1]) * int(streams),)
+    return out
+
+
+def mhc_reduce(x, streams, name=None):
+    """x [B, T, streams * C] -> [B, T, C], the streams' sum: the readout."""
+    helper = LayerHelper("mhc_reduce", **locals())
+    out = helper.create_variable_for_type_inference(x.dtype)
+    helper.append_op(type="mhc_reduce", inputs={"X": [x]},
+                     outputs={"Out": [out]}, attrs={"streams": int(streams)})
+    if x.shape is not None:
+        out.shape = tuple(x.shape[:-1]) + (int(x.shape[-1]) // int(streams),)
     return out
 
 

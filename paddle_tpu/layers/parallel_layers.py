@@ -274,7 +274,8 @@ def switch_moe(input, num_experts, d_hidden, capacity_factor=1.25,
 def moe_ffn(input, num_experts, d_expert, top_k, norm_topk_prob=False,
             param_attr=None, name=None, router_input=None, activation="silu",
             experts_held=None, first_expert=0, scoring="softmax",
-            expert_bias_attr=None, routed_scaling_factor=1.0):
+            expert_bias_attr=None, routed_scaling_factor=1.0,
+            norm_epsilon=None):
     """Dropless top-k routed experts, each a gated FFN without bias
     (lowering: ops/parallel_ops.py -> parallel/moe.py routed_ffn). input
     [..., D] -> (out [..., D], balance_loss [1], z_loss [1], expert_load
@@ -308,7 +309,9 @@ def moe_ffn(input, num_experts, d_expert, top_k, norm_topk_prob=False,
     the weights come from the scores without it. Its initializer is the
     attr's (zeros by default); None or False: no bias. It is refused with
     softmax scoring, where no model defines it. routed_scaling_factor
-    multiplies the weights after the renormalisation.
+    multiplies the weights after the renormalisation. norm_epsilon: what a
+    sigmoid router's renormalisation adds to the chosen scores' sum instead
+    of 1e-6 (None: 1e-6).
     """
     helper = LayerHelper("moe_ffn", name=name)
     dtype = input.dtype
@@ -368,6 +371,8 @@ def moe_ffn(input, num_experts, d_expert, top_k, norm_topk_prob=False,
         attrs["scoring"] = str(scoring)
     if float(routed_scaling_factor) != 1.0:
         attrs["scale"] = float(routed_scaling_factor)
+    if norm_epsilon is not None:
+        attrs["norm_epsilon"] = float(norm_epsilon)
     out = helper.create_variable_for_type_inference(dtype)
     balance = helper.create_variable_for_type_inference("float32")
     z = helper.create_variable_for_type_inference("float32")

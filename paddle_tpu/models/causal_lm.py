@@ -26,7 +26,11 @@ branch normed going in and coming out, an exit gate that weighs the passes'
 losses) and LFM2-8B-A1B (`model_type: lfm2_moe`, LiquidAI: three gated
 short convolutions to one attention layer with QK-norm a head, leading
 dense layers before the expert layers, a sigmoid router whose top-k is
-chosen with an expert bias and weighed without it, a tied head), whose
+chosen with an expert bias and weighed without it, a tied head) and
+Xing4.0-29B-A4B (`model_type: xing4_0`, XingChen-AGI: DeepSeek-V3's latent
+attention, sigmoid router with a correction bias and ungated shared expert
+after leading dense layers, behind hc_mult residual streams that
+manifold-constrained hyper-connections mix, arXiv:2512.24880), whose
 equations the module follows; `causal_lm_reference.py` is the same forward
 in plain jax.numpy.
 
@@ -37,7 +41,8 @@ heads), intermediate_size (the dense FFN's width, or one expert's),
 num_experts (0 or absent: dense SwiGLU), num_experts_per_tok,
 norm_topk_prob, rms_norm_eps, rope_theta (None: no rotary), hidden_act
 (silu; relu for experts), attention_bias (false), clip_qkv (null),
-rope_scaling (null), tie_word_embeddings (false; true: LFM2's, below),
+rope_scaling (null, or type yarn: below), tie_word_embeddings (false; true:
+LFM2's, below),
 initializer_range, embedding_initializer_range (absent: the same),
 router_aux_loss_coef, router_z_loss_coef; `qk_norm`, which `config.json`
 does not carry because `modeling_olmoe.py` always applies it (true: over all
@@ -80,7 +85,35 @@ normal(0, that) at startup from a stream of its own, EXPERT_BIAS_SEED, the
 same draw in every run as a checkpoint's buffer is the same under whatever
 else is initialised around it; 0: zeros). A loop over short_conv mixers is
 refused. The final norm closes every pass, and the next pass starts from
-the normed state. A loop over routed experts is refused. `model_type`
+the normed state. A loop over routed experts is refused. Xing4.0's
+(DeepSeek-V3's names): q_lora_rank, kv_lora_rank, qk_nope_head_dim,
+qk_rope_head_dim, v_head_dim (all five or none: latent attention, c_q = N(x
+W_qa), q = c_q W_qb a head [nope; rope]; [c_kv; k_r] = x W_kva, kv = N(c_kv)
+W_kvb a head [k_nope; v]; rotary on q's rope part and on k_r, one key all
+heads read; a key/value head a query head, no QK-norm, no gate);
+rope_scaling {type yarn, factor, original_max_position_embeddings,
+beta_fast (32), beta_slow (1), mscale (1), mscale_all_dim (0)}: the
+frequency table and the scores' scale of `yarn_table`, made on the host
+once a program, for either attention form (any other type is refused);
+n_routed_experts (num_experts), first_k_dense_replace (num_dense_layers),
+scoring_func (router_scoring), topk_method (noaux_tc: use_expert_bias;
+greedy: none; others refused), n_shared_experts (so many SwiGLUs of
+moe_intermediate_size every token passes, as one of their summed width,
+ungated: shared_expert_gate false, where Qwen3-Next's is gated), n_group
+and topk_group (1; a group limit is refused), moe_layer_freq (1),
+num_nextn_predict_layers (0: multi-token prediction is built by nothing and
+any other value is refused), ep_size and max_position_embeddings (not
+read); hc_mult (the residual streams n; 1: the plain residual), where n > 1
+hc_sinkhorn_iters, hc_eps, mhc_h_res_clamp_min and mhc_h_res_clamp_max (the
+hyper-connections': layers.mhc_pre has the equations); a looped stack over
+hc_mult > 1 is refused. As keys of their own, what the published files do
+not fix: `rope_interleaved` (rotary pairs (2i, 2i + 1), DeepSeek-V3's
+stored layout; false: half-split), `router_renorm_epsilon` (what a sigmoid
+router's renormalisation adds to the chosen scores' sum; null: LFM2's 1e-6;
+DeepSeek-V3's 1e-20), `hc_alpha_init` and `hc_res_diag_init` (the
+hyper-connections' start, `hyper_connection`: 0.5 and 1.0 unless given, a
+model whose coefficients differ from token to token; arXiv:2512.24880
+starts at alpha 0.01 with H_res near the identity). `model_type`
 is not read: a config says what it builds by these keys. SmallThinker's own
 names are mapped onto these:
 moe_ffn_hidden_size (intermediate_size), moe_num_primary_experts
@@ -98,17 +131,22 @@ computes experts chip * held .. chip * held + held - 1, and attention's and
 the experts' outputs are the partial sums of what is held.
 
 Parameters are created in the order the reference reads them: embedding;
-a layer's input norm, then Wq, Wk, Wv, q norm, k norm, Wo (attention) or
+a layer's [attention hyper-connection: Phi, b, alpha], input norm, then Wq,
+Wk, Wv, q norm, k norm, Wo (attention), or W_qa, the q norm, W_qb, W_kva,
+the kv norm, W_kvb, Wo (latent attention), or
 W_qkvz, W_ba, the convolution's filter, dt_bias, A_log, the gated norm's
 weight, W_out (gated delta net) or w_in, the convolution's filter, w_out
-(short_conv), [the mixer's outgoing norm], post-attention norm, then router,
-[the expert bias], gate, up, down (experts; then the shared expert's gate,
-up, down and its sigmoid gate's weight) or gate, up, down (dense), [the
-FFN's outgoing norm]; final norm; [the exit gate's weight and bias]; head
-(none of its own where it is tied). The bracketed ones exist with
-sandwich_norm, use_expert_bias and exit_gate. A parameter is
+(short_conv), [the mixer's outgoing norm], [the FFN's hyper-connection: Phi,
+b, alpha], post-attention norm, then router, [the expert bias], gate, up,
+down (experts; then the shared expert's gate, up, down and [its sigmoid
+gate's weight]) or gate, up, down (dense), [the FFN's outgoing norm]; final
+norm; [the exit gate's weight and bias]; head (none of its own where it is
+tied). The bracketed ones exist with hc_mult > 1, sandwich_norm,
+use_expert_bias, shared_expert_gate and exit_gate. A parameter is
 named by layer and role, `layer_<i>.<role>` (`layer_0.wq`,
-`layer_3.experts.w_gate`, `layer_3.experts.expert_bias`) and `embedding`,
+`layer_3.experts.w_gate`, `layer_3.experts.expert_bias`,
+`layer_1.attn_hc.phi`, `layer_1.ffn_hc.alpha`, `layer_1.wkv_b`) and
+`embedding`,
 `final_norm`, `exit_gate.w`,
 `exit_gate.b`, `head`: the passes of a looped model find their weights by
 name.
@@ -132,7 +170,13 @@ DEFAULTS = {
     "early_exit_threshold": 1, "sandwich_norm": False, "exit_gate": False,
     "exit_entropy_coef": 0.0, "conv_bias": False, "num_dense_layers": 0,
     "use_expert_bias": False, "routed_scaling_factor": 1,
-    "router_scoring": "softmax", "expert_bias_initializer_range": 0.0}
+    "router_scoring": "softmax", "expert_bias_initializer_range": 0.0,
+    "shared_expert_gate": True, "router_renorm_epsilon": None,
+    "hc_mult": 1, "hc_sinkhorn_iters": 20, "hc_eps": 1e-6,
+    "mhc_h_res_clamp_min": -30.0, "mhc_h_res_clamp_max": 30.0,
+    "hc_alpha_init": 0.5, "hc_res_diag_init": 1.0,
+    "num_nextn_predict_layers": 0, "n_group": 1, "topk_group": 1,
+    "moe_layer_freq": 1, "rope_interleaved": False}
 # the stream every expert bias is drawn from, whatever the program's seed:
 # the draw the LFM2 cell's limits were read under (PERF.md section 4)
 EXPERT_BIAS_SEED = 39
@@ -140,7 +184,14 @@ EXPERT_BIAS_SEED = 39
 ALIASES = {"moe_ffn_hidden_size": "intermediate_size",
            "moe_num_primary_experts": "num_experts",
            "moe_num_active_primary_experts": "num_experts_per_tok",
-           "norm_eps": "rms_norm_eps"}
+           "norm_eps": "rms_norm_eps",
+           # DeepSeek-V3's names (Xing4.0's config.json)
+           "n_routed_experts": "num_experts",
+           "first_k_dense_replace": "num_dense_layers",
+           "scoring_func": "router_scoring"}
+# the keys latent attention needs, all or none
+LATENT_KEYS = ("q_lora_rank", "kv_lora_rank", "qk_nope_head_dim",
+               "qk_rope_head_dim", "v_head_dim")
 # a layer's kind in `layer_types` -> its mixer here
 LAYER_TYPES = {"conv": "short_conv", "full_attention": "attention"}
 # the keys a gated delta net needs
@@ -154,7 +205,9 @@ def resolve(cfg):
     than building something else under the model's name. Adds what the
     builder derives: head_dim, rotary_dim, experts_held and first_expert
     (the share), dense_intermediate_size (the dense FFN's width, where
-    `intermediate_size` became an expert's) and the per-layer patterns
+    `intermediate_size` became an expert's), `latent` (the attention form),
+    rope_inv_freq, rope_table_scale and attention_scale (YaRN's, or None, 1
+    and None) and the per-layer patterns
     `rope_layers`, `window_layers`, `mixer_layers` ("attention",
     "gated_delta" or "short_conv") and `ffn_layers` ("dense" or
     "experts")."""
@@ -166,15 +219,65 @@ def resolve(cfg):
     if c["num_experts"] and "moe_intermediate_size" in c:
         c["intermediate_size"] = c["moe_intermediate_size"]
     c.setdefault("num_key_value_heads", c["num_attention_heads"])
+    # DeepSeek-V3's router and shared experts, in the keys the builder reads
+    if "topk_method" in c:
+        if c["topk_method"] not in ("noaux_tc", "greedy"):
+            raise NotImplementedError(
+                "causal_lm builds topk_method noaux_tc (the top k chosen "
+                "over the scores + a correction bias) or greedy, the config "
+                "has %r" % (c["topk_method"],))
+        c["use_expert_bias"] = c["topk_method"] == "noaux_tc"
+    if c.get("n_shared_experts"):
+        # so many SwiGLUs of an expert's width, every token through all of
+        # them, added as they are: one SwiGLU of their summed width, ungated
+        c["shared_expert_intermediate_size"] = \
+            c["n_shared_experts"] * c["intermediate_size"]
+        c["shared_expert_gate"] = False
     for key, want in (("attention_bias", False), ("clip_qkv", None),
-                      ("rope_scaling", None), ("conv_bias", False),
+                      ("conv_bias", False),
                       ("moe_primary_router_apply_softmax", True),
                       ("mlp_only_layers", []), ("decoder_sparse_step", 1),
-                      ("early_exit_threshold", 1)):
+                      ("early_exit_threshold", 1),
+                      ("num_nextn_predict_layers", 0), ("n_group", 1),
+                      ("topk_group", 1), ("moe_layer_freq", 1)):
         if c[key] != want:
             raise NotImplementedError(
                 "causal_lm builds %s=%r only, the config has %r"
                 % (key, want, c[key]))
+    scaling = c["rope_scaling"]
+    if scaling is not None and scaling.get(
+            "type", scaling.get("rope_type")) != "yarn":
+        raise NotImplementedError(
+            "causal_lm builds rope_scaling null or of type yarn, the config "
+            "has %r" % (scaling,))
+    if scaling is not None:
+        missing = [key for key in ("factor",
+                                   "original_max_position_embeddings")
+                   if key not in scaling]
+        if missing:
+            raise ValueError("rope_scaling of type yarn needs %s" % missing)
+    c["hc_mult"] = int(c["hc_mult"])
+    if c["hc_mult"] < 1:
+        raise ValueError("hc_mult %d: a model has at least one residual "
+                         "stream" % c["hc_mult"])
+    c["latent"] = any(c.get(key) is not None for key in LATENT_KEYS)
+    if c["latent"]:
+        missing = [key for key in LATENT_KEYS if c.get(key) is None]
+        if missing:
+            raise NotImplementedError(
+                "causal_lm builds latent attention with a low-rank q and a "
+                "low-rank kv, both: the config lacks %s" % missing)
+        if c["num_key_value_heads"] != c["num_attention_heads"]:
+            raise NotImplementedError(
+                "causal_lm builds latent attention with a key/value head a "
+                "query head, the config has %d on %d"
+                % (c["num_attention_heads"], c["num_key_value_heads"]))
+        for key in ("qk_norm", "attention_gate"):
+            if c[key]:
+                raise NotImplementedError(
+                    "causal_lm builds latent attention without %s" % key)
+        c.setdefault("head_dim", c["qk_nope_head_dim"]
+                     + c["qk_rope_head_dim"])
     if c["hidden_act"] not in (("silu", "relu") if c["num_experts"]
                                else ("silu",)):
         raise NotImplementedError(
@@ -212,6 +315,11 @@ def resolve(cfg):
     if c["total_ut_steps"] < 1:
         raise ValueError("total_ut_steps %d: a model runs its layers at "
                          "least once" % c["total_ut_steps"])
+    if c["total_ut_steps"] > 1 and c["hc_mult"] > 1:
+        raise NotImplementedError(
+            "causal_lm runs a stack total_ut_steps=%d times over one "
+            "residual stream, not over hc_mult=%d"
+            % (c["total_ut_steps"], c["hc_mult"]))
     if c["total_ut_steps"] > 1 and c["num_experts"]:
         raise NotImplementedError(
             "causal_lm runs a stack of dense layers total_ut_steps=%d times, "
@@ -235,9 +343,10 @@ def resolve(cfg):
     share = c.get("share") or {}
     c["experts_held"] = c["num_experts"]
     published = share.get("published", {})
-    c["num_experts"] = published.get(
-        "moe_num_primary_experts", published.get("num_experts",
-                                                 c["num_experts"]))
+    c["num_experts"] = next(
+        (published[key] for key in ("moe_num_primary_experts", "num_experts",
+                                    "n_routed_experts") if key in published),
+        c["num_experts"])
     c["first_expert"] = share.get("chip", 0) * c["experts_held"] \
         if c["experts_held"] != c["num_experts"] else 0
     if c["first_expert"] + c["experts_held"] > c["num_experts"]:
@@ -256,7 +365,11 @@ def resolve(cfg):
         c["sliding_window_size"]
         if c.get("sliding_window_layout", [0] * layers)[i] else None
         for i in range(layers)]
-    c["rotary_dim"] = int(c["head_dim"] * c["partial_rotary_factor"])
+    c["rotary_dim"] = c["qk_rope_head_dim"] if c["latent"] \
+        else int(c["head_dim"] * c["partial_rotary_factor"])
+    c["rope_inv_freq"], c["rope_table_scale"], c["attention_scale"] = \
+        yarn_table(scaling, c["rope_theta"], c["rotary_dim"], c["head_dim"]) \
+        if scaling is not None else (None, 1.0, None)
     if c["rotary_dim"] % 2 or not 0 < c["rotary_dim"] <= c["head_dim"]:
         raise ValueError("partial_rotary_factor %r of a head of %d turns %d "
                          "channels: not an even number in (0, %d]"
@@ -301,6 +414,41 @@ def resolve(cfg):
                              "key heads" % (c["linear_num_value_heads"],
                                             c["linear_num_key_heads"]))
     return c
+
+
+def yarn_table(scaling, theta, rotary_dim, head_dim):
+    """(inv_freq, the factor on cos and sin, the attention's scale) of
+    rope_scaling {type: yarn} (arXiv:2309.00071 as DeepSeek-V3's modelling
+    code applies it), float32 on the host, once a program. With f_i =
+    theta^(-2i/R) over the R/2 pairs: those that turn more than beta_fast
+    times over the original length keep f_i, those that turn less than
+    beta_slow times get f_i / factor, a linear ramp between (pair lo to pair
+    hi): inv_freq_i = f_i (1 - r_i) + (f_i / factor) r_i, r_i = clamp((i -
+    lo) / (hi - lo), 0, 1). m(s) = 0.1 s ln(factor) + 1: cos and sin are
+    multiplied by m(mscale) / m(mscale_all_dim) and the scores' scale is
+    head_dim^(-1/2) m(mscale_all_dim)^2."""
+    import math
+    import numpy as np
+    factor = float(scaling["factor"])
+    original = scaling["original_max_position_embeddings"]
+    fast, slow = scaling.get("beta_fast", 32), scaling.get("beta_slow", 1)
+
+    def pair(turns):        # the pair that turns so often over `original`
+        return rotary_dim * math.log(original / (turns * 2 * math.pi)) \
+            / (2 * math.log(theta))
+
+    def m(s):
+        return 0.1 * s * math.log(factor) + 1.0 if factor > 1 else 1.0
+
+    lo = max(math.floor(pair(fast)), 0)
+    hi = min(math.ceil(pair(slow)), rotary_dim - 1)
+    i = np.arange(rotary_dim // 2, dtype=np.float32)
+    f = np.float32(theta) ** (-2 * i / np.float32(rotary_dim))
+    ramp = np.clip((i - lo) / np.float32(max(hi - lo, 1e-3)), 0, 1)
+    inv_freq = f * (1 - ramp) + f / np.float32(factor) * ramp
+    all_dim = m(scaling.get("mscale_all_dim", 0))
+    return [float(x) for x in inv_freq.astype(np.float32)], \
+        m(scaling.get("mscale", 1)) / all_dim, head_dim ** -0.5 * all_dim ** 2
 
 
 def _layer(c, i):
@@ -365,14 +513,102 @@ def attention(x, pos, c):
         q, k = _norm(q, cq), _norm(k, ck)
     if c["rope_theta"] is not None:
         q, k = (fluid.layers.rotary_embedding(
-            t, pos, base=c["rope_theta"], rotary_dim=c["rotary_dim"])
+            t, pos, base=c["rope_theta"], rotary_dim=c["rotary_dim"],
+            inv_freq=c["rope_inv_freq"], table_scale=c["rope_table_scale"],
+            layout="interleaved" if c["rope_interleaved"] else "half")
             for t in (q, k))
     ctx = fluid.layers.fused_attention(q, k, v, causal=True,
-                                       window=c["window"])
+                                       window=c["window"],
+                                       scale=c["attention_scale"])
     if gated:
         ctx = ctx * fluid.layers.sigmoid(gate)
     return _linear(fluid.layers.reshape(ctx, shape=[0, -1, h * hd]), d, c,
                    "wo")
+
+
+def _head_columns(w, heads, widths):
+    """A projection's weight [K, heads * sum(widths)], a head's columns the
+    parts of `widths` side by side, as one matrix a part, [K, heads *
+    width]: the parts come out of matmuls of their own and no pass over
+    the activations takes them apart."""
+    k = int(w.shape[0])
+    parts = fluid.layers.split(
+        fluid.layers.reshape(w, shape=[k, heads, sum(widths)]),
+        list(widths), dim=-1)
+    return [fluid.layers.reshape(part, shape=[k, heads * width])
+            for part, width in zip(parts, widths)]
+
+
+def latent_attention(x, pos, c):
+    """Multi-head latent attention (DeepSeek-V2/V3's; arXiv:2405.04434)
+    over x [B, T, D]: c_q = N(x W_qa) [q_lora_rank], q = c_q W_qb, a head
+    [q_nope (qk_nope_head_dim); q_rope (qk_rope_head_dim)]; [c_kv
+    (kv_lora_rank); k_r (qk_rope_head_dim)] = x W_kva, kv = N(c_kv) W_kvb, a
+    head [k_nope; v (v_head_dim)]; rotary positions turn q_rope of every
+    head and k_r, ONE key that all heads read; a head's score is scale x
+    (q_nope . k_nope + q_rope . k_rope), its output P v; then W_o. W_qb and
+    W_kvb are one parameter each in the published column order; their
+    parts' columns are taken apart as weights (`_head_columns`), so q_nope,
+    q_rope, k_nope and v are matmuls' own results and the core
+    (layers.fused_attention's latent form) reads them where they lie.
+    Rotary pairs are interleaved (2i, 2i + 1) with rope_interleaved, and
+    the table is YaRN's where rope_scaling says so."""
+    layers = fluid.layers
+    h, d = c["num_attention_heads"], c["hidden_size"]
+    dn, dr, dv = (c[key] for key in ("qk_nope_head_dim", "qk_rope_head_dim",
+                                     "v_head_dim"))
+    rq, rkv = c["q_lora_rank"], c["kv_lora_rank"]
+
+    def weight(role, shape):
+        return layers.create_parameter(shape, "float32",
+                                       attr=_matrix(c, role))
+
+    cq = _norm(_linear(x, rq, c, "wq_a"), c, "q_a_norm")
+    w_nope, w_rope = _head_columns(weight("wq_b", [rq, h * (dn + dr)]), h,
+                                   (dn, dr))
+    q = layers.reshape(layers.matmul(cq, w_nope), shape=[0, -1, h, dn])
+    q_rope = layers.reshape(layers.matmul(cq, w_rope), shape=[0, -1, h, dr])
+    ckv, k_rope = layers.split(_linear(x, rkv + dr, c, "wkv_a"), [rkv, dr],
+                               dim=-1)
+    ckv = _norm(ckv, c, "kv_a_norm")
+    w_k, w_v = _head_columns(weight("wkv_b", [rkv, h * (dn + dv)]), h,
+                             (dn, dv))
+    k = layers.reshape(layers.matmul(ckv, w_k), shape=[0, -1, h, dn])
+    v = layers.reshape(layers.matmul(ckv, w_v), shape=[0, -1, h, dv])
+    k_rope = layers.reshape(k_rope, shape=[0, -1, 1, dr])
+    if c["rope_theta"] is not None:
+        q_rope, k_rope = (layers.rotary_embedding(
+            t, pos, base=c["rope_theta"], inv_freq=c["rope_inv_freq"],
+            table_scale=c["rope_table_scale"],
+            layout="interleaved" if c["rope_interleaved"] else "half")
+            for t in (q_rope, k_rope))
+    ctx = layers.fused_attention(
+        q, k, v, causal=True, window=c["window"], q_rope=q_rope,
+        k_rope=k_rope, scale=c["attention_scale"] or (dn + dr) ** -0.5)
+    return _linear(layers.reshape(ctx, shape=[0, -1, h * dv]), d, c, "wo")
+
+
+def hyper_connection(x, c, role):
+    """layers.mhc_pre on the streams x [B, T, hc_mult * D] for the sub-layer
+    `role` ("attn_hc" or "ffn_hc") of layer c["layer"]: (what the sub-layer
+    reads, the coefficients, the streams for `mhc_post`). Parameters
+    `layer_<i>.<role>.phi`, `.b` and `.alpha`: Phi normal(0,
+    initializer_range); alpha hc_alpha_init thrice; b so that at alpha = 0
+    H_pre = 1 / n, H_post = 1 and H_res is the Sinkhorn of exp(hc_res_diag_init
+    x I) (0: uniform, 1 / n everywhere; large: the identity)."""
+    import numpy as np
+    n = c["hc_mult"]
+    bias = np.zeros([n * n + 2 * n], "float32")
+    bias[:n] = -np.log(max(n - 1, 1))
+    bias[2 * n:] = (c["hc_res_diag_init"] * np.eye(n)).reshape(-1)
+    init = fluid.initializer
+    return fluid.layers.mhc_pre(
+        x, n, sinkhorn_iters=c["hc_sinkhorn_iters"], epsilon=c["hc_eps"],
+        clamp=(c["mhc_h_res_clamp_min"], c["mhc_h_res_clamp_max"]),
+        phi_attr=_matrix(c, role + ".phi"),
+        bias_attr=_attr(c, role + ".b", init.NumpyArrayInitializer(bias)),
+        alpha_attr=_attr(c, role + ".alpha",
+                         init.Constant(c["hc_alpha_init"])))
 
 
 def gated_delta_net(x, c):
@@ -461,8 +697,9 @@ def feed_forward(x, c, router_input=None):
     aux = (balance_loss, z_loss, expert_load), the dense SwiGLU None.
     `router_input` is what the router reads where it is not x. A shared
     expert (shared_expert_intermediate_size), a SwiGLU every token passes
-    scaled by sigmoid(x w_s), is added to the routed experts' output; in a
-    share of a layer it is every chip's own, computed once. c["ffn"] is the
+    scaled by sigmoid(x w_s) (shared_expert_gate; without it, DeepSeek-V3's
+    n_shared_experts, as it is), is added to the routed experts' output; in
+    a share of a layer it is every chip's own, computed once. c["ffn"] is the
     layer's kind (absent, a config seen outside a stack: experts where it
     has any): a model with num_dense_layers has dense layers at
     dense_intermediate_size before its expert layers. An expert bias is
@@ -484,12 +721,15 @@ def feed_forward(x, c, router_input=None):
             experts_held=c["experts_held"], first_expert=c["first_expert"],
             scoring=c["router_scoring"],
             expert_bias_attr=bias,
-            routed_scaling_factor=c["routed_scaling_factor"])
+            routed_scaling_factor=c["routed_scaling_factor"],
+            norm_epsilon=c["router_renorm_epsilon"])
         if c["shared_expert_intermediate_size"]:
             shared = _swiglu(x, c["shared_expert_intermediate_size"], c,
                              "shared_expert.")
-            out = out + shared * fluid.layers.sigmoid(
-                _linear(x, 1, c, "shared_expert.gate"))
+            if c["shared_expert_gate"]:
+                shared = shared * fluid.layers.sigmoid(
+                    _linear(x, 1, c, "shared_expert.gate"))
+            out = out + shared
         return out, (balance, z, load)
     return _swiglu(x, c["dense_intermediate_size"], c), None
 
@@ -595,25 +835,42 @@ def causal_lm(cfg, seq_len, extras=None, recompute=True):
     with loop.step() if loop else contextlib.nullcontext():
         if loop:
             h = state = loop.memory(init=h)
+        # hc_mult = n > 1 streams (arXiv:2512.24880): the layers carry X [B,
+        # T, n * D], the embedding n times; a sub-layer reads h = sum_i
+        # H_pre[i] X[i] and X' = H_res X + H_post y takes its output; the
+        # streams' sum is read out
+        streams = c["hc_mult"]
+        if streams > 1:
+            h = layers.mhc_expand(h, streams)
         for i in range(c["num_hidden_layers"]):
             cl, mixer = _layer(c, i), c["mixer_layers"][i]
             _count_layer(cl, mixer)
-            a = _norm(h, cl, "input_norm")
-            mixed = attention(a, pos, cl) if mixer == "attention" \
+            if streams > 1:
+                read, coef, h = hyper_connection(h, cl, "attn_hc")
+            a = _norm(read if streams > 1 else h, cl, "input_norm")
+            mixed = (latent_attention if c["latent"] else attention)(
+                a, pos, cl) if mixer == "attention" \
                 else short_conv(a, cl) if mixer == "short_conv" \
                 else gated_delta_net(a, cl)
             if c["sandwich_norm"]:
                 mixed = _norm(mixed, cl, "mixer_out_norm")
-            h = h + mixed
+            if streams > 1:
+                h = layers.mhc_post(h, mixed, coef, streams)
+                read, coef, h = hyper_connection(h, cl, "ffn_hc")
+            else:
+                h = h + mixed
             out, layer_aux = feed_forward(
-                _norm(h, cl, "post_attention_norm"), cl,
-                router_input=a if c["router_input"] == "pre_attention"
+                _norm(read if streams > 1 else h, cl, "post_attention_norm"),
+                cl, router_input=a if c["router_input"] == "pre_attention"
                 else None)
             if c["sandwich_norm"]:
                 out = _norm(out, cl, "ffn_out_norm")
-            h = h + out
+            h = layers.mhc_post(h, out, coef, streams) if streams > 1 \
+                else h + out
             if layer_aux is not None:
                 aux.append(layer_aux)
+        if streams > 1:
+            h = layers.mhc_reduce(h, streams)
         h = _norm(h, c, "final_norm")
         if loop:
             loop.update_memory(state, h)

@@ -20,9 +20,15 @@ Hugging Face's `modeling_ouro.py` and the loss the paper's. For LFM2
 (`model_type: lfm2_moe`: gated short convolutions and attention layers by
 `layer_types`, leading dense layers, a sigmoid router whose top-k is chosen
 with an expert bias and weighed without it, a tied head) Hugging Face's
-`modeling_lfm2_moe.py`; it has no multi-token prediction. `params` is the
-list of the Program's parameters in the order models/causal_lm.py creates
-them.
+`modeling_lfm2_moe.py`; it has no multi-token prediction. For Xing4.0
+(`model_type: xing4_0`: DeepSeek-V3's latent attention, sigmoid router with
+a correction bias and ungated shared expert, behind `hc_mult` residual
+streams mixed by manifold-constrained hyper-connections) DeepSeek-V3's
+released `modeling_deepseek.py` for what its keys mean and arXiv:2512.24880
+(over arXiv:2409.19606) for the `hc_*` keys; its multi-token-prediction
+module (`num_nextn_predict_layers`) is not built, here or in the Program.
+`params` is the list of the Program's parameters in the order
+models/causal_lm.py creates them.
 
 One chip's share of a layer comes as arguments: `attention` computes the
 heads whose weights it is given (Wq's, Wk's and Wv's columns and Wo's rows
@@ -57,19 +63,128 @@ def rms_norm(x, w, eps, zero_centered=False):
     return w * x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps)
 
 
-def rope(x, pos, theta, rotary_dim=None):
+def rope(x, pos, theta, rotary_dim=None, inv_freq=None, interleaved=False,
+         table_scale=1.0):
     """x [B, T, H, D], pos [B, T]: HF's rotate_half convention over the
     first R = rotary_dim channels (all by default), the pair (i, i + R/2)
-    turns by pos * theta^(-2i/R); the channels from R on pass."""
+    turns by pos * theta^(-2i/R); the channels from R on pass. inv_freq [R /
+    2]: a table that replaces theta^(-2i/R) (`yarn_inv_freq`); interleaved:
+    the pairs are (2i, 2i + 1) (DeepSeek-V3's stored layout); cos and sin
+    are multiplied by table_scale."""
     d = x.shape[-1] if rotary_dim is None else rotary_dim
-    inv_freq = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    if inv_freq is None:
+        inv_freq = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
     angle = pos.astype(jnp.float32)[:, :, None, None] * inv_freq
-    angle = jnp.concatenate([angle, angle], -1)
+    cos, sin = jnp.cos(angle) * table_scale, jnp.sin(angle) * table_scale
     x, rest = x[..., :d], x[..., d:]
-    x1, x2 = jnp.split(x, 2, axis=-1)
-    rotated = jnp.concatenate([-x2, x1], -1)
-    return jnp.concatenate(
-        [x * jnp.cos(angle) + rotated * jnp.sin(angle), rest], -1)
+    if interleaved:
+        x1, x2 = x[..., 0::2], x[..., 1::2]
+        turned = jnp.stack([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                           -1).reshape(x.shape)
+    else:
+        x1, x2 = jnp.split(x, 2, axis=-1)
+        turned = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                                 -1)
+    return jnp.concatenate([turned, rest], -1)
+
+
+def yarn_mscale(factor, mscale):
+    """YaRN's m: 0.1 mscale ln(factor) + 1 past factor 1."""
+    return 0.1 * mscale * jnp.log(factor) + 1.0 if factor > 1 else 1.0
+
+
+def yarn_inv_freq(scaling, theta, d):
+    """YaRN's frequency table over the d / 2 rotary pairs (arXiv:2309.00071,
+    as DeepSeek-V3's `DeepseekV3YarnRotaryEmbedding` computes it): f_i =
+    theta^(-2i/d); the pair that turns `turns` times over the original
+    length is number d ln(original / (2 pi turns)) / (2 ln theta); pairs up
+    to lo = floor(that at beta_fast) keep f_i, pairs from hi = ceil(that at
+    beta_slow) get f_i / factor, a linear ramp between. The reference's own
+    reading of `rope_scaling`, not the builder's table."""
+    factor, original = scaling["factor"], \
+        scaling["original_max_position_embeddings"]
+
+    def pair(turns):
+        return d * jnp.log(original / (turns * 2 * jnp.pi)) \
+            / (2 * jnp.log(float(theta)))
+
+    lo = jnp.maximum(jnp.floor(pair(scaling.get("beta_fast", 32))), 0)
+    hi = jnp.minimum(jnp.ceil(pair(scaling.get("beta_slow", 1))), d - 1)
+    i = jnp.arange(d // 2, dtype=jnp.float32)
+    f = float(theta) ** (-2 * i / d)
+    ramp = jnp.clip((i - lo) / jnp.maximum(hi - lo, 1e-3), 0, 1)
+    return f * (1 - ramp) + f / factor * ramp
+
+
+def latent_attention(a, pos, wq_a, q_a_norm, wq_b, wkv_a, kv_a_norm, wkv_b,
+                     wo, c):
+    """DeepseekV3Attention on a [B, T, D], the naive way: q = N(a W_qa)
+    W_qb, a head [q_nope; q_rope]; [c_kv; k_r] = a W_kva; kv = N(c_kv)
+    W_kvb, a head [k_nope; v]; rotary turns q_rope of every head and k_r,
+    which is then repeated to every head and concatenated behind its
+    k_nope; a dense causal softmax over scale x q . k with scale =
+    (dn + dr)^(-1/2) m^2, m = yarn_mscale(factor, mscale_all_dim); P v; W_o.
+
+    Departure: HF permutes q_rope's and k_r's channels from interleaved
+    pairs to halves and then applies rotate_half; the scores are the same
+    and the channels of q and k are those of the stored layout here."""
+    b, t, _ = a.shape
+    dn, dr, dv = c["qk_nope_head_dim"], c["qk_rope_head_dim"], c["v_head_dim"]
+    eps = c["rms_norm_eps"]
+    h = wq_b.shape[1] // (dn + dr)
+    scaling = c["rope_scaling"]
+    q = (rms_norm(a @ wq_a, q_a_norm, eps) @ wq_b).reshape(b, t, h, dn + dr)
+    ckv = a @ wkv_a
+    kv = (rms_norm(ckv[..., :c["kv_lora_rank"]], kv_a_norm, eps)
+          @ wkv_b).reshape(b, t, h, dn + dv)
+    k_r = ckv[..., c["kv_lora_rank"]:].reshape(b, t, 1, dr)
+    scale, table, table_scale = (dn + dr) ** -0.5, None, 1.0
+    if scaling is not None:
+        m = yarn_mscale(scaling["factor"], scaling.get("mscale_all_dim", 0))
+        scale = scale * m * m
+        table = yarn_inv_freq(scaling, c["rope_theta"], dr)
+        table_scale = yarn_mscale(scaling["factor"],
+                                  scaling.get("mscale", 1)) / m
+    q_rope = q[..., dn:]
+    if c["rope_theta"] is not None:
+        q_rope, k_r = (rope(x, pos, c["rope_theta"], inv_freq=table,
+                            interleaved=c["rope_interleaved"],
+                            table_scale=table_scale) for x in (q_rope, k_r))
+    q = jnp.concatenate([q[..., :dn], q_rope], -1)
+    k = jnp.concatenate([kv[..., :dn], jnp.broadcast_to(k_r, (b, t, h, dr))],
+                        -1)
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k) * scale
+    s = jnp.where(jnp.tril(jnp.ones((t, t), bool)), s, -jnp.inf)
+    ctx = jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s, -1), kv[..., dn:])
+    return ctx.reshape(b, t, h * dv) @ wo, (q, k)
+
+
+def hyper_connection(x, phi, bias, alpha, c):
+    """(H_pre [.., n], H_post [.., n], H_res [.., n, n]) of the streams x
+    [.., n, D], a token at a time (arXiv:2512.24880, section 3): x' =
+    vec(x) / sqrt(mean(vec(x)^2) + hc_eps), Ht = alpha (x' Phi) + b with
+    columns [pre (n) | post (n) | res (n x n by rows)] and alpha one scalar
+    a group; H_pre = sigmoid, H_post = 2 sigmoid, H_res = the Sinkhorn
+    normalisation of exp(clip(Ht_res)): hc_sinkhorn_iters times columns
+    then rows, each divided by its sum + hc_eps.
+
+    Departure (the paper leaves them open, the config names no key): no
+    learned weight on the flat RMS norm (it folds into Phi); hc_eps in the
+    norm and in both divisors; the clip before the exp."""
+    n, eps = x.shape[-2], c["hc_eps"]
+    flat = x.reshape(x.shape[:-2] + (-1,))
+    z = (flat * jax.lax.rsqrt(jnp.mean(flat * flat, -1, keepdims=True)
+                              + eps)) @ phi
+    ht = jnp.concatenate([jnp.full((n,), alpha[0]), jnp.full((n,), alpha[1]),
+                          jnp.full((n * n,), alpha[2])]) * z + bias
+    m = jnp.exp(jnp.clip(ht[..., 2 * n:], c["mhc_h_res_clamp_min"],
+                         c["mhc_h_res_clamp_max"])).reshape(
+                             ht.shape[:-1] + (n, n))
+    for _ in range(c["hc_sinkhorn_iters"]):
+        m = m / (m.sum(-2, keepdims=True) + eps)        # columns
+        m = m / (m.sum(-1, keepdims=True) + eps)        # rows
+    return jax.nn.sigmoid(ht[..., :n]), \
+        2.0 * jax.nn.sigmoid(ht[..., n:2 * n]), m
 
 
 def attention(a, pos, wq, wk, wv, q_norm, k_norm, wo, c):
@@ -96,11 +211,19 @@ def attention(a, pos, wq, wk, wv, q_norm, k_norm, wo, c):
     if c["qk_norm"] == "head":
         q = rms_norm(q, q_norm, eps, centred)
         k = rms_norm(k, k_norm, eps, centred)
+    scale, table, table_scale = hd ** -0.5, None, 1.0
+    if c["rope_scaling"] is not None:           # YaRN, as latent_attention
+        factor = c["rope_scaling"]["factor"]
+        m = yarn_mscale(factor, c["rope_scaling"].get("mscale_all_dim", 0))
+        scale, table = scale * m * m, yarn_inv_freq(
+            c["rope_scaling"], c["rope_theta"], c["rotary_dim"])
+        table_scale = yarn_mscale(factor,
+                                  c["rope_scaling"].get("mscale", 1)) / m
     if c["rope_theta"] is not None:
-        q, k = (rope(x, pos, c["rope_theta"], c["rotary_dim"])
-                for x in (q, k))
+        q, k = (rope(x, pos, c["rope_theta"], c["rotary_dim"], table,
+                     c["rope_interleaved"], table_scale) for x in (q, k))
     k, v = (jnp.repeat(x, h // hkv, axis=2) for x in (k, v))
-    s = jnp.einsum("bqhd,bkhd->bhqk", q, k) * hd ** -0.5
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k) * scale
     age = jnp.arange(t)[:, None] - jnp.arange(t)[None, :]       # i - j
     visible = age >= 0
     if c["window"] is not None:
@@ -182,9 +305,11 @@ def gated_delta_net(a, w_qkvz, w_ba, w_conv, dt_bias, a_log, w_norm, w_out,
     return o.reshape(b, t, hv * dv) @ w_out
 
 
-def shared_expert(m, wg, wu, wd, ws):
-    """sigmoid(m w_s) * SwiGLU(m): every token passes it."""
-    return jax.nn.sigmoid(m @ ws) * ((jax.nn.silu(m @ wg) * (m @ wu)) @ wd)
+def shared_expert(m, wg, wu, wd, ws=None):
+    """sigmoid(m w_s) * SwiGLU(m): every token passes it; without w_s
+    (DeepSeek-V3's shared experts) the SwiGLU as it is."""
+    out = (jax.nn.silu(m @ wg) * (m @ wu)) @ wd
+    return out if ws is None else jax.nn.sigmoid(m @ ws) * out
 
 
 def gated_unit(m, wg, wu, c):
@@ -203,8 +328,9 @@ def routed_experts(m, router, w_gate, w_up, w_down, c, router_x=None,
     `out` is their part of the sum. With router_scoring sigmoid
     (Lfm2MoeSparseMoeBlock) the scores are s = sigmoid(logits), the top k is
     chosen over s + expert_bias [E] where there is one and weighed by s
-    itself, renormalised over the chosen with 1e-6 added to their sum, and
-    scaled by routed_scaling_factor; the two auxiliary terms are 0.
+    itself, renormalised over the chosen with 1e-6 (router_renorm_epsilon
+    where given: DeepSeek-V3's is 1e-20) added to their sum, and scaled by
+    routed_scaling_factor; the two auxiliary terms are 0.
 
     Departure: the expert bias is a buffer in `modeling_lfm2_moe.py`, moved
     during pre-training by a rule the config does not give; here it is an
@@ -224,8 +350,10 @@ def routed_experts(m, router, w_gate, w_up, w_down, c, router_x=None,
     if c["norm_topk_prob"]:
         # a softmax over all E renormalised over the chosen k is the softmax
         # over the k chosen logits
+        eps = c.get("router_renorm_epsilon")
         gate = gate / (gate.sum(-1, keepdims=True)
-                       + (1e-6 if sigmoid else 0.0))
+                       + ((1e-6 if eps is None else eps) if sigmoid
+                          else 0.0))
     gate = gate * c.get("routed_scaling_factor", 1)
 
     # Departure: HF gathers an expert's tokens and index_adds its outputs;
@@ -289,21 +417,30 @@ def passes(cfg, params, ids, pos):
     routed = c["ffn_layers"].count("experts")   # the terms' mean is theirs
     embedding = take(1)[0]
     weights = []                # a layer: (N1, mixer, N2, N3, ffn, N4)
-    for i in range(layers):
+    streams = c["hc_mult"]
+    hcs = []                    # a layer: (attention's, the FFN's) (phi, b,
+    for i in range(layers):     # alpha), or (None, None)
+        hc_a = take(3) if streams > 1 else None
         n1 = take(1)[0]
         if c["mixer_layers"][i] == "gated_delta":
             mixer = take(7)
         elif c["mixer_layers"][i] == "short_conv":
             mixer = take(3)
+        elif c["latent"]:
+            mixer = take(7)
         else:
             mixer = take(3) + (take(2) if c["qk_norm"] else [None, None]) \
                 + take(1)
         n2 = take(1)[0] if sandwich else None
+        hcs.append((hc_a, take(3) if streams > 1 else None))
         n3 = take(1)[0]
-        # experts: router, [expert bias], gate, up, down, [shared expert's 4]
+        # experts: router, [expert bias], gate, up, down, [shared expert's 3
+        # and its gate's weight]
+        shared = 0 if not c["shared_expert_intermediate_size"] \
+            else 4 if c["shared_expert_gate"] else 3
         ffn = take(3) if c["ffn_layers"][i] == "dense" else (
             take(1) + (take(1) if c["use_expert_bias"] else [None])
-            + take(3 + (4 if c["shared_expert_intermediate_size"] else 0)))
+            + take(3 + shared))
         weights.append((n1, mixer, n2, n3, ffn,
                         take(1)[0] if sandwich else None))
     w_f = take(1)[0]
@@ -320,19 +457,40 @@ def passes(cfg, params, ids, pos):
     with jax.default_matmul_precision("highest"):
         h = embedding[ids]
         b, t, d = h.shape
+        if streams > 1:
+            # Departure (arXiv:2409.19606's convention; the config has no key
+            # for either end): the streams start as `streams` copies of the
+            # embedding and are read out as their sum
+            h = jnp.broadcast_to(h[:, :, None], (b, t, streams, d))
+
+        def read(x, hc):
+            """(what the sub-layer reads, how its output is written back)
+            through a hyper-connection, or the plain residual."""
+            if hc is None:
+                return x, lambda y: x + y
+            pre, post, res = hyper_connection(x, *hc, c)
+            return jnp.einsum("bti,btid->btd", pre, x), lambda y: \
+                jnp.einsum("btij,btjd->btid", res, x) \
+                + post[..., None] * y[:, :, None]
+
         for _ in range(c["total_ut_steps"]):
             for i, (n1, mixer, n2, n3, ffn, n4) in enumerate(weights):
-                a = rms_norm(h, n1, eps, centred)
+                x, write = read(h, hcs[i][0])
+                a = rms_norm(x, n1, eps, centred)
                 if c["mixer_layers"][i] == "gated_delta":
                     mixed = gated_delta_net(a, *mixer, c)
                 elif c["mixer_layers"][i] == "short_conv":
                     mixed = short_conv(a, *mixer)
+                elif c["latent"]:
+                    mixed, _ = latent_attention(a, pos, *mixer,
+                                                layer_config(c, i))
                 else:
                     mixed = attention(a, pos, *mixer, layer_config(c, i))
                 if sandwich:
                     mixed = rms_norm(mixed, n2, eps, centred)
-                h = h + mixed
-                m = rms_norm(h, n3, eps, centred)
+                h = write(mixed)
+                x, write = read(h, hcs[i][1])
+                m = rms_norm(x, n3, eps, centred)
                 if c["ffn_layers"][i] == "experts":
                     out, lb, lz, ld = routed_experts(
                         m.reshape(b * t, d), ffn[0], *ffn[2:5], c,
@@ -349,7 +507,9 @@ def passes(cfg, params, ids, pos):
                     out = (jax.nn.silu(m @ wg) * (m @ wu)) @ wd
                 if sandwich:
                     out = rms_norm(out, n4, eps, centred)
-                h = h + out
+                h = write(out)
+            if streams > 1:
+                h = h.sum(2)
             h = rms_norm(h, w_f, eps, centred)
             logits.append(h @ w_lm)
             if c["exit_gate"]:

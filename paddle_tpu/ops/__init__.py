@@ -13,3 +13,4 @@ from . import volumetric_ops  # noqa: F401
 from . import guard_ops      # noqa: F401
 from . import quant_ops      # noqa: F401
 from . import linear_attention_ops  # noqa: F401
+from . import hyper_connection_ops  # noqa: F401

@@ -20,7 +20,7 @@ Accepted forms of PADDLE_TPU_PALLAS:
     - "attn,xent"       : allowlist — exactly the named ops on, the
                           rest off.  Unknown names raise LOUDLY (a typo
                           must not silently run the other path).
-Op names: attn, xent, ln, lstm, seq, gdr, conv, emb (KERNEL_OPS).  For 'attn' the flag
+Op names: attn, xent, ln, lstm, seq, gdr, conv, emb, mhc (KERNEL_OPS).  For 'attn' the flag
 is an opt-OUT only: fused_attention's positive dispatch is always the
 flash_at() rule, so enabling 'attn' does not force flash below the
 crossover (pin FLAGS_flash_min_seq=0 for that).
@@ -82,7 +82,16 @@ __all__ = [
 # [37984, 2560], 2.61 / 2.41 / 2.32 for 16384 into [50304, 2048], 1.00 /
 # 0.95 / 0.91 for 8192 into [16384, 2048], no difference at [49152,
 # 2048], [18992, 2048] and [32000, 512]; two blocks in flight are half of
-# Mosaic's default scoped VMEM at 4 MiB.
+# Mosaic's default scoped VMEM at 4 MiB.  "mhc" is the rows of a
+# stream [N, n * C] one grid step of ops/mhc_kernels.py's six stream
+# kernels takes (the two coefficient kernels take 1024 tokens a step,
+# a vector register a coefficient): a row of four streams of 3584 in
+# bf16 is 28 KiB, so 128 rows are 3.5 MiB an operand, long enough for
+# the DMA, with three such operands in two buffers each under the
+# kernels' own VMEM limit.  Swept on the v5e
+# (my chip run, PR 43: `chip_smoke.py --phases J`, a sub-layer's forward +
+# backward alone at [4096, 14336] bf16): 2.121 ms at 64 rows, 2.094 at
+# 128, 2.125 at 256: flat, the kernels run at their bytes' pace.
 DEFAULT_TILES = {
     "attn": {"block_q": 512, "block_k": 512},
     "xent": {"block_n": 8},
@@ -92,6 +101,7 @@ DEFAULT_TILES = {
     "gdr": {"chunk": 64, "block_h": 8},
     "conv": {"tile_bytes": 1 << 20},
     "emb": {"tile_bytes": 4 << 20},
+    "mhc": {"block_rows": 128},
 }
 KERNEL_OPS = frozenset(DEFAULT_TILES)
 # Dense attention below this query length, flash at and above it.  The

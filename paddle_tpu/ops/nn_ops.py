@@ -291,23 +291,42 @@ def _rotary_embedding(ctx, ins, attrs):
     [B, T] (an input, not a constant: a decode step feeds its own). The
     half-split convention over the first R = rotary_dim channels (all D by
     default): the pair (i, i + R/2) of every head turns by pos *
-    base^(-2i/R), the channels from R on pass. Angles, cos and sin and the
-    rotation are float32; the result comes back in x's dtype."""
+    base^(-2i/R), the channels from R on pass. With the attr `inv_freq`
+    (R/2 floats, a table made outside: a scaled one) pair i turns by pos *
+    inv_freq[i] instead, and cos and sin are multiplied by `table_scale`
+    (1). layout "interleaved": the pairs are (2i, 2i + 1). Angles, cos
+    and sin and the rotation are float32; the result comes back in x's
+    dtype."""
     x = single(ins, "X")
     pos = single(ins, "Pos")
     d = attrs.get("rotary_dim") or x.shape[-1]
     if d % 2 or d > x.shape[-1]:
         raise ValueError("rotary_embedding needs an even width up to the "
                          "head's %d, got %d" % (x.shape[-1], d))
-    inv_freq = attrs.get("base", 10000.0) ** (
-        -jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    if attrs.get("inv_freq") is not None:
+        inv_freq = jnp.asarray(attrs["inv_freq"], jnp.float32)
+        if inv_freq.shape != (d // 2,):
+            raise ValueError("rotary_embedding: inv_freq has %d entries for "
+                             "%d pairs" % (inv_freq.size, d // 2))
+    else:
+        inv_freq = attrs.get("base", 10000.0) ** (
+            -jnp.arange(0, d, 2, dtype=jnp.float32) / d)
     angle = pos.reshape(x.shape[:2]).astype(jnp.float32)[:, :, None, None] \
         * inv_freq
     cos, sin = jnp.cos(angle), jnp.sin(angle)
+    if attrs.get("table_scale", 1.0) != 1.0:
+        cos, sin = cos * attrs["table_scale"], sin * attrs["table_scale"]
     x32 = x.astype(jnp.float32)
     whole = d == x.shape[-1]
-    x1, x2 = jnp.split(x32 if whole else x32[..., :d], 2, axis=-1)
-    parts = [x1 * cos - x2 * sin, x2 * cos + x1 * sin]
+    turned = x32 if whole else x32[..., :d]
+    if attrs.get("layout", "half") == "interleaved":
+        pairs = turned.reshape(turned.shape[:-1] + (d // 2, 2))
+        x1, x2 = pairs[..., 0], pairs[..., 1]
+        parts = [jnp.stack([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                           -1).reshape(turned.shape)]
+    else:
+        x1, x2 = jnp.split(turned, 2, axis=-1)
+        parts = [x1 * cos - x2 * sin, x2 * cos + x1 * sin]
     if not whole:
         parts.append(x32[..., d:])
     return _out(jnp.concatenate(parts, -1).astype(x.dtype))
@@ -421,10 +440,19 @@ def _fused_attention(ctx, ins, attrs):
     ParallelExecutor mesh with an 'sp' axis, the same op dispatches to
     parallel/ring_attention.py — the sequence dim shards over sp, K/V
     blocks rotate the ring via lax.ppermute, and the online softmax
-    matches the single-chip kernel exactly (incl. causal + kv_len)."""
+    matches the single-chip kernel exactly (incl. causal + kv_len).
+
+    The latent form: QRope [B, T, Hq, dr] and KRope [B, T, 1, dr] beside Q,
+    K and V of one width D: a head's score is q . k + q_rope . k_rope, the
+    rotary key one that all heads share, its value D wide
+    (pallas_kernels.flash_attention). The dense path concatenates the two
+    parts and repeats the shared key a head; the flash kernels do
+    neither."""
     q = single(ins, "Q")
     k = single(ins, "K")
     v = single(ins, "V")
+    q_rope = single(ins, "QRope") if ins.get("QRope") else None
+    k_rope = single(ins, "KRope") if ins.get("KRope") else None
     kv_len = single(ins, "KVLen") if ins.get("KVLen") else None
     causal = attrs.get("causal", False)
     scale = attrs.get("scale", None)
@@ -434,12 +462,14 @@ def _fused_attention(ctx, ins, attrs):
     window = attrs.get("window", None)
     mesh = ctx.mesh
     if mesh is not None and mesh.shape.get("sp", 1) > 1:
-        if window is not None or k.shape[2] != q.shape[2]:
+        if window is not None or k.shape[2] != q.shape[2] \
+                or q_rope is not None:
             raise NotImplementedError(
                 "fused_attention under an 'sp' mesh axis has neither a "
-                "window nor grouped queries: the ring and Ulysses paths "
-                "would ignore window=%r and %d key/value heads for %d "
-                "query heads" % (window, k.shape[2], q.shape[2]))
+                "window nor grouped queries nor the latent form: the ring "
+                "and Ulysses paths would ignore window=%r, %d key/value "
+                "heads for %d query heads and the rotary parts"
+                % (window, k.shape[2], q.shape[2]))
         # sp_impl picks the sequence-parallel algorithm: "ring" (default;
         # K/V blocks rotate over ICI, O(T/sp) memory, any head count) or
         # "ulysses" (all-to-all head sharding — one collective round
@@ -457,12 +487,19 @@ def _fused_attention(ctx, ins, attrs):
     # the tile kernel_config.DEFAULT_TILES holds.
     if not flash_at(q.shape[1]):
         from ..parallel.ring_attention import attention_reference
+        if q_rope is not None:
+            if scale is None:
+                scale = (q.shape[3] + q_rope.shape[3]) ** -0.5
+            q = jnp.concatenate([q, q_rope], -1)
+            k = jnp.concatenate(
+                [k, jnp.broadcast_to(k_rope, q_rope.shape)], -1)
         return _out(attention_reference(
             q, k, v, causal=causal, scale=scale, kv_len=kv_len,
-            window=window).astype(q.dtype))
+            window=window).astype(v.dtype))
     from . import pallas_kernels as pk
     out = pk.flash_attention(
-        q, k, v, causal=causal, scale=scale, kv_len=kv_len, window=window)
+        q, k, v, causal=causal, scale=scale, kv_len=kv_len, window=window,
+        q_rope=q_rope, k_rope=k_rope)
     return _out(out)
 
 

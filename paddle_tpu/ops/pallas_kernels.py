@@ -47,13 +47,17 @@ _NEG = -1e30
 
 # The name each pallas_call gives its Mosaic custom call: the HLO instruction
 # is `<name>.<n>`, which a device trace, the profiler's table and the
-# benchmark's per-kernel metrics find it by. The last four are other modules'.
+# benchmark's per-kernel metrics find it by. Those from ptpu_gated_delta_fwd
+# on are other modules' (the last eight ops/mhc_kernels.py's KERNELS).
 KERNEL_NAMES = (
     "ptpu_flash_fwd", "ptpu_flash_bwd_dkdv", "ptpu_flash_bwd_dq",
     "ptpu_softmax_xent_fwd", "ptpu_layer_norm_fwd", "ptpu_lstm_seq",
     "ptpu_lstmp_seq", "ptpu_masked_softmax", "ptpu_masked_pool",
     "ptpu_gated_delta_fwd", "ptpu_gated_delta_bwd",
-    "ptpu_causal_conv1d_fwd", "ptpu_causal_conv1d_bwd", "ptpu_embedding_grad")
+    "ptpu_causal_conv1d_fwd", "ptpu_causal_conv1d_bwd", "ptpu_embedding_grad",
+    "ptpu_mhc_pre_fwd", "ptpu_mhc_pre_bwd", "ptpu_mhc_post_fwd",
+    "ptpu_mhc_post_bwd", "ptpu_mhc_expand", "ptpu_mhc_reduce",
+    "ptpu_mhc_coeffs_fwd", "ptpu_mhc_coeffs_bwd")
 
 
 def _interpret_default():
@@ -134,7 +138,11 @@ def heads_a_block(hq, hkv, d):
     blocks, 128 // D heads share one (2 at D=64); where all the heads
     together are under 128 lanes the block is the whole row. The last two
     need as many key/value heads as query heads: a block of K has to hold
-    the same heads at the same lanes as the block of Q it meets."""
+    the same heads at the same lanes as the block of Q it meets. A head of
+    192 (latent attention's 128 + 64) is none of these (32, 32, 192 gives
+    None): it does not come here whole but as its two parts, q, k and v at
+    128 (1 a block) and the rotary part beside them (`_rope_rows`: two
+    heads of 64 a block, against one key)."""
     if d % 128 == 0:
         return 1
     if hq == hkv and 128 % d == 0 and (hq * d) % 128 == 0:
@@ -198,6 +206,19 @@ def _as_col(row):
     return lax.slice(lax.transpose(tall, (1, 0)), (0, 0), (n, 1))
 
 
+def _rope_lanes(w, dr, hr):
+    """[1, W] mask of the dr lanes that hold this grid step's head among
+    the hr heads of a block of the rotary queries [.., Hq*dr] (the head is
+    grid axis 1: the latent form runs one head a step); None where a head
+    is whole blocks."""
+    if hr == 1:
+        return None
+    lane = lax.broadcasted_iota(jnp.int32, (1, w), 1)
+    member = lax.rem(pl.program_id(1), np.int32(hr))
+    return lax.eq(lax.div(lane, lax.full_like(lane, dr)),
+                  lax.broadcast(member, (1, w)))
+
+
 def _lin(*terms):
     """sum(index * n) over (index, n) terms of an index map."""
     total = None
@@ -207,8 +228,16 @@ def _lin(*terms):
     return total
 
 
-def _flash_fwd_kernel(q_ref, k_ref, v_ref, len_ref, o_ref, lse_ref, *,
-                      scale, causal, window, block_q, block_k, t_pad, d, hb):
+def _flash_fwd_kernel(q_ref, k_ref, v_ref, *refs, scale, causal, window,
+                      block_q, block_k, t_pad, d, hb, rope=None):
+    """`rope` (dr, hr), the latent form: two operands more, the rotary
+    queries' block [bq, Wr] and the one rotary key all heads share, pinned
+    [t_pad, Wr]; a score is the sum of the two products."""
+    if rope:
+        qr_ref, kr_ref, len_ref, o_ref, lse_ref = refs
+        qr = _only(_rope_lanes(qr_ref.shape[1], *rope), qr_ref[...])
+    else:
+        len_ref, o_ref, lse_ref = refs
     qb = pl.program_id(2)
     bq, w = q_ref.shape
     lanes = _head_lanes(w, d, hb)
@@ -222,7 +251,11 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, len_ref, o_ref, lse_ref, *,
         m, l, acc = carry
         k = k_ref[pl.ds(kb * block_k, block_k), :]
         v = v_ref[pl.ds(kb * block_k, block_k), :]
-        s = _dot(q, k, _NT) * scale                          # [bq, bk] f32
+        if rope:
+            s = (_dot(q, k, _NT) + _dot(
+                qr, kr_ref[pl.ds(kb * block_k, block_k), :], _NT)) * scale
+        else:
+            s = _dot(q, k, _NT) * scale                      # [bq, bk] f32
         kpos = kb * block_k + lax.broadcasted_iota(jnp.int32, (1, block_k),
                                                    1)
         valid = _visible(kpos < kv_len, qpos, kpos, causal, window)
@@ -265,8 +298,34 @@ def _pad_t(t, block_q, block_k):
     return int(-(-t // blk) * blk)
 
 
+def _rope_rows(rope, d, hb, pad):
+    """The latent form's two operands as the kernels take them, and the
+    static (dr, hr) the kernels are built with: the rotary queries [rows, T,
+    Hq*dr], hr = 128 // dr heads a lane block, and the one rotary key
+    [rows, T, dr] repeated to a block's width: a head's lanes of a block
+    of queries meet the key at the same lanes (`_rope_lanes`), and the MXU
+    pads a contraction of 64 to 128 deep anyway. The repeat is T x 128,
+    not a key a head."""
+    qr, kr = rope
+    dr = kr.shape[2]
+    if hb != 1 or d % 128 or not (128 % dr == 0 or dr % 128 == 0) \
+            or qr.shape[2] % max(dr, 128):
+        raise ValueError(
+            "flash_attention: the latent form takes heads whose q, k and v "
+            "are whole lane blocks (a multiple of 128 wide) and a rotary part "
+            "that divides 128 or is a multiple of it, its heads filling "
+            "whole blocks; got %d and %d" % (d, dr))
+    hr = max(1, 128 // dr)
+    if hr > 1:
+        kr = jnp.tile(kr, (1, 1, hr))
+    if pad:
+        qr, kr = (jnp.pad(a, pad) for a in (qr, kr))
+    return (qr.reshape(-1, qr.shape[2]), kr.reshape(-1, kr.shape[2])), \
+        (dr, hr)
+
+
 def _flash_fwd(q, k, v, kv_len, d, hb, scale, causal, window, block_q,
-               block_k, interpret):
+               block_k, interpret, rope=None):
     """q: [Bq, T, Hq*D]; k, v: [Bk, T, Hkv*D], heads of D lanes side by
     side in a row; kv_len: [Bq] int32 (true key length per query row); hb
     query heads a lane block (`heads_a_block`) -> (out [Bq, T, Hq*D], lse
@@ -283,8 +342,8 @@ def _flash_fwd(q, k, v, kv_len, d, hb, scale, causal, window, block_q,
     heads, w = hd // d, hb * d
     kv_b, kv_h = _kv_row(rows, k.shape[0]), _kv_row(heads, k.shape[2] // d)
     t_pad = _pad_t(t, block_q, block_k)
-    if t_pad != t:
-        pad = [(0, 0), (0, t_pad - t), (0, 0)]
+    pad = [(0, 0), (0, t_pad - t), (0, 0)] if t_pad != t else None
+    if pad:
         q, k, v = (jnp.pad(a, pad) for a in (q, k, v))
     nq = t_pad // block_q
     q, k, v = (a.reshape(-1, a.shape[2]) for a in (q, k, v))
@@ -295,9 +354,18 @@ def _flash_fwd(q, k, v, kv_len, d, hb, scale, causal, window, block_q,
     def kv_pair(b, p, i, hh):        # the whole K or V of the head's row
         return kv_b(b), kv_h(p)
 
-    kernel = functools.partial(
-        _flash_fwd_kernel, scale=scale, causal=causal, window=window,
-        block_q=block_q, block_k=block_k, t_pad=t_pad, d=d, hb=hb)
+    static = dict(scale=scale, causal=causal, window=window,
+                  block_q=block_q, block_k=block_k, t_pad=t_pad, d=d, hb=hb)
+    rope_args, rope_specs = (), []
+    if rope is not None:
+        rope_args, static["rope"] = _rope_rows(rope, d, hb, pad)
+        hr = static["rope"][1]
+        wr = rope_args[1].shape[1]
+        rope_specs = [
+            _vmem_spec((block_q, wr), lambda b, p, i, hh: (
+                _lin((b, nq), (i, 1)), lax.div(p, np.int32(hr)))),
+            _vmem_spec((t_pad, wr), lambda b, p, i, hh: (kv_b(b), 0))]
+    kernel = functools.partial(_flash_fwd_kernel, **static)
     # lens: whole array in SMEM (no blocking); lse: a row of block_q lanes
     # a (head, q block): Mosaic requires the last two block dims divisible
     # by (8, 128) or equal to the array's, and (1, block_q) is the array's
@@ -308,8 +376,7 @@ def _flash_fwd(q, k, v, kv_len, d, hb, scale, causal, window, block_q,
             _vmem_spec((block_q, w), q_block),
             _vmem_spec((t_pad, w), kv_pair),
             _vmem_spec((t_pad, w), kv_pair),
-            _SMEM_WHOLE,
-        ],
+        ] + rope_specs + [_SMEM_WHOLE],
         out_specs=[
             _vmem_spec((block_q, w), q_block),
             _vmem_spec((1, 1, 1, block_q), lambda b, p, i, hh: (
@@ -322,14 +389,14 @@ def _flash_fwd(q, k, v, kv_len, d, hb, scale, causal, window, block_q,
         ],
         interpret=interpret,
         name="ptpu_flash_fwd",
-    )(q, k, v, kv_len.reshape(rows, 1).astype(jnp.int32))
+    )(q, k, v, *rope_args, kv_len.reshape(rows, 1).astype(jnp.int32))
     out = out.reshape(rows, t_pad, hd)
     return (out if t_pad == t else out[:, :t]), lse
 
 
 def _flash_bwd_dkdv_kernel(q_ref, g_ref, k_ref, v_ref, lse_ref, delta_ref,
-                           len_ref, dk_ref, dv_ref, *, scale, causal,
-                           window, block_q, block_k, t_pad, d, hb):
+                           *refs, scale, causal, window, block_q, block_k,
+                           t_pad, d, hb, rope=None):
     """One k-block's dK/dV: stream q-blocks past it, starting at the
     causal frontier (q blocks strictly before this k block contribute
     nothing — the same 2x FLOP skip the forward kernel does) and, under a
@@ -339,7 +406,18 @@ def _flash_bwd_dkdv_kernel(q_ref, g_ref, k_ref, v_ref, lse_ref, delta_ref,
     p.T and ds.T then enter their dots as they are, with no transpose of a
     [bq, bk] tile a block (a fifth to a quarter of this kernel's time, my
     chip run, PR 27), and lse / delta are rows [1, bq] that broadcast down
-    the sublanes, as they lie in HBM: [nq, 1, block_q] a head."""
+    the sublanes, as they lie in HBM: [nq, 1, block_q] a head.
+
+    `rope` (dr, hr), the latent form: the head's rotary queries pinned
+    beside q and dO, the k block's rows of the shared rotary key, and a
+    third result, this head's float32 share of that key's gradient (the
+    heads' shares are summed after the kernel, as a group's are)."""
+    if rope:
+        qr_ref, kr_ref, len_ref, dk_ref, dv_ref, dkr_ref = refs
+        rlanes = _rope_lanes(qr_ref.shape[1], *rope)
+        kr = kr_ref[...]
+    else:
+        len_ref, dk_ref, dv_ref = refs
     kb = pl.program_id(2)
     bk, w = k_ref.shape
     lanes = _head_lanes(w, d, hb)
@@ -368,13 +446,27 @@ def _flash_bwd_dkdv_kernel(q_ref, g_ref, k_ref, v_ref, lse_ref, delta_ref,
             qpos = qb * block_q + lax.broadcasted_iota(
                 jnp.int32, (1, block_q), 1)
             valid = _visible(valid, qpos, kpos, causal, window)
-        p = jnp.where(valid, jnp.exp(_dot(k, q, _NT) * scale - lse), 0.0)
+        if rope:
+            qr = _only(rlanes, qr_ref[pl.ds(qb * block_q, block_q), :])
+            st = (_dot(k, q, _NT) + _dot(kr, qr, _NT)) * scale
+        else:
+            st = _dot(k, q, _NT) * scale
+        p = jnp.where(valid, jnp.exp(st - lse), 0.0)
         ds = p * (_dot(v, g, _NT) - delta)
-        return (_dot(ds.astype(q.dtype), q),
-                _dot(p.astype(g.dtype), g))                  # p [bk, bq]
+        terms = (_dot(ds.astype(q.dtype), q),
+                 _dot(p.astype(g.dtype), g))                 # p [bk, bq]
+        return terms + (_dot(ds.astype(q.dtype), qr),) if rope else terms
 
     zeros = jnp.zeros((bk, w), jnp.float32)
-    if dk_ref.dtype == jnp.float32 and lanes is None:
+    if rope:
+        def body(qb, carry):
+            return tuple(a + b for a, b in zip(carry, step(qb)))
+        dk, dv, dkr = lax.fori_loop(qb0, nq, body, (
+            zeros, zeros, jnp.zeros(kr.shape, jnp.float32)))
+        dk_ref[...] = (dk * scale).astype(dk_ref.dtype)
+        dv_ref[...] = dv.astype(dv_ref.dtype)
+        dkr_ref[...] = dkr * scale
+    elif dk_ref.dtype == jnp.float32 and lanes is None:
         # a group's float32 shares: the output blocks are the accumulators,
         # and no [bk, W] float32 pair is carried beside them (2.3 MiB of
         # VMEM at W=256: what T=4096 at D=256 does not have to spare)
@@ -397,14 +489,26 @@ def _flash_bwd_dkdv_kernel(q_ref, g_ref, k_ref, v_ref, lse_ref, delta_ref,
 
 
 def _flash_bwd_dq_kernel(q_ref, g_ref, o_ref, k_ref, v_ref, lse_ref,
-                         len_ref, dq_ref, delta_ref, *, scale, causal,
-                         window, block_q, block_k, t_pad, d, hb):
+                         *refs, scale, causal, window, block_q, block_k,
+                         t_pad, d, hb, rope=None):
     """One q-block's dQ: stream the k-blocks between the window's edge and
     the causal / key-length frontier (mirror of the forward loop). Before
     the loop, delta = rowsum(dO * O) of the block's own rows, from the dO
     block it holds anyway and the O block beside it: the column its tiles
     want, and written out as a row for the dK/dV kernel, which runs after
-    this one."""
+    this one.
+
+    `rope` (dr, hr), the latent form: the rotary queries' block and the
+    shared rotary key, pinned, and a third result, the rotary queries'
+    gradient, the head's lanes of a block whose other lanes are zero: the
+    hr heads of a block write hr blocks (their sum is taken after the
+    kernel), since the grid meets them q blocks apart."""
+    if rope:
+        qr_ref, kr_ref, len_ref, dq_ref, delta_ref, dqr_ref = refs
+        rlanes = _rope_lanes(qr_ref.shape[1], *rope)
+        qr = _only(rlanes, qr_ref[...])
+    else:
+        len_ref, dq_ref, delta_ref = refs
     qb = pl.program_id(2)
     bq, w = q_ref.shape
     lanes = _head_lanes(w, d, hb)
@@ -423,18 +527,30 @@ def _flash_bwd_dq_kernel(q_ref, g_ref, o_ref, k_ref, v_ref, lse_ref,
         kpos = kb * block_k + lax.broadcasted_iota(
             jnp.int32, (1, block_k), 1)
         valid = _visible(kpos < kv_len, qpos, kpos, causal, window)
-        p = jnp.where(valid, jnp.exp(_dot(q, k, _NT) * scale - lse), 0.0)
+        if rope:
+            kr = kr_ref[pl.ds(kb * block_k, block_k), :]
+            s = (_dot(q, k, _NT) + _dot(qr, kr, _NT)) * scale
+        else:
+            s = _dot(q, k, _NT) * scale
+        p = jnp.where(valid, jnp.exp(s - lse), 0.0)
         ds = p * (_dot(g, v, _NT) - delta)
+        if rope:
+            return (dq[0] + _dot(ds.astype(k.dtype), k),
+                    dq[1] + _dot(ds.astype(k.dtype), kr))
         return dq + _dot(ds.astype(k.dtype), k)
 
+    zeros = jnp.zeros((bq, w), jnp.float32)
     dq = lax.fori_loop(
         *_k_blocks(qb, kv_len, causal, window, block_q, block_k, t_pad), body,
-        jnp.zeros((bq, w), jnp.float32))
+        (zeros, jnp.zeros(qr.shape, jnp.float32)) if rope else zeros)
+    if rope:
+        dq, dqr = dq
+        dqr_ref[...] = _only(rlanes, (dqr * scale).astype(dqr_ref.dtype))
     _put(lanes, dq_ref, (dq * scale).astype(dq_ref.dtype))
 
 
 def _flash_bwd(d, hb, scale, causal, window, block_q, block_k, interpret,
-               res, g):
+               res, g, rope=None):
     """Flash backward as two pallas kernels (standard flash-attention recompute
     from the saved logsumexp — the [T, T] matrix never exists): a dK/dV
     kernel gridded over k-blocks and a dQ kernel gridded over q-blocks,
@@ -508,15 +624,25 @@ def _flash_bwd(d, hb, scale, causal, window, block_q, block_k, interpret,
     block that can see the k block's newest key (`_k_blocks`); every block
     that is computed is masked as before (`_visible`), the edges' and the
     interior's alike, so `window=None` compiles to the kernels it always
-    did."""
+    did.
+
+    The latent form (`rope`: the rotary queries [Bq, T, Hq*dr] and the one
+    rotary key [Bk, T, dr] all heads share) gives two results more. The
+    rotary queries' gradient: a head writes its dr lanes of a block and
+    zeros beside them into the slab of its place among the hr heads of that
+    block, and the hr slabs are summed after the kernel (16 MiB each at [1,
+    4096, 32 x 64] bf16). The shared key's gradient: every head writes a
+    float32 share [T, 128], summed after the kernel as a group's shares
+    are, and the hr copies the key was repeated to fold back into one.
+    Returns (dq, dk, dv, dq_rope, dk_rope) then."""
     q, k, v, kv_len, out, lse = res
     rows, t, hd = q.shape
     rows_kv, heads, heads_kv = k.shape[0], hd // d, k.shape[2] // d
     w = hb * d
     kv_b, kv_h = _kv_row(rows, rows_kv), _kv_row(heads, heads_kv)
     t_pad = _pad_t(t, block_q, block_k)
-    if t_pad != t:
-        pad = [(0, 0), (0, t_pad - t), (0, 0)]
+    pad = [(0, 0), (0, t_pad - t), (0, 0)] if t_pad != t else None
+    if pad:
         q, k, v, g, out = (jnp.pad(a, pad) for a in (q, k, v, g, out))
     nq, nk = t_pad // block_q, t_pad // block_k
     q, k, v, g, out = (a.reshape(-1, a.shape[2]) for a in (q, k, v, g, out))
@@ -529,6 +655,33 @@ def _flash_bwd(d, hb, scale, causal, window, block_q, block_k, interpret,
     dkv_dtype = jnp.float32 if grouped else k.dtype
     static = dict(scale=scale, causal=causal, window=window,
                   block_q=block_q, block_k=block_k, t_pad=t_pad, d=d, hb=hb)
+    rope_args = ()
+    dq_rope_specs, dq_rope_out, dq_rope_shape = [], [], []
+    dkv_rope_specs, dkv_rope_out, dkv_rope_shape = [], [], []
+    if rope is not None:
+        rope_args, static["rope"] = _rope_rows(rope, d, hb, pad)
+        hr = static["rope"][1]
+        wq, wr = (a.shape[1] for a in rope_args)
+
+        def member(p):
+            return lax.rem(p, np.int32(hr)), lax.div(p, np.int32(hr))
+
+        dq_rope_specs = [
+            _vmem_spec((block_q, wr), lambda b, p, i, hh: (
+                _lin((b, nq), (i, 1)), member(p)[1])),
+            _vmem_spec((t_pad, wr), lambda b, p, i, hh: (kv_b(b), 0))]
+        dq_rope_out = [_vmem_spec((block_q, wr), lambda b, p, i, hh: (
+            _lin((member(p)[0], rows * nq), (b, nq), (i, 1)), member(p)[1]))]
+        dq_rope_shape = [jax.ShapeDtypeStruct((hr * rows * t_pad, wq),
+                                              q.dtype)]
+        dkv_rope_specs = [
+            _vmem_spec((t_pad, wr), lambda b, p, j, hh: (b, member(p)[1])),
+            _vmem_spec((block_k, wr), lambda b, p, j, hh: (
+                _lin((kv_b(b), nk), (j, 1)), 0))]
+        dkv_rope_out = [_vmem_spec((block_k, wr), lambda b, p, j, hh: (
+            _lin((b, heads * nk), (p, nk), (j, 1)), 0))]
+        dkv_rope_shape = [jax.ShapeDtypeStruct((rows * heads * t_pad, wr),
+                                               jnp.float32)]
 
     def stat_block(b, p, i, hh):     # a head's (1, block_q) of q block i
         return _lin((b, heads), (p, hb), (hh, 1)), i, 0, 0
@@ -539,7 +692,7 @@ def _flash_bwd(d, hb, scale, causal, window, block_q, block_k, interpret,
     def kv_pair(b, p, i, hh):        # the whole K or V of the head's row
         return kv_b(b), kv_h(p)
 
-    dq, delta = pl.pallas_call(
+    dq, delta, *dq_rope = pl.pallas_call(
         functools.partial(_flash_bwd_dq_kernel, **static),
         grid=(rows, heads // hb, nq, hb),
         in_specs=[
@@ -549,17 +702,17 @@ def _flash_bwd(d, hb, scale, causal, window, block_q, block_k, interpret,
             _vmem_spec((t_pad, w), kv_pair),                           # k
             _vmem_spec((t_pad, w), kv_pair),                           # v
             _vmem_spec((1, 1, 1, block_q), stat_block),                # lse
-            _SMEM_WHOLE,
-        ],
+        ] + dq_rope_specs + [_SMEM_WHOLE],
         out_specs=[
             _vmem_spec((block_q, w), q_block),
             _vmem_spec((1, 1, 1, block_q), stat_block),              # delta
-        ],
+        ] + dq_rope_out,
         out_shape=[jax.ShapeDtypeStruct((rows * t_pad, hd), q.dtype),
-                   jax.ShapeDtypeStruct(lse.shape, jnp.float32)],
+                   jax.ShapeDtypeStruct(lse.shape, jnp.float32)]
+        + dq_rope_shape,
         interpret=interpret,
         name="ptpu_flash_bwd_dq",
-    )(q, g, out, k, v, lse, lens)
+    )(q, g, out, k, v, lse, *rope_args, lens)
 
     def stat_row(b, p, j, hh):       # a head's whole [nq, 1, block_q]
         return _lin((b, heads), (p, hb), (hh, 1)), 0, 0, 0
@@ -578,7 +731,7 @@ def _flash_bwd(d, hb, scale, causal, window, block_q, block_k, interpret,
         member = lax.rem(p, np.int32(group))
         return _lin((b, group * nk), (member, nk), (j, 1)), kv_h(p)
 
-    dk, dv = pl.pallas_call(
+    dk, dv, *dk_rope = pl.pallas_call(
         functools.partial(_flash_bwd_dkdv_kernel, **static),
         grid=(rows, heads // hb, t_pad // block_k, hb),
         in_specs=[
@@ -588,23 +741,29 @@ def _flash_bwd(d, hb, scale, causal, window, block_q, block_k, interpret,
             _vmem_spec((block_k, w), k_block),                         # v
             _vmem_spec((1, nq, 1, block_q), stat_row),                # lse
             _vmem_spec((1, nq, 1, block_q), stat_row),              # delta
-            _SMEM_WHOLE,
-        ],
-        out_specs=[_vmem_spec((block_k, w), share)] * 2,
+        ] + dkv_rope_specs + [_SMEM_WHOLE],
+        out_specs=[_vmem_spec((block_k, w), share)] * 2 + dkv_rope_out,
         out_shape=[jax.ShapeDtypeStruct(
-            (rows * group * t_pad, heads_kv * d), dkv_dtype)] * 2,
+            (rows * group * t_pad, heads_kv * d), dkv_dtype)] * 2
+        + dkv_rope_shape,
         interpret=interpret,
         name="ptpu_flash_bwd_dkdv",
-    )(q, g, k, v, lse, delta, lens)
+    )(q, g, k, v, lse, delta, *rope_args, lens)
     dq = dq.reshape(rows, t_pad, hd)
     if grouped:
         dk, dv = (a.reshape(rows_kv, -1, t_pad, heads_kv * d).sum(1)
                   .astype(k.dtype) for a in (dk, dv))
     else:
         dk, dv = (a.reshape(rows_kv, t_pad, -1) for a in (dk, dv))
+    grads = (dq, dk, dv)
+    if rope is not None:
+        dr = static["rope"][0]
+        dqr = dq_rope[0].reshape(hr, rows, t_pad, wq).sum(0)
+        dkr = dk_rope[0].reshape(rows_kv, -1, t_pad, hr, dr).sum((1, 3))
+        grads += (dqr, dkr.astype(rope[1].dtype))
     if t_pad != t:
-        dq, dk, dv = dq[:, :t], dk[:, :t], dv[:, :t]
-    return dq, dk, dv
+        grads = tuple(a[:, :t] for a in grads)
+    return grads
 
 
 def _wait_for(g, *residuals):
@@ -661,11 +820,11 @@ def _unrows(x, like, hb):
 # XLA ran it tokens-minor behind three float32 relayouts of dO and O, 220
 # MiB a call at [8, 2048, 8, 64]; AOT compile, PR 38). No barrier holds the
 # residuals back: the backward rule's first touch of them is a reshape.
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9))
-def _flash_core(q, k, v, kv_len, scale, causal, window, block_q, block_k,
-                interpret):
-    return _flash_core_fwd(q, k, v, kv_len, scale, causal, window, block_q,
-                           block_k, interpret)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10))
+def _flash_core(q, k, v, rope, kv_len, scale, causal, window, block_q,
+                block_k, interpret):
+    return _flash_core_fwd(q, k, v, rope, kv_len, scale, causal, window,
+                           block_q, block_k, interpret)[0]
 
 
 def _flash_layout(q, k, kv_len):
@@ -675,36 +834,60 @@ def _flash_layout(q, k, kv_len):
     return hb, kv_len if hb else jnp.repeat(kv_len, q.shape[2])
 
 
-def _flash_core_fwd(q, k, v, kv_len, scale, causal, window, block_q, block_k,
-                    interpret):
+def _rope_flat(rope):
+    """The latent form's (q_rope [B, T, Hq, dr], k_rope [B, T, 1, dr]) as
+    rows of lanes, or None."""
+    return None if rope is None else tuple(
+        a.reshape(a.shape[:2] + (-1,)) for a in rope)
+
+
+def _flash_core_fwd(q, k, v, rope, kv_len, scale, causal, window, block_q,
+                    block_k, interpret):
     hb, lens = _flash_layout(q, k, kv_len)
     out, lse = _flash_fwd(_rows(q, hb), _rows(k, hb), _rows(v, hb), lens,
                           q.shape[3], hb or 1, scale, causal, window,
-                          block_q, block_k, interpret)
+                          block_q, block_k, interpret, _rope_flat(rope))
     out = _unrows(out, q, hb)
-    return out, (q, k, v, kv_len, out, lse)
+    return out, (q, k, v, rope, kv_len, out, lse)
 
 
 def _flash_core_bwd(scale, causal, window, block_q, block_k, interpret, res,
                     g):
-    q, k, v, kv_len, out, lse = res
+    q, k, v, rope, kv_len, out, lse = res
     hb, lens = _flash_layout(q, k, kv_len)
-    dq, dk, dv = _flash_bwd(
+    dq, dk, dv, *drope = _flash_bwd(
         q.shape[3], hb or 1, scale, causal, window, block_q, block_k,
         interpret, (_rows(q, hb), _rows(k, hb), _rows(v, hb), lens,
-                    _rows(out, hb), lse), _rows(g, hb))
-    return _unrows(dq, q, hb), _unrows(dk, k, hb), _unrows(dv, v, hb), None
+                    _rows(out, hb), lse), _rows(g, hb), _rope_flat(rope))
+    drope = tuple(d.reshape(a.shape) for d, a in zip(drope, rope)) \
+        if rope is not None else None
+    return _unrows(dq, q, hb), _unrows(dk, k, hb), _unrows(dv, v, hb), \
+        drope, None
 
 
 _flash_core.defvjp(_flash_core_fwd, _flash_core_bwd)
 
 
 def flash_attention(q, k, v, causal=False, scale=None, kv_len=None,
-                    block_q=None, block_k=None, interpret=None, window=None):
+                    block_q=None, block_k=None, interpret=None, window=None,
+                    q_rope=None, k_rope=None):
     """Exact attention, flash-style. q: [B, T, Hq, D], k, v: [B, T, Hkv, D]
     (BTHD, the layout ring_attention uses); returns [B, T, Hq, D]. block_q /
     block_k default to kernel_config.DEFAULT_TILES["attn"] and are clamped
-    to T.
+    to T. The widths it takes: q, k and v of one width D, any; and, the
+    latent form, a head of D + dr on keys of D + dr and values of D, given
+    as its two parts (below).
+
+    The latent form (q_rope [B, T, Hq, dr] and k_rope [B, T, 1, dr], both
+    or neither): a head's score is q . k + q_rope . k_rope, the second key
+    one that every head reads, as in latent attention where a head is [a
+    part without position (D); a rotary part (dr)] and its value is D wide.
+    The two products are taken apart in the kernels: nothing is
+    concatenated, and the shared key is not repeated a head, in HBM. D must
+    be a multiple of 128 and dr divide 128 (or be a multiple of it), with
+    as many key/value heads as query heads; `scale` is the caller's (the
+    default is 1 / sqrt(D + dr)). dq_rope and dk_rope come back in their
+    operands' shapes, dk_rope summed over the heads.
 
     Grouped queries come from the shapes: Hq a multiple of Hkv, and query
     head h reads key/value head h // (Hq // Hkv); the kernels find that
@@ -739,11 +922,26 @@ def flash_attention(q, k, v, causal=False, scale=None, kv_len=None,
     if interpret is None:
         interpret = _interpret_default()
     b, t, h, d = q.shape
-    if k.shape != v.shape or h % k.shape[2]:
+    if k.shape != v.shape or h % k.shape[2] or k.shape[3] != d:
         raise ValueError(
-            "flash_attention: q %s needs k and v alike, [B, T, Hkv, D] with "
-            "Hkv dividing the query heads; got k %s, v %s"
+            "flash_attention: q %s needs k and v alike, [B, T, Hkv, D] at q's "
+            "own width D with Hkv dividing the query heads (a head whose "
+            "keys are wider than its values gives the further width as "
+            "q_rope and k_rope); got k %s, v %s"
             % (q.shape, k.shape, v.shape))
+    rope = None
+    if q_rope is not None or k_rope is not None:
+        if q_rope is None or k_rope is None or k.shape[2] != h \
+                or q_rope.shape[:3] != q.shape[:3] \
+                or k_rope.shape != (b, t, 1, q_rope.shape[3]):
+            raise ValueError(
+                "flash_attention: the latent form takes q_rope [B, T, Hq, "
+                "dr] and k_rope [B, T, 1, dr] together, on as many key/value "
+                "heads as query heads; got q %s, k %s, q_rope %s, k_rope %s"
+                % (q.shape, k.shape, getattr(q_rope, "shape", None),
+                   getattr(k_rope, "shape", None)))
+        rope = (q_rope, k_rope)
+        d += q_rope.shape[3]
     if window is not None and int(window) < 1:
         raise ValueError("flash_attention: window must be None or >= 1, got "
                          "%r" % (window,))
@@ -757,7 +955,7 @@ def flash_attention(q, k, v, causal=False, scale=None, kv_len=None,
         lens = jnp.full((b,), t, jnp.int32)
     else:
         lens = jnp.asarray(kv_len, jnp.int32).reshape(b)
-    return _flash_core(q, k, v, lens, float(scale), bool(causal),
+    return _flash_core(q, k, v, rope, lens, float(scale), bool(causal),
                        None if window is None else int(window),
                        int(block_q), int(block_k), bool(interpret))
 

@@ -146,8 +146,8 @@ def _moe_ffn_lower(ctx, ins, attrs):
     router reads instead of X; the weights' leading dimension is the experts
     held, `first_expert` the index of the first. `scoring` (softmax where
     absent, or sigmoid), ExpertBias [E] (added to the scores for the choice
-    of the top_k alone; an input without a gradient variable) and `scale`
-    are routed_ffn's."""
+    of the top_k alone; an input without a gradient variable), `scale` and
+    `norm_epsilon` (routed_ffn's norm_eps) are routed_ffn's."""
     from ..parallel.moe import routed_ffn
     x = single(ins, "X")
     router_x = single(ins, "RouterX") if ins.get("RouterX") else None
@@ -164,7 +164,8 @@ def _moe_ffn_lower(ctx, ins, attrs):
         scoring=str(attrs.get("scoring", "softmax")),
         expert_bias=single(ins, "ExpertBias") if ins.get("ExpertBias")
         else None,
-        scale=float(attrs.get("scale", 1.0)))
+        scale=float(attrs.get("scale", 1.0)),
+        norm_eps=attrs.get("norm_epsilon"))
     return {"Out": [out.reshape(x.shape)], "BalanceLoss": [balance],
             "ZLoss": [z], "ExpertLoad": [load]}
 

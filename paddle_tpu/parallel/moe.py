@@ -561,7 +561,8 @@ _held_experts.defvjp(_held_experts_fwd, _held_experts_bwd)
 SIGMOID_NORM_EPS = 1e-6
 
 
-def _route(logits, top_k, norm_topk_prob, scoring, expert_bias, scale):
+def _route(logits, top_k, norm_topk_prob, scoring, expert_bias, scale,
+           norm_eps=None):
     """(scores [N, E], the logits' logsumexp [N] or None under sigmoid, the
     chosen experts' weights [N, top_k], their indices) from float32 router
     logits [N, E]: `routed_ffn`'s choice, by its docstring."""
@@ -582,7 +583,8 @@ def _route(logits, top_k, norm_topk_prob, scoring, expert_bias, scale):
     if norm_topk_prob:
         total = gate.sum(-1, keepdims=True)
         if scoring == "sigmoid":
-            total = total + SIGMOID_NORM_EPS
+            total = total + (SIGMOID_NORM_EPS if norm_eps is None
+                             else norm_eps)
         gate = gate / total
     if scale != 1.0:
         gate = gate * scale
@@ -592,7 +594,7 @@ def _route(logits, top_k, norm_topk_prob, scoring, expert_bias, scale):
 def routed_ffn(x, router, w_gate, w_up, w_down, top_k, norm_topk_prob=False,
                expert_dtype=None, router_x=None, activation="silu",
                first_expert=0, scoring="softmax", expert_bias=None,
-               scale=1.0):
+               scale=1.0, norm_eps=None):
     """Dropless top-k routed gated experts over tokens x [N, D].
 
     router [D, E]; w_gate, w_up [H, D, F]; w_down [H, F, D]; no bias. The
@@ -666,8 +668,8 @@ def routed_ffn(x, router, w_gate, w_up, w_down, top_k, norm_topk_prob=False,
     moves which experts run and never how much one counts, and it has no
     gradient (its only use is an argument of top_k; the router's gradient
     comes through s). With norm_topk_prob a sigmoid router divides by the
-    chosen scores' sum + SIGMOID_NORM_EPS. `scale` multiplies the weights
-    last. Under sigmoid scoring the balance and z terms are zeros: both are
+    chosen scores' sum + SIGMOID_NORM_EPS (+ `norm_eps` where it is given:
+    DeepSeek-V3's is 1e-20). `scale` multiplies the weights last. Under sigmoid scoring the balance and z terms are zeros: both are
     defined on a softmax's probabilities and its logsumexp.
 
     Returns (out [N, D] in the experts' dtype,
@@ -685,8 +687,11 @@ def routed_ffn(x, router, w_gate, w_up, w_down, top_k, norm_topk_prob=False,
     logits = jnp.dot((x if router_x is None else router_x)
                      .astype(jnp.float32), router.astype(jnp.float32),
                      precision=jax.lax.Precision.HIGHEST)
-    probs, lse, gate, expert = _route(logits, top_k, norm_topk_prob, scoring,
-                                      expert_bias, scale)
+    # norm_eps only where it is given: benchmark/tests/mutant_lfm2.py stands
+    # a `_route` of the six arguments above in this one's place
+    probs, lse, gate, expert = _route(
+        logits, top_k, norm_topk_prob, scoring, expert_bias, scale,
+        **({} if norm_eps is None else {"norm_eps": norm_eps}))
 
     # assignment a = j * N + n (slot-major); `order` lists the assignments by
     # expert (stable, so by slot then token inside an expert), `rank` is

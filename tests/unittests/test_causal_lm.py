@@ -169,6 +169,8 @@ def test_builder_refuses_what_it_cannot_build():
     with pytest.raises(ValueError, match="key/value"):
         causal_lm.resolve(dict(CFG, num_key_value_heads=3))
     with pytest.raises(NotImplementedError, match="rope_scaling"):
+        causal_lm.resolve(dict(CFG, rope_scaling={"type": "linear"}))
+    with pytest.raises(ValueError, match="factor"):     # yarn builds (PR 43)
         causal_lm.resolve(dict(CFG, rope_scaling={"type": "yarn"}))
     # a tied head is built since PR 39; the key is a boolean
     with pytest.raises(NotImplementedError, match="tie_word_embeddings"):

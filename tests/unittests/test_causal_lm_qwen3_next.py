@@ -157,7 +157,7 @@ def test_resolve_reads_qwen3_nexts_keys():
     (dict(mlp_only_layers=[0]), NotImplementedError, "mlp_only_layers"),
     (dict(decoder_sparse_step=2), NotImplementedError,
      "decoder_sparse_step"),
-    (dict(rope_scaling={"type": "yarn"}), NotImplementedError,
+    (dict(rope_scaling={"type": "linear"}), NotImplementedError,
      "rope_scaling"),
     (dict(qk_norm="group"), NotImplementedError, "qk_norm"),
     (dict(partial_rotary_factor=0.2), ValueError, "partial_rotary_factor"),

@@ -40,10 +40,17 @@ PHASE_SAYS = {
         any("causal_conv1d %s path" % path in line
             and "off the float32 recomputation by y " in line
             for line in lines) for path in ("kernel", "xla")),
+    # phase J ran the flash kernels' latent form and the hyper-connections'
+    # kernels against their float32 references
+    "J": lambda lines: any(
+        "J flash at 192 | 128 with one rotary key" in line
+        and "dk_rope" in line for line in lines) and any(
+        "J hyper-connection of 4 streams" in line and "dphi" in line
+        and "rows a block" in line for line in lines),
 }
 
 
-@pytest.mark.parametrize("letter", "ABCDEFGHI")
+@pytest.mark.parametrize("letter", "ABCDEFGHIJ")
 def test_tiny_rehearsal_passes_every_phase(letter):
     """One case a phase (`--phases <letter>`), so that a red run names it."""
     # phase E times the program phase A left

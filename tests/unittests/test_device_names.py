@@ -171,23 +171,35 @@ def test_an_at_sign_would_cut_the_op_name_short():
 
 def test_every_pallas_call_takes_its_name_from_kernel_names():
     from paddle_tpu.ops import causal_conv_kernels, embedding_grad, \
-        gated_delta_kernels
+        gated_delta_kernels, mhc_kernels
     names = []
     for module in (pallas_kernels, gated_delta_kernels, causal_conv_kernels,
-                   embedding_grad):
+                   embedding_grad, mhc_kernels):
         with open(module.__file__) as f:
             tree = ast.parse(f.read())
+        # mhc_kernels' pallas_calls sit in two helpers that are handed the
+        # name: there the literal is the helper's second argument
+        helpers = {"_call", "_coeffs_call"} if module is mhc_kernels \
+            else set()
         for node in ast.walk(tree):
-            if isinstance(node, ast.Call) and isinstance(
-                    node.func, ast.Attribute) \
+            if not isinstance(node, ast.Call):
+                continue
+            if isinstance(node.func, ast.Name) and node.func.id in helpers:
+                assert isinstance(node.args[1], ast.Constant), \
+                    "%s at line %d is given no literal name" \
+                    % (node.func.id, node.lineno)
+                names.append(node.args[1].value)
+            elif isinstance(node.func, ast.Attribute) \
                     and node.func.attr == "pallas_call":
                 kw = {k.arg: k.value for k in node.keywords}
+                if helpers and isinstance(kw.get("name"), ast.Name):
+                    continue
                 assert isinstance(kw.get("name"), ast.Constant), \
                     "pallas_call at line %d has no literal name=" \
                     % node.lineno
                 names.append(kw["name"].value)
     assert sorted(names) == sorted(pallas_kernels.KERNEL_NAMES)
-    assert len(set(names)) == len(names) == 14
+    assert len(set(names)) == len(names) == 22
     for a in names:         # a reader matching `<name>` or `<name>.<n>`
         for b in names:     # never counts one kernel under another
             assert a == b or not (b + ".").startswith(a + ".")

@@ -19,7 +19,8 @@ from paddle_tpu.observability.registry import REGISTRY
 
 CALLS_PALLAS = {"fused_attention", "layer_norm", "softmax_with_cross_entropy",
                 "sequence_pool", "sequence_softmax", "lstm", "lstmp",
-                "gated_delta_rule", "causal_conv1d"}
+                "gated_delta_rule", "causal_conv1d", "mhc_pre", "mhc_post",
+                "mhc_expand", "mhc_reduce"}
 
 
 @pytest.fixture(autouse=True)
@@ -306,7 +307,8 @@ def test_the_rules_that_import_pallas_kernels_carry_the_field():
     for op_type, od in registry._OPS.items():
         src = inspect.getsource(od.lower)
         if re.search(r"import pallas_kernels|pallas_kernels\.|"
-                     r"gated_delta_kernels|causal_conv_kernels", src):
+                     r"gated_delta_kernels|causal_conv_kernels|mhc_kernels",
+                     src):
             reach.add(op_type)
     assert reach == CALLS_PALLAS
     assert {t for t, od in registry._OPS.items()
