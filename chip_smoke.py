@@ -121,6 +121,10 @@ FULL = {
     # values of 128; four streams of 3584
     "latent": dict(t=4096, h=32, d=128, dr=64, streams=4, c=3584,
                    iters=20, tol=3e-2),
+    # the output head and its loss at the SmallThinker and OLMoE cells:
+    # (rows, width, vocabulary); then a ragged N for the kernels alone
+    "head": dict(shapes=((8192, 2560, 37984), (16384, 2048, 50304)),
+                 ragged=(1000, 37984), rows=(16, 32, 64)),
     "barrier": dict(steps=5, rounds=3, tol=0.15),
     "dp_loss_rtol": 2e-2,
 }
@@ -151,6 +155,7 @@ TINY = {
                   ("a row of 1 KiB", 96, 200, 256)),
     "latent": dict(t=64, h=2, d=128, dr=64, streams=4, c=128, iters=20,
                    tol=3e-2),
+    "head": dict(shapes=((48, 32, 200),), ragged=(40, 200), rows=(16, 32)),
     "barrier": dict(steps=5, rounds=3, tol=0.75),
     "dp_loss_rtol": 2e-2,
 }
@@ -1594,6 +1599,116 @@ def phase_j(smoke):
         raise AssertionError("the mhc kernels are %.2e off" % worst)
 
 
+def phase_k(smoke):
+    """One output head with its loss, forward + backward (the matmul, the
+    loss, dX and dW), bf16 operands as under AMP, in two forms of
+    `softmax_with_cross_entropy`: `parent`, PR 43's (the logits upcast to
+    float32 in HBM, the kernel, a dense Softmax beside it that gets a
+    cotangent of zeros) and `now`, PR 44's (bf16 logits into the kernel, no
+    Softmax); dlogits is jax.numpy in both, fused by XLA into the two
+    gradient matmuls. Then the kernel alone, rows a grid step swept, beside
+    the time its bytes take at 819 GB/s. Loss and dlogits are held to the
+    float32 formula on the same bf16 logits, at a ragged N too. Times are
+    printed for the next reader (PERF.md section 6, PR 44, which also has
+    the third form's: a second kernel that wrote dlogits once)."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.ops import pallas_kernels as pk
+
+    bf16, f32 = jnp.bfloat16, jnp.float32
+    c = smoke.cfg["head"]
+
+    def formula(x, lab, g):
+        x = x.astype(f32)
+        lse = jax.nn.logsumexp(x, axis=-1, keepdims=True)
+        loss = lse - jnp.take_along_axis(x, lab[:, None], axis=1)
+        d = (jnp.exp(x - lse) - jax.nn.one_hot(lab, x.shape[1])) * g
+        return loss, d
+
+    def both_ways(x, lab, g):
+        loss, vjp = jax.vjp(lambda x: pk.softmax_xent(x, lab), x)
+        return loss, vjp(g)[0]
+
+    def head(form):
+        def f(h, w, lab):
+            x = jnp.dot(h, w.astype(bf16),
+                        preferred_element_type=f32).astype(bf16)
+            if form == "now":
+                return jnp.mean(pk.softmax_xent(x, lab)), None
+            x = x.astype(f32)
+            return (jnp.mean(pk.softmax_xent(x, lab)),
+                    jnp.exp(jax.nn.log_softmax(x, axis=-1)))
+
+        def run(h, w, lab):
+            (loss, dense), vjp = jax.vjp(lambda h, w: f(h, w, lab), h, w)
+            return (loss,) + vjp((jnp.ones((), f32),
+                                  None if dense is None
+                                  else jnp.zeros_like(dense)))
+        return jax.jit(run)
+
+    for n, v in [s[::2] for s in c["shapes"]] + [c["ragged"]]:
+        rng = np.random.RandomState(47)
+        x = jnp.asarray(rng.randn(n, v) * 3.0, bf16)
+        lab = jnp.asarray(rng.randint(0, v, n), jnp.int32)
+        g = jnp.asarray(rng.rand(n, 1) / n, f32)
+        want_loss, want_d = jax.jit(formula)(x, lab, g)
+        loss, d = jax.jit(both_ways)(x, lab, g)
+        err_l = float(jnp.max(jnp.abs(loss - want_loss))
+                      / jnp.max(jnp.abs(want_loss)))
+        err_d = float(jnp.max(jnp.abs(d.astype(f32) - want_d))
+                      / jnp.max(jnp.abs(want_d)))
+        if d.dtype != bf16 or err_l > 1e-5 or err_d > 1e-2:
+            raise AssertionError(
+                "softmax_xent [%d, %d]: loss %.2e and dlogits (%s) %.2e off "
+                "the float32 formula" % (n, v, err_l, d.dtype, err_d))
+        del want_d, d
+        times = []
+        for rows in (None,) + tuple(c["rows"]):
+            run = jax.jit(lambda x, lab, rows=rows: pk.softmax_xent(
+                x, lab, block_n=rows))
+            try:
+                off = float(jnp.max(jnp.abs(run(x, lab) - want_loss)))
+            except Exception as e:  # noqa: BLE001 — a tile Mosaic refuses
+                times.append("%s rows: refused (%s)"
+                             % (rows, str(e).splitlines()[0][:80]))
+                continue
+            if off > 1e-4:
+                raise AssertionError("softmax_xent [%d, %d] at %s rows: "
+                                     "loss %.2e off" % (n, v, rows, off))
+            times.append("%s rows %.3f" % (rows or "the table's",
+                                           _in_flight_ms(run, (x, lab))))
+        smoke.say("K softmax_xent kernel [%d, %d] bf16, loss %.1e and "
+                  "dlogits %.1e off the formula; forward ms (its bytes take "
+                  "%.3f): %s" % (n, v, err_l, err_d,
+                                 1e3 * n * v * 2 / 819e9, "; ".join(times)))
+        del x, want_loss
+    for n, width, v in c["shapes"]:
+        rng = np.random.RandomState(53)
+        h = jnp.asarray(rng.randn(n, width), bf16)
+        w = jnp.asarray(rng.randn(width, v) * 0.02, f32)
+        lab = jnp.asarray(rng.randint(0, v, n), jnp.int32)
+        peak = 1e3 * 3 * 2.0 * n * width * v / 197e12
+        out = {}
+        for form in ("now", "parent"):
+            run = head(form)
+            out[form] = jax.block_until_ready(run(h, w, lab))
+            out[form] = (out[form], _in_flight_ms(run, (h, w, lab), calls=2))
+        for a, b, what in zip(out["parent"][0], out["now"][0],
+                              ("loss", "dX", "dW")):
+            err = float(jnp.max(jnp.abs(a.astype(f32) - b.astype(f32)))
+                        / jnp.max(jnp.abs(b.astype(f32))))
+            if err > 2e-2:
+                raise AssertionError(
+                    "head [%d, %d] x [%d, %d]: the parent's %s is %.2e off"
+                    % (n, width, width, v, what, err))
+        smoke.say("K head [%d, %d] x [%d, %d], forward + backward ms (three "
+                  "matmuls at the peak %.2f): %s"
+                  % (n, width, width, v, peak,
+                     ", ".join("%s %.3f" % (k, t[1])
+                               for k, t in out.items())))
+        del out
+
+
 PHASES = (("A", "ResNet-50 training", phase_a),
           ("B", "transformer training", phase_b),
           ("C", "Pallas kernel families", phase_c),
@@ -1603,7 +1718,8 @@ PHASES = (("A", "ResNet-50 training", phase_a),
           ("G", "looped decoder's summed gradients", phase_g),
           ("H", "LFM2's gated convolution and sigmoid router", phase_h),
           ("I", "the embedding's backward", phase_i),
-          ("J", "latent attention and hyper-connections", phase_j))
+          ("J", "latent attention and hyper-connections", phase_j),
+          ("K", "the output head and its loss", phase_k))
 
 
 def main(argv=None):
@@ -1611,7 +1727,7 @@ def main(argv=None):
     ap.add_argument("--tiny", action="store_true",
                     help="CPU rehearsal at toy sizes (needs "
                          "JAX_PLATFORMS=cpu)")
-    ap.add_argument("--phases", default="ABCDEFGHIJ",
+    ap.add_argument("--phases", default="ABCDEFGHIJK",
                     help="letters of the phases to run (default all)")
     args = ap.parse_args(argv)
 

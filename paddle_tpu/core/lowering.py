@@ -107,7 +107,12 @@ _AMP_BF16_OPS = frozenset({
 # `moe_ffn` is in neither table: one input feeds its float32 router and its
 # bf16 experts, so its rule casts for itself (ops/parallel_ops.py).
 # Numerically sensitive ops: force their float inputs back up to f32 so the
-# loss/probability path never rounds through bf16.
+# loss/probability path never rounds through bf16. One of them is upcast
+# only where it needs to be: `softmax_with_cross_entropy` on its kernel
+# path with no dense Softmax (ops/nn_ops.softmax_xent_form) takes bf16
+# logits as they are, because the kernel casts a tile to f32 in VMEM, which
+# is exact, and a cast here is a float32 [N, V] array in HBM with that one
+# reader; its loss and logsumexp are f32 all the same.
 _AMP_F32_OPS = frozenset({
     "softmax", "log_softmax", "cross_entropy", "softmax_with_cross_entropy",
     "sigmoid_cross_entropy_with_logits", "mean"})
@@ -121,10 +126,15 @@ def _amp_cast_ins(ins, dtype, from_dtype):
     return {slot: [cast(v) for v in vals] for slot, vals in ins.items()}
 
 
-def _apply_amp(op_type, ins):
+def _apply_amp(ctx, op_type, ins, attrs):
     if op_type in _AMP_BF16_OPS:
         return _amp_cast_ins(ins, jnp.bfloat16, jnp.float32)
     if op_type in _AMP_F32_OPS:
+        if op_type == "softmax_with_cross_entropy":
+            from ..ops.nn_ops import softmax_xent_form
+            if softmax_xent_form(ctx, ins["Logits"][0], attrs) == (
+                    "kernel", False):
+                return ins
         return _amp_cast_ins(ins, jnp.float32, jnp.bfloat16)
     return ins
 
@@ -141,6 +151,10 @@ class LowerCtx(object):
         self.amp = bool(getattr(program, "_amp", False))
         self._op_salt = 0
         self._op_calls = 0
+        # the slots, among the lowering op's OpDef.optional_outputs, that
+        # nothing reads (_unread_outputs): its rule may leave them out
+        self.unread_outputs = frozenset()
+        self._read_names = None
         # traced iteration counters of enclosing lax.scan/while_loop bodies
         # (pushed by control-flow lowerings) — folded into every key so
         # dropout/random ops inside loops vary per time step.
@@ -693,21 +707,24 @@ def _lower_op_inner(ctx, op, env):
         _count_embedding_layer(ctx, ins)
     elif op.type == "mhc_pre":
         _count_hyper_connection_layer(ctx, op.attrs, ins)
+    ctx.unread_outputs = _unread_outputs(ctx, od, op.outputs)
+    if op.type == "softmax_with_cross_entropy":
+        _count_softmax_xent_layer(ctx, op.attrs, ins)
     if op.uid in ctx.linearized:
         # a grad op of this block differentiates this op: run the rule once,
         # under jax.vjp, and keep what the backward needs
-        out_order = _out_order(op.outputs)
-        f, primal = _differentiable(ctx, od, op_scope(op), op.type, op.attrs,
-                                    op.uid, ins, out_order)
+        f, primal, out_order = _differentiable(
+            ctx, od, op_scope(op), op.type, op.attrs, op.uid, ins,
+            _out_order(op.outputs))
         primals, vjp_fn, err = jax.vjp(f, primal, has_aux=True)
-        ctx.linearized[op.uid] = (primals, vjp_fn)
+        ctx.linearized[op.uid] = (primals, vjp_fn, out_order)
         outs = {slot: [None] * len(names)
                 for slot, names in op.outputs.items()}
         for (slot, i, _), p in zip(out_order, primals):
             outs[slot][i] = p
     else:
         if ctx.amp:
-            ins = _apply_amp(op.type, ins)
+            ins = _apply_amp(ctx, op.type, ins, op.attrs)
         ctx.begin_op(op.uid)
         outs = od.lower(ctx, ins, op.attrs)
         err = outs.pop("__errors__", None) if isinstance(outs, dict) else None
@@ -767,21 +784,51 @@ SPECIAL_GRADS = {
 
 def _out_order(outputs):
     """A forward op's named outputs in a deterministic order: [(slot, index,
-    name)]. The differentiated function returns them so, and only the
-    floating-point ones carry cotangents."""
+    name)]. The differentiated function returns them so (less the slots its
+    rule left out: _differentiable), and only the floating-point ones carry
+    cotangents."""
     return [(slot, i, n)
             for slot, names in sorted(outputs.items())
             for i, n in enumerate(names) if n]
 
 
+def _unread_outputs(ctx, od, outputs):
+    """The slots among `od.optional_outputs` whose variables nothing can
+    see: no op of any block of the program reads one (a grad op names its
+    forward op's inputs and its outputs' gradients, not the outputs) or
+    names one in a list attribute (a control-flow op's `out_names`,
+    `carry_names`: variables of its sub-block), none is fetched and none is
+    persistable state. A fact of the Program and of the call's fetch list;
+    the rule reads it as ctx.unread_outputs."""
+    if not od.optional_outputs:
+        return frozenset()
+    if ctx._read_names is None:
+        # what leaves the step (build_program_fn's remat_keep) and what an
+        # op reads
+        read = set(getattr(ctx, "remat_keep", ()))
+        for block in ctx.program.blocks:
+            for op in block.ops:
+                read.update(op.all_input_vars())
+                for v in op.attrs.values():
+                    if isinstance(v, (list, tuple)):
+                        read.update(n for n in v if isinstance(n, str))
+        ctx._read_names = read
+    return frozenset(
+        slot for slot in od.optional_outputs
+        if not any(n in ctx._read_names for n in outputs.get(slot, ()) if n))
+
+
 def _differentiable(ctx, od, scope, op_type, attrs, uid, in_vals, out_order):
-    """(f, primal): a forward op's rule as a function of its floating-point
-    inputs `primal` ({(slot, index): value}), for jax.vjp with has_aux. f
-    returns the outputs in `out_order` and, as auxiliary data, the rule's
-    `__errors__` flag or None. One builder for both users: the forward op
-    that keeps its linearization (_lower_op_inner) and the grad op that
-    replays the forward (_lower_grad_of), so the two differentiate the same
-    function.
+    """(f, primal, built): a forward op's rule as a function of its
+    floating-point inputs `primal` ({(slot, index): value}), for jax.vjp
+    with has_aux. f returns the outputs in `out_order` and, as auxiliary
+    data, the rule's `__errors__` flag or None. `built` is filled when f is
+    traced: `out_order` less the slots the rule left out (only ones named in
+    ctx.unread_outputs: _unread_outputs), which are then no results of the
+    differentiated function and get no cotangent. One builder for both
+    users: the forward op that keeps its linearization (_lower_op_inner) and
+    the grad op that replays the forward (_lower_grad_of), so the two
+    differentiate the same function.
 
     `scope` is the lowering op's own scope, opened once more inside f: jax
     renders a transform around the first scope inside it, and without this
@@ -789,23 +836,28 @@ def _differentiable(ctx, od, scope, op_type, attrs, uid, in_vals, out_order):
     would be `jvp_ptpu_layer_norm_fwd_` and not `ptpu_layer_norm_fwd`."""
     primal = {(slot, i): v for slot, vals in in_vals.items()
               for i, v in enumerate(vals) if _is_float(v)}
+    unread = ctx.unread_outputs     # the caller's, for this op
+    built = []
 
     def f(diff):
         ins = {slot: list(vals) for slot, vals in in_vals.items()}
         for (slot, i), v in diff.items():
             ins[slot][i] = v
+        ctx.unread_outputs = unread
         if ctx.amp:
             # the casts live inside the vjp, so bf16 ops get bf16 activation
             # cotangents while f32 master params receive f32 grads (the vjp
             # of the f32->bf16 cast upcasts)
-            ins = _apply_amp(op_type, ins)
+            ins = _apply_amp(ctx, op_type, ins, attrs)
         ctx.begin_op(uid)  # the forward op's exact PRNG stream
         with jax.named_scope(scope):
             outs = od.lower(ctx, ins, attrs)
-        return ([outs[slot][i] for slot, i, _ in out_order],
+        built[:] = [o for o in out_order
+                    if o[0] in outs or o[0] not in unread]
+        return ([outs[slot][i] for slot, i, _ in built],
                 outs.get("__errors__"))
 
-    return f, primal
+    return f, primal, built
 
 
 def _count_grad_op(path, fwd_type):
@@ -896,6 +948,26 @@ def _count_hyper_connection_layer(ctx, attrs, ins):
           path=mhc_path(ctx.mesh, x.shape, attrs["streams"]))
 
 
+def _count_softmax_xent_layer(ctx, attrs, ins):
+    from ..observability.registry import REGISTRY
+    from ..ops.nn_ops import softmax_xent_form
+    logits = ins["Logits"][0]
+    form = softmax_xent_form(ctx, logits, attrs)
+    seen = logits.dtype
+    if ctx.amp and form != ("kernel", False) and seen == jnp.bfloat16:
+        seen = jnp.dtype(jnp.float32)       # _apply_amp's upcast
+    REGISTRY.counter(
+        "ptpu_softmax_xent_layers_total",
+        "softmax_with_cross_entropy ops lowered (forward ops, not a grad "
+        "op's replay), by who computes the loss (the Pallas kernel, or "
+        "XLA), the dtype the rule reads the logits in (under AMP bfloat16 "
+        "where the kernel runs and builds no Softmax, float32 elsewhere) "
+        "and whether anything reads the dense Softmax output (unread, the "
+        "kernel path builds none; the XLA path builds it either way)"
+    ).inc(path=form[0], logits=str(seen),
+          softmax="unread" if "Softmax" in ctx.unread_outputs else "read")
+
+
 def _count_linear_attention_layer(ins):
     from ..observability.registry import REGISTRY
     from ..ops.kernel_config import DEFAULT_TILES
@@ -957,18 +1029,21 @@ def _lower_grad_of(ctx, op, env):
         return
     fwd_inputs = op.attrs["fwd_inputs"]    # slot -> [names]
     fwd_outputs = op.attrs["fwd_outputs"]  # slot -> [names]
-    out_order = _out_order(fwd_outputs)
     # read, not popped: calc_gradient may differentiate one op twice
     kept = ctx.linearized.get(op.attrs.get("fwd_uid"))
     if kept is not None:
-        primals, vjp_fn = kept
+        primals, vjp_fn, out_order = kept
     else:
         fwd_in_vals = {slot: [env.read(n) for n in names]
                        for slot, names in fwd_inputs.items()}
-        f, primal = _differentiable(
-            ctx, registry.get(fwd_type), op_scope(op), fwd_type,
+        # as its forward op lowered: the slots that one left out are no
+        # results of the function differentiated here either
+        od = registry.get(fwd_type)
+        ctx.unread_outputs = _unread_outputs(ctx, od, fwd_outputs)
+        f, primal, out_order = _differentiable(
+            ctx, od, op_scope(op), fwd_type,
             op.attrs["fwd_attrs"], op.attrs.get("fwd_uid", 0), fwd_in_vals,
-            out_order)
+            _out_order(fwd_outputs))
         # Rematerialization: when the segment-level pass handles this grad
         # op (top-level backward of a >=8-op forward), it hands the replay
         # recomputed barrier-guarded primals — per-op jax.checkpoint must

@@ -42,7 +42,7 @@ def squeeze_label(label):
 
 class OpDef(object):
     def __init__(self, type, lower, infer=None, uses_rng=False,
-                 calls_pallas=False):
+                 calls_pallas=False, optional_outputs=()):
         self.type = type
         self.lower = lower
         self.infer = infer
@@ -53,17 +53,24 @@ class OpDef(object):
         # such an op's linearization for its grad op and does not run the
         # rule again there (core/lowering.py: _linearizations)
         self.calls_pallas = calls_pallas
+        # output slots the rule builds only where the program reads them:
+        # the lowering names the unread ones in ctx.unread_outputs, the
+        # rule may leave those out, and such a slot is then no result of
+        # the function its grad op differentiates (core/lowering.py:
+        # _unread_outputs)
+        self.optional_outputs = tuple(optional_outputs)
 
 
 _OPS = {}
 
 
 def register(type, lower=None, infer=None, uses_rng=False,
-             calls_pallas=False):
+             calls_pallas=False, optional_outputs=()):
     """Register an op. Usable as decorator: @register('relu')."""
     def deco(fn):
         _OPS[type] = OpDef(type, fn, infer=infer, uses_rng=uses_rng,
-                           calls_pallas=calls_pallas)
+                           calls_pallas=calls_pallas,
+                           optional_outputs=optional_outputs)
         return fn
     if lower is not None:
         return deco(lower)
@@ -106,6 +113,8 @@ class AbstractCtx(object):
     is_startup = False
     is_abstract = True
     mesh = None
+    amp = False
+    unread_outputs = frozenset()    # shape inference builds every slot
 
     def rng(self, salt=0, seed=0):
         import jax

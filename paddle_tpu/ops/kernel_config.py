@@ -72,9 +72,16 @@ __all__ = [
 # kernel's two scratches and six buffers at its default VMEM limit, so
 # 1 MiB it is.  At [2, 4096, 2048] every tile from 256 KiB up reads 0.20
 # / 0.39 and XLA's own forward 0.21: the host's dispatch more than the
-# kernel.  The 8-row tiles of
-# "xent" and "seq" have not been swept on the chip (ROADMAP A3);
-# softmax_xent at 8 rows already runs near the HBM rate.  "emb" is the
+# kernel.  "xent" is the same kind of budget for the softmax_xent kernel
+# (PR 44): 16 rows of bf16 logits a grid step at every vocabulary a cell
+# has (a row of 37984 is 148 KiB in float32).  At its old 8 rows the
+# kernel read float32 logits at 732 GB/s, twice the bytes it needed: AMP
+# had upcast them in HBM.  On bf16 logits it takes 1.00 ms for
+# [8192, 37984] in the SmallThinker cell (622 GB/s) and 2.47 for
+# [16384, 50304] in OLMoE's (667 GB/s); alone, 32 and 64 rows read 5-10 %
+# under 16 (2.31 / 2.29 against 2.58 ms at OLMoE's shape), not tried in a
+# cell (my chip runs, PR 44: chip_smoke.py --phases K).  The 8-row
+# tile of "seq" has not been swept on the chip (ROADMAP A3).  "emb" is the
 # bytes of one block of vocabulary rows of embedding_grad's kernel, the
 # float32 [rows, D] a grid step zero-fills, adds to and writes.  Swept
 # on the v5e at 1, 2 and 4 MiB (PERF.md section 6, PR 41; the whole
@@ -94,7 +101,7 @@ __all__ = [
 # 128, 2.125 at 256: flat, the kernels run at their bytes' pace.
 DEFAULT_TILES = {
     "attn": {"block_q": 512, "block_k": 512},
-    "xent": {"block_n": 8},
+    "xent": {"tile_bytes": 1 << 20},
     "ln": {"tile_bytes": 1 << 20},
     "lstm": {"block_b": 0},
     "seq": {"block_n": 8},

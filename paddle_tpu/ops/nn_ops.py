@@ -408,17 +408,41 @@ def _cross_entropy(ctx, ins, attrs):
     return {"Y": [loss]}
 
 
-@register("softmax_with_cross_entropy", calls_pallas=True)
+def softmax_xent_form(ctx, logits, attrs):
+    """(path, dense) of one softmax_with_cross_entropy op, from what its rule
+    sees. path: `kernel` for hard labels on 2-D logits where the kernel is
+    on (a TPU), else `xla`. dense: whether the rule builds the `Softmax`
+    output; always on the XLA path, on the kernel path only where something
+    reads it. Kernel and not dense, the op touches [N, V] once, in the dtype
+    the logits come in (AMP does not upcast them: core/lowering._apply_amp),
+    and its backward has no result but dlogits."""
+    if attrs.get("soft_label", False) or logits.ndim != 2 \
+            or not pallas_on("xent"):
+        return "xla", True
+    # a ctx that says nothing of its op's readers has every slot built
+    return "kernel", "Softmax" not in getattr(ctx, "unread_outputs", ())
+
+
+@register("softmax_with_cross_entropy", calls_pallas=True,
+          optional_outputs=("Softmax",))
 def _softmax_xent(ctx, ins, attrs):
     logits = single(ins, "Logits")
     label = single(ins, "Label")
-    if not attrs.get("soft_label", False) and logits.ndim == 2 \
-            and pallas_on("xent"):
-        # fused pallas path: loss + logsumexp in one VMEM pass, softmax
-        # never materialized in the forward (the dense Softmax slot below
-        # is DCE'd by XLA unless the program actually consumes it)
+    path, dense = softmax_xent_form(ctx, logits, attrs)
+    if path == "kernel":
+        # loss + logsumexp in one VMEM pass; the softmax itself is only
+        # formed in the backward, where it is the gradient
         from . import pallas_kernels as pk
         loss = pk.softmax_xent(logits, label.reshape(-1))
+        if not dense:
+            # nothing reads Softmax, so none is built: a slot built here is
+            # a result of the differentiated function (the op lowers under
+            # jax.vjp), and XLA keeps its exponentials and their transpose,
+            # two passes over [N, V], for a cotangent of zeros. Under AMP
+            # the logits come as they are (bf16) and the loss stays float32,
+            # as the upcast gave it.
+            return {"Loss": [loss.astype(
+                jnp.float32 if ctx.amp else logits.dtype)]}
         logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
         return {"Softmax": [jnp.exp(logp).astype(logits.dtype)],
                 "Loss": [loss.astype(logits.dtype)]}

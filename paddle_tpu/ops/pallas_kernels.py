@@ -17,7 +17,9 @@ is float32 either way.
 
 softmax_xent — fused log-softmax + label pick over the vocab dim: one VMEM
 pass computes the loss and the logsumexp residual; the probability matrix is
-only formed in the backward (where it is the gradient anyway).
+only formed in the backward (where it is the gradient anyway), as jax.numpy
+that XLA fuses into the operand of whoever reads dlogits. The kernel takes
+the logits in the dtype they come in and casts a tile to float32 in VMEM.
 
 Every kernel compiles through Mosaic when the program dispatches to a TPU
 and runs the same body in interpret mode elsewhere (the unit tests exercise
@@ -976,31 +978,56 @@ def _xent_kernel(logits_ref, labels_ref, loss_ref, lse_ref):
     lse_ref[:] = lse
 
 
-def _xent_fwd_call(logits, labels, block_n, interpret):
+def _xent_rows(logits, block_n):
+    """Rows a grid step of the kernel takes: what the caller names, else
+    DEFAULT_TILES["xent"]'s bytes as layer_norm turns its own into rows (a
+    float32 working copy of one tile; 16 rows of a 2-byte dtype at every
+    vocabulary the cells have)."""
+    if block_n is not None:
+        return int(block_n)
     n, v = logits.shape
-    n_pad = int(-(-n // block_n) * block_n)
-    lp = jnp.pad(logits, [(0, n_pad - n), (0, 0)]) if n_pad != n else logits
-    lb = labels.reshape(-1, 1).astype(jnp.int32)
-    lb = jnp.pad(lb, [(0, n_pad - n), (0, 0)]) if n_pad != n else lb
-    loss, lse = pl.pallas_call(
+    return _ln_block_rows(n, v, logits.dtype,
+                          DEFAULT_TILES["xent"]["tile_bytes"])
+
+
+def _xent_params(block_n, v, itemsize):
+    """Mosaic's scoped-VMEM limit for the kernel: two buffers of a
+    [block_n, V] block in the logits' dtype and room for the float32
+    working copies of one block; never under the default 16 MiB. A row of
+    151936 logits is 0.6 MiB in float32, and sixteen of them with their
+    temporaries pass the default."""
+    need = block_n * v * (2 * itemsize + 6 * 4)
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel",),
+        vmem_limit_bytes=int(min(max(need, 16 << 20), 100 << 20)))
+
+
+def _xent_fwd_call(logits, labels, block_n, interpret):
+    # no pad: the last block of a grid that does not divide N reads rows
+    # past the end (unspecified values, each row's own) and its writes there
+    # are dropped
+    n, v = logits.shape
+    block_n = _xent_rows(logits, block_n)
+    row = lambda i: (i, 0)
+    return pl.pallas_call(
         _xent_kernel,
-        grid=(n_pad // block_n,),
+        grid=(pl.cdiv(n, block_n),),
         in_specs=[
-            _vmem_spec((block_n, v), lambda i: (i, 0)),
-            _vmem_spec((block_n, 1), lambda i: (i, 0)),
+            _vmem_spec((block_n, v), row),
+            _vmem_spec((block_n, 1), row),
         ],
         out_specs=[
-            _vmem_spec((block_n, 1), lambda i: (i, 0)),
-            _vmem_spec((block_n, 1), lambda i: (i, 0)),
+            _vmem_spec((block_n, 1), row),
+            _vmem_spec((block_n, 1), row),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((n_pad, 1), jnp.float32),
-            jax.ShapeDtypeStruct((n_pad, 1), jnp.float32),
+            jax.ShapeDtypeStruct((n, 1), jnp.float32),
+            jax.ShapeDtypeStruct((n, 1), jnp.float32),
         ],
+        compiler_params=_xent_params(block_n, v, logits.dtype.itemsize),
         interpret=interpret,
         name="ptpu_softmax_xent_fwd",
-    )(lp, lb)
-    return loss[:n], lse[:n]
+    )(logits, labels.reshape(-1, 1).astype(jnp.int32))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
@@ -1015,6 +1042,11 @@ def _xent_core_fwd(logits, labels, block_n, interpret):
 
 
 def _xent_core_bwd(block_n, interpret, res, g):
+    # jax.numpy, so that XLA computes it on the way into the two matmuls
+    # that read dlogits (float32, rounded once to the logits' dtype). A
+    # kernel that wrote dlogits once for both lost to this in every cell
+    # (PERF.md section 6, PR 44): the exponentials hide under the MXU's
+    # time, a finished [N, V] array costs a read and a write in HBM
     logits, labels, lse = res
     p = jnp.exp(logits.astype(jnp.float32) - lse)            # softmax
     onehot = jax.nn.one_hot(labels.reshape(-1), logits.shape[-1],
@@ -1027,12 +1059,15 @@ _xent_core.defvjp(_xent_core_fwd, _xent_core_bwd)
 
 
 def softmax_xent(logits, labels, block_n=None, interpret=None):
-    """Fused log-softmax + NLL. logits [N, V], labels [N] (or [N,1]) int.
-    Returns loss [N, 1] float32. Differentiable (custom_vjp)."""
+    """Fused log-softmax + NLL. logits [N, V] in any float dtype (a tile is
+    cast to float32 in VMEM), labels [N] (or [N,1]) int. Returns loss [N, 1]
+    float32. Differentiable (custom_vjp); dlogits comes in the logits'
+    dtype."""
     if interpret is None:
         interpret = _interpret_default()
     return _xent_core(logits, labels.reshape(-1),
-                      _tile("xent", "block_n", block_n), bool(interpret))
+                      None if block_n is None else int(block_n),
+                      bool(interpret))
 
 
 # ---------------------------------------------------------------------------

@@ -299,6 +299,7 @@ def profile_report(sorted_key=None, json=False):
                    ", ".join("%s=%d" % kv
                              for kv in sorted(ss["by_tag"].items()))))
         lines.extend(_embedding_lines())
+        lines.extend(_softmax_xent_lines())
         lines.extend(_lowering_lines())
     return "\n".join(lines)
 
@@ -314,6 +315,20 @@ def _embedding_lines():
         k = dict(key)
         lines.append("embedding: %d x %s rows of [%s, %s], gradient by %s"
                      % (n, k["rows"], k["vocab"], k["width"], k["grad"]))
+    return lines
+
+
+def _softmax_xent_lines():
+    """One line a kind of softmax_with_cross_entropy lowered: who computes
+    the loss, the dtype its rule read the logits in and whether anything
+    reads the dense Softmax (`ptpu_softmax_xent_layers_total`)."""
+    from .observability.registry import REGISTRY
+    lines = []
+    for key, n in REGISTRY.counter(
+            "ptpu_softmax_xent_layers_total").samples():
+        k = dict(key)
+        lines.append("softmax_xent: %d x loss by %s on %s logits, Softmax %s"
+                     % (n, k["path"], k["logits"], k["softmax"]))
     return lines
 
 

@@ -47,10 +47,17 @@ PHASE_SAYS = {
         and "dk_rope" in line for line in lines) and any(
         "J hyper-connection of 4 streams" in line and "dphi" in line
         and "rows a block" in line for line in lines),
+    # phase K held the loss kernel to the float32 formula and timed one
+    # head in the parent's form and in PR 44's
+    "K": lambda lines: any(
+        "K softmax_xent kernel [" in line and "off the formula" in line
+        for line in lines) and any(
+        "K head [" in line and "now " in line and "parent " in line
+        for line in lines),
 }
 
 
-@pytest.mark.parametrize("letter", "ABCDEFGHIJ")
+@pytest.mark.parametrize("letter", "ABCDEFGHIJK")
 def test_tiny_rehearsal_passes_every_phase(letter):
     """One case a phase (`--phases <letter>`), so that a red run names it."""
     # phase E times the program phase A left

@@ -223,9 +223,10 @@ def _kernel_program():
     return main, startup, loss
 
 
-def _kernel_program_args(main, startup, loss, shard=None):
-    """(fn, args) of the step; `shard` turns the arguments into shapes on a
-    described device."""
+def _kernel_program_args(main, startup, loss, shard=None,
+                         x_shape=(4, 16, 2, 8)):
+    """(fn, args) of the step on a batch `x_shape`; `shard` turns the
+    arguments into shapes on a described device."""
     feeds = ["x", "lab"]
     rw, ro, out = lowering.analyze_state(main, feeds, [loss.name])
     scope = fluid.Scope()
@@ -234,7 +235,8 @@ def _kernel_program_args(main, startup, loss, shard=None):
         vals = {n: np.asarray(scope.find_var(n).get_tensor())
                 for n in set(rw) | set(ro)}
     fn = lowering.build_program_fn(main, feeds, [loss.name], rw, ro, out)
-    args = ([np.zeros((4, 16, 2, 8), "float32"), np.zeros((4, 1), "int32")],
+    args = ([np.zeros(x_shape, "float32"),
+             np.zeros((x_shape[0], 1), "int32")],
             [vals[n] for n in rw], [vals[n] for n in ro])
     if shard is not None:
         args = jax.tree_util.tree_map(
@@ -632,6 +634,47 @@ def test_mosaic_calls_are_named_on_a_described_v5e(one_chip, monkeypatch):
               if lowering.parse_op_scope(m)}
     assert {"layer_norm", "layer_norm_grad", "fused_attention_grad",
             "softmax_with_cross_entropy_grad", "mul_grad"} <= scoped
+
+
+def test_the_loss_reads_the_logits_as_they_come_on_a_described_v5e(
+        one_chip, monkeypatch):
+    """An AMP training step whose head feeds softmax_with_cross_entropy,
+    compiled for a TPU (PR 44): one `ptpu_softmax_xent_fwd`, on the bf16
+    logits the head's matmul wrote, and no second kernel (one that wrote
+    dlogits lost to XLA's fusing it into the two gradient matmuls); no
+    float32 [N, V] array is a result of any instruction of the step (the
+    parent's forward fusion wrote one beside the bf16 logits, for the loss
+    alone), and no instruction comes from a `log_softmax` (the dense
+    Softmax nobody reads was a result of the differentiated function, and
+    XLA kept its sum and that sum's transpose)."""
+    from paddle_tpu.ops import kernel_config
+    monkeypatch.setattr(kernel_config, "dispatch_platform", lambda: "tpu")
+    monkeypatch.setattr(pallas_kernels, "dispatch_platform", lambda: "tpu")
+    n, d, v = 64, 256, 1000
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.unique_name.guard(), fluid.program_guard(main, startup):
+        x = fluid.layers.data(name="x", shape=[d], dtype="float32")
+        lab = fluid.layers.data(name="lab", shape=[1], dtype="int64")
+        h = fluid.layers.fc(input=x, size=d, act="relu")
+        loss = fluid.layers.mean(fluid.layers.softmax_with_cross_entropy(
+            fluid.layers.fc(input=h, size=v, bias_attr=False), lab))
+        fluid.optimizer.SGD(learning_rate=0.1).minimize(loss)
+    main.enable_mixed_precision()
+    fn, args = _kernel_program_args(main, startup, loss, shard=one_chip,
+                                    x_shape=(n, d))
+    text = _compile_uncached(fn, *args).as_text()
+    calls = {m.group(1): m.group(2) for m in re.finditer(
+        r"%?(ptpu_[a-z_]+)[\w.]* = ([^\n]*custom_call_target="
+        r'"tpu_custom_call"[^\n]*)', text)}
+    assert sorted(calls) == ["ptpu_softmax_xent_fwd"]
+    logits = "bf16[%d,%d]" % (n, v)     # the first operand, as constrained
+    assert "operand_layout_constraints={%s" % logits in \
+        calls["ptpu_softmax_xent_fwd"]
+    entry = text[text.index("ENTRY"):]
+    assert "f32[%d,%d]" % (n, v) not in re.sub(r"\(.*", "", "\n".join(
+        line.split(" = ", 1)[1] for line in entry.splitlines()
+        if " = " in line))
+    assert "log_softmax" not in text
 
 
 @pytest.mark.parametrize("n,d,dtype", [

@@ -195,9 +195,12 @@ _RULES = {
         {"X": [_f32(4, 8, 16)], "Scale": [_f32(16)], "Bias": [_f32(16)]},
         {"begin_norm_axis": 2}, "ln", "tile_bytes", 0, 1024,
         lambda tile_bytes: min(tile_bytes // 64, 32)),
+    # bytes again (PR 44): 40 a float32 row here; 1024 of them are 24 rows,
+    # and the 16 that divide N lie within a factor of two below
     "softmax_with_cross_entropy": (
         {"Logits": [_f32(32, 10)], "Label": [_i32(32, 1)]}, {},
-        "xent", "block_n", 0, 16, int),
+        "xent", "tile_bytes", 0, 1024,
+        lambda tile_bytes: 32 if tile_bytes >= 32 * 40 else 16),
     "sequence_pool": (
         {"X": [_f32(32, 6, 4)], "XLen": [_i32(32)]},
         {"pooltype": "AVERAGE"}, "seq", "block_n", 0, 16, int),

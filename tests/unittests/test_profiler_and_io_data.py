@@ -30,15 +30,15 @@ def test_profiler_records_per_entry_stats(capsys):
     report = profiler.profile_report(sorted_key="calls")
     # the training program entry ran 4 times; startup ran once each
     # 11 numeric columns after the (possibly space-containing) tag; the
-    # "compile cache:" / "host syncs:" / "embedding:" footers are
-    # summaries, not rows (the last one a kind of lookup_table this process
-    # has lowered), and the "Lowering(s) by op type" block after them is
-    # its own table
+    # "compile cache:" / "host syncs:" / "embedding:" / "softmax_xent:"
+    # footers are summaries, not rows (the last two a kind of lookup_table
+    # and of softmax_with_cross_entropy this process has lowered), and the
+    # "Lowering(s) by op type" block after them is its own table
     entries = report[:report.index("Lowering(s) by op type")]
     counts = sorted(int(line.split()[-11]) for line in
                     entries.splitlines()[1:]
                     if not line.startswith(("compile cache:", "host syncs:",
-                                            "embedding:")))
+                                            "embedding:", "softmax_xent:")))
     assert counts[-1] == 4, report
     with pytest.raises(ValueError, match="sorted_key"):
         profiler.profile_report(sorted_key="bogus")
