@@ -230,16 +230,58 @@ def _lin(*terms):
     return total
 
 
+# The running max of a row starts at a finite floor above `_NEG`: a masked
+# score is `_NEG`, so exp(_NEG - floor) is exactly 0 and a row that has met
+# no visible key yet gets p = 0 from the `exp` itself, with no second select
+# on the tile; real scores are far above the floor, so it never wins a max.
+_M_FLOOR = _NEG / 2
+
+
+def _partial_lanes(n):
+    """Lanes of a row sum's partials over blocks of n keys: one lane tile,
+    or n itself where it is no whole lane tiles (the tests' small blocks)."""
+    return n if n % 128 else 128
+
+
+def _lane_sums(p):
+    """[rows, n] -> [rows, `_partial_lanes(n)`]: the lane tiles of p added
+    elementwise (VALU adds of whole vregs, nothing crosses a lane)."""
+    rows, n = p.shape
+    lanes = _partial_lanes(n)
+    total = lax.slice(p, (0, 0), (rows, lanes))
+    for i in range(lanes, n, lanes):
+        total = lax.add(total, lax.slice(p, (0, i), (rows, i + lanes)))
+    return total
+
+
+def _rescaled(ref, corr, term):
+    """ref * corr + term, corr a [rows, 1] column."""
+    return lax.add(lax.mul(ref[...], lax.broadcast_in_dim(
+        corr, ref.shape, (0, 1))), term)
+
+
 def _flash_fwd_kernel(q_ref, k_ref, v_ref, *refs, scale, causal, window,
                       block_q, block_k, t_pad, d, hb, rope=None):
-    """`rope` (dr, hr), the latent form: two operands more, the rotary
+    """One q block's output and logsumexp: the k blocks `_k_blocks` names
+    stream past it. Of the online softmax's state only the running max is
+    carried by the loop; the row sums `l_ref` [bq, 128] and the output's
+    accumulator `acc_ref` [bq, W] are VMEM scratch, read and written once a
+    block. Carried, the three are 192 vregs across the loop's back edge on
+    64 registers, and the compiler moved them from spill slot to spill slot
+    at the loop's head and tail: 275 of a block step's 1,730 bundles in which
+    the MXU had nothing to run, beside the spills inside (AOT compile, PR
+    46). A row's sum is kept as 128 lane partials and reduced across lanes
+    once, after the loop, so that the loop's one cross-lane reduction is the
+    max.
+
+    `rope` (dr, hr), the latent form: two operands more, the rotary
     queries' block [bq, Wr] and the one rotary key all heads share, pinned
     [t_pad, Wr]; a score is the sum of the two products."""
     if rope:
-        qr_ref, kr_ref, len_ref, o_ref, lse_ref = refs
+        qr_ref, kr_ref, len_ref, o_ref, lse_ref, l_ref, acc_ref = refs
         qr = _only(_rope_lanes(qr_ref.shape[1], *rope), qr_ref[...])
     else:
-        len_ref, o_ref, lse_ref = refs
+        len_ref, o_ref, lse_ref, l_ref, acc_ref = refs
     qb = pl.program_id(2)
     bq, w = q_ref.shape
     lanes = _head_lanes(w, d, hb)
@@ -248,9 +290,10 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, *refs, scale, causal, window,
     # whole [B, 1] array lives in SMEM (a (1,1)-blocked spec violates
     # Mosaic's (8,128) block rule — caught on first real-TPU run, round 4)
     kv_len = len_ref[pl.program_id(0), 0]                    # this row's T
+    l_ref[...] = lax.full(l_ref.shape, 0.0, jnp.float32)
+    acc_ref[...] = lax.full(acc_ref.shape, 0.0, jnp.float32)
 
-    def body(kb, carry):
-        m, l, acc = carry
+    def body(kb, m):
         k = k_ref[pl.ds(kb * block_k, block_k), :]
         v = v_ref[pl.ds(kb * block_k, block_k), :]
         if rope:
@@ -263,21 +306,23 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, *refs, scale, causal, window,
         valid = _visible(kpos < kv_len, qpos, kpos, causal, window)
         s = jnp.where(valid, s, _NEG)
         m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        p = jnp.where(valid, p, 0.0)                         # masked -> 0
+        p = jnp.exp(s - m_new)                               # masked -> 0
         corr = jnp.exp(m - m_new)
-        l = l * corr + jnp.sum(p, axis=-1, keepdims=True)
-        acc = acc * corr + _dot(p.astype(v.dtype), v)
-        return m_new, l, acc
+        l_ref[...] = _rescaled(l_ref, corr, _lane_sums(p))
+        acc_ref[...] = _rescaled(acc_ref, corr, _dot(p.astype(v.dtype), v))
+        return m_new
 
-    m, l, acc = lax.fori_loop(
+    m = lax.fori_loop(
         *_k_blocks(qb, kv_len, causal, window, block_q, block_k, t_pad), body,
-        (jnp.full((bq, 1), _NEG, jnp.float32),
-         jnp.zeros((bq, 1), jnp.float32),
-         jnp.zeros((bq, w), jnp.float32)))
+        lax.full((bq, 1), _M_FLOOR, jnp.float32))
 
+    l = lax.broadcast_in_dim(lax.reduce_sum(l_ref[...], (1,)), (bq, 1), (0,))
+    # a row that saw no key at all (kv_len 0, padding past a window): out 0
+    # and the lse a max still at `_NEG` gives
+    m = lax.select(lax.gt(l, lax.full_like(l, 0.0)), m,
+                   lax.full_like(m, _NEG))
     l_safe = jnp.maximum(l, 1e-30)
-    _put(lanes, o_ref, (acc / l_safe).astype(o_ref.dtype))
+    _put(lanes, o_ref, (acc_ref[...] / l_safe).astype(o_ref.dtype))
     lse_ref[0, 0] = _as_row(m + jnp.log(l_safe))             # [1, bq]
 
 
@@ -339,7 +384,10 @@ def _flash_fwd(q, k, v, kv_len, d, hb, scale, causal, window, block_q,
     around the op give and take: with [B, T, H*D] operands XLA laid the
     neighbouring matmuls' operands out tokens-minor, and in the OLMoE cell,
     at its memory limit, scheduled and rematerialised its way to a step 15
-    ms slower (my chip run and AOT compile, PR 38)."""
+    ms slower (my chip run and AOT compile, PR 38). Beside the blocks a grid
+    step has two float32 scratch arrays, the kernel's own state across its k
+    loop: the row sums as lane partials [block_q, 128] and the output's
+    accumulator [block_q, W], 0.25 MiB each at 512 rows and W=128."""
     rows, t, hd = q.shape
     heads, w = hd // d, hb * d
     kv_b, kv_h = _kv_row(rows, k.shape[0]), _kv_row(heads, k.shape[2] // d)
@@ -389,6 +437,10 @@ def _flash_fwd(q, k, v, kv_len, d, hb, scale, causal, window, block_q,
             jax.ShapeDtypeStruct((rows * heads, nq, 1, block_q),
                                  jnp.float32),
         ],
+        # the row sums' lane partials and the output's accumulator
+        scratch_shapes=[
+            pltpu.VMEM((block_q, _partial_lanes(block_k)), jnp.float32),
+            pltpu.VMEM((block_q, w), jnp.float32)],
         interpret=interpret,
         name="ptpu_flash_fwd",
     )(q, k, v, *rope_args, kv_len.reshape(rows, 1).astype(jnp.int32))

@@ -944,6 +944,46 @@ def test_flash_kernels_index_heads_in_place_on_a_described_v5e(
         assert np.prod([int(n) for n in shape.split(",")]) < big, shape
 
 
+# (B, T, Hq, Hkv, D, the latent form's rotary width): the two cells' flash
+# shapes `_FLASH_CELLS` does not have, a row a head through `_to_bh` and the
+# latent form with its pinned rotary key
+_FLASH_FORWARD_CELLS = {
+    "lfm2_t8192": (1, 8192, 32, 8, 64, 0),
+    "xing4_0_t4096": (1, 4096, 32, 32, 128, 64),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(_FLASH_FORWARD_CELLS))
+def test_flash_kernels_fit_vmem_at_the_other_cells_shapes(one_chip, cell):
+    """The three kernels at the default 512 x 512 blocks where the forward
+    pins the most beside its tiles: the row sums' lane partials and the
+    output's accumulator are VMEM scratch (PR 46), and no limit is raised
+    for them."""
+    b, t, h, hkv, d, dr = _FLASH_FORWARD_CELLS[cell]
+
+    def forward(q, k, v, *rope):
+        return pallas_kernels.flash_attention(
+            q, k, v, causal=True, interpret=False,
+            **dict(zip(("q_rope", "k_rope"), rope)))
+
+    def both(*operands):
+        out, vjp = jax.vjp(forward, *operands)
+        return (out,) + vjp(out)
+    args = [jax.ShapeDtypeStruct((b, t, n, w), jnp.bfloat16,
+                                 sharding=one_chip)
+            for n, w in ((h, d), (hkv, d), (hkv, d))
+            + (((h, dr), (1, dr)) if dr else ())]
+    text = _compile_uncached(both, *args).as_text()
+    # with no op scope around it jax names the call under vjp
+    # jvp_ptpu_flash_fwd_
+    kernels = sorted(re.search(r"ptpu_flash_[a-z_]*[a-z]", name).group(0)
+                     for name in re.findall(
+        r'%?([\w.\-]+) = [^\n]*custom_call_target="tpu_custom_call"', text))
+    assert kernels == ["ptpu_flash_bwd_dkdv", "ptpu_flash_bwd_dq",
+                       "ptpu_flash_fwd"]
+    assert "vmem_limit" not in text
+
+
 @pytest.mark.parametrize("model,heads", [("transformer", "2"),
                                          ("causal_lm", "1")])
 def test_the_attention_counter_says_how_many_heads_a_block(monkeypatch,

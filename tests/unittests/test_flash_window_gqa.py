@@ -229,3 +229,165 @@ def test_no_layout_pass_around_the_kernels_at_the_cells_shapes(cell):
         for var in list(e.invars) + list(e.outvars):
             assert not (var.aval.shape[-1] == 1 and var.aval.shape[-2] >= t), (
                 e.params["name"], var.aval.shape)
+
+
+# --- the forward kernel's k loop: out AND lse ------------------------------
+
+def _dense_out_and_lse(q, k, v, scale, causal=False, window=None,
+                       kv_len=None, q_rope=None, k_rope=None):
+    """Float32 dense attention and its logsumexp [B, H, T] with the
+    kernels' convention for a query that sees no key: out 0 and the lse a
+    running max still at -1e30 gives."""
+    q, k, v = (np.asarray(x, np.float32) for x in (q, k, v))
+    b, t, hq, _ = q.shape
+    k, v = (np.repeat(x, hq // x.shape[2], axis=2) for x in (k, v))
+    s = np.einsum("bqhd,bkhd->bhqk", q, k)
+    if q_rope is not None:
+        s = s + np.einsum("bqhd,bkd->bhqk", np.asarray(q_rope, np.float32),
+                          np.asarray(k_rope, np.float32)[:, :, 0])
+    s = s * np.float32(scale)
+    i, j = np.arange(t)[:, None], np.arange(t)[None, :]
+    seen = np.ones((b, 1, t, t), bool)
+    if causal:
+        seen = seen & (i >= j)
+    if window is not None:
+        seen = seen & (i - j < window)
+    if kv_len is not None:
+        seen = seen & (j[None, None] < np.asarray(kv_len)[:, None, None,
+                                                          None])
+    s = np.where(seen, s, -np.inf)
+    some = seen.any(-1)                                      # [B, 1, T]
+    m = np.where(some, s.max(-1, initial=-np.inf), 0.0)
+    p = np.exp(s - m[..., None])
+    l = p.sum(-1)
+    out = np.einsum("bhqk,bkhd->bqhd", p / np.maximum(l, 1e-30)[..., None],
+                    v)
+    lse = np.where(some, m + np.log(np.maximum(l, 1e-30)), np.float32(-1e30))
+    return out, np.broadcast_to(lse, (b, hq, t))
+
+
+# id: (T, Hq, Hkv, D, block_q, block_k, kwargs, dtype); `rope`: the latent
+# form's rotary width
+_FORWARD = {
+    # prologue and last step are the whole loop: one k block a q block
+    "one_block_full": (16, 2, 2, 16, 16, 16, dict(), "float32"),
+    "one_block_a_q_block_causal": (64, 2, 2, 16, 16, 64,
+                                   dict(causal=True), "float32"),
+    "one_block_under_a_window": (64, 2, 2, 16, 16, 16,
+                                 dict(causal=True, window=1), "float32"),
+    # rows 20.. of q block 1 see no key of its first k block (keys 8..15)
+    "window_first_block_hides_every_key": (
+        48, 2, 2, 16, 16, 8, dict(causal=True, window=4), "float32"),
+    "window_without_causal": (48, 2, 1, 16, 16, 8, dict(window=5),
+                              "float32"),
+    "no_keys_at_all": (32, 2, 2, 16, 16, 8, dict(kv_len=[0, 32]),
+                       "float32"),
+    "no_keys_causal": (32, 2, 2, 16, 16, 8,
+                       dict(causal=True, kv_len=[0, 5]), "float32"),
+    # a window that ends before the padded rows start: they see nothing
+    "padded_rows_past_the_window": (48, 2, 2, 16, 16, 8,
+                                    dict(window=6, kv_len=[9, 30]),
+                                    "float32"),
+    "t_no_multiple_of_the_blocks": (40, 3, 3, 16, 16, 8,
+                                    dict(causal=True), "float32"),
+    "t_no_multiple_mismatched_blocks": (50, 2, 2, 16, 16, 24,
+                                        dict(kv_len=[50, 17]), "float32"),
+    "two_heads_a_block": (40, 4, 4, 64, 16, 16,
+                          dict(causal=True, kv_len=[40, 21]), "float32"),
+    "grouped_in_place": (48, 7, 1, 128, 16, 16,
+                         dict(causal=True, window=20), "float32"),
+    "grouped_transposed": (40, 4, 2, 64, 16, 8, dict(causal=True),
+                           "float32"),
+    "lane_partials_bf16": (256, 2, 2, 64, 128, 256, dict(causal=True),
+                           "bfloat16"),
+    "lane_partials_ragged_keys": (256, 1, 1, 128, 128, 128,
+                                  dict(kv_len=[256, 130, 0]), "float32"),
+    "latent": (96, 4, 4, 128, 32, 32, dict(causal=True, rope=64),
+               "float32"),
+    "latent_no_keys": (64, 2, 2, 128, 32, 32,
+                       dict(causal=True, rope=64, kv_len=[0, 40]),
+                       "float32"),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_FORWARD))
+def test_forward_out_and_lse_against_float32_dense_attention(case):
+    """`out` and `lse` of the forward kernel alone, where its k loop could
+    break: a loop of one step, a first block that hides every key from some
+    rows (their running max is still at its floor when the next block
+    comes), rows with no key at all (out 0, lse -1e30 as always), padded T,
+    two heads a lane block, grouped heads both ways, blocks of whole lane
+    tiles (the row sums' lane partials) and the latent form."""
+    from paddle_tpu.ops import pallas_kernels as pk
+    t, hq, hkv, d, bq, bk, kw, dtype = _FORWARD[case]
+    kw = dict(kw)
+    dr = kw.pop("rope", None)
+    b = len(kw["kv_len"]) if "kv_len" in kw else 2
+    rng = np.random.RandomState(len(case))
+    q = jnp.asarray(rng.randn(b, t, hq, d), dtype)
+    k, v = (jnp.asarray(rng.randn(b, t, hkv, d), dtype) for _ in range(2))
+    rope = None
+    if dr:
+        rope = (jnp.asarray(rng.randn(b, t, hq, dr), dtype),
+                jnp.asarray(rng.randn(b, t, 1, dr), dtype))
+    scale = 1.0 / float(np.sqrt(d + (dr or 0)))
+    kv_len = jnp.asarray(kw.pop("kv_len", [t] * b), jnp.int32)
+    causal, window = kw.get("causal", False), kw.get("window")
+    out, res = pk._flash_core_fwd(q, k, v, rope, kv_len, scale, causal,
+                                  window, bq, bk, True)
+    lse = res[-1]
+    assert out.shape == q.shape and out.dtype == q.dtype
+    t_pad = pk._pad_t(t, bq, bk)
+    assert lse.shape == (b * hq, t_pad // bq, 1, bq) \
+        and lse.dtype == jnp.float32
+    want_out, want_lse = _dense_out_and_lse(
+        q, k, v, scale, causal, window, kv_len,
+        *(rope or ()))
+    tol = 2e-5 if dtype == "float32" else 1e-2
+    assert _error(out, want_out) < tol, _error(out, want_out)
+    got_lse = np.asarray(lse).reshape(b, hq, t_pad)[:, :, :t]
+    some = want_lse > -1e29
+    np.testing.assert_allclose(got_lse[some], want_lse[some], rtol=2e-5,
+                               atol=2e-5)
+    np.testing.assert_array_equal(got_lse[~some], np.float32(-1e30))
+    empty = np.broadcast_to(~some.transpose(0, 2, 1)[..., None], out.shape)
+    np.testing.assert_array_equal(np.asarray(out, np.float32)[empty], 0.0)
+
+
+@pytest.mark.parametrize("form", ["plain", "latent"])
+def test_the_forward_loop_reduces_across_lanes_once_and_selects_once(form):
+    """The k loop of `ptpu_flash_fwd`, as jax traces it: one reduction along
+    the keys of a [bq, bk] tile (the row max; the row sums stay lane
+    partials until the loop is over) and one select on the tile (the mask
+    on the scores; the `exp` of a masked score is 0 by itself), and the
+    loop carries the running max alone."""
+    from paddle_tpu.ops import pallas_kernels as pk
+    bq = bk = 256
+    q = jnp.zeros((1, 512, 2, 128), jnp.bfloat16)
+    kw = {}
+    if form == "latent":
+        kw = dict(q_rope=jnp.zeros((1, 512, 2, 64), jnp.bfloat16),
+                  k_rope=jnp.zeros((1, 512, 1, 64), jnp.bfloat16))
+    jaxpr = jax.make_jaxpr(lambda q: pk.flash_attention(
+        q, q, q, causal=True, block_q=bq, block_k=bk, interpret=True,
+        **kw))(q)
+    loops, found = [], {}
+
+    def walk(j, inside, loop):
+        for e in j.eqns:
+            name = e.primitive.name
+            if loop and name in ("reduce_max", "reduce_sum", "select_n") \
+                    and e.invars[-1].aval.shape == (bq, bk):
+                found[name] = found.get(name, 0) + 1
+            if name == "pallas_call":
+                inside = e.params["name"] == "ptpu_flash_fwd"
+            if inside and name == "while":
+                loops.append(e)
+            for sub in jax.core.jaxprs_in_params(e.params):
+                walk(sub, inside, loop or (inside and name == "while"))
+    walk(jaxpr.jaxpr, False, False)
+    assert len(loops) == 1
+    assert found == {"reduce_max": 1, "select_n": 1}, found
+    carried = [v.aval.shape for v in loops[0].params["body_jaxpr"].jaxpr
+               .outvars if getattr(v.aval, "shape", ()) != ()]
+    assert carried == [(bq, 1)], carried

@@ -109,6 +109,11 @@ def bench_softmax_xent(n=8192, v=32000):
 
 
 _SWEEP_SHAPES = "8x2048x8x8x64x0,8x2048x8x8x64x1,4x4096x16x16x128x1"
+# MXU products a [bq, bk] tile costs in the forward, dQ and dK/dV kernels:
+# q k^T and p v; q k^T, dO v^T and ds k; those two scores and ds^T q, p^T
+# dO. The latent form adds the rotary product to the scores of all three
+# and a rotary gradient to each backward kernel.
+_PRODUCTS = {False: (2, 3, 4), True: (3, 5, 6)}
 _SWEEP_BLOCKS = tuple((bq, bk) for bq in (128, 256, 512, 1024)
                       for bk in (128, 256, 512, 1024))
 
@@ -118,10 +123,16 @@ def sweep_flash_blocks(shapes=_SWEEP_SHAPES, blocks=_SWEEP_BLOCKS,
     """ms a call of each of the three flash kernels alone and of
     `flash_attention` whole, forward and forward + backward, so that what
     lies around the kernels is read off one line; over block_q x block_k at
-    the shapes the benchmark's cells run (BxTxHqxHkvxDxcausal); and each
-    result's largest error against dense float32 attention on the same
-    rounded inputs (first sequence). A pair Mosaic refuses is a line with
-    its error. The kernels take the op's arrays as [B, T, H*D] (or a row a
+    the shapes the benchmark's cells run (BxTxHqxHkvxDxcausal, and a
+    seventh number for the latent form's rotary width: 1x4096x32x32x128x1x64
+    is the Xing4.0 cell's); and each result's largest error against dense
+    float32 attention on the same rounded inputs (first sequence; null
+    where its [H, T, T] float32 scores do not fit the chip). Beside a
+    kernel's ms a call stands its ms a product (`_PRODUCTS`: the three share
+    their tiles and their one `exp` an element and differ in the MXU
+    products a tile costs, so a kernel that pays more for a product than
+    the others is the one that is not bound by them). A pair Mosaic refuses
+    is a line with its error. The kernels take the op's arrays as [B, T, H*D] (or a row a
     head: `heads_a_block`, on the line). Under jit a kernel whose result is
     dropped is dead code, so `_flash_bwd`'s dq alone times the dQ kernel;
     dK/dV reads the delta rows the dQ kernel writes, so (dk, dv) alone
@@ -132,42 +143,58 @@ def sweep_flash_blocks(shapes=_SWEEP_SHAPES, blocks=_SWEEP_BLOCKS,
     from paddle_tpu.parallel.ring_attention import attention_reference
 
     for spec in shapes.split(","):
-        b, t, h, hkv, d, causal = (int(x) for x in spec.split("x"))
-        causal, scale = bool(causal), 1.0 / float(np.sqrt(d))
+        b, t, h, hkv, d, causal, *dr = (int(x) for x in spec.split("x"))
+        dr = dr[0] if dr else 0
+        causal, scale = bool(causal), 1.0 / float(np.sqrt(d + dr))
         rng = np.random.RandomState(0)
-        q, g = (jnp.asarray(rng.randn(b, t, h, d).astype("f") * 0.5,
-                            dtype=dtype) for _ in range(2))
-        k, v = (jnp.asarray(rng.randn(b, t, hkv, d).astype("f") * 0.5,
-                            dtype=dtype) for _ in range(2))
+
+        def normal(heads, width):
+            return jnp.asarray(rng.randn(b, t, heads, width).astype("f")
+                               * 0.5, dtype=dtype)
+        q, g, k, v = normal(h, d), normal(h, d), normal(hkv, d), \
+            normal(hkv, d)
+        rope = (normal(h, dr), normal(1, dr)) if dr else ()
         hb = pk.heads_a_block(h, hkv, d)
         lens = jnp.full((b if hb else b * h,), t, jnp.int32)
         rows = [pk._rows(x, hb) for x in (q, k, v, g)]
+        flat = pk._rope_flat(rope or None)
 
-        def dense(q, k, v):
-            return attention_reference(
-                *(x.astype(jnp.float32) for x in (q, k, v)), causal=causal)
-        ref_o, vjp = jax.vjp(dense, q[:1], k[:1], v[:1])
-        refs = (ref_o,) + vjp(g[:1].astype(jnp.float32))
+        def dense(q, k, v, *rope):
+            q, k, v, *rope = (x.astype(jnp.float32)
+                              for x in (q, k, v) + rope)
+            if rope:            # a head is [its own part; the shared key]
+                q = jnp.concatenate([q, rope[0]], -1)
+                k = jnp.concatenate([k, jnp.repeat(rope[1], h, 2)], -1)
+            return attention_reference(q, k, v, causal=causal, scale=scale)
+        try:
+            ref_o, vjp = jax.vjp(dense, q[:1], k[:1], v[:1],
+                                 *(x[:1] for x in rope))
+            refs = (ref_o,) + vjp(g[:1].astype(jnp.float32))
+        except Exception:  # noqa: BLE001 — [H, T, T] float32 did not fit
+            refs = None
 
         for bq, bk in blocks:
             if bq > t or bk > t:
                 continue
             fwd = jax.jit(lambda q, k, v, bq=bq, bk=bk: pk._flash_fwd(
                 q, k, v, lens, d, hb or 1, scale, causal, None, bq, bk,
-                False))
+                False, flat))
 
             def bwd(q, k, v, o, lse, g, bq=bq, bk=bk):
                 return pk._flash_bwd(d, hb or 1, scale, causal, None, bq,
-                                     bk, False, (q, k, v, lens, o, lse), g)
+                                     bk, False, (q, k, v, lens, o, lse), g,
+                                     flat)
 
-            def whole(q, k, v, bq=bq, bk=bk):
-                return pk.flash_attention(q, k, v, causal=causal,
-                                          block_q=bq, block_k=bk)
+            def whole(q, k, v, *rope, bq=bq, bk=bk):
+                return pk.flash_attention(
+                    q, k, v, causal=causal, block_q=bq, block_k=bk,
+                    scale=scale, **dict(zip(("q_rope", "k_rope"), rope)))
 
-            def whole_bwd(q, k, v, g):
-                out, vjp = jax.vjp(whole, q, k, v)
+            def whole_bwd(q, k, v, g, *rope):
+                out, vjp = jax.vjp(whole, q, k, v, *rope)
                 return (out,) + vjp(g)
-            line = {"kernel": "flash_sweep", "shape": [b, t, h, hkv, d],
+            line = {"kernel": "flash_sweep",
+                    "shape": [b, t, h, hkv, d] + ([dr] if dr else []),
                     "heads_a_block": hb or "transposed", "causal": causal,
                     "dtype": dtype, "block_q": bq, "block_k": bk,
                     "device": str(jax.devices()[0])}
@@ -177,8 +204,8 @@ def sweep_flash_blocks(shapes=_SWEEP_SHAPES, blocks=_SWEEP_BLOCKS,
                 for name, fn, args in (
                         ("dq_ms", lambda *a: bwd(*a)[:1], None),
                         ("dq_dkdv_ms", lambda *a: bwd(*a)[1:], None),
-                        ("op_fwd_ms", whole, (q, k, v)),
-                        ("op_fwd_bwd_ms", whole_bwd, (q, k, v, g))):
+                        ("op_fwd_ms", whole, (q, k, v) + rope),
+                        ("op_fwd_bwd_ms", whole_bwd, (q, k, v, g) + rope)):
                     args = args or (*rows[:3], o, lse, rows[3])
                     line[name] = round(_time(jax.jit(fn), *args), 3)
                 line["dkdv_ms"] = round(line["dq_dkdv_ms"]
@@ -186,11 +213,16 @@ def sweep_flash_blocks(shapes=_SWEEP_SHAPES, blocks=_SWEEP_BLOCKS,
                 line["around_kernels_ms"] = round(
                     line["op_fwd_bwd_ms"] - line["fwd_ms"]
                     - line["dq_dkdv_ms"], 3)
-                got = jax.jit(whole_bwd)(q, k, v, g)
-                line["max_err"] = {
+                line["ms_a_product"] = {
+                    n: round(line[n + "_ms"] / count, 4) for n, count in
+                    zip(("fwd", "dq", "dkdv"), _PRODUCTS[bool(dr)])}
+                got = jax.jit(whole_bwd)(q, k, v, g, *rope)
+                line["max_err"] = refs and {
                     n: round(float(jnp.max(jnp.abs(
                         a[:1].astype(jnp.float32) - r))), 5)
-                    for n, a, r in zip(("o", "dq", "dk", "dv"), got, refs)}
+                    for n, a, r in zip(
+                        ("o", "dq", "dk", "dv", "dq_rope", "dk_rope"), got,
+                        refs)}
             except Exception as e:  # noqa: BLE001 — record, keep sweeping
                 line["error"] = str(e).replace("\n", " ")[-300:]
             print(json.dumps(line), flush=True)
@@ -316,7 +348,7 @@ if __name__ == "__main__":
             if os.environ.get("MB_BLOCKS") else _LN_KIB,
             os.environ.get("MB_DTYPE", "float32"))
     elif os.environ.get("MB_TUNE") == "1":
-        # MB_SHAPES=BxTxHqxHkvxDxcausal[,...], MB_BLOCKS=BQxBK[,...]
+        # MB_SHAPES=BxTxHqxHkvxDxcausal[xdr][,...], MB_BLOCKS=BQxBK[,...]
         sweep_flash_blocks(
             os.environ.get("MB_SHAPES", _SWEEP_SHAPES),
             tuple(tuple(int(x) for x in b.split("x"))
