@@ -28,6 +28,7 @@ import itertools
 import json
 import shutil
 import statistics
+import sys
 import tempfile
 import time
 
@@ -36,6 +37,10 @@ import numpy as np
 from . import manifest
 
 TRACE_SECONDS = 4.0     # how much of the window a --trace 1 run records
+# a fetch of at most this many elements a step (a count an expert, never a
+# crop of the logits) is kept for every step of the window: what the run
+# itself says each step did, for a reader that counts work from it
+KEEP_ELEMENTS = 1 << 12
 COMPILE_REQUEST = "/jax/compilation_cache/compile_requests_use_cache"
 CACHE_HIT = "/jax/compilation_cache/cache_hits"
 # the program draws the seed of every run from the scope's counter, a value
@@ -275,7 +280,7 @@ def _window(jax, fluid, loop, scope, cell, args, spans, counters):
     calls_per_block = max(1, int(cell.traffic["steps_per_block"])
                           // loop.steps_per_call)
     seconds = min(args.seconds, TRACE_SECONDS) if args.trace else args.seconds
-    blocks, scalars, attempted, failed = [], [], 0, 0
+    blocks, scalars, kept, attempted, failed = [], [], {}, 0, 0
     trace_dir = None
     requests_before = counters["compile_requests"]
     with fluid.scope_guard(scope):
@@ -300,6 +305,10 @@ def _window(jax, fluid, loop, scope, cell, args, spans, counters):
                             % (attempted, type(e).__name__, e))
                         break
                     scalars.append(out[loop.names[0]])
+                    for name in loop.names[1:]:
+                        if out[name].size <= KEEP_ELEMENTS \
+                                * loop.steps_per_call:
+                            kept.setdefault(name, []).append(out[name])
                 if failed:
                     break
                 with annotate("bench/block_sync"):
@@ -316,7 +325,11 @@ def _window(jax, fluid, loop, scope, cell, args, spans, counters):
                 jax.profiler.stop_trace()
     compiles = counters["compile_requests"] - requests_before
     scalars = [float(v) for x in scalars for v in np.ravel(np.asarray(x))]
-    return dict(blocks=blocks, scalars=scalars, attempted=attempted,
+    fetches = {name: np.concatenate([
+        np.asarray(x).reshape(loop.steps_per_call, -1) for x in calls])
+        for name, calls in kept.items()}         # [steps, elements] each
+    return dict(blocks=blocks, scalars=scalars, fetches=fetches,
+                attempted=attempted,
                 failed=failed, compiles_in_window=compiles,
                 trace_dir=trace_dir, t_open=t_open)
 
@@ -459,4 +472,10 @@ def run(cell, args, t_process):
             metrics[entry["name"]] = {"value": value, "unit": entry["unit"]}
     result["metrics"] = metrics
     result["device"] = device
+    # what `correct` compared, each number beside its limit as the
+    # configuration's check words it: the line's last key, and the last
+    # lines of standard error, where a run that is not correct is read
+    result["compared"] = {"verdicts": verdicts, "found": found}
+    print("bench: compared: %s\nbench: verdicts: %s"
+          % (found, json.dumps(verdicts)), file=sys.stderr, flush=True)
     return result

@@ -72,6 +72,70 @@ def ops_per_sample(cfg, traffic):
         + 3 * attn_core * 0.5 * cfg["num_hidden_layers"]
 
 
+def flash_kernel_ops(cfg, traffic):
+    """Matmul operations a step of the three flash kernels, every layer
+    causal over the whole sequence (OLMoE: 16 heads of 128, T=4096, batch
+    4), counting only the pairs inside the mask: 4, 8 and 6 x D a pair and
+    query head for the forward, dK/dV and dQ kernels
+    (configs/smallthinker.py has why). Edge blocks compute masked pairs
+    too, so a share of the peak from this cannot pass 100 %."""
+    t, heads = traffic["seq_len"], cfg["num_attention_heads"]
+    head_dim = cfg["hidden_size"] // heads
+    pairs = cfg["num_hidden_layers"] * t * (t + 1) // 2 * traffic["batch"] \
+        * heads
+    return {"ptpu_flash_fwd": 4 * head_dim * pairs,
+            "ptpu_flash_bwd_dkdv": 8 * head_dim * pairs,
+            "ptpu_flash_bwd_dq": 6 * head_dim * pairs}
+
+
+# the matmuls of one gated expert (gate, up, down) and the passes over each
+# (forward, gradient to the rows, gradient to the weights)
+EXPERT_MATMULS = 3
+PASSES = 3
+
+
+def expert_matmul_ops(cfg, traffic, load):
+    """Matmul operations a step of the routed experts held here, counted
+    from the work and not from what implements it: every assignment that a
+    held expert computed is one row through the expert's EXPERT_MATMULS
+    matrices of hidden_size x the expert's width, two operations a
+    multiply-add, PASSES passes. `load` is the `expert_load` fetch, the
+    assignments by expert summed over the routed layers, [E] of one step or
+    [steps, E] of several (then the count is all those steps'; all of the
+    router's columns: the held experts' are taken out
+    here, as `check` does for `dropless`). Rows that a grouped matmul pads
+    a group or a tile with are not counted, nor rows of experts held
+    elsewhere that an implementation walks over, so a share of the peak
+    from this cannot pass 100 %. The router, the gated unit between the
+    matmuls and the permutations around them are not the matmuls' and are
+    not counted.
+    OLMoE holds every expert: 16384 tokens x 8 x 1 layer = 131072
+    assignments x 3 x 3 x 2 x 2048 x 1024 = 4.948e12 whatever the load's
+    shape is."""
+    from paddle_tpu.models.causal_lm import resolve
+    c = resolve(cfg)
+    held = slice(c["first_expert"], c["first_expert"] + c["experts_held"])
+    assignments = int(np.asarray(load, np.int64).reshape(
+        -1, c["num_experts"])[:, held].sum())
+    return PASSES * EXPERT_MATMULS * 2 * c["hidden_size"] \
+        * c["intermediate_size"] * assignments
+
+
+def embedding_grad_bytes(cfg, traffic):
+    """Bytes a step that the embedding's gradient has to move, counted from
+    the work: lookup_table's backward hands the optimizer a dense [V, D]
+    gradient in the table's dtype (float32: the master weight's), so every
+    element of it is written once, and every one of the [tokens, D] rows of
+    the output's gradient is read once, at the same 4 bytes (the sum of a
+    repeated id's rows is float32's). One lookup a step. What sorts the ids
+    and brings the rows into their order (XLA's, around the kernel) moves
+    bytes of its own that no form of the gradient needs; they are not
+    counted. SmallThinker: 37984 x 2560 x 4 = 389.0e6 written + 8192 x 2560
+    x 4 = 83.9e6 read."""
+    tokens = traffic["batch"] * traffic["seq_len"]
+    return 4 * cfg["hidden_size"] * (cfg["vocab_size"] + tokens)
+
+
 def reference(cfg, traffic, params, batch):
     """What `build` fetches, from the plain float32 forward of
     paddle_tpu/models/causal_lm_reference.py on the program's weights, with

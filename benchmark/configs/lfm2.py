@@ -32,6 +32,8 @@ samples_per_step = base.samples_per_step
 # vocab_size is this chip's slice of the published vocabulary (chip 0's,
 # words 0 .. 16383), so ids and labels are drawn from the slice
 make_batch = base.make_batch
+expert_matmul_ops = base.expert_matmul_ops
+embedding_grad_bytes = base.embedding_grad_bytes
 MARGINS = (0.0, 0.02, 0.05, 0.1, 0.2)
 # the two Pallas passes of layers.causal_conv1d, which a short_conv mixer's
 # convolution runs as (between the two gate multiplies, which XLA runs)

@@ -26,6 +26,7 @@ PROBE_COLUMNS = base.PROBE_COLUMNS
 HEAD_ROWS = 1024        # rows of the heads' logits alive at a time
 samples_per_step = base.samples_per_step
 make_batch = base.make_batch
+embedding_grad_bytes = base.embedding_grad_bytes
 
 
 def _resolved(cfg):

@@ -35,6 +35,8 @@ samples_per_step = base.samples_per_step
 # vocab_size is this chip's slice of the published vocabulary (chip 0's,
 # words 0 .. 18991), so ids and labels are drawn from the slice
 make_batch = base.make_batch
+expert_matmul_ops = base.expert_matmul_ops
+embedding_grad_bytes = base.embedding_grad_bytes
 # the held-set margin of configs/smallthinker.py
 _router_margin = shared._router_margin
 MARGINS = (0.0, 0.02, 0.05, 0.1, 0.2)

@@ -31,6 +31,10 @@ samples_per_step = base.samples_per_step
 # vocab_size is this chip's slice of the published vocabulary (chip 0's,
 # words 0 .. 37983), so ids and labels are drawn from the slice
 make_batch = base.make_batch
+# counted from the work, by the sizes `resolve` gives a share too: the rows
+# the 16 held experts computed, and this chip's slice of the vocabulary
+expert_matmul_ops = base.expert_matmul_ops
+embedding_grad_bytes = base.embedding_grad_bytes
 
 
 def _resolved(cfg):

@@ -89,6 +89,27 @@ def ops_per_sample(cfg, traffic):
     return 3 * 2 * weights + 3 * attn_core * cores
 
 
+def flash_kernel_ops(cfg, traffic):
+    """Matmul operations a step of the three flash kernels, counting only
+    the pairs inside the mask, every sequence at full length T: a layer of
+    the encoder attends T x T pairs, a layer of the decoder T (T + 1) / 2 in
+    its causal self-attention and T x T in its cross-attention. A pair costs
+    a head two operations a multiply-add over d_key for each product with q
+    or k and over d_value for each with v or dO: the forward kernel q k^T
+    and p v; dK/dV k q^T, p^T dO, v dO^T and ds^T q; dQ q k^T, dO v^T and
+    ds k (4, 8 and 6 x D at d_key = d_value = D, the count of
+    configs/smallthinker.py). Edge blocks of the causal layers compute
+    masked pairs too, so a share of the peak from this cannot pass 100 %.
+    Below the program's flash crossover (the t256 cell) no such kernel runs
+    and the reader that divides by their time finds nothing."""
+    t, dk, dv = traffic["seq_len"], cfg["d_key"], cfg["d_value"]
+    pairs = cfg["n_layer"] * (t * t + t * (t + 1) // 2 + t * t) \
+        * traffic["batch"] * cfg["n_head"]
+    return {"ptpu_flash_fwd": 2 * (dk + dv) * pairs,
+            "ptpu_flash_bwd_dkdv": 4 * (dk + dv) * pairs,
+            "ptpu_flash_bwd_dq": 2 * (2 * dk + dv) * pairs}
+
+
 def reference(cfg, traffic, params, batch):
     """What `build` fetches, from the plain forward pass in float32: dense
     attention, no AMP, no kernel. Every sequence is full

@@ -32,6 +32,8 @@ samples_per_step = base.samples_per_step
 # vocab_size is chip 0's slice of the published vocabulary (words 0 ..
 # 16383), so ids and labels are drawn from the slice
 make_batch = base.make_batch
+expert_matmul_ops = base.expert_matmul_ops
+embedding_grad_bytes = base.embedding_grad_bytes
 MARGINS = lfm2.MARGINS
 _router_margin = lfm2._router_margin        # the held-set margin on s + b
 # the Pallas passes over the residual streams (ops/mhc_kernels.py)

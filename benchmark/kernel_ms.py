@@ -5,9 +5,28 @@ A `pl.pallas_call(..., name=<kernel>)` compiles to an HLO instruction named
 `<kernel>` or `<kernel>.<n>`, and trace_reduce keeps the instruction's name:
 `record["trace"]["top_ops"]` lists every device operation as
 ["<instruction> <opcode> <target>", seconds a plane over the traced window].
-The kernels' names are the program's (`paddle_tpu/ops/pallas_kernels.py`,
-KERNEL_NAMES). Where the program names no kernel so (the parent of the PR
-that named them), every reader finds nothing and gives None."""
+Where the program names no kernel so (the parent of the PR that named
+them), every reader finds nothing and gives None.
+
+A reader finds a kernel in one of two ways, and both are the program's to
+say (`paddle_tpu/ops/pallas_kernels.py`), never a list kept here:
+
+  by name        a reader that is one kernel's (`flash_fwd_ms_per_step`,
+                 `embedding_grad_ms_per_step`) sums the calls of one entry
+                 of KERNEL_NAMES; the kernel keeps its name through a
+                 rewrite, and the metric follows it;
+  by membership  a reader of some WORK that more than one implementation
+                 may do (`expert_matmul_ms_per_step`: the grouped expert
+                 matmuls, XLA's `ragged-dot*` today) sums, beside XLA's
+                 instructions, every kernel of a tuple in which the program
+                 lists who does that work (EXPERT_MATMUL_KERNELS, each name
+                 in KERNEL_NAMES too; absent or empty while no kernel does).
+                 A PR that brings such a kernel appends its name to the
+                 tuple, at the end of the module, and edits nothing here.
+
+The counts that a roofline share divides by the time are of the work (a
+configuration module's `*_ops` and `*_bytes`), so a share reads the same
+work whoever does it."""
 MOSAIC = ("custom-call", "tpu_custom_call")
 
 

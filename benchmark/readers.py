@@ -2,8 +2,9 @@
 `read(record)`, which returns the metric's value in the unit BENCHMARK.json
 gives it, or None where there is nothing to read; the harness then leaves
 the metric out of the line. `record` is the dict cell.run builds: the host
-spans, the set-up counters, the window's blocks, the memory peak and, in a
-traced run, trace_reduce's summary under "trace"."""
+spans, the set-up counters, the window's blocks (with every step's small
+fetches, [steps, elements] a name, under "window" / "fetches"), the memory
+peak and, in a traced run, trace_reduce's summary under "trace"."""
 import statistics
 
 

@@ -4,7 +4,9 @@
 
 runs one cell of BENCHMARK.json on the machine it is started on and prints,
 as the last line of its standard output, one JSON object: `correct`,
-`attempted`, `failed`, `metrics`, `device` and, traced, `breakdown`. Earlier
+`attempted`, `failed`, `metrics`, `device`, traced `breakdown`, and last
+`compared` (what `correct` compared, each number beside its limit; the same
+words end standard error). Earlier
 lines (prefixed `bench:`) name the platform, the device kind and count, the
 set-up's parts, the window's sample count and quartiles, and the checks.
 

@@ -108,8 +108,10 @@ def test_the_manifest_lists_the_five_for_the_transformer_cells():
                 entry["layer"], entry["moves"]) == (
             "ms", "lower", "device_trace", "kernels",
             "tokens_per_s_per_chip")
-        assert entry["workloads"] == (
-            both if name in ("layer_norm_ms_per_step",
-                             "softmax_xent_ms_per_step") else both[1:])
+        # the decoder cells that run a kernel came behind the two
+        listed = entry["workloads"]
+        assert listed[:2] == both if name in (
+            "layer_norm_ms_per_step", "softmax_xent_ms_per_step") \
+            else listed[0] == both[1] and both[0] not in listed
     cell = manifest.load_cell(os.path.join(ROOT, "BENCHMARK.json"), both[1])
     assert set(READERS) <= {m["name"] for m, _ in cell.metrics["per_layer"]}

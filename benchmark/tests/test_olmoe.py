@@ -114,23 +114,27 @@ def test_configuration_keeps_every_published_number():
     assert cell.traffic["seq_len"] == cfg["max_position_embeddings"]
 
 
-def test_manifest_gains_one_configuration_and_one_cell():
+def test_manifest_holds_the_configuration_and_its_cell():
+    """Later PRs add configurations, cells and readers behind these: the
+    test holds what the manifest promises of this cell, not its length."""
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         m = json.load(f)
-    assert [c["name"] for c in m["configs"]][-1] == "olmoe_1b_7b"
-    assert m["workloads"][-1] == dict(
-        m["workloads"][-1], name=CELL, config="olmoe_1b_7b",
-        traffic="train_t4096", chips=1)
-    assert len(m["workloads"]) == 5
+    assert "olmoe_1b_7b" in [c["name"] for c in m["configs"]]
+    cell = next(w for w in m["workloads"] if w["name"] == CELL)
+    assert cell == dict(cell, config="olmoe_1b_7b", traffic="train_t4096",
+                        chips=1)
     assert sum(w["chips"] == 4 for w in m["workloads"]) == 1
-    assert len(m["workloads"][-1]["why"]) <= 200
+    assert len(cell["why"]) <= 200
     reports = {e["name"] for key in ("end_to_end", "per_layer")
                for e in m[key] if CELL in e.get("workloads", [CELL])}
     assert reports >= {
         "tokens_per_s_per_chip", "mfu", "peak_hbm_gib", "setup_s",
         "pallas_ms_per_step", "flash_fwd_ms_per_step",
         "flash_bwd_dkdv_ms_per_step", "flash_bwd_dq_ms_per_step",
-        "softmax_xent_ms_per_step"}
+        "softmax_xent_ms_per_step", "flash_roofline_share",
+        "expert_matmul_ms_per_step", "expert_matmul_roofline_share",
+        "embedding_grad_ms_per_step", "embedding_grad_roofline_share",
+        "step_mfu"}
     assert "layer_norm_ms_per_step" not in reports     # it has no layer_norm
 
 
