@@ -46,6 +46,18 @@ from a seed, and checks what comes out by the repo's own means:
      on a stream [4096, 4 x 3584] bf16, forward and backward, against the
      plain jax.numpy passes in float32; each part's milliseconds.
 
+  L  the latent core at GLM-4.7-Flash's widths, 20 heads of 192 + 64 on
+     values of 256 ([2, 4096, 20, ..] bf16), in two forms: `whole`, the one
+     `pallas_kernels.flash_attention` runs such a head in (the two parts
+     joined in HBM, the rotary key repeated a head, the plain kernels at 256
+     on 256), and `two_part`, made here and nowhere in the tree (the part
+     without position padded with zero lanes to 256, then the two-part
+     kernels as at equal widths; their dK/dV kernel needs 16.29 MiB of VMEM
+     at 512 x 512 and is run at block_k 256), forward, dq, dk, dv, dq_rope
+     and dk_rope of each against the dense float32 attention, and each
+     form's milliseconds forward and forward + backward, beside the plain
+     kernels on heads that arrive joined (what the join costs).
+
 Every phase that fails makes the exit code non-zero. Timings are printed
 for the next reader, labelled with the device; they are not metrics. The
 last line of stdout is one JSON object, {"ok": ..., "device": {...}}.
@@ -121,6 +133,11 @@ FULL = {
     # values of 128; four streams of 3584
     "latent": dict(t=4096, h=32, d=128, dr=64, streams=4, c=3584,
                    iters=20, tol=3e-2),
+    # the GLM-4.7-Flash cell's core: two sequences of 4096, 20 heads of 192
+    # + 64 on values of 256; the blocks each form runs at
+    "latent_unequal": dict(b=2, t=4096, h=20, d=192, dr=64, dv=256,
+                           blocks={"whole": (512, 512),
+                                   "two_part": (512, 256)}, tol=3e-2),
     # the output head and its loss at the SmallThinker and OLMoE cells:
     # (rows, width, vocabulary); then a ragged N for the kernels alone
     "head": dict(shapes=((8192, 2560, 37984), (16384, 2048, 50304)),
@@ -155,6 +172,9 @@ TINY = {
                   ("a row of 1 KiB", 96, 200, 256)),
     "latent": dict(t=64, h=2, d=128, dr=64, streams=4, c=128, iters=20,
                    tol=3e-2),
+    "latent_unequal": dict(b=2, t=64, h=2, d=192, dr=64, dv=256,
+                           blocks={"whole": (32, 32), "two_part": (32, 32)},
+                           tol=3e-2),
     "head": dict(shapes=((48, 32, 200),), ragged=(40, 200), rows=(16, 32)),
     "barrier": dict(steps=5, rounds=3, tol=0.75),
     "dp_loss_rtol": 2e-2,
@@ -1599,6 +1619,90 @@ def phase_j(smoke):
         raise AssertionError("the mhc kernels are %.2e off" % worst)
 
 
+def phase_l(smoke):
+    """The latent core where the part without position (192) is not the
+    value's width (256), at the GLM-4.7-Flash cell's shape, in the form
+    pallas_kernels.flash_attention runs it in (whole heads) and in the form
+    that lost to it (two_part: padded here to the value's width for the
+    two-part kernels): each against the float32 dense attention on the
+    same rounded inputs, and timed."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.ops import pallas_kernels
+    from paddle_tpu.parallel.ring_attention import attention_reference
+
+    c = smoke.cfg["latent_unequal"]
+    b, t, h, d, dr, dv = (c[k] for k in ("b", "t", "h", "d", "dr", "dv"))
+    keys = jax.random.split(jax.random.key(49), 6)
+    bf = jnp.bfloat16
+    q, k = (jax.random.normal(keys[i], (b, t, h, d), jnp.float32).astype(bf)
+            for i in range(2))
+    v, g = (jax.random.normal(keys[i], (b, t, h, dv), jnp.float32).astype(bf)
+            for i in (2, 3))
+    qr = jax.random.normal(keys[4], (b, t, h, dr), jnp.float32).astype(bf)
+    kr = jax.random.normal(keys[5], (b, t, 1, dr), jnp.float32).astype(bf)
+    scale = (d + dr) ** -0.5
+
+    def flash(form):
+        bq, bk = c["blocks"][form]
+        lanes = [(0, 0)] * 3 + [(0, dv - d if form == "two_part" else 0)]
+        return lambda q, k, v, qr, kr: pallas_kernels.flash_attention(
+            jnp.pad(q, lanes), jnp.pad(k, lanes), v, causal=True,
+            scale=scale, q_rope=qr, k_rope=kr, block_q=bq, block_k=bk)
+
+    def dense(q, k, v, qr, kr):     # float32, a (sequence, head) at once
+        qq = jnp.concatenate([q, qr], -1).astype(jnp.float32)
+        kk = jnp.concatenate([k, jnp.broadcast_to(kr, qr.shape)],
+                             -1).astype(jnp.float32)
+
+        def rows(x):                # [B, T, H, D] -> [B * H, T, D]
+            return x.transpose(0, 2, 1, 3).reshape(b * h, t, -1)
+        with jax.default_matmul_precision("highest"):
+            out = jax.lax.map(
+                lambda xs: attention_reference(
+                    xs[0][None, :, None], xs[1][None, :, None],
+                    xs[2][None, :, None], causal=True, scale=scale)[0, :, 0],
+                (rows(qq), rows(kk), rows(v.astype(jnp.float32))))
+        return out.reshape(b, h, t, dv).transpose(0, 2, 1, 3)
+
+    def both(fn):
+        def run(*args):
+            out, vjp = jax.vjp(fn, *args)
+            return (out,) + vjp(g.astype(out.dtype))
+        return jax.jit(run)
+
+    names = ("out", "dq", "dk", "dv", "dq_rope", "dk_rope")
+    args = (q, k, v, qr, kr)
+    want = both(dense)(*args)
+    worst, found = 0.0, []
+    for form in ("whole", "two_part"):
+        full = both(flash(form))
+        errors = _normalized_errors(names, full(*args), want)
+        worst = max(worst, *errors.values())
+        found.append("%s at blocks %s off by %s; ms forward %.3f, forward + "
+                     "backward %.3f" % (
+                         form, "%d x %d" % c["blocks"][form], ", ".join(
+                             "%s %.2e" % kv for kv in errors.items()),
+                         _in_flight_ms(jax.jit(flash(form)), args),
+                         _in_flight_ms(full, args)))
+    # heads that arrive joined: the plain kernels alone
+    joined = (jnp.concatenate([q, qr], -1),
+              jnp.concatenate([k, jnp.broadcast_to(kr, qr.shape)], -1), v)
+
+    def plain(q, k, v):
+        return pallas_kernels.flash_attention(q, k, v, causal=True,
+                                              scale=scale)
+    found.append("heads of %d arriving joined: ms forward %.3f, forward + "
+                 "backward %.3f" % (dv, _in_flight_ms(jax.jit(plain), joined),
+                                    _in_flight_ms(both(plain), joined)))
+    smoke.say("L latent core at %d + %d on %d, [%d, %d, %d] (tolerance %g, "
+              "against the float32 dense attention): %s"
+              % (d, dr, dv, b, t, h, c["tol"], "; ".join(found)))
+    if not worst <= c["tol"]:
+        raise AssertionError("the latent core at unequal widths is %.2e off"
+                             % worst)
+
+
 def phase_k(smoke):
     """One output head with its loss, forward + backward (the matmul, the
     loss, dX and dW), bf16 operands as under AMP, in two forms of
@@ -1719,7 +1823,8 @@ PHASES = (("A", "ResNet-50 training", phase_a),
           ("H", "LFM2's gated convolution and sigmoid router", phase_h),
           ("I", "the embedding's backward", phase_i),
           ("J", "latent attention and hyper-connections", phase_j),
-          ("K", "the output head and its loss", phase_k))
+          ("K", "the output head and its loss", phase_k),
+          ("L", "the latent core at 192 + 64 on 256", phase_l))
 
 
 def main(argv=None):
@@ -1727,7 +1832,7 @@ def main(argv=None):
     ap.add_argument("--tiny", action="store_true",
                     help="CPU rehearsal at toy sizes (needs "
                          "JAX_PLATFORMS=cpu)")
-    ap.add_argument("--phases", default="ABCDEFGHIJK",
+    ap.add_argument("--phases", default="ABCDEFGHIJKL",
                     help="letters of the phases to run (default all)")
     args = ap.parse_args(argv)
 

@@ -582,6 +582,13 @@ PASS_MARK = "pass:"
 # multiplies of models/causal_lm.py:short_conv): the type `op_scope` names
 # the op by. A grad op has it with its forward op's attributes.
 SCOPE_ATTR = "__scope__"
+# the attribute a model's builder writes on the ops of a module that is not
+# part of its stack of layers (models/causal_lm.py's multi-token-prediction
+# module, "mtp.0"): `op_scope` puts it before the op's instance,
+# "op:<type>/mtp.0.<first output>", so the table by instance tells the
+# module's device time from the trunk's. A grad op has it with its forward
+# op's attributes.
+ROLE_ATTR = "__role__"
 _PASS_RE = re.compile(r"(?:^|[/(])" + PASS_MARK + r"(\d+(?:-\d+)?)/")
 
 
@@ -598,13 +605,16 @@ def scope_type(op):
 
 def op_scope(op):
     """The named scope of one fluid op: "op:<type>/<instance>", under
-    "pass:<passes>/" where the op runs passes of a looped stack."""
+    "pass:<passes>/" where the op runs passes of a looped stack; the
+    instance behind "<role>." where the model gave the op one (ROLE_ATTR)."""
     instance = next((n for names in op.outputs.values() for n in names if n),
                     "-")
-    scope = "%s%s/%s" % (SCOPE_MARK, scope_type(op),
-                         instance.translate(_SCOPE_ESCAPES))
     attrs = op.attrs.get("fwd_attrs", ()) if op.type == "grad_of" \
         else op.attrs
+    if ROLE_ATTR in attrs:
+        instance = "%s.%s" % (attrs[ROLE_ATTR], instance)
+    scope = "%s%s/%s" % (SCOPE_MARK, scope_type(op),
+                         instance.translate(_SCOPE_ESCAPES))
     if PASS_ATTR in attrs:
         return "%s%s/%s" % (PASS_MARK, attrs[PASS_ATTR], scope)
     return scope
@@ -897,7 +907,7 @@ def _count_moe_layer(attrs, ins):
 def _count_attention_layer(ctx, attrs, ins):
     from ..observability.registry import REGISTRY
     from ..ops.kernel_config import flash_at
-    from ..ops.pallas_kernels import heads_a_block
+    from ..ops.pallas_kernels import heads_a_block, latent_form
     q, k = ins["Q"][0], ins["K"][0]
     window = attrs.get("window")
     if ctx.mesh is not None and ctx.mesh.shape.get("sp", 1) > 1:
@@ -913,9 +923,20 @@ def _count_attention_layer(ctx, attrs, ins):
     latent = {}
     if ins.get("QRope"):
         q_rope, k_rope = ins["QRope"][0], ins["KRope"][0]
-        latent = dict(form="latent", v_dim=str(ins["V"][0].shape[3]),
+        v_dim = ins["V"][0].shape[3]
+        latent = dict(form="latent", v_dim=str(v_dim),
                       rope_dim=str(q_rope.shape[3]),
                       rope_key_group=str(q_rope.shape[2] // k_rope.shape[2]))
+        if v_dim != q.shape[3]:
+            # a part without position that is not the value's width: the
+            # form the flash kernels run the head in is one more label, and
+            # the lane blocks are the whole head's where it is joined
+            core = latent_form(q.shape[3], q_rope.shape[3], v_dim) \
+                if path == "flash" else "dense"
+            latent["core"] = core
+            if core == "whole":
+                heads = heads_a_block(q.shape[2], k.shape[2], v_dim) \
+                    or "transposed"
     REGISTRY.counter(
         "ptpu_attention_layers_total",
         "fused_attention ops lowered (forward ops, not a grad op's replay), "
@@ -925,7 +946,10 @@ def _count_attention_layer(ctx, attrs, ins):
         "index in one lane block (or transposed); the latent form besides "
         "by form=latent, the value's width, the rotary part's width "
         "(head_dim is then the part without position) and the query heads "
-        "that read one rotary key"
+        "that read one rotary key, and, where the value is not as wide as "
+        "the part without position (192 + 64 on 256), by core, the form the "
+        "head runs in: whole (the two parts joined, the plain kernels at "
+        "v_dim) or dense"
     ).inc(kind="full" if window is None else "window",
           window=str(window or 0), q_heads=str(q.shape[2]),
           kv_heads=str(k.shape[2]), path=path, head_dim=str(q.shape[3]),

@@ -715,8 +715,10 @@ def fused_attention(q, k, v, causal=False, scale=None, kv_len=None,
     The latent form: q_rope [B, T, Hq, dr] and k_rope [B, T, 1, dr], given
     together: a head's score is q . k + q_rope . k_rope, so its keys are D
     + dr wide, dr of them one rotary key that all heads read, and its
-    values D (latent attention's head of 192 on values of 128). `scale`
-    defaults to 1 / sqrt(D + dr)."""
+    values D (latent attention's head of 192 on values of 128) or, v [B, T,
+    Hkv, dv], another width (192 + 64 on values of 256: the result is then
+    [B, T, Hq, dv]; ops/pallas_kernels.py latent_form has the form the
+    kernels run it in). `scale` defaults to 1 / sqrt(D + dr)."""
     if (q_rope is None) != (k_rope is None):
         raise ValueError("fused_attention takes q_rope and k_rope together")
     if sp_impl not in ("ring", "ulysses"):
@@ -744,7 +746,8 @@ def fused_attention(q, k, v, causal=False, scale=None, kv_len=None,
         type="fused_attention", inputs=inputs,
         outputs={"Out": [out]}, attrs=attrs)
     if q.shape is not None:
-        out.shape = tuple(q.shape)
+        out.shape = tuple(q.shape) if q_rope is None or v.shape is None \
+            else tuple(q.shape[:-1]) + (v.shape[-1],)
     return out
 
 

@@ -101,8 +101,8 @@ greedy: none; others refused), n_shared_experts (so many SwiGLUs of
 moe_intermediate_size every token passes, as one of their summed width,
 ungated: shared_expert_gate false, where Qwen3-Next's is gated), n_group
 and topk_group (1; a group limit is refused), moe_layer_freq (1),
-num_nextn_predict_layers (0: multi-token prediction is built by nothing and
-any other value is refused), ep_size and max_position_embeddings (not
+num_nextn_predict_layers (0, or 1: one multi-token-prediction module,
+below; more are refused), ep_size and max_position_embeddings (not
 read); hc_mult (the residual streams n; 1: the plain residual), where n > 1
 hc_sinkhorn_iters, hc_eps, mhc_h_res_clamp_min and mhc_h_res_clamp_max (the
 hyper-connections': layers.mhc_pre has the equations); a looped stack over
@@ -113,7 +113,9 @@ router's renormalisation adds to the chosen scores' sum; null: LFM2's 1e-6;
 DeepSeek-V3's 1e-20), `hc_alpha_init` and `hc_res_diag_init` (the
 hyper-connections' start, `hyper_connection`: 0.5 and 1.0 unless given, a
 model whose coefficients differ from token to token; arXiv:2512.24880
-starts at alpha 0.01 with H_res near the identity). `model_type`
+starts at alpha 0.01 with H_res near the identity). `mtp_loss_weight`
+(lambda, the weight of the module's loss term; 0.3, DeepSeek-V3's for most
+of its pre-training). `model_type`
 is not read: a config says what it builds by these keys. SmallThinker's own
 names are mapped onto these:
 moe_ffn_hidden_size (intermediate_size), moe_num_primary_experts
@@ -121,6 +123,28 @@ moe_ffn_hidden_size (intermediate_size), moe_num_primary_experts
 moe_primary_router_apply_softmax (true), rope_layout and
 sliding_window_layout (one 0/1 a layer: rotary on, window on) and
 sliding_window_size.
+
+Multi-token prediction (num_nextn_predict_layers 1; DeepSeek-V3,
+arXiv:2412.19437, section 2.2; GLM-4.7-Flash, `model_type: glm4_moe_lite`):
+one module behind the trunk, outside its count of layers. With s_i the
+state after the last trunk layer and N_f the final norm, the module reads
+x_i = W_eh [N_e(Emb(t_(i+1))); N_h(N_f(s_i))] (the embedding first and the
+normed state, as the family's public inference code has them; W_eh [2 D,
+D], no bias), runs one whole decoder layer of its own on x at the same
+positions (the trunk's layer code at index num_hidden_layers: latent or
+plain attention, then the experts where the model has any, that index
+being past the leading dense layers), and gives logits'_i = W_out N_s(y_i)
+against t_(i+2). `Emb` and `W_out` are the trunk's own parameters, each ONE
+variable with two uses: the embedding's gradient is two scatter-adds summed
+and the head's two matmuls' summed, where core/backward.py accumulates a
+variable's several uses. The loss is L_main + mtp_loss_weight x L_mtp. The
+module is refused over a looped stack, over hc_mult > 1, with a tied head
+and with a pattern by layer (layer_types, rope_layout,
+sliding_window_layout, full_attention_interval): its layer would have no
+entry there. Every op of the module carries its role (`mtp.0`) under
+lowering.ROLE_ATTR, which `op_scope` puts before the op's instance. At the
+weight of 1 a norm starts from, N_h of a normed state is the identity: a
+check that is to see `hnorm` moves its weight off 1 first.
 
 A configuration that is one chip's share of a layer divided over several
 says so under `share`: {"chips": n, "chip": i, "published": {key: the whole
@@ -140,21 +164,24 @@ weight, W_out (gated delta net) or w_in, the convolution's filter, w_out
 b, alpha], post-attention norm, then router, [the expert bias], gate, up,
 down (experts; then the shared expert's gate, up, down and [its sigmoid
 gate's weight]) or gate, up, down (dense), [the FFN's outgoing norm]; final
-norm; [the exit gate's weight and bias]; head (none of its own where it is
-tied). The bracketed ones exist with hc_mult > 1, sandwich_norm,
+norm; [the module's: enorm, hnorm, eh_proj, then its layer's as any
+layer's, then shared_head.norm]; [the exit gate's weight and bias]; head
+(none of its own where it is tied). The bracketed ones exist with hc_mult > 1, sandwich_norm,
 use_expert_bias, shared_expert_gate and exit_gate. A parameter is
 named by layer and role, `layer_<i>.<role>` (`layer_0.wq`,
 `layer_3.experts.w_gate`, `layer_3.experts.expert_bias`,
 `layer_1.attn_hc.phi`, `layer_1.ffn_hc.alpha`, `layer_1.wkv_b`) and
 `embedding`,
-`final_norm`, `exit_gate.w`,
+`final_norm`, the module's `layer_<num_hidden_layers>.<role>` (as a
+checkpoint names them: `.enorm`, `.hnorm`, `.eh_proj`,
+`.shared_head.norm` beside the layer's own), `exit_gate.w`,
 `exit_gate.b`, `head`: the passes of a looped model find their weights by
 name.
 """
 import contextlib
 
 import paddle_tpu as fluid
-from ..core.lowering import PASS_ATTR, SCOPE_ATTR
+from ..core.lowering import PASS_ATTR, ROLE_ATTR, SCOPE_ATTR
 
 DEFAULTS = {
     "num_experts": 0, "num_experts_per_tok": 0, "norm_topk_prob": False,
@@ -176,7 +203,7 @@ DEFAULTS = {
     "mhc_h_res_clamp_min": -30.0, "mhc_h_res_clamp_max": 30.0,
     "hc_alpha_init": 0.5, "hc_res_diag_init": 1.0,
     "num_nextn_predict_layers": 0, "n_group": 1, "topk_group": 1,
-    "moe_layer_freq": 1, "rope_interleaved": False}
+    "moe_layer_freq": 1, "rope_interleaved": False, "mtp_loss_weight": 0.3}
 # the stream every expert bias is drawn from, whatever the program's seed:
 # the draw the LFM2 cell's limits were read under (PERF.md section 4)
 EXPERT_BIAS_SEED = 39
@@ -210,7 +237,9 @@ def resolve(cfg):
     and None) and the per-layer patterns
     `rope_layers`, `window_layers`, `mixer_layers` ("attention",
     "gated_delta" or "short_conv") and `ffn_layers` ("dense" or
-    "experts")."""
+    "experts"), each with one entry more than the trunk has layers where
+    there is a multi-token-prediction module (`mtp_layers` 1): the module's
+    layer, an attention layer with the model's experts."""
     c = dict(DEFAULTS, **cfg)
     for theirs, ours in ALIASES.items():
         if theirs in c:
@@ -237,8 +266,7 @@ def resolve(cfg):
                       ("conv_bias", False),
                       ("moe_primary_router_apply_softmax", True),
                       ("mlp_only_layers", []), ("decoder_sparse_step", 1),
-                      ("early_exit_threshold", 1),
-                      ("num_nextn_predict_layers", 0), ("n_group", 1),
+                      ("early_exit_threshold", 1), ("n_group", 1),
                       ("topk_group", 1), ("moe_layer_freq", 1)):
         if c[key] != want:
             raise NotImplementedError(
@@ -329,6 +357,24 @@ def resolve(cfg):
     if c["exit_gate"] and c["total_ut_steps"] == 1:
         raise ValueError("exit_gate weighs the passes of a looped model; "
                          "total_ut_steps is 1")
+    c["mtp_layers"] = mtp = int(c["num_nextn_predict_layers"])
+    if mtp not in (0, 1):
+        raise NotImplementedError(
+            "causal_lm builds num_nextn_predict_layers 0 or 1 (one "
+            "multi-token-prediction module: a second would read the first's "
+            "state and the tokens two ahead), the config has %r"
+            % (c["num_nextn_predict_layers"],))
+    if mtp:
+        for key, want in (("total_ut_steps", 1), ("hc_mult", 1),
+                          ("tie_word_embeddings", False),
+                          ("full_attention_interval", 1),
+                          ("layer_types", None), ("rope_layout", None),
+                          ("sliding_window_layout", None)):
+            if c.get(key) != want:
+                raise NotImplementedError(
+                    "causal_lm builds a multi-token-prediction module "
+                    "(num_nextn_predict_layers %d) behind a trunk with %s=%r "
+                    "only, the config has %r" % (mtp, key, want, c.get(key)))
     if "head_dim" not in c:
         if c["hidden_size"] % c["num_attention_heads"]:
             raise ValueError("hidden_size %d is not a multiple of %d heads"
@@ -358,13 +404,15 @@ def resolve(cfg):
         if key in c and len(c[key]) < layers:
             raise ValueError("%s has %d entries for %d layers"
                              % (key, len(c[key]), layers))
+    # the module's layer (index `layers`) is one entry more in every pattern
     c["rope_layers"] = [c["rope_theta"] is not None
                         and bool(c.get("rope_layout", [1] * layers)[i])
-                        for i in range(layers)]
+                        for i in range(layers)] \
+        + [c["rope_theta"] is not None] * mtp
     c["window_layers"] = [
         c["sliding_window_size"]
         if c.get("sliding_window_layout", [0] * layers)[i] else None
-        for i in range(layers)]
+        for i in range(layers)] + [None] * mtp
     c["rotary_dim"] = c["qk_rope_head_dim"] if c["latent"] \
         else int(c["head_dim"] * c["partial_rotary_factor"])
     c["rope_inv_freq"], c["rope_table_scale"], c["attention_scale"] = \
@@ -391,7 +439,8 @@ def resolve(cfg):
         c["mixer_layers"] = [LAYER_TYPES[kind] for kind in kinds[:layers]]
     else:
         c["mixer_layers"] = ["attention" if (i + 1) % interval == 0
-                             else "gated_delta" for i in range(layers)]
+                             else "gated_delta" for i in range(layers)] \
+            + ["attention"] * mtp
     if "short_conv" in c["mixer_layers"]:
         if "conv_L_cache" not in c:
             raise ValueError("layer_types has conv layers, which need "
@@ -403,7 +452,8 @@ def resolve(cfg):
                 % c["total_ut_steps"])
     dense = min(int(c["num_dense_layers"]), layers) if c["num_experts"] \
         else layers
-    c["ffn_layers"] = ["dense"] * dense + ["experts"] * (layers - dense)
+    c["ffn_layers"] = ["dense"] * dense + ["experts"] * (layers - dense) \
+        + ["experts" if c["num_experts"] else "dense"] * mtp
     if "gated_delta" in c["mixer_layers"]:
         missing = [key for key in LINEAR_KEYS if key not in c]
         if missing:
@@ -734,7 +784,7 @@ def feed_forward(x, c, router_input=None):
     return _swiglu(x, c["dense_intermediate_size"], c), None
 
 
-def _count_layer(c, mixer):
+def _count_layer(c, mixer, module="trunk"):
     """One count a layer built, by what the model puts around its ops and
     no op can observe (the ops' own counters have the rest: heads, widths,
     paths). A looped model builds a layer once and counts it once."""
@@ -747,9 +797,10 @@ def _count_layer(c, mixer):
         "attention's output, the taps of the convolution before a gated "
         "delta rule or of a short_conv mixer's own (0: none), the FFN's "
         "kind (dense, or routed experts), the width of the shared expert "
-        "beside the routed ones (0: none) and whether each branch is normed "
-        "going out as well as going in (a sandwich)"
-    ).inc(mixer=mixer,
+        "beside the routed ones (0: none), whether each branch is normed "
+        "going out as well as going in (a sandwich) and the module the layer "
+        "belongs to (trunk, or mtp: a multi-token-prediction module's)"
+    ).inc(mixer=mixer, module=module,
           rotary_dim=str(c["rotary_dim"] if attention
                          and c["rope_theta"] is not None else 0),
           gate=str(bool(attention and c["attention_gate"])).lower(),
@@ -791,12 +842,18 @@ def exit_distribution(states, c):
 def causal_lm(cfg, seq_len, extras=None, recompute=True):
     """Build the training graph in the current program guard. Feeds: `ids`
     [B, T] token ids, `pos` [B, T] their positions, `labels` [B, T, 1] the
-    next token at every position. Returns (loss, logits [B, T, V],
-    expert_load): the loss is the mean cross-entropy a position plus
-    router_aux_loss_coef x the layers' mean balance loss plus
+    next token at every position and, with a multi-token-prediction module,
+    a third, `labels_next` [B, T, 1], the token after that. Returns (loss,
+    logits [B, T, V], expert_load): the loss is the mean cross-entropy a
+    position plus router_aux_loss_coef x the layers' mean balance loss plus
     router_z_loss_coef x their mean z loss (neither term is built where
     both coefficients are 0); expert_load [E] int32 sums the layers'
-    assignment counts (None without experts).
+    assignment counts (None without experts), the module's layer among
+    them. With the module the loss has two terms, L_main + mtp_loss_weight
+    x L_mtp, L_mtp the mean cross-entropy of the module's logits (through
+    the trunk's own head) against `labels_next`; `logits` are the trunk's,
+    and `extras` gets `main_loss`, `mtp_loss`, `mtp_logits` [B, T, V] and
+    `mtp_input` [B, T, D], what the module's layer reads.
 
     With total_ut_steps = P > 1 the layers and the final norm are built
     once, as the sub-block of one loop op (a StaticRNN with no step input:
@@ -817,11 +874,20 @@ def causal_lm(cfg, seq_len, extras=None, recompute=True):
     ids = layers.data("ids", [seq_len], dtype="int64")
     pos = layers.data("pos", [seq_len], dtype="int64")
     labels = layers.data("labels", [seq_len, 1], dtype="int64")
-    h = layers.embedding(
-        ids, size=[c["vocab_size"], c["hidden_size"]],
-        param_attr=_attr(c, "embedding", fluid.initializer.Normal(
-            0.0, c.get("embedding_initializer_range",
-                       c["initializer_range"]))))
+    trunk, mtp = c["num_hidden_layers"], c["mtp_layers"]
+
+    def embed(tokens):
+        # asked for again (the module's lookup of the next tokens), the
+        # name gives the parameter the first lookup made
+        return layers.embedding(
+            tokens, size=[c["vocab_size"], c["hidden_size"]],
+            param_attr=_attr(c, "embedding", fluid.initializer.Normal(
+                0.0, c.get("embedding_initializer_range",
+                           c["initializer_range"]))))
+
+    h = embed(ids)
+    ops = fluid.default_main_program().global_block().ops
+    module_ops = []             # [first, end) runs of the module's ops
     # One pass: the layers, then the final norm; a looped model builds it
     # as the sub-block of a loop op. A layer is h + mixer(N1(h)), then that
     # + FFN(N3(.)); with sandwich_norm each branch passes a norm of its own
@@ -842,9 +908,21 @@ def causal_lm(cfg, seq_len, extras=None, recompute=True):
         streams = c["hc_mult"]
         if streams > 1:
             h = layers.mhc_expand(h, streams)
-        for i in range(c["num_hidden_layers"]):
+        for i in range(trunk + mtp):
             cl, mixer = _layer(c, i), c["mixer_layers"][i]
-            _count_layer(cl, mixer)
+            if i == trunk:
+                # the multi-token-prediction module: the trunk's state is
+                # kept, normed, for the trunk's head, and the module's layer
+                # (the layer body below, at index `trunk`) reads W_eh
+                # [N_e(Emb(t_(i+1))); N_h(that)]
+                trunk_state = _norm(h, c, "final_norm")
+                module_ops.append(len(ops))
+                h = mtp_input = _linear(layers.concat([
+                    _norm(embed(layers.reshape(labels, shape=[0, seq_len])),
+                          cl, "enorm"),
+                    _norm(trunk_state, cl, "hnorm")], axis=2),
+                    c["hidden_size"], cl, "eh_proj")
+            _count_layer(cl, mixer, "mtp" if i >= trunk else "trunk")
             if streams > 1:
                 read, coef, h = hyper_connection(h, cl, "attn_hc")
             a = _norm(read if streams > 1 else h, cl, "input_norm")
@@ -871,7 +949,12 @@ def causal_lm(cfg, seq_len, extras=None, recompute=True):
                 aux.append(layer_aux)
         if streams > 1:
             h = layers.mhc_reduce(h, streams)
-        h = _norm(h, c, "final_norm")
+        if mtp:
+            mtp_state = _norm(h, cl, "shared_head.norm")
+            module_ops.append(len(ops))
+            h = trunk_state
+        else:
+            h = _norm(h, c, "final_norm")
         if loop:
             loop.update_memory(state, h)
             loop.output(h)
@@ -888,10 +971,11 @@ def causal_lm(cfg, seq_len, extras=None, recompute=True):
     tied = fluid.default_main_program().global_block().var("embedding") \
         if c["tie_word_embeddings"] else None
 
-    def head(state):
+    def head(state, labels=labels):
         # tied: h E^T on the embedding's own [V, D] parameter; its gradient
         # is the lookup's scatter-add plus this matmul's, summed where
-        # core/backward.py accumulates a variable's several uses
+        # core/backward.py accumulates a variable's several uses. Untied and
+        # run twice (the module's pass), the name gives the one parameter
         logits = layers.matmul(state, tied, transpose_y=True) \
             if tied is not None else _linear(state, c["vocab_size"], c,
                                              "head")
@@ -914,6 +998,22 @@ def causal_lm(cfg, seq_len, extras=None, recompute=True):
     else:
         found, (pass_logits, costs) = {}, zip(head(states[-1]))
         loss = layers.mean(costs[0])
+    if mtp:
+        # L_main + lambda L_mtp: the module's state through the trunk's
+        # head, against the tokens two ahead
+        module_ops.append(len(ops))
+        mtp_logits, mtp_cost = head(
+            mtp_state, layers.data("labels_next", [seq_len, 1],
+                                   dtype="int64"))
+        mtp_loss = layers.mean(mtp_cost)
+        module_ops.append(len(ops))
+        for first, end in zip(module_ops[::2], module_ops[1::2]):
+            for op in ops[first:end]:
+                op.attrs[ROLE_ATTR] = "mtp.0"
+        found.update(main_loss=loss, mtp_loss=mtp_loss,
+                     mtp_logits=mtp_logits, mtp_input=mtp_input)
+        loss = loss + layers.scale(mtp_loss, scale=c["mtp_loss_weight"])
+        _count_module(c)
     logits = pass_logits[-1]
     if extras is not None:
         extras.update(found, pass_logits=list(pass_logits))
@@ -932,8 +1032,23 @@ def _count_head(c):
     REGISTRY.counter(
         "ptpu_causal_lm_heads_total",
         "models causal_lm built, by whether the output head reads the "
-        "embedding's parameter (tied) or has a matrix of its own"
+        "embedding's parameter (tied) or has a matrix of its own; a head is "
+        "one parameter and may run more than once a step (a looped model's "
+        "passes, a multi-token-prediction module's pass: "
+        "ptpu_causal_lm_mtp_modules_total)"
     ).inc(tied=str(bool(c["tie_word_embeddings"])).lower())
+
+
+def _count_module(c):
+    from ..observability.registry import REGISTRY
+    REGISTRY.counter(
+        "ptpu_causal_lm_mtp_modules_total",
+        "multi-token-prediction modules causal_lm built, by depth (the "
+        "tokens ahead of the next one a module predicts: 1 is t_(i+2)), "
+        "whether its embedding and its head are the trunk's own parameters, "
+        "and lambda, the weight of its loss term"
+    ).inc(depth="1", shared_embedding="true", shared_head="true",
+          loss_weight="%g" % c["mtp_loss_weight"])
 
 
 def _count_passes(c):

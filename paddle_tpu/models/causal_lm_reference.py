@@ -26,7 +26,11 @@ a correction bias and ungated shared expert, behind `hc_mult` residual
 streams mixed by manifold-constrained hyper-connections) DeepSeek-V3's
 released `modeling_deepseek.py` for what its keys mean and arXiv:2512.24880
 (over arXiv:2409.19606) for the `hc_*` keys; its multi-token-prediction
-module (`num_nextn_predict_layers`) is not built, here or in the Program.
+module does not fit beside its cut and is left out of it. For GLM-4.7-Flash
+(`model_type: glm4_moe_lite`: DeepSeek-V3's block without the streams, a
+head of 192 + 64 on a value of 256, and `num_nextn_predict_layers` 1)
+DeepSeek-V3's report, arXiv:2412.19437 section 2.2, for the
+multi-token-prediction module and its loss (`mtp_input`, `loss_fn`).
 `params` is the list of the Program's parameters in the order
 models/causal_lm.py creates them.
 
@@ -390,9 +394,15 @@ def exit_distribution(lam):
     return jnp.stack(p + [left])
 
 
-def passes(cfg, params, ids, pos):
+def passes(cfg, params, ids, pos, next_ids=None, found=None):
     """([logits [B, T, V] of pass 1 .. P], p [P, B, T] or None, balance
-    term, z term, expert_load) of the model on ids, pos [B, T]. A model
+    term, z term, expert_load) of the model on ids, pos [B, T]. With a
+    multi-token-prediction module (num_nextn_predict_layers 1; `next_ids`
+    [B, T], the token after each position, which the module embeds) the
+    module's logits are one entry more at the end of the list (without
+    `next_ids` the trunk alone is computed), its layer's
+    assignments are in expert_load, and a dict given as `found` gets
+    `mtp_input`, what the module's layer reads. A model
     with total_ut_steps = P > 1 runs the same layers and the same final
     norm P times, each pass on the normed state of the pass before: a
     Python loop over one set of weights, each read from `params` once. With
@@ -417,9 +427,14 @@ def passes(cfg, params, ids, pos):
     routed = c["ffn_layers"].count("experts")   # the terms' mean is theirs
     embedding = take(1)[0]
     weights = []                # a layer: (N1, mixer, N2, N3, ffn, N4)
-    streams = c["hc_mult"]
+    streams, mtp = c["hc_mult"], c["mtp_layers"]
     hcs = []                    # a layer: (attention's, the FFN's) (phi, b,
-    for i in range(layers):     # alpha), or (None, None)
+    w_f = module = None         # alpha), or (None, None)
+    for i in range(layers + mtp):
+        if i == layers:
+            # the module lies behind the final norm: enorm, hnorm, eh_proj,
+            # then a layer's own, then shared_head.norm
+            w_f, module = take(1)[0], take(3)
         hc_a = take(3) if streams > 1 else None
         n1 = take(1)[0]
         if c["mixer_layers"][i] == "gated_delta":
@@ -443,7 +458,10 @@ def passes(cfg, params, ids, pos):
             + take(3 + shared))
         weights.append((n1, mixer, n2, n3, ffn,
                         take(1)[0] if sandwich else None))
-    w_f = take(1)[0]
+    if mtp:
+        module = module + take(1)
+    else:
+        w_f = take(1)[0]
     w_g, b_g = take(2) if c["exit_gate"] else (None, None)
     # a tied head is the embedding read again, transposed
     w_lm = embedding.T if c["tie_word_embeddings"] else take(1)[0]
@@ -473,49 +491,76 @@ def passes(cfg, params, ids, pos):
                 jnp.einsum("btij,btjd->btid", res, x) \
                 + post[..., None] * y[:, :, None]
 
+        def layer(h, i, terms):
+            """Layer i on h; terms = (balance, z, load) with the layer's."""
+            n1, mixer, n2, n3, ffn, n4 = weights[i]
+            x, write = read(h, hcs[i][0])
+            a = rms_norm(x, n1, eps, centred)
+            if c["mixer_layers"][i] == "gated_delta":
+                mixed = gated_delta_net(a, *mixer, c)
+            elif c["mixer_layers"][i] == "short_conv":
+                mixed = short_conv(a, *mixer)
+            elif c["latent"]:
+                mixed, _ = latent_attention(a, pos, *mixer,
+                                            layer_config(c, i))
+            else:
+                mixed = attention(a, pos, *mixer, layer_config(c, i))
+            if sandwich:
+                mixed = rms_norm(mixed, n2, eps, centred)
+            h = write(mixed)
+            x, write = read(h, hcs[i][1])
+            m = rms_norm(x, n3, eps, centred)
+            if c["ffn_layers"][i] == "experts":
+                out, lb, lz, ld = routed_experts(
+                    m.reshape(b * t, d), ffn[0], *ffn[2:5], c,
+                    router_x=a.reshape(b * t, d)
+                    if c["router_input"] == "pre_attention" else None,
+                    expert_bias=ffn[1])
+                out = out.reshape(b, t, d)
+                if c["shared_expert_intermediate_size"]:
+                    out = out + shared_expert(m, *ffn[5:])
+                terms = (terms[0] + lb / routed, terms[1] + lz / routed,
+                         terms[2] + ld)
+            else:
+                wg, wu, wd = ffn
+                out = (jax.nn.silu(m @ wg) * (m @ wu)) @ wd
+            if sandwich:
+                out = rms_norm(out, n4, eps, centred)
+            return write(out), terms
+
+        terms = (balance, z, load)
         for _ in range(c["total_ut_steps"]):
-            for i, (n1, mixer, n2, n3, ffn, n4) in enumerate(weights):
-                x, write = read(h, hcs[i][0])
-                a = rms_norm(x, n1, eps, centred)
-                if c["mixer_layers"][i] == "gated_delta":
-                    mixed = gated_delta_net(a, *mixer, c)
-                elif c["mixer_layers"][i] == "short_conv":
-                    mixed = short_conv(a, *mixer)
-                elif c["latent"]:
-                    mixed, _ = latent_attention(a, pos, *mixer,
-                                                layer_config(c, i))
-                else:
-                    mixed = attention(a, pos, *mixer, layer_config(c, i))
-                if sandwich:
-                    mixed = rms_norm(mixed, n2, eps, centred)
-                h = write(mixed)
-                x, write = read(h, hcs[i][1])
-                m = rms_norm(x, n3, eps, centred)
-                if c["ffn_layers"][i] == "experts":
-                    out, lb, lz, ld = routed_experts(
-                        m.reshape(b * t, d), ffn[0], *ffn[2:5], c,
-                        router_x=a.reshape(b * t, d)
-                        if c["router_input"] == "pre_attention" else None,
-                        expert_bias=ffn[1])
-                    out = out.reshape(b, t, d)
-                    if c["shared_expert_intermediate_size"]:
-                        out = out + shared_expert(m, *ffn[5:])
-                    balance, z, load = balance + lb / routed, \
-                        z + lz / routed, load + ld
-                else:
-                    wg, wu, wd = ffn
-                    out = (jax.nn.silu(m @ wg) * (m @ wu)) @ wd
-                if sandwich:
-                    out = rms_norm(out, n4, eps, centred)
-                h = write(out)
+            for i in range(layers):
+                h, terms = layer(h, i, terms)
             if streams > 1:
                 h = h.sum(2)
             h = rms_norm(h, w_f, eps, centred)
             logits.append(h @ w_lm)
             if c["exit_gate"]:
                 lam.append(jax.nn.sigmoid(h @ w_g + b_g))
+        if mtp and next_ids is not None:
+            x = mtp_input(embedding[next_ids], h, *module[:3], eps)
+            if found is not None:
+                found["mtp_input"] = x
+            y, terms = layer(x, layers, terms)
+            logits.append(rms_norm(y, module[3], eps, centred) @ w_lm)
+        balance, z, load = terms
     p = exit_distribution(jnp.stack(lam)) if c["exit_gate"] else None
     return logits, p, balance, z, load
+
+
+def mtp_input(e, s, enorm, hnorm, eh_proj, eps):
+    """What the multi-token-prediction module's layer reads (arXiv:
+    2412.19437, equation 21): W_eh [N_e(e); N_h(s)] on e [B, T, D], the
+    embedding of the token after each position, and s, the trunk's state.
+
+    Departure: the report writes [N(h); N(Emb)], the state first, on the
+    state before the final norm; the family's public inference code
+    concatenates the embedding first and takes the state after the trunk's
+    final norm, which is what a checkpoint's `eh_proj` was trained under,
+    and what this does (s is the normed state)."""
+    return jnp.concatenate([rms_norm(e, enorm, eps), rms_norm(s, hnorm, eps)],
+                           -1) @ eh_proj
 
 
 def forward(cfg, params, ids, pos):
@@ -527,9 +572,18 @@ def forward(cfg, params, ids, pos):
     return logits[-1], balance, z, load
 
 
-def loss_fn(cfg, params, ids, pos, labels, with_passes=False):
+def loss_fn(cfg, params, ids, pos, labels, with_passes=False,
+            labels_next=None, found=None):
     """(loss, (logits, expert_load)), or with_passes (loss, (logits of every
-    pass, p, expert_load)). With exit_gate the loss is the mean a position
+    pass, p, expert_load)). With a multi-token-prediction module the loss
+    is L_main + mtp_loss_weight x L_mtp (arXiv:2412.19437, equations 24 and
+    25 at depth 1), L_mtp the mean cross-entropy of the module's logits
+    against `labels_next`, the token two after each position; `logits` are
+    the trunk's, and a dict given as `found` gets `main_loss`, `mtp_loss`,
+    `mtp_logits` and `mtp_input`. Departure: the report's L_mtp runs over
+    positions 2 .. T of a sequence of T tokens, dropping the position whose
+    target lies past the end; here every position has both targets, the
+    sequence being cut from T + 2 tokens. With exit_gate the loss is the mean a position
     of sum_t p_t CE(z_t, y) - exit_entropy_coef x H(p), H(p) = -sum_t p_t
     log p_t (0 log 0 = 0): the entropy-regularised objective of
     arXiv:2510.25741 with a uniform prior over the exit step.
@@ -540,9 +594,13 @@ def loss_fn(cfg, params, ids, pos, labels, with_passes=False):
     hyper-parameter of its stage I; `exit_entropy_coef` is an assumed
     value."""
     c = resolve(cfg)
-    logits, p, balance, z, load = passes(cfg, params, ids, pos)
+    mtp = c["mtp_layers"]
+    logits, p, balance, z, load = passes(
+        cfg, params, ids, pos,
+        next_ids=labels.reshape(ids.shape) if mtp else None, found=found)
+    mtp_logits = logits.pop() if mtp else None
 
-    def nll(one):
+    def nll(one, labels=labels):
         return -jnp.take_along_axis(
             jax.nn.log_softmax(one, -1),
             labels.reshape(one.shape[:2] + (1,)), axis=-1)[..., 0]
@@ -553,6 +611,12 @@ def loss_fn(cfg, params, ids, pos, labels, with_passes=False):
         ce = jnp.stack([nll(one) for one in logits])            # [P, B, T]
         entropy = -jax.scipy.special.xlogy(p, p).sum(0)
         loss = ((p * ce).sum(0) - c["exit_entropy_coef"] * entropy).mean()
+    if mtp:
+        mtp_loss = nll(mtp_logits, labels_next).mean()
+        if found is not None:
+            found.update(main_loss=loss, mtp_loss=mtp_loss,
+                         mtp_logits=mtp_logits)
+        loss = loss + c["mtp_loss_weight"] * mtp_loss
     loss = loss + c["router_aux_loss_coef"] * balance \
         + c["router_z_loss_coef"] * z
     if with_passes:
@@ -560,8 +624,9 @@ def loss_fn(cfg, params, ids, pos, labels, with_passes=False):
     return loss, (logits[-1], load)
 
 
-def loss_and_grads(cfg, params, ids, pos, labels):
+def loss_and_grads(cfg, params, ids, pos, labels, labels_next=None):
     """((loss, (logits, expert_load)), [d loss / d parameter])."""
     return jax.value_and_grad(
-        lambda p: loss_fn(cfg, p, ids, pos, labels), has_aux=True)(
+        lambda p: loss_fn(cfg, p, ids, pos, labels,
+                          labels_next=labels_next), has_aux=True)(
             [jnp.asarray(p, jnp.float32) for p in params])

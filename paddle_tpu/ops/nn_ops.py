@@ -466,12 +466,13 @@ def _fused_attention(ctx, ins, attrs):
     blocks rotate the ring via lax.ppermute, and the online softmax
     matches the single-chip kernel exactly (incl. causal + kv_len).
 
-    The latent form: QRope [B, T, Hq, dr] and KRope [B, T, 1, dr] beside Q,
-    K and V of one width D: a head's score is q . k + q_rope . k_rope, the
-    rotary key one that all heads share, its value D wide
-    (pallas_kernels.flash_attention). The dense path concatenates the two
-    parts and repeats the shared key a head; the flash kernels do
-    neither."""
+    The latent form: QRope [B, T, Hq, dr] and KRope [B, T, 1, dr] beside Q
+    and K of one width D and V of D or of another width (192 + 64 on 256):
+    a head's score is q . k + q_rope . k_rope, the rotary key one that all
+    heads share (pallas_kernels.flash_attention). The dense path
+    concatenates the two parts and repeats the shared key a head; the flash
+    kernels do neither at equal widths, and at unequal ones what
+    pallas_kernels.latent_form says."""
     q = single(ins, "Q")
     k = single(ins, "K")
     v = single(ins, "V")

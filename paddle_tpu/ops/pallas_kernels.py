@@ -144,7 +144,10 @@ def heads_a_block(hq, hkv, d):
     192 (latent attention's 128 + 64) is none of these (32, 32, 192 gives
     None): it does not come here whole but as its two parts, q, k and v at
     128 (1 a block) and the rotary part beside them (`_rope_rows`: two
-    heads of 64 a block, against one key)."""
+    heads of 64 a block, against one key). Nor is a part without position
+    of 192 beside a value of 256 (20, 20, 192: None, a head and a half a
+    lane block): that head comes here whole, 192 + 64 = 256 on 256 (1 a
+    block), joined by `flash_attention` (`latent_form`)."""
     if d % 128 == 0:
         return 1
     if hq == hkv and 128 % d == 0 and (hq * d) % 128 == 0:
@@ -922,6 +925,29 @@ def _flash_core_bwd(scale, causal, window, block_q, block_k, interpret, res,
 _flash_core.defvjp(_flash_core_fwd, _flash_core_bwd)
 
 
+def latent_form(d, dr, dv):
+    """The form the flash kernels run a latent head [D without position; dr
+    rotary] on a value of dv in, by its widths alone: "two_part" where D is
+    the value's width (128 + 64 on 128: the two products taken apart in the
+    kernels, nothing joined or repeated in HBM); "whole" where the whole
+    head is (192 + 64 on 256: q = [q; q_rope] and k = [k; k_rope repeated a
+    head] joined in HBM for the plain kernels at that width, dk_rope the
+    sum over heads that the repeat's transpose takes after the kernel).
+    Padding the part without position to the value's width for the
+    two-part kernels lost to the join by 22 % forward and 27 % forward +
+    backward on the v5e at 20 heads and T=4096 (PERF.md section 6, PR 49)
+    and is not built. Any other widths: a ValueError."""
+    if d == dv:
+        return "two_part"
+    if d + dr == dv:
+        return "whole"
+    raise ValueError(
+        "flash_attention: the latent form takes a part without position as "
+        "wide as the value (D + dr on D, D a multiple of 128: 128 + 64 on "
+        "128) or a whole head as wide as the value (D + dr on dv = D + dr: "
+        "192 + 64 on 256); got %d + %d on %d" % (d, dr, dv))
+
+
 def flash_attention(q, k, v, causal=False, scale=None, kv_len=None,
                     block_q=None, block_k=None, interpret=None, window=None,
                     q_rope=None, k_rope=None):
@@ -929,8 +955,9 @@ def flash_attention(q, k, v, causal=False, scale=None, kv_len=None,
     (BTHD, the layout ring_attention uses); returns [B, T, Hq, D]. block_q /
     block_k default to kernel_config.DEFAULT_TILES["attn"] and are clamped
     to T. The widths it takes: q, k and v of one width D, any; and, the
-    latent form, a head of D + dr on keys of D + dr and values of D, given
-    as its two parts (below).
+    latent form, a head of D + dr on keys of D + dr and values of D (128 +
+    64 on 128) or of another width dv (192 + 64 on 256; the result is then
+    [B, T, Hq, dv]), given as its two parts (below).
 
     The latent form (q_rope [B, T, Hq, dr] and k_rope [B, T, 1, dr], both
     or neither): a head's score is q . k + q_rope . k_rope, the second key
@@ -941,7 +968,9 @@ def flash_attention(q, k, v, causal=False, scale=None, kv_len=None,
     be a multiple of 128 and dr divide 128 (or be a multiple of it), with
     as many key/value heads as query heads; `scale` is the caller's (the
     default is 1 / sqrt(D + dr)). dq_rope and dk_rope come back in their
-    operands' shapes, dk_rope summed over the heads.
+    operands' shapes, dk_rope summed over the heads. Where the value is not
+    D wide but D + dr (192 + 64 on 256) the head runs whole (`latent_form`):
+    the two parts joined here, the plain kernels at the value's width.
 
     Grouped queries come from the shapes: Hq a multiple of Hkv, and query
     head h reads key/value head h // (Hq // Hkv); the kernels find that
@@ -976,15 +1005,18 @@ def flash_attention(q, k, v, causal=False, scale=None, kv_len=None,
     if interpret is None:
         interpret = _interpret_default()
     b, t, h, d = q.shape
-    if k.shape != v.shape or h % k.shape[2] or k.shape[3] != d:
+    latent = q_rope is not None or k_rope is not None
+    if k.shape[:3] != v.shape[:3] or h % k.shape[2] or k.shape[3] != d \
+            or not latent and v.shape[3] != d:
         raise ValueError(
             "flash_attention: q %s needs k and v alike, [B, T, Hkv, D] at q's "
             "own width D with Hkv dividing the query heads (a head whose "
-            "keys are wider than its values gives the further width as "
-            "q_rope and k_rope); got k %s, v %s"
+            "keys are wider than its values, 128 + 64 on 128, or whose part "
+            "without position is narrower, 192 + 64 on 256, gives the "
+            "rotary width as q_rope and k_rope); got k %s, v %s"
             % (q.shape, k.shape, v.shape))
     rope = None
-    if q_rope is not None or k_rope is not None:
+    if latent:
         if q_rope is None or k_rope is None or k.shape[2] != h \
                 or q_rope.shape[:3] != q.shape[:3] \
                 or k_rope.shape != (b, t, 1, q_rope.shape[3]):
@@ -994,8 +1026,17 @@ def flash_attention(q, k, v, causal=False, scale=None, kv_len=None,
                 "heads as query heads; got q %s, k %s, q_rope %s, k_rope %s"
                 % (q.shape, k.shape, getattr(q_rope, "shape", None),
                    getattr(k_rope, "shape", None)))
-        rope = (q_rope, k_rope)
-        d += q_rope.shape[3]
+        dr, dv = q_rope.shape[3], v.shape[3]
+        if scale is None:
+            scale = 1.0 / float(np.sqrt(d + dr))
+        if latent_form(d, dr, dv) == "two_part":
+            rope = (q_rope, k_rope)
+        else:
+            # differentiated by jax around the kernels' own rule: the
+            # repeat's transpose sums dk_rope over the heads
+            q = jnp.concatenate([q, q_rope], -1)
+            k = jnp.concatenate(
+                [k, jnp.broadcast_to(k_rope, q_rope.shape)], -1)
     if window is not None and int(window) < 1:
         raise ValueError("flash_attention: window must be None or >= 1, got "
                          "%r" % (window,))

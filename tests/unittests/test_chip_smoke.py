@@ -54,10 +54,17 @@ PHASE_SAYS = {
         for line in lines) and any(
         "K head [" in line and "now " in line and "parent " in line
         for line in lines),
+    # phase L ran the core at 192 + 64 on 256 in both forms, timed each and
+    # the plain kernels on joined heads
+    "L": lambda lines: any(
+        "L latent core at 192 + 64 on 256" in line
+        and "whole at blocks" in line and "two_part at blocks" in line
+        and "dk_rope" in line and "arriving joined" in line
+        for line in lines),
 }
 
 
-@pytest.mark.parametrize("letter", "ABCDEFGHIJK")
+@pytest.mark.parametrize("letter", "ABCDEFGHIJKL")
 def test_tiny_rehearsal_passes_every_phase(letter):
     """One case a phase (`--phases <letter>`), so that a red run names it."""
     # phase E times the program phase A left
