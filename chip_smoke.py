@@ -58,6 +58,13 @@ from a seed, and checks what comes out by the repo's own means:
      form's milliseconds forward and forward + backward, beside the plain
      kernels on heads that arrive joined (what the join costs).
 
+  M  the routed experts' nine grouped matmuls of a layer alone (gate, up
+     and down: forward, d rows, d weights), `ops/expert_gmm.py`'s kernels
+     against `jax.lax.ragged_dot` and its transposes, at the six expert
+     cells' shapes with the groups a seeded router gives and bf16 operands:
+     ms and TFLOP/s a pass of each route, the kernels at several row tiles,
+     and the largest difference between the routes over the rows in a group.
+
 Every phase that fails makes the exit code non-zero. Timings are printed
 for the next reader, labelled with the device; they are not metrics. The
 last line of stdout is one JSON object, {"ok": ..., "device": {...}}.
@@ -142,6 +149,16 @@ FULL = {
     # (rows, width, vocabulary); then a ragged N for the kernels alone
     "head": dict(shapes=((8192, 2560, 37984), (16384, 2048, 50304)),
                  ragged=(1000, 37984), rows=(16, 32, 64)),
+    # an expert layer of the six expert cells: tokens, hidden, experts
+    # routed over and held, their width, experts a token; the row tiles
+    "expert_gmm": dict(
+        shapes=(("smallthinker_21b_a3b", 8192, 2560, 64, 16, 768, 6),
+                ("lfm2_8b_a1b", 8192, 2048, 32, 8, 1792, 4),
+                ("olmoe_1b_7b", 16384, 2048, 64, 64, 1024, 8),
+                ("qwen3_next_80b_a3b", 4096, 2048, 512, 32, 512, 10),
+                ("xing4_0_29b_a4b", 4096, 3584, 64, 8, 1024, 4),
+                ("glm_4_7_flash", 8192, 2048, 64, 8, 1536, 4)),
+        block_m=(256, 512, 1024), tol=2e-2),
     "barrier": dict(steps=5, rounds=3, tol=0.15),
     "dp_loss_rtol": 2e-2,
 }
@@ -176,6 +193,9 @@ TINY = {
                            blocks={"whole": (32, 32), "two_part": (32, 32)},
                            tol=3e-2),
     "head": dict(shapes=((48, 32, 200),), ragged=(40, 200), rows=(16, 32)),
+    "expert_gmm": dict(shapes=(("a share held", 64, 128, 8, 2, 128, 3),
+                               ("every expert held", 32, 128, 4, 4, 256, 2)),
+                       block_m=(16, 32), tol=2e-2),
     "barrier": dict(steps=5, rounds=3, tol=0.75),
     "dp_loss_rtol": 2e-2,
 }
@@ -1813,6 +1833,104 @@ def phase_k(smoke):
         del out
 
 
+def phase_m(smoke):
+    """A layer's nine expert matmuls alone, route against route."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.ops import expert_gmm
+
+    c = smoke.cfg["expert_gmm"]
+    bf = jnp.bfloat16
+    for name, n, d, e, held, f, top_k in c["shapes"]:
+        keys = jax.random.split(jax.random.key(50), 8)
+        # a router at initialisation with a lean of its own an expert
+        logits = jax.random.normal(keys[0], (n, e)) \
+            + 0.5 * jax.random.normal(keys[1], (e,))
+        chosen = jax.lax.top_k(logits, top_k)[1].reshape(-1)
+        sizes = jnp.sum(chosen[:, None] == jnp.arange(held), axis=0,
+                        dtype=jnp.int32)
+        total, rows = int(sizes.sum()), top_k * n
+        x, dy = (jax.random.normal(k, (rows, d), jnp.float32).astype(bf)
+                 for k in keys[2:4])
+        w_in = (jax.random.normal(keys[4], (held, d, f)) * d ** -0.5) \
+            .astype(bf)
+        w_out = (jax.random.normal(keys[5], (held, f, d)) * f ** -0.5) \
+            .astype(bf)
+
+        def ragged(lhs, rhs):
+            return jax.lax.ragged_dot(lhs, rhs, sizes,
+                                      preferred_element_type=lhs.dtype)
+
+        def ragged_t(lhs, rhs, dout):
+            return jax.vjp(ragged, lhs, rhs)[1](dout)
+
+        def passes(fwd, d_rows, d_weights):
+            """The three passes over (gate, up, down), each one program."""
+            def forward(x, h, w_in, w_out):
+                return fwd(x, w_in), fwd(x, w_in), fwd(h, w_out)
+
+            def rows_t(dh, dy, w_in, w_out):
+                return (d_rows(dh, w_in), d_rows(dh, w_in),
+                        d_rows(dy, w_out))
+
+            def weights_t(x, h, dh, dy):
+                return (d_weights(x, dh), d_weights(x, dh),
+                        d_weights(h, dy))
+
+            return jax.jit(forward), jax.jit(rows_t), jax.jit(weights_t)
+
+        routes = {"ragged_dot": passes(
+            ragged, lambda dout, rhs: ragged_t(jnp.zeros(
+                (rows, rhs.shape[1]), bf), rhs, dout)[0],
+            lambda lhs, dout: ragged_t(lhs, jnp.zeros(
+                (held, lhs.shape[1], dout.shape[1]), bf), dout)[1])}
+        for block_m in c["block_m"]:
+            plan = expert_gmm.plan(sizes, rows, block_m)
+            routes["expert_gmm at %d rows" % block_m] = passes(
+                lambda lhs, rhs, p=plan: expert_gmm.gmm(lhs, rhs, p),
+                lambda dout, rhs, p=plan: expert_gmm.gmm_drows(dout, rhs, p),
+                lambda lhs, dout, p=plan: expert_gmm.gmm_dweights(
+                    lhs, dout, p))
+        h, dh = (jax.random.normal(k, (rows, f), jnp.float32).astype(bf)
+                 for k in keys[6:8])
+        args = ((x, h, w_in, w_out), (dh, dy, w_in, w_out), (x, h, dh, dy))
+        # as many calls in flight as leave the device room for their results
+        calls = max(2, min(10, (4 << 30) // (3 * rows * max(d, f) * 2)))
+        ops = 3 * 2 * total * d * f
+        want = None
+        smoke.say("expert matmuls, %s: %d of %d rows in %d groups of %d to "
+                  "%d, [%d x %d]" % (name, total, rows, held,
+                                     int(sizes.min()), int(sizes.max()),
+                                     d, f))
+        for route, runs in routes.items():
+            got = [run(*a) for run, a in zip(runs, args)]
+            # what lies past the groups belongs to no one
+            got = [[o[:total] if o.shape[0] == rows else o for o in outs]
+                   for outs in got]
+            ms = [_in_flight_ms(run, a, calls=calls)
+                  for run, a in zip(runs, args)]
+            if want is None:
+                want, errs = got, []
+            else:
+                errs = [float(jnp.max(jnp.abs(
+                    a.astype(jnp.float32) - b.astype(jnp.float32)))
+                    / (jnp.max(jnp.abs(b.astype(jnp.float32))) + 1e-6))
+                    for outs, refs in zip(got, want)
+                    for a, b in zip(outs, refs)]
+            smoke.say("  %-26s forward %.3f ms %.1f TFLOP/s, d rows %.3f ms "
+                      "%.1f, d weights %.3f ms %.1f, the nine %.3f ms %.1f%s"
+                      % ((route,) + tuple(itertools.chain.from_iterable(
+                          (t, done / t / 1e9) for t, done in zip(
+                              ms + [sum(ms)], [ops] * 3 + [3 * ops])))
+                         + ("; largest difference %.2e" % max(errs)
+                            if errs else "",)))
+            if errs and not max(errs) <= c["tol"]:
+                raise AssertionError(
+                    "%s disagrees with ragged_dot at %s: %r (tol %g)"
+                    % (route, name, errs, c["tol"]))
+            del got
+
+
 PHASES = (("A", "ResNet-50 training", phase_a),
           ("B", "transformer training", phase_b),
           ("C", "Pallas kernel families", phase_c),
@@ -1824,7 +1942,8 @@ PHASES = (("A", "ResNet-50 training", phase_a),
           ("I", "the embedding's backward", phase_i),
           ("J", "latent attention and hyper-connections", phase_j),
           ("K", "the output head and its loss", phase_k),
-          ("L", "the latent core at 192 + 64 on 256", phase_l))
+          ("L", "the latent core at 192 + 64 on 256", phase_l),
+          ("M", "the routed experts' grouped matmuls", phase_m))
 
 
 def main(argv=None):
@@ -1832,7 +1951,7 @@ def main(argv=None):
     ap.add_argument("--tiny", action="store_true",
                     help="CPU rehearsal at toy sizes (needs "
                          "JAX_PLATFORMS=cpu)")
-    ap.add_argument("--phases", default="ABCDEFGHIJKL",
+    ap.add_argument("--phases", default="ABCDEFGHIJKLM",
                     help="letters of the phases to run (default all)")
     args = ap.parse_args(argv)
 

@@ -706,7 +706,7 @@ def _lower_op_inner(ctx, op, env):
     ins = {slot: [env.read(n) for n in names]
            for slot, names in op.inputs.items()}
     if op.type == "moe_ffn":
-        _count_moe_layer(op.attrs, ins)
+        _count_moe_layer(ctx, op.attrs, ins)
     elif op.type == "fused_attention":
         _count_attention_layer(ctx, op.attrs, ins)
     elif op.type == "gated_delta_rule":
@@ -879,16 +879,18 @@ def _count_grad_op(path, fwd_type):
     ).inc(path=path, op=fwd_type)
 
 
-def _count_moe_layer(attrs, ins):
+def _count_moe_layer(ctx, attrs, ins):
     from ..observability.registry import REGISTRY
-    from ..parallel.moe import GROUPED_MATMUL, rows_moved
-    experts, held = ins["Router"][0].shape[1], ins["WGate"][0].shape[0]
+    from ..parallel.moe import matmul_route, rows_moved
+    experts, w_gate = ins["Router"][0].shape[1], ins["WGate"][0]
+    held = w_gate.shape[0]
     REGISTRY.counter(
         "ptpu_moe_layers_total",
         "moe_ffn ops lowered (forward ops, not a grad op's replay), by "
         "experts a token, experts routed over, experts held, the gate's "
         "activation, what the router reads (the experts' own input or "
-        "another tensor, pre_attention), the grouped-matmul route, the "
+        "another tensor, pre_attention), the grouped-matmul route "
+        "(ragged_dot, or expert_gmm: the kernels of ops/expert_gmm.py), the "
         "rows of the slot-major buffer that every pass between the router "
         "and the layer's output touches (all, or the tiles of the held "
         "assignments: the four permutations, the two d rows' sum and the "
@@ -898,7 +900,10 @@ def _count_moe_layer(attrs, ins):
     ).inc(top_k=str(attrs["top_k"]), experts=str(experts), held=str(held),
           activation=str(attrs.get("activation", "silu")),
           router_input="pre_attention" if ins.get("RouterX") else "own",
-          path=GROUPED_MATMUL, rows=rows_moved(experts, held),
+          path=matmul_route(
+              w_gate.shape[1], w_gate.shape[2],
+              jnp.bfloat16 if ctx.amp else ins["X"][0].dtype, ctx.mesh),
+          rows=rows_moved(experts, held),
           scoring=str(attrs.get("scoring", "softmax")),
           bias=str(bool(ins.get("ExpertBias"))).lower(),
           scale="%g" % attrs.get("scale", 1.0))

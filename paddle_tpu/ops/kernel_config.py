@@ -20,7 +20,7 @@ Accepted forms of PADDLE_TPU_PALLAS:
     - "attn,xent"       : allowlist — exactly the named ops on, the
                           rest off.  Unknown names raise LOUDLY (a typo
                           must not silently run the other path).
-Op names: attn, xent, ln, lstm, seq, gdr, conv, emb, mhc (KERNEL_OPS).  For 'attn' the flag
+Op names: attn, xent, ln, lstm, seq, gdr, conv, emb, mhc, gmm (KERNEL_OPS).  For 'attn' the flag
 is an opt-OUT only: fused_attention's positive dispatch is always the
 flash_at() rule, so enabling 'attn' does not force flash below the
 crossover (pin FLAGS_flash_min_seq=0 for that).
@@ -98,7 +98,18 @@ __all__ = [
 # kernels' own VMEM limit.  Swept on the v5e
 # (my chip run, PR 43: `chip_smoke.py --phases J`, a sub-layer's forward +
 # backward alone at [4096, 14336] bf16): 2.121 ms at 64 rows, 2.094 at
-# 128, 2.125 at 256: flat, the kernels run at their bytes' pace.
+# 128, 2.125 at 256: flat, the kernels run at their bytes' pace.  "gmm" is
+# the rows a visit of ops/expert_gmm.py's three kernels streams past an
+# expert's resident matrix (inside it they go to the MXU 128 at a time).
+# Swept on the v5e (my chip runs, PR 50: `chip_smoke.py --phases M`, a
+# layer's nine matmuls alone at the six expert cells' shapes, ms): 256 /
+# 512 / 1024 rows read 4.25 / 4.21 / 4.20 at SmallThinker's shape, 3.95 /
+# 3.89 / 3.85 at LFM2's, 25.51 / 25.19 / 25.49 at OLMoE's, 2.06 / 2.05 /
+# 2.03 at Qwen3-Next's, 2.08 / 2.02 / 2.02 at Xing4.0's, 2.68 / 2.62 / 2.57 at
+# GLM-4.7-Flash's: flat, so one entry and no rule on the shape.  Inner
+# trips of 256 rows were 2-5 % slower than 128 at every shape and whole
+# tiles of 512 8-20 %: a trip that starts in another group's rows is work
+# for nothing, and a taller dot bought nothing back where a group fills it.
 DEFAULT_TILES = {
     "attn": {"block_q": 512, "block_k": 512},
     "xent": {"tile_bytes": 1 << 20},
@@ -109,6 +120,7 @@ DEFAULT_TILES = {
     "conv": {"tile_bytes": 1 << 20},
     "emb": {"tile_bytes": 4 << 20},
     "mhc": {"block_rows": 128},
+    "gmm": {"block_m": 512},
 }
 KERNEL_OPS = frozenset(DEFAULT_TILES)
 # Dense attention below this query length, flash at and above it.  The

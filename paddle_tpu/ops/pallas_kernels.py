@@ -47,19 +47,19 @@ __all__ = ["flash_attention", "softmax_xent", "layer_norm",
 
 _NEG = -1e30
 
-# The name each pallas_call gives its Mosaic custom call: the HLO instruction
-# is `<name>.<n>`, which a device trace, the profiler's table and the
-# benchmark's per-kernel metrics find it by. Those from ptpu_gated_delta_fwd
-# on are other modules' (the last eight ops/mhc_kernels.py's KERNELS).
+# The name each pallas_call gives its Mosaic custom call (the HLO instruction
+# is `<name>.<n>`): a trace, the profiler's table and the benchmark find it by
+# this. From ptpu_gated_delta_fwd on they are other modules' kernels.
 KERNEL_NAMES = (
     "ptpu_flash_fwd", "ptpu_flash_bwd_dkdv", "ptpu_flash_bwd_dq",
     "ptpu_softmax_xent_fwd", "ptpu_layer_norm_fwd", "ptpu_lstm_seq",
     "ptpu_lstmp_seq", "ptpu_masked_softmax", "ptpu_masked_pool",
-    "ptpu_gated_delta_fwd", "ptpu_gated_delta_bwd",
-    "ptpu_causal_conv1d_fwd", "ptpu_causal_conv1d_bwd", "ptpu_embedding_grad",
-    "ptpu_mhc_pre_fwd", "ptpu_mhc_pre_bwd", "ptpu_mhc_post_fwd",
-    "ptpu_mhc_post_bwd", "ptpu_mhc_expand", "ptpu_mhc_reduce",
-    "ptpu_mhc_coeffs_fwd", "ptpu_mhc_coeffs_bwd")
+    "ptpu_gated_delta_fwd", "ptpu_gated_delta_bwd", "ptpu_causal_conv1d_fwd",
+    "ptpu_causal_conv1d_bwd", "ptpu_embedding_grad", "ptpu_mhc_pre_fwd",
+    "ptpu_mhc_pre_bwd", "ptpu_mhc_post_fwd", "ptpu_mhc_post_bwd",
+    "ptpu_mhc_expand", "ptpu_mhc_reduce", "ptpu_mhc_coeffs_fwd",
+    "ptpu_mhc_coeffs_bwd", "ptpu_expert_gmm_fwd", "ptpu_expert_gmm_drows",
+    "ptpu_expert_gmm_dweights")
 
 
 def _interpret_default():
@@ -1784,3 +1784,12 @@ def layer_norm(x, scale, bias, eps=1e-5, block_n=None, interpret=None):
                  None if block_n is None else int(block_n), bool(interpret))
     xf = x.astype(jnp.float32)
     return y, jnp.mean(xf, axis=-1), jnp.var(xf, axis=-1)
+
+
+# Who runs the routed experts' grouped matmuls beside XLA's `ragged-dot*`
+# instructions (parallel/moe.py `_grouped_matmul` on one TPU): the kernels of
+# ops/expert_gmm.py, its KERNELS written out so that nothing here imports
+# them. The benchmark's expert_matmul_ms_per_step sums these names' calls.
+# Kept at the end of the module: no line above a kernel moves.
+EXPERT_MATMUL_KERNELS = ("ptpu_expert_gmm_fwd", "ptpu_expert_gmm_drows",
+                         "ptpu_expert_gmm_dweights")

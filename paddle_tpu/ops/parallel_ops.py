@@ -165,9 +165,25 @@ def _moe_ffn_lower(ctx, ins, attrs):
         expert_bias=single(ins, "ExpertBias") if ins.get("ExpertBias")
         else None,
         scale=float(attrs.get("scale", 1.0)),
-        norm_eps=attrs.get("norm_epsilon"))
+        norm_eps=attrs.get("norm_epsilon"), mesh=ctx.mesh)
     return {"Out": [out.reshape(x.shape)], "BalanceLoss": [balance],
             "ZLoss": [z], "ExpertLoad": [load]}
 
 
-registry.register("moe_ffn", _moe_ffn_lower)
+def _moe_ffn_infer(block, op, out_vars):
+    """The outputs' shapes, written down: Out is X's, the two loss terms
+    [1] float32, ExpertLoad [E] int32. Inferred by tracing the rule, as an
+    op without this is, the whole layer (the router, the sort, the loops over
+    the held rows and, on a TPU, the grouped-matmul kernels) was traced twice
+    a layer when the program was built, for these four shapes."""
+    x = block.var_recursive(op.inputs["X"][0])
+    experts = block.var_recursive(op.inputs["Router"][0]).shape[1]
+    for slot, shape, dtype in (("Out", x.shape, x.dtype),
+                               ("BalanceLoss", (1,), "float32"),
+                               ("ZLoss", (1,), "float32"),
+                               ("ExpertLoad", (int(experts),), "int32")):
+        for var in out_vars.get(slot, ()):
+            var.shape, var.dtype = tuple(shape), dtype
+
+
+registry.register("moe_ffn", _moe_ffn_lower, infer=_moe_ffn_infer)

@@ -134,17 +134,63 @@ def moe_layer(params, x, capacity_factor=1.25, mesh=None, axis="ep"):
 
 
 # --- dropless top-k routed experts (the `moe_ffn` op) -----------------------
-# The grouped-matmul route every expert matmul of `routed_ffn` takes, as the
-# counter ptpu_moe_layers_total{path} names it.
+# The two grouped-matmul routes the expert matmuls of `routed_ffn` take, as
+# the counter ptpu_moe_layers_total{path} names them: XLA's own, and the
+# kernels of ops/expert_gmm.py.
 GROUPED_MATMUL = "ragged_dot"
+KERNEL_MATMUL = "expert_gmm"
+
+# The widest [K, N] matrix the kernels hold whole in VMEM, in bytes: d
+# weights keeps its two output buffers and a float32 accumulator of it (60 MiB
+# at 12 MiB in bfloat16, of the 100 the kernels ask for). The cells' are 2 to
+# 7 MiB.
+_RESIDENT_BYTES = 12 << 20
 
 
-def _grouped_matmul(lhs, rhs, group_sizes):
+def matmul_route(width, expert_width, dtype, mesh=None):
+    """Who runs a layer's nine expert matmuls, decided from what the call
+    can see, as embedding_grad.grad_form decides: the kernels where they are
+    on (a TPU), the step is one device's (a Mosaic call does not partition),
+    both widths are whole lane tiles and an expert's matrix fits the VMEM
+    the kernels keep it in; else `jax.lax.ragged_dot`. At every group size
+    the benchmark's cells have, 80 to 2048 rows, the kernels are the faster
+    (chip_smoke.py --phases M), so the rows are no part of the rule."""
+    from ..ops import kernel_config
+    if mesh is not None or width % 128 or expert_width % 128 \
+            or width * expert_width * jnp.dtype(dtype).itemsize \
+            > _RESIDENT_BYTES or not kernel_config.pallas_on("gmm"):
+        return GROUPED_MATMUL
+    return KERNEL_MATMUL
+
+
+def _grouped_matmul(lhs, rhs, group_sizes, plan=None):
     """lhs [M, K] rows sorted by group, rhs [G, K, N], group_sizes [G] with
     sum M: row m times its own group's matrix, [M, N] in lhs's dtype. Costs
-    M x K x N multiply-adds whatever G is."""
+    M x K x N multiply-adds whatever G is. `plan` is the kernels' list of
+    visits (expert_gmm.plan of the same sizes), made once a layer; None:
+    `ragged_dot`."""
+    if plan is not None:
+        return _kernel_matmul(lhs, rhs, plan)
     return jax.lax.ragged_dot(lhs, rhs, group_sizes,
                               preferred_element_type=lhs.dtype)
+
+
+@jax.custom_vjp
+def _kernel_matmul(lhs, rhs, plan):
+    from ..ops import expert_gmm
+    return expert_gmm.gmm(lhs, rhs, plan)
+
+
+def _kernel_matmul_fwd(lhs, rhs, plan):
+    return _kernel_matmul(lhs, rhs, plan), (lhs, rhs, plan)
+
+
+def _kernel_matmul_bwd(res, d_out):
+    lhs, rhs, plan = res
+    return _matmul_transposes(lhs, rhs, None, plan, d_out) + (None,)
+
+
+_kernel_matmul.defvjp(_kernel_matmul_fwd, _kernel_matmul_bwd)
 
 
 def rows_moved(experts, held):
@@ -485,8 +531,12 @@ def _gated(gate, up, activation):
     return _gated_unit(activation)(gate, up)
 
 
-def _matmul_transposes(lhs, rhs, sizes, d_out):
-    """(d lhs, d rhs) of `_grouped_matmul(lhs, rhs, sizes)`."""
+def _matmul_transposes(lhs, rhs, sizes, plan, d_out):
+    """(d lhs, d rhs) of `_grouped_matmul(lhs, rhs, sizes, plan)`."""
+    if plan is not None:
+        from ..ops import expert_gmm
+        return (expert_gmm.gmm_drows(d_out, rhs, plan),
+                expert_gmm.gmm_dweights(lhs, d_out, plan, rhs.dtype))
     return jax.vjp(lambda a, b: _grouped_matmul(a, b, sizes), lhs, rhs)[1](
         d_out)
 
@@ -515,26 +565,27 @@ def _held_gated_transpose(gate, up, d_hidden, total, *, unit, tile):
                        (d_hidden, jnp.zeros_like(up)), tile)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(9,))
-def _held_experts(x, w_gate, w_up, w_down, order, rank, sizes, total, places,
-                  activation):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(10,))
+def _held_experts(x, w_gate, w_up, w_down, order, rank, sizes, plan, total,
+                  places, activation):
     """A share held: x [N, D] -> the held experts' outputs by sorted row
     [A, D], through `_held_rows`, the three grouped matmuls and `_gated`; the
-    integers as in `_combine`. One rule, so that its backward pass can run
-    the passes between the matmuls' transposes in the held tiles."""
+    integers as in `_combine`, `plan` as in `_grouped_matmul`. One rule, so
+    that its backward pass can run the passes between the matmuls'
+    transposes in the held tiles."""
     return _held_experts_fwd(x, w_gate, w_up, w_down, order, rank, sizes,
-                             total, places, activation)[0]
+                             plan, total, places, activation)[0]
 
 
-def _held_experts_fwd(x, w_gate, w_up, w_down, order, rank, sizes, total,
-                      places, activation):
+def _held_experts_fwd(x, w_gate, w_up, w_down, order, rank, sizes, plan,
+                      total, places, activation):
     rows = _held_rows(x, order, total, tile=ROW_TILE)
-    gate = _grouped_matmul(rows, w_gate, sizes)
-    up = _grouped_matmul(rows, w_up, sizes)
+    gate = _grouped_matmul(rows, w_gate, sizes, plan)
+    up = _grouped_matmul(rows, w_up, sizes, plan)
     hidden = _gated(gate, up, activation)
-    return _grouped_matmul(hidden, w_down, sizes), (
-        rows, gate, up, hidden, w_gate, w_up, w_down, rank, sizes, total,
-        places)
+    return _grouped_matmul(hidden, w_down, sizes, plan), (
+        rows, gate, up, hidden, w_gate, w_up, w_down, rank, sizes, plan,
+        total, places)
 
 
 def _held_experts_bwd(activation, res, dy):
@@ -542,15 +593,16 @@ def _held_experts_bwd(activation, res, dy):
     tiles below `total` (`_held_gated_transpose`); and a token's gradient
     summed from the two matmuls' d rows where they lie (`_token_sum`): a row
     past `total` holds whatever the transposes left there."""
-    rows, gate, up, hidden, w_gate, w_up, w_down, rank, sizes, total, \
+    rows, gate, up, hidden, w_gate, w_up, w_down, rank, sizes, plan, total, \
         places = res
-    d_hidden, d_down = _matmul_transposes(hidden, w_down, sizes, dy)
+    d_hidden, d_down = _matmul_transposes(hidden, w_down, sizes, plan, dy)
     d_gate, d_up = _held_gated_transpose(
         gate, up, d_hidden, total, unit=_gated_unit(activation), tile=ROW_TILE)
-    d_rows_gate, d_w_gate = _matmul_transposes(rows, w_gate, sizes, d_gate)
-    d_rows_up, d_w_up = _matmul_transposes(rows, w_up, sizes, d_up)
+    d_rows_gate, d_w_gate = _matmul_transposes(rows, w_gate, sizes, plan,
+                                               d_gate)
+    d_rows_up, d_w_up = _matmul_transposes(rows, w_up, sizes, plan, d_up)
     return (_token_sum((d_rows_gate, d_rows_up), rank, places, tile=SUM_TILE),
-            d_w_gate, d_w_up, d_down) + (None,) * 5
+            d_w_gate, d_w_up, d_down) + (None,) * 6
 
 
 _held_experts.defvjp(_held_experts_fwd, _held_experts_bwd)
@@ -594,7 +646,7 @@ def _route(logits, top_k, norm_topk_prob, scoring, expert_bias, scale,
 def routed_ffn(x, router, w_gate, w_up, w_down, top_k, norm_topk_prob=False,
                expert_dtype=None, router_x=None, activation="silu",
                first_expert=0, scoring="softmax", expert_bias=None,
-               scale=1.0, norm_eps=None):
+               scale=1.0, norm_eps=None, mesh=None):
     """Dropless top-k routed gated experts over tokens x [N, D].
 
     router [D, E]; w_gate, w_up [H, D, F]; w_down [H, F, D]; no bias. The
@@ -654,7 +706,10 @@ def routed_ffn(x, router, w_gate, w_up, w_down, top_k, norm_topk_prob=False,
     output and in the gradient of its left operand alike (my chip run, PR
     31). So nothing rests on them: no pass reads a row from `total` on into
     a token's sum or a weight's gradient, forward or backward, so no NaN in
-    an unwritten row reaches either.
+    an unwritten row reaches either. The kernels of ops/expert_gmm.py, which
+    run the nine matmuls on one TPU (`matmul_route`; `mesh` is the step's,
+    None on one device), do as the v5e's `ragged_dot` does: a tile of rows
+    wholly past `total` is neither read nor written.
 
     The router's matmul, softmax and top-k are float32 at full precision
     whatever x's dtype; the experts compute in `expert_dtype` (x's own if
@@ -710,17 +765,24 @@ def routed_ffn(x, router, w_gate, w_up, w_down, top_k, norm_topk_prob=False,
     rank = jnp.zeros_like(order).at[order].set(
         jnp.arange(order.shape[0], dtype=order.dtype))
 
+    # the kernels' visits, once for the layer's nine matmuls
+    plan = None
+    if matmul_route(x.shape[1], w_gate.shape[2], dtype, mesh) \
+            == KERNEL_MATMUL:
+        from ..ops import expert_gmm
+        plan = expert_gmm.plan(sizes, order.shape[0])
     if total is None:
         rows, places = _dispatch(x.astype(dtype), order, rank), None
-        hidden = _gated(_grouped_matmul(rows, w_gate.astype(dtype), sizes),
-                        _grouped_matmul(rows, w_up.astype(dtype), sizes),
-                        activation)
-        y = _grouped_matmul(hidden, w_down.astype(dtype), sizes)
+        hidden = _gated(
+            _grouped_matmul(rows, w_gate.astype(dtype), sizes, plan),
+            _grouped_matmul(rows, w_up.astype(dtype), sizes, plan),
+            activation)
+        y = _grouped_matmul(hidden, w_down.astype(dtype), sizes, plan)
     else:
         places = _token_places(rank, total, top_k)
         y = _held_experts(x.astype(dtype), w_gate.astype(dtype),
                           w_up.astype(dtype), w_down.astype(dtype), order,
-                          rank, sizes, total, places, activation)
+                          rank, sizes, plan, total, places, activation)
     out = _combine(y, gate, order, rank, total, places)
 
     if scoring == "sigmoid":

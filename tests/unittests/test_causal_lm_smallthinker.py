@@ -316,9 +316,9 @@ def test_rows_past_the_groups_sum_reach_nothing(monkeypatch, tile):
     poison.defvjp(lambda y, total: (poison(y, total), total),
                   lambda total, g: (poison(g, total), None))
 
-    def unwritten(lhs, rhs, sizes):
+    def unwritten(lhs, rhs, sizes, plan=None):
         total = sizes.sum()
-        return poison(grouped(poison(lhs, total), rhs, sizes), total)
+        return poison(grouped(poison(lhs, total), rhs, sizes, plan), total)
 
     def run(x, a, wg, wu, wd):
         return moe.routed_ffn(x, router, wg[:4], wu[:4], wd[:4], top_k=6,

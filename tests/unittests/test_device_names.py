@@ -171,16 +171,17 @@ def test_an_at_sign_would_cut_the_op_name_short():
 
 def test_every_pallas_call_takes_its_name_from_kernel_names():
     from paddle_tpu.ops import causal_conv_kernels, embedding_grad, \
-        gated_delta_kernels, mhc_kernels
+        expert_gmm, gated_delta_kernels, mhc_kernels
     names = []
     for module in (pallas_kernels, gated_delta_kernels, causal_conv_kernels,
-                   embedding_grad, mhc_kernels):
+                   embedding_grad, mhc_kernels, expert_gmm):
         with open(module.__file__) as f:
             tree = ast.parse(f.read())
         # mhc_kernels' pallas_calls sit in two helpers that are handed the
-        # name: there the literal is the helper's second argument
-        helpers = {"_call", "_coeffs_call"} if module is mhc_kernels \
-            else set()
+        # name, and expert_gmm's forward and d rows in one: there the
+        # literal is the helper's second argument
+        helpers = {mhc_kernels: {"_call", "_coeffs_call"},
+                   expert_gmm: {"_rows_call"}}.get(module, set())
         for node in ast.walk(tree):
             if not isinstance(node, ast.Call):
                 continue
@@ -199,7 +200,8 @@ def test_every_pallas_call_takes_its_name_from_kernel_names():
                     % node.lineno
                 names.append(kw["name"].value)
     assert sorted(names) == sorted(pallas_kernels.KERNEL_NAMES)
-    assert len(set(names)) == len(names) == 22
+    assert pallas_kernels.EXPERT_MATMUL_KERNELS == expert_gmm.KERNELS
+    assert len(set(names)) == len(names) == 25
     for a in names:         # a reader matching `<name>` or `<name>.<n>`
         for b in names:     # never counts one kernel under another
             assert a == b or not (b + ".").startswith(a + ".")
@@ -1080,3 +1082,48 @@ def test_the_embeddings_backward_on_a_described_v5e(one_chip, monkeypatch,
             for name, op_name in calls] \
         == [("ptpu_embedding_grad", "lookup_table_grad")]
     assert " scatter(" not in text
+
+
+# --- the routed experts' grouped matmuls (PR 50) ----------------------------
+
+# rows, hidden, the experts' width, experts held: SmallThinker's (a share of
+# the rows in a group) and Xing4.0's (the widest matrix a cell has: 7 MiB)
+_EXPERT_CELLS = {"smallthinker": (49152, 2560, 768, 16),
+                 "xing4_0": (16384, 3584, 1024, 8)}
+
+
+@pytest.mark.parametrize("cell", sorted(_EXPERT_CELLS))
+def test_expert_gmm_kernels_on_a_described_v5e(one_chip, monkeypatch, cell):
+    """routed_ffn's nine matmuls at a cell's widths, compiled for a TPU: the
+    route is the kernels', Mosaic takes all three at both shapes (an
+    expert's matrix, its second buffer and d weights' float32 accumulator
+    inside the VMEM the kernels ask for), each is named from KERNEL_NAMES,
+    and no `ragged-dot` is left."""
+    from paddle_tpu.ops import kernel_config
+    from paddle_tpu.parallel import moe
+    rows, d, f, held = _EXPERT_CELLS[cell]
+    monkeypatch.delenv("PADDLE_TPU_PALLAS", raising=False)
+    monkeypatch.setattr(kernel_config, "dispatch_platform", lambda: "tpu")
+    assert moe.matmul_route(d, f, jnp.bfloat16) == moe.KERNEL_MATMUL
+
+    def layer(x, w_in, w_out, dy, sizes):
+        from paddle_tpu.ops import expert_gmm
+        plan = expert_gmm.plan(sizes, rows)
+        y, vjp = jax.vjp(
+            lambda x, w_in, w_out: moe._grouped_matmul(
+                moe._grouped_matmul(x, w_in, sizes, plan), w_out, sizes,
+                plan), x, w_in, w_out)
+        return y, vjp(dy)
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    text = _compile_uncached(
+        layer, sds((rows, d)), sds((held, d, f)), sds((held, f, d)),
+        sds((rows, d)), sds((held,), jnp.int32)).as_text()
+    calls = collections.Counter(
+        name.rpartition(".")[0] if name.rpartition(".")[2].isdigit()
+        else name for name in re.findall(
+            r'%?([\w.\-]+) = [^\n]*custom_call_target="tpu_custom_call"',
+            text))
+    assert calls == {name: 2 for name in pallas_kernels.EXPERT_MATMUL_KERNELS}
+    assert "ragged-dot" not in text
