@@ -15,6 +15,10 @@ Execution is whole-program XLA compilation (core/lowering.py), autodiff is
 jax.vjp over op lowering rules (core/backward.py), and multi-device runs ride
 jax.sharding Meshes (parallel/).
 """
+import time as _time
+_t_import = _time.perf_counter()    # this file's first line to its last:
+# ptpu_import_seconds{module="paddle_tpu"}, booked at the end
+
 # Sharding-invariant PRNG, process-wide: with the legacy (non-
 # partitionable) threefry, the SAME program traced under a tensor-
 # parallel mesh draws DIFFERENT random bits than single-device (XLA's
@@ -97,3 +101,6 @@ from .resilience import (Supervisor, TrainingAborted,
 Tensor = LoDTensor
 
 __version__ = "0.1.0"
+
+observability.registry.note_import(
+    "paddle_tpu", _time.perf_counter() - _t_import)

@@ -12,7 +12,7 @@ fan-out (the reference's inserted sum_op after @RENAME@ bookkeeping) is
 handled by emitting grad ops in reverse topological order and accumulating
 into <var>@GRAD at lowering time.
 """
-from .framework import grad_var_name, GRAD_SUFFIX
+from .framework import grad_var_name, GRAD_SUFFIX, build_phase
 from . import registry
 
 
@@ -48,10 +48,16 @@ def append_backward(loss, parameter_list=None, no_grad_set=None,
                     callbacks=None):
     """Append gradient ops for `loss` to its program.
 
-    Returns [(Parameter, grad Variable)] like the reference.
+    Returns [(Parameter, grad Variable)] like the reference. The span
+    `build/append_backward` is opened here and not in Optimizer.minimize,
+    so a caller of this function alone has it too.
     """
+    with build_phase("append_backward", loss.block.program):
+        return _append_backward(loss, parameter_list, no_grad_set)
+
+
+def _append_backward(loss, parameter_list, no_grad_set):
     block = loss.block
-    program = block.program
     no_grad = set(no_grad_set or ())
     for v in block.vars.values():
         if v.stop_gradient:

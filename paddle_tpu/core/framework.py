@@ -14,10 +14,14 @@ import itertools
 import os
 import re
 import sys
+import threading
+import time
 
 import numpy as np
 
 from . import unique_name
+from ..observability import trace as _trace
+from ..observability.registry import REGISTRY
 
 GRAD_SUFFIX = "@GRAD"
 
@@ -747,18 +751,91 @@ def switch_startup_program(program):
     return old
 
 
+_build_open = threading.local()     # .phases: the build_phases open on
+# the calling thread, outermost first
+
+
+def _op_count(programs):
+    return sum(len(b.ops) for p in programs for b in p.blocks)
+
+
+class build_phase(object):
+    """`with build_phase("append_backward", program):` is one phase of
+    building a Program, seen from inside: a span `build/<phase>` of
+    cat="build" whose parent is the build phase already open on the
+    thread (the outermost one mints the trace id they all share), and,
+    from the span's own two clock readings,
+    `ptpu_build_seconds_total{phase}` with `ptpu_build_ops_total{phase}`
+    beside it: the ops `programs` (the main and the startup program)
+    gained while it was open. No span an op (a benchmark cell appends
+    400 to 1,400): shape inference, the one costly thing an append_op
+    does, is booked by op type in registry.infer_and_set_shapes."""
+
+    __slots__ = ("phase", "programs", "span", "t0", "ops0")
+
+    def __init__(self, phase, *programs):
+        self.phase = phase
+        self.programs = tuple({id(p): p for p in programs}.values())
+
+    def __enter__(self):
+        phases = getattr(_build_open, "phases", None)
+        if phases is None:
+            phases = _build_open.phases = []
+        outer = phases[-1].span if phases else None
+        self.ops0 = _op_count(self.programs)
+        self.t0 = time.perf_counter()
+        self.span = _trace.span(
+            "build/" + self.phase, cat="build", _t0=self.t0,
+            trace=_trace.new_trace() if outer is None else outer.trace,
+            parent=None if outer is None else outer.sid)
+        phases.append(self)
+        return self
+
+    def __exit__(self, etype, exc, tb):
+        t1 = time.perf_counter()
+        _build_open.phases.pop()
+        ops = max(0, _op_count(self.programs) - self.ops0)
+        self.span.end(_t1=t1, ops=ops,
+                      **({"error": etype.__name__} if etype else {}))
+        REGISTRY.counter(
+            "ptpu_build_seconds_total",
+            "host seconds building Programs, by phase: program (the "
+            "outermost program_guard on a thread, what a benchmark times "
+            "from outside as build_s), minimize (Optimizer.minimize) and "
+            "its parts append_backward, clip, regularize, optimize_pass; "
+            "a phase's seconds hold its children's").inc(
+                t1 - self.t0, phase=self.phase)
+        REGISTRY.counter(
+            "ptpu_build_ops_total",
+            "ops the main and the startup program gained while a build "
+            "phase of ptpu_build_seconds_total was open").inc(
+                ops, phase=self.phase)
+        return False
+
+
+def in_build_phase():
+    """Whether a build_phase is open on the calling thread."""
+    return bool(getattr(_build_open, "phases", None))
+
+
 @contextlib.contextmanager
 def program_guard(main_program, startup_program=None):
-    old_main = switch_main_program(main_program)
-    old_startup = None
-    if startup_program is not None:
-        old_startup = switch_startup_program(startup_program)
-    try:
-        yield
-    finally:
-        switch_main_program(old_main)
-        if old_startup is not None:
-            switch_startup_program(old_startup)
+    # the OUTERMOST guard on a thread is build/program; one opened under
+    # a build phase (minimize's, a control-flow layer's, an LR schedule's)
+    # is part of that phase: no span, and no second count
+    with contextlib.nullcontext() if in_build_phase() else build_phase(
+            "program", main_program,
+            startup_program or default_startup_program()):
+        old_main = switch_main_program(main_program)
+        old_startup = None
+        if startup_program is not None:
+            old_startup = switch_startup_program(startup_program)
+        try:
+            yield
+        finally:
+            switch_main_program(old_main)
+            if old_startup is not None:
+                switch_startup_program(old_startup)
 
 
 def get_var(name, program=None):

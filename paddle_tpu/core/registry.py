@@ -12,7 +12,11 @@ lowering rule under jax.eval_shape on ShapeDtypeStructs. A custom `infer`
 can override for ops whose output shape can't be derived that way
 (data-dependent shapes, sub-block ops).
 """
+import time
+
 import numpy as np
+
+from ..observability.registry import REGISTRY
 
 # sentinels substituted for the dynamic batch dim (-1) during abstract shape
 # inference. Outputs are inferred under BOTH primes; any output dim that
@@ -217,22 +221,43 @@ def infer_and_set_shapes(block, op):
     if not is_registered(op.type):
         return  # ops lowered specially (grad_of, control-flow) set shapes themselves
     od = get(op.type)
-    out_vars = {slot: [block.var_recursive(n) for n in names]
-                for slot, names in op.outputs.items()}
-    if od.infer is not None:
-        od.infer(block, op, out_vars)
-        return
-    res = abstract_eval(block, op)
-    if res is None:
-        return
-    for slot, entries in res.items():
-        for var, entry in zip(out_vars[slot], entries):
-            if entry is None:
-                continue
-            public, (shape_a, shape_b), dtype = entry
-            var.shape = public
-            # keep the exact sentinel shapes for downstream inference (a -1
-            # re-substitution would lose folded batch products); the public
-            # snapshot invalidates the record if anything reassigns shape
-            var._abstract_shapes = (shape_a, shape_b, var.shape)
-            var.dtype = dtype
+    # the build phase's twin of lower_op's clock: every
+    # append_op(infer_shape=True) and prepend_op passes here and nowhere
+    # else. The clock sits in this function and in no wrapper around it:
+    # jax records the Python stack with every equation eval_shape traces
+    t0 = time.perf_counter()
+    try:
+        out_vars = {slot: [block.var_recursive(n) for n in names]
+                    for slot, names in op.outputs.items()}
+        if od.infer is not None:
+            od.infer(block, op, out_vars)
+            return
+        res = abstract_eval(block, op)
+        if res is None:
+            return
+        for slot, entries in res.items():
+            for var, entry in zip(out_vars[slot], entries):
+                if entry is None:
+                    continue
+                public, (shape_a, shape_b), dtype = entry
+                var.shape = public
+                # keep the exact sentinel shapes for downstream inference
+                # (a -1 re-substitution would lose folded batch products);
+                # the public snapshot invalidates the record if anything
+                # reassigns shape
+                var._abstract_shapes = (shape_a, shape_b, var.shape)
+                var.dtype = dtype
+    finally:
+        seconds = time.perf_counter() - t0
+        how = "custom" if od.infer is not None else "eval_shape"
+        REGISTRY.counter(
+            "ptpu_infer_shape_seconds_total",
+            "host seconds of shape inference while a Program is built, by "
+            "op type and by how: eval_shape (the lowering rule traced "
+            "abstractly, twice where a dim is -1; what jax reports of "
+            "that trace lies inside these seconds) or custom (the op's "
+            "own OpDef.infer)").inc(seconds, op=op.type, how=how)
+        REGISTRY.counter(
+            "ptpu_infer_shape_calls_total",
+            "ops counted into ptpu_infer_shape_seconds_total").inc(
+                op=op.type, how=how)

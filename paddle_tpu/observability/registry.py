@@ -34,7 +34,8 @@ import threading
 import weakref
 
 __all__ = ["Counter", "Gauge", "Histogram", "MetricsRegistry",
-           "REGISTRY", "note_window", "note_batcher", "note_decoder",
+           "REGISTRY", "note_import", "note_window", "note_batcher",
+           "note_decoder",
            "watch_cluster",
            "serve_metrics", "MetricsServer", "write_textfile"]
 
@@ -275,6 +276,20 @@ class MetricsRegistry(object):
 
 
 REGISTRY = MetricsRegistry()
+
+
+def note_import(module, seconds):
+    """`ptpu_import_seconds{module}`: what a restart pays before the first
+    line of the user's program runs. Set once a process by the module
+    itself: paddle_tpu/__init__.py and ops/pallas_import.py."""
+    REGISTRY.gauge(
+        "ptpu_import_seconds",
+        "seconds a module took to import, once a process: paddle_tpu (the "
+        "first line of its __init__ to the last; jax's own import is in "
+        "it only where the caller had not imported jax before) and "
+        "jax.experimental.pallas (ops/pallas_import.py; absent until the "
+        "first op that needs a kernel imports it, and then inside whatever "
+        "was building or lowering that op)").set(seconds, module=module)
 
 
 # ---------------------------------------------------------------------------

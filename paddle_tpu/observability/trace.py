@@ -255,10 +255,14 @@ class FlightRecorder(object):
         with self._open_lock:
             self._open.pop(sp.sid, None)
 
-    def span(self, name, cat="runtime", trace=None, parent=None, **args):
+    def span(self, name, cat="runtime", trace=None, parent=None, _t0=None,
+             **args):
+        """`_t0`: a time.perf_counter reading the caller has taken and
+        books a counter from too (`Span.end(_t1=)` is its twin), so that
+        span and counter hold the same seconds."""
         if not self.enabled:
             return _NOOP
-        return Span(self, name, cat, trace, parent, args)
+        return Span(self, name, cat, trace, parent, args, t0=_t0)
 
     def instant(self, name, cat="event", trace=None, **args):
         if not self.enabled:
@@ -325,22 +329,25 @@ def recorder():
     return _recorder
 
 
-def configure(capacity=None, enabled=None):
-    """Swap in a fresh ring (tests / benches scope a window with it).
-    Returns the active recorder."""
+def configure(capacity=None):
+    """Swap in a fresh ring (tests scope a window with it); on or off as
+    the one before it was. Returns the active recorder."""
     global _recorder
     if capacity is not None:
         rec = FlightRecorder(capacity)
         rec.enabled = _recorder.enabled
         _recorder = rec
-    if enabled is not None:
-        _recorder.enabled = bool(enabled)
     return _recorder
 
 
 def set_enabled(flag):
-    """Overhead A/B switch. The recorder defaults ON and is
-    meant to stay on — spans are host timestamps into a bounded ring."""
+    """THE on/off switch, for reading what the recorder costs: the same
+    process run with it on and off (PERF.md section 6, PR 51, did so for
+    `setup_s` and `build_s`). Off, `span()` returns a no-op span and
+    `instant()` records nothing; counters that code books beside a span
+    (core/framework.build_phase) are booked all the same. The recorder
+    defaults ON and is meant to stay on — spans are host timestamps
+    into a bounded ring."""
     _recorder.enabled = bool(flag)
 
 
@@ -373,14 +380,14 @@ def scope_trace(trace_id):
         _ambient_tls.trace = prev
 
 
-def span(name, cat="runtime", trace=None, parent=None, **args):
+def span(name, cat="runtime", trace=None, parent=None, _t0=None, **args):
     """trace=None inherits the thread's ambient trace (scope_trace) —
     how the engine's pad/enqueue spans land in their batch's trace
     without threading an id through every call signature."""
     if trace is None:
         trace = ambient()
     return _recorder.span(name, cat=cat, trace=trace, parent=parent,
-                          **args)
+                          _t0=_t0, **args)
 
 
 def instant(name, cat="event", trace=None, **args):

@@ -51,8 +51,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
+from .pallas_import import pl
+from .pallas_import import pltpu
 
 from . import kernel_config
 

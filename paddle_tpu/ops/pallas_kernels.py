@@ -37,8 +37,8 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
+from .pallas_import import pl
+from .pallas_import import pltpu
 
 from .kernel_config import DEFAULT_TILES, dispatch_platform
 

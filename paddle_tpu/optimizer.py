@@ -8,8 +8,9 @@ in-place TPU update.
 """
 from collections import defaultdict
 
-from .core.framework import (Variable, Parameter, default_main_program,
-                             default_startup_program, program_guard)
+from .core.framework import (Variable, Parameter, build_phase,
+                             default_main_program, default_startup_program,
+                             program_guard)
 from .core.layer_helper import LayerHelper
 from .core.initializer import ConstantInitializer
 from .core.backward import append_backward
@@ -191,15 +192,20 @@ class Optimizer(object):
 
     def minimize(self, loss, startup_program=None, parameter_list=None,
                  no_grad_set=None):
-        params_grads = append_backward(loss, parameter_list, no_grad_set)
         from .clip import append_gradient_clip_ops
-        with program_guard(loss.block.program, startup_program or
-                           default_startup_program()):
-            params_grads = append_gradient_clip_ops(params_grads)
-            params_grads = regularizer_mod.append_regularization_ops(
-                params_grads, self.regularization)
-        optimize_ops = self._create_optimization_pass(
-            params_grads, loss, startup_program)
+        programs = (loss.block.program,
+                    startup_program or default_startup_program())
+        with build_phase("minimize", *programs):
+            params_grads = append_backward(loss, parameter_list, no_grad_set)
+            with program_guard(*programs):
+                with build_phase("clip", *programs):
+                    params_grads = append_gradient_clip_ops(params_grads)
+                with build_phase("regularize", *programs):
+                    params_grads = regularizer_mod.append_regularization_ops(
+                        params_grads, self.regularization)
+            with build_phase("optimize_pass", *programs):
+                optimize_ops = self._create_optimization_pass(
+                    params_grads, loss, startup_program)
         return optimize_ops, params_grads
 
 

@@ -300,7 +300,7 @@ def profile_report(sorted_key=None, json=False):
                              for kv in sorted(ss["by_tag"].items()))))
         lines.extend(_embedding_lines())
         lines.extend(_softmax_xent_lines())
-        lines.extend(_lowering_lines())
+        lines.extend(_restart_tables())
     return "\n".join(lines)
 
 
@@ -332,23 +332,66 @@ def _softmax_xent_lines():
     return lines
 
 
-def _lowering_lines(limit=10):
-    """The "Lowering(s) by op type" block: what the trace phase of a first
-    run is made of (`ptpu_lowering_seconds_total`), most seconds first."""
+def _seconds_table(title, family, label, limit=10):
+    """One "<... by op type>  Seconds  %" block of the report: a registry
+    counter's seconds summed by `label` (a tuple of labels reads "a/b"),
+    most seconds first, the rows past `limit` as one."""
     from .observability.registry import REGISTRY
-    rows = sorted(((dict(key)["op"], v) for key, v in REGISTRY.counter(
-        "ptpu_lowering_seconds_total").samples()), key=lambda r: -r[1])
+    names = (label,) if isinstance(label, str) else label
+    by = {}
+    for key, v in REGISTRY.counter(family).samples():
+        row = "/".join(dict(key)[n] for n in names)
+        by[row] = by.get(row, 0.0) + v
+    rows = sorted(by.items(), key=lambda r: -r[1])
     if not rows:
         return []
-    total = sum(v for _, v in rows)
-    lines = ["%-40s %12s %7s" % ("Lowering(s) by op type", "Seconds", "%")]
-    lines += ["%-40s %12.4f %7.2f" % (op[:40], v, 100.0 * v / total)
-              for op, v in rows[:limit]]
+    total = sum(v for _, v in rows) or 1.0
+    lines = ["%-40s %12s %7s" % (title, "Seconds", "%")]
+    lines += ["%-40s %12.4f %7.2f" % (row[:40], v, 100.0 * v / total)
+              for row, v in rows[:limit]]
     if len(rows) > limit:
         rest = sum(v for _, v in rows[limit:])
         lines.append("%-40s %12.4f %7.2f" % (
             "(%d more op types)" % (len(rows) - limit), rest,
             100.0 * rest / total))
+    return lines
+
+
+def _restart_tables():
+    """The report's last blocks, one reader for both halves of a restart:
+    "Lowering(s) by op type", what the trace phase of a first run is made
+    of (`ptpu_lowering_seconds_total`), then what building the Programs
+    was made of: "Build(s) by phase" (`ptpu_build_seconds_total`; a phase
+    holds its children, so the column is no sum: % is of `program`) and
+    "Shape inference(s) by op type" (`ptpu_infer_shape_seconds_total`, a
+    custom `infer` as `<op>/custom`)."""
+    lines = _seconds_table("Lowering(s) by op type",
+                           "ptpu_lowering_seconds_total", "op")
+    lines += _build_phase_lines()
+    lines += _seconds_table("Shape inference(s) by op type",
+                            "ptpu_infer_shape_seconds_total", ("op", "how"))
+    return lines
+
+
+_BUILD_PHASES = ("program", "minimize", "append_backward", "clip",
+                 "regularize", "optimize_pass")
+
+
+def _build_phase_lines():
+    from .observability.registry import REGISTRY
+    seconds = {dict(k)["phase"]: v for k, v in REGISTRY.counter(
+        "ptpu_build_seconds_total").samples()}
+    ops = {dict(k)["phase"]: v for k, v in REGISTRY.counter(
+        "ptpu_build_ops_total").samples()}
+    if not seconds:
+        return []
+    whole = seconds.get("program") or max(seconds.values()) or 1.0
+    lines = ["%-40s %12s %7s %9s" % ("Build(s) by phase", "Seconds", "%",
+                                     "Ops")]
+    lines += ["%-40s %12.4f %7.2f %9d" % (
+        ("" if ph in ("program", "minimize") else "  ") + ph, seconds[ph],
+        100.0 * seconds[ph] / whole, ops.get(ph, 0))
+        for ph in _BUILD_PHASES if ph in seconds]
     return lines
 
 

@@ -74,7 +74,10 @@ READERS = sorted(
     glob.glob(os.path.join(REPO, "benchmark", "layer_metrics", "*.py"))
     + glob.glob(os.path.join(REPO, "benchmark", "configs", "*.py"))
     + [os.path.join(REPO, "benchmark", "program_reads.py"),
+       os.path.join(REPO, "benchmark", "registry_reads.py"),
        os.path.join(REPO, "benchmark", "kernel_ms.py")])
+# families that are no counter (the others end in _total): PR 51's gauge
+GAUGES = ("ptpu_import_seconds",)
 
 
 def _spelled():
@@ -107,8 +110,8 @@ def test_a_name_the_benchmark_reads_is_one_the_program_defines(name):
     families, spans = _declared()
     if name.startswith("exec/"):
         assert name in spans, "no span %r under paddle_tpu/" % name
-    elif name.endswith("_total"):
-        assert name in families, "no counter family %r" % name
+    elif name.endswith("_total") or name in GAUGES:
+        assert name in families, "no counter or gauge family %r" % name
     else:
         assert name in KERNEL_NAMES, "no Pallas kernel named %r" % name
 
@@ -119,6 +122,29 @@ def test_the_readers_spell_some_names():
     assert any(n.startswith("exec/") for n in names)
     assert any(n.endswith("_total") for n in names)
     assert len(names) >= 10
+    # what set-up's six readers spell (PR 51)
+    assert {"ptpu_build_seconds_total", "ptpu_infer_shape_seconds_total",
+            "ptpu_import_seconds"} <= set(names)
+
+
+@pytest.mark.parametrize("label,value", [
+    ("phase", "program"), ("phase", "append_backward"), ("phase", "clip"),
+    ("phase", "regularize"), ("phase", "optimize_pass"),
+    ("module", "paddle_tpu"), ("module", "jax.experimental.pallas")])
+def test_a_label_value_the_build_readers_spell_is_one_the_program_books(
+        label, value):
+    """A reader that asks for `phase="optimise_pass"` reads 0.0 for ever."""
+    spelled = "".join(_read(p) for p in READERS
+                      if os.path.basename(p) in (
+                          "program_build_s.py", "append_backward_s.py",
+                          "optimizer_pass_s.py", "package_import_s.py",
+                          "pallas_import_s.py"))
+    assert '"%s"' % value in spelled
+    booked = "".join(_read(os.path.join(REPO, "paddle_tpu", p)) for p in (
+        "core/framework.py", "core/backward.py", "optimizer.py",
+        "__init__.py", "ops/pallas_import.py"))
+    assert re.search(r'build_phase\(\s*"%s"' % value, booked) \
+        if label == "phase" else '"%s"' % value in booked
 
 
 # -------------------------------------------------- (c) one way to measure --

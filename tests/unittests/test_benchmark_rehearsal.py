@@ -22,8 +22,11 @@ import pytest
 REPO = os.path.dirname(os.path.dirname(
     os.path.dirname(os.path.abspath(__file__))))
 RUN = os.path.join(REPO, "benchmark", "run.py")
-# tiny_host_phases lists two of tiny's workloads again under other readers
-REPEATS = ("tiny_host_phases",)
+# tiny_host_phases and tiny_build_phases list some of tiny's workloads again
+# under other readers
+REPEATS = ("tiny_host_phases", "tiny_build_phases")
+BUILD_PHASES = os.path.join(REPO, "benchmark", "tests", "tiny_build_phases",
+                            "manifest.json")
 # --seconds where 1 is too few: tiny_hostu8's loss has to fall over three
 # rotating batches, which takes two blocks of two steps. Its own test under
 # benchmark/tests passes 5; beside five other test workers one block alone
@@ -44,8 +47,7 @@ def _cells():
     return cells
 
 
-@pytest.mark.parametrize("manifest,workload,chips", _cells())
-def test_rehearsal(manifest, workload, chips, tmp_path):
+def _rehearse(manifest, workload, chips, tmp_path, trace=0):
     env = dict(os.environ, JAX_PLATFORMS="cpu",
                XLA_FLAGS="--xla_force_host_platform_device_count=%d" % chips,
                # a cache of this run's own: nothing an earlier tree left
@@ -54,7 +56,7 @@ def test_rehearsal(manifest, workload, chips, tmp_path):
     proc = subprocess.run(
         [sys.executable, RUN, "--manifest", manifest, "--workload", workload,
          "--seed", "5", "--seconds", str(SECONDS.get(workload, 1)),
-         "--rehearse", "--trace", "0"],
+         "--rehearse", "--trace", str(trace)],
         env=env, capture_output=True, text=True, timeout=600)
     assert proc.returncode == 0, proc.stdout[-2000:] + proc.stderr[-3000:]
     out = json.loads(proc.stdout.strip().splitlines()[-1])
@@ -62,3 +64,26 @@ def test_rehearsal(manifest, workload, chips, tmp_path):
     assert out["failed"] == 0 and out["attempted"] > 0, out
     assert out["device"]["platform"] == "cpu"
     assert out["device"]["count"] == chips
+    return out
+
+
+@pytest.mark.parametrize("manifest,workload,chips", _cells())
+def test_rehearsal(manifest, workload, chips, tmp_path):
+    _rehearse(manifest, workload, chips, tmp_path)
+
+
+def test_a_traced_rehearsal_prints_the_six_of_set_up(tmp_path):
+    """PR 51's per-layer metrics are `program_counter`s, which a CPU run
+    prints: under --trace 1 the line has all six, through
+    ParallelExecutor on four devices here (test_build_phases.py runs the
+    one-device workloads of the same manifest and holds the numbers to the
+    set-up line)."""
+    with open(BUILD_PHASES) as f:
+        listed = [m["name"] for m in json.load(f)["per_layer"]]
+    six = ["program_build_s", "infer_shape_s", "append_backward_s",
+           "optimizer_pass_s", "package_import_s", "pallas_import_s"]
+    assert listed[-6:] == six
+    got = _rehearse(BUILD_PHASES, "tiny_dp4", 4, tmp_path, trace=1)["metrics"]
+    assert set(six) <= set(got)
+    assert all(got[n]["unit"] == "s" and got[n]["value"] >= 0 for n in six)
+    assert got["program_build_s"]["value"] > got["infer_shape_s"]["value"] > 0

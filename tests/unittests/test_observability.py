@@ -43,9 +43,11 @@ REPO = os.path.dirname(os.path.dirname(
 @pytest.fixture(autouse=True)
 def _fresh_recorder():
     """Each test gets its own bounded ring; always-on is restored."""
-    trace.configure(capacity=4096, enabled=True)
+    trace.set_enabled(True)
+    trace.configure(capacity=4096)
     yield
-    trace.configure(capacity=4096, enabled=True)
+    trace.set_enabled(True)
+    trace.configure(capacity=4096)
 
 
 # ---------------------------------------------------------------------------
@@ -99,8 +101,14 @@ def test_disabled_recorder_is_noop():
     assert sp.child("y") is sp
     sp.end()
     trace.instant("z")
+    # a fresh ring is on or off as the one before it: set_enabled is the
+    # one switch (configure(enabled=) went with PR 51)
+    assert trace.configure(capacity=64).enabled is False
+    assert trace.span("x2") is sp
     trace.set_enabled(True)
     assert trace.dump()["events"] == []
+    with pytest.raises(TypeError):
+        trace.configure(capacity=64, enabled=True)
 
 
 def test_end_open_closes_a_trace_not_others():
@@ -456,7 +464,7 @@ def test_training_prefetch_steps_k_trace_bit_exact_vs_recorder_off(
     overlapping on the background thread."""
     path = _make_recordio(tmp_path, n=12)
     profiler.reset_profiler()
-    trace.configure(capacity=4096, enabled=True)
+    trace.configure(capacity=4096)
     trace.clear()
     o_on, s_on = _train_to_eof(path, steps=3)
     d = trace.dump()
