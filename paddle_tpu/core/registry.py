@@ -9,8 +9,21 @@ jax.vjp (see core/lowering.py) so no per-op grad kernels exist at all.
 
 Shape inference (the reference's InferShape methods) is generic: run the
 lowering rule under jax.eval_shape on ShapeDtypeStructs. A custom `infer`
-can override for ops whose output shape can't be derived that way
-(data-dependent shapes, sub-block ops).
+overrides it in two cases. (1) The output shape can't be derived that way:
+data-dependent shapes, sub-block ops. (2) Tracing costs more than the answer
+is worth: a rule that holds a Pallas kernel, a loop or more than a few
+milliseconds of trace, and whose outputs are shaped like its inputs, writes
+the shapes down (`shapes_from`, a table of output slot -> input slot;
+`set_like` under it). The trace is the whole rule, kernel body included,
+twice where a dim is -1, once an op, and the step traces the same rule
+again: fused_attention cost 2.7 s of an 18-layer program's build that way
+(PERF.md section 6, PR 53). Such an `infer` must equal what tracing gives
+for every program, -1 and the sentinel shapes included
+(tests/unittests/test_infer_parity.py holds each to `abstract_eval`); it
+imports no kernel module; the static analyzer, which re-derives shapes by
+`abstract_eval`, skips an op that has one. "Zero per-op code in the common
+case" stands: an elementwise op, a matmul, a reshape trace in a millisecond
+or two and have no `infer`.
 """
 import time
 
@@ -146,6 +159,47 @@ def _struct_for(var, idx=0):
     sentinel = (BATCH_SENTINEL, BATCH_SENTINEL_B)[idx]
     shape = tuple(sentinel if d == -1 else d for d in var.shape)
     return jax.ShapeDtypeStruct(shape, np.dtype(var.dtype))
+
+
+def set_like(var, src, reshape=None, dtype=None):
+    """Declare the output Variable `var` from the input Variable `src`, with
+    nothing traced: src's shape (through `reshape`, a function of a shape
+    tuple, where given) and src's dtype (or `dtype`). What tracing the rule
+    would have recorded, -1 and the sentinel shapes included: `reshape` runs
+    on src's two sentinel shapes and a dim that differs between them is -1,
+    so a folded batch product (a norm's [-1 * T] statistics) stays one."""
+    import jax
+    structs = [_struct_for(src, idx) for idx in (0, 1)]
+    if structs[0] is None:
+        return  # un-inferable input, as abstract_eval leaves it
+    shape_a, shape_b = (
+        tuple(int(d) for d in (reshape(st.shape) if reshape else st.shape))
+        for st in structs)
+    var.shape = tuple(-1 if a != b else a for a, b in zip(shape_a, shape_b))
+    var._abstract_shapes = (shape_a, shape_b, var.shape)
+    var.dtype = np.dtype(jax.dtypes.canonicalize_dtype(
+        np.dtype(src.dtype if dtype is None else dtype))).name
+
+
+def shapes_from(**slots):
+    """An `OpDef.infer` written as a table: output slot -> the input slot it
+    is shaped and typed like, or (input slot, reshape(shape, attrs)[, dtype])
+    where it is not quite (`set_like`). For a rule that holds
+    a kernel, a loop or more than a few milliseconds of trace and whose
+    outputs' shapes are its inputs': tracing it to learn them costs the
+    whole rule, twice where a dim is -1, once an op (module docstring)."""
+    def infer(block, op, out_vars):
+        for slot, spec in slots.items():
+            source, reshape, dtype = (
+                ((spec,) if isinstance(spec, str) else spec)
+                + (None, None))[:3]
+            if not op.inputs.get(source):
+                continue
+            src = block.var_recursive(op.inputs[source][0])
+            for var in out_vars.get(slot, ()):
+                set_like(var, src, reshape and (
+                    lambda shape: reshape(shape, op.attrs)), dtype)
+    return infer
 
 
 def abstract_eval(block, op):

@@ -356,8 +356,6 @@ def causal_conv1d(input, kernel_size, act=None, param_attr=None, name=None):
         type="causal_conv1d", inputs={"X": [input], "Filter": [w]},
         outputs={"Out": [out]},
         attrs={"activation": act} if act else {})
-    if input.shape is not None:
-        out.shape = tuple(input.shape)
     return out
 
 
@@ -376,8 +374,6 @@ def gated_delta_rule(q, k, v, g, beta, name=None):
         type="gated_delta_rule",
         inputs={"Q": [q], "K": [k], "V": [v], "G": [g], "Beta": [beta]},
         outputs={"Out": [out]}, attrs={})
-    if v.shape is not None:
-        out.shape = tuple(v.shape)
     return out
 
 
@@ -745,9 +741,6 @@ def fused_attention(q, k, v, causal=False, scale=None, kv_len=None,
     helper.append_op(
         type="fused_attention", inputs=inputs,
         outputs={"Out": [out]}, attrs=attrs)
-    if q.shape is not None:
-        out.shape = tuple(q.shape) if q_rope is None or v.shape is None \
-            else tuple(q.shape[:-1]) + (v.shape[-1],)
     return out
 
 
@@ -795,10 +788,6 @@ def mhc_pre(x, streams, sinkhorn_iters=20, epsilon=1e-6, clamp=(-30.0, 30.0),
         attrs={"streams": n, "sinkhorn_iters": int(sinkhorn_iters),
                "epsilon": float(epsilon), "clamp_min": float(clamp[0]),
                "clamp_max": float(clamp[1])})
-    if x.shape is not None:
-        out.shape = tuple(x.shape[:-1]) + (width // n,)
-        coef.shape = tuple(x.shape[:-1]) + (128,)
-        stream.shape = tuple(x.shape)
     return out, coef, stream
 
 
@@ -811,8 +800,6 @@ def mhc_post(x, y, coef, streams, name=None):
     helper.append_op(type="mhc_post",
                      inputs={"X": [x], "Y": [y], "Coef": [coef]},
                      outputs={"Out": [out]}, attrs={"streams": int(streams)})
-    if x.shape is not None:
-        out.shape = tuple(x.shape)
     return out
 
 
@@ -823,8 +810,6 @@ def mhc_expand(x, streams, name=None):
     out = helper.create_variable_for_type_inference(x.dtype)
     helper.append_op(type="mhc_expand", inputs={"X": [x]},
                      outputs={"Out": [out]}, attrs={"streams": int(streams)})
-    if x.shape is not None:
-        out.shape = tuple(x.shape[:-1]) + (int(x.shape[-1]) * int(streams),)
     return out
 
 
@@ -834,8 +819,6 @@ def mhc_reduce(x, streams, name=None):
     out = helper.create_variable_for_type_inference(x.dtype)
     helper.append_op(type="mhc_reduce", inputs={"X": [x]},
                      outputs={"Out": [out]}, attrs={"streams": int(streams)})
-    if x.shape is not None:
-        out.shape = tuple(x.shape[:-1]) + (int(x.shape[-1]) // int(streams),)
     return out
 
 

@@ -6,7 +6,7 @@ import numpy as np
 
 import jax.numpy as jnp
 
-from ..core.registry import register, single
+from ..core.registry import register, shapes_from, single
 from .kernel_config import pallas_on
 
 
@@ -26,7 +26,17 @@ def _kernels(ctx, shape, attrs):
     return mhc_path(ctx.mesh, shape, attrs["streams"]) == "kernel"
 
 
-@register("mhc_pre", calls_pallas=True)
+def _one_stream(shape, attrs):
+    return shape[:-1] + (shape[-1] // attrs["streams"],)
+
+
+def _all_streams(shape, attrs):
+    return shape[:-1] + (shape[-1] * attrs["streams"],)
+
+
+@register("mhc_pre", calls_pallas=True, infer=shapes_from(
+    Out=("X", _one_stream), Stream="X",
+    Coef=("X", lambda shape, attrs: shape[:-1] + (128,), "float32")))
 def _mhc_pre(ctx, ins, attrs):
     """Out [..., C], the streams X [..., n*C] read into a sub-layer by H_pre;
     Coef [..., 128] float32, the token's H_pre, H_post and H_res (columns
@@ -45,7 +55,7 @@ def _mhc_pre(ctx, ins, attrs):
     return {"Out": [h], "Coef": [coef], "Stream": [stream]}
 
 
-@register("mhc_post", calls_pallas=True)
+@register("mhc_post", calls_pallas=True, infer=shapes_from(Out="X"))
 def _mhc_post(ctx, ins, attrs):
     """Out[i] = sum_j H_res[i, j] X[j] + H_post[i] Y: the streams mixed and
     the sub-layer's output Y [..., C] written into them, by mhc_pre's
@@ -58,7 +68,8 @@ def _mhc_post(ctx, ins, attrs):
                                      _kernels(ctx, x.shape, attrs))]}
 
 
-@register("mhc_expand", calls_pallas=True)
+@register("mhc_expand", calls_pallas=True,
+          infer=shapes_from(Out=("X", _all_streams)))
 def _mhc_expand(ctx, ins, attrs):
     """X [..., C] -> `streams` copies side by side, the streams' start.
     Under AMP the streams are bfloat16 from here on: a layer's passes over
@@ -72,7 +83,8 @@ def _mhc_expand(ctx, ins, attrs):
     return {"Out": [mhc_kernels.expand(x, n, _kernels(ctx, wide, attrs))]}
 
 
-@register("mhc_reduce", calls_pallas=True)
+@register("mhc_reduce", calls_pallas=True,
+          infer=shapes_from(Out=("X", _one_stream)))
 def _mhc_reduce(ctx, ins, attrs):
     """X [..., streams * C] -> the streams' sum [..., C], the readout."""
     from . import mhc_kernels

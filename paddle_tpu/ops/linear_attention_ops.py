@@ -5,7 +5,7 @@ is LoD-based and dense over channels."""
 import jax
 import jax.numpy as jnp
 
-from ..core.registry import register, single
+from ..core.registry import register, shapes_from, single
 from .kernel_config import pallas_on
 
 
@@ -16,7 +16,7 @@ def gated_delta_path():
     return "kernel" if pallas_on("gdr") else "scan"
 
 
-@register("gated_delta_rule", calls_pallas=True)
+@register("gated_delta_rule", calls_pallas=True, infer=shapes_from(Out="V"))
 def _gated_delta_rule(ctx, ins, attrs):
     """Out [B, T, Hv, dv] of the gated delta rule for Q, K [B, T, Hk, dk],
     V [B, T, Hv, dv], G (log decay) and Beta [B, T, Hv]. Under AMP the
@@ -41,7 +41,7 @@ def causal_conv_path(x, w):
     return "kernel" if fits and pallas_on("conv") else "xla"
 
 
-@register("causal_conv1d", calls_pallas=True)
+@register("causal_conv1d", calls_pallas=True, infer=shapes_from(Out="X"))
 def _causal_conv1d(ctx, ins, attrs):
     """y_t[c] = sum_m w[c, m] x_(t-K+1+m)[c] over X [B, T, C] with Filter
     [C, K], zeros before the sequence, then `activation` ("silu" or none):

@@ -18,7 +18,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ..core.registry import register, single
+from ..core.registry import register, set_like, shapes_from, single
 from ..core.utils import pair as _pair
 from .kernel_config import flash_at, pallas_on
 
@@ -181,7 +181,11 @@ def _maxout(ctx, ins, attrs):
 # normalization
 # ---------------------------------------------------------------------------
 
-@register("batch_norm")
+# Mean and Variance are the running statistics, [C] float32 as the layer
+# makes them; the batch's own (train mode) have their shape and dtype
+@register("batch_norm", infer=shapes_from(
+    Y="X", MeanOut="Mean", VarianceOut="Variance", SavedMean="Mean",
+    SavedVariance="Variance"))
 def _batch_norm(ctx, ins, attrs):
     x = single(ins, "X")          # NCHW or NC
     scale = single(ins, "Scale")  # [C]
@@ -224,7 +228,13 @@ def _batch_norm(ctx, ins, attrs):
             "SavedMean": [saved_mean], "SavedVariance": [saved_var]}
 
 
-@register("layer_norm", calls_pallas=True)
+def _layer_norm_rows(shape, attrs):
+    return (int(np.prod(shape[:attrs.get("begin_norm_axis", 1)])),)
+
+
+@register("layer_norm", calls_pallas=True, infer=shapes_from(
+    Y="X", Mean=("X", _layer_norm_rows, "float32"),
+    Variance=("X", _layer_norm_rows, "float32")))
 def _layer_norm(ctx, ins, attrs):
     x = single(ins, "X")
     scale = single(ins, "Scale")
@@ -424,7 +434,9 @@ def softmax_xent_form(ctx, logits, attrs):
 
 
 @register("softmax_with_cross_entropy", calls_pallas=True,
-          optional_outputs=("Softmax",))
+          optional_outputs=("Softmax",), infer=shapes_from(
+              Softmax="Logits",
+              Loss=("Logits", lambda shape, attrs: shape[:-1] + (1,))))
 def _softmax_xent(ctx, ins, attrs):
     logits = single(ins, "Logits")
     label = single(ins, "Label")
@@ -455,7 +467,28 @@ def _softmax_xent(ctx, ins, attrs):
             "Loss": [loss.astype(logits.dtype)]}
 
 
-@register("fused_attention", calls_pallas=True)
+def _fused_attention_infer(block, op, out_vars):
+    """Out is Q's [B, T, Hq] on V's last dim (the latent forms' value may be
+    another width than the part without position), in V's dtype off the
+    dense path and Q's off the flash kernels. Traced to learn that, the
+    flash kernels' bodies cost 2.7 s of an 18-layer program's build (PERF.md
+    section 6, PR 53). A latent head at widths the flash kernels refuse is
+    refused here, while the program is built, by the kernels' own check."""
+    q, v = (block.var_recursive(op.inputs[s][0]) for s in ("Q", "V"))
+    if q.shape is None or v.shape is None:
+        return
+    flash = q.shape[1] != -1 and flash_at(q.shape[1])
+    if flash and op.inputs.get("QRope") and q.shape[-1] != v.shape[-1]:
+        # the one latent form that needs asking; the module is a kernel's
+        from .pallas_kernels import latent_form
+        rope = block.var_recursive(op.inputs["QRope"][0])
+        latent_form(q.shape[-1], rope.shape[-1], v.shape[-1])
+    for out in out_vars.get("Out", ()):
+        set_like(out, q, lambda shape: shape[:-1] + (v.shape[-1],),
+                 q.dtype if flash else v.dtype)
+
+
+@register("fused_attention", calls_pallas=True, infer=_fused_attention_infer)
 def _fused_attention(ctx, ins, attrs):
     """flash attention over [B, T, H, D] q/k/v (TPU-native addition; see
     ops/pallas_kernels.py). Differentiable via the kernel's custom_vjp.

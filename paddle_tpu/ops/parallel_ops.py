@@ -127,15 +127,9 @@ def _moe_lower(ctx, ins, attrs):
     return {"Out": [y.reshape(x.shape)], "AuxLoss": [aux.reshape(1)]}
 
 
-def _moe_infer(block, op, out_vars):
-    xv = block.var_recursive(op.inputs["X"][0])
-    ov = block.var_recursive(op.outputs["Out"][0])
-    ov.shape, ov.dtype = xv.shape, xv.dtype
-    av = block.var_recursive(op.outputs["AuxLoss"][0])
-    av.shape, av.dtype = (1,), "float32"
-
-
-registry.register("moe", _moe_lower, infer=_moe_infer)
+_scalar = ("X", lambda shape, attrs: (1,), "float32")
+registry.register("moe", _moe_lower, infer=registry.shapes_from(
+    Out="X", AuxLoss=_scalar))
 
 
 def _moe_ffn_lower(ctx, ins, attrs):
@@ -170,20 +164,11 @@ def _moe_ffn_lower(ctx, ins, attrs):
             "ZLoss": [z], "ExpertLoad": [load]}
 
 
-def _moe_ffn_infer(block, op, out_vars):
-    """The outputs' shapes, written down: Out is X's, the two loss terms
-    [1] float32, ExpertLoad [E] int32. Inferred by tracing the rule, as an
-    op without this is, the whole layer (the router, the sort, the loops over
-    the held rows and, on a TPU, the grouped-matmul kernels) was traced twice
-    a layer when the program was built, for these four shapes."""
-    x = block.var_recursive(op.inputs["X"][0])
-    experts = block.var_recursive(op.inputs["Router"][0]).shape[1]
-    for slot, shape, dtype in (("Out", x.shape, x.dtype),
-                               ("BalanceLoss", (1,), "float32"),
-                               ("ZLoss", (1,), "float32"),
-                               ("ExpertLoad", (int(experts),), "int32")):
-        for var in out_vars.get(slot, ()):
-            var.shape, var.dtype = tuple(shape), dtype
-
-
-registry.register("moe_ffn", _moe_ffn_lower, infer=_moe_ffn_infer)
+# The outputs' shapes, written down: Out is X's, the two loss terms [1]
+# float32, ExpertLoad [E] int32. Inferred by tracing the rule, as an op
+# without this is, the whole layer (the router, the sort, the loops over the
+# held rows and, on a TPU, the grouped-matmul kernels) was traced twice a
+# layer when the program was built, for these four shapes.
+registry.register("moe_ffn", _moe_ffn_lower, infer=registry.shapes_from(
+    Out="X", BalanceLoss=_scalar, ZLoss=_scalar,
+    ExpertLoad=("Router", lambda shape, attrs: shape[1:2], "int32")))

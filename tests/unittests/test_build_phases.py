@@ -281,7 +281,9 @@ def test_infer_shape_rows_by_op_type_and_how():
     assert calls[("mul", "eval_shape")] == 1
     assert calls[("moe_ffn", "custom")] == 1
     assert calls[("sigmoid", "eval_shape")] == 1    # prepend_op passes too
-    assert not any(op == "tanh" for op, _ in calls)
+    # gained, not seen: the counters are the process's, and a test file that
+    # ran before this one in the same worker may have built a tanh
+    assert not any(n for (op, _), n in calls.items() if op == "tanh")
     assert out.shape is None and tuple(pre.shape) == (-1, 6, 16)
     assert set(calls) == set(seconds)
     assert all(seconds[k] > 0 for k in calls if calls[k])
