@@ -64,6 +64,13 @@ from a seed, and checks what comes out by the repo's own means:
      cells' shapes with the groups a seeded router gives and bf16 operands:
      ms and TFLOP/s a pass of each route, the kernels at several row tiles,
      and the largest difference between the routes over the rows in a group.
+  N  the selective scan's two kernels alone at the Phi-4-mini-flash cell's
+     shape, [1, 8192, 5120] with 16 states: forward and forward + backward
+     against the plain `lax.scan` over tokens on this device (every
+     gradient), timed; and ONE differential core at the cell's shape in the
+     form the builder gives the flash kernels (40 heads of 128 on 20 key
+     heads: a pair's two maps, queries and keys padded from 64), under the
+     window of 512 and full, against the float32 dense two-map form.
 
 Every phase that fails makes the exit code non-zero. Timings are printed
 for the next reader, labelled with the device; they are not metrics. The
@@ -142,6 +149,10 @@ FULL = {
                    iters=20, tol=3e-2),
     # the GLM-4.7-Flash cell's core: two sequences of 4096, 20 heads of 192
     # + 64 on values of 256; the blocks each form runs at
+    # the Phi-4-mini-flash cell's scan and differential core
+    "selective_scan": dict(b=1, t=8192, c=5120, n=16, tol=2e-3),
+    "differential": dict(b=1, t=8192, pairs=20, kv_pairs=10, hd=64,
+                         window=512, tol=2e-2),
     "latent_unequal": dict(b=2, t=4096, h=20, d=192, dr=64, dv=256,
                            blocks={"whole": (512, 512),
                                    "two_part": (512, 256)}, tol=3e-2),
@@ -189,6 +200,9 @@ TINY = {
                   ("a row of 1 KiB", 96, 200, 256)),
     "latent": dict(t=64, h=2, d=128, dr=64, streams=4, c=128, iters=20,
                    tol=3e-2),
+    "selective_scan": dict(b=2, t=72, c=1024, n=4, tol=2e-3),
+    "differential": dict(b=1, t=64, pairs=4, kv_pairs=2, hd=16, window=16,
+                         tol=2e-2),
     "latent_unequal": dict(b=2, t=64, h=2, d=192, dr=64, dv=256,
                            blocks={"whole": (32, 32), "two_part": (32, 32)},
                            tol=3e-2),
@@ -1931,6 +1945,129 @@ def phase_m(smoke):
             del got
 
 
+# --------------------------------------------------------------- phase N --
+def phase_n(smoke):
+    """The selective scan's kernels against lax.scan over tokens, and one
+    differential attention core in the builder's form against the float32
+    dense two-map form, at the Phi-4-mini-flash cell's shapes; each
+    timed."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.ops import pallas_kernels
+    from paddle_tpu.ops import selective_scan_kernels as scan
+    from paddle_tpu.ops.kernel_config import DEFAULT_TILES
+
+    c = smoke.cfg["selective_scan"]
+    b, t, ch, n = (c[k] for k in ("b", "t", "c", "n"))
+    keys = jax.random.split(jax.random.key(54), 7)
+    with jax.default_device(smoke.device):
+        x = jax.random.normal(keys[0], (b, t, ch))
+        delta = jax.nn.softplus(jax.random.normal(keys[1], (b, t, ch)) - 4.0)
+        a = -jnp.broadcast_to(jnp.arange(1.0, n + 1), (ch, n))
+        bm, cm = (jax.random.normal(k, (b, t, n)) for k in keys[2:4])
+        d = 1.0 + 0.1 * jax.random.normal(keys[4], (ch,))
+        dy = jax.random.normal(keys[5], (b, t, ch))
+        args = (x, delta, a, bm, cm, d)
+
+        def both(path):
+            def run(*args):
+                y, vjp = jax.vjp(lambda *v: scan.selective_scan(
+                    *v, path=path), *args)
+                return (y,) + vjp(dy)
+            return jax.jit(run)
+
+        names = ("y", "dx", "ddelta", "da", "db", "dc", "dd")
+        errors = _normalized_errors(names, both("kernel")(*args),
+                                    both("xla")(*args))
+        forward = jax.jit(lambda *v: scan.selective_scan(*v, path="kernel"))
+        smoke.say("N selective scan kernels, x %s, %d states, chunks of %d "
+                  "(tolerance %g, against lax.scan over tokens on this "
+                  "device): off by %s; ms forward %.3f, forward + backward "
+                  "%.3f" % (
+                      [b, t, ch], n, DEFAULT_TILES["scan"]["chunk"], c["tol"],
+                      ", ".join(
+                          "%s %.2e" % kv for kv in errors.items()),
+                      _in_flight_ms(forward, args),
+                      _in_flight_ms(both("kernel"), args)))
+        if not max(errors.values()) <= c["tol"]:
+            raise AssertionError("the selective scan's kernels are %.2e off "
+                                 "lax.scan" % max(errors.values()))
+
+        c = smoke.cfg["differential"]
+        b, t, pairs, kvp, hd = (c[k] for k in ("b", "t", "pairs", "kv_pairs",
+                                               "hd"))
+        group, bf = pairs // kvp, jnp.bfloat16
+        q = jax.random.normal(keys[0], (b, t, kvp, group, 2, hd)).astype(bf)
+        k = jax.random.normal(keys[1], (b, t, kvp, 2, hd)).astype(bf)
+        v = jax.random.normal(keys[2], (b, t, kvp, 2 * hd)).astype(bf)
+        g = jax.random.normal(keys[3], (b, t, kvp, 2, group, 2 * hd))
+        lam = 0.3
+
+        def built(window):
+            """models/causal_lm.py differential_attention's core."""
+            def core(q, k, v):
+                pad = [(0, 0)] * 3 + [(0, hd)]
+                qq = jnp.pad(q.transpose(0, 1, 2, 4, 3, 5).reshape(
+                    b, t, 2 * pairs, hd), pad)
+                kk = jnp.pad(k.reshape(b, t, 2 * kvp, hd), pad)
+                vv = jnp.broadcast_to(v[:, :, :, None], (b, t, kvp, 2, 2 * hd)
+                                      ).reshape(b, t, 2 * kvp, 2 * hd)
+                ctx = pallas_kernels.flash_attention(
+                    qq, kk, vv, causal=True, window=window, scale=hd ** -0.5)
+                ctx = ctx.reshape(b, t, kvp, 2, group, 2 * hd)
+                return ctx[:, :, :, 0] - lam * ctx[:, :, :, 1]
+            return core
+
+        def dense(window):
+            def core(q, k, v):          # float32, a query pair at a time
+                age = jnp.arange(t)[:, None] - jnp.arange(t)[None, :]
+                visible = (age >= 0) if window is None \
+                    else (age >= 0) & (age < window)
+
+                @jax.checkpoint         # or a pair's two maps are kept
+                def one(xs):            # q, k [T, 2, hd], v [T, 2 hd]
+                    q, k, v = (x.astype(jnp.float32) for x in xs)
+                    s = jnp.einsum("qjd,kjd->jqk", q, k) * hd ** -0.5
+                    p = jax.nn.softmax(jnp.where(visible, s, -jnp.inf), -1)
+                    ctx = jnp.einsum("jqk,kd->jqd", p, v)
+                    return ctx[0] - lam * ctx[1]
+                with jax.default_matmul_precision("highest"):
+                    out = jax.lax.map(one, (
+                        q[0].reshape(t, pairs, 2, hd).transpose(1, 0, 2, 3),
+                        jnp.repeat(k[0], group, axis=1).transpose(1, 0, 2, 3),
+                        jnp.repeat(v[0], group, axis=1).transpose(1, 0, 2)))
+                return out.transpose(1, 0, 2).reshape(
+                    1, t, kvp, group, 2 * hd)
+            return core
+
+        def with_grads(fn):
+            def run(*args):
+                out, vjp = jax.vjp(fn, *args)
+                return (out,) + vjp(g[:, :, :, 0].astype(out.dtype))
+            return jax.jit(run)
+
+        worst, found = 0.0, []
+        for window in (c["window"], None):
+            errors = _normalized_errors(
+                ("out", "dq", "dk", "dv"),
+                with_grads(built(window))(q, k, v),
+                with_grads(dense(window))(q, k, v))
+            worst = max(worst, *errors.values())
+            found.append("window %s off by %s; ms forward %.3f, forward + "
+                         "backward %.3f" % (
+                             window, ", ".join("%s %.2e" % kv
+                                               for kv in errors.items()),
+                             _in_flight_ms(jax.jit(built(window)), (q, k, v)),
+                             _in_flight_ms(with_grads(built(window)),
+                                           (q, k, v))))
+        smoke.say("N differential core, %d pairs on %d of %d, T=%d, as %d "
+                  "heads of %d (tolerance %g, against the float32 dense two "
+                  "maps): %s" % (pairs, kvp, hd, t, 2 * pairs, 2 * hd,
+                                 c["tol"], "; ".join(found)))
+        if not worst <= c["tol"]:
+            raise AssertionError("the differential core is %.2e off" % worst)
+
+
 PHASES = (("A", "ResNet-50 training", phase_a),
           ("B", "transformer training", phase_b),
           ("C", "Pallas kernel families", phase_c),
@@ -1943,7 +2080,8 @@ PHASES = (("A", "ResNet-50 training", phase_a),
           ("J", "latent attention and hyper-connections", phase_j),
           ("K", "the output head and its loss", phase_k),
           ("L", "the latent core at 192 + 64 on 256", phase_l),
-          ("M", "the routed experts' grouped matmuls", phase_m))
+          ("M", "the routed experts' grouped matmuls", phase_m),
+          ("N", "the selective scan and the differential core", phase_n))
 
 
 def main(argv=None):

@@ -713,6 +713,8 @@ def _lower_op_inner(ctx, op, env):
         _count_linear_attention_layer(ins)
     elif op.type == "causal_conv1d":
         _count_causal_conv_layer(op.attrs, ins)
+    elif op.type == "selective_scan":
+        _count_selective_scan_layer(ins)
     elif op.type == "lookup_table":
         _count_embedding_layer(ctx, ins)
     elif op.type == "mhc_pre":
@@ -1024,6 +1026,22 @@ def _count_causal_conv_layer(attrs, ins):
     ).inc(path=causal_conv_path(x, w), width=str(w.shape[1]),
           channels=str(w.shape[0]),
           activation=str(attrs.get("activation", "none")))
+
+
+def _count_selective_scan_layer(ins):
+    from ..observability.registry import REGISTRY
+    from ..ops.kernel_config import DEFAULT_TILES
+    from ..ops.linear_attention_ops import selective_scan_path
+    x, a = ins["X"][0], ins["A"][0]
+    REGISTRY.counter(
+        "ptpu_selective_scan_layers_total",
+        "selective_scan ops lowered (forward ops, not a grad op's replay), "
+        "by the channels, the states a channel, the tokens between two "
+        "states the backward pass is given and the path (the two Pallas "
+        "kernels, or lax.scan over tokens)"
+    ).inc(channels=str(a.shape[0]), states=str(a.shape[1]),
+          chunk=str(DEFAULT_TILES["scan"]["chunk"]),
+          path=selective_scan_path(x, a))
 
 
 def _count_embedding_layer(ctx, ins):

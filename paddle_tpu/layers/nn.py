@@ -24,7 +24,8 @@ __all__ = [
     "expand", "sequence_mask", "linear_chain_crf", "crf_decoding",
     "chunk_eval", "warpctc", "ctc_greedy_decoder", "sequence_erase",
     "edit_distance", "fused_attention", "rms_norm", "rotary_embedding",
-    "causal_conv1d", "gated_delta_rule", "mhc_pre", "mhc_post", "mhc_expand",
+    "causal_conv1d", "gated_delta_rule", "selective_scan", "mhc_pre",
+    "mhc_post", "mhc_expand",
     "mhc_reduce",
 ]
 
@@ -373,6 +374,22 @@ def gated_delta_rule(q, k, v, g, beta, name=None):
     helper.append_op(
         type="gated_delta_rule",
         inputs={"Q": [q], "K": [k], "V": [v], "G": [g], "Beta": [beta]},
+        outputs={"Out": [out]}, attrs={})
+    return out
+
+
+def selective_scan(x, delta, a, b, c, d, name=None):
+    """A Mamba mixer's selective scan (ops/selective_scan_kernels.py): x,
+    delta [B, T, C] (delta > 0, after its softplus), a [C, N] (negative), b,
+    c [B, T, N] and d [C] -> [B, T, C] in x's dtype. A channel from s = 0:
+    s_t[n] = exp(delta_t a[n]) s_(t-1)[n] + delta_t b_t[n] x_t; y_t = sum_n
+    c_t[n] s_t[n] + d x_t, in float32."""
+    helper = LayerHelper("selective_scan", name=name)
+    out = helper.create_variable_for_type_inference(x.dtype)
+    helper.append_op(
+        type="selective_scan",
+        inputs={"X": [x], "Delta": [delta], "A": [a], "B": [b], "C": [c],
+                "D": [d]},
         outputs={"Out": [out]}, attrs={})
     return out
 

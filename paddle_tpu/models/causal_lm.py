@@ -2,7 +2,7 @@
 dict whose keys are those of a Hugging Face `config.json`.
 
 One builder for the family: token embedding (no scale, no position table),
-N x (RMS norm, a mixer, RMS norm, by layer a dense SwiGLU FFN or dropless
+N x (a norm, a mixer, a norm, by layer a dense SwiGLU FFN or dropless
 top-k routed experts with an optional gated shared expert beside them),
 final RMS norm, an output head of its own or tied to the embedding,
 next-token cross-entropy plus the routers' auxiliary losses. The mixer is,
@@ -12,7 +12,9 @@ head or its first channels on the layers whose pattern says so, a sliding
 window on the layers whose pattern says so, an optional sigmoid gate on the
 output) or a gated delta net (a causal depthwise convolution over q, k and
 v, the gated delta rule, a gated RMS norm a head) or a gated short
-convolution (C * conv(B * u) between two projections). A new decoder-only
+convolution (C * conv(B * u) between two projections) or a Mamba mixer (a
+selective scan behind a convolution) or a gated memory unit on another
+layer's scan output. A new decoder-only
 architecture is a config plus the ops it lacks, not a model file. Users:
 OLMoE-1B-7B (`model_type: olmoe`; Muennighoff et al. 2024, arXiv:2409.02060),
 SmallThinker-21BA3B (PowerInfer; window and full attention mixed with
@@ -124,6 +126,32 @@ moe_primary_router_apply_softmax (true), rope_layout and
 sliding_window_layout (one 0/1 a layer: rotary on, window on) and
 sliding_window_size.
 
+Phi-4-mini-flash-reasoning's (`model_type: phi4flash`; SambaY's
+decoder-hybrid-decoder, arXiv:2507.06607): mb_per_layer (a Mamba mixer on
+the layers whose published index is a multiple of it; `_decoder_hybrid_
+decoder` has the pattern: Mamba and windowed attention below the middle, the
+middle layer's scan output and the next layer's keys and values handed on,
+gated memory units and attention with a query projection alone above),
+sliding_window, layer_norm_eps (LayerNorm with weight and bias as the
+block's norm, for rms_norm_eps), embd_pdrop and resid_pdrop (0), mlp_bias
+and lm_head_bias (false), no rope_theta (no positional term: absent under
+mb_per_layer is None); and, as keys of their own, what
+`modeling_phi4flash.py` always does and `config.json` does not carry:
+`differential_attention` (arXiv:2410.05258: `differential_attention` has the
+equations), `attention_bias` and `conv_bias` (true: built under these two
+mixers alone), `mlp_gate_up_fused` (the MLP's first matrix holds gate and
+value), `mamba_d_state` (16), `mamba_d_conv` (4), `mamba_expand` (2),
+`mamba_dt_rank` ("auto": hidden_size / 16 rounded up),
+`lambda_initializer_range` (0.1) and `layer_indices` (the published index
+of each layer a cut stack kept; a layer's kind, window and lambda_init
+follow it).
+A Mamba layer's parameters: w_in, conv, [conv.bias], w_x, w_dt, dt_bias,
+a_log, d, w_out; a memory unit's: w_in, w_out; a differential attention's:
+wq, [wq.bias], then where it makes its own keys and values wk, [wk.bias],
+wv, [wv.bias], then lambda_q1, lambda_k1, lambda_q2, lambda_k2, subln, wo,
+[wo.bias]; a LayerNorm's weight then `<role>.bias`; a fused MLP's w_gate_up,
+w_down.
+
 Multi-token prediction (num_nextn_predict_layers 1; DeepSeek-V3,
 arXiv:2412.19437, section 2.2; GLM-4.7-Flash, `model_type: glm4_moe_lite`):
 one module behind the trunk, outside its count of layers. With s_i the
@@ -203,7 +231,12 @@ DEFAULTS = {
     "mhc_h_res_clamp_min": -30.0, "mhc_h_res_clamp_max": 30.0,
     "hc_alpha_init": 0.5, "hc_res_diag_init": 1.0,
     "num_nextn_predict_layers": 0, "n_group": 1, "topk_group": 1,
-    "moe_layer_freq": 1, "rope_interleaved": False, "mtp_loss_weight": 0.3}
+    "moe_layer_freq": 1, "rope_interleaved": False, "mtp_loss_weight": 0.3,
+    "mb_per_layer": 0, "differential_attention": False,
+    "mlp_gate_up_fused": False, "mamba_d_state": 16, "mamba_d_conv": 4,
+    "mamba_expand": 2, "mamba_dt_rank": "auto", "lambda_initializer_range": 0.1,
+    "embd_pdrop": 0, "resid_pdrop": 0, "mlp_bias": False,
+    "lm_head_bias": False}
 # the stream every expert bias is drawn from, whatever the program's seed:
 # the draw the LFM2 cell's limits were read under (PERF.md section 4)
 EXPERT_BIAS_SEED = 39
@@ -219,6 +252,9 @@ ALIASES = {"moe_ffn_hidden_size": "intermediate_size",
 # the keys latent attention needs, all or none
 LATENT_KEYS = ("q_lora_rank", "kv_lora_rank", "qk_nope_head_dim",
                "qk_rope_head_dim", "v_head_dim")
+# the stream a Mamba mixer's Delta bias is drawn from, whatever the program's
+# seed (the draw the Phi-4-mini-flash cell's limits were read under)
+MAMBA_DT_SEED = 54
 # a layer's kind in `layer_types` -> its mixer here
 LAYER_TYPES = {"conv": "short_conv", "full_attention": "attention"}
 # the keys a gated delta net needs
@@ -262,8 +298,19 @@ def resolve(cfg):
         c["shared_expert_intermediate_size"] = \
             c["n_shared_experts"] * c["intermediate_size"]
         c["shared_expert_gate"] = False
-    for key, want in (("attention_bias", False), ("clip_qkv", None),
-                      ("conv_bias", False),
+    c["mb_per_layer"] = int(c["mb_per_layer"])
+    # a bias in the attention's projections is built for differential
+    # attention, one on the convolution for a Mamba mixer's: the other
+    # mixers' references have neither
+    for key, under in (("attention_bias", "differential_attention"),
+                       ("conv_bias", "mb_per_layer")):
+        if c[key] not in (False, True) or c[key] and not c[under]:
+            raise NotImplementedError(
+                "causal_lm builds %s=False, and True under %s only; the "
+                "config has %r" % (key, under, c[key]))
+    for key, want in (("clip_qkv", None), ("embd_pdrop", 0),
+                      ("resid_pdrop", 0), ("mlp_bias", False),
+                      ("lm_head_bias", False),
                       ("moe_primary_router_apply_softmax", True),
                       ("mlp_only_layers", []), ("decoder_sparse_step", 1),
                       ("early_exit_threshold", 1), ("n_group", 1),
@@ -272,6 +319,12 @@ def resolve(cfg):
             raise NotImplementedError(
                 "causal_lm builds %s=%r only, the config has %r"
                 % (key, want, c[key]))
+    # LayerNorm with weight and bias where the config names its epsilon so
+    c["norm_type"] = "layer_norm" if "layer_norm_eps" in c else "rms_norm"
+    if "layer_norm_eps" in c:
+        c["rms_norm_eps"] = c["layer_norm_eps"]
+    if c["mb_per_layer"] and "rope_theta" not in cfg:
+        c["rope_theta"] = None      # the family has no positional term
     scaling = c["rope_scaling"]
     if scaling is not None and scaling.get(
             "type", scaling.get("rope_type")) != "yarn":
@@ -441,6 +494,16 @@ def resolve(cfg):
         c["mixer_layers"] = ["attention" if (i + 1) % interval == 0
                              else "gated_delta" for i in range(layers)] \
             + ["attention"] * mtp
+    c["reads_layers"] = ["own"] * (layers + mtp)
+    c["lambda_init_layers"] = [None] * (layers + mtp)
+    c["memory_layer"] = c["kv_layer"] = None
+    if c["mb_per_layer"]:
+        _decoder_hybrid_decoder(c, published.get("num_hidden_layers",
+                                                 layers))
+    elif c["differential_attention"]:
+        raise NotImplementedError(
+            "causal_lm builds differential_attention under mb_per_layer "
+            "(lambda_init is a function of the published layer index)")
     if "short_conv" in c["mixer_layers"]:
         if "conv_L_cache" not in c:
             raise ValueError("layer_types has conv layers, which need "
@@ -464,6 +527,75 @@ def resolve(cfg):
                              "key heads" % (c["linear_num_value_heads"],
                                             c["linear_num_key_heads"]))
     return c
+
+
+def _decoder_hybrid_decoder(c, published):
+    """The layer pattern of SambaY (arXiv:2507.06607; `model_type:
+    phi4flash`) written into c, by PUBLISHED index i of `published` layers,
+    half = published // 2: i < half, the self-decoder: a Mamba mixer where i
+    % mb_per_layer == 0, else attention under sliding_window; i == half: a
+    Mamba mixer that hands on its scan output (`memory_layer`: its place in
+    the stack built); i == half + 1: full attention that hands on its keys
+    and values (`kv_layer`); after that, the cross-decoder: a gated memory
+    unit ("gmu") reading the memory where i % mb_per_layer == 0, else
+    attention reading those keys and values (`reads_layers` "shared"). A
+    stack cut short says which published layers it kept under
+    `layer_indices` (absent: 0 .. num_hidden_layers - 1). lambda_init of a
+    differential attention is 0.8 - 0.6 exp(-0.3 i)."""
+    import math
+    layers, mb = c["num_hidden_layers"], c["mb_per_layer"]
+    for key, want in (("total_ut_steps", 1), ("hc_mult", 1),
+                      ("mtp_layers", 0), ("num_experts", 0),
+                      ("latent", False), ("qk_norm", False),
+                      ("attention_gate", False),
+                      ("full_attention_interval", 1), ("layer_types", None),
+                      ("rope_layout", None), ("sliding_window_layout", None)):
+        if c.get(key) != want:
+            raise NotImplementedError(
+                "causal_lm builds mb_per_layer %d (Mamba mixers, a memory "
+                "and keys and values that later layers read) with %s=%r "
+                "only, the config has %r" % (mb, key, want, c.get(key)))
+    indices = [int(i) for i in c.get("layer_indices", range(layers))]
+    if len(indices) != layers or sorted(set(indices)) != indices \
+            or indices[-1] >= published:
+        raise ValueError("layer_indices %r: %d rising published indices "
+                         "under %d are needed" % (indices, layers, published))
+    half = published // 2
+    if half % mb or (half + 1) % mb == 0:
+        raise NotImplementedError(
+            "causal_lm builds a decoder-hybrid-decoder whose layer %d is a "
+            "Mamba mixer and whose layer %d is attention; mb_per_layer %d "
+            "makes it otherwise" % (half, half + 1, mb))
+    heads, kv = c["num_attention_heads"], c["num_key_value_heads"]
+    if c["differential_attention"] and (heads % 2 or kv % 2
+                                        or (heads // 2) % (kv // 2)):
+        raise ValueError("differential attention pairs the heads: %d on %d "
+                         "are not pairs on pairs" % (heads, kv))
+    if c["mamba_dt_rank"] == "auto":
+        c["mamba_dt_rank"] = -(-c["hidden_size"] // 16)
+    c["layer_indices"] = indices
+    for k, i in enumerate(indices):
+        mamba = i % mb == 0
+        c["mixer_layers"][k] = ("mamba" if i <= half else "gmu") if mamba \
+            else "attention"
+        c["window_layers"][k] = c.get("sliding_window") \
+            if not mamba and i < half else None
+        c["reads_layers"][k] = "shared" if i > half + 1 else "own"
+        if not mamba and c["differential_attention"]:
+            c["lambda_init_layers"][k] = 0.8 - 0.6 * math.exp(-0.3 * i)
+        if i == half:
+            c["memory_layer"] = k
+        if i == half + 1:
+            c["kv_layer"] = k
+    for k, i in enumerate(indices):
+        if c["reads_layers"][k] == "shared" and c[
+                "memory_layer" if c["mixer_layers"][k] == "gmu"
+                else "kv_layer"] is None:
+            raise ValueError(
+                "layer_indices %r keeps layer %d, which reads what layer %d "
+                "hands on, and not that layer" % (
+                    indices, i, half if c["mixer_layers"][k] == "gmu"
+                    else half + 1))
 
 
 def yarn_table(scaling, theta, rotary_dim, head_dim):
@@ -506,7 +638,8 @@ def _layer(c, i):
     parameters; `rope_theta` None where the pattern gives the layer no
     rotary, `window` its sliding window or None, `ffn` its FFN's kind."""
     return dict(c, layer=i, window=c["window_layers"][i],
-                ffn=c["ffn_layers"][i],
+                ffn=c["ffn_layers"][i], reads=c["reads_layers"][i],
+                lambda_init=c["lambda_init_layers"][i],
                 rope_theta=c["rope_theta"] if c["rope_layers"][i] else None)
 
 
@@ -525,17 +658,29 @@ def _matrix(c, role):
                                                    c["initializer_range"]))
 
 
-def _linear(x, size, c, role):
-    return fluid.layers.fc(input=x, size=size, bias_attr=False,
-                           num_flatten_dims=2, param_attr=_matrix(c, role))
+def _linear(x, size, c, role, bias=False):
+    """x W, and + b where `bias` (the parameter `<role>.bias`, zeros)."""
+    return fluid.layers.fc(
+        input=x, size=size, num_flatten_dims=2, param_attr=_matrix(c, role),
+        bias_attr=_attr(c, role + ".bias", fluid.initializer.Constant(0.0))
+        if bias else False)
 
 
 def _norm(x, c, role=None):
-    """An RMS norm with its own weight, named by `role` or, called with two
-    arguments, by c["role"] where the caller put one there."""
+    """The model's norm with its own weight, named by `role` or, called with
+    two arguments, by c["role"] where the caller put one there: an RMS norm,
+    or (`layer_norm_eps`) a LayerNorm over the last axis with weight and
+    bias (`<role>.bias`)."""
+    role = role or c.get("role")
+    if c["norm_type"] == "layer_norm":
+        return fluid.layers.layer_norm(
+            x, begin_norm_axis=len(x.shape) - 1, epsilon=c["rms_norm_eps"],
+            param_attr=_attr(c, role, fluid.initializer.Constant(1.0)),
+            bias_attr=_attr(c, role + ".bias",
+                            fluid.initializer.Constant(0.0)))
     return fluid.layers.rms_norm(x, epsilon=c["rms_norm_eps"],
                                  zero_centered=c["norm_zero_centered"],
-                                 param_attr=_attr(c, role or c.get("role")))
+                                 param_attr=_attr(c, role))
 
 
 def attention(x, pos, c):
@@ -736,9 +881,142 @@ def _gated(gate, x):
     return out
 
 
+def differential_attention(x, pos, c):
+    """Differential attention (arXiv:2410.05258 as `modeling_phi4flash.py`
+    has it) over x [B, T, D]: the H query heads are H / 2 pairs, q = x W_q +
+    b_q as [T, H / 2, 2, hd] -> q1, q2; k and v likewise on Hkv / 2 pairs, V
+    = [v1; v2] one value of 2 hd a pair; P_j = softmax(q_j k_j^T / sqrt(hd)
+    + mask); o = P_1 V - lambda P_2 V with lambda = exp(lq1 . lk1) - exp(lq2
+    . lk2) + lambda_init, four learned vectors of hd; RMSNorm over the 2 hd
+    (a weight of 2 hd) x (1 - lambda_init); then W_o + b_o. The mask is
+    causal, under c["window"] where the layer has one.
+
+    The core is ONE layers.fused_attention at a head of 2 hd: H "heads" (a
+    pair's two maps side by side) on Hkv key heads, a map's query and key
+    padded from hd to 2 hd with zeros (the scores are the same, and the
+    softmax of a map is taken once where four calls at hd would take it
+    twice), the pair's value repeated for its two maps. Where c["reads"] is
+    "shared" the layer has W_q, b_q, W_o, b_o, lambda and the norm only, and
+    reads the keys and values another layer left in c["handed_on"]; the
+    layer c["kv_layer"] leaves them there as its own core reads them."""
+    layers = fluid.layers
+    d, hd = c["hidden_size"], c["head_dim"]
+    pairs, kv_pairs = c["num_attention_heads"] // 2, \
+        c["num_key_value_heads"] // 2
+    group = pairs // kv_pairs
+    bias = c["attention_bias"]
+
+    def padded(t):                      # [.., hd] -> [.., 2 hd], zeros after
+        return layers.concat([t, layers.scale(t, scale=0.0)], axis=3)
+
+    # a key pair's queries: [kv pair, query pair, map] -> [kv pair, map,
+    # query pair], so that map j's queries read key head (kv pair, j)
+    q = layers.reshape(_linear(x, 2 * pairs * hd, c, "wq", bias),
+                       shape=[0, -1, kv_pairs, group, 2, hd])
+    q = padded(layers.reshape(layers.transpose(q, perm=[0, 1, 2, 4, 3, 5]),
+                              shape=[0, -1, 2 * pairs, hd]))
+    if c["reads"] == "shared":
+        k, v = c["handed_on"]["kv"]
+    else:
+        k = padded(layers.reshape(
+            _linear(x, 2 * kv_pairs * hd, c, "wk", bias),
+            shape=[0, -1, 2 * kv_pairs, hd]))
+        v = layers.reshape(_linear(x, 2 * kv_pairs * hd, c, "wv", bias),
+                           shape=[0, -1, kv_pairs, 1, 2 * hd])
+        v = layers.reshape(layers.expand(v, expand_times=[1, 1, 1, 2, 1]),
+                           shape=[0, -1, 2 * kv_pairs, 2 * hd])
+        if c["layer"] == c["kv_layer"]:
+            c["handed_on"]["kv"] = (k, v)
+    ctx = layers.fused_attention(q, k, v, causal=True, window=c["window"],
+                                 scale=hd ** -0.5)
+    first, second = layers.split(
+        layers.reshape(ctx, shape=[0, -1, kv_pairs, 2, group * 2 * hd]), 2,
+        dim=3)
+    vectors = [layers.create_parameter(
+        [hd], "float32", attr=_attr(c, role, fluid.initializer.Normal(
+            0.0, c["lambda_initializer_range"])))
+        for role in ("lambda_q1", "lambda_k1", "lambda_q2", "lambda_k2")]
+    lam = layers.scale(
+        layers.exp(layers.reduce_sum(vectors[0] * vectors[1]))
+        - layers.exp(layers.reduce_sum(vectors[2] * vectors[3])),
+        bias=c["lambda_init"])
+    out = first - layers.elementwise_mul(
+        second, layers.cast(lam, second.dtype))
+    out = layers.rms_norm(
+        layers.reshape(out, shape=[0, -1, pairs, 2 * hd]),
+        epsilon=c["rms_norm_eps"], param_attr=_attr(c, "subln"))
+    return _linear(layers.reshape(
+        layers.scale(out, scale=1.0 - c["lambda_init"]),
+        shape=[0, -1, pairs * 2 * hd]), d, c, "wo", bias)
+
+
+def mamba(x, c):
+    """A Mamba-1 mixer (arXiv:2312.00752) over x [B, T, D], d_i =
+    mamba_expand x D, N = mamba_d_state, R = mamba_dt_rank: [u; z] = x W_in;
+    c = SiLU(conv(u) + b_c), a causal depthwise convolution of mamba_d_conv
+    taps; [r; B; C] = c W_x; Delta = softplus(r W_Delta + b_Delta) in
+    float32; A = -exp(A_log); y = selective_scan(c, Delta, A, B, C, D); (y *
+    SiLU(z)) W_out. The layer c["memory_layer"] leaves y, BEFORE the gate,
+    in c["handed_on"] for the gated memory units. A_log starts at log(1 ..
+    N) a channel, D at 1, b_Delta at the inverse softplus of exp(U(ln 1e-3,
+    ln 1e-1)) from MAMBA_DT_SEED's stream, W_Delta uniform within R^-0.5."""
+    import numpy as np
+    layers, init = fluid.layers, fluid.initializer
+    d, n, rank = c["hidden_size"], c["mamba_d_state"], c["mamba_dt_rank"]
+    di = c["mamba_expand"] * d
+    u, z = layers.split(_linear(x, 2 * di, c, "w_in"), 2, dim=-1)
+    u = layers.causal_conv1d(u, c["mamba_d_conv"], param_attr=_matrix(
+        c, "conv"), act=None if c["conv_bias"] else "silu")
+    if c["conv_bias"]:
+        u = layers.swish(layers.elementwise_add(
+            u, layers.create_parameter(
+                [di], "float32",
+                attr=_attr(c, "conv.bias", init.Constant(0.0))), axis=2))
+    r, b, cc = layers.split(_linear(u, rank + 2 * n, c, "w_x"),
+                            [rank, n, n], dim=-1)
+    dt = np.exp(np.random.RandomState(MAMBA_DT_SEED + c["layer"]).uniform(
+        np.log(1e-3), np.log(1e-1), di))
+    delta = layers.softplus(layers.elementwise_add(
+        layers.cast(layers.fc(
+            input=r, size=di, bias_attr=False, num_flatten_dims=2,
+            param_attr=_attr(c, "w_dt", init.Uniform(-rank ** -0.5,
+                                                      rank ** -0.5))),
+            "float32"),
+        layers.create_parameter(
+            [di], "float32", attr=_attr(c, "dt_bias", init.NumpyArrayInitializer(
+                (dt + np.log(-np.expm1(-dt))).astype("float32")))),
+        axis=2))
+    a_log = layers.create_parameter(
+        [di, n], "float32", attr=_attr(c, "a_log", init.NumpyArrayInitializer(
+            np.log(np.tile(np.arange(1, n + 1, dtype="float32"), (di, 1))))))
+    skip = layers.create_parameter(
+        [di], "float32", attr=_attr(c, "d", init.Constant(1.0)))
+    y = layers.selective_scan(
+        u, delta, layers.scale(layers.exp(a_log), scale=-1.0),
+        layers.cast(b, "float32"), layers.cast(cc, "float32"), skip)
+    if c["layer"] == c["memory_layer"]:
+        c["handed_on"]["memory"] = y
+    return _linear(y * layers.swish(z), d, c, "w_out")
+
+
+def gated_memory_unit(x, c):
+    """(SiLU(x W_1) * m) W_2 over x [B, T, D], m [B, T, d_i] the scan
+    output that layer c["memory_layer"] left in c["handed_on"]."""
+    gate = fluid.layers.swish(_linear(
+        x, c["mamba_expand"] * c["hidden_size"], c, "w_in"))
+    return _linear(gate * c["handed_on"]["memory"], c["hidden_size"], c,
+                   "w_out")
+
+
 def _swiglu(x, width, c, role=""):
-    gate = fluid.layers.swish(_linear(x, width, c, role + "w_gate"))
-    up = _linear(x, width, c, role + "w_up")
+    if c["mlp_gate_up_fused"]:
+        # one first matrix, [gate; value]
+        gate, up = fluid.layers.split(
+            _linear(x, 2 * width, c, role + "w_gate_up"), 2, dim=-1)
+        gate = fluid.layers.swish(gate)
+    else:
+        gate = fluid.layers.swish(_linear(x, width, c, role + "w_gate"))
+        up = _linear(x, width, c, role + "w_up")
     return _linear(gate * up, c["hidden_size"], c, role + "w_down")
 
 
@@ -798,15 +1076,20 @@ def _count_layer(c, mixer, module="trunk"):
         "delta rule or of a short_conv mixer's own (0: none), the FFN's "
         "kind (dense, or routed experts), the width of the shared expert "
         "beside the routed ones (0: none), whether each branch is normed "
-        "going out as well as going in (a sandwich) and the module the layer "
-        "belongs to (trunk, or mtp: a multi-token-prediction module's)"
-    ).inc(mixer=mixer, module=module,
+        "going out as well as going in (a sandwich), the module the layer "
+        "belongs to (trunk, or mtp: a multi-token-prediction module's), "
+        "whose state the mixer reads (own: its input's alone; shared: the "
+        "memory or the keys and values another layer handed on) and whether "
+        "the attention is differential"
+    ).inc(mixer=mixer, module=module, reads=c["reads"],
+          differential=str(bool(attention
+                                and c["differential_attention"])).lower(),
           rotary_dim=str(c["rotary_dim"] if attention
                          and c["rope_theta"] is not None else 0),
           gate=str(bool(attention and c["attention_gate"])).lower(),
-          conv=str(0 if attention else c["conv_L_cache"]
-                   if mixer == "short_conv"
-                   else c["linear_conv_kernel_dim"]),
+          conv=str(0 if attention or mixer == "gmu" else c["conv_L_cache"]
+                   if mixer == "short_conv" else c["mamba_d_conv"]
+                   if mixer == "mamba" else c["linear_conv_kernel_dim"]),
           ffn=c["ffn"],
           shared=str(c["shared_expert_intermediate_size"]
                      if c["ffn"] == "experts" else 0),
@@ -908,6 +1191,8 @@ def causal_lm(cfg, seq_len, extras=None, recompute=True):
         streams = c["hc_mult"]
         if streams > 1:
             h = layers.mhc_expand(h, streams)
+        # what one layer leaves for later layers to read (`memory`, `kv`)
+        c["handed_on"] = {}
         for i in range(trunk + mtp):
             cl, mixer = _layer(c, i), c["mixer_layers"][i]
             if i == trunk:
@@ -926,9 +1211,13 @@ def causal_lm(cfg, seq_len, extras=None, recompute=True):
             if streams > 1:
                 read, coef, h = hyper_connection(h, cl, "attn_hc")
             a = _norm(read if streams > 1 else h, cl, "input_norm")
-            mixed = (latent_attention if c["latent"] else attention)(
+            mixed = (latent_attention if c["latent"]
+                     else differential_attention
+                     if c["differential_attention"] else attention)(
                 a, pos, cl) if mixer == "attention" \
                 else short_conv(a, cl) if mixer == "short_conv" \
+                else mamba(a, cl) if mixer == "mamba" \
+                else gated_memory_unit(a, cl) if mixer == "gmu" \
                 else gated_delta_net(a, cl)
             if c["sandwich_norm"]:
                 mixed = _norm(mixed, cl, "mixer_out_norm")

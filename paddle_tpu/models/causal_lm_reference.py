@@ -31,6 +31,15 @@ module does not fit beside its cut and is left out of it. For GLM-4.7-Flash
 head of 192 + 64 on a value of 256, and `num_nextn_predict_layers` 1)
 DeepSeek-V3's report, arXiv:2412.19437 section 2.2, for the
 multi-token-prediction module and its loss (`mtp_input`, `loss_fn`).
+For Phi-4-mini-flash-reasoning (`model_type: phi4flash`; SambaY,
+arXiv:2507.06607: Mamba mixers, windowed and full differential attention,
+arXiv:2410.05258, and a cross-decoder whose gated memory units read ONE
+layer's scan output and whose attention layers read ONE layer's keys and
+values; LayerNorm, a gated MLP whose first matrix holds gate and value, a
+tied head, no positional term) the equations of ISSUE 54 as
+`modeling_phi4flash.py` has them: `selective_scan` is Mamba-1's recurrence
+token by token (`lax.scan`), `differential_attention` two dense masked
+softmax maps a pair of heads.
 `params` is the list of the Program's parameters in the order
 models/causal_lm.py creates them.
 
@@ -54,7 +63,8 @@ def layer_config(c, i):
     None. The reference's own reading of the two patterns, not the
     builder's."""
     return dict(c, rope_theta=c["rope_theta"] if c["rope_layers"][i]
-                else None, window=c["window_layers"][i])
+                else None, window=c["window_layers"][i],
+                lambda_init=c["lambda_init_layers"][i])
 
 
 def rms_norm(x, w, eps, zero_centered=False):
@@ -237,6 +247,94 @@ def attention(a, pos, wq, wk, wv, q_norm, k_norm, wo, c):
     if gated:
         ctx = ctx * jax.nn.sigmoid(gate)
     return ctx.reshape(b, t, h * hd) @ wo
+
+
+def layer_norm(x, w, b, eps):
+    """LayerNorm over the last axis with weight and bias."""
+    mean = x.mean(-1, keepdims=True)
+    var = ((x - mean) ** 2).mean(-1, keepdims=True)
+    return (x - mean) * jax.lax.rsqrt(var + eps) * w + b
+
+
+def selective_scan(x, delta, a, b, c, d):
+    """Mamba-1's recurrence token by token on x, delta [B, T, C], a [C, N],
+    b, c [B, T, N], d [C]: s_t[c, n] = exp(delta_t[c] a[c, n]) s_(t-1)[c, n]
+    + delta_t[c] b_t[n] x_t[c] from s = 0; y_t[c] = sum_n c_t[n] s_t[c, n]
+    + d[c] x_t[c]."""
+    def step(s, xs):
+        x, dt, b, c = xs
+        s = jnp.exp(dt[..., None] * a) * s + (dt * x)[..., None] * b[:, None]
+        return s, (s * c[:, None]).sum(-1)
+
+    _, y = jax.lax.scan(step, jnp.zeros(x.shape[:1] + a.shape, x.dtype),
+                        tuple(jnp.moveaxis(v, 1, 0)
+                              for v in (x, delta, b, c)))
+    return jnp.moveaxis(y, 0, 1) + d * x
+
+
+def mamba(a, w_in, w_conv, b_conv, w_x, w_dt, b_dt, a_log, d, w_out,
+          found=None):
+    """(the mixer's output, its scan output y before the gate) of a Mamba-1
+    mixer on a [B, T, D]: [u; z] = a w_in; c = SiLU(conv(u) + b_conv)
+    (b_conv None: no bias); [r; B; C] = c w_x; Delta = softplus(r w_dt +
+    b_dt); A = -exp(a_log); y = selective_scan(c, Delta, A, B, C, d); (y *
+    SiLU(z)) w_out. A dict given as `found` gets `delta`."""
+    n, rank = a_log.shape[1], w_dt.shape[0]
+    u, z = jnp.split(a @ w_in, 2, axis=-1)
+    u = causal_conv(u, w_conv)
+    u = jax.nn.silu(u if b_conv is None else u + b_conv)
+    r, b, c = jnp.split(u @ w_x, [rank, rank + n], axis=-1)
+    delta = jax.nn.softplus(r @ w_dt + b_dt)
+    if found is not None:
+        found.setdefault("delta", delta)
+    y = selective_scan(u, delta, -jnp.exp(a_log), b, c, d)
+    return (y * jax.nn.silu(z)) @ w_out, y
+
+
+def gated_memory_unit(a, memory, w_in, w_out):
+    """(SiLU(a w_in) * memory) w_out: `memory` another layer's scan
+    output."""
+    return (jax.nn.silu(a @ w_in) * memory) @ w_out
+
+
+def differential_attention(a, wq, bq, kv, lambdas, subln, wo, bo, c):
+    """(output, (k, v)) of differential attention on a [B, T, D]: q = a wq
+    + bq as [B, T, H / 2, 2, hd] -> q1, q2; `kv` either this layer's (wk,
+    bk, wv, bv), k and v as [B, T, Hkv / 2, 2, hd] -> k1, k2 and V = [v1;
+    v2], or another layer's (k, V) as this returns them; a query pair p
+    reads key pair p // (pairs / key pairs); P_j = softmax(q_j k_j^T /
+    sqrt(hd) + mask), causal and under c["window"]; o = P_1 V - lambda P_2
+    V, lambda = exp(lq1 . lk1) - exp(lq2 . lk2) + c["lambda_init"];
+    RMSNorm over the 2 hd under `subln`, times (1 - lambda_init); wo + bo.
+    A bias of None is no bias."""
+    b, t, _ = a.shape
+    hd, eps = c["head_dim"], c["rms_norm_eps"]
+
+    def project(w, bias):
+        y = a @ w
+        return (y if bias is None else y + bias).reshape(b, t, -1, 2, hd)
+
+    q = project(wq, bq)
+    if len(kv) == 4:
+        k, v = project(*kv[:2]), project(*kv[2:])
+        v = v.reshape(b, t, -1, 2 * hd)
+    else:
+        k, v = kv
+    group = q.shape[2] // k.shape[2]
+    kr, vr = jnp.repeat(k, group, axis=2), jnp.repeat(v, group, axis=2)
+    s = jnp.einsum("bqpjd,bkpjd->bpjqk", q, kr) * hd ** -0.5
+    age = jnp.arange(t)[:, None] - jnp.arange(t)[None, :]
+    visible = age >= 0
+    if c["window"] is not None:
+        visible = visible & (age < c["window"])
+    maps = jax.nn.softmax(jnp.where(visible, s, -jnp.inf), -1)
+    ctx = jnp.einsum("bpjqk,bkpd->bqpjd", maps, vr)
+    lq1, lk1, lq2, lk2 = lambdas
+    lam = jnp.exp(lq1 @ lk1) - jnp.exp(lq2 @ lk2) + c["lambda_init"]
+    out = rms_norm(ctx[..., 0, :] - lam * ctx[..., 1, :], subln, eps) \
+        * (1.0 - c["lambda_init"])
+    out = out.reshape(b, t, -1) @ wo
+    return (out if bo is None else out + bo), (k, v)
 
 
 def causal_conv(x, w):
@@ -423,6 +521,19 @@ def passes(cfg, params, ids, pos, next_ids=None, found=None):
 
     e, eps = c["num_experts"], c["rms_norm_eps"]
     layers, centred = c["num_hidden_layers"], c["norm_zero_centered"]
+    plain_norm = c["norm_type"] == "layer_norm"     # weight and bias
+
+    def take_norm():
+        return take(2) if plain_norm else take(1)[0]
+
+    def norm(x, w):
+        return layer_norm(x, *w, eps) if plain_norm \
+            else rms_norm(x, w, eps, centred)
+
+    def with_bias(n, has):              # n matrices, each with its bias
+        return [w for _ in range(n)
+                for w in (take(2) if has else take(1) + [None])]
+
     sandwich = c["sandwich_norm"]
     routed = c["ffn_layers"].count("experts")   # the terms' mean is theirs
     embedding = take(1)[0]
@@ -436,8 +547,21 @@ def passes(cfg, params, ids, pos, next_ids=None, found=None):
             # then a layer's own, then shared_head.norm
             w_f, module = take(1)[0], take(3)
         hc_a = take(3) if streams > 1 else None
-        n1 = take(1)[0]
-        if c["mixer_layers"][i] == "gated_delta":
+        n1 = take_norm()
+        if c["mixer_layers"][i] == "mamba":
+            mixer = take(2) + (take(1) if c["conv_bias"] else [None]) \
+                + take(6)
+        elif c["mixer_layers"][i] == "gmu":
+            mixer = take(2)
+        elif c["differential_attention"]:
+            # wq, bq, [wk, bk, wv, bv], the four lambda vectors, the
+            # norm's weight, wo, bo
+            has = c["attention_bias"]
+            own = c["reads_layers"][i] == "own"
+            mixer = with_bias(1, has) + [with_bias(2, has) if own else None,
+                                         take(4)] + take(1) \
+                + with_bias(1, has)
+        elif c["mixer_layers"][i] == "gated_delta":
             mixer = take(7)
         elif c["mixer_layers"][i] == "short_conv":
             mixer = take(3)
@@ -448,12 +572,13 @@ def passes(cfg, params, ids, pos, next_ids=None, found=None):
                 + take(1)
         n2 = take(1)[0] if sandwich else None
         hcs.append((hc_a, take(3) if streams > 1 else None))
-        n3 = take(1)[0]
+        n3 = take_norm()
         # experts: router, [expert bias], gate, up, down, [shared expert's 3
         # and its gate's weight]
         shared = 0 if not c["shared_expert_intermediate_size"] \
             else 4 if c["shared_expert_gate"] else 3
-        ffn = take(3) if c["ffn_layers"][i] == "dense" else (
+        ffn = take(2) if c["mlp_gate_up_fused"] \
+            else take(3) if c["ffn_layers"][i] == "dense" else (
             take(1) + (take(1) if c["use_expert_bias"] else [None])
             + take(3 + shared))
         weights.append((n1, mixer, n2, n3, ffn,
@@ -461,7 +586,7 @@ def passes(cfg, params, ids, pos, next_ids=None, found=None):
     if mtp:
         module = module + take(1)
     else:
-        w_f = take(1)[0]
+        w_f = take_norm()
     w_g, b_g = take(2) if c["exit_gate"] else (None, None)
     # a tied head is the embedding read again, transposed
     w_lm = embedding.T if c["tie_word_embeddings"] else take(1)[0]
@@ -491,12 +616,31 @@ def passes(cfg, params, ids, pos, next_ids=None, found=None):
                 jnp.einsum("btij,btjd->btid", res, x) \
                 + post[..., None] * y[:, :, None]
 
+        handed_on = {}          # what one layer leaves for later ones
+
         def layer(h, i, terms):
             """Layer i on h; terms = (balance, z, load) with the layer's."""
             n1, mixer, n2, n3, ffn, n4 = weights[i]
             x, write = read(h, hcs[i][0])
-            a = rms_norm(x, n1, eps, centred)
-            if c["mixer_layers"][i] == "gated_delta":
+            a = norm(x, n1)
+            if c["mixer_layers"][i] == "mamba":
+                mixed, y = mamba(a, *mixer, found=found)
+                if i == c["memory_layer"]:
+                    handed_on["memory"] = y
+                    if found is not None:
+                        found["memory"] = y
+            elif c["mixer_layers"][i] == "gmu":
+                mixed = gated_memory_unit(a, handed_on["memory"], *mixer)
+            elif c["differential_attention"]:
+                wq, bq, own, lambdas, subln, wo, bo = mixer
+                mixed, kv = differential_attention(
+                    a, wq, bq, handed_on["kv"] if own is None else own,
+                    lambdas, subln, wo, bo, layer_config(c, i))
+                if i == c["kv_layer"]:
+                    handed_on["kv"] = kv
+                    if found is not None:
+                        found["shared_k"], found["shared_v"] = kv
+            elif c["mixer_layers"][i] == "gated_delta":
                 mixed = gated_delta_net(a, *mixer, c)
             elif c["mixer_layers"][i] == "short_conv":
                 mixed = short_conv(a, *mixer)
@@ -509,7 +653,7 @@ def passes(cfg, params, ids, pos, next_ids=None, found=None):
                 mixed = rms_norm(mixed, n2, eps, centred)
             h = write(mixed)
             x, write = read(h, hcs[i][1])
-            m = rms_norm(x, n3, eps, centred)
+            m = norm(x, n3)
             if c["ffn_layers"][i] == "experts":
                 out, lb, lz, ld = routed_experts(
                     m.reshape(b * t, d), ffn[0], *ffn[2:5], c,
@@ -521,6 +665,9 @@ def passes(cfg, params, ids, pos, next_ids=None, found=None):
                     out = out + shared_expert(m, *ffn[5:])
                 terms = (terms[0] + lb / routed, terms[1] + lz / routed,
                          terms[2] + ld)
+            elif c["mlp_gate_up_fused"]:
+                gate, up = jnp.split(m @ ffn[0], 2, axis=-1)
+                out = (jax.nn.silu(gate) * up) @ ffn[1]
             else:
                 wg, wu, wd = ffn
                 out = (jax.nn.silu(m @ wg) * (m @ wu)) @ wd
@@ -534,7 +681,7 @@ def passes(cfg, params, ids, pos, next_ids=None, found=None):
                 h, terms = layer(h, i, terms)
             if streams > 1:
                 h = h.sum(2)
-            h = rms_norm(h, w_f, eps, centred)
+            h = norm(h, w_f)
             logits.append(h @ w_lm)
             if c["exit_gate"]:
                 lam.append(jax.nn.sigmoid(h @ w_g + b_g))

@@ -20,7 +20,7 @@ Accepted forms of PADDLE_TPU_PALLAS:
     - "attn,xent"       : allowlist — exactly the named ops on, the
                           rest off.  Unknown names raise LOUDLY (a typo
                           must not silently run the other path).
-Op names: attn, xent, ln, lstm, seq, gdr, conv, emb, mhc, gmm (KERNEL_OPS).  For 'attn' the flag
+Op names: attn, xent, ln, lstm, seq, gdr, conv, emb, mhc, gmm, scan (KERNEL_OPS).  For 'attn' the flag
 is an opt-OUT only: fused_attention's positive dispatch is always the
 flash_at() rule, so enabling 'attn' does not force flash below the
 crossover (pin FLAGS_flash_min_seq=0 for that).
@@ -121,6 +121,7 @@ DEFAULT_TILES = {
     "emb": {"tile_bytes": 4 << 20},
     "mhc": {"block_rows": 128},
     "gmm": {"block_m": 512},
+    "scan": {"chunk": 64},
 }
 KERNEL_OPS = frozenset(DEFAULT_TILES)
 # Dense attention below this query length, flash at and above it.  The

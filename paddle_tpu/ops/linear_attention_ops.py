@@ -1,7 +1,8 @@
 """Lowerings of the linear-attention mixer's ops: the gated delta rule
-(ops/gated_delta_kernels.py) and the short causal depthwise convolution
-over time that feeds it (ops/causal_conv_kernels.py). No reference-era op computes either: sequence_conv
-is LoD-based and dense over channels."""
+(ops/gated_delta_kernels.py), the short causal depthwise convolution
+over time that feeds it (ops/causal_conv_kernels.py) and a Mamba mixer's
+selective scan (ops/selective_scan_kernels.py). No reference-era op computes
+any of them: sequence_conv is LoD-based and dense over channels."""
 import jax
 import jax.numpy as jnp
 
@@ -66,3 +67,28 @@ def _causal_conv1d(ctx, ins, attrs):
         return (jax.nn.silu(y) if silu else y).astype(x.dtype)
 
     return {"Out": [conv(x, w)]}
+
+
+def selective_scan_path(x, a):
+    """"kernel" where selective_scan_kernels' two passes run for X [B, T,
+    C] under A [C, N]: kernel_config.pallas_on("scan") (a TPU, or
+    PADDLE_TPU_PALLAS) and whole registers of channels at no more than 16
+    states; else "xla", lax.scan over tokens. The one place that decides;
+    the layer counter reads it too."""
+    from .selective_scan_kernels import applies
+    fits = x.ndim == 3 and applies(x.shape[2], a.shape[1])
+    return "kernel" if fits and pallas_on("scan") else "xla"
+
+
+@register("selective_scan", calls_pallas=True, infer=shapes_from(Out="X"))
+def _selective_scan(ctx, ins, attrs):
+    """Out [B, T, C] of the selective scan s_t = exp(Delta_t A) s_(t-1) +
+    Delta_t B_t x_t, y_t = C_t . s_t + D x_t for X, Delta [B, T, C], A [C,
+    N] (negative), B, C [B, T, N] and D [C]: every product, exponential and
+    the state in float32 whatever the operands come in (the op is in neither
+    AMP table), the result back in X's dtype."""
+    from .selective_scan_kernels import selective_scan
+    x, delta, a, b, c, d = (single(ins, name) for name in (
+        "X", "Delta", "A", "B", "C", "D"))
+    out = selective_scan(x, delta, a, b, c, d, path=selective_scan_path(x, a))
+    return {"Out": [out.astype(x.dtype)]}

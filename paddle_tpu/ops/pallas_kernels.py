@@ -47,9 +47,8 @@ __all__ = ["flash_attention", "softmax_xent", "layer_norm",
 
 _NEG = -1e30
 
-# The name each pallas_call gives its Mosaic custom call (the HLO instruction
-# is `<name>.<n>`): a trace, the profiler's table and the benchmark find it by
-# this. From ptpu_gated_delta_fwd on they are other modules' kernels.
+# The name each pallas_call gives its Mosaic custom call (HLO: `<name>.<n>`): a
+# trace and the benchmark find it by this. ptpu_gated_delta_fwd on: other modules'.
 KERNEL_NAMES = (
     "ptpu_flash_fwd", "ptpu_flash_bwd_dkdv", "ptpu_flash_bwd_dq",
     "ptpu_softmax_xent_fwd", "ptpu_layer_norm_fwd", "ptpu_lstm_seq",
@@ -59,7 +58,8 @@ KERNEL_NAMES = (
     "ptpu_mhc_pre_bwd", "ptpu_mhc_post_fwd", "ptpu_mhc_post_bwd",
     "ptpu_mhc_expand", "ptpu_mhc_reduce", "ptpu_mhc_coeffs_fwd",
     "ptpu_mhc_coeffs_bwd", "ptpu_expert_gmm_fwd", "ptpu_expert_gmm_drows",
-    "ptpu_expert_gmm_dweights")
+    "ptpu_expert_gmm_dweights", "ptpu_selective_scan_fwd",
+    "ptpu_selective_scan_bwd")
 
 
 def _interpret_default():
@@ -1793,3 +1793,8 @@ def layer_norm(x, scale, bias, eps=1e-5, block_n=None, interpret=None):
 # Kept at the end of the module: no line above a kernel moves.
 EXPERT_MATMUL_KERNELS = ("ptpu_expert_gmm_fwd", "ptpu_expert_gmm_drows",
                          "ptpu_expert_gmm_dweights")
+# Who runs a Mamba mixer's selective scan (ops/selective_scan_kernels.py, its
+# two passes written out so that nothing here imports them): the benchmark's
+# selective_scan_ms_per_step sums these names' calls.
+SELECTIVE_SCAN_KERNELS = ("ptpu_selective_scan_fwd",
+                          "ptpu_selective_scan_bwd")

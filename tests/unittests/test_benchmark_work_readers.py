@@ -35,6 +35,10 @@ globals().update({name: test for name, test in vars(readers).items()
                   if name.startswith("test_") and name != PINNED})
 
 GLM = "glm_4_7_flash_train_t4096"
+PHI = "phi4_mini_flash_train_t8192"
+# the three metrics PR 54 added with its cell
+PHI_METRICS = ("selective_scan_ms_per_step", "selective_scan_roofline_share",
+               "shared_state_layer_share")
 
 
 def test_glm_flash_operations_against_the_hand_count():
@@ -98,21 +102,26 @@ def test_the_pinned_manifest_test_is_red_for_the_eleventh_cell_alone(
         tmp_path, monkeypatch):
     """PR 47's test as it stands fails on this manifest at the first list it
     pins, and passes on the same manifest with GLM-4.7-Flash's cell, its
-    configuration and its metric taken off again: nothing else it holds
-    has moved."""
+    configuration and its metric taken off again, and with them the
+    twelfth cell (Phi-4-mini-flash's), its configuration and its three
+    metrics: nothing else it holds has moved."""
     pinned = getattr(readers, PINNED)
     with pytest.raises(AssertionError, match="^expert_matmul_ms_per_step$"):
         pinned()
     with open(readers.BENCHMARK) as f:
         bench = json.load(f)
-    bench["workloads"] = [w for w in bench["workloads"] if w["name"] != GLM]
+    bench["workloads"] = [w for w in bench["workloads"]
+                          if w["name"] not in (GLM, PHI)]
     bench["configs"] = [c for c in bench["configs"]
-                        if c["name"] != "glm_4_7_flash"]
+                        if c["name"] not in ("glm_4_7_flash",
+                                             "phi4_mini_flash")]
     bench["per_layer"] = [m for m in bench["per_layer"]
-                          if m["name"] != "mtp_layer_share"]
+                          if m["name"] not in ("mtp_layer_share",)
+                          + PHI_METRICS]
     for metric in bench["end_to_end"] + bench["per_layer"]:
-        if GLM in metric.get("workloads", ()):
-            metric["workloads"].remove(GLM)
+        for cell in (GLM, PHI):
+            if cell in metric.get("workloads", ()):
+                metric["workloads"].remove(cell)
     without = tmp_path / "BENCHMARK.json"
     without.write_text(json.dumps(bench))
     monkeypatch.setattr(readers, "BENCHMARK", str(without))
@@ -160,6 +169,24 @@ def test_the_manifest_lists_the_work_readers_in_eleven_cells():
                 or (metric == "flash_roofline_share"
                     and workload["name"] == "transformer_base_train_t256"), \
                 (workload["name"], metric)
+    # the twelfth cell behind them on the lists whose reader finds something
+    # to read there, with its module's hand counts: 40 heads of 128 over the
+    # pairs inside three masks (one of 512 keys, two causal) and the table of
+    # 25008 words written (its module's own count: the compiled step holds
+    # the 8192 rows in VMEM)
+    for name in ("flash_roofline_share", "embedding_grad_ms_per_step",
+                 "embedding_grad_roofline_share", "step_mfu"):
+        assert entries[name]["workloads"][-1] == PHI, name
+    phi = readers._cell(PHI)
+    pairs = (512 * 513 // 2 + (8192 - 512) * 512) + 2 * (8192 * 8193 // 2)
+    assert phi.config_module.flash_kernel_ops(phi.config, phi.traffic) == {
+        "ptpu_flash_fwd": 4 * 128 * 40 * pairs,
+        "ptpu_flash_bwd_dkdv": 8 * 128 * 40 * pairs,
+        "ptpu_flash_bwd_dq": 6 * 128 * 40 * pairs}
+    assert phi.config_module.embedding_grad_bytes(phi.config, phi.traffic) \
+        == 4 * 2560 * 25008 == 256081920
+    assert phi.config_module.embedding_grad_bytes \
+        is not phi.config_module.base.embedding_grad_bytes
     # eleven cells (PR 49), one of them on four chips: floor(11 x 0.25) = 2
     assert len(bench["workloads"]) >= 11
     assert [w["name"] for w in bench["workloads"]][10] == GLM

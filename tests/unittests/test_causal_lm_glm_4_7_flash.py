@@ -232,7 +232,8 @@ def test_the_module_is_counted():
     modules = REGISTRY.counter("ptpu_causal_lm_mtp_modules_total", "")
     attention = REGISTRY.counter("ptpu_attention_layers_total", "")
     built = dict(mixer="attention", rotary_dim="16", gate="false", conv="0",
-                 shared="32", sandwich="false")
+                 shared="32", sandwich="false", reads="own",
+                 differential="false")
     keys = [dict(built, ffn="experts", module="mtp"),
             dict(built, ffn="experts", module="trunk"),
             dict(built, ffn="dense", shared="0", module="trunk")]

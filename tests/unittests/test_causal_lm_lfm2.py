@@ -61,13 +61,16 @@ def _feed(seed=0):
 COUNTED = {
     "conv_dense": ("ptpu_causal_lm_layers_total", dict(
         mixer="short_conv", rotary_dim="0", gate="false", conv="3",
-        ffn="dense", shared="0", sandwich="false", module="trunk")),
+        ffn="dense", shared="0", sandwich="false", module="trunk",
+        reads="own", differential="false")),
     "conv_experts": ("ptpu_causal_lm_layers_total", dict(
         mixer="short_conv", rotary_dim="0", gate="false", conv="3",
-        ffn="experts", shared="0", sandwich="false", module="trunk")),
+        ffn="experts", shared="0", sandwich="false", module="trunk",
+        reads="own", differential="false")),
     "attention_experts": ("ptpu_causal_lm_layers_total", dict(
         mixer="attention", rotary_dim="8", gate="false", conv="0",
-        ffn="experts", shared="0", sandwich="false", module="trunk")),
+        ffn="experts", shared="0", sandwich="false", module="trunk",
+        reads="own", differential="false")),
     "tied_head": ("ptpu_causal_lm_heads_total", dict(tied="true")),
     "moe": ("ptpu_moe_layers_total", dict(
         top_k="3", experts="8", held="4", activation="silu",

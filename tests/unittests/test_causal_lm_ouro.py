@@ -101,7 +101,8 @@ def _count(name, **labels):
 COUNTED = {
     "built": ("ptpu_causal_lm_layers_total", dict(
         mixer="attention", rotary_dim="8", gate="false", conv="0",
-        ffn="dense", shared="0", sandwich="true", module="trunk")),
+        ffn="dense", shared="0", sandwich="true", module="trunk",
+        reads="own", differential="false")),
     "passes": ("ptpu_layer_passes_total", dict(passes="4", layers="2",
                                                form="scan")),
     "forward": ("ptpu_remat_ops_total", dict(kind="forward",

@@ -77,11 +77,13 @@ def _counts():
         "built_full": _counter("ptpu_causal_lm_layers_total",
                                mixer="attention", rotary_dim="4", gate="true",
                                conv="0", ffn="experts", shared="8",
-                               sandwich="false", module="trunk"),
+                               sandwich="false", module="trunk",
+                               reads="own", differential="false"),
         "built_delta": _counter("ptpu_causal_lm_layers_total",
                                 mixer="gated_delta", rotary_dim="0",
                                 gate="false", conv="4", ffn="experts",
-                                shared="8", sandwich="false", module="trunk")}
+                                shared="8", sandwich="false", module="trunk",
+                                reads="own", differential="false")}
 
 
 def _run_program(amp, pallas=None, monkeypatch=None, cfg=CFG, t=T):

@@ -127,7 +127,8 @@ def test_resolve_maps_smallthinkers_keys():
     plain = causal_lm.resolve(dict(
         vocab_size=8, hidden_size=8, num_hidden_layers=2,
         num_attention_heads=2, intermediate_size=4))
-    assert causal_lm._layer(plain, 1) == dict(plain, layer=1, ffn="dense")
+    assert causal_lm._layer(plain, 1) == dict(plain, layer=1, ffn="dense",
+                                              reads="own", lambda_init=None)
     assert plain["head_dim"] == 4 and plain["experts_held"] == 0
 
 

@@ -61,10 +61,17 @@ PHASE_SAYS = {
         and "whole at blocks" in line and "two_part at blocks" in line
         and "dk_rope" in line and "arriving joined" in line
         for line in lines),
+    # phase N held the scan's kernels to lax.scan and one differential core,
+    # windowed and full, to the dense two maps
+    "N": lambda lines: any(
+        "N selective scan kernels" in line and "ddelta" in line
+        and "forward + backward" in line for line in lines) and any(
+        "N differential core" in line and "window 16 off by" in line
+        and "window None off by" in line for line in lines),
 }
 
 
-@pytest.mark.parametrize("letter", "ABCDEFGHIJKL")
+@pytest.mark.parametrize("letter", "ABCDEFGHIJKLN")
 def test_tiny_rehearsal_passes_every_phase(letter):
     """One case a phase (`--phases <letter>`), so that a red run names it."""
     # phase E times the program phase A left

@@ -27,7 +27,7 @@ REPO = os.path.dirname(os.path.dirname(
 F32, BF16, I32 = "float32", "bfloat16", "int32"
 INFERRED = ("fused_attention", "layer_norm", "softmax_with_cross_entropy",
             "batch_norm", "mhc_pre", "mhc_post", "mhc_expand", "mhc_reduce",
-            "gated_delta_rule", "causal_conv1d", "moe_ffn")
+            "gated_delta_rule", "causal_conv1d", "moe_ffn", "selective_scan")
 
 
 def _attention(t, hq, hkv, d, dv=None, rope=None, batch=-1, dtype=F32,
@@ -96,6 +96,13 @@ def _conv(t, c, k, act=None, batch=-1):
             ("Out",), {"activation": act} if act else {})
 
 
+def _scan(t, c, n, batch=-1, dtype=F32):
+    return ("selective_scan",
+            {"X": ((batch, t, c), dtype), "Delta": ((batch, t, c), F32),
+             "A": ((c, n), F32), "B": ((batch, t, n), F32),
+             "C": ((batch, t, n), F32), "D": ((c,), F32)}, ("Out",), {})
+
+
 def _moe_ffn(t, d, f, experts, held, top_k, **attrs):
     return ("moe_ffn",
             {"X": ((-1, t, d), F32), "Router": ((d, experts), F32),
@@ -150,6 +157,10 @@ CASES = {
     "causal_conv1d-qwen3next-silu": _conv(4096, 8192, 4, "silu"),
     "causal_conv1d-lfm2": _conv(8192, 2048, 3),
     "causal_conv1d-off-the-kernel": _conv(100, 96, 3, batch=2),
+    "selective_scan-phi4flash": _scan(8192, 5120, 16),
+    "selective_scan-phi4flash-static-bfloat16": _scan(8192, 5120, 16,
+                                                      batch=1, dtype=BF16),
+    "selective_scan-off-the-kernel": _scan(40, 96, 4, batch=2),
     # PR 50's infer, a table since PR 53
     "moe_ffn-olmoe-64-all-held": _moe_ffn(4096, 2048, 1024, 64, 64, 8),
     "moe_ffn-smallthinker-64-a-quarter-held": _moe_ffn(
