@@ -177,7 +177,7 @@ def _device_batch(jax, cell, loop, seed):
 
 def _host_reader(cell, loop, seed):
     """uint8 batches staged host -> device by the program's own
-    DoubleBufferReader, capacity 2 (copied from bench.py's host_u8 mode:
+    DoubleBufferReader, capacity 2 (a traffic file's `feed: host_u8`:
     three host batches in rotation, so the copy is real and the generator
     costs nothing)."""
     from paddle_tpu.core.readers import DoubleBufferReader, IteratorReader

@@ -47,8 +47,7 @@ def make_batch(cfg, traffic, key):
 
 
 def host_batches(cfg, traffic, rng, n):
-    """`n` numpy batches with uint8 images for the host-fed traffic (copied
-    from bench.py's BENCH_FEED=host_u8 generator)."""
+    """`n` numpy batches with uint8 images for the host-fed traffic."""
     b, hw = traffic["batch"], cfg["image_hw"]
     return [{
         "image": (rng.rand(b, cfg["image_channels"], hw, hw)
@@ -86,7 +85,7 @@ def ops_per_sample(cfg, traffic):
     the weights). Batch norm, activations, pooling and the optimizer are
     not counted; nor is it taken off that the first convolution needs no
     gradient to its input (1.4 % of the total). 24.53e9 at the paper's
-    sizes, which is bench.py's audited 3 x 8.18e9."""
+    sizes, 3 x 8.18e9."""
     macs = sum(ci * co * k * k * hw * hw for ci, co, k, hw in _convs(cfg))
     return 3 * 2 * macs
 

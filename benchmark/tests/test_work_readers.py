@@ -96,9 +96,8 @@ def test_expert_matmul_reader_on_a_cpu_rehearsal_and_an_empty_window():
 
 def test_every_expert_matmul_kernel_is_a_named_kernel_of_the_program():
     """Every name the reader takes from the program is one the trace can
-    show (KERNEL_NAMES). The program has no EXPERT_MATMUL_KERNELS yet (this
-    PR may not add it: PERF.md section 7), and the reader then names no
-    kernel."""
+    show (KERNEL_NAMES). The program lists them in EXPERT_MATMUL_KERNELS;
+    where it has no such tuple the reader names no kernel."""
     from paddle_tpu.ops import pallas_kernels
     reader = _reader("expert_matmul_ms_per_step")
     assert reader.kernels() == tuple(
