@@ -46,7 +46,9 @@ import shutil
 import time
 import warnings
 
-AOT_FORMAT_VERSION = 1
+# 2: the step returns (new_state, fetches, errors), state_rw first
+# (lowering.jit_step); an executable stored under 1 unpacks wrongly
+AOT_FORMAT_VERSION = 2
 AOT_ENTRY_PREFIX = "aot_"
 AOT_TMP_PREFIX = ".tmp_aot_"
 META_FILE = "meta.json"

@@ -846,7 +846,7 @@ class Executor(object):
                     try:
                         t0c = time.perf_counter()
                         with jax.default_device(self.place.device()):
-                            comp = jax.jit(fn, donate_argnums=(1,)).lower(
+                            comp = lowering.jit_step(fn).lower(
                                 [feed_arrays[n] for n in feed_names],
                                 read_state(state_rw),
                                 read_state(state_ro),
@@ -860,7 +860,7 @@ class Executor(object):
                         # errors with their op annotations at dispatch
                         pass
                 if executable is None:
-                    executable = jax.jit(fn, donate_argnums=(1,))
+                    executable = lowering.jit_step(fn)
             entry = (executable, state_rw, state_ro, state_out)
             if use_program_cache:
                 _cache_put_lru(self._cache, key, entry,
@@ -920,19 +920,26 @@ class Executor(object):
                 fn = lowering.build_program_fn(
                     program, feed_names, fetch_names, state_rw, state_ro,
                     state_out, collect_errors=True)
-            fresh = jax.jit(fn, donate_argnums=(1,))
+            fresh = lowering.jit_step(fn)
             if use_program_cache:
                 _cache_put_lru(self._cache, key,
                                (fresh, state_rw, state_ro, state_out),
                                _jit_cache_capacity())
             return fresh
 
-        (fetches, new_state, errors), fell_back = \
+        # lowering.jit_step's order: the state first, for the donation
+        (new_state, fetches, errors), fell_back = \
             _dispatch.call_with_aval_fallback(
                 _call, jitted, aot_entry, _find_aot_entry, _rebuild)
         if fell_back:
             compiled, aot_hit, aot_saved, aot_entry = \
                 True, False, 0.0, None
+        if compiled:
+            # once a compile: which result jax gave each donated buffer to
+            # (the scope still holds the donated arrays; types are enough)
+            lowering.count_donated_buffers(
+                state_rw, [scope.get(n) for n in state_rw], state_out,
+                new_state, (fetches, errors))
         # sentinel stat tap: peel float statistics (grad norm) off the
         # error dict before any error sync; values stay device-resident
         self.last_stats = pop_guard_stats(errors)

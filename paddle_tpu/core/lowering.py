@@ -17,6 +17,8 @@ Pallas kernel (OpDef.calls_pallas) and whose gradient is taken in the same
 block lowers under jax.vjp once and keeps its vjp_fn for the grad op
 (_linearizations): every Pallas forward kernel runs once a step.
 """
+import collections
+import itertools
 import re
 import threading
 import time
@@ -1140,12 +1142,17 @@ def build_program_fn(program, feed_names, fetch_names, state_rw, state_ro,
 
     fn(feed_vals, state_rw_vals, state_ro_vals, seed)
         -> (fetch_vals, new_state_vals)            # collect_errors=False
-        -> (fetch_vals, new_state_vals, errors)    # collect_errors=True
+        -> (new_state_vals, fetch_vals, errors)    # collect_errors=True
+
+    collect_errors=True is the form the executors jit (jit_step), and it
+    returns the state FIRST: the order of a donating jit's results decides
+    which donated buffer each takes (see jit_step).
 
     state_rw: persistable vars both read and overwritten — safe to donate
     (in-place parameter update on device). state_ro: read-only persistables
     (e.g. the learning-rate var) — must NOT be donated, the Scope keeps them.
-    state_out: all persistables written (order of the returned new state).
+    state_out: all persistables written (order of the returned new state;
+    analyze_state puts state_rw first — the donation contract, see jit_step).
 
     errors is a {message: bool_scalar} dict of in-graph assertion flags
     (e.g. TensorArray capacity overflows) the caller must raise on — the
@@ -1208,7 +1215,7 @@ def build_program_fn(program, feed_names, fetch_names, state_rw, state_ro,
                     any_flag = any_flag | (
                         f.any() if getattr(f, "ndim", 0) else f)
                 errors["__any__"] = any_flag
-            return fetches, new_state, errors
+            return new_state, fetches, errors
         return fetches, new_state
 
     return fn
@@ -1274,7 +1281,7 @@ def lower_multi_step(program, feed_names, fetch_names, state_rw, state_ro,
 
     Returns fn(feed_vals, state_rw_vals, state_ro_vals, seed) with the SAME
     signature and return shape as the single-step collect_errors=True fn —
-    (fetch_vals, new_state_vals, errors) — but internally a lax.scan runs
+    (new_state_vals, fetch_vals, errors) — but internally a lax.scan runs
     the step K times with state kept on device: the host syncs once per K
     steps instead of once per step, which is the whole point (TensorFlow's
     in-graph loops made the same move against per-step dispatch).
@@ -1306,8 +1313,11 @@ def lower_multi_step(program, feed_names, fetch_names, state_rw, state_ro,
                                state_ro, state_out, mesh=mesh,
                                collect_errors=True,
                                shard_constraints=shard_constraints)
-    rw_pos = {n: i for i, n in enumerate(state_rw)}
-    out_pos = {n: i for i, n in enumerate(state_out)}
+    # analyze_state's contract: state_out is state_rw, then write-only names
+    n_rw = len(state_rw)
+    if list(state_out[:n_rw]) != list(state_rw):
+        raise ValueError("state_out must start with state_rw (the order "
+                         "lowering.analyze_state returns)")
     stacked = frozenset(stacked_feed_names)
 
     def fn(feed_vals, state_rw_vals, state_ro_vals, seed):
@@ -1321,9 +1331,8 @@ def lower_multi_step(program, feed_names, fetch_names, state_rw, state_ro,
             for i in range(steps):
                 cur_feeds = step_feeds(
                     lambda n, v, i=i: v[i] if n in stacked else v)
-                rw_vals = state_rw_vals if state is None else \
-                    [state[out_pos[n]] for n in state_rw]
-                fetches, state, errors = step_fn(
+                rw_vals = state_rw_vals if state is None else state[:n_rw]
+                state, fetches, errors = step_fn(
                     cur_feeds, rw_vals, state_ro_vals,
                     jnp.asarray(seed, jnp.uint32) + jnp.uint32(i))
                 err_acc = errors if err_acc is None else \
@@ -1345,10 +1354,10 @@ def lower_multi_step(program, feed_names, fetch_names, state_rw, state_ro,
             else:
                 fetches = [jnp.stack([stp[j] for stp in per_step])
                            for j in range(len(fetch_names))]
-            return fetches, list(state), err_acc
+            return list(state), fetches, err_acc
 
         # shapes/dtypes of one step's outputs (abstract trace — no XLA)
-        fetch_sh, state_sh, err_sh = jax.eval_shape(
+        state_sh, fetch_sh, err_sh = jax.eval_shape(
             step_fn,
             step_feeds(lambda n, v: jax.ShapeDtypeStruct(
                 v.shape[1:] if n in stacked else v.shape, v.dtype)),
@@ -1356,10 +1365,8 @@ def lower_multi_step(program, feed_names, fetch_names, state_rw, state_ro,
         # loop carry: full state_out row. rw names start from the scope's
         # values; write-only names are overwritten before anyone reads them,
         # so zeros of the right aval satisfy scan's carry typing.
-        init_state = [
-            state_rw_vals[rw_pos[n]] if n in rw_pos
-            else jnp.zeros(state_sh[i].shape, state_sh[i].dtype)
-            for i, n in enumerate(state_out)]
+        init_state = list(state_rw_vals) + [
+            jnp.zeros(s.shape, s.dtype) for s in state_sh[n_rw:]]
         if fetch_reduce == "mean":
             init_fetch = [jnp.zeros(s.shape, _mean_acc_dtype(s.dtype))
                           for s in fetch_sh]
@@ -1382,9 +1389,8 @@ def lower_multi_step(program, feed_names, fetch_names, state_rw, state_ro,
             it = iter(cur_stacked)
             cur_feeds = step_feeds(
                 lambda n, v: next(it) if n in stacked else v)
-            rw_vals = [state_vals[out_pos[n]] for n in state_rw]
-            fetches, new_state, errors = step_fn(
-                cur_feeds, rw_vals, state_ro_vals, step_seed)
+            new_state, fetches, errors = step_fn(
+                cur_feeds, state_vals[:n_rw], state_ro_vals, step_seed)
             err_acc = fold_errors(err_acc, errors)
             if fetch_reduce == "mean":
                 fetch_acc = [a + f.astype(a.dtype)
@@ -1406,7 +1412,7 @@ def lower_multi_step(program, feed_names, fetch_names, state_rw, state_ro,
             fetches = fetch_acc
         else:
             fetches = list(ys)
-        return fetches, final_state, err_acc
+        return final_state, fetches, err_acc
 
     return fn
 
@@ -1415,9 +1421,21 @@ def analyze_state(program, feed_names, fetch_names=()):
     """Decide which persistable vars are program state (static analysis).
 
     Returns (state_rw, state_ro, state_out):
-      state_rw — read from Scope AND overwritten (donate: in-place update)
+      state_rw — read from Scope AND overwritten (donate: in-place update),
+                 in order of first write
       state_ro — read from Scope, never written (do not donate)
-      state_out — all persistables written (order of returned new state)
+      state_out — all persistables written (order of returned new state):
+                  state_rw IN ITS OWN ORDER, then the write-only names in
+                  order of first write
+
+    state_out's order is a contract with jax's donation, not an accident of
+    the op walk: jax gives each result, in order, the first free donated
+    argument of its shape and dtype, so only results that come in the
+    arguments' order get their own variable's buffer (see jit_step). Any
+    one order for both lists holds that; the order of first write is the
+    one measured fastest of four where the order is all that moves
+    (resnet50_train_b256, PERF.md section 6, PR 56: XLA breaks its
+    scheduling ties by it).
 
     `fetch_names` count as reads: fetching a persistable var no op produces
     (the evaluator.eval pattern — an empty program fetching state) reads it
@@ -1454,9 +1472,98 @@ def analyze_state(program, feed_names, fetch_names=()):
     # plain fetch, not a scope read.
     for name in fetch_names:
         visit_read(name)
-    state_rw = [n for n in state_in if n in seen_out]
+    state_rw = [n for n in state_out if n in seen_in]
     state_ro = [n for n in state_in if n not in seen_out]
-    return state_rw, state_ro, state_out
+    write_only = [n for n in state_out if n not in seen_in]
+    return state_rw, state_ro, state_rw + write_only
+
+
+def donation_pairing(donated, results):
+    """Which result jax gives each donated argument's buffer to, by name.
+
+    donated: [(name, shape, dtype)] of the donated arguments, in argument
+    order. results: [(name or None, shape, dtype)] of the flattened results,
+    in result order. Returns {donated name: "own" | "other" | "none"}: the
+    buffer went to the result of the same name, to some other result, to
+    none.
+
+    The rule is jax's (jax/_src/interpreters/mlir.py _set_up_aliases):
+    walking the results in order, each takes the first donated argument of
+    its (shape, dtype) that no earlier result took. jax knows no names, so
+    tests/unittests/test_donation_pairing.py holds this copy of the rule to
+    the tf.aliasing_output attributes jax wrote into the lowered module."""
+    free = collections.defaultdict(collections.deque)
+    for name, shape, dtype in donated:
+        free[tuple(shape), jnp.dtype(dtype)].append(name)
+    paired = {name: "none" for name, _, _ in donated}
+    for name, shape, dtype in results:
+        queue = free.get((tuple(shape), jnp.dtype(dtype)))
+        if queue:
+            taken = queue.popleft()
+            paired[taken] = "own" if taken == name else "other"
+    return paired
+
+
+def jit_step(fn, **jit_kwargs):
+    """jax.jit of an executor's step (build_program_fn with
+    collect_errors=True, or lower_multi_step) with state_rw donated: the
+    one place that donates, and the reason the step returns
+
+        (new_state_vals, fetch_vals, errors)
+
+    the state FIRST, in analyze_state's order (state_rw, then the
+    write-only names, each in order of first write). jax pairs donated
+    buffers with results first come, first served within a (shape, dtype)
+    class (donation_pairing), so this order gives every state_rw buffer to
+    its own variable's new value and the update runs in place. A fetch or
+    an error statistic of a parameter's shape comes after every state result
+    and can take only a buffer no variable claimed; so can a write-only
+    persistable, which has no buffer of its own. In any other order XLA must
+    copy each mispaired new value out of the way of a buffer that is still
+    being read: one copy of every parameter and moment a step.
+
+    A variable whose new value has another shape or dtype than its old one
+    cannot alias its own buffer; nothing is done about it. Its new value
+    takes the buffer of the next variable of the NEW class, which shifts
+    that class by one from there on, and
+    ptpu_donated_state_buffers_total{paired="other"} shows it.
+
+    `fn` itself is jitted, under no wrapper: a Python frame between the jit
+    and the rules costs every traced equation (a wrapper that swapped the
+    results read +0.6 to +1.9 s of a first step, PERF.md section 6, PR 56).
+
+    jit_kwargs: in_shardings / out_shardings (ParallelExecutor), in the
+    step's argument and result order."""
+    return jax.jit(fn, donate_argnums=(1,), **jit_kwargs)
+
+
+def count_donated_buffers(state_rw, state_rw_vals, state_out, new_state,
+                          others):
+    """Books ptpu_donated_state_buffers_total for one compiled step: the
+    executors call it once a compile (never on the warm path) with the
+    values they passed and got. state_rw_vals may be the donated, deleted
+    arrays: only their types are read. `others`: the step's results after
+    the state (fetches, errors)."""
+    from ..observability.registry import REGISTRY
+    paired = donation_pairing(
+        _named_avals(state_rw, state_rw_vals),
+        _named_avals(state_out, new_state)
+        + _named_avals(itertools.repeat(None),
+                       jax.tree_util.tree_leaves(others)))
+    counter = REGISTRY.counter(
+        "ptpu_donated_state_buffers_total",
+        "state_rw buffers donated to a compiled step, by the result jax "
+        "aliased each to: the same variable's new value (own: updated in "
+        "place), another result (other: XLA copies), none")
+    for how, buffers in collections.Counter(paired.values()).items():
+        counter.inc(buffers, paired=how)
+
+
+def _named_avals(names, vals):
+    """[(name, shape, dtype)] of the arrays in `vals`, as jit flattens
+    them."""
+    return [(n, t.shape, t.dtype) for n, v in zip(names, vals)
+            for t in map(jax.typeof, jax.tree_util.tree_leaves(v))]
 
 
 def build_slot_update_fn():

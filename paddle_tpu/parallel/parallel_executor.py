@@ -316,9 +316,9 @@ class ParallelExecutor(object):
                 [self._state_sharding(n) for n in state_ro],
                 rep,
             )
-            out_shardings = (rep,
-                             [self._state_sharding(n) for n in state_out],
-                             rep)
+            # lowering.jit_step's order: the state first, for the donation
+            out_shardings = ([self._state_sharding(n) for n in state_out],
+                             rep, rep)
             # the plan's gradient constraints pin each sharded param's
             # grad to the owner's shard layout inside the traced step, so
             # GSPMD lowers the cross-replica gradient sum as
@@ -343,9 +343,8 @@ class ParallelExecutor(object):
                     program, feed_names, fetch_names, state_rw,
                     state_ro, state_out, mesh=self.mesh,
                     collect_errors=True, shard_constraints=constraints)
-            return jax.jit(fn, in_shardings=in_shardings,
-                           out_shardings=out_shardings,
-                           donate_argnums=(1,))
+            return lowering.jit_step(fn, in_shardings=in_shardings,
+                                     out_shardings=out_shardings)
 
         def aot_key():
             # the sharded executable is keyed on everything that shapes
@@ -505,12 +504,18 @@ class ParallelExecutor(object):
                            _jit_cache_capacity())
             return fresh
 
-        (fetches, new_state, errors), fell_back = \
+        (new_state, fetches, errors), fell_back = \
             _dispatch.call_with_aval_fallback(
                 _call, jitted, aot_entry, _find_aot_entry, _rebuild)
         if fell_back:
             compiled, aot_hit, aot_saved, aot_entry = \
                 True, False, 0.0, None
+        if compiled:
+            # once a compile: which result jax gave each donated buffer to
+            # (the scope still holds the donated arrays; types are enough)
+            lowering.count_donated_buffers(
+                state_rw, [scope.get(n) for n in state_rw], state_out,
+                new_state, (fetches, errors))
         # sentinel stat tap: peel float statistics (grad norm) off the
         # error dict before any error sync (see Executor._run_impl)
         from ..core.executor import pop_guard_stats
