@@ -151,6 +151,10 @@ FULL = {
     # + 64 on values of 256; the blocks each form runs at
     # the Phi-4-mini-flash cell's scan and differential core
     "selective_scan": dict(b=1, t=8192, c=5120, n=16, tol=2e-3),
+    # the granite-4.0-h-micro cell's state-space-dual scan: one sequence of
+    # 2048, 64 heads of 64 on 128 states; (chunk, heads a grid step) swept
+    "ssd": dict(b=1, t=2048, h=64, p=64, n=128, tol=3e-2, segment=64,
+                sweep=((128, 8), (128, 16), (128, 32), (256, 16))),
     "differential": dict(b=1, t=8192, pairs=20, kv_pairs=10, hd=64,
                          window=512, tol=2e-2),
     "latent_unequal": dict(b=2, t=4096, h=20, d=192, dr=64, dv=256,
@@ -201,6 +205,8 @@ TINY = {
     "latent": dict(t=64, h=2, d=128, dr=64, streams=4, c=128, iters=20,
                    tol=3e-2),
     "selective_scan": dict(b=2, t=72, c=1024, n=4, tol=2e-3),
+    "ssd": dict(b=2, t=72, h=4, p=64, n=16, tol=3e-2, segment=8,
+                sweep=((16, 2),)),
     "differential": dict(b=1, t=64, pairs=4, kv_pairs=2, hd=16, window=16,
                          tol=2e-2),
     "latent_unequal": dict(b=2, t=64, h=2, d=192, dr=64, dv=256,
@@ -2068,6 +2074,79 @@ def phase_n(smoke):
             raise AssertionError("the differential core is %.2e off" % worst)
 
 
+def phase_o(smoke):
+    """The state-space-dual scan's two kernels (bf16 operands, as a cell
+    runs them) against the recurrence token by token in float32 on this
+    device, forward and the six gradients, under the decay a layer starts
+    with and under Delta A = -6 a token; then timed, forward and forward +
+    backward, at the table's tile and at the tiles of the sweep."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.models.causal_lm_reference import ssd_scan as plain
+    from paddle_tpu.ops import ssd_kernels as ssd
+    from paddle_tpu.ops.kernel_config import DEFAULT_TILES
+
+    c = smoke.cfg["ssd"]
+    b, t, h, p, n = (c[k] for k in ("b", "t", "h", "p", "n"))
+    keys = jax.random.split(jax.random.key(57), 6)
+    bf = jnp.bfloat16
+    with jax.default_device(smoke.device):
+        x = jax.random.normal(keys[0], (b, t, h, p)).astype(bf)
+        bm, cm = (jax.random.normal(k, (b, t, n)).astype(bf)
+                  for k in keys[1:3])
+        d = 1.0 + 0.1 * jax.random.normal(keys[3], (h,))
+        dy = jax.random.normal(keys[4], (b, t, h, p))
+        starts = jnp.exp(jax.random.uniform(
+            keys[5], (b, t, h), minval=jnp.log(1e-3), maxval=jnp.log(1e-1)))
+        decays = {"a layer's start": (starts, -jnp.linspace(1.0, h, h)),
+                  "Delta A = -6 a token": (jnp.ones((b, t, h)),
+                                           jnp.full((h,), -6.0))}
+
+        def both(fn):
+            def run(*args):
+                y, vjp = jax.vjp(fn, *args)
+                return (y,) + vjp(dy)
+            return jax.jit(run)
+
+        def kernels(*v):
+            return ssd.ssd_scan(*v, operand_dtype=bf, path="kernel")
+
+        def tokens(*v):
+            with jax.default_matmul_precision("highest"):
+                return plain(*(u.astype(jnp.float32) for u in v),
+                             segment=c["segment"])
+
+        names = ("y", "dx", "ddelta", "da", "db", "dc", "dd")
+        worst = 0.0
+        for name, (delta, a) in decays.items():
+            args = (x, delta, a, bm, cm, d)
+            errors = _normalized_errors(names, both(kernels)(*args),
+                                        both(tokens)(*args))
+            worst = max(worst, *errors.values())
+            smoke.say("O ssd kernels, x %s, %d states, %s (tolerance %g, "
+                      "against the recurrence token by token in float32 on "
+                      "this device): off by %s" % (
+                          [b, t, h, p], n, name, c["tol"], ", ".join(
+                              "%s %.2e" % kv for kv in errors.items())))
+        args = (x,) + decays["a layer's start"] + (bm, cm, d)
+        tiles = DEFAULT_TILES["ssd"]
+        table = dict(tiles)
+        try:
+            for chunk, block_h in ((table["chunk"], table["block_h"]),) \
+                    + tuple(c["sweep"]):
+                tiles.update(chunk=chunk, block_h=block_h)
+                smoke.say("O ssd kernels at chunks of %d, %d heads a grid "
+                          "step: ms forward %.3f, forward + backward %.3f"
+                          % (chunk, block_h,
+                             _in_flight_ms(jax.jit(kernels), args),
+                             _in_flight_ms(both(kernels), args)))
+        finally:
+            tiles.update(table)
+        if not worst <= c["tol"]:
+            raise AssertionError("the ssd kernels are %.2e off the "
+                                 "recurrence" % worst)
+
+
 PHASES = (("A", "ResNet-50 training", phase_a),
           ("B", "transformer training", phase_b),
           ("C", "Pallas kernel families", phase_c),
@@ -2081,7 +2160,8 @@ PHASES = (("A", "ResNet-50 training", phase_a),
           ("K", "the output head and its loss", phase_k),
           ("L", "the latent core at 192 + 64 on 256", phase_l),
           ("M", "the routed experts' grouped matmuls", phase_m),
-          ("N", "the selective scan and the differential core", phase_n))
+          ("N", "the selective scan and the differential core", phase_n),
+          ("O", "the state-space-dual scan", phase_o))
 
 
 def main(argv=None):
@@ -2089,7 +2169,8 @@ def main(argv=None):
     ap.add_argument("--tiny", action="store_true",
                     help="CPU rehearsal at toy sizes (needs "
                          "JAX_PLATFORMS=cpu)")
-    ap.add_argument("--phases", default="ABCDEFGHIJKLM",
+    ap.add_argument("--phases",
+                    default="".join(letter for letter, _, _ in PHASES),
                     help="letters of the phases to run (default all)")
     args = ap.parse_args(argv)
 

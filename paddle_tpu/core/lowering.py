@@ -717,6 +717,8 @@ def _lower_op_inner(ctx, op, env):
         _count_causal_conv_layer(op.attrs, ins)
     elif op.type == "selective_scan":
         _count_selective_scan_layer(ins)
+    elif op.type == "ssd_scan":
+        _count_ssd_scan_layer(ins)
     elif op.type == "lookup_table":
         _count_embedding_layer(ctx, ins)
     elif op.type == "mhc_pre":
@@ -1044,6 +1046,21 @@ def _count_selective_scan_layer(ins):
     ).inc(channels=str(a.shape[0]), states=str(a.shape[1]),
           chunk=str(DEFAULT_TILES["scan"]["chunk"]),
           path=selective_scan_path(x, a))
+
+
+def _count_ssd_scan_layer(ins):
+    from ..observability.registry import REGISTRY
+    from ..ops.kernel_config import DEFAULT_TILES
+    from ..ops.linear_attention_ops import ssd_scan_path
+    x, b = ins["X"][0], ins["B"][0]
+    REGISTRY.counter(
+        "ptpu_ssd_scan_layers_total",
+        "ssd_scan ops lowered (forward ops, not a grad op's replay), by the "
+        "heads, a head's channels, the states a channel, the chunk and the "
+        "path of the pass over chunks (the two Pallas kernels, or lax.scan)"
+    ).inc(heads=str(x.shape[2]), head_dim=str(x.shape[3]),
+          states=str(b.shape[2]), chunk=str(DEFAULT_TILES["ssd"]["chunk"]),
+          path=ssd_scan_path(x))
 
 
 def _count_embedding_layer(ctx, ins):

@@ -24,7 +24,8 @@ __all__ = [
     "expand", "sequence_mask", "linear_chain_crf", "crf_decoding",
     "chunk_eval", "warpctc", "ctc_greedy_decoder", "sequence_erase",
     "edit_distance", "fused_attention", "rms_norm", "rotary_embedding",
-    "causal_conv1d", "gated_delta_rule", "selective_scan", "mhc_pre",
+    "causal_conv1d", "gated_delta_rule", "selective_scan", "ssd_scan",
+    "mhc_pre",
     "mhc_post", "mhc_expand",
     "mhc_reduce",
 ]
@@ -388,6 +389,23 @@ def selective_scan(x, delta, a, b, c, d, name=None):
     out = helper.create_variable_for_type_inference(x.dtype)
     helper.append_op(
         type="selective_scan",
+        inputs={"X": [x], "Delta": [delta], "A": [a], "B": [b], "C": [c],
+                "D": [d]},
+        outputs={"Out": [out]}, attrs={})
+    return out
+
+
+def ssd_scan(x, delta, a, b, c, d, name=None):
+    """A Mamba-2 mixer's state-space-dual scan (ops/ssd_kernels.py): x [B,
+    T, H, P], delta [B, T, H] (> 0, after its softplus), a (negative) and d
+    [H], and b, c [B, T, N], one group that every head reads -> [B, T, H, P]
+    in x's dtype. A head from s = 0, s [N, P]: s_t = exp(delta_t a) s_(t-1)
+    + b_t^T (delta_t x_t); y_t = c_t s_t + d x_t, computed a chunk of
+    tokens at a time as matmuls."""
+    helper = LayerHelper("ssd_scan", name=name)
+    out = helper.create_variable_for_type_inference(x.dtype)
+    helper.append_op(
+        type="ssd_scan",
         inputs={"X": [x], "Delta": [delta], "A": [a], "B": [b], "C": [c],
                 "D": [d]},
         outputs={"Out": [out]}, attrs={})

@@ -14,7 +14,8 @@ output) or a gated delta net (a causal depthwise convolution over q, k and
 v, the gated delta rule, a gated RMS norm a head) or a gated short
 convolution (C * conv(B * u) between two projections) or a Mamba mixer (a
 selective scan behind a convolution) or a gated memory unit on another
-layer's scan output. A new decoder-only
+layer's scan output or a Mamba-2 mixer (a state-space-dual scan behind a
+convolution, gated before its norm). A new decoder-only
 architecture is a config plus the ops it lacks, not a model file. Users:
 OLMoE-1B-7B (`model_type: olmoe`; Muennighoff et al. 2024, arXiv:2409.02060),
 SmallThinker-21BA3B (PowerInfer; window and full attention mixed with
@@ -145,6 +146,25 @@ value), `mamba_d_state` (16), `mamba_d_conv` (4), `mamba_expand` (2),
 `lambda_initializer_range` (0.1) and `layer_indices` (the published index
 of each layer a cut stack kept; a layer's kind, window and lambda_init
 follow it).
+granite-4.0-h-micro's (`model_type: granitemoehybrid`; Mamba-2,
+arXiv:2405.21060): layer_types of `mamba` (a Mamba-2 mixer, "mamba2" among
+`mixer_layers`, known where the config has mamba_n_heads: `mamba2` has the
+equations) and `attention`; a list longer than the stack is cut to its
+first layers where share.published.num_hidden_layers says it is the whole
+model's; mamba_n_heads x mamba_d_head = mamba_expand x hidden_size,
+mamba_d_state, mamba_d_conv, mamba_conv_bias (true), mamba_proj_bias (false;
+true refused), mamba_n_groups (1; more refused), mamba_chunk_size (not
+read: the kernels' chunk is kernel_config's); position_embedding_type (rope,
+or nope: no rotary in any layer, whatever rope_theta says);
+shared_intermediate_size (the dense MLP's width, its first matrix gate and
+value in one), num_local_experts 0 (dense; routed ones refused under this
+key); and four scalars, each 1 by default and an op only where it is not:
+embedding_multiplier (on the embedding's output), residual_multiplier (on
+what each of a layer's two branches adds to the stream),
+attention_multiplier (the scores' scale ITSELF, what the attention's core
+is given, in place of head_dim^-0.5), logits_scaling (the logits are
+divided by it). A Mamba-2 layer's parameters: w_in, conv, [conv.bias],
+dt_bias, a_log, d, gated_norm, w_out.
 A Mamba layer's parameters: w_in, conv, [conv.bias], w_x, w_dt, dt_bias,
 a_log, d, w_out; a memory unit's: w_in, w_out; a differential attention's:
 wq, [wq.bias], then where it makes its own keys and values wk, [wk.bias],
@@ -236,7 +256,10 @@ DEFAULTS = {
     "mlp_gate_up_fused": False, "mamba_d_state": 16, "mamba_d_conv": 4,
     "mamba_expand": 2, "mamba_dt_rank": "auto", "lambda_initializer_range": 0.1,
     "embd_pdrop": 0, "resid_pdrop": 0, "mlp_bias": False,
-    "lm_head_bias": False}
+    "lm_head_bias": False, "embedding_multiplier": 1,
+    "residual_multiplier": 1, "attention_multiplier": None,
+    "logits_scaling": 1, "mamba_conv_bias": True, "mamba_proj_bias": False,
+    "mamba_n_groups": 1, "position_embedding_type": "rope"}
 # the stream every expert bias is drawn from, whatever the program's seed:
 # the draw the LFM2 cell's limits were read under (PERF.md section 4)
 EXPERT_BIAS_SEED = 39
@@ -255,8 +278,15 @@ LATENT_KEYS = ("q_lora_rank", "kv_lora_rank", "qk_nope_head_dim",
 # the stream a Mamba mixer's Delta bias is drawn from, whatever the program's
 # seed (the draw the Phi-4-mini-flash cell's limits were read under)
 MAMBA_DT_SEED = 54
-# a layer's kind in `layer_types` -> its mixer here
-LAYER_TYPES = {"conv": "short_conv", "full_attention": "attention"}
+# a layer's kind in `layer_types` -> its mixer here; granitemoehybrid's
+# `mamba` is a Mamba-2 mixer ("mamba2": the config's mamba_n_heads and
+# mamba_d_head tell it from the Mamba-1 mixer that mb_per_layer builds) and
+# is known only to a config that has those keys
+LAYER_TYPES = {"conv": "short_conv", "full_attention": "attention",
+               "attention": "attention"}
+# the stream a Mamba-2 mixer's Delta bias is drawn from (MAMBA_DT_SEED's
+# kind: the draw the granite-4.0-h-micro cell's limits were read under)
+MAMBA2_DT_SEED = 57
 # the keys a gated delta net needs
 LINEAR_KEYS = ("linear_num_key_heads", "linear_num_value_heads",
                "linear_key_head_dim", "linear_value_head_dim",
@@ -272,7 +302,8 @@ def resolve(cfg):
     rope_inv_freq, rope_table_scale and attention_scale (YaRN's, or None, 1
     and None) and the per-layer patterns
     `rope_layers`, `window_layers`, `mixer_layers` ("attention",
-    "gated_delta" or "short_conv") and `ffn_layers` ("dense" or
+    "gated_delta", "short_conv", "mamba", "gmu" or "mamba2") and
+    `ffn_layers` ("dense" or
     "experts"), each with one entry more than the trunk has layers where
     there is a multi-token-prediction module (`mtp_layers` 1): the module's
     layer, an attention layer with the model's experts."""
@@ -299,6 +330,32 @@ def resolve(cfg):
             c["n_shared_experts"] * c["intermediate_size"]
         c["shared_expert_gate"] = False
     c["mb_per_layer"] = int(c["mb_per_layer"])
+    # granitemoehybrid's names: no routed experts is a dense model, whose
+    # MLP's width is shared_intermediate_size, its first matrix gate and
+    # value in one; no positional term under `nope`
+    if "num_local_experts" in c:
+        if c["num_local_experts"] or c.get("num_experts_per_tok"):
+            raise NotImplementedError(
+                "causal_lm builds num_local_experts 0 (a dense MLP of "
+                "shared_intermediate_size), the config has %r routed, %r a "
+                "token" % (c["num_local_experts"],
+                           c.get("num_experts_per_tok")))
+        c["num_experts"] = c["num_experts_per_tok"] = 0
+    if "shared_intermediate_size" in c:
+        c["dense_intermediate_size"] = c["shared_intermediate_size"]
+        c["mlp_gate_up_fused"] = True
+    if c["position_embedding_type"] not in ("rope", "nope"):
+        raise NotImplementedError(
+            "causal_lm builds position_embedding_type rope or nope (no "
+            "positional term in any layer), the config has %r"
+            % (c["position_embedding_type"],))
+    if c["position_embedding_type"] == "nope":
+        c["rope_theta"] = None
+    for key in ("embedding_multiplier", "residual_multiplier",
+                "logits_scaling"):
+        if not c[key] > 0:
+            raise ValueError("%s %r: a positive number is needed"
+                             % (key, c[key]))
     # a bias in the attention's projections is built for differential
     # attention, one on the convolution for a Mamba mixer's: the other
     # mixers' references have neither
@@ -471,6 +528,14 @@ def resolve(cfg):
     c["rope_inv_freq"], c["rope_table_scale"], c["attention_scale"] = \
         yarn_table(scaling, c["rope_theta"], c["rotary_dim"], c["head_dim"]) \
         if scaling is not None else (None, 1.0, None)
+    if c["attention_multiplier"] is not None:
+        # the scores' scale itself, what the cores are given: not a second
+        # multiply behind head_dim^-0.5
+        if scaling is not None or c["latent"]:
+            raise NotImplementedError(
+                "causal_lm builds attention_multiplier on plain attention "
+                "without rope_scaling")
+        c["attention_scale"] = float(c["attention_multiplier"])
     if c["rotary_dim"] % 2 or not 0 < c["rotary_dim"] <= c["head_dim"]:
         raise ValueError("partial_rotary_factor %r of a head of %d turns %d "
                          "channels: not an even number in (0, %d]"
@@ -479,17 +544,24 @@ def resolve(cfg):
     interval = int(c["full_attention_interval"])
     if "layer_types" in c:
         kinds = list(c["layer_types"])
-        unknown = sorted(set(kinds) - set(LAYER_TYPES))
+        known = dict(LAYER_TYPES, mamba="mamba2") if "mamba_n_heads" in c \
+            else LAYER_TYPES
+        unknown = sorted(set(kinds) - set(known))
         # a published list over a stack cut short says which layers were
-        # kept only where it is one kind throughout
+        # kept where it is one kind throughout, or where the share says that
+        # it is the published model's whole list: the stack is then its
+        # first layers
         if unknown or len(kinds) < layers or (
-                len(kinds) > layers and len(set(kinds)) > 1):
+                len(kinds) > layers and len(set(kinds)) > 1
+                and len(kinds) != published.get("num_hidden_layers")):
             raise NotImplementedError(
                 "causal_lm builds layer_types of %s, one a layer (more only "
-                "of one kind); the config has %d for %d layers%s"
-                % (sorted(LAYER_TYPES), len(kinds), layers,
+                "of one kind, or the share's published num_hidden_layers of "
+                "them: the first are built); the config has %d for %d "
+                "layers%s"
+                % (sorted(known), len(kinds), layers,
                    ", among them %s" % unknown if unknown else ""))
-        c["mixer_layers"] = [LAYER_TYPES[kind] for kind in kinds[:layers]]
+        c["mixer_layers"] = [known[kind] for kind in kinds[:layers]]
     else:
         c["mixer_layers"] = ["attention" if (i + 1) % interval == 0
                              else "gated_delta" for i in range(layers)] \
@@ -504,6 +576,8 @@ def resolve(cfg):
         raise NotImplementedError(
             "causal_lm builds differential_attention under mb_per_layer "
             "(lambda_init is a function of the published layer index)")
+    if "mamba2" in c["mixer_layers"]:
+        _mamba2(c)
     if "short_conv" in c["mixer_layers"]:
         if "conv_L_cache" not in c:
             raise ValueError("layer_types has conv layers, which need "
@@ -527,6 +601,32 @@ def resolve(cfg):
                              "key heads" % (c["linear_num_value_heads"],
                                             c["linear_num_key_heads"]))
     return c
+
+
+def _mamba2(c):
+    """What a stack with Mamba-2 mixers (granitemoehybrid's `mamba` layers)
+    needs and refuses: mamba_n_heads x mamba_d_head = mamba_expand x
+    hidden_size channels, one group of B and C, no bias on the
+    projections."""
+    for key, want in (("total_ut_steps", 1), ("hc_mult", 1),
+                      ("mtp_layers", 0), ("mamba_n_groups", 1),
+                      ("mamba_proj_bias", False), ("sandwich_norm", False)):
+        if c.get(key) != want:
+            raise NotImplementedError(
+                "causal_lm builds Mamba-2 mixers (layer_types mamba) with "
+                "%s=%r only, the config has %r" % (key, want, c.get(key)))
+    if c["mamba_conv_bias"] not in (False, True):
+        raise NotImplementedError("causal_lm builds mamba_conv_bias false or "
+                                  "true, the config has %r"
+                                  % (c["mamba_conv_bias"],))
+    if "mamba_d_head" not in c:
+        raise ValueError("layer_types has mamba layers, which need "
+                         "mamba_n_heads and mamba_d_head")
+    inner = c["mamba_expand"] * c["hidden_size"]
+    if c["mamba_n_heads"] * c["mamba_d_head"] != inner:
+        raise ValueError(
+            "%d Mamba-2 heads of %d are not mamba_expand x hidden_size = %d "
+            "channels" % (c["mamba_n_heads"], c["mamba_d_head"], inner))
 
 
 def _decoder_hybrid_decoder(c, published):
@@ -950,6 +1050,15 @@ def differential_attention(x, pos, c):
         shape=[0, -1, pairs * 2 * hd]), d, c, "wo", bias)
 
 
+def _delta_bias_start(seed, n):
+    """n biases of Delta at Mamba's own start: the inverse softplus of
+    exp(U(ln 1e-3, ln 1e-1)), from the stream `seed`."""
+    import numpy as np
+    dt = np.exp(np.random.RandomState(seed).uniform(
+        np.log(1e-3), np.log(1e-1), n))
+    return (dt + np.log(-np.expm1(-dt))).astype("float32")
+
+
 def mamba(x, c):
     """A Mamba-1 mixer (arXiv:2312.00752) over x [B, T, D], d_i =
     mamba_expand x D, N = mamba_d_state, R = mamba_dt_rank: [u; z] = x W_in;
@@ -974,8 +1083,6 @@ def mamba(x, c):
                 attr=_attr(c, "conv.bias", init.Constant(0.0))), axis=2))
     r, b, cc = layers.split(_linear(u, rank + 2 * n, c, "w_x"),
                             [rank, n, n], dim=-1)
-    dt = np.exp(np.random.RandomState(MAMBA_DT_SEED + c["layer"]).uniform(
-        np.log(1e-3), np.log(1e-1), di))
     delta = layers.softplus(layers.elementwise_add(
         layers.cast(layers.fc(
             input=r, size=di, bias_attr=False, num_flatten_dims=2,
@@ -984,7 +1091,7 @@ def mamba(x, c):
             "float32"),
         layers.create_parameter(
             [di], "float32", attr=_attr(c, "dt_bias", init.NumpyArrayInitializer(
-                (dt + np.log(-np.expm1(-dt))).astype("float32")))),
+                _delta_bias_start(MAMBA_DT_SEED + c["layer"], di)))),
         axis=2))
     a_log = layers.create_parameter(
         [di, n], "float32", attr=_attr(c, "a_log", init.NumpyArrayInitializer(
@@ -997,6 +1104,56 @@ def mamba(x, c):
     if c["layer"] == c["memory_layer"]:
         c["handed_on"]["memory"] = y
     return _linear(y * layers.swish(z), d, c, "w_out")
+
+
+def mamba2(x, c):
+    """A Mamba-2 mixer (arXiv:2405.21060 as granitemoehybrid has it) over x
+    [B, T, D]: H = mamba_n_heads heads of P = mamba_d_head, d_i = H P, N =
+    mamba_d_state, one group. [z; xBC; dt] = x W_in (d_i + (d_i + 2 N) + H
+    columns, no bias); xBC' = SiLU(conv(xBC) + b_c), a causal depthwise
+    convolution of mamba_d_conv taps over x, B and C side by side; [x; B; C]
+    = xBC'; Delta = softplus(dt + dt_bias) in float32; A = -exp(A_log) a
+    head; y = ssd_scan(x, Delta, A, B, C, D); o = RMSNorm(y * SiLU(z)) over
+    all d_i channels at once, the gate FIRST (rms_norm(gate=) norms first);
+    o W_out. Parameters: w_in, conv, [conv.bias], dt_bias, a_log, d,
+    gated_norm, w_out. A_log starts at log(1 .. H) a head, D at 1, dt_bias
+    at the inverse softplus of exp(U(ln 1e-3, ln 1e-1)) from
+    MAMBA2_DT_SEED's stream (the family's modeling file starts it at 1,
+    where A = -64 forgets within a token)."""
+    import numpy as np
+    layers, init = fluid.layers, fluid.initializer
+    d, n = c["hidden_size"], c["mamba_d_state"]
+    h, p = c["mamba_n_heads"], c["mamba_d_head"]
+    di = h * p
+    z, xbc, dt = layers.split(_linear(x, 2 * di + 2 * n + h, c, "w_in"),
+                              [di, di + 2 * n, h], dim=-1)
+    xbc = layers.causal_conv1d(
+        xbc, c["mamba_d_conv"], param_attr=_matrix(c, "conv"),
+        act=None if c["mamba_conv_bias"] else "silu")
+    if c["mamba_conv_bias"]:
+        xbc = layers.swish(layers.elementwise_add(
+            xbc, layers.create_parameter(
+                [di + 2 * n], "float32",
+                attr=_attr(c, "conv.bias", init.Constant(0.0))), axis=2))
+    u, b, cc = layers.split(xbc, [di, n, n], dim=-1)
+    delta = layers.softplus(layers.elementwise_add(
+        layers.cast(dt, "float32"),
+        layers.create_parameter(
+            [h], "float32", attr=_attr(c, "dt_bias", init.NumpyArrayInitializer(
+                _delta_bias_start(MAMBA2_DT_SEED + c["layer"], h)))),
+        axis=2))
+    a_log = layers.create_parameter(
+        [h], "float32", attr=_attr(c, "a_log", init.NumpyArrayInitializer(
+            np.log(np.arange(1, h + 1, dtype="float32")))))
+    skip = layers.create_parameter(
+        [h], "float32", attr=_attr(c, "d", init.Constant(1.0)))
+    y = layers.ssd_scan(
+        layers.reshape(u, shape=[0, -1, h, p]), delta,
+        layers.scale(layers.exp(a_log), scale=-1.0), b, cc, skip)
+    gated = layers.reshape(y, shape=[0, -1, di]) * layers.swish(z)
+    return _linear(layers.rms_norm(
+        gated, epsilon=c["rms_norm_eps"], param_attr=_attr(c, "gated_norm")),
+        d, c, "w_out")
 
 
 def gated_memory_unit(x, c):
@@ -1089,7 +1246,8 @@ def _count_layer(c, mixer, module="trunk"):
           gate=str(bool(attention and c["attention_gate"])).lower(),
           conv=str(0 if attention or mixer == "gmu" else c["conv_L_cache"]
                    if mixer == "short_conv" else c["mamba_d_conv"]
-                   if mixer == "mamba" else c["linear_conv_kernel_dim"]),
+                   if mixer in ("mamba", "mamba2")
+                   else c["linear_conv_kernel_dim"]),
           ffn=c["ffn"],
           shared=str(c["shared_expert_intermediate_size"]
                      if c["ffn"] == "experts" else 0),
@@ -1169,6 +1327,13 @@ def causal_lm(cfg, seq_len, extras=None, recompute=True):
                            c["initializer_range"]))))
 
     h = embed(ids)
+    # granitemoehybrid's scalars on the main path, each an op only where it
+    # is not 1: embedding_multiplier here, residual_multiplier on what each
+    # branch adds to the stream, logits_scaling under the logits
+    # (attention_multiplier is the scale the attention's core is given)
+    if c["embedding_multiplier"] != 1:
+        h = layers.scale(h, scale=float(c["embedding_multiplier"]))
+    branch = float(c["residual_multiplier"])
     ops = fluid.default_main_program().global_block().ops
     module_ops = []             # [first, end) runs of the module's ops
     # One pass: the layers, then the final norm; a looped model builds it
@@ -1217,10 +1382,13 @@ def causal_lm(cfg, seq_len, extras=None, recompute=True):
                 a, pos, cl) if mixer == "attention" \
                 else short_conv(a, cl) if mixer == "short_conv" \
                 else mamba(a, cl) if mixer == "mamba" \
+                else mamba2(a, cl) if mixer == "mamba2" \
                 else gated_memory_unit(a, cl) if mixer == "gmu" \
                 else gated_delta_net(a, cl)
             if c["sandwich_norm"]:
                 mixed = _norm(mixed, cl, "mixer_out_norm")
+            if branch != 1:
+                mixed = layers.scale(mixed, scale=branch)
             if streams > 1:
                 h = layers.mhc_post(h, mixed, coef, streams)
                 read, coef, h = hyper_connection(h, cl, "ffn_hc")
@@ -1232,6 +1400,8 @@ def causal_lm(cfg, seq_len, extras=None, recompute=True):
                 else None)
             if c["sandwich_norm"]:
                 out = _norm(out, cl, "ffn_out_norm")
+            if branch != 1:
+                out = layers.scale(out, scale=branch)
             h = layers.mhc_post(h, out, coef, streams) if streams > 1 \
                 else h + out
             if layer_aux is not None:
@@ -1268,6 +1438,8 @@ def causal_lm(cfg, seq_len, extras=None, recompute=True):
         logits = layers.matmul(state, tied, transpose_y=True) \
             if tied is not None else _linear(state, c["vocab_size"], c,
                                              "head")
+        if c["logits_scaling"] != 1:
+            logits = layers.scale(logits, scale=1.0 / c["logits_scaling"])
         return logits, layers.softmax_with_cross_entropy(
             logits=layers.reshape(logits, shape=[-1, c["vocab_size"]]),
             label=layers.reshape(labels, shape=[-1, 1]))

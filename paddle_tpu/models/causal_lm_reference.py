@@ -40,6 +40,13 @@ tied head, no positional term) the equations of ISSUE 54 as
 `modeling_phi4flash.py` has them: `selective_scan` is Mamba-1's recurrence
 token by token (`lax.scan`), `differential_attention` two dense masked
 softmax maps a pair of heads.
+For granite-4.0-h-micro (`model_type: granitemoehybrid`: nine Mamba-2
+mixers, arXiv:2405.21060, to one attention layer without positional term;
+embedding_multiplier, residual_multiplier, attention_multiplier and
+logits_scaling on the main path; a tied head) the equations of ISSUE 57 as
+`modeling_granitemoehybrid.py` has them: `ssd_scan` is Mamba-2's recurrence
+token by token (`lax.scan`; the Program computes it a chunk at a time as
+matmuls, and shares no algebra with this), `mamba2` gates BEFORE its norm.
 `params` is the list of the Program's parameters in the order
 models/causal_lm.py creates them.
 
@@ -226,6 +233,8 @@ def attention(a, pos, wq, wk, wv, q_norm, k_norm, wo, c):
         q = rms_norm(q, q_norm, eps, centred)
         k = rms_norm(k, k_norm, eps, centred)
     scale, table, table_scale = hd ** -0.5, None, 1.0
+    if c["attention_multiplier"] is not None:   # granite's: the scale itself
+        scale = c["attention_multiplier"]
     if c["rope_scaling"] is not None:           # YaRN, as latent_attention
         factor = c["rope_scaling"]["factor"]
         m = yarn_mscale(factor, c["rope_scaling"].get("mscale_all_dim", 0))
@@ -289,6 +298,69 @@ def mamba(a, w_in, w_conv, b_conv, w_x, w_dt, b_dt, a_log, d, w_out,
         found.setdefault("delta", delta)
     y = selective_scan(u, delta, -jnp.exp(a_log), b, c, d)
     return (y * jax.nn.silu(z)) @ w_out, y
+
+
+def ssd_scan(x, delta, a, b, c, d, found=None, segment=None):
+    """Mamba-2's recurrence token by token on x [B, T, H, P], delta [B, T,
+    H], a and d [H], b, c [B, T, N] (one group: every head reads the same b
+    and c): a head's state s [N, P] from 0, s_t = exp(delta_t a) s_(t-1) +
+    b_t^T (delta_t x_t); y_t = c_t s_t + d x_t. A dict given as `found`
+    gets `state`, the state after the last token [B, H, N, P]. `segment`
+    (a divisor of T) changes no result: so many tokens run under one
+    jax.checkpoint, and a backward pass keeps a state a segment and not one
+    a token (2 MB a token at 64 heads of 64 on 128 states)."""
+    def step(s, xs):
+        x, dt, b, c = xs
+        s = jnp.exp(dt * a)[..., None, None] * s \
+            + b[:, None, :, None] * (dt[..., None] * x)[:, :, None, :]
+        return s, jnp.einsum("bn,bhnp->bhp", c, s)
+
+    def tokens(s, xs):
+        return jax.lax.scan(step, s, xs)
+
+    t = x.shape[1]
+    xs = tuple(jnp.moveaxis(v, 1, 0) for v in (x, delta, b, c))
+    s = jnp.zeros(x.shape[:1] + (x.shape[2], b.shape[-1], x.shape[3]),
+                  x.dtype)
+    if segment is None:
+        last, y = tokens(s, xs)
+    else:
+        last, y = jax.lax.scan(jax.checkpoint(tokens), s, tuple(
+            v.reshape((t // segment, segment) + v.shape[1:]) for v in xs))
+    if found is not None:
+        found["state"] = last
+    return jnp.moveaxis(y.reshape((t,) + y.shape[-3:]), 0, 1) + d[:, None] * x
+
+
+def mamba2(a, w_in, w_conv, b_conv, dt_bias, a_log, d, w_norm, w_out, eps,
+           found=None, segment=None):
+    """A Mamba-2 mixer (GraniteMoeHybridMambaLayer) on a [B, T, D], H heads
+    of P with N states, one group: [z; xBC; dt] = a w_in, d_i + (d_i + 2 N)
+    + H columns; xBC' = SiLU(conv(xBC) + b_conv) (b_conv None: no bias); [x;
+    B; C] = xBC'; Delta = softplus(dt + dt_bias); A = -exp(a_log); y =
+    ssd_scan(x, Delta, A, B, C, d); RMSNorm(y * SiLU(z)) over all d_i
+    channels under w_norm, the gate first; that w_out. A dict given as
+    `found` gets the first mixer's `delta`, `scan` (y before the gate) and
+    `carried` (y less its skip term D x: what the state gave) and every
+    mixer's `state` (the last one's stays)."""
+    h = dt_bias.shape[0]
+    di = w_out.shape[0]
+    n = (w_in.shape[1] - 2 * di - h) // 2
+    z, xbc, dt = jnp.split(a @ w_in, [di, 2 * di + 2 * n], axis=-1)
+    xbc = causal_conv(xbc, w_conv)
+    xbc = jax.nn.silu(xbc if b_conv is None else xbc + b_conv)
+    x, b, c = jnp.split(xbc, [di, di + n], axis=-1)
+    delta = jax.nn.softplus(dt + dt_bias)
+    heads = x.reshape(x.shape[:2] + (h, di // h))
+    y = ssd_scan(heads, delta, -jnp.exp(a_log), b, c, d, found=found,
+                 segment=segment)
+    if found is not None:
+        found.setdefault("delta", delta)
+        found.setdefault("scan", y.reshape(x.shape))
+        found.setdefault("carried",
+                         (y - d[:, None] * heads).reshape(x.shape))
+    y = y.reshape(x.shape)
+    return rms_norm(y * jax.nn.silu(z), w_norm, eps) @ w_out
 
 
 def gated_memory_unit(a, memory, w_in, w_out):
@@ -553,6 +625,9 @@ def passes(cfg, params, ids, pos, next_ids=None, found=None):
                 + take(6)
         elif c["mixer_layers"][i] == "gmu":
             mixer = take(2)
+        elif c["mixer_layers"][i] == "mamba2":
+            mixer = take(2) + (take(1) if c["mamba_conv_bias"] else [None]) \
+                + take(5)
         elif c["differential_attention"]:
             # wq, bq, [wk, bk, wv, bv], the four lambda vectors, the
             # norm's weight, wo, bo
@@ -598,8 +673,9 @@ def passes(cfg, params, ids, pos, next_ids=None, found=None):
     load = jnp.zeros((max(e, 1),), jnp.int32)
     logits, lam = [], []
     with jax.default_matmul_precision("highest"):
-        h = embedding[ids]
+        h = embedding[ids] * c["embedding_multiplier"]
         b, t, d = h.shape
+        branch = c["residual_multiplier"]   # on what each branch adds
         if streams > 1:
             # Departure (arXiv:2409.19606's convention; the config has no key
             # for either end): the streams start as `streams` copies of the
@@ -631,6 +707,8 @@ def passes(cfg, params, ids, pos, next_ids=None, found=None):
                         found["memory"] = y
             elif c["mixer_layers"][i] == "gmu":
                 mixed = gated_memory_unit(a, handed_on["memory"], *mixer)
+            elif c["mixer_layers"][i] == "mamba2":
+                mixed = mamba2(a, *mixer, eps, found=found)
             elif c["differential_attention"]:
                 wq, bq, own, lambdas, subln, wo, bo = mixer
                 mixed, kv = differential_attention(
@@ -651,7 +729,9 @@ def passes(cfg, params, ids, pos, next_ids=None, found=None):
                 mixed = attention(a, pos, *mixer, layer_config(c, i))
             if sandwich:
                 mixed = rms_norm(mixed, n2, eps, centred)
-            h = write(mixed)
+            if found is not None and c["mixer_layers"][i] == "attention":
+                found.setdefault("attention", mixed)
+            h = write(branch * mixed)
             x, write = read(h, hcs[i][1])
             m = norm(x, n3)
             if c["ffn_layers"][i] == "experts":
@@ -673,7 +753,7 @@ def passes(cfg, params, ids, pos, next_ids=None, found=None):
                 out = (jax.nn.silu(m @ wg) * (m @ wu)) @ wd
             if sandwich:
                 out = rms_norm(out, n4, eps, centred)
-            return write(out), terms
+            return write(branch * out), terms
 
         terms = (balance, z, load)
         for _ in range(c["total_ut_steps"]):
@@ -682,7 +762,7 @@ def passes(cfg, params, ids, pos, next_ids=None, found=None):
             if streams > 1:
                 h = h.sum(2)
             h = norm(h, w_f)
-            logits.append(h @ w_lm)
+            logits.append(h @ w_lm / c["logits_scaling"])
             if c["exit_gate"]:
                 lam.append(jax.nn.sigmoid(h @ w_g + b_g))
         if mtp and next_ids is not None:
@@ -690,7 +770,8 @@ def passes(cfg, params, ids, pos, next_ids=None, found=None):
             if found is not None:
                 found["mtp_input"] = x
             y, terms = layer(x, layers, terms)
-            logits.append(rms_norm(y, module[3], eps, centred) @ w_lm)
+            logits.append(rms_norm(y, module[3], eps, centred) @ w_lm
+                          / c["logits_scaling"])
         balance, z, load = terms
     p = exit_distribution(jnp.stack(lam)) if c["exit_gate"] else None
     return logits, p, balance, z, load

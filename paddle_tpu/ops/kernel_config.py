@@ -20,7 +20,7 @@ Accepted forms of PADDLE_TPU_PALLAS:
     - "attn,xent"       : allowlist — exactly the named ops on, the
                           rest off.  Unknown names raise LOUDLY (a typo
                           must not silently run the other path).
-Op names: attn, xent, ln, lstm, seq, gdr, conv, emb, mhc, gmm, scan (KERNEL_OPS).  For 'attn' the flag
+Op names: attn, xent, ln, lstm, seq, gdr, conv, emb, mhc, gmm, scan, ssd (KERNEL_OPS).  For 'attn' the flag
 is an opt-OUT only: fused_attention's positive dispatch is always the
 flash_at() rule, so enabling 'attn' does not force flash below the
 crossover (pin FLAGS_flash_min_seq=0 for that).
@@ -110,6 +110,17 @@ __all__ = [
 # trips of 256 rows were 2-5 % slower than 128 at every shape and whole
 # tiles of 512 8-20 %: a trip that starts in another group's rows is work
 # for nothing, and a taller dot bought nothing back where a group fills it.
+# "ssd" is the chunk of ops/ssd_kernels.py's two passes (the tokens a
+# grid step computes as matmuls; Mosaic wants whole 128s) and the heads a
+# grid step takes, 128 lanes (two heads of 64) at a time.  Swept on the v5e
+# (my chip runs, PR 57: `chip_smoke.py --phases O`, one layer's scan alone
+# at [1, 2048, 64, 64] on 128 states, forward / forward + backward ms, the
+# final kernels): (128, 8) 0.28 / 1.23, (128, 16) 0.28 / 1.09, (128, 32)
+# 0.28 / 1.04, (256, 16) 0.28 / 1.07: the forward is flat (the
+# bytes and the steps' overhead, not the products), the backward wants
+# fewer, larger steps and no longer chunk (the products under L grow with
+# it).  32 heads a step is 5 % under 16 on the backward pass alone and
+# twice the unrolled body to trace and compile, so 16.
 DEFAULT_TILES = {
     "attn": {"block_q": 512, "block_k": 512},
     "xent": {"tile_bytes": 1 << 20},
@@ -122,6 +133,7 @@ DEFAULT_TILES = {
     "mhc": {"block_rows": 128},
     "gmm": {"block_m": 512},
     "scan": {"chunk": 64},
+    "ssd": {"chunk": 128, "block_h": 16},
 }
 KERNEL_OPS = frozenset(DEFAULT_TILES)
 # Dense attention below this query length, flash at and above it.  The

@@ -1,7 +1,8 @@
 """Lowerings of the linear-attention mixer's ops: the gated delta rule
 (ops/gated_delta_kernels.py), the short causal depthwise convolution
-over time that feeds it (ops/causal_conv_kernels.py) and a Mamba mixer's
-selective scan (ops/selective_scan_kernels.py). No reference-era op computes
+over time that feeds it (ops/causal_conv_kernels.py), a Mamba mixer's
+selective scan (ops/selective_scan_kernels.py) and a Mamba-2 mixer's
+state-space-dual scan (ops/ssd_kernels.py). No reference-era op computes
 any of them: sequence_conv is LoD-based and dense over channels."""
 import jax
 import jax.numpy as jnp
@@ -91,4 +92,33 @@ def _selective_scan(ctx, ins, attrs):
     x, delta, a, b, c, d = (single(ins, name) for name in (
         "X", "Delta", "A", "B", "C", "D"))
     out = selective_scan(x, delta, a, b, c, d, path=selective_scan_path(x, a))
+    return {"Out": [out.astype(x.dtype)]}
+
+
+def ssd_scan_path(x):
+    """"kernel" where ssd_kernels' two passes over chunks run for X [B, T,
+    H, P]: kernel_config.pallas_on("ssd") (a TPU, or PADDLE_TPU_PALLAS) and
+    heads that fill whole lane tiles (P divides 128); else "scan", the same
+    chunked form in jax.numpy under lax.scan. The one place that decides;
+    the layer counter reads it too."""
+    from .ssd_kernels import applies
+    fits = x.ndim == 4 and applies(x.shape[2], x.shape[3])
+    return "kernel" if fits and pallas_on("ssd") else "scan"
+
+
+@register("ssd_scan", calls_pallas=True, infer=shapes_from(Out="X"))
+def _ssd_scan(ctx, ins, attrs):
+    """Out [B, T, H, P] of the state-space-dual scan s_t = exp(Delta_t A)
+    s_(t-1) + B_t^T (Delta_t x_t), y_t = C_t s_t + D x_t a head, for X [B,
+    T, H, P], Delta [B, T, H], A (negative) and D [H], and B, C [B, T, N]
+    that all heads read. Under AMP the matmuls take bf16 operands; Delta,
+    A, the running sums, every exponential, the state and every accumulator
+    are float32 either way, so the op is in neither AMP table. The result
+    comes back in X's dtype."""
+    from .ssd_kernels import ssd_scan
+    x, delta, a, b, c, d = (single(ins, name) for name in (
+        "X", "Delta", "A", "B", "C", "D"))
+    out = ssd_scan(
+        x, delta, a, b, c, d, path=ssd_scan_path(x),
+        operand_dtype=jnp.bfloat16 if getattr(ctx, "amp", False) else None)
     return {"Out": [out.astype(x.dtype)]}

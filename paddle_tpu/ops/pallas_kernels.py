@@ -59,7 +59,7 @@ KERNEL_NAMES = (
     "ptpu_mhc_expand", "ptpu_mhc_reduce", "ptpu_mhc_coeffs_fwd",
     "ptpu_mhc_coeffs_bwd", "ptpu_expert_gmm_fwd", "ptpu_expert_gmm_drows",
     "ptpu_expert_gmm_dweights", "ptpu_selective_scan_fwd",
-    "ptpu_selective_scan_bwd")
+    "ptpu_selective_scan_bwd", "ptpu_ssd_fwd", "ptpu_ssd_bwd")
 
 
 def _interpret_default():
@@ -1798,3 +1798,7 @@ EXPERT_MATMUL_KERNELS = ("ptpu_expert_gmm_fwd", "ptpu_expert_gmm_drows",
 # selective_scan_ms_per_step sums these names' calls.
 SELECTIVE_SCAN_KERNELS = ("ptpu_selective_scan_fwd",
                           "ptpu_selective_scan_bwd")
+# Who runs a Mamba-2 mixer's state-space-dual scan (ops/ssd_kernels.py, its
+# two passes over chunks written out so that nothing here imports them): the
+# benchmark's ssd_scan_ms_per_step sums these names' calls.
+SSD_KERNELS = ("ptpu_ssd_fwd", "ptpu_ssd_bwd")

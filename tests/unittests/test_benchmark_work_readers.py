@@ -39,6 +39,10 @@ PHI = "phi4_mini_flash_train_t8192"
 # the three metrics PR 54 added with its cell
 PHI_METRICS = ("selective_scan_ms_per_step", "selective_scan_roofline_share",
                "shared_state_layer_share")
+GRANITE = "granite_4_0_h_micro_train_t2048"
+# the three metrics PR 57 added with its cell
+GRANITE_METRICS = ("ssd_scan_ms_per_step", "ssd_scan_roofline_share",
+                   "ssd_layer_share")
 
 
 def test_glm_flash_operations_against_the_hand_count():
@@ -104,22 +108,25 @@ def test_the_pinned_manifest_test_is_red_for_the_eleventh_cell_alone(
     pins, and passes on the same manifest with GLM-4.7-Flash's cell, its
     configuration and its metric taken off again, and with them the
     twelfth cell (Phi-4-mini-flash's), its configuration and its three
-    metrics: nothing else it holds has moved."""
+    metrics, and the thirteenth (granite-4.0-h-micro's), its configuration,
+    its traffic's cell and its three metrics: nothing else it holds has
+    moved."""
     pinned = getattr(readers, PINNED)
     with pytest.raises(AssertionError, match="^expert_matmul_ms_per_step$"):
         pinned()
     with open(readers.BENCHMARK) as f:
         bench = json.load(f)
     bench["workloads"] = [w for w in bench["workloads"]
-                          if w["name"] not in (GLM, PHI)]
+                          if w["name"] not in (GLM, PHI, GRANITE)]
     bench["configs"] = [c for c in bench["configs"]
                         if c["name"] not in ("glm_4_7_flash",
-                                             "phi4_mini_flash")]
+                                             "phi4_mini_flash",
+                                             "granite_4_0_h_micro")]
     bench["per_layer"] = [m for m in bench["per_layer"]
                           if m["name"] not in ("mtp_layer_share",)
-                          + PHI_METRICS]
+                          + PHI_METRICS + GRANITE_METRICS]
     for metric in bench["end_to_end"] + bench["per_layer"]:
-        for cell in (GLM, PHI):
+        for cell in (GLM, PHI, GRANITE):
             if cell in metric.get("workloads", ()):
                 metric["workloads"].remove(cell)
     without = tmp_path / "BENCHMARK.json"
@@ -176,7 +183,7 @@ def test_the_manifest_lists_the_work_readers_in_eleven_cells():
     # the 8192 rows in VMEM)
     for name in ("flash_roofline_share", "embedding_grad_ms_per_step",
                  "embedding_grad_roofline_share", "step_mfu"):
-        assert entries[name]["workloads"][-1] == PHI, name
+        assert entries[name]["workloads"][-2:] == [PHI, GRANITE], name
     phi = readers._cell(PHI)
     pairs = (512 * 513 // 2 + (8192 - 512) * 512) + 2 * (8192 * 8193 // 2)
     assert phi.config_module.flash_kernel_ops(phi.config, phi.traffic) == {
@@ -187,6 +194,33 @@ def test_the_manifest_lists_the_work_readers_in_eleven_cells():
         == 4 * 2560 * 25008 == 256081920
     assert phi.config_module.embedding_grad_bytes \
         is not phi.config_module.base.embedding_grad_bytes
+    # the thirteenth cell behind that one, with its module's hand counts:
+    # one core of 32 heads of 64, causal at T = 2048; the table of 12544
+    # words of 2048 written; the scan's two kernels at chunks of 128, nine
+    # layers, the forward pass's twice (a call: operations, bytes)
+    granite = readers._cell(GRANITE)
+    mod = granite.config_module
+    pairs = 2048 * 2049 // 2
+    assert mod.flash_kernel_ops(granite.config, granite.traffic) == {
+        "ptpu_flash_fwd": 4 * 64 * 32 * pairs,
+        "ptpu_flash_bwd_dkdv": 8 * 64 * 32 * pairs,
+        "ptpu_flash_bwd_dq": 6 * 64 * 32 * pairs}
+    assert mod.embedding_grad_bytes(granite.config, granite.traffic) \
+        == 4 * 2048 * 12544 == 102760448
+    calls = mod.ssd_kernel_ops(granite.config, granite.traffic, 128)
+    assert {k: len(v) for k, v in calls.items()} == {"ptpu_ssd_fwd": 18,
+                                                     "ptpu_ssd_bwd": 9}
+    assert calls["ptpu_ssd_fwd"][0] == (
+        2 * 2048 * (64.5 * 128 + 64 * (64.5 * 64 + 2 * 128 * 64)),
+        2048 * (2 * 2 * 4096 + 2 * 2 * 128 + 4 * 64))
+    assert calls["ptpu_ssd_bwd"][0] == (
+        2 * 2048 * (2 * 64.5 * 128 + 64 * (2 * 64.5 * 64 + 4 * 128 * 64)),
+        2048 * (3 * 2 * 4096 + 4 * 2 * 128 + 2 * 4 * 64))
+    assert [m["workloads"] for m in bench["per_layer"]
+            if m["name"] in GRANITE_METRICS] == [[GRANITE]] * 3
+    # thirteen cells, one of them on four chips: floor(13 x 0.25) = 3
+    assert [w["name"] for w in bench["workloads"]][12] == GRANITE
+    assert [w["chips"] for w in bench["workloads"]][:13].count(4) == 1
     # eleven cells (PR 49), one of them on four chips: floor(11 x 0.25) = 2
     assert len(bench["workloads"]) >= 11
     assert [w["name"] for w in bench["workloads"]][10] == GLM

@@ -68,10 +68,18 @@ PHASE_SAYS = {
         and "forward + backward" in line for line in lines) and any(
         "N differential core" in line and "window 16 off by" in line
         and "window None off by" in line for line in lines),
+    # phase O held the state-space-dual scan's kernels to the recurrence
+    # token by token under both decays, and timed the tiles of the sweep
+    "O": lambda lines: sum(
+        "O ssd kernels, x" in line and "ddelta" in line and "da " in line
+        for line in lines) == 2 and any(
+        "Delta A = -6 a token" in line for line in lines) and sum(
+        "O ssd kernels at chunks of" in line and "forward + backward" in line
+        for line in lines) >= 2,
 }
 
 
-@pytest.mark.parametrize("letter", "ABCDEFGHIJKLN")
+@pytest.mark.parametrize("letter", "ABCDEFGHIJKLNO")
 def test_tiny_rehearsal_passes_every_phase(letter):
     """One case a phase (`--phases <letter>`), so that a red run names it."""
     # phase E times the program phase A left
@@ -130,6 +138,22 @@ def test_a_failing_phase_makes_the_exit_code_nonzero():
                for line in lines)
     # and with only passing phases selected the same run exits 0
     assert run("--phases", "BC").returncode == 0
+
+
+def test_no_phases_given_is_every_phase_there_is():
+    """The default of --phases is every letter of PHASES: it read "default
+    all" and stopped at M while N existed."""
+    script = _FAILING_RUN.replace(
+        '("A", "fails", boom)', '("Z", "a later letter", lambda smoke: None)')
+    out = subprocess.run([sys.executable, "-c", script, "--tiny"],
+                         env=_env(), cwd=REPO, capture_output=True, text=True,
+                         timeout=300)
+    assert out.returncode == 0, out.stdout + out.stderr
+    assert "phase Z (a later letter) passed" in out.stdout
+    with open(SMOKE) as f:
+        source = f.read()
+    assert '("O", "the state-space-dual scan", phase_o)' in source
+    assert 'default="ABCDEFGHIJKLM"' not in source
 
 
 def test_without_a_tpu_nothing_runs():

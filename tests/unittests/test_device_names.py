@@ -171,11 +171,12 @@ def test_an_at_sign_would_cut_the_op_name_short():
 
 def test_every_pallas_call_takes_its_name_from_kernel_names():
     from paddle_tpu.ops import causal_conv_kernels, embedding_grad, \
-        expert_gmm, gated_delta_kernels, mhc_kernels, selective_scan_kernels
+        expert_gmm, gated_delta_kernels, mhc_kernels, \
+        selective_scan_kernels, ssd_kernels
     names = []
     for module in (pallas_kernels, gated_delta_kernels, causal_conv_kernels,
                    embedding_grad, mhc_kernels, expert_gmm,
-                   selective_scan_kernels):
+                   selective_scan_kernels, ssd_kernels):
         with open(module.__file__) as f:
             tree = ast.parse(f.read())
         # mhc_kernels' pallas_calls sit in two helpers that are handed the
@@ -203,8 +204,9 @@ def test_every_pallas_call_takes_its_name_from_kernel_names():
     assert sorted(names) == sorted(pallas_kernels.KERNEL_NAMES)
     assert pallas_kernels.EXPERT_MATMUL_KERNELS == expert_gmm.KERNELS
     assert pallas_kernels.SELECTIVE_SCAN_KERNELS \
-        == pallas_kernels.KERNEL_NAMES[-2:]
-    assert len(set(names)) == len(names) == 27
+        == pallas_kernels.KERNEL_NAMES[-4:-2]
+    assert pallas_kernels.SSD_KERNELS == pallas_kernels.KERNEL_NAMES[-2:]
+    assert len(set(names)) == len(names) == 29
     for a in names:         # a reader matching `<name>` or `<name>.<n>`
         for b in names:     # never counts one kernel under another
             assert a == b or not (b + ".").startswith(a + ".")

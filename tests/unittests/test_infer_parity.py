@@ -27,7 +27,8 @@ REPO = os.path.dirname(os.path.dirname(
 F32, BF16, I32 = "float32", "bfloat16", "int32"
 INFERRED = ("fused_attention", "layer_norm", "softmax_with_cross_entropy",
             "batch_norm", "mhc_pre", "mhc_post", "mhc_expand", "mhc_reduce",
-            "gated_delta_rule", "causal_conv1d", "moe_ffn", "selective_scan")
+            "gated_delta_rule", "causal_conv1d", "moe_ffn", "selective_scan",
+            "ssd_scan")
 
 
 def _attention(t, hq, hkv, d, dv=None, rope=None, batch=-1, dtype=F32,
@@ -103,6 +104,13 @@ def _scan(t, c, n, batch=-1, dtype=F32):
              "C": ((batch, t, n), F32), "D": ((c,), F32)}, ("Out",), {})
 
 
+def _ssd(t, h, p, n, batch=-1, dtype=F32):
+    return ("ssd_scan",
+            {"X": ((batch, t, h, p), dtype), "Delta": ((batch, t, h), F32),
+             "A": ((h,), F32), "B": ((batch, t, n), dtype),
+             "C": ((batch, t, n), dtype), "D": ((h,), F32)}, ("Out",), {})
+
+
 def _moe_ffn(t, d, f, experts, held, top_k, **attrs):
     return ("moe_ffn",
             {"X": ((-1, t, d), F32), "Router": ((d, experts), F32),
@@ -161,6 +169,10 @@ CASES = {
     "selective_scan-phi4flash-static-bfloat16": _scan(8192, 5120, 16,
                                                       batch=1, dtype=BF16),
     "selective_scan-off-the-kernel": _scan(40, 96, 4, batch=2),
+    "ssd_scan-granite": _ssd(2048, 64, 64, 128),
+    "ssd_scan-granite-static-bfloat16": _ssd(2048, 64, 64, 128, batch=1,
+                                             dtype=BF16),
+    "ssd_scan-off-the-kernel": _ssd(40, 3, 24, 8, batch=2),
     # PR 50's infer, a table since PR 53
     "moe_ffn-olmoe-64-all-held": _moe_ffn(4096, 2048, 1024, 64, 64, 8),
     "moe_ffn-smallthinker-64-a-quarter-held": _moe_ffn(
