@@ -170,8 +170,8 @@ class LowerCtx(object):
         # after the step (same channel as TensorArray overflow). Sticky OR
         # per message.
         self.op_errors = {}
-        # trips of the loops lowered that recompute, by sub-block
-        self.loop_trips = {}
+        # loops that recompute: (trips, kept ops) by sub-block; in one's body?
+        self.recomputing_loops, self.in_recomputing_loop = {}, False
         # forward op uid -> (primal outputs, vjp_fn), for the forward ops of
         # the block being lowered whose grad ops call the vjp_fn the forward
         # op kept (see _linearizations); None until the forward op has
@@ -319,13 +319,13 @@ def _linearizations(ctx, ops):
     and keep their linearization, (primal outputs, vjp_fn), for the grad ops
     of the same block: those a `grad_of` op names whose rule can reach a
     Pallas kernel (OpDef.calls_pallas). A grad op differentiates its forward
-    rule, and differentiating it from scratch runs the rule's forward again;
-    where XLA generated the forward it merges the two, a Mosaic custom call
-    it runs twice, and a loop that recomputes its body (`recompute` on an
-    rnn_scan op) it would run twice as well. Every other op keeps the
-    replay: nothing would be gained on the device, and the HLO of every
-    program would change. Under rematerialization the replay is the point,
-    so nothing is kept."""
+    rule and so runs the rule's forward again; where XLA generated the
+    forward it merges the two, a Mosaic custom call it runs twice, and a
+    loop that recomputes its body (`recompute` on an rnn_scan op) as well.
+    (INSIDE such a loop the same holds a trip: its checkpoint keeps the
+    kernels' outputs, ops/control_ops.py keeps_across_passes.) Every other
+    op keeps the replay: nothing would be gained on the device, and every
+    program's HLO would change. Under rematerialization nothing is kept."""
     if getattr(ctx.program, "_rematerialize", False):
         return {}
     return {op.attrs["fwd_uid"]: None for op in ops
@@ -495,11 +495,11 @@ def _count_remat_ops(kind, ops, times=1):
     from ..observability.registry import REGISTRY
     counter = REGISTRY.counter(
         "ptpu_remat_ops_total",
-        "forward ops of a program that recomputes, by fluid op type, as "
-        "often as they run a step: `forward` in the forward pass, `replayed` "
-        "a second time in the backward pass (a segment of enable_"
-        "rematerialization behind its barrier, or the body of a loop op "
-        "that recomputes, once a trip)")
+        "forward ops of a program that recomputes, by fluid op type, as often "
+        "as they run a step: `forward`, and `replayed` a second time in the "
+        "backward pass (a segment of enable_rematerialization, or a loop op's "
+        "body a trip LESS the ops whose kernel or matmul the loop keeps and "
+        "does not run again: ptpu_remat_kept_values_total counts those)")
     for op in ops:
         counter.inc(times, kind=kind, op=op.type)
 

@@ -714,8 +714,12 @@ class StaticRNN(_RNNBase):
     of a loop with no step input, memories and stacked outputs alone (a
     stack of layers run `steps` times over weights the block closes over,
     models/causal_lm.py). `recompute`: the body runs under jax.checkpoint,
-    so a trip keeps its memories and the backward pass replays it; the
-    forward op then keeps its linearization for its grad op."""
+    so a trip keeps its memories and the backward pass replays it, but for
+    what is cheap to keep and dear to replay: a Pallas forward kernel's
+    outputs, the result of a `mul` whose contraction is wider than its
+    result and row statistics (ops/control_ops.py keeps_across_passes
+    decides by the traced equation; there is no knob). The forward op then keeps its
+    linearization for its grad op."""
 
     def __init__(self, name=None, steps=None, recompute=False):
         super(StaticRNN, self).__init__("static_rnn", name)

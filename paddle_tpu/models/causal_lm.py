@@ -1302,9 +1302,13 @@ def causal_lm(cfg, seq_len, extras=None, recompute=True):
     times over the same weights, which the loop closes over; a weight's
     gradient is the sum over its P uses through the scan's transpose. With
     `recompute` the loop's body runs under jax.checkpoint: a pass keeps the
-    state it started from and the backward pass replays its forward, so
-    what crosses from the forward to the backward pass is P states and not
-    P x layers' worth of activations. `logits` are the last pass's. With
+    state it started from and, a layer, the attention kernel's outputs, the
+    down projection's result and the norms' row statistics (what the loop
+    finds cheap to keep and dear to replay, ops/control_ops.py
+    keeps_across_passes), and the
+    backward pass replays the rest of its forward, so what crosses from the
+    forward to the backward pass is P states and 2 P x layers arrays of the
+    state's shape, not P x layers' worth of activations. `logits` are the last pass's. With
     exit_gate every pass's state goes through the head and the loss is
     the mean a position of sum_t p_t CE_t - exit_entropy_coef x
     H(p), p the exit distribution; without it only the last pass has a

@@ -12,6 +12,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 
 from ..core.registry import register, single
 
@@ -103,6 +104,16 @@ def _minus(ctx, ins, attrs):
 # mul / matmul (reference: mul_op.cc, matmul_op.cc) — MXU path
 # ---------------------------------------------------------------------------
 
+# The name a `mul` gives its result inside the body of a loop that recomputes
+# (ctx.in_recomputing_loop: no other program traces it) where the contraction
+# is wider than the result's columns (x2.shape[1] > y2.shape[1]): the whole
+# of the shape rule `narrow_matmul`, stated here because only here are the
+# 2-D shapes and the cast product both in view. The name is an identity that
+# lowers to no operation; its one reader is that loop's policy
+# (ops/control_ops.py _kept_by), which keeps a value of this name.
+NARROW_MATMUL = "ptpu_narrow_matmul"
+
+
 def _flatten2d(x, num_col_dims):
     lead = int(np.prod(x.shape[:num_col_dims])) if num_col_dims > 0 else 1
     return x.reshape(lead, -1)
@@ -117,6 +128,11 @@ def _mul(ctx, ins, attrs):
     y2 = y.reshape(int(np.prod(y.shape[:yn])), -1)
     out = jnp.matmul(x2, y2, preferred_element_type=jnp.float32).astype(x.dtype) \
         if x.dtype == jnp.bfloat16 else x2 @ y2
+    if getattr(ctx, "in_recomputing_loop", False) \
+            and x2.shape[1] > y2.shape[1]:
+        # NARROW_MATMUL's rule (a LowerCtx's attribute: shape inference's
+        # stand-in has none); what reads the product must read this value
+        out = checkpoint_name(out, NARROW_MATMUL)
     out_shape = x.shape[:xn] + y.shape[yn:]
     return _out(out.reshape(out_shape))
 

@@ -38,6 +38,7 @@ from ..core.registry import register, single
 from ..core import lowering
 from ..core.lowering import (register_special, Env, lower_block,
                              PROGRAM_ERR, accumulate_error)
+from .basic import NARROW_MATMUL
 
 DEFAULT_ARRAY_CAPACITY = 256
 
@@ -413,28 +414,137 @@ def _rnn_scan_lower(ctx, ins, attrs):
         return (t + 1, tuple(new_mems), _sweep_overflow(benv, err)), \
             tuple(outs)
 
+    carry = (jnp.zeros((), jnp.int32), tuple(boots), jnp.zeros((), bool))
     if attrs.get("recompute"):
-        # a trip keeps its carry and the backward pass replays its body
-        step = jax.checkpoint(step)
-        ctx.loop_trips[attrs["sub_block"]] = T
-        count_loop_ops(ctx, "forward", attrs)
+        step = _recomputing(ctx, attrs, step, carry, tuple(
+            jax.ShapeDtypeStruct(x.shape[1:], x.dtype) for x in xs_t), T)
     (_, final_mems, final_err), stacked = lax.scan(
-        step, (jnp.zeros((), jnp.int32), tuple(boots),
-               jnp.zeros((), bool)), tuple(xs_t),
-        length=T)
+        step, carry, tuple(xs_t), length=T)
     outs = [jnp.moveaxis(o, 0, 1) for o in stacked]  # [B, T, ...]
     # "__errors__" is accumulated into the enclosing env by lower_op
     return {"Out": outs, "LastMem": list(final_mems),
             "__errors__": final_err}
 
 
+def _kept_by(prim, avals, params):
+    """The rule by which a recomputing loop keeps an equation's outputs
+    across the forward/backward boundary, or None: what is cheap to keep
+    and dear to replay, by what the equation itself shows.
+
+    kernel_output: a Pallas forward kernel's. XLA merges nothing into a
+    Mosaic call's replay, so the replay costs the kernel whole (why
+    _linearizations exists outside loops). No test of bytes: the one kernel
+    a looped body holds today (flash attention) leaves the op's own result
+    and a row of statistics; a kernel with larger outputs would be kept
+    whole (ROADMAP A15).
+    narrow_matmul: a value named ops/basic.py NARROW_MATMUL. The shape rule
+    is `mul`'s and is stated there, once (contraction wider than the
+    result's columns; the cast product, not the dot_general's float32
+    one; named only while _recomputing traces a body): of all matmuls the
+    fewest bytes for the replay it saves.
+    row_reduction: a reduction over 128 elements (a lane row) or more, a
+    128th of what it read or less. Kept for the compiled step's memory,
+    not for its time: without the statistics the replay reads every kept
+    value once more for them and XLA holds float32 copies to do it, 14.886
+    GiB compiled against 13.787 with them (PERF.md section 6, PR 58). The
+    threshold is not measured: every reduction a cell's loop meets is over
+    2048, so any threshold from 2 to 2048 gives the same step."""
+    if prim.name == "pallas_call":
+        return "kernel_output"
+    if prim.name == "name":
+        return "narrow_matmul" if params["name"] == NARROW_MATMUL else None
+    if prim.name.startswith("reduce_") and "axes" in params and np.prod(
+            [avals[0].shape[a] for a in params["axes"]]) >= 128:
+        return "row_reduction"
+    return None
+
+
+def keeps_across_passes(prim, *avals, **params):
+    """The policy of a recomputing loop's jax.checkpoint: a trip keeps its
+    carry and the outputs of the equations _kept_by names; the backward
+    pass replays everything else."""
+    return _kept_by(prim, avals, params) is not None
+
+
+def _kept_values(jaxpr, scope=None):
+    """(fluid op (type, instance), rule, bytes) for every equation of a
+    loop's body whose outputs the loop keeps, as jax asks the policy: an
+    equation nested in another (a custom_vjp's, a jit's) under the outer
+    one's fluid scope ("-" where it has none), a kept equation's own body
+    not at all. Read off the body BEFORE jax differentiates it, a
+    custom_vjp's by its primal, where jax asks the policy about the forward
+    rule's equations: equal for a kernel op whose primal is its forward
+    rule's first result (flash attention's), and held to jax's own account
+    of the saved residuals by tests/unittests/test_loop_keep_policy.py."""
+    for eqn in jaxpr.eqns:
+        at = scope or lowering.parse_op_scope(
+            str(eqn.source_info.name_stack)) or ("-", "-")
+        rule = _kept_by(eqn.primitive, [v.aval for v in eqn.invars],
+                        eqn.params)
+        if rule:
+            yield (at, rule, sum(v.aval.size * v.aval.dtype.itemsize
+                                 for v in eqn.outvars))
+        else:
+            for inner in jax.core.jaxprs_in_params(eqn.params):
+                yield from _kept_values(inner, at)
+
+
+def _recomputing(ctx, attrs, step, carry, xt, trips):
+    """`step` under jax.checkpoint: a trip keeps its carry and what
+    keeps_across_passes names, and the backward pass replays the rest of
+    its body. The body is traced here, once, so that the kept values can be
+    read off its equations and booked: the ops they belong to are not
+    counted as replayed (count_loop_ops), ptpu_remat_kept_values_total and
+    ptpu_remat_kept_bytes say what was kept."""
+    from ..observability.registry import REGISTRY
+    # in the body, and nowhere else, a `mul` names its narrow product
+    outer, ctx.in_recomputing_loop = ctx.in_recomputing_loop, True
+    try:
+        body, out = jax.make_jaxpr(step, return_shape=True)(carry, xt)
+    finally:
+        ctx.in_recomputing_loop = outer
+    out_tree = jax.tree.structure(out)
+    loop = str(attrs["sub_block"])
+    kept = list(_kept_values(body.jaxpr))
+    # an op whose statistics alone are kept still runs again
+    ctx.recomputing_loops[attrs["sub_block"]] = (
+        trips, {at for at, rule, _ in kept if rule != "row_reduction"})
+    count_loop_ops(ctx, "forward", attrs)
+    values = REGISTRY.counter(
+        "ptpu_remat_kept_values_total",
+        "values a recomputing loop op keeps across the forward/backward "
+        "boundary beside its carry, a trip, by the loop's sub-block, the "
+        "fluid op of the body that computes the value and the rule that "
+        "kept it: kernel_output (a Pallas forward kernel's outputs), "
+        "narrow_matmul (a `mul` whose contraction is wider than its "
+        "result) or row_reduction (a reduction over 128 elements or more); "
+        "an op whose kernel or matmul is kept is not counted as replayed "
+        "in ptpu_remat_ops_total")
+    for (op_type, _), rule, _ in kept:
+        values.inc(trips, loop=loop, op=op_type, rule=rule)
+    REGISTRY.gauge(
+        "ptpu_remat_kept_bytes",
+        "bytes a recomputing loop op keeps across the forward/backward "
+        "boundary beside its carry: a trip's kept values times the trips"
+    ).set(trips * sum(size for _, _, size in kept), loop=loop)
+
+    def run(carry, xt):
+        return jax.tree.unflatten(out_tree, jax.core.eval_jaxpr(
+            body.jaxpr, body.consts, *jax.tree.leaves((carry, xt))))
+    return jax.checkpoint(run, policy=keeps_across_passes)
+
+
 def count_loop_ops(ctx, kind, attrs):
     """The ops of a recomputing loop's body in ptpu_remat_ops_total, once a
     trip: `forward` where the loop is lowered, `replayed` where its grad op
-    is."""
+    is, less the ops whose values the loop keeps (_recomputing): the
+    compiled step does not run their kernel or matmul again."""
     sub = ctx.program.blocks[attrs["sub_block"]]
-    lowering._count_remat_ops(kind, sub.ops,
-                              times=ctx.loop_trips[attrs["sub_block"]])
+    trips, kept = ctx.recomputing_loops[attrs["sub_block"]]
+    lowering._count_remat_ops(
+        kind, [op for op in sub.ops if kind == "forward"
+               or lowering.parse_op_scope(lowering.op_scope(op)) not in kept],
+        times=trips)
 
 
 def _rnn_scan_infer(block, op, out_vars):

@@ -300,6 +300,7 @@ def profile_report(sorted_key=None, json=False):
                              for kv in sorted(ss["by_tag"].items()))))
         lines.extend(_embedding_lines())
         lines.extend(_softmax_xent_lines())
+        lines.extend(_recompute_lines())
         lines.extend(_restart_tables())
     return "\n".join(lines)
 
@@ -329,6 +330,38 @@ def _softmax_xent_lines():
         k = dict(key)
         lines.append("softmax_xent: %d x loss by %s on %s logits, Softmax %s"
                      % (n, k["path"], k["logits"], k["softmax"]))
+    return lines
+
+
+def _recompute_lines():
+    """What a program that recomputes runs twice and what it keeps instead:
+    forward ops a step and those the backward pass replays
+    (`ptpu_remat_ops_total`; an op whose value a loop keeps is not counted
+    as replayed), then one line a recomputing loop: the values it keeps by
+    fluid op and rule (`ptpu_remat_kept_values_total`) and their bytes
+    (`ptpu_remat_kept_bytes`)."""
+    from .observability.registry import REGISTRY
+    ops = {"forward": 0.0, "replayed": 0.0}
+    for key, n in REGISTRY.counter("ptpu_remat_ops_total").samples():
+        ops[dict(key)["kind"]] += n
+    if not ops["forward"]:
+        return []
+    lines = ["recompute: %d forward ops lowered, %d replayed in the backward "
+             "pass (ptpu_remat_ops_total; a kept op is not replayed)"
+             % (ops["forward"], ops["replayed"])]
+    kept = {}
+    for key, n in REGISTRY.counter(
+            "ptpu_remat_kept_values_total").samples():
+        k = dict(key)
+        kept.setdefault(k["loop"], []).append(
+            "%d x %s of %s" % (n, k["rule"], k["op"]))
+    for key, size in REGISTRY.gauge("ptpu_remat_kept_bytes").samples():
+        loop = dict(key)["loop"]
+        lines.append(
+            "recompute: loop %s keeps %s (ptpu_remat_kept_values_total), "
+            "%.1f MiB (ptpu_remat_kept_bytes)"
+            % (loop, ", ".join(kept.get(loop, [])) or "nothing",
+               size / 2.0 ** 20))
     return lines
 
 

@@ -30,15 +30,17 @@ def test_profiler_records_per_entry_stats(capsys):
     report = profiler.profile_report(sorted_key="calls")
     # the training program entry ran 4 times; startup ran once each
     # 11 numeric columns after the (possibly space-containing) tag; the
-    # "compile cache:" / "host syncs:" / "embedding:" / "softmax_xent:"
-    # footers are summaries, not rows (the last two a kind of lookup_table
-    # and of softmax_with_cross_entropy this process has lowered), and the
+    # "compile cache:" / "host syncs:" / "embedding:" / "softmax_xent:" /
+    # "recompute:" footers are summaries, not rows (the last three a kind of
+    # lookup_table, of softmax_with_cross_entropy and a program that
+    # recomputes that this process has lowered), and the
     # "Lowering(s) by op type" block after them is its own table
     entries = report[:report.index("Lowering(s) by op type")]
     counts = sorted(int(line.split()[-11]) for line in
                     entries.splitlines()[1:]
                     if not line.startswith(("compile cache:", "host syncs:",
-                                            "embedding:", "softmax_xent:")))
+                                            "embedding:", "softmax_xent:",
+                                            "recompute:")))
     assert counts[-1] == 4, report
     with pytest.raises(ValueError, match="sorted_key"):
         profiler.profile_report(sorted_key="bogus")
