@@ -763,7 +763,7 @@ class Executor(object):
         # program._uid is mandatory (as in ParallelExecutor): id() of a GC'd
         # program can be recycled and silently serve a stale jitted fn.
         # trace_env_key() carries every trace-time env flag (conv layout,
-        # flash dispatch, remat tuning) — flipping one between runs must
+        # flash dispatch, kernel selection) — flipping one between runs must
         # re-trace, not silently serve the other configuration's fn.
         # (steps, fetch_reduce, stacked feed set) shape the traced loop the
         # same way: a K=8 'mean' fn must never serve a K=4 'stack' call.

@@ -16,6 +16,11 @@ custom call is not deduplicated, so a forward op whose rule can reach a
 Pallas kernel (OpDef.calls_pallas) and whose gradient is taken in the same
 block lowers under jax.vjp once and keeps its vjp_fn for the grad op
 (_linearizations): every Pallas forward kernel runs once a step.
+
+A block has one lowering. Recomputing activations is no pass of this module:
+it lives in the loop op that replays its body (`recompute` on an rnn_scan op,
+ops/control_ops.py _recomputing), and what this module does for it is count
+what runs twice (_count_remat_ops).
 """
 import collections
 import itertools
@@ -38,48 +43,26 @@ from .utils import find_var as _find_var
 _SPECIAL = {}
 
 
-def remat_segment_len_flag():
-    """FLAGS_remat_segment_len: explicit ops-per-segment for segment
-    remat (unset/empty = the sqrt(n) default -> None). Single owner of
-    the flag read: _lower_block_remat, trace_env_key() and the compile
-    probe all call this. Non-numeric values raise LOUDLY (like
-    FLAGS_conv_layout): a typo silently measured as the sqrt default
-    would mislabel banked compile-time numbers. Values < 4 are clamped
-    to 4 by the lowering; the resolved value is what this returns."""
-    import os
-    v = os.environ.get("FLAGS_remat_segment_len", "")
-    if not v:
-        return None
-    try:
-        n = int(v)
-    except ValueError:
-        raise ValueError(
-            "FLAGS_remat_segment_len=%r: expected an integer (ops per "
-            "remat segment) or unset" % v)
-    return max(4, n)
-
-
 def trace_env_key():
     """Values of every env flag that is read at TRACE time (they shape
     the lowered computation): any jit-program cache over lowered fns must
     include this tuple in its key, or flipping a flag between runs would
     silently serve the other configuration's compiled fn.
 
-    Current flags: FLAGS_conv_layout (conv/pool compute layout),
-    the flash crossover (kernel_config.flash_min_seq:
-    FLAGS_flash_min_seq, else the constant), FLAGS_remat_segment_len
-    (segment-remat tuning knob) and the raw PADDLE_TPU_PALLAS env string
-    — the RAW string, not pallas_on(): that helper also reads the
-    dispatch platform, which is fixed per executor (and in the AOT key
-    through the device), so the env string alone captures everything
-    that can change between runs of one executor. A function of the
-    environment and the jax config only: both executors call it on every
-    run, and it touches no file. When adding a trace-time flag, add its
-    resolved value HERE."""
+    The four members: FLAGS_conv_layout (conv/pool compute layout), the
+    flash crossover (kernel_config.flash_min_seq: FLAGS_flash_min_seq,
+    else the constant), the raw PADDLE_TPU_PALLAS env string — the RAW
+    string, not pallas_on(): that helper also reads the dispatch
+    platform, which is fixed per executor (and in the AOT key through the
+    device), so the env string alone captures everything that can change
+    between runs of one executor — and jax's PRNG formulation. A function
+    of the environment and the jax config only: both executors call it on
+    every run, and it touches no file. When adding a trace-time flag, add
+    its resolved value HERE."""
     import os
     from ..ops.kernel_config import flash_min_seq
     from ..ops.nn_ops import _conv_layout
-    return (_conv_layout(), flash_min_seq(), remat_segment_len_flag(),
+    return (_conv_layout(), flash_min_seq(),
             os.environ.get("PADDLE_TPU_PALLAS", ""),
             # the PRNG formulation is traced into every random op; the
             # package __init__ pins it partitionable, so this entry's
@@ -157,6 +140,9 @@ class LowerCtx(object):
         # nothing reads (_unread_outputs): its rule may leave them out
         self.unread_outputs = frozenset()
         self._read_names = None
+        # the names that leave the step (build_program_fn): fetches, state,
+        # feeds. _unread_outputs counts them as read.
+        self.leaves_step = frozenset()
         # traced iteration counters of enclosing lax.scan/while_loop bodies
         # (pushed by control-flow lowerings) — folded into every key so
         # dropout/random ops inside loops vary per time step.
@@ -204,9 +190,10 @@ class LowerCtx(object):
     def rng(self, salt=0, seed=0):
         """Deterministic key derived from (run seed, op uid, call index within
         the op). Re-lowering the same forward op inside jax.vjp (a grad op's
-        replay, a remat segment) replays the identical key stream, so dropout
-        masks / random inits are grad-consistent and XLA CSE dedupes what it
-        generated itself (not a Pallas kernel: _linearizations).
+        replay, a recomputing loop's body) replays the identical key stream,
+        so dropout masks / random inits are grad-consistent and XLA CSE
+        dedupes what it generated itself (not a Pallas kernel:
+        _linearizations).
 
         A nonzero user `seed` (the op's seed attr — fluid's reproducibility
         contract) pins the key independent of the run counter, so the op
@@ -221,16 +208,6 @@ class LowerCtx(object):
         for it in self._rng_extra:
             key = jax.random.fold_in(key, it)
         return key
-
-
-class _Lazy(object):
-    """Deferred env value: resolving it triggers a segment recompute
-    (rematerialization). See _lower_block_remat."""
-
-    __slots__ = ("fn",)
-
-    def __init__(self, fn):
-        self.fn = fn
 
 
 class EnvReadError(KeyError):
@@ -267,18 +244,10 @@ class Env(object):
         if name not in self.values:
             raise EnvReadError("variable %r read before it was written; "
                                "is it fed / initialized?" % name)
-        v = self.values[name]
-        if isinstance(v, _Lazy):
-            v = v.fn()
-            self.values[name] = v
-        return v
+        return self.values[name]
 
     def read_opt(self, name):
-        v = self.values.get(name)
-        if isinstance(v, _Lazy):
-            v = v.fn()
-            self.values[name] = v
-        return v
+        return self.values.get(name)
 
     def write(self, name, value):
         self.values[name] = self._constrain(name, value)
@@ -296,9 +265,6 @@ def lower_block(ctx, block, env):
     from .readers import is_host_io_op
     ops = [op for op in block.ops if not is_host_io_op(op.type)]
     # host io ops are executed host-side by the Executor's io pre-pass
-    if getattr(ctx.program, "_rematerialize", False) and block.idx == 0 \
-            and not ctx.is_startup and _lower_block_remat(ctx, ops, env):
-        return
     outer = ctx.linearized
     ctx.linearized = _linearizations(ctx, ops)
     if block.idx == 0 and any(op.attrs.get("recompute") for op in ops):
@@ -325,9 +291,7 @@ def _linearizations(ctx, ops):
     (INSIDE such a loop the same holds a trip: its checkpoint keeps the
     kernels' outputs, ops/control_ops.py keeps_across_passes.) Every other
     op keeps the replay: nothing would be gained on the device, and every
-    program's HLO would change. Under rematerialization nothing is kept."""
-    if getattr(ctx.program, "_rematerialize", False):
-        return {}
+    program's HLO would change."""
     return {op.attrs["fwd_uid"]: None for op in ops
             if op.type == "grad_of" and "fwd_uid" in op.attrs
             and registry.is_registered(op.attrs["fwd_type"])
@@ -349,157 +313,15 @@ def _is_traced_array(v):
     return isinstance(v, jax.Array) or isinstance(v, jax.core.Tracer)
 
 
-def _lower_block_remat(ctx, ops, env):
-    """Segment-level rematerialization (enable_rematerialization).
-
-    TPU-native activation checkpointing over the explicit fluid backward:
-    the forward region (ops before the first gradient op) is split into
-    ~sqrt(n)-op segments. After lowering a segment, every value it
-    produced whose remaining consumers are exclusively in the backward
-    region is swapped for a deferred recompute: when the backward reads
-    it, the whole segment re-lowers from its boundary inputs behind a
-    lax.optimization_barrier (so XLA cannot CSE the replay with the
-    forward and silently resurrect the saved residual). Only segment
-    boundaries stay live across the forward→backward gap — peak
-    activation memory drops from O(n) to O(n/s + s), the classic
-    checkpointing tradeoff the reference has no counterpart for.
-
-    RNG discipline: recompute replays lower_op with the same op uids, so
-    counter-derived keys (dropout masks etc.) are bit-identical to the
-    forward's. Returns False when the program has no backward region to
-    rematerialize (caller falls back to plain lowering).
-    """
-    first_bwd = _first_backward_op(ops)
-    if first_bwd is None or first_bwd < 8:
-        return False
-    fwd_ops, bwd_ops = ops[:first_bwd], ops[first_bwd:]
-
-    def resolve_lazies():
-        # special-lowered ops (while/conditional_block/beam_search...) read
-        # enclosing-scope values via wholesale env copies that op.inputs
-        # does not list, and resolve them INSIDE lax sub-traces — a _Lazy
-        # reaching one would replay its segment at inner trace level and
-        # poison the shared recompute cache with escaped tracers. Force
-        # every deferred value concrete (top-level trace) first.
-        for nm, v in list(env.values.items()):
-            if isinstance(v, _Lazy):
-                env.values[nm] = v.fn()
-
-    fwd_write_counts = {}
-    for op in fwd_ops:
-        for nm in op.all_output_vars():
-            if nm:
-                fwd_write_counts[nm] = fwd_write_counts.get(nm, 0) + 1
-    read_by_bwd = set()
-    for op in bwd_ops:
-        for nm in op.all_input_vars():
-            read_by_bwd.add(nm)
-    keep = set(getattr(ctx, "remat_keep", ()))
-
-    import math
-    seg_len_flag = remat_segment_len_flag()
-    if seg_len_flag is not None:
-        # tuning knob (round-4 verdict weak #3): sqrt(n) segments means
-        # sqrt(n) optimization barriers; compile time is sensitive to
-        # the barrier count, so the sweep can probe longer segments
-        # (fewer barriers, more recompute per barrier)
-        seg_len = seg_len_flag
-    else:
-        seg_len = max(4, int(math.ceil(math.sqrt(len(fwd_ops)))))
-    segments = [fwd_ops[i:i + seg_len]
-                for i in range(0, len(fwd_ops), seg_len)]
-    seg_reads = []
-    for seg in segments:
-        seg_reads.append({nm for op in seg
-                          for nm in op.all_input_vars() if nm})
-    # names read by any LATER forward segment (those stay live anyway —
-    # they are the checkpoints; rematerializing them would cascade)
-    suffix_after = [set() for _ in segments]
-    acc = set()
-    for k in range(len(segments) - 1, -1, -1):
-        suffix_after[k] = set(acc)
-        acc |= seg_reads[k]
-
-    for k, seg in enumerate(segments):
-        has_special = any(op.type in _SPECIAL for op in seg)
-        before = dict(env.values)
-        _count_remat_ops("forward", seg)
-        for op in seg:
-            if op.type in _SPECIAL:
-                resolve_lazies()
-            lower_op(ctx, op, env)
-        if has_special:
-            # a segment with a sub-block op cannot be replayed faithfully
-            # (its implicit enclosing-scope reads are not in op.inputs) —
-            # keep its products as plain checkpoints
-            continue
-        interior = sorted({
-            nm for op in seg for nm in op.all_output_vars()
-            if nm and nm in read_by_bwd
-            and nm not in suffix_after[k]
-            and nm not in keep
-            and fwd_write_counts.get(nm) == 1       # SSA-safe only
-            and _is_traced_array(env.values.get(nm))})
-        if not interior:
-            continue
-        boundary = {nm: before[nm] for nm in seg_reads[k]
-                    if nm in before and not isinstance(before[nm], _Lazy)}
-
-        def make_recompute(seg=seg, boundary=boundary,
-                           interior=tuple(interior)):
-            cache = {}
-
-            def recompute():
-                if cache:
-                    return cache
-                names = sorted(boundary)
-                arrs = [boundary[nm] for nm in names]
-                arr_idx = [i for i, a in enumerate(arrs)
-                           if _is_traced_array(a)]
-                if arr_idx:
-                    barred = jax.lax.optimization_barrier(
-                        [arrs[i] for i in arr_idx])
-                    for i, b in zip(arr_idx, barred):
-                        arrs[i] = b
-                sub = Env()
-                sub.values.update(zip(names, arrs))
-                _count_remat_ops("replayed", seg)
-                for op in seg:
-                    lower_op(ctx, op, sub)
-                for nm in interior:
-                    cache[nm] = sub.values[nm]
-                return cache
-
-            return recompute
-
-        rec = make_recompute()
-        for nm in interior:
-            env.values[nm] = _Lazy(lambda nm=nm, rec=rec: rec()[nm])
-
-    for op in bwd_ops:
-        if op.type in _SPECIAL:
-            # nested sub-block grads are NOT segment-handled: leave
-            # _segment_handled unset so they keep the per-op fallback
-            resolve_lazies()
-            lower_op(ctx, op, env)
-            continue
-        ctx._segment_handled = True
-        try:
-            lower_op(ctx, op, env)
-        finally:
-            ctx._segment_handled = False
-    return True
-
-
 def _count_remat_ops(kind, ops, times=1):
     from ..observability.registry import REGISTRY
     counter = REGISTRY.counter(
         "ptpu_remat_ops_total",
         "forward ops of a program that recomputes, by fluid op type, as often "
         "as they run a step: `forward`, and `replayed` a second time in the "
-        "backward pass (a segment of enable_rematerialization, or a loop op's "
-        "body a trip LESS the ops whose kernel or matmul the loop keeps and "
-        "does not run again: ptpu_remat_kept_values_total counts those)")
+        "backward pass (the body of a loop op that recomputes, a trip, LESS "
+        "the ops whose kernel or matmul the loop keeps and does not run "
+        "again: ptpu_remat_kept_values_total counts those)")
     for op in ops:
         counter.inc(times, kind=kind, op=op.type)
 
@@ -819,9 +641,9 @@ def _unread_outputs(ctx, od, outputs):
     if not od.optional_outputs:
         return frozenset()
     if ctx._read_names is None:
-        # what leaves the step (build_program_fn's remat_keep) and what an
+        # what leaves the step (build_program_fn's leaves_step) and what an
         # op reads
-        read = set(getattr(ctx, "remat_keep", ()))
+        read = set(ctx.leaves_step)
         for block in ctx.program.blocks:
             for op in block.ops:
                 read.update(op.all_input_vars())
@@ -1110,18 +932,6 @@ def _lower_grad_of(ctx, op, env):
             ctx, od, op_scope(op), fwd_type,
             op.attrs["fwd_attrs"], op.attrs.get("fwd_uid", 0), fwd_in_vals,
             _out_order(fwd_outputs))
-        # Rematerialization: when the segment-level pass handles this grad
-        # op (top-level backward of a >=8-op forward), it hands the replay
-        # recomputed barrier-guarded primals — per-op jax.checkpoint must
-        # NOT stack on top: for boundary/checkpoint inputs the replay SHOULD
-        # CSE with the forward (the residual is live anyway; blocking that
-        # was measured at +15G HBM on ResNet-50@512). Everywhere the segment
-        # pass cannot reach (grad ops inside control-flow sub-blocks,
-        # programs below the segment gate) the per-op checkpoint is still
-        # the only remat lever, so it stays as the fallback.
-        if getattr(ctx.program, "_rematerialize", False) \
-                and not getattr(ctx, "_segment_handled", False):
-            f = jax.checkpoint(f)
         primals, vjp_fn, _ = jax.vjp(f, primal, has_aux=True)
     _count_grad_op("replayed" if kept is None else "kept", fwd_type)
     if kept is not None and op.attrs["fwd_attrs"].get("recompute"):
@@ -1185,10 +995,11 @@ def build_program_fn(program, feed_names, fetch_names, state_rw, state_ro,
         base_key = jax.random.fold_in(
             jax.random.key(program.random_seed), seed)
         ctx = LowerCtx(program, base_key=base_key, mesh=mesh)
-        # names the remat pass must never defer: externally observed values
-        # (fetches, persistable state) and everything fed from outside
-        ctx.remat_keep = (set(fetch_names) | set(state_out) | set(state_rw)
-                         | set(state_ro) | set(feed_names))
+        # what _unread_outputs must count as read though no op reads it:
+        # externally observed values (fetches, persistable state) and
+        # everything fed from outside
+        ctx.leaves_step = (set(fetch_names) | set(state_out) | set(state_rw)
+                           | set(state_ro) | set(feed_names))
         env = Env(constraints=shard_constraints)
         for n, v in zip(feed_names, feed_vals):
             env.write(n, v)

@@ -1,4 +1,4 @@
-"""Memory optimization: liveness analysis + rematerialization control.
+"""Memory optimization: liveness analysis.
 
 Parity: python/paddle/fluid/memory_optimization_transpiler.py. The
 reference rewrites the program to reuse variable buffers based on a
@@ -7,18 +7,15 @@ dataflow liveness analysis (ControlFlowGraph with live_in/live_out).
 On TPU the executor lowers the whole program to one XLA computation and
 XLA's buffer assignment already performs exactly this reuse, so rewriting
 var names would change nothing about the compiled memory plan. This
-module therefore:
-
-- runs the same liveness analysis and returns/prints the reuse report
-  (`memory_optimize(program, print_log=True)`), preserving the API and
-  letting users inspect what XLA will coalesce;
-- `enable_rematerialization(program)` marks the program so the executor
-  wraps forward lowering in `jax.checkpoint` — the TPU-native way to
-  trade FLOPs for activation memory (the knob the reference lacks).
+module therefore runs the same liveness analysis and returns/prints the
+reuse report (`memory_optimize(program, print_log=True)`), preserving the
+API and letting users inspect what XLA will coalesce. Trading FLOPs for
+activation memory is not done here: a loop that recomputes its body is
+built with `layers.StaticRNN(steps=, recompute=True)`.
 """
 import numpy as np
 
-__all__ = ["memory_optimize", "release_memory", "enable_rematerialization"]
+__all__ = ["memory_optimize", "release_memory"]
 
 
 _PROCESSED_FLAG = "__memopt_analyzed__"
@@ -115,11 +112,3 @@ def release_memory(input_program):
     frees buffers at computation boundaries automatically."""
     return input_program
 
-
-def enable_rematerialization(program):
-    """Mark the program so the executor lowers the forward pass under
-    jax.checkpoint (recompute activations in backward instead of storing
-    them) — the TPU-native memory/compute tradeoff."""
-    program._rematerialize = True
-    program._bump_version()  # invalidate cached jitted entries
-    return program

@@ -292,8 +292,8 @@ class ParallelExecutor(object):
         def _feed_sharding(name, ndim):
             return _sharding_for(name, ndim, stacked_names)
 
-        # every trace-time env flag (conv layout, flash dispatch, remat
-        # tuning) is traced into the fn — key on them so an env-var flip
+        # every trace-time env flag (conv layout, flash dispatch, kernel
+        # selection) is traced into the fn — key on them so an env-var flip
         # re-traces instead of serving the other configuration. (steps,
         # fetch_reduce, stacked feeds) shape the traced loop the same way.
         from ..core import compile_cache

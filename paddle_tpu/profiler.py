@@ -334,8 +334,9 @@ def _softmax_xent_lines():
 
 
 def _recompute_lines():
-    """What a program that recomputes runs twice and what it keeps instead:
-    forward ops a step and those the backward pass replays
+    """What a program whose loop recomputes its body (`StaticRNN(steps=,
+    recompute=True)`) runs twice and what it keeps instead: forward ops a
+    step and those the loop's backward scan replays
     (`ptpu_remat_ops_total`; an op whose value a loop keeps is not counted
     as replayed), then one line a recomputing loop: the values it keeps by
     fluid op and rule (`ptpu_remat_kept_values_total`) and their bytes

@@ -1,5 +1,5 @@
 """Aux subsystems: evaluators, WeightedAverage, debugger printer,
-memory_optimize liveness, rematerialization flag.
+memory_optimize liveness.
 
 Parity: reference tests/unittests/{test_fluid_evaluator-era usage,
 test_memory_optimization_transpiler.py, debuger usage}.
@@ -113,35 +113,11 @@ def test_debugger_printer_and_graphviz(tmp_path):
     assert "digraph G" in text and "mul" in text
 
 
-def test_memory_optimize_report_and_remat():
+def test_memory_optimize_report_and_release_memory():
     main, startup, pred, loss, _ = _mlp_program()
     report = fluid.memory_optimize(main)
     assert isinstance(report, list)
     assert fluid.release_memory(main) is main
-
-    # remat: program still trains and matches the non-remat loss exactly
-    def run(remat):
-        main, startup, pred, loss, _ = _mlp_program()
-        if remat:
-            fluid.memory_optimization_transpiler.enable_rematerialization(
-                main)
-        rng = np.random.RandomState(1)
-        exe = fluid.Executor(fluid.CPUPlace())
-        scope = fluid.Scope()
-        with fluid.scope_guard(scope):
-            exe.run(startup)
-            out = []
-            for i in range(3):
-                xs = rng.rand(8, 8).astype("f")
-                ys = rng.randint(0, 4, (8, 1)).astype("int64")
-                l, = exe.run(main, feed={"x": xs, "label": ys},
-                             fetch_list=[loss])
-                out.append(float(np.ravel(l)[0]))
-        return out
-
-    base = run(False)
-    remat = run(True)
-    np.testing.assert_allclose(base, remat, rtol=1e-6)
 
 
 def test_fetch_param_from_startup_program():

@@ -22,14 +22,22 @@ from paddle_tpu.ops import pallas_kernels
 
 # --- a scope a fluid op ----------------------------------------------------
 
-def _program(amp=False, remat=False):
+def _program(amp=False, loop=False):
+    """`loop`: two trips of a recomputing loop op (its body an fc over
+    weights it closes over) between the two layers."""
     main, startup = fluid.Program(), fluid.Program()
     with fluid.unique_name.guard(), fluid.program_guard(main, startup):
         x = fluid.layers.data(name="x", shape=[13], dtype="float32")
         y = fluid.layers.data(name="y", shape=[1], dtype="float32")
-        h = x
-        for _ in range(4 if remat else 1):   # remat wants >= 8 forward ops
-            h = fluid.layers.fc(input=h, size=8, act="relu")
+        h = fluid.layers.fc(input=x, size=8, act="relu")
+        if loop:
+            rnn = fluid.layers.StaticRNN(steps=2, recompute=True)
+            with rnn.step():
+                state = rnn.memory(init=h)
+                new = fluid.layers.fc(input=state, size=8, act="relu")
+                rnn.update_memory(state, new)
+                rnn.output(new)
+            h = fluid.layers.reduce_sum(rnn(), dim=1)
         pred = fluid.layers.fc(input=h, size=1)
         loss = fluid.layers.mean(
             x=fluid.layers.square_error_cost(input=pred, label=y))
@@ -37,13 +45,12 @@ def _program(amp=False, remat=False):
                                  momentum=0.9).minimize(loss)
     if amp:
         main.enable_mixed_precision()
-    if remat:
-        fluid.memory_optimization_transpiler.enable_rematerialization(main)
     return main, startup, loss
 
 
-def _lowered_op_names(main, startup, loss, steps, unroll):
-    """The op_name paths of the lowered step (its MLIR locations)."""
+def _lowered_op_names(main, startup, loss, steps, unroll, whole=False):
+    """The op_name paths of the lowered step (its MLIR locations); with
+    `whole`, those of the compiled HLO beside them."""
     feeds = ["x", "y"]
     rw, ro, out = lowering.analyze_state(main, feeds, [loss.name])
     scope = fluid.Scope()
@@ -61,24 +68,24 @@ def _lowered_op_names(main, startup, loss, steps, unroll):
         [vals[n] for n in rw], [vals[n] for n in ro])
     names = set(re.findall(r'loc\("([^"]*)"',
                            lowered.as_text(debug_info=True)))
-    if steps > 1 and not unroll:    # the scan's body is a call of its own
+    if whole or steps > 1 and not unroll:  # a scan's body is a call of its own
         names |= set(re.findall(    # there; the compiled HLO has whole paths
             r'op_name="([^"]*)"', lowered.compile().as_text()))
     return names
 
 
-@pytest.mark.parametrize("amp,remat,steps,unroll", [
+@pytest.mark.parametrize("amp,loop,steps,unroll", [
     (False, False, 1, False), (True, False, 1, False),
     (False, True, 1, False), (False, False, 2, False),
     (False, False, 2, True), (True, True, 2, False)],
-    ids=["plain", "amp", "remat", "steps2_scan", "steps2_unrolled",
-         "amp_remat_steps2"])
+    ids=["plain", "amp", "loop_recompute", "steps2_scan", "steps2_unrolled",
+         "amp_loop_recompute_steps2"])
 def test_every_fluid_op_of_the_block_lowers_under_its_scope(
-        amp, remat, steps, unroll):
-    main, startup, loss = _program(amp, remat)
-    names = _lowered_op_names(main, startup, loss, steps, unroll)
+        amp, loop, steps, unroll):
+    main, startup, loss = _program(amp, loop)
+    names = _lowered_op_names(main, startup, loss, steps, unroll, whole=loop)
     got = {lowering.parse_op_scope(n) for n in names} - {None}
-    ops = main.global_block().ops
+    ops = [op for block in main.blocks for op in block.ops]
     want = {}
     for op in ops:
         op_type = (op.attrs["fwd_type"] + "_grad" if op.type == "grad_of"
@@ -91,8 +98,14 @@ def test_every_fluid_op_of_the_block_lowers_under_its_scope(
     if steps > 1 and not unroll:
         assert any("while/body" in n and lowering.parse_op_scope(n)
                    for n in names)
-    if remat:       # a segment replayed by the backward nests in a grad op
-        assert any(n.count(lowering.SCOPE_MARK) >= 2 for n in names)
+    if loop:
+        # the body's ops, replayed inside the backward scan, each under its
+        # own scope inside the loop's grad op's
+        body = {lowering.op_scope(op) for op in main.blocks[1].ops}
+        assert len(body) == 3 and all(any(
+            "/op:rnn_scan_grad/" in n and "/while/body/" in n
+            and "/checkpoint/rematted_computation/%s/" % scope in n
+            for n in names) for scope in body)
 
 
 @pytest.mark.parametrize("path,want", [
@@ -121,8 +134,8 @@ def test_every_fluid_op_of_the_block_lowers_under_its_scope(
     ("jit(fn)/while/body/op:softmax_with_cross_entropy_grad/fc~GRAD/"
      "transpose(jvp(op:softmax_with_cross_entropy/loss))/sub",
      ("softmax_with_cross_entropy_grad", "fc@GRAD")),
-    # ... and what is not: the forward op itself, a forward op a remat
-    # segment lowers again inside its own grad op, another op's scope
+    # ... and what is not: the forward op itself, a forward op that a
+    # replay lowers again inside a grad op, another op's scope
     ("jit(fn)/op:layer_norm/ln_0.tmp_2/jvp(op:layer_norm/ln_0.tmp_2)/"
      "ptpu_layer_norm_fwd/pallas_call", ("layer_norm", "ln_0.tmp_2")),
     ("jit(fn)/op:relu_grad/a~GRAD/op:relu/a/max", ("relu", "a")),

@@ -34,7 +34,7 @@ def _replay(monkeypatch):
     monkeypatch.setattr(lowering, "_linearizations", lambda ctx, ops: {})
 
 
-def _train_program(amp=False, remat=False):
+def _train_program(amp=False):
     """layer_norm, flash attention and softmax_xent in one training step;
     two of each norm so that `kept` counts more than one grad op a type."""
     main, startup = fluid.Program(), fluid.Program()
@@ -51,8 +51,6 @@ def _train_program(amp=False, remat=False):
         fluid.optimizer.SGD(learning_rate=0.1).minimize(loss)
     if amp:
         main.enable_mixed_precision()
-    if remat:
-        fluid.memory_optimization_transpiler.enable_rematerialization(main)
     return main, startup, loss
 
 
@@ -170,23 +168,7 @@ def test_the_executor_trains_on_kept_linearizations():
     assert losses[-1] < losses[0] - 0.05, losses
 
 
-# --- (c) where nothing is kept, and where it is used twice ------------------
-
-def test_under_rematerialization_every_grad_op_replays():
-    main, startup, loss = _train_program(remat=True)
-    fn, args = _lowered(main, startup, ["x", "lab"], [loss.name])
-    counted = _counted(fn, args)
-    assert counted and all(path == "replayed" for path, _ in counted)
-    assert counted[("replayed", "layer_norm")] == 2
-    plain = _lowered(*_train_program()[:2], ["x", "lab"],
-                     [loss.name] + _param_grads(main))
-    remat = _lowered(main, startup, ["x", "lab"],
-                     [loss.name] + _param_grads(main))
-    for a, b in zip(jax.jit(plain[0])(*plain[1]),
-                    jax.jit(remat[0])(*remat[1])):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-5,
-                                   atol=1e-6)
-
+# --- (c) where it is used twice --------------------------------------------
 
 def _calc_gradient_twice():
     main, startup = fluid.Program(), fluid.Program()

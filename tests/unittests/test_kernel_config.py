@@ -345,7 +345,6 @@ def test_layer_norm_reaches_its_named_kernel_on_a_tpu(monkeypatch,
 @pytest.mark.parametrize("moved,name,value", [
     (True, "FLAGS_conv_layout", "NHWC"),
     (True, "FLAGS_flash_min_seq", "64"),
-    (True, "FLAGS_remat_segment_len", "12"),
     (True, "PADDLE_TPU_PALLAS", "attn,ln"),
     (True, "jax_threefry_partitionable", None),
     (False, "FLAGS_kernel_store_dir", "/nonexistent"),
@@ -354,10 +353,10 @@ def test_layer_norm_reaches_its_named_kernel_on_a_tpu(monkeypatch,
 def test_trace_env_key_touches_no_file(monkeypatch, moved, name, value):
     """Both executors call trace_env_key() on every run: it reads the
     environment and the jax config and nothing on disk, and it moves when,
-    and only when, one of its five inputs does."""
+    and only when, one of its four inputs does."""
     from paddle_tpu.core.lowering import trace_env_key
     for var in ("FLAGS_conv_layout", "FLAGS_flash_min_seq",
-                "FLAGS_remat_segment_len", "PADDLE_TPU_PALLAS"):
+                "PADDLE_TPU_PALLAS"):
         monkeypatch.delenv(var, raising=False)
     trace_env_key()                     # imports done before files go away
 
@@ -368,7 +367,7 @@ def test_trace_env_key_touches_no_file(monkeypatch, moved, name, value):
                     (builtins, "open")):
         monkeypatch.setattr(mod, fn, no_files)
     key0 = trace_env_key()
-    assert len(key0) == 5
+    assert len(key0) == 4
     if name == "jax_threefry_partitionable":
         was = bool(jax.config.jax_threefry_partitionable)
         jax.config.update(name, not was)

@@ -263,7 +263,7 @@ EQUIV = {
     "test_lstmp_op.py": [U + "test_rnn_numeric.py"],
     "test_math_op_patch.py": [U + "test_math_op_patch.py"],
     "test_memory_optimization_transpiler.py": [U + "test_aux_modules.py",
-                                               U + "test_remat_segments.py"],
+                                               U + "test_native_graph.py"],
     "test_modified_huber_loss_op.py": [U + "test_tail_ops.py"],
     "test_momentum_op.py": [U + "test_optimizer_numeric.py"],
     "test_multi_pass_reader.py": [U + "test_reader_layers.py"],
@@ -606,7 +606,7 @@ TREE_EQUIV = {
     "book_memory_optimization/test_memopt_fit_a_line.py": [
         U + "test_aux_modules.py"],
     "book_memory_optimization/test_memopt_image_classification_train.py": [
-        U + "test_remat_segments.py"],
+        U + "test_aux_modules.py", U + "test_loop_recompute.py"],
     "book_memory_optimization/test_memopt_machine_translation.py": [
         U + "test_aux_modules.py"],
     "demo/fc_gan.py": [B + "test_fc_gan.py"],

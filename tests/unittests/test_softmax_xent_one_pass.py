@@ -280,13 +280,13 @@ def test_a_read_in_a_sub_block_counts(how):
 
 def test_nothing_reads_the_softmax_of_a_plain_training_program():
     """The grad op names the forward op's inputs and Loss@GRAD, not its
-    outputs; a fetch (build_program_fn's remat_keep) is a read."""
+    outputs; a fetch (build_program_fn's leaves_step) is a read."""
     main = _program()[0]
     op, od = _xent_op(main)
     assert lowering._unread_outputs(lowering.LowerCtx(main), od,
                                     op.outputs) == {"Softmax"}
     ctx = lowering.LowerCtx(main)
-    ctx.remat_keep = {op.outputs["Softmax"][0]}
+    ctx.leaves_step = {op.outputs["Softmax"][0]}
     assert lowering._unread_outputs(ctx, od, op.outputs) == frozenset()
 
 

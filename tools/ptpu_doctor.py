@@ -121,7 +121,7 @@ def cmd_replay(args):
     from paddle_tpu.core.compile_cache import enable_persistent_cache
     from paddle_tpu.core.executor import NumericalGuardError
     from paddle_tpu.resilience.watchdog import read_bundle
-    # a replay of a remat-heavy training step pays the same compile the
+    # a replay of a large training step pays the same compile the
     # wedged trainer did; the persistent cache makes repeat replays (and
     # a replay on the machine that trained) load it from disk instead
     enable_persistent_cache()
