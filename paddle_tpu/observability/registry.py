@@ -292,6 +292,19 @@ def note_import(module, seconds):
         "was building or lowering that op)").set(seconds, module=module)
 
 
+def note_kernel_trace(kernel):
+    """`ptpu_kernel_body_traces_total{kernel}`, from the Python body of a
+    kernel entry (ops/pallas_import.py `kernel_entry`): a count is a real
+    trace, a hit of the entry's jit cache none."""
+    REGISTRY.counter(
+        "ptpu_kernel_body_traces_total",
+        "times jax ran the Python body of a jitted Pallas kernel entry, by "
+        "the Mosaic call's name: once a distinct set of shapes, dtypes and "
+        "static arguments in a process, however many call sites a step "
+        "has (a layer's counter, ptpu_attention_layers_total and its kin, "
+        "says how many those were)").inc(kernel=kernel)
+
+
 # ---------------------------------------------------------------------------
 # built-in collectors: the existing measurement surfaces, fronted
 # ---------------------------------------------------------------------------

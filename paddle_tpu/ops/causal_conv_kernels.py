@@ -32,8 +32,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax import lax
-from .pallas_import import pl
-from .pallas_import import pltpu
+from .pallas_import import kernel_entry, pl, pltpu
 
 from . import kernel_config
 
@@ -205,10 +204,15 @@ def _tile(x, tile):
                   kernel_config.DEFAULT_TILES["conv"]["tile_bytes"])
 
 
-def _fwd_call(x, wt, silu, tile):
+# The two calls are jax.jits of their own, everything but the arrays static
+# (ops/pallas_import.py has the rule): a model's layers call them at one
+# shape, and a step traces each kernel's body once and not once a layer.
+@kernel_entry("ptpu_causal_conv1d_fwd",
+              static_argnames=("silu", "tile", "interpret"))
+def _fwd_call(x, wt, *, silu, tile, interpret):
     b, t, c = x.shape
     width = wt.shape[0]
-    block_t, block_c = _tile(x, tile)
+    block_t, block_c = tile
     return pl.pallas_call(
         functools.partial(_fwd_kernel, width=width, silu=silu,
                           block_t=block_t),
@@ -220,15 +224,17 @@ def _fwd_call(x, wt, silu, tile):
         out_specs=_vmem((1, block_t, block_c), lambda i, j, k: (i, k, j)),
         out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
         scratch_shapes=[pltpu.VMEM((_HALO + block_t, block_c), _F32)],
-        interpret=_interpret(),
+        interpret=interpret,
         name="ptpu_causal_conv1d_fwd",
     )(x, wt)
 
 
-def _bwd_call(x, wt, dy, silu, tile):
+@kernel_entry("ptpu_causal_conv1d_bwd",
+              static_argnames=("silu", "tile", "interpret"))
+def _bwd_call(x, wt, dy, *, silu, tile, interpret):
     b, t, c = x.shape
     width = wt.shape[0]
-    block_t, block_c = _tile(x, tile)
+    block_t, block_c = tile
     nt, per = t // block_t, block_t // _HALO
 
     def tile_at(i, j, k):               # tiles from the last to the first
@@ -251,14 +257,15 @@ def _bwd_call(x, wt, dy, silu, tile):
                    jax.ShapeDtypeStruct((b, width, c), _F32)],
         scratch_shapes=[pltpu.VMEM((_HALO + block_t, block_c), _F32),
                         pltpu.VMEM((block_t + _HALO, block_c), _F32)],
-        interpret=_interpret(),
+        interpret=interpret,
         name="ptpu_causal_conv1d_bwd",
     )(x, x, dy, wt)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
 def _conv(x, w, silu, tile):
-    return _fwd_call(x, w.T.astype(_F32), silu, tile)
+    return _fwd_call(x, w.T.astype(_F32), silu=silu, tile=_tile(x, tile),
+                     interpret=_interpret())
 
 
 def _conv_fwd(x, w, silu, tile):
@@ -267,7 +274,8 @@ def _conv_fwd(x, w, silu, tile):
 
 def _conv_bwd(silu, tile, res, dy):
     x, w = res
-    dx, dw = _bwd_call(x, w.T.astype(_F32), dy.astype(x.dtype), silu, tile)
+    dx, dw = _bwd_call(x, w.T.astype(_F32), dy.astype(x.dtype), silu=silu,
+                       tile=_tile(x, tile), interpret=_interpret())
     return dx, dw.sum(0).T.astype(w.dtype)
 
 

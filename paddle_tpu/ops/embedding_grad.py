@@ -31,8 +31,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax import lax
-from .pallas_import import pl
-from .pallas_import import pltpu
+from .pallas_import import kernel_entry, pl, pltpu
 
 from . import kernel_config
 
@@ -136,8 +135,8 @@ def _kernel(start_ref, ids_ref, g_hbm, out_ref, buf, sem, *, block_rows):
     lax.fori_loop(first, last, chunk, 0)
 
 
-@functools.partial(jax.jit, static_argnames=("vocab", "block_rows",
-                                             "interpret"))
+@kernel_entry("ptpu_embedding_grad",
+              static_argnames=("vocab", "block_rows", "interpret"))
 def _dense_grad(ids, g, vocab, block_rows, interpret):
     n, d = g.shape
     blocks = -(-vocab // block_rows)

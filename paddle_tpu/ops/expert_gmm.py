@@ -52,8 +52,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax import lax
-from .pallas_import import pl
-from .pallas_import import pltpu
+from .pallas_import import kernel_entry, pl, pltpu
 
 from . import kernel_config
 
@@ -231,19 +230,20 @@ def _rows_call(lhs, name, rhs, group_plan, transposed, interpret):
       group_plan.visits, lhs, rhs)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
+@kernel_entry("ptpu_expert_gmm_fwd", static_argnames=("interpret",))
 def _gmm(lhs, rhs, group_plan, *, interpret):
     return _rows_call(lhs, "ptpu_expert_gmm_fwd", rhs, group_plan, False,
                       interpret)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
+@kernel_entry("ptpu_expert_gmm_drows", static_argnames=("interpret",))
 def _gmm_drows(dout, rhs, group_plan, *, interpret):
     return _rows_call(dout, "ptpu_expert_gmm_drows", rhs, group_plan, True,
                       interpret)
 
 
-@functools.partial(jax.jit, static_argnames=("dtype", "interpret"))
+@kernel_entry("ptpu_expert_gmm_dweights",
+              static_argnames=("dtype", "interpret"))
 def _gmm_dweights(lhs, dout, group_plan, *, dtype, interpret):
     block_m = group_plan.block_m
     groups = group_plan.offsets.shape[0] - 1

@@ -51,8 +51,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax import lax
-from .pallas_import import pl
-from .pallas_import import pltpu
+from .pallas_import import kernel_entry, pl, pltpu
 
 from . import kernel_config
 
@@ -293,11 +292,22 @@ def _interpret():
     return kernel_config.dispatch_platform() != "tpu"
 
 
-def _fwd_call(ops, emit):
+def _how(ops):
+    """The static arguments of the two calls, beside `emit`: what their
+    bodies would read from this module and kernel_config, resolved here (a
+    trace is kept under its arguments)."""
+    return dict(hb=_block_h(ops[0].shape[0]), interpret=_interpret())
+
+
+# The two calls are jax.jits of their own, everything but the arrays static
+# (ops/pallas_import.py has the rule): a model's layers call them at one
+# shape, and a step traces each kernel's body once and not once a layer.
+@kernel_entry("ptpu_gated_delta_fwd",
+              static_argnames=("emit", "hb", "interpret"))
+def _fwd_call(ops, *, emit, hb, interpret):
     qe, u = ops[0], ops[3]
     bh, n, chunk, dk = qe.shape
     dv = u.shape[-1]
-    hb = _block_h(bh)
 
     def index(i, j):
         return (i, j, 0, 0)
@@ -313,16 +323,16 @@ def _fwd_call(ops, emit):
         out_specs=_vmem((hb, 1) + out_shape.shape[2:], index),
         out_shape=out_shape,
         scratch_shapes=[pltpu.VMEM((hb, dk, dv), _F32)],
-        interpret=_interpret(),
+        interpret=interpret,
         name="ptpu_gated_delta_fwd",
     )(*ops)
 
 
-def _bwd_call(ops, states, do):
+@kernel_entry("ptpu_gated_delta_bwd", static_argnames=("hb", "interpret"))
+def _bwd_call(ops, states, do, *, hb, interpret):
     qe, kd, m, u, w, erow = ops
     bh, n, _, dk = qe.shape
     dv = u.shape[-1]
-    hb = _block_h(bh)
 
     def index(i, j):                    # chunks from the last to the first
         return (i, n - 1 - j, 0, 0)
@@ -336,19 +346,20 @@ def _bwd_call(ops, states, do):
         out_specs=_specs(outs, hb, index),
         out_shape=[jax.ShapeDtypeStruct(a.shape, a.dtype) for a in outs],
         scratch_shapes=[pltpu.VMEM((hb, dk, dv), _F32)],
-        interpret=_interpret(),
+        interpret=interpret,
         name="ptpu_gated_delta_bwd",
     )(*ins)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
 def _kernel_path(prepare, q, k, v, g, beta):
-    return _fwd_call(prepare(q, k, v, g, beta), False)
+    ops = prepare(q, k, v, g, beta)
+    return _fwd_call(ops, emit=False, **_how(ops))
 
 
 def _kernel_path_fwd(prepare, q, k, v, g, beta):
     ops, prepare_vjp = jax.vjp(prepare, q, k, v, g, beta)
-    return _fwd_call(ops, False), (ops, prepare_vjp)
+    return _fwd_call(ops, emit=False, **_how(ops)), (ops, prepare_vjp)
 
 
 def _kernel_path_bwd(prepare, res, do):
@@ -357,8 +368,9 @@ def _kernel_path_bwd(prepare, res, do):
     # states as soon as the operands exist and hold them from the forward
     # pass to here (pallas_kernels._wait_for has the finding)
     do, ops = lax.optimization_barrier((do, ops))
-    states = _fwd_call(ops, True)
-    grads = _bwd_call(ops, states, do.astype(ops[0].dtype))
+    how = _how(ops)
+    states = _fwd_call(ops, emit=True, **how)
+    grads = _bwd_call(ops, states, do.astype(ops[0].dtype), **how)
     return prepare_vjp(tuple(grads))
 
 

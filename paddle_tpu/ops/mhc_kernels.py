@@ -51,8 +51,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax import lax
-from .pallas_import import pl
-from .pallas_import import pltpu
+from .pallas_import import kernel_entry, pl, pltpu
 
 from . import kernel_config
 
@@ -161,11 +160,10 @@ def post_plain(x, y, coef, n):
 # the kernels
 # ---------------------------------------------------------------------------
 
-def _call(kernel, name, rows, in_widths, out_shapes, interpret):
-    """pallas_call over blocks of rows: every operand [rows, width] is cut
-    into [block, width]; an operand given as (shape,) instead of a width
-    is whole in every step (a parameter)."""
-    block = _block_rows(rows)
+def _call(kernel, name, rows, in_widths, out_shapes, block, interpret):
+    """pallas_call over blocks of `block` rows: every operand [rows, width]
+    is cut into [block, width]; an operand given as (shape,) instead of a
+    width is whole in every step (a parameter)."""
 
     def spec(width):
         if isinstance(width, tuple):
@@ -452,7 +450,7 @@ def _kp(n):
 # once a sub-layer (the bodies are some hundred operators on tracers, each a
 # jitted call of its own under a trace: 0.5 s a sub-layer on the chip's
 # host, and as much again at build time, where shape inference traces them)
-@functools.partial(jax.jit, static_argnums=(3, 4, 5, 6, 7))
+@kernel_entry("ptpu_mhc_coeffs_fwd", static_argnums=(3, 4, 5, 6, 7))
 def coefficients(z, alpha, bias, n, iters, eps, clamp, interpret):
     """`coefficients_plain` as ptpu_mhc_coeffs_fwd; z [N, 128]."""
     static = dict(n=n, iters=iters, eps=eps, clamp=clamp)
@@ -462,7 +460,7 @@ def coefficients(z, alpha, bias, n, iters, eps, clamp, interpret):
         [_tiles(z, _kp(n))], n, iters, interpret))
 
 
-@functools.partial(jax.jit, static_argnums=(4, 5, 6, 7, 8))
+@kernel_entry("ptpu_mhc_coeffs_bwd", static_argnums=(4, 5, 6, 7, 8))
 def coefficients_bwd(z, alpha, bias, dcoef, n, iters, eps, clamp, interpret):
     """(dz [N, 128], dalpha [3], dbias [K]) of `coefficients` under the
     cotangent dcoef [N, 128], by ptpu_mhc_coeffs_bwd."""
@@ -510,20 +508,88 @@ def _prm(alpha, bias, n):
                    [(0, 6), (0, LANES - columns(n))])
 
 
+# The six stream calls, each a jax.jit of its own with everything but the
+# arrays static (ops/pallas_import.py has the rule; `block`, the rows a grid
+# step, is `_block_rows` of the caller): a model's sub-layers are alike, and
+# a step traces each kernel's body once and not once a sub-layer.
+@kernel_entry("ptpu_mhc_pre_fwd",
+              static_argnames=("n", "eps", "split", "block", "interpret"))
+def _pre_fwd_call(x, lanes, prm, *, n, eps, split, block, interpret):
+    rows, c = x.shape[0], x.shape[1] // n
+    return _call(
+        functools.partial(_pre_fwd_kernel, n=n, c=c, eps=eps, split=split),
+        "ptpu_mhc_pre_fwd", rows, [n * c, lanes.shape, (8, LANES)],
+        [jax.ShapeDtypeStruct((rows, c), x.dtype),
+         jax.ShapeDtypeStruct((rows, LANES), _F32)], block, interpret)(
+             x, lanes, prm)
+
+
+@kernel_entry("ptpu_mhc_pre_bwd", static_argnames=("n", "block", "interpret"))
+def _pre_bwd_call(x, dxc, dh, z, dzr, prm, phit, *, n, block, interpret):
+    rows, c = x.shape[0], x.shape[1] // n
+    return _call(
+        functools.partial(_pre_bwd_kernel, n=n, c=c), "ptpu_mhc_pre_bwd",
+        rows, [n * c, n * c, c, LANES, LANES, (8, LANES), phit.shape],
+        [jax.ShapeDtypeStruct(x.shape, x.dtype),
+         jax.ShapeDtypeStruct((rows, LANES), _F32)], block, interpret)(
+             x, dxc, dh, z, dzr, prm, phit)
+
+
+@kernel_entry("ptpu_mhc_post_fwd",
+              static_argnames=("n", "block", "interpret"))
+def _post_fwd_call(x, y, coef, *, n, block, interpret):
+    rows, c = y.shape
+    return _call(
+        functools.partial(_post_fwd_kernel, n=n, c=c), "ptpu_mhc_post_fwd",
+        rows, [n * c, c, LANES], [jax.ShapeDtypeStruct(x.shape, x.dtype)],
+        block, interpret)(x, y, coef)[0]
+
+
+@kernel_entry("ptpu_mhc_post_bwd",
+              static_argnames=("n", "block", "interpret"))
+def _post_bwd_call(d, x, y, coef, *, n, block, interpret):
+    rows, c = y.shape
+    return _call(
+        functools.partial(_post_bwd_kernel, n=n, c=c), "ptpu_mhc_post_bwd",
+        rows, [n * c, n * c, c, LANES],
+        [jax.ShapeDtypeStruct(x.shape, x.dtype),
+         jax.ShapeDtypeStruct(y.shape, y.dtype),
+         jax.ShapeDtypeStruct((rows, LANES), _F32)], block, interpret)(
+             d, x, y, coef)
+
+
+@kernel_entry("ptpu_mhc_expand", static_argnames=("n", "block", "interpret"))
+def _expand_call(x, *, n, block, interpret):
+    rows, c = x.shape
+    return _call(functools.partial(_expand_kernel, n=n, c=c),
+                 "ptpu_mhc_expand", rows, [c],
+                 [jax.ShapeDtypeStruct((rows, n * c), x.dtype)], block,
+                 interpret)(x)[0]
+
+
+@kernel_entry("ptpu_mhc_reduce", static_argnames=("n", "block", "interpret"))
+def _reduce_call(x, *, n, block, interpret):
+    rows, c = x.shape[0], x.shape[1] // n
+    return _call(functools.partial(_reduce_kernel, n=n, c=c),
+                 "ptpu_mhc_reduce", rows, [n * c],
+                 [jax.ShapeDtypeStruct((rows, c), x.dtype)], block,
+                 interpret)(x)[0]
+
+
+def _how(x, n, interpret):
+    """The stream calls' static arguments for a stream x [rows, ..]."""
+    return dict(n=n, block=_block_rows(x.shape[0]), interpret=interpret)
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
 def _pre(x, phi, alpha, bias, n, iters, eps, clamp, interpret):
     return _pre_fwd(x, phi, alpha, bias, n, iters, eps, clamp, interpret)[0]
 
 
 def _pre_fwd(x, phi, alpha, bias, n, iters, eps, clamp, interpret):
-    rows, c = x.shape[0], x.shape[1] // n
     lanes, split = _phi_lanes(phi, x.dtype)
-    h, z = _call(
-        functools.partial(_pre_fwd_kernel, n=n, c=c, eps=eps, split=split),
-        "ptpu_mhc_pre_fwd", rows, [n * c, lanes.shape, (8, LANES)],
-        [jax.ShapeDtypeStruct((rows, c), x.dtype),
-         jax.ShapeDtypeStruct((rows, LANES), _F32)], interpret)(
-             x, lanes, _prm(alpha, bias, n))
+    h, z = _pre_fwd_call(x, lanes, _prm(alpha, bias, n), eps=eps,
+                         split=split, **_how(x, n, interpret))
     coef = coefficients(z, alpha, bias, n, iters, eps, clamp, interpret)
     return (h, coef, x), (x, z, phi, alpha, bias)
 
@@ -531,17 +597,13 @@ def _pre_fwd(x, phi, alpha, bias, n, iters, eps, clamp, interpret):
 def _pre_bwd(n, iters, eps, clamp, interpret, res, cts):
     x, z, phi, alpha, bias = res
     dh, dcoef, dxc = cts
-    rows, c, k = x.shape[0], x.shape[1] // n, columns(n)
+    k = columns(n)
     dzr, dalpha, dbias = coefficients_bwd(z, alpha, bias, dcoef, n, iters,
                                           eps, clamp, interpret)
     phit = jnp.pad(phi.astype(_F32).T.astype(x.dtype),
                    [(0, LANES - k), (0, 0)])
-    dx, g = _call(
-        functools.partial(_pre_bwd_kernel, n=n, c=c), "ptpu_mhc_pre_bwd",
-        rows, [n * c, n * c, c, LANES, LANES, (8, LANES), phit.shape],
-        [jax.ShapeDtypeStruct(x.shape, x.dtype),
-         jax.ShapeDtypeStruct((rows, LANES), _F32)], interpret)(
-             x, dxc, dh, z, dzr, _prm(alpha, bias, n), phit)
+    dx, g = _pre_bwd_call(x, dxc, dh, z, dzr, _prm(alpha, bias, n), phit,
+                          **_how(x, n, interpret))
     dz, dht = g[:, :k], g[:, k + 1:k + 1 + n]
     r = z[:, k:k + 1]
     dphi = jnp.dot(x.T, (dz * r).astype(x.dtype),
@@ -560,24 +622,12 @@ def _post(x, y, coef, n, interpret):
 
 
 def _post_fwd(x, y, coef, n, interpret):
-    rows, c = y.shape
-    out, = _call(
-        functools.partial(_post_fwd_kernel, n=n, c=c), "ptpu_mhc_post_fwd",
-        rows, [n * c, c, LANES], [jax.ShapeDtypeStruct(x.shape, x.dtype)],
-        interpret)(x, y, coef)
-    return out, (x, y, coef)
+    return _post_fwd_call(x, y, coef, **_how(x, n, interpret)), (x, y, coef)
 
 
 def _post_bwd(n, interpret, res, d):
     x, y, coef = res
-    rows, c = y.shape
-    return tuple(_call(
-        functools.partial(_post_bwd_kernel, n=n, c=c), "ptpu_mhc_post_bwd",
-        rows, [n * c, n * c, c, LANES],
-        [jax.ShapeDtypeStruct(x.shape, x.dtype),
-         jax.ShapeDtypeStruct(y.shape, y.dtype),
-         jax.ShapeDtypeStruct((rows, LANES), _F32)], interpret)(
-             d, x, y, coef))
+    return tuple(_post_bwd_call(d, x, y, coef, **_how(x, n, interpret)))
 
 
 _post.defvjp(_post_fwd, _post_bwd)
@@ -585,19 +635,12 @@ _post.defvjp(_post_fwd, _post_bwd)
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2))
 def _expand(x, n, interpret):
-    rows, c = x.shape
-    return _call(functools.partial(_expand_kernel, n=n, c=c),
-                 "ptpu_mhc_expand", rows, [c],
-                 [jax.ShapeDtypeStruct((rows, n * c), x.dtype)],
-                 interpret)(x)[0]
+    return _expand_call(x, **_how(x, n, interpret))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2))
 def _reduce(x, n, interpret):
-    rows, c = x.shape[0], x.shape[1] // n
-    return _call(functools.partial(_reduce_kernel, n=n, c=c),
-                 "ptpu_mhc_reduce", rows, [n * c],
-                 [jax.ShapeDtypeStruct((rows, c), x.dtype)], interpret)(x)[0]
+    return _reduce_call(x, **_how(x, n, interpret))
 
 
 _expand.defvjp(lambda x, n, interpret: (_expand(x, n, interpret), None),

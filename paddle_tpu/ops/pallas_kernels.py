@@ -37,8 +37,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax import lax
-from .pallas_import import pl
-from .pallas_import import pltpu
+from .pallas_import import kernel_entry, pl, pltpu
 
 from .kernel_config import DEFAULT_TILES, dispatch_platform
 
@@ -160,9 +159,10 @@ def heads_a_block(hq, hkv, d):
 # What these helpers and the index maps add to a kernel is written with lax
 # primitives, not jnp functions or operators on tracers: each of those is a
 # call of a jitted function, half a millisecond of a step's trace on the
-# chip's host, and a kernel's body and index maps are traced anew at every
-# call site (2,000 such calls more were 2 s of `jaxpr_trace_s` at T=2048,
-# 9 % of `setup_s`; my chip run, PR 38).
+# chip's host (2,000 such calls more were 2 s of `jaxpr_trace_s` at T=2048,
+# 9 % of `setup_s`, while a kernel's body and index maps were traced anew at
+# every call site; my chip run, PR 38. Since PR 60 they are traced once a
+# shape: ops/pallas_import.py `kernel_entry`).
 
 def _head_lanes(w, d, hb):
     """[1, W] mask of the D lanes of the head this grid step works on, of
@@ -374,32 +374,31 @@ def _rope_rows(rope, d, hb, pad):
         (dr, hr)
 
 
-def _flash_fwd(q, k, v, kv_len, d, hb, scale, causal, window, block_q,
-               block_k, interpret, rope=None):
-    """q: [Bq, T, Hq*D]; k, v: [Bk, T, Hkv*D], heads of D lanes side by
-    side in a row; kv_len: [Bq] int32 (true key length per query row); hb
-    query heads a lane block (`heads_a_block`) -> (out [Bq, T, Hq*D], lse
-    [Bq*Hq, nq, 1, block_q] over the padded T). The grid is (row, block of
-    heads, q block, head in its block); a head's blocks are W = hb * D
-    lanes wide at lane block `head // hb`, and K/V rows and heads may be
-    fewer than the queries' (`_kv_row`, both). The pallas_call takes the
-    arrays as [Bq * T, Hq*D], tokens by features, the shape the projections
-    around the op give and take: with [B, T, H*D] operands XLA laid the
-    neighbouring matmuls' operands out tokens-minor, and in the OLMoE cell,
-    at its memory limit, scheduled and rematerialised its way to a step 15
-    ms slower (my chip run and AOT compile, PR 38). Beside the blocks a grid
-    step has two float32 scratch arrays, the kernel's own state across its k
-    loop: the row sums as lane partials [block_q, 128] and the output's
-    accumulator [block_q, W], 0.25 MiB each at 512 rows and W=128."""
-    rows, t, hd = q.shape
-    heads, w = hd // d, hb * d
-    kv_b, kv_h = _kv_row(rows, k.shape[0]), _kv_row(heads, k.shape[2] // d)
-    t_pad = _pad_t(t, block_q, block_k)
-    pad = [(0, 0), (0, t_pad - t), (0, 0)] if t_pad != t else None
-    if pad:
-        q, k, v = (jnp.pad(a, pad) for a in (q, k, v))
+# What the three flash entries are built under, beside their arrays: static
+# arguments of their jits. `rows`: the query rows (the arrays come as [rows *
+# t_pad, lanes]); `rope`: None, or the latent form's (dr, hr) of `_rope_rows`.
+_FLASH_STATIC = ("rows", "d", "hb", "scale", "causal", "window", "block_q",
+                 "block_k", "rope", "interpret")
+
+
+def _flash_static(q, k, rows, d, hb):
+    """(t_pad, heads, W, the K/V row of a query row, the K/V head of a
+    query head) of a flash entry's [rows * t_pad, H*D] operands."""
+    t_pad = q.shape[0] // rows
+    return t_pad, q.shape[1] // d, hb * d, \
+        _kv_row(rows, k.shape[0] // t_pad), \
+        _kv_row(q.shape[1] // d, k.shape[1] // d)
+
+
+@kernel_entry("ptpu_flash_fwd", static_argnames=_FLASH_STATIC)
+def _flash_fwd_call(q, k, v, rope_args, lens, *, rows, d, hb, scale, causal,
+                    window, block_q, block_k, rope, interpret):
+    """The forward pallas_call on `_flash_fwd`'s operands as it lays them
+    out: q [rows * t_pad, Hq*D], k, v [rows_kv * t_pad, Hkv*D], rope_args
+    () or `_rope_rows`'s two, lens [rows, 1] int32 -> (out as q, lse)."""
+    hd = q.shape[1]
+    t_pad, heads, w, kv_b, kv_h = _flash_static(q, k, rows, d, hb)
     nq = t_pad // block_q
-    q, k, v = (a.reshape(-1, a.shape[2]) for a in (q, k, v))
 
     def q_block(b, p, i, hh):
         return _lin((b, nq), (i, 1)), p
@@ -409,10 +408,10 @@ def _flash_fwd(q, k, v, kv_len, d, hb, scale, causal, window, block_q,
 
     static = dict(scale=scale, causal=causal, window=window,
                   block_q=block_q, block_k=block_k, t_pad=t_pad, d=d, hb=hb)
-    rope_args, rope_specs = (), []
+    rope_specs = []
     if rope is not None:
-        rope_args, static["rope"] = _rope_rows(rope, d, hb, pad)
-        hr = static["rope"][1]
+        static["rope"] = rope
+        hr = rope[1]
         wr = rope_args[1].shape[1]
         rope_specs = [
             _vmem_spec((block_q, wr), lambda b, p, i, hh: (
@@ -422,7 +421,7 @@ def _flash_fwd(q, k, v, kv_len, d, hb, scale, causal, window, block_q,
     # lens: whole array in SMEM (no blocking); lse: a row of block_q lanes
     # a (head, q block): Mosaic requires the last two block dims divisible
     # by (8, 128) or equal to the array's, and (1, block_q) is the array's
-    out, lse = pl.pallas_call(
+    return pl.pallas_call(
         kernel,
         grid=(rows, heads // hb, nq, hb),
         in_specs=[
@@ -446,7 +445,42 @@ def _flash_fwd(q, k, v, kv_len, d, hb, scale, causal, window, block_q,
             pltpu.VMEM((block_q, w), jnp.float32)],
         interpret=interpret,
         name="ptpu_flash_fwd",
-    )(q, k, v, *rope_args, kv_len.reshape(rows, 1).astype(jnp.int32))
+    )(q, k, v, *rope_args, lens)
+
+
+def _flash_fwd(q, k, v, kv_len, d, hb, scale, causal, window, block_q,
+               block_k, interpret, rope=None):
+    """q: [Bq, T, Hq*D]; k, v: [Bk, T, Hkv*D], heads of D lanes side by
+    side in a row; kv_len: [Bq] int32 (true key length per query row); hb
+    query heads a lane block (`heads_a_block`) -> (out [Bq, T, Hq*D], lse
+    [Bq*Hq, nq, 1, block_q] over the padded T). The grid is (row, block of
+    heads, q block, head in its block); a head's blocks are W = hb * D
+    lanes wide at lane block `head // hb`, and K/V rows and heads may be
+    fewer than the queries' (`_kv_row`, both). The pallas_call takes the
+    arrays as [Bq * T, Hq*D], tokens by features, the shape the projections
+    around the op give and take: with [B, T, H*D] operands XLA laid the
+    neighbouring matmuls' operands out tokens-minor, and in the OLMoE cell,
+    at its memory limit, scheduled and rematerialised its way to a step 15
+    ms slower (my chip run and AOT compile, PR 38). Beside the blocks a grid
+    step has two float32 scratch arrays, the kernel's own state across its k
+    loop: the row sums as lane partials [block_q, 128] and the output's
+    accumulator [block_q, W], 0.25 MiB each at 512 rows and W=128. The
+    call itself is `_flash_fwd_call`, traced once a shape; the pad, the
+    reshapes and the latent form's repeat stay here, under the op's scope."""
+    rows, t, hd = q.shape
+    t_pad = _pad_t(t, block_q, block_k)
+    pad = [(0, 0), (0, t_pad - t), (0, 0)] if t_pad != t else None
+    if pad:
+        q, k, v = (jnp.pad(a, pad) for a in (q, k, v))
+    q, k, v = (a.reshape(-1, a.shape[2]) for a in (q, k, v))
+    rope_args, rope_static = (), None
+    if rope is not None:
+        rope_args, rope_static = _rope_rows(rope, d, hb, pad)
+    out, lse = _flash_fwd_call(
+        q, k, v, rope_args, kv_len.reshape(rows, 1).astype(jnp.int32),
+        rows=rows, d=d, hb=hb, scale=scale, causal=causal, window=window,
+        block_q=block_q, block_k=block_k, rope=rope_static,
+        interpret=interpret)
     out = out.reshape(rows, t_pad, hd)
     return (out if t_pad == t else out[:, :t]), lse
 
@@ -606,6 +640,140 @@ def _flash_bwd_dq_kernel(q_ref, g_ref, o_ref, k_ref, v_ref, lse_ref,
     _put(lanes, dq_ref, (dq * scale).astype(dq_ref.dtype))
 
 
+def _rope_member(hr):
+    """A head `p` of the latent form as (its place among the hr heads of
+    its block of rotary lanes, that block)."""
+    return lambda p: (lax.rem(p, np.int32(hr)), lax.div(p, np.int32(hr)))
+
+
+@kernel_entry("ptpu_flash_bwd_dq", static_argnames=_FLASH_STATIC)
+def _flash_bwd_dq_call(q, g, out, k, v, lse, rope_args, lens, *, rows, d, hb,
+                       scale, causal, window, block_q, block_k, rope,
+                       interpret):
+    """The dQ pallas_call on `_flash_bwd`'s operands as it lays them out
+    (`_flash_fwd_call`'s, dO and O as q) -> (dq as q, delta as lse, and
+    under the latent form the rotary queries' gradient in hr slabs)."""
+    hd = q.shape[1]
+    t_pad, heads, w, kv_b, kv_h = _flash_static(q, k, rows, d, hb)
+    nq = t_pad // block_q
+    static = dict(scale=scale, causal=causal, window=window,
+                  block_q=block_q, block_k=block_k, t_pad=t_pad, d=d, hb=hb)
+    rope_specs, rope_out, rope_shape = [], [], []
+    if rope is not None:
+        static["rope"] = rope
+        hr = rope[1]
+        member = _rope_member(hr)
+        wq, wr = (a.shape[1] for a in rope_args)
+        rope_specs = [
+            _vmem_spec((block_q, wr), lambda b, p, i, hh: (
+                _lin((b, nq), (i, 1)), member(p)[1])),
+            _vmem_spec((t_pad, wr), lambda b, p, i, hh: (kv_b(b), 0))]
+        rope_out = [_vmem_spec((block_q, wr), lambda b, p, i, hh: (
+            _lin((member(p)[0], rows * nq), (b, nq), (i, 1)), member(p)[1]))]
+        rope_shape = [jax.ShapeDtypeStruct((hr * rows * t_pad, wq), q.dtype)]
+
+    def stat_block(b, p, i, hh):     # a head's (1, block_q) of q block i
+        return _lin((b, heads), (p, hb), (hh, 1)), i, 0, 0
+
+    def q_block(b, p, i, hh):
+        return _lin((b, nq), (i, 1)), p
+
+    def kv_pair(b, p, i, hh):        # the whole K or V of the head's row
+        return kv_b(b), kv_h(p)
+
+    return pl.pallas_call(
+        functools.partial(_flash_bwd_dq_kernel, **static),
+        grid=(rows, heads // hb, nq, hb),
+        in_specs=[
+            _vmem_spec((block_q, w), q_block),                         # q
+            _vmem_spec((block_q, w), q_block),                         # g
+            _vmem_spec((block_q, w), q_block),                         # o
+            _vmem_spec((t_pad, w), kv_pair),                           # k
+            _vmem_spec((t_pad, w), kv_pair),                           # v
+            _vmem_spec((1, 1, 1, block_q), stat_block),                # lse
+        ] + rope_specs + [_SMEM_WHOLE],
+        out_specs=[
+            _vmem_spec((block_q, w), q_block),
+            _vmem_spec((1, 1, 1, block_q), stat_block),              # delta
+        ] + rope_out,
+        out_shape=[jax.ShapeDtypeStruct((rows * t_pad, hd), q.dtype),
+                   jax.ShapeDtypeStruct(lse.shape, jnp.float32)]
+        + rope_shape,
+        interpret=interpret,
+        name="ptpu_flash_bwd_dq",
+    )(q, g, out, k, v, lse, *rope_args, lens)
+
+
+@kernel_entry("ptpu_flash_bwd_dkdv", static_argnames=_FLASH_STATIC)
+def _flash_bwd_dkdv_call(q, g, k, v, lse, delta, rope_args, lens, *, rows, d,
+                         hb, scale, causal, window, block_q, block_k, rope,
+                         interpret):
+    """The dK/dV pallas_call on `_flash_bwd`'s operands as it lays them
+    out -> (dk, dv [rows * group * t_pad, Hkv*D], a query head's share in
+    the slab of its place in its group, float32 where there is a group,
+    and under the latent form every head's float32 share of the shared
+    rotary key's gradient)."""
+    t_pad, heads, w, kv_b, kv_h = _flash_static(q, k, rows, d, hb)
+    heads_kv = k.shape[1] // d
+    nq, nk = t_pad // block_q, t_pad // block_k
+    # grouped queries: the kernel runs a query head at a time, as it does
+    # ungrouped, and gives that head's float32 share of its K/V head's
+    # gradient; the group's shares are summed after it (`_flash_bwd` says
+    # why after and not inside)
+    grouped = q.shape != k.shape
+    static = dict(scale=scale, causal=causal, window=window,
+                  block_q=block_q, block_k=block_k, t_pad=t_pad, d=d, hb=hb)
+    rope_specs, rope_out, rope_shape = [], [], []
+    if rope is not None:
+        static["rope"] = rope
+        member = _rope_member(rope[1])
+        wr = rope_args[1].shape[1]
+        rope_specs = [
+            _vmem_spec((t_pad, wr), lambda b, p, j, hh: (b, member(p)[1])),
+            _vmem_spec((block_k, wr), lambda b, p, j, hh: (
+                _lin((kv_b(b), nk), (j, 1)), 0))]
+        rope_out = [_vmem_spec((block_k, wr), lambda b, p, j, hh: (
+            _lin((b, heads * nk), (p, nk), (j, 1)), 0))]
+        rope_shape = [jax.ShapeDtypeStruct((rows * heads * t_pad, wr),
+                                           jnp.float32)]
+
+    def stat_row(b, p, j, hh):       # a head's whole [nq, 1, block_q]
+        return _lin((b, heads), (p, hb), (hh, 1)), 0, 0, 0
+
+    # a head's dK and dV blocks lie where its K and V blocks do, in the slab
+    # of its place in its group: [rows * group * T, Hkv*D], which is [rows *
+    # T, Hq*D] where there is no group, and a sum over slabs where there is
+    group = heads // heads_kv
+
+    def k_block(b, p, j, hh):
+        return _lin((kv_b(b), nk), (j, 1)), kv_h(p)
+
+    def share(b, p, j, hh):
+        if group == 1:
+            return _lin((b, nk), (j, 1)), p
+        member = lax.rem(p, np.int32(group))
+        return _lin((b, group * nk), (member, nk), (j, 1)), kv_h(p)
+
+    return pl.pallas_call(
+        functools.partial(_flash_bwd_dkdv_kernel, **static),
+        grid=(rows, heads // hb, nk, hb),
+        in_specs=[
+            _vmem_spec((t_pad, w), lambda b, p, j, hh: (b, p)),         # q
+            _vmem_spec((t_pad, w), lambda b, p, j, hh: (b, p)),         # g
+            _vmem_spec((block_k, w), k_block),                         # k
+            _vmem_spec((block_k, w), k_block),                         # v
+            _vmem_spec((1, nq, 1, block_q), stat_row),                # lse
+            _vmem_spec((1, nq, 1, block_q), stat_row),              # delta
+        ] + rope_specs + [_SMEM_WHOLE],
+        out_specs=[_vmem_spec((block_k, w), share)] * 2 + rope_out,
+        out_shape=[jax.ShapeDtypeStruct(
+            (rows * group * t_pad, heads_kv * d),
+            jnp.float32 if grouped else k.dtype)] * 2 + rope_shape,
+        interpret=interpret,
+        name="ptpu_flash_bwd_dkdv",
+    )(q, g, k, v, lse, delta, *rope_args, lens)
+
+
 def _flash_bwd(d, hb, scale, causal, window, block_q, block_k, interpret,
                res, g, rope=None):
     """Flash backward as two pallas kernels (standard flash-attention recompute
@@ -691,131 +859,40 @@ def _flash_bwd(d, hb, scale, causal, window, block_q, block_k, interpret,
     4096, 32 x 64] bf16). The shared key's gradient: every head writes a
     float32 share [T, 128], summed after the kernel as a group's shares
     are, and the hr copies the key was repeated to fold back into one.
-    Returns (dq, dk, dv, dq_rope, dk_rope) then."""
+    Returns (dq, dk, dv, dq_rope, dk_rope) then.
+
+    The two calls are `_flash_bwd_dq_call` and `_flash_bwd_dkdv_call`,
+    traced once a shape; the pad, the reshapes, the latent form's repeat
+    and the sums after the kernels stay here, under the op's scope."""
     q, k, v, kv_len, out, lse = res
     rows, t, hd = q.shape
-    rows_kv, heads, heads_kv = k.shape[0], hd // d, k.shape[2] // d
-    w = hb * d
-    kv_b, kv_h = _kv_row(rows, rows_kv), _kv_row(heads, heads_kv)
+    rows_kv, heads_kv = k.shape[0], k.shape[2] // d
     t_pad = _pad_t(t, block_q, block_k)
     pad = [(0, 0), (0, t_pad - t), (0, 0)] if t_pad != t else None
     if pad:
         q, k, v, g, out = (jnp.pad(a, pad) for a in (q, k, v, g, out))
-    nq, nk = t_pad // block_q, t_pad // block_k
     q, k, v, g, out = (a.reshape(-1, a.shape[2]) for a in (q, k, v, g, out))
     lens = kv_len.reshape(rows, 1).astype(jnp.int32)
-    grouped = rows * heads != rows_kv * heads_kv
-    # grouped queries: the dK/dV kernel runs a query head at a time, as it
-    # does ungrouped, and gives that head's float32 share of its K/V head's
-    # gradient; the group's shares are summed after it (see the docstring
-    # for why after and not inside)
-    dkv_dtype = jnp.float32 if grouped else k.dtype
-    static = dict(scale=scale, causal=causal, window=window,
-                  block_q=block_q, block_k=block_k, t_pad=t_pad, d=d, hb=hb)
-    rope_args = ()
-    dq_rope_specs, dq_rope_out, dq_rope_shape = [], [], []
-    dkv_rope_specs, dkv_rope_out, dkv_rope_shape = [], [], []
+    rope_args, rope_static = (), None
     if rope is not None:
-        rope_args, static["rope"] = _rope_rows(rope, d, hb, pad)
-        hr = static["rope"][1]
-        wq, wr = (a.shape[1] for a in rope_args)
-
-        def member(p):
-            return lax.rem(p, np.int32(hr)), lax.div(p, np.int32(hr))
-
-        dq_rope_specs = [
-            _vmem_spec((block_q, wr), lambda b, p, i, hh: (
-                _lin((b, nq), (i, 1)), member(p)[1])),
-            _vmem_spec((t_pad, wr), lambda b, p, i, hh: (kv_b(b), 0))]
-        dq_rope_out = [_vmem_spec((block_q, wr), lambda b, p, i, hh: (
-            _lin((member(p)[0], rows * nq), (b, nq), (i, 1)), member(p)[1]))]
-        dq_rope_shape = [jax.ShapeDtypeStruct((hr * rows * t_pad, wq),
-                                              q.dtype)]
-        dkv_rope_specs = [
-            _vmem_spec((t_pad, wr), lambda b, p, j, hh: (b, member(p)[1])),
-            _vmem_spec((block_k, wr), lambda b, p, j, hh: (
-                _lin((kv_b(b), nk), (j, 1)), 0))]
-        dkv_rope_out = [_vmem_spec((block_k, wr), lambda b, p, j, hh: (
-            _lin((b, heads * nk), (p, nk), (j, 1)), 0))]
-        dkv_rope_shape = [jax.ShapeDtypeStruct((rows * heads * t_pad, wr),
-                                               jnp.float32)]
-
-    def stat_block(b, p, i, hh):     # a head's (1, block_q) of q block i
-        return _lin((b, heads), (p, hb), (hh, 1)), i, 0, 0
-
-    def q_block(b, p, i, hh):
-        return _lin((b, nq), (i, 1)), p
-
-    def kv_pair(b, p, i, hh):        # the whole K or V of the head's row
-        return kv_b(b), kv_h(p)
-
-    dq, delta, *dq_rope = pl.pallas_call(
-        functools.partial(_flash_bwd_dq_kernel, **static),
-        grid=(rows, heads // hb, nq, hb),
-        in_specs=[
-            _vmem_spec((block_q, w), q_block),                         # q
-            _vmem_spec((block_q, w), q_block),                         # g
-            _vmem_spec((block_q, w), q_block),                         # o
-            _vmem_spec((t_pad, w), kv_pair),                           # k
-            _vmem_spec((t_pad, w), kv_pair),                           # v
-            _vmem_spec((1, 1, 1, block_q), stat_block),                # lse
-        ] + dq_rope_specs + [_SMEM_WHOLE],
-        out_specs=[
-            _vmem_spec((block_q, w), q_block),
-            _vmem_spec((1, 1, 1, block_q), stat_block),              # delta
-        ] + dq_rope_out,
-        out_shape=[jax.ShapeDtypeStruct((rows * t_pad, hd), q.dtype),
-                   jax.ShapeDtypeStruct(lse.shape, jnp.float32)]
-        + dq_rope_shape,
-        interpret=interpret,
-        name="ptpu_flash_bwd_dq",
-    )(q, g, out, k, v, lse, *rope_args, lens)
-
-    def stat_row(b, p, j, hh):       # a head's whole [nq, 1, block_q]
-        return _lin((b, heads), (p, hb), (hh, 1)), 0, 0, 0
-
-    # a head's dK and dV blocks lie where its K and V blocks do, in the slab
-    # of its place in its group: [rows * group * T, Hkv*D], which is [rows *
-    # T, Hq*D] where there is no group, and a sum over slabs where there is
-    group = heads // heads_kv
-
-    def k_block(b, p, j, hh):
-        return _lin((kv_b(b), nk), (j, 1)), kv_h(p)
-
-    def share(b, p, j, hh):
-        if group == 1:
-            return _lin((b, nk), (j, 1)), p
-        member = lax.rem(p, np.int32(group))
-        return _lin((b, group * nk), (member, nk), (j, 1)), kv_h(p)
-
-    dk, dv, *dk_rope = pl.pallas_call(
-        functools.partial(_flash_bwd_dkdv_kernel, **static),
-        grid=(rows, heads // hb, t_pad // block_k, hb),
-        in_specs=[
-            _vmem_spec((t_pad, w), lambda b, p, j, hh: (b, p)),         # q
-            _vmem_spec((t_pad, w), lambda b, p, j, hh: (b, p)),         # g
-            _vmem_spec((block_k, w), k_block),                         # k
-            _vmem_spec((block_k, w), k_block),                         # v
-            _vmem_spec((1, nq, 1, block_q), stat_row),                # lse
-            _vmem_spec((1, nq, 1, block_q), stat_row),              # delta
-        ] + dkv_rope_specs + [_SMEM_WHOLE],
-        out_specs=[_vmem_spec((block_k, w), share)] * 2 + dkv_rope_out,
-        out_shape=[jax.ShapeDtypeStruct(
-            (rows * group * t_pad, heads_kv * d), dkv_dtype)] * 2
-        + dkv_rope_shape,
-        interpret=interpret,
-        name="ptpu_flash_bwd_dkdv",
-    )(q, g, k, v, lse, delta, *rope_args, lens)
+        rope_args, rope_static = _rope_rows(rope, d, hb, pad)
+    static = dict(rows=rows, d=d, hb=hb, scale=scale, causal=causal,
+                  window=window, block_q=block_q, block_k=block_k,
+                  rope=rope_static, interpret=interpret)
+    dq, delta, *dq_rope = _flash_bwd_dq_call(
+        q, g, out, k, v, lse, rope_args, lens, **static)
+    dk, dv, *dk_rope = _flash_bwd_dkdv_call(
+        q, g, k, v, lse, delta, rope_args, lens, **static)
     dq = dq.reshape(rows, t_pad, hd)
-    if grouped:
+    if q.shape != k.shape:              # grouped: the shares' sum
         dk, dv = (a.reshape(rows_kv, -1, t_pad, heads_kv * d).sum(1)
                   .astype(k.dtype) for a in (dk, dv))
     else:
         dk, dv = (a.reshape(rows_kv, t_pad, -1) for a in (dk, dv))
     grads = (dq, dk, dv)
     if rope is not None:
-        dr = static["rope"][0]
-        dqr = dq_rope[0].reshape(hr, rows, t_pad, wq).sum(0)
+        dr, hr = rope_static
+        dqr = dq_rope[0].reshape(hr, rows, t_pad, -1).sum(0)
         dkr = dk_rope[0].reshape(rows_kv, -1, t_pad, hr, dr).sum((1, 3))
         grads += (dqr, dkr.astype(rope[1].dtype))
     if t_pad != t:
@@ -1095,12 +1172,15 @@ def _xent_params(block_n, v, itemsize):
         vmem_limit_bytes=int(min(max(need, 16 << 20), 100 << 20)))
 
 
-def _xent_fwd_call(logits, labels, block_n, interpret):
+@kernel_entry("ptpu_softmax_xent_fwd",
+              static_argnames=("block_n", "interpret"))
+def _xent_call(logits, labels, *, block_n, interpret):
+    """(loss, lse) [N, 1] of logits [N, V] and labels [N, 1] int32, block_n
+    rows a grid step."""
     # no pad: the last block of a grid that does not divide N reads rows
     # past the end (unspecified values, each row's own) and its writes there
     # are dropped
     n, v = logits.shape
-    block_n = _xent_rows(logits, block_n)
     row = lambda i: (i, 0)
     return pl.pallas_call(
         _xent_kernel,
@@ -1120,7 +1200,13 @@ def _xent_fwd_call(logits, labels, block_n, interpret):
         compiler_params=_xent_params(block_n, v, logits.dtype.itemsize),
         interpret=interpret,
         name="ptpu_softmax_xent_fwd",
-    )(logits, labels.reshape(-1, 1).astype(jnp.int32))
+    )(logits, labels)
+
+
+def _xent_fwd_call(logits, labels, block_n, interpret):
+    return _xent_call(logits, labels.reshape(-1, 1).astype(jnp.int32),
+                      block_n=_xent_rows(logits, block_n),
+                      interpret=interpret)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
@@ -1197,14 +1283,13 @@ def _ln_block_rows(n, d, dtype, tile_bytes):
     return rows
 
 
-def _ln_fwd_call(x, scale, bias, eps, block_n, interpret):
-    n, d = x.shape
-    if block_n is None:
-        block_n = _ln_block_rows(n, d, x.dtype,
-                                 DEFAULT_TILES["ln"]["tile_bytes"])
-    n_pad = int(-(-n // block_n) * block_n)
-    xp = jnp.pad(x, [(0, n_pad - n), (0, 0)]) if n_pad != n else x
-    y, mean, rstd = pl.pallas_call(
+@kernel_entry("ptpu_layer_norm_fwd",
+              static_argnames=("eps", "block_n", "interpret"))
+def _ln_call(x, scale, bias, *, eps, block_n, interpret):
+    """(y, mean [N, 1], rstd [N, 1]) of x [N, D], N whole blocks of block_n
+    rows, under scale and bias [1, D]."""
+    n_pad, d = x.shape
+    return pl.pallas_call(
         functools.partial(_ln_kernel, eps=eps),
         grid=(n_pad // block_n,),
         in_specs=[
@@ -1224,7 +1309,18 @@ def _ln_fwd_call(x, scale, bias, eps, block_n, interpret):
         ],
         interpret=interpret,
         name="ptpu_layer_norm_fwd",
-    )(xp, scale.reshape(1, d), bias.reshape(1, d))
+    )(x, scale, bias)
+
+
+def _ln_fwd_call(x, scale, bias, eps, block_n, interpret):
+    n, d = x.shape
+    if block_n is None:
+        block_n = _ln_block_rows(n, d, x.dtype,
+                                 DEFAULT_TILES["ln"]["tile_bytes"])
+    n_pad = int(-(-n // block_n) * block_n)
+    xp = jnp.pad(x, [(0, n_pad - n), (0, 0)]) if n_pad != n else x
+    y, mean, rstd = _ln_call(xp, scale.reshape(1, d), bias.reshape(1, d),
+                             eps=eps, block_n=block_n, interpret=interpret)
     return y[:n], mean[:n], rstd[:n]
 
 
@@ -1315,18 +1411,12 @@ def _lstm_seq_kernel(x_ref, m_ref, w_ref, b_ref, h0_ref, c0_ref,
     c_out[0] = c.astype(c_out.dtype)
 
 
-def _lstm_fwd_call(xs, ms, w, b, h0, c0, block_b, interpret):
-    """xs [T, B, 4D] f32, ms [T, B, 1], w [D, 4D], b [4D], h0/c0 [B, D]
-    -> (hs, cs) [T, B, D]."""
-    t, bsz, four_d = xs.shape
+@kernel_entry("ptpu_lstm_seq", static_argnames=("blk", "interpret"))
+def _lstm_call(xs, ms, w, b, h0, c0, *, blk, interpret):
+    """`_lstm_fwd_call` on a batch of whole blocks of blk rows, b [1, 4D]."""
+    t, b_pad, four_d = xs.shape
     d = four_d // 4
-    blk, b_pad = _resolve_block_b(bsz, block_b)
-    if b_pad != bsz:
-        xs = jnp.pad(xs, [(0, 0), (0, b_pad - bsz), (0, 0)])
-        ms = jnp.pad(ms, [(0, 0), (0, b_pad - bsz), (0, 0)])
-        h0 = _pad_rows(h0, b_pad)
-        c0 = _pad_rows(c0, b_pad)
-    hs, cs = pl.pallas_call(
+    return pl.pallas_call(
         functools.partial(_lstm_seq_kernel, d=d),
         # batch blocks on the MAJOR grid axis: each block walks its
         # full time loop before the next block reuses the state scratch
@@ -1353,7 +1443,21 @@ def _lstm_fwd_call(xs, ms, w, b, h0, c0, block_b, interpret):
         ],
         interpret=interpret,
         name="ptpu_lstm_seq",
-    )(xs, ms, w, b.reshape(1, -1), h0, c0)
+    )(xs, ms, w, b, h0, c0)
+
+
+def _lstm_fwd_call(xs, ms, w, b, h0, c0, block_b, interpret):
+    """xs [T, B, 4D] f32, ms [T, B, 1], w [D, 4D], b [4D], h0/c0 [B, D]
+    -> (hs, cs) [T, B, D]."""
+    bsz = xs.shape[1]
+    blk, b_pad = _resolve_block_b(bsz, block_b)
+    if b_pad != bsz:
+        xs = jnp.pad(xs, [(0, 0), (0, b_pad - bsz), (0, 0)])
+        ms = jnp.pad(ms, [(0, 0), (0, b_pad - bsz), (0, 0)])
+        h0 = _pad_rows(h0, b_pad)
+        c0 = _pad_rows(c0, b_pad)
+    hs, cs = _lstm_call(xs, ms, w, b.reshape(1, -1), h0, c0, blk=blk,
+                        interpret=interpret)
     return hs[:, :bsz], cs[:, :bsz]
 
 
@@ -1484,17 +1588,14 @@ def _lstmp_seq_kernel(x_ref, m_ref, w_ref, wp_ref, b_ref, r0_ref, c0_ref,
     c_out[0] = c.astype(c_out.dtype)
 
 
-def _lstmp_fwd_call(xs, ms, w, w_proj, b, r0, c0, block_b, interpret):
-    t, bsz, four_d = xs.shape
+@kernel_entry("ptpu_lstmp_seq", static_argnames=("blk", "interpret"))
+def _lstmp_call(xs, ms, w, w_proj, b, r0, c0, *, blk, interpret):
+    """`_lstmp_fwd_call` on a batch of whole blocks of blk rows, b [1,
+    4D]."""
+    t, b_pad, four_d = xs.shape
     d = four_d // 4
     p = w_proj.shape[1]
-    blk, b_pad = _resolve_block_b(bsz, block_b)
-    if b_pad != bsz:
-        xs = jnp.pad(xs, [(0, 0), (0, b_pad - bsz), (0, 0)])
-        ms = jnp.pad(ms, [(0, 0), (0, b_pad - bsz), (0, 0)])
-        r0 = _pad_rows(r0, b_pad)
-        c0 = _pad_rows(c0, b_pad)
-    rs, cs = pl.pallas_call(
+    return pl.pallas_call(
         functools.partial(_lstmp_seq_kernel, d=d),
         grid=(b_pad // blk, t),
         in_specs=[
@@ -1520,7 +1621,19 @@ def _lstmp_fwd_call(xs, ms, w, w_proj, b, r0, c0, block_b, interpret):
         ],
         interpret=interpret,
         name="ptpu_lstmp_seq",
-    )(xs, ms, w, w_proj, b.reshape(1, -1), r0, c0)
+    )(xs, ms, w, w_proj, b, r0, c0)
+
+
+def _lstmp_fwd_call(xs, ms, w, w_proj, b, r0, c0, block_b, interpret):
+    bsz = xs.shape[1]
+    blk, b_pad = _resolve_block_b(bsz, block_b)
+    if b_pad != bsz:
+        xs = jnp.pad(xs, [(0, 0), (0, b_pad - bsz), (0, 0)])
+        ms = jnp.pad(ms, [(0, 0), (0, b_pad - bsz), (0, 0)])
+        r0 = _pad_rows(r0, b_pad)
+        c0 = _pad_rows(c0, b_pad)
+    rs, cs = _lstmp_call(xs, ms, w, w_proj, b.reshape(1, -1), r0, c0,
+                         blk=blk, interpret=interpret)
     return rs[:, :bsz], cs[:, :bsz]
 
 
@@ -1633,12 +1746,13 @@ def _masked_softmax_kernel(x_ref, len_ref, y_ref):
     y_ref[:] = (p / denom).astype(y_ref.dtype)
 
 
-def _masked_softmax_call(x, lens, block_n, interpret):
-    n, t = x.shape
-    n_pad = int(-(-n // block_n) * block_n)
-    xp = _pad_rows(x, n_pad)
-    lp = _pad_rows(lens.reshape(-1, 1).astype(jnp.int32), n_pad)
-    y = pl.pallas_call(
+@kernel_entry("ptpu_masked_softmax",
+              static_argnames=("block_n", "interpret"))
+def _masked_softmax_rows(x, lens, *, block_n, interpret):
+    """`_masked_softmax_call` on whole blocks of block_n rows, lens [N, 1]
+    int32."""
+    n_pad, t = x.shape
+    return pl.pallas_call(
         _masked_softmax_kernel,
         grid=(n_pad // block_n,),
         in_specs=[
@@ -1649,8 +1763,16 @@ def _masked_softmax_call(x, lens, block_n, interpret):
         out_shape=jax.ShapeDtypeStruct((n_pad, t), x.dtype),
         interpret=interpret,
         name="ptpu_masked_softmax",
-    )(xp, lp)
-    return y[:n]
+    )(x, lens)
+
+
+def _masked_softmax_call(x, lens, block_n, interpret):
+    n = x.shape[0]
+    n_pad = int(-(-n // block_n) * block_n)
+    return _masked_softmax_rows(
+        _pad_rows(x, n_pad),
+        _pad_rows(lens.reshape(-1, 1).astype(jnp.int32), n_pad),
+        block_n=block_n, interpret=interpret)[:n]
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
@@ -1701,12 +1823,13 @@ def _masked_pool_kernel(x_ref, len_ref, o_ref, *, ptype):
     o_ref[:] = s.astype(o_ref.dtype)
 
 
-def _masked_pool_call(x, lens, ptype, block_n, interpret):
-    n, t, f = x.shape
-    n_pad = int(-(-n // block_n) * block_n)
-    xp = _pad_rows(x, n_pad)
-    lp = _pad_rows(lens.reshape(-1, 1).astype(jnp.int32), n_pad)
-    out = pl.pallas_call(
+@kernel_entry("ptpu_masked_pool",
+              static_argnames=("ptype", "block_n", "interpret"))
+def _masked_pool_rows(x, lens, *, ptype, block_n, interpret):
+    """`_masked_pool_call` on whole blocks of block_n rows, lens [N, 1]
+    int32."""
+    n_pad, t, f = x.shape
+    return pl.pallas_call(
         functools.partial(_masked_pool_kernel, ptype=ptype),
         grid=(n_pad // block_n,),
         in_specs=[
@@ -1717,8 +1840,16 @@ def _masked_pool_call(x, lens, ptype, block_n, interpret):
         out_shape=jax.ShapeDtypeStruct((n_pad, f), x.dtype),
         interpret=interpret,
         name="ptpu_masked_pool",
-    )(xp, lp)
-    return out[:n]
+    )(x, lens)
+
+
+def _masked_pool_call(x, lens, ptype, block_n, interpret):
+    n = x.shape[0]
+    n_pad = int(-(-n // block_n) * block_n)
+    return _masked_pool_rows(
+        _pad_rows(x, n_pad),
+        _pad_rows(lens.reshape(-1, 1).astype(jnp.int32), n_pad),
+        ptype=ptype, block_n=block_n, interpret=interpret)[:n]
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4))

@@ -34,8 +34,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax import lax
-from .pallas_import import pl
-from .pallas_import import pltpu
+from .pallas_import import kernel_entry, pl, pltpu
 
 from . import kernel_config
 
@@ -176,7 +175,12 @@ def _operand_specs(n, chunk, chunk_index):
     return bc, tokens, a, d
 
 
-def _fwd_call(bc, x, dt, a, d, *, n, chunk):
+# The two calls are jax.jits of their own, everything but the arrays static
+# (ops/pallas_import.py has the rule): a model's layers call them at one
+# shape, and a step traces each kernel's body once and not once a layer.
+@kernel_entry("ptpu_selective_scan_fwd",
+              static_argnames=("n", "chunk", "interpret"))
+def _fwd_call(bc, x, dt, a, d, *, n, chunk, interpret):
     batch, t, groups, _ = x.shape
     spec_bc, tokens, spec_a, spec_d = _operand_specs(n, chunk, lambda i: i)
     return pl.pallas_call(
@@ -189,12 +193,14 @@ def _fwd_call(bc, x, dt, a, d, *, n, chunk):
                    jax.ShapeDtypeStruct(
                        (batch, t // chunk, n, groups, _LANES), _F32)],
         scratch_shapes=[pltpu.VMEM((n, _SUBLANES, _LANES), _F32)],
-        compiler_params=_PARAMS, interpret=_interpret(),
+        compiler_params=_PARAMS, interpret=interpret,
         name="ptpu_selective_scan_fwd",
     )(bc, x, dt, a, d)
 
 
-def _bwd_call(bc, x, dt, dy, a, d, enter, *, n, chunk):
+@kernel_entry("ptpu_selective_scan_bwd",
+              static_argnames=("n", "chunk", "interpret"))
+def _bwd_call(bc, x, dt, dy, a, d, enter, *, n, chunk, interpret):
     batch, t, groups, _ = x.shape
     chunks = t // chunk
     spec_bc, tokens, spec_a, spec_d = _operand_specs(
@@ -221,7 +227,7 @@ def _bwd_call(bc, x, dt, dy, a, d, enter, *, n, chunk):
         scratch_shapes=[pltpu.VMEM((n, _SUBLANES, _LANES), _F32),
                         pltpu.VMEM((chunk + 1, n, _SUBLANES, _LANES), _F32),
                         pltpu.VMEM((chunk, 2 * n, _LANES), _F32)],
-        compiler_params=_PARAMS, interpret=_interpret(),
+        compiler_params=_PARAMS, interpret=interpret,
         name="ptpu_selective_scan_bwd",
     )(bc, x, dt, dy, a, d, enter)
 
@@ -250,7 +256,8 @@ def _kernel_path(chunk, x, delta, a, b, c, d):
 
 def _kernel_fwd(chunk, x, delta, a, b, c, d):
     ops = _kernel_operands(x, delta, a, b, c, d, chunk)
-    y, enter = _fwd_call(*ops, n=a.shape[1], chunk=chunk)
+    y, enter = _fwd_call(*ops, n=a.shape[1], chunk=chunk,
+                         interpret=_interpret())
     return y.reshape(x.shape[0], -1, x.shape[2])[:, :x.shape[1]], (ops, enter)
 
 
@@ -261,7 +268,7 @@ def _kernel_bwd(chunk, res, dy):
     t_real = dy.shape[1]
     dy = jnp.pad(dy, [(0, 0), (0, t - t_real), (0, 0)]).reshape(x.shape)
     dx, ddt, dbc, da, dd = _bwd_call(bc, x, dt, dy, a, d, enter, n=n,
-                                     chunk=chunk)
+                                     chunk=chunk, interpret=_interpret())
     dbc = dbc.sum(1)[:, :t_real]
     return (dx.reshape(batch, t, -1)[:, :t_real],
             ddt.reshape(batch, t, -1)[:, :t_real],

@@ -89,8 +89,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax import lax
-from .pallas_import import pl
-from .pallas_import import pltpu
+from .pallas_import import kernel_entry, pl, pltpu
 
 from . import kernel_config
 
@@ -346,13 +345,14 @@ def _specs(chunk, width, block_h, n, at):
             _vmem((None, chunk, n), lambda b, h, i: (b, at(i), 0)))
 
 
-# The two calls are jax.jits of their own, everything but the arrays static:
-# a model's layers call them at one shape, the jit keeps the traced kernel
+# The two calls are jax.jits of their own, everything but the arrays static
+# (ops/pallas_import.py has the rule, of which these two are the model): a
+# model's layers call them at one shape, the jit keeps the traced kernel
 # under its arguments, and a step traces each kernel's body once and not
 # once a layer (a body is a hundred equations a lane tile, unrolled; nine
 # layers' three kernels were 60 % of the granite-4.0-h-micro cell's trace).
-@functools.partial(jax.jit, static_argnames=("p", "chunk", "block_h", "emit",
-                                             "interpret"))
+@kernel_entry("ptpu_ssd_fwd", static_argnames=("p", "chunk", "block_h", "emit",
+                                               "interpret"))
 def _fwd_call(ops, *, p, chunk, block_h, emit, interpret):
     x, b = ops[0], ops[4]
     batch, t, hp = x.shape
@@ -379,8 +379,8 @@ def _fwd_call(ops, *, p, chunk, block_h, emit, interpret):
     )(*ops)
 
 
-@functools.partial(jax.jit, static_argnames=("p", "chunk", "block_h",
-                                             "interpret"))
+@kernel_entry("ptpu_ssd_bwd", static_argnames=("p", "chunk", "block_h",
+                                               "interpret"))
 def _bwd_call(ops, enters, dy, *, p, chunk, block_h, interpret):
     x, b = ops[0], ops[4]
     batch, t, hp = x.shape

@@ -301,6 +301,7 @@ def profile_report(sorted_key=None, json=False):
         lines.extend(_embedding_lines())
         lines.extend(_softmax_xent_lines())
         lines.extend(_recompute_lines())
+        lines.extend(_kernel_trace_lines())
         lines.extend(_restart_tables())
     return "\n".join(lines)
 
@@ -364,6 +365,21 @@ def _recompute_lines():
             % (loop, ", ".join(kept.get(loop, [])) or "nothing",
                size / 2.0 ** 20))
     return lines
+
+
+def _kernel_trace_lines():
+    """One line: how often jax ran each kernel entry's Python body
+    (`ptpu_kernel_body_traces_total`, ops/pallas_import.py): once a shape
+    and a set of static arguments, where the layers' counters above say how
+    many call sites a step had."""
+    from .observability.registry import REGISTRY
+    traces = ["%s %d" % (dict(key)["kernel"], n)
+              for key, n in REGISTRY.counter(
+                  "ptpu_kernel_body_traces_total").samples()]
+    if not traces:
+        return []
+    return ["kernel bodies traced (ptpu_kernel_body_traces_total; once a "
+            "shape, not once a call site): " + ", ".join(traces)]
 
 
 def _seconds_table(title, family, label, limit=10):

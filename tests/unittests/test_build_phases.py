@@ -366,13 +366,14 @@ def test_import_gauges_in_a_child_process():
 
 @pytest.mark.parametrize("module", [
     "pallas_kernels", "expert_gmm", "mhc_kernels", "causal_conv_kernels",
-    "gated_delta_kernels", "embedding_grad"])
+    "gated_delta_kernels", "embedding_grad", "selective_scan_kernels",
+    "ssd_kernels"])
 def test_a_kernel_module_takes_pallas_from_the_one_place(module):
-    """The six kernel modules import pallas through ops/pallas_import.py
+    """The eight kernel modules import pallas through ops/pallas_import.py,
+    in one line with `kernel_entry`, the form their entries take (PR 60),
     and nowhere else does the package import it."""
     src = open(os.path.join(ROOT, "paddle_tpu", "ops", module + ".py")).read()
-    assert "from .pallas_import import pl\n" in src
-    assert "from .pallas_import import pltpu\n" in src
+    assert "from .pallas_import import kernel_entry, pl, pltpu\n" in src
     assert "from jax.experimental import pallas" not in src
     assert "from jax.experimental.pallas import" not in src
 

@@ -28,7 +28,11 @@ def _set(monkeypatch, name, value):
 
 @pytest.fixture
 def pallas_calls(monkeypatch):
-    """Keyword arguments of every pl.pallas_call made, in order."""
+    """Keyword arguments of every pl.pallas_call made, in order. A kernel
+    entry is a jax.jit that keeps its trace under its arguments (PR 60,
+    ops/pallas_import.py): the traces of earlier tests are dropped, so that
+    a call at a shape they ran reaches pl.pallas_call again."""
+    jax.clear_caches()
     seen = []
     real = pk.pl.pallas_call
 

@@ -105,6 +105,12 @@ def steps():
     for form in FORMS:
         with pytest.MonkeyPatch.context() as patch:
             patch.setenv("FLAGS_flash_min_seq", "0")
+            # since PR 60 the kernel's call is a jax.jit of its own, and jax
+            # keeps its partial evaluation of that jit's jaxpr under the
+            # policy FUNCTION: a form that patches what the one function
+            # answers starts from no cache, or it is handed the form
+            # before's split of the kernel (its outputs saved)
+            jax.clear_caches()
             if form == "replayed":
                 # as the parent lowered the loop: a policy that keeps nothing
                 patch.setattr(control_ops, "_kept_by", _keep_nothing)
@@ -256,7 +262,11 @@ def test_the_booked_values_are_what_the_checkpoint_saves(steps):
     counted = steps["kept"][2]
     assert TRIPS * len(saved) \
         == 2 * counted["kept_kernels"] + counted["kept_products"]
-    assert sum("pallas_call" in where for _, where in saved) == LAYERS
+    # the logsumexp rows as the kernel's jit leaves them (PR 60: the call
+    # behind a `jit` equation, which jax's partial evaluation splits under
+    # the same policy); the output rows pass a reshape first, as before
+    assert sum("jitted function '_flash_fwd_call'" in where
+               for _, where in saved) == LAYERS
     # under the policy that keeps nothing, the carry and the weights alone
     assert _saved_by_policy(steps["saved_replayed"]) == []
     assert len(steps["saved_replayed"]) \

@@ -31,16 +31,18 @@ def test_profiler_records_per_entry_stats(capsys):
     # the training program entry ran 4 times; startup ran once each
     # 11 numeric columns after the (possibly space-containing) tag; the
     # "compile cache:" / "host syncs:" / "embedding:" / "softmax_xent:" /
-    # "recompute:" footers are summaries, not rows (the last three a kind of
-    # lookup_table, of softmax_with_cross_entropy and a program that
-    # recomputes that this process has lowered), and the
+    # "recompute:" / "kernel bodies traced" footers are summaries, not rows
+    # (the last four a kind of lookup_table, of softmax_with_cross_entropy,
+    # a program that recomputes and a kernel entry that this process has
+    # lowered or traced), and the
     # "Lowering(s) by op type" block after them is its own table
     entries = report[:report.index("Lowering(s) by op type")]
     counts = sorted(int(line.split()[-11]) for line in
                     entries.splitlines()[1:]
                     if not line.startswith(("compile cache:", "host syncs:",
                                             "embedding:", "softmax_xent:",
-                                            "recompute:")))
+                                            "recompute:",
+                                            "kernel bodies traced")))
     assert counts[-1] == 4, report
     with pytest.raises(ValueError, match="sorted_key"):
         profiler.profile_report(sorted_key="bogus")
