@@ -710,8 +710,17 @@ def _count_grad_op(path, fwd_type):
 def _count_moe_layer(ctx, attrs, ins):
     from ..observability.registry import REGISTRY
     from ..parallel.moe import matmul_route, rows_moved
-    experts, w_gate = ins["Router"][0].shape[1], ins["WGate"][0]
-    held = w_gate.shape[0]
+    router, w_up = ins["Router"][0], ins["WUp"][0]
+    experts, held = router.shape[1], w_up.shape[0]
+    # what the defaults leave as it was counts under the labels it always
+    # had: an ungated layer says so, and a router that reads another width
+    # than the experts' input says which
+    own = {}
+    if not ins.get("WGate"):
+        own["gated"] = "false"
+    router_input = "pre_attention" if ins.get("RouterX") else "own"
+    if router.shape[0] != ins["X"][0].shape[-1]:
+        router_input = str(router.shape[0])
     REGISTRY.counter(
         "ptpu_moe_layers_total",
         "moe_ffn ops lowered (forward ops, not a grad op's replay), by "
@@ -724,17 +733,20 @@ def _count_moe_layer(ctx, attrs, ins):
         "assignments: the four permutations, the two d rows' sum and the "
         "gate's transpose), how the router scores "
         "(softmax or sigmoid), whether an expert bias enters the choice of "
-        "the top_k and the factor that scales the weights"
+        "the top_k and the factor that scales the weights; `gated` false "
+        "where an expert is two matrices (activation relu2), and under "
+        "router_input the router's own input width where it is not the "
+        "experts'"
     ).inc(top_k=str(attrs["top_k"]), experts=str(experts), held=str(held),
           activation=str(attrs.get("activation", "silu")),
-          router_input="pre_attention" if ins.get("RouterX") else "own",
+          router_input=router_input,
           path=matmul_route(
-              w_gate.shape[1], w_gate.shape[2],
+              w_up.shape[1], w_up.shape[2],
               jnp.bfloat16 if ctx.amp else ins["X"][0].dtype, ctx.mesh),
           rows=rows_moved(experts, held),
           scoring=str(attrs.get("scoring", "softmax")),
           bias=str(bool(ins.get("ExpertBias"))).lower(),
-          scale="%g" % attrs.get("scale", 1.0))
+          scale="%g" % attrs.get("scale", 1.0), **own)
 
 
 def _count_attention_layer(ctx, attrs, ins):
@@ -875,14 +887,20 @@ def _count_ssd_scan_layer(ins):
     from ..ops.kernel_config import DEFAULT_TILES
     from ..ops.linear_attention_ops import ssd_scan_path
     x, b = ins["X"][0], ins["B"][0]
+    # B and C [B, T, G, N] say their groups; one group that all heads read
+    # counts under the labels it always had
+    groups = b.shape[2] if b.ndim == 4 else 1
     REGISTRY.counter(
         "ptpu_ssd_scan_layers_total",
         "ssd_scan ops lowered (forward ops, not a grad op's replay), by the "
-        "heads, a head's channels, the states a channel, the chunk and the "
-        "path of the pass over chunks (the two Pallas kernels, or lax.scan)"
+        "heads (those held, where a share is), a head's channels, the "
+        "states a channel, the chunk, the path of the pass over chunks (the "
+        "two Pallas kernels, or lax.scan) and, where B and C come in more "
+        "than one, the groups"
     ).inc(heads=str(x.shape[2]), head_dim=str(x.shape[3]),
-          states=str(b.shape[2]), chunk=str(DEFAULT_TILES["ssd"]["chunk"]),
-          path=ssd_scan_path(x))
+          states=str(b.shape[-1]), chunk=str(DEFAULT_TILES["ssd"]["chunk"]),
+          path=ssd_scan_path(x, groups),
+          **({"groups": str(groups)} if b.ndim == 4 else {}))
 
 
 def _count_embedding_layer(ctx, ins):

@@ -275,19 +275,29 @@ def layer_norm(input, scale=True, shift=True, begin_norm_axis=1,
 
 
 def rms_norm(input, begin_norm_axis=-1, epsilon=1e-5, param_attr=None,
-             name=None, zero_centered=False, gate=None):
+             name=None, zero_centered=False, gate=None,
+             begin_scale_axis=None):
     """Root-mean-square norm over the axes from begin_norm_axis on, with a
     learned scale (initialised to 1) and no shift: scale * x /
     sqrt(mean(x^2) + epsilon), accumulated in float32 (ops/nn_ops.py).
     zero_centered: the weight is stored around 0 (initialised to 0) and the
     result is (1 + scale) * x_hat. gate: a Variable of input's shape, the
-    result is scale * x_hat * silu(gate) (a gated norm)."""
+    result is scale * x_hat * silu(gate) (a gated norm). begin_scale_axis:
+    an axis before begin_norm_axis from which the scale has an element of
+    its own (a norm a group under one weight over all groups: input [..,
+    G, C / G], begin_norm_axis -1, begin_scale_axis -2); None: the axes
+    normed over."""
     helper = LayerHelper("rms_norm", **locals())
     dtype = helper.input_dtype()
     begin = begin_norm_axis % len(input.shape)
+    scaled = begin if begin_scale_axis is None \
+        else begin_scale_axis % len(input.shape)
+    if scaled > begin:
+        raise ValueError("rms_norm: begin_scale_axis %d lies behind "
+                         "begin_norm_axis %d" % (scaled, begin))
     scale = helper.create_parameter(
         attr=helper.param_attr,
-        shape=[int(np.prod(input.shape[begin:]))], dtype=dtype,
+        shape=[int(np.prod(input.shape[scaled:]))], dtype=dtype,
         default_initializer=ConstantInitializer(
             0.0 if zero_centered else 1.0))
     out = helper.create_variable_for_type_inference(dtype)
@@ -296,6 +306,8 @@ def rms_norm(input, begin_norm_axis=-1, epsilon=1e-5, param_attr=None,
     # what the defaults leave as it was is not written
     if zero_centered:
         attrs["zero_centered"] = True
+    if scaled != begin:
+        attrs["begin_scale_axis"] = scaled
     if gate is not None:
         inputs["Gate"] = [gate]
     helper.append_op(type="rms_norm", inputs=inputs, outputs={"Y": [out]},
@@ -398,7 +410,8 @@ def selective_scan(x, delta, a, b, c, d, name=None):
 def ssd_scan(x, delta, a, b, c, d, name=None):
     """A Mamba-2 mixer's state-space-dual scan (ops/ssd_kernels.py): x [B,
     T, H, P], delta [B, T, H] (> 0, after its softplus), a (negative) and d
-    [H], and b, c [B, T, N], one group that every head reads -> [B, T, H, P]
+    [H], and b, c [B, T, N], one group that every head reads, or [B, T, G,
+    N], head h reading group h // (H / G) -> [B, T, H, P]
     in x's dtype. A head from s = 0, s [N, P]: s_t = exp(delta_t a) s_(t-1)
     + b_t^T (delta_t x_t); y_t = c_t s_t + d x_t, computed a chunk of
     tokens at a time as matmuls."""

@@ -275,7 +275,7 @@ def moe_ffn(input, num_experts, d_expert, top_k, norm_topk_prob=False,
             param_attr=None, name=None, router_input=None, activation="silu",
             experts_held=None, first_expert=0, scoring="softmax",
             expert_bias_attr=None, routed_scaling_factor=1.0,
-            norm_epsilon=None):
+            norm_epsilon=None, gated=True):
     """Dropless top-k routed experts, each a gated FFN without bias
     (lowering: ops/parallel_ops.py -> parallel/moe.py routed_ffn). input
     [..., D] -> (out [..., D], balance_loss [1], z_loss [1], expert_load
@@ -289,8 +289,12 @@ def moe_ffn(input, num_experts, d_expert, top_k, norm_topk_prob=False,
     multiples of both to the training loss. expert_load is c_e, the
     assignments an expert received; it sums to top_k * N.
 
-    router_input: the tensor the router reads, [..., D] like input; None:
-    input itself. activation: "silu" or "relu", the gate branch's. A layer
+    router_input: the tensor the router reads, [..., Dr] (another width
+    than input's where the experts work in a latent space: the router's
+    parameter is then [Dr, num_experts]); None: input itself. activation:
+    "silu" or "relu", the gate branch's. gated False: an expert is two
+    matrices, act(x w_up) w_down with activation "relu2" (relu(.)^2), and
+    the layer has no w_gate parameter. A layer
     that is one chip's share of an expert-parallel one gives experts_held
     (None: all) and first_expert: the router keeps num_experts columns and
     the top_k is over all of them, the expert weights are [experts_held, ..],
@@ -323,9 +327,11 @@ def moe_ffn(input, num_experts, d_expert, top_k, norm_topk_prob=False,
     if not (1 <= held and 0 <= int(first_expert) <= e - held):
         raise ValueError("moe_ffn cannot hold experts %d..%d of %d"
                          % (first_expert, int(first_expert) + held - 1, e))
-    if activation not in ("silu", "relu"):
-        raise ValueError("moe_ffn activation must be 'silu' or 'relu', got "
-                         "%r" % (activation,))
+    if activation not in (("silu", "relu") if gated else ("relu2",)):
+        raise ValueError(
+            "moe_ffn activation must be 'silu' or 'relu' in gated experts "
+            "and 'relu2' in ungated ones, got %r with gated=%r"
+            % (activation, gated))
     if scoring not in ("softmax", "sigmoid"):
         raise ValueError("moe_ffn scoring must be 'softmax' or 'sigmoid', "
                          "got %r" % (scoring,))
@@ -341,7 +347,8 @@ def moe_ffn(input, num_experts, d_expert, top_k, norm_topk_prob=False,
         return helper.create_parameter(attr=_suffixed(base, suffix),
                                        shape=shape, dtype=dtype)
 
-    inputs = {"X": [input], "Router": [param("router", [d, e])]}
+    inputs = {"X": [input], "Router": [param("router", [
+        d if router_input is None else int(router_input.shape[-1]), e])]}
     if biased:
         given = ParamAttr() if expert_bias_attr is True \
             else ParamAttr.to_attr(expert_bias_attr)
@@ -353,8 +360,9 @@ def moe_ffn(input, num_experts, d_expert, top_k, norm_topk_prob=False,
             shape=[e], dtype="float32")
         bias.stop_gradient = True
         inputs["ExpertBias"] = [bias]
+    if gated:
+        inputs["WGate"] = [param("w_gate", [held, d, f])]
     inputs.update({
-              "WGate": [param("w_gate", [held, d, f])],
               "WUp": [param("w_up", [held, d, f])],
               "WDown": [param("w_down", [held, f, d])]})
     # what the defaults leave as it was is not written: a layer that holds

@@ -15,7 +15,8 @@ v, the gated delta rule, a gated RMS norm a head) or a gated short
 convolution (C * conv(B * u) between two projections) or a Mamba mixer (a
 selective scan behind a convolution) or a gated memory unit on another
 layer's scan output or a Mamba-2 mixer (a state-space-dual scan behind a
-convolution, gated before its norm). A new decoder-only
+convolution, gated before its norm); or a layer is ONE branch, a mixer or
+an FFN alone, by `hybrid_override_pattern`. A new decoder-only
 architecture is a config plus the ops it lacks, not a model file. Users:
 OLMoE-1B-7B (`model_type: olmoe`; Muennighoff et al. 2024, arXiv:2409.02060),
 SmallThinker-21BA3B (PowerInfer; window and full attention mixed with
@@ -153,7 +154,8 @@ equations) and `attention`; a list longer than the stack is cut to its
 first layers where share.published.num_hidden_layers says it is the whole
 model's; mamba_n_heads x mamba_d_head = mamba_expand x hidden_size,
 mamba_d_state, mamba_d_conv, mamba_conv_bias (true), mamba_proj_bias (false;
-true refused), mamba_n_groups (1; more refused), mamba_chunk_size (not
+true refused), mamba_n_groups (1; more: nemotron_h's, below),
+mamba_chunk_size (not
 read: the kernels' chunk is kernel_config's); position_embedding_type (rope,
 or nope: no rotary in any layer, whatever rope_theta says);
 shared_intermediate_size (the dense MLP's width, its first matrix gate and
@@ -165,6 +167,33 @@ attention_multiplier (the scores' scale ITSELF, what the attention's core
 is given, in place of head_dim^-0.5), logits_scaling (the logits are
 divided by it). A Mamba-2 layer's parameters: w_in, conv, [conv.bias],
 dt_bias, a_log, d, gated_norm, w_out.
+NVIDIA-Nemotron-3-Super's (`model_type: nemotron_h`): hybrid_override_pattern,
+a letter a layer, and a layer is ONE branch behind one norm, h + f(N(h)):
+`M` a Mamba-2 mixer, `*` attention, `E` routed experts, `-` a dense MLP
+(`_one_branch_layers`; a pattern longer than the stack is cut as a
+layer_types list is; other letters are refused). The family's names are
+mapped (NEMOTRON_H): mamba_num_heads, mamba_head_dim, ssm_state_size,
+conv_kernel, n_groups (mamba_n_groups: G groups of B and C, head h reads
+group h // (H / G), and the gated norm is a norm a group under one weight;
+G must divide the heads), expand, use_conv_bias, layer_norm_epsilon (an RMS
+norm's), mlp_hidden_act (relu2, the only value built under the pattern:
+every FFN there is UNGATED, relu(x W_up)^2 W_down, the routed experts two
+matrices an expert), moe_shared_expert_intermediate_size; moe_latent_size (L
+> 0: the experts work on u = x W_dn [L] and their sum goes through W_up [L,
+D], while the router and the shared expert read x; refused outside the
+pattern); mtp_hybrid_override_pattern (`*E`, the module's one layer with
+both branches, the only value built); use_bias (false). What the family's
+modeling file always does and its config.json does not carry is a default
+there that a config's own key overrides (NEMOTRON_H_ALWAYS): router_scoring
+sigmoid with use_expert_bias, router_renorm_epsilon 1e-20, no auxiliary
+loss, shared_expert_gate false; rope_theta is not read (no positional
+term). chunk_size, time_step_min/max/floor, rescale_prenorm_residual,
+residual_in_fp32, moe_shared_expert_overlap, num_logits_to_keep and
+use_mamba_kernels are not read. A one-branch layer's parameters: `M`: norm,
+w_in, conv, [conv.bias], dt_bias, a_log, d, gated_norm, w_out; `*`: norm,
+wq, wk, wv, wo; `E`: norm, [latent_down], experts.router,
+[experts.expert_bias], experts.w_up, experts.w_down, [latent_up],
+shared_expert.w_up, shared_expert.w_down; `-`: norm, w_up, w_down.
 A Mamba layer's parameters: w_in, conv, [conv.bias], w_x, w_dt, dt_bias,
 a_log, d, w_out; a memory unit's: w_in, w_out; a differential attention's:
 wq, [wq.bias], then where it makes its own keys and values wk, [wk.bias],
@@ -259,7 +288,8 @@ DEFAULTS = {
     "lm_head_bias": False, "embedding_multiplier": 1,
     "residual_multiplier": 1, "attention_multiplier": None,
     "logits_scaling": 1, "mamba_conv_bias": True, "mamba_proj_bias": False,
-    "mamba_n_groups": 1, "position_embedding_type": "rope"}
+    "mamba_n_groups": 1, "position_embedding_type": "rope",
+    "moe_latent_size": 0}
 # the stream every expert bias is drawn from, whatever the program's seed:
 # the draw the LFM2 cell's limits were read under (PERF.md section 4)
 EXPERT_BIAS_SEED = 39
@@ -287,6 +317,27 @@ LAYER_TYPES = {"conv": "short_conv", "full_attention": "attention",
 # the stream a Mamba-2 mixer's Delta bias is drawn from (MAMBA_DT_SEED's
 # kind: the draw the granite-4.0-h-micro cell's limits were read under)
 MAMBA2_DT_SEED = 57
+# nemotron_h's names (known where the config has hybrid_override_pattern) ->
+# the key the builder reads
+NEMOTRON_H = {"mamba_num_heads": "mamba_n_heads",
+              "mamba_head_dim": "mamba_d_head",
+              "ssm_state_size": "mamba_d_state",
+              "conv_kernel": "mamba_d_conv", "n_groups": "mamba_n_groups",
+              "expand": "mamba_expand", "use_conv_bias": "mamba_conv_bias",
+              "layer_norm_epsilon": "rms_norm_eps",
+              "mlp_hidden_act": "hidden_act",
+              "moe_shared_expert_intermediate_size":
+              "shared_expert_intermediate_size"}
+# what the family's modeling file always does and its config.json therefore
+# does not carry; a config that has the key says otherwise
+NEMOTRON_H_ALWAYS = {"router_scoring": "sigmoid", "use_expert_bias": True,
+                     "router_renorm_epsilon": 1e-20,
+                     "router_aux_loss_coef": 0, "router_z_loss_coef": 0,
+                     "shared_expert_gate": False}
+# a letter of hybrid_override_pattern -> the layer's ONE branch, (mixer,
+# FFN): a Mamba-2 mixer, attention, routed experts or a dense MLP
+PATTERN = {"M": ("mamba2", "none"), "*": ("attention", "none"),
+           "E": ("none", "experts"), "-": ("none", "dense")}
 # the keys a gated delta net needs
 LINEAR_KEYS = ("linear_num_key_heads", "linear_num_value_heads",
                "linear_key_head_dim", "linear_value_head_dim",
@@ -329,6 +380,20 @@ def resolve(cfg):
         c["shared_expert_intermediate_size"] = \
             c["n_shared_experts"] * c["intermediate_size"]
         c["shared_expert_gate"] = False
+    pattern = c.get("hybrid_override_pattern")
+    if pattern is not None:
+        # nemotron_h: the family's names, what its modeling file always
+        # does, and no positional term in any layer (rope_theta stays in the
+        # file, unread)
+        for theirs, ours in NEMOTRON_H.items():
+            if theirs in c:
+                c[ours] = c[theirs]
+        for key, always in NEMOTRON_H_ALWAYS.items():
+            if key not in cfg:
+                c[key] = always
+        c["use_expert_bias"] = bool(c["use_expert_bias"]
+                                    and c["num_experts"])
+        c["rope_theta"] = None
     c["mb_per_layer"] = int(c["mb_per_layer"])
     # granitemoehybrid's names: no routed experts is a dense model, whose
     # MLP's width is shared_intermediate_size, its first matrix gate and
@@ -416,11 +481,25 @@ def resolve(cfg):
                     "causal_lm builds latent attention without %s" % key)
         c.setdefault("head_dim", c["qk_nope_head_dim"]
                      + c["qk_rope_head_dim"])
-    if c["hidden_act"] not in (("silu", "relu") if c["num_experts"]
-                               else ("silu",)):
+    if pattern is not None:
+        # the family's MLPs, shared experts and routed experts are all
+        # ungated: act(x W_up) W_down
+        if c["hidden_act"] != "relu2":
+            raise NotImplementedError(
+                "causal_lm builds mlp_hidden_act relu2 (ungated MLPs and "
+                "experts of two matrices) under hybrid_override_pattern, "
+                "the config has %r" % (c["hidden_act"],))
+    elif c["hidden_act"] not in (("silu", "relu") if c["num_experts"]
+                                 else ("silu",)):
         raise NotImplementedError(
-            "causal_lm builds hidden_act silu, and relu in routed experts; "
-            "the config has %r" % (c["hidden_act"],))
+            "causal_lm builds hidden_act silu, and relu in routed experts "
+            "(relu2 under hybrid_override_pattern); the config has %r"
+            % (c["hidden_act"],))
+    c["ffn_gated"] = pattern is None
+    if c["moe_latent_size"] and pattern is None:
+        raise NotImplementedError(
+            "causal_lm builds moe_latent_size (experts in a latent space) "
+            "under hybrid_override_pattern only")
     if c["router_input"] not in ("own", "pre_attention"):
         raise NotImplementedError("causal_lm builds router_input own or "
                                   "pre_attention, the config has %r"
@@ -474,6 +553,13 @@ def resolve(cfg):
             "multi-token-prediction module: a second would read the first's "
             "state and the tokens two ahead), the config has %r"
             % (c["num_nextn_predict_layers"],))
+    if mtp and pattern is not None \
+            and c.get("mtp_hybrid_override_pattern", "*E") != "*E":
+        raise NotImplementedError(
+            "causal_lm builds a multi-token-prediction module of one layer "
+            "with two branches, attention then experts "
+            "(mtp_hybrid_override_pattern '*E'), the config has %r"
+            % (c["mtp_hybrid_override_pattern"],))
     if mtp:
         for key, want in (("total_ut_steps", 1), ("hc_mult", 1),
                           ("tie_word_embeddings", False),
@@ -562,6 +648,8 @@ def resolve(cfg):
                 % (sorted(known), len(kinds), layers,
                    ", among them %s" % unknown if unknown else ""))
         c["mixer_layers"] = [known[kind] for kind in kinds[:layers]]
+    elif pattern is not None:
+        _one_branch_layers(c, pattern, published)
     else:
         c["mixer_layers"] = ["attention" if (i + 1) % interval == 0
                              else "gated_delta" for i in range(layers)] \
@@ -577,7 +665,7 @@ def resolve(cfg):
             "causal_lm builds differential_attention under mb_per_layer "
             "(lambda_init is a function of the published layer index)")
     if "mamba2" in c["mixer_layers"]:
-        _mamba2(c)
+        _mamba2(c, published)
     if "short_conv" in c["mixer_layers"]:
         if "conv_L_cache" not in c:
             raise ValueError("layer_types has conv layers, which need "
@@ -589,8 +677,9 @@ def resolve(cfg):
                 % c["total_ut_steps"])
     dense = min(int(c["num_dense_layers"]), layers) if c["num_experts"] \
         else layers
-    c["ffn_layers"] = ["dense"] * dense + ["experts"] * (layers - dense) \
-        + ["experts" if c["num_experts"] else "dense"] * mtp
+    if pattern is None:
+        c["ffn_layers"] = ["dense"] * dense + ["experts"] * (layers - dense) \
+            + ["experts" if c["num_experts"] else "dense"] * mtp
     if "gated_delta" in c["mixer_layers"]:
         missing = [key for key in LINEAR_KEYS if key not in c]
         if missing:
@@ -603,14 +692,55 @@ def resolve(cfg):
     return c
 
 
-def _mamba2(c):
-    """What a stack with Mamba-2 mixers (granitemoehybrid's `mamba` layers)
-    needs and refuses: mamba_n_heads x mamba_d_head = mamba_expand x
-    hidden_size channels, one group of B and C, no bias on the
-    projections."""
+def _one_branch_layers(c, pattern, published):
+    """`mixer_layers` and `ffn_layers` of hybrid_override_pattern
+    (nemotron_h): a letter a layer, and a layer is ONE branch, h + f(N(h)),
+    f a mixer (`M` a Mamba-2 mixer, `*` attention) or an FFN (`E` routed
+    experts, `-` a dense MLP); the other list says "none" there. A pattern
+    longer than the stack is cut to its first layers where the share says it
+    is the published model's whole pattern. A multi-token-prediction
+    module's one layer has both branches, `*` then `E`."""
+    layers, mtp = c["num_hidden_layers"], c["mtp_layers"]
+    unknown = sorted(set(pattern) - set(PATTERN))
+    if unknown or len(pattern) < layers or (
+            len(pattern) > layers
+            and len(pattern) != published.get("num_hidden_layers")):
+        raise NotImplementedError(
+            "causal_lm builds hybrid_override_pattern of %s, a letter a "
+            "layer (more only where it is the share's published "
+            "num_hidden_layers of them: the first are built); the config "
+            "has %d for %d layers%s"
+            % (sorted(PATTERN), len(pattern), layers,
+               ", among them %s" % unknown if unknown else ""))
     for key, want in (("total_ut_steps", 1), ("hc_mult", 1),
-                      ("mtp_layers", 0), ("mamba_n_groups", 1),
-                      ("mamba_proj_bias", False), ("sandwich_norm", False)):
+                      ("sandwich_norm", False), ("router_input", "own"),
+                      ("latent", False), ("num_dense_layers", 0),
+                      ("qk_norm", False), ("attention_gate", False),
+                      ("norm_type", "rms_norm"), ("use_bias", False),
+                      ("tie_word_embeddings", False),
+                      ("residual_multiplier", 1)):
+        if c.get(key, want) != want:
+            raise NotImplementedError(
+                "causal_lm builds hybrid_override_pattern with %s=%r only, "
+                "the config has %r" % (key, want, c.get(key)))
+    kinds = [PATTERN[letter] for letter in pattern[:layers]]
+    if ("none", "experts") in kinds and not c["num_experts"]:
+        raise ValueError("hybrid_override_pattern has E layers, which need "
+                         "n_routed_experts")
+    c["mixer_layers"] = [mixer for mixer, _ in kinds] + ["attention"] * mtp
+    c["ffn_layers"] = [ffn for _, ffn in kinds] + ["experts"] * mtp
+
+
+def _mamba2(c, published):
+    """What a stack with Mamba-2 mixers (granitemoehybrid's `mamba` layers,
+    nemotron_h's `M`) needs and refuses: mamba_n_heads x mamba_d_head =
+    mamba_expand x hidden_size channels (the published heads', where a
+    share of them is held), whole groups of heads under the groups of B and
+    C, no bias on the projections."""
+    for key, want in (("total_ut_steps", 1), ("hc_mult", 1),
+                      ("mamba_proj_bias", False), ("sandwich_norm", False)) \
+            + (() if c.get("hybrid_override_pattern")
+               else (("mtp_layers", 0),)):
         if c.get(key) != want:
             raise NotImplementedError(
                 "causal_lm builds Mamba-2 mixers (layer_types mamba) with "
@@ -623,10 +753,17 @@ def _mamba2(c):
         raise ValueError("layer_types has mamba layers, which need "
                          "mamba_n_heads and mamba_d_head")
     inner = c["mamba_expand"] * c["hidden_size"]
-    if c["mamba_n_heads"] * c["mamba_d_head"] != inner:
+    heads = next((published[key] for key in ("mamba_num_heads",
+                                             "mamba_n_heads")
+                  if key in published), c["mamba_n_heads"])
+    if heads * c["mamba_d_head"] != inner:
         raise ValueError(
             "%d Mamba-2 heads of %d are not mamba_expand x hidden_size = %d "
-            "channels" % (c["mamba_n_heads"], c["mamba_d_head"], inner))
+            "channels" % (heads, c["mamba_d_head"], inner))
+    groups = c["mamba_n_groups"] = int(c["mamba_n_groups"])
+    if groups < 1 or c["mamba_n_heads"] % groups:
+        raise ValueError("%d Mamba-2 heads are not whole groups of %r"
+                         % (c["mamba_n_heads"], c["mamba_n_groups"]))
 
 
 def _decoder_hybrid_decoder(c, published):
@@ -1109,12 +1246,15 @@ def mamba(x, c):
 def mamba2(x, c):
     """A Mamba-2 mixer (arXiv:2405.21060 as granitemoehybrid has it) over x
     [B, T, D]: H = mamba_n_heads heads of P = mamba_d_head, d_i = H P, N =
-    mamba_d_state, one group. [z; xBC; dt] = x W_in (d_i + (d_i + 2 N) + H
+    mamba_d_state, G = mamba_n_groups groups of B and C (head h reads group
+    h // (H / G)). [z; xBC; dt] = x W_in (d_i + (d_i + 2 G N) + H
     columns, no bias); xBC' = SiLU(conv(xBC) + b_c), a causal depthwise
     convolution of mamba_d_conv taps over x, B and C side by side; [x; B; C]
-    = xBC'; Delta = softplus(dt + dt_bias) in float32; A = -exp(A_log) a
+    = xBC', B and C [G, N] a token; Delta = softplus(dt + dt_bias) in
+    float32; A = -exp(A_log) a
     head; y = ssd_scan(x, Delta, A, B, C, D); o = RMSNorm(y * SiLU(z)) over
-    all d_i channels at once, the gate FIRST (rms_norm(gate=) norms first);
+    each group's d_i / G channels under one weight of d_i (one group: over
+    all d_i at once), the gate FIRST (rms_norm(gate=) norms first);
     o W_out. Parameters: w_in, conv, [conv.bias], dt_bias, a_log, d,
     gated_norm, w_out. A_log starts at log(1 .. H) a head, D at 1, dt_bias
     at the inverse softplus of exp(U(ln 1e-3, ln 1e-1)) from
@@ -1123,19 +1263,21 @@ def mamba2(x, c):
     import numpy as np
     layers, init = fluid.layers, fluid.initializer
     d, n = c["hidden_size"], c["mamba_d_state"]
-    h, p = c["mamba_n_heads"], c["mamba_d_head"]
+    h, p, g = c["mamba_n_heads"], c["mamba_d_head"], c["mamba_n_groups"]
     di = h * p
-    z, xbc, dt = layers.split(_linear(x, 2 * di + 2 * n + h, c, "w_in"),
-                              [di, di + 2 * n, h], dim=-1)
+    z, xbc, dt = layers.split(_linear(x, 2 * di + 2 * g * n + h, c, "w_in"),
+                              [di, di + 2 * g * n, h], dim=-1)
     xbc = layers.causal_conv1d(
         xbc, c["mamba_d_conv"], param_attr=_matrix(c, "conv"),
         act=None if c["mamba_conv_bias"] else "silu")
     if c["mamba_conv_bias"]:
         xbc = layers.swish(layers.elementwise_add(
             xbc, layers.create_parameter(
-                [di + 2 * n], "float32",
+                [di + 2 * g * n], "float32",
                 attr=_attr(c, "conv.bias", init.Constant(0.0))), axis=2))
-    u, b, cc = layers.split(xbc, [di, n, n], dim=-1)
+    u, b, cc = layers.split(xbc, [di, g * n, g * n], dim=-1)
+    if g > 1:       # one group stays [B, T, N], the op it always was
+        b, cc = (layers.reshape(t, shape=[0, -1, g, n]) for t in (b, cc))
     delta = layers.softplus(layers.elementwise_add(
         layers.cast(dt, "float32"),
         layers.create_parameter(
@@ -1151,9 +1293,15 @@ def mamba2(x, c):
         layers.reshape(u, shape=[0, -1, h, p]), delta,
         layers.scale(layers.exp(a_log), scale=-1.0), b, cc, skip)
     gated = layers.reshape(y, shape=[0, -1, di]) * layers.swish(z)
-    return _linear(layers.rms_norm(
-        gated, epsilon=c["rms_norm_eps"], param_attr=_attr(c, "gated_norm")),
-        d, c, "w_out")
+    if g > 1:
+        normed = layers.reshape(layers.rms_norm(
+            layers.reshape(gated, shape=[0, -1, g, di // g]),
+            epsilon=c["rms_norm_eps"], param_attr=_attr(c, "gated_norm"),
+            begin_scale_axis=-2), shape=[0, -1, di])
+    else:
+        normed = layers.rms_norm(gated, epsilon=c["rms_norm_eps"],
+                                 param_attr=_attr(c, "gated_norm"))
+    return _linear(normed, d, c, "w_out")
 
 
 def gated_memory_unit(x, c):
@@ -1163,6 +1311,13 @@ def gated_memory_unit(x, c):
         x, c["mamba_expand"] * c["hidden_size"], c, "w_in"))
     return _linear(gate * c["handed_on"]["memory"], c["hidden_size"], c,
                    "w_out")
+
+
+def _relu2_mlp(x, width, c, role=""):
+    """nemotron_h's MLP, two matrices: relu(x W_up)^2 W_down."""
+    return _linear(fluid.layers.square(fluid.layers.relu(
+        _linear(x, width, c, role + "w_up"))), c["hidden_size"], c,
+        role + "w_down")
 
 
 def _swiglu(x, width, c, role=""):
@@ -1189,8 +1344,17 @@ def feed_forward(x, c, router_input=None):
     has any): a model with num_dense_layers has dense layers at
     dense_intermediate_size before its expert layers. An expert bias is
     drawn at expert_bias_initializer_range from EXPERT_BIAS_SEED's stream
-    and training does not move it."""
+    and training does not move it. With moe_latent_size = L > 0 (nemotron_h's
+    LatentMoE) the routed experts work on u = x W_dn [L] and their sum goes
+    through W_up [L, D] (`latent_down`, `latent_up`); the router and the
+    shared expert read x. Under hybrid_override_pattern every FFN is
+    ungated, relu(x W_up)^2 W_down: the experts have no w_gate, the shared
+    expert and the dense MLP two matrices."""
     if c.get("ffn", "experts" if c["num_experts"] else "dense") == "experts":
+        routed_in = x
+        if c["moe_latent_size"]:
+            routed_in, router_input = _linear(
+                x, c["moe_latent_size"], c, "latent_down"), x
         bias = None
         if c["use_expert_bias"]:
             bias = fluid.ParamAttr(initializer=fluid.initializer.Normal(
@@ -1198,7 +1362,8 @@ def feed_forward(x, c, router_input=None):
                 seed=EXPERT_BIAS_SEED)
                 if c["expert_bias_initializer_range"] else None)
         out, balance, z, load = fluid.layers.moe_ffn(
-            x, num_experts=c["num_experts"], d_expert=c["intermediate_size"],
+            routed_in, num_experts=c["num_experts"],
+            d_expert=c["intermediate_size"],
             top_k=c["num_experts_per_tok"],
             norm_topk_prob=c["norm_topk_prob"],
             param_attr=_matrix(c, "experts"), router_input=router_input,
@@ -1207,16 +1372,19 @@ def feed_forward(x, c, router_input=None):
             scoring=c["router_scoring"],
             expert_bias_attr=bias,
             routed_scaling_factor=c["routed_scaling_factor"],
-            norm_epsilon=c["router_renorm_epsilon"])
+            norm_epsilon=c["router_renorm_epsilon"], gated=c["ffn_gated"])
+        if c["moe_latent_size"]:
+            out = _linear(out, c["hidden_size"], c, "latent_up")
         if c["shared_expert_intermediate_size"]:
-            shared = _swiglu(x, c["shared_expert_intermediate_size"], c,
-                             "shared_expert.")
+            shared = (_swiglu if c["ffn_gated"] else _relu2_mlp)(
+                x, c["shared_expert_intermediate_size"], c, "shared_expert.")
             if c["shared_expert_gate"]:
                 shared = shared * fluid.layers.sigmoid(
                     _linear(x, 1, c, "shared_expert.gate"))
             out = out + shared
         return out, (balance, z, load)
-    return _swiglu(x, c["dense_intermediate_size"], c), None
+    return (_swiglu if c["ffn_gated"] else _relu2_mlp)(
+        x, c["dense_intermediate_size"], c), None
 
 
 def _count_layer(c, mixer, module="trunk"):
@@ -1237,14 +1405,25 @@ def _count_layer(c, mixer, module="trunk"):
         "belongs to (trunk, or mtp: a multi-token-prediction module's), "
         "whose state the mixer reads (own: its input's alone; shared: the "
         "memory or the keys and values another layer handed on) and whether "
-        "the attention is differential"
+        "the attention is differential; and, under hybrid_override_pattern "
+        "alone (the other models' layers count under the labels they always "
+        "had), the layer's branches (1: a mixer or an FFN; 2: both), the "
+        "width of the latent space its routed experts work in (0: the "
+        "model's own) and whether its FFN is gated (mixer none: a layer "
+        "that is an FFN alone; ffn none: a mixer alone)"
     ).inc(mixer=mixer, module=module, reads=c["reads"],
+          **({} if c.get("hybrid_override_pattern") is None else dict(
+              branches=str(2 - ("none" in (mixer, c["ffn"]))),
+              latent=str(c["moe_latent_size"] if c["ffn"] == "experts"
+                         else 0),
+              gated=str(bool(c["ffn_gated"])).lower())),
           differential=str(bool(attention
                                 and c["differential_attention"])).lower(),
           rotary_dim=str(c["rotary_dim"] if attention
                          and c["rope_theta"] is not None else 0),
           gate=str(bool(attention and c["attention_gate"])).lower(),
-          conv=str(0 if attention or mixer == "gmu" else c["conv_L_cache"]
+          conv=str(0 if attention or mixer in ("gmu", "none")
+                   else c["conv_L_cache"]
                    if mixer == "short_conv" else c["mamba_d_conv"]
                    if mixer in ("mamba", "mamba2")
                    else c["linear_conv_kernel_dim"]),
@@ -1377,6 +1556,19 @@ def causal_lm(cfg, seq_len, extras=None, recompute=True):
                     _norm(trunk_state, cl, "hnorm")], axis=2),
                     c["hidden_size"], cl, "eh_proj")
             _count_layer(cl, mixer, "mtp" if i >= trunk else "trunk")
+            if "none" in (mixer, cl["ffn"]):
+                # hybrid_override_pattern's layer: ONE branch behind one
+                # norm (`layer_<i>.norm`), h + f(N(h)), f a mixer or an FFN
+                a = _norm(h, cl, "norm")
+                if mixer != "none":
+                    h = h + (attention(a, pos, cl) if mixer == "attention"
+                             else mamba2(a, cl))
+                    continue
+                out, layer_aux = feed_forward(a, cl)
+                h = h + out
+                if layer_aux is not None:
+                    aux.append(layer_aux)
+                continue
             if streams > 1:
                 read, coef, h = hyper_connection(h, cl, "attn_hc")
             a = _norm(read if streams > 1 else h, cl, "input_norm")

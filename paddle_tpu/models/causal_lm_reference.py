@@ -303,22 +303,28 @@ def mamba(a, w_in, w_conv, b_conv, w_x, w_dt, b_dt, a_log, d, w_out,
 def ssd_scan(x, delta, a, b, c, d, found=None, segment=None):
     """Mamba-2's recurrence token by token on x [B, T, H, P], delta [B, T,
     H], a and d [H], b, c [B, T, N] (one group: every head reads the same b
-    and c): a head's state s [N, P] from 0, s_t = exp(delta_t a) s_(t-1) +
+    and c) or [B, T, G, N] (head h reads group h // (H / G)): a head's state
+    s [N, P] from 0, s_t = exp(delta_t a) s_(t-1) +
     b_t^T (delta_t x_t); y_t = c_t s_t + d x_t. A dict given as `found`
     gets `state`, the state after the last token [B, H, N, P]. `segment`
     (a divisor of T) changes no result: so many tokens run under one
     jax.checkpoint, and a backward pass keeps a state a segment and not one
     a token (2 MB a token at 64 heads of 64 on 128 states)."""
     def step(s, xs):
-        x, dt, b, c = xs
+        x, dt, b, c = xs                # b, c [B, H, N]: a head's own group's
         s = jnp.exp(dt * a)[..., None, None] * s \
-            + b[:, None, :, None] * (dt[..., None] * x)[:, :, None, :]
-        return s, jnp.einsum("bn,bhnp->bhp", c, s)
+            + b[..., None] * (dt[..., None] * x)[:, :, None, :]
+        return s, jnp.einsum("bhn,bhnp->bhp", c, s)
 
     def tokens(s, xs):
         return jax.lax.scan(step, s, xs)
 
+    def by_head(v):                     # [B, T, (G,) N] -> [B, T, H, N]
+        v = v[:, :, None] if v.ndim == 3 else v
+        return jnp.repeat(v, x.shape[2] // v.shape[2], axis=2)
+
     t = x.shape[1]
+    b, c = by_head(b), by_head(c)
     xs = tuple(jnp.moveaxis(v, 1, 0) for v in (x, delta, b, c))
     s = jnp.zeros(x.shape[:1] + (x.shape[2], b.shape[-1], x.shape[3]),
                   x.dtype)
@@ -333,23 +339,28 @@ def ssd_scan(x, delta, a, b, c, d, found=None, segment=None):
 
 
 def mamba2(a, w_in, w_conv, b_conv, dt_bias, a_log, d, w_norm, w_out, eps,
-           found=None, segment=None):
-    """A Mamba-2 mixer (GraniteMoeHybridMambaLayer) on a [B, T, D], H heads
-    of P with N states, one group: [z; xBC; dt] = a w_in, d_i + (d_i + 2 N)
+           found=None, segment=None, groups=1):
+    """A Mamba-2 mixer (GraniteMoeHybridMambaLayer; NemotronHMamba2Mixer
+    with `groups` G > 1) on a [B, T, D], H heads
+    of P with N states, G groups of B and C: [z; xBC; dt] = a w_in, d_i +
+    (d_i + 2 G N)
     + H columns; xBC' = SiLU(conv(xBC) + b_conv) (b_conv None: no bias); [x;
-    B; C] = xBC'; Delta = softplus(dt + dt_bias); A = -exp(a_log); y =
-    ssd_scan(x, Delta, A, B, C, d); RMSNorm(y * SiLU(z)) over all d_i
-    channels under w_norm, the gate first; that w_out. A dict given as
+    B; C] = xBC', B and C [G, N] a token; Delta = softplus(dt + dt_bias); A
+    = -exp(a_log); y =
+    ssd_scan(x, Delta, A, B, C, d); RMSNorm(y * SiLU(z)) over each group's
+    d_i / G channels under w_norm [d_i] (one group: all d_i at once), the
+    gate first; that w_out. A dict given as
     `found` gets the first mixer's `delta`, `scan` (y before the gate) and
     `carried` (y less its skip term D x: what the state gave) and every
     mixer's `state` (the last one's stays)."""
     h = dt_bias.shape[0]
     di = w_out.shape[0]
-    n = (w_in.shape[1] - 2 * di - h) // 2
-    z, xbc, dt = jnp.split(a @ w_in, [di, 2 * di + 2 * n], axis=-1)
+    n = (w_in.shape[1] - 2 * di - h) // (2 * groups)
+    z, xbc, dt = jnp.split(a @ w_in, [di, 2 * di + 2 * groups * n], axis=-1)
     xbc = causal_conv(xbc, w_conv)
     xbc = jax.nn.silu(xbc if b_conv is None else xbc + b_conv)
-    x, b, c = jnp.split(xbc, [di, di + n], axis=-1)
+    x, b, c = jnp.split(xbc, [di, di + groups * n], axis=-1)
+    b, c = (v.reshape(v.shape[:2] + (groups, n)) for v in (b, c))
     delta = jax.nn.softplus(dt + dt_bias)
     heads = x.reshape(x.shape[:2] + (h, di // h))
     y = ssd_scan(heads, delta, -jnp.exp(a_log), b, c, d, found=found,
@@ -359,8 +370,10 @@ def mamba2(a, w_in, w_conv, b_conv, dt_bias, a_log, d, w_norm, w_out, eps,
         found.setdefault("scan", y.reshape(x.shape))
         found.setdefault("carried",
                          (y - d[:, None] * heads).reshape(x.shape))
-    y = y.reshape(x.shape)
-    return rms_norm(y * jax.nn.silu(z), w_norm, eps) @ w_out
+    gated = (y.reshape(x.shape) * jax.nn.silu(z)).reshape(
+        x.shape[:2] + (groups, di // groups))
+    return rms_norm(gated, w_norm.reshape(groups, -1), eps).reshape(
+        x.shape) @ w_out
 
 
 def gated_memory_unit(a, memory, w_in, w_out):
@@ -486,6 +499,12 @@ def shared_expert(m, wg, wu, wd, ws=None):
     return out if ws is None else jax.nn.sigmoid(m @ ws) * out
 
 
+def relu2_mlp(m, wu, wd):
+    """nemotron_h's MLP, shared expert or one routed expert: two matrices,
+    relu(m w_u)^2 w_d."""
+    return jnp.square(jax.nn.relu(m @ wu)) @ wd
+
+
 def gated_unit(m, wg, wu, c):
     """act(m @ wg) * (m @ wu): SiLU (SwiGLU) or, hidden_act relu, ReLU on
     the gate branch (ReGLU)."""
@@ -496,8 +515,10 @@ def gated_unit(m, wg, wu, c):
 def routed_experts(m, router, w_gate, w_up, w_down, c, router_x=None,
                    first_expert=None, expert_bias=None):
     """m [N, D] -> (out [N, D], balance term, z term, load [E] int32). The
-    router reads router_x where it is given, m otherwise, and routes over
-    all E columns; the experts computed are those whose weights are given,
+    router reads router_x where it is given (of any width: router [Dr, E]),
+    m otherwise, and routes over
+    all E columns; w_gate None: experts of two matrices, relu(m w_up)^2
+    w_down; the experts computed are those whose weights are given,
     first_expert .. first_expert + len(w_gate) - 1 (c's own by default), and
     `out` is their part of the sum. With router_scoring sigmoid
     (Lfm2MoeSparseMoeBlock) the scores are s = sigmoid(logits), the top k is
@@ -536,9 +557,10 @@ def routed_experts(m, router, w_gate, w_up, w_down, c, router_x=None,
     def one(args):
         i, wg, wu, wd = args
         weight = jnp.sum(jnp.where(idx == i, gate, 0.0), -1)
-        return weight[:, None] * (gated_unit(m, wg, wu, c) @ wd)
+        return weight[:, None] * (relu2_mlp(m, wu, wd) if wg is None
+                                  else gated_unit(m, wg, wu, c) @ wd)
 
-    out = jax.lax.map(one, (first + jnp.arange(w_gate.shape[0]), w_gate,
+    out = jax.lax.map(one, (first + jnp.arange(w_up.shape[0]), w_gate,
                             w_up, w_down)).sum(0)
     load = jnp.sum(idx[:, :, None] == jnp.arange(e), axis=(0, 1),
                    dtype=jnp.int32)
@@ -606,10 +628,21 @@ def passes(cfg, params, ids, pos, next_ids=None, found=None):
         return [w for _ in range(n)
                 for w in (take(2) if has else take(1) + [None])]
 
+    def ungated_experts():
+        """nemotron_h's experts: ([latent_down], (router, expert bias or
+        None, None for the gate there is not, w_up, w_down), [latent_up],
+        the shared expert's two or None)."""
+        latent = 1 if c["moe_latent_size"] else 0
+        return (take(latent), take(1) + (
+            take(1) if c["use_expert_bias"] else [None]) + [None] + take(2),
+            take(latent),
+            take(2) if c["shared_expert_intermediate_size"] else None)
+
     sandwich = c["sandwich_norm"]
     routed = c["ffn_layers"].count("experts")   # the terms' mean is theirs
     embedding = take(1)[0]
-    weights = []                # a layer: (N1, mixer, N2, N3, ffn, N4)
+    weights = []                # a layer: (N1, mixer, N2, N3, ffn, N4), or
+    one_branch_kind = {}        # (N, its one branch's own): layer -> kind
     streams, mtp = c["hc_mult"], c["mtp_layers"]
     hcs = []                    # a layer: (attention's, the FFN's) (phi, b,
     w_f = module = None         # alpha), or (None, None)
@@ -618,6 +651,21 @@ def passes(cfg, params, ids, pos, next_ids=None, found=None):
             # the module lies behind the final norm: enorm, hnorm, eh_proj,
             # then a layer's own, then shared_head.norm
             w_f, module = take(1)[0], take(3)
+        kind = c["mixer_layers"][i] if c["ffn_layers"][i] == "none" \
+            else c["ffn_layers"][i] if c["mixer_layers"][i] == "none" \
+            else None
+        if kind is not None:
+            # one branch behind one norm (hybrid_override_pattern): a
+            # mixer's own (w_in .. w_out, or wq, wk, wv, wo), the experts'
+            # (`ungated_experts`) or a dense MLP's two
+            hcs.append((None, None))
+            one_branch_kind[i] = kind
+            weights.append((take_norm(), (
+                take(2) + (take(1) if c["mamba_conv_bias"] else [None])
+                + take(5) if kind == "mamba2"
+                else take(3) + [None, None] + take(1) if kind == "attention"
+                else ungated_experts() if kind == "experts" else take(2))))
+            continue
         hc_a = take(3) if streams > 1 else None
         n1 = take_norm()
         if c["mixer_layers"][i] == "mamba":
@@ -652,10 +700,15 @@ def passes(cfg, params, ids, pos, next_ids=None, found=None):
         # and its gate's weight]
         shared = 0 if not c["shared_expert_intermediate_size"] \
             else 4 if c["shared_expert_gate"] else 3
-        ffn = take(2) if c["mlp_gate_up_fused"] \
-            else take(3) if c["ffn_layers"][i] == "dense" else (
-            take(1) + (take(1) if c["use_expert_bias"] else [None])
-            + take(3 + shared))
+        if not c["ffn_gated"]:
+            # a module's layer under hybrid_override_pattern: its experts
+            # as a one-branch layer's
+            ffn = ungated_experts()
+        else:
+            ffn = take(2) if c["mlp_gate_up_fused"] \
+                else take(3) if c["ffn_layers"][i] == "dense" else (
+                take(1) + (take(1) if c["use_expert_bias"] else [None])
+                + take(3 + shared))
         weights.append((n1, mixer, n2, n3, ffn,
                         take(1)[0] if sandwich else None))
     if mtp:
@@ -694,8 +747,53 @@ def passes(cfg, params, ids, pos, next_ids=None, found=None):
 
         handed_on = {}          # what one layer leaves for later ones
 
+        def latent_experts(m, ffn, terms):
+            """(nemotron_h's LatentMoE on m [B, T, D], terms with its own):
+            u = m W_dn; the routed experts on u, the router on m; that W_up;
+            + the shared expert on m. A dict given as `found` gets the first
+            such layer's `latent` (u), `routed` (the experts' sum before
+            W_up), `routed_out` (behind it) and `shared`."""
+            down, (router, bias, _, wu, wd), up, shared = ffn
+            flat = m.reshape(b * t, d)
+            u = flat @ down[0] if down else flat
+            r, lb, lz, ld = routed_experts(
+                u, router, None, wu, wd, c, router_x=flat, expert_bias=bias)
+            out = (r @ up[0] if up else r).reshape(b, t, d)
+            kept = {"latent": u, "routed": r, "routed_out": out}
+            if shared is not None:
+                kept["shared"] = relu2_mlp(m, *shared)
+                out = out + kept["shared"]
+            if found is not None:
+                for name, value in kept.items():
+                    found.setdefault(name, value)
+            return out, (terms[0] + lb / routed, terms[1] + lz / routed,
+                         terms[2] + ld)
+
+        def one_branch(h, i, terms):
+            """hybrid_override_pattern's layer i: h + f(N(h)), f a mixer or
+            an FFN. A dict given as `found` gets `layers`, the state after
+            each."""
+            (n, own), kind = weights[i], one_branch_kind[i]
+            a = norm(h, n)
+            if kind == "mamba2":
+                out = mamba2(a, *own, eps, found=found,
+                             groups=c["mamba_n_groups"])
+            elif kind == "attention":
+                out = attention(a, pos, *own, layer_config(c, i))
+                if found is not None:
+                    found.setdefault("attention", out)
+            elif kind == "experts":
+                out, terms = latent_experts(a, own, terms)
+            else:
+                out = relu2_mlp(a, *own)
+            if found is not None:
+                found.setdefault("layers", []).append(h + out)
+            return h + out, terms
+
         def layer(h, i, terms):
             """Layer i on h; terms = (balance, z, load) with the layer's."""
+            if i in one_branch_kind:
+                return one_branch(h, i, terms)
             n1, mixer, n2, n3, ffn, n4 = weights[i]
             x, write = read(h, hcs[i][0])
             a = norm(x, n1)
@@ -708,7 +806,8 @@ def passes(cfg, params, ids, pos, next_ids=None, found=None):
             elif c["mixer_layers"][i] == "gmu":
                 mixed = gated_memory_unit(a, handed_on["memory"], *mixer)
             elif c["mixer_layers"][i] == "mamba2":
-                mixed = mamba2(a, *mixer, eps, found=found)
+                mixed = mamba2(a, *mixer, eps, found=found,
+                               groups=c["mamba_n_groups"])
             elif c["differential_attention"]:
                 wq, bq, own, lambdas, subln, wo, bo = mixer
                 mixed, kv = differential_attention(
@@ -734,7 +833,9 @@ def passes(cfg, params, ids, pos, next_ids=None, found=None):
             h = write(branch * mixed)
             x, write = read(h, hcs[i][1])
             m = norm(x, n3)
-            if c["ffn_layers"][i] == "experts":
+            if not c["ffn_gated"]:
+                out, terms = latent_experts(m, ffn, terms)
+            elif c["ffn_layers"][i] == "experts":
                 out, lb, lz, ld = routed_experts(
                     m.reshape(b * t, d), ffn[0], *ffn[2:5], c,
                     router_x=a.reshape(b * t, d)

@@ -95,14 +95,16 @@ def _selective_scan(ctx, ins, attrs):
     return {"Out": [out.astype(x.dtype)]}
 
 
-def ssd_scan_path(x):
+def ssd_scan_path(x, groups=1):
     """"kernel" where ssd_kernels' two passes over chunks run for X [B, T,
     H, P]: kernel_config.pallas_on("ssd") (a TPU, or PADDLE_TPU_PALLAS) and
-    heads that fill whole lane tiles (P divides 128); else "scan", the same
-    chunked form in jax.numpy under lax.scan. The one place that decides;
-    the layer counter reads it too."""
+    heads that fill whole lane tiles (P divides 128), a group's where B and
+    C come in `groups`; else "scan", the same chunked form in jax.numpy
+    under lax.scan. The one place that decides; the layer counter reads it
+    too."""
     from .ssd_kernels import applies
-    fits = x.ndim == 4 and applies(x.shape[2], x.shape[3])
+    fits = x.ndim == 4 and x.shape[2] % groups == 0 \
+        and applies(x.shape[2] // groups, x.shape[3])
     return "kernel" if fits and pallas_on("ssd") else "scan"
 
 
@@ -111,7 +113,8 @@ def _ssd_scan(ctx, ins, attrs):
     """Out [B, T, H, P] of the state-space-dual scan s_t = exp(Delta_t A)
     s_(t-1) + B_t^T (Delta_t x_t), y_t = C_t s_t + D x_t a head, for X [B,
     T, H, P], Delta [B, T, H], A (negative) and D [H], and B, C [B, T, N]
-    that all heads read. Under AMP the matmuls take bf16 operands; Delta,
+    that all heads read, or [B, T, G, N], head h reading group h // (H /
+    G). Under AMP the matmuls take bf16 operands; Delta,
     A, the running sums, every exponential, the state and every accumulator
     are float32 either way, so the op is in neither AMP table. The result
     comes back in X's dtype."""
@@ -119,6 +122,7 @@ def _ssd_scan(ctx, ins, attrs):
     x, delta, a, b, c, d = (single(ins, name) for name in (
         "X", "Delta", "A", "B", "C", "D"))
     out = ssd_scan(
-        x, delta, a, b, c, d, path=ssd_scan_path(x),
+        x, delta, a, b, c, d,
+        path=ssd_scan_path(x, b.shape[2] if b.ndim == 4 else 1),
         operand_dtype=jnp.bfloat16 if getattr(ctx, "amp", False) else None)
     return {"Out": [out.astype(x.dtype)]}

@@ -260,14 +260,15 @@ def _layer_norm(ctx, ins, attrs):
             "Mean": [mean.reshape(lead)], "Variance": [var.reshape(lead)]}
 
 
-def _rms_norm_math(x, scale, gate, begin, eps, zero_centered):
+def _rms_norm_math(x, scale, gate, begin, eps, zero_centered, scaled=None):
     x32 = x.astype(jnp.float32)
     ms = jnp.mean(jnp.square(x32), axis=tuple(range(begin, x.ndim)),
                   keepdims=True)
     scale = scale.astype(jnp.float32)
     if zero_centered:
         scale = 1.0 + scale
-    y = x32 * lax.rsqrt(ms + eps) * scale.reshape(x.shape[begin:])
+    y = x32 * lax.rsqrt(ms + eps) * scale.reshape(
+        x.shape[begin if scaled is None else scaled:])
     if gate is not None:
         y = y * jax.nn.silu(gate.astype(jnp.float32))
     return y.astype(x.dtype)
@@ -278,7 +279,10 @@ def _rms_norm(ctx, ins, attrs):
     """y = scale * x / sqrt(mean(x^2) + eps) over the axes from
     begin_norm_axis on; the statistics and the product accumulate in
     float32 whatever x's dtype is, and y comes back in it. zero_centered:
-    the weight is stored around 0, y = (1 + scale) * x_hat. Gate, where
+    the weight is stored around 0, y = (1 + scale) * x_hat.
+    begin_scale_axis: the scale has an element for every position from that
+    axis on (an axis before begin_norm_axis: a norm a group under one
+    weight over all groups). Gate, where
     given (x's shape): y = scale * x_hat * silu(gate), under jax.checkpoint
     so that the backward pass keeps x and the gate as they came and not
     their float32 copies (192 MiB a layer at [2, 4096, 32, 128]; AOT
@@ -286,7 +290,8 @@ def _rms_norm(ctx, ins, attrs):
     cell shows one winning."""
     x = single(ins, "X")
     args = (attrs.get("begin_norm_axis", 1) % x.ndim,
-            attrs.get("epsilon", 1e-5), attrs.get("zero_centered", False))
+            attrs.get("epsilon", 1e-5), attrs.get("zero_centered", False),
+            attrs.get("begin_scale_axis"))
     if ins.get("Gate"):
         y = jax.checkpoint(lambda x, s, g: _rms_norm_math(x, s, g, *args))(
             x, single(ins, "Scale"), single(ins, "Gate"))

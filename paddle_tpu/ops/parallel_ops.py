@@ -133,11 +133,13 @@ registry.register("moe", _moe_lower, infer=registry.shapes_from(
 
 
 def _moe_ffn_lower(ctx, ins, attrs):
-    """Dropless top-k routed gated experts (parallel/moe.py routed_ffn).
+    """Dropless top-k routed experts (parallel/moe.py routed_ffn), gated
+    (WGate, WUp, WDown) or, without WGate, of two matrices.
     Under AMP the router stays float32 and the experts compute in bfloat16
     from the float32 master weights; the op decides that here because one
     input, X, may feed both. RouterX, where the model gives it, is what the
-    router reads instead of X; the weights' leading dimension is the experts
+    router reads instead of X, at a width of its own where the Router's
+    rows say so; the weights' leading dimension is the experts
     held, `first_expert` the index of the first. `scoring` (softmax where
     absent, or sigmoid), ExpertBias [E] (added to the scores for the choice
     of the top_k alone; an input without a gradient variable), `scale` and
@@ -147,7 +149,8 @@ def _moe_ffn_lower(ctx, ins, attrs):
     router_x = single(ins, "RouterX") if ins.get("RouterX") else None
     out, balance, z, load = routed_ffn(
         x.reshape(-1, x.shape[-1]), single(ins, "Router"),
-        single(ins, "WGate"), single(ins, "WUp"), single(ins, "WDown"),
+        single(ins, "WGate") if ins.get("WGate") else None,
+        single(ins, "WUp"), single(ins, "WDown"),
         top_k=int(attrs["top_k"]),
         norm_topk_prob=bool(attrs.get("norm_topk_prob", False)),
         expert_dtype=jnp.bfloat16 if getattr(ctx, "amp", False) else None,

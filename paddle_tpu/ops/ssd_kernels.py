@@ -7,7 +7,10 @@ channels with a state [N, P], from s = 0:
     y_t[h] = C_t s_t + D[h] x_t[h]
 
 for x [B, T, H, P], Delta [B, T, H], A (negative) and D [H], and B, C [B, T,
-N], which ALL heads share (one group). Computed here Q tokens at a time, so
+N], which ALL heads share (one group), or [B, T, G, N]: head h reads group
+h // (H / G), and a grid step takes its heads from one group (the operands
+then lie a group at a time, [B, G, T', N], and a step's block is its group's
+chunk: the same two kernels). Computed here Q tokens at a time, so
 that the sequential depth is T / Q. With a_t = Delta_t A, c the running sum
 of a inside a chunk, S the state that enters it, X = Delta * x and i, j
 positions inside it:
@@ -120,7 +123,8 @@ _TN = ((0,), (0,))      # a.T @ b
 
 def _operands(x, delta, a, b, c, *, chunk, block_h, dt):
     """x [B, T, H, P], delta [B, T, H], a [H], b, c [B, T, N] -> the
-    kernels' operands: x as [B, T', H x P] and b, c [B, T', N] in `dt`;
+    kernels' operands: x as [B, T', H x P] and b, c [B, T', N] in `dt` (b, c
+    [B, T, G, N]: [B, G, T', N], a group's tokens together);
     delta and the running sum of delta x a inside each chunk as [B, H /
     block_h, T', block_h] (a head a column) and that sum again as [B, H /
     block_h, block_h, T'] (a head a row), float32. T' is T padded to whole
@@ -139,10 +143,13 @@ def _operands(x, delta, a, b, c, *, chunk, block_h, dt):
     def columns(v):
         return jnp.moveaxis(v.reshape(batch, -1, h // block_h, block_h), 2, 1)
 
+    def shared(v):              # a group's tokens together
+        v = tokens(v).astype(dt)
+        return v if v.ndim == 3 else jnp.swapaxes(v, 1, 2)
+
     return (tokens(x).reshape(batch, n * chunk, h * p).astype(dt),
             columns(delta), columns(run),
-            jnp.swapaxes(columns(run), 2, 3),
-            tokens(b).astype(dt), tokens(c).astype(dt))
+            jnp.swapaxes(columns(run), 2, 3), shared(b), shared(c))
 
 
 # ---- the chunked form in jax.numpy ------------------------------------------
@@ -155,6 +162,19 @@ def _scan_path(x, delta, a, b, c, *, chunk, dt):
     batch, t, h, p = x.shape
     n = -(-t // chunk)
     pad = [(0, 0), (0, n * chunk - t)]
+    if b.ndim == 4:
+        # G groups: the heads of a group are a batch of their own, [B x G,
+        # T, H / G, P] under the group's b and c
+        g = b.shape[2]
+
+        def of(v, k):                   # group k's heads of [B, T, H, ..]
+            return v.reshape((batch, t, g, h // g) + v.shape[3:])[:, :, k]
+
+        y = jnp.stack([_scan_path(
+            of(x, k), of(delta, k), a.reshape(g, -1)[k], b[:, :, k],
+            c[:, :, k], chunk=chunk, dt=dt)
+            for k in range(g)], axis=2)                 # [B, T, G, H / G, P]
+        return y.reshape(batch, t, h, p)
 
     def chunks(v):
         v = jnp.pad(v, pad + [(0, 0)] * (v.ndim - 2))
@@ -334,15 +354,26 @@ _PARAMS = pltpu.CompilerParams(
     dimension_semantics=("parallel", "parallel", "arbitrary"))
 
 
-def _specs(chunk, width, block_h, n, at):
+def _specs(chunk, width, block_h, n, at, blocks_a_group=None):
     """BlockSpecs of (x-like [B, T', H x P], a head a column, a head a row,
-    b-like [B, T', N]) arrays, chunk `at(i)` of grid step i."""
+    b-like [B, T', N]) arrays, chunk `at(i)` of grid step i. With
+    `blocks_a_group` (so many blocks of heads read one group of b and c) the
+    b-like arrays are [B, G, T', N] and a step's block its group's."""
     return (_vmem((None, chunk, width), lambda b, h, i: (b, at(i), h)),
             _vmem((None, None, chunk, block_h),
                   lambda b, h, i: (b, h, at(i), 0)),
             _vmem((None, None, block_h, chunk),
                   lambda b, h, i: (b, h, 0, at(i))),
-            _vmem((None, chunk, n), lambda b, h, i: (b, at(i), 0)))
+            _vmem((None, chunk, n), lambda b, h, i: (b, at(i), 0))
+            if blocks_a_group is None else
+            _vmem((None, None, chunk, n),
+                  lambda b, h, i: (b, h // blocks_a_group, at(i), 0)))
+
+
+def _blocks_a_group(b, blocks):
+    """The blocks of heads that read one group of b [B, G, T', N], `blocks`
+    of them in all; None for b [B, T', N], the one group all read."""
+    return None if b.ndim == 3 else blocks // b.shape[1]
 
 
 # The two calls are jax.jits of their own, everything but the arrays static
@@ -357,8 +388,9 @@ def _fwd_call(ops, *, p, chunk, block_h, emit, interpret):
     x, b = ops[0], ops[4]
     batch, t, hp = x.shape
     width, n, chunks = block_h * p, b.shape[-1], t // chunk
-    tokens, column, row, shared = _specs(chunk, width, block_h, n,
-                                         lambda i: i)
+    tokens, column, row, shared = _specs(
+        chunk, width, block_h, n, lambda i: i,
+        _blocks_a_group(b, hp // width))
     # the backward pass's Y in float32: its row sums are differences
     out_specs, out_shape = [tokens], [jax.ShapeDtypeStruct(
         x.shape, _F32 if emit else x.dtype)]
@@ -385,8 +417,9 @@ def _bwd_call(ops, enters, dy, *, p, chunk, block_h, interpret):
     x, b = ops[0], ops[4]
     batch, t, hp = x.shape
     width, n, chunks = block_h * p, b.shape[-1], t // chunk
-    tokens, column, row, shared = _specs(chunk, width, block_h, n,
-                                         lambda i: chunks - 1 - i)
+    tokens, column, row, shared = _specs(
+        chunk, width, block_h, n, lambda i: chunks - 1 - i,
+        _blocks_a_group(b, hp // width))
     part = _vmem((None, None, chunk, n),
                  lambda b, h, i: (b, h, chunks - 1 - i, 0))
     part_shape = jax.ShapeDtypeStruct((batch, hp // width, t, n), _F32)
@@ -474,11 +507,18 @@ def _kernel_bwd(how, res, dy):
           + whole.reshape(batch, -1, 1, h, p).sum(-1)) \
         .reshape(batch, -1, h)[:, :t]
     dxt = dxt + dxs
+
+    def shared(part):           # a block of heads' part -> b's own shape
+        if b.ndim == 3:
+            return part.sum(1)[:, :t].astype(b.dtype)
+        # [B, G x blocks a group, T', N] -> [B, T, G, N]
+        part = part.reshape((batch, b.shape[2], -1) + part.shape[2:]).sum(2)
+        return jnp.swapaxes(part, 1, 2)[:, :t].astype(b.dtype)
+
     return ((dxt * delta[..., None]).astype(x.dtype),
             (da * a.astype(_F32) + jnp.sum(dxt * xf, -1)).astype(delta.dtype),
             jnp.sum(da * delta, (0, 1)).astype(a.dtype),
-            db.sum(1)[:, :t].astype(b.dtype),
-            dc.sum(1)[:, :t].astype(c.dtype))
+            shared(db), shared(dc))
 
 
 _kernel_path.defvjp(_kernel_fwd, _kernel_bwd)
@@ -496,7 +536,7 @@ def ssd_scan(x, delta, a, b, c, d, operand_dtype=None, path="kernel",
     """y [B, T, H, P] float32 of the state-space-dual scan (module
     docstring) for x [B, T, H, P], delta [B, T, H] (> 0, after its
     softplus), a (negative) and d [H], and b, c [B, T, N], one group that
-    all heads read.
+    all heads read, or [B, T, G, N], head h reading group h // (H / G).
 
     path "kernel": the Pallas kernels (Mosaic where the program dispatches
     to a TPU, the interpreter elsewhere; `applies` says which shapes they
@@ -506,12 +546,14 @@ def ssd_scan(x, delta, a, b, c, d, operand_dtype=None, path="kernel",
     128s), and a T that is no multiple of it is padded with tokens that
     neither write nor decay."""
     batch, t, h, p = x.shape
-    n = b.shape[-1]
+    n, groups = b.shape[-1], b.shape[2] if b.ndim == 4 else 1
     if delta.shape != (batch, t, h) or a.shape != (h,) or d.shape != (h,) \
-            or b.shape != (batch, t, n) or c.shape != b.shape:
+            or b.shape not in ((batch, t, n), (batch, t, groups, n)) \
+            or c.shape != b.shape or h % groups:
         raise ValueError(
             "ssd_scan: x [B, T, H, P], delta [B, T, H], a and d [H], b and "
-            "c [B, T, N] alike; got x %s, delta %s, a %s, b %s, c %s, d %s"
+            "c [B, T, N] or [B, T, G, N] alike, G a divisor of H; got x %s, "
+            "delta %s, a %s, b %s, c %s, d %s"
             % (x.shape, delta.shape, a.shape, b.shape, c.shape, d.shape))
     if path not in ("kernel", "scan"):
         raise ValueError("ssd_scan: path must be 'kernel' or 'scan', got %r"
@@ -525,10 +567,12 @@ def ssd_scan(x, delta, a, b, c, d, operand_dtype=None, path="kernel",
     if path == "scan":
         y = _scan_path(x, delta, a, b, c, chunk=int(chunk), dt=dt)
     else:
-        if not applies(h, p):
+        if not applies(h // groups, p):
             raise ValueError(
                 "ssd_scan: the kernels take heads whose width divides 128, "
-                "whole lane tiles of them; got %d heads of %d" % (h, p))
-        y = _kernel_path((int(chunk), _block_h(h, _LANES // p), dt.name),
-                         x, delta, a, b, c)
+                "whole lane tiles of them a group; got %d heads of %d in %d "
+                "group(s)" % (h, p, groups))
+        # a grid step's heads are one group's
+        y = _kernel_path((int(chunk), _block_h(h // groups, _LANES // p),
+                          dt.name), x, delta, a, b, c)
     return y + d.astype(_F32)[:, None] * x.astype(_F32)

@@ -501,7 +501,14 @@ def _combine_bwd(res, g):
     else:
         dy, dweight = _held_weighted(y, g, token, weight, total,
                                      tile=ROW_TILE)
-    return dy, dweight[rank].reshape(gate.shape), None, None, None, None
+    if dweight.shape[0] < rank.shape[0]:
+        # a buffer cut to the rows that can be held (`routed_ffn`): an
+        # assignment ranked past it is held elsewhere, and its weight's
+        # gradient here is 0
+        by_slot = dweight.at[rank].get(mode="fill", fill_value=0.0)
+    else:
+        by_slot = dweight[rank]
+    return dy, by_slot.reshape(gate.shape), None, None, None, None
 
 
 _combine.defvjp(_combine_fwd, _combine_bwd)
@@ -529,6 +536,22 @@ def _gated_unit(activation):
 
 def _gated(gate, up, activation):
     return _gated_unit(activation)(gate, up)
+
+
+def _ungated_relu2(up):
+    """relu(up)^2 in float32, back in the input's dtype: the unit of an
+    expert of two matrices, which has no gate branch."""
+    r = jax.nn.relu(up.astype(jnp.float32))
+    return (r * r).astype(up.dtype)
+
+
+def _ungated_unit(activation):
+    """The unit of an ungated expert (`w_gate` None), act(x @ w_up), looked
+    up in the module when called."""
+    if activation != "relu2":
+        raise ValueError("routed_ffn without w_gate takes activation "
+                         "'relu2', got %r" % (activation,))
+    return _ungated_relu2
 
 
 def _matmul_transposes(lhs, rhs, sizes, plan, d_out):
@@ -565,6 +588,21 @@ def _held_gated_transpose(gate, up, d_hidden, total, *, unit, tile):
                        (d_hidden, jnp.zeros_like(up)), tile)
 
 
+@functools.partial(jax.jit, static_argnames=("unit", "tile"))
+def _held_ungated_transpose(up, d_hidden, total, *, unit, tile):
+    """d up of unit(up) in the tiles below `total`, written over d hidden,
+    tile by tile (from `total` on it holds what d hidden held)."""
+    def one_tile(start, tile, live, d_hidden_then_up):
+        met = _met(d_hidden_then_up, start, tile)
+        d_up_rows, = jax.vjp(unit, jax.lax.dynamic_slice(
+            up, (start, 0), (tile, up.shape[1])))[1](met)
+        return jax.lax.dynamic_update_slice(
+            d_hidden_then_up,
+            jnp.where(_first_time(start, tile), d_up_rows, met), (start, 0))
+
+    return _held_tiles(up.shape[0], total, one_tile, d_hidden, tile)
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(10,))
 def _held_experts(x, w_gate, w_up, w_down, order, rank, sizes, plan, total,
                   places, activation):
@@ -572,7 +610,10 @@ def _held_experts(x, w_gate, w_up, w_down, order, rank, sizes, plan, total,
     [A, D], through `_held_rows`, the three grouped matmuls and `_gated`; the
     integers as in `_combine`, `plan` as in `_grouped_matmul`. One rule, so
     that its backward pass can run the passes between the matmuls'
-    transposes in the held tiles."""
+    transposes in the held tiles. `w_gate` None: experts of two matrices,
+    act(rows @ w_up) @ w_down; of the forward pass the rule then keeps the
+    rows and `up` alone, and its backward pass makes the hidden rows again
+    (one pass, where keeping them is a buffer a layer)."""
     return _held_experts_fwd(x, w_gate, w_up, w_down, order, rank, sizes,
                              plan, total, places, activation)[0]
 
@@ -580,6 +621,12 @@ def _held_experts(x, w_gate, w_up, w_down, order, rank, sizes, plan, total,
 def _held_experts_fwd(x, w_gate, w_up, w_down, order, rank, sizes, plan,
                       total, places, activation):
     rows = _held_rows(x, order, total, tile=ROW_TILE)
+    if w_gate is None:
+        up = _grouped_matmul(rows, w_up, sizes, plan)
+        return _grouped_matmul(_ungated_unit(activation)(up), w_down, sizes,
+                               plan), (
+            rows, None, up, None, None, w_up, w_down, rank, sizes, plan,
+            total, places)
     gate = _grouped_matmul(rows, w_gate, sizes, plan)
     up = _grouped_matmul(rows, w_up, sizes, plan)
     hidden = _gated(gate, up, activation)
@@ -595,6 +642,15 @@ def _held_experts_bwd(activation, res, dy):
     past `total` holds whatever the transposes left there."""
     rows, gate, up, hidden, w_gate, w_up, w_down, rank, sizes, plan, total, \
         places = res
+    if w_gate is None:
+        unit = _ungated_unit(activation)
+        d_hidden, d_down = _matmul_transposes(unit(up), w_down, sizes, plan,
+                                              dy)
+        d_rows, d_w_up = _matmul_transposes(
+            rows, w_up, sizes, plan, _held_ungated_transpose(
+                up, d_hidden, total, unit=unit, tile=ROW_TILE))
+        return (_token_sum((d_rows,), rank, places, tile=SUM_TILE), None,
+                d_w_up, d_down) + (None,) * 6
     d_hidden, d_down = _matmul_transposes(hidden, w_down, sizes, plan, dy)
     d_gate, d_up = _held_gated_transpose(
         gate, up, d_hidden, total, unit=_gated_unit(activation), tile=ROW_TILE)
@@ -651,10 +707,14 @@ def routed_ffn(x, router, w_gate, w_up, w_down, top_k, norm_topk_prob=False,
 
     router [D, E]; w_gate, w_up [H, D, F]; w_down [H, F, D]; no bias. The
     gated unit is act(x @ w_gate) * (x @ w_up) with `activation` "silu"
-    (SwiGLU) or "relu" (ReGLU). The router reads `router_x` [N, D] where it
-    is given (a model that routes from another tensor than the one the
-    experts transform, so that a deployment can fetch experts early) and x
-    itself where it is None.
+    (SwiGLU) or "relu" (ReGLU). `w_gate` None: experts of two matrices,
+    act(x @ w_up) @ w_down with `activation` "relu2", relu(.)^2; whatever
+    this docstring says of the three matmuls and of `_gated` then holds of
+    the two and of the unit between them. The router reads `router_x` [N,
+    Dr] where it is given (a model that routes from another tensor than the
+    one the experts transform, so that a deployment can fetch experts early;
+    of another width where the experts work in a latent space: router [Dr,
+    E]) and x itself where it is None.
 
     H is the experts held: the weights' leading dimension. With H = E every
     expert lives here. With H < E this is one chip's share of an
@@ -670,8 +730,10 @@ def routed_ffn(x, router, w_gate, w_up, w_down, top_k, norm_topk_prob=False,
     last, and the three expert matmuls run grouped over the sorted rows
     (`_grouped_matmul`) with the held experts' counts as group sizes, so
     they cost the held assignments' operations and not the stored experts'.
-    The row buffer is top_k * N rows whatever H is, because every one of a
-    token's choices may be held (1.5 N on average at 6 of 64 with 16 held).
+    The row buffer is min(top_k, H) * N rows, because every one of a
+    token's choices may be held (1.5 N on average at 6 of 64 with 16 held)
+    and its choices are distinct experts, so no more than H of them are
+    (22 of 512 a token on 8 held: 8 N rows and not 22 N).
 
     Four permutations move rows, a layer's forward and backward, and where
     a share is held (`rows_moved`) all four, and the elementwise passes
@@ -734,7 +796,7 @@ def routed_ffn(x, router, w_gate, w_up, w_down, top_k, norm_topk_prob=False,
              c[first_expert : first_expert + H] are the rows computed).
     """
     n = x.shape[0]
-    e, held = router.shape[1], w_gate.shape[0]
+    e, held = router.shape[1], w_up.shape[0]
     if not 0 <= first_expert <= e - held:
         raise ValueError("routed_ffn holds experts %d..%d of %d"
                          % (first_expert, first_expert + held - 1, e))
@@ -764,23 +826,33 @@ def routed_ffn(x, router, w_gate, w_up, w_down, top_k, norm_topk_prob=False,
     order = jnp.argsort(sort_key, stable=True)
     rank = jnp.zeros_like(order).at[order].set(
         jnp.arange(order.shape[0], dtype=order.dtype))
+    # a token's choices are distinct experts, so at most `held` of them are
+    # held: the sorted rows past n * held belong to no group whatever the
+    # router does, and no buffer has them
+    if total is not None and held < top_k:
+        order = order[:n * held]
 
     # the kernels' visits, once for the layer's nine matmuls
     plan = None
-    if matmul_route(x.shape[1], w_gate.shape[2], dtype, mesh) \
+    if matmul_route(x.shape[1], w_up.shape[2], dtype, mesh) \
             == KERNEL_MATMUL:
         from ..ops import expert_gmm
         plan = expert_gmm.plan(sizes, order.shape[0])
     if total is None:
         rows, places = _dispatch(x.astype(dtype), order, rank), None
-        hidden = _gated(
-            _grouped_matmul(rows, w_gate.astype(dtype), sizes, plan),
-            _grouped_matmul(rows, w_up.astype(dtype), sizes, plan),
-            activation)
+        if w_gate is None:
+            hidden = _ungated_unit(activation)(
+                _grouped_matmul(rows, w_up.astype(dtype), sizes, plan))
+        else:
+            hidden = _gated(
+                _grouped_matmul(rows, w_gate.astype(dtype), sizes, plan),
+                _grouped_matmul(rows, w_up.astype(dtype), sizes, plan),
+                activation)
         y = _grouped_matmul(hidden, w_down.astype(dtype), sizes, plan)
     else:
         places = _token_places(rank, total, top_k)
-        y = _held_experts(x.astype(dtype), w_gate.astype(dtype),
+        y = _held_experts(x.astype(dtype),
+                          None if w_gate is None else w_gate.astype(dtype),
                           w_up.astype(dtype), w_down.astype(dtype), order,
                           rank, sizes, plan, total, places, activation)
     out = _combine(y, gate, order, rank, total, places)

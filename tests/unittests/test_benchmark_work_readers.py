@@ -43,6 +43,9 @@ GRANITE = "granite_4_0_h_micro_train_t2048"
 # the three metrics PR 57 added with its cell
 GRANITE_METRICS = ("ssd_scan_ms_per_step", "ssd_scan_roofline_share",
                    "ssd_layer_share")
+NEMOTRON = "nemotron_3_super_120b_a12b_train_t4096"
+# the two metrics PR 61 added with its cell
+NEMOTRON_METRICS = ("latent_moe_layer_share", "single_branch_layer_share")
 
 
 def test_glm_flash_operations_against_the_hand_count():
@@ -109,24 +112,27 @@ def test_the_pinned_manifest_test_is_red_for_the_eleventh_cell_alone(
     configuration and its metric taken off again, and with them the
     twelfth cell (Phi-4-mini-flash's), its configuration and its three
     metrics, and the thirteenth (granite-4.0-h-micro's), its configuration,
-    its traffic's cell and its three metrics: nothing else it holds has
-    moved."""
+    its traffic's cell and its three metrics, and the fourteenth
+    (Nemotron-3-Super's), its configuration and its two metrics: nothing
+    else it holds has moved."""
     pinned = getattr(readers, PINNED)
     with pytest.raises(AssertionError, match="^expert_matmul_ms_per_step$"):
         pinned()
     with open(readers.BENCHMARK) as f:
         bench = json.load(f)
     bench["workloads"] = [w for w in bench["workloads"]
-                          if w["name"] not in (GLM, PHI, GRANITE)]
+                          if w["name"] not in (GLM, PHI, GRANITE, NEMOTRON)]
     bench["configs"] = [c for c in bench["configs"]
                         if c["name"] not in ("glm_4_7_flash",
                                              "phi4_mini_flash",
-                                             "granite_4_0_h_micro")]
+                                             "granite_4_0_h_micro",
+                                             "nemotron_3_super_120b_a12b")]
     bench["per_layer"] = [m for m in bench["per_layer"]
                           if m["name"] not in ("mtp_layer_share",)
-                          + PHI_METRICS + GRANITE_METRICS]
+                          + PHI_METRICS + GRANITE_METRICS
+                          + NEMOTRON_METRICS]
     for metric in bench["end_to_end"] + bench["per_layer"]:
-        for cell in (GLM, PHI, GRANITE):
+        for cell in (GLM, PHI, GRANITE, NEMOTRON):
             if cell in metric.get("workloads", ()):
                 metric["workloads"].remove(cell)
     without = tmp_path / "BENCHMARK.json"
@@ -183,7 +189,8 @@ def test_the_manifest_lists_the_work_readers_in_eleven_cells():
     # the 8192 rows in VMEM)
     for name in ("flash_roofline_share", "embedding_grad_ms_per_step",
                  "embedding_grad_roofline_share", "step_mfu"):
-        assert entries[name]["workloads"][-2:] == [PHI, GRANITE], name
+        assert entries[name]["workloads"][-3:] == [PHI, GRANITE,
+                                                   NEMOTRON], name
     phi = readers._cell(PHI)
     pairs = (512 * 513 // 2 + (8192 - 512) * 512) + 2 * (8192 * 8193 // 2)
     assert phi.config_module.flash_kernel_ops(phi.config, phi.traffic) == {
@@ -217,7 +224,48 @@ def test_the_manifest_lists_the_work_readers_in_eleven_cells():
         2 * 2048 * (2 * 64.5 * 128 + 64 * (2 * 64.5 * 64 + 4 * 128 * 64)),
         2048 * (3 * 2 * 4096 + 4 * 2 * 128 + 2 * 4 * 64))
     assert [m["workloads"] for m in bench["per_layer"]
-            if m["name"] in GRANITE_METRICS] == [[GRANITE]] * 3
+            if m["name"] in GRANITE_METRICS] == [[GRANITE, NEMOTRON]] * 3
+    # the fourteenth cell behind that one on the lists whose reader finds
+    # something in its trace (the scan's, the flash kernels', the experts',
+    # the embedding gradient's, the loss's), with its module's hand counts:
+    # one core of 4 query heads of 128 on 1, causal at T = 4096; the table
+    # of 16384 words of 4096 written; the scan's two kernels at 16 heads and
+    # one group, five layers; and TWO matmuls an expert of [1024 x 2688]
+    # (configs/causal_lm.py's three would read 1.5 times the work)
+    for name in ("expert_matmul_ms_per_step", "expert_matmul_roofline_share"):
+        assert entries[name]["workloads"][-2:] == [GLM, NEMOTRON], name
+    for name in ("pallas_ms_per_step", "softmax_xent_ms_per_step",
+                 "flash_fwd_ms_per_step", "flash_bwd_dkdv_ms_per_step",
+                 "flash_bwd_dq_ms_per_step"):
+        assert entries[name]["workloads"][-2:] == [GRANITE, NEMOTRON], name
+    nemotron = readers._cell(NEMOTRON)
+    mod = nemotron.config_module
+    pairs = 4096 * 4097 // 2
+    assert mod.flash_kernel_ops(nemotron.config, nemotron.traffic) == {
+        "ptpu_flash_fwd": 4 * 128 * 4 * pairs,
+        "ptpu_flash_bwd_dkdv": 8 * 128 * 4 * pairs,
+        "ptpu_flash_bwd_dq": 6 * 128 * 4 * pairs}
+    assert mod.embedding_grad_bytes(nemotron.config, nemotron.traffic) \
+        == 4 * 4096 * 16384 == 268435456
+    calls = mod.ssd_kernel_ops(nemotron.config, nemotron.traffic, 128)
+    assert {k: len(v) for k, v in calls.items()} == {"ptpu_ssd_fwd": 10,
+                                                     "ptpu_ssd_bwd": 5}
+    assert calls["ptpu_ssd_bwd"][0] == (
+        2 * 4096 * (2 * 64.5 * 128 + 16 * (2 * 64.5 * 64 + 4 * 128 * 64)),
+        4096 * (3 * 2 * 1024 + 4 * 2 * 128 + 2 * 4 * 16))
+    assert mod.EXPERT_MATMULS == 2 and mod.base.EXPERT_MATMULS == 3
+    even = np.zeros(512, np.int64)
+    even[:8] = 5 * 176              # 22 x 4096 / 512 rows a held expert
+    assert mod.expert_matmul_ops(nemotron.config, nemotron.traffic, even) \
+        == 3 * 2 * 2 * 1024 * 2688 * 7040
+    assert mod.base.expert_matmul_ops is not mod.expert_matmul_ops
+    assert [m["workloads"] for m in bench["per_layer"]
+            if m["name"] in NEMOTRON_METRICS] == [[NEMOTRON]] * 2
+    assert sum(mod.forward_macs(nemotron.config, nemotron.traffic)
+               .values()) == pytest.approx(426.3e6, rel=1e-3)
+    # fourteen cells, one of them on four chips: floor(14 x 0.25) = 3
+    assert [w["name"] for w in bench["workloads"]][13] == NEMOTRON
+    assert [w["chips"] for w in bench["workloads"]][:14].count(4) == 1
     # thirteen cells, one of them on four chips: floor(13 x 0.25) = 3
     assert [w["name"] for w in bench["workloads"]][12] == GRANITE
     assert [w["chips"] for w in bench["workloads"]][:13].count(4) == 1

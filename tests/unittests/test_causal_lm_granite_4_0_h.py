@@ -135,7 +135,7 @@ def test_resolve_reads_granitemoehybrids_keys():
     (dict(position_embedding_type="alibi"), NotImplementedError,
      "position_embedding_type"),
     (dict(mamba_proj_bias=True), NotImplementedError, "mamba_proj_bias"),
-    (dict(mamba_n_groups=2), NotImplementedError, "mamba_n_groups"),
+    (dict(mamba_n_groups=3), ValueError, "whole groups of 3"),
     (dict(num_local_experts=4, num_experts_per_tok=2), NotImplementedError,
      "num_local_experts"),
     (dict(mamba_n_heads=3), ValueError, "Mamba-2 heads"),
