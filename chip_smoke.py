@@ -109,7 +109,12 @@ FULL = {
             dict(name="SmallThinker", n=8192, d=2560, e=64, held=16, f=768,
                  top_k=6, activation="relu", scoring="softmax"),
             dict(name="Qwen3-Next", n=4096, d=2048, e=512, held=32, f=512,
-                 top_k=10, activation="silu", scoring="softmax")),
+                 top_k=10, activation="silu", scoring="softmax"),
+            # Nemotron-3-Super's: a share narrower than top_k, ungated
+            # experts in a latent of 1024 under a router that reads 4096
+            dict(name="Nemotron-3-Super", n=4096, d=1024, router_d=4096,
+                 e=512, held=8, f=2688, top_k=22, gated=False,
+                 activation="relu2", scoring="sigmoid")),
         # Qwen3-Next's gated delta rule at its cell's shapes: one sequence
         # of 4096, 16 key heads on 32 value heads of 128
         gated_delta=dict(b=1, t=4096, hk=16, hv=32, d=128),
@@ -187,7 +192,10 @@ TINY = {
         attn_window=dict(b=2, t=40, h=4, hkv=2, d=16, window=12),
         moe=dict(n=64, d=16, f=8),
         moe_layers=(dict(name="tiny", n=64, d=128, e=8, held=2, f=64,
-                         top_k=3, activation="relu", scoring="softmax"),),
+                         top_k=3, activation="relu", scoring="softmax"),
+                    dict(name="tiny ungated", n=64, d=128, router_d=64, e=16,
+                         held=4, f=128, top_k=3, gated=False,
+                         activation="relu2", scoring="sigmoid")),
         gated_delta=dict(b=2, t=40, hk=2, hv=4, d=16),
         causal_conv=dict(b=2, t=64, c=256, width=4),
         xent=dict(n=32, v=64),
@@ -792,53 +800,70 @@ def _held_layer_times(smoke, c, expert_bias=None):
     """One expert layer of which a share is held, alone at a cell's sizes,
     bf16 experts as under AMP: the token-side sum alone, weighted
     (`_combine` forward) and plain (the backward of the rows' dispatch), in
-    PR 32's form (a gather of all top_k * N rows) and in parallel/moe.py's
+    PR 32's form (a gather of all the numbered rows) and in parallel/moe.py's
     (`_token_sum`: the held rows only), the two compared; then the layer
-    forward and forward + backward. Times for the next reader (no metric);
-    PERF.md section 6, PR 40, quotes them. Sub-millisecond times taken this
-    way hold the host's dispatch."""
+    forward and forward + backward. The assignments are numbered as
+    `moe.numbered_by` says for the shapes (by held expert where the share is
+    narrower than top_k); `gated` False: experts of two matrices, relu2;
+    `router_d`: the router reads a tensor of that width. Times for the next
+    reader (no metric); PERF.md section 6, PR 40, quotes them.
+    Sub-millisecond times taken this way hold the host's dispatch."""
     import jax
     import jax.numpy as jnp
     from paddle_tpu.parallel import moe
 
     n, d, e, held, f, k = (c[key] for key in ("n", "d", "e", "held", "f",
                                               "top_k"))
+    by_expert = moe.numbered_by(e, held, k) == "expert"
+    slots = held if by_expert else k
     rng = np.random.RandomState(29)
     x, g = (jnp.asarray(rng.randn(n, d), jnp.bfloat16) for _ in range(2))
-    router = jnp.asarray(rng.randn(d, e) * 0.02, jnp.float32)
+    router_x = jnp.asarray(rng.randn(n, c["router_d"]), jnp.bfloat16) \
+        if "router_d" in c else None
+    router = jnp.asarray(rng.randn(c.get("router_d", d), e) * 0.02,
+                         jnp.float32)
     wg, wu = (jnp.asarray(rng.randn(held, d, f) * 0.02, jnp.float32)
               for _ in range(2))
+    if not c.get("gated", True):
+        wg = None
     wd = jnp.asarray(rng.randn(held, f, d) * 0.02, jnp.float32)
-    rows = jnp.asarray(rng.randn(k * n, d), jnp.bfloat16)
+    rows = jnp.asarray(rng.randn(slots * n, d), jnp.bfloat16)
 
     def integers(x, router):
-        """gate [top_k, N], rank [A] and the held rows, as routed_ffn makes
+        """gate [slots, N], rank [A] and the held rows, as routed_ffn makes
         them for experts 0 .. held - 1."""
-        logits = jnp.dot(x.astype(jnp.float32), router,
+        logits = jnp.dot((x if router_x is None else router_x)
+                         .astype(jnp.float32), router,
                          precision=jax.lax.Precision.HIGHEST)
         _, _, gate, expert = moe._route(logits, k, True, c["scoring"],
                                         expert_bias, 1.0)
-        expert = expert.T.reshape(-1)
+        expert, gate = expert.T, gate.T
+        if by_expert:
+            sizes = jnp.sum(expert.reshape(-1)[:, None] == jnp.arange(held),
+                            axis=0, dtype=jnp.int32)
+            gate, _, rank, _ = moe._by_held_expert(expert, gate, sizes)
+            return gate, rank, sizes.sum()
+        expert = expert.reshape(-1)
         order = jnp.argsort(jnp.where(expert < held, expert, held),
                             stable=True)
-        return gate.T, jnp.argsort(order), jnp.sum(expert < held)
+        return gate, jnp.argsort(order), jnp.sum(expert < held)
 
     def layer(x, router, wg, wu, wd):
         return moe.routed_ffn(
             x, router, wg, wu, wd, k, True, expert_dtype=jnp.bfloat16,
-            activation=c["activation"], scoring=c["scoring"],
-            expert_bias=expert_bias)[0]
+            router_x=router_x, activation=c["activation"],
+            scoring=c["scoring"], expert_bias=expert_bias)[0]
 
     def trained(x, router, wg, wu, wd, g):
         return jax.vjp(layer, x, router, wg, wu, wd)[1](g)
 
     def new(rows, rank, total, gate=None):
         return moe._token_sum((rows,), rank,
-                              moe._token_places(rank, total, k), gate,
+                              moe._token_places(rank, total, slots), gate,
                               tile=moe.SUM_TILE)
 
     def old(rows, rank, total, gate=None):
-        return _slot_sum_of_pr_32(rows, rank, total, k, gate)
+        return _slot_sum_of_pr_32(rows, rank, total, slots, gate)
 
     with jax.default_device(smoke.device):
         gate, rank, total = jax.jit(integers)(x, router)
@@ -859,11 +884,12 @@ def _held_layer_times(smoke, c, expert_bias=None):
         args = (x, router, wg, wu, wd)
         smoke.say(
             "expert layer at %s's sizes, %d tokens of %d, top-%d of %d, %d "
-            "held of width %d, %d of %d assignments held: a token's sum "
+            "held of width %d, %d of %d assignments held, %d numbered: a "
+            "token's sum "
             "alone, weighted / plain, as a gather of all rows (PR 32) %.3f "
             "/ %.3f ms, over the held rows (%.2e apart at most) %.3f / %.3f "
             "ms; the layer forward %.3f ms, forward + backward %.3f ms"
-            % (c["name"], n, d, k, e, held, f, total, k * n,
+            % (c["name"], n, d, k, e, held, f, total, k * n, slots * n,
                times["old", "weighted"], times["old", "plain"], worst,
                times["new", "weighted"], times["new", "plain"],
                _in_flight_ms(jax.jit(layer), args),

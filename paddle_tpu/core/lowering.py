@@ -709,15 +709,18 @@ def _count_grad_op(path, fwd_type):
 
 def _count_moe_layer(ctx, attrs, ins):
     from ..observability.registry import REGISTRY
-    from ..parallel.moe import matmul_route, rows_moved
+    from ..parallel.moe import matmul_route, numbered_by, rows_moved
     router, w_up = ins["Router"][0], ins["WUp"][0]
     experts, held = router.shape[1], w_up.shape[0]
     # what the defaults leave as it was counts under the labels it always
     # had: an ungated layer says so, and a router that reads another width
-    # than the experts' input says which
+    # than the experts' input says which, and a share narrower than top_k
+    # that its assignments are numbered by held expert
     own = {}
     if not ins.get("WGate"):
         own["gated"] = "false"
+    if numbered_by(experts, held, attrs["top_k"]) == "expert":
+        own["numbered"] = "expert"
     router_input = "pre_attention" if ins.get("RouterX") else "own"
     if router.shape[0] != ins["X"][0].shape[-1]:
         router_input = str(router.shape[0])
@@ -728,7 +731,7 @@ def _count_moe_layer(ctx, attrs, ins):
         "activation, what the router reads (the experts' own input or "
         "another tensor, pre_attention), the grouped-matmul route "
         "(ragged_dot, or expert_gmm: the kernels of ops/expert_gmm.py), the "
-        "rows of the slot-major buffer that every pass between the router "
+        "rows of the sorted buffer that every pass between the router "
         "and the layer's output touches (all, or the tiles of the held "
         "assignments: the four permutations, the two d rows' sum and the "
         "gate's transpose), how the router scores "
@@ -736,7 +739,12 @@ def _count_moe_layer(ctx, attrs, ins):
         "the top_k and the factor that scales the weights; `gated` false "
         "where an expert is two matrices (activation relu2), and under "
         "router_input the router's own input width where it is not the "
-        "experts'"
+        "experts'; `numbered` expert where the share held is narrower than "
+        "top_k and the assignments are numbered by held expert (held * N of "
+        "them, a group's rows by token and a token's sum in expert order) "
+        "and not by top-k slot (top_k * N, by slot then token, in score "
+        "order), as they are wherever the label is absent "
+        "(moe.numbered_by)"
     ).inc(top_k=str(attrs["top_k"]), experts=str(experts), held=str(held),
           activation=str(attrs.get("activation", "silu")),
           router_input=router_input,

@@ -19,7 +19,9 @@ is spent on an expert a token did not choose. It can be told which experts
 it holds: it then routes over all of them and computes its own share of the
 layer's output, as one chip of an expert-parallel layer would, without the
 exchange. Its row buffer (top_k * N rows) is numbered slot-major, so that a
-token's slots are summed without a relayout. Where a share is held, the
+token's slots are summed without a relayout; a share narrower than top_k
+numbers its assignments by held expert instead (held * N: `numbered_by`).
+Where a share is held, the
 passes between the router and the layer's output touch the held rows only
 (all but `_gated` forward, one elementwise pass), in loops whose trip count
 is the held assignments' (`_held_experts`, `_combine`): the sorted rows are
@@ -194,13 +196,30 @@ _kernel_matmul.defvjp(_kernel_matmul_fwd, _kernel_matmul_bwd)
 
 
 def rows_moved(experts, held):
-    """Which rows of the top_k * N row buffer `routed_ffn`'s passes touch,
+    """Which rows of the sorted row buffer `routed_ffn`'s passes touch,
     decided from the shapes: "all" where every expert is held (every row is
     in a group: four gathers of the buffer and the elementwise passes XLA
     makes of the rest), "held" where a share is: the four permutations, the
     sum of the two d rows and `_gated`'s transpose all run in the tiles
     below the held assignments' count, a run-time value."""
     return "all" if held == experts else "held"
+
+
+def numbered_by(experts, held, top_k):
+    """Which axis numbers the assignments of a held share, decided from the
+    shapes: a token's choices are distinct experts, so it has an assignment
+    a top-k slot (top_k x N of them) and at most one a held expert (held x
+    N), and every integer pass between the router and the rows (the sort
+    key, `order`, `rank`, `_token_places`, the windows of `_token_sum`, the
+    weights' gradient) runs over whichever is numbered. "expert" where the
+    share is narrower than top_k (a = h * N + token, h the held expert:
+    Nemotron-3-Super's 8 of 512 at top-22), "slot" where it is not (a = slot
+    * N + token, the shorter there), None where every expert is held
+    (`rows_moved` "all": every slot is a row, and slot-major is the buffer
+    as it lies)."""
+    if held == experts:
+        return None
+    return "expert" if held < top_k else "slot"
 
 
 # Rows a trip of the held-rows loops moves; flat from 512 to 2048 (my chip run,
@@ -438,21 +457,24 @@ def _held_rows(x, order, total, *, tile):
 
 
 @jax.custom_vjp
-def _combine(y, gate, order, rank, total, places):
-    """y [A, D] the experts' outputs by sorted row, gate [top_k, N] float32
-    the assignments' weights; order and rank as in `_dispatch`; `total` the
-    sorted rows that are held and `places` their token-major numbering
-    (`_token_places`), both None where every expert is held. -> [N, D] in
-    y's dtype: the float32 sum over a token's slots of weight times output
+def _combine(y, gate, order, rank, total, places, by_row=None):
+    """y [A, D] the experts' outputs by sorted row, gate [slots, N] float32
+    the assignments' weights (slots: top_k, or the held experts where the
+    assignments are numbered by them); order and rank as in `_dispatch`;
+    `total` the sorted rows that are held and `places` their token-major
+    numbering (`_token_places`), both None where every expert is held;
+    `by_row` [A] the weights by sorted row where the caller has them
+    (`_by_held_expert`), None: gathered going back. -> [N, D] in y's dtype:
+    the float32 sum over a token's slots of weight times output
     (`_slot_sum`; over its held slots, `_token_sum`)."""
     if total is None:
         return _slot_sum(y, rank, gate.shape[0], gate)
     return _token_sum((y,), rank, places, gate, tile=SUM_TILE)
 
 
-def _combine_fwd(y, gate, order, rank, total, places):
-    return _combine(y, gate, order, rank, total, places), (
-        y, gate, order, rank, total)
+def _combine_fwd(y, gate, order, rank, total, places, by_row):
+    return _combine(y, gate, order, rank, total, places, by_row), (
+        y, gate, order, rank, total, by_row)
 
 
 def _weighted(g_rows, y_rows, w_rows):
@@ -491,24 +513,21 @@ def _combine_bwd(res, g):
     """Both gradients on the experts' side, where the held rows are
     contiguous: sorted row r of dy is g[its token] times its weight, and its
     weight's gradient is the dot of g[its token] with y[r]; the weights'
-    gradients then go back to slot-major by `rank`, [A] numbers. Where a
-    share is held only the tiles below `total` are gathered
-    (`_held_weighted`)."""
-    y, gate, order, rank, total = res
-    token, weight = order % gate.shape[1], gate.reshape(-1)[order]
+    gradients then go back to the assignments' numbering by `rank`, [A]
+    numbers (a gather; a sort keyed on `order` where `by_row` came, as the
+    weights did: `_sorted_by`). Where a share is held only the tiles below
+    `total` are gathered (`_held_weighted`)."""
+    y, gate, order, rank, total, by_row = res
+    token = order % gate.shape[1]
+    weight = gate.reshape(-1)[order] if by_row is None else by_row
     if total is None:
         dy, dweight = _weighted(g[token], y, weight)
     else:
         dy, dweight = _held_weighted(y, g, token, weight, total,
                                      tile=ROW_TILE)
-    if dweight.shape[0] < rank.shape[0]:
-        # a buffer cut to the rows that can be held (`routed_ffn`): an
-        # assignment ranked past it is held elsewhere, and its weight's
-        # gradient here is 0
-        by_slot = dweight.at[rank].get(mode="fill", fill_value=0.0)
-    else:
-        by_slot = dweight[rank]
-    return dy, by_slot.reshape(gate.shape), None, None, None, None
+    dweight = dweight[rank] if by_row is None \
+        else _sorted_by(order, dweight)[0]
+    return dy, dweight.reshape(gate.shape), None, None, None, None, None
 
 
 _combine.defvjp(_combine_fwd, _combine_bwd)
@@ -664,6 +683,55 @@ def _held_experts_bwd(activation, res, dy):
 _held_experts.defvjp(_held_experts_fwd, _held_experts_bwd)
 
 
+def _sorted_by(keys, *values):
+    """`values` [A] in the order that makes `keys` ascend, one sort of
+    tuples. With keys a permutation of 0 .. A - 1 that is v[inverse of
+    keys], the gather, and v scattered to keys, both at once; XLA's gather
+    and scatter of 32-bit scalars cost the v5e 8 and 5 ns an index (263 and
+    155 us at 32,768, 676 us for 90,112 of them filled), a sort of 32,768
+    triples 25 us (my chip run, PR 62)."""
+    return jax.lax.sort((keys,) + values, num_keys=1)[1:]
+
+
+def _by_held_expert(local, gate, sizes):
+    """The assignments of a share narrower than top_k numbered by held
+    expert (`numbered_by`), a = h * N + token. local [top_k, N] a token's
+    choices less first_expert, gate [top_k, N] float32 their weights, sizes
+    [held] the held experts' counts -> (weight [held, N] float32: the weight
+    of the token's choice of held expert h, 0 where it made none; order [A]
+    the assignment at each sorted row, the held ones first, by expert then
+    token, the rest behind them as they are numbered; rank [A] its inverse;
+    the weights by sorted row [A], without gradient: what `_combine`'s
+    backward pass reads).
+
+    A token's choices are distinct, so (h, token) is one assignment at most
+    and one compare over [held, top_k, N] (the tokens in the lanes, as
+    `local` lies) finds it and its weight; plain jax.numpy, which jax
+    transposes, so a weight's gradient goes back to its slot. `rank` needs
+    no sort: a held assignment's is the held ones before it in (h, token)
+    order, a running sum along each expert's row behind the experts before
+    it, and one that is not held follows them in its own order. `order`
+    is the numbering sorted by that rank, and the weights are sorted by it
+    likewise."""
+    held, n = sizes.shape[0], local.shape[1]
+    chosen = local[None] == jnp.arange(held)[:, None, None]
+    member = chosen.any(1)
+    weight = jnp.sum(jnp.where(chosen, gate[None], 0.0), axis=1)
+    ends = jnp.cumsum(sizes)
+    before = (ends - sizes)[:, None] + jnp.cumsum(member, axis=1,
+                                                  dtype=jnp.int32)
+    a = jnp.arange(held * n, dtype=jnp.int32).reshape(held, n)
+    rank = jnp.where(member, before - 1, ends[-1] + a - before).reshape(-1)
+    # two sorts: `order` is the integers' alone, so the forward op's and the
+    # one a grad op's replay of the rule makes are one to XLA (the weights'
+    # gather in `_route` is not, and took the rows' loops, a matmul and the
+    # unit with it into the grad op when one sort carried both: +4.5 ms a
+    # step in the Nemotron cell; my chip run, PR 62)
+    order, = _sorted_by(rank, a.reshape(-1))
+    by_row, = _sorted_by(rank, jax.lax.stop_gradient(weight).reshape(-1))
+    return weight, order, rank, by_row
+
+
 # what a sigmoid router's renormalisation adds to the chosen scores' sum
 # (modeling_lfm2_moe.py; a softmax router's sum cannot vanish and adds nothing)
 SIGMOID_NORM_EPS = 1e-6
@@ -734,6 +802,26 @@ def routed_ffn(x, router, w_gate, w_up, w_down, top_k, norm_topk_prob=False,
     token's choices may be held (1.5 N on average at 6 of 64 with 16 held)
     and its choices are distinct experts, so no more than H of them are
     (22 of 512 a token on 8 held: 8 N rows and not 22 N).
+
+    Which numbering holds is `numbered_by`'s to say, from the shapes: where
+    a share is held and H < top_k the assignments are numbered by held
+    expert, a = h * N + token with h in [0, H), H * N of them
+    (`_by_held_expert`): one compare over [H, top_k, N] says whether the
+    token chose expert first_expert + h and with what weight, [H, N] each
+    (jax transposes it, so a weight's gradient goes back to its slot and on
+    into the router). `rank` is a running sum of that membership, `order`
+    the numbering sorted by it; the weights go to their sorted rows by such
+    a sort too, and their gradients come back by one (`_sorted_by`): no
+    scatter and no gather of scalars. `_token_places`, the windows of
+    `_token_sum` and the loops over the held rows then run as they do by
+    slot, over H * N integers and H "slots" a token; the buffer is
+    `order`'s own length, and nothing is numbered that cannot be held. With
+    H >= top_k, and wherever every expert is held, the numbering is the
+    slot-major one, the shorter there. The two differ in the order of
+    float32 sums and in nothing else: numbered by expert a group's rows lie
+    by token (by slot, then token, numbered by slot), so the weights'
+    gradients sum an expert's rows in another order, and a token's held
+    rows are summed in expert order (in score order, numbered by slot).
 
     Four permutations move rows, a layer's forward and backward, and where
     a share is held (`rows_moved`) all four, and the elementwise passes
@@ -813,24 +901,27 @@ def routed_ffn(x, router, w_gate, w_up, w_down, top_k, norm_topk_prob=False,
     # assignment a = j * N + n (slot-major); `order` lists the assignments by
     # expert (stable, so by slot then token inside an expert), `rank` is
     # where each went
+    by = numbered_by(e, held, top_k)
     expert, gate = expert.T.reshape(-1), gate.T
     load = jnp.sum(expert[:, None] == jnp.arange(e), axis=0, dtype=jnp.int32)
-    if rows_moved(e, held) == "all":
+    by_row = None
+    if by is None:
         sort_key, sizes, total = expert, load, None
     else:
         local = expert - first_expert
-        here = (local >= 0) & (local < held)
-        sort_key = jnp.where(here, local, held)    # not held: past the groups
+        if by == "slot":
+            here = (local >= 0) & (local < held)
+            sort_key = jnp.where(here, local, held)    # not held: past them
         sizes = load[first_expert:first_expert + held]
         total = sizes.sum()
-    order = jnp.argsort(sort_key, stable=True)
-    rank = jnp.zeros_like(order).at[order].set(
-        jnp.arange(order.shape[0], dtype=order.dtype))
-    # a token's choices are distinct experts, so at most `held` of them are
-    # held: the sorted rows past n * held belong to no group whatever the
-    # router does, and no buffer has them
-    if total is not None and held < top_k:
-        order = order[:n * held]
+    if by == "expert":
+        # a = h * N + n, h the held expert: held * N assignments, not top_k * N
+        gate, order, rank, by_row = _by_held_expert(
+            local.reshape(top_k, n), gate, sizes)
+    else:
+        order = jnp.argsort(sort_key, stable=True)
+        rank = jnp.zeros_like(order).at[order].set(
+            jnp.arange(order.shape[0], dtype=order.dtype))
 
     # the kernels' visits, once for the layer's nine matmuls
     plan = None
@@ -850,12 +941,12 @@ def routed_ffn(x, router, w_gate, w_up, w_down, top_k, norm_topk_prob=False,
                 activation)
         y = _grouped_matmul(hidden, w_down.astype(dtype), sizes, plan)
     else:
-        places = _token_places(rank, total, top_k)
+        places = _token_places(rank, total, gate.shape[0])
         y = _held_experts(x.astype(dtype),
                           None if w_gate is None else w_gate.astype(dtype),
                           w_up.astype(dtype), w_down.astype(dtype), order,
                           rank, sizes, plan, total, places, activation)
-    out = _combine(y, gate, order, rank, total, places)
+    out = _combine(y, gate, order, rank, total, places, by_row)
 
     if scoring == "sigmoid":
         return out, jnp.zeros((1,), jnp.float32), \
