@@ -19,6 +19,10 @@ from a seed, and checks what comes out by the repo's own means:
      inspected: the kernel was dispatched, and not interpreted on a TPU.
      The gated delta rule's two kernels and its lax.scan path against the
      token-by-token recurrence at the Qwen3-Next cell's shapes.
+     One routed expert layer alone at three cells' sizes, and the scalars
+     `routed_ffn` moves between the router and the rows at five cells'
+     shapes, each site by XLA's gather or scatter (the form up to PR 62,
+     kept here) and by the compare or sort the program has, equal to the bit.
   D  (>= 4 devices) ParallelExecutor over a dp=4 mesh on phase A's
      program, replicated and with the ZeRO-sharded weight update.
   E  the timing-barrier premise: K steps timed to jax.block_until_ready
@@ -115,6 +119,15 @@ FULL = {
             dict(name="Nemotron-3-Super", n=4096, d=1024, router_d=4096,
                  e=512, held=8, f=2688, top_k=22, gated=False,
                  activation="relu2", scoring="sigmoid")),
+        # the scalars `routed_ffn` moves between the router and the rows, at
+        # five cells' shapes: tokens, experts routed over and held, experts
+        # a token (PR 63: each site's two forms alone)
+        routing_sites=(
+            dict(name="Nemotron-3-Super", n=4096, e=512, held=8, top_k=22),
+            dict(name="LFM2", n=8192, e=32, held=8, top_k=4),
+            dict(name="SmallThinker", n=8192, e=64, held=16, top_k=6),
+            dict(name="Qwen3-Next", n=4096, e=512, held=32, top_k=10),
+            dict(name="OLMoE", n=16384, e=64, held=64, top_k=8)),
         # Qwen3-Next's gated delta rule at its cell's shapes: one sequence
         # of 4096, 16 key heads on 32 value heads of 128
         gated_delta=dict(b=1, t=4096, hk=16, hv=32, d=128),
@@ -196,6 +209,8 @@ TINY = {
                     dict(name="tiny ungated", n=64, d=128, router_d=64, e=16,
                          held=4, f=128, top_k=3, gated=False,
                          activation="relu2", scoring="sigmoid")),
+        routing_sites=(dict(name="tiny", n=64, e=16, held=4, top_k=5),
+                       dict(name="tiny whole", n=64, e=8, held=8, top_k=2)),
         gated_delta=dict(b=2, t=40, hk=2, hv=4, d=16),
         causal_conv=dict(b=2, t=64, c=256, width=4),
         xent=dict(n=32, v=64),
@@ -841,7 +856,7 @@ def _held_layer_times(smoke, c, expert_bias=None):
         if by_expert:
             sizes = jnp.sum(expert.reshape(-1)[:, None] == jnp.arange(held),
                             axis=0, dtype=jnp.int32)
-            gate, _, rank, _ = moe._by_held_expert(expert, gate, sizes)
+            gate, _, rank = moe._by_held_expert(expert, gate, sizes)
             return gate, rank, sizes.sum()
         expert = expert.reshape(-1)
         order = jnp.argsort(jnp.where(expert < held, expert, held),
@@ -894,6 +909,86 @@ def _held_layer_times(smoke, c, expert_bias=None):
                times["new", "weighted"], times["new", "plain"],
                _in_flight_ms(jax.jit(layer), args),
                _in_flight_ms(jax.jit(trained), args + (g,))))
+
+
+def _routing_site_times(smoke, c):
+    """The scalars `routed_ffn` moves between the router's top-k and the
+    rows' passes, each site alone at a cell's shape in the form the program
+    had up to PR 62 (XLA's gather and scatter of 32-bit scalars; the old
+    forms live here and nowhere in the program) and in the one it has (a
+    compare, `moe._chosen`; sorts, `moe._sorted_by`), the two compared to
+    the bit: (a) the chosen scores out of [N, E] and their gradient into it,
+    under a bias (`take_along_axis`) and without (`top_k`'s own values);
+    (b) `rank`, the inverse of `order`; (c) the weights by sorted row; (d)
+    their gradients back to the assignments' numbering. A is the
+    assignments as `moe.numbered_by` numbers them. In flight, so the
+    numbers order the forms; a cell's trace by scope is the number."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.parallel import moe
+
+    n, e, held, k = (c[key] for key in ("n", "e", "held", "top_k"))
+    slots = held if moe.numbered_by(e, held, k) == "expert" else k
+    rng = np.random.RandomState(31)
+    probs = jax.nn.sigmoid(jnp.asarray(rng.randn(n, e), jnp.float32))
+    d_gate = jnp.asarray(rng.randn(n, k), jnp.float32)
+    _, expert = jax.lax.top_k(
+        probs + jnp.asarray(rng.randn(e) * 0.1, jnp.float32), k)
+    order = jnp.asarray(rng.permutation(slots * n), jnp.int32)
+    rank = jnp.argsort(order).astype(jnp.int32)
+    scalars = jnp.asarray(rng.randn(slots * n), jnp.float32)
+    iota = jnp.arange(slots * n, dtype=jnp.int32)
+
+    def with_gradient(read):
+        def run(probs, expert, d_gate):
+            out, vjp = jax.vjp(lambda p: read(p, expert), probs)
+            return out, vjp(d_gate)[0]
+        return run
+
+    def own_values(probs, expert):          # no bias: what top_k returns
+        return jax.lax.top_k(probs, k)[0]
+
+    def chosen_again(probs, expert):
+        return moe._chosen(probs, jax.lax.top_k(probs, k)[1])
+
+    def gathered(probs, expert):
+        return jnp.take_along_axis(probs, expert, axis=-1)
+
+    sites = (
+        ("(a) the chosen scores [%d, %d] of [.., %d]" % (n, k, e),
+         gathered, moe._chosen, (probs, expert)),
+        ("(a) and their gradient", with_gradient(gathered),
+         with_gradient(moe._chosen), (probs, expert, d_gate)),
+        ("(a) with no bias, the top-k and its values",
+         own_values, chosen_again, (probs, expert)),
+        ("(a) with no bias, and their gradient", with_gradient(own_values),
+         with_gradient(chosen_again), (probs, expert, d_gate)),
+        ("(b) rank of %d" % (slots * n),
+         lambda order: jnp.zeros_like(order).at[order].set(iota),
+         lambda order: moe._sorted_by(order, iota)[0], (order,)),
+        ("(c) the weights by sorted row",
+         lambda w, order, rank: w[order],
+         lambda w, order, rank: moe._sorted_by(rank, w)[0],
+         (scalars, order, rank)),
+        ("(d) their gradients back",
+         lambda w, order, rank: w[rank],
+         lambda w, order, rank: moe._sorted_by(order, w)[0],
+         (scalars, order, rank)))
+    lines = []
+    with jax.default_device(smoke.device):
+        for label, old, new, args in sites:
+            old, new = jax.jit(old), jax.jit(new)
+            for got, want in zip(jax.tree_util.tree_leaves(new(*args)),
+                                 jax.tree_util.tree_leaves(old(*args))):
+                if not bool(jnp.array_equal(got, want)):
+                    raise AssertionError("%s at %s's shape: the two forms "
+                                         "differ" % (label, c["name"]))
+            lines.append("%s %.3f -> %.3f ms" % (
+                label, _in_flight_ms(old, args), _in_flight_ms(new, args)))
+    smoke.say("routed_ffn's scalars at %s's shape, %d tokens top-%d of %d, "
+              "%d held, through XLA's gather / scatter -> by compare / sort "
+              "(equal to the bit; median of 5 x 10 calls in flight): %s"
+              % (c["name"], n, k, e, held, "; ".join(lines)))
 
 
 def _gated_delta_case(smoke, c, tol):
@@ -1041,6 +1136,9 @@ def phase_c(smoke):
     for c in smoke.cfg["kernels"]["moe_layers"]:
         runs.append(("routed_ffn's passes over the held rows, %s" % c["name"],
                      lambda c=c: _held_layer_times(smoke, c)))
+    for c in smoke.cfg["kernels"]["routing_sites"]:
+        runs.append(("routed_ffn's scalars by gather and by sort, %s"
+                     % c["name"], lambda c=c: _routing_site_times(smoke, c)))
     runs.append(("gated_delta_rule against the recurrence",
                  lambda: _gated_delta_case(
                      smoke, smoke.cfg["kernels"]["gated_delta"],

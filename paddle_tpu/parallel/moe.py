@@ -457,24 +457,22 @@ def _held_rows(x, order, total, *, tile):
 
 
 @jax.custom_vjp
-def _combine(y, gate, order, rank, total, places, by_row=None):
+def _combine(y, gate, order, rank, total, places):
     """y [A, D] the experts' outputs by sorted row, gate [slots, N] float32
     the assignments' weights (slots: top_k, or the held experts where the
     assignments are numbered by them); order and rank as in `_dispatch`;
     `total` the sorted rows that are held and `places` their token-major
-    numbering (`_token_places`), both None where every expert is held;
-    `by_row` [A] the weights by sorted row where the caller has them
-    (`_by_held_expert`), None: gathered going back. -> [N, D] in y's dtype:
-    the float32 sum over a token's slots of weight times output
-    (`_slot_sum`; over its held slots, `_token_sum`)."""
+    numbering (`_token_places`), both None where every expert is held. ->
+    [N, D] in y's dtype: the float32 sum over a token's slots of weight
+    times output (`_slot_sum`; over its held slots, `_token_sum`)."""
     if total is None:
         return _slot_sum(y, rank, gate.shape[0], gate)
     return _token_sum((y,), rank, places, gate, tile=SUM_TILE)
 
 
-def _combine_fwd(y, gate, order, rank, total, places, by_row):
-    return _combine(y, gate, order, rank, total, places, by_row), (
-        y, gate, order, rank, total, by_row)
+def _combine_fwd(y, gate, order, rank, total, places):
+    return _combine(y, gate, order, rank, total, places), (
+        y, gate, order, rank, total)
 
 
 def _weighted(g_rows, y_rows, w_rows):
@@ -512,22 +510,23 @@ def _held_weighted(y, g, token, weight, total, *, tile):
 def _combine_bwd(res, g):
     """Both gradients on the experts' side, where the held rows are
     contiguous: sorted row r of dy is g[its token] times its weight, and its
-    weight's gradient is the dot of g[its token] with y[r]; the weights'
-    gradients then go back to the assignments' numbering by `rank`, [A]
-    numbers (a gather; a sort keyed on `order` where `by_row` came, as the
-    weights did: `_sorted_by`). Where a share is held only the tiles below
-    `total` are gathered (`_held_weighted`)."""
-    y, gate, order, rank, total, by_row = res
+    weight's gradient is the dot of g[its token] with y[r]. The weights
+    come to their sorted rows, gate.reshape(-1)[order], by a sort keyed on
+    `rank`, and their gradients go back to the assignments' numbering,
+    dweight[rank], by one keyed on `order` (`_sorted_by`: [A] scalars
+    each, which XLA's gather moves at 8 ns an index), under every
+    numbering. Where a share is held only the tiles below `total` are
+    gathered (`_held_weighted`)."""
+    y, gate, order, rank, total = res
     token = order % gate.shape[1]
-    weight = gate.reshape(-1)[order] if by_row is None else by_row
+    weight, = _sorted_by(rank, gate.reshape(-1))
     if total is None:
         dy, dweight = _weighted(g[token], y, weight)
     else:
         dy, dweight = _held_weighted(y, g, token, weight, total,
                                      tile=ROW_TILE)
-    dweight = dweight[rank] if by_row is None \
-        else _sorted_by(order, dweight)[0]
-    return dy, dweight.reshape(gate.shape), None, None, None, None, None
+    dweight, = _sorted_by(order, dweight)
+    return dy, dweight.reshape(gate.shape), None, None, None, None
 
 
 _combine.defvjp(_combine_fwd, _combine_bwd)
@@ -686,10 +685,14 @@ _held_experts.defvjp(_held_experts_fwd, _held_experts_bwd)
 def _sorted_by(keys, *values):
     """`values` [A] in the order that makes `keys` ascend, one sort of
     tuples. With keys a permutation of 0 .. A - 1 that is v[inverse of
-    keys], the gather, and v scattered to keys, both at once; XLA's gather
-    and scatter of 32-bit scalars cost the v5e 8 and 5 ns an index (263 and
-    155 us at 32,768, 676 us for 90,112 of them filled), a sort of 32,768
-    triples 25 us (my chip run, PR 62)."""
+    keys], the gather, and v scattered to keys, both at once: every scalar
+    that `routed_ffn` permutes goes this way (`rank`, the inverse of
+    `order`: arange sorted by `order`; the weights by sorted row and their
+    gradients back, `_combine_bwd`; `order` out of `rank` numbered by held
+    expert), because XLA's gather and scatter of 32-bit scalars cost the
+    v5e 8 and 5 ns an index (263 and 155 us at 32,768, 676 us for 90,112 of
+    them filled) and a sort of 32,768 triples 25 us (my chip run, PR 62).
+    What is read at an index a token chose is a compare (`_chosen`)."""
     return jax.lax.sort((keys,) + values, num_keys=1)[1:]
 
 
@@ -700,9 +703,7 @@ def _by_held_expert(local, gate, sizes):
     [held] the held experts' counts -> (weight [held, N] float32: the weight
     of the token's choice of held expert h, 0 where it made none; order [A]
     the assignment at each sorted row, the held ones first, by expert then
-    token, the rest behind them as they are numbered; rank [A] its inverse;
-    the weights by sorted row [A], without gradient: what `_combine`'s
-    backward pass reads).
+    token, the rest behind them as they are numbered; rank [A] its inverse).
 
     A token's choices are distinct, so (h, token) is one assignment at most
     and one compare over [held, top_k, N] (the tokens in the lanes, as
@@ -711,8 +712,7 @@ def _by_held_expert(local, gate, sizes):
     no sort: a held assignment's is the held ones before it in (h, token)
     order, a running sum along each expert's row behind the experts before
     it, and one that is not held follows them in its own order. `order`
-    is the numbering sorted by that rank, and the weights are sorted by it
-    likewise."""
+    is the numbering sorted by that rank."""
     held, n = sizes.shape[0], local.shape[1]
     chosen = local[None] == jnp.arange(held)[:, None, None]
     member = chosen.any(1)
@@ -722,14 +722,39 @@ def _by_held_expert(local, gate, sizes):
                                                   dtype=jnp.int32)
     a = jnp.arange(held * n, dtype=jnp.int32).reshape(held, n)
     rank = jnp.where(member, before - 1, ends[-1] + a - before).reshape(-1)
-    # two sorts: `order` is the integers' alone, so the forward op's and the
-    # one a grad op's replay of the rule makes are one to XLA (the weights'
-    # gather in `_route` is not, and took the rows' loops, a matmul and the
-    # unit with it into the grad op when one sort carried both: +4.5 ms a
-    # step in the Nemotron cell; my chip run, PR 62)
+    # `order` is the integers' alone, so the forward op's and the one a grad
+    # op's replay of the rule makes are one to XLA: a sort that carried the
+    # weights too took the rows' loops, a matmul and the unit with it into
+    # the grad op while the weights came by a gather XLA did not merge (+4.5
+    # ms a step in the Nemotron cell; my chip run, PR 62). The weights go to
+    # their rows where they are read, in `_combine_bwd`
     order, = _sorted_by(rank, a.reshape(-1))
-    by_row, = _sorted_by(rank, jax.lax.stop_gradient(weight).reshape(-1))
-    return weight, order, rank, by_row
+    return weight, order, rank
+
+
+def _chosen(probs, expert):
+    """probs [N, E] float32 at the experts a token chose, expert [N, top_k]
+    distinct in a row -> [N, top_k], which is take_along_axis(probs, expert,
+    -1) to the bit: a compare of `expert` against the experts' axis and a
+    sum over it that has one term that is not zero, one reduce fusion over
+    [top_k, E, N] with the tokens in the lanes that writes [top_k, N]. jax
+    transposes it to where(chosen, d, 0) summed over the slots, so the
+    gradient into the scores is a pass of the same shape and no scatter-add
+    of top_k * N scalars into zeros. At Nemotron-3-Super's [22, 512, 4096]
+    the two passes are 33 and 98 us a layer on the v5e where the gather was
+    0.93 ms, 0.93 again in the grad op (the one operation of the replayed
+    rule XLA did not merge with the forward op's) and the scatter-add 0.87
+    (my chip runs, PRs 62 and 63).
+
+    The result stands behind a barrier: a renormalisation sums it over the
+    slots, and XLA merges a sum of a sum into ONE over [top_k, E] (float32
+    additions in another order, so a weight an ulp off the gathered form)
+    and then runs the compare a second time for the weights themselves
+    (CPU compile, PR 63)."""
+    chosen = expert.T[:, None] == jnp.arange(
+        probs.shape[1], dtype=expert.dtype)[:, None]
+    return jax.lax.optimization_barrier(
+        jnp.sum(jnp.where(chosen, probs.T, 0.0), axis=1)).T
 
 
 # what a sigmoid router's renormalisation adds to the chosen scores' sum
@@ -750,12 +775,11 @@ def _route(logits, top_k, norm_topk_prob, scoring, expert_bias, scale,
         probs = jnp.exp(logits - lse[:, None])
     else:
         lse, probs = None, jax.nn.sigmoid(logits)
-    if expert_bias is None:
-        gate, expert = jax.lax.top_k(probs, top_k)         # [N, top_k]
-    else:
-        _, expert = jax.lax.top_k(
-            probs + expert_bias.astype(jnp.float32), top_k)
-        gate = jnp.take_along_axis(probs, expert, axis=-1)
+    # the bias is an argument of the choice and nothing else
+    _, expert = jax.lax.top_k(
+        probs if expert_bias is None
+        else probs + expert_bias.astype(jnp.float32), top_k)   # [N, top_k]
+    gate = _chosen(probs, expert)
     if norm_topk_prob:
         total = gate.sum(-1, keepdims=True)
         if scoring == "sigmoid":
@@ -810,9 +834,7 @@ def routed_ffn(x, router, w_gate, w_up, w_down, top_k, norm_topk_prob=False,
     token chose expert first_expert + h and with what weight, [H, N] each
     (jax transposes it, so a weight's gradient goes back to its slot and on
     into the router). `rank` is a running sum of that membership, `order`
-    the numbering sorted by it; the weights go to their sorted rows by such
-    a sort too, and their gradients come back by one (`_sorted_by`): no
-    scatter and no gather of scalars. `_token_places`, the windows of
+    the numbering sorted by it. `_token_places`, the windows of
     `_token_sum` and the loops over the held rows then run as they do by
     slot, over H * N integers and H "slots" a token; the buffer is
     `order`'s own length, and nothing is numbered that cannot be held. With
@@ -822,6 +844,23 @@ def routed_ffn(x, router, w_gate, w_up, w_down, top_k, norm_topk_prob=False,
     by token (by slot, then token, numbered by slot), so the weights'
     gradients sum an expert's rows in another order, and a token's held
     rows are summed in expert order (in score order, numbered by slot).
+
+    Between the router's top-k and the rows' passes no scalar is moved by
+    index, under any numbering: XLA's gather and scatter of 32-bit scalars
+    cost the v5e 8-10 and 5 ns an index, serially (`_sorted_by`). What a
+    token reads at the experts it chose, its scores, is a COMPARE of the
+    choices against the experts' axis and a sum of one term (`_chosen`, as
+    `load` and `_by_held_expert` are compares), and jax's transpose of it
+    puts the weights' gradients back into [N, E] without a scatter-add. What
+    is permuted is SORTED (`_sorted_by`): `rank`, the inverse of `order`, is
+    arange sorted by `order` (numbered by expert `order` is the numbering
+    sorted by `rank`); going back, the weights come to their sorted rows by
+    a sort keyed on `rank` and their gradients return by one keyed on
+    `order` (`_combine_bwd`, one form for the three numberings). Each is the
+    gather or scatter it replaced to the bit. `order` and what the rows'
+    loops read going forward are functions of the integers alone: the grad
+    op replays this rule, and XLA merges the replay with the forward op's
+    operations only where they are the same (PR 62).
 
     Four permutations move rows, a layer's forward and backward, and where
     a share is held (`rows_moved`) all four, and the elementwise passes
@@ -869,7 +908,7 @@ def routed_ffn(x, router, w_gate, w_up, w_down, top_k, norm_topk_prob=False,
     `scoring` "softmax" scores a token's experts by the softmax of its
     router logits, "sigmoid" by s = sigmoid(logits), each expert on its own.
     `expert_bias` [E], where given, enters the choice and nothing else: the
-    top_k is over s + b and the weights are gathered from s, so the bias
+    top_k is over s + b and the weights are read from s, so the bias
     moves which experts run and never how much one counts, and it has no
     gradient (its only use is an argument of top_k; the router's gradient
     comes through s). With norm_topk_prob a sigmoid router divides by the
@@ -904,7 +943,6 @@ def routed_ffn(x, router, w_gate, w_up, w_down, top_k, norm_topk_prob=False,
     by = numbered_by(e, held, top_k)
     expert, gate = expert.T.reshape(-1), gate.T
     load = jnp.sum(expert[:, None] == jnp.arange(e), axis=0, dtype=jnp.int32)
-    by_row = None
     if by is None:
         sort_key, sizes, total = expert, load, None
     else:
@@ -916,12 +954,12 @@ def routed_ffn(x, router, w_gate, w_up, w_down, top_k, norm_topk_prob=False,
         total = sizes.sum()
     if by == "expert":
         # a = h * N + n, h the held expert: held * N assignments, not top_k * N
-        gate, order, rank, by_row = _by_held_expert(
+        gate, order, rank = _by_held_expert(
             local.reshape(top_k, n), gate, sizes)
     else:
         order = jnp.argsort(sort_key, stable=True)
-        rank = jnp.zeros_like(order).at[order].set(
-            jnp.arange(order.shape[0], dtype=order.dtype))
+        rank, = _sorted_by(order, jnp.arange(order.shape[0],
+                                             dtype=order.dtype))
 
     # the kernels' visits, once for the layer's nine matmuls
     plan = None
@@ -946,7 +984,7 @@ def routed_ffn(x, router, w_gate, w_up, w_down, top_k, norm_topk_prob=False,
                           None if w_gate is None else w_gate.astype(dtype),
                           w_up.astype(dtype), w_down.astype(dtype), order,
                           rank, sizes, plan, total, places, activation)
-    out = _combine(y, gate, order, rank, total, places, by_row)
+    out = _combine(y, gate, order, rank, total, places)
 
     if scoring == "sigmoid":
         return out, jnp.zeros((1,), jnp.float32), \

@@ -7,7 +7,20 @@ each unit, router and option, through `ragged_dot` and through the kernels'
 interpreter; the shares of all chips add up to the layer with every expert
 held; a token none of whose choices is held gets zeros; a share as wide as
 top_k or wider lowers as it did (no compare over [held, top_k, N]); and
-`ptpu_moe_layers_total` says `numbered="expert"` for a narrow share alone."""
+`ptpu_moe_layers_total` says `numbered="expert"` for a narrow share alone.
+
+And what moves a scalar an assignment between the router's top-k and the
+rows' passes (PR 63): the chosen scores are read by a compare (`_chosen`),
+`rank` and the weights' two permutations by sorts (`_sorted_by`), under every
+numbering. The forms they replaced (`take_along_axis`, `.at[order].set`,
+`[order]`, `[rank]`) are kept here as plain jax.numpy oracles and `_route`
+and the layer are held to them to the bit at the seven cells' shapes; the
+jaxpr of a layer forward and backward holds no gather or scatter of one
+scalar an assignment; and the compiled step of one layer has no forward pass
+under its grad op's scope."""
+import itertools
+import re
+
 import numpy as np
 import pytest
 
@@ -235,17 +248,21 @@ def test_a_token_with_no_held_choice_gets_zeros(monkeypatch):
 
 # --- a share as wide as top_k, or wider, lowers as it did -------------------
 
-def _compares_over(jaxpr, dims):
-    """The shapes of the `eq`s over three axes of the sizes `dims`, in any
-    order, anywhere in the jaxpr."""
-    found = []
+def _all_eqns(jaxpr):
+    """Every equation of the jaxpr and of the jaxprs in their parameters."""
     for eqn in jaxpr.eqns:
-        if eqn.primitive.name == "eq" and sorted(
-                eqn.outvars[0].aval.shape) == sorted(dims):
-            found.append(eqn.outvars[0].aval.shape)
+        yield eqn
         for sub in jax.core.jaxprs_in_params(eqn.params):
-            found += _compares_over(sub, dims)
-    return found
+            yield from _all_eqns(sub)
+
+
+def _compares_over(jaxpr, dims):
+    """The shapes of the `eq`s over the axes `dims`, in that order (the
+    chosen scores' compare, `moe._chosen`, is over [top_k, E, N] under every
+    numbering), anywhere in the jaxpr."""
+    return [eqn.outvars[0].aval.shape for eqn in _all_eqns(jaxpr)
+            if eqn.primitive.name == "eq"
+            and tuple(eqn.outvars[0].aval.shape) == tuple(dims)]
 
 
 @pytest.mark.parametrize("held,top_k,numbered", [
@@ -261,21 +278,16 @@ def test_only_a_narrow_share_compares_over_held_experts(held, top_k,
     found = _compares_over(jaxpr, (held, top_k, N))
     assert bool(found) == (numbered == "expert")
     # and the integers are as many as the numbering says: by slot one
-    # argsort of the keys; by expert `order` sorted out of `rank`, the
-    # weights sorted to their rows and their gradients sorted back
-    # (`moe._sorted_by`)
+    # argsort of the keys and `rank` sorted out of `order`; by expert `order`
+    # sorted out of `rank`; under either the weights sorted to their rows
+    # and their gradients sorted back (`moe._sorted_by`)
     assert _sorted_lengths(jaxpr) == (
-        [N * held] * 3 if numbered == "expert" else [N * top_k])
+        [N * held] * 3 if numbered == "expert" else [N * top_k] * 4)
 
 
 def _sorted_lengths(jaxpr):
-    found = []
-    for eqn in jaxpr.eqns:
-        if eqn.primitive.name == "sort":
-            found.append(eqn.outvars[0].aval.shape[0])
-        for sub in jax.core.jaxprs_in_params(eqn.params):
-            found += _sorted_lengths(sub)
-    return found
+    return [eqn.outvars[0].aval.shape[0] for eqn in _all_eqns(jaxpr)
+            if eqn.primitive.name == "sort"]
 
 
 # --- the counter ------------------------------------------------------------
@@ -322,3 +334,313 @@ def test_the_counter_names_the_numbering_of_a_narrow_share_alone(cell):
     else:
         assert "numbered" not in labels
         assert labels["rows"] == ("all" if cell == "olmoe" else "held")
+
+
+# --- no scalar moves by gather or scatter (PR 63) ---------------------------
+
+# scoring, expert bias, norm_topk_prob, scale, norm_eps, activation of the
+# seven cells' routers (benchmark/configs/*.json)
+ROUTERS = {"olmoe": ("softmax", False, False, 1.0, None, "silu"),
+           "smallthinker": ("softmax", False, True, 1.0, None, "relu"),
+           "qwen3_next": ("softmax", False, True, 1.0, None, "silu"),
+           "lfm2": ("sigmoid", True, True, 1.0, None, "silu"),
+           "xing4_0": ("sigmoid", True, True, 2.0, 1e-20, "silu"),
+           "glm_4_7_flash": ("sigmoid", True, True, 1.8, 1e-20, "silu"),
+           "nemotron_3_super": ("sigmoid", True, True, 5.0, 1e-20, "relu2")}
+
+
+def _route_of_pr_62(logits, top_k, norm_topk_prob, scoring, expert_bias,
+                    scale, norm_eps=None):
+    """`moe._route` as it stood up to PR 62: the chosen scores are `top_k`'s
+    own values, and a gather where a bias enters the choice."""
+    if scoring == "softmax":
+        lse = jax.nn.logsumexp(logits, axis=-1)
+        probs = jnp.exp(logits - lse[:, None])
+    else:
+        lse, probs = None, jax.nn.sigmoid(logits)
+    if expert_bias is None:
+        gate, expert = jax.lax.top_k(probs, top_k)
+    else:
+        _, expert = jax.lax.top_k(
+            probs + expert_bias.astype(jnp.float32), top_k)
+        gate = jnp.take_along_axis(probs, expert, axis=-1)
+    if norm_topk_prob:
+        total = gate.sum(-1, keepdims=True)
+        if scoring == "sigmoid":
+            total = total + (moe.SIGMOID_NORM_EPS if norm_eps is None
+                             else norm_eps)
+        gate = gate / total
+    if scale != 1.0:
+        gate = gate * scale
+    return probs, lse, gate, expert
+
+
+def _moved_as_up_to_pr_62(keys, *values):
+    """`moe._sorted_by` for keys a permutation, by XLA's scatter and gather:
+    the inverse as `zeros.at[keys].set(arange)` (how `rank` came out of
+    `order`) and each value read at it (`gate.reshape(-1)[order]` keyed on
+    `rank`, `dweight[rank]` keyed on `order`)."""
+    inverse = jnp.zeros_like(keys).at[keys].set(
+        jnp.arange(keys.shape[0], dtype=keys.dtype))
+    return tuple(v[inverse] for v in values)
+
+
+def _the_parents_forms(monkeypatch):
+    monkeypatch.setattr(moe, "_route", _route_of_pr_62)
+    monkeypatch.setattr(moe, "_sorted_by", _moved_as_up_to_pr_62)
+
+
+@pytest.mark.parametrize("cell", sorted(CELLS))
+def test_the_router_is_the_parents_to_the_bit(cell):
+    """`_route`'s four results, and the gradient into the logits of a loss
+    that reads all of them, under the cell's scoring, bias,
+    renormalisation and scale."""
+    experts, _, top_k = CELLS[cell]
+    scoring, biased, norm, scale, norm_eps, _ = ROUTERS[cell]
+    rng = np.random.RandomState(11)
+    n = 96
+    logits = jnp.asarray(rng.randn(n, experts) * 2.0, jnp.float32)
+    bias = jnp.asarray(rng.randn(experts) * 0.1, jnp.float32) if biased \
+        else None
+    g_gate = jnp.asarray(rng.randn(n, top_k), jnp.float32)
+    g_probs = jnp.asarray(rng.randn(n, experts), jnp.float32)
+    eps = {} if norm_eps is None else {"norm_eps": norm_eps}
+
+    def both(route, logits):
+        def loss(logits):
+            probs, lse, gate, expert = route(logits, top_k, norm, scoring,
+                                             bias, scale, **eps)
+            total = jnp.sum(gate * g_gate) + jnp.sum(probs * g_probs)
+            if lse is not None:
+                total = total + jnp.sum(jnp.square(lse))
+            return total, (probs, lse, gate, expert)
+        (_, results), grad = jax.value_and_grad(loss, has_aux=True)(logits)
+        return results + (grad,)
+
+    for run in (both, lambda route, logits: jax.jit(
+            lambda logits: both(route, logits))(logits)):
+        got = run(moe._route, logits)
+        want = run(_route_of_pr_62, logits)
+        assert (got[1] is None) == (want[1] is None) == (scoring == "sigmoid")
+        for name, a, b in zip(("scores", "lse", "weights", "experts",
+                               "d logits"), got, want):
+            if a is not None:
+                assert a.shape == b.shape and a.dtype == b.dtype, name
+                np.testing.assert_array_equal(np.asarray(a), np.asarray(b),
+                                              err_msg=name)
+        assert np.abs(np.asarray(got[4])).max() > 0
+
+
+def _cell_layer(cell, dtype, n=48, d=128, f=128, seed=13):
+    """One layer at the cell's (experts, held, top_k) and router, the held
+    experts in the middle of the range, widths of whole lane tiles (so that
+    the kernels' interpreter takes them): (the inputs, run(inputs) -> (out,
+    load), the output's cotangent)."""
+    experts, held, top_k = CELLS[cell]
+    scoring, biased, norm, scale, norm_eps, activation = ROUTERS[cell]
+    rng = np.random.RandomState(seed)
+
+    def draw(*shape, scale=1.0):
+        return jnp.asarray(rng.randn(*shape) * scale, jnp.float32)
+
+    w = {"x": draw(n, d), "router": draw(d, experts, scale=0.4),
+         "w_up": draw(held, d, f, scale=d ** -0.5),
+         "w_down": draw(held, f, d, scale=f ** -0.5)}
+    if activation != "relu2":
+        w["w_gate"] = draw(held, d, f, scale=d ** -0.5)
+    bias = draw(experts, scale=0.1) if biased else None
+    first = (experts - held) // 2
+
+    def run(w):
+        out, _, _, load = moe.routed_ffn(
+            w["x"], w["router"], w.get("w_gate"), w["w_up"], w["w_down"],
+            top_k, norm, expert_dtype=dtype, activation=activation,
+            first_expert=first, scoring=scoring, expert_bias=bias,
+            scale=scale, norm_eps=norm_eps)
+        return out, load
+
+    return w, run, draw(n, d).astype(dtype or jnp.float32)
+
+
+def _routes():
+    for cell in sorted(CELLS):
+        for route, dtype in ((moe.GROUPED_MATMUL, None),
+                             (moe.GROUPED_MATMUL, "bfloat16"),
+                             (moe.KERNEL_MATMUL, None)):
+            yield pytest.param(cell, route, dtype, id="%s-%s-%s" % (
+                cell, route, dtype or "float32"))
+
+
+def _take(route, monkeypatch):
+    if route == moe.KERNEL_MATMUL:
+        monkeypatch.setenv("PADDLE_TPU_PALLAS", "gmm")
+    else:
+        monkeypatch.delenv("PADDLE_TPU_PALLAS", raising=False)
+
+
+@pytest.mark.parametrize("cell,route,dtype", _routes())
+def test_the_layer_is_the_parents_to_the_bit(cell, route, dtype,
+                                             monkeypatch):
+    """The output, `ExpertLoad` and every input's gradient with the scalars
+    moved by compare and sort, against the same layer with them moved by
+    `take_along_axis`, a scatter and two gathers: through `ragged_dot` in
+    float32 and with bfloat16 experts (AMP), and through the kernels'
+    interpreter."""
+    _take(route, monkeypatch)
+    w, run, g = _cell_layer(cell, dtype and jnp.dtype(dtype))
+    assert moe.matmul_route(128, 128, jnp.dtype(dtype or "float32")) == route
+    got = _value_and_grads(run, w, g)
+    with monkeypatch.context() as parent:
+        _the_parents_forms(parent)
+        want = _value_and_grads(run, w, g)
+    assert int(got[1].sum()) == CELLS[cell][2] * w["x"].shape[0]
+    assert np.abs(np.asarray(got[0], np.float32)).max() > 0
+    np.testing.assert_array_equal(np.asarray(got[0], np.float32),
+                                  np.asarray(want[0], np.float32))
+    np.testing.assert_array_equal(np.asarray(got[1]), np.asarray(want[1]))
+    assert sorted(got[2]) == sorted(w)
+    for name in w:
+        assert np.abs(np.asarray(got[2][name])).max() > 0, name
+        np.testing.assert_array_equal(np.asarray(got[2][name]),
+                                      np.asarray(want[2][name]), err_msg=name)
+
+
+def _scalar_moves(jaxpr, counts):
+    """(primitive, indices) of every gather, scatter and scatter-add,
+    anywhere in the jaxpr, that moves single elements (a slice, or an
+    update window, of one element an index) at one of `counts` indices: a
+    scalar an assignment or a token. The rows' gathers (a whole row a
+    slice), the loops' one-index updates and what the kernels' plan reads
+    a visit are not such."""
+    found = []
+    for eqn in _all_eqns(jaxpr):
+        name = eqn.primitive.name
+        if name == "gather":
+            single = all(size == 1 for size in eqn.params["slice_sizes"])
+        elif name in ("scatter", "scatter-add"):
+            single = not eqn.params["dimension_numbers"].update_window_dims
+        else:
+            continue
+        indices = int(np.prod(eqn.invars[1].aval.shape[:-1]))
+        if single and indices in counts:
+            found.append((name, indices))
+    return found
+
+
+def _row_moves(jaxpr, width):
+    return [eqn for eqn in _all_eqns(jaxpr) if eqn.primitive.name == "gather"
+            and tuple(eqn.params["slice_sizes"]) == (1, width)]
+
+
+@pytest.mark.parametrize("cell,route", itertools.product(
+    sorted(CELLS), (moe.GROUPED_MATMUL, moe.KERNEL_MATMUL)))
+def test_no_scalar_moves_by_gather_or_scatter(cell, route, monkeypatch):
+    """One layer forward and backward at the cell's shapes: no gather,
+    scatter or scatter-add of one scalar an assignment (top_k * N, held * N
+    or [N, top_k] indices) or a token; the parent's forms, stood in their
+    place, are seen (so the walk is not blind), and the rows' gathers
+    stay."""
+    _take(route, monkeypatch)
+    n = 52                          # no multiple of it counts experts or visits
+    w, run, g = _cell_layer(cell, None, n=n)
+    experts, held, top_k = CELLS[cell]
+    counts = (n, n * top_k, n * held)
+
+    def lowered():
+        return jax.make_jaxpr(lambda w: _value_and_grads(run, w, g))(w).jaxpr
+
+    jaxpr = lowered()
+    assert _scalar_moves(jaxpr, counts) == []
+    assert _row_moves(jaxpr, 128)
+    biased = ROUTERS[cell][1]
+    with monkeypatch.context() as parent:
+        _the_parents_forms(parent)
+        seen = sorted(_scalar_moves(lowered(), counts))
+    a = n * (held if moe.numbered_by(experts, held, top_k) == "expert"
+             else top_k)
+    # the chosen scores and their scatter-add (`top_k`'s own transpose where
+    # no bias enters), then a scatter and a gather a call of `_sorted_by`
+    want = ([("gather", n * top_k)] if biased else []) \
+        + [("scatter-add", n * top_k)] + [("gather", a), ("scatter", a)] * 3
+    assert seen == sorted(want)
+
+
+# --- the grad op replays no forward pass ("Left by PR 62" (b)) --------------
+
+def _one_layer_step(experts, held, top_k, width=128):
+    """The compiled text (this backend's) of one training step of a layer:
+    a projection, `moe_ffn` under a sigmoid router with a bias, SGD."""
+    from paddle_tpu.core import lowering
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup):
+        x = fluid.layers.data(name="x", shape=[width], dtype="float32")
+        hidden = fluid.layers.fc(input=x, size=width, bias_attr=False)
+        out, _, _, _ = fluid.layers.moe_ffn(
+            hidden, experts, width, top_k, norm_topk_prob=True,
+            experts_held=held, scoring="sigmoid", expert_bias_attr=True)
+        loss = fluid.layers.mean(out)
+        fluid.optimizer.SGD(learning_rate=0.1).minimize(loss)
+    rw, ro, outs = lowering.analyze_state(main, ["x"], [loss.name])
+    scope = fluid.Scope()
+    with fluid.scope_guard(scope):
+        fluid.Executor(fluid.CPUPlace()).run(startup)
+        state = [[np.asarray(scope.find_var(name).get_tensor())
+                  for name in names] for names in (rw, ro)]
+    step = lowering.build_program_fn(main, ["x"], [loss.name], rw, ro, outs)
+    return jax.jit(lambda feed, rw, ro: step(feed, rw, ro, 0)).lower(
+        [np.zeros((64, width), "float32")], *state).compile().as_text()
+
+
+def _under(text, scope, *marks):
+    """The instructions of the compiled step whose `op_name` lies under the
+    fluid op `scope` and holds every one of `marks`."""
+    return [name for name in re.findall(r'op_name="([^"]*)"', text)
+            if re.search(r"op:%s/" % scope, name)
+            and all(mark in name for mark in marks)]
+
+
+def _forward_passes(text, scope, route):
+    """The rows' forward passes under `scope`: `_held_rows`' loop and the
+    forward grouped matmul (the kernel by its name; `ragged_dot`, which this
+    backend lowers to a dot, by a dot that is no transpose's and no
+    `_token_sum`'s, the router's own among them)."""
+    held_rows = _under(text, scope, "jit(_held_rows)/while")
+    if route == moe.KERNEL_MATMUL:
+        return held_rows, _under(text, scope, "ptpu_expert_gmm_fwd")
+    return held_rows, [name for name in _under(text, scope, "dot_general")
+                       if "transpose(" not in name and "_token_sum" not in name]
+
+
+@pytest.mark.parametrize("cell,route", [
+    ("lfm2", moe.GROUPED_MATMUL), ("lfm2", moe.KERNEL_MATMUL),
+    ("nemotron_3_super", moe.GROUPED_MATMUL),
+    ("nemotron_3_super", moe.KERNEL_MATMUL)])
+def test_the_grad_op_replays_no_forward_pass(cell, route, monkeypatch):
+    """`moe_ffn`'s grad op replays the forward rule and counts on XLA to
+    merge the replay with the forward op's operations. Whatever the rows'
+    passes read has to stay apart from anything XLA will not merge, or the
+    passes run twice a step (PR 62: a forward kernel, the unit's pass and
+    `_held_rows` under `op:moe_ffn_grad`, +4.5 ms a step). The compiled
+    step of one layer has them under the forward op's scope and none under
+    the grad op's; and a router whose chosen experts the replay computes
+    differently (a stand-in for an operation XLA does not merge) has them
+    under both, so the count sees."""
+    _take(route, monkeypatch)
+    text = _one_layer_step(*CELLS[cell])
+    for found in _forward_passes(text, "moe_ffn", route):
+        assert found
+    for found in _forward_passes(text, "moe_ffn_grad", route):
+        assert found == []
+    assert _under(text, "moe_ffn_grad", "jit(_held_weighted)/while")
+
+    calls = itertools.count(1)
+    route_of_the_tree = moe._route
+
+    def never_the_same_twice(*args, **kwargs):
+        probs, lse, gate, expert = route_of_the_tree(*args, **kwargs)
+        return probs, lse, gate, jnp.where(expert >= 0, expert, -next(calls))
+
+    monkeypatch.setattr(moe, "_route", never_the_same_twice)
+    text = _one_layer_step(*CELLS[cell])
+    for found in _forward_passes(text, "moe_ffn_grad", route):
+        assert found
