@@ -194,6 +194,36 @@ w_in, conv, [conv.bias], dt_bias, a_log, d, gated_norm, w_out; `*`: norm,
 wq, wk, wv, wo; `E`: norm, [latent_down], experts.router,
 [experts.expert_bias], experts.w_up, experts.w_down, [latent_up],
 shared_expert.w_up, shared_expert.w_down; `-`: norm, w_up, w_down.
+Laguna-S-2.1's (`model_type: laguna`, poolside): attention whose geometry
+is a LAYER's (`_geometry_by_layer`, known where the config has
+rope_parameters or num_attention_heads_per_layer): layer_types of
+`full_attention` and `sliding_attention` (an attention layer behind the
+window `sliding_window`: key j is visible to query i iff 0 <= i - j <
+sliding_window); num_attention_heads_per_layer (layer i has H_i query heads
+on the model's num_key_value_heads, every H_i a multiple of them; W_q, W_g
+and W_o are [D, H_i x head_dim], so head_dim must be given and is not held
+to hidden_size / heads; a list longer than the stack is cut to its first
+layers where it is the share's published num_hidden_layers of them);
+rope_parameters, a set a KIND of layer, {rope_type default or yarn (others
+refused), rope_theta, partial_rotary_factor, and for yarn factor,
+original_max_position_embeddings, beta_fast, beta_slow and attention_factor
+(the factor on cos and sin; absent: `yarn_table`'s m(1) / m(0) = 0.1
+ln(factor) + 1)}: ONE table a kind, made once, read by every layer of the
+kind (`rope_tables`, counted by ptpu_rope_tables_total), the scores' scale
+head_dim^-1/2 unless the set has mscale_all_dim; gating `per-head`
+(attention_gate "per_head": g = sigmoid(x W_g) [T, H_i], a projection of its
+own without bias, `layer_<i>.wg`, one scalar a head on the core's output
+before W_o: the headwise form of arXiv:2505.06708; other values refused);
+mlp_only_layers as a LEADING run (0 .. n - 1: num_dense_layers n; any other
+list refused); moe_routed_scaling_factor (routed_scaling_factor);
+mlp_layer_types and gating_types, which say again what mlp_only_layers and
+gating say and are held to them, not read twice;
+moe_apply_router_weight_on_input (false) and moe_router_logit_softcapping
+(0): other values refused. The softmax router, the gated shared expert and
+the QK-norm a head (`qk_norm: head`, a key of the config's own) are
+Qwen3-Next's. A share's published num_attention_heads_per_layer is held to
+the heads given: each is the published count x held / published key/value
+heads. A layer's attention parameters: wq, wk, wv, [q_norm, k_norm], wg, wo.
 A Mamba layer's parameters: w_in, conv, [conv.bias], w_x, w_dt, dt_bias,
 a_log, d, w_out; a memory unit's: w_in, w_out; a differential attention's:
 wq, [wq.bias], then where it makes its own keys and values wk, [wk.bias],
@@ -233,7 +263,8 @@ the experts' outputs are the partial sums of what is held.
 
 Parameters are created in the order the reference reads them: embedding;
 a layer's [attention hyper-connection: Phi, b, alpha], input norm, then Wq,
-Wk, Wv, q norm, k norm, Wo (attention), or W_qa, the q norm, W_qb, W_kva,
+Wk, Wv, q norm, k norm, [W_g, the gate a head], Wo (attention), or W_qa, the
+q norm, W_qb, W_kva,
 the kv norm, W_kvb, Wo (latent attention), or
 W_qkvz, W_ba, the convolution's filter, dt_bias, A_log, the gated norm's
 weight, W_out (gated delta net) or w_in, the convolution's filter, w_out
@@ -244,8 +275,9 @@ gate's weight]) or gate, up, down (dense), [the FFN's outgoing norm]; final
 norm; [the module's: enorm, hnorm, eh_proj, then its layer's as any
 layer's, then shared_head.norm]; [the exit gate's weight and bias]; head
 (none of its own where it is tied). The bracketed ones exist with hc_mult > 1, sandwich_norm,
-use_expert_bias, shared_expert_gate and exit_gate. A parameter is
-named by layer and role, `layer_<i>.<role>` (`layer_0.wq`,
+use_expert_bias, shared_expert_gate, exit_gate and attention_gate
+"per_head". A parameter is
+named by layer and role, `layer_<i>.<role>` (`layer_0.wq`, `layer_0.wg`,
 `layer_3.experts.w_gate`, `layer_3.experts.expert_bias`,
 `layer_1.attn_hc.phi`, `layer_1.ffn_hc.alpha`, `layer_1.wkv_b`) and
 `embedding`,
@@ -289,7 +321,8 @@ DEFAULTS = {
     "residual_multiplier": 1, "attention_multiplier": None,
     "logits_scaling": 1, "mamba_conv_bias": True, "mamba_proj_bias": False,
     "mamba_n_groups": 1, "position_embedding_type": "rope",
-    "moe_latent_size": 0}
+    "moe_latent_size": 0, "moe_apply_router_weight_on_input": False,
+    "moe_router_logit_softcapping": 0}
 # the stream every expert bias is drawn from, whatever the program's seed:
 # the draw the LFM2 cell's limits were read under (PERF.md section 4)
 EXPERT_BIAS_SEED = 39
@@ -301,7 +334,9 @@ ALIASES = {"moe_ffn_hidden_size": "intermediate_size",
            # DeepSeek-V3's names (Xing4.0's config.json)
            "n_routed_experts": "num_experts",
            "first_k_dense_replace": "num_dense_layers",
-           "scoring_func": "router_scoring"}
+           "scoring_func": "router_scoring",
+           # laguna's name (Laguna-S-2.1's config.json)
+           "moe_routed_scaling_factor": "routed_scaling_factor"}
 # the keys latent attention needs, all or none
 LATENT_KEYS = ("q_lora_rank", "kv_lora_rank", "qk_nope_head_dim",
                "qk_rope_head_dim", "v_head_dim")
@@ -314,6 +349,10 @@ MAMBA_DT_SEED = 54
 # is known only to a config that has those keys
 LAYER_TYPES = {"conv": "short_conv", "full_attention": "attention",
                "attention": "attention"}
+# laguna's: an attention layer behind the window `sliding_window`, known
+# where the config has rope_parameters or num_attention_heads_per_layer (a
+# geometry by layer)
+SLIDING = "sliding_attention"
 # the stream a Mamba-2 mixer's Delta bias is drawn from (MAMBA_DT_SEED's
 # kind: the draw the granite-4.0-h-micro cell's limits were read under)
 MAMBA2_DT_SEED = 57
@@ -351,8 +390,11 @@ def resolve(cfg):
     (the share), dense_intermediate_size (the dense FFN's width, where
     `intermediate_size` became an expert's), `latent` (the attention form),
     rope_inv_freq, rope_table_scale and attention_scale (YaRN's, or None, 1
-    and None) and the per-layer patterns
-    `rope_layers`, `window_layers`, `mixer_layers` ("attention",
+    and None), `rope_tables` (the rotary parameter sets made, (kind,
+    rotary_dim) each) and the per-layer patterns
+    `rope_layers`, `window_layers`, `geometry_layers` (a layer's own heads
+    and rotary parameters, {} where the program's single values hold:
+    `_geometry_by_layer`), `mixer_layers` ("attention",
     "gated_delta", "short_conv", "mamba", "gmu" or "mamba2") and
     `ffn_layers` ("dense" or
     "experts"), each with one entry more than the trunk has layers where
@@ -434,13 +476,37 @@ def resolve(cfg):
                       ("resid_pdrop", 0), ("mlp_bias", False),
                       ("lm_head_bias", False),
                       ("moe_primary_router_apply_softmax", True),
-                      ("mlp_only_layers", []), ("decoder_sparse_step", 1),
+                      ("decoder_sparse_step", 1),
+                      ("moe_apply_router_weight_on_input", False),
+                      ("moe_router_logit_softcapping", 0),
                       ("early_exit_threshold", 1), ("n_group", 1),
                       ("topk_group", 1), ("moe_layer_freq", 1)):
         if c[key] != want:
             raise NotImplementedError(
                 "causal_lm builds %s=%r only, the config has %r"
                 % (key, want, c[key]))
+    # the layers named are dense where the model has experts: built as a
+    # leading run, which is num_dense_layers
+    only = [int(i) for i in c["mlp_only_layers"]]
+    if only:
+        if only != list(range(len(only))):
+            raise NotImplementedError(
+                "causal_lm builds mlp_only_layers as a leading run (0 .. n - "
+                "1: num_dense_layers n), the config has %r"
+                % (c["mlp_only_layers"],))
+        c["num_dense_layers"] = len(only)
+    # laguna's gate: one scalar a head from a projection of its own
+    if "gating" in c:
+        if c["gating"] != "per-head":
+            raise NotImplementedError(
+                "causal_lm builds gating 'per-head' (sigmoid(x W_g) a head "
+                "on the core's output), the config has %r" % (c["gating"],))
+        c["attention_gate"] = "per_head"
+    if c["attention_gate"] not in (False, True, "per_head"):
+        raise NotImplementedError(
+            "causal_lm builds attention_gate false, true (a gate a channel "
+            "from a twice-wide W_q) or 'per_head', the config has %r"
+            % (c["attention_gate"],))
     # LayerNorm with weight and bias where the config names its epsilon so
     c["norm_type"] = "layer_norm" if "layer_norm_eps" in c else "rms_norm"
     if "layer_norm_eps" in c:
@@ -614,6 +680,15 @@ def resolve(cfg):
     c["rope_inv_freq"], c["rope_table_scale"], c["attention_scale"] = \
         yarn_table(scaling, c["rope_theta"], c["rotary_dim"], c["head_dim"]) \
         if scaling is not None else (None, 1.0, None)
+    # the rotary parameter sets a program has, (kind, rotary_dim) each: one
+    # table of YaRN's here, two under a geometry by layer
+    c["rope_tables"] = [("yarn", c["rotary_dim"])] if scaling is not None \
+        else []
+    # a layer's own geometry: one entry a layer of what `_layer` puts in
+    # place of the program's single value, nothing where there is none
+    c["geometry_layers"] = [{}] * (layers + mtp)
+    c["geometry_by_layer"] = "rope_parameters" in c \
+        or "num_attention_heads_per_layer" in c
     if c["attention_multiplier"] is not None:
         # the scores' scale itself, what the cores are given: not a second
         # multiply behind head_dim^-0.5
@@ -631,7 +706,8 @@ def resolve(cfg):
     if "layer_types" in c:
         kinds = list(c["layer_types"])
         known = dict(LAYER_TYPES, mamba="mamba2") if "mamba_n_heads" in c \
-            else LAYER_TYPES
+            else dict(LAYER_TYPES, **{SLIDING: "attention"}) \
+            if c["geometry_by_layer"] else LAYER_TYPES
         unknown = sorted(set(kinds) - set(known))
         # a published list over a stack cut short says which layers were
         # kept where it is one kind throughout, or where the share says that
@@ -654,6 +730,8 @@ def resolve(cfg):
         c["mixer_layers"] = ["attention" if (i + 1) % interval == 0
                              else "gated_delta" for i in range(layers)] \
             + ["attention"] * mtp
+    if c["geometry_by_layer"]:
+        _geometry_by_layer(c, published)
     c["reads_layers"] = ["own"] * (layers + mtp)
     c["lambda_init_layers"] = [None] * (layers + mtp)
     c["memory_layer"] = c["kv_layer"] = None
@@ -680,6 +758,17 @@ def resolve(cfg):
     if pattern is None:
         c["ffn_layers"] = ["dense"] * dense + ["experts"] * (layers - dense) \
             + ["experts" if c["num_experts"] else "dense"] * mtp
+    # keys that say again what others said: held to them, not read twice
+    for key, said, mean in (
+            ("mlp_layer_types", [{"dense": "dense", "experts": "sparse"}.get(
+                kind, kind) for kind in c["ffn_layers"][:layers]],
+             "mlp_only_layers, num_dense_layers and num_experts"),
+            ("gating_types", [str(c["attention_gate"]).lower()] * layers,
+             "gating")):
+        if key in c and list(c[key])[:layers] != said:
+            raise ValueError(
+                "%s %r disagrees with what %s give: %r"
+                % (key, list(c[key])[:layers], mean, said))
     if "gated_delta" in c["mixer_layers"]:
         missing = [key for key in LINEAR_KEYS if key not in c]
         if missing:
@@ -690,6 +779,128 @@ def resolve(cfg):
                              "key heads" % (c["linear_num_value_heads"],
                                             c["linear_num_key_heads"]))
     return c
+
+
+def _geometry_by_layer(c, published):
+    """laguna's attention (Laguna-S-2.1, `model_type: laguna`), whose
+    geometry is a LAYER's: `geometry_layers`, for every attention layer what
+    `_layer` puts in place of the program's single value. A head count:
+    `num_attention_heads_per_layer` (a list longer than the stack is cut to
+    its first layers where it is the share's published num_hidden_layers of
+    them; every entry a multiple of the key/value heads, so that W_q, W_g
+    and W_o are [D, H_i x head_dim]: head_dim must be given). A window:
+    `sliding_window` on the layers whose `layer_types` says
+    `sliding_attention` (`window_layers`). Rotary parameters:
+    `rope_parameters[layer_types[i]]`, {rope_type default or yarn,
+    rope_theta, partial_rotary_factor and, for yarn, `yarn_table`'s keys and
+    attention_factor, the factor on cos and sin where given}: rotary_dim,
+    rope_inv_freq, rope_table_scale, rope_theta and attention_scale, ONE
+    table a KIND of layer (`rope_tables` has them), shared by the layers of
+    the kind. A layer that is no attention has no entry."""
+    layers, hkv = c["num_hidden_layers"], c["num_key_value_heads"]
+    for key, want in (("latent", False), ("differential_attention", False),
+                      ("mb_per_layer", 0), ("rope_scaling", None),
+                      ("mtp_layers", 0), ("total_ut_steps", 1),
+                      ("rope_layout", None), ("sliding_window_layout", None),
+                      ("attention_multiplier", None)):
+        if c.get(key) != want:
+            raise NotImplementedError(
+                "causal_lm builds a geometry by layer (rope_parameters, "
+                "num_attention_heads_per_layer) with %s=%r only, the config "
+                "has %r" % (key, want, c.get(key)))
+    if "layer_types" not in c or "head_dim" not in c:
+        raise ValueError("a geometry by layer needs layer_types (a layer's "
+                         "kind names its rope_parameters and its window) "
+                         "and head_dim (no head count gives it)")
+    kinds = list(c["layer_types"])[:layers]
+    attends = [mixer == "attention" for mixer in c["mixer_layers"][:layers]]
+    heads = list(c.get("num_attention_heads_per_layer",
+                       [c["num_attention_heads"]] * layers))
+    if len(heads) < layers or (
+            len(heads) > layers
+            and len(heads) != published.get("num_hidden_layers")):
+        raise ValueError(
+            "num_attention_heads_per_layer has %d entries for %d layers "
+            "(more only where it is the share's published num_hidden_layers "
+            "of them: the first are built)" % (len(heads), layers))
+    heads = [int(n) for n in heads[:layers]]
+    wrong = sorted({n for n in heads if n < 1 or n % hkv})
+    if wrong:
+        raise ValueError("num_attention_heads_per_layer has %r, no multiple "
+                         "of %d key/value heads" % (wrong, hkv))
+    whole = published.get("num_attention_heads_per_layer")
+    if whole is not None and any(
+            n * published.get("num_key_value_heads", hkv) != m * hkv
+            for n, m in zip(heads, whole)):
+        raise ValueError(
+            "num_attention_heads_per_layer %r on %d key/value heads is not "
+            "the share of the published %r on %r that those key/value heads "
+            "carry" % (heads, hkv, list(whole)[:layers],
+                       published.get("num_key_value_heads", hkv)))
+    window = c.get("sliding_window")
+    if SLIDING in kinds and (not isinstance(window, int) or window < 1):
+        raise ValueError("layer_types has %s layers, which need "
+                         "sliding_window, a whole number of keys; the "
+                         "config has %r" % (SLIDING, window))
+    c["window_layers"] = [window if kind == SLIDING else None
+                          for kind in kinds]
+    sets, tables = c.get("rope_parameters"), {}
+    if sets is not None:
+        for kind in sorted({k for k, a in zip(kinds, attends) if a}):
+            if not isinstance(sets.get(kind), dict):
+                raise NotImplementedError(
+                    "causal_lm builds rope_parameters by layer type, a set "
+                    "for each of layer_types' kinds; the config has none for "
+                    "%r among %s" % (kind, sorted(sets)))
+            tables[kind] = _rope_kind(c, kind, sets[kind])
+        c["rope_tables"] = [(tables[kind]["rope_type"],
+                             tables[kind]["rotary_dim"])
+                            for kind in sorted(tables)]
+    c["geometry_layers"] = [
+        dict(tables.get(kind, {}), num_attention_heads=n) if attention
+        else {} for kind, n, attention in zip(kinds, heads, attends)]
+    c["rope_layers"] = [
+        attention and g.get("rope_theta", c["rope_theta"]) is not None
+        for g, attention in zip(c["geometry_layers"], attends)]
+
+
+def _rope_kind(c, kind, params):
+    """One kind of layer's rotary parameters as `attention` reads them:
+    rope_type `default` (theta^(-2i/R) over the R = head_dim x
+    partial_rotary_factor first channels of a head) or `yarn`
+    (`yarn_table` over those R, cos and sin multiplied by attention_factor
+    where the set gives one, else by the table's m(1) / m(0); the scores'
+    scale stays head_dim^-1/2 unless the set has mscale_all_dim)."""
+    rope_type = params.get("rope_type", "default")
+    if rope_type not in ("default", "yarn"):
+        raise NotImplementedError(
+            "causal_lm builds rope_parameters of rope_type default or yarn, "
+            "%s has %r" % (kind, rope_type))
+    hd = c["head_dim"]
+    factor = params.get("partial_rotary_factor", c["partial_rotary_factor"])
+    rotary = int(hd * factor)
+    if rotary % 2 or not 0 < rotary <= hd:
+        raise ValueError("partial_rotary_factor %r of a head of %d turns %d "
+                         "channels on %s layers: not an even number in (0, "
+                         "%d]" % (factor, hd, rotary, kind, hd))
+    found = dict(rope_type=rope_type, rotary_dim=rotary, rope_inv_freq=None,
+                 rope_theta=params.get("rope_theta", c["rope_theta"]),
+                 rope_table_scale=1.0, attention_scale=None)
+    if rope_type == "yarn":
+        missing = [key for key in ("factor",
+                                   "original_max_position_embeddings")
+                   if key not in params]
+        if missing:
+            raise ValueError("rope_parameters of rope_type yarn need %s (%s)"
+                             % (missing, kind))
+        inv_freq, table_scale, scale = yarn_table(
+            params, found["rope_theta"], rotary, hd)
+        given = params.get("attention_factor")
+        found.update(
+            rope_inv_freq=inv_freq,
+            rope_table_scale=table_scale if given is None else float(given),
+            attention_scale=scale if params.get("mscale_all_dim") else None)
+    return found
 
 
 def _one_branch_layers(c, pattern, published):
@@ -873,11 +1084,16 @@ def yarn_table(scaling, theta, rotary_dim, head_dim):
 def _layer(c, i):
     """The config as layer i sees it: `layer` its index, which names its
     parameters; `rope_theta` None where the pattern gives the layer no
-    rotary, `window` its sliding window or None, `ffn` its FFN's kind."""
-    return dict(c, layer=i, window=c["window_layers"][i],
-                ffn=c["ffn_layers"][i], reads=c["reads_layers"][i],
-                lambda_init=c["lambda_init_layers"][i],
-                rope_theta=c["rope_theta"] if c["rope_layers"][i] else None)
+    rotary, `window` its sliding window or None, `ffn` its FFN's kind; and,
+    under a geometry by layer, its own num_attention_heads, rotary_dim,
+    rope_inv_freq, rope_table_scale, rope_theta, attention_scale and
+    rope_type in place of the program's (`_geometry_by_layer`)."""
+    cl = dict(c, layer=i, window=c["window_layers"][i],
+              ffn=c["ffn_layers"][i], reads=c["reads_layers"][i],
+              lambda_init=c["lambda_init_layers"][i],
+              rope_theta=c["rope_theta"] if c["rope_layers"][i] else None)
+    cl.update(c["geometry_layers"][i])
+    return cl
 
 
 def _attr(c, role, initializer=None):
@@ -928,10 +1144,16 @@ def attention(x, pos, c):
     channels of every head of q and k; key/value heads may be fewer than
     query heads; the core is layers.fused_attention. With attention_gate the
     query projection is twice as wide, a head [q, gate], and the context is
-    multiplied by sigmoid(gate) before the output projection."""
+    multiplied by sigmoid(gate) before the output projection; with
+    attention_gate "per_head" (laguna's) the gate is one scalar a head from
+    a projection of its own, sigmoid(x W_g) [B, T, H] (`wg`, no bias), on
+    the core's output before W_o. The heads, the rotary's width, table and
+    theta and the window are the LAYER's (`_layer`): W_q and W_o are [D, H
+    x head_dim] whatever the hidden size is."""
     d, hd = c["hidden_size"], c["head_dim"]
     h, hkv = c["num_attention_heads"], c["num_key_value_heads"]
-    gated = c["attention_gate"]
+    per_head = c["attention_gate"] == "per_head"
+    gated = c["attention_gate"] and not per_head
     q, k, v = (_linear(x, n * hd, c, role) for n, role in (
         (2 * h if gated else h, "wq"), (hkv, "wk"), (hkv, "wv")))
     cq, ck = dict(c, role="q_norm"), dict(c, role="k_norm")
@@ -954,6 +1176,9 @@ def attention(x, pos, c):
                                        scale=c["attention_scale"])
     if gated:
         ctx = ctx * fluid.layers.sigmoid(gate)
+    if per_head:
+        ctx = fluid.layers.elementwise_mul(
+            ctx, fluid.layers.sigmoid(_linear(x, h, c, "wg")), axis=0)
     return _linear(fluid.layers.reshape(ctx, shape=[0, -1, h * hd]), d, c,
                    "wo")
 
@@ -1410,8 +1635,19 @@ def _count_layer(c, mixer, module="trunk"):
         "had), the layer's branches (1: a mixer or an FFN; 2: both), the "
         "width of the latent space its routed experts work in (0: the "
         "model's own) and whether its FFN is gated (mixer none: a layer "
-        "that is an FFN alone; ffn none: a mixer alone)"
+        "that is an FFN alone; ffn none: a mixer alone); and, for a config "
+        "with a geometry by layer alone (rope_parameters, "
+        "num_attention_heads_per_layer), the query and key/value heads the "
+        "layer holds, its window (0: none) and its rotary parameters' kind "
+        "(default, yarn, none), rotary_dim then the layer's own; gate is "
+        "per_head where the gate is a scalar a head"
     ).inc(mixer=mixer, module=module, reads=c["reads"],
+          **({} if not c["geometry_by_layer"] else dict(
+              heads=str(c["num_attention_heads"] if attention else 0),
+              kv_heads=str(c["num_key_value_heads"] if attention else 0),
+              window=str(c["window"] or 0),
+              rope=c.get("rope_type", "default")
+              if attention and c["rope_theta"] is not None else "none")),
           **({} if c.get("hybrid_override_pattern") is None else dict(
               branches=str(2 - ("none" in (mixer, c["ffn"]))),
               latent=str(c["moe_latent_size"] if c["ffn"] == "experts"
@@ -1421,7 +1657,8 @@ def _count_layer(c, mixer, module="trunk"):
                                 and c["differential_attention"])).lower(),
           rotary_dim=str(c["rotary_dim"] if attention
                          and c["rope_theta"] is not None else 0),
-          gate=str(bool(attention and c["attention_gate"])).lower(),
+          gate="per_head" if attention and c["attention_gate"] == "per_head"
+          else str(bool(attention and c["attention_gate"])).lower(),
           conv=str(0 if attention or mixer in ("gmu", "none")
                    else c["conv_L_cache"]
                    if mixer == "short_conv" else c["mamba_d_conv"]
@@ -1621,6 +1858,7 @@ def causal_lm(cfg, seq_len, extras=None, recompute=True):
     else:
         states = [h]
     _count_passes(c)
+    _count_rope_tables(c)
 
     _count_head(c)
     tied = fluid.default_main_program().global_block().var("embedding") \
@@ -1706,6 +1944,20 @@ def _count_module(c):
         "and lambda, the weight of its loss term"
     ).inc(depth="1", shared_embedding="true", shared_head="true",
           loss_weight="%g" % c["mtp_loss_weight"])
+
+
+def _count_rope_tables(c):
+    from ..observability.registry import REGISTRY
+    tables = REGISTRY.counter(
+        "ptpu_rope_tables_total",
+        "rotary parameter sets `resolve` made for the models causal_lm "
+        "built, by kind (yarn: a frequency table made on the host; default: "
+        "theta^(-2i/R), the op's own, counted where rope_parameters names "
+        "it) and the channels of a head it turns: one a program under "
+        "rope_scaling, one a KIND of layer under rope_parameters, so a "
+        "table made a layer shows")
+    for kind, rotary_dim in c["rope_tables"]:
+        tables.inc(kind=kind, rotary_dim=str(rotary_dim))
 
 
 def _count_passes(c):

@@ -69,9 +69,24 @@ def layer_config(c, i):
     pattern gives the layer no rotary (NoPE), `window` its sliding window or
     None. The reference's own reading of the two patterns, not the
     builder's."""
-    return dict(c, rope_theta=c["rope_theta"] if c["rope_layers"][i]
-                else None, window=c["window_layers"][i],
-                lambda_init=c["lambda_init_layers"][i])
+    cl = dict(c, rope_theta=c["rope_theta"] if c["rope_layers"][i]
+              else None, window=c["window_layers"][i],
+              lambda_init=c["lambda_init_layers"][i])
+    if c["geometry_by_layer"]:
+        # laguna's: the layer's kind names its window and its rotary
+        # parameters, read here from the config's own keys
+        kind = c["layer_types"][i]
+        cl["window"] = c["sliding_window"] if kind == "sliding_attention" \
+            else None
+        rope = c.get("rope_parameters", {}).get(kind)
+        if rope is not None:
+            cl.update(rope_theta=rope.get("rope_theta", c["rope_theta"]),
+                      rope_scaling=rope if rope.get("rope_type") == "yarn"
+                      else None,
+                      rotary_dim=int(c["head_dim"] * rope.get(
+                          "partial_rotary_factor",
+                          c["partial_rotary_factor"])))
+    return cl
 
 
 def rms_norm(x, w, eps, zero_centered=False):
@@ -208,7 +223,8 @@ def hyper_connection(x, phi, bias, alpha, c):
         2.0 * jax.nn.sigmoid(ht[..., n:2 * n]), m
 
 
-def attention(a, pos, wq, wk, wv, q_norm, k_norm, wo, c):
+def attention(a, pos, wq, wk, wv, q_norm, k_norm, wo, c, w_gate=None,
+              found=None):
     """Causal attention of the heads whose weights are given: wq [D, Hq x
     hd], wk and wv [D, Hkv x hd], wo [Hq x hd, D]; query head h reads
     key/value head h // (Hq / Hkv). c["rope_theta"] None: no position
@@ -216,10 +232,17 @@ def attention(a, pos, wq, wk, wv, q_norm, k_norm, wo, c):
     i - j < w (the Hugging Face sliding-window mask's convention). With
     attention_gate wq is [D, Hq x 2 hd], a head [q, gate], and the context
     is multiplied by sigmoid(gate) before wo; qk_norm "head" is over each
-    head's hd after the split, with one weight [hd]."""
+    head's hd after the split, with one weight [hd]. w_gate [D, Hq]
+    (laguna's `gating: per-head`): the context of head h is multiplied by
+    sigmoid(a w_gate)[h] before wo. Under rope_scaling (a layer's own
+    rope_parameters of rope_type yarn) cos and sin are multiplied by
+    `attention_factor` where the set gives it, else by m(mscale) /
+    m(mscale_all_dim). A dict given as `found` gets, a call, `core_q` and
+    `core_k` (q and k as the core reads them: normed and turned) and
+    `head_gate` (the per-head gate's sigmoid)."""
     b, t, _ = a.shape
     hd, eps = c["head_dim"], c["rms_norm_eps"]
-    gated, centred = c["attention_gate"], c["norm_zero_centered"]
+    gated, centred = c["attention_gate"] is True, c["norm_zero_centered"]
     h, hkv = wq.shape[1] // (2 * hd if gated else hd), wk.shape[1] // hd
     q, k, v = a @ wq, a @ wk, a @ wv
     if c["qk_norm"] is True:     # over all channels, before the head split
@@ -242,9 +265,14 @@ def attention(a, pos, wq, wk, wv, q_norm, k_norm, wo, c):
             c["rope_scaling"], c["rope_theta"], c["rotary_dim"])
         table_scale = yarn_mscale(factor,
                                   c["rope_scaling"].get("mscale", 1)) / m
+        if c["rope_scaling"].get("attention_factor") is not None:
+            table_scale = c["rope_scaling"]["attention_factor"]
     if c["rope_theta"] is not None:
         q, k = (rope(x, pos, c["rope_theta"], c["rotary_dim"], table,
                      c["rope_interleaved"], table_scale) for x in (q, k))
+    if found is not None:
+        found.setdefault("core_q", []).append(q)
+        found.setdefault("core_k", []).append(k)
     k, v = (jnp.repeat(x, h // hkv, axis=2) for x in (k, v))
     s = jnp.einsum("bqhd,bkhd->bhqk", q, k) * scale
     age = jnp.arange(t)[:, None] - jnp.arange(t)[None, :]       # i - j
@@ -255,6 +283,11 @@ def attention(a, pos, wq, wk, wv, q_norm, k_norm, wo, c):
     ctx = jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s, -1), v)
     if gated:
         ctx = ctx * jax.nn.sigmoid(gate)
+    if w_gate is not None:
+        gate = jax.nn.sigmoid(a @ w_gate)
+        ctx = ctx * gate[..., None]
+        if found is not None:
+            found.setdefault("head_gate", []).append(gate)
     return ctx.reshape(b, t, h * hd) @ wo
 
 
@@ -691,8 +724,11 @@ def passes(cfg, params, ids, pos, next_ids=None, found=None):
         elif c["latent"]:
             mixer = take(7)
         else:
-            mixer = take(3) + (take(2) if c["qk_norm"] else [None, None]) \
-                + take(1)
+            # wq, wk, wv, [q_norm, k_norm], wo, then [the per-head gate],
+            # which the program creates before wo
+            mixer = take(3) + (take(2) if c["qk_norm"] else [None, None])
+            gate = take(1) if c["attention_gate"] == "per_head" else []
+            mixer = mixer + take(1) + gate
         n2 = take(1)[0] if sandwich else None
         hcs.append((hc_a, take(3) if streams > 1 else None))
         n3 = take_norm()
@@ -825,7 +861,10 @@ def passes(cfg, params, ids, pos, next_ids=None, found=None):
                 mixed, _ = latent_attention(a, pos, *mixer,
                                             layer_config(c, i))
             else:
-                mixed = attention(a, pos, *mixer, layer_config(c, i))
+                mixed = attention(a, pos, *mixer[:6], layer_config(c, i),
+                                  *mixer[6:], found=found)
+                if found is not None:
+                    found.setdefault("attention_layers", []).append(mixed)
             if sandwich:
                 mixed = rms_norm(mixed, n2, eps, centred)
             if found is not None and c["mixer_layers"][i] == "attention":

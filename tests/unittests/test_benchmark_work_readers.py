@@ -46,6 +46,9 @@ GRANITE_METRICS = ("ssd_scan_ms_per_step", "ssd_scan_roofline_share",
 NEMOTRON = "nemotron_3_super_120b_a12b_train_t4096"
 # the two metrics PR 61 added with its cell
 NEMOTRON_METRICS = ("latent_moe_layer_share", "single_branch_layer_share")
+LAGUNA = "laguna_s_2_1_train_t4096"
+# the two metrics PR 64 added with its cell
+LAGUNA_METRICS = ("windowed_layer_share", "per_head_gate_layer_share")
 
 
 def test_glm_flash_operations_against_the_hand_count():
@@ -113,26 +116,29 @@ def test_the_pinned_manifest_test_is_red_for_the_eleventh_cell_alone(
     twelfth cell (Phi-4-mini-flash's), its configuration and its three
     metrics, and the thirteenth (granite-4.0-h-micro's), its configuration,
     its traffic's cell and its three metrics, and the fourteenth
-    (Nemotron-3-Super's), its configuration and its two metrics: nothing
-    else it holds has moved."""
+    (Nemotron-3-Super's), its configuration and its two metrics, and the
+    fifteenth (Laguna-S-2.1's), its configuration and its two metrics:
+    nothing else it holds has moved."""
     pinned = getattr(readers, PINNED)
     with pytest.raises(AssertionError, match="^expert_matmul_ms_per_step$"):
         pinned()
     with open(readers.BENCHMARK) as f:
         bench = json.load(f)
     bench["workloads"] = [w for w in bench["workloads"]
-                          if w["name"] not in (GLM, PHI, GRANITE, NEMOTRON)]
+                          if w["name"] not in (GLM, PHI, GRANITE, NEMOTRON,
+                                               LAGUNA)]
     bench["configs"] = [c for c in bench["configs"]
                         if c["name"] not in ("glm_4_7_flash",
                                              "phi4_mini_flash",
                                              "granite_4_0_h_micro",
-                                             "nemotron_3_super_120b_a12b")]
+                                             "nemotron_3_super_120b_a12b",
+                                             "laguna_s_2_1")]
     bench["per_layer"] = [m for m in bench["per_layer"]
                           if m["name"] not in ("mtp_layer_share",)
                           + PHI_METRICS + GRANITE_METRICS
-                          + NEMOTRON_METRICS]
+                          + NEMOTRON_METRICS + LAGUNA_METRICS]
     for metric in bench["end_to_end"] + bench["per_layer"]:
-        for cell in (GLM, PHI, GRANITE, NEMOTRON):
+        for cell in (GLM, PHI, GRANITE, NEMOTRON, LAGUNA):
             if cell in metric.get("workloads", ()):
                 metric["workloads"].remove(cell)
     without = tmp_path / "BENCHMARK.json"
@@ -189,8 +195,8 @@ def test_the_manifest_lists_the_work_readers_in_eleven_cells():
     # the 8192 rows in VMEM)
     for name in ("flash_roofline_share", "embedding_grad_ms_per_step",
                  "embedding_grad_roofline_share", "step_mfu"):
-        assert entries[name]["workloads"][-3:] == [PHI, GRANITE,
-                                                   NEMOTRON], name
+        assert entries[name]["workloads"][-4:] == [PHI, GRANITE, NEMOTRON,
+                                                   LAGUNA], name
     phi = readers._cell(PHI)
     pairs = (512 * 513 // 2 + (8192 - 512) * 512) + 2 * (8192 * 8193 // 2)
     assert phi.config_module.flash_kernel_ops(phi.config, phi.traffic) == {
@@ -233,11 +239,13 @@ def test_the_manifest_lists_the_work_readers_in_eleven_cells():
     # one group, five layers; and TWO matmuls an expert of [1024 x 2688]
     # (configs/causal_lm.py's three would read 1.5 times the work)
     for name in ("expert_matmul_ms_per_step", "expert_matmul_roofline_share"):
-        assert entries[name]["workloads"][-2:] == [GLM, NEMOTRON], name
+        assert entries[name]["workloads"][-3:] == [GLM, NEMOTRON,
+                                                   LAGUNA], name
     for name in ("pallas_ms_per_step", "softmax_xent_ms_per_step",
                  "flash_fwd_ms_per_step", "flash_bwd_dkdv_ms_per_step",
                  "flash_bwd_dq_ms_per_step"):
-        assert entries[name]["workloads"][-2:] == [GRANITE, NEMOTRON], name
+        assert entries[name]["workloads"][-3:] == [GRANITE, NEMOTRON,
+                                                   LAGUNA], name
     nemotron = readers._cell(NEMOTRON)
     mod = nemotron.config_module
     pairs = 4096 * 4097 // 2
@@ -263,6 +271,36 @@ def test_the_manifest_lists_the_work_readers_in_eleven_cells():
             if m["name"] in NEMOTRON_METRICS] == [[NEMOTRON]] * 2
     assert sum(mod.forward_macs(nemotron.config, nemotron.traffic)
                .values()) == pytest.approx(426.3e6, rel=1e-3)
+    # the fifteenth cell behind that one on the lists whose reader finds
+    # something in its trace (the flash kernels', the experts', the
+    # embedding gradient's, the loss's), with its module's hand counts: a
+    # LAYER's heads over a LAYER's pairs (12 of 128 over all causal pairs on
+    # layers 0 and 4, 18 over the visible pairs of a window of 512 on layers
+    # 1-3); the table of 25088 words of 3072 written; three matmuls an
+    # expert of [3072 x 1024]
+    laguna = readers._cell(LAGUNA)
+    mod = laguna.config_module
+    windowed = 512 * 513 // 2 + (4096 - 512) * 512
+    pairs = 2 * 12 * (4096 * 4097 // 2) + 3 * 18 * windowed
+    assert windowed == 1966336 and pairs == 307557888
+    assert mod.flash_kernel_ops(laguna.config, laguna.traffic) == {
+        "ptpu_flash_fwd": 4 * 128 * pairs,
+        "ptpu_flash_bwd_dkdv": 8 * 128 * pairs,
+        "ptpu_flash_bwd_dq": 6 * 128 * pairs}
+    assert mod.embedding_grad_bytes(laguna.config, laguna.traffic) \
+        == 4 * 3072 * 25088 == 308281344
+    assert mod.embedding_grad_bytes is not mod.base.embedding_grad_bytes
+    even = np.zeros(256, np.int64)
+    even[:8] = 4 * 160              # 10 x 4096 / 256 rows a held expert
+    assert mod.expert_matmul_ops(laguna.config, laguna.traffic, even) \
+        == 3 * 3 * 2 * 3072 * 1024 * 5120
+    assert [m["workloads"] for m in bench["per_layer"]
+            if m["name"] in LAGUNA_METRICS] == [[LAGUNA]] * 2
+    assert sum(mod.forward_macs(laguna.config, laguna.traffic)
+               .values()) == pytest.approx(331.6e6, rel=1e-3)
+    # fifteen cells, one of them on four chips: floor(15 x 0.25) = 3
+    assert [w["name"] for w in bench["workloads"]][14] == LAGUNA
+    assert [w["chips"] for w in bench["workloads"]][:15].count(4) == 1
     # fourteen cells, one of them on four chips: floor(14 x 0.25) = 3
     assert [w["name"] for w in bench["workloads"]][13] == NEMOTRON
     assert [w["chips"] for w in bench["workloads"]][:14].count(4) == 1

@@ -156,7 +156,7 @@ def test_resolve_reads_qwen3_nexts_keys():
 
 
 @pytest.mark.parametrize("edit,error,match", [
-    (dict(mlp_only_layers=[0]), NotImplementedError, "mlp_only_layers"),
+    (dict(mlp_only_layers=[1]), NotImplementedError, "mlp_only_layers"),
     (dict(decoder_sparse_step=2), NotImplementedError,
      "decoder_sparse_step"),
     (dict(rope_scaling={"type": "linear"}), NotImplementedError,
