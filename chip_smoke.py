@@ -82,6 +82,7 @@ last line of stdout is one JSON object, {"ok": ..., "device": {...}}.
 Exit codes: 0 all phases passed, 1 a phase failed, 2 no TPU (nothing ran).
 """
 import argparse
+import functools
 import itertools
 import json
 import os
@@ -119,6 +120,23 @@ FULL = {
             dict(name="Nemotron-3-Super", n=4096, d=1024, router_d=4096,
                  e=512, held=8, f=2688, top_k=22, gated=False,
                  activation="relu2", scoring="sigmoid")),
+        # the rows no group reads (PR 65), at six cells' shapes: the unit as
+        # the gate/up kernel's epilogue, what a buffer of sorted rows starts
+        # from, the layer before and after
+        dead_rows=(
+            dict(name="Nemotron-3-Super", n=4096, d=1024, router_d=4096,
+                 e=512, held=8, f=2688, top_k=22, gated=False,
+                 activation="relu2", scoring="sigmoid"),
+            dict(name="Laguna-S-2.1", n=4096, d=3072, e=256, held=8, f=1024,
+                 top_k=10, activation="silu", scoring="softmax"),
+            dict(name="LFM2", n=8192, d=2048, e=32, held=8, f=1792, top_k=4,
+                 activation="silu", scoring="sigmoid"),
+            dict(name="SmallThinker", n=8192, d=2560, e=64, held=16, f=768,
+                 top_k=6, activation="relu", scoring="softmax"),
+            dict(name="Qwen3-Next", n=4096, d=2048, e=512, held=32, f=512,
+                 top_k=10, activation="silu", scoring="softmax"),
+            dict(name="OLMoE", n=16384, d=2048, e=64, held=64, f=1024,
+                 top_k=8, activation="silu", scoring="softmax")),
         # the scalars `routed_ffn` moves between the router and the rows, at
         # five cells' shapes: tokens, experts routed over and held, experts
         # a token (PR 63: each site's two forms alone)
@@ -209,6 +227,13 @@ TINY = {
                     dict(name="tiny ungated", n=64, d=128, router_d=64, e=16,
                          held=4, f=128, top_k=3, gated=False,
                          activation="relu2", scoring="sigmoid")),
+        dead_rows=(dict(name="tiny", n=64, d=256, e=8, held=2, f=128,
+                        top_k=3, activation="silu", scoring="softmax"),
+                   dict(name="tiny ungated", n=64, d=256, router_d=64, e=16,
+                        held=4, f=128, top_k=5, gated=False,
+                        activation="relu2", scoring="sigmoid"),
+                   dict(name="tiny whole", n=64, d=256, e=4, held=4, f=128,
+                        top_k=2, activation="relu", scoring="softmax")),
         routing_sites=(dict(name="tiny", n=64, e=16, held=4, top_k=5),
                        dict(name="tiny whole", n=64, e=8, held=8, top_k=2)),
         gated_delta=dict(b=2, t=40, hk=2, hv=4, d=16),
@@ -991,6 +1016,209 @@ def _routing_site_times(smoke, c):
               % (c["name"], n, k, e, held, "; ".join(lines)))
 
 
+def _rows_by_dma(x3, token, total, block):
+    """x3 [N, C, 128] of 32-bit words, a token's row the C x 128 of one
+    leading index -> [A, C, 128]: row r is x3[token[r]] below `total`, a
+    DMA a row from HBM into the output's tile in VMEM, a tile's all in
+    flight at once; tiles past `total` name the last live one again and do
+    nothing. The form `_held_rows` would take as a kernel (ISSUE 65 (a)),
+    kept here for its one number, the ns a row a DMA gathers at: Mosaic
+    slices a tiled array's rows by eights, so a row has to be a leading
+    index, which a [A, D] buffer of bfloat16 rows is not (two ROWS share a
+    word there); what a kernel in the program would add is that relayout
+    in VMEM."""
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+    from paddle_tpu.ops.pallas_import import pl, pltpu
+
+    def kernel(total, token_ref, x_hbm, out_ref, sem):
+        live = jnp.clip(total[0] - pl.program_id(0) * block, 0, block)
+
+        def copy(r, source):
+            return pltpu.make_async_copy(x_hbm.at[source], out_ref.at[r], sem)
+
+        @pl.when(live > 0)
+        def _():
+            def issue(r, carry):
+                copy(r, token_ref[r]).start()
+                return carry
+
+            def wait(r, carry):
+                copy(r, 0).wait()
+                return carry
+
+            lax.fori_loop(0, live, issue, 0)
+            lax.fori_loop(0, live, wait, 0)
+
+    def live_tile(i, total):
+        return jnp.minimum(i, jnp.maximum(total[0] - 1, 0) // block)
+
+    rows = token.shape[0]
+    return pl.pallas_call(
+        kernel,
+        out_shape=jax.ShapeDtypeStruct((rows,) + x3.shape[1:], x3.dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(rows // block,),
+            in_specs=[pl.BlockSpec((block,), lambda i, t: (live_tile(i, t),),
+                                   memory_space=pltpu.SMEM),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec((block,) + x3.shape[1:],
+                                   lambda i, t: (live_tile(i, t), 0, 0)),
+            scratch_shapes=[pltpu.SemaphoreType.DMA(())]),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",), vmem_limit_bytes=100 << 20),
+        interpret=jax.devices()[0].platform != "tpu",
+        name="smoke_rows_by_dma")(total.reshape(1), token, x3)
+
+
+def _dead_rows_times(smoke, c):
+    """What PR 65 took off the rows of the sorted buffer that belong to no
+    group, each part alone at a cell's sizes, bf16 experts as under AMP:
+    (1) the gate/up matmuls and the unit as two kernels and an XLA pass over
+    all A rows (the form up to PR 64, made here from the program's own
+    kernels) and as ONE kernel with the unit its epilogue
+    (expert_gmm.gmm_unit), the hidden rows below the groups' sum compared;
+    (2) where a share is held, `_held_rows` from a buffer of zeros, from one
+    nothing wrote (expert_gmm.unwritten), and the same rows gathered by a
+    DMA a row (`_rows_by_dma`), with the ns a gathered row of XLA's loop and
+    of the DMAs; (3) the layer forward and forward + backward with the old
+    forms stood in the program's place, and as it is. In flight: the
+    numbers order the forms, a cell's trace by scope is the number. Ten
+    calls in flight keep ten sets of results: three where a set passes a
+    quarter of a GiB (OLMoE's three [131072, 1024] are 0.75)."""
+    import contextlib
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.ops import expert_gmm
+    from paddle_tpu.parallel import moe
+
+    n, d, e, held, f, k = (c[key] for key in ("n", "d", "e", "held", "f",
+                                              "top_k"))
+    gated = c.get("gated", True)
+    unit = moe._gated_unit(c["activation"]) if gated \
+        else moe._ungated_unit(c["activation"])
+    slots = held if moe.numbered_by(e, held, k) == "expert" else k
+    a = slots * n
+    rng = np.random.RandomState(37)
+    x, g = (jnp.asarray(rng.randn(n, d), jnp.bfloat16) for _ in range(2))
+    router_x = jnp.asarray(rng.randn(n, c["router_d"]), jnp.bfloat16) \
+        if "router_d" in c else None
+    router = jnp.asarray(rng.randn(c.get("router_d", d), e) * 0.02,
+                         jnp.float32)
+    wg, wu = (jnp.asarray(rng.randn(held, d, f) * 0.02, jnp.float32)
+              for _ in range(2))
+    wd = jnp.asarray(rng.randn(held, f, d) * 0.02, jnp.float32)
+    if not gated:
+        wg = None
+    rows = jnp.asarray(rng.randn(a, d), jnp.bfloat16)
+    # the held experts' counts under a uniform router, as a cell's are
+    chosen = np.argsort(rng.rand(n, e), axis=1)[:, :k]
+    sizes = jnp.asarray(np.bincount(chosen.reshape(-1), minlength=e)[:held],
+                        jnp.int32)
+    total = int(sizes.sum())
+    token = jnp.asarray(rng.randint(0, n, a), jnp.int32)
+    timed = functools.partial(
+        _in_flight_ms, calls=10 if 6 * a * max(d, f) <= 1 << 28 else 3)
+
+    def bf16(w):
+        return None if w is None else w.astype(jnp.bfloat16)
+
+    def apart(rows, w_gate, w_up, plan, unit):
+        made = [expert_gmm.gmm(rows, w, plan) for w in (w_gate, w_up)
+                if w is not None]
+        return made + [unit(*made)]
+
+    def as_one(rows, wg, wu, sizes):
+        return expert_gmm.gmm_unit(rows, bf16(wg), bf16(wu),
+                                   expert_gmm.plan(sizes, a), unit)
+
+    def as_three(rows, wg, wu, sizes):
+        return apart(rows, bf16(wg), bf16(wu), expert_gmm.plan(sizes, a),
+                     unit)
+
+    @contextlib.contextmanager
+    def up_to_pr_64():
+        kept = expert_gmm.gmm_unit, expert_gmm.unwritten
+        expert_gmm.gmm_unit = apart
+        expert_gmm.unwritten = lambda shape, dtype, like: jnp.zeros(shape,
+                                                                    dtype)
+        try:
+            yield
+        finally:
+            expert_gmm.gmm_unit, expert_gmm.unwritten = kept
+
+    def layer(x, router, wg, wu, wd):
+        return moe.routed_ffn(
+            x, router, wg, wu, wd, k, True, expert_dtype=jnp.bfloat16,
+            router_x=router_x, activation=c["activation"],
+            scoring=c["scoring"])[0]
+
+    def trained(x, router, wg, wu, wd, g):
+        return jax.vjp(layer, x, router, wg, wu, wd)[1](g)
+
+    with jax.default_device(smoke.device):
+        if moe.matmul_route(d, f, jnp.bfloat16) != moe.KERNEL_MATMUL:
+            raise AssertionError("%s's widths do not take the kernels here"
+                                 % c["name"])
+        said = ["%s's sizes, %d tokens of %d, top-%d of %d, %d held of "
+                "width %d, %d of %d rows in a group"
+                % (c["name"], n, d, k, e, held, f, total, a)]
+        args = (rows, wg, wu, sizes)
+        want = jax.jit(as_three)(*args)[-1][:total].astype(jnp.float32)
+        got = jax.jit(as_one)(*args)[-1][:total].astype(jnp.float32)
+        off = float(jnp.abs(got - want).max() / jnp.abs(want).max())
+        if not off <= 2.0 ** -7:    # one rounding of the unit to bfloat16
+            raise AssertionError("the unit as the kernel's epilogue is %.2e "
+                                 "off the pass over the stored arrays" % off)
+        said.append("(1) gate, up and the unit as two kernels and XLA's pass "
+                    "%.3f ms, as one kernel %.3f ms (hidden rows %.1e apart)"
+                    % (timed(jax.jit(as_three), args),
+                       timed(jax.jit(as_one), args), off))
+        if held < e:
+            def gathered(start):
+                return jax.jit(lambda x, token, total: moe._held_rows(
+                    x, token, total, start(token), tile=moe.ROW_TILE))
+            starts = {
+                "zeros": lambda token: jnp.zeros((a, d), jnp.bfloat16),
+                "unwritten": lambda token: expert_gmm.unwritten(
+                    (a, d), jnp.bfloat16, token)}
+            held_rows = {name: timed(gathered(start), (
+                x, token, jnp.asarray(total, jnp.int32)))
+                for name, start in starts.items()}
+            x3 = jnp.asarray(rng.randint(0, 2 ** 31, (n, d // 256, 128)),
+                             jnp.uint32)
+            block = min(1024, a)
+            by_dma = jax.jit(lambda x3, token, total: _rows_by_dma(
+                x3, token, total, block))
+            dma_args = (x3, token, jnp.asarray(total, jnp.int32))
+            if not bool(jnp.array_equal(by_dma(*dma_args)[:total],
+                                        x3[token[:total]])):
+                raise AssertionError("the rows gathered by DMA are not "
+                                     "x[token]")
+            dma = timed(by_dma, dma_args)
+            said.append(
+                "(2) %d rows of %d bytes gathered into [%d, %d]: "
+                "`_held_rows` from zeros %.3f ms, from a buffer nothing "
+                "wrote %.3f ms (%.1f ns a row), a DMA a row %.3f ms (%.1f "
+                "ns a row; the rows as 32-bit words [%d, 128], no relayout)"
+                % (total, 2 * d, a, d, held_rows["zeros"],
+                   held_rows["unwritten"],
+                   1e6 * held_rows["unwritten"] / max(total, 1), dma,
+                   1e6 * dma / max(total, 1), d // 256))
+        args = (x, router, wg, wu, wd)
+        with up_to_pr_64():
+            before = (timed(jax.jit(lambda *a: layer(*a)), args),
+                      timed(jax.jit(lambda *a: trained(*a)),
+                                    args + (g,)))
+        said.append("(3) the layer forward %.3f -> %.3f ms, forward + "
+                    "backward %.3f -> %.3f ms"
+                    % (before[0], timed(jax.jit(layer), args),
+                       before[1], timed(jax.jit(trained),
+                                                args + (g,))))
+    smoke.say("routed_ffn's rows that no group reads at " + "; ".join(said))
+
+
 def _gated_delta_case(smoke, c, tol):
     """ops/gated_delta_kernels.py on this device at the Qwen3-Next cell's
     shapes, bf16 operands as under AMP: the chunked forward and backward on
@@ -1072,7 +1300,8 @@ def _gated_delta_case(smoke, c, tol):
 def _in_flight_ms(run, args, calls=10, rounds=5):
     """Median over `rounds` of the time of `calls` calls in flight, a call,
     in ms: a part of a millisecond or two is not timed through one
-    dispatch."""
+    dispatch. Every call's results are live until the round ends: fewer
+    `calls` where they are large."""
     import jax
     jax.block_until_ready(run(*args))
     times = []
@@ -1136,6 +1365,9 @@ def phase_c(smoke):
     for c in smoke.cfg["kernels"]["moe_layers"]:
         runs.append(("routed_ffn's passes over the held rows, %s" % c["name"],
                      lambda c=c: _held_layer_times(smoke, c)))
+    for c in smoke.cfg["kernels"]["dead_rows"]:
+        runs.append(("routed_ffn's rows that no group reads, %s" % c["name"],
+                     lambda c=c: _dead_rows_times(smoke, c)))
     for c in smoke.cfg["kernels"]["routing_sites"]:
         runs.append(("routed_ffn's scalars by gather and by sort, %s"
                      % c["name"], lambda c=c: _routing_site_times(smoke, c)))

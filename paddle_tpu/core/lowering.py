@@ -709,14 +709,21 @@ def _count_grad_op(path, fwd_type):
 
 def _count_moe_layer(ctx, attrs, ins):
     from ..observability.registry import REGISTRY
-    from ..parallel.moe import matmul_route, numbered_by, rows_moved
+    from ..parallel.moe import (KERNEL_MATMUL, matmul_route, numbered_by,
+                                rows_moved)
     router, w_up = ins["Router"][0], ins["WUp"][0]
     experts, held = router.shape[1], w_up.shape[0]
+    path = matmul_route(
+        w_up.shape[1], w_up.shape[2],
+        jnp.bfloat16 if ctx.amp else ins["X"][0].dtype, ctx.mesh)
     # what the defaults leave as it was counts under the labels it always
     # had: an ungated layer says so, and a router that reads another width
     # than the experts' input says which, and a share narrower than top_k
-    # that its assignments are numbered by held expert
+    # that its assignments are numbered by held expert, and a layer on the
+    # kernels' route that its unit is the gate/up kernel's epilogue
     own = {}
+    if path == KERNEL_MATMUL:
+        own["unit"] = "kernel"
     if not ins.get("WGate"):
         own["gated"] = "false"
     if numbered_by(experts, held, attrs["top_k"]) == "expert":
@@ -733,8 +740,9 @@ def _count_moe_layer(ctx, attrs, ins):
         "(ragged_dot, or expert_gmm: the kernels of ops/expert_gmm.py), the "
         "rows of the sorted buffer that every pass between the router "
         "and the layer's output touches (all, or the tiles of the held "
-        "assignments: the four permutations, the two d rows' sum and the "
-        "gate's transpose), how the router scores "
+        "assignments: the four permutations, the two d rows' sum, the "
+        "unit's transpose, and the unit itself and every buffer's first "
+        "value where `unit` is kernel), how the router scores "
         "(softmax or sigmoid), whether an expert bias enters the choice of "
         "the top_k and the factor that scales the weights; `gated` false "
         "where an expert is two matrices (activation relu2), and under "
@@ -744,14 +752,16 @@ def _count_moe_layer(ctx, attrs, ins):
         "them, a group's rows by token and a token's sum in expert order) "
         "and not by top-k slot (top_k * N, by slot then token, in score "
         "order), as they are wherever the label is absent "
-        "(moe.numbered_by)"
+        "(moe.numbered_by); `unit` kernel where the experts' unit runs as "
+        "the epilogue of the one kernel that multiplies a row tile by the "
+        "gate and up matrices and the buffers of sorted rows start as a "
+        "call's output that nothing filled (path expert_gmm: "
+        "expert_gmm.gmm_unit, moe._sorted_rows_start), absent where it is a "
+        "pass of XLA's over all the buffer's rows (path ragged_dot)"
     ).inc(top_k=str(attrs["top_k"]), experts=str(experts), held=str(held),
           activation=str(attrs.get("activation", "silu")),
           router_input=router_input,
-          path=matmul_route(
-              w_up.shape[1], w_up.shape[2],
-              jnp.bfloat16 if ctx.amp else ins["X"][0].dtype, ctx.mesh),
-          rows=rows_moved(experts, held),
+          path=path, rows=rows_moved(experts, held),
           scoring=str(attrs.get("scoring", "softmax")),
           bias=str(bool(ins.get("ExpertBias"))).lower(),
           scale="%g" % attrs.get("scale", 1.0), **own)

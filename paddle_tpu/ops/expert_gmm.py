@@ -57,11 +57,11 @@ from .pallas_import import kernel_entry, pl, pltpu
 from . import kernel_config
 
 __all__ = ["KERNELS", "GroupPlan", "plan", "gmm", "gmm_drows",
-           "gmm_dweights"]
+           "gmm_dweights", "gmm_unit", "unwritten"]
 
 # as pallas_kernels.KERNEL_NAMES and EXPERT_MATMUL_KERNELS list them
 KERNELS = ("ptpu_expert_gmm_fwd", "ptpu_expert_gmm_drows",
-           "ptpu_expert_gmm_dweights")
+           "ptpu_expert_gmm_dweights", "ptpu_expert_gmm_unit_fwd")
 
 # Rows a trip of a visit's inner loop sends to the MXU: what decides how
 # much of a tile shared by several groups is computed for nothing. Trips of
@@ -291,3 +291,127 @@ def gmm_dweights(lhs, dout, group_plan, dtype=None, interpret=None):
                          dtype=jnp.dtype(lhs.dtype if dtype is None
                                          else dtype),
                          interpret=_interpret(interpret))
+
+
+# --- PR 65: below the three kernels above, so that no line of theirs moves ---
+# (a Mosaic payload carries file and line). Two more calls, which between them
+# leave no pass of a layer outside a tile below the groups' sum:
+#
+#   ptpu_expert_gmm_unit_fwd  the forward walk with the experts' unit as its
+#                             epilogue: a row tile is read ONCE, met by the
+#                             group's `w_gate` and `w_up` blocks (one matrix
+#                             for an expert of two), and `gate`, `up` and
+#                             hidden = unit(gate, up) are written for the
+#                             group's own rows. The float32 accumulators are
+#                             rounded to the output dtype FIRST and the unit
+#                             is computed from the rounded values, as
+#                             moe.py's units compute it from the stored
+#                             arrays: the three outputs are two `gmm` calls'
+#                             and the jax.numpy unit's to the bit (on the
+#                             interpreter; Mosaic's logistic is its own).
+#   ptpu_expert_rows_unwritten  a call that writes nothing: its output is a
+#                             buffer of sorted rows as the allocator left it,
+#                             what the loops over the held tiles start their
+#                             carry from where a fill of zeros was a pass over
+#                             all the rows for the sake of tiles nothing reads
+#                             (moe.py `_sorted_rows_start`).
+
+
+def _unit_kernel(group_of, tile_of, offsets, visits, lhs_ref, *refs, sub,
+                 unit):
+    """One visit of `gmm_unit`: refs are (w_gate, w_up, gate, up, hidden),
+    or (w_up, up, hidden) for experts of two matrices."""
+    gated = len(refs) == 5
+    if gated:
+        gate_w, up_w, gate_ref, up_ref, hidden_ref = refs
+    else:
+        up_w, up_ref, hidden_ref = refs
+    block_m = lhs_ref.shape[0]
+    _, lo, hi = _visit(group_of, tile_of, offsets, block_m)
+    dims = (((1,), (0,)), ((), ()))
+
+    @pl.when((pl.program_id(0) < visits[0]) & (hi > lo))
+    def _():
+        def trip(s, carry):
+            at = pl.multiple_of(s * sub, sub)
+            rows = pl.ds(at, sub)
+            own = _own_rows(at, sub, lo, hi)
+            lhs = lhs_ref[rows, :]
+
+            def product(w_ref, out_ref):
+                out = lax.dot_general(
+                    lhs, w_ref[0], dims, preferred_element_type=jnp.float32
+                ).astype(out_ref.dtype)
+                out_ref[rows, :] = jnp.where(own, out, out_ref[rows, :])
+                return out
+
+            up = product(up_w, up_ref)
+            hidden = unit(product(gate_w, gate_ref), up) if gated \
+                else unit(up)
+            hidden_ref[rows, :] = jnp.where(own, hidden, hidden_ref[rows, :])
+            return carry
+
+        lax.fori_loop(lo // sub, (hi + sub - 1) // sub, trip, 0)
+
+
+@kernel_entry("ptpu_expert_gmm_unit_fwd",
+              static_argnames=("unit", "interpret"))
+def _gmm_unit(lhs, weights, group_plan, *, unit, interpret):
+    m, width = lhs.shape
+    block_m = group_plan.block_m
+    out_width = weights[0].shape[2]
+
+    def tile(w):
+        return pl.BlockSpec((block_m, w), lambda v, g, t, o, n: (t[v], 0))
+
+    return pl.pallas_call(
+        functools.partial(_unit_kernel, sub=_sub_tile(block_m), unit=unit),
+        out_shape=[jax.ShapeDtypeStruct((m, out_width), lhs.dtype)]
+        * (len(weights) + 1),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4, grid=(group_plan.group_of.shape[0],),
+            in_specs=[tile(width)] + [
+                pl.BlockSpec((1,) + w.shape[1:],
+                             lambda v, g, t, o, n: (g[v], 0, 0))
+                for w in weights],
+            out_specs=[tile(out_width)] * (len(weights) + 1)),
+        compiler_params=_PARAMS, interpret=interpret,
+        name="ptpu_expert_gmm_unit_fwd",
+    )(group_plan.group_of, group_plan.tile_of, group_plan.offsets,
+      group_plan.visits, lhs, *weights)
+
+
+def gmm_unit(lhs, w_gate, w_up, group_plan, unit, interpret=None):
+    """(gate, up, hidden) = (lhs x w_gate, lhs x w_up, unit(gate, up)) by
+    `group_plan`, each [M, F] in lhs's dtype and each as `gmm` and `unit`
+    (a function of arrays in that dtype, hashable: it is a static argument)
+    would give it; `w_gate` None: (up, unit(up)). The rows past the groups'
+    sum are not written."""
+    weights = (w_up,) if w_gate is None else (w_gate, w_up)
+    return _gmm_unit(lhs, weights, group_plan, unit=unit,
+                     interpret=_interpret(interpret))
+
+
+def _unwritten_kernel(like_ref, out_ref):
+    del like_ref, out_ref
+
+
+@kernel_entry("ptpu_expert_rows_unwritten",
+              static_argnames=("shape", "dtype", "interpret"))
+def _unwritten(like, *, shape, dtype, interpret):
+    whole = pl.BlockSpec(memory_space=pl.ANY)
+    return pl.pallas_call(
+        _unwritten_kernel, out_shape=jax.ShapeDtypeStruct(shape, dtype),
+        in_specs=[whole], out_specs=whole, interpret=interpret,
+        name="ptpu_expert_rows_unwritten")(like)
+
+
+def unwritten(shape, dtype, like, interpret=None):
+    """An array of `shape` and `dtype` that nothing wrote: on the chip
+    whatever its buffer held (no pass, no bytes), NaN on the interpreter.
+    `like` is any array the caller has at hand, left where it is and not
+    read: an operand makes two such calls of one computation ONE to XLA
+    (it merges no instruction without operands), which is what a grad op's
+    replay of a forward rule needs (moe.routed_ffn's docstring)."""
+    return _unwritten(like, shape=tuple(shape), dtype=jnp.dtype(dtype),
+                      interpret=_interpret(interpret))

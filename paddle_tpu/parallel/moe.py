@@ -22,14 +22,16 @@ exchange. Its row buffer (top_k * N rows) is numbered slot-major, so that a
 token's slots are summed without a relayout; a share narrower than top_k
 numbers its assignments by held expert instead (held * N: `numbered_by`).
 Where a share is held, the
-passes between the router and the layer's output touch the held rows only
-(all but `_gated` forward, one elementwise pass), in loops whose trip count
+passes between the router and the layer's output touch the held rows only,
+in loops whose trip count
 is the held assignments' (`_held_experts`, `_combine`): the sorted rows are
 gathered and the output's gradient weighted a tile of held rows at a time
 (`_held_rows`, `_held_weighted`), a token's held rows are gathered and
 summed by a one-hot matmul a tile of token-major places at a time
 (`_token_sum`), and `_gated`'s transpose runs in the held tiles
-(`_held_gated_transpose`).
+(`_held_gated_transpose`); on the kernels' route `_gated` forward is the
+gate/up kernel's epilogue and the buffers of sorted rows start as a call's
+output that nothing filled (`_sorted_rows_start`).
 """
 import functools
 
@@ -195,13 +197,45 @@ def _kernel_matmul_bwd(res, d_out):
 _kernel_matmul.defvjp(_kernel_matmul_fwd, _kernel_matmul_bwd)
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _kernel_unit(rows, w_gate, w_up, plan, unit):
+    """Every expert held, on the kernels: unit(rows x w_gate, rows x w_up)
+    (unit(rows x w_up) with `w_gate` None) by sorted row, one kernel that
+    reads a row tile once and makes the unit its epilogue
+    (expert_gmm.gmm_unit). The rule keeps the kernel's `gate` and `up` and
+    its backward pass is jax's own of the unit and the matmuls'
+    transposes."""
+    return _kernel_unit_fwd(rows, w_gate, w_up, plan, unit)[0]
+
+
+def _kernel_unit_fwd(rows, w_gate, w_up, plan, unit):
+    from ..ops import expert_gmm
+    *made, hidden = expert_gmm.gmm_unit(rows, w_gate, w_up, plan, unit)
+    return hidden, (rows, w_gate, w_up, plan, made)
+
+
+def _kernel_unit_bwd(unit, res, d_hidden):
+    rows, w_gate, w_up, plan, made = res
+    d_made = jax.vjp(unit, *made)[1](d_hidden)
+    d_rows, d_w_up = _matmul_transposes(rows, w_up, None, plan, d_made[-1])
+    if w_gate is None:
+        return d_rows, None, d_w_up, None
+    d_rows_gate, d_w_gate = _matmul_transposes(rows, w_gate, None, plan,
+                                               d_made[0])
+    return d_rows_gate + d_rows, d_w_gate, d_w_up, None
+
+
+_kernel_unit.defvjp(_kernel_unit_fwd, _kernel_unit_bwd)
+
+
 def rows_moved(experts, held):
     """Which rows of the sorted row buffer `routed_ffn`'s passes touch,
     decided from the shapes: "all" where every expert is held (every row is
     in a group: four gathers of the buffer and the elementwise passes XLA
     makes of the rest), "held" where a share is: the four permutations, the
     sum of the two d rows and `_gated`'s transpose all run in the tiles
-    below the held assignments' count, a run-time value."""
+    below the held assignments' count, a run-time value (on the kernels'
+    route `_gated` forward too, and no buffer is filled past them)."""
     return "all" if held == experts else "held"
 
 
@@ -439,11 +473,26 @@ def _dispatch_bwd(res, g):
 _dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
 
 
+def _sorted_rows_start(shape, dtype, like, plan):
+    """What a loop over the held tiles starts a buffer of sorted rows [A, .]
+    from, which it writes below `total` and nothing reads from there on: on
+    the kernels' route (`plan` given) a Mosaic call's output that nothing
+    wrote (expert_gmm.unwritten; `like` an array at hand that the forward op
+    and a grad op's replay compute alike), so that no pass fills A rows for
+    the sake of the dead ones; zeros on `ragged_dot`'s, as ever."""
+    if plan is None:
+        return jnp.zeros(shape, dtype)
+    from ..ops import expert_gmm
+    return expert_gmm.unwritten(shape, dtype, like)
+
+
 @functools.partial(jax.jit, static_argnames=("tile",))
-def _held_rows(x, order, total, *, tile):
+def _held_rows(x, order, total, rows, *, tile):
     """A share held. x [N, D], order as in `_dispatch`, `total` the sorted
-    rows that are held. -> [A, D], sorted row r is x[order[r] % N] below
-    `total` and zero from there on: only the tiles below `total` are
+    rows that are held, `rows` [A, D] the buffer to write them into
+    (`_sorted_rows_start`). -> [A, D], sorted row r is x[order[r] % N]
+    below `total`, zero in the rest of the last tile that holds one, and
+    from there on what `rows` held: only the tiles below `total` are
     gathered."""
     token = order % x.shape[0]
 
@@ -452,8 +501,7 @@ def _held_rows(x, order, total, *, tile):
         return jax.lax.dynamic_update_slice(
             rows, jnp.where(live, x[t], 0), (start, 0))
 
-    return _held_tiles(order.shape[0], total, one_tile,
-                       jnp.zeros((order.shape[0], x.shape[1]), x.dtype), tile)
+    return _held_tiles(order.shape[0], total, one_tile, rows, tile)
 
 
 @jax.custom_vjp
@@ -572,6 +620,12 @@ def _ungated_unit(activation):
     return _ungated_relu2
 
 
+def _unit(activation, gated):
+    """The experts' unit: gated, of (gate, up); of `up` alone where an
+    expert is two matrices."""
+    return _gated_unit(activation) if gated else _ungated_unit(activation)
+
+
 def _matmul_transposes(lhs, rhs, sizes, plan, d_out):
     """(d lhs, d rhs) of `_grouped_matmul(lhs, rhs, sizes, plan)`."""
     if plan is not None:
@@ -583,10 +637,11 @@ def _matmul_transposes(lhs, rhs, sizes, plan, d_out):
 
 
 @functools.partial(jax.jit, static_argnames=("unit", "tile"))
-def _held_gated_transpose(gate, up, d_hidden, total, *, unit, tile):
+def _held_gated_transpose(gate, up, d_hidden, d_up, total, *, unit, tile):
     """(d gate, d up) of unit(gate, up) in the tiles below `total`: d gate
     written over d hidden, tile by tile (from `total` on it holds what d
-    hidden held), d up zeros there."""
+    hidden held), d up over the buffer `d_up` (`_sorted_rows_start`), which
+    keeps what it held there."""
     def one_tile(start, tile, live, carry):
         d_hidden_then_gate, d_up = carry
 
@@ -602,23 +657,33 @@ def _held_gated_transpose(gate, up, d_hidden, total, *, unit, tile):
                 jax.lax.dynamic_update_slice(
                     d_up, jnp.where(first, d_up_rows, cut(d_up)), (start, 0)))
 
-    return _held_tiles(gate.shape[0], total, one_tile,
-                       (d_hidden, jnp.zeros_like(up)), tile)
+    return _held_tiles(gate.shape[0], total, one_tile, (d_hidden, d_up),
+                       tile)
 
 
 @functools.partial(jax.jit, static_argnames=("unit", "tile"))
-def _held_ungated_transpose(up, d_hidden, total, *, unit, tile):
+def _held_ungated_transpose(up, d_hidden, hidden, total, *, unit, tile):
     """d up of unit(up) in the tiles below `total`, written over d hidden,
-    tile by tile (from `total` on it holds what d hidden held)."""
-    def one_tile(start, tile, live, d_hidden_then_up):
+    tile by tile (from `total` on it holds what d hidden held). `hidden`,
+    where given, is a buffer [A, F] (`_sorted_rows_start`) into which the
+    same trips write unit(up), the hidden rows again: -> (d up, hidden),
+    hidden None where none was given."""
+    def one_tile(start, tile, live, carry):
+        d_hidden_then_up, hidden = carry
         met = _met(d_hidden_then_up, start, tile)
-        d_up_rows, = jax.vjp(unit, jax.lax.dynamic_slice(
-            up, (start, 0), (tile, up.shape[1])))[1](met)
-        return jax.lax.dynamic_update_slice(
+        hidden_rows, transpose = jax.vjp(unit, jax.lax.dynamic_slice(
+            up, (start, 0), (tile, up.shape[1])))
+        d_up_rows, = transpose(met)
+        if hidden is not None:
+            # a last trip moved back writes what an earlier one wrote
+            hidden = jax.lax.dynamic_update_slice(hidden, hidden_rows,
+                                                  (start, 0))
+        return (jax.lax.dynamic_update_slice(
             d_hidden_then_up,
-            jnp.where(_first_time(start, tile), d_up_rows, met), (start, 0))
+            jnp.where(_first_time(start, tile), d_up_rows, met), (start, 0)),
+            hidden)
 
-    return _held_tiles(up.shape[0], total, one_tile, d_hidden, tile)
+    return _held_tiles(up.shape[0], total, one_tile, (d_hidden, hidden), tile)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(10,))
@@ -628,29 +693,37 @@ def _held_experts(x, w_gate, w_up, w_down, order, rank, sizes, plan, total,
     [A, D], through `_held_rows`, the three grouped matmuls and `_gated`; the
     integers as in `_combine`, `plan` as in `_grouped_matmul`. One rule, so
     that its backward pass can run the passes between the matmuls'
-    transposes in the held tiles. `w_gate` None: experts of two matrices,
-    act(rows @ w_up) @ w_down; of the forward pass the rule then keeps the
-    rows and `up` alone, and its backward pass makes the hidden rows again
-    (one pass, where keeping them is a buffer a layer)."""
+    transposes in the held tiles. On the kernels' route (`plan` given) the
+    two matmuls into the unit and the unit are ONE kernel
+    (expert_gmm.gmm_unit), the sorted rows are written into a buffer that
+    nothing filled (`_sorted_rows_start`), and so no pass, forward or
+    backward, touches a tile past `total`. `w_gate` None: experts of two
+    matrices, act(rows @ w_up) @ w_down; of the forward pass the rule then
+    keeps the rows and `up` alone, and its backward pass makes the hidden
+    rows again (where keeping them is a buffer a layer: +0.28 GiB at the
+    Nemotron cell's peak, AOT compile, PR 65): one pass over all the rows
+    on `ragged_dot`, in the trips of the unit's transpose on the kernels
+    (`_held_ungated_transpose`)."""
     return _held_experts_fwd(x, w_gate, w_up, w_down, order, rank, sizes,
                              plan, total, places, activation)[0]
 
 
 def _held_experts_fwd(x, w_gate, w_up, w_down, order, rank, sizes, plan,
                       total, places, activation):
-    rows = _held_rows(x, order, total, tile=ROW_TILE)
-    if w_gate is None:
-        up = _grouped_matmul(rows, w_up, sizes, plan)
-        return _grouped_matmul(_ungated_unit(activation)(up), w_down, sizes,
-                               plan), (
-            rows, None, up, None, None, w_up, w_down, rank, sizes, plan,
-            total, places)
-    gate = _grouped_matmul(rows, w_gate, sizes, plan)
-    up = _grouped_matmul(rows, w_up, sizes, plan)
-    hidden = _gated(gate, up, activation)
+    rows = _held_rows(x, order, total, _sorted_rows_start(
+        (order.shape[0], x.shape[1]), x.dtype, order, plan), tile=ROW_TILE)
+    unit = _unit(activation, w_gate is not None)
+    if plan is not None:
+        from ..ops import expert_gmm
+        *made, hidden = expert_gmm.gmm_unit(rows, w_gate, w_up, plan, unit)
+    else:
+        made = [_grouped_matmul(rows, w, sizes, plan)
+                for w in (w_gate, w_up) if w is not None]
+        hidden = unit(*made)
+    gate, up = made if w_gate is not None else (None, made[0])
     return _grouped_matmul(hidden, w_down, sizes, plan), (
-        rows, gate, up, hidden, w_gate, w_up, w_down, rank, sizes, plan,
-        total, places)
+        rows, gate, up, None if w_gate is None else hidden, w_gate, w_up,
+        w_down, rank, sizes, plan, total, places)
 
 
 def _held_experts_bwd(activation, res, dy):
@@ -661,17 +734,28 @@ def _held_experts_bwd(activation, res, dy):
     rows, gate, up, hidden, w_gate, w_up, w_down, rank, sizes, plan, total, \
         places = res
     if w_gate is None:
-        unit = _ungated_unit(activation)
-        d_hidden, d_down = _matmul_transposes(unit(up), w_down, sizes, plan,
-                                              dy)
-        d_rows, d_w_up = _matmul_transposes(
-            rows, w_up, sizes, plan, _held_ungated_transpose(
-                up, d_hidden, total, unit=unit, tile=ROW_TILE))
+        unit = _unit(activation, False)
+        if plan is None:
+            d_hidden, d_down = _matmul_transposes(unit(up), w_down, sizes,
+                                                  plan, dy)
+            d_up, _ = _held_ungated_transpose(up, d_hidden, None, total,
+                                              unit=unit, tile=ROW_TILE)
+        else:
+            from ..ops import expert_gmm
+            d_hidden = expert_gmm.gmm_drows(dy, w_down, plan)
+            d_up, hidden = _held_ungated_transpose(
+                up, d_hidden, _sorted_rows_start(up.shape, up.dtype, dy,
+                                                 plan),
+                total, unit=unit, tile=ROW_TILE)
+            d_down = expert_gmm.gmm_dweights(hidden, dy, plan, w_down.dtype)
+        d_rows, d_w_up = _matmul_transposes(rows, w_up, sizes, plan, d_up)
         return (_token_sum((d_rows,), rank, places, tile=SUM_TILE), None,
                 d_w_up, d_down) + (None,) * 6
     d_hidden, d_down = _matmul_transposes(hidden, w_down, sizes, plan, dy)
     d_gate, d_up = _held_gated_transpose(
-        gate, up, d_hidden, total, unit=_gated_unit(activation), tile=ROW_TILE)
+        gate, up, d_hidden, _sorted_rows_start(up.shape, up.dtype, d_hidden,
+                                               plan),
+        total, unit=_unit(activation, True), tile=ROW_TILE)
     d_rows_gate, d_w_gate = _matmul_transposes(rows, w_gate, sizes, plan,
                                                d_gate)
     d_rows_up, d_w_up = _matmul_transposes(rows, w_up, sizes, plan, d_up)
@@ -870,8 +954,10 @@ def routed_ffn(x, router, w_gate, w_up, w_down, top_k, norm_topk_prob=False,
     value every caller can fetch. The two that produce sorted rows
     (`_held_rows` forward, `_held_weighted` in `_combine`'s backward) gather
     the tiles of `ROW_TILE` rows below total = sizes.sum(); `_held_rows`
-    leaves zeros from `total` on, `_held_weighted` writes over y and leaves
-    what y held. The two that produce a token's rows (`_combine` forward,
+    leaves what its buffer held from the last such tile on (zeros on
+    `ragged_dot`'s route, nothing at all on the kernels':
+    `_sorted_rows_start`), `_held_weighted` writes over y and leaves what
+    y held. The two that produce a token's rows (`_combine` forward,
     `_held_experts` backward) number the held assignments token-major on
     [N] integers (`_token_places`) and, `SUM_TILE` places a trip, gather
     their rows and sum them by token with a one-hot matmul (`_token_sum`):
@@ -879,8 +965,17 @@ def routed_ffn(x, router, w_gate, w_up, w_down, top_k, norm_topk_prob=False,
     rows' gather is what is left of them. The two d rows that `w_gate`'s and
     `w_up`'s transposes return are added where that sum reads them, and
     `_gated`'s transpose runs in the held tiles, d gate written over d
-    hidden; `_gated` forward is a pass over all A rows still (a new carry's
-    zero fill costs what the tiles save). Where every expert is held the
+    hidden. `_gated` forward is a pass over all A rows on `ragged_dot`'s
+    route alone: on the kernels' it is the epilogue of the ONE kernel that
+    multiplies a row tile by `w_gate` and `w_up` (expert_gmm.gmm_unit: the
+    accumulators rounded to the experts' dtype first and the unit taken of
+    the rounded values, so `gate`, `up` and the hidden rows are the
+    separate passes' to the bit), and the two buffers a loop over the held
+    tiles used to fill with zeros first (`_held_rows`' rows, d up) start as
+    a call's output that nothing wrote, so on that route nothing outside a
+    tile below `total` is written or read, forward or backward. What such
+    a start reads is the integers' alone, as `order` is: the replay's and
+    the forward op's are one to XLA. Where every expert is held the
     four are gathers of the whole buffer: the two token-side ones sum over
     the leading axis of [top_k, N, D], which is the buffer as it lies
     (numbered token-major, top_k = 6 made each of them a relayout of the
@@ -969,7 +1064,12 @@ def routed_ffn(x, router, w_gate, w_up, w_down, top_k, norm_topk_prob=False,
         plan = expert_gmm.plan(sizes, order.shape[0])
     if total is None:
         rows, places = _dispatch(x.astype(dtype), order, rank), None
-        if w_gate is None:
+        if plan is not None:
+            hidden = _kernel_unit(
+                rows, None if w_gate is None else w_gate.astype(dtype),
+                w_up.astype(dtype), plan, _unit(activation,
+                                                w_gate is not None))
+        elif w_gate is None:
             hidden = _ungated_unit(activation)(
                 _grouped_matmul(rows, w_up.astype(dtype), sizes, plan))
         else:

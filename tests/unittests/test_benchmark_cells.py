@@ -69,6 +69,79 @@ def test_cell_builds_its_training_program_at_published_widths(name):
     assert cell.config_module.samples_per_step(cell.config, cell.traffic) > 0
 
 
+@functools.lru_cache(maxsize=None)
+def _expert_layers(name):
+    """(attrs, {slot: [the input's shape and dtype]}) of every forward
+    `moe_ffn` op of the cell's Program, and whether it computes under AMP:
+    what `lowering._count_moe_layer` reads of a layer, with no array."""
+    import types
+
+    import paddle_tpu as fluid
+    from benchmark import manifest
+
+    cell = manifest.load_cell(MANIFEST, name)
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.unique_name.guard(), fluid.program_guard(main, startup):
+        cell.config_module.build(fluid, cell.config, cell.traffic)
+    block = main.global_block()
+    layers = []
+    for op in block.ops:
+        if op.type == "moe_ffn":
+            layers.append((op.attrs, {
+                slot: [types.SimpleNamespace(
+                    shape=tuple(block.var(n).shape), dtype=block.var(n).dtype)
+                    for n in names] for slot, names in op.inputs.items()}))
+    return layers, bool(main._amp)
+
+
+EXPERT_CELLS = [
+    "olmoe_1b_7b_train_t4096", "smallthinker_21b_a3b_train_t8192",
+    "qwen3_next_80b_a3b_train_t4096", "lfm2_8b_a1b_train_t8192",
+    "xing4_0_29b_a4b_train_1seq", "glm_4_7_flash_train_t4096",
+    "nemotron_3_super_120b_a12b_train_t4096", "laguna_s_2_1_train_t4096"]
+
+
+@pytest.mark.parametrize("route", ["expert_gmm", "ragged_dot"])
+@pytest.mark.parametrize("name", EXPERT_CELLS)
+def test_an_expert_layer_says_where_its_unit_runs(name, route, monkeypatch):
+    """ptpu_moe_layers_total counts every `moe_ffn` layer of an expert
+    cell's Program once under `unit="kernel"` where the step is one TPU's
+    (the kernels' route at the cell's published widths: the unit is the
+    gate/up kernel's epilogue, PR 65), and under no `unit` at all on
+    `ragged_dot`'s (here, with no TPU)."""
+    import types
+
+    from paddle_tpu.core import lowering
+    from paddle_tpu.observability.registry import REGISTRY
+    from paddle_tpu.ops import kernel_config
+
+    assert set(EXPERT_CELLS) <= set(CELLS)
+    monkeypatch.delenv("PADDLE_TPU_PALLAS", raising=False)
+    if route == "expert_gmm":
+        monkeypatch.setattr(kernel_config, "dispatch_platform",
+                            lambda: "tpu")
+    layers, amp = _expert_layers(name)
+    assert layers and amp
+
+    def samples():
+        return {tuple(sorted(labels.items())): value for labels, value
+                in REGISTRY.snapshot().get(
+                    "ptpu_moe_layers_total", {"samples": []})["samples"]}
+
+    before = samples()
+    ctx = types.SimpleNamespace(amp=amp, mesh=None)
+    for attrs, ins in layers:
+        lowering._count_moe_layer(ctx, attrs, ins)
+    moved = {labels: value - before.get(labels, 0)
+             for labels, value in samples().items()
+             if value != before.get(labels, 0)}
+    assert sum(moved.values()) == len(layers)
+    for labels in map(dict, moved):
+        assert labels["path"] == route
+        assert labels.get("unit") == ("kernel" if route == "expert_gmm"
+                                      else None)
+
+
 # ------------------------------------------ (b) the names the readers spell --
 READERS = sorted(
     glob.glob(os.path.join(REPO, "benchmark", "layer_metrics", "*.py"))

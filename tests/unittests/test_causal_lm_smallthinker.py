@@ -474,7 +474,8 @@ def test_the_last_tiles_tail_is_zero_in_the_rows_and_in_their_gradient(
         monkeypatch, tile):
     """sizes.sum() is no multiple of the tile (nor the buffer, at 100, where
     the last trip meets rows a second time): `_held_rows` gives x[token]
-    below the sum and zeros from there on; `_combine`'s backward the
+    below the sum and zeros from there on (in a buffer of zeros; in the
+    rest of the last tile it met, from any other); `_combine`'s backward the
     weighted g[token] below it and, since PR 40 writes dy over y, what y
     held from there on, which no group reads; and no NaN past the sum
     reaches a weight's or a token's gradient."""
@@ -488,9 +489,20 @@ def test_the_last_tiles_tail_is_zero_in_the_rows_and_in_their_gradient(
     live = (np.arange(k * n) < int(total))[:, None]
     places = moe._token_places(rank, total, k)
 
-    rows = moe._held_rows(x, order, total, tile=tile)
+    rows = moe._held_rows(x, order, total, jnp.zeros((k * n, d), x.dtype),
+                          tile=tile)
     np.testing.assert_array_equal(
         rows, np.where(live, np.asarray(x)[np.asarray(order) % n], 0))
+    # from a buffer nothing wrote (PR 65: the kernels' route; NaN on the
+    # interpreter) the tiles past the last one met keep what they held
+    unfilled = moe._held_rows(x, order, total,
+                              jnp.full((k * n, d), jnp.nan, x.dtype),
+                              tile=tile)
+    t = min(tile, k * n)
+    # the last trip starts no later than where it ends with the buffer
+    met = min((-(-int(total) // t) - 1) * t, k * n - t) + t
+    np.testing.assert_array_equal(unfilled[:met], rows[:met])
+    assert bool(jnp.isnan(unfilled[met:]).all())
 
     y = jnp.where(live, jnp.asarray(rng.randn(k * n, d), jnp.float32),
                   jnp.nan)

@@ -217,9 +217,13 @@ def test_every_pallas_call_takes_its_name_from_kernel_names():
     assert sorted(names) == sorted(pallas_kernels.KERNEL_NAMES)
     assert pallas_kernels.EXPERT_MATMUL_KERNELS == expert_gmm.KERNELS
     assert pallas_kernels.SELECTIVE_SCAN_KERNELS \
-        == pallas_kernels.KERNEL_NAMES[-4:-2]
-    assert pallas_kernels.SSD_KERNELS == pallas_kernels.KERNEL_NAMES[-2:]
-    assert len(set(names)) == len(names) == 29
+        == pallas_kernels.KERNEL_NAMES[-6:-4]
+    assert pallas_kernels.SSD_KERNELS == pallas_kernels.KERNEL_NAMES[-4:-2]
+    # PR 65's two, named at the module's end: the forward walk with the
+    # unit in it is an expert matmul, the buffer nothing wrote is not
+    assert pallas_kernels.KERNEL_NAMES[-2:] == (
+        "ptpu_expert_gmm_unit_fwd", "ptpu_expert_rows_unwritten")
+    assert len(set(names)) == len(names) == 31
     for a in names:         # a reader matching `<name>` or `<name>.<n>`
         for b in names:     # never counts one kernel under another
             assert a == b or not (b + ".").startswith(a + ".")
@@ -1157,5 +1161,84 @@ def test_expert_gmm_kernels_on_a_described_v5e(one_chip, monkeypatch, cell):
         else name for name in re.findall(
             r'%?([\w.\-]+) = [^\n]*custom_call_target="tpu_custom_call"',
             text))
-    assert calls == {name: 2 for name in pallas_kernels.EXPERT_MATMUL_KERNELS}
+    assert calls == {name: 2
+                     for name in pallas_kernels.EXPERT_MATMUL_KERNELS[:3]}
     assert "ragged-dot" not in text
+
+
+# experts, held, top_k: a share numbered by slot (LFM2's), one numbered by
+# held expert and ungated (Nemotron-3-Super's), every expert held (OLMoE's)
+_ONE_LAYER = {"by_slot": (32, 8, 4, True), "by_expert": (512, 8, 22, False),
+              "all_held": (64, 64, 8, True)}
+
+
+@pytest.mark.parametrize("case", sorted(_ONE_LAYER))
+def test_a_layer_of_experts_on_a_described_v5e(one_chip, monkeypatch, case):
+    """One training step of a `moe_ffn` layer at 512 tokens of width 256,
+    compiled for a TPU: the forward kernels (the down matmul's, the one
+    with the unit in it, and where a share is held the call whose output
+    the rows' loop starts from) are Mosaic calls under the forward op and
+    NONE under the grad op, which replays the rule and counts on XLA to
+    merge the replay (a Mosaic call is whole to XLA; the interpreter's loop
+    is cut to the outputs a consumer reads, so only here can the kernel
+    with three outputs be seen merged); the transposes are under the grad
+    op; and outside the Mosaic calls nothing under either scope has all
+    the buffer's rows in its result but a loop's carry: no fill, no pass
+    of the unit (PR 65)."""
+    from paddle_tpu.ops import kernel_config
+    experts, held, top_k, gated = _ONE_LAYER[case]
+    tokens, width = 512, 256
+    monkeypatch.delenv("PADDLE_TPU_PALLAS", raising=False)
+    monkeypatch.setattr(kernel_config, "dispatch_platform", lambda: "tpu")
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.unique_name.guard(), fluid.program_guard(main, startup):
+        x = fluid.layers.data(name="x", shape=[width], dtype="float32")
+        hidden = fluid.layers.fc(input=x, size=width, bias_attr=False)
+        out, _, _, _ = fluid.layers.moe_ffn(
+            hidden, experts, width, top_k, norm_topk_prob=True,
+            experts_held=held, scoring="sigmoid", expert_bias_attr=True,
+            gated=gated, activation="silu" if gated else "relu2")
+        loss = fluid.layers.mean(out)
+        fluid.optimizer.SGD(learning_rate=0.1).minimize(loss)
+    rw, ro, outs = lowering.analyze_state(main, ["x"], [loss.name])
+    fn = lowering.build_program_fn(main, ["x"], [loss.name], rw, ro, outs)
+    block = main.global_block()
+
+    def sds(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=one_chip)
+
+    def state(names):
+        return [sds(block.var(name).shape) for name in names]
+    text = _compile_uncached(
+        lambda feed, rw, ro: fn(feed, rw, ro, 0), [sds((tokens, width))],
+        state(rw), state(ro)).as_text()
+    calls = collections.Counter(
+        (re.sub(r"\.\d+$", "", name), lowering.parse_op_scope(op_name)[0])
+        for name, op_name in re.findall(
+            r'%?([\w.\-]+) = [^\n]*custom_call_target="tpu_custom_call"'
+            r'[^\n]*op_name="([^"]*)"', text))
+    fwd, drows, dweights, unit_fwd = pallas_kernels.EXPERT_MATMUL_KERNELS
+    want = {(fwd, "moe_ffn"): 1, (unit_fwd, "moe_ffn"): 1,
+            (drows, "moe_ffn_grad"): 3 if gated else 2,
+            (dweights, "moe_ffn_grad"): 3 if gated else 2}
+    if held < experts:
+        # the rows' start under the forward op; under the grad op d up's,
+        # or the hidden rows' that an ungated layer makes again
+        want["ptpu_expert_rows_unwritten", "moe_ffn"] = 1
+        want["ptpu_expert_rows_unwritten", "moe_ffn_grad"] = 1
+    assert calls == want
+    if held == experts:
+        return                  # every row is in a group: the passes stay
+    rows = tokens * min(held, top_k)
+    passes = []
+    for line in text.splitlines():
+        met = re.match(r"\s*(?:ROOT )?%?[\w.\-]+ = (\(?[^=]*?\)?) "
+                       r"(\w[\w\-]*)\(", line)
+        name = re.search(r'op_name="([^"]*)"', line)
+        if met and name and "op:moe_ffn" in name.group(1) \
+                and "[%d,%d]" % (rows, width) in met.group(1) \
+                and met.group(2) not in (
+                    "parameter", "get-tuple-element", "tuple", "bitcast",
+                    "while", "dynamic-update-slice", "custom-call"):
+            passes.append((met.group(2), name.group(1)[-60:]))
+    assert passes == []

@@ -94,6 +94,76 @@ def test_plan_visits_every_tile_of_every_group_once():
     assert np.asarray(plan.offsets).tolist() == [0, 10, 10, 11, 34, 41]
 
 
+# --- the unit as the gate/up kernel's epilogue (PR 65) ----------------------
+
+# rows, K, F, the groups' sizes, the row tile
+UNIT_CASES = {
+    # group 3 ends in tile 2, which group 4 shares; one tile past the sum
+    "a_tile_shared_by_two_groups": (64, 32, 48, (10, 0, 1, 23, 7), 16),
+    "an_empty_group_between_two": (48, 32, 32, (16, 0, 9), 16),
+    # two whole tiles and part of a third belong to no group
+    "rows_past_the_groups_sum": (96, 32, 128, (20, 7, 11), 16),
+    "a_tile_above_the_rows": (40, 128, 128, (3, 5, 0, 13), 256),
+}
+
+
+@pytest.mark.parametrize("dtype", sorted(DTYPES))
+@pytest.mark.parametrize("activation", ["silu", "relu", "relu2"])
+@pytest.mark.parametrize("case", sorted(UNIT_CASES))
+def test_the_unit_in_the_kernel_is_two_matmuls_and_the_unit_to_the_bit(
+        case, activation, dtype):
+    """`gmm_unit`'s gate, up and hidden rows against two `gmm` calls and the
+    jax.numpy unit of moe.py on the stored arrays, on the groups' rows to
+    the bit (the accumulators are rounded before the unit, as the stored
+    arrays are); a tile wholly past the groups' sum is not written: it
+    holds the interpreter's value of a buffer nothing wrote, NaN, the
+    sentinel every output starts from; and no NaN in a row past the sum
+    (which may hold anything) reaches a row below it."""
+    from paddle_tpu.ops import expert_gmm
+    m, k, f, sizes, block_m = UNIT_CASES[case]
+    kind, _ = DTYPES[dtype]
+    gated = activation != "relu2"
+    unit = moe._gated_unit(activation) if gated \
+        else moe._ungated_unit(activation)
+    rng = np.random.RandomState(len(case))
+    total = sum(sizes)
+    lhs = jnp.asarray(rng.randn(m, k), kind).at[total:].set(np.nan)
+    w_gate, w_up = (jnp.asarray(rng.randn(len(sizes), k, f) * k ** -0.5, kind)
+                    for _ in range(2))
+    plan = expert_gmm.plan(jnp.asarray(sizes, jnp.int32), m, block_m)
+    want = [expert_gmm.gmm(lhs, w, plan, interpret=True)
+            for w in ((w_gate, w_up) if gated else (w_up,))]
+    want.append(unit(*want))
+    got = expert_gmm.gmm_unit(lhs, w_gate if gated else None, w_up, plan,
+                              unit, interpret=True)
+    assert len(got) == len(want) == (3 if gated else 2)
+    written = min(-(-total // block_m) * block_m, m)
+    for name, a, b in zip(("gate", "up", "hidden")[-len(got):], got, want):
+        assert a.dtype == kind and a.shape == (m, f), name
+        a, b = (np.asarray(v, np.float32) for v in (a, b))
+        assert np.isfinite(a[:total]).all() and np.abs(a[:total]).max() > 0
+        np.testing.assert_array_equal(a[:total], b[:total], err_msg=name)
+        assert np.isnan(a[written:]).all(), name + ": a tile past the sum"
+    if case == "rows_past_the_groups_sum":
+        assert m - written >= 2 * block_m
+
+
+def test_a_buffer_nothing_wrote_has_an_operand_and_no_value():
+    """`unwritten` is a call with one operand (XLA merges no instruction
+    without operands, and a grad op's replay has to be merged) that reads
+    nothing and writes nothing: the interpreter's NaN everywhere."""
+    from paddle_tpu.ops import expert_gmm
+    like = jnp.arange(7, dtype=jnp.int32)
+    for dtype in (jnp.float32, jnp.bfloat16):
+        got = expert_gmm.unwritten((24, 128), dtype, like, interpret=True)
+        assert got.shape == (24, 128) and got.dtype == dtype
+        assert np.isnan(np.asarray(got, np.float32)).all()
+    jaxpr = jax.make_jaxpr(lambda like: expert_gmm.unwritten(
+        (24, 128), jnp.float32, like, interpret=True))(like)
+    call, = (eqn for eqn in jaxpr.jaxpr.eqns)
+    assert [v.aval.shape for v in call.invars] == [(7,)]
+
+
 # --- routed_ffn through the kernels ----------------------------------------
 
 N, D, E, F, TOP_K = 48, 128, 8, 128, 3
@@ -199,8 +269,10 @@ def _step(cfg):
 
 def test_a_kernel_is_traced_once_a_shape_not_once_a_layer(monkeypatch):
     """Four expert layers, nine grouped matmuls each: the step is traced
-    with six kernel instances (gate and up alike, down, for each pass), the
-    counter names the kernels' route, and the step's loss and gradients are
+    with six matmul kernel instances (gate and up as ONE forward kernel
+    with the unit in it, down; two shapes of each transpose) and the two
+    shapes of the buffer nothing wrote, the counter names the kernels'
+    route and the unit in the kernel, and the step's loss and gradients are
     the `ragged_dot` route's."""
     from paddle_tpu.ops import expert_gmm
     monkeypatch.delenv("PADDLE_TPU_PALLAS", raising=False)
@@ -218,11 +290,17 @@ def test_a_kernel_is_traced_once_a_shape_not_once_a_layer(monkeypatch):
     labels = dict(top_k="3", experts="16", held="8", activation="relu",
                   router_input="pre_attention", rows="held",
                   scoring="softmax", bias="false", scale="1")
-    before = {path: counter.value(path=path, **labels)
-              for path in ("expert_gmm", "ragged_dot")}
+    routes = {"expert_gmm": dict(path="expert_gmm", unit="kernel"),
+              "ragged_dot": dict(path="ragged_dot")}
+    before = {path: counter.value(**labels, **own)
+              for path, own in routes.items()}
     got = _step(CFG)
-    assert {path: counter.value(path=path, **labels) - before[path]
-            for path in before} == {"expert_gmm": 4, "ragged_dot": 0}
-    assert sorted(traced) == sorted(2 * expert_gmm.KERNELS)
+    assert {path: counter.value(**labels, **own) - before[path]
+            for path, own in routes.items()} \
+        == {"expert_gmm": 4, "ragged_dot": 0}
+    fwd, drows, dweights, unit_fwd = expert_gmm.KERNELS
+    assert sorted(traced) == sorted(
+        [fwd, unit_fwd] + 2 * [drows, dweights]
+        + 2 * ["ptpu_expert_rows_unwritten"])
     for a, b in zip(got, want):
         assert np.abs(a - b).max() <= 2e-5 * max(np.abs(b).max(), 1e-3)

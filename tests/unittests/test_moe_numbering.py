@@ -14,10 +14,19 @@ rows' passes (PR 63): the chosen scores are read by a compare (`_chosen`),
 `rank` and the weights' two permutations by sorts (`_sorted_by`), under every
 numbering. The forms they replaced (`take_along_axis`, `.at[order].set`,
 `[order]`, `[rank]`) are kept here as plain jax.numpy oracles and `_route`
-and the layer are held to them to the bit at the seven cells' shapes; the
+and the layer are held to them to the bit at the cells' shapes; the
 jaxpr of a layer forward and backward holds no gather or scatter of one
 scalar an assignment; and the compiled step of one layer has no forward pass
-under its grad op's scope."""
+under its grad op's scope.
+
+And the rows of the sorted buffer that belong to no group (PR 65): on the
+kernels' route the experts' unit is the gate/up kernel's epilogue and the
+buffers of sorted rows start as a call's output that nothing filled. The
+layer is held to the forms they replaced (two forward kernels and the
+jax.numpy unit over the stored arrays; a fill of zeros), stood in their
+place, to the bit at the eight cells' shapes, and the compiled step of one
+layer has no instruction with all the buffer's rows under the op's scopes
+outside the kernels."""
 import itertools
 import re
 
@@ -39,7 +48,9 @@ TOLERANCE = 2e-5
 CELLS = {"olmoe": (64, 64, 8), "smallthinker": (64, 16, 6),
          "lfm2": (32, 8, 4), "qwen3_next": (512, 32, 10),
          "xing4_0": (64, 8, 4), "glm_4_7_flash": (64, 8, 4),
-         "nemotron_3_super": (512, 8, 22)}
+         "nemotron_3_super": (512, 8, 22), "laguna_s_2_1": (256, 8, 10)}
+# the cells whose share is narrower than top_k
+NARROW = ("laguna_s_2_1", "nemotron_3_super")
 
 
 @pytest.mark.parametrize("experts,held,top_k,want", [
@@ -54,8 +65,7 @@ def test_the_rule_reads_the_shapes_alone(experts, held, top_k, want):
 def test_the_cells_keep_their_numbering(cell):
     experts, held, top_k = CELLS[cell]
     assert moe.numbered_by(experts, held, top_k) == (
-        "expert" if cell == "nemotron_3_super" else
-        None if cell == "olmoe" else "slot")
+        "expert" if cell in NARROW else None if cell == "olmoe" else "slot")
 
 
 # --- against plain jax over every expert ------------------------------------
@@ -329,7 +339,8 @@ def test_the_counter_names_the_numbering_of_a_narrow_share_alone(cell):
              if value != before.get(labels, 0)}
     assert list(moved.values()) == [1]          # the forward op, once
     labels, = (dict(k) for k in moved)
-    if cell == "nemotron_3_super":
+    assert "unit" not in labels                 # `ragged_dot`'s route
+    if cell in NARROW:
         assert labels["numbered"] == "expert" and labels["rows"] == "held"
     else:
         assert "numbered" not in labels
@@ -339,14 +350,15 @@ def test_the_counter_names_the_numbering_of_a_narrow_share_alone(cell):
 # --- no scalar moves by gather or scatter (PR 63) ---------------------------
 
 # scoring, expert bias, norm_topk_prob, scale, norm_eps, activation of the
-# seven cells' routers (benchmark/configs/*.json)
+# eight cells' routers (benchmark/configs/*.json)
 ROUTERS = {"olmoe": ("softmax", False, False, 1.0, None, "silu"),
            "smallthinker": ("softmax", False, True, 1.0, None, "relu"),
            "qwen3_next": ("softmax", False, True, 1.0, None, "silu"),
            "lfm2": ("sigmoid", True, True, 1.0, None, "silu"),
            "xing4_0": ("sigmoid", True, True, 2.0, 1e-20, "silu"),
            "glm_4_7_flash": ("sigmoid", True, True, 1.8, 1e-20, "silu"),
-           "nemotron_3_super": ("sigmoid", True, True, 5.0, 1e-20, "relu2")}
+           "nemotron_3_super": ("sigmoid", True, True, 5.0, 1e-20, "relu2"),
+           "laguna_s_2_1": ("softmax", False, True, 2.5, None, "silu")}
 
 
 def _route_of_pr_62(logits, top_k, norm_topk_prob, scoring, expert_bias,
@@ -567,7 +579,7 @@ def test_no_scalar_moves_by_gather_or_scatter(cell, route, monkeypatch):
 
 # --- the grad op replays no forward pass ("Left by PR 62" (b)) --------------
 
-def _one_layer_step(experts, held, top_k, width=128):
+def _one_layer_step(experts, held, top_k, width=128, gated=True):
     """The compiled text (this backend's) of one training step of a layer:
     a projection, `moe_ffn` under a sigmoid router with a bias, SGD."""
     from paddle_tpu.core import lowering
@@ -577,7 +589,8 @@ def _one_layer_step(experts, held, top_k, width=128):
         hidden = fluid.layers.fc(input=x, size=width, bias_attr=False)
         out, _, _, _ = fluid.layers.moe_ffn(
             hidden, experts, width, top_k, norm_topk_prob=True,
-            experts_held=held, scoring="sigmoid", expert_bias_attr=True)
+            experts_held=held, scoring="sigmoid", expert_bias_attr=True,
+            gated=gated, activation="silu" if gated else "relu2")
         loss = fluid.layers.mean(out)
         fluid.optimizer.SGD(learning_rate=0.1).minimize(loss)
     rw, ro, outs = lowering.analyze_state(main, ["x"], [loss.name])
@@ -599,22 +612,30 @@ def _under(text, scope, *marks):
             and all(mark in name for mark in marks)]
 
 
-def _forward_passes(text, scope, route):
-    """The rows' forward passes under `scope`: `_held_rows`' loop and the
-    forward grouped matmul (the kernel by its name; `ragged_dot`, which this
-    backend lowers to a dot, by a dot that is no transpose's and no
-    `_token_sum`'s, the router's own among them)."""
-    held_rows = _under(text, scope, "jit(_held_rows)/while")
+def _forward_passes(text, scope, route, held_share=True):
+    """The rows' forward passes under `scope`: where a share is held
+    `_held_rows`' loop, and the forward grouped matmuls (the kernels by
+    their names: the down matmul's;
+    `ragged_dot`, which this backend lowers to a dot, by a dot that is no
+    transpose's and no `_token_sum`'s, the router's own among them)."""
+    found = [_under(text, scope, "jit(_held_rows)/while")] if held_share \
+        else []
     if route == moe.KERNEL_MATMUL:
-        return held_rows, _under(text, scope, "ptpu_expert_gmm_fwd")
-    return held_rows, [name for name in _under(text, scope, "dot_general")
-                       if "transpose(" not in name and "_token_sum" not in name]
+        # the kernel with the unit in it is counted on a described v5e
+        # (test_device_names.py: a Mosaic call is whole to XLA): of the
+        # interpreter's loop XLA keeps the outputs a consumer reads, the
+        # hidden rows alone in the forward op and all three in the replay,
+        # and merges no two loops that differ
+        return found + [_under(text, scope, "ptpu_expert_gmm_fwd")]
+    return found + [[name for name in _under(text, scope, "dot_general")
+                     if "transpose(" not in name
+                     and "_token_sum" not in name]]
 
 
-@pytest.mark.parametrize("cell,route", [
-    ("lfm2", moe.GROUPED_MATMUL), ("lfm2", moe.KERNEL_MATMUL),
-    ("nemotron_3_super", moe.GROUPED_MATMUL),
-    ("nemotron_3_super", moe.KERNEL_MATMUL)])
+# one cell a numbering: by slot, by held expert, and every expert held
+@pytest.mark.parametrize("cell,route", itertools.product(
+    ("lfm2", "nemotron_3_super", "olmoe"),
+    (moe.GROUPED_MATMUL, moe.KERNEL_MATMUL)))
 def test_the_grad_op_replays_no_forward_pass(cell, route, monkeypatch):
     """`moe_ffn`'s grad op replays the forward rule and counts on XLA to
     merge the replay with the forward op's operations. Whatever the rows'
@@ -626,12 +647,16 @@ def test_the_grad_op_replays_no_forward_pass(cell, route, monkeypatch):
     differently (a stand-in for an operation XLA does not merge) has them
     under both, so the count sees."""
     _take(route, monkeypatch)
+    held_share = moe.numbered_by(*CELLS[cell]) is not None
     text = _one_layer_step(*CELLS[cell])
-    for found in _forward_passes(text, "moe_ffn", route):
+    for found in _forward_passes(text, "moe_ffn", route, held_share):
         assert found
-    for found in _forward_passes(text, "moe_ffn_grad", route):
+    for found in _forward_passes(text, "moe_ffn_grad", route, held_share):
         assert found == []
-    assert _under(text, "moe_ffn_grad", "jit(_held_weighted)/while")
+    if held_share:
+        assert _under(text, "moe_ffn_grad", "jit(_held_weighted)/while")
+    else:
+        assert _under(text, "moe_ffn_grad", "transpose(")
 
     calls = itertools.count(1)
     route_of_the_tree = moe._route
@@ -642,5 +667,117 @@ def test_the_grad_op_replays_no_forward_pass(cell, route, monkeypatch):
 
     monkeypatch.setattr(moe, "_route", never_the_same_twice)
     text = _one_layer_step(*CELLS[cell])
-    for found in _forward_passes(text, "moe_ffn_grad", route):
+    for found in _forward_passes(text, "moe_ffn_grad", route, held_share):
         assert found
+
+
+# --- the rows that belong to no group (PR 65) -------------------------------
+
+def _apart(rows, w_gate, w_up, plan, unit):
+    """`expert_gmm.gmm_unit` as the layer made it up to PR 64: a forward
+    kernel a matrix and the unit over the stored arrays, all their rows."""
+    from paddle_tpu.ops import expert_gmm
+    made = [expert_gmm.gmm(rows, w, plan) for w in (w_gate, w_up)
+            if w is not None]
+    return made + [unit(*made)]
+
+
+def _the_forms_up_to_pr_64(monkeypatch):
+    from paddle_tpu.ops import expert_gmm
+    monkeypatch.setattr(expert_gmm, "gmm_unit", _apart)
+    monkeypatch.setattr(expert_gmm, "unwritten",
+                        lambda shape, dtype, like: jnp.zeros(shape, dtype))
+
+
+@pytest.mark.parametrize("dtype", [None, "bfloat16"],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("cell", sorted(CELLS))
+def test_the_kernels_layer_is_the_parents_to_the_bit(cell, dtype,
+                                                     monkeypatch):
+    """The output, `ExpertLoad` and every input's gradient through the
+    kernels' interpreter, with the unit in the gate/up kernel and the
+    buffers of sorted rows started from a call's output that nothing wrote
+    (NaN, on the interpreter: none may reach a result), against the same
+    layer with two forward kernels, the unit as a pass over the stored
+    arrays and a fill of zeros, at the eight cells' (top_k, E, H,
+    activation), with float32 and with bfloat16 experts. The tiles are
+    cut so that the loops make several trips and leave whole tiles
+    unmet."""
+    monkeypatch.setenv("PADDLE_TPU_PALLAS", "gmm")
+    monkeypatch.setattr(moe, "ROW_TILE", 32)
+    monkeypatch.setattr(moe, "SUM_TILE", 32)
+    w, run, g = _cell_layer(cell, dtype and jnp.dtype(dtype))
+    assert moe.matmul_route(128, 128, jnp.dtype(dtype or "float32")) \
+        == moe.KERNEL_MATMUL
+    got = _value_and_grads(run, w, g)
+    with monkeypatch.context() as parent:
+        _the_forms_up_to_pr_64(parent)
+        want = _value_and_grads(run, w, g)
+    assert int(got[1].sum()) == CELLS[cell][2] * w["x"].shape[0]
+    assert np.abs(np.asarray(got[0], np.float32)).max() > 0
+    np.testing.assert_array_equal(np.asarray(got[0], np.float32),
+                                  np.asarray(want[0], np.float32))
+    np.testing.assert_array_equal(np.asarray(got[1]), np.asarray(want[1]))
+    assert sorted(got[2]) == sorted(w)
+    for name in w:
+        assert np.abs(np.asarray(got[2][name])).max() > 0, name
+        np.testing.assert_array_equal(np.asarray(got[2][name]),
+                                      np.asarray(want[2][name]), err_msg=name)
+
+
+# what may have all the buffer's rows in its result and be no pass over
+# them: a loop's carry and the tile written into it, in place
+_NO_PASS = ("parameter", "get-tuple-element", "tuple", "bitcast", "while",
+            "dynamic-update-slice", "copy")
+
+
+def _whole_buffer_passes(text, rows, widths):
+    """(scope, opcode, the end of its op_name) of every instruction of the
+    compiled step under `op:moe_ffn` or `op:moe_ffn_grad`, outside the
+    kernels' interpreters (their instructions carry the kernel's name),
+    whose result has all `rows` rows at one of `widths` and is no loop's
+    carry: an elementwise pass, a fill, a gather of the whole buffer."""
+    found = []
+    for line in text.splitlines():
+        met = re.match(r"\s*(?:ROOT )?%?[\w.\-]+ = (\(?[^=]*?\)?) "
+                       r"(\w[\w\-]*)\(", line)
+        name = re.search(r'op_name="([^"]*)"', line)
+        if not met or not name or "op:moe_ffn" not in name.group(1) \
+                or "ptpu_" in name.group(1):
+            continue
+        shape, opcode = met.groups()
+        if opcode in _NO_PASS or not any(
+                "[%d,%d]" % (rows, width) in shape for width in widths):
+            continue
+        if opcode == "fusion" and name.group(1).endswith(
+                "dynamic_update_slice"):
+            continue
+        found.append(("moe_ffn_grad" if "op:moe_ffn_grad" in name.group(1)
+                      else "moe_ffn", opcode, name.group(1)[-40:]))
+    return found
+
+
+@pytest.mark.parametrize("cell,gated", [
+    ("lfm2", True), ("lfm2", False), ("nemotron_3_super", True),
+    ("nemotron_3_super", False)])
+def test_no_pass_has_all_the_rows_of_a_held_share(cell, gated, monkeypatch):
+    """One layer's step through the kernels' interpreter, a share held,
+    numbered by slot and by held expert, gated and not: under the op's two
+    scopes nothing outside the kernels has all A rows of an [A, D] or [A,
+    F] array but the loops' carries: no fill, no elementwise pass. With the
+    forms up to PR 64 stood in their place the unit's pass is there, so the
+    walk sees."""
+    monkeypatch.setenv("PADDLE_TPU_PALLAS", "gmm")
+    monkeypatch.setattr(moe, "ROW_TILE", 16)
+    monkeypatch.setattr(moe, "SUM_TILE", 16)
+    experts, held, top_k = CELLS[cell]
+    rows = 64 * (held if moe.numbered_by(*CELLS[cell]) == "expert"
+                 else top_k)
+    text = _one_layer_step(experts, held, top_k, gated=gated)
+    assert _under(text, "moe_ffn", "ptpu_expert_gmm_unit_fwd")
+    assert _whole_buffer_passes(text, rows, (128,)) == []
+    _the_forms_up_to_pr_64(monkeypatch)
+    seen = _whole_buffer_passes(_one_layer_step(experts, held, top_k,
+                                                gated=gated), rows, (128,))
+    assert {scope for scope, _, _ in seen} == {"moe_ffn"}
+    assert any(opcode in ("multiply", "maximum") for _, opcode, _ in seen)
