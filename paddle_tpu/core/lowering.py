@@ -772,7 +772,7 @@ def _count_attention_layer(ctx, attrs, ins):
     from ..ops.kernel_config import flash_at
     from ..ops.pallas_kernels import heads_a_block, latent_form
     q, k = ins["Q"][0], ins["K"][0]
-    window = attrs.get("window")
+    window, bd = attrs.get("window"), attrs.get("block_diffusion")
     if ctx.mesh is not None and ctx.mesh.shape.get("sp", 1) > 1:
         path = str(attrs.get("sp_impl", "ring"))
     else:
@@ -812,8 +812,12 @@ def _count_attention_layer(ctx, attrs, ins):
         "that read one rotary key, and, where the value is not as wide as "
         "the part without position (192 + 64 on 256), by core, the form the "
         "head runs in: whole (the two parts joined, the plain kernels at "
-        "v_dim) or dense"
-    ).inc(kind="full" if window is None else "window",
+        "v_dim) or dense; an op under the block-diffusion mask alone counts "
+        "as kind block_diffusion with its block_length and copy_length (its "
+        "T rows are two copies of copy_length tokens)"
+    ).inc(kind="block_diffusion" if bd else "full" if window is None
+          else "window", **({} if not bd else dict(
+              block_length=str(bd[0]), copy_length=str(bd[1]))),
           window=str(window or 0), q_heads=str(q.shape[2]),
           kv_heads=str(k.shape[2]), path=path, head_dim=str(q.shape[3]),
           heads_a_block=str(heads), **latent)

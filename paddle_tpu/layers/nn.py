@@ -740,7 +740,7 @@ def autoincreased_step_counter(counter_name=None, begin=1, step=1):
 
 def fused_attention(q, k, v, causal=False, scale=None, kv_len=None,
                     sp_impl="ring", name=None, window=None, q_rope=None,
-                    k_rope=None):
+                    k_rope=None, block_diffusion=None):
     """Flash attention over q [B, T, Hq, D] and k, v [B, T, Hkv, D]
     (TPU-native addition — the reference era built attention from
     matmul+softmax ops; this is the fused pallas path, see
@@ -762,7 +762,19 @@ def fused_attention(q, k, v, causal=False, scale=None, kv_len=None,
     values D (latent attention's head of 192 on values of 128) or, v [B, T,
     Hkv, dv], another width (192 + 64 on values of 256: the result is then
     [B, T, Hq, dv]; ops/pallas_kernels.py latent_form has the form the
-    kernels run it in). `scale` defaults to 1 / sqrt(D + dr)."""
+    kernels run it in). `scale` defaults to 1 / sqrt(D + dr).
+
+    block_diffusion: None, or (block_length, L): the T = 2 L rows are two
+    copies of one sequence of L tokens, the NOISED copy in rows 0 .. L - 1
+    and the CLEAN copy in rows L .. 2 L - 1 (block diffusion's training
+    mask, BD3-LM, arXiv:2503.09573), block_length dividing L. Row r is
+    (copy, position i, block b = i // block_length) and sees row s iff:
+    both are noised and b_s = b_r (block-diagonal, both directions inside a
+    block); or r is noised, s clean and b_s < b_r (offset block-causal); or
+    both are clean and b_s <= b_r (block-causal); a clean row never sees a
+    noised one. It is the whole mask: causal, window, kv_len, the latent
+    form and the sequence-parallel paths are refused beside it. The attr is
+    written only where it is asked for."""
     if (q_rope is None) != (k_rope is None):
         raise ValueError("fused_attention takes q_rope and k_rope together")
     if sp_impl not in ("ring", "ulysses"):
@@ -772,6 +784,20 @@ def fused_attention(q, k, v, causal=False, scale=None, kv_len=None,
     if window is not None and int(window) < 1:
         raise ValueError("fused_attention window must be None or >= 1, got "
                          "%r" % (window,))
+    if block_diffusion is not None:
+        block_diffusion = [int(n) for n in block_diffusion]
+        length, copy = block_diffusion
+        if causal or window is not None or kv_len is not None \
+                or q_rope is not None:
+            raise ValueError(
+                "fused_attention block_diffusion is the whole mask: causal, "
+                "window, kv_len and the latent form are refused beside it")
+        if length < 1 or copy % length or (
+                q.shape[1] not in (-1, None) and q.shape[1] != 2 * copy):
+            raise ValueError(
+                "fused_attention block_diffusion (block_length, L) takes T "
+                "= 2 L rows in whole blocks, got %r on q %r"
+                % (block_diffusion, tuple(q.shape)))
     helper = LayerHelper("fused_attention", **locals())
     out = helper.create_variable_for_type_inference(q.dtype)
     inputs = {"Q": [q], "K": [k], "V": [v]}
@@ -786,6 +812,8 @@ def fused_attention(q, k, v, causal=False, scale=None, kv_len=None,
              "sp_impl": str(sp_impl)}
     if window is not None:
         attrs["window"] = int(window)
+    if block_diffusion is not None:
+        attrs["block_diffusion"] = block_diffusion
     helper.append_op(
         type="fused_attention", inputs=inputs,
         outputs={"Out": [out]}, attrs=attrs)

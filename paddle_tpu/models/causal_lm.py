@@ -224,6 +224,38 @@ the QK-norm a head (`qk_norm: head`, a key of the config's own) are
 Qwen3-Next's. A share's published num_attention_heads_per_layer is held to
 the heads given: each is the published count x held / published key/value
 heads. A layer's attention parameters: wq, wk, wv, [q_norm, k_norm], wg, wo.
+SDAR-30B-A3B-Chat's (`model_type: sdar_moe`, JetLM; SDAR, arXiv:2510.06303):
+the layer is Qwen3-MoE's, by keys the builder had (num_experts,
+moe_intermediate_size, norm_topk_prob, head_dim, num_key_value_heads,
+decoder_sparse_step 1, mlp_only_layers empty; `qk_norm: head` a key of the
+config's own), and use_sliding_window (false: true refused), sliding_window
+(null) and max_window_layers (not read). What the family adds is its
+TRAINING OBJECTIVE, one block of the config: `objective: block_diffusion`
+(BD3-LM, arXiv:2503.09573; absent, the objective is next-token as it always
+was, and no program changes), `block_length` (positions a block),
+`mask_token_id` (among the held words) and `noise_eps` (the batch's, not
+read by the program). Under it the feeds are `ids` [B, T] the clean ids,
+`noisy_ids` [B, T] (the mask id where a position was masked), `pos` [B, T]
+and `loss_weight` [B, T] float32 (1 / t at a masked position, 0 elsewhere),
+and no `labels`; the rows are E[noisy_ids] then E[ids], 2 T of them from ONE
+lookup of ONE embedding parameter, both copies of token i at rotary position
+pos[i]; every layer's attention runs under the block-diffusion mask
+(`layers.fused_attention(block_diffusion=(block_length, T))`: row r = (copy,
+position i, block b = i // block_length) sees row s iff both are noised and
+b_s = b_r, or r is noised, s clean and b_s < b_r, or both are clean and b_s
+<= b_r); behind the LAST layer's core the noised copy's rows alone go on (W_o,
+the residual, the FFN, the final norm and the head on T rows: nothing reads a
+clean row there); the loss is the mean over the B x T noised rows of
+loss_weight_i x CE(logits_i, ids_i), the label the clean id at the SAME
+position, a sequence's sum divided by its T tokens. `resolve` refuses under
+the objective, by name: a window, a looped stack, several streams, a
+multi-token-prediction module, a mixer other than attention, latent or
+differential attention, a tied head, a mask_token_id outside the held words;
+`causal_lm` a block_length that does not divide the sequence. Parameters in
+the order and under the names of any attention-and-experts layer:
+embedding; layer_<i>.input_norm, wq, wk, wv, q_norm, k_norm, wo,
+post_attention_norm, experts.router, experts.w_gate, experts.w_up,
+experts.w_down; final_norm; head.
 A Mamba layer's parameters: w_in, conv, [conv.bias], w_x, w_dt, dt_bias,
 a_log, d, w_out; a memory unit's: w_in, w_out; a differential attention's:
 wq, [wq.bias], then where it makes its own keys and values wk, [wk.bias],
@@ -778,7 +810,68 @@ def resolve(cfg):
             raise ValueError("%d linear value heads are no multiple of %d "
                              "key heads" % (c["linear_num_value_heads"],
                                             c["linear_num_key_heads"]))
+    # sdar_moe's (Qwen3-MoE's) window switch: off is all that is built
+    if c.get("use_sliding_window"):
+        raise NotImplementedError(
+            "causal_lm builds use_sliding_window false only (a window is a "
+            "layer's, by sliding_window_layout or layer_types); the config "
+            "has %r" % (c["use_sliding_window"],))
+    _objective(c)
     return c
+
+
+def _objective(c):
+    """The training objective: next-token (no `objective` key, as it always
+    was: c["block_diffusion"] None) or `objective: block_diffusion` (BD3-LM,
+    arXiv:2503.09573; SDAR's, arXiv:2510.06303), whose keys become
+    c["block_diffusion"] = {block_length, mask_token_id, noise_eps}. Under
+    it `resolve` refuses by name what `causal_lm` cannot build over two
+    copies of a sequence: a window, a looped stack, several streams, a
+    multi-token-prediction module, a mixer other than attention, latent or
+    differential attention, a tied head, a mask_token_id outside the held
+    words (a block_length that does not divide the sequence is `causal_lm`'s
+    to refuse: it knows the length)."""
+    objective = c.get("objective")
+    c["block_diffusion"] = None
+    if objective is None:
+        return
+    if objective != "block_diffusion":
+        raise NotImplementedError(
+            "causal_lm builds the next-token objective (no `objective` key) "
+            "or objective block_diffusion, the config has %r" % (objective,))
+    layers = c["num_hidden_layers"]
+    for what, found in (
+            ("a window (window_layers)",
+             any(w is not None for w in c["window_layers"])),
+            ("a looped stack (total_ut_steps %d)" % c["total_ut_steps"],
+             c["total_ut_steps"] > 1),
+            ("several residual streams (hc_mult %d)" % c["hc_mult"],
+             c["hc_mult"] > 1),
+            ("a multi-token-prediction module (num_nextn_predict_layers)",
+             c["mtp_layers"] > 0),
+            ("a mixer other than attention (%s)" % sorted(
+                set(c["mixer_layers"][:layers]) - {"attention"}),
+             set(c["mixer_layers"][:layers]) != {"attention"}),
+            ("latent attention", c["latent"]),
+            ("differential attention", c["differential_attention"]),
+            ("a tied head (tie_word_embeddings)", c["tie_word_embeddings"])):
+        if found:
+            raise NotImplementedError(
+                "causal_lm builds objective block_diffusion (a noised and a "
+                "clean copy of every sequence under the block-diffusion "
+                "mask) without %s" % what)
+    length, mask_id = c.get("block_length"), c.get("mask_token_id")
+    if not isinstance(length, int) or length < 1:
+        raise ValueError("objective block_diffusion needs block_length, a "
+                         "whole number of positions; the config has %r"
+                         % (length,))
+    if not isinstance(mask_id, int) or not 0 <= mask_id < c["vocab_size"]:
+        raise ValueError(
+            "objective block_diffusion needs mask_token_id among the %d held "
+            "words (0 .. %d), the config has %r"
+            % (c["vocab_size"], c["vocab_size"] - 1, mask_id))
+    c["block_diffusion"] = {"block_length": length, "mask_token_id": mask_id,
+                            "noise_eps": float(c.get("noise_eps", 1e-3))}
 
 
 def _geometry_by_layer(c, published):
@@ -1149,7 +1242,13 @@ def attention(x, pos, c):
     a projection of its own, sigmoid(x W_g) [B, T, H] (`wg`, no bias), on
     the core's output before W_o. The heads, the rotary's width, table and
     theta and the window are the LAYER's (`_layer`): W_q and W_o are [D, H
-    x head_dim] whatever the hidden size is."""
+    x head_dim] whatever the hidden size is. Under objective block_diffusion
+    (c["copies"] = (block_length, T)) x is [B, 2 T, D], the noised copy's
+    rows then the clean copy's, pos [B, 2 T] the same T positions twice,
+    and the core's mask is block diffusion's in place of the causal one
+    (layers.fused_attention has the rule); with c["noised_rows"] = T (the
+    last layer) the core's output is cut to the noised copy's rows before
+    W_o, and the result is [B, T, D]."""
     d, hd = c["hidden_size"], c["head_dim"]
     h, hkv = c["num_attention_heads"], c["num_key_value_heads"]
     per_head = c["attention_gate"] == "per_head"
@@ -1171,14 +1270,20 @@ def attention(x, pos, c):
             inv_freq=c["rope_inv_freq"], table_scale=c["rope_table_scale"],
             layout="interleaved" if c["rope_interleaved"] else "half")
             for t in (q, k))
-    ctx = fluid.layers.fused_attention(q, k, v, causal=True,
-                                       window=c["window"],
-                                       scale=c["attention_scale"])
+    # under objective block_diffusion the rows are a noised and a clean copy
+    # of the sequence and the mask is block diffusion's, the whole of it
+    copies = c.get("copies")
+    ctx = fluid.layers.fused_attention(
+        q, k, v, causal=copies is None, window=c["window"],
+        scale=c["attention_scale"], block_diffusion=copies)
     if gated:
         ctx = ctx * fluid.layers.sigmoid(gate)
     if per_head:
         ctx = fluid.layers.elementwise_mul(
             ctx, fluid.layers.sigmoid(_linear(x, h, c, "wg")), axis=0)
+    if c.get("noised_rows"):
+        # the last layer: nothing reads the clean copy's rows behind the core
+        ctx = fluid.layers.crop(ctx, shape=[-1, c["noised_rows"], -1, -1])
     return _linear(fluid.layers.reshape(ctx, shape=[0, -1, h * hd]), d, c,
                    "wo")
 
@@ -1640,8 +1745,13 @@ def _count_layer(c, mixer, module="trunk"):
         "num_attention_heads_per_layer), the query and key/value heads the "
         "layer holds, its window (0: none) and its rotary parameters' kind "
         "(default, yarn, none), rotary_dim then the layer's own; gate is "
-        "per_head where the gate is a scalar a head"
+        "per_head where the gate is a scalar a head; and, for a config with "
+        "objective block_diffusion alone, mask block_diffusion and the "
+        "block_length of the mask its attention runs under"
     ).inc(mixer=mixer, module=module, reads=c["reads"],
+          **({} if c["block_diffusion"] is None else dict(
+              mask="block_diffusion",
+              block_length=str(c["block_diffusion"]["block_length"]))),
           **({} if not c["geometry_by_layer"] else dict(
               heads=str(c["num_attention_heads"] if attention else 0),
               kv_heads=str(c["num_key_value_heads"] if attention else 0),
@@ -1700,7 +1810,10 @@ def causal_lm(cfg, seq_len, extras=None, recompute=True):
     """Build the training graph in the current program guard. Feeds: `ids`
     [B, T] token ids, `pos` [B, T] their positions, `labels` [B, T, 1] the
     next token at every position and, with a multi-token-prediction module,
-    a third, `labels_next` [B, T, 1], the token after that. Returns (loss,
+    a third, `labels_next` [B, T, 1], the token after that; under objective
+    block_diffusion `ids`, `noisy_ids`, `pos` and `loss_weight` [B, T]
+    (module docstring: 2 T rows through the trunk, the logits [B, T, V] the
+    noised copy's, the loss the weighted mean). Returns (loss,
     logits [B, T, V], expert_load): the loss is the mean cross-entropy a
     position plus router_aux_loss_coef x the layers' mean balance loss plus
     router_z_loss_coef x their mean z loss (neither term is built where
@@ -1732,9 +1845,29 @@ def causal_lm(cfg, seq_len, extras=None, recompute=True):
     the passes' logits [B, T, V] that have a loss, and `exit_p` [B, P, T]."""
     c = resolve(cfg)
     layers, passes = fluid.layers, c["total_ut_steps"]
+    objective = c["block_diffusion"]
     ids = layers.data("ids", [seq_len], dtype="int64")
-    pos = layers.data("pos", [seq_len], dtype="int64")
-    labels = layers.data("labels", [seq_len, 1], dtype="int64")
+    if objective is None:
+        pos = layers.data("pos", [seq_len], dtype="int64")
+        labels = layers.data("labels", [seq_len, 1], dtype="int64")
+        tokens = ids
+    else:
+        if seq_len % objective["block_length"]:
+            raise ValueError(
+                "objective block_diffusion trains whole blocks: block_length "
+                "%d does not divide the sequence of %d"
+                % (objective["block_length"], seq_len))
+        noisy_ids = layers.data("noisy_ids", [seq_len], dtype="int64")
+        pos = layers.data("pos", [seq_len], dtype="int64")
+        loss_weight = layers.data("loss_weight", [seq_len], dtype="float32")
+        # 2 T rows through the trunk: the noised copy's, then the clean
+        # copy's, one lookup of one parameter, both copies of token i at
+        # position i; the label of a noised row is the clean id at its own
+        # position
+        tokens = layers.concat([noisy_ids, ids], axis=1)
+        pos = layers.concat([pos, pos], axis=1)
+        labels = ids
+        copies = (objective["block_length"], seq_len)
     trunk, mtp = c["num_hidden_layers"], c["mtp_layers"]
 
     def embed(tokens):
@@ -1746,7 +1879,7 @@ def causal_lm(cfg, seq_len, extras=None, recompute=True):
                 0.0, c.get("embedding_initializer_range",
                            c["initializer_range"]))))
 
-    h = embed(ids)
+    h = embed(tokens)
     # granitemoehybrid's scalars on the main path, each an op only where it
     # is not 1: embedding_multiplier here, residual_multiplier on what each
     # branch adds to the stream, logits_scaling under the logits
@@ -1780,6 +1913,12 @@ def causal_lm(cfg, seq_len, extras=None, recompute=True):
         c["handed_on"] = {}
         for i in range(trunk + mtp):
             cl, mixer = _layer(c, i), c["mixer_layers"][i]
+            if objective is not None:
+                # every layer's core under the mask; the last layer takes
+                # the noised copy's rows behind it (they alone carry a loss)
+                cl["copies"] = copies
+                cl["noised_rows"] = seq_len if i == trunk - 1 else None
+                _count_rows(seq_len, last=i == trunk - 1)
             if i == trunk:
                 # the multi-token-prediction module: the trunk's state is
                 # kept, normed, for the trunk's head, and the module's layer
@@ -1825,6 +1964,8 @@ def causal_lm(cfg, seq_len, extras=None, recompute=True):
             if streams > 1:
                 h = layers.mhc_post(h, mixed, coef, streams)
                 read, coef, h = hyper_connection(h, cl, "ffn_hc")
+            elif cl.get("noised_rows"):
+                h = layers.crop(h, shape=[-1, seq_len, -1]) + mixed
             else:
                 h = h + mixed
             out, layer_aux = feed_forward(
@@ -1892,7 +2033,12 @@ def causal_lm(cfg, seq_len, extras=None, recompute=True):
         found = {"exit_p": p}
     else:
         found, (pass_logits, costs) = {}, zip(head(states[-1]))
-        loss = layers.mean(costs[0])
+        # block diffusion's: (1 / T) sum_i w_i CE(logits_i, x_i), w_i = 1 /
+        # t a masked position and 0 elsewhere, as fed
+        loss = layers.mean(costs[0] if objective is None else costs[0]
+                           * layers.reshape(loss_weight, shape=[-1, 1]))
+        if objective is not None:
+            _count_rows(seq_len, head=True)
     if mtp:
         # L_main + lambda L_mtp: the module's state through the trunk's
         # head, against the tokens two ahead
@@ -1920,6 +2066,31 @@ def causal_lm(cfg, seq_len, extras=None, recompute=True):
     elif aux:
         load = layers.sums([terms[2] for terms in aux])
     return loss, logits, load
+
+
+def _count_rows(seq_len, last=False, head=False):
+    """The rows of one sequence that one layer's parts (or the head) are
+    built over under objective block_diffusion: the attention over both
+    copies, the FFN over both but in the last layer, the head over the
+    noised copy's alone."""
+    from ..observability.registry import REGISTRY
+    rows = REGISTRY.counter(
+        "ptpu_causal_lm_rows_total",
+        "rows of ONE sequence (a step's are the batch's times as many) that "
+        "causal_lm built each part of each layer over under objective "
+        "block_diffusion, by part (attention: the projections into the core "
+        "and the core; ffn: what lies behind the core, W_o, the residual "
+        "and the FFN; head: the final norm, the head and the loss) and copy "
+        "(noised, clean): the last layer's ffn and the head have no clean "
+        "rows. A next-token model builds every part over its T rows and "
+        "counts nothing here")
+    if head:
+        rows.inc(seq_len, part="head", copy="noised")
+        return
+    for copy in ("noised", "clean"):
+        rows.inc(seq_len, part="attention", copy=copy)
+        if copy == "noised" or not last:
+            rows.inc(seq_len, part="ffn", copy=copy)
 
 
 def _count_head(c):

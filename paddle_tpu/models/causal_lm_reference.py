@@ -224,7 +224,7 @@ def hyper_connection(x, phi, bias, alpha, c):
 
 
 def attention(a, pos, wq, wk, wv, q_norm, k_norm, wo, c, w_gate=None,
-              found=None):
+              found=None, visible=None, rows=None):
     """Causal attention of the heads whose weights are given: wq [D, Hq x
     hd], wk and wv [D, Hkv x hd], wo [Hq x hd, D]; query head h reads
     key/value head h // (Hq / Hkv). c["rope_theta"] None: no position
@@ -239,7 +239,10 @@ def attention(a, pos, wq, wk, wv, q_norm, k_norm, wo, c, w_gate=None,
     `attention_factor` where the set gives it, else by m(mscale) /
     m(mscale_all_dim). A dict given as `found` gets, a call, `core_q` and
     `core_k` (q and k as the core reads them: normed and turned) and
-    `head_gate` (the per-head gate's sigmoid)."""
+    `head_gate` (the per-head gate's sigmoid). `visible` [T, T] bool: the
+    mask itself, in place of the causal one and the window (block
+    diffusion's, `block_diffusion_mask`); `rows`: the core's output is cut
+    to its first so many rows before the gate a head and wo."""
     b, t, _ = a.shape
     hd, eps = c["head_dim"], c["rms_norm_eps"]
     gated, centred = c["attention_gate"] is True, c["norm_zero_centered"]
@@ -275,10 +278,11 @@ def attention(a, pos, wq, wk, wv, q_norm, k_norm, wo, c, w_gate=None,
         found.setdefault("core_k", []).append(k)
     k, v = (jnp.repeat(x, h // hkv, axis=2) for x in (k, v))
     s = jnp.einsum("bqhd,bkhd->bhqk", q, k) * scale
-    age = jnp.arange(t)[:, None] - jnp.arange(t)[None, :]       # i - j
-    visible = age >= 0
-    if c["window"] is not None:
-        visible = visible & (age < c["window"])
+    if visible is None:
+        age = jnp.arange(t)[:, None] - jnp.arange(t)[None, :]   # i - j
+        visible = age >= 0
+        if c["window"] is not None:
+            visible = visible & (age < c["window"])
     s = jnp.where(visible, s, -jnp.inf)
     ctx = jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s, -1), v)
     if gated:
@@ -288,7 +292,9 @@ def attention(a, pos, wq, wk, wv, q_norm, k_norm, wo, c, w_gate=None,
         ctx = ctx * gate[..., None]
         if found is not None:
             found.setdefault("head_gate", []).append(gate)
-    return ctx.reshape(b, t, h * hd) @ wo
+    if rows is not None:
+        ctx = ctx[:, :rows]
+    return ctx.reshape(b, -1, h * hd) @ wo
 
 
 def layer_norm(x, w, b, eps):
@@ -998,3 +1004,131 @@ def loss_and_grads(cfg, params, ids, pos, labels, labels_next=None):
         lambda p: loss_fn(cfg, p, ids, pos, labels,
                           labels_next=labels_next), has_aux=True)(
             [jnp.asarray(p, jnp.float32) for p in params])
+
+
+def block_diffusion_mask(t, block_length):
+    """[2 T, 2 T] bool, row r sees row s, of block diffusion's training mask
+    (BD3-LM, arXiv:2503.09573, section 3.1 and its appendix on the
+    vectorised objective), written from the rule by (copy, position, block):
+    the rows are a noised copy of the T tokens, then a clean copy; with b =
+    position // block_length, r sees s iff both are noised and b_s = b_r
+    (inside a block, both directions), or r is noised, s clean and b_s <
+    b_r (the clean blocks before its own), or both are clean and b_s <= b_r
+    (block-causal). A clean row never sees a noised one."""
+    copy = jnp.concatenate([jnp.ones(t, bool), jnp.zeros(t, bool)])  # noised?
+    block = jnp.tile(jnp.arange(t) // block_length, 2)
+    r_noised, s_noised = copy[:, None], copy[None, :]
+    b_r, b_s = block[:, None], block[None, :]
+    return jnp.where(
+        r_noised, jnp.where(s_noised, b_s == b_r, b_s < b_r),
+        jnp.where(s_noised, False, b_s <= b_r))
+
+
+def block_diffusion_loss(cfg, params, ids, noisy_ids, pos, loss_weight,
+                         found=None):
+    """(loss, (logits [B, T, V] on the noised copy's rows, expert_load)) of
+    a config with `objective: block_diffusion` (SDAR, arXiv:2510.06303,
+    whose objective is BD3-LM's): the rows are E[noisy_ids] then E[ids], 2
+    T of them, both copies of token i at position pos[i]; every layer is a
+    = h + Attn(N1(h)) under `block_diffusion_mask`, h = a + FFN(N2(a)) (a
+    dense SwiGLU or the routed experts whose weights are given, with the
+    shared expert where there is one); nothing reads the clean copy's rows
+    behind the last layer's attention core, so that layer's W_o, residual
+    and FFN, the final norm and the head run on the noised copy's T rows;
+    the loss is (1 / (B T)) sum_i loss_weight_i CE(logits_i, ids_i): the
+    label is the clean id at the SAME position, the weight 1 / t at a
+    masked position and 0 elsewhere, as fed. A dict given as `found` gets
+    `attention_layers` (each layer's attention output behind W_o: 2 T rows,
+    T in the last), `core_q` and `core_k` (`attention`'s), `routed` (the
+    first expert layer's routed output), `router_logits` (a layer's, [rows,
+    E]) and `state` (what the final norm reads)."""
+    c = resolve(cfg)
+    if c["block_diffusion"] is None:
+        raise ValueError("the config has no objective block_diffusion")
+    params = iter(params)
+
+    def take(n):
+        return [jnp.asarray(next(params), jnp.float32) for _ in range(n)]
+
+    eps, layers = c["rms_norm_eps"], c["num_hidden_layers"]
+    centred = c["norm_zero_centered"]
+    embedding = take(1)[0]
+    weights = []
+    for i in range(layers):
+        n1 = take(1)[0]
+        mixer = take(3) + (take(2) if c["qk_norm"] else [None, None])
+        gate = take(1) if c["attention_gate"] == "per_head" else []
+        mixer = mixer + take(1) + gate
+        n3 = take(1)[0]
+        if c["ffn_layers"][i] == "dense":
+            ffn = take(3)
+        else:
+            if c["use_expert_bias"] or not c["ffn_gated"]:
+                raise NotImplementedError(
+                    "the block-diffusion reference has softmax-routed gated "
+                    "experts and dense SwiGLUs")
+            shared = 0 if not c["shared_expert_intermediate_size"] \
+                else 4 if c["shared_expert_gate"] else 3
+            ffn = take(4 + shared)
+        weights.append((n1, mixer, n3, ffn))
+    w_f, w_lm = take(2)
+    if next(params, None) is not None:
+        raise ValueError("the reference read fewer parameters than the "
+                         "program has: the two are not the same architecture")
+    b, t = ids.shape
+    d = c["hidden_size"]
+    visible = block_diffusion_mask(t, c["block_diffusion"]["block_length"])
+    load = jnp.zeros((max(c["num_experts"], 1),), jnp.int32)
+    with jax.default_matmul_precision("highest"):
+        h = embedding[jnp.concatenate([noisy_ids, ids], 1)] \
+            * c["embedding_multiplier"]
+        pos2 = jnp.concatenate([pos, pos], 1)
+        for i, (n1, mixer, n3, ffn) in enumerate(weights):
+            last = i == layers - 1
+            a = rms_norm(h, n1, eps, centred)
+            mixed = attention(a, pos2, *mixer[:6], layer_config(c, i),
+                              *mixer[6:], found=found, visible=visible,
+                              rows=t if last else None)
+            if found is not None:
+                found.setdefault("attention_layers", []).append(mixed)
+            h = (h[:, :t] if last else h) + mixed
+            m = rms_norm(h, n3, eps, centred)
+            if c["ffn_layers"][i] == "dense":
+                wg, wu, wd = ffn
+                out = (jax.nn.silu(m @ wg) * (m @ wu)) @ wd
+            else:
+                flat = m.reshape(-1, d)
+                out, _, _, ld = routed_experts(flat, ffn[0], *ffn[1:4], c)
+                out = out.reshape(m.shape)
+                if found is not None:
+                    found.setdefault("routed", out)
+                    found.setdefault("router_logits", []).append(
+                        flat @ ffn[0])
+                if len(ffn) > 4:
+                    out = out + shared_expert(m, *ffn[4:])
+                load = load + ld
+            h = h + out
+        if found is not None:
+            found["state"] = h
+        logits = rms_norm(h, w_f, eps, centred) @ w_lm / c["logits_scaling"]
+        nll = -jnp.take_along_axis(jax.nn.log_softmax(logits, -1),
+                                   ids[..., None], axis=-1)[..., 0]
+        loss = (loss_weight * nll).sum() / (b * t)
+    return loss, (logits, load)
+
+
+def block_diffusion_batch(key, ids, block_length, mask_token_id,
+                          noise_eps=1e-3):
+    """(noisy_ids, loss_weight) of clean ids [B, T] from a jax key: a noise
+    level a block of block_length positions on the linear schedule, t_b =
+    eps + (1 - eps) u_b, u_b uniform on [0, 1) (alpha_t = 1 - t; BD3-LM's and
+    LLaDA's form); m_i Bernoulli(t_b(i)); noisy_i = mask_token_id where m_i
+    else ids_i; loss_weight_i = m_i / t_b(i), float32."""
+    b, t = ids.shape
+    k_level, k_mask = jax.random.split(key)
+    level = noise_eps + (1.0 - noise_eps) * jax.random.uniform(
+        k_level, (b, t // block_length), jnp.float32)
+    level = jnp.repeat(level, block_length, axis=1)
+    masked = jax.random.uniform(k_mask, (b, t), jnp.float32) < level
+    return jnp.where(masked, mask_token_id, ids).astype(ids.dtype), \
+        jnp.where(masked, 1.0 / level, 0.0).astype(jnp.float32)

@@ -510,7 +510,14 @@ def _fused_attention(ctx, ins, attrs):
     heads share (pallas_kernels.flash_attention). The dense path
     concatenates the two parts and repeats the shared key a head; the flash
     kernels do neither at equal widths, and at unequal ones what
-    pallas_kernels.latent_form says."""
+    pallas_kernels.latent_form says.
+
+    `block_diffusion` [block_length, L], an attr a program has only where
+    it was asked for: the T = 2 L rows are a noised and a clean copy of one
+    sequence under the block-diffusion mask (pallas_kernels.flash_attention
+    has the rule; the dense path writes it out, ring_attention.
+    block_diffusion_mask). Refused beside causal, a window, KVLen, the
+    latent form and an 'sp' mesh axis."""
     q = single(ins, "Q")
     k = single(ins, "K")
     v = single(ins, "V")
@@ -523,16 +530,26 @@ def _fused_attention(ctx, ins, attrs):
     # a divisor of them); `window` is an attr: query i sees key j only where
     # i - j < window
     window = attrs.get("window", None)
+    bd = attrs.get("block_diffusion", None)
+    if bd is not None:
+        bd = tuple(int(n) for n in bd)
+        if causal or window is not None or kv_len is not None \
+                or q_rope is not None:
+            raise ValueError(
+                "fused_attention: block_diffusion %r is the whole mask: "
+                "causal, window, KVLen and the latent form are refused "
+                "beside it" % (bd,))
     mesh = ctx.mesh
     if mesh is not None and mesh.shape.get("sp", 1) > 1:
         if window is not None or k.shape[2] != q.shape[2] \
-                or q_rope is not None:
+                or q_rope is not None or bd is not None:
             raise NotImplementedError(
                 "fused_attention under an 'sp' mesh axis has neither a "
-                "window nor grouped queries nor the latent form: the ring "
+                "window nor grouped queries nor the latent form nor the "
+                "block-diffusion mask: the ring "
                 "and Ulysses paths would ignore window=%r, %d key/value "
-                "heads for %d query heads and the rotary parts"
-                % (window, k.shape[2], q.shape[2]))
+                "heads for %d query heads, the rotary parts and "
+                "block_diffusion=%r" % (window, k.shape[2], q.shape[2], bd))
         # sp_impl picks the sequence-parallel algorithm: "ring" (default;
         # K/V blocks rotate over ICI, O(T/sp) memory, any head count) or
         # "ulysses" (all-to-all head sharding — one collective round
@@ -558,11 +575,11 @@ def _fused_attention(ctx, ins, attrs):
                 [k, jnp.broadcast_to(k_rope, q_rope.shape)], -1)
         return _out(attention_reference(
             q, k, v, causal=causal, scale=scale, kv_len=kv_len,
-            window=window).astype(v.dtype))
+            window=window, block_diffusion=bd).astype(v.dtype))
     from . import pallas_kernels as pk
     out = pk.flash_attention(
         q, k, v, causal=causal, scale=scale, kv_len=kv_len, window=window,
-        q_rope=q_rope, k_rope=k_rope)
+        q_rope=q_rope, k_rope=k_rope, block_diffusion=bd)
     return _out(out)
 
 

@@ -128,6 +128,106 @@ def _visible(valid, qpos, kpos, causal, window):
     return valid
 
 
+# The block-diffusion mask (BD3-LM, arXiv:2503.09573; SDAR's training
+# objective): `bd` = (block_length, L), static. The 2 L rows are two copies of
+# one sequence of L tokens, the NOISED copy in rows 0 .. L - 1 and the CLEAN
+# copy in rows L .. 2 L - 1; row r is (copy, position i = r or r - L, block b
+# = i // block_length), and row r sees row s iff: both noised and b_s = b_r
+# (block-diagonal, both directions inside a block); or r noised, s clean and
+# b_s < b_r (offset block-causal); or both clean and b_s <= b_r
+# (block-causal). A clean row never sees a noised one. It is causal in no row
+# order, and the rows a tile of rows meets are TWO ranges of tiles.
+
+# added to a noised row's block number: above any block number, so that one
+# equality and one inequality on [bq, bk] decide a tile (`_bd_visible`)
+_BD_NOISED = 1 << 24
+
+
+def _cdiv(a, b):
+    return (a + b - 1) // b
+
+
+def _bd_blocks(i, block_i, block_j, bd, transposed=False):
+    """((first, end), (first, end)): the two ranges of j tiles (of block_j
+    rows) in which tile i (of block_i rows) has a visible pair under the
+    block-diffusion mask `bd` = (block_length, L); every tile inside them
+    has one and no tile outside has. The first range lies among the noised
+    rows (a tile that holds row L - 1), the second among the clean ones,
+    after the first and never over it. Not transposed: i is a tile of
+    QUERIES and the ranges are of key tiles (a noised query's own diagonal
+    blocks, then the clean keys up to the tile's frontier). Transposed: i is
+    a tile of KEYS and the ranges are of query tiles (the noised queries of
+    a noised key's blocks and those past a clean key's block, then the
+    clean queries from a clean key's block on). i may be a traced scalar;
+    with Python ints the four bounds are jax scalars all the same."""
+    bl, n = bd
+    r0 = i * block_i
+    r1 = jnp.minimum(r0 + block_i, 2 * n)            # the tile's rows
+    has_n, has_c = r0 < n, r1 > n                    # noised, clean rows
+    p1 = jnp.minimum(r1, n) - 1                      # its last noised position
+    # the noised rows of the blocks the tile's noised rows lie in
+    lo = jnp.where(has_n, (r0 // bl) * bl, n)
+    hi = jnp.where(has_n, jnp.minimum((p1 // bl + 1) * bl, n), 0)
+    if transposed:
+        c0 = jnp.maximum(r0, n) - n                  # its first clean position
+        # the noised queries past a clean key's block, the clean from it on
+        past = jnp.where(has_c, jnp.minimum((c0 // bl + 1) * bl, n), n)
+        lo = jnp.minimum(lo, past)
+        hi = jnp.where(past < n, n, hi)
+        c_lo = n + (c0 // bl) * bl
+        c_hi = jnp.where(has_c, 2 * n, c_lo)
+    else:
+        c1 = r1 - 1 - n                              # its last clean position
+        # the clean keys before a noised query's block, up to a clean one's
+        reach = jnp.maximum(
+            jnp.where(has_n, (p1 // bl) * bl, 0),
+            jnp.where(has_c, jnp.minimum((c1 // bl + 1) * bl, n), 0))
+        c_lo, c_hi = n, n + reach
+    a_first = lo // block_j
+    a_end = jnp.where(hi > lo, _cdiv(hi, block_j), a_first)
+    b_first = jnp.maximum(c_lo // block_j, a_end)
+    b_end = jnp.where(c_hi > c_lo,
+                      jnp.maximum(_cdiv(c_hi, block_j), b_first), b_first)
+    return (a_first, a_end), (b_first, b_end)
+
+
+def _bd_block_of(pos, bd):
+    """(noised, block number) of rows pos: an int32 vector."""
+    bl, n = bd
+    noised = pos < n
+    i = pos - jnp.where(noised, 0, n)
+    return noised, lax.div(i, lax.full_like(i, bl))
+
+
+def _bd_query_codes(qpos, bd):
+    """(equal, at_most) of query rows qpos: a key's code (`_bd_key_code`)
+    is visible iff it equals the first or is at most the second."""
+    noised, b = _bd_block_of(qpos, bd)
+    return jnp.where(noised, b + _BD_NOISED, -1), jnp.where(noised, b - 1, b)
+
+
+def _bd_key_code(kpos, bd):
+    noised, b = _bd_block_of(kpos, bd)
+    return jnp.where(noised, b + _BD_NOISED, b)
+
+
+def _bd_visible(valid, qcodes, kcode):
+    """The block-diffusion mask of one block step from the rows' codes, two
+    compares on the tile: a noised key (code >= _BD_NOISED) meets the noised
+    queries of its own block by the equality and no inequality; a clean key
+    (its block number) meets no equality and the inequality of the noised
+    queries past its block and the clean ones from it on."""
+    equal, at_most = qcodes
+    return valid & ((kcode == equal) | (kcode <= at_most))
+
+
+def _loops(ranges, body, init):
+    """`body` over each [first, end) of `ranges` in turn, one carry."""
+    for first, end in ranges:
+        init = lax.fori_loop(first, end, body, init)
+    return init
+
+
 def heads_a_block(hq, hkv, d):
     """How many query heads one lane block of q as [B, T, Hq*D] holds, from
     the shapes alone; None where a head cannot be indexed in place and the
@@ -264,7 +364,7 @@ def _rescaled(ref, corr, term):
 
 
 def _flash_fwd_kernel(q_ref, k_ref, v_ref, *refs, scale, causal, window,
-                      block_q, block_k, t_pad, d, hb, rope=None):
+                      block_q, block_k, t_pad, d, hb, rope=None, bd=None):
     """One q block's output and logsumexp: the k blocks `_k_blocks` names
     stream past it. Of the online softmax's state only the running max is
     carried by the loop; the row sums `l_ref` [bq, 128] and the output's
@@ -279,7 +379,11 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, *refs, scale, causal, window,
 
     `rope` (dr, hr), the latent form: two operands more, the rotary
     queries' block [bq, Wr] and the one rotary key all heads share, pinned
-    [t_pad, Wr]; a score is the sum of the two products."""
+    [t_pad, Wr]; a score is the sum of the two products.
+
+    `bd` (block_length, L), the block-diffusion mask: the k blocks are
+    `_bd_blocks`'s two ranges, one loop after the other over one carry, and
+    a tile is masked by `_bd_visible`."""
     if rope:
         qr_ref, kr_ref, len_ref, o_ref, lse_ref, l_ref, acc_ref = refs
         qr = _only(_rope_lanes(qr_ref.shape[1], *rope), qr_ref[...])
@@ -295,6 +399,7 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, *refs, scale, causal, window,
     kv_len = len_ref[pl.program_id(0), 0]                    # this row's T
     l_ref[...] = lax.full(l_ref.shape, 0.0, jnp.float32)
     acc_ref[...] = lax.full(acc_ref.shape, 0.0, jnp.float32)
+    qcodes = _bd_query_codes(qpos, bd) if bd else None
 
     def body(kb, m):
         k = k_ref[pl.ds(kb * block_k, block_k), :]
@@ -306,7 +411,8 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, *refs, scale, causal, window,
             s = _dot(q, k, _NT) * scale                      # [bq, bk] f32
         kpos = kb * block_k + lax.broadcasted_iota(jnp.int32, (1, block_k),
                                                    1)
-        valid = _visible(kpos < kv_len, qpos, kpos, causal, window)
+        valid = _bd_visible(kpos < kv_len, qcodes, _bd_key_code(kpos, bd)) \
+            if bd else _visible(kpos < kv_len, qpos, kpos, causal, window)
         s = jnp.where(valid, s, _NEG)
         m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
         p = jnp.exp(s - m_new)                               # masked -> 0
@@ -315,8 +421,9 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, *refs, scale, causal, window,
         acc_ref[...] = _rescaled(acc_ref, corr, _dot(p.astype(v.dtype), v))
         return m_new
 
-    m = lax.fori_loop(
-        *_k_blocks(qb, kv_len, causal, window, block_q, block_k, t_pad), body,
+    m = _loops(
+        _bd_blocks(qb, block_q, block_k, bd) if bd else (_k_blocks(
+            qb, kv_len, causal, window, block_q, block_k, t_pad),), body,
         lax.full((bq, 1), _M_FLOOR, jnp.float32))
 
     l = lax.broadcast_in_dim(lax.reduce_sum(l_ref[...], (1,)), (bq, 1), (0,))
@@ -378,7 +485,7 @@ def _rope_rows(rope, d, hb, pad):
 # arguments of their jits. `rows`: the query rows (the arrays come as [rows *
 # t_pad, lanes]); `rope`: None, or the latent form's (dr, hr) of `_rope_rows`.
 _FLASH_STATIC = ("rows", "d", "hb", "scale", "causal", "window", "block_q",
-                 "block_k", "rope", "interpret")
+                 "block_k", "rope", "interpret", "bd")
 
 
 def _flash_static(q, k, rows, d, hb):
@@ -392,7 +499,7 @@ def _flash_static(q, k, rows, d, hb):
 
 @kernel_entry("ptpu_flash_fwd", static_argnames=_FLASH_STATIC)
 def _flash_fwd_call(q, k, v, rope_args, lens, *, rows, d, hb, scale, causal,
-                    window, block_q, block_k, rope, interpret):
+                    window, block_q, block_k, rope, interpret, bd=None):
     """The forward pallas_call on `_flash_fwd`'s operands as it lays them
     out: q [rows * t_pad, Hq*D], k, v [rows_kv * t_pad, Hkv*D], rope_args
     () or `_rope_rows`'s two, lens [rows, 1] int32 -> (out as q, lse)."""
@@ -407,7 +514,8 @@ def _flash_fwd_call(q, k, v, rope_args, lens, *, rows, d, hb, scale, causal,
         return kv_b(b), kv_h(p)
 
     static = dict(scale=scale, causal=causal, window=window,
-                  block_q=block_q, block_k=block_k, t_pad=t_pad, d=d, hb=hb)
+                  block_q=block_q, block_k=block_k, t_pad=t_pad, d=d, hb=hb,
+                  bd=bd)
     rope_specs = []
     if rope is not None:
         static["rope"] = rope
@@ -449,7 +557,7 @@ def _flash_fwd_call(q, k, v, rope_args, lens, *, rows, d, hb, scale, causal,
 
 
 def _flash_fwd(q, k, v, kv_len, d, hb, scale, causal, window, block_q,
-               block_k, interpret, rope=None):
+               block_k, interpret, rope=None, bd=None):
     """q: [Bq, T, Hq*D]; k, v: [Bk, T, Hkv*D], heads of D lanes side by
     side in a row; kv_len: [Bq] int32 (true key length per query row); hb
     query heads a lane block (`heads_a_block`) -> (out [Bq, T, Hq*D], lse
@@ -480,14 +588,14 @@ def _flash_fwd(q, k, v, kv_len, d, hb, scale, causal, window, block_q,
         q, k, v, rope_args, kv_len.reshape(rows, 1).astype(jnp.int32),
         rows=rows, d=d, hb=hb, scale=scale, causal=causal, window=window,
         block_q=block_q, block_k=block_k, rope=rope_static,
-        interpret=interpret)
+        interpret=interpret, bd=bd)
     out = out.reshape(rows, t_pad, hd)
     return (out if t_pad == t else out[:, :t]), lse
 
 
 def _flash_bwd_dkdv_kernel(q_ref, g_ref, k_ref, v_ref, lse_ref, delta_ref,
                            *refs, scale, causal, window, block_q, block_k,
-                           t_pad, d, hb, rope=None):
+                           t_pad, d, hb, rope=None, bd=None):
     """One k-block's dK/dV: stream q-blocks past it, starting at the
     causal frontier (q blocks strictly before this k block contribute
     nothing — the same 2x FLOP skip the forward kernel does) and, under a
@@ -502,7 +610,10 @@ def _flash_bwd_dkdv_kernel(q_ref, g_ref, k_ref, v_ref, lse_ref, delta_ref,
     `rope` (dr, hr), the latent form: the head's rotary queries pinned
     beside q and dO, the k block's rows of the shared rotary key, and a
     third result, this head's float32 share of that key's gradient (the
-    heads' shares are summed after the kernel, as a group's are)."""
+    heads' shares are summed after the kernel, as a group's are).
+
+    `bd`, the block-diffusion mask: the q blocks are `_bd_blocks`'s two
+    ranges transposed, the forward loop's mirror."""
     if rope:
         qr_ref, kr_ref, len_ref, dk_ref, dv_ref, dkr_ref = refs
         rlanes = _rope_lanes(qr_ref.shape[1], *rope)
@@ -525,6 +636,10 @@ def _flash_bwd_dkdv_kernel(q_ref, g_ref, k_ref, v_ref, lse_ref, delta_ref,
         # the block's newest key, (kb + 1) * block_k - 1, is seen last by
         # the query window - 1 after it
         nq = jnp.minimum(nq, ((kb + 1) * block_k + window - 2) // block_q + 1)
+    ranges = ((qb0, nq),)
+    if bd:
+        ranges = _bd_blocks(kb, block_k, block_q, bd, transposed=True)
+        kcode = _bd_key_code(kpos, bd)
 
     def step(qb):
         """This q block's terms of (dk, dv)."""
@@ -533,10 +648,11 @@ def _flash_bwd_dkdv_kernel(q_ref, g_ref, k_ref, v_ref, lse_ref, delta_ref,
         lse = lse_ref[0, qb]                                 # [1, bq] f32
         delta = delta_ref[0, qb]
         valid = kpos < kv_len
-        if causal or window is not None:
+        if causal or window is not None or bd:
             qpos = qb * block_q + lax.broadcasted_iota(
                 jnp.int32, (1, block_q), 1)
-            valid = _visible(valid, qpos, kpos, causal, window)
+            valid = _bd_visible(valid, _bd_query_codes(qpos, bd), kcode) \
+                if bd else _visible(valid, qpos, kpos, causal, window)
         if rope:
             qr = _only(rlanes, qr_ref[pl.ds(qb * block_q, block_q), :])
             st = (_dot(k, q, _NT) + _dot(kr, qr, _NT)) * scale
@@ -552,7 +668,7 @@ def _flash_bwd_dkdv_kernel(q_ref, g_ref, k_ref, v_ref, lse_ref, delta_ref,
     if rope:
         def body(qb, carry):
             return tuple(a + b for a, b in zip(carry, step(qb)))
-        dk, dv, dkr = lax.fori_loop(qb0, nq, body, (
+        dk, dv, dkr = _loops(ranges, body, (
             zeros, zeros, jnp.zeros(kr.shape, jnp.float32)))
         dk_ref[...] = (dk * scale).astype(dk_ref.dtype)
         dv_ref[...] = dv.astype(dv_ref.dtype)
@@ -568,20 +684,20 @@ def _flash_bwd_dkdv_kernel(q_ref, g_ref, k_ref, v_ref, lse_ref, delta_ref,
             dk_ref[...] += dk
             dv_ref[...] += dv
             return carry
-        lax.fori_loop(qb0, nq, body, 0)
+        _loops(ranges, body, 0)
         dk_ref[...] *= scale
     else:
         def body(qb, carry):
             dk, dv = step(qb)
             return carry[0] + dk, carry[1] + dv
-        dk, dv = lax.fori_loop(qb0, nq, body, (zeros, zeros))
+        dk, dv = _loops(ranges, body, (zeros, zeros))
         _put(lanes, dk_ref, (dk * scale).astype(dk_ref.dtype))
         _put(lanes, dv_ref, dv.astype(dv_ref.dtype))
 
 
 def _flash_bwd_dq_kernel(q_ref, g_ref, o_ref, k_ref, v_ref, lse_ref,
                          *refs, scale, causal, window, block_q, block_k,
-                         t_pad, d, hb, rope=None):
+                         t_pad, d, hb, rope=None, bd=None):
     """One q-block's dQ: stream the k-blocks between the window's edge and
     the causal / key-length frontier (mirror of the forward loop). Before
     the loop, delta = rowsum(dO * O) of the block's own rows, from the dO
@@ -593,7 +709,10 @@ def _flash_bwd_dq_kernel(q_ref, g_ref, o_ref, k_ref, v_ref, lse_ref,
     shared rotary key, pinned, and a third result, the rotary queries'
     gradient, the head's lanes of a block whose other lanes are zero: the
     hr heads of a block write hr blocks (their sum is taken after the
-    kernel), since the grid meets them q blocks apart."""
+    kernel), since the grid meets them q blocks apart.
+
+    `bd`, the block-diffusion mask: the forward kernel's two ranges and
+    its tile mask."""
     if rope:
         qr_ref, kr_ref, len_ref, dq_ref, delta_ref, dqr_ref = refs
         rlanes = _rope_lanes(qr_ref.shape[1], *rope)
@@ -611,13 +730,15 @@ def _flash_bwd_dq_kernel(q_ref, g_ref, o_ref, k_ref, v_ref, lse_ref,
     delta_ref[0, 0] = _as_row(delta)
     qpos = qb * block_q + lax.broadcasted_iota(jnp.int32, (bq, 1), 0)
     kv_len = len_ref[pl.program_id(0), 0]
+    qcodes = _bd_query_codes(qpos, bd) if bd else None
 
     def body(kb, dq):
         k = k_ref[pl.ds(kb * block_k, block_k), :]
         v = v_ref[pl.ds(kb * block_k, block_k), :]
         kpos = kb * block_k + lax.broadcasted_iota(
             jnp.int32, (1, block_k), 1)
-        valid = _visible(kpos < kv_len, qpos, kpos, causal, window)
+        valid = _bd_visible(kpos < kv_len, qcodes, _bd_key_code(kpos, bd)) \
+            if bd else _visible(kpos < kv_len, qpos, kpos, causal, window)
         if rope:
             kr = kr_ref[pl.ds(kb * block_k, block_k), :]
             s = (_dot(q, k, _NT) + _dot(qr, kr, _NT)) * scale
@@ -631,8 +752,9 @@ def _flash_bwd_dq_kernel(q_ref, g_ref, o_ref, k_ref, v_ref, lse_ref,
         return dq + _dot(ds.astype(k.dtype), k)
 
     zeros = jnp.zeros((bq, w), jnp.float32)
-    dq = lax.fori_loop(
-        *_k_blocks(qb, kv_len, causal, window, block_q, block_k, t_pad), body,
+    dq = _loops(
+        _bd_blocks(qb, block_q, block_k, bd) if bd else (_k_blocks(
+            qb, kv_len, causal, window, block_q, block_k, t_pad),), body,
         (zeros, jnp.zeros(qr.shape, jnp.float32)) if rope else zeros)
     if rope:
         dq, dqr = dq
@@ -649,7 +771,7 @@ def _rope_member(hr):
 @kernel_entry("ptpu_flash_bwd_dq", static_argnames=_FLASH_STATIC)
 def _flash_bwd_dq_call(q, g, out, k, v, lse, rope_args, lens, *, rows, d, hb,
                        scale, causal, window, block_q, block_k, rope,
-                       interpret):
+                       interpret, bd=None):
     """The dQ pallas_call on `_flash_bwd`'s operands as it lays them out
     (`_flash_fwd_call`'s, dO and O as q) -> (dq as q, delta as lse, and
     under the latent form the rotary queries' gradient in hr slabs)."""
@@ -657,7 +779,8 @@ def _flash_bwd_dq_call(q, g, out, k, v, lse, rope_args, lens, *, rows, d, hb,
     t_pad, heads, w, kv_b, kv_h = _flash_static(q, k, rows, d, hb)
     nq = t_pad // block_q
     static = dict(scale=scale, causal=causal, window=window,
-                  block_q=block_q, block_k=block_k, t_pad=t_pad, d=d, hb=hb)
+                  block_q=block_q, block_k=block_k, t_pad=t_pad, d=d, hb=hb,
+                  bd=bd)
     rope_specs, rope_out, rope_shape = [], [], []
     if rope is not None:
         static["rope"] = rope
@@ -707,7 +830,7 @@ def _flash_bwd_dq_call(q, g, out, k, v, lse, rope_args, lens, *, rows, d, hb,
 @kernel_entry("ptpu_flash_bwd_dkdv", static_argnames=_FLASH_STATIC)
 def _flash_bwd_dkdv_call(q, g, k, v, lse, delta, rope_args, lens, *, rows, d,
                          hb, scale, causal, window, block_q, block_k, rope,
-                         interpret):
+                         interpret, bd=None):
     """The dK/dV pallas_call on `_flash_bwd`'s operands as it lays them
     out -> (dk, dv [rows * group * t_pad, Hkv*D], a query head's share in
     the slab of its place in its group, float32 where there is a group,
@@ -722,7 +845,8 @@ def _flash_bwd_dkdv_call(q, g, k, v, lse, delta, rope_args, lens, *, rows, d,
     # why after and not inside)
     grouped = q.shape != k.shape
     static = dict(scale=scale, causal=causal, window=window,
-                  block_q=block_q, block_k=block_k, t_pad=t_pad, d=d, hb=hb)
+                  block_q=block_q, block_k=block_k, t_pad=t_pad, d=d, hb=hb,
+                  bd=bd)
     rope_specs, rope_out, rope_shape = [], [], []
     if rope is not None:
         static["rope"] = rope
@@ -775,7 +899,7 @@ def _flash_bwd_dkdv_call(q, g, k, v, lse, delta, rope_args, lens, *, rows, d,
 
 
 def _flash_bwd(d, hb, scale, causal, window, block_q, block_k, interpret,
-               res, g, rope=None):
+               res, g, rope=None, bd=None):
     """Flash backward as two pallas kernels (standard flash-attention recompute
     from the saved logsumexp — the [T, T] matrix never exists): a dK/dV
     kernel gridded over k-blocks and a dQ kernel gridded over q-blocks,
@@ -878,7 +1002,7 @@ def _flash_bwd(d, hb, scale, causal, window, block_q, block_k, interpret,
         rope_args, rope_static = _rope_rows(rope, d, hb, pad)
     static = dict(rows=rows, d=d, hb=hb, scale=scale, causal=causal,
                   window=window, block_q=block_q, block_k=block_k,
-                  rope=rope_static, interpret=interpret)
+                  rope=rope_static, interpret=interpret, bd=bd)
     dq, delta, *dq_rope = _flash_bwd_dq_call(
         q, g, out, k, v, lse, rope_args, lens, **static)
     dk, dv, *dk_rope = _flash_bwd_dkdv_call(
@@ -954,11 +1078,11 @@ def _unrows(x, like, hb):
 # XLA ran it tokens-minor behind three float32 relayouts of dO and O, 220
 # MiB a call at [8, 2048, 8, 64]; AOT compile, PR 38). No barrier holds the
 # residuals back: the backward rule's first touch of them is a reshape.
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10, 11))
 def _flash_core(q, k, v, rope, kv_len, scale, causal, window, block_q,
-                block_k, interpret):
+                block_k, interpret, bd=None):
     return _flash_core_fwd(q, k, v, rope, kv_len, scale, causal, window,
-                           block_q, block_k, interpret)[0]
+                           block_q, block_k, interpret, bd)[0]
 
 
 def _flash_layout(q, k, kv_len):
@@ -976,23 +1100,23 @@ def _rope_flat(rope):
 
 
 def _flash_core_fwd(q, k, v, rope, kv_len, scale, causal, window, block_q,
-                    block_k, interpret):
+                    block_k, interpret, bd=None):
     hb, lens = _flash_layout(q, k, kv_len)
     out, lse = _flash_fwd(_rows(q, hb), _rows(k, hb), _rows(v, hb), lens,
                           q.shape[3], hb or 1, scale, causal, window,
-                          block_q, block_k, interpret, _rope_flat(rope))
+                          block_q, block_k, interpret, _rope_flat(rope), bd)
     out = _unrows(out, q, hb)
     return out, (q, k, v, rope, kv_len, out, lse)
 
 
-def _flash_core_bwd(scale, causal, window, block_q, block_k, interpret, res,
-                    g):
+def _flash_core_bwd(scale, causal, window, block_q, block_k, interpret, bd,
+                    res, g):
     q, k, v, rope, kv_len, out, lse = res
     hb, lens = _flash_layout(q, k, kv_len)
     dq, dk, dv, *drope = _flash_bwd(
         q.shape[3], hb or 1, scale, causal, window, block_q, block_k,
         interpret, (_rows(q, hb), _rows(k, hb), _rows(v, hb), lens,
-                    _rows(out, hb), lse), _rows(g, hb), _rope_flat(rope))
+                    _rows(out, hb), lse), _rows(g, hb), _rope_flat(rope), bd)
     drope = tuple(d.reshape(a.shape) for d, a in zip(drope, rope)) \
         if rope is not None else None
     return _unrows(dq, q, hb), _unrows(dk, k, hb), _unrows(dv, v, hb), \
@@ -1027,7 +1151,7 @@ def latent_form(d, dr, dv):
 
 def flash_attention(q, k, v, causal=False, scale=None, kv_len=None,
                     block_q=None, block_k=None, interpret=None, window=None,
-                    q_rope=None, k_rope=None):
+                    q_rope=None, k_rope=None, block_diffusion=None):
     """Exact attention, flash-style. q: [B, T, Hq, D], k, v: [B, T, Hkv, D]
     (BTHD, the layout ring_attention uses); returns [B, T, Hq, D]. block_q /
     block_k default to kernel_config.DEFAULT_TILES["attn"] and are clamped
@@ -1060,6 +1184,20 @@ def flash_attention(q, k, v, causal=False, scale=None, kv_len=None,
     than the window are skipped like those past the causal frontier, in
     all three kernels; a window of T or more changes nothing but the loop
     bounds' arithmetic.
+
+    block_diffusion: None, or two static ints (block_length, L): the T = 2 L
+    rows are two copies of one sequence of L tokens, the noised copy in rows
+    0 .. L - 1 and the clean copy in rows L .. 2 L - 1 (BD3-LM,
+    arXiv:2503.09573), block_length dividing L. Row r is (copy, position i,
+    block b = i // block_length) and sees row s iff: both noised and b_s =
+    b_r (block-diagonal, both directions inside a block); or r noised, s
+    clean and b_s < b_r (offset block-causal); or both clean and b_s <= b_r
+    (block-causal); a clean row never sees a noised one. No key block
+    without a visible pair is streamed, in any of the three kernels: a block
+    of queries meets the two ranges of `_bd_blocks` (its own diagonal on the
+    noised copy, the clean keys up to its frontier), the dK/dV kernel their
+    transpose. With `causal`, a window, kv_len or the latent form it is
+    refused: the mask is all there is.
 
     kv_len: optional [B] int true key lengths — keys at position >= kv_len
     are masked out AND their blocks skipped entirely (the padded-batch
@@ -1117,6 +1255,18 @@ def flash_attention(q, k, v, causal=False, scale=None, kv_len=None,
     if window is not None and int(window) < 1:
         raise ValueError("flash_attention: window must be None or >= 1, got "
                          "%r" % (window,))
+    if block_diffusion is not None:
+        block_diffusion = tuple(int(n) for n in block_diffusion)
+        length, copy = block_diffusion
+        if causal or window is not None or kv_len is not None or latent:
+            raise ValueError(
+                "flash_attention: block_diffusion is the whole mask: causal, "
+                "window, kv_len and the latent form are refused beside it")
+        if length < 1 or copy % length or t != 2 * copy:
+            raise ValueError(
+                "flash_attention: block_diffusion (block_length, L) takes "
+                "rows of two copies of L tokens, T = 2 L, in whole blocks; "
+                "got %r at T = %d" % (block_diffusion, t))
     if scale is None:
         scale = 1.0 / float(np.sqrt(d))
     block_q = max(8, min(_tile("attn", "block_q", block_q),
@@ -1129,7 +1279,8 @@ def flash_attention(q, k, v, causal=False, scale=None, kv_len=None,
         lens = jnp.asarray(kv_len, jnp.int32).reshape(b)
     return _flash_core(q, k, v, rope, lens, float(scale), bool(causal),
                        None if window is None else int(window),
-                       int(block_q), int(block_k), bool(interpret))
+                       int(block_q), int(block_k), bool(interpret),
+                       block_diffusion)
 
 
 # ---------------------------------------------------------------------------

@@ -30,15 +30,33 @@ __all__ = ["ring_attention", "attention_reference", "ring_attention_sharded",
 _NEG_INF = -1e30
 
 
+def block_diffusion_mask(block_length, copy_len):
+    """[2 L, 2 L] bool of the block-diffusion mask (BD3-LM,
+    arXiv:2503.09573): rows 0 .. L - 1 are the noised copy of a sequence of
+    L tokens and rows L .. 2 L - 1 the clean one; row r is (copy, position
+    i, block b = i // block_length) and sees row s iff both are noised and
+    b_s = b_r, or r is noised, s clean and b_s < b_r, or both are clean and
+    b_s <= b_r."""
+    row = jnp.arange(2 * copy_len)
+    noised = row < copy_len
+    block = (row - jnp.where(noised, 0, copy_len)) // block_length
+    qn, kn = noised[:, None], noised[None, :]
+    qb, kb = block[:, None], block[None, :]
+    return (qn & kn & (kb == qb)) | (qn & ~kn & (kb < qb)) \
+        | (~qn & ~kn & (kb <= qb))
+
+
 def attention_reference(q, k, v, causal=False, scale=None, kv_len=None,
-                        window=None):
+                        window=None, block_diffusion=None):
     """Dense single-device attention, q [B,T,Hq,D], k and v [B,T,Hkv,D]. The
     numerical reference the ring path and the flash kernels must match; also
     the fallback when no `sp` axis exists and the path under the flash
     crossover. kv_len: optional [B] true key lengths (key-padding mask).
     Grouped queries from the shapes: query head h reads key/value head
     h // (Hq // Hkv). window: None or an int, query i sees key j only where
-    i - j < window (with `causal`, the `window` newest keys up to itself)."""
+    i - j < window (with `causal`, the `window` newest keys up to itself).
+    block_diffusion: None or (block_length, L): `block_diffusion_mask` over
+    T = 2 L rows."""
     if scale is None:
         scale = 1.0 / np.sqrt(q.shape[-1])
     group = q.shape[2] // k.shape[2]
@@ -56,6 +74,9 @@ def attention_reference(q, k, v, causal=False, scale=None, kv_len=None,
     if window is not None:
         age = jnp.arange(tq)[:, None] - jnp.arange(tk)[None, :]
         logits = jnp.where(age < window, logits, _NEG_INF)
+    if block_diffusion is not None:
+        logits = jnp.where(block_diffusion_mask(*block_diffusion), logits,
+                           _NEG_INF)
     if kv_len is not None:
         # accept [B] or the fluid-convention [B, 1] (the flash kernel
         # normalizes the same way; a [B, 1] here would silently

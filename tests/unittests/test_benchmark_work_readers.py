@@ -49,6 +49,9 @@ NEMOTRON_METRICS = ("latent_moe_layer_share", "single_branch_layer_share")
 LAGUNA = "laguna_s_2_1_train_t4096"
 # the two metrics PR 64 added with its cell
 LAGUNA_METRICS = ("windowed_layer_share", "per_head_gate_layer_share")
+SDAR = "sdar_30b_a3b_chat_train_t4096"
+# the two metrics PR 66 added with its cell
+SDAR_METRICS = ("block_diffusion_layer_share", "ffn_rows_per_token")
 
 
 def test_glm_flash_operations_against_the_hand_count():
@@ -117,8 +120,9 @@ def test_the_pinned_manifest_test_is_red_for_the_eleventh_cell_alone(
     metrics, and the thirteenth (granite-4.0-h-micro's), its configuration,
     its traffic's cell and its three metrics, and the fourteenth
     (Nemotron-3-Super's), its configuration and its two metrics, and the
-    fifteenth (Laguna-S-2.1's), its configuration and its two metrics:
-    nothing else it holds has moved."""
+    fifteenth (Laguna-S-2.1's), its configuration and its two metrics, and
+    the sixteenth (SDAR-30B-A3B-Chat's), its configuration and its two
+    metrics: nothing else it holds has moved."""
     pinned = getattr(readers, PINNED)
     with pytest.raises(AssertionError, match="^expert_matmul_ms_per_step$"):
         pinned()
@@ -126,19 +130,21 @@ def test_the_pinned_manifest_test_is_red_for_the_eleventh_cell_alone(
         bench = json.load(f)
     bench["workloads"] = [w for w in bench["workloads"]
                           if w["name"] not in (GLM, PHI, GRANITE, NEMOTRON,
-                                               LAGUNA)]
+                                               LAGUNA, SDAR)]
     bench["configs"] = [c for c in bench["configs"]
                         if c["name"] not in ("glm_4_7_flash",
                                              "phi4_mini_flash",
                                              "granite_4_0_h_micro",
                                              "nemotron_3_super_120b_a12b",
-                                             "laguna_s_2_1")]
+                                             "laguna_s_2_1",
+                                             "sdar_30b_a3b_chat")]
     bench["per_layer"] = [m for m in bench["per_layer"]
                           if m["name"] not in ("mtp_layer_share",)
                           + PHI_METRICS + GRANITE_METRICS
-                          + NEMOTRON_METRICS + LAGUNA_METRICS]
+                          + NEMOTRON_METRICS + LAGUNA_METRICS
+                          + SDAR_METRICS]
     for metric in bench["end_to_end"] + bench["per_layer"]:
-        for cell in (GLM, PHI, GRANITE, NEMOTRON, LAGUNA):
+        for cell in (GLM, PHI, GRANITE, NEMOTRON, LAGUNA, SDAR):
             if cell in metric.get("workloads", ()):
                 metric["workloads"].remove(cell)
     without = tmp_path / "BENCHMARK.json"
@@ -195,8 +201,8 @@ def test_the_manifest_lists_the_work_readers_in_eleven_cells():
     # the 8192 rows in VMEM)
     for name in ("flash_roofline_share", "embedding_grad_ms_per_step",
                  "embedding_grad_roofline_share", "step_mfu"):
-        assert entries[name]["workloads"][-4:] == [PHI, GRANITE, NEMOTRON,
-                                                   LAGUNA], name
+        assert entries[name]["workloads"][-5:] == [PHI, GRANITE, NEMOTRON,
+                                                   LAGUNA, SDAR], name
     phi = readers._cell(PHI)
     pairs = (512 * 513 // 2 + (8192 - 512) * 512) + 2 * (8192 * 8193 // 2)
     assert phi.config_module.flash_kernel_ops(phi.config, phi.traffic) == {
@@ -239,13 +245,13 @@ def test_the_manifest_lists_the_work_readers_in_eleven_cells():
     # one group, five layers; and TWO matmuls an expert of [1024 x 2688]
     # (configs/causal_lm.py's three would read 1.5 times the work)
     for name in ("expert_matmul_ms_per_step", "expert_matmul_roofline_share"):
-        assert entries[name]["workloads"][-3:] == [GLM, NEMOTRON,
-                                                   LAGUNA], name
+        assert entries[name]["workloads"][-4:] == [GLM, NEMOTRON, LAGUNA,
+                                                   SDAR], name
     for name in ("pallas_ms_per_step", "softmax_xent_ms_per_step",
                  "flash_fwd_ms_per_step", "flash_bwd_dkdv_ms_per_step",
                  "flash_bwd_dq_ms_per_step"):
-        assert entries[name]["workloads"][-3:] == [GRANITE, NEMOTRON,
-                                                   LAGUNA], name
+        assert entries[name]["workloads"][-4:] == [GRANITE, NEMOTRON,
+                                                   LAGUNA, SDAR], name
     nemotron = readers._cell(NEMOTRON)
     mod = nemotron.config_module
     pairs = 4096 * 4097 // 2
@@ -298,6 +304,36 @@ def test_the_manifest_lists_the_work_readers_in_eleven_cells():
             if m["name"] in LAGUNA_METRICS] == [[LAGUNA]] * 2
     assert sum(mod.forward_macs(laguna.config, laguna.traffic)
                .values()) == pytest.approx(331.6e6, rel=1e-3)
+    # the sixteenth cell behind that one on the same lists, with its
+    # module's hand counts: every layer's 32 heads of 128 over the visible
+    # pairs of BOTH copies under the block-diffusion mask (T^2 + 4 T a head
+    # at blocks of 4); the table of 18992 words of 2048 written and the
+    # 8192 rows of both copies' ONE lookup read; three
+    # matmuls an expert of [2048 x 768]; two rows a token in the trunk, one
+    # behind the last layer's core
+    sdar = readers._cell(SDAR)
+    mod = sdar.config_module
+    pairs = 4 * 32 * (4096 * 4096 + 4 * 4096)
+    assert pairs == 2149580800
+    assert mod.flash_kernel_ops(sdar.config, sdar.traffic) == {
+        "ptpu_flash_fwd": 4 * 128 * pairs,
+        "ptpu_flash_bwd_dkdv": 8 * 128 * pairs,
+        "ptpu_flash_bwd_dq": 6 * 128 * pairs}
+    assert mod.embedding_grad_bytes(sdar.config, sdar.traffic) \
+        == 4 * 2048 * (18992 + 8192) == 222691328
+    assert mod.embedding_grad_bytes is not mod.base.embedding_grad_bytes
+    even = np.zeros(128, np.int64)
+    even[:16] = 3 * 512 + 256       # 8 x 8192 / 128 rows a held expert
+    assert mod.expert_matmul_ops(sdar.config, sdar.traffic, even) \
+        == 3 * 3 * 2 * 2048 * 768 * 28672
+    assert [m["workloads"] for m in bench["per_layer"]
+            if m["name"] in SDAR_METRICS] == [[SDAR]] * 2
+    assert sum(mod.forward_macs(sdar.config, sdar.traffic)
+               .values()) == pytest.approx(333.9e6, rel=1e-3)
+    assert mod.samples_per_step(sdar.config, sdar.traffic) == 4096
+    # sixteen cells, one of them on four chips: floor(16 x 0.25) = 4
+    assert [w["name"] for w in bench["workloads"]][15] == SDAR
+    assert [w["chips"] for w in bench["workloads"]][:16].count(4) == 1
     # fifteen cells, one of them on four chips: floor(15 x 0.25) = 3
     assert [w["name"] for w in bench["workloads"]][14] == LAGUNA
     assert [w["chips"] for w in bench["workloads"]][:15].count(4) == 1
