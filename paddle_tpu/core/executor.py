@@ -940,6 +940,10 @@ class Executor(object):
             lowering.count_donated_buffers(
                 state_rw, [scope.get(n) for n in state_rw], state_out,
                 new_state, (fetches, errors))
+            # and what lets the profiler ask jax for this executable again
+            # without a compile (profiler.step_op_names), beside the entry
+            _note_compiled_step(self, key, feed_arrays, feed_names, scope,
+                                seed)
         # sentinel stat tap: peel float statistics (grad norm) off the
         # error dict before any error sync; values stay device-resident
         self.last_stats = pop_guard_stats(errors)
@@ -1004,6 +1008,31 @@ class Executor(object):
         return [FetchHandle(f) for f in fetches]
 
 
+
+
+def _note_compiled_step(exe, key, feed_arrays, feed_names, scope, seed):
+    """Once a compile: describe to the profiler the arguments `_call` just
+    gave the step `exe` holds under `key` (nothing where it holds none:
+    use_program_cache=False). The scope still holds the donated arrays,
+    and types are enough; `_call` committed the state where a feed was."""
+    held = exe._cache.get(key)
+    if held is None:
+        return
+    from .. import profiler as _prof
+    _, state_rw, state_ro, _ = held
+    dev = exe.place.device()
+    feeds = [feed_arrays[n] for n in feed_names]
+    placed = jax.sharding.SingleDeviceSharding(dev) if any(
+        getattr(f, "committed", False) for f in feeds) else None
+
+    def seen(vals, otherwise=None):     # a committed array stays put
+        return [v.sharding if getattr(v, "committed", False) else otherwise
+                for v in vals]
+    rw = [scope.get(n) for n in state_rw]
+    ro = [scope.get(n) for n in state_ro]
+    _prof.note_step(held[0], "exe", (feeds, rw, ro, seed),
+                    (seen(feeds), seen(rw, placed), seen(ro, placed)),
+                    device=dev)
 
 
 def _commit(vals, device):

@@ -628,3 +628,14 @@ RMSProp = RMSPropOptimizer
 Ftrl = FtrlOptimizer
 ProximalGD = ProximalGDOptimizer
 ProximalAdagrad = ProximalAdagradOptimizer
+
+
+# the op types an optimizer's `_append_optimize_op` appends, the update
+# itself: what device time by fluid op reads as "the optimizer"
+# (benchmark/layer_metrics/optimizer_ms_per_step.py). The generic `scale`,
+# `increment` and `elementwise_*` ops of the schedule, of clipping and of
+# regularization are other ops' types too and are not in it.
+UPDATE_OP_TYPES = ("sgd", "momentum", "adagrad", "adam",
+                   "adam_beta_pow_update", "adamax", "decayed_adagrad",
+                   "adadelta", "rmsprop", "ftrl", "proximal_gd",
+                   "proximal_adagrad")

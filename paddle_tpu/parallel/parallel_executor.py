@@ -516,6 +516,17 @@ class ParallelExecutor(object):
             lowering.count_donated_buffers(
                 state_rw, [scope.get(n) for n in state_rw], state_out,
                 new_state, (fetches, errors))
+            # and what lets the profiler ask jax for this executable again
+            # without a compile (profiler.step_op_names): the call's
+            # arguments lay as the plan says (read_state saw to it)
+            _prof.note_step(
+                self._cache[key][0], "pexe", (
+                    feed_vals, [scope.get(n) for n in state_rw],
+                    [scope.get(n) for n in state_ro], seed), (
+                    [v.sharding for v in feed_vals],
+                    [self._state_sharding(n) for n in state_rw],
+                    [self._state_sharding(n) for n in state_ro]),
+                device=self._device0)
         # sentinel stat tap: peel float statistics (grad norm) off the
         # error dict before any error sync (see Executor._run_impl)
         from ..core.executor import pop_guard_stats

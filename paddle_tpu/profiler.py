@@ -19,6 +19,15 @@ by `sorted_key` in {calls,total,max,min,ave}). Two tables take its place:
               scope. Off a TPU the trace has no device plane and the table
               is empty.
 
+The same table without a trace file (PR 69): an executor describes, once a
+compile, the arguments its step was compiled for (`note_step`); asked
+(`step_op_names`), jax hands the loaded executable back for them without a
+compile and its text says each instruction's op_name, so
+`device_seconds_by_op` reduces {instruction: self seconds}, what the
+benchmark's trace reduction keeps, through the same row key and maker. It
+costs nothing until it is asked for. `--by scope` (or `by="scope"`) names a
+row by the whole path of scopes: what a layer's time is made of.
+
 Two smaller blocks: `profile_report` ends with the host seconds the lowering
 rules took under jax's trace, by fluid op type (core/lowering.lower_op), and
 `python -m paddle_tpu.profiler <trace dir>` with the device's idle gaps by
@@ -33,16 +42,19 @@ import os
 import re
 import threading
 import time
+import weakref
 
 import jax
 
-from .core.lowering import parse_op_scope, parse_pass_scope
+from .core.lowering import (PASS_MARK, SCOPE_MARK, parse_op_scope,
+                            parse_pass_scope)
 from .observability.trace import ANNOTATION_PREFIX
 
 __all__ = ["profiler", "start_profiler", "stop_profiler", "reset_profiler",
            "profile_report", "record_event", "cache_stats", "note_sync",
            "sync_stats", "dispatch_path", "record_idle", "snapshot",
            "device_op_table", "device_op_table_from", "read_op_names",
+           "note_step", "step_op_names", "device_seconds_by_op",
            "render_device_ops", "idle_gaps_by_span", "render_idle_gaps"]
 
 _active = False
@@ -302,6 +314,12 @@ def profile_report(sorted_key=None, json=False):
         lines.extend(_softmax_xent_lines())
         lines.extend(_recompute_lines())
         lines.extend(_kernel_trace_lines())
+        lines.append(
+            "device time by fluid op: python -m paddle_tpu.profiler <trace "
+            "dir> [--by type|instance|scope] on a kept trace (scope: the "
+            "whole path of scopes under a fluid op); with no trace file, "
+            "profiler.device_seconds_by_op({instruction: seconds}) over the "
+            "compiled steps' own names (profiler.step_op_names)")
         lines.extend(_restart_tables())
     return "\n".join(lines)
 
@@ -567,8 +585,9 @@ def device_op_table(planes, op_names, by="type"):
     reads it from the same bytes.
 
     Returns {"planes": n, "busy_self_ms", "scoped_ms", "rows": [...]}: one
-    row a fluid op type (`by="type"`) or a fluid op instance
-    (`by="instance"`: type/first output variable), the named Pallas kernels
+    row a fluid op type (`by="type"`), a fluid op instance
+    (`by="instance"`: type/first output variable) or a whole path of scopes
+    (`by="scope"`: `scope_path`), the named Pallas kernels
     and the collectives of a fluid op in rows of their own (`kernel`: the
     kernel's name, or `all-reduce` and the like), and every operation that
     carries no fluid scope under its own instruction name (`scoped` False),
@@ -578,8 +597,7 @@ def device_op_table(planes, op_names, by="type"):
     fluid op, the optimizer's among them; -: no scope), "scoped", "events",
     "total_ms", "max_ms", "min_ms", "ave_ms", "share" (% of busy_self_ms)}.
     """
-    if by not in ("type", "instance"):
-        raise ValueError("by must be 'type' or 'instance', got %r" % (by,))
+    _check_by(by)
     device_planes = [p for p in planes if p.name.startswith(_DEVICE_PLANE)]
     rows, keys = {}, {}
     n = 0
@@ -594,11 +612,30 @@ def device_op_table(planes, op_names, by="type"):
             if key is None:     # one look at an operation's text
                 key = keys[e.name] = _row_key(
                     e.name, op_names.get(e.name, ""), by)
-            row = rows.setdefault(key, [0, 0.0, 0.0, float("inf")])
-            row[0] += 1
-            row[1] += self_ns
-            row[2] = max(row[2], self_ns)
-            row[3] = min(row[3], self_ns)
+            _book(rows, key, self_ns / 1e6)
+    return _table(rows, n)
+
+
+_BY = ("type", "instance", "scope")
+
+
+def _check_by(by):
+    if by not in _BY:
+        raise ValueError("by must be one of %s, got %r" % (list(_BY), by))
+
+
+def _book(rows, key, ms):
+    row = rows.setdefault(key, [0, 0.0, 0.0, float("inf")])
+    row[0] += 1
+    row[1] += ms
+    row[2] = max(row[2], ms)
+    row[3] = min(row[3], ms)
+
+
+def _table(rows, n):
+    """The table of `device_op_table` from {row key: [events, ms over all
+    planes, max, min]} booked over `n` device planes: the one maker of both
+    reductions' tables (the trace's `tf_op`, the compiled step's map)."""
     busy = sum(r[1] for r in rows.values())
     out = []
     for (name, kernel, scoped), (count, total, mx, mn) in rows.items():
@@ -606,12 +643,11 @@ def device_op_table(planes, op_names, by="type"):
             "name": name, "kernel": kernel, "scoped": scoped,
             "pass": "-" if not scoped else
                     "bwd" if name.split("/")[0].endswith("_grad") else "fwd",
-            "events": count, "total_ms": total / 1e6 / n,
-            "max_ms": mx / 1e6, "min_ms": mn / 1e6,
-            "ave_ms": total / 1e6 / count,
+            "events": count, "total_ms": total / max(n, 1),
+            "max_ms": mx, "min_ms": mn, "ave_ms": total / count,
             "share": 100.0 * total / busy if busy else 0.0})
     out.sort(key=lambda r: -r["total_ms"])
-    return {"planes": n, "busy_self_ms": busy / 1e6 / max(n, 1),
+    return {"planes": n, "busy_self_ms": busy / max(n, 1),
             "scoped_ms": sum(
                 r["total_ms"] for r in out if r["scoped"] or
                 r["kernel"] and not r["kernel"].startswith(_COLLECTIVES)),
@@ -715,16 +751,29 @@ def render_pass_table(table):
 def _row_key(text, op_name, by):
     """(row name, kernel or collective name or '', carries a fluid scope)
     of one device operation from its whole HLO instruction text and its
-    op_name. GSPMD gives a collective the op_name of the fluid op whose
-    values it reduces, so it is set apart as a kernel is."""
-    instruction = text.split(" = ", 1)[0].lstrip("%")
+    op_name."""
+    return _op_key(text.split(" = ", 1)[0].lstrip("%"), _MOSAIC in text,
+                   op_name, by)
+
+
+def _op_key(instruction, mosaic, op_name, by):
+    """The row key of one HLO instruction, for every table by fluid op: the
+    trace's (`_row_key`) and the compiled step's (`device_seconds_by_op`).
+    GSPMD gives a collective the op_name of the fluid op whose values it
+    reduces, so it is set apart as a kernel is. `by="scope"` names a row by
+    the whole path of scopes the instruction was lowered under, fluid ops
+    by type and jax's own scopes and primitive as they are
+    ("rnn_scan/while/body/mul/dot_general": a loop op's own row is a prefix
+    of its body's)."""
     base, _, n = instruction.rpartition(".")
     if not n.isdigit():
         base = instruction
-    kernel = base if _MOSAIC in text or base.startswith(_COLLECTIVES) else ""
+    kernel = base if mosaic or base.startswith(_COLLECTIVES) else ""
     scope = parse_op_scope(op_name)
     if scope is None:
         return (instruction if by == "instance" else base), kernel, False
+    if by == "scope":
+        return scope_path(op_name), kernel, True
     return ("/".join(scope) if by == "instance" else scope[0]), kernel, True
 
 
@@ -746,6 +795,193 @@ def device_op_table_from(trace_dir, by="type"):
     there); an empty table where there is none."""
     planes, op_names = _read_trace(trace_dir)
     return device_op_table(planes, op_names, by)
+
+
+# --- the compiled step's own names: HLO instruction -> op_name --------------
+# What an executor compiled, kept weakly: an entry goes when the executor
+# drops the executable (its LRU, its own end).
+_steps = weakref.WeakKeyDictionary()    # executable -> _Step
+_SCOPE_IN_PATH = re.compile(
+    r"(?:%s\d+(?:-\d+)?/)?%s([^/()]+)/[^/()]+" % (PASS_MARK, SCOPE_MARK))
+_HLO_INSTRUCTION = re.compile(r"\s*(?:ROOT\s+)?%?([\w.\-]+) = ")
+_HLO_OP_NAME = re.compile(r'op_name="([^"]*)"')
+
+
+def scope_path(op_name):
+    """The whole path of scopes of an HLO op_name, as `--by scope` names a
+    row: the jitted function's own name off the front, every fluid op by
+    its type alone (no pass, no instance: the layers of a stack add up),
+    jax's scopes and the primitive as they are. Of the paths XLA joined
+    with ";" for a merged instruction, the last, as `parse_op_scope`; a
+    trace's `tf_op` ends in ":", the compiled text's op_name does not."""
+    path = _SCOPE_IN_PATH.sub(r"\1", op_name.rpartition(";")[2].rstrip(":"))
+    head, _, rest = path.partition("/")
+    return rest if rest and head.startswith(("jit(", "pjit(")) else path
+
+
+class _Step(object):
+    __slots__ = ("label", "args", "device", "found")
+
+    def __init__(self, label, args, device):
+        self.label, self.args, self.device = label, args, device
+        self.found = None       # step_op_names' entry, once asked for
+
+
+def note_step(executable, label, args=None, shardings=None, device=None):
+    """An executor's hook, called once a compile and never on the warm
+    path: `executable` is what its cache now holds for a step (a jitted
+    function, or the `jax.stages.Compiled` of an AOT branch, which needs no
+    `args`), and `args` = (feeds, state_rw, state_ro, seed) the arguments
+    of the call that compiled it, of which only the description is kept:
+    shape, dtype, weak type and, from `shardings` (the same lists, a
+    sharding or None each, None for the seed), where the call saw the
+    argument committed. jax keys its trace by the mesh of an argument's
+    sharding and its lowering by which arguments were committed and where,
+    so a description that differs in either misses. `device`: the
+    `jax.default_device` of the call. Donated arrays are fine: a deleted
+    array still says its type. Nothing is lowered or read here;
+    `step_op_names` does that when asked."""
+    def describe(v, sharding):
+        t = jax.typeof(v)
+        return jax.ShapeDtypeStruct(t.shape, t.dtype, sharding=sharding,
+                                    weak_type=t.weak_type)
+    if args is not None:
+        args = tuple([describe(v, sh) for v, sh in zip(vals, shs)]
+                     for vals, shs in zip(args[:3], shardings)) \
+            + (describe(args[3], None),)
+    _steps[executable] = _Step(label, args, device)
+
+
+class _WouldRetrace(Exception):
+    """jax missed its trace of a step and began to run the program's rules
+    again."""
+
+
+@contextlib.contextmanager
+def _rules_may_not_run():
+    """While jax is asked for a step's lowering again, a miss of its trace
+    cache must not run the program's lowering rules a second time (seconds,
+    and every `ptpu_*_layers_total` booked twice): `lower_block`, which the
+    step's function calls before any rule, raises on this thread."""
+    from .core import lowering
+    real, asking = lowering.lower_block, threading.get_ident()
+
+    def lower_block(*args, **kwargs):
+        if threading.get_ident() == asking:
+            raise _WouldRetrace()
+        return real(*args, **kwargs)
+    lowering.lower_block = lower_block
+    try:
+        yield
+    finally:
+        lowering.lower_block = real
+
+
+def _compiled_text(step, executable):
+    """(the compiled module's text, None) or (None, why not) for one noted
+    step, without a compile: a `Compiled` has it; a jitted function is asked
+    to lower the arguments it was compiled for, which jax answers from its
+    caches (the jaxpr it traced, the lowering the call made, that
+    lowering's loaded executable) when they are described as the call saw
+    them. Where a described argument misses, a new trace stops before the
+    first rule (`_rules_may_not_run`) and a new lowering (it holds no
+    executable yet) is dropped and never compiled: a second executable
+    would cost seconds and the chip's memory."""
+    if isinstance(executable, jax.stages.Compiled):
+        return executable.as_text(), None
+    if step.args is None:
+        return None, "no arguments were kept for it"
+    try:
+        with jax.default_device(step.device), _rules_may_not_run():
+            lowered = executable.lower(*step.args)
+    except _WouldRetrace:
+        return None, ("jax traced it anew: the described arguments miss the "
+                      "call's")
+    # the call's own lowering holds the executable the call loaded; one made
+    # just now holds none
+    if getattr(getattr(lowered, "_lowering", None), "_executable",
+               None) is None:
+        return None, ("jax lowered it anew: the described arguments miss "
+                      "the call's")
+    return lowered.compile().as_text(), None
+
+
+def parse_hlo_op_names(text):
+    """(module name, {HLO instruction name: (op_name, is a Mosaic call)}) of
+    a compiled module's text (`Compiled.as_text()`), every computation
+    included: the bodies of `while` and `conditional`, whose instructions
+    the trace shows as children, and the fusions' own (a fusion carries its
+    root's op_name). An instruction without metadata has op_name ''."""
+    module, names = "", {}
+    for line in text.splitlines():
+        m = _HLO_INSTRUCTION.match(line)
+        if m is None:
+            if not module and line.startswith("HloModule "):
+                module = line.split()[1].rstrip(",")
+            continue
+        found = _HLO_OP_NAME.search(line, m.end())
+        names[m.group(1)] = (found.group(1) if found else "",
+                             _MOSAIC in line)
+    return module, names
+
+
+def _names_of(executable, step):
+    """`step_op_names`' entry of one noted step, made once and kept."""
+    if step.found is None:
+        t0 = time.perf_counter()
+        try:
+            text, why = _compiled_text(step, executable)
+        except Exception as e:  # noqa: BLE001 — a table, never a crash
+            text, why = None, "%s: %s" % (type(e).__name__, e)
+        if text is None:
+            step.found = {"label": step.label, "left_out": why}
+        else:
+            module, names = parse_hlo_op_names(text)
+            step.found = {"label": step.label, "module": module,
+                          "op_names": names,
+                          "seconds": time.perf_counter() - t0}
+    return step.found
+
+
+def step_op_names():
+    """What each step executable the process's executors hold says of its
+    own instructions, in the order they were compiled: [{"label" (exe,
+    pexe), "module" (the HLO module's name), "op_names": {instruction:
+    (op_name, is a Mosaic call)}, "seconds" (what reading it cost)}], and
+    {"label", "left_out": why} for one that cannot say it without a
+    compile. Made when asked, from `note_step`'s descriptions, and kept;
+    before a step has run, []. It never compiles: see `_compiled_text`."""
+    return [_names_of(executable, step)
+            for executable, step in list(_steps.items())]
+
+
+def device_seconds_by_op(seconds_by_instruction, by="type"):
+    """`device_op_table`'s table from {HLO instruction name: self seconds}
+    (a trace's device operations as benchmark/trace_reduce.py's `top_ops`
+    names them) and the compiled step's own map, with no `.xplane.pb` at
+    hand: the same row key (`_op_key`), the same maker (`_table`), so the
+    two tables of one trace are one table (`events` counts instructions
+    here, and max / min / ave are an instruction's). Plus "step", the entry
+    of `step_op_names` it read.
+
+    The step is the one whose instructions ARE the trace's: every name
+    given has to be in ONE map (the startup program's module has a
+    `fusion.1` of its own: maps are never merged). The newest step is asked
+    first, so a trace of what ran last reads no older step's text. None
+    where no held step has them all, or none says its names."""
+    _check_by(by)
+    for executable, step in reversed(list(_steps.items())):
+        found = _names_of(executable, step)
+        names = found.get("op_names")
+        if names and all(i in names for i in seconds_by_instruction):
+            break
+    else:
+        return None
+    rows = {}
+    for instruction, seconds in seconds_by_instruction.items():
+        op_name, mosaic = names[instruction]
+        _book(rows, _op_key(instruction, mosaic, op_name, by), 1e3 * seconds)
+    return dict(_table(rows, 1), step=found)
 
 
 # --- the device's idle gaps, by what the program was doing -----------------
@@ -873,18 +1109,21 @@ def render_device_ops(table, sorted_key=None, limit=None):
     rows = sorted(table["rows"],
                   key=lambda r: -r[_DEVICE_SORT[sorted_key or "total"]])
     busy = table["busy_self_ms"]
-    lines = ["%-44s %-22s %4s %8s %11s %9s %9s %9s %7s" % (
+    # a row by scope is a path: as wide as the longest, within reason
+    width = max(44, min(100, max(len(r["name"]) for r in rows)))
+    name = "%%-%ds" % width
+    lines = [(name + " %-22s %4s %8s %11s %9s %9s %9s %7s") % (
         "Device op (fluid type, else instruction)", "Kernel/collective",
         "Pass",
         "Events", "Total(ms)", "Max(ms)", "Min(ms)", "Ave(ms)", "Busy%")]
     for r in rows[:limit]:
-        lines.append("%-44s %-22s %4s %8d %11.3f %9.4f %9.4f %9.4f %7.2f" % (
-            r["name"][:44], r["kernel"][:22] or "-", r["pass"], r["events"],
-            r["total_ms"], r["max_ms"], r["min_ms"], r["ave_ms"],
-            r["share"]))
+        lines.append((name + " %-22s %4s %8d %11.3f %9.4f %9.4f %9.4f %7.2f")
+                     % (r["name"][:width], r["kernel"][:22] or "-", r["pass"],
+                        r["events"], r["total_ms"], r["max_ms"], r["min_ms"],
+                        r["ave_ms"], r["share"]))
     if limit is not None and len(rows) > limit:
         rest = rows[limit:]
-        lines.append("%-44s %-22s %4s %8d %11.3f %9s %9s %9s %7.2f" % (
+        lines.append((name + " %-22s %4s %8d %11.3f %9s %9s %9s %7.2f") % (
             "(%d more rows)" % len(rest), "", "",
             sum(r["events"] for r in rest),
             sum(r["total_ms"] for r in rest), "", "", "",
@@ -945,8 +1184,9 @@ def cuda_profiler(*args, **kwargs):
 
 def main(argv=None):
     """python -m paddle_tpu.profiler <trace dir>: the device's per-op table
-    of a kept trace, its time by pass where a loop op ran a stack of layers
-    several times, then its idle gaps by program span."""
+    of a kept trace (--by type, instance or scope), its time by pass where a
+    loop op ran a stack of layers several times, then its idle gaps by
+    program span."""
     import argparse
     import json
     ap = argparse.ArgumentParser(
@@ -955,7 +1195,11 @@ def main(argv=None):
                     "profiler(profile_path=...) or benchmark/run.py "
                     "--keep-trace left), or an .xplane.pb file")
     ap.add_argument("--sorted-key", choices=_SORT_KEYS, default=None)
-    ap.add_argument("--by", choices=("type", "instance"), default="type")
+    ap.add_argument("--by", choices=_BY, default="type",
+                    help="a row a fluid op type, a fluid op instance "
+                    "(type/[role.]first output), or a whole path of scopes "
+                    "(fluid ops by type, jax's scopes and the primitive "
+                    "below them: what a layer's time is made of)")
     ap.add_argument("--limit", type=int, default=None,
                     help="print the first LIMIT rows and sum the rest")
     ap.add_argument("--json", action="store_true",

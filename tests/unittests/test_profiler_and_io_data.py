@@ -31,7 +31,8 @@ def test_profiler_records_per_entry_stats(capsys):
     # the training program entry ran 4 times; startup ran once each
     # 11 numeric columns after the (possibly space-containing) tag; the
     # "compile cache:" / "host syncs:" / "embedding:" / "softmax_xent:" /
-    # "recompute:" / "kernel bodies traced" footers are summaries, not rows
+    # "recompute:" / "kernel bodies traced" / "device time by fluid op:"
+    # footers are summaries, not rows
     # (the last four a kind of lookup_table, of softmax_with_cross_entropy,
     # a program that recomputes and a kernel entry that this process has
     # lowered or traced), and the
@@ -42,7 +43,8 @@ def test_profiler_records_per_entry_stats(capsys):
                     if not line.startswith(("compile cache:", "host syncs:",
                                             "embedding:", "softmax_xent:",
                                             "recompute:",
-                                            "kernel bodies traced")))
+                                            "kernel bodies traced",
+                                            "device time by fluid op:")))
     assert counts[-1] == 4, report
     with pytest.raises(ValueError, match="sorted_key"):
         profiler.profile_report(sorted_key="bogus")
