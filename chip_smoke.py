@@ -75,6 +75,14 @@ from a seed, and checks what comes out by the repo's own means:
      form the builder gives the flash kernels (40 heads of 128 on 20 key
      heads: a pair's two maps, queries and keys padded from 64), under the
      window of 512 and full, against the float32 dense two-map form.
+  P  rotary_embedding's one Pallas pass (`ops/rotary_kernels.py`) through
+     Mosaic against the rule's jax.numpy lines through XLA, on this device,
+     at the q and k shapes of the cells that take it (SDAR, OLMoE, Ouro, SmallThinker,
+     Laguna's sliding layers), forward and transpose: EQUAL
+     (`array_equal`, or one unit in the last place of bf16 on at most 1
+     element in 10,000, the count printed) or the phase fails; ms a call of
+     both beside the time the bytes take, and the kernel at the tiles of
+     the sweep.
 
 Every phase that fails makes the exit code non-zero. Timings are printed
 for the next reader, labelled with the device; they are not metrics. The
@@ -191,6 +199,18 @@ FULL = {
     # 2048, 64 heads of 64 on 128 states; (chunk, heads a grid step) swept
     "ssd": dict(b=1, t=2048, h=64, p=64, n=128, tol=3e-2, segment=64,
                 sweep=((128, 8), (128, 16), (128, 32), (256, 16))),
+    # (cell's tensor, B, T, heads, head, base): the whole heads of 128 that
+    # rotary_embedding turns in a cell, q then k
+    "rope": dict(cases=(("SDAR q", 1, 8192, 32, 128, 1e6),
+                        ("SDAR k", 1, 8192, 4, 128, 1e6),
+                        ("OLMoE q and k", 4, 4096, 16, 128, 1e4),
+                        ("Ouro q and k", 1, 4096, 16, 128, 1e6),
+                        ("SmallThinker q", 1, 8192, 7, 128, 1.5e6),
+                        ("SmallThinker k", 1, 8192, 1, 128, 1.5e6),
+                        ("Laguna sliding q", 1, 4096, 18, 128, 1e4),
+                        ("Laguna sliding k", 1, 4096, 2, 128, 1e4),
+                        ("a head of 256", 1, 4096, 4, 256, 1e6)),
+                 sweep=(1 << 19, 1 << 20, 1 << 21)),
     "differential": dict(b=1, t=8192, pairs=20, kv_pairs=10, hd=64,
                          window=512, tol=2e-2),
     "latent_unequal": dict(b=2, t=4096, h=20, d=192, dr=64, dv=256,
@@ -253,6 +273,9 @@ TINY = {
     "latent": dict(t=64, h=2, d=128, dr=64, streams=4, c=128, iters=20,
                    tol=3e-2),
     "selective_scan": dict(b=2, t=72, c=1024, n=4, tol=2e-3),
+    "rope": dict(cases=(("q", 2, 40, 4, 128, 1e6), ("k", 1, 37, 2, 128, 1e4),
+                        ("a head of 256", 1, 24, 2, 256, 1e6)),
+                 sweep=(1 << 14,)),
     "ssd": dict(b=2, t=72, h=4, p=64, n=16, tol=3e-2, segment=8,
                 sweep=((16, 2),)),
     "differential": dict(b=1, t=64, pairs=4, kv_pairs=2, hd=16, window=16,
@@ -2503,6 +2526,106 @@ def phase_o(smoke):
                                  "recurrence" % worst)
 
 
+def phase_p(smoke):
+    """rotary_embedding's rule on its two paths, the Pallas pass (Mosaic on
+    a TPU) and the jax.numpy lines (XLA), on the same bf16 x and dy: y and
+    dx are held EQUAL, element for element, or off by one unit in the last
+    place on at most 1 element in 10,000; then both are timed. x comes and
+    goes as [B*T, H*D] rows, as the projection beside the op writes and
+    reads it."""
+    import types
+    import jax
+    import jax.numpy as jnp
+    import paddle_tpu.ops  # noqa: F401 — registers the rule
+    from paddle_tpu.core import registry
+    from paddle_tpu.ops import kernel_config
+    from paddle_tpu.ops.nn_ops import rotary_path
+
+    c = smoke.cfg["rope"]
+    rule = registry.get("rotary_embedding").lower
+    ctx = types.SimpleNamespace(mesh=None, amp=False)
+    was = os.environ.get("PADDLE_TPU_PALLAS")
+    bf = jnp.bfloat16
+
+    def on_path(path, shape, base):
+        """(forward, forward + transpose) of the rule traced on `path`:
+        rows [B*T, H*D] in, rows out."""
+        def turn(x, pos):
+            os.environ["PADDLE_TPU_PALLAS"] = "rope" if path == "kernel" \
+                else "0"
+            x4 = x.reshape(shape)
+            assert rotary_path(ctx, x4, pos, {}) == path
+            return rule(ctx, {"X": [x4], "Pos": [pos]},
+                        {"base": base})["Out"][0].reshape(x.shape)
+
+        def both(x, pos, dy):
+            y, vjp = jax.vjp(lambda x: turn(x, pos), x)
+            return y, vjp(dy)[0]
+        return jax.jit(turn), jax.jit(both)
+
+    def ulps(a, b):
+        """(elements that differ, the largest difference in units of the
+        last place) of two bf16 arrays."""
+        a, b = (np.asarray(v).view(np.int16).astype(np.int32) for v in (a, b))
+        a, b = (np.where(v < 0, -32768 - v, v) for v in (a, b))
+        off = np.abs(a - b)
+        return int((off > 0).sum()), int(off.max())
+
+    failed = []
+    try:
+        with jax.default_device(smoke.device):
+            for label, b, t, h, d, base in c["cases"]:
+                keys = jax.random.split(jax.random.key(70 + h), 3)
+                x, dy = (jax.random.normal(k, (b * t, h * d)).astype(bf)
+                         for k in keys[:2])
+                pos = jax.random.permutation(
+                    keys[2], b * t).reshape(b, t).astype(jnp.int32)
+                shape = (b, t, h, d)
+                k_fwd, k_both = on_path("kernel", shape, base)
+                x_fwd, x_both = on_path("xla", shape, base)
+                got, want = k_both(x, pos, dy), x_both(x, pos, dy)
+                counts = []
+                for name, u, v in zip(("y", "dx"), got, want):
+                    n, worst = ulps(u, v)
+                    counts.append("%s %d of %d differ (largest %d ulp)"
+                                  % (name, n, u.size, worst))
+                    if n and (worst > 1 or n * 10000 > u.size):
+                        failed.append("%s %s" % (label, counts[-1]))
+                moved = 2 * x.size * x.dtype.itemsize + 2 * b * t * d * 4
+                smoke.say(
+                    "P rotary %s %s bf16, base %g: %s; ms forward kernel "
+                    "%.3f, xla %.3f; forward + transpose kernel %.3f, xla "
+                    "%.3f; a pass's bytes at 819 GB/s %.3f"
+                    % (label, list(shape), base, "; ".join(counts),
+                       _in_flight_ms(k_fwd, (x, pos)),
+                       _in_flight_ms(x_fwd, (x, pos)),
+                       _in_flight_ms(k_both, (x, pos, dy)),
+                       _in_flight_ms(x_both, (x, pos, dy)),
+                       1e3 * moved / 819e9))
+            label, b, t, h, d, base = c["cases"][0]
+            tiles = kernel_config.DEFAULT_TILES["rope"]
+            table = dict(tiles)
+            try:
+                x = jnp.ones((b * t, h * d), bf)
+                pos = jnp.zeros((b, t), jnp.int32)
+                for tile_bytes in c["sweep"]:
+                    tiles["tile_bytes"] = tile_bytes
+                    k_fwd, _ = on_path("kernel", (b, t, h, d), base)
+                    smoke.say("P rotary %s at blocks of %d KiB: ms forward "
+                              "%.3f" % (label, tile_bytes >> 10,
+                                        _in_flight_ms(k_fwd, (x, pos))))
+            finally:
+                tiles.update(table)
+    finally:
+        if was is None:
+            os.environ.pop("PADDLE_TPU_PALLAS", None)
+        else:
+            os.environ["PADDLE_TPU_PALLAS"] = was
+    if failed:
+        raise AssertionError("the rotary kernel is not the rule's "
+                             "arithmetic: " + "; ".join(failed))
+
+
 PHASES = (("A", "ResNet-50 training", phase_a),
           ("B", "transformer training", phase_b),
           ("C", "Pallas kernel families", phase_c),
@@ -2517,7 +2640,8 @@ PHASES = (("A", "ResNet-50 training", phase_a),
           ("L", "the latent core at 192 + 64 on 256", phase_l),
           ("M", "the routed experts' grouped matmuls", phase_m),
           ("N", "the selective scan and the differential core", phase_n),
-          ("O", "the state-space-dual scan", phase_o))
+          ("O", "the state-space-dual scan", phase_o),
+          ("P", "rotary_embedding's one pass", phase_p))
 
 
 def main(argv=None):

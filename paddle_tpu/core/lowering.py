@@ -545,6 +545,8 @@ def _lower_op_inner(ctx, op, env):
         _count_embedding_layer(ctx, ins)
     elif op.type == "mhc_pre":
         _count_hyper_connection_layer(ctx, op.attrs, ins)
+    elif op.type == "rotary_embedding":
+        _count_rotary_call(ctx, op.attrs, ins)
     ctx.unread_outputs = _unread_outputs(ctx, od, op.outputs)
     if op.type == "softmax_with_cross_entropy":
         _count_softmax_xent_layer(ctx, op.attrs, ins)
@@ -923,6 +925,22 @@ def _count_ssd_scan_layer(ins):
           states=str(b.shape[-1]), chunk=str(DEFAULT_TILES["ssd"]["chunk"]),
           path=ssd_scan_path(x, groups),
           **({"groups": str(groups)} if b.ndim == 4 else {}))
+
+
+def _count_rotary_call(ctx, attrs, ins):
+    from ..observability.registry import REGISTRY
+    from ..ops.nn_ops import rotary_path
+    x = ins["X"][0]
+    REGISTRY.counter(
+        "ptpu_rotary_calls_total",
+        "rotary_embedding ops lowered (forward ops, not a grad op's replay), "
+        "by the path taken (kernel: the one Pallas pass of "
+        "ops/rotary_kernels.py, where the whole head turns, half-split, and "
+        "a head is whole lane tiles; xla: the jax.numpy lines), the heads, "
+        "a head's width and the channels that turn"
+    ).inc(path=rotary_path(ctx, x, ins["Pos"][0], attrs),
+          heads=str(x.shape[2]), head_dim=str(x.shape[3]),
+          rotary_dim=str(attrs.get("rotary_dim") or x.shape[3]))
 
 
 def _count_embedding_layer(ctx, ins):

@@ -433,10 +433,15 @@ def _kept_by(prim, avals, params):
 
     kernel_output: a Pallas forward kernel's. XLA merges nothing into a
     Mosaic call's replay, so the replay costs the kernel whole (why
-    _linearizations exists outside loops). No test of bytes: the one kernel
-    a looped body holds today (flash attention) leaves the op's own result
-    and a row of statistics; a kernel with larger outputs would be kept
-    whole (ROADMAP A15).
+    _linearizations exists outside loops). No test of bytes: the one such
+    kernel a looped body holds today (flash attention) leaves the op's own
+    result and a row of statistics; a kernel with larger outputs would be
+    kept whole (ROADMAP A15). Not a kernel whose entry says that it costs
+    its bytes (`kernel_entry(.., costs_its_bytes=True)`, one read and one
+    write of its operand: ops/rotary_kernels.py): its replay is the traffic
+    that reading a kept result back would be, and keeping costs the memory
+    besides (Ouro: 16 results of 16 MiB a trip, 15.209 GiB compiled for the
+    described v5e against 13.769 replayed; PR 70).
     narrow_matmul: a value named ops/basic.py NARROW_MATMUL. The shape rule
     is `mul`'s and is stated there, once (contraction wider than the
     result's columns; the cast product, not the dot_general's float32
@@ -450,7 +455,10 @@ def _kept_by(prim, avals, params):
     threshold is not measured: every reduction a cell's loop meets is over
     2048, so any threshold from 2 to 2048 gives the same step."""
     if prim.name == "pallas_call":
-        return "kernel_output"
+        # an equation of this primitive exists: pallas is imported
+        from .pallas_import import costs_its_bytes
+        return None if costs_its_bytes(params.get("name")) \
+            else "kernel_output"
     if prim.name == "name":
         return "narrow_matmul" if params["name"] == NARROW_MATMUL else None
     if prim.name.startswith("reduce_") and "axes" in params and np.prod(

@@ -20,10 +20,11 @@ Accepted forms of PADDLE_TPU_PALLAS:
     - "attn,xent"       : allowlist — exactly the named ops on, the
                           rest off.  Unknown names raise LOUDLY (a typo
                           must not silently run the other path).
-Op names: attn, xent, ln, lstm, seq, gdr, conv, emb, mhc, gmm, scan, ssd (KERNEL_OPS).  For 'attn' the flag
-is an opt-OUT only: fused_attention's positive dispatch is always the
-flash_at() rule, so enabling 'attn' does not force flash below the
-crossover (pin FLAGS_flash_min_seq=0 for that).
+Op names: attn, xent, ln, lstm, seq, gdr, conv, emb, mhc, gmm, scan, ssd,
+rope (KERNEL_OPS).  For 'attn' the flag is an opt-OUT only:
+fused_attention's positive dispatch is always the flash_at() rule, so
+enabling 'attn' does not force flash below the crossover (pin
+FLAGS_flash_min_seq=0 for that).
 
 Both variables are read at trace time, so both are part of
 core.lowering.trace_env_key(), which the executors' jit caches and the
@@ -120,7 +121,20 @@ __all__ = [
 # bytes and the steps' overhead, not the products), the backward wants
 # fewer, larger steps and no longer chunk (the products under L grow with
 # it).  32 heads a step is 5 % under 16 on the backward pass alone and
-# twice the unrolled body to trace and compile, so 16.
+# twice the unrolled body to trace and compile, so 16.  "rope" is the
+# bytes a grid step of ops/rotary_kernels.py's one pass reads: one block
+# of rows of x [B*T, H*D], in x's dtype, and with it one block of each of
+# the two float32 tables, 128 lanes a row (at one head four times x's
+# bytes; the step writes a block of x's size besides).  The rows of a
+# block are the most whole sublane tiles inside it that DIVIDE B*T
+# (rotary_kernels.block_rows says why no block is ragged).  Swept on the
+# v5e (my chip run, PR 70: `chip_smoke.py --phases P`, the forward pass
+# alone at SDAR's q, [8192, 4096] bf16, whose bytes take 0.174 ms at 819
+# GB/s): 0.308 ms at 512 KiB (blocks of 32 rows), 0.297 at 1 MiB (64),
+# 0.299 at 2 MiB (128), in flight from the host, which reads 0.20 ms for
+# a call of 8 MiB: in the cell's trace a q call is 0.18 ms.  Flat: the
+# bytes' pace.  At 2 MiB x's and the tables' blocks, each held twice by
+# Mosaic, and the result's stay under 8 MiB of its 16 MiB of VMEM.
 DEFAULT_TILES = {
     "attn": {"block_q": 512, "block_k": 512},
     "xent": {"tile_bytes": 1 << 20},
@@ -134,6 +148,7 @@ DEFAULT_TILES = {
     "gmm": {"block_m": 512},
     "scan": {"chunk": 64},
     "ssd": {"chunk": 128, "block_h": 16},
+    "rope": {"tile_bytes": 2 << 20},
 }
 KERNEL_OPS = frozenset(DEFAULT_TILES)
 # Dense attention below this query length, flash at and above it.  The

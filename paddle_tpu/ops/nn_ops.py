@@ -300,7 +300,24 @@ def _rms_norm(ctx, ins, attrs):
     return {"Y": [y]}
 
 
-@register("rotary_embedding")
+def rotary_path(ctx, x, pos, attrs):
+    """"kernel" where rotary_kernels' one pass runs for X [B, T, H, D]:
+    kernel_config.pallas_on("rope") (a TPU, or PADDLE_TPU_PALLAS), no mesh,
+    integer positions, and a rotation the kernel computes
+    (rotary_kernels.applies: the whole head turns, half-split, D whole lane
+    tiles, rows that whole blocks divide); else "xla", the jax.numpy lines:
+    heads of 64, the interleaved layout, a head that turns in part. The one
+    place that decides; the counter reads it too."""
+    from .rotary_kernels import applies
+    fits = getattr(ctx, "mesh", None) is None \
+        and not jnp.issubdtype(pos.dtype, jnp.floating) and applies(
+            x.shape, x.dtype.itemsize,
+            attrs.get("rotary_dim") or x.shape[-1],
+            attrs.get("layout", "half"))
+    return "kernel" if fits and pallas_on("rope") else "xla"
+
+
+@register("rotary_embedding", calls_pallas=True, infer=shapes_from(Out="X"))
 def _rotary_embedding(ctx, ins, attrs):
     """Rotary position embedding of x [B, T, H, D] at the positions Pos
     [B, T] (an input, not a constant: a decode step feeds its own). The
@@ -311,7 +328,9 @@ def _rotary_embedding(ctx, ins, attrs):
     inv_freq[i] instead, and cos and sin are multiplied by `table_scale`
     (1). layout "interleaved": the pairs are (2i, 2i + 1). Angles, cos
     and sin and the rotation are float32; the result comes back in x's
-    dtype."""
+    dtype. Where `rotary_path` says so the rotation is one Pallas pass over
+    x that computes the same float32 products from the same cos and sin;
+    else the lines below, which XLA compiles to three passes."""
     x = single(ins, "X")
     pos = single(ins, "Pos")
     d = attrs.get("rotary_dim") or x.shape[-1]
@@ -331,6 +350,9 @@ def _rotary_embedding(ctx, ins, attrs):
     cos, sin = jnp.cos(angle), jnp.sin(angle)
     if attrs.get("table_scale", 1.0) != 1.0:
         cos, sin = cos * attrs["table_scale"], sin * attrs["table_scale"]
+    if rotary_path(ctx, x, pos, attrs) == "kernel":
+        from .rotary_kernels import rotary, tables
+        return _out(rotary(x, *tables(cos, sin)))
     x32 = x.astype(jnp.float32)
     whole = d == x.shape[-1]
     turned = x32 if whole else x32[..., :d]

@@ -45,10 +45,20 @@ from ..observability.registry import note_import, note_kernel_trace  # noqa: E40
 note_import("jax.experimental.pallas", _seconds)
 
 
-def kernel_entry(kernel, **static):
+_COSTS_ITS_BYTES = set()
+
+
+def kernel_entry(kernel, costs_its_bytes=False, **static):
     """`jax.jit(fn, **static)` for a function that holds the pallas_call
     named `kernel`; its Python body counts itself in
-    `ptpu_kernel_body_traces_total{kernel}` (module docstring)."""
+    `ptpu_kernel_body_traces_total{kernel}` (module docstring).
+    costs_its_bytes: the kernel is one read and one write of its operand
+    and nothing dearer, so running it again costs what reading a kept
+    result back would: what a recomputing loop asks before it keeps a
+    kernel's outputs (`costs_its_bytes` below)."""
+    if costs_its_bytes:
+        _COSTS_ITS_BYTES.add(kernel)
+
     def entry(fn):
         @functools.wraps(fn)
         def body(*args, **kwargs):
@@ -56,3 +66,8 @@ def kernel_entry(kernel, **static):
             return fn(*args, **kwargs)
         return jax.jit(body, **static)
     return entry
+
+
+def costs_its_bytes(kernel):
+    """Did the entry of the pallas_call named `kernel` say so?"""
+    return kernel in _COSTS_ITS_BYTES

@@ -2091,3 +2091,6 @@ SSD_KERNELS = ("ptpu_ssd_fwd", "ptpu_ssd_bwd")
 # nothing) is a Mosaic call of the program's and no expert matmul.
 EXPERT_MATMUL_KERNELS += ("ptpu_expert_gmm_unit_fwd",)
 KERNEL_NAMES += EXPERT_MATMUL_KERNELS[-1:] + ("ptpu_expert_rows_unwritten",)
+# PR 70: ops/rotary_kernels.py's one pass over a rotary_embedding's heads,
+# forward and (at the negated angle) backward.
+KERNEL_NAMES += ("ptpu_rotary",)

@@ -367,9 +367,9 @@ def test_import_gauges_in_a_child_process():
 @pytest.mark.parametrize("module", [
     "pallas_kernels", "expert_gmm", "mhc_kernels", "causal_conv_kernels",
     "gated_delta_kernels", "embedding_grad", "selective_scan_kernels",
-    "ssd_kernels"])
+    "ssd_kernels", "rotary_kernels"])
 def test_a_kernel_module_takes_pallas_from_the_one_place(module):
-    """The eight kernel modules import pallas through ops/pallas_import.py,
+    """The nine kernel modules import pallas through ops/pallas_import.py,
     in one line with `kernel_entry`, the form their entries take (PR 60),
     and nowhere else does the package import it."""
     src = open(os.path.join(ROOT, "paddle_tpu", "ops", module + ".py")).read()

@@ -185,11 +185,11 @@ def test_an_at_sign_would_cut_the_op_name_short():
 def test_every_pallas_call_takes_its_name_from_kernel_names():
     from paddle_tpu.ops import causal_conv_kernels, embedding_grad, \
         expert_gmm, gated_delta_kernels, mhc_kernels, \
-        selective_scan_kernels, ssd_kernels
+        rotary_kernels, selective_scan_kernels, ssd_kernels
     names = []
     for module in (pallas_kernels, gated_delta_kernels, causal_conv_kernels,
                    embedding_grad, mhc_kernels, expert_gmm,
-                   selective_scan_kernels, ssd_kernels):
+                   selective_scan_kernels, ssd_kernels, rotary_kernels):
         with open(module.__file__) as f:
             tree = ast.parse(f.read())
         # mhc_kernels' pallas_calls sit in two helpers that are handed the
@@ -217,13 +217,15 @@ def test_every_pallas_call_takes_its_name_from_kernel_names():
     assert sorted(names) == sorted(pallas_kernels.KERNEL_NAMES)
     assert pallas_kernels.EXPERT_MATMUL_KERNELS == expert_gmm.KERNELS
     assert pallas_kernels.SELECTIVE_SCAN_KERNELS \
-        == pallas_kernels.KERNEL_NAMES[-6:-4]
-    assert pallas_kernels.SSD_KERNELS == pallas_kernels.KERNEL_NAMES[-4:-2]
+        == pallas_kernels.KERNEL_NAMES[-7:-5]
+    assert pallas_kernels.SSD_KERNELS == pallas_kernels.KERNEL_NAMES[-5:-3]
     # PR 65's two, named at the module's end: the forward walk with the
     # unit in it is an expert matmul, the buffer nothing wrote is not
-    assert pallas_kernels.KERNEL_NAMES[-2:] == (
+    assert pallas_kernels.KERNEL_NAMES[-3:-1] == (
         "ptpu_expert_gmm_unit_fwd", "ptpu_expert_rows_unwritten")
-    assert len(set(names)) == len(names) == 31
+    # PR 70's, at the module's end too
+    assert pallas_kernels.KERNEL_NAMES[-1] == "ptpu_rotary"
+    assert len(set(names)) == len(names) == 32
     for a in names:         # a reader matching `<name>` or `<name>.<n>`
         for b in names:     # never counts one kernel under another
             assert a == b or not (b + ".").startswith(a + ".")
@@ -1242,3 +1244,66 @@ def test_a_layer_of_experts_on_a_described_v5e(one_chip, monkeypatch, case):
                     "while", "dynamic-update-slice", "custom-call"):
             passes.append((met.group(2), name.group(1)[-60:]))
     assert passes == []
+
+
+def test_an_sdar_layers_rotary_on_a_described_v5e(one_chip, monkeypatch):
+    """One attention layer at SDAR's geometry (8192 rows, 32 query heads of
+    128 on 4, a norm a head, rotary, flash) as a training step compiled for
+    a TPU: `ptpu_rotary` is a Mosaic call under `op:rotary_embedding` for q
+    and for k and under `op:rotary_embedding_grad` for both, four calls and
+    none replayed under the grad op (the forward op keeps its
+    linearization), and no convert writes a float32 image of q, which is
+    how XLA started its three passes over the jax.numpy lines (PR 70)."""
+    from paddle_tpu.ops import kernel_config
+    rows, width, heads, kv_heads, head = 8192, 2048, 32, 4, 128
+    monkeypatch.delenv("PADDLE_TPU_PALLAS", raising=False)
+    monkeypatch.setattr(kernel_config, "dispatch_platform", lambda: "tpu")
+    monkeypatch.setattr(pallas_kernels, "dispatch_platform", lambda: "tpu")
+    main, startup = fluid.Program(), fluid.Program()
+    main._amp = True
+    with fluid.unique_name.guard(), fluid.program_guard(main, startup):
+        x = fluid.layers.data(name="x", shape=[rows, width], dtype="float32")
+        pos = fluid.layers.data(name="pos", shape=[rows], dtype="int64")
+
+        def projected(n, turned=True):
+            y = fluid.layers.reshape(
+                fluid.layers.fc(input=x, size=n * head, num_flatten_dims=2,
+                                bias_attr=False), shape=[0, rows, n, head])
+            if not turned:
+                return y
+            return fluid.layers.rotary_embedding(
+                fluid.layers.rms_norm(y, begin_norm_axis=3), pos, base=1e6)
+        out = fluid.layers.fused_attention(
+            projected(heads), projected(kv_heads),
+            projected(kv_heads, turned=False), causal=True)
+        loss = fluid.layers.mean(fluid.layers.fc(
+            input=fluid.layers.reshape(out, shape=[0, rows, heads * head]),
+            size=width, num_flatten_dims=2, bias_attr=False))
+        fluid.optimizer.SGD(learning_rate=0.1).minimize(loss)
+    feeds = ["pos", "x"]
+    rw, ro, outs = lowering.analyze_state(main, feeds, [loss.name])
+    fn = lowering.build_program_fn(main, feeds, [loss.name], rw, ro, outs)
+    block = main.global_block()
+
+    def sds(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=one_chip)
+
+    def state(names):
+        return [sds(block.var(name).shape) for name in names]
+    text = _compile_uncached(
+        lambda feed, rw, ro: fn(feed, rw, ro, 0),
+        [sds((1, rows), jnp.int32), sds((1, rows, width))],
+        state(rw), state(ro)).as_text()
+    calls = collections.Counter(
+        (re.sub(r"\.\d+$", "", name), lowering.parse_op_scope(op_name)[0])
+        for name, op_name in re.findall(
+            r'%?([\w.\-]+) = [^\n]*custom_call_target="tpu_custom_call"'
+            r'[^\n]*op_name="([^"]*)"', text))
+    assert {k: n for k, n in calls.items() if k[0] == "ptpu_rotary"} == {
+        ("ptpu_rotary", "rotary_embedding"): 2,
+        ("ptpu_rotary", "rotary_embedding_grad"): 2}
+    images = [line.strip()[:160] for line in text.splitlines()
+              if re.search(r"= f32\[(1,)?%d,(%d,%d|%d)\][^ ]* convert\("
+                           % (rows, heads, head, heads * head), line)
+              and "op:rotary_embedding" in line]
+    assert images == []

@@ -28,7 +28,7 @@ F32, BF16, I32 = "float32", "bfloat16", "int32"
 INFERRED = ("fused_attention", "layer_norm", "softmax_with_cross_entropy",
             "batch_norm", "mhc_pre", "mhc_post", "mhc_expand", "mhc_reduce",
             "gated_delta_rule", "causal_conv1d", "moe_ffn", "selective_scan",
-            "ssd_scan")
+            "ssd_scan", "rotary_embedding")
 
 
 def _attention(t, hq, hkv, d, dv=None, rope=None, batch=-1, dtype=F32,
@@ -111,6 +111,12 @@ def _ssd(t, h, p, n, batch=-1, dtype=F32):
              "C": ((batch, t, n), dtype), "D": ((h,), F32)}, ("Out",), {})
 
 
+def _rotary(t, h, d, batch=-1, dtype=F32, **attrs):
+    return ("rotary_embedding",
+            {"X": ((batch, t, h, d), dtype), "Pos": ((batch, t), I32)},
+            ("Out",), dict({"base": 1e6}, **attrs))
+
+
 def _moe_ffn(t, d, f, experts, held, top_k, **attrs):
     return ("moe_ffn",
             {"X": ((-1, t, d), F32), "Router": ((d, experts), F32),
@@ -173,6 +179,14 @@ CASES = {
     "ssd_scan-granite-static-bfloat16": _ssd(2048, 64, 64, 128, batch=1,
                                              dtype=BF16),
     "ssd_scan-off-the-kernel": _ssd(40, 3, 24, 8, batch=2),
+    "rotary-sdar-q-32x128": _rotary(8192, 32, 128),
+    "rotary-sdar-k-static-bfloat16": _rotary(8192, 4, 128, batch=1,
+                                             dtype=BF16),
+    "rotary-laguna-yarn-64-of-128": _rotary(
+        4096, 12, 128, rotary_dim=64, table_scale=1.4852030263919618,
+        inv_freq=[0.5 ** i for i in range(32)]),
+    "rotary-qwen3next-64-of-256": _rotary(4096, 16, 256, rotary_dim=64),
+    "rotary-glm-interleaved-64": _rotary(4096, 20, 64, layout="interleaved"),
     # PR 50's infer, a table since PR 53
     "moe_ffn-olmoe-64-all-held": _moe_ffn(4096, 2048, 1024, 64, 64, 8),
     "moe_ffn-smallthinker-64-a-quarter-held": _moe_ffn(

@@ -24,7 +24,7 @@ import paddle_tpu as fluid
 from paddle_tpu.observability.registry import REGISTRY
 from paddle_tpu.ops import causal_conv_kernels, gated_delta_kernels
 from paddle_tpu.ops import mhc_kernels, pallas_kernels
-from paddle_tpu.ops import selective_scan_kernels
+from paddle_tpu.ops import rotary_kernels, selective_scan_kernels
 
 F32 = jnp.float32
 FLASH = {"ptpu_flash_fwd": "_flash_fwd_call",
@@ -133,6 +133,22 @@ def _mhc_stream(pass_, width):
 # the entry's name, where the three-site jaxpr holds it in ONE variant}).
 # The delta rule's backward pass runs its forward kernel again for the
 # states (`emit`): a second variant, so four traces and two inner jaxprs.
+def _rotary():
+    """x [1, T, 2, 128] and its tokens' integer positions; the tables are
+    made from them as the rule makes them."""
+    def args(other, seed):
+        t = 16 if other else 32
+        return _normal(seed, (1, t, 2, 128)) + (
+            (jnp.arange(t) * (seed + 1)).reshape(1, t),)
+
+    def call(x, pos):
+        angle = pos.astype(F32)[:, :, None, None] * 1e4 ** (
+            -jnp.arange(0, 128, 2, dtype=F32) / 128)
+        return rotary_kernels.rotary(
+            x, *rotary_kernels.tables(jnp.cos(angle), jnp.sin(angle)))
+    return call, args
+
+
 FAMILIES = {
     "flash_plain": (_flash(2, 2, 64), dict.fromkeys(FLASH, 2), FLASH),
     "flash_grouped": (_flash(4, 2, 128), dict.fromkeys(FLASH, 2), FLASH),
@@ -179,6 +195,11 @@ FAMILIES = {
         {"ptpu_mhc_reduce": 2, "ptpu_mhc_expand": 2},
         {"ptpu_mhc_reduce": "_reduce_call",
          "ptpu_mhc_expand": "_expand_call"}),
+    # one entry for both passes, the transpose the same kernel at the same
+    # shape: a site calls it twice, and a shape traces it twice (jax runs a
+    # backward rule under an abstract mesh of its own, and jit keeps a trace
+    # under its context)
+    "rotary": (_rotary(), {"ptpu_rotary": 4}, {"ptpu_rotary": "_call"}, 2),
 }
 
 
@@ -229,7 +250,10 @@ def _float_arrays(tree):
 
 @pytest.mark.parametrize("family", sorted(FAMILIES))
 def test_a_kernel_entry_traces_once_a_shape(family):
-    (call, args), traces, entries = FAMILIES[family]
+    (call, args), traces, entries = FAMILIES[family][:3]
+    # the calls of an entry a site makes, each pass's the one jaxpr: one,
+    # but where the backward pass is the forward's own entry
+    calls_a_site = (FAMILIES[family][3:] or (1,))[0]
     sites = tuple(args(False, seed) for seed in (1, 2, 3)) + (args(True, 4),)
     step = _step(call)
     jax.clear_caches()          # whatever earlier tests traced at these shapes
@@ -247,8 +271,9 @@ def test_a_kernel_entry_traces_once_a_shape(family):
         by_entry.setdefault(eqn.params["name"], []).append(
             eqn.params["jaxpr"])
     for kernel, entry in entries.items():
-        assert len(by_entry[entry]) == 3, (kernel, entry)
-        assert len({id(j) for j in by_entry[entry]}) == 1, (kernel, entry)
+        assert len(by_entry[entry]) == 3 * calls_a_site, (kernel, entry)
+        assert len({id(j) for j in by_entry[entry]}) == calls_a_site, (
+            kernel, entry)
     kernels_a_site = sum(traces.values()) // 2
     assert len(list(_equations(three.jaxpr, "pallas_call"))) \
         == 3 * kernels_a_site

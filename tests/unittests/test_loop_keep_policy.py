@@ -352,16 +352,26 @@ def test_profile_report_lists_the_kept_values(steps):
 # parent commit (PR 57) lowered it: sha256 of the text, first 16 digits. Its
 # down projections are narrow `mul`s too, and are not named: outside a
 # recomputing loop's body the `mul` rule traces what it traced.
-_PARENT_STEP = {"f32": "9a730a94c876afc8", "amp": "f49ffd8c5ce85187"}
+# Since PR 70 `rotary_embedding` keeps its linearization (`calls_pallas`: its
+# rule can reach `ptpu_rotary`), so the forward op lowers under jax.vjp and
+# the grad op replays nothing: other text, and with the field off the text of
+# PR 57 to the digit ("9a730a94c876afc8" / "f49ffd8c5ce85187").
+_PARENT_STEP = {("f32", True): "70cedc0731f00ce4",
+                ("amp", True): "f2d4b2c4d23cb0f8",
+                ("f32", False): "9a730a94c876afc8",
+                ("amp", False): "f49ffd8c5ce85187"}
 
 
-@pytest.mark.parametrize("precision", sorted(_PARENT_STEP))
+@pytest.mark.parametrize("precision,rotary_kept", sorted(_PARENT_STEP))
 def test_a_program_without_a_recomputing_loop_lowers_as_the_parent_did(
-        precision, monkeypatch):
+        precision, rotary_kept, monkeypatch):
+    from paddle_tpu.core import registry
     monkeypatch.delenv("FLAGS_flash_min_seq", raising=False)
     monkeypatch.delenv("PADDLE_TPU_PALLAS", raising=False)
+    monkeypatch.setattr(registry.get("rotary_embedding"), "calls_pallas",
+                        rotary_kept)
     fn, args, _ = _step(cfg=ouro.DENSE, amp=precision == "amp", grads=False)
     assert not _primitives(jax.make_jaxpr(fn)(*args).jaxpr, "name")
     text = jax.jit(fn).lower(*args).as_text()
     assert hashlib.sha256(text.encode()).hexdigest()[:16] \
-        == _PARENT_STEP[precision]
+        == _PARENT_STEP[precision, rotary_kept]
