@@ -83,6 +83,14 @@ from a seed, and checks what comes out by the repo's own means:
      element in 10,000, the count printed) or the phase fails; ms a call of
      both beside the time the bytes take, and the kernel at the tiles of
      the sweep.
+  Q  the delta rule whose decay is a key channel's (`ops/kda_kernels.py`)
+     at the Ling-3.0-flash cell's shape, [1, 4096, 8, 128] with bf16
+     operands: the two Pallas kernels against the same chunked form under
+     `lax.scan` (`path="scan"`), forward and forward + backward: the
+     largest error of the output and of each of the five gradients, and ms
+     of both paths, under the decay of a layer at its start (channels from
+     the bound of -5 a token to near 0) and with every channel at the
+     bound; the kernel path against the token-by-token recurrence too.
 
 Every phase that fails makes the exit code non-zero. Timings are printed
 for the next reader, labelled with the device; they are not metrics. The
@@ -211,6 +219,7 @@ FULL = {
                         ("Laguna sliding k", 1, 4096, 2, 128, 1e4),
                         ("a head of 256", 1, 4096, 4, 256, 1e6)),
                  sweep=(1 << 19, 1 << 20, 1 << 21)),
+    "kda": dict(b=1, t=4096, h=8, d=128, tol=2e-2, exact_tol=5e-2),
     "differential": dict(b=1, t=8192, pairs=20, kv_pairs=10, hd=64,
                          window=512, tol=2e-2),
     "latent_unequal": dict(b=2, t=4096, h=20, d=192, dr=64, dv=256,
@@ -278,6 +287,7 @@ TINY = {
                  sweep=(1 << 14,)),
     "ssd": dict(b=2, t=72, h=4, p=64, n=16, tol=3e-2, segment=8,
                 sweep=((16, 2),)),
+    "kda": dict(b=2, t=72, h=2, d=16, tol=2e-2, exact_tol=5e-2),
     "differential": dict(b=1, t=64, pairs=4, kv_pairs=2, hd=16, window=16,
                          tol=2e-2),
     "latent_unequal": dict(b=2, t=64, h=2, d=192, dr=64, dv=256,
@@ -2626,6 +2636,93 @@ def phase_p(smoke):
                              "arithmetic: " + "; ".join(failed))
 
 
+def phase_q(smoke):
+    """ops/kda_kernels.py's two Pallas kernels against the same chunked form
+    under lax.scan (`path="scan"`), bf16 operands as under AMP, at the
+    Ling-3.0-flash cell's shape: the output and the gradients of q, k, v, g
+    and beta, each by its largest error over the scan path's largest value,
+    and the milliseconds of both paths, forward and forward + backward.
+    The kernel path is held to the token-by-token recurrence of
+    models/causal_lm_reference.py (float32, "highest") as well: the two
+    chunked paths share `_prepare`, the recurrence shares nothing."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.models import causal_lm_reference as reference
+    from paddle_tpu.ops import kda_kernels
+
+    c = smoke.cfg["kda"]
+    b, t, h, d = c["b"], c["t"], c["h"], c["d"]
+    rng = np.random.RandomState(71)
+    q, k, v, ct = (jnp.asarray(rng.randn(b, t, h, d), jnp.bfloat16)
+                   for _ in range(4))
+    beta = jax.nn.sigmoid(jnp.asarray(rng.randn(b, t, h), jnp.float32))
+    a = jnp.asarray(rng.randn(b, t, h, d), jnp.float32)
+    rate = jnp.asarray(rng.uniform(1, 16, (h, 1)), jnp.float32)
+    decays = (("a layer's start", -5.0 * jax.nn.sigmoid(rate * (a - 0.5))),
+              ("every channel at the bound", jnp.full(a.shape, -5.0)))
+    names = ("out", "dq", "dk", "dv", "dg", "dbeta")
+
+    def both(fn):
+        def run(*args):
+            out, vjp = jax.vjp(fn, *args)
+            return (out,) + vjp(ct.astype(out.dtype))
+        return jax.jit(run)
+
+    @jax.checkpoint
+    def one_head(xs):       # [B, T, 1, ..]: a head's T states alone
+        return reference.kda_rule(*xs)
+
+    def recurrence(q, k, v, g, beta):
+        q, k = (reference.l2norm(x.astype(jnp.float32)) for x in (q, k))
+        heads = tuple(jnp.moveaxis(x, 2, 0)[:, :, :, None] for x in (
+            q * d ** -0.5, k, v.astype(jnp.float32), g, beta))
+        return jnp.moveaxis(jax.lax.map(one_head, heads)[:, :, :, 0], 0, 2)
+
+    def rule(path):
+        return lambda *args: kda_kernels.kda_delta_rule(
+            *args, path=path, operand_dtype=jnp.bfloat16)
+
+    failed = []
+    with jax.default_device(smoke.device):
+        for label, g in decays:
+            args = (q, k, v, g, beta)
+            runs = {path: both(rule(path)) for path in ("kernel", "scan")}
+            got = {path: jax.block_until_ready(run(*args))
+                   for path, run in runs.items()}
+            with jax.default_matmul_precision("highest"):
+                want = jax.block_until_ready(both(recurrence)(*args))
+            errs = _normalized_errors(names, got["kernel"], got["scan"])
+            exact = _normalized_errors(names, got["kernel"], want)
+            # the decay's own gradient at the bound is e^-5 of the others'
+            # and bf16's rounding of the operands does not shrink with it:
+            # printed, not held to the recurrence
+            held = [n for n in names if n != "dg"]
+            worst = max(errs, key=errs.get)
+            far = max(held, key=exact.get)
+            forward = {path: jax.jit(rule(path)) for path in runs}
+            smoke.say(
+                "Q kda kernels, q/k/v/g [%d, %d, %d, %d] bf16 operands, %s: "
+                "off the scan path by %s; off the recurrence by at most "
+                "%.2e (%s; dg %.2e); ms forward kernel %.3f, scan %.3f; "
+                "forward + backward kernel %.3f, scan %.3f"
+                % (b, t, h, d, label,
+                   ", ".join("%s %.2e" % (n, errs[n]) for n in names),
+                   exact[far], far, exact["dg"],
+                   _in_flight_ms(forward["kernel"], args),
+                   _in_flight_ms(forward["scan"], args),
+                   _in_flight_ms(runs["kernel"], args, calls=4),
+                   _in_flight_ms(runs["scan"], args, calls=4)))
+            if errs[worst] > c["tol"]:
+                failed.append("%s: kernel against scan %s %.2e"
+                              % (label, worst, errs[worst]))
+            if exact[far] > c["exact_tol"]:
+                failed.append("%s: kernel against the recurrence %r"
+                              % (label, exact))
+    if failed:
+        raise AssertionError("the KDA kernels are not the chunked rule: "
+                             + "; ".join(failed))
+
+
 PHASES = (("A", "ResNet-50 training", phase_a),
           ("B", "transformer training", phase_b),
           ("C", "Pallas kernel families", phase_c),
@@ -2641,7 +2738,8 @@ PHASES = (("A", "ResNet-50 training", phase_a),
           ("M", "the routed experts' grouped matmuls", phase_m),
           ("N", "the selective scan and the differential core", phase_n),
           ("O", "the state-space-dual scan", phase_o),
-          ("P", "rotary_embedding's one pass", phase_p))
+          ("P", "rotary_embedding's one pass", phase_p),
+          ("Q", "the delta rule with a decay a channel", phase_q))
 
 
 def main(argv=None):

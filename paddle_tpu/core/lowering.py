@@ -533,8 +533,8 @@ def _lower_op_inner(ctx, op, env):
         _count_moe_layer(ctx, op.attrs, ins)
     elif op.type == "fused_attention":
         _count_attention_layer(ctx, op.attrs, ins)
-    elif op.type == "gated_delta_rule":
-        _count_linear_attention_layer(ins)
+    elif op.type in ("gated_delta_rule", "kda_delta_rule"):
+        _count_linear_attention_layer(op.type, ins)
     elif op.type == "causal_conv1d":
         _count_causal_conv_layer(op.attrs, ins)
     elif op.type == "selective_scan":
@@ -730,6 +730,9 @@ def _count_moe_layer(ctx, attrs, ins):
         own["gated"] = "false"
     if numbered_by(experts, held, attrs["top_k"]) == "expert":
         own["numbered"] = "expert"
+    if attrs.get("n_group"):
+        own["groups"] = str(attrs["n_group"])
+        own["kept_groups"] = str(attrs["topk_group"])
     router_input = "pre_attention" if ins.get("RouterX") else "own"
     if router.shape[0] != ins["X"][0].shape[-1]:
         router_input = str(router.shape[0])
@@ -759,7 +762,10 @@ def _count_moe_layer(ctx, attrs, ins):
         "gate and up matrices and the buffers of sorted rows start as a "
         "call's output that nothing filled (path expert_gmm: "
         "expert_gmm.gmm_unit, moe._sorted_rows_start), absent where it is a "
-        "pass of XLA's over all the buffer's rows (path ragged_dot)"
+        "pass of XLA's over all the buffer's rows (path ragged_dot); "
+        "`groups` and `kept_groups` where the choice is limited to a "
+        "token's kept_groups best of `groups` runs of neighbouring experts "
+        "(moe._group_limited), absent where it is over all experts"
     ).inc(top_k=str(attrs["top_k"]), experts=str(experts), held=str(held),
           activation=str(attrs.get("activation", "silu")),
           router_input=router_input,
@@ -861,19 +867,28 @@ def _count_softmax_xent_layer(ctx, attrs, ins):
           softmax="unread" if "Softmax" in ctx.unread_outputs else "read")
 
 
-def _count_linear_attention_layer(ins):
+def _count_linear_attention_layer(op_type, ins):
     from ..observability.registry import REGISTRY
     from ..ops.kernel_config import DEFAULT_TILES
-    from ..ops.linear_attention_ops import gated_delta_path
+    from ..ops.linear_attention_ops import gated_delta_path, kda_path
     k, v = ins["K"][0], ins["V"][0]
+    kda = op_type == "kda_delta_rule"
+    own = {}
+    if kda:
+        from ..ops.kda_kernels import SUB_BLOCK
+        own["sub_block"] = str(SUB_BLOCK)
     REGISTRY.counter(
         "ptpu_linear_attention_layers_total",
         "linear-attention ops lowered (forward ops, not a grad op's replay), "
-        "by kind, key and value heads and their widths, the chunk and the "
-        "path of the pass over chunks (the Pallas kernels, or lax.scan)"
-    ).inc(kind="gated_delta", k_heads=str(k.shape[2]),
+        "by kind (gated_delta: a decay a head; kda: a decay a key channel), "
+        "key and value heads and their widths, the chunk and the path of the "
+        "pass over chunks (the Pallas kernels, or lax.scan); and, for kda "
+        "alone, sub_block: the rows that share one reference for the "
+        "exponentials of the decayed products"
+    ).inc(kind="kda" if kda else "gated_delta", k_heads=str(k.shape[2]),
           v_heads=str(v.shape[2]), d_k=str(k.shape[3]), d_v=str(v.shape[3]),
-          chunk=str(DEFAULT_TILES["gdr"]["chunk"]), path=gated_delta_path())
+          chunk=str(DEFAULT_TILES["kda" if kda else "gdr"]["chunk"]),
+          path=kda_path() if kda else gated_delta_path(), **own)
 
 
 def _count_causal_conv_layer(attrs, ins):

@@ -24,7 +24,8 @@ __all__ = [
     "expand", "sequence_mask", "linear_chain_crf", "crf_decoding",
     "chunk_eval", "warpctc", "ctc_greedy_decoder", "sequence_erase",
     "edit_distance", "fused_attention", "rms_norm", "rotary_embedding",
-    "causal_conv1d", "gated_delta_rule", "selective_scan", "ssd_scan",
+    "causal_conv1d", "gated_delta_rule", "kda_delta_rule", "selective_scan",
+    "ssd_scan",
     "mhc_pre",
     "mhc_post", "mhc_expand",
     "mhc_reduce",
@@ -386,6 +387,24 @@ def gated_delta_rule(q, k, v, g, beta, name=None):
     out = helper.create_variable_for_type_inference(v.dtype)
     helper.append_op(
         type="gated_delta_rule",
+        inputs={"Q": [q], "K": [k], "V": [v], "G": [g], "Beta": [beta]},
+        outputs={"Out": [out]}, attrs={})
+    return out
+
+
+def kda_delta_rule(q, k, v, g, beta, name=None):
+    """The delta rule whose decay is a key CHANNEL's (Kimi Delta Attention,
+    arXiv:2510.26692; ops/kda_kernels.py): q, k [B, T, H, dk], v [B, T, H,
+    dv], g [B, T, H, dk] the log decay a channel (in (-5.9, 0] a token: the
+    chunked form's exponentials are finite under that bound and no other)
+    and beta [B, T, H] the write strength -> [B, T, H, dv]. Per head, from
+    S_0 = 0: S' = Diag(exp(g_t)) S_(t-1); S_t = S' + beta_t k_t (v_t - S'^T
+    k_t)^T; o_t = S_t^T q_t, computed in chunks. q and k are l2-normalised
+    over dk first (1e-6 inside the root) and q multiplied by dk^-0.5."""
+    helper = LayerHelper("kda_delta_rule", name=name)
+    out = helper.create_variable_for_type_inference(v.dtype)
+    helper.append_op(
+        type="kda_delta_rule",
         inputs={"Q": [q], "K": [k], "V": [v], "G": [g], "Beta": [beta]},
         outputs={"Out": [out]}, attrs={})
     return out

@@ -275,7 +275,7 @@ def moe_ffn(input, num_experts, d_expert, top_k, norm_topk_prob=False,
             param_attr=None, name=None, router_input=None, activation="silu",
             experts_held=None, first_expert=0, scoring="softmax",
             expert_bias_attr=None, routed_scaling_factor=1.0,
-            norm_epsilon=None, gated=True):
+            norm_epsilon=None, gated=True, n_group=1, topk_group=1):
     """Dropless top-k routed experts, each a gated FFN without bias
     (lowering: ops/parallel_ops.py -> parallel/moe.py routed_ffn). input
     [..., D] -> (out [..., D], balance_loss [1], z_loss [1], expert_load
@@ -315,7 +315,13 @@ def moe_ffn(input, num_experts, d_expert, top_k, norm_topk_prob=False,
     softmax scoring, where no model defines it. routed_scaling_factor
     multiplies the weights after the renormalisation. norm_epsilon: what a
     sigmoid router's renormalisation adds to the chosen scores' sum instead
-    of 1e-6 (None: 1e-6).
+    of 1e-6 (None: 1e-6). n_group > 1 with topk_group < n_group: DeepSeek-V3's
+    group-limited choice (arXiv:2412.19437, `noaux_tc`): the experts are
+    n_group runs of neighbours, a group's score the sum of its two largest
+    scores (with the bias), and the top_k is taken inside the topk_group
+    best groups, every score outside them out of the choice; the weights
+    are the chosen experts' as without it. A share's held experts lie in
+    few groups: a token that keeps none of them gets zeros here.
     """
     helper = LayerHelper("moe_ffn", name=name)
     dtype = input.dtype
@@ -335,6 +341,16 @@ def moe_ffn(input, num_experts, d_expert, top_k, norm_topk_prob=False,
     if scoring not in ("softmax", "sigmoid"):
         raise ValueError("moe_ffn scoring must be 'softmax' or 'sigmoid', "
                          "got %r" % (scoring,))
+    n_group, topk_group = int(n_group), int(topk_group)
+    limited = n_group > 1 and topk_group < n_group
+    if n_group < 1 or e % n_group or not 1 <= topk_group <= n_group or (
+            limited and (e // n_group < 2
+                         or topk_group * (e // n_group) < int(top_k))):
+        raise ValueError(
+            "moe_ffn cannot choose top_k=%d inside topk_group=%d of n_group="
+            "%d groups of %d experts (groups of two or more that divide the "
+            "experts, and the kept ones hold top_k)" % (
+                top_k, topk_group, n_group, e))
     biased = expert_bias_attr not in (None, False)
     if biased and scoring != "sigmoid":
         raise ValueError("moe_ffn adds an expert bias to sigmoid scores "
@@ -381,6 +397,8 @@ def moe_ffn(input, num_experts, d_expert, top_k, norm_topk_prob=False,
         attrs["scale"] = float(routed_scaling_factor)
     if norm_epsilon is not None:
         attrs["norm_epsilon"] = float(norm_epsilon)
+    if limited:
+        attrs["n_group"], attrs["topk_group"] = n_group, topk_group
     out = helper.create_variable_for_type_inference(dtype)
     balance = helper.create_variable_for_type_inference("float32")
     z = helper.create_variable_for_type_inference("float32")

@@ -104,7 +104,8 @@ scoring_func (router_scoring), topk_method (noaux_tc: use_expert_bias;
 greedy: none; others refused), n_shared_experts (so many SwiGLUs of
 moe_intermediate_size every token passes, as one of their summed width,
 ungated: shared_expert_gate false, where Qwen3-Next's is gated), n_group
-and topk_group (1; a group limit is refused), moe_layer_freq (1),
+and topk_group (1 and 1: no limit; n_group > topk_group under noaux_tc:
+the group-limited choice, below), moe_layer_freq (1),
 num_nextn_predict_layers (0, or 1: one multi-token-prediction module,
 below; more are refused), ep_size and max_position_embeddings (not
 read); hc_mult (the residual streams n; 1: the plain residual), where n > 1
@@ -256,6 +257,39 @@ the order and under the names of any attention-and-experts layer:
 embedding; layer_<i>.input_norm, wq, wk, wv, q_norm, k_norm, wo,
 post_attention_norm, experts.router, experts.w_gate, experts.w_up,
 experts.w_down; final_norm; head.
+Ling-3.0-flash's (`model_type: bailing_hybrid`, inclusionAI; Kimi Delta
+Attention, Kimi Linear, arXiv:2510.26692), known where the config has
+layer_group_size (`_bailing_hybrid`): layer_group_size (published layer i
+is latent attention where (i + 1) % it == 0, else a KDA mixer, "kda" among
+`mixer_layers`: `kda` has the equations; a cut stack names its published
+layers under layer_indices), kda_lower_bound (the decay gate's bound, in
+(-5.9, 0)), short_conv_kernel_size, head_dim (a KDA head's d_k = d_v; its
+heads are num_attention_heads), q_lora_rank null beside the other four
+latent keys (DeepSeek-V2-Lite's form: q = x W_q, `wq`, no wq_a and no
+q_a_norm), gated_attention_proj_granularity_type head_wise (attention_gate
+"per_head", on the latent layer too: `wg`, before `wo`), n_group and
+topk_group (DeepSeek-V3's group limit, `noaux_tc`: the experts are n_group
+runs of neighbours, a group's score the sum of its two largest s + b, the
+top k inside the topk_group best groups; `layers.moe_ffn(n_group=,
+topk_group=)`), and the family's names mapped (BAILING):
+moe_router_enable_expert_bias (use_expert_bias), score_function
+(router_scoring), num_shared_experts with
+moe_shared_expert_intermediate_size (n_shared_experts of that width),
+rope_interleave (rope_interleaved). What the family can switch on and the
+builder does not build is refused by name (BAILING_ONLY: use_kda_lora,
+no_kda_lora false, kda_safe_gate false, mtp_use_kda, group_norm_size other
+than 1, use_nGPT, up_proj_norm, value_norm, use_mla_nope,
+scale_router_input, use_bias, use_qkv_bias, linear_silu false, use_qk_norm
+false, num_kv_heads_for_linear_attn other than 0, num_nextn_predict_layers
+other than 0: with mtp_loss_scaling_factor 0 the published loss has no term
+of the module, and none is built), as is a non-zero entry of
+expert_swiglu_limit_list or share_expert_swiglu_limit_list on a kept layer.
+seq_aux, max_window_layers, qk_head_dim (held to qk_nope_head_dim +
+qk_rope_head_dim), rotary_dim and mtp_loss_scaling_factor are not read. As
+a key of its own: `kda_dt_bias_range` ((-1, 0): the decay bias's start). A
+KDA layer's parameters: wq, conv_q, wk, conv_k, wv, conv_v, wf, dt_bias,
+a_log, wbeta, o_norm, wg, wo; a latent layer's without a query rank and
+with its gate: wq, wkv_a, kv_a_norm, wkv_b, wg, wo.
 A Mamba layer's parameters: w_in, conv, [conv.bias], w_x, w_dt, dt_bias,
 a_log, d, w_out; a memory unit's: w_in, w_out; a differential attention's:
 wq, [wq.bias], then where it makes its own keys and values wk, [wk.bias],
@@ -369,9 +403,30 @@ ALIASES = {"moe_ffn_hidden_size": "intermediate_size",
            "scoring_func": "router_scoring",
            # laguna's name (Laguna-S-2.1's config.json)
            "moe_routed_scaling_factor": "routed_scaling_factor"}
-# the keys latent attention needs, all or none
+# the keys latent attention needs, all or none; q_lora_rank alone may be
+# null beside the others (DeepSeek-V2-Lite's form: q = x W_q, no low rank)
 LATENT_KEYS = ("q_lora_rank", "kv_lora_rank", "qk_nope_head_dim",
                "qk_rope_head_dim", "v_head_dim")
+# bailing_hybrid's names (Ling-3.0-flash's config.json; known where the
+# config has layer_group_size) -> the key the builder reads
+BAILING = {"moe_router_enable_expert_bias": "use_expert_bias",
+           "score_function": "router_scoring",
+           "num_shared_experts": "n_shared_experts",
+           "rope_interleave": "rope_interleaved"}
+# what a bailing_hybrid config may say in one way only: the other is refused
+# by name (`_bailing_hybrid`)
+BAILING_ONLY = (("use_kda_lora", False), ("no_kda_lora", True),
+                ("kda_safe_gate", True), ("mtp_use_kda", False),
+                ("group_norm_size", 1), ("use_nGPT", False),
+                ("up_proj_norm", False), ("value_norm", False),
+                ("use_mla_nope", False), ("scale_router_input", False),
+                ("use_bias", False), ("use_qkv_bias", False),
+                ("linear_silu", True), ("use_qk_norm", True),
+                ("num_kv_heads_for_linear_attn", 0),
+                ("num_nextn_predict_layers", 0))
+# the stream a KDA mixer's decay bias is drawn from, whatever the program's
+# seed (the draw the Ling-3.0-flash cell's limits were read under)
+KDA_DT_SEED = 71
 # the stream a Mamba mixer's Delta bias is drawn from, whatever the program's
 # seed (the draw the Phi-4-mini-flash cell's limits were read under)
 MAMBA_DT_SEED = 54
@@ -433,7 +488,8 @@ def resolve(cfg):
     there is a multi-token-prediction module (`mtp_layers` 1): the module's
     layer, an attention layer with the model's experts."""
     c = dict(DEFAULTS, **cfg)
-    for theirs, ours in ALIASES.items():
+    for theirs, ours in tuple(ALIASES.items()) + (
+            tuple(BAILING.items()) if "layer_group_size" in c else ()):
         if theirs in c:
             c[ours] = c[theirs]
     c["dense_intermediate_size"] = c.get("intermediate_size")
@@ -451,8 +507,8 @@ def resolve(cfg):
     if c.get("n_shared_experts"):
         # so many SwiGLUs of an expert's width, every token through all of
         # them, added as they are: one SwiGLU of their summed width, ungated
-        c["shared_expert_intermediate_size"] = \
-            c["n_shared_experts"] * c["intermediate_size"]
+        c["shared_expert_intermediate_size"] = c["n_shared_experts"] * c.get(
+            "moe_shared_expert_intermediate_size", c["intermediate_size"])
         c["shared_expert_gate"] = False
     pattern = c.get("hybrid_override_pattern")
     if pattern is not None:
@@ -511,12 +567,27 @@ def resolve(cfg):
                       ("decoder_sparse_step", 1),
                       ("moe_apply_router_weight_on_input", False),
                       ("moe_router_logit_softcapping", 0),
-                      ("early_exit_threshold", 1), ("n_group", 1),
-                      ("topk_group", 1), ("moe_layer_freq", 1)):
+                      ("early_exit_threshold", 1), ("moe_layer_freq", 1)):
         if c[key] != want:
             raise NotImplementedError(
                 "causal_lm builds %s=%r only, the config has %r"
                 % (key, want, c[key]))
+    # DeepSeek-V3's group limit on the router's choice: n_group runs of
+    # neighbouring experts, the top k inside a token's topk_group best
+    c["n_group"], c["topk_group"] = int(c["n_group"]), int(c["topk_group"])
+    if not 1 <= c["topk_group"] <= c["n_group"]:
+        raise NotImplementedError(
+            "causal_lm builds topk_group in 1 .. n_group, the config has "
+            "topk_group %d of n_group %d" % (c["topk_group"], c["n_group"]))
+    c["group_limited"] = bool(c["num_experts"]) \
+        and c["topk_group"] < c["n_group"]
+    if c["group_limited"] and not c["use_expert_bias"]:
+        raise NotImplementedError(
+            "causal_lm builds a group limit on the router's choice (n_group "
+            "%d, topk_group %d) under topk_method noaux_tc (a sigmoid "
+            "router's scores + a correction bias), where a group's score is "
+            "the sum of its two largest; the config has no such router"
+            % (c["n_group"], c["topk_group"]))
     # the layers named are dense where the model has experts: built as a
     # leading run, which is num_dense_layers
     only = [int(i) for i in c["mlp_only_layers"]]
@@ -533,6 +604,15 @@ def resolve(cfg):
             raise NotImplementedError(
                 "causal_lm builds gating 'per-head' (sigmoid(x W_g) a head "
                 "on the core's output), the config has %r" % (c["gating"],))
+        c["attention_gate"] = "per_head"
+    if "gated_attention_proj_granularity_type" in c:
+        # bailing_hybrid's name for the same gate
+        if c["gated_attention_proj_granularity_type"] != "head_wise":
+            raise NotImplementedError(
+                "causal_lm builds gated_attention_proj_granularity_type "
+                "head_wise (sigmoid(x W_g) a head on the core's output), the "
+                "config has %r"
+                % (c["gated_attention_proj_granularity_type"],))
         c["attention_gate"] = "per_head"
     if c["attention_gate"] not in (False, True, "per_head"):
         raise NotImplementedError(
@@ -563,20 +643,24 @@ def resolve(cfg):
                          "stream" % c["hc_mult"])
     c["latent"] = any(c.get(key) is not None for key in LATENT_KEYS)
     if c["latent"]:
-        missing = [key for key in LATENT_KEYS if c.get(key) is None]
+        missing = [key for key in LATENT_KEYS[1:] if c.get(key) is None]
         if missing:
             raise NotImplementedError(
-                "causal_lm builds latent attention with a low-rank q and a "
-                "low-rank kv, both: the config lacks %s" % missing)
+                "causal_lm builds latent attention with a low-rank kv and a "
+                "head of two parts (q_lora_rank alone may be null: q = x "
+                "W_q): the config lacks %s" % missing)
+        c.setdefault("q_lora_rank", None)
         if c["num_key_value_heads"] != c["num_attention_heads"]:
             raise NotImplementedError(
                 "causal_lm builds latent attention with a key/value head a "
                 "query head, the config has %d on %d"
                 % (c["num_attention_heads"], c["num_key_value_heads"]))
-        for key in ("qk_norm", "attention_gate"):
-            if c[key]:
-                raise NotImplementedError(
-                    "causal_lm builds latent attention without %s" % key)
+        if c["qk_norm"] or c["attention_gate"] not in (False, "per_head"):
+            raise NotImplementedError(
+                "causal_lm builds latent attention without qk_norm and "
+                "without a gate, or with attention_gate 'per_head'; the "
+                "config has qk_norm %r, attention_gate %r"
+                % (c["qk_norm"], c["attention_gate"]))
         c.setdefault("head_dim", c["qk_nope_head_dim"]
                      + c["qk_rope_head_dim"])
     if pattern is not None:
@@ -693,6 +777,16 @@ def resolve(cfg):
         raise ValueError("chip %d cannot hold %d of %d experts"
                          % (share.get("chip", 0), c["experts_held"],
                             c["num_experts"]))
+    if c["group_limited"]:
+        group = c["num_experts"] // c["n_group"]
+        if c["num_experts"] % c["n_group"] or group < 2 \
+                or c["topk_group"] * group < c["num_experts_per_tok"]:
+            raise NotImplementedError(
+                "causal_lm builds n_group %d, topk_group %d over %d experts "
+                "as groups of two or more neighbours that divide the "
+                "experts, the kept ones holding num_experts_per_tok %d"
+                % (c["n_group"], c["topk_group"], c["num_experts"],
+                   c["num_experts_per_tok"]))
     layers = c["num_hidden_layers"]
     for key in ("rope_layout", "sliding_window_layout"):
         if key in c and len(c[key]) < layers:
@@ -758,6 +852,8 @@ def resolve(cfg):
         c["mixer_layers"] = [known[kind] for kind in kinds[:layers]]
     elif pattern is not None:
         _one_branch_layers(c, pattern, published)
+    elif "layer_group_size" in c:
+        _bailing_hybrid(c, published)
     else:
         c["mixer_layers"] = ["attention" if (i + 1) % interval == 0
                              else "gated_delta" for i in range(layers)] \
@@ -996,6 +1092,61 @@ def _rope_kind(c, kind, params):
     return found
 
 
+def _bailing_hybrid(c, published):
+    """The layer pattern of bailing_hybrid (Ling-3.0-flash) written into c:
+    by PUBLISHED index i a layer is latent attention where (i + 1) %
+    layer_group_size == 0, else a KDA mixer ("kda" among `mixer_layers`:
+    `kda` has the equations). A stack cut short says which published layers
+    it kept under `layer_indices` (absent: 0 .. num_hidden_layers - 1).
+    Refuses by name what the family's config can switch on and `kda` does
+    not build (BAILING_ONLY), a non-zero clamp of a kept layer's experts
+    (expert_swiglu_limit_list, share_expert_swiglu_limit_list), and what a
+    KDA layer cannot stand beside."""
+    layers, group = c["num_hidden_layers"], int(c["layer_group_size"])
+    for key, want in BAILING_ONLY + (
+            ("total_ut_steps", 1), ("hc_mult", 1), ("sandwich_norm", False),
+            ("mb_per_layer", 0), ("rope_layout", None),
+            ("sliding_window_layout", None), ("full_attention_interval", 1),
+            ("norm_type", "rms_norm")):
+        if c.get(key, want) != want:
+            raise NotImplementedError(
+                "causal_lm builds layer_group_size (KDA mixers and latent "
+                "attention) with %s=%r only, the config has %r"
+                % (key, want, c[key]))
+    if group < 1 or not c["latent"]:
+        raise NotImplementedError(
+            "causal_lm builds layer_group_size %r as KDA mixers with latent "
+            "attention on every layer_group_size-th layer: kv_lora_rank, "
+            "qk_nope_head_dim, qk_rope_head_dim and v_head_dim are needed"
+            % (c["layer_group_size"],))
+    indices = _layer_indices(c, published.get("num_hidden_layers", layers))
+    for key in ("expert_swiglu_limit_list",
+                "share_expert_swiglu_limit_list"):
+        limits = list(c.get(key) or [])
+        clamped = [i for i in indices if i < len(limits) and limits[i]]
+        if clamped:
+            raise NotImplementedError(
+                "causal_lm builds experts without a clamp on the SwiGLU: %s "
+                "is not 0 on the kept layers %s" % (key, clamped))
+    for key in ("kda_lower_bound", "short_conv_kernel_size"):
+        if key not in c:
+            raise ValueError("layer_group_size makes KDA mixers, which need "
+                             "%s" % key)
+    if not -5.9 < c["kda_lower_bound"] < 0:
+        raise NotImplementedError(
+            "causal_lm builds kda_lower_bound in (-5.9, 0): the chunked "
+            "rule's exponentials over 16 rows are finite under that bound "
+            "(ops/kda_kernels.py); the config has %r"
+            % (c["kda_lower_bound"],))
+    if "qk_head_dim" in c and c["qk_head_dim"] != \
+            c["qk_nope_head_dim"] + c["qk_rope_head_dim"]:
+        raise ValueError("qk_head_dim %r is not qk_nope_head_dim + "
+                         "qk_rope_head_dim" % (c["qk_head_dim"],))
+    c["layer_indices"] = indices
+    c["mixer_layers"] = ["attention" if (i + 1) % group == 0 else "kda"
+                         for i in indices]
+
+
 def _one_branch_layers(c, pattern, published):
     """`mixer_layers` and `ffn_layers` of hybrid_override_pattern
     (nemotron_h): a letter a layer, and a layer is ONE branch, h + f(N(h)),
@@ -1070,6 +1221,19 @@ def _mamba2(c, published):
                          % (c["mamba_n_heads"], c["mamba_n_groups"]))
 
 
+def _layer_indices(c, published):
+    """The published index of every layer a stack kept (`layer_indices`;
+    absent: 0 .. num_hidden_layers - 1), held to `published` layers: as
+    many as the stack has, rising, under the published depth."""
+    layers = c["num_hidden_layers"]
+    indices = [int(i) for i in c.get("layer_indices", range(layers))]
+    if len(indices) != layers or sorted(set(indices)) != indices \
+            or indices[-1] >= published:
+        raise ValueError("layer_indices %r: %d rising published indices "
+                         "under %d are needed" % (indices, layers, published))
+    return indices
+
+
 def _decoder_hybrid_decoder(c, published):
     """The layer pattern of SambaY (arXiv:2507.06607; `model_type:
     phi4flash`) written into c, by PUBLISHED index i of `published` layers,
@@ -1084,7 +1248,7 @@ def _decoder_hybrid_decoder(c, published):
     `layer_indices` (absent: 0 .. num_hidden_layers - 1). lambda_init of a
     differential attention is 0.8 - 0.6 exp(-0.3 i)."""
     import math
-    layers, mb = c["num_hidden_layers"], c["mb_per_layer"]
+    mb = c["mb_per_layer"]
     for key, want in (("total_ut_steps", 1), ("hc_mult", 1),
                       ("mtp_layers", 0), ("num_experts", 0),
                       ("latent", False), ("qk_norm", False),
@@ -1096,11 +1260,7 @@ def _decoder_hybrid_decoder(c, published):
                 "causal_lm builds mb_per_layer %d (Mamba mixers, a memory "
                 "and keys and values that later layers read) with %s=%r "
                 "only, the config has %r" % (mb, key, want, c.get(key)))
-    indices = [int(i) for i in c.get("layer_indices", range(layers))]
-    if len(indices) != layers or sorted(set(indices)) != indices \
-            or indices[-1] >= published:
-        raise ValueError("layer_indices %r: %d rising published indices "
-                         "under %d are needed" % (indices, layers, published))
+    indices = _layer_indices(c, published)
     half = published // 2
     if half % mb or (half + 1) % mb == 0:
         raise NotImplementedError(
@@ -1314,7 +1474,11 @@ def latent_attention(x, pos, c):
     q_rope, k_nope and v are matmuls' own results and the core
     (layers.fused_attention's latent form) reads them where they lie.
     Rotary pairs are interleaved (2i, 2i + 1) with rope_interleaved, and
-    the table is YaRN's where rope_scaling says so."""
+    the table is YaRN's where rope_scaling says so. With q_lora_rank null
+    (DeepSeek-V2-Lite's form) q = x W_q, `wq` [D, H (dn + dr)], and there is
+    no wq_a and no q_a_norm; with attention_gate "per_head" the core's
+    output is multiplied by sigmoid(x W_g) [B, T, H] before W_o (`wg`, made
+    before `wo`, as `attention` makes it)."""
     layers = fluid.layers
     h, d = c["num_attention_heads"], c["hidden_size"]
     dn, dr, dv = (c[key] for key in ("qk_nope_head_dim", "qk_rope_head_dim",
@@ -1325,8 +1489,12 @@ def latent_attention(x, pos, c):
         return layers.create_parameter(shape, "float32",
                                        attr=_matrix(c, role))
 
-    cq = _norm(_linear(x, rq, c, "wq_a"), c, "q_a_norm")
-    w_nope, w_rope = _head_columns(weight("wq_b", [rq, h * (dn + dr)]), h,
+    if rq is None:
+        # DeepSeek-V2-Lite's form: q = x W_q, one matrix, no low rank
+        cq, role, rq = x, "wq", d
+    else:
+        cq, role = _norm(_linear(x, rq, c, "wq_a"), c, "q_a_norm"), "wq_b"
+    w_nope, w_rope = _head_columns(weight(role, [rq, h * (dn + dr)]), h,
                                    (dn, dr))
     q = layers.reshape(layers.matmul(cq, w_nope), shape=[0, -1, h, dn])
     q_rope = layers.reshape(layers.matmul(cq, w_rope), shape=[0, -1, h, dr])
@@ -1347,6 +1515,9 @@ def latent_attention(x, pos, c):
     ctx = layers.fused_attention(
         q, k, v, causal=True, window=c["window"], q_rope=q_rope,
         k_rope=k_rope, scale=c["attention_scale"] or (dn + dr) ** -0.5)
+    if c["attention_gate"] == "per_head":
+        ctx = layers.elementwise_mul(
+            ctx, layers.sigmoid(_linear(x, h, c, "wg")), axis=0)
     return _linear(layers.reshape(ctx, shape=[0, -1, h * dv]), d, c, "wo")
 
 
@@ -1419,6 +1590,55 @@ def gated_delta_net(x, c):
         gate=fluid.layers.reshape(z, shape=[0, -1, hv, dv]))
     return _linear(fluid.layers.reshape(o, shape=[0, -1, hv * dv]),
                    c["hidden_size"], c, "w_out")
+
+
+def kda(x, c):
+    """Kimi Delta Attention (Kimi Linear, arXiv:2510.26692) over x [B, T,
+    D] as bailing_hybrid's config switches it (no_kda_lora, kda_safe_gate,
+    linear_silu, use_qk_norm), H = num_attention_heads heads of d_k = d_v =
+    head_dim: q~, k~, v = SiLU(conv(x W_q)), SiLU(conv(x W_k)), SiLU(conv(x
+    W_v)), each a causal depthwise convolution of short_conv_kernel_size
+    taps with a filter of its own; a = x W_f + dt_bias, one number a key
+    CHANNEL (W_f [D, H d_k], full rank); the log decay g = kda_lower_bound x
+    sigmoid(exp(A_log_h) a), in (kda_lower_bound, 0), float32; beta =
+    sigmoid(x W_beta), one a head; the delta rule with the decay a channel
+    (layers.kda_delta_rule: q and k l2-normalised there, q over sqrt(d_k));
+    y = [N_head(o) * sigmoid(x W_g)] W_o, the norm over a head's d_v with one
+    weight [d_v] all heads share, the gate a channel (W_g [D, H d_v], full
+    rank). Parameters, in this order: wq, conv_q, wk, conv_k, wv, conv_v,
+    wf, dt_bias [H d_k], a_log [H], wbeta, o_norm, wg, wo. A_log starts at
+    log(U(1, 16)) a head; dt_bias at U(kda_dt_bias_range), a key of the
+    config's own ((-1, 0) unless given), from KDA_DT_SEED's stream; the
+    norm's weight at 1."""
+    import numpy as np
+    layers, init = fluid.layers, fluid.initializer
+    h, dk, d = c["num_attention_heads"], c["head_dim"], c["hidden_size"]
+    taps = c["short_conv_kernel_size"]
+    q, k, v = (layers.reshape(layers.causal_conv1d(
+        _linear(x, h * dk, c, "w" + name), taps, act="silu",
+        param_attr=_matrix(c, "conv_" + name)), shape=[0, -1, h, dk])
+        for name in "qkv")
+    a = layers.cast(_linear(x, h * dk, c, "wf"), "float32")
+    low, high = c.get("kda_dt_bias_range", (-1.0, 0.0))
+    dt_bias = layers.create_parameter(
+        [h * dk], "float32", attr=_attr(
+            c, "dt_bias", init.NumpyArrayInitializer(
+                np.random.RandomState(KDA_DT_SEED + c["layer"]).uniform(
+                    low, high, h * dk).astype("float32"))))
+    a = layers.reshape(a + dt_bias, shape=[0, -1, h, dk])
+    a_log = layers.create_parameter(
+        [h], "float32", attr=_attr(c, "a_log", init.LogUniform(1.0, 16.0)))
+    g = layers.scale(layers.sigmoid(
+        layers.elementwise_mul(a, layers.exp(a_log), axis=2)),
+        scale=float(c["kda_lower_bound"]))
+    beta = layers.sigmoid(layers.cast(_linear(x, h, c, "wbeta"), "float32"))
+    o = layers.kda_delta_rule(q, k, v, g, beta)
+    o = layers.rms_norm(o, epsilon=c["rms_norm_eps"],
+                        param_attr=_attr(c, "o_norm"))
+    gate = layers.reshape(layers.sigmoid(_linear(x, h * dk, c, "wg")),
+                          shape=[0, -1, h, dk])
+    return _linear(layers.reshape(o * gate, shape=[0, -1, h * dk]), d, c,
+                   "wo")
 
 
 def short_conv(x, c):
@@ -1702,7 +1922,9 @@ def feed_forward(x, c, router_input=None):
             scoring=c["router_scoring"],
             expert_bias_attr=bias,
             routed_scaling_factor=c["routed_scaling_factor"],
-            norm_epsilon=c["router_renorm_epsilon"], gated=c["ffn_gated"])
+            norm_epsilon=c["router_renorm_epsilon"], gated=c["ffn_gated"],
+            n_group=c["n_group"] if c["group_limited"] else 1,
+            topk_group=c["topk_group"] if c["group_limited"] else 1)
         if c["moe_latent_size"]:
             out = _linear(out, c["hidden_size"], c, "latent_up")
         if c["shared_expert_intermediate_size"]:
@@ -1747,11 +1969,18 @@ def _count_layer(c, mixer, module="trunk"):
         "(default, yarn, none), rotary_dim then the layer's own; gate is "
         "per_head where the gate is a scalar a head; and, for a config with "
         "objective block_diffusion alone, mask block_diffusion and the "
-        "block_length of the mask its attention runs under"
+        "block_length of the mask its attention runs under; and, for a "
+        "layer of routed experts whose router's choice is limited to groups "
+        "alone, `groups` (the runs of neighbouring experts) and "
+        "`kept_groups` (those a token's top k is taken inside); mixer kda: "
+        "a delta rule whose decay is a key channel's"
     ).inc(mixer=mixer, module=module, reads=c["reads"],
           **({} if c["block_diffusion"] is None else dict(
               mask="block_diffusion",
               block_length=str(c["block_diffusion"]["block_length"]))),
+          **({} if not (c["group_limited"] and c["ffn"] == "experts")
+             else dict(groups=str(c["n_group"]),
+                       kept_groups=str(c["topk_group"]))),
           **({} if not c["geometry_by_layer"] else dict(
               heads=str(c["num_attention_heads"] if attention else 0),
               kv_heads=str(c["num_key_value_heads"] if attention else 0),
@@ -1770,6 +1999,7 @@ def _count_layer(c, mixer, module="trunk"):
           gate="per_head" if attention and c["attention_gate"] == "per_head"
           else str(bool(attention and c["attention_gate"])).lower(),
           conv=str(0 if attention or mixer in ("gmu", "none")
+                   else c["short_conv_kernel_size"] if mixer == "kda"
                    else c["conv_L_cache"]
                    if mixer == "short_conv" else c["mamba_d_conv"]
                    if mixer in ("mamba", "mamba2")
@@ -1956,6 +2186,7 @@ def causal_lm(cfg, seq_len, extras=None, recompute=True):
                 else mamba(a, cl) if mixer == "mamba" \
                 else mamba2(a, cl) if mixer == "mamba2" \
                 else gated_memory_unit(a, cl) if mixer == "gmu" \
+                else kda(a, cl) if mixer == "kda" \
                 else gated_delta_net(a, cl)
             if c["sandwich_norm"]:
                 mixed = _norm(mixed, cl, "mixer_out_norm")

@@ -153,13 +153,18 @@ def yarn_inv_freq(scaling, theta, d):
 
 
 def latent_attention(a, pos, wq_a, q_a_norm, wq_b, wkv_a, kv_a_norm, wkv_b,
-                     wo, c):
+                     wo, c, w_gate=None):
     """DeepseekV3Attention on a [B, T, D], the naive way: q = N(a W_qa)
     W_qb, a head [q_nope; q_rope]; [c_kv; k_r] = a W_kva; kv = N(c_kv)
     W_kvb, a head [k_nope; v]; rotary turns q_rope of every head and k_r,
     which is then repeated to every head and concatenated behind its
     k_nope; a dense causal softmax over scale x q . k with scale =
     (dn + dr)^(-1/2) m^2, m = yarn_mscale(factor, mscale_all_dim); P v; W_o.
+
+    With wq_a None (q_lora_rank null, DeepSeek-V2-Lite's form) q = a W_qb,
+    one matrix; with w_gate [D, H] (bailing_hybrid's head_wise gate) the
+    core's output is multiplied by sigmoid(a w_gate), one scalar a head,
+    before W_o.
 
     Departure: HF permutes q_rope's and k_r's channels from interleaved
     pairs to halves and then applies rotate_half; the scores are the same
@@ -169,7 +174,8 @@ def latent_attention(a, pos, wq_a, q_a_norm, wq_b, wkv_a, kv_a_norm, wkv_b,
     eps = c["rms_norm_eps"]
     h = wq_b.shape[1] // (dn + dr)
     scaling = c["rope_scaling"]
-    q = (rms_norm(a @ wq_a, q_a_norm, eps) @ wq_b).reshape(b, t, h, dn + dr)
+    q = ((a if wq_a is None else rms_norm(a @ wq_a, q_a_norm, eps))
+         @ wq_b).reshape(b, t, h, dn + dr)
     ckv = a @ wkv_a
     kv = (rms_norm(ckv[..., :c["kv_lora_rank"]], kv_a_norm, eps)
           @ wkv_b).reshape(b, t, h, dn + dv)
@@ -192,6 +198,8 @@ def latent_attention(a, pos, wq_a, q_a_norm, wq_b, wkv_a, kv_a_norm, wkv_b,
     s = jnp.einsum("bqhd,bkhd->bhqk", q, k) * scale
     s = jnp.where(jnp.tril(jnp.ones((t, t), bool)), s, -jnp.inf)
     ctx = jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s, -1), kv[..., dn:])
+    if w_gate is not None:
+        ctx = ctx * jax.nn.sigmoid(a @ w_gate)[..., None]
     return ctx.reshape(b, t, h * dv) @ wo, (q, k)
 
 
@@ -531,6 +539,91 @@ def gated_delta_net(a, w_qkvz, w_ba, w_conv, dt_bias, a_log, w_norm, w_out,
     return o.reshape(b, t, hv * dv) @ w_out
 
 
+def kda_step(state, xs):
+    """One token of the delta rule with a decay a key channel on a head's
+    state [B, H, dk, dv]: S' = Diag(exp(g_t)) S; S = S' + beta_t k_t (v_t -
+    S'^T k_t)^T; o_t = S^T q_t. -> (S, o_t)."""
+    qt, kt, vt, gt, bt = xs
+    state = state * jnp.exp(gt)[..., None]
+    written = vt - jnp.einsum("bhkv,bhk->bhv", state, kt)
+    state = state + kt[..., :, None] * (bt[..., None] * written)[..., None, :]
+    return state, jnp.einsum("bhkv,bhk->bhv", state, qt)
+
+
+def kda_rule(q, k, v, g, beta, found=None):
+    """The delta rule with a decay a key channel, token by token
+    (`kda_step`): q, k [B, T, H, dk] (already normalised and scaled), v [B,
+    T, H, dv], g [B, T, H, dk] the log decay, beta [B, T, H] -> o [B, T, H,
+    dv], a head's state S [dk, dv] from zero. The recurrence itself: it
+    shares no algebra with the chunked form the Program computes. A dict
+    given as `found` gets `kda_state`, the state after the last token."""
+    b, _, h, dk = q.shape
+    last, o = jax.lax.scan(
+        kda_step, jnp.zeros((b, h, dk, v.shape[-1]), q.dtype),
+        tuple(jnp.moveaxis(x, 1, 0) for x in (q, k, v, g, beta)))
+    if found is not None:
+        found.setdefault("kda_state", last)
+    return jnp.moveaxis(o, 0, 1)
+
+
+def kda_gates(a, wf, dt_bias, a_log, wbeta, c):
+    """(g [B, T, H, dk], beta [B, T, H]) of a KDA layer on a [B, T, D]: one
+    decay a key channel from a full-rank projection, g = kda_lower_bound x
+    sigmoid(exp(A_log_h) (a W_f + dt_bias)) (kda_safe_gate: bounded below,
+    so that a chunked form's exponentials stay finite), and one write
+    strength a head, beta = sigmoid(a W_beta)."""
+    b, t, _ = a.shape
+    h = a_log.shape[0]
+    g = c["kda_lower_bound"] * jax.nn.sigmoid(
+        jnp.exp(a_log)[:, None] * (a @ wf + dt_bias).reshape(b, t, h, -1))
+    return g, jax.nn.sigmoid(a @ wbeta)
+
+
+def kda(a, wq, conv_q, wk, conv_k, wv, conv_v, wf, dt_bias, a_log, wbeta,
+        o_norm, wg, wo, c, found=None, rule=kda_rule):
+    """Kimi Delta Attention (arXiv:2510.26692) on a [B, T, D] as
+    bailing_hybrid's config switches it: q~, k~, v = SiLU(conv(a W)), three
+    causal depthwise convolutions; q = l2(q~) / sqrt(dk), k = l2(k~)
+    (use_qk_norm); the gates of `kda_gates`; the recurrence (`rule`: the
+    benchmark's copy hands in the same with checkpoints); y = [N_head(o) *
+    sigmoid(a W_g)] W_o, the norm over a head's dv under one weight [dv].
+    A dict given as `found` gets the first such layer's `kda_g`, `kda_out`
+    (the recurrence's output) and `kda_ctx` (what W_o reads: normed, then
+    gated)."""
+    b, t, _ = a.shape
+    h = a_log.shape[0]
+    q, k, v = (jax.nn.silu(causal_conv(a @ w, conv)).reshape(b, t, h, -1)
+               for w, conv in ((wq, conv_q), (wk, conv_k), (wv, conv_v)))
+    dk = q.shape[-1]
+    g, beta = kda_gates(a, wf, dt_bias, a_log, wbeta, c)
+    o = rule(l2norm(q) * dk ** -0.5, l2norm(k), v, g, beta, found=found)
+    if found is not None:
+        found.setdefault("kda_g", g)
+        found.setdefault("kda_out", o)
+    o = rms_norm(o, o_norm, c["rms_norm_eps"]) \
+        * jax.nn.sigmoid(a @ wg).reshape(o.shape)
+    if found is not None:
+        found.setdefault("kda_ctx", o)
+    return o.reshape(b, t, -1) @ wo
+
+
+def group_limited(choice, n_group, topk_group):
+    """DeepSeek-V3's group limit on a router's choice (`noaux_tc`,
+    arXiv:2412.19437), written out: choice [N, E], the scores with their
+    bias; the E experts are n_group runs of neighbours; a group's score is
+    the sum of its two largest entries; the topk_group best groups stay and
+    every entry outside them is minus infinity (out of the choice, as the
+    family's inference code has it: not 0, which a negative s + b would lose
+    to)."""
+    n, e = choice.shape
+    by_group = choice.reshape(n, n_group, e // n_group)
+    score = jax.lax.top_k(by_group, 2)[0].sum(-1)           # [N, n_group]
+    best = jax.lax.top_k(score, topk_group)[1]
+    kept = jnp.zeros((n, n_group), bool).at[
+        jnp.arange(n)[:, None], best].set(True)
+    return jnp.where(jnp.repeat(kept, e // n_group, axis=1), choice, -jnp.inf)
+
+
 def shared_expert(m, wg, wu, wd, ws=None):
     """sigmoid(m w_s) * SwiGLU(m): every token passes it; without w_s
     (DeepSeek-V3's shared experts) the SwiGLU as it is."""
@@ -564,7 +657,9 @@ def routed_experts(m, router, w_gate, w_up, w_down, c, router_x=None,
     chosen over s + expert_bias [E] where there is one and weighed by s
     itself, renormalised over the chosen with 1e-6 (router_renorm_epsilon
     where given: DeepSeek-V3's is 1e-20) added to their sum, and scaled by
-    routed_scaling_factor; the two auxiliary terms are 0.
+    routed_scaling_factor; the two auxiliary terms are 0. With n_group and
+    topk_group (c["group_limited"]) the top k is chosen inside a token's
+    best groups (`group_limited`).
 
     Departure: the expert bias is a buffer in `modeling_lfm2_moe.py`, moved
     during pre-training by a rule the config does not give; here it is an
@@ -576,7 +671,12 @@ def routed_experts(m, router, w_gate, w_up, w_down, c, router_x=None,
     logits = (m if router_x is None else router_x) @ router
     sigmoid = c.get("router_scoring", "softmax") == "sigmoid"
     probs = jax.nn.sigmoid(logits) if sigmoid else jax.nn.softmax(logits, -1)
-    if expert_bias is None:
+    if c.get("group_limited"):
+        # the top k inside the kept groups; the weights are the scores'
+        _, idx = jax.lax.top_k(group_limited(
+            probs + expert_bias, c["n_group"], c["topk_group"]), k)
+        gate = jnp.take_along_axis(probs, idx, axis=-1)
+    elif expert_bias is None:
         gate, idx = jax.lax.top_k(probs, k)
     else:
         _, idx = jax.lax.top_k(probs + expert_bias, k)
@@ -638,7 +738,8 @@ def passes(cfg, params, ids, pos, next_ids=None, found=None):
     norm P times, each pass on the normed state of the pass before: a
     Python loop over one set of weights, each read from `params` once. With
     sandwich_norm a layer is a = x + N2(mixer(N1(x))), y = a + N4(FFN(N3(
-    a))). With exit_gate, p is the exit distribution of lambda_t =
+    a))). A dict given as `found` gets `states`, the state after each
+    two-branch layer. With exit_gate, p is the exit distribution of lambda_t =
     sigmoid(h_t w_g + b_g); without it only the last pass has logits that
     count and p is None.
 
@@ -725,10 +826,17 @@ def passes(cfg, params, ids, pos, next_ids=None, found=None):
                 + with_bias(1, has)
         elif c["mixer_layers"][i] == "gated_delta":
             mixer = take(7)
+        elif c["mixer_layers"][i] == "kda":
+            mixer = take(13)
         elif c["mixer_layers"][i] == "short_conv":
             mixer = take(3)
         elif c["latent"]:
-            mixer = take(7)
+            # [wq_a, q_a_norm], wq_b (wq where q has no low rank), wkv_a,
+            # kv_a_norm, wkv_b, [the gate a head, made before wo], wo
+            mixer = (take(2) if c["q_lora_rank"] is not None
+                     else [None, None]) + take(4)
+            gate = take(1) if c["attention_gate"] == "per_head" else [None]
+            mixer = mixer + take(1) + gate
         else:
             # wq, wk, wv, [q_norm, k_norm], wo, then [the per-head gate],
             # which the program creates before wo
@@ -861,11 +969,14 @@ def passes(cfg, params, ids, pos, next_ids=None, found=None):
                         found["shared_k"], found["shared_v"] = kv
             elif c["mixer_layers"][i] == "gated_delta":
                 mixed = gated_delta_net(a, *mixer, c)
+            elif c["mixer_layers"][i] == "kda":
+                mixed = kda(a, *mixer, c, found=found)
             elif c["mixer_layers"][i] == "short_conv":
                 mixed = short_conv(a, *mixer)
             elif c["latent"]:
-                mixed, _ = latent_attention(a, pos, *mixer,
-                                            layer_config(c, i))
+                mixed, _ = latent_attention(a, pos, *mixer[:7],
+                                            layer_config(c, i),
+                                            w_gate=mixer[7])
             else:
                 mixed = attention(a, pos, *mixer[:6], layer_config(c, i),
                                   *mixer[6:], found=found)
@@ -899,6 +1010,8 @@ def passes(cfg, params, ids, pos, next_ids=None, found=None):
                 out = (jax.nn.silu(m @ wg) * (m @ wu)) @ wd
             if sandwich:
                 out = rms_norm(out, n4, eps, centred)
+            if found is not None:
+                found.setdefault("states", []).append(write(branch * out))
             return write(branch * out), terms
 
         terms = (balance, z, load)

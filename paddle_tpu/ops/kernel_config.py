@@ -21,7 +21,7 @@ Accepted forms of PADDLE_TPU_PALLAS:
                           rest off.  Unknown names raise LOUDLY (a typo
                           must not silently run the other path).
 Op names: attn, xent, ln, lstm, seq, gdr, conv, emb, mhc, gmm, scan, ssd,
-rope (KERNEL_OPS).  For 'attn' the flag is an opt-OUT only:
+rope, kda (KERNEL_OPS).  For 'attn' the flag is an opt-OUT only:
 fused_attention's positive dispatch is always the flash_at() rule, so
 enabling 'attn' does not force flash below the crossover (pin
 FLAGS_flash_min_seq=0 for that).
@@ -134,7 +134,9 @@ __all__ = [
 # 0.299 at 2 MiB (128), in flight from the host, which reads 0.20 ms for
 # a call of 8 MiB: in the cell's trace a q call is 0.18 ms.  Flat: the
 # bytes' pace.  At 2 MiB x's and the tables' blocks, each held twice by
-# Mosaic, and the result's stay under 8 MiB of its 16 MiB of VMEM.
+# Mosaic, and the result's stay under 8 MiB of its 16 MiB of VMEM.  "kda"
+# (ops/kda_kernels.py) starts from "gdr"'s pair, whose kernels its own are
+# with the state transposed: not swept yet (PERF.md section 7, PR 71).
 DEFAULT_TILES = {
     "attn": {"block_q": 512, "block_k": 512},
     "xent": {"tile_bytes": 1 << 20},
@@ -149,6 +151,7 @@ DEFAULT_TILES = {
     "scan": {"chunk": 64},
     "ssd": {"chunk": 128, "block_h": 16},
     "rope": {"tile_bytes": 2 << 20},
+    "kda": {"chunk": 64, "block_h": 8},
 }
 KERNEL_OPS = frozenset(DEFAULT_TILES)
 # Dense attention below this query length, flash at and above it.  The

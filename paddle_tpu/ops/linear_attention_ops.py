@@ -1,5 +1,6 @@
 """Lowerings of the linear-attention mixer's ops: the gated delta rule
-(ops/gated_delta_kernels.py), the short causal depthwise convolution
+(ops/gated_delta_kernels.py), the delta rule with a decay a key channel
+(ops/kda_kernels.py), the short causal depthwise convolution
 over time that feeds it (ops/causal_conv_kernels.py), a Mamba mixer's
 selective scan (ops/selective_scan_kernels.py) and a Mamba-2 mixer's
 state-space-dual scan (ops/ssd_kernels.py). No reference-era op computes
@@ -29,6 +30,28 @@ def _gated_delta_rule(ctx, ins, attrs):
         *(single(ins, name) for name in ("Q", "K", "V", "G", "Beta")),
         operand_dtype=jnp.bfloat16 if getattr(ctx, "amp", False) else None,
         path=gated_delta_path())
+    return {"Out": [out]}
+
+
+def kda_path():
+    """"kernel" where the channel-decay delta rule's Pallas kernels are on
+    (kernel_config.pallas_on("kda"): a TPU, or PADDLE_TPU_PALLAS), else
+    "scan". The one place that decides; the layer counter reads it too."""
+    return "kernel" if pallas_on("kda") else "scan"
+
+
+@register("kda_delta_rule", calls_pallas=True, infer=shapes_from(Out="V"))
+def _kda_delta_rule(ctx, ins, attrs):
+    """Out [B, T, H, dv] of the delta rule whose decay is a key channel's
+    (Kimi Delta Attention) for Q, K [B, T, H, dk], V [B, T, H, dv], G [B, T,
+    H, dk] (log decay, above -5.9 a token) and Beta [B, T, H]. Under AMP the
+    matmuls take bf16 operands; G, Beta, the running sums, exponentials and
+    the state are float32 either way, so the op is in neither AMP table."""
+    from .kda_kernels import kda_delta_rule
+    out = kda_delta_rule(
+        *(single(ins, name) for name in ("Q", "K", "V", "G", "Beta")),
+        operand_dtype=jnp.bfloat16 if getattr(ctx, "amp", False) else None,
+        path=kda_path())
     return {"Out": [out]}
 
 

@@ -2094,3 +2094,7 @@ KERNEL_NAMES += EXPERT_MATMUL_KERNELS[-1:] + ("ptpu_expert_rows_unwritten",)
 # PR 70: ops/rotary_kernels.py's one pass over a rotary_embedding's heads,
 # forward and (at the negated angle) backward.
 KERNEL_NAMES += ("ptpu_rotary",)
+# PR 71: ops/kda_kernels.py's pass over chunks and its reverse (the delta
+# rule with a decay a key channel).
+KDA_KERNELS = ("ptpu_kda_fwd", "ptpu_kda_bwd")
+KERNEL_NAMES += KDA_KERNELS

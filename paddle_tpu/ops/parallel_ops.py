@@ -143,7 +143,9 @@ def _moe_ffn_lower(ctx, ins, attrs):
     held, `first_expert` the index of the first. `scoring` (softmax where
     absent, or sigmoid), ExpertBias [E] (added to the scores for the choice
     of the top_k alone; an input without a gradient variable), `scale` and
-    `norm_epsilon` (routed_ffn's norm_eps) are routed_ffn's."""
+    `norm_epsilon` (routed_ffn's norm_eps) are routed_ffn's, and `n_group`
+    with `topk_group` its `groups`, the group limit on the choice (absent:
+    none)."""
     from ..parallel.moe import routed_ffn
     x = single(ins, "X")
     router_x = single(ins, "RouterX") if ins.get("RouterX") else None
@@ -162,7 +164,9 @@ def _moe_ffn_lower(ctx, ins, attrs):
         expert_bias=single(ins, "ExpertBias") if ins.get("ExpertBias")
         else None,
         scale=float(attrs.get("scale", 1.0)),
-        norm_eps=attrs.get("norm_epsilon"), mesh=ctx.mesh)
+        norm_eps=attrs.get("norm_epsilon"), mesh=ctx.mesh,
+        groups=(int(attrs["n_group"]), int(attrs["topk_group"]))
+        if attrs.get("n_group") else None)
     return {"Out": [out.reshape(x.shape)], "BalanceLoss": [balance],
             "ZLoss": [z], "ExpertLoad": [load]}
 

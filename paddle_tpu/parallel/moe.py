@@ -846,11 +846,41 @@ def _chosen(probs, expert):
 SIGMOID_NORM_EPS = 1e-6
 
 
+def _group_limited(choice, groups):
+    """`choice` [N, E] (the scores the top k is taken over) with every
+    expert outside a token's kept groups at -inf: DeepSeek-V3's
+    group-limited choice (`noaux_tc`, arXiv:2412.19437). groups = (n_group,
+    topk_group): the E experts are n_group runs of E / n_group neighbours; a
+    group's score is the sum of its two largest entries; the topk_group
+    best groups stay (the lower index where two are level, as lax.top_k
+    has it). The two largest by two max passes, the second over the row
+    with the FIRST largest taken out, so two level entries count twice as
+    a sort's top 2 would; kept is a compare of the chosen groups against
+    the groups' axis: no gather, no scatter (`_chosen` says why)."""
+    n_group, topk_group = groups
+    n, e = choice.shape
+    by_group = choice.reshape(n, n_group, e // n_group)
+    first = jnp.argmax(by_group, axis=-1)
+    within = jax.lax.broadcasted_iota(jnp.int32, by_group.shape, 2)
+    second = jnp.where(within == first[..., None], -jnp.inf, by_group)
+    score = by_group.max(-1) + second.max(-1)               # [N, n_group]
+    _, best = jax.lax.top_k(score, topk_group)
+    kept = (best[:, :, None] == jnp.arange(n_group)).any(1)
+    return jnp.where(kept[:, :, None], by_group, -jnp.inf).reshape(n, e)
+
+
 def _route(logits, top_k, norm_topk_prob, scoring, expert_bias, scale,
-           norm_eps=None):
+           norm_eps=None, groups=None):
     """(scores [N, E], the logits' logsumexp [N] or None under sigmoid, the
     chosen experts' weights [N, top_k], their indices) from float32 router
-    logits [N, E]: `routed_ffn`'s choice, by its docstring."""
+    logits [N, E]: `routed_ffn`'s choice, by its docstring. `groups`
+    (n_group, topk_group), where given, limits the choice to a token's best
+    groups (`_group_limited`). What that does to a SHARE of the experts: the
+    held experts are neighbours (first_expert .. first_expert + H - 1), so
+    they lie in one group or a few (Ling-3.0-flash's 0-7 of 512 in group 0
+    of 8), and a token whose kept groups exclude theirs has no assignment
+    here at all, whatever its scores for the held experts are: it gets
+    zeros from this chip, as a token none of whose top k is held does."""
     if scoring not in ("softmax", "sigmoid"):
         raise ValueError("routed_ffn scoring must be 'softmax' or 'sigmoid', "
                          "got %r" % (scoring,))
@@ -860,9 +890,11 @@ def _route(logits, top_k, norm_topk_prob, scoring, expert_bias, scale,
     else:
         lse, probs = None, jax.nn.sigmoid(logits)
     # the bias is an argument of the choice and nothing else
-    _, expert = jax.lax.top_k(
-        probs if expert_bias is None
-        else probs + expert_bias.astype(jnp.float32), top_k)   # [N, top_k]
+    choice = probs if expert_bias is None \
+        else probs + expert_bias.astype(jnp.float32)
+    if groups is not None:
+        choice = _group_limited(choice, groups)
+    _, expert = jax.lax.top_k(choice, top_k)                   # [N, top_k]
     gate = _chosen(probs, expert)
     if norm_topk_prob:
         total = gate.sum(-1, keepdims=True)
@@ -878,7 +910,7 @@ def _route(logits, top_k, norm_topk_prob, scoring, expert_bias, scale,
 def routed_ffn(x, router, w_gate, w_up, w_down, top_k, norm_topk_prob=False,
                expert_dtype=None, router_x=None, activation="silu",
                first_expert=0, scoring="softmax", expert_bias=None,
-               scale=1.0, norm_eps=None, mesh=None):
+               scale=1.0, norm_eps=None, mesh=None, groups=None):
     """Dropless top-k routed gated experts over tokens x [N, D].
 
     router [D, E]; w_gate, w_up [H, D, F]; w_down [H, F, D]; no bias. The
@@ -1011,6 +1043,17 @@ def routed_ffn(x, router, w_gate, w_up, w_down, top_k, norm_topk_prob=False,
     DeepSeek-V3's is 1e-20). `scale` multiplies the weights last. Under sigmoid scoring the balance and z terms are zeros: both are
     defined on a softmax's probabilities and its logsumexp.
 
+    `groups` (n_group, topk_group), where given, is DeepSeek-V3's group
+    limit on the choice (`_group_limited`): the top k is taken inside a
+    token's topk_group best of n_group runs of neighbouring experts, a
+    group scored by the sum of its two largest s + b. In a share the held
+    experts are neighbours and so lie in few groups (0-7 of 512 all in
+    group 0 of 8): a token whose kept groups exclude them has no assignment
+    here and gets zeros from this chip, its weights for the experts it did
+    choose renormalised over those as on every chip; `load` counts its
+    assignments where they went. The shares still add up to the whole
+    layer: the choice is every chip's own and the same on all of them.
+
     Returns (out [N, D] in the experts' dtype,
              load-balance term [1]: E * sum_e (c_e / N) * mean_n p[n, e],
              router z term [1]: mean_n logsumexp(logits[n])^2,
@@ -1030,7 +1073,8 @@ def routed_ffn(x, router, w_gate, w_up, w_down, top_k, norm_topk_prob=False,
     # a `_route` of the six arguments above in this one's place
     probs, lse, gate, expert = _route(
         logits, top_k, norm_topk_prob, scoring, expert_bias, scale,
-        **({} if norm_eps is None else {"norm_eps": norm_eps}))
+        **({} if norm_eps is None else {"norm_eps": norm_eps}),
+        **({} if groups is None else {"groups": groups}))
 
     # assignment a = j * N + n (slot-major); `order` lists the assignments by
     # expert (stable, so by slot then token inside an expert), `rank` is

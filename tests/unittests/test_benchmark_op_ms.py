@@ -289,10 +289,16 @@ def _new_entries():
 
 def test_the_new_entries_end_the_manifest_on_their_lists():
     m, _ = _new_entries()
-    assert tuple(e["name"] for e in m["per_layer"][-len(NEW):]) == NEW
+    # PR 69's eight ended the list; PR 71's four stand behind them
+    names = [e["name"] for e in m["per_layer"]]
+    first = names.index(NEW[0])
+    assert tuple(names[first:first + len(NEW)]) == NEW
+    assert names[first + len(NEW):] == [
+        "kda_ms_per_step", "kda_roofline_share", "kda_layer_share",
+        "group_limited_router_layer_share"]
     by_name = {e["name"]: e for e in m["per_layer"]}
     experts = by_name["expert_matmul_ms_per_step"]["workloads"]
-    assert len(experts) == 9
+    assert len(experts) == 10       # PR 71's cell behind PR 69's nine
     for name in NEW:
         e = by_name[name]
         assert e["source"] == "device_trace" and e["layer"] == "op lowerings"

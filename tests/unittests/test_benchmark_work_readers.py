@@ -52,6 +52,10 @@ LAGUNA_METRICS = ("windowed_layer_share", "per_head_gate_layer_share")
 SDAR = "sdar_30b_a3b_chat_train_t4096"
 # the two metrics PR 66 added with its cell
 SDAR_METRICS = ("block_diffusion_layer_share", "ffn_rows_per_token")
+LING = "ling_3_0_flash_train_t4096"
+# the four metrics PR 71 added with its cell
+LING_METRICS = ("kda_ms_per_step", "kda_roofline_share", "kda_layer_share",
+                "group_limited_router_layer_share")
 
 
 def test_glm_flash_operations_against_the_hand_count():
@@ -122,7 +126,8 @@ def test_the_pinned_manifest_test_is_red_for_the_eleventh_cell_alone(
     (Nemotron-3-Super's), its configuration and its two metrics, and the
     fifteenth (Laguna-S-2.1's), its configuration and its two metrics, and
     the sixteenth (SDAR-30B-A3B-Chat's), its configuration and its two
-    metrics: nothing else it holds has moved."""
+    metrics, and the seventeenth (Ling-3.0-flash's), its configuration and
+    its four metrics: nothing else it holds has moved."""
     pinned = getattr(readers, PINNED)
     with pytest.raises(AssertionError, match="^expert_matmul_ms_per_step$"):
         pinned()
@@ -130,21 +135,22 @@ def test_the_pinned_manifest_test_is_red_for_the_eleventh_cell_alone(
         bench = json.load(f)
     bench["workloads"] = [w for w in bench["workloads"]
                           if w["name"] not in (GLM, PHI, GRANITE, NEMOTRON,
-                                               LAGUNA, SDAR)]
+                                               LAGUNA, SDAR, LING)]
     bench["configs"] = [c for c in bench["configs"]
                         if c["name"] not in ("glm_4_7_flash",
                                              "phi4_mini_flash",
                                              "granite_4_0_h_micro",
                                              "nemotron_3_super_120b_a12b",
                                              "laguna_s_2_1",
-                                             "sdar_30b_a3b_chat")]
+                                             "sdar_30b_a3b_chat",
+                                             "ling_3_0_flash")]
     bench["per_layer"] = [m for m in bench["per_layer"]
                           if m["name"] not in ("mtp_layer_share",)
                           + PHI_METRICS + GRANITE_METRICS
                           + NEMOTRON_METRICS + LAGUNA_METRICS
-                          + SDAR_METRICS]
+                          + SDAR_METRICS + LING_METRICS]
     for metric in bench["end_to_end"] + bench["per_layer"]:
-        for cell in (GLM, PHI, GRANITE, NEMOTRON, LAGUNA, SDAR):
+        for cell in (GLM, PHI, GRANITE, NEMOTRON, LAGUNA, SDAR, LING):
             if cell in metric.get("workloads", ()):
                 metric["workloads"].remove(cell)
     without = tmp_path / "BENCHMARK.json"
@@ -201,8 +207,8 @@ def test_the_manifest_lists_the_work_readers_in_eleven_cells():
     # the 8192 rows in VMEM)
     for name in ("flash_roofline_share", "embedding_grad_ms_per_step",
                  "embedding_grad_roofline_share", "step_mfu"):
-        assert entries[name]["workloads"][-5:] == [PHI, GRANITE, NEMOTRON,
-                                                   LAGUNA, SDAR], name
+        assert entries[name]["workloads"][-6:] == [PHI, GRANITE, NEMOTRON,
+                                                   LAGUNA, SDAR, LING], name
     phi = readers._cell(PHI)
     pairs = (512 * 513 // 2 + (8192 - 512) * 512) + 2 * (8192 * 8193 // 2)
     assert phi.config_module.flash_kernel_ops(phi.config, phi.traffic) == {
@@ -245,13 +251,13 @@ def test_the_manifest_lists_the_work_readers_in_eleven_cells():
     # one group, five layers; and TWO matmuls an expert of [1024 x 2688]
     # (configs/causal_lm.py's three would read 1.5 times the work)
     for name in ("expert_matmul_ms_per_step", "expert_matmul_roofline_share"):
-        assert entries[name]["workloads"][-4:] == [GLM, NEMOTRON, LAGUNA,
-                                                   SDAR], name
+        assert entries[name]["workloads"][-5:] == [GLM, NEMOTRON, LAGUNA,
+                                                   SDAR, LING], name
     for name in ("pallas_ms_per_step", "softmax_xent_ms_per_step",
                  "flash_fwd_ms_per_step", "flash_bwd_dkdv_ms_per_step",
                  "flash_bwd_dq_ms_per_step"):
-        assert entries[name]["workloads"][-4:] == [GRANITE, NEMOTRON,
-                                                   LAGUNA, SDAR], name
+        assert entries[name]["workloads"][-5:] == [GRANITE, NEMOTRON,
+                                                   LAGUNA, SDAR, LING], name
     nemotron = readers._cell(NEMOTRON)
     mod = nemotron.config_module
     pairs = 4096 * 4097 // 2
@@ -331,6 +337,37 @@ def test_the_manifest_lists_the_work_readers_in_eleven_cells():
     assert sum(mod.forward_macs(sdar.config, sdar.traffic)
                .values()) == pytest.approx(333.9e6, rel=1e-3)
     assert mod.samples_per_step(sdar.config, sdar.traffic) == 4096
+    # the seventeenth cell behind that one on the same lists (not on
+    # SDAR's own two), with its module's hand counts: ONE latent core of 8
+    # heads, 192 wide on the scores and 128 on the values, over the causal
+    # pairs; the table of 39296 words of 2560 written and 4096 rows read;
+    # three matmuls an expert of [2560 x 768]; the two KDA kernels' tiles
+    sdar_lists = {m["name"] for m in bench["per_layer"]
+                  if SDAR in m.get("workloads", ())}
+    ling_lists = {m["name"] for m in bench["per_layer"]
+                  if LING in m.get("workloads", ())}
+    assert ling_lists == (sdar_lists - set(SDAR_METRICS)) | set(LING_METRICS)
+    assert [m["workloads"] for m in bench["per_layer"]
+            if m["name"] in LING_METRICS] == [[LING]] * 4
+    ling = readers._cell(LING)
+    mod = ling.config_module
+    pairs = 8 * (4096 * 4097 // 2)
+    assert mod.flash_kernel_ops(ling.config, ling.traffic) == {
+        "ptpu_flash_fwd": 2 * 320 * pairs,
+        "ptpu_flash_bwd_dkdv": 2 * 640 * pairs,
+        "ptpu_flash_bwd_dq": 2 * 512 * pairs}
+    assert mod.embedding_grad_bytes(ling.config, ling.traffic) \
+        == 4 * 2560 * (39296 + 4096) == 444334080
+    even = np.zeros(512, np.int64)
+    even[:8] = 6 * 64               # 8 x 4096 / 512 rows a held expert
+    assert mod.expert_matmul_ops(ling.config, ling.traffic, even) \
+        == 3 * 3 * 2 * 2560 * 768 * 3072
+    assert sum(mod.forward_macs(ling.config, ling.traffic)
+               .values()) == pytest.approx(306.7e6, rel=1e-3)
+    assert mod.samples_per_step(ling.config, ling.traffic) == 4096
+    # seventeen cells, one of them on four chips: floor(17 x 0.25) = 4
+    assert [w["name"] for w in bench["workloads"]][16] == LING
+    assert [w["chips"] for w in bench["workloads"]][:17].count(4) == 1
     # sixteen cells, one of them on four chips: floor(16 x 0.25) = 4
     assert [w["name"] for w in bench["workloads"]][15] == SDAR
     assert [w["chips"] for w in bench["workloads"]][:16].count(4) == 1

@@ -392,7 +392,7 @@ def test_flash_attention_says_which_widths_it_takes():
      "sliding_window_layout=None"),
     (dict(full_attention_interval=2), "full_attention_interval=1"),
     (dict(topk_method="group_limited_greedy"), "topk_method"),
-    (dict(n_group=2), "n_group"),
+    (dict(n_group=3), "n_group"),
     (dict(v_head_dim=None), "lacks"),
 ])
 def test_resolve_refuses_by_name_what_is_not_built(edit, match):

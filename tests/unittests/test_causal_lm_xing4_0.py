@@ -434,12 +434,12 @@ def test_mhc_kernels_take_their_names_from_kernel_names():
 
 @pytest.mark.parametrize("edit,match", [
     (dict(num_nextn_predict_layers=1), "num_nextn_predict_layers"),
-    (dict(n_group=2), "n_group"),
+    (dict(n_group=3), "n_group"),
     (dict(topk_group=2), "topk_group"),
     (dict(rope_scaling=dict(YARN, type="linear")), "rope_scaling"),
     (dict(total_ut_steps=2, n_routed_experts=0), "hc_mult"),
     (dict(topk_method="group_limited_greedy"), "topk_method"),
-    (dict(q_lora_rank=None), "q_lora_rank"),
+    (dict(kv_lora_rank=None), "kv_lora_rank"),
     (dict(moe_layer_freq=2), "moe_layer_freq"),
     (dict(num_key_value_heads=2), "latent attention"),
 ])

@@ -76,10 +76,18 @@ PHASE_SAYS = {
         "Delta A = -6 a token" in line for line in lines) and sum(
         "O ssd kernels at chunks of" in line and "forward + backward" in line
         for line in lines) >= 2,
+    # phase Q held the KDA kernels to the scan path and to the recurrence
+    # under both decays, and timed both paths
+    "Q": lambda lines: sum(
+        "Q kda kernels, q/k/v/g [2, 72, 2, 16]" in line
+        and "off the scan path by out " in line and "dbeta" in line
+        and "off the recurrence by at most" in line
+        and "forward + backward kernel" in line for line in lines) == 2
+    and any("every channel at the bound" in line for line in lines),
 }
 
 
-@pytest.mark.parametrize("letter", "ABCDEFGHIJKLNO")
+@pytest.mark.parametrize("letter", "ABCDEFGHIJKLNOQ")
 def test_tiny_rehearsal_passes_every_phase(letter):
     """One case a phase (`--phases <letter>`), so that a red run names it."""
     # phase E times the program phase A left

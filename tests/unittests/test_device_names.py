@@ -184,12 +184,13 @@ def test_an_at_sign_would_cut_the_op_name_short():
 
 def test_every_pallas_call_takes_its_name_from_kernel_names():
     from paddle_tpu.ops import causal_conv_kernels, embedding_grad, \
-        expert_gmm, gated_delta_kernels, mhc_kernels, \
+        expert_gmm, gated_delta_kernels, kda_kernels, mhc_kernels, \
         rotary_kernels, selective_scan_kernels, ssd_kernels
     names = []
     for module in (pallas_kernels, gated_delta_kernels, causal_conv_kernels,
                    embedding_grad, mhc_kernels, expert_gmm,
-                   selective_scan_kernels, ssd_kernels, rotary_kernels):
+                   selective_scan_kernels, ssd_kernels, rotary_kernels,
+                   kda_kernels):
         with open(module.__file__) as f:
             tree = ast.parse(f.read())
         # mhc_kernels' pallas_calls sit in two helpers that are handed the
@@ -217,15 +218,16 @@ def test_every_pallas_call_takes_its_name_from_kernel_names():
     assert sorted(names) == sorted(pallas_kernels.KERNEL_NAMES)
     assert pallas_kernels.EXPERT_MATMUL_KERNELS == expert_gmm.KERNELS
     assert pallas_kernels.SELECTIVE_SCAN_KERNELS \
-        == pallas_kernels.KERNEL_NAMES[-7:-5]
-    assert pallas_kernels.SSD_KERNELS == pallas_kernels.KERNEL_NAMES[-5:-3]
+        == pallas_kernels.KERNEL_NAMES[-9:-7]
+    assert pallas_kernels.SSD_KERNELS == pallas_kernels.KERNEL_NAMES[-7:-5]
     # PR 65's two, named at the module's end: the forward walk with the
     # unit in it is an expert matmul, the buffer nothing wrote is not
-    assert pallas_kernels.KERNEL_NAMES[-3:-1] == (
+    assert pallas_kernels.KERNEL_NAMES[-5:-3] == (
         "ptpu_expert_gmm_unit_fwd", "ptpu_expert_rows_unwritten")
-    # PR 70's, at the module's end too
-    assert pallas_kernels.KERNEL_NAMES[-1] == "ptpu_rotary"
-    assert len(set(names)) == len(names) == 32
+    # PR 70's, and behind it PR 71's two, at the module's end too
+    assert pallas_kernels.KERNEL_NAMES[-3] == "ptpu_rotary"
+    assert pallas_kernels.KERNEL_NAMES[-2:] == pallas_kernels.KDA_KERNELS
+    assert len(set(names)) == len(names) == 34
     for a in names:         # a reader matching `<name>` or `<name>.<n>`
         for b in names:     # never counts one kernel under another
             assert a == b or not (b + ".").startswith(a + ".")

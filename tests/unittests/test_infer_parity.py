@@ -28,7 +28,7 @@ F32, BF16, I32 = "float32", "bfloat16", "int32"
 INFERRED = ("fused_attention", "layer_norm", "softmax_with_cross_entropy",
             "batch_norm", "mhc_pre", "mhc_post", "mhc_expand", "mhc_reduce",
             "gated_delta_rule", "causal_conv1d", "moe_ffn", "selective_scan",
-            "ssd_scan", "rotary_embedding")
+            "ssd_scan", "rotary_embedding", "kda_delta_rule")
 
 
 def _attention(t, hq, hkv, d, dv=None, rope=None, batch=-1, dtype=F32,
@@ -90,6 +90,13 @@ def _delta_rule(batch=-1, t=4096, hk=16, hv=32, d=128):
             {"Q": ((batch, t, hk, d), F32), "K": ((batch, t, hk, d), F32),
              "V": ((batch, t, hv, d), F32), "G": ((batch, t, hv), F32),
              "Beta": ((batch, t, hv), F32)}, ("Out",), {})
+
+
+def _kda_rule(batch=-1, t=4096, h=8, d=128):
+    return ("kda_delta_rule",
+            {"Q": ((batch, t, h, d), F32), "K": ((batch, t, h, d), F32),
+             "V": ((batch, t, h, d), F32), "G": ((batch, t, h, d), F32),
+             "Beta": ((batch, t, h), F32)}, ("Out",), {})
 
 
 def _conv(t, c, k, act=None, batch=-1):
@@ -168,6 +175,8 @@ CASES = {
     "mhc_reduce-odd-width": _mhc("mhc_reduce", t=16, c=24, n=2),
     "gated_delta_rule-qwen3next": _delta_rule(),
     "gated_delta_rule-static-batch": _delta_rule(batch=2),
+    "kda_delta_rule-ling": _kda_rule(),
+    "kda_delta_rule-static-batch": _kda_rule(batch=1, t=100),
     "causal_conv1d-qwen3next-silu": _conv(4096, 8192, 4, "silu"),
     "causal_conv1d-lfm2": _conv(8192, 2048, 3),
     "causal_conv1d-off-the-kernel": _conv(100, 96, 3, batch=2),

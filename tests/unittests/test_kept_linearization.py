@@ -21,7 +21,7 @@ CALLS_PALLAS = {"fused_attention", "layer_norm", "softmax_with_cross_entropy",
                 "sequence_pool", "sequence_softmax", "lstm", "lstmp",
                 "gated_delta_rule", "causal_conv1d", "mhc_pre", "mhc_post",
                 "mhc_expand", "mhc_reduce", "selective_scan", "ssd_scan",
-                "rotary_embedding"}
+                "rotary_embedding", "kda_delta_rule"}
 
 
 @pytest.fixture(autouse=True)
@@ -291,7 +291,8 @@ def test_the_rules_that_import_pallas_kernels_carry_the_field():
         src = inspect.getsource(od.lower)
         if re.search(r"import pallas_kernels|pallas_kernels\.|"
                      r"gated_delta_kernels|causal_conv_kernels|mhc_kernels|"
-                     r"selective_scan_kernels|ssd_kernels|rotary_kernels",
+                     r"selective_scan_kernels|ssd_kernels|rotary_kernels|"
+                     r"kda_kernels",
                      src):
             reach.add(op_type)
     assert reach == CALLS_PALLAS

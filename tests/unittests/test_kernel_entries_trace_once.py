@@ -22,7 +22,8 @@ import jax.numpy as jnp
 
 import paddle_tpu as fluid
 from paddle_tpu.observability.registry import REGISTRY
-from paddle_tpu.ops import causal_conv_kernels, gated_delta_kernels
+from paddle_tpu.ops import causal_conv_kernels, gated_delta_kernels, \
+    kda_kernels
 from paddle_tpu.ops import mhc_kernels, pallas_kernels
 from paddle_tpu.ops import rotary_kernels, selective_scan_kernels
 
@@ -78,6 +79,16 @@ def _delta_rule():
                                    (1, t, 2, 16), (1, t, 2), (1, t, 2))
         return q, k, v, -jax.nn.softplus(g), jax.nn.sigmoid(beta)
     return (lambda *a: gated_delta_kernels.gated_delta_rule(
+        *a, path="kernel", chunk=16)), args
+
+
+def _kda_rule():
+    def args(other, seed):
+        t = 16 if other else 32
+        q, k, v, g, beta = _normal(seed, (1, t, 2, 16), (1, t, 2, 16),
+                                   (1, t, 2, 16), (1, t, 2, 16), (1, t, 2))
+        return q, k, v, -5.0 * jax.nn.sigmoid(g), jax.nn.sigmoid(beta)
+    return (lambda *a: kda_kernels.kda_delta_rule(
         *a, path="kernel", chunk=16)), args
 
 
@@ -163,6 +174,9 @@ FAMILIES = {
         _delta_rule(),
         {"ptpu_gated_delta_fwd": 4, "ptpu_gated_delta_bwd": 2},
         {"ptpu_gated_delta_bwd": "_bwd_call"}),
+    "kda_delta_rule": (
+        _kda_rule(), {"ptpu_kda_fwd": 4, "ptpu_kda_bwd": 2},
+        {"ptpu_kda_bwd": "_bwd_call"}),
     "selective_scan": (
         _selective_scan(),
         {"ptpu_selective_scan_fwd": 2, "ptpu_selective_scan_bwd": 2},
