@@ -220,6 +220,19 @@ FULL = {
                         ("a head of 256", 1, 4096, 4, 256, 1e6)),
                  sweep=(1 << 19, 1 << 20, 1 << 21)),
     "kda": dict(b=1, t=4096, h=8, d=128, tol=2e-2, exact_tol=5e-2),
+    # (cell's tensor, B, T, heads, head, zero_centered): the heads a cell
+    # norms one by one under a weight [head]
+    "rms_head": dict(cases=(("SDAR q", 1, 8192, 32, 128, False),
+                            ("SDAR k", 1, 8192, 4, 128, False),
+                            ("Laguna full q", 1, 4096, 12, 128, False),
+                            ("Laguna sliding q", 1, 4096, 18, 128, False),
+                            ("Laguna k", 1, 4096, 2, 128, False),
+                            ("Qwen3-Next q", 1, 4096, 16, 256, True),
+                            ("Qwen3-Next k", 1, 4096, 2, 256, True),
+                            ("Ling o_norm", 1, 4096, 8, 128, False),
+                            ("Phi-4-mini-flash subln", 1, 8192, 20, 128,
+                             False)),
+                     sweep=(1 << 19, 1 << 20, 1 << 21)),
     "differential": dict(b=1, t=8192, pairs=20, kv_pairs=10, hd=64,
                          window=512, tol=2e-2),
     "latent_unequal": dict(b=2, t=4096, h=20, d=192, dr=64, dv=256,
@@ -284,10 +297,14 @@ TINY = {
     "selective_scan": dict(b=2, t=72, c=1024, n=4, tol=2e-3),
     "rope": dict(cases=(("q", 2, 40, 4, 128, 1e6), ("k", 1, 37, 2, 128, 1e4),
                         ("a head of 256", 1, 24, 2, 256, 1e6)),
-                 sweep=(1 << 14,)),
+                 sweep=(1 << 15,)),
     "ssd": dict(b=2, t=72, h=4, p=64, n=16, tol=3e-2, segment=8,
                 sweep=((16, 2),)),
     "kda": dict(b=2, t=72, h=2, d=16, tol=2e-2, exact_tol=5e-2),
+    "rms_head": dict(cases=(("q", 2, 40, 4, 128, False),
+                            ("k", 1, 37, 2, 128, False),
+                            ("a head of 256", 1, 24, 2, 256, True)),
+                     sweep=(1 << 16,)),
     "differential": dict(b=1, t=64, pairs=4, kv_pairs=2, hd=16, window=16,
                          tol=2e-2),
     "latent_unequal": dict(b=2, t=64, h=2, d=192, dr=64, dv=256,
@@ -2536,6 +2553,15 @@ def phase_o(smoke):
                                  "recurrence" % worst)
 
 
+def _bf16_ulps(a, b):
+    """(elements that differ, the largest difference in units of the last
+    place) of two bf16 arrays."""
+    a, b = (np.asarray(v).view(np.int16).astype(np.int32) for v in (a, b))
+    a, b = (np.where(v < 0, -32768 - v, v) for v in (a, b))
+    off = np.abs(a - b)
+    return int((off > 0).sum()), int(off.max())
+
+
 def phase_p(smoke):
     """rotary_embedding's rule on its two paths, the Pallas pass (Mosaic on
     a TPU) and the jax.numpy lines (XLA), on the same bf16 x and dy: y and
@@ -2573,14 +2599,6 @@ def phase_p(smoke):
             return y, vjp(dy)[0]
         return jax.jit(turn), jax.jit(both)
 
-    def ulps(a, b):
-        """(elements that differ, the largest difference in units of the
-        last place) of two bf16 arrays."""
-        a, b = (np.asarray(v).view(np.int16).astype(np.int32) for v in (a, b))
-        a, b = (np.where(v < 0, -32768 - v, v) for v in (a, b))
-        off = np.abs(a - b)
-        return int((off > 0).sum()), int(off.max())
-
     failed = []
     try:
         with jax.default_device(smoke.device):
@@ -2596,7 +2614,7 @@ def phase_p(smoke):
                 got, want = k_both(x, pos, dy), x_both(x, pos, dy)
                 counts = []
                 for name, u, v in zip(("y", "dx"), got, want):
-                    n, worst = ulps(u, v)
+                    n, worst = _bf16_ulps(u, v)
                     counts.append("%s %d of %d differ (largest %d ulp)"
                                   % (name, n, u.size, worst))
                     if n and (worst > 1 or n * 10000 > u.size):
@@ -2723,6 +2741,150 @@ def phase_q(smoke):
                              + "; ".join(failed))
 
 
+def phase_r(smoke):
+    """rms_norm over a head on its two paths, the jax.numpy lines with the
+    Pallas pass as their transpose (Mosaic on a TPU) and with the transpose
+    jax derives (XLA), on the same bf16 x and dy and float32 weight. The
+    forward pass is the lines on both (y equal); the transpose is written
+    out, with a head's sums over its lanes in another order, so: dx
+    differing on at most 1 element in 10,000 (3 where x is smaller than
+    that, the rehearsal's), each within one bf16 unit of its head's largest
+    |dx| (where g and xh * mean_D(g * xh) cancel, what is left of two
+    float32 roundings is many units of a result near 0, in either path: so
+    both paths' dx of the first rows are also set against the float64
+    formula rounded once, and the kernel may not be off on more elements
+    than XLA is, plus 1 in 10,000); dscale within 1e-5 of its largest
+    element. Then both are timed, forward and forward + transpose. x comes
+    and goes as [B*T, H*D] rows, as the projection beside the op writes and
+    reads it."""
+    import types
+    import jax
+    import jax.numpy as jnp
+    import paddle_tpu.ops  # noqa: F401 — registers the rule
+    from paddle_tpu.core import registry
+    from paddle_tpu.ops import kernel_config
+    from paddle_tpu.ops.nn_ops import rms_norm_path
+
+    c = smoke.cfg["rms_head"]
+    rule = registry.get("rms_norm").lower
+    ctx = types.SimpleNamespace(mesh=None, amp=False)
+    was = os.environ.get("PADDLE_TPU_PALLAS")
+    bf = jnp.bfloat16
+
+    def on_path(path, shape, zero_centered):
+        """(forward, forward + transpose) of the rule traced on `path`:
+        rows [B*T, H*D] in, rows out."""
+        attrs = {"begin_norm_axis": 3, "epsilon": 1e-6,
+                 "zero_centered": zero_centered}
+
+        def norm(x, scale):
+            os.environ["PADDLE_TPU_PALLAS"] = "rms_head" \
+                if path == "kernel" else "0"
+            x4 = x.reshape(shape)
+            ins = {"X": [x4], "Scale": [scale]}
+            assert rms_norm_path(ctx, x4, ins, attrs) == path
+            return rule(ctx, ins, attrs)["Y"][0].reshape(x.shape)
+
+        def both(x, scale, dy):
+            y, vjp = jax.vjp(norm, x, scale)
+            return (y,) + vjp(dy)
+        return jax.jit(norm), jax.jit(both)
+
+    def exact_dx(x, dy, scale, d, eps=1e-6):
+        """dx of the formula in float64 from the same bf16 x and dy."""
+        x, dy = (np.asarray(v.astype(jnp.float32), np.float64).reshape(
+            v.shape[0], -1, d) for v in (x, dy))
+        rstd = 1.0 / np.sqrt(np.square(x).mean(-1, keepdims=True) + eps)
+        g, xh = dy * np.asarray(scale, np.float64), x * rstd
+        dx = rstd * (g - xh * (g * xh).mean(-1, keepdims=True))
+        return jnp.asarray(dx.reshape(x.shape[0], -1), jnp.float32).astype(bf)
+
+    failed = []
+    try:
+        with jax.default_device(smoke.device):
+            for label, b, t, h, d, zero_centered in c["cases"]:
+                keys = jax.random.split(jax.random.key(72 + h), 3)
+                x, dy = (jax.random.normal(k, (b * t, h * d)).astype(bf)
+                         for k in keys[:2])
+                scale = 0.2 * jax.random.normal(keys[2], (d,)) \
+                    + (0.0 if zero_centered else 1.0)
+                shape = (b, t, h, d)
+                k_fwd, k_both = on_path("kernel", shape, zero_centered)
+                x_fwd, x_both = on_path("xla", shape, zero_centered)
+                got, want = k_both(x, scale, dy), x_both(x, scale, dy)
+                counts = []
+                few = max(3, x.size // 10000)
+                for name, u, v in zip(("y", "dx"), got, want):
+                    n, worst = _bf16_ulps(u, v)
+                    counts.append("%s %d of %d differ (largest %d ulp)"
+                                  % (name, n, u.size, worst))
+                    if n > few or (name == "y" and worst > 1):
+                        failed.append("%s %s" % (label, counts[-1]))
+                # dx where its two terms cancel: against its head's largest
+                dx, dx_want = (np.asarray(v.astype(jnp.float32)).reshape(
+                    b * t, h, d) for v in (got[1], want[1]))
+                unit = 2.0 ** (np.floor(np.log2(np.abs(dx_want).max(
+                    -1, keepdims=True))) - 7)
+                off_head = float((np.abs(dx - dx_want) / unit).max())
+                first = min(b * t, 256)
+                exact = exact_dx(x[:first], dy[:first],
+                                 scale + (1.0 if zero_centered else 0.0), d)
+                miss = [_bf16_ulps(v[:first], exact)[0] for v in (got[1], want[1])]
+                counts.append(
+                    "dx within %.2f bf16 units of its head's largest; of "
+                    "the first %d rows' %d elements %d are not the float64 "
+                    "formula's (xla: %d)" % (off_head, first, exact.size,
+                                             miss[0], miss[1]))
+                if off_head > 1 or miss[0] > miss[1] + max(
+                        3, exact.size // 10000):
+                    failed.append("%s %s" % (label, counts[-1]))
+                ds, ds_want = (np.asarray(v, np.float64) for v in (
+                    got[2], want[2]))
+                off = float(np.abs(ds - ds_want).max()
+                            / np.abs(ds_want).max())
+                if not off <= 1e-5:
+                    failed.append("%s dscale off by %.3g" % (label, off))
+                once = x.size * x.dtype.itemsize
+                smoke.say(
+                    "R rms_norm %s %s bf16%s: %s; dscale off by %.3g; ms "
+                    "forward kernel %.3f, xla %.3f; forward + transpose "
+                    "kernel %.3f, xla %.3f; the bytes at 819 GB/s: forward "
+                    "%.3f, transpose %.3f"
+                    % (label, list(shape),
+                       ", 1 + weight" if zero_centered else "",
+                       "; ".join(counts), off,
+                       _in_flight_ms(k_fwd, (x, scale)),
+                       _in_flight_ms(x_fwd, (x, scale)),
+                       _in_flight_ms(k_both, (x, scale, dy)),
+                       _in_flight_ms(x_both, (x, scale, dy)),
+                       1e3 * 2 * once / 819e9, 1e3 * 3 * once / 819e9))
+            label, b, t, h, d, zero_centered = c["cases"][0]
+            tiles = kernel_config.DEFAULT_TILES["rms_head"]
+            table = dict(tiles)
+            try:
+                x = jnp.ones((b * t, h * d), bf)
+                scale = jnp.ones((d,), jnp.float32)
+                for tile_bytes in c["sweep"]:
+                    tiles["tile_bytes"] = tile_bytes
+                    k_fwd, k_both = on_path("kernel", (b, t, h, d),
+                                            zero_centered)
+                    smoke.say("R rms_norm %s at blocks of %d KiB: ms forward "
+                              "%.3f, forward + transpose %.3f"
+                              % (label, tile_bytes >> 10,
+                                 _in_flight_ms(k_fwd, (x, scale)),
+                                 _in_flight_ms(k_both, (x, scale, x))))
+            finally:
+                tiles.update(table)
+    finally:
+        if was is None:
+            os.environ.pop("PADDLE_TPU_PALLAS", None)
+        else:
+            os.environ["PADDLE_TPU_PALLAS"] = was
+    if failed:
+        raise AssertionError("the rms_norm kernels are not the rule's "
+                             "arithmetic: " + "; ".join(failed))
+
+
 PHASES = (("A", "ResNet-50 training", phase_a),
           ("B", "transformer training", phase_b),
           ("C", "Pallas kernel families", phase_c),
@@ -2739,7 +2901,8 @@ PHASES = (("A", "ResNet-50 training", phase_a),
           ("N", "the selective scan and the differential core", phase_n),
           ("O", "the state-space-dual scan", phase_o),
           ("P", "rotary_embedding's one pass", phase_p),
-          ("Q", "the delta rule with a decay a channel", phase_q))
+          ("Q", "the delta rule with a decay a channel", phase_q),
+          ("R", "the norm over a head", phase_r))
 
 
 def main(argv=None):

@@ -545,8 +545,8 @@ def _lower_op_inner(ctx, op, env):
         _count_embedding_layer(ctx, ins)
     elif op.type == "mhc_pre":
         _count_hyper_connection_layer(ctx, op.attrs, ins)
-    elif op.type == "rotary_embedding":
-        _count_rotary_call(ctx, op.attrs, ins)
+    elif op.type in _COUNTED_BELOW:
+        _COUNTED_BELOW[op.type](ctx, op.attrs, ins)
     ctx.unread_outputs = _unread_outputs(ctx, od, op.outputs)
     if op.type == "softmax_with_cross_entropy":
         _count_softmax_xent_layer(ctx, op.attrs, ins)
@@ -956,6 +956,30 @@ def _count_rotary_call(ctx, attrs, ins):
     ).inc(path=rotary_path(ctx, x, ins["Pos"][0], attrs),
           heads=str(x.shape[2]), head_dim=str(x.shape[3]),
           rotary_dim=str(attrs.get("rotary_dim") or x.shape[3]))
+
+
+def _count_rms_norm_call(ctx, attrs, ins):
+    from ..observability.registry import REGISTRY
+    from ..ops.nn_ops import rms_norm_path
+    x = ins["X"][0]
+    if x.ndim != 4:
+        return      # a block norm: no head to count
+    REGISTRY.counter(
+        "ptpu_rms_norm_calls_total",
+        "rms_norm ops over a 4-D x lowered (forward ops, not a grad op's "
+        "replay), by who runs the norm's transpose (kernel: the one Pallas "
+        "pass of ops/rms_norm_kernels.py, where the norm is over a head of "
+        "whole lane tiles under a weight [D], ungated; xla: the transpose "
+        "jax derives from the jax.numpy lines), the heads and a head's width"
+    ).inc(path=rms_norm_path(ctx, x, ins, attrs), heads=str(x.shape[2]),
+          head_dim=str(x.shape[3]))
+
+
+# the ops _lower_op_inner counts through the two lines it had for the
+# rotary's counter: its own lines do not move (a Mosaic call's payload holds
+# the line of every Python frame that led to it)
+_COUNTED_BELOW = {"rotary_embedding": _count_rotary_call,
+                  "rms_norm": _count_rms_norm_call}
 
 
 def _count_embedding_layer(ctx, ins):

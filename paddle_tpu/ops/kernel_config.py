@@ -21,7 +21,7 @@ Accepted forms of PADDLE_TPU_PALLAS:
                           rest off.  Unknown names raise LOUDLY (a typo
                           must not silently run the other path).
 Op names: attn, xent, ln, lstm, seq, gdr, conv, emb, mhc, gmm, scan, ssd,
-rope, kda (KERNEL_OPS).  For 'attn' the flag is an opt-OUT only:
+rope, kda, rms_head (KERNEL_OPS).  For 'attn' the flag is an opt-OUT only:
 fused_attention's positive dispatch is always the flash_at() rule, so
 enabling 'attn' does not force flash below the crossover (pin
 FLAGS_flash_min_seq=0 for that).
@@ -137,6 +137,15 @@ __all__ = [
 # Mosaic, and the result's stay under 8 MiB of its 16 MiB of VMEM.  "kda"
 # (ops/kda_kernels.py) starts from "gdr"'s pair, whose kernels its own are
 # with the state transposed: not swept yet (PERF.md section 7, PR 71).
+# "rms_head" is the bytes of one block of rows of a group of heads of x
+# [B*T, H*D], in x's dtype, that a grid step of ops/rms_norm_kernels.py's
+# pass reads (a block of dy beside it, and it writes one of dx; no table).
+# The rows are rotary_kernels.block_rows' at no table: whole sublane tiles
+# that DIVIDE B*T.  Swept on the v5e (my chip run, PR 72: `chip_smoke.py
+# --phases R`, SDAR's q [8192, 4096] bf16, the lines forward + the kernel
+# in flight from the host, ms; see PERF.md section 6): flat from 512 KiB
+# to 2 MiB, the lane sums' pace and not the blocks'.  At 1 MiB the three
+# blocks, two buffers each, are 6 MiB of Mosaic's 16.
 DEFAULT_TILES = {
     "attn": {"block_q": 512, "block_k": 512},
     "xent": {"tile_bytes": 1 << 20},
@@ -152,6 +161,7 @@ DEFAULT_TILES = {
     "ssd": {"chunk": 128, "block_h": 16},
     "rope": {"tile_bytes": 2 << 20},
     "kda": {"chunk": 64, "block_h": 8},
+    "rms_head": {"tile_bytes": 1 << 20},
 }
 KERNEL_OPS = frozenset(DEFAULT_TILES)
 # Dense attention below this query length, flash at and above it.  The

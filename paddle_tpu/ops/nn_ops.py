@@ -274,7 +274,14 @@ def _rms_norm_math(x, scale, gate, begin, eps, zero_centered, scaled=None):
     return y.astype(x.dtype)
 
 
-@register("rms_norm")
+def _head_lines(x, scale, eps):
+    """The norm over the last axis of x under a float32 scale [D]:
+    _rms_norm_math's lines, as rms_norm_kernels.rms_norm takes them."""
+    return _rms_norm_math(x, scale, None, x.ndim - 1, eps, False)
+
+
+# the rule can hold a kernel: building a Program does not trace it
+@register("rms_norm", infer=shapes_from(Y="X"))
 def _rms_norm(ctx, ins, attrs):
     """y = scale * x / sqrt(mean(x^2) + eps) over the axes from
     begin_norm_axis on; the statistics and the product accumulate in
@@ -286,18 +293,45 @@ def _rms_norm(ctx, ins, attrs):
     given (x's shape): y = scale * x_hat * silu(gate), under jax.checkpoint
     so that the backward pass keeps x and the gate as they came and not
     their float32 copies (192 MiB a layer at [2, 4096, 32, 128]; AOT
-    compile, PR 33). Plain jax.numpy: no Pallas kernel until a benchmark
-    cell shows one winning."""
+    compile, PR 33). The forward pass is `_rms_norm_math`'s jax.numpy lines
+    everywhere; where `rms_norm_path` says so (the norm over a head of a 4-D
+    x: q's and k's, an `o_norm`, a `subln`) their transpose is one Pallas
+    pass over x's and dy's rows (ops/rms_norm_kernels.py), elsewhere the one
+    jax derives: the block norms, the gated and the grouped ones, heads of
+    64."""
     x = single(ins, "X")
     args = (attrs.get("begin_norm_axis", 1) % x.ndim,
             attrs.get("epsilon", 1e-5), attrs.get("zero_centered", False),
             attrs.get("begin_scale_axis"))
+    if rms_norm_path(ctx, x, ins, attrs) == "kernel":
+        from .rms_norm_kernels import rms_norm
+        _, eps, zero_centered, _ = args
+        scale = single(ins, "Scale").astype(jnp.float32)
+        return {"Y": [rms_norm(_head_lines, x,
+                               1.0 + scale if zero_centered else scale, eps)]}
     if ins.get("Gate"):
         y = jax.checkpoint(lambda x, s, g: _rms_norm_math(x, s, g, *args))(
             x, single(ins, "Scale"), single(ins, "Gate"))
     else:
         y = _rms_norm_math(x, single(ins, "Scale"), None, *args)
     return {"Y": [y]}
+
+
+def rms_norm_path(ctx, x, ins, attrs):
+    """"kernel" where rms_norm_kernels' one pass is the norm's transpose for
+    X: kernel_config.pallas_on("rms_head") (a TPU, or PADDLE_TPU_PALLAS), no
+    mesh, X [B, T, H, D] normed over its last axis alone, D whole lane
+    tiles, a scale of exactly [D] (no begin_scale_axis), no Gate, and rows
+    that whole blocks divide (rms_norm_kernels.applies); else "xla", the
+    jax.numpy lines: the block norms (3-D), the gated and the grouped norms,
+    heads of 64. The one place that decides; the counter reads it too."""
+    from .rms_norm_kernels import applies
+    fits = getattr(ctx, "mesh", None) is None and x.ndim == 4 \
+        and attrs.get("begin_norm_axis", 1) % x.ndim == 3 \
+        and attrs.get("begin_scale_axis") is None and not ins.get("Gate") \
+        and tuple(ins["Scale"][0].shape) == tuple(x.shape[3:]) \
+        and applies(x.shape)
+    return "kernel" if fits and pallas_on("rms_head") else "xla"
 
 
 def rotary_path(ctx, x, pos, attrs):

@@ -2098,3 +2098,6 @@ KERNEL_NAMES += ("ptpu_rotary",)
 # rule with a decay a key channel).
 KDA_KERNELS = ("ptpu_kda_fwd", "ptpu_kda_bwd")
 KERNEL_NAMES += KDA_KERNELS
+# PR 72: ops/rms_norm_kernels.py's one pass, the transpose of an rms_norm
+# over the heads of its rows.
+KERNEL_NAMES += ("ptpu_rms_norm_bwd",)

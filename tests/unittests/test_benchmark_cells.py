@@ -142,6 +142,82 @@ def test_an_expert_layer_says_where_its_unit_runs(name, route, monkeypatch):
                                       else None)
 
 
+# {cell: {(path, heads, head_dim): norms}}: the norms over a head a cell's
+# Program holds, as ptpu_rms_norm_calls_total books them where the step is
+# one TPU's (PR 72: ops/rms_norm_kernels.py's one pass, the norm's
+# transpose, enters five cells; a gated norm, a head of 64 and every block
+# norm keep jax's own transpose, and the other cells have no 4-D norm at all)
+HEAD_NORMS = {
+    "sdar_30b_a3b_chat_train_t4096": {("kernel", 32, 128): 4,
+                                      ("kernel", 4, 128): 4},
+    "laguna_s_2_1_train_t4096": {("kernel", 12, 128): 2,
+                                 ("kernel", 18, 128): 3,
+                                 ("kernel", 2, 128): 5},
+    "qwen3_next_80b_a3b_train_t4096": {("kernel", 16, 256): 1,
+                                       ("kernel", 2, 256): 1,
+                                       ("xla", 32, 128): 3},
+    "ling_3_0_flash_train_t4096": {("kernel", 8, 128): 6},
+    "phi4_mini_flash_train_t8192": {("kernel", 20, 128): 3},
+    "lfm2_8b_a1b_train_t8192": {("xla", 32, 64): 1, ("xla", 8, 64): 1},
+}
+
+
+@pytest.mark.parametrize("name", [c for c in CELLS if "resnet" not in c])
+def test_a_cell_says_which_of_its_norms_a_head_take_the_kernel(
+        name, monkeypatch):
+    """Every forward `rms_norm` op of the cell's Program through
+    lowering._count_rms_norm_call with no array, the step described as one
+    TPU's: the counter gains HEAD_NORMS' samples and no other (SDAR: 8
+    under path="kernel"); a cell that is not in the table has no norm over
+    a 4-D x, and its step does not change with the kernel."""
+    import types
+
+    import jax
+    import jax.numpy as jnp
+
+    import paddle_tpu as fluid
+    from benchmark import manifest
+    from paddle_tpu.core import lowering
+    from paddle_tpu.observability.registry import REGISTRY
+    from paddle_tpu.ops import kernel_config
+
+    monkeypatch.delenv("PADDLE_TPU_PALLAS", raising=False)
+    monkeypatch.setattr(kernel_config, "dispatch_platform", lambda: "tpu")
+    cell = manifest.load_cell(MANIFEST, name)
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.unique_name.guard(), fluid.program_guard(main, startup):
+        cell.config_module.build(fluid, cell.config, cell.traffic)
+    assert main._amp
+
+    def samples():
+        return {tuple(sorted(labels.items())): value for labels, value
+                in REGISTRY.snapshot().get(
+                    "ptpu_rms_norm_calls_total", {"samples": []})["samples"]}
+
+    def described(block, names, dtype):
+        # the batch axis is -1 in a Program: a cell feeds one sequence
+        return [jax.ShapeDtypeStruct(
+            tuple(abs(d) for d in block.var_recursive(n).shape), dtype)
+            for n in names]
+
+    before = samples()
+    ctx = types.SimpleNamespace(amp=True, mesh=None)
+    for block in main.blocks:
+        for op in block.ops:
+            if op.type == "rms_norm":
+                ins = {slot: described(block, names, jnp.bfloat16)
+                       for slot, names in op.inputs.items()}
+                lowering._count_rms_norm_call(ctx, op.attrs, ins)
+    moved = {}
+    for labels, value in samples().items():
+        if value != before.get(labels, 0):
+            labels = dict(labels)
+            moved[labels["path"], int(labels["heads"]),
+                  int(labels["head_dim"])] = value - before.get(
+                      tuple(sorted(labels.items())), 0)
+    assert moved == HEAD_NORMS.get(name, {})
+
+
 # ------------------------------------------ (b) the names the readers spell --
 READERS = sorted(
     glob.glob(os.path.join(REPO, "benchmark", "layer_metrics", "*.py"))

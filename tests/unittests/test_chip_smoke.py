@@ -84,10 +84,18 @@ PHASE_SAYS = {
         and "off the recurrence by at most" in line
         and "forward + backward kernel" in line for line in lines) == 2
     and any("every channel at the bound" in line for line in lines),
+    # phase R held the norm a head's two kernels to the jax.numpy lines and
+    # their vjp (y, dx, dscale), timed both paths, and swept the tile
+    "R": lambda lines: sum(
+        "R rms_norm " in line and "dx " in line and "dscale off by" in line
+        and "forward + transpose kernel" in line for line in lines) == 3
+    and any("a head of 256 [1, 24, 2, 256] bf16, 1 + weight" in line
+            for line in lines)
+    and any("at blocks of 64 KiB" in line for line in lines),
 }
 
 
-@pytest.mark.parametrize("letter", "ABCDEFGHIJKLNOQ")
+@pytest.mark.parametrize("letter", "ABCDEFGHIJKLNOPQR")
 def test_tiny_rehearsal_passes_every_phase(letter):
     """One case a phase (`--phases <letter>`), so that a red run names it."""
     # phase E times the program phase A left

@@ -185,12 +185,12 @@ def test_an_at_sign_would_cut_the_op_name_short():
 def test_every_pallas_call_takes_its_name_from_kernel_names():
     from paddle_tpu.ops import causal_conv_kernels, embedding_grad, \
         expert_gmm, gated_delta_kernels, kda_kernels, mhc_kernels, \
-        rotary_kernels, selective_scan_kernels, ssd_kernels
+        rms_norm_kernels, rotary_kernels, selective_scan_kernels, ssd_kernels
     names = []
     for module in (pallas_kernels, gated_delta_kernels, causal_conv_kernels,
                    embedding_grad, mhc_kernels, expert_gmm,
                    selective_scan_kernels, ssd_kernels, rotary_kernels,
-                   kda_kernels):
+                   kda_kernels, rms_norm_kernels):
         with open(module.__file__) as f:
             tree = ast.parse(f.read())
         # mhc_kernels' pallas_calls sit in two helpers that are handed the
@@ -218,16 +218,18 @@ def test_every_pallas_call_takes_its_name_from_kernel_names():
     assert sorted(names) == sorted(pallas_kernels.KERNEL_NAMES)
     assert pallas_kernels.EXPERT_MATMUL_KERNELS == expert_gmm.KERNELS
     assert pallas_kernels.SELECTIVE_SCAN_KERNELS \
-        == pallas_kernels.KERNEL_NAMES[-9:-7]
-    assert pallas_kernels.SSD_KERNELS == pallas_kernels.KERNEL_NAMES[-7:-5]
+        == pallas_kernels.KERNEL_NAMES[-10:-8]
+    assert pallas_kernels.SSD_KERNELS == pallas_kernels.KERNEL_NAMES[-8:-6]
     # PR 65's two, named at the module's end: the forward walk with the
     # unit in it is an expert matmul, the buffer nothing wrote is not
-    assert pallas_kernels.KERNEL_NAMES[-5:-3] == (
+    assert pallas_kernels.KERNEL_NAMES[-6:-4] == (
         "ptpu_expert_gmm_unit_fwd", "ptpu_expert_rows_unwritten")
-    # PR 70's, and behind it PR 71's two, at the module's end too
-    assert pallas_kernels.KERNEL_NAMES[-3] == "ptpu_rotary"
-    assert pallas_kernels.KERNEL_NAMES[-2:] == pallas_kernels.KDA_KERNELS
-    assert len(set(names)) == len(names) == 34
+    # PR 70's, and behind it PR 71's two and PR 72's one, at the module's
+    # end too
+    assert pallas_kernels.KERNEL_NAMES[-4] == "ptpu_rotary"
+    assert pallas_kernels.KERNEL_NAMES[-3:-1] == pallas_kernels.KDA_KERNELS
+    assert pallas_kernels.KERNEL_NAMES[-1] == "ptpu_rms_norm_bwd"
+    assert len(set(names)) == len(names) == 35
     for a in names:         # a reader matching `<name>` or `<name>.<n>`
         for b in names:     # never counts one kernel under another
             assert a == b or not (b + ".").startswith(a + ".")
@@ -1304,6 +1306,12 @@ def test_an_sdar_layers_rotary_on_a_described_v5e(one_chip, monkeypatch):
     assert {k: n for k, n in calls.items() if k[0] == "ptpu_rotary"} == {
         ("ptpu_rotary", "rotary_embedding"): 2,
         ("ptpu_rotary", "rotary_embedding_grad"): 2}
+    # the norm a head of q and of k (PR 72): its transpose is a kernel under
+    # the grad op, which replays the rule's jax.numpy lines for the forward
+    # (`rms_norm` keeps no linearization; what crosses the passes is the
+    # op's inputs); no kernel under the forward op
+    assert {k: n for k, n in calls.items() if "rms_norm" in k[0]} == {
+        ("ptpu_rms_norm_bwd", "rms_norm_grad"): 2}
     images = [line.strip()[:160] for line in text.splitlines()
               if re.search(r"= f32\[(1,)?%d,(%d,%d|%d)\][^ ]* convert\("
                            % (rows, heads, head, heads * head), line)

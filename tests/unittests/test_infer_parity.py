@@ -28,7 +28,7 @@ F32, BF16, I32 = "float32", "bfloat16", "int32"
 INFERRED = ("fused_attention", "layer_norm", "softmax_with_cross_entropy",
             "batch_norm", "mhc_pre", "mhc_post", "mhc_expand", "mhc_reduce",
             "gated_delta_rule", "causal_conv1d", "moe_ffn", "selective_scan",
-            "ssd_scan", "rotary_embedding", "kda_delta_rule")
+            "ssd_scan", "rotary_embedding", "kda_delta_rule", "rms_norm")
 
 
 def _attention(t, hq, hkv, d, dv=None, rope=None, batch=-1, dtype=F32,
@@ -124,6 +124,14 @@ def _rotary(t, h, d, batch=-1, dtype=F32, **attrs):
             ("Out",), dict({"base": 1e6}, **attrs))
 
 
+def _rms_norm(shape, begin, scale, dtype=F32, gate=False, **attrs):
+    ins = {"X": (shape, dtype), "Scale": ((scale,), F32)}
+    if gate:
+        ins["Gate"] = (shape, dtype)
+    return ("rms_norm", ins, ("Y",),
+            dict({"begin_norm_axis": begin, "epsilon": 1e-6}, **attrs))
+
+
 def _moe_ffn(t, d, f, experts, held, top_k, **attrs):
     return ("moe_ffn",
             {"X": ((-1, t, d), F32), "Router": ((d, experts), F32),
@@ -196,6 +204,17 @@ CASES = {
         inv_freq=[0.5 ** i for i in range(32)]),
     "rotary-qwen3next-64-of-256": _rotary(4096, 16, 256, rotary_dim=64),
     "rotary-glm-interleaved-64": _rotary(4096, 20, 64, layout="interleaved"),
+    # PR 72: the rule holds a kernel where the norm is over a head
+    "rms_norm-sdar-q-32x128": _rms_norm((-1, 8192, 32, 128), 3, 128),
+    "rms_norm-sdar-k-static-bfloat16": _rms_norm((1, 8192, 4, 128), 3, 128,
+                                                 BF16),
+    "rms_norm-qwen3next-zero-centred-256": _rms_norm(
+        (-1, 4096, 16, 256), 3, 256, zero_centered=True),
+    "rms_norm-qwen3next-gated": _rms_norm((-1, 4096, 32, 128), 3, 128,
+                                          gate=True),
+    "rms_norm-block-3d": _rms_norm((-1, 4096, 2048), 2, 2048),
+    "rms_norm-a-weight-a-group": _rms_norm((-1, 2048, 8, 512), 3, 4096,
+                                           begin_scale_axis=2),
     # PR 50's infer, a table since PR 53
     "moe_ffn-olmoe-64-all-held": _moe_ffn(4096, 2048, 1024, 64, 64, 8),
     "moe_ffn-smallthinker-64-a-quarter-held": _moe_ffn(

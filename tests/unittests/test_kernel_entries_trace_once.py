@@ -25,7 +25,9 @@ from paddle_tpu.observability.registry import REGISTRY
 from paddle_tpu.ops import causal_conv_kernels, gated_delta_kernels, \
     kda_kernels
 from paddle_tpu.ops import mhc_kernels, pallas_kernels
-from paddle_tpu.ops import rotary_kernels, selective_scan_kernels
+from paddle_tpu.ops import rms_norm_kernels, rotary_kernels
+from paddle_tpu.ops import selective_scan_kernels
+from paddle_tpu.ops.nn_ops import _head_lines
 
 F32 = jnp.float32
 FLASH = {"ptpu_flash_fwd": "_flash_fwd_call",
@@ -160,6 +162,17 @@ def _rotary():
     return call, args
 
 
+def _rms_norm():
+    """x [1, T, 2, 128] and the weight of a head, float32 as the rule hands
+    it over."""
+    def args(other, seed):
+        t = 16 if other else 32
+        x, scale = _normal(seed, (1, t, 2, 128), (128,))
+        return x, 1.0 + scale
+    return (lambda x, scale: rms_norm_kernels.rms_norm(
+        _head_lines, x, scale, 1e-6)), args
+
+
 FAMILIES = {
     "flash_plain": (_flash(2, 2, 64), dict.fromkeys(FLASH, 2), FLASH),
     "flash_grouped": (_flash(4, 2, 128), dict.fromkeys(FLASH, 2), FLASH),
@@ -214,6 +227,9 @@ FAMILIES = {
     # backward rule under an abstract mesh of its own, and jit keeps a trace
     # under its context)
     "rotary": (_rotary(), {"ptpu_rotary": 4}, {"ptpu_rotary": "_call"}, 2),
+    "rms_norm_head": (
+        _rms_norm(), {"ptpu_rms_norm_bwd": 2},
+        {"ptpu_rms_norm_bwd": "_bwd_call"}),
 }
 
 

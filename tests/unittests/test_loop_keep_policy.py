@@ -322,6 +322,25 @@ def test_a_reduction_over_a_lane_row_is_kept(shape, axes, rule):
     assert control_ops._kept_by(eqn.primitive, [x], eqn.params) is None
 
 
+@pytest.mark.parametrize("kernel,module,kept", [
+    ("ptpu_rotary", "rotary_kernels", None),
+    ("ptpu_rms_norm_bwd", "rms_norm_kernels", "kernel_output"),
+    ("ptpu_kda_fwd", "kda_kernels", "kernel_output")])
+def test_a_kernel_that_costs_its_bytes_is_replayed(kernel, module, kept):
+    """The first rule keeps a Pallas kernel's outputs, but not those of a
+    kernel whose entry says it is one read and one write of its operand
+    (`kernel_entry(.., costs_its_bytes=True)`, where it is defined): the
+    rotary's one pass. The policy holds no kernel's name."""
+    import importlib
+    import types
+    importlib.import_module("paddle_tpu.ops." + module)
+    prim = types.SimpleNamespace(name="pallas_call")
+    assert control_ops._kept_by(prim, [], {"name": kernel}) == kept
+    assert control_ops.keeps_across_passes(prim, name=kernel) \
+        == (kept is not None)
+    assert kernel not in open(control_ops.__file__).read()
+
+
 def test_a_loop_that_does_not_recompute_books_nothing(steps):
     """StaticRNN without `recompute` (the book's models): no checkpoint, no
     counter, no product named, and its body is not traced apart from the
