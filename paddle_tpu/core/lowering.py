@@ -529,27 +529,9 @@ def _lower_op_inner(ctx, op, env):
     od = registry.get(op.type)
     ins = {slot: [env.read(n) for n in names]
            for slot, names in op.inputs.items()}
-    if op.type == "moe_ffn":
-        _count_moe_layer(ctx, op.attrs, ins)
-    elif op.type == "fused_attention":
-        _count_attention_layer(ctx, op.attrs, ins)
-    elif op.type in ("gated_delta_rule", "kda_delta_rule"):
-        _count_linear_attention_layer(op.type, ins)
-    elif op.type == "causal_conv1d":
-        _count_causal_conv_layer(op.attrs, ins)
-    elif op.type == "selective_scan":
-        _count_selective_scan_layer(ins)
-    elif op.type == "ssd_scan":
-        _count_ssd_scan_layer(ins)
-    elif op.type == "lookup_table":
-        _count_embedding_layer(ctx, ins)
-    elif op.type == "mhc_pre":
-        _count_hyper_connection_layer(ctx, op.attrs, ins)
-    elif op.type in _COUNTED_BELOW:
-        _COUNTED_BELOW[op.type](ctx, op.attrs, ins)
     ctx.unread_outputs = _unread_outputs(ctx, od, op.outputs)
-    if op.type == "softmax_with_cross_entropy":
-        _count_softmax_xent_layer(ctx, op.attrs, ins)
+    if od.counts is not None:
+        od.counts(ctx, op.attrs, ins)
     if op.uid in ctx.linearized:
         # a grad op of this block differentiates this op: run the rule once,
         # under jax.vjp, and keep what the backward needs
@@ -707,293 +689,6 @@ def _count_grad_op(path, fwd_type):
         "grad ops lowered, by forward op type and by whether the op used the "
         "linearization its forward op kept or replayed the forward rule"
     ).inc(path=path, op=fwd_type)
-
-
-def _count_moe_layer(ctx, attrs, ins):
-    from ..observability.registry import REGISTRY
-    from ..parallel.moe import (KERNEL_MATMUL, matmul_route, numbered_by,
-                                rows_moved)
-    router, w_up = ins["Router"][0], ins["WUp"][0]
-    experts, held = router.shape[1], w_up.shape[0]
-    path = matmul_route(
-        w_up.shape[1], w_up.shape[2],
-        jnp.bfloat16 if ctx.amp else ins["X"][0].dtype, ctx.mesh)
-    # what the defaults leave as it was counts under the labels it always
-    # had: an ungated layer says so, and a router that reads another width
-    # than the experts' input says which, and a share narrower than top_k
-    # that its assignments are numbered by held expert, and a layer on the
-    # kernels' route that its unit is the gate/up kernel's epilogue
-    own = {}
-    if path == KERNEL_MATMUL:
-        own["unit"] = "kernel"
-    if not ins.get("WGate"):
-        own["gated"] = "false"
-    if numbered_by(experts, held, attrs["top_k"]) == "expert":
-        own["numbered"] = "expert"
-    if attrs.get("n_group"):
-        own["groups"] = str(attrs["n_group"])
-        own["kept_groups"] = str(attrs["topk_group"])
-    router_input = "pre_attention" if ins.get("RouterX") else "own"
-    if router.shape[0] != ins["X"][0].shape[-1]:
-        router_input = str(router.shape[0])
-    REGISTRY.counter(
-        "ptpu_moe_layers_total",
-        "moe_ffn ops lowered (forward ops, not a grad op's replay), by "
-        "experts a token, experts routed over, experts held, the gate's "
-        "activation, what the router reads (the experts' own input or "
-        "another tensor, pre_attention), the grouped-matmul route "
-        "(ragged_dot, or expert_gmm: the kernels of ops/expert_gmm.py), the "
-        "rows of the sorted buffer that every pass between the router "
-        "and the layer's output touches (all, or the tiles of the held "
-        "assignments: the four permutations, the two d rows' sum, the "
-        "unit's transpose, and the unit itself and every buffer's first "
-        "value where `unit` is kernel), how the router scores "
-        "(softmax or sigmoid), whether an expert bias enters the choice of "
-        "the top_k and the factor that scales the weights; `gated` false "
-        "where an expert is two matrices (activation relu2), and under "
-        "router_input the router's own input width where it is not the "
-        "experts'; `numbered` expert where the share held is narrower than "
-        "top_k and the assignments are numbered by held expert (held * N of "
-        "them, a group's rows by token and a token's sum in expert order) "
-        "and not by top-k slot (top_k * N, by slot then token, in score "
-        "order), as they are wherever the label is absent "
-        "(moe.numbered_by); `unit` kernel where the experts' unit runs as "
-        "the epilogue of the one kernel that multiplies a row tile by the "
-        "gate and up matrices and the buffers of sorted rows start as a "
-        "call's output that nothing filled (path expert_gmm: "
-        "expert_gmm.gmm_unit, moe._sorted_rows_start), absent where it is a "
-        "pass of XLA's over all the buffer's rows (path ragged_dot); "
-        "`groups` and `kept_groups` where the choice is limited to a "
-        "token's kept_groups best of `groups` runs of neighbouring experts "
-        "(moe._group_limited), absent where it is over all experts"
-    ).inc(top_k=str(attrs["top_k"]), experts=str(experts), held=str(held),
-          activation=str(attrs.get("activation", "silu")),
-          router_input=router_input,
-          path=path, rows=rows_moved(experts, held),
-          scoring=str(attrs.get("scoring", "softmax")),
-          bias=str(bool(ins.get("ExpertBias"))).lower(),
-          scale="%g" % attrs.get("scale", 1.0), **own)
-
-
-def _count_attention_layer(ctx, attrs, ins):
-    from ..observability.registry import REGISTRY
-    from ..ops.kernel_config import flash_at
-    from ..ops.pallas_kernels import heads_a_block, latent_form
-    q, k = ins["Q"][0], ins["K"][0]
-    window, bd = attrs.get("window"), attrs.get("block_diffusion")
-    if ctx.mesh is not None and ctx.mesh.shape.get("sp", 1) > 1:
-        path = str(attrs.get("sp_impl", "ring"))
-    else:
-        path = "flash" if flash_at(q.shape[1]) else "dense"
-    # how the flash kernels index a head of [B, T, H*D]: in place, so many
-    # heads a lane block, or after a transpose to a row a head
-    heads = heads_a_block(q.shape[2], k.shape[2], q.shape[3]) \
-        or "transposed" if path == "flash" else "none"
-    # the latent form's labels are its own: an op without QRope counts
-    # under the labels it always had
-    latent = {}
-    if ins.get("QRope"):
-        q_rope, k_rope = ins["QRope"][0], ins["KRope"][0]
-        v_dim = ins["V"][0].shape[3]
-        latent = dict(form="latent", v_dim=str(v_dim),
-                      rope_dim=str(q_rope.shape[3]),
-                      rope_key_group=str(q_rope.shape[2] // k_rope.shape[2]))
-        if v_dim != q.shape[3]:
-            # a part without position that is not the value's width: the
-            # form the flash kernels run the head in is one more label, and
-            # the lane blocks are the whole head's where it is joined
-            core = latent_form(q.shape[3], q_rope.shape[3], v_dim) \
-                if path == "flash" else "dense"
-            latent["core"] = core
-            if core == "whole":
-                heads = heads_a_block(q.shape[2], k.shape[2], v_dim) \
-                    or "transposed"
-    REGISTRY.counter(
-        "ptpu_attention_layers_total",
-        "fused_attention ops lowered (forward ops, not a grad op's replay), "
-        "by kind (full, or window with its size), query and key/value "
-        "heads, the path taken (flash, dense, or the sequence-parallel one), "
-        "the head's width and, on the flash path, the heads the kernels "
-        "index in one lane block (or transposed); the latent form besides "
-        "by form=latent, the value's width, the rotary part's width "
-        "(head_dim is then the part without position) and the query heads "
-        "that read one rotary key, and, where the value is not as wide as "
-        "the part without position (192 + 64 on 256), by core, the form the "
-        "head runs in: whole (the two parts joined, the plain kernels at "
-        "v_dim) or dense; an op under the block-diffusion mask alone counts "
-        "as kind block_diffusion with its block_length and copy_length (its "
-        "T rows are two copies of copy_length tokens)"
-    ).inc(kind="block_diffusion" if bd else "full" if window is None
-          else "window", **({} if not bd else dict(
-              block_length=str(bd[0]), copy_length=str(bd[1]))),
-          window=str(window or 0), q_heads=str(q.shape[2]),
-          kv_heads=str(k.shape[2]), path=path, head_dim=str(q.shape[3]),
-          heads_a_block=str(heads), **latent)
-
-
-def _count_hyper_connection_layer(ctx, attrs, ins):
-    from ..observability.registry import REGISTRY
-    from ..ops.hyper_connection_ops import mhc_path
-    x = ins["X"][0]
-    REGISTRY.counter(
-        "ptpu_hyper_connection_layers_total",
-        "mhc_pre ops lowered (forward ops, not a grad op's replay): the "
-        "sub-layers that read from and write to several residual streams, "
-        "by the streams, a stream's width, the Sinkhorn steps and the path "
-        "of the passes over the streams (the Pallas kernels, or XLA)"
-    ).inc(streams=str(attrs["streams"]),
-          width=str(x.shape[-1] // attrs["streams"]),
-          sinkhorn_iters=str(attrs["sinkhorn_iters"]),
-          path=mhc_path(ctx.mesh, x.shape, attrs["streams"]))
-
-
-def _count_softmax_xent_layer(ctx, attrs, ins):
-    from ..observability.registry import REGISTRY
-    from ..ops.nn_ops import softmax_xent_form
-    logits = ins["Logits"][0]
-    form = softmax_xent_form(ctx, logits, attrs)
-    seen = logits.dtype
-    if ctx.amp and form != ("kernel", False) and seen == jnp.bfloat16:
-        seen = jnp.dtype(jnp.float32)       # _apply_amp's upcast
-    REGISTRY.counter(
-        "ptpu_softmax_xent_layers_total",
-        "softmax_with_cross_entropy ops lowered (forward ops, not a grad "
-        "op's replay), by who computes the loss (the Pallas kernel, or "
-        "XLA), the dtype the rule reads the logits in (under AMP bfloat16 "
-        "where the kernel runs and builds no Softmax, float32 elsewhere) "
-        "and whether anything reads the dense Softmax output (unread, the "
-        "kernel path builds none; the XLA path builds it either way)"
-    ).inc(path=form[0], logits=str(seen),
-          softmax="unread" if "Softmax" in ctx.unread_outputs else "read")
-
-
-def _count_linear_attention_layer(op_type, ins):
-    from ..observability.registry import REGISTRY
-    from ..ops.kernel_config import DEFAULT_TILES
-    from ..ops.linear_attention_ops import gated_delta_path, kda_path
-    k, v = ins["K"][0], ins["V"][0]
-    kda = op_type == "kda_delta_rule"
-    own = {}
-    if kda:
-        from ..ops.kda_kernels import SUB_BLOCK
-        own["sub_block"] = str(SUB_BLOCK)
-    REGISTRY.counter(
-        "ptpu_linear_attention_layers_total",
-        "linear-attention ops lowered (forward ops, not a grad op's replay), "
-        "by kind (gated_delta: a decay a head; kda: a decay a key channel), "
-        "key and value heads and their widths, the chunk and the path of the "
-        "pass over chunks (the Pallas kernels, or lax.scan); and, for kda "
-        "alone, sub_block: the rows that share one reference for the "
-        "exponentials of the decayed products"
-    ).inc(kind="kda" if kda else "gated_delta", k_heads=str(k.shape[2]),
-          v_heads=str(v.shape[2]), d_k=str(k.shape[3]), d_v=str(v.shape[3]),
-          chunk=str(DEFAULT_TILES["kda" if kda else "gdr"]["chunk"]),
-          path=kda_path() if kda else gated_delta_path(), **own)
-
-
-def _count_causal_conv_layer(attrs, ins):
-    from ..observability.registry import REGISTRY
-    from ..ops.linear_attention_ops import causal_conv_path
-    x, w = ins["X"][0], ins["Filter"][0]
-    REGISTRY.counter(
-        "ptpu_causal_conv_layers_total",
-        "causal_conv1d ops lowered (forward ops, not a grad op's replay), by "
-        "the path taken (the two Pallas kernels, or XLA's shifted passes), "
-        "the filter's taps, the channels and the activation"
-    ).inc(path=causal_conv_path(x, w), width=str(w.shape[1]),
-          channels=str(w.shape[0]),
-          activation=str(attrs.get("activation", "none")))
-
-
-def _count_selective_scan_layer(ins):
-    from ..observability.registry import REGISTRY
-    from ..ops.kernel_config import DEFAULT_TILES
-    from ..ops.linear_attention_ops import selective_scan_path
-    x, a = ins["X"][0], ins["A"][0]
-    REGISTRY.counter(
-        "ptpu_selective_scan_layers_total",
-        "selective_scan ops lowered (forward ops, not a grad op's replay), "
-        "by the channels, the states a channel, the tokens between two "
-        "states the backward pass is given and the path (the two Pallas "
-        "kernels, or lax.scan over tokens)"
-    ).inc(channels=str(a.shape[0]), states=str(a.shape[1]),
-          chunk=str(DEFAULT_TILES["scan"]["chunk"]),
-          path=selective_scan_path(x, a))
-
-
-def _count_ssd_scan_layer(ins):
-    from ..observability.registry import REGISTRY
-    from ..ops.kernel_config import DEFAULT_TILES
-    from ..ops.linear_attention_ops import ssd_scan_path
-    x, b = ins["X"][0], ins["B"][0]
-    # B and C [B, T, G, N] say their groups; one group that all heads read
-    # counts under the labels it always had
-    groups = b.shape[2] if b.ndim == 4 else 1
-    REGISTRY.counter(
-        "ptpu_ssd_scan_layers_total",
-        "ssd_scan ops lowered (forward ops, not a grad op's replay), by the "
-        "heads (those held, where a share is), a head's channels, the "
-        "states a channel, the chunk, the path of the pass over chunks (the "
-        "two Pallas kernels, or lax.scan) and, where B and C come in more "
-        "than one, the groups"
-    ).inc(heads=str(x.shape[2]), head_dim=str(x.shape[3]),
-          states=str(b.shape[-1]), chunk=str(DEFAULT_TILES["ssd"]["chunk"]),
-          path=ssd_scan_path(x, groups),
-          **({"groups": str(groups)} if b.ndim == 4 else {}))
-
-
-def _count_rotary_call(ctx, attrs, ins):
-    from ..observability.registry import REGISTRY
-    from ..ops.nn_ops import rotary_path
-    x = ins["X"][0]
-    REGISTRY.counter(
-        "ptpu_rotary_calls_total",
-        "rotary_embedding ops lowered (forward ops, not a grad op's replay), "
-        "by the path taken (kernel: the one Pallas pass of "
-        "ops/rotary_kernels.py, where the whole head turns, half-split, and "
-        "a head is whole lane tiles; xla: the jax.numpy lines), the heads, "
-        "a head's width and the channels that turn"
-    ).inc(path=rotary_path(ctx, x, ins["Pos"][0], attrs),
-          heads=str(x.shape[2]), head_dim=str(x.shape[3]),
-          rotary_dim=str(attrs.get("rotary_dim") or x.shape[3]))
-
-
-def _count_rms_norm_call(ctx, attrs, ins):
-    from ..observability.registry import REGISTRY
-    from ..ops.nn_ops import rms_norm_path
-    x = ins["X"][0]
-    if x.ndim != 4:
-        return      # a block norm: no head to count
-    REGISTRY.counter(
-        "ptpu_rms_norm_calls_total",
-        "rms_norm ops over a 4-D x lowered (forward ops, not a grad op's "
-        "replay), by who runs the norm's transpose (kernel: the one Pallas "
-        "pass of ops/rms_norm_kernels.py, where the norm is over a head of "
-        "whole lane tiles under a weight [D], ungated; xla: the transpose "
-        "jax derives from the jax.numpy lines), the heads and a head's width"
-    ).inc(path=rms_norm_path(ctx, x, ins, attrs), heads=str(x.shape[2]),
-          head_dim=str(x.shape[3]))
-
-
-# the ops _lower_op_inner counts through the two lines it had for the
-# rotary's counter: its own lines do not move (a Mosaic call's payload holds
-# the line of every Python frame that led to it)
-_COUNTED_BELOW = {"rotary_embedding": _count_rotary_call,
-                  "rms_norm": _count_rms_norm_call}
-
-
-def _count_embedding_layer(ctx, ins):
-    from ..observability.registry import REGISTRY
-    from ..ops.embedding_grad import grad_form
-    w, ids = ins["W"][0], ins["Ids"][0]
-    REGISTRY.counter(
-        "ptpu_embedding_layers_total",
-        "lookup_table ops lowered (forward ops, not a grad op's replay), by "
-        "the rows looked up, the table's rows and width, and who builds the "
-        "table's dense gradient in the backward pass (XLA's scatter, or the "
-        "kernel that writes the table block by block)"
-    ).inc(rows=str(ids.size), vocab=str(w.shape[0]), width=str(w.shape[1]),
-          grad=grad_form(ids.size, w.shape[1], ctx.mesh))
 
 
 def _lower_grad_of(ctx, op, env):

@@ -76,6 +76,12 @@ class OpDef(object):
         # the function its grad op differentiates (core/lowering.py:
         # _unread_outputs)
         self.optional_outputs = tuple(optional_outputs)
+        # what the op reports of a forward lowering, `counts(ctx, attrs,
+        # ins)`, defined beside the rule and the decider it reads and set by
+        # `@counts(type)`; None for an op that reports nothing.
+        # core/lowering.py calls it for a forward op alone (not for a grad
+        # op's replay, not under shape inference) and names no op
+        self.counts = None
 
 
 _OPS = {}
@@ -91,6 +97,16 @@ def register(type, lower=None, infer=None, uses_rng=False,
         return fn
     if lower is not None:
         return deco(lower)
+    return deco
+
+
+def counts(type):
+    """Decorator: the function that counts a forward lowering of the
+    registered op `type` (`OpDef.counts`), in the module of its rule:
+    @counts('moe_ffn') above `def _count_moe_layer(ctx, attrs, ins)`."""
+    def deco(fn):
+        _OPS[type].counts = fn
+        return fn
     return deco
 
 

@@ -6,7 +6,8 @@ import numpy as np
 
 import jax.numpy as jnp
 
-from ..core.registry import register, shapes_from, single
+from ..core.registry import counts, register, shapes_from, single
+from ..observability.registry import REGISTRY
 from .kernel_config import pallas_on
 
 
@@ -53,6 +54,21 @@ def _mhc_pre(ctx, ins, attrs):
         (attrs["clamp_min"], attrs["clamp_max"]),
         _kernels(ctx, x.shape, attrs))
     return {"Out": [h], "Coef": [coef], "Stream": [stream]}
+
+
+@counts("mhc_pre")
+def _count_hyper_connection_layer(ctx, attrs, ins):
+    x = ins["X"][0]
+    REGISTRY.counter(
+        "ptpu_hyper_connection_layers_total",
+        "mhc_pre ops lowered (forward ops, not a grad op's replay): the "
+        "sub-layers that read from and write to several residual streams, "
+        "by the streams, a stream's width, the Sinkhorn steps and the path "
+        "of the passes over the streams (the Pallas kernels, or XLA)"
+    ).inc(streams=str(attrs["streams"]),
+          width=str(x.shape[-1] // attrs["streams"]),
+          sinkhorn_iters=str(attrs["sinkhorn_iters"]),
+          path=mhc_path(ctx.mesh, x.shape, attrs["streams"]))
 
 
 @register("mhc_post", calls_pallas=True, infer=shapes_from(Out="X"))

@@ -8,8 +8,9 @@ any of them: sequence_conv is LoD-based and dense over channels."""
 import jax
 import jax.numpy as jnp
 
-from ..core.registry import register, shapes_from, single
-from .kernel_config import pallas_on
+from ..core.registry import counts, register, shapes_from, single
+from ..observability.registry import REGISTRY
+from .kernel_config import DEFAULT_TILES, pallas_on
 
 
 def gated_delta_path():
@@ -33,6 +34,30 @@ def _gated_delta_rule(ctx, ins, attrs):
     return {"Out": [out]}
 
 
+def _count_linear_attention_layer(kind, tile, path, ins, **own):
+    """The one counter of the two delta rules: each registers beside its
+    rule and says its kind, its DEFAULT_TILES row, its path and its own
+    labels."""
+    k, v = ins["K"][0], ins["V"][0]
+    REGISTRY.counter(
+        "ptpu_linear_attention_layers_total",
+        "linear-attention ops lowered (forward ops, not a grad op's replay), "
+        "by kind (gated_delta: a decay a head; kda: a decay a key channel), "
+        "key and value heads and their widths, the chunk and the path of the "
+        "pass over chunks (the Pallas kernels, or lax.scan); and, for kda "
+        "alone, sub_block: the rows that share one reference for the "
+        "exponentials of the decayed products"
+    ).inc(kind=kind, k_heads=str(k.shape[2]), v_heads=str(v.shape[2]),
+          d_k=str(k.shape[3]), d_v=str(v.shape[3]),
+          chunk=str(DEFAULT_TILES[tile]["chunk"]), path=path, **own)
+
+
+@counts("gated_delta_rule")
+def _count_gated_delta_layer(ctx, attrs, ins):
+    _count_linear_attention_layer("gated_delta", "gdr", gated_delta_path(),
+                                  ins)
+
+
 def kda_path():
     """"kernel" where the channel-decay delta rule's Pallas kernels are on
     (kernel_config.pallas_on("kda"): a TPU, or PADDLE_TPU_PALLAS), else
@@ -53,6 +78,13 @@ def _kda_delta_rule(ctx, ins, attrs):
         operand_dtype=jnp.bfloat16 if getattr(ctx, "amp", False) else None,
         path=kda_path())
     return {"Out": [out]}
+
+
+@counts("kda_delta_rule")
+def _count_kda_layer(ctx, attrs, ins):
+    from .kda_kernels import SUB_BLOCK
+    _count_linear_attention_layer("kda", "kda", kda_path(), ins,
+                                  sub_block=str(SUB_BLOCK))
 
 
 def causal_conv_path(x, w):
@@ -93,6 +125,19 @@ def _causal_conv1d(ctx, ins, attrs):
     return {"Out": [conv(x, w)]}
 
 
+@counts("causal_conv1d")
+def _count_causal_conv_layer(ctx, attrs, ins):
+    x, w = ins["X"][0], ins["Filter"][0]
+    REGISTRY.counter(
+        "ptpu_causal_conv_layers_total",
+        "causal_conv1d ops lowered (forward ops, not a grad op's replay), by "
+        "the path taken (the two Pallas kernels, or XLA's shifted passes), "
+        "the filter's taps, the channels and the activation"
+    ).inc(path=causal_conv_path(x, w), width=str(w.shape[1]),
+          channels=str(w.shape[0]),
+          activation=str(attrs.get("activation", "none")))
+
+
 def selective_scan_path(x, a):
     """"kernel" where selective_scan_kernels' two passes run for X [B, T,
     C] under A [C, N]: kernel_config.pallas_on("scan") (a TPU, or
@@ -116,6 +161,20 @@ def _selective_scan(ctx, ins, attrs):
         "X", "Delta", "A", "B", "C", "D"))
     out = selective_scan(x, delta, a, b, c, d, path=selective_scan_path(x, a))
     return {"Out": [out.astype(x.dtype)]}
+
+
+@counts("selective_scan")
+def _count_selective_scan_layer(ctx, attrs, ins):
+    x, a = ins["X"][0], ins["A"][0]
+    REGISTRY.counter(
+        "ptpu_selective_scan_layers_total",
+        "selective_scan ops lowered (forward ops, not a grad op's replay), "
+        "by the channels, the states a channel, the tokens between two "
+        "states the backward pass is given and the path (the two Pallas "
+        "kernels, or lax.scan over tokens)"
+    ).inc(channels=str(a.shape[0]), states=str(a.shape[1]),
+          chunk=str(DEFAULT_TILES["scan"]["chunk"]),
+          path=selective_scan_path(x, a))
 
 
 def ssd_scan_path(x, groups=1):
@@ -149,3 +208,22 @@ def _ssd_scan(ctx, ins, attrs):
         path=ssd_scan_path(x, b.shape[2] if b.ndim == 4 else 1),
         operand_dtype=jnp.bfloat16 if getattr(ctx, "amp", False) else None)
     return {"Out": [out.astype(x.dtype)]}
+
+
+@counts("ssd_scan")
+def _count_ssd_scan_layer(ctx, attrs, ins):
+    x, b = ins["X"][0], ins["B"][0]
+    # B and C [B, T, G, N] say their groups; one group that all heads read
+    # counts under the labels it always had
+    groups = b.shape[2] if b.ndim == 4 else 1
+    REGISTRY.counter(
+        "ptpu_ssd_scan_layers_total",
+        "ssd_scan ops lowered (forward ops, not a grad op's replay), by the "
+        "heads (those held, where a share is), a head's channels, the "
+        "states a channel, the chunk, the path of the pass over chunks (the "
+        "two Pallas kernels, or lax.scan) and, where B and C come in more "
+        "than one, the groups"
+    ).inc(heads=str(x.shape[2]), head_dim=str(x.shape[3]),
+          states=str(b.shape[-1]), chunk=str(DEFAULT_TILES["ssd"]["chunk"]),
+          path=ssd_scan_path(x, groups),
+          **({"groups": str(groups)} if b.ndim == 4 else {}))

@@ -18,8 +18,10 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ..core.registry import register, set_like, shapes_from, single
+from ..core.registry import (counts, register, set_like, shapes_from,
+                             single)
 from ..core.utils import pair as _pair
+from ..observability.registry import REGISTRY
 from .kernel_config import flash_at, pallas_on
 
 
@@ -334,6 +336,22 @@ def rms_norm_path(ctx, x, ins, attrs):
     return "kernel" if fits and pallas_on("rms_head") else "xla"
 
 
+@counts("rms_norm")
+def _count_rms_norm_call(ctx, attrs, ins):
+    x = ins["X"][0]
+    if x.ndim != 4:
+        return      # a block norm: no head to count
+    REGISTRY.counter(
+        "ptpu_rms_norm_calls_total",
+        "rms_norm ops over a 4-D x lowered (forward ops, not a grad op's "
+        "replay), by who runs the norm's transpose (kernel: the one Pallas "
+        "pass of ops/rms_norm_kernels.py, where the norm is over a head of "
+        "whole lane tiles under a weight [D], ungated; xla: the transpose "
+        "jax derives from the jax.numpy lines), the heads and a head's width"
+    ).inc(path=rms_norm_path(ctx, x, ins, attrs), heads=str(x.shape[2]),
+          head_dim=str(x.shape[3]))
+
+
 def rotary_path(ctx, x, pos, attrs):
     """"kernel" where rotary_kernels' one pass runs for X [B, T, H, D]:
     kernel_config.pallas_on("rope") (a TPU, or PADDLE_TPU_PALLAS), no mesh,
@@ -401,6 +419,21 @@ def _rotary_embedding(ctx, ins, attrs):
     if not whole:
         parts.append(x32[..., d:])
     return _out(jnp.concatenate(parts, -1).astype(x.dtype))
+
+
+@counts("rotary_embedding")
+def _count_rotary_call(ctx, attrs, ins):
+    x = ins["X"][0]
+    REGISTRY.counter(
+        "ptpu_rotary_calls_total",
+        "rotary_embedding ops lowered (forward ops, not a grad op's replay), "
+        "by the path taken (kernel: the one Pallas pass of "
+        "ops/rotary_kernels.py, where the whole head turns, half-split, and "
+        "a head is whole lane tiles; xla: the jax.numpy lines), the heads, "
+        "a head's width and the channels that turn"
+    ).inc(path=rotary_path(ctx, x, ins["Pos"][0], attrs),
+          heads=str(x.shape[2]), head_dim=str(x.shape[3]),
+          rotary_dim=str(attrs.get("rotary_dim") or x.shape[3]))
 
 
 @register("lrn")
@@ -528,6 +561,25 @@ def _softmax_xent(ctx, ins, attrs):
             "Loss": [loss.astype(logits.dtype)]}
 
 
+@counts("softmax_with_cross_entropy")
+def _count_softmax_xent_layer(ctx, attrs, ins):
+    logits = ins["Logits"][0]
+    form = softmax_xent_form(ctx, logits, attrs)
+    seen = logits.dtype
+    if ctx.amp and form != ("kernel", False) and seen == jnp.bfloat16:
+        seen = jnp.dtype(jnp.float32)       # _apply_amp's upcast
+    REGISTRY.counter(
+        "ptpu_softmax_xent_layers_total",
+        "softmax_with_cross_entropy ops lowered (forward ops, not a grad "
+        "op's replay), by who computes the loss (the Pallas kernel, or "
+        "XLA), the dtype the rule reads the logits in (under AMP bfloat16 "
+        "where the kernel runs and builds no Softmax, float32 elsewhere) "
+        "and whether anything reads the dense Softmax output (unread, the "
+        "kernel path builds none; the XLA path builds it either way)"
+    ).inc(path=form[0], logits=str(seen),
+          softmax="unread" if "Softmax" in ctx.unread_outputs else "read")
+
+
 def _fused_attention_infer(block, op, out_vars):
     """Out is Q's [B, T, Hq] on V's last dim (the latent forms' value may be
     another width than the part without position), in V's dtype off the
@@ -639,6 +691,61 @@ def _fused_attention(ctx, ins, attrs):
     return _out(out)
 
 
+@counts("fused_attention")
+def _count_attention_layer(ctx, attrs, ins):
+    from .pallas_kernels import heads_a_block, latent_form
+    q, k = ins["Q"][0], ins["K"][0]
+    window, bd = attrs.get("window"), attrs.get("block_diffusion")
+    if ctx.mesh is not None and ctx.mesh.shape.get("sp", 1) > 1:
+        path = str(attrs.get("sp_impl", "ring"))
+    else:
+        path = "flash" if flash_at(q.shape[1]) else "dense"
+    # how the flash kernels index a head of [B, T, H*D]: in place, so many
+    # heads a lane block, or after a transpose to a row a head
+    heads = heads_a_block(q.shape[2], k.shape[2], q.shape[3]) \
+        or "transposed" if path == "flash" else "none"
+    # the latent form's labels are its own: an op without QRope counts
+    # under the labels it always had
+    latent = {}
+    if ins.get("QRope"):
+        q_rope, k_rope = ins["QRope"][0], ins["KRope"][0]
+        v_dim = ins["V"][0].shape[3]
+        latent = dict(form="latent", v_dim=str(v_dim),
+                      rope_dim=str(q_rope.shape[3]),
+                      rope_key_group=str(q_rope.shape[2] // k_rope.shape[2]))
+        if v_dim != q.shape[3]:
+            # a part without position that is not the value's width: the
+            # form the flash kernels run the head in is one more label, and
+            # the lane blocks are the whole head's where it is joined
+            core = latent_form(q.shape[3], q_rope.shape[3], v_dim) \
+                if path == "flash" else "dense"
+            latent["core"] = core
+            if core == "whole":
+                heads = heads_a_block(q.shape[2], k.shape[2], v_dim) \
+                    or "transposed"
+    REGISTRY.counter(
+        "ptpu_attention_layers_total",
+        "fused_attention ops lowered (forward ops, not a grad op's replay), "
+        "by kind (full, or window with its size), query and key/value "
+        "heads, the path taken (flash, dense, or the sequence-parallel one), "
+        "the head's width and, on the flash path, the heads the kernels "
+        "index in one lane block (or transposed); the latent form besides "
+        "by form=latent, the value's width, the rotary part's width "
+        "(head_dim is then the part without position) and the query heads "
+        "that read one rotary key, and, where the value is not as wide as "
+        "the part without position (192 + 64 on 256), by core, the form the "
+        "head runs in: whole (the two parts joined, the plain kernels at "
+        "v_dim) or dense; an op under the block-diffusion mask alone counts "
+        "as kind block_diffusion with its block_length and copy_length (its "
+        "T rows are two copies of copy_length tokens)"
+    ).inc(kind="block_diffusion" if bd else "full" if window is None
+          else "window", **({} if not bd else dict(
+              block_length=str(bd[0]), copy_length=str(bd[1]))),
+          window=str(window or 0), q_heads=str(q.shape[2]),
+          kv_heads=str(k.shape[2]), path=path, head_dim=str(q.shape[3]),
+          heads_a_block=str(heads), **latent)
+
+
 @register("sigmoid_cross_entropy_with_logits")
 def _sigmoid_xent(ctx, ins, attrs):
     x = single(ins, "X")
@@ -744,6 +851,20 @@ def _lookup_table(ctx, ins, attrs):
     out_shape = tuple(ids.shape[:-1]) + (w.shape[-1],) \
         if ids.shape and ids.shape[-1] == 1 else tuple(ids.shape) + (w.shape[-1],)
     return _out(out.reshape(out_shape))
+
+
+@counts("lookup_table")
+def _count_embedding_layer(ctx, attrs, ins):
+    from .embedding_grad import grad_form
+    w, ids = ins["W"][0], ins["Ids"][0]
+    REGISTRY.counter(
+        "ptpu_embedding_layers_total",
+        "lookup_table ops lowered (forward ops, not a grad op's replay), by "
+        "the rows looked up, the table's rows and width, and who builds the "
+        "table's dense gradient in the backward pass (XLA's scatter, or the "
+        "kernel that writes the table block by block)"
+    ).inc(rows=str(ids.size), vocab=str(w.shape[0]), width=str(w.shape[1]),
+          grad=grad_form(ids.size, w.shape[1], ctx.mesh))
 
 
 # ---------------------------------------------------------------------------

@@ -23,6 +23,7 @@ from jax import lax
 from ..core import registry
 from ..core.registry import single
 from ..core.lowering import Env, lower_block, PROGRAM_ERR
+from ..observability.registry import REGISTRY
 
 
 def _stage_runner(ctx, attrs):
@@ -179,3 +180,69 @@ def _moe_ffn_lower(ctx, ins, attrs):
 registry.register("moe_ffn", _moe_ffn_lower, infer=registry.shapes_from(
     Out="X", BalanceLoss=_scalar, ZLoss=_scalar,
     ExpertLoad=("Router", lambda shape, attrs: shape[1:2], "int32")))
+
+
+@registry.counts("moe_ffn")
+def _count_moe_layer(ctx, attrs, ins):
+    from ..parallel.moe import (KERNEL_MATMUL, matmul_route, numbered_by,
+                                rows_moved)
+    router, w_up = ins["Router"][0], ins["WUp"][0]
+    experts, held = router.shape[1], w_up.shape[0]
+    path = matmul_route(
+        w_up.shape[1], w_up.shape[2],
+        jnp.bfloat16 if ctx.amp else ins["X"][0].dtype, ctx.mesh)
+    # what the defaults leave as it was counts under the labels it always
+    # had: an ungated layer says so, and a router that reads another width
+    # than the experts' input says which, and a share narrower than top_k
+    # that its assignments are numbered by held expert, and a layer on the
+    # kernels' route that its unit is the gate/up kernel's epilogue
+    own = {}
+    if path == KERNEL_MATMUL:
+        own["unit"] = "kernel"
+    if not ins.get("WGate"):
+        own["gated"] = "false"
+    if numbered_by(experts, held, attrs["top_k"]) == "expert":
+        own["numbered"] = "expert"
+    if attrs.get("n_group"):
+        own["groups"] = str(attrs["n_group"])
+        own["kept_groups"] = str(attrs["topk_group"])
+    router_input = "pre_attention" if ins.get("RouterX") else "own"
+    if router.shape[0] != ins["X"][0].shape[-1]:
+        router_input = str(router.shape[0])
+    REGISTRY.counter(
+        "ptpu_moe_layers_total",
+        "moe_ffn ops lowered (forward ops, not a grad op's replay), by "
+        "experts a token, experts routed over, experts held, the gate's "
+        "activation, what the router reads (the experts' own input or "
+        "another tensor, pre_attention), the grouped-matmul route "
+        "(ragged_dot, or expert_gmm: the kernels of ops/expert_gmm.py), the "
+        "rows of the sorted buffer that every pass between the router "
+        "and the layer's output touches (all, or the tiles of the held "
+        "assignments: the four permutations, the two d rows' sum, the "
+        "unit's transpose, and the unit itself and every buffer's first "
+        "value where `unit` is kernel), how the router scores "
+        "(softmax or sigmoid), whether an expert bias enters the choice of "
+        "the top_k and the factor that scales the weights; `gated` false "
+        "where an expert is two matrices (activation relu2), and under "
+        "router_input the router's own input width where it is not the "
+        "experts'; `numbered` expert where the share held is narrower than "
+        "top_k and the assignments are numbered by held expert (held * N of "
+        "them, a group's rows by token and a token's sum in expert order) "
+        "and not by top-k slot (top_k * N, by slot then token, in score "
+        "order), as they are wherever the label is absent "
+        "(moe.numbered_by); `unit` kernel where the experts' unit runs as "
+        "the epilogue of the one kernel that multiplies a row tile by the "
+        "gate and up matrices and the buffers of sorted rows start as a "
+        "call's output that nothing filled (path expert_gmm: "
+        "expert_gmm.gmm_unit, moe._sorted_rows_start), absent where it is a "
+        "pass of XLA's over all the buffer's rows (path ragged_dot); "
+        "`groups` and `kept_groups` where the choice is limited to a "
+        "token's kept_groups best of `groups` runs of neighbouring experts "
+        "(moe._group_limited), absent where it is over all experts"
+    ).inc(top_k=str(attrs["top_k"]), experts=str(experts), held=str(held),
+          activation=str(attrs.get("activation", "silu")),
+          router_input=router_input,
+          path=path, rows=rows_moved(experts, held),
+          scoring=str(attrs.get("scoring", "softmax")),
+          bias=str(bool(ins.get("ExpertBias"))).lower(),
+          scale="%g" % attrs.get("scale", 1.0), **own)

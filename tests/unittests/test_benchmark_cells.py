@@ -73,7 +73,8 @@ def test_cell_builds_its_training_program_at_published_widths(name):
 def _expert_layers(name):
     """(attrs, {slot: [the input's shape and dtype]}) of every forward
     `moe_ffn` op of the cell's Program, and whether it computes under AMP:
-    what `lowering._count_moe_layer` reads of a layer, with no array."""
+    what the op's own count (`registry.get("moe_ffn").counts`) reads of a
+    layer, with no array."""
     import types
 
     import paddle_tpu as fluid
@@ -104,14 +105,15 @@ EXPERT_CELLS = [
 @pytest.mark.parametrize("route", ["expert_gmm", "ragged_dot"])
 @pytest.mark.parametrize("name", EXPERT_CELLS)
 def test_an_expert_layer_says_where_its_unit_runs(name, route, monkeypatch):
-    """ptpu_moe_layers_total counts every `moe_ffn` layer of an expert
-    cell's Program once under `unit="kernel"` where the step is one TPU's
-    (the kernels' route at the cell's published widths: the unit is the
-    gate/up kernel's epilogue, PR 65), and under no `unit` at all on
+    """ptpu_moe_layers_total, asked through the seam the lowering calls
+    (`registry.get("moe_ffn").counts`), counts every `moe_ffn` layer of an
+    expert cell's Program once under `unit="kernel"` where the step is one
+    TPU's (the kernels' route at the cell's published widths: the unit is
+    the gate/up kernel's epilogue, PR 65), and under no `unit` at all on
     `ragged_dot`'s (here, with no TPU)."""
     import types
 
-    from paddle_tpu.core import lowering
+    from paddle_tpu.core import registry
     from paddle_tpu.observability.registry import REGISTRY
     from paddle_tpu.ops import kernel_config
 
@@ -131,7 +133,7 @@ def test_an_expert_layer_says_where_its_unit_runs(name, route, monkeypatch):
     before = samples()
     ctx = types.SimpleNamespace(amp=amp, mesh=None)
     for attrs, ins in layers:
-        lowering._count_moe_layer(ctx, attrs, ins)
+        registry.get("moe_ffn").counts(ctx, attrs, ins)
     moved = {labels: value - before.get(labels, 0)
              for labels, value in samples().items()
              if value != before.get(labels, 0)}
@@ -165,8 +167,9 @@ HEAD_NORMS = {
 @pytest.mark.parametrize("name", [c for c in CELLS if "resnet" not in c])
 def test_a_cell_says_which_of_its_norms_a_head_take_the_kernel(
         name, monkeypatch):
-    """Every forward `rms_norm` op of the cell's Program through
-    lowering._count_rms_norm_call with no array, the step described as one
+    """Every forward `rms_norm` op of the cell's Program through the op's
+    own count (`registry.get("rms_norm").counts`, the seam the lowering
+    calls) with no array, the step described as one
     TPU's: the counter gains HEAD_NORMS' samples and no other (SDAR: 8
     under path="kernel"); a cell that is not in the table has no norm over
     a 4-D x, and its step does not change with the kernel."""
@@ -177,7 +180,7 @@ def test_a_cell_says_which_of_its_norms_a_head_take_the_kernel(
 
     import paddle_tpu as fluid
     from benchmark import manifest
-    from paddle_tpu.core import lowering
+    from paddle_tpu.core import registry
     from paddle_tpu.observability.registry import REGISTRY
     from paddle_tpu.ops import kernel_config
 
@@ -207,7 +210,7 @@ def test_a_cell_says_which_of_its_norms_a_head_take_the_kernel(
             if op.type == "rms_norm":
                 ins = {slot: described(block, names, jnp.bfloat16)
                        for slot, names in op.inputs.items()}
-                lowering._count_rms_norm_call(ctx, op.attrs, ins)
+                registry.get("rms_norm").counts(ctx, op.attrs, ins)
     moved = {}
     for labels, value in samples().items():
         if value != before.get(labels, 0):

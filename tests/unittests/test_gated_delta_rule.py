@@ -1,7 +1,9 @@
 """The gated delta rule op (ops/gated_delta_kernels.py): the chunked forward
 and backward, on the Pallas kernels (interpreted here) and on lax.scan,
 against the token-by-token recurrence of models/causal_lm_reference.py and
-jax.grad of it; (I + L)^-1 and the shape of its products; `_prepare` on
+jax.grad of it (the ten shapes of that comparison are a file of their own,
+test_gated_delta_recurrence.py: the suite's longest function in one
+process, and under `--dist loadfile` a file is one worker's); (I + L)^-1 and the shape of its products; `_prepare` on
 bf16 against float32 inputs; the op through a Program; its counter."""
 import numpy as np
 import pytest
@@ -51,41 +53,6 @@ def _forward_and_grads(fn, args, ct):
         grads = jax.grad(lambda *a: jnp.sum(fn(*a) * ct),
                          argnums=tuple(range(5)))(*args)
     return out, grads
-
-
-CASES = [     # id, T, chunk, kwargs of _inputs
-    ("t64_c16", 64, 16, {}),
-    ("t64_c64", 64, 64, {}),
-    ("t75_c16_ragged", 75, 16, {}),
-    ("t75_c32_ragged", 75, 32, {}),
-    ("t130_c128_ragged", 130, 128, {}),
-    ("one_chunk_short", 9, 16, {}),
-    ("g_near_zero", 48, 16, {"g_scale": 1e-4}),
-    ("g_strongly_negative", 48, 16, {"g_scale": 4.0, "g_shift": 12.0}),
-    ("one_head_each", 40, 16, {"hk": 3, "hv": 3}),
-    ("four_value_heads_a_key_head", 40, 16, {"hk": 1, "hv": 4}),
-]
-
-
-@pytest.mark.parametrize("path", ["kernel", "scan"])
-@pytest.mark.parametrize("name,t,chunk,kw", CASES, ids=[c[0] for c in CASES])
-def test_chunked_forward_and_backward_against_the_recurrence(
-        monkeypatch, path, name, t, chunk, kw):
-    # two blocks of heads: the second starts from a state scratch the first
-    # one left full
-    monkeypatch.setitem(kernel_config.DEFAULT_TILES, "gdr",
-                        dict(kernel_config.DEFAULT_TILES["gdr"], block_h=4))
-    args = _inputs(t, **kw)
-    ct = jnp.asarray(np.random.RandomState(1).randn(*args[2].shape),
-                     jnp.float32)
-    want, want_grads = _forward_and_grads(_recurrence, args, ct)
-    got, got_grads = _forward_and_grads(
-        lambda *a: gdk.gated_delta_rule(*a, path=path, chunk=chunk), args,
-        ct)
-    assert got.shape == want.shape and got.dtype == want.dtype
-    assert _error(got, want) < TOLERANCE
-    for which, a, b in zip("q k v g beta".split(), got_grads, want_grads):
-        assert _error(a, b, floor=1e-3) < 5 * TOLERANCE, which
 
 
 def test_kernel_and_scan_paths_run_the_same_arithmetic():

@@ -278,8 +278,14 @@ def _float_arrays(tree):
             if jnp.issubdtype(a.dtype, jnp.floating)]
 
 
-@pytest.mark.parametrize("family", sorted(FAMILIES))
-def test_a_kernel_entry_traces_once_a_shape(family):
+# The families are two files' (this one and
+# test_kernel_entries_trace_once_b.py, each with sorted(FAMILIES)[i::FILES]):
+# under `--dist loadfile` a file is one worker's from start to end, and the
+# files of the fewest cases are handed out last (ROADMAP C8, PR 73).
+FILES = 2
+
+
+def a_kernel_entry_traces_once_a_shape(family):
     (call, args), traces, entries = FAMILIES[family][:3]
     # the calls of an entry a site makes, each pass's the one jaxpr: one,
     # but where the backward pass is the forward's own entry
@@ -328,6 +334,11 @@ def test_a_kernel_entry_traces_once_a_shape(family):
         for a, b in zip(got, want):
             assert np.abs(b).max() > 0
             assert np.array_equal(a, b)
+
+
+@pytest.mark.parametrize("family", sorted(FAMILIES)[0::FILES])
+def test_a_kernel_entry_traces_once_a_shape(family):
+    a_kernel_entry_traces_once_a_shape(family)
 
 
 def test_a_three_layer_transformer_traces_a_kernel_a_variant(monkeypatch):
